@@ -10,6 +10,7 @@ does not exist on this path.
 
 from __future__ import annotations
 
+import sys
 from typing import Any, List, Optional, Sequence
 
 import numpy as np
@@ -66,12 +67,11 @@ def from_device_many(xs: Sequence[Any], dtype: Optional[Any] = None) -> List[np.
 
 
 def _is_jax_array(x: Any) -> bool:
-    try:
-        import jax
-
-        return isinstance(x, jax.Array)
-    except ImportError:  # pragma: no cover
-        return False
+    # a process that never imported jax holds no jax.Array — and must
+    # not import it for the asking: a client beside a chip-owning
+    # server stays off jax entirely
+    jax = sys.modules.get("jax")
+    return jax is not None and isinstance(x, jax.Array)
 
 
 def is_device_array(x: Any) -> bool:

@@ -68,6 +68,16 @@ def validate(dep: TpuDeployment) -> List[str]:
     for p in dep.predictors:
         if p.replicas < 1:
             problems.append(f"predictor {p.name!r}: replicas must be >= 1")
+        if p.mesh_axes or p.device_ids:
+            # nothing carries a predictor's placement to its components
+            # (controlplane/placement.py), so accepting these would run
+            # the graph on one chip under a multi-chip label
+            problems.append(
+                f"predictor {p.name!r}: predictor-level meshAxes/deviceIds are "
+                "not honoured by this deployer (every JAX_SERVER computes on "
+                "the first device); shard a generation component with its own "
+                "tp / dp / mesh_axes parameters instead"
+            )
         try:
             validate_graph(p.graph)
         except GraphSpecError as e:

@@ -265,18 +265,27 @@ def _spawn_remote_workers(spec: TpuDeployment):
             "seldon.io/worker-ready-timeout-s must be a positive number, "
             f"got {spec.annotations.get('seldon.io/worker-ready-timeout-s')!r}"
         )
+    # TLS terminates at the external gateway; internal DCN edges dial
+    # plaintext (the reference's in-cluster model), so workers must not
+    # inherit SELDON_TLS_*
+    worker_env = {"SELDON_TLS_CERT": "", "SELDON_TLS_KEY": "", "SELDON_TLS_CA": ""}
+    components = []
+    for _p, unit in remote_units:
+        if unit.component_class:
+            component = unit.component_class
+        elif unit.implementation:
+            component = implementation_path(unit.implementation)
+        else:
+            raise DeploymentSpecError(
+                f"remote node {unit.name!r} has no implementation/"
+                "component_class to run out-of-process"
+            )
+        # refused before anything is spawned
+        _reject_device_exclusive_remote(unit.name, component, worker_env)
+        components.append(component)
     supervisor = Supervisor()
     try:
-        for p, unit in remote_units:
-            if unit.component_class:
-                component = unit.component_class
-            elif unit.implementation:
-                component = implementation_path(unit.implementation)
-            else:
-                raise DeploymentSpecError(
-                    f"remote node {unit.name!r} has no implementation/"
-                    "component_class to run out-of-process"
-                )
+        for (p, unit), component in zip(remote_units, components):
             grpc_port = free_port()
             supervisor.add(
                 ProcessSpec(
@@ -286,10 +295,7 @@ def _spawn_remote_workers(spec: TpuDeployment):
                     grpc_port=grpc_port,
                     parameters_json=json.dumps(unit.parameters or []),
                     api="BOTH",
-                    # TLS terminates at the external gateway; internal DCN
-                    # edges dial plaintext (the reference's in-cluster
-                    # model), so workers must not inherit SELDON_TLS_*
-                    env={"SELDON_TLS_CERT": "", "SELDON_TLS_KEY": "", "SELDON_TLS_CA": ""},
+                    env=dict(worker_env),
                 ),
                 wait_ready_s=ready_s,
             )
@@ -315,23 +321,38 @@ def _reject_device_exclusive_root(predictor: str, component: str, hpa) -> None:
     unimportable component class is the subprocess's problem, not this
     guard's — skip silently.
     """
-    import importlib
+    from seldon_core_tpu.controlplane.supervisor import component_device_exclusive
 
     if getattr(hpa, "max_replicas", 2) <= 1:
         return
-    module, _, cls = component.rpartition(".")
-    try:
-        klass = getattr(importlib.import_module(module), cls)
-    except Exception:  # noqa: BLE001 — unimportable component: the
-        # device-exclusivity probe is advisory; load reports the real error
-        return
-    if getattr(klass, "device_exclusive", False):
+    if component_device_exclusive(component):
         raise DeploymentSpecError(
             f"predictor {predictor!r}: hpa subprocess replicas are not "
             f"possible for TPU-device-exclusive component {component!r} "
             "(libtpu is single-process per chip). Scale in-process "
             "instead: raise max_batch_size / batcher concurrency, or "
-            "give the predictor more chips via mesh_axes."
+            "give the component more chips (tp= / dp= / mesh_axes)."
+        )
+
+
+def _reject_device_exclusive_remote(node: str, component: str, env: Dict[str, str]) -> None:
+    """The same guard for a ``remote: true`` node.  The deployer process
+    builds the rest of the graph in-process and may itself hold the
+    chip, and a process that initialises jax takes every chip it can
+    see — a device-exclusive worker beside it would hang on device
+    acquisition.  A deployment held to the CPU backend
+    (``JAX_PLATFORMS=cpu``, which workers inherit) has no chip to fight
+    over and passes."""
+    from seldon_core_tpu.controlplane.supervisor import needs_chip
+
+    if needs_chip(component, env):
+        raise DeploymentSpecError(
+            f"remote node {node!r}: TPU-device-exclusive component "
+            f"{component!r} cannot run as a worker process beside the "
+            "deployer (libtpu is single-process per chip). Run it "
+            "in-process (drop `remote: true`): one process can drive "
+            "every chip of the host (tp= / dp= / mesh_axes) and can hold "
+            "several one-chip replicas."
         )
 
 
@@ -681,6 +702,10 @@ def main(argv: Optional[List[str]] = None) -> None:
         default_and_validate(spec)
         print(f"deployment {spec.name!r} is valid")
         return
+
+    from seldon_core_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
     async def _run():
         import signal

@@ -2,18 +2,24 @@
 
 The reference's scheduler is Kubernetes: one container per graph node,
 kube-scheduler picks machines.  Here the schedulable resource is the
-TPU device set of this host (and, later, of peer hosts over DCN): each
-predictor gets a device group sized by its ``mesh_axes`` request (or
-one device), chosen round-robin so co-deployed predictors don't
-contend for the same chip (the multi-tenancy concern of SURVEY §7
-"hard parts").
+TPU device set of this host: each predictor gets a device group sized
+by its ``mesh_axes`` request (or one device), chosen round-robin.
+
+The plan is BOOKKEEPING: no component consumes it yet (every
+``JAX_SERVER`` computes on ``jax.devices()[0]``; generation components
+take ``tp``/``dp``/``mesh_axes`` as their own parameters), which is why
+``default_and_validate`` refuses predictor-level ``meshAxes`` and
+``deviceIds``.  It is planned over the device ids the operator passes
+and never asks jax for them: a process that initialises the backend
+holds the chip, and the deployer may be about to spawn the worker that
+needs it.
 """
 
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Dict, List, Optional
 
 from seldon_core_tpu.controlplane.spec import DeploymentSpecError, TpuDeployment
 
@@ -23,18 +29,6 @@ class PredictorPlacement:
     predictor: str
     device_ids: List[int]
     mesh_axes: Optional[Dict[str, int]] = None
-
-    def build_mesh(self):
-        """Materialise the jax Mesh for this placement (None = 1 device)."""
-        import jax
-
-        from seldon_core_tpu.parallel.mesh import create_mesh
-
-        all_devices = {d.id: d for d in jax.devices()}
-        devices = [all_devices[i] for i in self.device_ids]
-        if self.mesh_axes:
-            return create_mesh(dict(self.mesh_axes), devices=devices)
-        return create_mesh({"data": len(devices)}, devices=devices)
 
 
 @dataclass
@@ -51,13 +45,12 @@ def plan_placement(dep: TpuDeployment, device_ids: Optional[List[int]] = None) -
     Explicit ``deviceIds`` on a predictor are honoured (after checking
     they exist and don't collide); others are packed round-robin.
     A ``mesh_axes`` request sizes the group to the mesh volume.
+    ``device_ids=None`` (the CLI's default) plans nothing.
     """
-    if device_ids is None:
-        import jax
-
-        device_ids = [d.id for d in jax.devices()]
-    available = list(device_ids)
     plan = PlacementPlan()
+    if device_ids is None:
+        return plan
+    available = list(device_ids)
 
     # explicit claims first
     for p in dep.predictors:

@@ -40,6 +40,30 @@ class ProcessSpec:
     cwd: Optional[str] = None
 
 
+def component_device_exclusive(component: str) -> Optional[bool]:
+    """Whether the dotted ``module.Class`` declares ``device_exclusive``
+    (libtpu binds one process per chip, and a process that initialises
+    jax takes every chip it can see).  ``None`` when the class cannot
+    be imported here: that is the worker's error to report at load, and
+    an unknown class is neither pinned nor refused."""
+    import importlib
+
+    module, _, cls = component.rpartition(".")
+    try:
+        klass = getattr(importlib.import_module(module or cls), cls)
+    except Exception:  # noqa: BLE001 — advisory probe of user code
+        return None
+    return bool(getattr(klass, "device_exclusive", False))
+
+
+def needs_chip(component: str, env: Dict[str, str]) -> bool:
+    """A worker that will take the chip: a ``device_exclusive``
+    component whose environment (``env``, else the inherited one) does
+    not hold it to the CPU backend."""
+    platforms = env.get("JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", ""))
+    return platforms != "cpu" and component_device_exclusive(component) is True
+
+
 def _default_journal_path(spec: ProcessSpec) -> str:
     """Stable-per-worker drain-journal path (r12): the SAME path across
     respawns of one worker — a SIGTERM'd process drains its live
@@ -95,6 +119,13 @@ class SupervisedProcess:
     def _spawn(self) -> None:
         env = dict(os.environ)
         env.update(self.spec.env)
+        if (
+            "JAX_PLATFORMS" not in self.spec.env
+            and component_device_exclusive(self.spec.component) is False
+        ):
+            # a component that does not claim the device must never
+            # take a chip its parent or a sibling worker holds
+            env["JAX_PLATFORMS"] = "cpu"
         self.proc = subprocess.Popen(self._command(), env=env, cwd=self.spec.cwd)
         logger.info("spawned node %s pid=%d", self.spec.name, self.proc.pid)
 
@@ -319,6 +350,24 @@ class Supervisor:
         return started
 
     def add(self, spec: ProcessSpec, wait_ready_s: float = 30.0) -> SupervisedProcess:
+        if needs_chip(spec.component, spec.env):
+            holder = next(
+                (n for n, sp in self.processes.items()
+                 if needs_chip(sp.spec.component, sp.spec.env)),
+                None,
+            )
+            if holder is not None:
+                from seldon_core_tpu.controlplane.spec import DeploymentSpecError
+
+                raise DeploymentSpecError(
+                    f"worker {spec.name!r} ({spec.component}) needs the chip "
+                    f"that worker {holder!r} already holds: libtpu binds one "
+                    "process per chip, so a second TPU-device-exclusive worker "
+                    "would hang on device acquisition. One process can drive "
+                    "every chip of the host (tp=/dp=) and can hold several "
+                    "one-chip replicas; hold extra workers to the CPU backend "
+                    "with JAX_PLATFORMS=cpu in their env."
+                )
         sp = SupervisedProcess(spec)
         sp.start()
         if wait_ready_s and not sp.wait_ready(wait_ready_s):

@@ -130,8 +130,8 @@ class NativeIngressHandle:
 
 class _DeploymentRawHandler:
     """GatewayRawHandler plus the non-engine GET endpoints the Python
-    app serves (/metrics, /seldon.json) so the native ingress is a
-    drop-in replacement on the HTTP port."""
+    app serves (/metrics, /seldon.json, /health/status) so the native
+    ingress is a drop-in replacement on the HTTP port."""
 
     def __init__(self, gateway, loop):
         from seldon_core_tpu.native.frontserver import GatewayRawHandler
@@ -150,6 +150,12 @@ class _DeploymentRawHandler:
                 return 200, CONTENT_TYPE_LATEST.split(";")[0], generate_latest()
             except Exception as e:  # noqa: BLE001
                 return 500, "text/plain", str(e).encode()
+        if method == "GET" and bare == "/health/status":
+            from seldon_core_tpu.engine.server import components_health
+
+            status = components_health(self._inner.gateway)
+            return 200, "application/json", json.dumps(
+                {"frontend": "native", "predictors": status}).encode()
         if method == "GET" and bare == "/seldon.json":
             from seldon_core_tpu.runtime.openapi import gateway_openapi
 

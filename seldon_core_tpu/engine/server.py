@@ -233,6 +233,22 @@ class Gateway:
                 logger.exception("gateway request logger close failed")
 
 
+def components_health(gateway) -> Dict[str, Dict[str, object]]:
+    """predictor -> node -> the local component's ``health_status()``:
+    the gateway's ``GET /health/status`` body on either frontend, so a
+    client of a deployment can tell which device served it (the
+    microservice CLI serves the same hook per component)."""
+    out: Dict[str, Dict[str, object]] = {}
+    for svc in gateway.predictors:
+        nodes = {}
+        for unit in svc.graph.walk():
+            fn = getattr(svc.executor.component(unit.name), "health_status", None)
+            if fn is not None:
+                nodes[unit.name] = fn()
+        out[svc.name] = nodes
+    return out
+
+
 def _http_status(out: InternalMessage) -> int:
     """HTTP code for a gateway response: FAILURE statuses surface their
     code (clamped to a valid HTTP error range), everything else is 200.
@@ -487,6 +503,10 @@ def build_gateway_app(gateway: Gateway, auth=None) -> web.Application:
     async def ready(_r: web.Request) -> web.Response:
         ok = await gateway.ready()
         return web.Response(text="ready" if ok else "not ready", status=200 if ok else 503)
+
+    async def health_status(_r: web.Request) -> web.Response:
+        status = await asyncio.to_thread(components_health, gateway)
+        return web.json_response({"frontend": "python", "predictors": status})
 
     async def pause(_r: web.Request) -> web.Response:
         gateway.pause()
@@ -799,6 +819,7 @@ def build_gateway_app(gateway: Gateway, auth=None) -> web.Application:
     app.router.add_get("/ping", ping)
     app.router.add_get("/live", live)
     app.router.add_get("/ready", ready)
+    app.router.add_get("/health/status", health_status)
     app.router.add_route("*", "/pause", pause)
     app.router.add_route("*", "/unpause", unpause)
     app.router.add_get("/metrics", metrics_endpoint)

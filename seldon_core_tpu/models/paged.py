@@ -1618,6 +1618,14 @@ class PagedEngine:
             self._chunk_impl == "pool" and kernel_eligible
             and not self._pool_flat
         )
+        # which kernel implementation serves this geometry (a stream
+        # request on an unaligned h*hd is swapped for grid) — reported
+        # next to kernel_active in lane_report()
+        self._kernel_impl = None
+        if self._kernel_active:
+            from seldon_core_tpu.ops.kernels import paged_kernel_impl
+
+            self._kernel_impl = paged_kernel_impl(num_heads, head_dim)
         # r18 int8 KV pool: pages rest int8 with ONE f32 scale per page
         # per k/v in a sibling (layers, num_pages) table — half the
         # pool bytes (≈2x paged_capacity_streams), dequantised
@@ -4511,9 +4519,9 @@ class PagedEngine:
             return []
         g = len(finals)
         # batched tail: per-stream .at[].set / key() calls are tiny
-        # device dispatches, and ~3 per stream serialised through a
-        # relayed dispatch stream measured as a large share of
-        # admission wall time at 16 joiners.  Three dispatches total
+        # device dispatches, and ~3 per stream serialise on the
+        # dispatch stream (a large share of admission wall time at 16
+        # joiners on a slow link).  Three dispatches total
         # instead: one fixed-shape key derivation, two scatters.
         slots = jnp.asarray(
             np.array([s.slot for _i, s in finals], np.int32)
@@ -5504,6 +5512,23 @@ class PagedEngine:
         with self._lock:
             return bool(self._queue) or any(s is not None for s in self._slots)
 
+    def lane_report(self) -> Dict[str, Any]:
+        """The static lane this engine was built on — what a client
+        needs to tell WHERE and HOW it is served (``/health/status``):
+        the mesh degrees the engine got (not what was requested), the
+        chunk implementation, and whether decode attention runs the
+        Pallas kernel and which implementation of it."""
+        return {
+            "tp": self.tp_degree,
+            "dp": self.dp_degree,
+            "chunk_impl": self._chunk_impl,
+            "pool_layout": "flat" if self._pool_flat else "split",
+            "kv_dtype": "int8" if self._kv_int8 else str(np.dtype(self._dtype)),
+            "kernel_active": self._kernel_active,
+            "kernel_impl": self._kernel_impl,
+            "pool_shard_bytes": self._pool_shard_bytes,
+        }
+
     def engine_stats(self, detail: bool = False) -> Dict[str, Any]:
         """Counters + live occupancy, the generation observability
         surface (jaxserver's batcher stats equivalent).
@@ -6360,9 +6385,8 @@ class PagedEngine:
             model_drafts = None
             if mode == "model" and runnable:
                 # one batched rollout call for every runnable slot (the
-                # draft is small; through a relayed host this adds one
-                # round-trip per round — on attached hardware it is
-                # microseconds).  Windows end at each stream's pending
+                # draft is small: one extra device round-trip per
+                # round).  Windows end at each stream's pending
                 # token (tokens[-1] — the loop invariant), so drafts
                 # continue exactly the sequence the verify checks.
                 W = self.draft_window
@@ -7454,6 +7478,20 @@ class StreamingLM(TPUComponent):
             return self.telemetry_snapshot(window_s)
 
         return {"/debug/telemetry": debug_telemetry}
+
+    def health_status(self):
+        """Where this replica runs: the device as jax reports it, the
+        serving-mesh degrees the engine actually got (a degraded
+        ``tp=``/``dp=`` request shows here) and the decode lane."""
+        from seldon_core_tpu.parallel.mesh import device_report
+
+        out: Dict[str, Any] = {
+            "loaded": self.engine is not None,
+            "device": device_report(),
+        }
+        if self.engine is not None:
+            out.update(self.engine.lane_report())
+        return out
 
     def metrics(self):
         """Paged-engine health for the dashboards.  All GAUGEs:

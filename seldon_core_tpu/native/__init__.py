@@ -68,9 +68,17 @@ def _build_if_stale(so: str) -> None:
         subprocess.run(
             ["make", "-C", makefile_dir], check=True, capture_output=True, timeout=120
         )
-    except Exception as e:  # noqa: BLE001 — opportunistic rebuild; the
-        # load path reports the real failure
-        logger.debug("native build failed: %s", e)
+    except FileNotFoundError:
+        logger.info("no `make` on this host: native core not built, python lanes serve")
+    except subprocess.CalledProcessError as e:
+        # a toolchain is present and the sources did not build: the
+        # python lanes still serve, but never silently
+        logger.warning(
+            "native build failed (rc=%d), python lanes serve: %s",
+            e.returncode, (e.stderr or b"")[-600:].decode(errors="replace"),
+        )
+    except (OSError, subprocess.TimeoutExpired) as e:
+        logger.warning("native build failed, python lanes serve: %s", e)
 
 
 class _BuildLock:

@@ -11,8 +11,11 @@ Two ops dominate image/tabular serving outside the model matmuls:
   HBM footprint and bandwidth.  ``Int8Dense`` wraps it as a flax module
   and ``quantize_weights`` converts trained f32/bf16 kernels.
 
-Kernels run in interpret mode automatically off-TPU, so the test tier
-exercises them on the virtual CPU mesh; on TPU they compile to Mosaic.
+On a TPU the kernels compile to Mosaic; on any other backend they run in
+the Pallas interpreter (:func:`interpret_mode`), which checks the
+arithmetic on the virtual CPU mesh and says nothing about lowering or
+speed.  Serving components report the mode in ``/health/status`` and
+``tools/probe_kernels.py`` is the on-chip compile check.
 (reference has no counterpart — its data plane never touches the
 accelerator; this is part of the TPU-first redesign.)
 """
@@ -20,15 +23,39 @@ accelerator; this is part of the TPU-first redesign.)
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+import logging
+from typing import Tuple
 
 import numpy as np
 
+logger = logging.getLogger(__name__)
 
-def _use_interpret() -> bool:
+# Mosaic's default scoped-VMEM limit on a v5e core; blocks are
+# double-buffered, so one grid step may hold half of it
+_VMEM_LIMIT_BYTES = 16 * 1024 * 1024
+
+
+def interpret_mode() -> bool:
+    """Whether ``pallas_call`` runs INTERPRETED in this process — true on
+    every backend but a TPU.  The one place the decision lives: every
+    kernel below passes it, and ``health_status`` of the serving
+    components reports it, so a client can tell a Mosaic-compiled lane
+    from the interpreter."""
     import jax
 
     return jax.default_backend() != "tpu"
+
+
+def _padded_block_bytes(shape, dtype) -> int:
+    """VMEM bytes of one block under the TPU tiling: the minor dim pads
+    to 128 lanes, the second-minor to the dtype's sublane count."""
+    itemsize = np.dtype(dtype).itemsize
+    sublanes = 8 * max(1, 4 // itemsize)
+    dims = list(shape)
+    dims[-1] = -(-dims[-1] // 128) * 128
+    if len(dims) > 1:
+        dims[-2] = -(-dims[-2] // sublanes) * sublanes
+    return int(np.prod(dims)) * itemsize
 
 
 # ---------------------------------------------------------------------------
@@ -59,6 +86,24 @@ def fused_normalize(x, scale, shift, out_dtype=None):
     c = img_shape[-1]
     scale = jnp.asarray(scale, jnp.float32).reshape((1,) * (len(img_shape) - 1) + (c,))
     shift = jnp.asarray(shift, jnp.float32).reshape((1,) * (len(img_shape) - 1) + (c,))
+    # one image per grid step, in + out blocks double-buffered.  The
+    # channel dim is the minor one and pads 3 -> 128 lanes, so a
+    # 224x224x3 image needs ~64 MiB: Mosaic refuses it on the v5e
+    # (RESOURCE_EXHAUSTED in vmem, tools/probe_kernels.py).  Refused
+    # here on every backend, so the interpreter cannot pass a shape the
+    # chip will not take.
+    need = 2 * (
+        _padded_block_bytes(img_shape, x.dtype)
+        + _padded_block_bytes(img_shape, out_dtype)
+    )
+    if need > _VMEM_LIMIT_BYTES:
+        raise ValueError(
+            f"fused_normalize: one {tuple(img_shape)} image block needs "
+            f"{need / 2**20:.0f} MiB of VMEM (its {c}-wide minor dim pads to "
+            f"128 lanes), over the {_VMEM_LIMIT_BYTES / 2**20:.0f} MiB a TPU "
+            "core allows — serve this shape with normalize=False (XLA fuses "
+            "the cast and affine into the first conv's input)"
+        )
 
     return pl.pallas_call(
         _normalize_kernel,
@@ -70,7 +115,7 @@ def fused_normalize(x, scale, shift, out_dtype=None):
         ],
         out_specs=pl.BlockSpec((1, *img_shape), lambda i: (i, *([0] * len(img_shape)))),
         out_shape=jax.ShapeDtypeStruct(x.shape, out_dtype),
-        interpret=_use_interpret(),
+        interpret=interpret_mode(),
     )(x, scale, shift)
 
 
@@ -126,13 +171,14 @@ def int8_matmul(x, w_int8, scale, block_m: int = 128, block_n: int = 128, out_dt
     # surfacing the raw Mosaic/XLA "not divisible" error: blocks are
     # rounded to the f32 (8, 128) tile (a 100-row M becomes a 104-row
     # block, a 70-col N a 128-col block), inputs zero-pad to the block
-    # grid, and the pad region is sliced off the output.  Zero K pad
-    # columns contribute exactly 0.0 to the contraction.
+    # grid, and the pad region is sliced off the output.  K pads to the
+    # 128-lane tile on every backend (zero K columns contribute exactly
+    # 0.0 to the contraction), so the interpreter runs the chip's path.
     bm = min(block_m, -(-m // 8) * 8)
     bn = min(block_n, -(-n // 128) * 128)
     m_pad = (-m) % bm
     n_pad = (-n) % bn
-    k_pad = 0 if _use_interpret() else (-k) % 128
+    k_pad = (-k) % 128
     if m_pad or k_pad:
         x = jnp.pad(x, ((0, m_pad), (0, k_pad)))
     if n_pad or k_pad:
@@ -153,7 +199,7 @@ def int8_matmul(x, w_int8, scale, block_m: int = 128, block_n: int = 128, out_dt
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
-        interpret=_use_interpret(),
+        interpret=interpret_mode(),
     )(x, w_int8, scale2d)
     return out[:m, :n]
 
@@ -321,7 +367,7 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 128, block_k: 
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=_use_interpret(),
+        interpret=interpret_mode(),
     )(qf, kf, vf)
     out = out.reshape(b, h, sq_p, d).transpose(0, 2, 1, 3)
     return out[:, :sq] if pad_q else out
@@ -418,12 +464,11 @@ def _paged_decode_kernel_stream(tables_ref, lens_ref, *refs, page_size,
     sliced table even past ``length`` (pl.when skips the compute, not
     the DMA).  Here the page loop is ``pl.when``-guarded per slot, so
     short streams stop paying max-length HBM traffic, and the next
-    page's DMA overlaps the current page's compute.  Measured on this
-    toolchain the DMA-issue overhead still leaves it at 1,715 us/step
-    vs the grid kernel's 1,604 and XLA's gather at 1,127 (B=16 d512/L8,
-    docs/architecture.md) — kept in-tree, float64-oracle-verified, for
-    toolchains with cheaper DMA issue and for mixed-length regimes
-    where the traffic skipping matters more.
+    page's DMA overlaps the current page's compute.  It compiles under
+    Mosaic on the v5e with jax 0.9.0 and agrees with a host float64
+    oracle in every variant (tools/probe_kernels.py); its speed against
+    the grid kernel and XLA's gather is not measured on the current
+    code (ROADMAP S1).
 
     Everything stays in the pool's flattened (ps, h*hd) layout — Mosaic
     supports neither value shape-casts nor batched dots, so the
@@ -609,7 +654,12 @@ def paged_kernel_impl(heads: int, head_dim: int) -> str:
     from seldon_core_tpu.runtime import knobs
 
     impl = knobs.raw("SELDON_TPU_PAGED_KERNEL_IMPL", "stream")
-    if impl == "stream" and (heads * head_dim) % 128 != 0 and not _use_interpret():
+    if impl == "stream" and (heads * head_dim) % 128 != 0 and not interpret_mode():
+        logger.warning(
+            "paged decode kernel: stream impl needs a 128-aligned h*hd, got "
+            "%d x %d — serving this geometry with the grid impl",
+            heads, head_dim,
+        )
         return "grid"
     return impl
 
@@ -743,7 +793,7 @@ def paged_attention_decode(q, pk, pv, block_tables, lengths, *, page_size,
             kernel,
             grid_spec=grid_spec,
             out_shape=out_shape,
-            interpret=_use_interpret(),
+            interpret=interpret_mode(),
         )(*scalar_args, *tensor_args)
         acc, m, l = outs[0], outs[1], outs[2]
         res = (acc.reshape(B, h, hd), m[:, :, 0], l[:, :, 0])
@@ -787,6 +837,6 @@ def paged_attention_decode(q, pk, pv, block_tables, lengths, *, page_size,
             jax.ShapeDtypeStruct((B, h, 128), jnp.float32),
             jax.ShapeDtypeStruct((B, h, 128), jnp.float32),
         ],
-        interpret=_use_interpret(),
+        interpret=interpret_mode(),
     )(*scalar_args, q, pk, pv)
     return acc, m[:, :, 0], l[:, :, 0]

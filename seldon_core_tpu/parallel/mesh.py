@@ -241,3 +241,26 @@ def tp_mesh(
 
 def mesh_shape(mesh) -> Dict[str, int]:
     return dict(zip(mesh.axis_names, mesh.devices.shape))
+
+
+def device_report() -> Dict[str, object]:
+    """What this process computes on, as jax reports it — the
+    ``device`` block of a serving component's ``/health/status``, so a
+    client can tell a chip from the CPU backend, a compiled Pallas lane
+    from the interpreter, and whether every device of a mesh holds its
+    share.  Initialises the backend."""
+    import jax
+
+    from seldon_core_tpu.ops.kernels import interpret_mode
+
+    devices = jax.devices()
+    out = {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+        "pallas_interpret": interpret_mode(),
+    }
+    stats = [d.memory_stats() for d in devices]
+    if all(stats):  # the CPU backend reports none
+        out["bytes_in_use"] = [int(s["bytes_in_use"]) for s in stats]
+    return out

@@ -87,14 +87,10 @@ def _ring_shard_body(q, k, v, axis_name: str, causal: bool):
     m0 = jnp.full((b, h, s_local), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, h, s_local), jnp.float32)
     acc0 = jnp.zeros((b, h, s_local, d), jnp.float32)
-    # newer jax: loop carries must be typed as axis-varying (pcast
-    # replaces the deprecated pvary; older jax has neither)
-    if hasattr(lax, "pcast"):
-        m0, l0, acc0 = (
-            lax.pcast(x, (axis_name,), to="varying") for x in (m0, l0, acc0)
-        )
-    elif hasattr(lax, "pvary"):  # pragma: no cover — pre-pcast jax
-        m0, l0, acc0 = (lax.pvary(x, (axis_name,)) for x in (m0, l0, acc0))
+    # loop carries must be typed as axis-varying
+    m0, l0, acc0 = (
+        lax.pcast(x, (axis_name,), to="varying") for x in (m0, l0, acc0)
+    )
     _, _, m, l, acc = lax.fori_loop(0, n, step, (k, v, m0, l0, acc0))
     out = acc / jnp.maximum(l, 1e-30)[..., None]  # [b,h,q,d]
     return jnp.einsum("bhqd->bqhd", out).astype(q.dtype)
@@ -110,17 +106,11 @@ def ring_attention(q, k, v, mesh, seq_axis: str = "seq", causal: bool = False):
 
     spec = P(None, seq_axis, None, None)
     body = partial(_ring_shard_body, axis_name=seq_axis, causal=causal)
-    try:
-        from jax import shard_map
+    from jax import shard_map
 
-        f = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
-    except (ImportError, TypeError):  # older jax API
-        from jax.experimental.shard_map import shard_map as old_shard_map
-
-        f = old_shard_map(
-            body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_rep=False
-        )
-    return f(q, k, v)
+    return shard_map(
+        body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
+    )(q, k, v)
 
 
 def sequence_sharding(mesh, seq_axis: str = "seq"):

@@ -97,9 +97,9 @@ def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     parser.add_argument("--log-level", default=os.environ.get("SELDON_LOG_LEVEL", "INFO"))
     parser.add_argument(
         "--platform", default=knobs.raw("SELDON_TPU_PLATFORM", ""),
-        help="force the jax platform (cpu|tpu|...). Needed because some "
-        "environments pre-import jax before env vars like JAX_PLATFORMS "
-        "can take effect; applied through jax.config before backend init",
+        help="force the jax platform (cpu|tpu|...) through jax.config "
+        "before the backend initialises; JAX_PLATFORMS in the environment "
+        "does the same",
     )
     return parser.parse_args(argv)
 
@@ -183,6 +183,9 @@ def main(argv: Optional[List[str]] = None) -> None:
         import jax
 
         jax.config.update("jax_platforms", args.platform)
+    from seldon_core_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
     if args.unit_id:
         # export the unit identity for in-process consumers that have

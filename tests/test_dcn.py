@@ -161,7 +161,7 @@ _MULTIHOST_WORKER = textwrap.dedent(
     from functools import partial
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     mesh = multihost.global_mesh({"data": 8})
 

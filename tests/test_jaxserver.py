@@ -327,7 +327,18 @@ class TestMultiSignatureServing:
         # a length outside the served signatures is rejected, not retraced
         with pytest.raises(MicroserviceError):
             server.predict(rng.integers(0, 64, size=(2, 24)).astype(np.int32), [])
-        assert server.health_status()["signatures"] == [[16], [32]]
+        status = server.health_status()
+        assert status["signatures"] == [[16], [32]]
+        # ... and where it ran, and what the batcher did: two 2-row
+        # requests, each padded into the 2-bucket of its own signature
+        import jax
+
+        devices = jax.devices()
+        assert status["device"] == {
+            "platform": "cpu", "kind": devices[0].device_kind,
+            "count": len(devices), "pallas_interpret": True,
+        }
+        assert status["batcher"] == {"batches": 2, "rows": 4, "padded_rows": 0}
         server.unload()
 
 

@@ -447,7 +447,7 @@ class TestLoadClientAgainstGrpcPython:
     (grpc-python installs table entries with its first response and
     indexes them afterwards — the old literal-scan classifier counted
     every post-first response as an error).  This is what makes the
-    bench's relay-free native-vs-python stub comparison possible."""
+    bench's device-free native-vs-python stub comparison possible."""
 
     def test_stub_load_against_grpc_python_server(self):
         import asyncio

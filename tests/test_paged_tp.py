@@ -315,6 +315,18 @@ class TestTpObservability:
             assert comp.engine.tp_degree == 2
             by_key = {m["key"]: m["value"] for m in comp.metrics()}
             assert by_key["paged_tp_degree"] == 2
+            # /health/status says where and how it is served: the device
+            # as jax reports it, the degrees the engine GOT, the lane
+            status = comp.health_status()
+            devices = jax.devices()
+            assert status["device"] == {
+                "platform": "cpu", "kind": devices[0].device_kind,
+                "count": len(devices), "pallas_interpret": True,
+            }
+            assert (status["tp"], status["dp"]) == (2, 1)
+            assert status["kernel_active"] is False and status["kernel_impl"] is None
+            assert status["chunk_impl"] == "ring" and status["pool_layout"] == "flat"
+            assert status["pool_shard_bytes"] == comp.engine.engine_stats()["pool_shard_bytes"]
         finally:
             comp.shutdown()
 

@@ -182,6 +182,9 @@ def main(argv: Optional[list] = None) -> int:
         import jax
 
         jax.config.update("jax_platforms", args.platform)
+    from seldon_core_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     if args.quick:
         args.seconds = min(args.seconds, 3.0)
         default = [c for c in CONFIGS if c != "resnet50_grpc"]

@@ -3,7 +3,7 @@ lane on the raw attention step (r18, ROADMAP 1).
 
 Times `paged_attention_decode` (kernel) vs the dense gather+softmax
 XLA program on identical pool state — N iterations inside one jit per
-arm (one dispatch, one readback, so the harness relay cannot pollute
+arm (one dispatch, one readback, so per-call cost cannot pollute
 the per-step number) — and prints the capacity-side arithmetic next to
 the timing: HBM bytes/step at bf16 vs int8 page storage and the Mosaic
 grid-step count of each kernel impl.

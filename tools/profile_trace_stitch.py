@@ -48,11 +48,14 @@ def free_port() -> int:
 
 def spawn_worker(component: str, http_port: int, grpc_port: int, span_path: str,
                  log_path: str):
+    # two worker processes cannot share a chip (libtpu binds one
+    # process per chip), and the hops this tool times are host-side:
+    # workers are held to the CPU backend whatever the parent sees
     env = dict(
         os.environ,
         TRACING="1",
         SELDON_TPU_TRACE_EXPORT=span_path,
-        JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"),
+        JAX_PLATFORMS="cpu",
     )
     # worker output goes to a FILE, not a pipe: nothing drains a pipe
     # after startup, and a chatty worker (access logs, jit-sentinel
