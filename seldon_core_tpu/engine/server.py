@@ -380,11 +380,13 @@ def build_gateway_app(gateway: Gateway, auth=None) -> web.Application:
         ``Seldon/GenerateStream`` lane; same eligibility rule)."""
         import asyncio as _asyncio
         import json as _json
+        import time as _mono_time
 
         import numpy as _np
 
         from seldon_core_tpu.runtime.component import MicroserviceError
 
+        t_ingress = _mono_time.monotonic()
         try:
             body = await _request_body(request)
             msg = InternalMessage.from_json(body)
@@ -403,7 +405,13 @@ def build_gateway_app(gateway: Gateway, auth=None) -> web.Application:
                             "reason": "NOT_IMPLEMENTED"}},
                 status=501,
             )
-        meta = {"tags": dict(msg.meta.tags), "puid": msg.meta.puid}
+        # t_ingress: the handler's entry stamp.  The generator's first
+        # next() waits for a thread of the default executor, and every
+        # stream waiting in token_queue.get() holds one: the engine
+        # counts entry -> submit as ingress_wait_s, the queue it cannot
+        # see (same process, so a monotonic stamp is a valid carrier)
+        meta = {"tags": dict(msg.meta.tags), "puid": msg.meta.puid,
+                "t_ingress": t_ingress}
         # the streaming generator runs on plain executor threads (no
         # contextvar copy), so the SLO headers ride meta.tags instead
         # of the ambient budget (tags in the body win).  The expiry is
@@ -411,8 +419,6 @@ def build_gateway_app(gateway: Gateway, auth=None) -> web.Application:
         # re-minted at submit would silently refund the executor
         # queueing time (this lane calls the local model in-process,
         # so a monotonic timestamp is a valid carrier)
-        import time as _mono_time
-
         from seldon_core_tpu.utils import deadlines as _deadlines
 
         sse_ms = _deadlines.extract_ms(request.headers)
@@ -521,6 +527,15 @@ def build_gateway_app(gateway: Gateway, auth=None) -> web.Application:
 
         return web.Response(body=generate_latest(), content_type=CONTENT_TYPE_LATEST.split(";")[0])
 
+    def _local_engines():
+        """(predictor, node, engine) of every local component that runs
+        a generation engine (anything with ``engine_stats``)."""
+        for svc in gateway.predictors:
+            for unit in svc.graph.walk():
+                engine = getattr(svc.executor.component(unit.name), "engine", None)
+                if hasattr(engine, "engine_stats"):
+                    yield svc.name, unit.name, engine
+
     async def debug_engine(request: web.Request) -> web.Response:
         """Generation-engine stats for every local component that runs a
         paged engine, keyed predictor -> node.  ``?detail=1`` adds the
@@ -528,20 +543,42 @@ def build_gateway_app(gateway: Gateway, auth=None) -> web.Application:
         payload; see docs/architecture.md §Generation observability)."""
         detail = request.query.get("detail", "") in ("1", "true", "yes")
         out: Dict[str, Dict[str, object]] = {}
-        for svc in gateway.predictors:
-            nodes = {}
-            for unit in svc.graph.walk():
-                component = svc.executor.component(unit.name)
-                engine = getattr(component, "engine", None)
-                stats_fn = getattr(engine, "engine_stats", None)
-                if stats_fn is None:
-                    continue
+        for predictor, node, engine in _local_engines():
+            try:
+                stats = engine.engine_stats(detail=detail)
+            except TypeError:  # engines predating the detail arg
+                stats = engine.engine_stats()
+            out.setdefault(predictor, {})[node] = stats
+        return web.json_response(out)
+
+    async def debug_profile(request: web.Request) -> web.Response:
+        """``POST /debug/profile?seconds=<s>`` arms a ``jax.profiler``
+        window on the running engine: it opens at the next wave
+        boundary, closes at the first one after ``s`` seconds and is
+        written under ``SELDON_TPU_PROFILE_DIR`` (unset = 409: a serving
+        process writes no profiles unless told where).  One profiler per
+        process, so the first engine found is armed.  ``GET`` returns
+        each engine's window: state, directory, the two
+        ``time.monotonic()`` stamps and the ``engine_stats()`` snapshot
+        taken at each (docs/architecture.md §Generation observability)."""
+        engines = [e for e in _local_engines() if hasattr(e[2], "arm_profile")]
+        try:
+            if request.method == "POST":
+                if not engines:
+                    return web.json_response(
+                        {"status": {"status": "FAILURE", "code": 404,
+                                    "info": "no local paged engine to profile",
+                                    "reason": "NOT_FOUND"}}, status=404)
                 try:
-                    nodes[unit.name] = stats_fn(detail=detail)
-                except TypeError:  # engines predating the detail arg
-                    nodes[unit.name] = stats_fn()
-            if nodes:
-                out[svc.name] = nodes
+                    seconds = float(request.query.get("seconds", ""))
+                except ValueError:
+                    seconds = float("nan")  # arm() refuses it with a 400
+                engines[0][2].arm_profile(seconds)
+        except Exception as e:  # noqa: BLE001
+            return _error_response(e)
+        out: Dict[str, Dict[str, object]] = {}
+        for predictor, node, engine in engines:
+            out.setdefault(predictor, {})[node] = engine.profile_status()
         return web.json_response(out)
 
     async def debug_workers(_r: web.Request) -> web.Response:
@@ -557,27 +594,21 @@ def build_gateway_app(gateway: Gateway, auth=None) -> web.Application:
         # the quarantine/migration counters the evacuation layer and
         # alerting read alongside the process lifecycle states above
         engines: Dict[str, Dict[str, object]] = {}
-        for svc in gateway.predictors:
-            for unit in svc.graph.walk():
-                component = svc.executor.component(unit.name)
-                engine = getattr(component, "engine", None)
-                stats_fn = getattr(engine, "engine_stats", None)
-                if stats_fn is None:
-                    continue
-                try:
-                    s = stats_fn()
-                except Exception:  # noqa: BLE001 — one sick engine must
-                    # not take the whole debug surface down
-                    engines[f"{svc.name}/{unit.name}"] = {"error": True}
-                    continue
-                engines[f"{svc.name}/{unit.name}"] = {
-                    "health": s.get("health", "healthy"),
-                    "health_state": s.get("health_state", 0),
-                    "watchdog_trips": s.get("watchdog_trips", 0),
-                    "quarantined": s.get("quarantined", 0),
-                    "migrated_out": s.get("migrated_out", 0),
-                    "migrated_in": s.get("migrated_in", 0),
-                }
+        for predictor, node, engine in _local_engines():
+            try:
+                s = engine.engine_stats()
+            except Exception:  # noqa: BLE001 — one sick engine must
+                # not take the whole debug surface down
+                engines[f"{predictor}/{node}"] = {"error": True}
+                continue
+            engines[f"{predictor}/{node}"] = {
+                "health": s.get("health", "healthy"),
+                "health_state": s.get("health_state", 0),
+                "watchdog_trips": s.get("watchdog_trips", 0),
+                "quarantined": s.get("quarantined", 0),
+                "migrated_out": s.get("migrated_out", 0),
+                "migrated_in": s.get("migrated_in", 0),
+            }
         return web.json_response({
             "workers": health,
             "engines": engines,
@@ -824,6 +855,8 @@ def build_gateway_app(gateway: Gateway, auth=None) -> web.Application:
     app.router.add_route("*", "/unpause", unpause)
     app.router.add_get("/metrics", metrics_endpoint)
     app.router.add_get("/debug/engine", debug_engine)
+    app.router.add_get("/debug/profile", debug_profile)
+    app.router.add_post("/debug/profile", debug_profile)
     app.router.add_get("/debug/workers", debug_workers)
     app.router.add_get("/debug/traces", debug_traces)
     app.router.add_get("/debug/knobs", debug_knobs)
@@ -912,13 +945,17 @@ def add_seldon_service(server: grpc.aio.Server, gateway: Gateway, auth=None) -> 
                 "GenerateStream needs a single-local-model predictor whose "
                 "component implements predict_stream (e.g. STREAMING_LM)",
             )
-        meta = {"tags": dict(msg.meta.tags), "puid": msg.meta.puid}
+        import time as _mono_time
+
+        # t_ingress: as in the SSE twin, this lane's first next() waits
+        # for a thread of the same default executor
+        meta = {"tags": dict(msg.meta.tags), "puid": msg.meta.puid,
+                "t_ingress": _mono_time.monotonic()}
         # SLO parity with the SSE twin: the streaming generator runs on
         # plain executor threads (no contextvar copy), so the deadline
         # and priority ride meta.tags as an ABSOLUTE monotonic expiry
         # minted here at ingress (tags in the body win).  Without this
         # the gRPC stream lane silently ignored x-seldon-deadline-ms.
-        import time as _mono_time
 
         from seldon_core_tpu.runtime.grpc_server import _grpc_deadline_ms
         from seldon_core_tpu.utils import deadlines as _deadlines

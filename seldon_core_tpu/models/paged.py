@@ -1379,6 +1379,198 @@ def journal_entry(
     }
 
 
+class _WaveSeam:
+    """The one seam every host phase and every device call of the wave
+    loop passes through.  Always on: what it costs is in every run.
+
+    * **Phases on the profiler's clock.**  ``begin_wave`` opens a
+      ``jax.profiler.StepTraceAnnotation`` (``seldon.wave``, ``step_num``
+      = the wave's number) and ``enter`` a ``TraceAnnotation``
+      (``seldon.wave.<phase>``) that lasts until the next ``enter``, so
+      the phases tile the step: ``admit``, ``launch``, ``wait``,
+      ``harvest``, ``record``.  ``prefill`` (one per ``_prefill_group``
+      call) nests inside whichever of them runs it.  They land on the
+      engine thread's line of the host plane of the same ``.xplane.pb``
+      as the device's operations; with no profiler session open each is
+      a flag test.
+    * **The host gap.**  ``drained`` marks the return of a blocking
+      readback (nothing is in flight any more), ``dispatched`` the
+      return of the next dispatch of a program of the wave loop (its
+      argument transfers, signature walk and enqueue are host work the
+      device waits for); the time between is ``host_gap_s``, kept by
+      the phase it was spent in (``phase_s``; ``between`` is the time
+      between two ``step()`` calls).  A wave that leaves no work behind
+      closes the gap uncounted.
+    * **The profile window.**  ``arm`` asks for ``seconds`` of
+      ``jax.profiler`` trace under ``SELDON_TPU_PROFILE_DIR``;
+      ``boundary`` (every wave boundary, on the engine thread) starts it,
+      stops it at the first boundary after ``seconds`` and keeps an
+      ``engine_stats()`` snapshot taken at each of the two instants.
+    """
+
+    PHASES = ("admit", "prefill", "launch", "wait", "harvest", "record",
+              "between")
+
+    def __init__(self, engine: "PagedEngine", profile_dir: Optional[str]):
+        import time as _time
+
+        self._engine = engine
+        self._profiler = engine._jax.profiler
+        self._clock = _time.perf_counter
+        self._monotonic = _time.monotonic
+        self.wave = 0
+        self.phase_s: Dict[str, float] = {p: 0.0 for p in self.PHASES}
+        # open annotations, outermost first: the step, its current
+        # phase, a prefill group nested in that — (phase, annotation)
+        self._open: List[Tuple[str, Any]] = []
+        self._phase = "between"
+        self._gap_open = False
+        self._mark = 0.0
+        self._profile_dir = profile_dir
+        self._profile_lock = threading.Lock()
+        self._profile: Dict[str, Any] = {"state": "idle"}
+
+    # ---- phases --------------------------------------------------------
+
+    def _account(self, phase: str) -> None:
+        """Book the open gap's time since the last mark to the phase
+        that ends here, and move on to ``phase``."""
+        if self._gap_open:
+            now = self._clock()
+            self.phase_s[self._phase] += now - self._mark
+            self._mark = now
+        self._phase = phase
+
+    def _push(self, phase: str, annotation: Any) -> None:
+        self._account(phase)
+        annotation.__enter__()
+        self._open.append((phase, annotation))
+
+    def _pop(self) -> None:
+        self._open.pop()[1].__exit__(None, None, None)
+        self._account(self._open[-1][0] if self._open else "between")
+
+    def begin_wave(self) -> None:
+        self.boundary()
+        self.wave += 1
+        # the step itself is no phase: time under it alone stays with
+        # whatever was running (``between``, until ``enter``)
+        self._push(self._phase, self._profiler.StepTraceAnnotation(
+            "seldon.wave", step_num=self.wave))
+
+    def enter(self, phase: str, **stats: Any) -> None:
+        """End the wave's current phase and begin ``phase``."""
+        while len(self._open) > 1:
+            self._pop()
+        self._push(phase, self._profiler.TraceAnnotation(
+            "seldon.wave." + phase, **stats))
+
+    def stats(self, **stats: Any) -> None:
+        """Work counted after the innermost phase began, onto its
+        annotation."""
+        if self._open:
+            self._open[-1][1].set_metadata(**stats)
+
+    def begin_prefill(self, **stats: Any) -> None:
+        """One prefill group, nested in the phase that runs it."""
+        self._push("prefill", self._profiler.TraceAnnotation(
+            "seldon.wave.prefill", **stats))
+
+    def end_prefill(self) -> None:
+        self._pop()
+
+    def end_wave(self, more: bool) -> None:
+        """Close whatever the wave left open (an exception may have cut
+        it anywhere).  ``more`` False: the engine has no work, so what
+        follows is waiting for a request and no host gap."""
+        while self._open:
+            self._pop()
+        if not more:
+            self._gap_open = False
+
+    # ---- the host gap --------------------------------------------------
+
+    def drained(self) -> None:
+        """A blocking readback returned: nothing is in flight."""
+        self._gap_open = True
+        self._mark = self._clock()
+
+    def dispatched(self) -> None:
+        """A program of the wave loop has been enqueued."""
+        if self._gap_open:
+            self._account(self._phase)
+            self._gap_open = False
+
+    @property
+    def host_gap_s(self) -> float:
+        return sum(self.phase_s.values())
+
+    # ---- the profile window --------------------------------------------
+
+    def arm(self, seconds: float) -> Dict[str, Any]:
+        """Ask for a window of ``seconds``; it opens at the next wave
+        boundary.  409 with no directory to write to (the safe default
+        for a profiler on a serving process) or a window already under
+        way."""
+        from seldon_core_tpu.runtime.component import MicroserviceError
+
+        if not 0.0 < seconds <= 600.0:
+            raise MicroserviceError(
+                f"profile window of {seconds!r} s: give 0 < seconds <= 600",
+                status_code=400, reason="BAD_REQUEST",
+            )
+        with self._profile_lock:
+            if not self._profile_dir:
+                raise MicroserviceError(
+                    "SELDON_TPU_PROFILE_DIR is not set: this process "
+                    "writes no profiles", status_code=409,
+                    reason="PROFILE_DISABLED",
+                )
+            if self._profile["state"] in ("armed", "tracing"):
+                raise MicroserviceError(
+                    f"a profile window is {self._profile['state']}",
+                    status_code=409, reason="PROFILE_BUSY",
+                )
+            self._profile = {
+                "state": "armed", "dir": self._profile_dir,
+                "seconds": float(seconds),
+            }
+            return dict(self._profile)
+
+    def profile_status(self) -> Dict[str, Any]:
+        with self._profile_lock:
+            return dict(self._profile)
+
+    def boundary(self) -> None:
+        """Open an armed window, close one that has run its time.
+        Engine thread, between waves; profiler failures end the window,
+        never decoding.  The profiler's own calls run outside the lock
+        (stopping a trace takes seconds, and ``profile_status`` is asked
+        from the server's event loop): only this thread moves a window
+        on from ``armed``, and ``arm`` replaces none that is under way."""
+        prof = self._profile
+        state = prof["state"]
+        try:
+            if state == "armed":
+                self._profiler.start_trace(prof["dir"])
+                update = dict(state="tracing", t_start=self._monotonic(),
+                              wave_start=self.wave,
+                              stats_start=self._engine.engine_stats())
+            elif (state == "tracing"
+                  and self._monotonic() - prof["t_start"] >= prof["seconds"]):
+                update = dict(t_stop=self._monotonic(), wave_stop=self.wave,
+                              stats_stop=self._engine.engine_stats())
+                self._profiler.stop_trace()
+                update["state"] = "done"
+            else:
+                return
+        except Exception as exc:  # noqa: BLE001 — profiler failures never stop decoding
+            logger.exception("profile window failed")
+            update = dict(state="failed", error=f"{type(exc).__name__}: {exc}")
+        with self._profile_lock:
+            prof.update(update)
+
+
 class PagedEngine:
     """Continuous-batching decode engine over a paged K/V pool.
 
@@ -1890,6 +2082,25 @@ class PagedEngine:
                           # prefill/decode split the flight-recorder
                           # chunk records carry per wave
                           "prefill_tokens": 0, "prefill_chunks": 0,
+                          # what those calls paid for: a group is padded
+                          # to a power of two and each prompt to its
+                          # bucket, so a call computes k * bucket
+                          # positions whatever its true tokens
+                          "prefill_padded_tokens": 0,
+                          # decode work where it is done: cached tokens
+                          # each lane's decode steps attended (the
+                          # lane's length at each step it ran) and
+                          # lanes x steps actually run — their ratio is
+                          # the context a decode step is read against
+                          "decode_kv_tokens": 0, "decode_lane_steps": 0,
+                          # waiting where it happens: seconds (and
+                          # streams) between submit and a stream's first
+                          # prefill slice — the engine's own queue —
+                          # and between a handler's entry stamp
+                          # (submit(t_ingress=)) and submit: the wait
+                          # for an executor thread the engine cannot see
+                          "queue_wait_s": 0.0, "queue_waits": 0,
+                          "ingress_wait_s": 0.0, "ingress_waits": 0,
                           # disaggregation (r15): prefills exported as
                           # KV-page handoff payloads, and imported
                           # payloads scatter-written into this pool
@@ -2034,17 +2245,12 @@ class PagedEngine:
                     * (1 << 30)
                 ),
             )
-        # opt-in XLA-level inspection: the first N decode chunks run
-        # inside jax.profiler.trace (N = SELDON_TPU_PROFILE_CHUNKS,
-        # default 4) writing to SELDON_TPU_PROFILE_DIR — enough to catch
-        # the compiled chunk program's timeline without profiling the
-        # whole serving lifetime
-        self._profile_dir = _knobs.raw("SELDON_TPU_PROFILE_DIR") or None
-        self._profile_chunks_left = (
-            int(_knobs.raw("SELDON_TPU_PROFILE_CHUNKS", "4"))
-            if self._profile_dir else 0
+        # the wave loop's seam: phase annotations on the profiler's
+        # clock, the host gap, and the profile window POST /debug/profile
+        # arms (written under SELDON_TPU_PROFILE_DIR; unset = refused)
+        self._seam = _WaveSeam(
+            self, _knobs.raw("SELDON_TPU_PROFILE_DIR") or None
         )
-        self._profile_started = False
 
         # speculative mode: per-slot draft/verify INSIDE the batched
         # engine — each chunk is ONE verify forward of width draft_k+1
@@ -2153,7 +2359,10 @@ class PagedEngine:
         self._spec_chunk = (
             self._sentinels["paged_spec_chunk"].wrap(
                 self._tp_jit(
-                    self._spec_chunk_fn, n_rep_in=5,
+                    self._spec_chunk_fn,
+                    name=f"paged_spec_chunk_w{self.draft_k + 1}"
+                         f"_{self.max_slots}",
+                    n_rep_in=5,
                     out_spec=("lane", "lane", "pool", "pool", "lane"),
                     lora=True, lane_hosts=True,
                 )
@@ -2213,11 +2422,20 @@ class PagedEngine:
         dtype = self._jnp.float32 if self.precision == "w8a8" else self._dtype
         return materialize(params, self.quantize, dtype)
 
-    def _tp_jit(self, fn, *, n_rep_in: int, out_spec: Sequence[str],
+    def _tp_jit(self, fn, *, name: str, n_rep_in: int,
+                out_spec: Sequence[str],
                 donate_argnums: Tuple[int, ...] = (1, 2),
                 lora: bool = False, lane_hosts: bool = False):
         """jit an engine program, annotated for GSPMD under the
         serving mesh (1-D ``{model}`` or 2-D ``{data, model}``).
+
+        ``name`` spells the program's static shape
+        (``paged_prefill_b1024_k4``, ``paged_chunk_s8_32x16``): jit names
+        the module after the function it is given, so the profiler's
+        ``XLA Modules`` line says which compiled shape ran and a reader
+        of the trace can count padded positions from the names alone.
+        A ``functools.partial`` takes a ``__name__`` where a bound
+        method cannot.
 
         Every engine program shares one argument convention — ``(params,
         pk, pv, *host_arrays)`` — so one helper covers the prefill, the
@@ -2261,7 +2479,11 @@ class PagedEngine:
         B row-parallel with their base layer), the index replicates.
         With adapters off nothing is appended and the signature (and
         lowering) is byte-identical to the pre-adapter engine."""
+        from functools import partial
+
         jax = self._jax
+        fn = partial(fn)
+        fn.__name__ = name
         if self._mesh is None:
             return jax.jit(fn, donate_argnums=donate_argnums)
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -2325,7 +2547,8 @@ class PagedEngine:
             return last, pk, pv
 
         return self._sentinels["paged_prefill"].wrap(
-            self._tp_jit(prefill, n_rep_in=3, out_spec=("rep", "pool", "pool"),
+            self._tp_jit(prefill, name=f"paged_prefill_b{bucket}_k{k}",
+                         n_rep_in=3, out_spec=("rep", "pool", "pool"),
                          lora=True),
             static=f"bucket={bucket},k={k}",
         )
@@ -2373,7 +2596,9 @@ class PagedEngine:
             return last, pk, pv
 
         return self._sentinels["paged_prefill"].wrap(
-            self._tp_jit(prefill, n_rep_in=5, out_spec=("rep", "pool", "pool"),
+            self._tp_jit(prefill,
+                         name=f"paged_prefill_cached_b{bucket}_k{k}_r{rp}",
+                         n_rep_in=5, out_spec=("rep", "pool", "pool"),
                          lora=True),
             static=f"cached,bucket={bucket},k={k},rp={rp}",
         )
@@ -2526,8 +2751,9 @@ class PagedEngine:
             body = partial(self._chunk_fn_pool, steps, buckets)
         else:
             body = partial(self._chunk_fn, steps, buckets)
+        spec = "_".join(f"{lanes}x{pages}" for lanes, pages in buckets)
         return self._tp_jit(
-            body, n_rep_in=11,
+            body, name=f"paged_chunk_s{steps}_{spec}", n_rep_in=11,
             out_spec=("lane", "pool", "pool", "lane", "lane", "lane",
                       "lane", "lane"),
             lora=True, lane_hosts=True,
@@ -2978,7 +3204,18 @@ class PagedEngine:
         submitter's request span by the (trace_id=puid, parent_span_id)
         pair captured at submit — the decode loop runs on its own
         thread, so contextvar nesting cannot do it.  No-op (no tracer or
-        untraced stream) costs one attribute read."""
+        untraced stream) costs one attribute read.
+
+        These spans are on the host's clock.  JAX returns from a
+        dispatch before the device finishes, so ``gen.prefill`` (ended
+        where ``_prefill_group`` returns, with no readback) times the
+        ENQUEUE on an accelerator and carries ``timed="enqueue"``; the
+        prefill's execution lands in the wave's chunk readback, i.e. in
+        ``gen.decode`` and ``chunk_wall_s`` (measured on the v5e, PERF.md
+        §6, PR 24).  Only a group that ends in a blocking readback (the
+        speculative engine's pending token, a KV export's logits) is
+        ``timed="device"``.  The device's own clock is the profile
+        window's: ``seldon.wave.*`` beside the programs' executions."""
         if not stream.trace_id:
             return
         from seldon_core_tpu.utils.tracing import record_span
@@ -3009,6 +3246,9 @@ class PagedEngine:
         # recorder ring is the debug surface that answers "was the
         # Pallas kernel live when this chunk ran?" after the fact
         rec.setdefault("kernel_active", int(self._kernel_active))
+        # the wave's number, as its seldon.wave step carries it in a
+        # profile: request (puids) -> record -> annotation is one chain
+        rec.setdefault("wave", self._seam.wave)
         if self.recorder is not None:
             self.recorder.record(rec)
         self._feed_watchdog(float(rec.get("wall_ms", 0.0)), fault=False)
@@ -3188,35 +3428,6 @@ class PagedEngine:
         )
         return poisoned
 
-    def _profile_before_chunk(self) -> None:
-        """SELDON_TPU_PROFILE_DIR hook: the first N chunk programs run
-        inside one jax.profiler.trace for XLA-level inspection; profiler
-        failures disable the hook, never decoding."""
-        if self._profile_chunks_left <= 0 or self._profile_started:
-            return
-        try:
-            self._jax.profiler.start_trace(self._profile_dir)
-            self._profile_started = True
-            logger.info(
-                "profiling the next %d decode chunks to %s",
-                self._profile_chunks_left, self._profile_dir,
-            )
-        except Exception:  # noqa: BLE001 — profiler failures disable the
-            # hook, never decoding
-            logger.exception("jax profiler start failed; hook disabled")
-            self._profile_chunks_left = 0
-
-    def _profile_after_chunk(self) -> None:
-        if not self._profile_started:
-            return
-        self._profile_chunks_left -= 1
-        if self._profile_chunks_left <= 0:
-            try:
-                self._jax.profiler.stop_trace()
-            except Exception:  # noqa: BLE001 — profiler failures never stop decoding
-                logger.exception("jax profiler stop failed")
-            self._profile_started = False
-
     # ---- host control -----------------------------------------------------
 
     def submit(
@@ -3237,6 +3448,7 @@ class PagedEngine:
         kv_import: Optional[Dict[str, Any]] = None,
         adapter: Optional[str] = None,
         puid: str = "",
+        t_ingress: Optional[float] = None,
     ) -> _Stream:
         """Queue one prompt (1-D int array). Returns a stream handle whose
         ``event`` fires when ``result`` (``(max_new,)`` ids) is ready.
@@ -3273,7 +3485,13 @@ class PagedEngine:
         decodes with: a resident adapter pins its pool slot for the
         stream's lifetime, a cold one loads through the weight registry
         first (load -> pin -> serve -> unpin).  ``None`` is the base
-        model — slot 0, the zero adapter, no lookup, no pin."""
+        model — slot 0, the zero adapter, no lookup, no pin.
+
+        ``t_ingress`` is the ``time.monotonic()`` stamp of the request's
+        entry into this process's handler (the SSE and gRPC streaming
+        lanes mint it); the time from there to here — spent waiting for
+        an executor thread, invisible to the engine's queue — is
+        counted as ``ingress_wait_s``."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         plen = len(prompt)
         if plen < 1:
@@ -3346,7 +3564,7 @@ class PagedEngine:
                 prompt, max_new_tokens, temperature, top_k, eos_id, seed,
                 draft_hint, stream_tokens, trace_id, parent_span_id,
                 priority, deadline, kv_export, kv_import, adapter,
-                adapter_slot, puid,
+                adapter_slot, puid, t_ingress,
             )
         except BaseException:
             if adapter_slot:
@@ -3359,7 +3577,7 @@ class PagedEngine:
         self, prompt, max_new_tokens, temperature, top_k, eos_id, seed,
         draft_hint, stream_tokens, trace_id, parent_span_id,
         priority, deadline, kv_export, kv_import, adapter, adapter_slot,
-        puid="",
+        puid="", t_ingress=None,
     ) -> _Stream:
         import queue as _queue
         import time as _time
@@ -3399,6 +3617,11 @@ class PagedEngine:
             # profile tool, tracer installed or not
             stream.t_submit = _time.time()
             stream.queue_depth_at_submit = len(self._queue)
+            if t_ingress is not None:
+                self._counters["ingress_wait_s"] += max(
+                    0.0, _time.monotonic() - float(t_ingress)
+                )
+                self._counters["ingress_waits"] += 1
             # puid linkage is independent of tracing: wave records and
             # capture containers must join to the request even when no
             # tracer is installed (trace_id remains the fallback key)
@@ -4351,7 +4574,14 @@ class PagedEngine:
         IS a suffix prefill whose "cached" prefix is the pages earlier
         slices already wrote.  Returns ``(completed streams, prompt
         tokens computed, wall seconds)``; kv_export streams resolve
-        with their handoff payload instead of entering decode."""
+        with their handoff payload instead of entering decode.
+
+        The wall seconds (``prefill_wall_s``, the recorder's
+        ``prefill_wall_ms``) are host time around dispatches: on an
+        accelerator they read the enqueue, not the prefill programs'
+        execution, which the wave's chunk readback waits out
+        (``chunk_wall_s``).  No ``block_until_ready`` is added to make
+        them true: that would serialise the host behind the device."""
         if not slices:
             return [], 0, 0.0
         # KV tier (r22): staged demotions must gather before this
@@ -4361,9 +4591,16 @@ class PagedEngine:
 
         t_start = _time.perf_counter()
         t_admit = _time.time()
+        queue_wait, queue_waits = 0.0, 0
         for stream, start, _n in slices:
             if not stream.t_prefill_start:
                 stream.t_prefill_start = t_admit  # queue-wait term ends
+                if stream.t_submit:
+                    # counted for every stream, traced or not (an
+                    # eviction restarts both stamps: a re-queue is a
+                    # wait of its own)
+                    queue_wait += max(0.0, t_admit - stream.t_submit)
+                    queue_waits += 1
             # queue-wait is the irreducible tail term (§10a): one span
             # per stream, emitted on its FIRST slice
             if stream.trace_id and start == stream.cached_len:
@@ -4406,6 +4643,8 @@ class PagedEngine:
             calls += 1
         wall = _time.perf_counter() - t_start
         with self._lock:
+            self._counters["queue_wait_s"] += queue_wait
+            self._counters["queue_waits"] += queue_waits
             if calls:
                 self._counters["prefill_wall_s"] += wall
                 self._counters["prefill_tokens"] += tokens
@@ -4436,14 +4675,34 @@ class PagedEngine:
         cache-off lane keeps its compiled shapes).  Returns the streams
         whose prompt is now FULLY prefilled: their decode state
         (logits, rng keys, speculative pending) installs here;
-        mid-prompt slices only advance the ``prefilled`` cursor."""
+        mid-prompt slices only advance the ``prefilled`` cursor.
+
+        The call is one ``seldon.wave.prefill`` phase (host packing and
+        dispatch) and pays for ``k * bucket`` positions, ``k`` the group
+        rounded up to a power of two: ``prefill_padded_tokens``."""
+        k = 1
+        while k < len(group):
+            k *= 2
+        self._seam.begin_prefill(
+            bucket=bucket, k=k, rows=len(group),
+            tokens=sum(n for _s, _start, n in group), padded=k * bucket,
+            cached=int(use_cache),
+        )
+        try:
+            with self._lock:
+                self._counters["prefill_padded_tokens"] += k * bucket
+            return self._prefill_group_call(bucket, k, group, use_cache)
+        finally:
+            self._seam.end_prefill()
+
+    def _prefill_group_call(
+        self, bucket: int, k: int, group: List[Tuple[_Stream, int, int]],
+        use_cache: bool,
+    ) -> List[_Stream]:
         import time as _time
 
         jnp = self._jnp
         t_group = _time.time()
-        k = 1
-        while k < len(group):
-            k *= 2
         ps = self.page_size
         # multi-LoRA trailing args: per-row adapter slots (pad rows 0 —
         # the zero adapter, deltas exactly 0.0 into the trash page)
@@ -4486,6 +4745,7 @@ class PagedEngine:
                 jnp.asarray(cached_lens), jnp.asarray(read_rows),
                 jnp.asarray(write_rows), *lora_args,
             )
+            self._seam.dispatched()
             self._store_kv(pk_out, pv_out)
         else:
             key2 = (bucket, k)
@@ -4508,6 +4768,7 @@ class PagedEngine:
                 jnp.asarray(padded), jnp.asarray(true_lens),
                 jnp.asarray(block_rows), *lora_args,
             )
+            self._seam.dispatched()
             self._store_kv(pk_out, pv_out)
         finals: List[Tuple[int, _Stream]] = []
         for i, (stream, start, n) in enumerate(group):
@@ -4542,6 +4803,7 @@ class PagedEngine:
             # host decides the next greedy token between verify
             # rounds — ONE blocking readback for the whole group
             pending = np.asarray(jnp.argmax(last_f, axis=-1))
+            self._seam.drained()
             for j, (_i, stream) in enumerate(finals):
                 stream.pending = int(pending[j])
         exports = [
@@ -4552,11 +4814,17 @@ class PagedEngine:
             # the handoff payload carries the last-token logits so the
             # decode worker starts sampling without a forward of its own
             last_np = np.asarray(last_f)
+            self._seam.drained()
             for j, stream in exports:
                 stream.kv_payload = {
                     "last_logits": last_np[j].astype(np.float32, copy=False)
                 }
         t_done = _time.time()
+        # what the span's end waited for: a readback above, or nothing
+        timed = (
+            "device" if self.speculative is not None or exports
+            else "enqueue"
+        )
         out: List[_Stream] = []
         for _i, stream in finals:
             stream.t_decode_start = t_done
@@ -4570,7 +4838,7 @@ class PagedEngine:
                     prompt_len=len(stream.prompt),
                     cached_tokens=stream.cached_len,
                     pages_held=len(stream.pages),
-                    group_size=len(group),
+                    group_size=len(group), timed=timed,
                 )
             out.append(stream)
         return out
@@ -4594,7 +4862,8 @@ class PagedEngine:
             place = lambda pool, val: pool.at[:, pages].set(val)  # noqa: E731
             return jax.tree.map(place, pk, k), jax.tree.map(place, pv, v)
 
-        return self._tp_jit(imp, n_rep_in=3, out_spec=("pool", "pool"))
+        return self._tp_jit(imp, name=f"paged_import_kv_p{P}", n_rep_in=3,
+                            out_spec=("pool", "pool"))
 
     def _import_kv_stream(self, stream: _Stream) -> None:
         """Scatter an imported prefill's pages into this pool and
@@ -4633,6 +4902,7 @@ class PagedEngine:
             self.params, *self._kv_args(), k, v,
             jnp.asarray(pages),
         )
+        self._seam.dispatched()
         self._store_kv(pk_out, pv_out)
         last = np.asarray(
             payload["last_logits"], np.float32
@@ -4679,6 +4949,7 @@ class PagedEngine:
                 slot=slot, bucket=0, prompt_len=plen,
                 cached_tokens=0, pages_held=len(stream.pages),
                 group_size=1, imported=True, migrated=migration,
+                timed="enqueue",
             )
 
     # ---- hierarchical KV tier (r22) ---------------------------------------
@@ -5619,6 +5890,12 @@ class PagedEngine:
                 # SELDON_TPU_KV_OFFLOAD=1; the off lane pops all ten
                 "kv_tier_host_bytes": 0,
                 "kv_tier_disk_bytes": 0,
+                # seconds in which the engine had work and nothing in
+                # flight: from the return of a wave's last blocking
+                # readback to the return of the next dispatch of a
+                # wave-loop program (the device's idle time as the
+                # program sees it)
+                "host_gap_s": self._seam.host_gap_s,
             }
         if self._capture_enabled:
             try:
@@ -5652,6 +5929,8 @@ class PagedEngine:
             ):
                 out.pop(k, None)
         if detail:
+            # host_gap_s by the phase it was spent in
+            out["phase_s"] = dict(self._seam.phase_s)
             if self._watchdog is not None:
                 out["watchdog"] = self._watchdog.stats()
             if self.recorder is not None:
@@ -5661,6 +5940,27 @@ class PagedEngine:
                 out["recorder"] = []
                 out["recorder_stats"] = {"records": 0, "seq": 0}
         return out
+
+    def arm_profile(self, seconds: float) -> Dict[str, Any]:
+        """Arm a ``jax.profiler`` window of ``seconds`` on the running
+        engine (``POST /debug/profile``): it opens at the next wave
+        boundary and closes at the first one after ``seconds``, written
+        under ``SELDON_TPU_PROFILE_DIR``.  409 when that is unset or a
+        window is under way."""
+        return self._seam.arm(float(seconds))
+
+    def profile_status(self) -> Dict[str, Any]:
+        """State of the profile window (``GET /debug/profile``): idle,
+        armed, tracing, done or failed; once done, the directory, the
+        two ``time.monotonic()`` stamps and the ``engine_stats()``
+        snapshot taken at each."""
+        return self._seam.profile_status()
+
+    def wave_boundary(self) -> None:
+        """For the thread that drives ``step()``, while it idles: an
+        armed profile window opens, and one whose time is up closes,
+        without waiting for the next wave."""
+        self._seam.boundary()
 
     @staticmethod
     def _journal_entry(s: _Stream, now: float) -> Dict[str, Any]:
@@ -5869,6 +6169,7 @@ class PagedEngine:
         recorder's window mix undercounts against the prefill_tokens
         counter exactly on pure prefill workers.  Returns step()'s
         has-more-work value."""
+        self._seam.enter("record")
         with self._lock:
             if self._debug_invariants:
                 self._check_invariants_locked()
@@ -5921,16 +6222,26 @@ class PagedEngine:
         """Admit + prefill joiners, run one decode chunk, retire finished.
 
         Returns True while there is (or may be) more work.
+
+        One wave = one ``seldon.wave`` step on the profiler's clock,
+        tiled by its phases (:class:`_WaveSeam`).
         """
+        seam = self._seam
+        seam.begin_wave()
+        more = False
         try:
+            seam.enter("admit")
             if self.speculative is not None:
-                return self._step_speculative()
-            return self._step_decode()
+                more = self._step_speculative()
+            else:
+                more = self._step_decode()
+            return more
         finally:
             # spans queued inside _lock-held retire/evict code emit here,
             # after every lock has dropped (a JSONL-exporting tracer does
             # disk I/O) — including on the early-return paths
             self._flush_spans()
+            seam.end_wave(more)
 
     def _step_decode(self) -> bool:
         jnp = self._jnp
@@ -5945,6 +6256,9 @@ class PagedEngine:
                 if self._kv_tier is not None else None
             )
             admitted = self._admit_locked()
+            self._seam.stats(
+                admitted=len(admitted), queue_depth=len(self._queue)
+            )
         # KV tier (r22): admissions' promoted chains scatter before any
         # prefill or decode work touches the wave (no-op when off)
         self._tier_promote_ready()
@@ -5958,6 +6272,7 @@ class PagedEngine:
                 self._prefill_streams([s for s, _ in admitted])
             )
 
+        self._seam.enter("launch")
         with self._lock:
             self._counters["prefills"] += len(admitted)
             active = self._retire_cancelled_locked(
@@ -6073,6 +6388,13 @@ class PagedEngine:
                 top_ks[s] = stream.top_k
                 eos_ids[s] = stream.eos_id
             pages_h = self._pages_horizon(runnable_now, steps)
+            # cached tokens per lane as the chunk starts: the launch's
+            # kv_tokens, and the base of decode_kv_tokens at harvest
+            lens0 = {s.slot: int(self._lengths[s.slot]) for s in runnable_now}
+            self._seam.stats(
+                steps=steps, lanes=len(runnable_now),
+                kv_tokens=sum(lens0.values()),
+            )
             # ctx horizons for the chunk: per length bucket (the ring
             # impl gathers only pages holding tokens that EXIST at
             # chunk start — in-chunk tokens live in the ring; the pool
@@ -6138,7 +6460,6 @@ class PagedEngine:
         # staged demotions — gather them before the chunk writes the
         # pool (no-op when off)
         self._tier_flush()
-        self._profile_before_chunk()
         t_chunk = _time.perf_counter()
         chunk_args = (
             self.params, *self._kv_args(), self._lane_put(self._logits),
@@ -6154,15 +6475,18 @@ class PagedEngine:
         toks, pk_out, pv_out, self._logits, lengths_out, self._keys, _, emitted = (
             self._get_chunk(steps, buckets)(*chunk_args)
         )
+        self._seam.dispatched()
         self._store_kv(pk_out, pv_out)
+        self._seam.enter("wait")
         toks_np = np.asarray(toks)
         emitted_np = np.asarray(emitted)
         # single-writer window: the chunk runs with its streams pinned
         # and admission only mutates lengths between chunks under the lock
         # graftlint: allow[lock-discipline] — single-writer chunk window
         self._lengths = np.array(lengths_out)  # copy: jax views are read-only
+        self._seam.drained()
         chunk_wall = _time.perf_counter() - t_chunk
-        self._profile_after_chunk()
+        self._seam.enter("harvest")
         # poison-stream quarantine BEFORE harvest: a lane whose served
         # logits went non-finite must not deliver this chunk's tokens
         # (they were computed alongside the poison) — it retires with
@@ -6174,6 +6498,15 @@ class PagedEngine:
             self._counters["bucketed_chunks"] += int(len(buckets) > 1)
             self._counters["chunk_wall_s"] += chunk_wall
             chunk_tokens = 0
+            finished = 0
+            for slot, len0 in lens0.items():
+                # the lane ran n steps, whatever became of its stream;
+                # step t attended the len0 + t tokens cached before it
+                n = int(emitted_np[slot])
+                self._counters["decode_lane_steps"] += n
+                self._counters["decode_kv_tokens"] += (
+                    n * len0 + n * (n - 1) // 2
+                )
             t_now = _time.time()
             for stream in decoding:
                 if stream.error is not None:
@@ -6195,8 +6528,10 @@ class PagedEngine:
                 hit_eos = stream.eos_id in got
                 if hit_eos or len(stream.tokens) >= stream.max_new:
                     self._finish_locked(stream)
+                    finished += 1
                 else:
                     self._stream_push(stream)
+            self._seam.stats(tokens=chunk_tokens, finished=finished)
             if self._debug_invariants:  # chunk-boundary allocator audit
                 self._check_invariants_locked()
             more = bool(self._queue) or any(s is not None for s in self._slots)
@@ -6221,6 +6556,7 @@ class PagedEngine:
             wave_puids = sorted(
                 {s.puid for s in active if s.puid}
             )
+        self._seam.enter("record")
         self._record_chunk({
             "phase": "decode",
             "puids": wave_puids,
@@ -6272,6 +6608,9 @@ class PagedEngine:
                 if self._kv_tier is not None else None
             )
             admitted = self._admit_locked()
+            self._seam.stats(
+                admitted=len(admitted), queue_depth=len(self._queue)
+            )
         # KV tier (r22): promoted chains scatter before the wave's
         # prefill/verify work (no-op when off)
         self._tier_promote_ready()
@@ -6303,6 +6642,7 @@ class PagedEngine:
                     self._run_prefill_slices(slices)
                 )
 
+        self._seam.enter("launch")
         with self._lock:
             self._counters["prefills"] += len(admitted)
             t_now = _time.time()
@@ -6399,11 +6739,12 @@ class PagedEngine:
                     tail = ctx[-W:]
                     windows[stream.slot, : len(tail)] = tail
                     lens[stream.slot] = len(tail)
-                model_drafts = np.asarray(
-                    self._draft_rollout(
-                        self._draft_params, jnp.asarray(windows), jnp.asarray(lens)
-                    )
+                drafts = self._draft_rollout(
+                    self._draft_params, jnp.asarray(windows), jnp.asarray(lens)
                 )
+                self._seam.dispatched()
+                model_drafts = np.asarray(drafts)
+                self._seam.drained()
             for stream in runnable:
                 slot = stream.slot
                 # never draft past the stream's budget: each accepted
@@ -6433,6 +6774,12 @@ class PagedEngine:
                 active_mask[slot] = True
                 self._counters["spec_drafted"] += len(drafted)
             pages_h = self._pages_horizon(runnable, self.draft_k + 1)
+            # one verify forward is one step a lane, over what it holds
+            verify_kv = sum(int(self._lengths[s.slot]) for s in runnable)
+            self._seam.stats(
+                steps=self.draft_k + 1, lanes=len(runnable),
+                kv_tokens=verify_kv,
+            )
             tables = jnp.asarray(self._block_tables[:, :pages_h])
             lengths = jnp.asarray(self._lengths)
             adapter_wave = (
@@ -6456,7 +6803,6 @@ class PagedEngine:
         # KV tier (r22): verify-lane page growth may have staged
         # demotions — gather before the chunk writes the pool
         self._tier_flush()
-        self._profile_before_chunk()
         t_chunk = _time.perf_counter()
         spec_args = (
             self.params, *self._kv_args(), jnp.asarray(segs),
@@ -6469,20 +6815,26 @@ class PagedEngine:
         out, counts, pk_out, pv_out, lengths_out = self._spec_chunk(
             *spec_args
         )
+        self._seam.dispatched()
         self._store_kv(pk_out, pv_out)
+        self._seam.enter("wait")
         out_np = np.asarray(out)
         counts_np = np.asarray(counts)
         # same single-writer window as the decode chunk: streams
         # pinned, admission between chunks
         # graftlint: allow[lock-discipline] — single-writer chunk window
         self._lengths = np.array(lengths_out)
+        self._seam.drained()
         chunk_wall = _time.perf_counter() - t_chunk
-        self._profile_after_chunk()
+        self._seam.enter("harvest")
 
         with self._lock:
             self._counters["chunks"] += 1
             self._counters["chunk_wall_s"] += chunk_wall
+            self._counters["decode_lane_steps"] += len(runnable)
+            self._counters["decode_kv_tokens"] += verify_kv
             chunk_tokens = 0
+            finished = 0
             for stream in runnable:
                 s = stream.slot
                 n = int(counts_np[s])
@@ -6496,8 +6848,10 @@ class PagedEngine:
                 hit_eos = stream.eos_id in got
                 if hit_eos or len(stream.tokens) >= stream.max_new:
                     self._finish_locked(stream)
+                    finished += 1
                 else:
                     self._stream_push(stream)
+            self._seam.stats(tokens=chunk_tokens, finished=finished)
             if self._debug_invariants:  # chunk-boundary allocator audit
                 self._check_invariants_locked()
             more = bool(self._queue) or any(s is not None for s in self._slots)
@@ -6518,6 +6872,7 @@ class PagedEngine:
             wave_puids = sorted(
                 {s.puid for s in active if s.puid}
             )
+        self._seam.enter("record")
         self._record_chunk({
             "phase": "spec_verify",
             "puids": wave_puids,
@@ -6825,6 +7180,7 @@ class StreamingLM(TPUComponent):
             self._wake.wait(timeout=0.5)
             self._wake.clear()
             try:
+                self.engine.wave_boundary()
                 while self.engine.has_work():
                     if self._stop:
                         break
@@ -7332,6 +7688,7 @@ class StreamingLM(TPUComponent):
                     seed=self.seed ^ (request_seed * 1000003 + i),
                     priority=priority, deadline=deadline, adapter=adapter,
                     puid=str(meta.get("puid", "")),
+                    t_ingress=meta.get("t_ingress"),
                 ))
             self._wake.set()
             for stream in streams:
@@ -7412,6 +7769,7 @@ class StreamingLM(TPUComponent):
             priority=priority, deadline=deadline,
             adapter=self._request_adapter(tags),
             puid=str(meta.get("puid", "")),
+            t_ingress=meta.get("t_ingress"),
         )
         self._wake.set()
         try:

@@ -263,4 +263,7 @@ def device_report() -> Dict[str, object]:
     stats = [d.memory_stats() for d in devices]
     if all(stats):  # the CPU backend reports none
         out["bytes_in_use"] = [int(s["bytes_in_use"]) for s in stats]
+        out["peak_bytes_in_use"] = [
+            int(s.get("peak_bytes_in_use", s["bytes_in_use"])) for s in stats
+        ]
     return out

@@ -300,10 +300,8 @@ ENV_KNOBS: Dict[str, Knob] = _knobs(
          "directory for p99-breach flight-recorder JSONL dumps",
          "architecture.md §5c"),
     Knob("SELDON_TPU_PROFILE_DIR", "path", "", False,
-         "jax.profiler trace output dir for the first N decode chunks",
-         "architecture.md §5c"),
-    Knob("SELDON_TPU_PROFILE_CHUNKS", "int", "4", False,
-         "how many decode chunks run under the profiler hook",
+         "where the profile windows armed with POST /debug/profile are "
+         "written (unset = the route answers 409)",
          "architecture.md §5c"),
     # ---- fleet telemetry plane (r20) --------------------------------------
     Knob("SELDON_TPU_TELEMETRY", "flag", "1", True,

@@ -288,6 +288,36 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
     "prefill_chunks": ("counter", "seldon_tpu_engine_prefill_chunks_total",
                        "prefill device calls (whole prompts and "
                        "token-budget chunk slices alike)"),
+    # the wave loop's own counts (PR 24): work where it is done and
+    # waiting where it happens, beside the counters that time the same
+    # layers from outside
+    "prefill_padded_tokens": ("counter",
+                              "seldon_tpu_engine_prefill_padded_tokens_total",
+                              "positions the prefill programs computed: "
+                              "each call pays its group rounded up to a "
+                              "power of two times its prompt bucket"),
+    "decode_kv_tokens": ("counter",
+                         "seldon_tpu_engine_decode_kv_tokens_total",
+                         "cached tokens attended, summed over every "
+                         "decode step of every lane that ran"),
+    "decode_lane_steps": ("counter",
+                          "seldon_tpu_engine_decode_lane_steps_total",
+                          "decode steps run, summed over lanes"),
+    "queue_wait_s": ("counter", "seldon_tpu_engine_queue_wait_seconds_total",
+                     "seconds streams spent in the engine's queue, "
+                     "submit to first prefill slice"),
+    "queue_waits": ("counter", "seldon_tpu_engine_queue_waits_total",
+                    "streams whose queue wait was counted"),
+    "ingress_wait_s": ("counter",
+                       "seldon_tpu_engine_ingress_wait_seconds_total",
+                       "seconds between a streaming handler's entry and "
+                       "engine.submit (the wait for an executor thread)"),
+    "ingress_waits": ("counter", "seldon_tpu_engine_ingress_waits_total",
+                      "submits that carried a handler entry stamp"),
+    "host_gap_s": ("counter", "seldon_tpu_engine_host_gap_seconds_total",
+                   "seconds the engine had work and nothing in flight: "
+                   "last readback of a wave to the return of the next "
+                   "dispatch"),
     # disaggregated prefill/decode (r15): the KV-page handoff lane
     "kv_exports": ("counter", "seldon_tpu_engine_kv_exports_total",
                    "prefills exported as KV-page handoff payloads "

@@ -1,0 +1,454 @@
+"""The wave loop's seam (PR 24): phase annotations on the profiler's
+clock, work and waiting counted where they happen, programs named by
+their static shape, and the profile window armed on a running engine.
+
+Fast tier, CPU: tiny engines; one profiler session at a time (the
+tests of this file run in one worker: ``--dist loadfile``).
+"""
+
+import glob
+import os
+import time
+
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+CFG = dict(vocab_size=64, d_model=32, num_layers=1, num_heads=2, max_len=128)
+PHASES = ("admit", "launch", "wait", "harvest", "record")
+
+
+def _tiny_engine(**kw):
+    import jax
+
+    from seldon_core_tpu.models.paged import PagedEngine
+    from seldon_core_tpu.models.transformer import TransformerLM
+
+    lm = TransformerLM(dtype=jnp.float32, **CFG)
+    params = lm.init(jax.random.key(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    base = dict(dtype=jnp.float32, page_size=8, max_slots=8, steps_per_call=4,
+                prefix_cache=False)
+    base.update(kw)
+    return PagedEngine(params, **CFG, **base)
+
+
+def _prompt(length, first):
+    """Unique content per prompt: nothing shares a page-long prefix."""
+    return ((np.arange(length, dtype=np.int32) * 7 + first) % 64).astype(np.int32)
+
+
+def _seam_events(trace_dir):
+    """``(name, start_ns, end_ns, stats)`` of every ``seldon.wave*``
+    event of a recorded trace, read as the benchmark reads one."""
+    from jax.profiler import ProfileData
+
+    found = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                             recursive=True))
+    assert found, f"no xplane under {trace_dir}"
+    out = []
+    for plane in ProfileData.from_file(found[-1]).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("seldon.wave"):
+                    out.append((ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                                {str(k): v for k, v in ev.stats}))
+    return sorted(out, key=lambda e: e[1])
+
+
+class TestPhasesOnTheProfilersClock:
+    def test_every_wave_is_a_step_tiled_by_its_phases(self, tmp_path):
+        import jax
+
+        eng = _tiny_engine()
+        try:
+            # warm every shape outside the trace, then the traced
+            # sequence: one prompt alone, then 3 + 5 over two buckets
+            for group in ([(5, 1)], [(6, 2), (7, 3), (9, 4)] + [(20 + i, 5 + i) for i in range(5)]):
+                for n, f in group:
+                    eng.submit(_prompt(n, f), max_new_tokens=6)
+                eng.run()
+            before = eng.engine_stats()
+            wave0 = eng._seam.wave
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+            try:
+                eng.submit(_prompt(5, 11), max_new_tokens=6)
+                eng.run()
+                for n, f in [(6, 12), (7, 13), (9, 14)] + [(20 + i, 15 + i) for i in range(5)]:
+                    eng.submit(_prompt(n, f), max_new_tokens=6)
+                eng.run()
+            finally:
+                jax.profiler.stop_trace()
+            after = eng.engine_stats()
+        finally:
+            eng.close()
+
+        events = _seam_events(str(tmp_path))
+        waves = [e for e in events if e[0] == "seldon.wave"]
+        assert [w[3]["step_num"] for w in waves] == list(
+            range(wave0 + 1, eng._seam.wave + 1))
+        chunks = after["chunks"] - before["chunks"]
+        assert len(waves) == chunks  # every wave of this sequence decodes
+
+        def inside(wave, name):
+            return [e for e in events if e[0] == f"seldon.wave.{name}"
+                    and wave[1] <= e[1] and e[2] <= wave[2]]
+
+        prefills = []
+        for wave in waves:
+            got = {name: inside(wave, name) for name in PHASES}
+            assert {k: len(v) for k, v in got.items()} == dict.fromkeys(PHASES, 1)
+            admit, launch, wait, harvest, record = (got[n][0] for n in PHASES)
+            # the phases tile the step, in order
+            assert admit[2] <= launch[1] and launch[2] <= wait[1]
+            assert wait[2] <= harvest[1] and harvest[2] <= record[1]
+            assert set(admit[3]) >= {"admitted", "queue_depth"}
+            assert set(launch[3]) >= {"steps", "lanes", "kv_tokens"}
+            assert set(harvest[3]) >= {"tokens", "finished"}
+            assert launch[3]["steps"] == 4 and launch[3]["lanes"] >= 1
+            prefills += inside(wave, "prefill")
+        # one prefill annotation per prefill group, carrying its work
+        assert len(prefills) == after["prefill_chunks"] - before["prefill_chunks"] == 3
+        shapes = sorted((p[3]["bucket"], p[3]["k"], p[3]["rows"], p[3]["padded"],
+                         p[3]["cached"]) for p in prefills)
+        assert shapes == [(16, 1, 1, 16, 0), (16, 4, 3, 64, 0), (32, 8, 5, 256, 0)]
+        assert sum(p[3]["tokens"] for p in prefills) == (
+            after["prefill_tokens"] - before["prefill_tokens"])
+        assert sum(p[3]["padded"] for p in prefills) == (
+            after["prefill_padded_tokens"] - before["prefill_padded_tokens"])
+        harvested = [inside(w, "harvest")[0][3] for w in waves]
+        assert sum(h["tokens"] for h in harvested) == after["tokens"] - before["tokens"]
+        assert sum(h["finished"] for h in harvested) == 9
+        admitted = [inside(w, "admit")[0][3]["admitted"] for w in waves]
+        assert sum(admitted) == 9
+
+    def test_wave_number_rides_the_flight_recorder(self):
+        eng = _tiny_engine()
+        try:
+            eng.submit(_prompt(5, 1), max_new_tokens=6)
+            eng.run()
+            records = eng.engine_stats(detail=True)["recorder"]
+            assert [r["wave"] for r in records] == list(range(1, eng._seam.wave + 1))
+        finally:
+            eng.close()
+
+
+class TestCountedWhereItHappens:
+    def test_padding_decode_and_waits_against_hand_computed_values(self):
+        eng = _tiny_engine()
+        try:
+            # groups of 1, 3 and 5 over two buckets (16 and 32)
+            first = [(5, 1)]
+            second = [(6, 2), (7, 3), (9, 4)] + [(20 + i, 5 + i) for i in range(5)]
+            new = 6
+            t_in = time.monotonic() - 1.5
+            for n, f in first:
+                s = eng.submit(_prompt(n, f), max_new_tokens=new, t_ingress=t_in)
+                s.t_submit -= 2.0
+            eng.run()
+            for n, f in second:
+                s = eng.submit(_prompt(n, f), max_new_tokens=new)
+                s.t_submit -= 2.0
+            eng.run()
+            stats = eng.engine_stats(detail=True)
+        finally:
+            eng.close()
+        lengths = [n for n, _f in first + second]
+        assert stats["prefill_tokens"] == sum(lengths)
+        # k * bucket per call: 1 x 16, 3 -> 4 x 16, 5 -> 8 x 32
+        assert stats["prefill_padded_tokens"] == 16 + 4 * 16 + 8 * 32
+        assert stats["prefill_chunks"] == 3
+        # every stream runs `new` steps; step t attends length + t
+        assert stats["decode_lane_steps"] == new * len(lengths)
+        assert stats["decode_kv_tokens"] == sum(
+            new * n + new * (new - 1) // 2 for n in lengths)
+        assert stats["queue_waits"] == len(lengths)
+        assert 2.0 * len(lengths) <= stats["queue_wait_s"] < 2.0 * len(lengths) + 1.0
+        assert stats["ingress_waits"] == 1
+        assert 1.5 <= stats["ingress_wait_s"] < 2.5
+        # the host gap is kept by phase, and the phases are its whole
+        assert stats["host_gap_s"] == pytest.approx(sum(stats["phase_s"].values()))
+        assert stats["host_gap_s"] > 0.0
+        assert stats["phase_s"]["harvest"] > 0.0 and stats["phase_s"]["launch"] > 0.0
+        assert set(stats["phase_s"]) == {"admit", "prefill", "launch", "wait",
+                                         "harvest", "record", "between"}
+
+    def test_no_gap_is_counted_across_an_idle_engine(self):
+        eng = _tiny_engine()
+        try:
+            eng.submit(_prompt(5, 1), max_new_tokens=4)
+            eng.run()
+            gap = eng.engine_stats()["host_gap_s"]
+            time.sleep(0.3)  # idle: no work, so no gap
+            eng.submit(_prompt(5, 2), max_new_tokens=4)
+            eng.run()
+            assert eng.engine_stats()["host_gap_s"] - gap < 0.25
+        finally:
+            eng.close()
+
+    def test_speculative_verify_counts_one_step_a_lane(self):
+        eng = _tiny_engine(speculative={"draft": "ngram", "draft_k": 2})
+        try:
+            eng.submit(_prompt(9, 1), max_new_tokens=5)
+            eng.run()
+            stats = eng.engine_stats()
+        finally:
+            eng.close()
+        assert stats["decode_lane_steps"] == stats["chunks"] >= 1
+        assert stats["decode_kv_tokens"] >= 9 * stats["chunks"]
+        assert stats["prefill_padded_tokens"] == 16
+
+
+class TestProgramsNamedByTheirShape:
+    @staticmethod
+    def _jitted(fn):
+        return getattr(fn, "__wrapped__", fn)  # under the jit sentinel's wrapper
+
+    def test_prefill_and_cached_prefill_module_names(self):
+        eng = _tiny_engine()
+        try:
+            ps = eng.page_size
+            bucket, k, rp = 16, 2, 2
+            i32 = jnp.int32
+            plain = self._jitted(eng._build_prefill(bucket, k)).lower(
+                eng.params, *eng._kv_args(), jnp.zeros((k, bucket), i32),
+                jnp.ones((k,), i32),
+                jnp.zeros((k, eng._pages_pow2(-(-bucket // ps))), i32),
+            ).as_text()
+            assert f"module @jit_paged_prefill_b{bucket}_k{k} " in plain
+            cached = self._jitted(eng._build_prefill_cached(bucket, k, rp)).lower(
+                eng.params, *eng._kv_args(), jnp.zeros((k, bucket), i32),
+                jnp.ones((k,), i32), jnp.zeros((k,), i32),
+                jnp.zeros((k, rp), i32), jnp.zeros((k, -(-bucket // ps)), i32),
+            ).as_text()
+            assert f"module @jit_paged_prefill_cached_b{bucket}_k{k}_r{rp} " in cached
+        finally:
+            eng.close()
+
+    @pytest.mark.parametrize("impl", ["ring", "pool"])
+    def test_chunk_module_names_both_impls(self, impl, monkeypatch):
+        monkeypatch.setenv("SELDON_TPU_CHUNK_IMPL", impl)
+        eng = _tiny_engine()
+        try:
+            assert eng._chunk_impl == impl
+            one = eng.lower_chunk(2, ((8, 4),)).as_text()
+            assert "module @jit_paged_chunk_s2_8x4 " in one
+            two = eng.lower_chunk(4, ((4, 2), (4, 8))).as_text()
+            assert "module @jit_paged_chunk_s4_4x2_4x8 " in two
+            assert "jit__unknown" not in one + two
+        finally:
+            eng.close()
+
+    def test_import_and_spec_chunk_names(self):
+        eng = _tiny_engine(speculative={"draft": "ngram", "draft_k": 2})
+        try:
+            assert self._jitted(eng._spec_chunk).__name__ == "paged_spec_chunk_w3_8"
+            assert self._jitted(eng._build_import_kv(3)).__name__ == "paged_import_kv_p3"
+        finally:
+            eng.close()
+
+
+def _streaming_lm():
+    from seldon_core_tpu.models.paged import StreamingLM
+
+    lm = StreamingLM(max_new_tokens=6, page_size=8, max_slots=2,
+                     steps_per_call=4, **CFG)
+    lm.load()
+    return lm
+
+
+def _gateway(lm):
+    from seldon_core_tpu.engine import PredictorService, UnitSpec
+    from seldon_core_tpu.engine.server import Gateway
+
+    return Gateway([(PredictorService(
+        UnitSpec(name="lm", type="MODEL", component=lm), name="main"), 1.0)])
+
+
+class TestProfileWindow:
+    def test_arm_trace_done_with_snapshots_at_the_edges(self, monkeypatch, tmp_path):
+        import asyncio
+
+        from aiohttp.test_utils import TestClient, TestServer
+
+        from seldon_core_tpu.engine.server import build_gateway_app
+
+        monkeypatch.setenv("SELDON_TPU_PROFILE_DIR", str(tmp_path))
+        lm = _streaming_lm()
+        body = {"data": {"ndarray": [[1, 2, 3, 4, 5]]}}
+
+        async def scenario():
+            client = TestClient(TestServer(build_gateway_app(_gateway(lm))))
+            await client.start_server()
+            try:
+                idle = await (await client.get("/debug/profile")).json()
+                # warm the shapes, so the window holds waves and no compile
+                assert (await client.post("/api/v0.1/predictions", json=body)).status == 200
+                bad = await client.post("/debug/profile", params={"seconds": "soon"})
+                armed = await client.post("/debug/profile", params={"seconds": "0.2"})
+                armed_doc = await armed.json()
+                busy = await client.post("/debug/profile", params={"seconds": "0.2"})
+                states = set()
+                deadline = time.monotonic() + 60
+                while time.monotonic() < deadline:
+                    assert (await client.post("/api/v0.1/predictions", json=body)).status == 200
+                    doc = (await (await client.get("/debug/profile")).json())["main"]["lm"]
+                    states.add(doc["state"])
+                    if doc["state"] in ("done", "failed"):
+                        break
+                detail = await (await client.get(
+                    "/debug/engine", params={"detail": "1"})).json()
+                return idle, bad.status, armed.status, armed_doc, busy.status, states, doc, detail
+            finally:
+                await client.close()
+
+        try:
+            idle, bad, armed, armed_doc, busy, states, doc, detail = asyncio.run(scenario())
+        finally:
+            lm.shutdown()
+        assert idle["main"]["lm"] == {"state": "idle"}
+        assert bad == 400 and armed == 200 and busy == 409
+        assert armed_doc["main"]["lm"]["state"] in ("armed", "tracing")
+        assert "tracing" in states and doc["state"] == "done", doc
+        assert doc["dir"] == str(tmp_path) and doc["t_stop"] - doc["t_start"] >= 0.2
+        assert glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"), recursive=True)
+        # the snapshots' deltas are exactly the waves run in between
+        a, b = doc["stats_start"], doc["stats_stop"]
+        ran = [r for r in detail["main"]["lm"]["recorder"]
+               if doc["wave_start"] < r["wave"] <= doc["wave_stop"]]
+        assert ran and doc["wave_stop"] - doc["wave_start"] == len(ran)
+        assert b["chunks"] - a["chunks"] == sum(r["phase"] == "decode" for r in ran)
+        assert b["tokens"] - a["tokens"] == sum(r["decode_tokens"] for r in ran)
+        assert b["prefill_tokens"] - a["prefill_tokens"] == sum(
+            r["prefill_tokens"] for r in ran)
+
+    def test_route_answers_409_with_the_directory_unset(self, monkeypatch):
+        import asyncio
+
+        from aiohttp.test_utils import TestClient, TestServer
+
+        from seldon_core_tpu.engine.server import build_gateway_app
+
+        monkeypatch.delenv("SELDON_TPU_PROFILE_DIR", raising=False)
+        lm = _streaming_lm()
+
+        async def scenario():
+            client = TestClient(TestServer(build_gateway_app(_gateway(lm))))
+            await client.start_server()
+            try:
+                resp = await client.post("/debug/profile", params={"seconds": "1"})
+                return resp.status, await resp.json(), await (
+                    await client.get("/debug/profile")).json()
+            finally:
+                await client.close()
+
+        try:
+            status, doc, after = asyncio.run(scenario())
+        finally:
+            lm.shutdown()
+        assert status == 409 and doc["status"]["reason"] == "PROFILE_DISABLED"
+        assert after["main"]["lm"] == {"state": "idle"}
+
+    def test_an_idle_engine_closes_its_window_at_a_boundary(self, monkeypatch, tmp_path):
+        monkeypatch.setenv("SELDON_TPU_PROFILE_DIR", str(tmp_path))
+        eng = _tiny_engine()
+        try:
+            eng.submit(_prompt(5, 1), max_new_tokens=4)
+            eng.run()  # warm
+            eng.arm_profile(0.05)
+            eng.submit(_prompt(5, 2), max_new_tokens=4)
+            eng.run()
+            assert eng.profile_status()["state"] == "tracing"
+            time.sleep(0.06)
+            eng.wave_boundary()  # what StreamingLM's loop does while it idles
+            doc = eng.profile_status()
+            assert doc["state"] == "done"
+            assert doc["stats_stop"]["chunks"] - doc["stats_start"]["chunks"] == 1
+        finally:
+            eng.close()
+
+
+class TestIngressStamp:
+    def test_sse_handler_stamps_and_the_engine_counts_it(self):
+        import asyncio
+
+        from aiohttp.test_utils import TestClient, TestServer
+
+        from seldon_core_tpu.engine.server import build_gateway_app
+
+        lm = _streaming_lm()
+
+        async def scenario():
+            client = TestClient(TestServer(build_gateway_app(_gateway(lm))))
+            await client.start_server()
+            try:
+                resp = await client.post("/api/v0.1/generate/stream",
+                                         json={"data": {"ndarray": [[1, 2, 3, 4, 5]]}})
+                text = await resp.text()
+                return resp.status, text
+            finally:
+                await client.close()
+
+        try:
+            status, text = asyncio.run(scenario())
+            stats = lm.engine.engine_stats()
+        finally:
+            lm.shutdown()
+        assert status == 200 and "event: end" in text
+        assert stats["ingress_waits"] == 1 and 0.0 <= stats["ingress_wait_s"] < 30.0
+        assert stats["queue_waits"] == 1
+
+
+def test_device_report_gives_the_peak_beside_bytes_in_use(monkeypatch):
+    import jax
+
+    from seldon_core_tpu.parallel import mesh
+
+    class Dev:
+        platform, device_kind = "tpu", "TPU v5 lite"
+
+        def memory_stats(self):
+            return {"bytes_in_use": 10, "peak_bytes_in_use": 30}
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [Dev(), Dev()])
+    report = mesh.device_report()
+    assert report["bytes_in_use"] == [10, 10]
+    assert report["peak_bytes_in_use"] == [30, 30]
+
+
+def test_wave_gaps_tool_splits_a_gap_by_the_innermost_annotation():
+    from tools.profile_wave_gaps import gaps_of, split
+
+    ms = 1_000_000
+    ops = [(0, 10 * ms), (30 * ms, 40 * ms), (41 * ms, 50 * ms)]
+    assert gaps_of(ops) == [(10 * ms, 30 * ms), (40 * ms, 41 * ms)]
+    marks = [
+        (5 * ms, 26 * ms, "seldon.wave"),
+        (5 * ms, 12 * ms, "seldon.wave.wait"),
+        (12 * ms, 16 * ms, "seldon.wave.harvest"),
+        (17 * ms, 26 * ms, "seldon.wave.admit"),
+        (20 * ms, 24 * ms, "seldon.wave.prefill"),
+    ]
+    parts = split((10 * ms, 30 * ms), marks)
+    want = {"wait": 0.002, "harvest": 0.004, "uncovered": 0.001, "admit": 0.005,
+            "prefill": 0.004, "between": 0.004}
+    assert parts == pytest.approx(want)
+
+
+@pytest.mark.parametrize("speculative,timed", [
+    (None, "enqueue"), ({"draft": "ngram", "draft_k": 2}, "device")])
+def test_gen_prefill_span_says_what_its_end_waited_for(speculative, timed):
+    from seldon_core_tpu.utils import tracing
+
+    tracer = tracing.setup_tracing("wave-seam-test")
+    eng = _tiny_engine(speculative=speculative)
+    try:
+        eng.submit(_prompt(5, 1), max_new_tokens=4, trace_id="puid-24")
+        eng.run()
+        spans = {s.name: s for s in tracer.find("puid-24")}
+        assert spans["gen.prefill"].tags["timed"] == timed
+    finally:
+        eng.close()
+        tracing._tracer = None

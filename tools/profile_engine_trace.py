@@ -19,8 +19,13 @@ production deployment gets, exercised standalone):
 Run:  python tools/profile_engine_trace.py [--slots 8] [--streams 24]
       [--short 16] [--long 192] [--new 64] [--out /tmp/engine-trace]
 
-Set SELDON_TPU_PROFILE_DIR to additionally wrap the first chunks in
-``jax.profiler.trace`` for XLA-level inspection.
+Set SELDON_TPU_PROFILE_DIR and the run is taken inside one armed
+profile window (``PagedEngine.arm_profile``, what ``POST /debug/profile``
+calls) after a warm-up pass over the same lengths: the trace lands
+there with the wave loop's ``seldon.wave.*`` annotations beside the
+device's operations, and a last table sets the host's prefill clocks
+(``prefill_wall_s``, the ``gen.prefill`` spans) against the prefill
+programs' device seconds of the same window.
 """
 
 import argparse
@@ -50,6 +55,11 @@ def main():
         "--chunk-budget", type=int, default=0,
         help="SELDON_TPU_CHUNK_TOKEN_BUDGET for the engine (0 = "
              "monolithic prefill, the historical scheduler)",
+    )
+    ap.add_argument(
+        "--profile-s", type=float, default=2.0,
+        help="length of the armed profile window (only with "
+             "SELDON_TPU_PROFILE_DIR set)",
     )
     ap.add_argument("--out", default="/tmp/engine-trace")
     args = ap.parse_args()
@@ -81,13 +91,25 @@ def main():
     )
 
     rng = np.random.default_rng(7)
-    prompts = [
-        rng.integers(
-            0, args.vocab,
-            size=(args.short if i % 2 == 0 else args.long,),
-        ).astype(np.int32)
-        for i in range(args.streams)
-    ]
+
+    def draw_prompts():
+        return [
+            rng.integers(
+                0, args.vocab,
+                size=(args.short if i % 2 == 0 else args.long,),
+            ).astype(np.int32)
+            for i in range(args.streams)
+        ]
+
+    profiling = bool(os.environ.get("SELDON_TPU_PROFILE_DIR"))
+    if profiling:
+        # the window is for steady waves: meet every shape first, with
+        # other content (the same content would hit the prefix cache)
+        for p in draw_prompts():
+            eng.submit(p, max_new_tokens=args.new)
+        eng.run()
+        eng.arm_profile(args.profile_s)
+    prompts = draw_prompts()
 
     print(f"submitting {args.streams} streams ({args.short}/{args.long} "
           f"bimodal prompts, {args.new} new tokens) at {args.slots} slots")
@@ -98,6 +120,11 @@ def main():
     ]
     eng.run()
     wall = time.perf_counter() - t0
+    while profiling and eng.profile_status()["state"] in ("armed", "tracing"):
+        # a run shorter than its window: idle until the window's time
+        # is up, as StreamingLM's loop does between requests
+        time.sleep(0.02)
+        eng.wave_boundary()
     total = sum(int(s.result.shape[0]) for s in streams)
     print(f"done: {total} tokens in {wall:.2f}s = {total / wall:.0f} tok/s\n")
 
@@ -108,7 +135,8 @@ def main():
         eng.recorder.dump_jsonl(rec_path)
     span_path = os.path.join(args.out, "spans.jsonl")
     with tracer._lock:  # noqa: SLF001 — read-only snapshot
-        spans = list(tracer.spans)
+        # the warm-up pass of a profiled run is traced too: keep the run's
+        spans = [s for s in tracer.spans if s.trace_id.startswith("req-")]
     with open(span_path, "w") as f:
         for s in spans:
             f.write(json.dumps(s.to_dict()) + "\n")
@@ -180,8 +208,51 @@ def main():
               f"({100.0 * rs['window_prefill_tokens'] / total:.0f}% "
               f"prefill), {mixed}/{rs['records']} waves mixed "
               "prefill+decode")
+    if profiling:
+        enqueue_table(eng.profile_status(), spans)
     eng.close()
     tracing._tracer = None
+
+
+def enqueue_table(window, spans):
+    """The host's prefill clocks against the device's, over one armed
+    window.  JAX returns from a dispatch before the device finishes, so
+    a host span around ``_prefill_group`` that ends in no readback
+    times the enqueue."""
+    from jax.profiler import ProfileData
+
+    from tools.profile_wave_gaps import find_xplane
+
+    if window.get("state") != "done":
+        print(f"profile window: {window}")
+        return
+    found = find_xplane(window["dir"])
+    device_s, runs, phases = 0.0, 0, defaultdict(float)
+    for plane in ProfileData.from_file(found).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if plane.name.startswith("/device:") and line.name == "XLA Modules" \
+                        and "paged_prefill" in ev.name:
+                    device_s += ev.duration_ns / 1e9
+                    runs += 1
+                elif ev.name.startswith("seldon.wave."):
+                    phases[ev.name] += ev.duration_ns / 1e9
+    a, b = window["stats_start"], window["stats_stop"]
+    in_window = [s for s in spans if s.name == "gen.prefill"]
+    groups = {(round(s.start_s, 6), s.tags.get("bucket")): s.duration_s
+              for s in in_window}
+    print(f"\nprofile window: waves {window['wave_start']}..{window['wave_stop']}, "
+          f"{window['t_stop'] - window['t_start']:.3f} s -> {found}")
+    print(f"prefill programs on the device: {runs} runs, {device_s * 1e3:.1f} ms "
+          "(0 runs: no device plane, i.e. not a chip)")
+    print(f"prefill_wall_s over the window: "
+          f"{(b['prefill_wall_s'] - a['prefill_wall_s']) * 1e3:.1f} ms; "
+          f"chunk_wall_s {(b['chunk_wall_s'] - a['chunk_wall_s']) * 1e3:.1f} ms; "
+          f"host_gap_s {(b['host_gap_s'] - a['host_gap_s']) * 1e3:.1f} ms")
+    print(f"gen.prefill spans: {len(in_window)} over {len(groups)} groups, "
+          f"{sum(groups.values()) * 1e3:.1f} ms summed once a group")
+    print("seldon.wave.* seconds in the trace: " + ", ".join(
+        f"{k.rsplit('.', 1)[1]} {v * 1e3:.1f} ms" for k, v in sorted(phases.items())))
 
 
 if __name__ == "__main__":
