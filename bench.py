@@ -3467,8 +3467,7 @@ def generation_phase() -> dict:
         cap_ctx = 512
         cap_model = dict(
             d_model=cfg["d_model"], num_layers=cfg["num_layers"],
-            page_size=64, steps_per_call=8, dtype_bytes=2,
-            flat_pool=True, chunk_impl="ring",
+            page_size=64, steps_per_call=8, dtype_bytes=2, chunk_impl="ring",
         )
         budget = int(cap_gib * (1 << 30))
         donated = paged_capacity_streams(
@@ -3537,8 +3536,7 @@ def generation_phase() -> dict:
         lc_ctx = 32 * 1024
         lc_model = dict(
             d_model=cfg["d_model"], num_layers=cfg["num_layers"],
-            steps_per_call=8, dtype_bytes=2,
-            flat_pool=True, chunk_impl="ring",
+            steps_per_call=8, dtype_bytes=2, chunk_impl="ring",
         )
         lc_full = paged_hbm_accounting(streams=1, ctx_len=lc_ctx, **lc_model)
         lc_shard = paged_hbm_accounting(
@@ -3619,7 +3617,7 @@ def generation_phase() -> dict:
         lane_kw = dict(
             num_layers=cfg["num_layers"], d_model=cfg["d_model"],
             page_size=64, ctx_len=lane_ctx, streams=serve_slots,
-            chunk_impl="pool", flat_pool=False, dtype_bytes=2,
+            chunk_impl="pool", dtype_bytes=2,
         )
         bf16_acct = paged_hbm_accounting(**lane_kw)
         int8_acct = paged_hbm_accounting(kv_dtype="int8", **lane_kw)

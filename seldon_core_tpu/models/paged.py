@@ -89,7 +89,7 @@ def paged_kernel_static_eligible(mode: str, mesh_absent: bool, dtype) -> bool:
     partition the pallas call), a bf16 or f32 pool (f32 is the
     exactness lane the kernel-parity tests pin), and a TPU backend
     unless forced (interpret mode).  The block adds its trace-local
-    terms (decode step, split pool layout) on top."""
+    terms (decode step, the pool in its impl's layout) on top."""
     import jax
     import jax.numpy as jnp
 
@@ -154,19 +154,22 @@ def _build_modules():
         mlp_ratio: int = 4
         dtype: Any = jnp.bfloat16
         precision: str = "bf16"  # "w8a8": int8×int8 projections
-        # decode fast path (pallas flash-decoding) — the engine turns
-        # this off under tensor-parallel meshes: GSPMD cannot partition
-        # a pallas_call whose BlockSpecs span the full heads axis, so a
-        # heads-sharded pool would all-gather per layer per step
-        decode_kernel: bool = True
 
         @nn.compact
         def __call__(self, x, pk, pv, block_tables, lengths,
-                     lora=None, adapter_idx=None, kv_scales=None):
-            # x: (B, L, d)  pk/pv: (num_pages, ps, h, hd) split, or the
-            # r5-default flat (num_pages, ps, d) — the gather below
-            # reshapes either to (B, cache_len, h, hd), and the kernel
-            # gate keys on pk.ndim (the pallas BlockSpecs need split)
+                     lora=None, adapter_idx=None, kv_scales=None,
+                     layer=None):
+            # x: (B, L, d)
+            # pk/pv + layer: two forms, picked by the LM.  ``layer`` an
+            # int — the kernel lane's: pk/pv are the WHOLE pools, flat
+            # (L, num_pages, ps, d) or (grid impl) split (L, num_pages,
+            # ps, h, hd); the decode kernel addresses (layer, page) in
+            # them, the gather reads pk[layer, tables], lora/kv_scales
+            # are the whole (L, ...) tables, and a flat pool gets its
+            # K/V back flat (B, L, d).  ``layer=None`` — every other
+            # lane's, traced exactly as before PR 25: pk/pv are ONE
+            # layer, (num_pages, ps, d) or (num_pages, ps, h, hd); the
+            # gather below reshapes either to (B, cache_len, h, hd)
             # block_tables: (B, P) int32, or a TUPLE of per-bucket
             # tables ((B0, P0), (B1, P1), ...) with sum(Bb) == B — the
             # r6 length-bucketed gather: lanes arrive bucket-sorted and
@@ -199,20 +202,28 @@ def _build_modules():
             # vs-kernel measurements that kept it opt-in predate the
             # streaming DMA rework; SELDON_TPU_PAGED_KERNEL=0 restores
             # the XLA gather lane byte-for-byte
-            use_kernel = (
-                seg_len == 1
-                # decode_kernel=False is how the engine encodes a TP
-                # mesh; the static terms (env, dtype, backend) live in
-                # the shared predicate the chunk auto-select also uses
-                and self.decode_kernel
-                # the kernels' BlockSpecs index the SPLIT (pages, ps,
-                # h, hd) layout — a flat pool (the r5 default) takes
-                # the gather path regardless of the env opt-in
-                and pk.ndim == 4
-                and paged_kernel_static_eligible(
-                    paged_kernel_mode(), True, self.dtype
-                )
-            )
+            whole = layer is not None
+            use_kernel = seg_len == 1 and whole
+            if use_kernel:
+                from seldon_core_tpu.ops.kernels import paged_kernel_impl
+
+                # the LM hands over the whole pool only where the kernel
+                # lane serves (no TP mesh; env, dtype, backend — the
+                # shared static predicate); what is left is that the
+                # pool rests in the layout the serving impl reads
+                # (pool_is_flat makes the same choice): flat for the
+                # stream kernel's (ps, h*hd) page DMA, split for the
+                # grid impl's BlockSpecs
+                kernel_impl = paged_kernel_impl(heads, head_dim)
+                use_kernel = pk.ndim == (4 if kernel_impl == "stream" else 5)
+            # the kernel indexes the whole (L, ...) factor pools and
+            # scale tables itself; everything else reads this layer's
+            lora_pools, scale_tables = lora, kv_scales
+            if whole and lora is not None:
+                lora = {t: (ab[0][layer], ab[1][layer])
+                        for t, ab in lora.items()}
+            if whole and kv_scales is not None:
+                kv_scales = (kv_scales[0][layer], kv_scales[1][layer])
             # r18: the per-lane qkv LoRA BGMV folds INTO the stream
             # kernel launch (the slot-index gather rides the scalar
             # prefetch next to the block tables) — one fused program
@@ -222,11 +233,10 @@ def _build_modules():
             # add at the LM level), so the low-rank delta is linear in
             # the projection output.  Grid impl keeps the outside-
             # kernel einsum path.
-            fold_qkv = False
-            if use_kernel and lora is not None and "qkv" in lora:
-                from seldon_core_tpu.ops.kernels import paged_kernel_impl
-
-                fold_qkv = paged_kernel_impl(heads, head_dim) == "stream"
+            fold_qkv = (
+                use_kernel and lora is not None and "qkv" in lora
+                and kernel_impl == "stream"
+            )
 
             def _proj(name, features, inp):
                 out = _dense(self.precision, features, self.dtype, name)(inp)
@@ -244,6 +254,11 @@ def _build_modules():
             y = nn.LayerNorm(dtype=jnp.float32)(x)
             qkv = _proj("qkv", 3 * d_model, y)
             q, k, v = jnp.split(qkv, 3, axis=-1)
+            # a flat whole pool takes its K/V as the projection left
+            # them: (B, L, h, hd) -> (B, L, d) is a re-lay on the chip
+            # ((20, 64) minor dims do not tile like 1280), so handing
+            # the split form to write_kv cost a copy per page block
+            k_flat, v_flat = k, v
             shape = (batch, seg_len, heads, head_dim)
             q, k, v = q.reshape(shape), k.reshape(shape), v.reshape(shape)
 
@@ -269,10 +284,12 @@ def _build_modules():
                 from seldon_core_tpu.ops.kernels import paged_attention_decode
 
                 if fold_qkv:
-                    a_f, b_fact = lora["qkv"]
-                    # the kernel DMAs one lane's (r, D) factor rows; the
-                    # 128-aligned d minor wants A TRANSPOSED
-                    a_T = jnp.swapaxes(a_f, -1, -2)   # (slots, r, d)
+                    a_f, b_fact = lora_pools["qkv"]
+                    # the kernel DMAs one lane's (r, D) factor rows of
+                    # this layer; the 128-aligned d minor wants A
+                    # TRANSPOSED (one transpose of the whole pool: the
+                    # layers' calls share it)
+                    a_T = jnp.swapaxes(a_f, -1, -2)   # (L, slots, r, d)
                     q_scale_f = float(head_dim) ** -0.5
                 outs = []
                 deltas = []
@@ -283,8 +300,8 @@ def _build_modules():
                     q1 = (q[sl] * scale)[:, 0]  # (nb, h, hd)
                     if fold_qkv:
                         acc, m, l, delta = paged_attention_decode(
-                            q1, pk, pv, tb, lengths[sl],
-                            page_size=pk.shape[1], kv_scales=kv_scales,
+                            q1, pk, pv, tb, lengths[sl], layer=layer,
+                            page_size=pk.shape[2], kv_scales=scale_tables,
                             lora=(y[sl][:, 0], a_T, b_fact,
                                   adapter_idx[sl], q_scale_f),
                         )
@@ -304,8 +321,8 @@ def _build_modules():
                         )
                     else:
                         acc, m, l = paged_attention_decode(
-                            q1, pk, pv, tb, lengths[sl],
-                            page_size=pk.shape[1], kv_scales=kv_scales,
+                            q1, pk, pv, tb, lengths[sl], layer=layer,
+                            page_size=pk.shape[2], kv_scales=scale_tables,
                         )
                         q_self = q1.astype(jnp.float32)
                         k_self = k[sl][:, 0].astype(jnp.float32)
@@ -342,6 +359,8 @@ def _build_modules():
                         v.astype(jnp.float32)
                         + dv_all.reshape(batch, 1, heads, head_dim)
                     ).astype(self.dtype)
+                    k_flat = k.reshape(batch, 1, d_model)
+                    v_flat = v.reshape(batch, 1, d_model)
             else:
                 # gather path — same arithmetic as
                 # TransformerBlock._cached_attention: bf16 scores
@@ -352,8 +371,12 @@ def _build_modules():
                 for tb in tables:
                     nb = tb.shape[0]
                     sl = slice(off, off + nb)
-                    gk = pk[tb]  # (nb, P, ps, h, hd) split / (nb, P, ps, d) flat
-                    gv = pv[tb]
+                    # (nb, P, ps, h, hd) split / (nb, P, ps, d) flat.  A
+                    # whole pool is indexed (layer, page) in ONE gather:
+                    # pk[layer][tb] would cut the layer out first, and
+                    # XLA does not fuse that slice into the gather
+                    gk = pk[layer, tb] if whole else pk[tb]
+                    gv = pv[layer, tb] if whole else pv[tb]
                     pages_per, page_size = gk.shape[1], gk.shape[2]
                     cache_len = pages_per * page_size
                     if kv_scales is not None:
@@ -403,6 +426,8 @@ def _build_modules():
             y = _proj("mlp_in", self.mlp_ratio * d_model, y)
             y = nn.gelu(y)
             x = x + _proj("mlp_out", d_model, y)
+            if whole and pk.ndim == 4:
+                return x, k_flat, v_flat
             return x, k, v
 
     class ChunkTransformerBlock(nn.Module):
@@ -580,6 +605,10 @@ def _build_modules():
         max_len: int = 2048
         dtype: Any = jnp.bfloat16
         precision: str = "bf16"
+        # decode fast path (pallas flash-decoding) — the engine turns
+        # this off under tensor-parallel meshes: GSPMD cannot partition
+        # a pallas_call over the whole heads axis, so a heads-sharded
+        # pool would all-gather per layer per step
         decode_kernel: bool = True
 
         @nn.compact
@@ -593,22 +622,38 @@ def _build_modules():
                 self.max_len, self.d_model, dtype=self.dtype, name="pos_embed"
             )(positions)
             x = x + pos
+            # The kernel lane (no TP mesh — decode_kernel=False is how
+            # the engine encodes one; env, dtype, backend: the shared
+            # static predicate) hands every block the WHOLE pool and its
+            # layer number: the decode kernel DMAs pool.at[layer, page],
+            # so no layer (84 MB at GPT-2-large size) is ever cut out of
+            # the pool, in any program of that engine.  Every other lane
+            # slices here, as before PR 25, and lowers unchanged.
+            whole = self.decode_kernel and paged_kernel_static_eligible(
+                paged_kernel_mode(), True, self.dtype
+            )
             new_k, new_v = [], []
             for i in range(self.num_layers):
-                lora_i = (
-                    {t: (ab[0][i], ab[1][i]) for t, ab in lora.items()}
-                    if lora is not None else None
-                )
-                scales_i = (
-                    (kv_scales[0][i], kv_scales[1][i])
-                    if kv_scales is not None else None
-                )
+                if whole:
+                    pools = (pages_k, pages_v)
+                    per_layer = dict(lora=lora, kv_scales=kv_scales, layer=i)
+                else:
+                    per_layer = dict(
+                        lora=(
+                            {t: (ab[0][i], ab[1][i]) for t, ab in lora.items()}
+                            if lora is not None else None
+                        ),
+                        kv_scales=(
+                            (kv_scales[0][i], kv_scales[1][i])
+                            if kv_scales is not None else None
+                        ),
+                    )
+                    pools = (pages_k[i], pages_v[i])
                 x, k, v = PagedTransformerBlock(
                     num_heads=self.num_heads, dtype=self.dtype,
-                    precision=self.precision,
-                    decode_kernel=self.decode_kernel, name=f"block_{i}"
-                )(x, pages_k[i], pages_v[i], block_tables, lengths,
-                  lora=lora_i, adapter_idx=adapter_idx, kv_scales=scales_i)
+                    precision=self.precision, name=f"block_{i}"
+                )(x, *pools, block_tables, lengths,
+                  adapter_idx=adapter_idx, **per_layer)
                 new_k.append(k)
                 new_v.append(v)
             x = nn.LayerNorm(dtype=jnp.float32)(x)
@@ -637,18 +682,37 @@ def get_chunk_lm_class():
     return _MODULES[2]
 
 
-def pool_is_flat(mesh=None) -> bool:
-    """Whether KV pools store FLAT ``(L, pages, ps, d_model)`` — the r5
-    default (the split (h, hd) trailing dims pad 2x under the TPU
-    (8,128) tile).  The opt-in pallas kernels need the split layout
-    (their BlockSpecs index it), but they are also force-disabled
-    under a TP mesh — so a mesh stays flat regardless of the env
-    opt-in.  ONE shared decision for every lane (PagedEngine and the
-    speculative _PagedState must agree, or cross-lane bit-equality
-    breaks on layout)."""
-    if mesh is not None:
+def pool_is_flat(mesh=None, *, num_heads: int, head_dim: int) -> bool:
+    """Whether KV pools rest FLAT ``(L, pages, ps, d_model)``: the
+    layout every reader but one works in.  The stream decode kernel
+    DMAs ``(ps, h*hd)`` page slices, the XLA gather lane, the ring chunk
+    and every TP-mesh lane read the flat pool, and ``write_kv``'s
+    in-place updates tile on it.  Only the ``grid`` kernel impl's
+    BlockSpecs index the split ``(L, pages, ps, h, hd)`` form — so the
+    pool is split exactly where the kernel lane is wanted (no mesh: a
+    TP mesh turns the kernels off) AND the impl that will serve this
+    geometry (:func:`ops.kernels.paged_kernel_impl`: the env choice or
+    the Mosaic alignment fallback) is ``grid``.
+
+    What the v5e showed (PERF.md §6, PR 25): XLA lays a split bf16 pool
+    out PAGE-MINOR (``{1,4,3,2,0}``: a 64-wide minor dim would pad 2x
+    under the (8, 128) tile, so the 513 pages become the minor dim and
+    nothing is padded) — one page is then strided across its whole
+    layer, a ``write_kv`` update cost 0.16 ms (decode token) or 8.4-9.7
+    ms (prefill page block), and feeding the stream kernel meant slicing
+    a layer out (84 MB, 2.4 ms) and re-laying it page-major (another
+    84 MB, 0.23 ms) per layer per decode step: 69-80 % of device time
+    at GPT-2-large size.  At rest in the reader's layout none of that
+    runs, and the same writes cost 7 and 15-18 microseconds.
+
+    ONE shared decision for every lane (PagedEngine and the speculative
+    _PagedState must agree, or cross-lane bit-equality breaks on
+    layout)."""
+    if mesh is not None or not paged_kernel_requested():
         return True
-    return not paged_kernel_requested()
+    from seldon_core_tpu.ops.kernels import paged_kernel_impl
+
+    return paged_kernel_impl(num_heads, head_dim) != "grid"
 
 
 def kv_split(pool):
@@ -683,7 +747,8 @@ def kv_scales_arg(sk, sv):
 
 def write_kv(pk, pv, new_k, new_v, block_tables, start, valid, *, page_size, max_len,
              from_zero: bool = False):
-    """Write (layers, B, L, h, hd) K/V into a paged pool.
+    """Write one call's K/V — ``(layers, B, L, d)`` flat, or ``(layers,
+    B, L, h, hd)`` split — into a paged pool, in place.
 
     ``start``: (B,) absolute position of each row's first token;
     invalid lanes are redirected to trash page 0.  Shared by the
@@ -693,7 +758,7 @@ def write_kv(pk, pv, new_k, new_v, block_tables, start, valid, *, page_size, max
     serialises (measured ~0.22 ms per index row at d512 — it dominated
     both the decode chunk at 16 slots and the batched prefill at
     16x128 tokens), while ``dynamic_update_slice`` stays in place on
-    scan carries and costs microseconds.  So every path here is DUS:
+    scan carries.  So every path here is DUS:
 
     * **decode steps (seg_len == 1)** — one DUS per slot.
     * **prefill (``from_zero=True``, static flag)** — writes always
@@ -704,6 +769,19 @@ def write_kv(pk, pv, new_k, new_v, block_tables, start, valid, *, page_size, max
       entry) — attention masks by length, and later tokens overwrite.
     * **short segments (speculative verify)** — token-wise DUS,
       seg_len x rows unrolled.
+
+    In place is not the same as cheap: what a DUS costs is set by the
+    pool's layout.  On the FLAT pool an update is ``[L, 1, 1, d]`` or
+    ``[L, 1, ps, d]`` against a page-major ``(…, ps, d)`` tiling and
+    touches L short runs: 7 us a decode token, 15-18 us a page block on
+    the v5e at GPT-2-large size.  On the SPLIT pool the v5e's layout is
+    page-minor (:func:`pool_is_flat`), every element of the update
+    lands in a tile of its own, and one update cost 0.16 ms (decode
+    token) or 8.4-9.7 ms (page block) — 37-47 % of device time before
+    PR 25 (PERF.md §5, §6).  New K/V should arrive in
+    the pool's own form: the flat pool's block hands them back flat,
+    because the ``(h, hd) -> d`` reshape done here is a re-lay (a copy
+    per page block) on the chip, not a free collapse.
     """
     import jax
     import jax.numpy as jnp
@@ -720,15 +798,11 @@ def write_kv(pk, pv, new_k, new_v, block_tables, start, valid, *, page_size, max
         )
         return (pk_pages, sk), (pv_pages, sv)
 
-    # Two pool storage layouts (r5): FLAT ``(L, pages, ps, d_model)`` —
-    # the default, because the split (heads=8, head_dim=64) trailing
-    # dims pad 2x under the TPU (8,128) tile (measured: pool and ctx
-    # buffers at 2.0x expansion in the HBM breakdown; a gather+attention
-    # microbench ran 2.5x faster on the flat layout) — and the legacy
-    # 5-d split layout, kept for the opt-in pallas kernels whose
-    # BlockSpecs index (pages, ps, h, hd).  New K/V arrive split from
-    # the module; merge the trailing dims to match a flat pool (h x hd
-    # is contiguous, so the reshape is layout-preserving).
+    # Two pool storage layouts: FLAT ``(L, pages, ps, d_model)``, where
+    # every lane but one rests, and the 5-d split layout of the grid
+    # kernel impl (:func:`pool_is_flat`).  A lane that hands over split
+    # K/V for a flat pool (every lane but the kernel lane's) has them
+    # merged here — logically contiguous, a re-lay on the chip.
     if pk.ndim == 4 and new_k.ndim == 5:
         new_k = new_k.reshape(*new_k.shape[:3], -1)
         new_v = new_v.reshape(*new_v.shape[:3], -1)
@@ -923,7 +997,6 @@ def paged_hbm_accounting(
     page_size: int = 64,
     steps_per_call: int = 8,
     dtype_bytes: int = 2,
-    flat_pool: bool = True,
     chunk_impl: str = "ring",
     donated: bool = True,
     split_tile_pad: float = 2.0,
@@ -944,16 +1017,21 @@ def paged_hbm_accounting(
     Terms, each measured in earlier rounds rather than assumed:
 
     * **pool (at rest)** — pages x page_size x d_model x 2 (K+V) x
-      layers.  The flat layout stores logical bytes; the split
-      (heads, head_dim) layout physically pads ``split_tile_pad``
-      (2.0x measured under the TPU (8,128) tile — §10b r5b).
+      layers: logical bytes in either layout.  The v5e holds the split
+      (heads, head_dim) pool unpadded too — it lays it out page-minor
+      instead of padding the 64-wide minor dim (``hbm_peak_gib`` 10.33
+      = f32 weights + their bf16 cast + 6.05 GB of pool; PERF.md §6,
+      PR 25) — so the 2.0x this term once charged the split pool is
+      gone.
     * **donated vs copied** — the chunk program donates pk/pv
       (``donate_argnums``), so exactly ONE pool copy is live during a
       chunk; without donation XLA keeps input AND output pools and the
       at-rest term doubles.  ``donated=False`` prices that world — the
       accounting the capacity claim must state.
     * **working set (ring impl only)** — the once-per-chunk ctx copy
-      (split in flight: pays the tile pad) plus the step-indexed ring;
+      (split in flight: charged ``split_tile_pad``, 2.0x, an r5 reading
+      of the (8,128) tile that no chip run since has re-taken) plus the
+      step-indexed ring;
       the pool impl reads the pool per step and carries no copy.
       Under the r6 length-bucketed gather this is the WORST case
       (uniform ctx_len); mixed traffic gathers less.
@@ -1053,8 +1131,7 @@ def paged_hbm_accounting(
     tok_bytes = num_layers * d_model * 2 * pool_elt_bytes
     # sibling scale table: one f32 per page per k/v per layer
     page_scale_bytes = num_layers * 2 * 4 if kv_int8 else 0
-    pool_pad = 1.0 if flat_pool else split_tile_pad
-    page_bytes = page_size * tok_bytes * pool_pad + page_scale_bytes
+    page_bytes = page_size * tok_bytes + page_scale_bytes
     pool = int(streams * pages * page_bytes) // kv_shard
     ws = 0
     if chunk_impl == "ring":
@@ -1732,15 +1809,13 @@ class PagedEngine:
         # COUPLED ENV KNOBS: SELDON_TPU_PAGED_KERNEL opts into the
         # pallas decode kernels, but those live in the POOL chunk's
         # per-step attention — the default ring chunk never reads the
-        # pool per step, so with CHUNK_IMPL=ring the kernel opt-in
-        # would only buy the split-layout pool's 2x HBM padding
-        # (pool_is_flat keys on the kernel env) with ZERO speed effect.
-        # Unset CHUNK_IMPL therefore auto-selects the pool impl when
-        # the kernel opt-in can actually fire — same eligibility terms
-        # as the block's gate (bf16, no TP mesh, TPU backend unless
-        # forced); a requested-but-ineligible kernel keeps the ring
-        # chunk and says why.  An EXPLICIT ring choice wins but is
-        # warned about.
+        # pool per step, so with CHUNK_IMPL=ring the kernel opt-in has
+        # ZERO speed effect.  Unset CHUNK_IMPL therefore auto-selects
+        # the pool impl when the kernel opt-in can actually fire — same
+        # eligibility terms as the LM's gate (bf16/f32, no TP mesh, TPU
+        # backend unless forced); a requested-but-ineligible kernel
+        # keeps the ring chunk and says why.  An EXPLICIT ring choice
+        # wins but is warned about.
         kernel_mode = paged_kernel_mode()
         kernel_eligible = paged_kernel_static_eligible(
             kernel_mode, mesh is None, dtype
@@ -1761,16 +1836,14 @@ class PagedEngine:
                 logger.warning(
                     "SELDON_TPU_PAGED_KERNEL=%s requested but the kernel "
                     "cannot run here (needs bf16/f32, no TP mesh, and a TPU "
-                    "backend unless force) — keeping the ring chunk; note "
-                    "the env still selects the split pool layout",
+                    "backend unless force) — keeping the ring chunk",
                     kernel_mode,
                 )
         elif paged_kernel_explicit(kernel_mode) and self._chunk_impl == "ring":
             logger.warning(
                 "SELDON_TPU_PAGED_KERNEL is set but SELDON_TPU_CHUNK_IMPL="
                 "ring: the ring chunk never invokes the pallas decode "
-                "kernel, so this combination pays the split-layout pool's "
-                "2x HBM padding with no speed effect — set "
+                "kernel, so the opt-in has no speed effect — set "
                 "SELDON_TPU_CHUNK_IMPL=pool to actually exercise the kernel"
             )
         # r6 length-bucketed context gather: inside ONE chunk program,
@@ -1790,25 +1863,26 @@ class PagedEngine:
                 "are '1' (disable) and '2' (default)"
             )
         self._ctx_buckets = int(buckets_env)
-        # pool storage layout (r5): FLAT (L, pages, ps, d_model) by
-        # default — the split (h=8, hd=64) trailing dims pad 2x under
-        # the TPU (8,128) tile (pool AND gathered-ctx buffers at 2.0x
-        # in the HBM breakdown).  Shared decision helper: kernel mode
-        # keeps split, a TP mesh is always flat (kernels can't run
-        # there anyway)
-        self._pool_flat = pool_is_flat(mesh)
+        # pool storage layout: FLAT (L, pages, ps, d_model) wherever
+        # its readers work in that form — the stream kernel, the XLA
+        # gather, the ring chunk, every mesh lane — and split only under
+        # the grid kernel impl, whose BlockSpecs index (h, hd).  The
+        # shared decision helper reads the impl that will SERVE this
+        # geometry, not the env wish
+        self._pool_flat = pool_is_flat(
+            mesh, num_heads=num_heads, head_dim=head_dim)
         pool_shape = (
             (num_layers, self.num_pages, self.page_size, d_model)
             if self._pool_flat
             else (num_layers, self.num_pages, self.page_size, num_heads, head_dim)
         )
         # r18: which decode lane this replica actually runs — the
-        # kernel fires only when the pool chunk invokes it against a
-        # split pool; exported as the `kernel_active` gauge so
-        # dashboards see the lane, not just a one-shot WARN
+        # kernel fires where the pool chunk invokes it (the pool rests
+        # in its impl's layout by the rule above); exported as the
+        # `kernel_active` gauge so dashboards see the lane, not just a
+        # one-shot WARN
         self._kernel_active = bool(
             self._chunk_impl == "pool" and kernel_eligible
-            and not self._pool_flat
         )
         # which kernel implementation serves this geometry (a stream
         # request on an unaligned h*hd is swapped for grid) — reported
@@ -2770,6 +2844,13 @@ class PagedEngine:
         horizon — representative, not necessarily a specialization the
         scheduler has compiled (serving slices tables to its own pow2
         page horizon per call)."""
+        return self._chunk_program(steps, buckets).lower(
+            *self.chunk_example_args(buckets))
+
+    def chunk_example_args(self, buckets: Tuple[Tuple[int, int], ...]):
+        """Representative arguments of the decode chunk for one bucket
+        spec (abstract pools, zero host arrays) — what ``lower_chunk``
+        lowers against, and what a test traces the program with."""
         jax, jnp = self._jax, self._jnp
         B = self.max_slots
         horizon = max(h for _, h in buckets)
@@ -2814,7 +2895,7 @@ class PagedEngine:
             ex = ex + (
                 self._lora.device_args(), jnp.zeros((B,), jnp.int32),
             )
-        return self._chunk_program(steps, buckets).lower(*ex)
+        return ex
 
     def _chunk_fn(
         self, steps, buckets, params, pk, pv, logits, lengths, block_tables,
@@ -2884,9 +2965,9 @@ class PagedEngine:
                 adapter_idx = adapter_idx[perm]
 
         len0 = lengths  # frozen at chunk start: ctx mask + write-back base
-        # POOL layout: flat (L, pages, ps, d) by default (halves HBM —
-        # the split trailing dims pad 2x under the TPU tile) or split
-        # (L, pages, ps, h, hd) in kernel mode.  WORKING-SET layout:
+        # POOL layout: flat (L, pages, ps, d), or split (L, pages, ps,
+        # h, hd) under the grid kernel impl (pool_is_flat).
+        # WORKING-SET layout:
         # always split — measured end-to-end, the per-step dense ctx
         # reads run ~1.5x faster against the split buffer (flat ctx
         # repacked per step for the attention einsums: 13.9k vs 21.2k

@@ -98,7 +98,7 @@ class _PagedState:
 
         # ONE shared layout decision with PagedEngine (cross-lane
         # bit-equality depends on both lanes picking the same pool form)
-        if pool_is_flat(mesh):
+        if pool_is_flat(mesh, num_heads=cfg.num_heads, head_dim=head_dim):
             shape = (cfg.num_layers, num_pages, page_size, cfg.d_model)
         else:
             shape = (cfg.num_layers, num_pages, page_size, cfg.num_heads, head_dim)
@@ -209,6 +209,10 @@ class SpeculativeGenerator:
         target_cfg = dict(
             vocab_size=vocab_size, d_model=d_model, num_layers=num_layers,
             num_heads=num_heads, max_len=max_len, dtype=dtype,
+            # as in PagedEngine: no pallas kernel under a TP mesh (GSPMD
+            # cannot partition the call); the flat pool's ndim no longer
+            # says so by itself
+            decode_kernel=mesh is None,
         )
         self.target = _PagedState(
             cls(**target_cfg), params, max_len=max_len, page_size=page_size,

@@ -452,11 +452,15 @@ def _paged_decode_kernel(tables_ref, lens_ref, *refs, page_size,
         acc_ref[0] = acc_ref[0] * alpha[:, None] + pv_dot
 
 
-def _paged_decode_kernel_stream(tables_ref, lens_ref, *refs, page_size,
-                                heads, head_dim, quantized=False,
+def _paged_decode_kernel_stream(tables_ref, lens_ref, layer_ref, *refs,
+                                page_size, heads, head_dim, quantized=False,
                                 fold_lora=False, q_scale=1.0):
-    """One slot of streaming flash-decoding: grid=(B,), K/V stay in HBM
-    and each slot's live pages arrive via double-buffered manual DMA.
+    """One slot of streaming flash-decoding: grid=(B,), the WHOLE
+    ``(L, pages, ps, h*hd)`` K/V pools stay in HBM and each slot's live
+    pages arrive via double-buffered manual DMA of
+    ``pool.at[layer, page]`` — the layer is a scalar-prefetch operand,
+    so every layer of a model runs the same compiled kernel and no
+    layer of the pool is ever sliced out for it.
 
     The design motivation vs the (B, P) grid kernel: that kernel pays a
     Mosaic grid-step per (slot, page) — B x P x layers ~ 1,000 grid
@@ -515,6 +519,7 @@ def _paged_decode_kernel_stream(tables_ref, lens_ref, *refs, page_size,
     delta_ref = refs[pos + 5] if fold_lora else None
 
     b = pl.program_id(0)
+    layer = layer_ref[0]
     h, hd = heads, head_dim
     D = h * hd
     length = lens_ref[b]
@@ -523,7 +528,7 @@ def _paged_decode_kernel_stream(tables_ref, lens_ref, *refs, page_size,
     def body(k_scratch, v_scratch, sems, a_scr=None, b_scr=None, lsems=None):
         def dma(pool, scratch, slot, i, which):
             return pltpu.make_async_copy(
-                pool.at[tables_ref[b, i]], scratch.at[slot],
+                pool.at[layer, tables_ref[b, i]], scratch.at[slot],
                 sems.at[slot, which],
             )
 
@@ -532,8 +537,10 @@ def _paged_decode_kernel_stream(tables_ref, lens_ref, *refs, page_size,
             # page DMA — the slot-index gather rides the same scalar
             # prefetch as the block table
             lane = adapter_ref[b]
-            cp_a = pltpu.make_async_copy(a_hbm.at[lane], a_scr, lsems.at[0])
-            cp_b = pltpu.make_async_copy(b_hbm.at[lane], b_scr, lsems.at[1])
+            cp_a = pltpu.make_async_copy(
+                a_hbm.at[layer, lane], a_scr, lsems.at[0])
+            cp_b = pltpu.make_async_copy(
+                b_hbm.at[layer, lane], b_scr, lsems.at[1])
             cp_a.start()
             cp_b.start()
 
@@ -581,8 +588,8 @@ def _paged_decode_kernel_stream(tables_ref, lens_ref, *refs, page_size,
             v = v_scratch[slot].astype(jnp.float32)
             if quantized:
                 # per-page dequant in-register (scales scalar-prefetched)
-                k = k * sk_ref[tables_ref[b, i]]
-                v = v * sv_ref[tables_ref[b, i]]
+                k = k * sk_ref[layer, tables_ref[b, i]]
+                v = v * sv_ref[layer, tables_ref[b, i]]
             # HIGHEST: a default-precision f32 dot runs as bf16 MXU
             # passes and costs ~0.05 absolute score error (measured
             # against a float64 host reference; the grid kernel's VPU
@@ -634,7 +641,7 @@ def _paged_decode_kernel_stream(tables_ref, lens_ref, *refs, page_size,
         sems=pltpu.SemaphoreType.DMA((2, 2)),
     )
     if fold_lora:
-        rank = a_hbm.shape[1]
+        rank = a_hbm.shape[2]
         scope.update(
             a_scr=pltpu.VMEM((rank, D), a_hbm.dtype),
             b_scr=pltpu.VMEM((rank, 3 * D), b_hbm.dtype),
@@ -664,29 +671,45 @@ def paged_kernel_impl(heads: int, head_dim: int) -> str:
     return impl
 
 
-def paged_attention_decode(q, pk, pv, block_tables, lengths, *, page_size,
-                           kv_scales=None, lora=None):
-    """Unnormalised flash state of decode attention over a paged pool.
+def paged_attention_decode(q, pk, pv, block_tables, lengths, *, layer,
+                           page_size, kv_scales=None, lora=None):
+    """Unnormalised flash state of decode attention over one layer of a
+    paged pool, addressed IN the whole pool.
 
     ``q`` (B, h, hd) — current-step queries, already scaled;
-    ``pk``/``pv`` (num_pages, ps, h, hd); ``block_tables`` (B, P);
-    ``lengths`` (B,) cached token counts.  Returns ``(acc, m, l)``
-    f32 — merge with the in-segment term via the usual flash rule.
+    ``pk``/``pv`` — the WHOLE pools, in the layout the serving impl
+    reads: ``(L, num_pages, ps, h*hd)`` flat for ``stream``,
+    ``(L, num_pages, ps, h, hd)`` split for ``grid``
+    (``models/paged.pool_is_flat`` makes the same choice for the pool
+    at rest); ``layer`` — which layer of them to attend over, a python
+    int or a traced int32 scalar; ``block_tables`` (B, P); ``lengths``
+    (B,) cached token counts.  Returns ``(acc, m, l)`` f32 — merge with
+    the in-segment term via the usual flash rule.
 
-    ``kv_scales`` (r18): ``(sk, sv)`` per-page f32 scale vectors
-    ``(num_pages,)`` for an int8 pool — pages dequantise in-register
-    inside the online-softmax loop (no dequantised copy of the cache
-    ever exists in HBM).
+    Why the whole pool: on the v5e a ``pool[layer]`` slice is an 84 MB
+    copy at GPT-2-large size, and re-laying a split layer to the flat
+    ``(ps, h*hd)`` form the stream kernel DMAs is another (a minor-dims
+    reshape is NOT free under the (8, 128) tiling: it was
+    ``copy_bf16_513_64_1280_``, 12 % of device time; PERF.md §6, PR 25).
+    The stream kernel therefore takes the pool in ``pl.ANY`` and the
+    layer as a scalar-prefetch operand — one compiled kernel for every
+    layer — and DMAs ``pool.at[layer, page]``.
+
+    ``kv_scales`` (r18): ``(sk, sv)`` per-page f32 scale tables
+    ``(L, num_pages)`` for an int8 pool, indexed ``[layer, page]`` like
+    the pool — pages dequantise in-register inside the online-softmax
+    loop (no dequantised copy of the cache ever exists in HBM).
 
     ``lora`` (r18, stream impl only): ``(x, a_T, b, adapter_idx,
     q_scale)`` folds the per-lane qkv BGMV delta into the same launch —
-    ``x`` (B, d) block inputs, ``a_T`` (slots, r, d) TRANSPOSED first
-    factors (the DMA wants the 128-aligned d minor), ``b`` (slots, r,
-    3d), ``adapter_idx`` (B,) int32 slot ids, ``q_scale`` the static
-    1/sqrt(hd) already applied to q.  The return grows a fourth element:
-    the raw (B, 3d) f32 delta for the caller's self-term and pool write.
+    ``x`` (B, d) block inputs, ``a_T`` (L, slots, r, d) TRANSPOSED first
+    factors (the DMA wants the 128-aligned d minor), ``b`` (L, slots, r,
+    3d), both indexed ``[layer, slot]``, ``adapter_idx`` (B,) int32 slot
+    ids, ``q_scale`` the static 1/sqrt(hd) already applied to q.  The
+    return grows a fourth element: the raw (B, 3d) f32 delta for the
+    caller's self-term and pool write.
 
-    TPU-first replacement for the ``pk[block_tables]`` gather in
+    TPU-first replacement for the ``pk[layer][block_tables]`` gather in
     ``PagedTransformerBlock`` (models/paged.py): the gather copies the
     whole live cache through HBM per layer per step; here pages stream
     HBM->VMEM, indexed by the scalar-prefetched block table
@@ -697,11 +720,11 @@ def paged_attention_decode(q, pk, pv, block_tables, lengths, *, page_size,
 
     * ``stream`` (default) — grid=(B,), double-buffered manual DMA,
       page loop bounded by each slot's own length.
-    * ``grid`` — the original (B, P) grid with block-table BlockSpecs;
-      kept for A/B measurement (tools/profile_paged_step.py).
+    * ``grid`` — the original (B, P) grid with block-table BlockSpecs
+      over ONE layer of the split pool (sliced here, as its caller did
+      before); kept for A/B measurement (tools/profile_paged_kernel.py).
     """
     import functools
-    import os
 
     import jax
     import jax.numpy as jnp
@@ -710,7 +733,7 @@ def paged_attention_decode(q, pk, pv, block_tables, lengths, *, page_size,
 
     B, h, hd = q.shape
     P = block_tables.shape[1]
-    ps = pk.shape[1]
+    ps = pk.shape[2]
     if page_size != ps:
         raise ValueError(
             f"page_size={page_size} does not match the pool's page dim {ps}"
@@ -730,11 +753,24 @@ def paged_attention_decode(q, pk, pv, block_tables, lengths, *, page_size,
             "must gate the fold on paged_kernel_impl(heads, head_dim)"
         )
 
+    if impl not in ("stream", "grid"):
+        raise ValueError(
+            f"unknown SELDON_TPU_PAGED_KERNEL_IMPL {impl!r}: use 'stream' or 'grid'"
+        )
+    want_ndim = 4 if impl == "stream" else 5
+    if pk.ndim != want_ndim or pv.ndim != want_ndim:
+        raise ValueError(
+            f"paged_attention_decode: the {impl} impl reads the whole pool "
+            f"as a {want_ndim}-d array, got {pk.shape} — the pool rests in "
+            "the layout models/paged.pool_is_flat picks for the serving impl"
+        )
+
     if impl == "stream":
         D = h * hd
         fold = lora is not None
-        scalar_args = [block_tables, lengths]
-        n_prefetch = 2
+        scalar_args = [
+            block_tables, lengths, jnp.asarray(layer, jnp.int32).reshape(1)]
+        n_prefetch = 3
         if quantized:
             scalar_args += [sk, sv]
             n_prefetch += 2
@@ -742,12 +778,11 @@ def paged_attention_decode(q, pk, pv, block_tables, lengths, *, page_size,
             x, a_T, b_f, adapter_idx, q_scale = lora
             scalar_args.append(jnp.asarray(adapter_idx, jnp.int32))
             n_prefetch += 1
-        # the kernel works in the pool's flattened (ps, h*hd) layout:
-        # HBM page slices need a 128-aligned minor dim and Mosaic has no
-        # value shape-casts; these reshapes are free minor-dims collapses
+        # the kernel works in the pool's flat (ps, h*hd) layout: HBM
+        # page slices need a 128-aligned minor dim and Mosaic has no
+        # value shape-casts.  The pool arrives in it; only q (KBs) is
+        # re-laid here
         q = q.reshape(B, 1, D)
-        pk = pk.reshape(pk.shape[0], ps, D)
-        pv = pv.reshape(pv.shape[0], ps, D)
         # q/acc ride as (B, 1, D) with (1, 1, D) blocks: the (8, 128)
         # divisibility rule applies to the LAST TWO dims, and the
         # singleton middle dim satisfies it.  Index lambdas take the
@@ -801,14 +836,11 @@ def paged_attention_decode(q, pk, pv, block_tables, lengths, *, page_size,
             res = res + (outs[3].reshape(B, 3 * D),)
         return res
 
-    if impl != "grid":
-        raise ValueError(
-            f"unknown SELDON_TPU_PAGED_KERNEL_IMPL {impl!r}: use 'stream' or 'grid'"
-        )
+    pk, pv = pk[layer], pv[layer]
     scalar_args = [block_tables, lengths]
     n_prefetch = 2
     if quantized:
-        scalar_args += [sk, sv]
+        scalar_args += [sk[layer], sv[layer]]
         n_prefetch += 2
     lane2 = lambda b, p, *prefetch: (b, 0, 0)  # noqa: E731
     page2 = lambda b, p, *prefetch: (prefetch[0][b, p], 0, 0, 0)  # noqa: E731

@@ -165,11 +165,14 @@ ENV_KNOBS: Dict[str, Knob] = _knobs(
     Knob("SELDON_TPU_PAGED_KERNEL", "str", "auto", True,
          "pallas decode-kernel lane ('0' | '1' | 'auto' | 'force'; "
          "default 'auto' = on for single-chip TPU backends, off "
-         "elsewhere — '0' restores the XLA gather lane byte-for-byte)",
+         "elsewhere — '0' restores the XLA gather lane byte-for-byte); "
+         "the KV pool rests flat under it unless the impl is 'grid'",
          "architecture.md §5b-septies"),
     Knob("SELDON_TPU_PAGED_KERNEL_IMPL", "str", "stream", False,
-         "pallas decode kernel implementation ('stream' | 'grid')",
-         "architecture.md §5b"),
+         "pallas decode kernel implementation ('stream' | 'grid'): "
+         "'stream' addresses (layer, page) in the whole flat pool; "
+         "'grid' (A/B only) keeps the split pool its BlockSpecs index",
+         "architecture.md §5b-septies"),
     Knob("SELDON_TPU_KV_DTYPE", "str", "bf16", False,
          "KV pool element dtype ('bf16' | 'int8'); int8 stores pages "
          "quantised with one f32 scale per page per k/v in a sibling "

@@ -523,7 +523,7 @@ def test_capacity_accounting_prices_inflight_prefill():
 
     kw = dict(
         d_model=512, num_layers=8, page_size=64, steps_per_call=8,
-        dtype_bytes=2, flat_pool=True, chunk_impl="ring",
+        dtype_bytes=2, chunk_impl="ring",
     )
     zero = paged_hbm_accounting(streams=1, ctx_len=512, **kw)
     one = paged_hbm_accounting(
@@ -689,7 +689,7 @@ def test_dp_hbm_accounting_per_shard():
     )
 
     kw = dict(d_model=512, num_layers=8, page_size=64, steps_per_call=8,
-              dtype_bytes=2, flat_pool=True, chunk_impl="ring")
+              dtype_bytes=2, chunk_impl="ring")
     one = paged_hbm_accounting(streams=4, ctx_len=512, **kw)
     both = paged_hbm_accounting(
         streams=4, ctx_len=512, tp_degree=2, dp_degree=2, **kw
@@ -770,7 +770,7 @@ def test_tp_hbm_accounting_per_shard():
     )
 
     kw = dict(d_model=512, num_layers=8, page_size=64, steps_per_call=8,
-              dtype_bytes=2, flat_pool=True, chunk_impl="ring")
+              dtype_bytes=2, chunk_impl="ring")
     one = paged_hbm_accounting(streams=4, ctx_len=512, **kw)
     four = paged_hbm_accounting(streams=4, ctx_len=512, tp_degree=4, **kw)
     assert four["pool_bytes"] == one["pool_bytes"] // 4
@@ -802,7 +802,7 @@ def test_prefix_capacity_accounting_reclaimable():
     )
 
     kw = dict(d_model=512, num_layers=8, page_size=64, steps_per_call=8,
-              dtype_bytes=2, flat_pool=True, chunk_impl="ring")
+              dtype_bytes=2, chunk_impl="ring")
     cold = paged_hbm_accounting(streams=1, ctx_len=512, **kw)
     warm = paged_hbm_accounting(
         streams=1, ctx_len=512, cached_prefix_pages=64, **kw
@@ -827,7 +827,7 @@ def test_capacity_accounting_donated_vs_copied():
     )
 
     kw = dict(d_model=512, num_layers=8, page_size=64, steps_per_call=8,
-              dtype_bytes=2, flat_pool=True, chunk_impl="ring")
+              dtype_bytes=2, chunk_impl="ring")
     budget = 8 << 30
     donated = paged_capacity_streams(budget, 512, donated=True, **kw)
     copied = paged_capacity_streams(budget, 512, donated=False, **kw)

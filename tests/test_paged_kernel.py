@@ -6,8 +6,6 @@ gather decode produce identical tokens).  Kernels run in interpret
 mode off-TPU, so this tier needs no hardware.
 """
 
-import os
-
 import numpy as np
 import pytest
 
@@ -20,7 +18,11 @@ pytestmark = pytest.mark.slow  # compile-heavy: excluded from the default fast t
 
 
 
+LAYERS = 3  # distinct layers in one pool: a wrong layer index is a wrong answer
+
+
 def _dense_reference(q, pk, pv, tables, lengths):
+    """pk/pv: ONE layer, split (num_pages, ps, h, hd)."""
     B = q.shape[0]
     P, ps = tables.shape[1], pk.shape[1]
     gk = pk[tables].reshape(B, P * ps, *pk.shape[2:])
@@ -33,22 +35,36 @@ def _dense_reference(q, pk, pv, tables, lengths):
     return jnp.einsum("bhk,bkhd->bhd", w, gv), m, w.sum(-1)
 
 
+def _whole_pool(rng, impl, num_pages, ps, h, hd):
+    """A LAYERS-deep pool as numpy (split) and as the device array the
+    serving impl reads: flat (L, pages, ps, h*hd) for stream, split for
+    grid."""
+    pool = rng.normal(size=(LAYERS, num_pages, ps, h, hd)).astype(np.float32)
+    dev = jnp.asarray(pool)
+    if impl == "stream":
+        dev = dev.reshape(LAYERS, num_pages, ps, h * hd)
+    return pool, dev
+
+
+@pytest.mark.parametrize("layer", range(LAYERS))
 @pytest.mark.parametrize("impl", ["stream", "grid"])
-def test_kernel_matches_dense_flash_state(impl, monkeypatch):
+def test_kernel_matches_dense_flash_state(impl, layer, monkeypatch):
     monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", impl)
     rng = np.random.default_rng(0)
     B, h, hd, ps, P, num_pages = 4, 8, 64, 16, 4, 32
     q = jnp.asarray(rng.normal(size=(B, h, hd)).astype(np.float32))
-    pk = jnp.asarray(rng.normal(size=(num_pages, ps, h, hd)).astype(np.float32))
-    pv = jnp.asarray(rng.normal(size=(num_pages, ps, h, hd)).astype(np.float32))
+    pkn, pk = _whole_pool(rng, impl, num_pages, ps, h, hd)
+    pvn, pv = _whole_pool(rng, impl, num_pages, ps, h, hd)
     tables = jnp.asarray(rng.integers(1, num_pages, size=(B, P)).astype(np.int32))
     # ragged lengths incl. partial pages and a full table
     lengths = jnp.asarray(np.array([5, 16, 37, 64], np.int32))
 
+    # the layer rides as a TRACED scalar: one compiled kernel serves all
     acc, m, l = jax.jit(
-        lambda *a: paged_attention_decode(*a, page_size=ps)
-    )(q, pk, pv, tables, lengths)
-    acc_ref, m_ref, l_ref = _dense_reference(q, pk, pv, tables, lengths)
+        lambda *a, layer: paged_attention_decode(*a, layer=layer, page_size=ps)
+    )(q, pk, pv, tables, lengths, layer=jnp.int32(layer))
+    acc_ref, m_ref, l_ref = _dense_reference(
+        q, jnp.asarray(pkn[layer]), jnp.asarray(pvn[layer]), tables, lengths)
 
     assert jnp.allclose(m, m_ref, atol=1e-5)
     assert jnp.allclose(l, l_ref, rtol=1e-5)
@@ -57,17 +73,19 @@ def test_kernel_matches_dense_flash_state(impl, monkeypatch):
     )
 
 
+@pytest.mark.parametrize("layer", range(LAYERS))
 @pytest.mark.parametrize("impl", ["stream", "grid"])
-def test_kernel_zero_length_lane_is_finite(impl, monkeypatch):
+def test_kernel_zero_length_lane_is_finite(impl, layer, monkeypatch):
     monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", impl)
     rng = np.random.default_rng(1)
     B, h, hd, ps, P, num_pages = 2, 4, 32, 8, 2, 8
     q = jnp.asarray(rng.normal(size=(B, h, hd)).astype(np.float32))
-    pk = jnp.asarray(rng.normal(size=(num_pages, ps, h, hd)).astype(np.float32))
-    pv = jnp.asarray(rng.normal(size=(num_pages, ps, h, hd)).astype(np.float32))
+    _, pk = _whole_pool(rng, impl, num_pages, ps, h, hd)
+    _, pv = _whole_pool(rng, impl, num_pages, ps, h, hd)
     tables = jnp.zeros((B, P), jnp.int32)
     lengths = jnp.asarray(np.array([0, 3], np.int32))
-    acc, m, l = paged_attention_decode(q, pk, pv, tables, lengths, page_size=ps)
+    acc, m, l = paged_attention_decode(
+        q, pk, pv, tables, lengths, layer=layer, page_size=ps)
     # lane 0 has no cache: flash state must be the neutral element the
     # self-token merge recovers from (acc 0, m -inf, l 0), not NaN
     assert float(l[0].sum()) == 0.0
@@ -76,8 +94,9 @@ def test_kernel_zero_length_lane_is_finite(impl, monkeypatch):
     assert np.all(np.isfinite(np.asarray(l[1])))
 
 
+@pytest.mark.parametrize("layer", range(LAYERS))
 @pytest.mark.parametrize("impl", ["stream", "grid"])
-def test_kernel_matches_float64_host_oracle(impl, monkeypatch):
+def test_kernel_matches_float64_host_oracle(impl, layer, monkeypatch):
     """Adjudicate numerics against a HOST float64 oracle, not another
     on-chip program: an on-TPU 'reference' einsum is itself bf16-rounded
     (default matmul precision), which masked a bf16-precision bug in
@@ -87,13 +106,13 @@ def test_kernel_matches_float64_host_oracle(impl, monkeypatch):
     rng = np.random.default_rng(3)
     B, h, hd, ps, P, num_pages = 4, 8, 64, 16, 4, 32
     qn = rng.normal(size=(B, h, hd)).astype(np.float32)
-    pkn = rng.normal(size=(num_pages, ps, h, hd)).astype(np.float32)
-    pvn = rng.normal(size=(num_pages, ps, h, hd)).astype(np.float32)
+    pkn, pk = _whole_pool(rng, impl, num_pages, ps, h, hd)
+    pvn, pv = _whole_pool(rng, impl, num_pages, ps, h, hd)
     tn = rng.integers(1, num_pages, size=(B, P)).astype(np.int32)
     ln = np.array([5, 16, 37, 64], np.int32)
 
-    gk = pkn[tn].reshape(B, P * ps, h, hd).astype(np.float64)
-    gv = pvn[tn].reshape(B, P * ps, h, hd).astype(np.float64)
+    gk = pkn[layer][tn].reshape(B, P * ps, h, hd).astype(np.float64)
+    gv = pvn[layer][tn].reshape(B, P * ps, h, hd).astype(np.float64)
     s = np.einsum("bhd,bkhd->bhk", qn.astype(np.float64), gk)
     mask = np.arange(P * ps)[None, :] < ln[:, None]
     s = np.where(mask[:, None, :], s, -np.inf)
@@ -102,8 +121,8 @@ def test_kernel_matches_float64_host_oracle(impl, monkeypatch):
     ref = np.einsum("bhk,bkhd->bhd", w, gv) / w.sum(-1)[..., None]
 
     acc, m, l = jax.jit(
-        lambda *a: paged_attention_decode(*a, page_size=ps)
-    )(*map(jnp.asarray, (qn, pkn, pvn, tn, ln)))
+        lambda *a: paged_attention_decode(*a, layer=layer, page_size=ps)
+    )(jnp.asarray(qn), pk, pv, jnp.asarray(tn), jnp.asarray(ln))
     out = np.asarray(acc / l[..., None], np.float64)
     assert float(np.nanmax(np.abs(out - ref))) < 1e-4, impl
     assert float(np.max(np.abs(np.asarray(m, np.float64) - m64))) < 1e-4, impl
@@ -156,9 +175,8 @@ def test_engine_tokens_identical_kernel_vs_gather(monkeypatch):
 def test_kernel_optin_autoselects_pool_chunk(monkeypatch):
     """The two env knobs are coupled: SELDON_TPU_PAGED_KERNEL opts into
     kernels that only the pool chunk invokes.  With CHUNK_IMPL unset the
-    engine auto-selects the pool impl (otherwise the opt-in silently
-    pays the split-layout pool's 2x HBM padding with zero speed
-    effect); an explicit ring choice wins but is warned about."""
+    engine auto-selects the pool impl (otherwise the opt-in has zero
+    speed effect); an explicit ring choice wins but is warned about."""
     cfg, params, prompts = _lm_fixture()
     monkeypatch.delenv("SELDON_TPU_CHUNK_IMPL", raising=False)
     monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")

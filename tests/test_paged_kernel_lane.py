@@ -182,7 +182,7 @@ class TestInt8Gating:
 
 class TestInt8Accounting:
     KW = dict(num_layers=8, d_model=512, page_size=64, chunk_impl="pool",
-              flat_pool=False, dtype_bytes=2)
+              dtype_bytes=2)
 
     def test_int8_roughly_doubles_capacity(self):
         budget = 8 << 30
@@ -197,8 +197,8 @@ class TestInt8Accounting:
         pages = -(-512 // 64)
         tok = self.KW["num_layers"] * self.KW["d_model"] * 2  # 1 byte/elt
         scale = self.KW["num_layers"] * 2 * 4                 # 8B/page
-        pad = 2.0  # the split layout's tile pad
-        assert acct["pool_bytes"] == int(pages * (64 * tok * pad + scale))
+        # logical bytes: the chip holds either pool layout unpadded
+        assert acct["pool_bytes"] == int(pages * (64 * tok + scale))
 
     def test_ring_working_set_ignores_kv_dtype(self):
         """The ring impl never stores int8 (pool-impl-only lever): its

@@ -1,0 +1,88 @@
+"""The stream decode kernel, compiled by Mosaic for a described v5e at
+GPT-2-large's serving widths — no chip attached, nothing runs.
+
+Interpret mode (every other kernel test) checks arithmetic and nothing
+about lowering: what Mosaic refuses (an unaligned DMA slice, a scalar-
+prefetch table too large for SMEM, a (layer, page) index it cannot
+lower) shows only here or on the chip.  Since PR 25 the kernel takes the
+WHOLE ``(36, 513, 64, 1280)`` pools in ``pl.ANY``, the layer as a
+scalar-prefetch operand, and under int8-KV the whole ``(36, 513)`` scale
+tables in SMEM: those are the shapes compiled.
+
+One file, one module fixture: only the worker that is given this file
+loads the TPU compiler, and only after a test of it has started.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from seldon_core_tpu.ops import kernels
+
+L, NUM_PAGES, PS, H, HD = 36, 513, 64, 20, 64
+D = H * HD
+RANK, SLOTS = 8, 8
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Compile, do not interpret, although the backend is the CPU; and
+    keep the persistent compile cache out of it (an entry compiled for
+    a described chip cannot be read back without one)."""
+    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
+    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", "stream")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.mark.parametrize("lanes,pages,variant", [
+    (16, 8, "bf16"), (32, 16, "bf16"), (32, 16, "int8"), (16, 8, "lora"),
+])
+def test_stream_kernel_compiles_on_the_whole_pool(one_chip, mosaic, lanes, pages, variant):
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool_dtype = jnp.int8 if variant == "int8" else jnp.bfloat16
+
+    def fn(q, pk, pv, tables, lengths, layer, sk, sv, x, a_t, b, idx):
+        return kernels.paged_attention_decode(
+            q, pk, pv, tables, lengths, layer=layer, page_size=PS,
+            kv_scales=(sk, sv) if variant == "int8" else None,
+            lora=(x, a_t, b, idx, HD ** -0.5) if variant == "lora" else None)
+
+    compiled = jax.jit(fn).lower(
+        spec((lanes, H, HD), jnp.bfloat16),
+        spec((L, NUM_PAGES, PS, D), pool_dtype), spec((L, NUM_PAGES, PS, D), pool_dtype),
+        spec((lanes, pages), jnp.int32), spec((lanes,), jnp.int32), spec((), jnp.int32),
+        spec((L, NUM_PAGES), jnp.float32), spec((L, NUM_PAGES), jnp.float32),
+        spec((lanes, D), jnp.bfloat16), spec((L, SLOTS, RANK, D), jnp.bfloat16),
+        spec((L, SLOTS, RANK, 3 * D), jnp.bfloat16), spec((lanes,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the pool goes to the kernel as it rests: nothing shaped like a
+    # layer of it exists, and the only pool-shaped values are parameters
+    whole, layer = f"[{L},{NUM_PAGES},{PS},{D}]", f"[{NUM_PAGES},{PS},{D}]"
+    assert layer not in text.replace(whole, "")
+    produced = [ln for ln in text.splitlines()
+                if whole in ln.partition(" = ")[2].partition("(")[0]
+                and " parameter(" not in ln]
+    assert not produced, produced[:3]

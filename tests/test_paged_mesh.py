@@ -445,7 +445,7 @@ class TestGeneratorLaneDp:
 class TestAccountingDp:
     """paged_hbm_accounting dp_degree + paged_max_context."""
 
-    KW = dict(d_model=256, num_layers=4, dtype_bytes=2, flat_pool=True,
+    KW = dict(d_model=256, num_layers=4, dtype_bytes=2,
               chunk_impl="ring")
 
     def test_dp_divides_kv_terms_and_keys_stay_separate(self):
@@ -651,7 +651,7 @@ class TestLongContextAdmit:
         acct_kw = dict(
             d_model=self.LCFG["d_model"],
             num_layers=self.LCFG["num_layers"],
-            page_size=8, dtype_bytes=4, flat_pool=True, chunk_impl="ring",
+            page_size=8, dtype_bytes=4, chunk_impl="ring",
         )
         full = paged_hbm_accounting(streams=1, ctx_len=ctx, **acct_kw)
         shard = paged_hbm_accounting(

@@ -1,0 +1,401 @@
+"""The KV pool does not move (PR 25): it rests in the layout its reader
+uses, the decode kernel addresses (layer, page) in the whole pool, and
+K/V writes land in place.
+
+Fast tier, CPU, the kernel forced (Pallas in interpret mode):
+
+* the traced ``paged_chunk`` and ``paged_prefill`` programs, read as
+  jaxprs: every ``pallas_call``'s K/V operands ARE the scan carry's pool
+  variables, and the pools a program returns come from its arguments
+  through ``dynamic_update_slice`` alone;
+* the kernel against a float64 host oracle on a 3-layer pool whose
+  layers differ, per layer, impl and pool dtype (a wrong layer index is
+  a wrong answer), the in-kernel LoRA fold and the zero-length lane;
+* the one layout decision (``pool_is_flat``) and what the engine
+  reports of it under stream, grid, kernel-off and a TP mesh.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.extend import core as jex_core
+
+from seldon_core_tpu.models.paged import PagedEngine, pool_is_flat
+from seldon_core_tpu.models.transformer import TransformerLM
+from seldon_core_tpu.ops.kernels import paged_attention_decode
+
+CFG = dict(vocab_size=64, d_model=32, num_layers=3, num_heads=2, max_len=256)
+LAYERS = CFG["num_layers"]
+
+
+@pytest.fixture(scope="module")
+def params():
+    lm = TransformerLM(dtype=jnp.float32, **CFG)
+    return lm.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]
+
+
+def _engine(params, **kw):
+    base = dict(dtype=jnp.float32, page_size=8, max_slots=4, steps_per_call=4)
+    base.update(kw)
+    return PagedEngine(params, **CFG, **base)
+
+
+@pytest.fixture
+def kernel_lane(monkeypatch):
+    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
+    monkeypatch.setenv("SELDON_TPU_CHUNK_IMPL", "pool")
+    monkeypatch.delenv("SELDON_TPU_PAGED_KERNEL_IMPL", raising=False)
+    monkeypatch.delenv("SELDON_TPU_KV_DTYPE", raising=False)
+
+
+# ---------------------------------------------------------------------------
+# (a) the programs, read as jaxprs
+# ---------------------------------------------------------------------------
+
+# primitives that hand their operands to a sub-jaxpr one for one
+_CALLS = ("pjit", "jit", "closed_call", "core_call", "remat", "checkpoint",
+          "custom_jvp_call", "custom_vjp_call")
+
+
+def _sub_jaxpr(eqn):
+    for key in ("jaxpr", "call_jaxpr", "fun_jaxpr"):
+        sub = eqn.params.get(key)
+        if sub is not None:
+            return getattr(sub, "jaxpr", sub)
+    return None
+
+
+class _PoolAudit:
+    """Follow the pool arguments of a traced program through it.
+
+    A variable is *the pool* if it is a pool argument, the result of a
+    ``dynamic_update_slice`` ON the pool, or either of those carried
+    into or out of a scan / call.  Everything else that touches the
+    pool is recorded by primitive name."""
+
+    def __init__(self, pool_shape):
+        self.pool_shape = tuple(pool_shape)
+        self.kernel_reads = []   # per pool-shaped operand of a kernel: writes
+        #                          since its scan step began; None = not the pool
+        self.consumers = set()   # primitives that took the pool as an operand
+
+    def walk(self, jaxpr, pool_in):
+        """``pool_in``: {invar index: writes so far}.  Returns the same
+        for the jaxpr's outvars."""
+        tag = {jaxpr.invars[i]: n for i, n in pool_in.items()}
+
+        def of(v):
+            return None if isinstance(v, jex_core.Literal) else tag.get(v)
+
+        for eqn in jaxpr.eqns:
+            name = eqn.primitive.name
+            ins = [of(v) for v in eqn.invars]
+            if name == "pallas_call":
+                self.kernel_reads += [
+                    n for v, n in zip(eqn.invars, ins)
+                    if tuple(getattr(v.aval, "shape", ())) == self.pool_shape]
+                self.consumers.add(name)
+                continue
+            if all(n is None for n in ins):
+                continue
+            self.consumers.add(name)
+            if name == "dynamic_update_slice" and ins[0] is not None:
+                tag[eqn.outvars[0]] = ins[0] + 1
+            elif name == "scan":
+                body = eqn.params["jaxpr"].jaxpr
+                # a step's reads see the pool as the step received it
+                out = self.walk(body, {i: 0 for i, n in enumerate(ins)
+                                       if n is not None})
+                for i, n in out.items():
+                    tag[eqn.outvars[i]] = n
+            elif name in _CALLS and _sub_jaxpr(eqn) is not None:
+                out = self.walk(_sub_jaxpr(eqn), {i: n for i, n in enumerate(ins)
+                                                  if n is not None})
+                for i, n in out.items():
+                    tag[eqn.outvars[i]] = n
+        return {i: of(v) for i, v in enumerate(jaxpr.outvars) if of(v) is not None}
+
+
+def _traced(jitted, args):
+    """``(jaxpr, {flat position of pk and of pv: 0})`` of a program
+    whose arguments are ``(params, pk, pv, ...)``."""
+    first = len(jax.tree.leaves(args[0]))
+    return jitted.trace(*args).jaxpr.jaxpr, {first: 0, first + 1: 0}
+
+
+def _unwrap(fn):  # the jit under the compile sentinel
+    return fn if hasattr(fn, "trace") else fn.__wrapped__
+
+
+class TestProgramsAddressThePoolInPlace:
+    @pytest.mark.parametrize("buckets", [((4, 4),), ((2, 2), (2, 4))])
+    def test_chunk_kernels_read_the_carry_and_writes_are_dus(
+        self, params, kernel_lane, buckets
+    ):
+        eng = _engine(params)
+        try:
+            assert eng._kernel_active and eng._pool_flat
+            jaxpr, pools = _traced(eng._chunk_program(4, buckets),
+                                   eng.chunk_example_args(buckets))
+            audit = _PoolAudit(eng.pages_k.shape)
+            out = audit.walk(jaxpr, pools)
+        finally:
+            eng.close()
+        # K and V of every kernel call (layers x buckets) are the carry's
+        # own pool variables, read before the step's first write
+        assert len(audit.kernel_reads) == 2 * LAYERS * len(buckets)
+        assert audit.kernel_reads == [0] * len(audit.kernel_reads)
+        # nothing slices, reshapes, copies or gathers the pool
+        assert audit.consumers <= {"pallas_call", "dynamic_update_slice",
+                                   "scan", *_CALLS}, audit.consumers
+        # the returned pools (outputs 1, 2) are the arguments, written in
+        # place: one DUS per lane per step
+        assert out.get(1) == out.get(2) == eng.max_slots
+
+    def test_prefill_gathers_the_whole_pool_and_writes_are_dus(
+        self, params, kernel_lane
+    ):
+        eng = _engine(params)
+        try:
+            k, bucket = 2, 16
+            kv_k, kv_v = eng._kv_args()
+            jaxpr, pools = _traced(
+                _unwrap(eng._build_prefill(bucket, k)),
+                (eng.params, kv_k, kv_v, jnp.zeros((k, bucket), jnp.int32),
+                 jnp.ones((k,), jnp.int32),
+                 jnp.zeros((k, bucket // eng.page_size), jnp.int32)))
+            audit = _PoolAudit(eng.pages_k.shape)
+            out = audit.walk(jaxpr, pools)
+        finally:
+            eng.close()
+        # the read is ONE (layer, page) gather of the whole pool: no
+        # layer is cut out of it first
+        assert audit.consumers <= {"gather", "dynamic_update_slice", *_CALLS}
+        assert out.get(1) == out.get(2) == k * (bucket // 8)
+
+    def test_kernel_off_chunk_still_slices_per_layer(self, params, monkeypatch):
+        """The contrast arm: the audit does see a pool that moves."""
+        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "0")
+        monkeypatch.setenv("SELDON_TPU_CHUNK_IMPL", "pool")
+        eng = _engine(params)
+        try:
+            jaxpr, pools = _traced(eng._chunk_program(4, ((4, 4),)),
+                                   eng.chunk_example_args(((4, 4),)))
+            audit = _PoolAudit(eng.pages_k.shape)
+            audit.walk(jaxpr, pools)
+        finally:
+            eng.close()
+        assert not audit.kernel_reads
+        assert "slice" in audit.consumers
+
+
+# ---------------------------------------------------------------------------
+# (b) the kernel on a pool of distinct layers
+# ---------------------------------------------------------------------------
+
+B, H, HD, PS, P, NUM_PAGES = 4, 2, 64, 8, 4, 12
+D = H * HD
+LENGTHS = np.array([0, 5, 16, 32], np.int32)  # a dead lane, a partial page, full
+
+
+def _pool(rng, impl, pool):
+    """``(what the pool stores, as float64, split; device array in the
+    impl's layout; per-page scales or None)``."""
+    raw = rng.normal(size=(LAYERS, NUM_PAGES, PS, H, HD)).astype(np.float32)
+    scales = None
+    if pool == "int8":
+        scales = (np.abs(raw).max(axis=(2, 3, 4)) / 127.0).astype(np.float32)
+        q = np.clip(np.round(raw / scales[:, :, None, None, None]), -127, 127)
+        stored = q * scales[:, :, None, None, None].astype(np.float64)
+        dev = jnp.asarray(q, jnp.int8)
+    else:
+        dev = jnp.asarray(raw, {"f32": jnp.float32, "bf16": jnp.bfloat16}[pool])
+        stored = np.asarray(dev.astype(jnp.float32), np.float64)
+    if impl == "stream":
+        dev = dev.reshape(LAYERS, NUM_PAGES, PS, D)
+    return stored, dev, scales
+
+
+def _oracle(q, pk, pv, tables, lengths):
+    gk = pk[tables].reshape(B, P * PS, H, HD)
+    gv = pv[tables].reshape(B, P * PS, H, HD)
+    s = np.einsum("bhd,bkhd->bhk", q.astype(np.float64), gk)
+    mask = np.arange(P * PS)[None, :] < lengths[:, None]
+    s = np.where(mask[:, None, :], s, -np.inf)
+    m = s.max(-1)
+    with np.errstate(invalid="ignore"):
+        w = np.where(mask[:, None, :], np.exp(s - m[..., None]), 0.0)
+    l = w.sum(-1)
+    return np.einsum("bhk,bkhd->bhd", w, gv) / np.where(l > 0, l, 1.0)[..., None]
+
+
+def _check(outs, ref):
+    acc, l = np.asarray(outs[0], np.float64), np.asarray(outs[2], np.float64)
+    live = LENGTHS > 0
+    got = acc / np.where(l > 0, l, 1.0)[..., None]
+    assert float(np.max(np.abs(got[live] - ref[live]))) < 1e-4
+    # the dead lane carries the neutral flash state, not NaN
+    assert np.all(l[~live] == 0.0) and np.all(acc[~live] == 0.0)
+    assert np.all(np.isinf(np.asarray(outs[1])[~live]))
+
+
+@pytest.mark.parametrize("layer", range(LAYERS))
+@pytest.mark.parametrize("pool", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("impl", ["stream", "grid"])
+def test_kernel_reads_its_layer_of_the_whole_pool(impl, pool, layer, monkeypatch):
+    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", impl)
+    rng = np.random.default_rng(7)
+    q = rng.normal(size=(B, H, HD)).astype(np.float32)
+    pkn, pk, sk = _pool(rng, impl, pool)
+    pvn, pv, sv = _pool(rng, impl, pool)
+    tables = rng.integers(1, NUM_PAGES, size=(B, P)).astype(np.int32)
+    kw = {}
+    if pool == "int8":
+        kw["kv_scales"] = (jnp.asarray(sk), jnp.asarray(sv))
+    # stream: the layer is a traced scalar (one kernel for all layers)
+    layer_arg = jnp.int32(layer) if impl == "stream" else layer
+    outs = jax.jit(
+        lambda q, pk, pv, t, n, layer: paged_attention_decode(
+            q, pk, pv, t, n, layer=layer, page_size=PS, **kw),
+        static_argnums=() if impl == "stream" else (5,),
+    )(jnp.asarray(q), pk, pv, jnp.asarray(tables), jnp.asarray(LENGTHS), layer_arg)
+    _check(outs, _oracle(q, pkn[layer], pvn[layer], tables, LENGTHS))
+
+
+@pytest.mark.parametrize("layer", range(LAYERS))
+def test_lora_fold_indexes_layer_and_slot(layer, monkeypatch):
+    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", "stream")
+    rng = np.random.default_rng(11)
+    rank, slots = 4, 3
+    q = rng.normal(size=(B, H, HD)).astype(np.float32)
+    pkn, pk, _ = _pool(rng, "stream", "f32")
+    pvn, pv, _ = _pool(rng, "stream", "f32")
+    tables = rng.integers(1, NUM_PAGES, size=(B, P)).astype(np.int32)
+    x = rng.normal(size=(B, D)).astype(np.float32)
+    a = rng.normal(size=(LAYERS, slots, D, rank)).astype(np.float32) * 0.05
+    b = rng.normal(size=(LAYERS, slots, rank, 3 * D)).astype(np.float32) * 0.05
+    a[:, 0] = 0.0  # slot 0 = no adapter
+    b[:, 0] = 0.0
+    idx = (np.arange(B) % slots).astype(np.int32)
+    q_scale = HD ** -0.5
+    outs = paged_attention_decode(
+        jnp.asarray(q), pk, pv, jnp.asarray(tables), jnp.asarray(LENGTHS),
+        layer=layer, page_size=PS,
+        lora=(jnp.asarray(x), jnp.asarray(np.swapaxes(a, -1, -2)),
+              jnp.asarray(b), jnp.asarray(idx), q_scale))
+    delta = np.einsum("bd,bdr,bre->be", x, a[layer][idx], b[layer][idx])
+    assert float(np.max(np.abs(np.asarray(outs[3]) - delta))) < 1e-4
+    q_eff = q + q_scale * delta[:, :D].reshape(B, H, HD)
+    _check(outs, _oracle(q_eff, pkn[layer], pvn[layer], tables, LENGTHS))
+
+
+@pytest.mark.parametrize("impl,ndim", [("stream", 5), ("grid", 4)])
+def test_pool_in_the_other_impls_layout_is_refused(impl, ndim, monkeypatch):
+    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", impl)
+    shape = (LAYERS, NUM_PAGES, PS, H, HD) if ndim == 5 else (LAYERS, NUM_PAGES, PS, D)
+    pool = jnp.zeros(shape, jnp.float32)
+    with pytest.raises(ValueError, match="pool_is_flat"):
+        paged_attention_decode(
+            jnp.zeros((B, H, HD)), pool, pool, jnp.zeros((B, P), jnp.int32),
+            jnp.asarray(LENGTHS), layer=0, page_size=PS)
+
+
+def _tokens(eng, n=4, max_new=10):
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, CFG["vocab_size"], size=(14 + 3 * i,)).astype(np.int32)
+               for i in range(n)]
+    streams = [eng.submit(p, max_new_tokens=max_new) for p in prompts]
+    eng.run()
+    return np.stack([s.result for s in streams])
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_three_layer_engine_tokens_kernel_vs_gather(params, monkeypatch, kv):
+    """End to end on three distinct layers: prefill's whole-pool gather,
+    the kernel's (layer, page) reads and the in-place writes give the
+    gather lane's tokens exactly (f32 engine; the int8 pool shares one
+    quantised pool between two readers)."""
+    monkeypatch.setenv("SELDON_TPU_CHUNK_IMPL", "pool")
+    monkeypatch.setenv("SELDON_TPU_KV_DTYPE", kv)
+    out = {}
+    for mode in ("0", "force"):
+        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", mode)
+        eng = _engine(params)
+        try:
+            assert eng._kernel_active is (mode == "force")
+            out[mode] = _tokens(eng)
+        finally:
+            eng.close()
+    np.testing.assert_array_equal(out["0"], out["force"])
+
+
+# ---------------------------------------------------------------------------
+# (c) the one layout decision, and what the engine reports of it
+# ---------------------------------------------------------------------------
+
+
+class TestLayoutDecision:
+    GEOM = dict(num_heads=CFG["num_heads"], head_dim=16)
+
+    @pytest.mark.parametrize("mode,impl,mesh,flat", [
+        ("force", "stream", None, True),    # the stream kernel reads flat
+        ("force", "grid", None, False),     # only grid's BlockSpecs need split
+        ("1", "grid", None, False),
+        ("0", "grid", None, True),          # kernel off: the impl is moot
+        ("0", "stream", None, True),
+        ("auto", "grid", None, jax.default_backend() != "tpu"),
+        ("force", "grid", object(), True),  # a TP mesh turns the kernels off
+    ])
+    def test_pool_is_flat(self, monkeypatch, mode, impl, mesh, flat):
+        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", mode)
+        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", impl)
+        assert pool_is_flat(mesh, **self.GEOM) is flat
+
+    @pytest.mark.parametrize("mode,impl,layout,active,reported", [
+        ("force", "stream", "flat", True, "stream"),
+        ("force", "grid", "split", True, "grid"),
+        ("0", "stream", "flat", False, None),
+        ("0", "grid", "flat", False, None),
+    ])
+    def test_engine_reports_the_lane(self, params, monkeypatch, mode, impl,
+                                     layout, active, reported):
+        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", mode)
+        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", impl)
+        monkeypatch.setenv("SELDON_TPU_CHUNK_IMPL", "pool")
+        eng = _engine(params)
+        try:
+            rep = eng.lane_report()
+            assert rep["pool_layout"] == layout
+            assert rep["kernel_active"] is active
+            assert rep["kernel_impl"] == reported
+            assert eng.engine_stats()["kernel_active"] == int(active)
+            assert eng.pages_k.ndim == (4 if layout == "flat" else 5)
+        finally:
+            eng.close()
+
+    def test_mesh_engine_rests_flat_with_the_kernel_off(self, params, monkeypatch):
+        if len(jax.devices()) < 2:
+            pytest.skip("needs two devices for a TP mesh")
+        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
+        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", "grid")
+        eng = _engine(params, tp=2)
+        try:
+            rep = eng.lane_report()
+            assert rep["tp"] == 2 and rep["pool_layout"] == "flat"
+            assert rep["kernel_active"] is False and rep["kernel_impl"] is None
+        finally:
+            eng.close()
+
+    def test_explicit_ring_with_kernel_request_stays_flat(self, params, monkeypatch):
+        """The ring chunk never calls the kernel: the request buys no
+        split pool any more, only the warning."""
+        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
+        monkeypatch.setenv("SELDON_TPU_CHUNK_IMPL", "ring")
+        eng = _engine(params)
+        try:
+            rep = eng.lane_report()
+            assert rep["pool_layout"] == "flat" and rep["kernel_active"] is False
+        finally:
+            eng.close()

@@ -37,34 +37,43 @@ PAGES_PER = MAX_LEN // PS
 NUM_PAGES = SLOTS * PAGES_PER + 1
 
 
-def _paged_inputs(rng, pool_dtype):
-    """Pool + block tables with ragged lengths: one empty lane, one
-    single token, partial pages and a full table."""
+LAYERS, LAYER = 2, 1  # a whole pool of two layers; the kernel reads the second
+
+
+def _paged_inputs(rng, pool_dtype, impl):
+    """A whole pool + block tables with ragged lengths: one empty lane,
+    one single token, partial pages and a full table.  The device pool
+    is in the layout the impl reads: flat ``(L, pages, ps, h*hd)`` for
+    stream, split for grid; the oracle gets layer ``LAYER`` of what the
+    pool stores."""
     import jax.numpy as jnp
 
     batch = SLOTS
     q = rng.normal(size=(batch, H, HD)).astype(np.float32) * HD ** -0.5
-    pk = rng.normal(size=(NUM_PAGES, PS, H, HD)).astype(np.float32)
-    pv = rng.normal(size=(NUM_PAGES, PS, H, HD)).astype(np.float32)
+    pk = rng.normal(size=(LAYERS, NUM_PAGES, PS, H, HD)).astype(np.float32)
+    pv = rng.normal(size=(LAYERS, NUM_PAGES, PS, H, HD)).astype(np.float32)
     tables = rng.permutation(np.arange(1, NUM_PAGES))[: batch * PAGES_PER]
     tables = tables.reshape(batch, PAGES_PER).astype(np.int32)
     lengths = rng.integers(1, MAX_LEN, size=(batch,)).astype(np.int32)
     lengths[:4] = (0, 1, PS, MAX_LEN - 1)
     scales = None
     if pool_dtype == jnp.int8:
-        sk = np.abs(pk).max(axis=(1, 2, 3)) / 127.0
-        sv = np.abs(pv).max(axis=(1, 2, 3)) / 127.0
-        pk_q = np.clip(np.round(pk / sk[:, None, None, None]), -127, 127)
-        pv_q = np.clip(np.round(pv / sv[:, None, None, None]), -127, 127)
+        sk = np.abs(pk).max(axis=(2, 3, 4)) / 127.0
+        sv = np.abs(pv).max(axis=(2, 3, 4)) / 127.0
+        pk_q = np.clip(np.round(pk / sk[..., None, None, None]), -127, 127)
+        pv_q = np.clip(np.round(pv / sv[..., None, None, None]), -127, 127)
         scales = (sk.astype(np.float32), sv.astype(np.float32))
         pk_dev, pv_dev = jnp.asarray(pk_q, jnp.int8), jnp.asarray(pv_q, jnp.int8)
-        pk, pv = pk_q * sk[:, None, None, None], pv_q * sv[:, None, None, None]
+        pk, pv = pk_q * sk[..., None, None, None], pv_q * sv[..., None, None, None]
     else:
         pk_dev, pv_dev = jnp.asarray(pk, pool_dtype), jnp.asarray(pv, pool_dtype)
         # the oracle sees what the pool stores, not what was drawn
         pk = np.asarray(pk_dev.astype(jnp.float32))
         pv = np.asarray(pv_dev.astype(jnp.float32))
-    return q, pk, pv, pk_dev, pv_dev, tables, lengths, scales
+    if impl == "stream":
+        pk_dev = pk_dev.reshape(LAYERS, NUM_PAGES, PS, D)
+        pv_dev = pv_dev.reshape(LAYERS, NUM_PAGES, PS, D)
+    return q, pk[LAYER], pv[LAYER], pk_dev, pv_dev, tables, lengths, scales
 
 
 def _paged_oracle(q, pk, pv, tables, lengths):
@@ -92,7 +101,8 @@ def _paged_case(impl, pool="bf16", lora=False):
         os.environ["SELDON_TPU_PAGED_KERNEL_IMPL"] = impl
         rng = np.random.default_rng(0)
         pool_dtype = {"bf16": jnp.bfloat16, "f32": jnp.float32, "int8": jnp.int8}[pool]
-        q, pk, pv, pk_dev, pv_dev, tables, lengths, scales = _paged_inputs(rng, pool_dtype)
+        q, pk, pv, pk_dev, pv_dev, tables, lengths, scales = _paged_inputs(
+            rng, pool_dtype, impl)
         kw = {}
         if scales is not None:
             kw["kv_scales"] = tuple(jnp.asarray(s) for s in scales)
@@ -101,21 +111,22 @@ def _paged_case(impl, pool="bf16", lora=False):
         if lora:
             rank, slots = 8, 4
             x = rng.normal(size=(SLOTS, D)).astype(np.float32)
-            a = rng.normal(size=(slots, D, rank)).astype(np.float32) * 0.05
-            b = rng.normal(size=(slots, rank, 3 * D)).astype(np.float32) * 0.05
-            a[0] = 0.0  # slot 0 = no adapter
-            b[0] = 0.0
+            a = rng.normal(size=(LAYERS, slots, D, rank)).astype(np.float32) * 0.05
+            b = rng.normal(size=(LAYERS, slots, rank, 3 * D)).astype(np.float32) * 0.05
+            a[:, 0] = 0.0  # slot 0 = no adapter
+            b[:, 0] = 0.0
             idx = (np.arange(SLOTS) % slots).astype(np.int32)
             q_scale = HD ** -0.5
             kw["lora"] = (
                 jnp.asarray(x), jnp.asarray(np.swapaxes(a, -1, -2)),
                 jnp.asarray(b), jnp.asarray(idx), q_scale,
             )
-            delta_ref = np.einsum("bd,bdr,bre->be", x, a[idx], b[idx])
+            delta_ref = np.einsum("bd,bdr,bre->be", x, a[LAYER][idx], b[LAYER][idx])
             # the kernel receives the UNADAPTED pre-scaled q and folds
             # the delta's q third itself
             q_eff = q + q_scale * delta_ref[:, :D].reshape(SLOTS, H, HD)
-        fn = jax.jit(lambda *a_: paged_attention_decode(*a_, page_size=PS, **kw))
+        fn = jax.jit(lambda *a_: paged_attention_decode(
+            *a_, layer=LAYER, page_size=PS, **kw))
         outs = fn(jnp.asarray(q), pk_dev, pv_dev, jnp.asarray(tables),
                   jnp.asarray(lengths))
         outs = jax.block_until_ready(outs)

@@ -77,8 +77,13 @@ def main():
     rng = np.random.default_rng(0)
     dt = jnp.bfloat16
     q = jnp.asarray(rng.normal(size=(B, h, hd)), dt)
-    pk = jnp.asarray(rng.normal(size=(num_pages, ps, h, hd)), dt)
-    pv = jnp.asarray(rng.normal(size=(num_pages, ps, h, hd)), dt)
+    # ONE layer of pool, held as the whole-pool form the wrapper takes
+    # (L = 1), in the layout the impl reads: flat for stream, split for
+    # grid — the form each rests in under the engine (pool_is_flat)
+    pool_shape = ((num_pages, ps, h * hd) if args.impl == "stream"
+                  else (num_pages, ps, h, hd))
+    pk = jnp.asarray(rng.normal(size=pool_shape), dt)
+    pv = jnp.asarray(rng.normal(size=pool_shape), dt)
     tables = jnp.asarray(
         1 + np.arange(B * pages_per).reshape(B, pages_per) % (num_pages - 1),
         jnp.int32)
@@ -86,18 +91,14 @@ def main():
 
     kv_scales = None
     if args.kv_dtype == "int8":
-        amax = jnp.maximum(
-            jnp.max(jnp.abs(pk.astype(jnp.float32)), axis=(1, 2, 3)) / 127.0,
-            1e-8)
-        pk = jnp.clip(jnp.round(pk.astype(jnp.float32)
-                                / amax[:, None, None, None]),
-                      -127, 127).astype(jnp.int8)
-        vmax = jnp.maximum(
-            jnp.max(jnp.abs(pv.astype(jnp.float32)), axis=(1, 2, 3)) / 127.0,
-            1e-8)
-        pv = jnp.clip(jnp.round(pv.astype(jnp.float32)
-                                / vmax[:, None, None, None]),
-                      -127, 127).astype(jnp.int8)
+        def quantise(pool):
+            f = pool.astype(jnp.float32).reshape(num_pages, -1)
+            amax = jnp.maximum(jnp.max(jnp.abs(f), axis=1) / 127.0, 1e-8)
+            q = jnp.clip(jnp.round(f / amax[:, None]), -127, 127)
+            return q.astype(jnp.int8).reshape(pool.shape), amax
+
+        pk, amax = quantise(pk)
+        pv, vmax = quantise(pv)
         kv_scales = (amax, vmax)
 
     steps = args.steps
@@ -106,8 +107,10 @@ def main():
     def kernel_arm(q, pk, pv, tables, lengths):
         def step(c, _):
             acc, m, el = paged_attention_decode(
-                c, pk, pv, tables, lengths, page_size=ps,
-                kv_scales=kv_scales)
+                c, pk[None], pv[None], tables, lengths, layer=0,
+                page_size=ps,
+                kv_scales=(None if kv_scales is None
+                           else tuple(s[None] for s in kv_scales)))
             return (acc / jnp.maximum(el, 1e-9)[..., None]).astype(c.dtype), 0
         out, _ = jax.lax.scan(step, q, None, length=steps)
         return out
@@ -140,7 +143,7 @@ def main():
 
     acct_kw = dict(
         num_layers=args.layers, d_model=h * hd, page_size=ps,
-        ctx_len=args.ctx, streams=B, chunk_impl="pool", flat_pool=False,
+        ctx_len=args.ctx, streams=B, chunk_impl="pool",
         dtype_bytes=2)
     bf16_bytes = paged_hbm_accounting(**acct_kw)["pool_bytes"]
     int8_bytes = paged_hbm_accounting(kv_dtype="int8", **acct_kw)["pool_bytes"]

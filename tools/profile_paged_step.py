@@ -74,8 +74,8 @@ def main():
     B, L = args.slots, args.layers
     h, hd = args.heads, args.d_model // args.heads
     params = eng.params
-    # match the engine's pool layout (flat (L, pages, ps, d) by default
-    # since r5; split (L, pages, ps, h, hd) under kernel mode) AND its
+    # match the engine's pool layout (flat (L, pages, ps, d); split
+    # (L, pages, ps, h, hd) under the grid kernel impl) AND its
     # sharding — under a TP mesh the chunk program pins heads-sharded
     # pools on its signature, so replicated zeros would pay a reshard
     # copy every timed call.  Created ALREADY sharded (jit with
