@@ -38,18 +38,32 @@ def _buckets_for(max_len: int) -> List[int]:
     return out
 
 
-def load_lm_params(model_uri: str, config: Dict[str, int], seed: int):
-    """Shared TransformerLM checkpoint loader for the generation lanes
-    (GenerativeLM / StreamingLM / SpeculativeLM): init the tree shape,
-    then overlay a flax msgpack checkpoint from the storage downloader
-    when ``model_uri`` is set."""
+def load_lm_params(model_uri: str, config: Dict[str, int], seed: int,
+                   spec: Any = None):
+    """Shared checkpoint loader for the generation lanes (GenerativeLM /
+    StreamingLM / SpeculativeLM): init the tree shape, then overlay a
+    flax msgpack checkpoint from the storage downloader when
+    ``model_uri`` is set.
+
+    ``spec`` (models/spec.py; None = GPT-2) says what the block is made
+    of.  The one block ``TransformerLM`` builds keeps that module's own
+    tree (float32 at rest); any other is ``spec.init_params``: the tree
+    the paged LM declares for that spec, each leaf made in the type it
+    rests in (bf16 matrices for OLMoE: an f32 tree of its size does not
+    fit the chip)."""
     import jax
     import jax.numpy as jnp
 
-    from seldon_core_tpu.models.transformer import TransformerLM
+    if spec is None or spec.transformer_lm:
+        from seldon_core_tpu.models.transformer import TransformerLM
 
-    module = TransformerLM(dtype=jnp.bfloat16, **config)
-    params = module.init(jax.random.key(seed), jnp.zeros((1, 8), jnp.int32))["params"]
+        module = TransformerLM(dtype=jnp.bfloat16, **config)
+        params = module.init(
+            jax.random.key(seed), jnp.zeros((1, 8), jnp.int32))["params"]
+    else:
+        from seldon_core_tpu.models.spec import init_params
+
+        params = init_params(spec, config, seed)
     if model_uri:
         from flax import serialization
 

@@ -126,7 +126,14 @@ def _build_modules():
     import jax
     import jax.numpy as jnp
 
-    def _dense(precision, features, dtype, name):
+    from seldon_core_tpu.models.spec import GPT2
+
+    def _rest(spec, dtype):
+        """The type a spec's matrices and embeddings rest in (what
+        ``init`` declares; ``apply`` takes the tree as it is given)."""
+        return jnp.float32 if spec.weights_f32 else dtype
+
+    def _dense(precision, features, dtype, name, spec=GPT2):
         """Projection factory: ``precision="w8a8"`` swaps every decode
         projection (qkv, attn_proj, mlp_in/out, the unembed head) for
         the int8×int8 layer (ops/w8a8.py) — SAME params tree as
@@ -141,7 +148,97 @@ def _build_modules():
             from seldon_core_tpu.ops.w8a8 import W8A8Dense
 
             return W8A8Dense(features=features, dtype=dtype, name=name)
-        return nn.Dense(features, dtype=dtype, name=name)
+        return nn.Dense(features, use_bias=spec.bias, dtype=dtype,
+                        param_dtype=_rest(spec, dtype), name=name)
+
+    # ---- what a ModelSpec (models/spec.py) changes in a block ---------
+    # Each helper traces exactly the GPT-2 operations for the GPT2 spec
+    # (the auto-named LayerNorms, the biased Dense, the GELU MLP), so
+    # GPT-2's programs lower as they did before a second model came.
+
+    def _norm(spec, name):
+        if spec.norm == "rmsnorm":
+            return nn.RMSNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
+                              name=name)
+        return nn.LayerNorm(dtype=jnp.float32)
+
+    def _heads(mod, q, k, v, positions, shape):
+        """Split flat q/k/v into heads; before that the spec's QK-norm
+        (RMSNorm over the whole projection), after it its rotary
+        embedding at the tokens' absolute positions — both on q and k
+        only, both before K is cached."""
+        spec = mod.spec
+        if spec.qk_norm:
+            q = nn.RMSNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
+                           name="q_norm")(q)
+            k = nn.RMSNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
+                           name="k_norm")(k)
+        q, k, v = q.reshape(shape), k.reshape(shape), v.reshape(shape)
+        if spec.rope:
+            from seldon_core_tpu.models.spec import rope
+
+            q = rope(q, positions, spec.rope_theta)
+            k = rope(k, positions, spec.rope_theta)
+        if spec.qk_norm or spec.rope:  # both compute in f32
+            q, k = q.astype(mod.dtype), k.astype(mod.dtype)
+        return q, k, v
+
+    def _ffn(mod, x, proj, token_mask):
+        """The block's second half: ``x + FFN(norm(x))``.  Dense GELU
+        MLP, or routed SwiGLU experts (ops/moe.py) — then the second
+        value holds the layer's assignment histogram ``(int32[E],)``
+        over the rows ``token_mask`` keeps (``()`` for a dense FFN)."""
+        spec = mod.spec
+        d_model = x.shape[-1]
+        y = _norm(spec, "ffn_norm")(x)
+        if not spec.routed:
+            y = proj("mlp_in", mod.mlp_ratio * d_model, y)
+            y = nn.gelu(y)
+            return x + proj("mlp_out", d_model, y), ()
+        from seldon_core_tpu.ops import moe
+
+        e, f = spec.num_experts, spec.expert_width
+        init = nn.initializers.normal(0.02)
+        w_router = mod.param("router", init, (d_model, e), jnp.float32)
+        rest = _rest(spec, mod.dtype)
+        w_gate = mod.param("experts_gate", init, (e, d_model, f), rest)
+        w_up = mod.param("experts_up", init, (e, d_model, f), rest)
+        w_down = mod.param("experts_down", init, (e, f, d_model), rest)
+        rows = y.reshape(-1, d_model)
+        gates, experts = moe.route(rows, w_router, spec.experts_per_tok)
+        out = moe.expert_ffn(
+            rows.astype(mod.dtype), w_gate, w_up, w_down, gates, experts)
+        hist = moe.expert_histogram(
+            experts, e,
+            None if token_mask is None else token_mask.reshape(-1))
+        return x + out.reshape(x.shape).astype(x.dtype), (hist,)
+
+    def _embed(lm, tokens, positions):
+        tokens = tokens.astype(jnp.int32)
+        rest = _rest(lm.spec, lm.dtype)
+        x = nn.Embed(
+            lm.vocab_size, lm.d_model, dtype=lm.dtype, param_dtype=rest,
+            name="tok_embed",
+        )(tokens)
+        if lm.spec.residual_f32:
+            x = x.astype(jnp.float32)  # and every ``x + ...`` after it
+        if lm.spec.rope:
+            return x  # positions enter in every block, on q and k
+        pos = nn.Embed(
+            lm.max_len, lm.d_model, dtype=lm.dtype, param_dtype=rest,
+            name="pos_embed",
+        )(positions)
+        return x + pos
+
+    def _head(lm, x, new_k, new_v, hists):
+        """Final norm and unembedding; ``(logits, K, V)`` stacked over
+        layers, and a routed spec's ``int32[layers, E]`` assignment
+        histogram as a fourth value."""
+        x = _norm(lm.spec, "final_norm")(x)
+        logits = _dense(lm.precision, lm.vocab_size, lm.dtype, "head",
+                        lm.spec)(x)
+        out = (logits.astype(jnp.float32), jnp.stack(new_k), jnp.stack(new_v))
+        return out + (jnp.stack(hists),) if hists else out
 
     class PagedTransformerBlock(nn.Module):
         """TransformerBlock whose attention reads a paged K/V pool.
@@ -154,12 +251,18 @@ def _build_modules():
         mlp_ratio: int = 4
         dtype: Any = jnp.bfloat16
         precision: str = "bf16"  # "w8a8": int8×int8 projections
+        spec: Any = GPT2
 
         @nn.compact
         def __call__(self, x, pk, pv, block_tables, lengths,
                      lora=None, adapter_idx=None, kv_scales=None,
-                     layer=None):
+                     layer=None, positions=None, token_mask=None):
             # x: (B, L, d)
+            # positions: (B, L) absolute token indices (a RoPE spec
+            # reads them; GPT-2's enter at the LM's embedding)
+            # token_mask: (B, L) rows the routing counters count
+            # returns (x, k, v), and a routed spec's assignment
+            # histogram int32[E] as a fourth value
             # pk/pv + layer: two forms, picked by the LM.  ``layer`` an
             # int — the kernel lane's: pk/pv are the WHOLE pools, flat
             # (L, num_pages, ps, d) or (grid impl) split (L, num_pages,
@@ -238,8 +341,11 @@ def _build_modules():
                 and kernel_impl == "stream"
             )
 
+            spec = self.spec
+
             def _proj(name, features, inp):
-                out = _dense(self.precision, features, self.dtype, name)(inp)
+                out = _dense(self.precision, features, self.dtype, name,
+                             spec)(inp)
                 if lora is not None and name in lora and not (
                     fold_qkv and name == "qkv"
                 ):
@@ -251,7 +357,7 @@ def _build_modules():
                     )
                 return out
 
-            y = nn.LayerNorm(dtype=jnp.float32)(x)
+            y = _norm(spec, "attn_norm")(x)
             qkv = _proj("qkv", 3 * d_model, y)
             q, k, v = jnp.split(qkv, 3, axis=-1)
             # a flat whole pool takes its K/V as the projection left
@@ -260,7 +366,10 @@ def _build_modules():
             # the split form to write_kv cost a copy per page block
             k_flat, v_flat = k, v
             shape = (batch, seg_len, heads, head_dim)
-            q, k, v = q.reshape(shape), k.reshape(shape), v.reshape(shape)
+            q, k, v = _heads(self, q, k, v, positions, shape)
+            if spec.qk_norm or spec.rope:
+                # K is cached as attention reads it: normed and rotated
+                k_flat = k.reshape(batch, seg_len, d_model)
 
             scale = 1.0 / jnp.sqrt(head_dim).astype(q.dtype)
             if use_kernel:
@@ -422,13 +531,10 @@ def _build_modules():
                 attn = attn.reshape(batch, seg_len, d_model)
 
             x = x + _proj("attn_proj", d_model, attn)
-            y = nn.LayerNorm(dtype=jnp.float32)(x)
-            y = _proj("mlp_in", self.mlp_ratio * d_model, y)
-            y = nn.gelu(y)
-            x = x + _proj("mlp_out", d_model, y)
+            x, hist = _ffn(self, x, _proj, token_mask)
             if whole and pk.ndim == 4:
-                return x, k_flat, v_flat
-            return x, k, v
+                k, v = k_flat, v_flat
+            return (x, k, v, *hist)
 
     class ChunkTransformerBlock(nn.Module):
         """TransformerBlock reading a pre-gathered contiguous context
@@ -452,10 +558,12 @@ def _build_modules():
         mlp_ratio: int = 4
         dtype: Any = jnp.bfloat16
         precision: str = "bf16"  # "w8a8": int8×int8 projections
+        spec: Any = GPT2
 
         @nn.compact
         def __call__(self, x, ctx_k, ctx_v, ring_k, ring_v, step, len0,
-                     lora=None, adapter_idx=None):
+                     lora=None, adapter_idx=None, positions=None,
+                     token_mask=None):
             # x: (B, 1, d)   ring_k/v: (B, S, h, hd)
             # ctx_k/v: (B, C, h, hd), or a TUPLE of per-bucket buffers
             # ((B0, C0, h, hd), (B1, C1, h, hd), ...) with sum(Bb) == B —
@@ -481,8 +589,11 @@ def _build_modules():
             # same grouped multi-LoRA hook as PagedTransformerBlock —
             # dense work (and therefore the delta) stays full-batch,
             # only the context attention splits by bucket
+            spec = self.spec
+
             def _proj(name, features, inp):
-                out = _dense(self.precision, features, self.dtype, name)(inp)
+                out = _dense(self.precision, features, self.dtype, name,
+                             spec)(inp)
                 if lora is not None and name in lora:
                     from seldon_core_tpu.ops.lora import lora_delta
 
@@ -492,11 +603,11 @@ def _build_modules():
                     )
                 return out
 
-            y = nn.LayerNorm(dtype=jnp.float32)(x)
+            y = _norm(spec, "attn_norm")(x)
             qkv = _proj("qkv", 3 * d_model, y)
             q, k, v = jnp.split(qkv, 3, axis=-1)
             shape = (batch, seg_len, heads, head_dim)
-            q, k, v = q.reshape(shape), k.reshape(shape), v.reshape(shape)
+            q, k, v = _heads(self, q, k, v, positions, shape)
             scale = 1.0 / jnp.sqrt(head_dim).astype(q.dtype)
 
             S = ring_k.shape[1]
@@ -530,11 +641,8 @@ def _build_modules():
             attn = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
             attn = attn.reshape(batch, seg_len, d_model)
             x = x + _proj("attn_proj", d_model, attn)
-            y = nn.LayerNorm(dtype=jnp.float32)(x)
-            y = _proj("mlp_in", self.mlp_ratio * d_model, y)
-            y = nn.gelu(y)
-            x = x + _proj("mlp_out", d_model, y)
-            return x, k, v
+            x, hist = _ffn(self, x, _proj, token_mask)
+            return (x, k, v, *hist)
 
     class ChunkTransformerLM(nn.Module):
         """PagedTransformerLM's decode-chunk twin: identical parameter
@@ -554,20 +662,15 @@ def _build_modules():
         max_len: int = 2048
         dtype: Any = jnp.bfloat16
         precision: str = "bf16"
+        spec: Any = GPT2
 
         @nn.compact
         def __call__(self, tokens, positions, ctx_k, ctx_v, ring_k, ring_v,
-                     step, len0, lora=None, adapter_idx=None):
-            tokens = tokens.astype(jnp.int32)
-            x = nn.Embed(
-                self.vocab_size, self.d_model, dtype=self.dtype, name="tok_embed"
-            )(tokens)
-            pos = nn.Embed(
-                self.max_len, self.d_model, dtype=self.dtype, name="pos_embed"
-            )(positions)
-            x = x + pos
+                     step, len0, lora=None, adapter_idx=None,
+                     token_mask=None):
+            x = _embed(self, tokens, positions)
             bucketed = isinstance(ctx_k, (tuple, list))
-            new_k, new_v = [], []
+            new_k, new_v, hists = [], [], []
             for i in range(self.num_layers):
                 layer_ck = (
                     tuple(c[i] for c in ctx_k) if bucketed else ctx_k[i]
@@ -579,16 +682,17 @@ def _build_modules():
                     {t: (ab[0][i], ab[1][i]) for t, ab in lora.items()}
                     if lora is not None else None
                 )
-                x, k, v = ChunkTransformerBlock(
+                x, k, v, *hist = ChunkTransformerBlock(
                     num_heads=self.num_heads, dtype=self.dtype,
-                    precision=self.precision, name=f"block_{i}"
+                    precision=self.precision, name=f"block_{i}",
+                    spec=self.spec,
                 )(x, layer_ck, layer_cv, ring_k[i], ring_v[i], step, len0,
-                  lora=lora_i, adapter_idx=adapter_idx)
+                  lora=lora_i, adapter_idx=adapter_idx,
+                  positions=positions, token_mask=token_mask)
                 new_k.append(k)
                 new_v.append(v)
-            x = nn.LayerNorm(dtype=jnp.float32)(x)
-            logits = _dense(self.precision, self.vocab_size, self.dtype, "head")(x)
-            return logits.astype(jnp.float32), jnp.stack(new_k), jnp.stack(new_v)
+                hists += hist
+            return _head(self, x, new_k, new_v, hists)
 
     class PagedTransformerLM(nn.Module):
         """TransformerLM forward against a paged pool.
@@ -610,18 +714,13 @@ def _build_modules():
         # a pallas_call over the whole heads axis, so a heads-sharded
         # pool would all-gather per layer per step
         decode_kernel: bool = True
+        spec: Any = GPT2
 
         @nn.compact
         def __call__(self, tokens, positions, pages_k, pages_v, block_tables,
-                     lengths, lora=None, adapter_idx=None, kv_scales=None):
-            tokens = tokens.astype(jnp.int32)
-            x = nn.Embed(
-                self.vocab_size, self.d_model, dtype=self.dtype, name="tok_embed"
-            )(tokens)
-            pos = nn.Embed(
-                self.max_len, self.d_model, dtype=self.dtype, name="pos_embed"
-            )(positions)
-            x = x + pos
+                     lengths, lora=None, adapter_idx=None, kv_scales=None,
+                     token_mask=None):
+            x = _embed(self, tokens, positions)
             # The kernel lane (no TP mesh — decode_kernel=False is how
             # the engine encodes one; env, dtype, backend: the shared
             # static predicate) hands every block the WHOLE pool and its
@@ -632,7 +731,7 @@ def _build_modules():
             whole = self.decode_kernel and paged_kernel_static_eligible(
                 paged_kernel_mode(), True, self.dtype
             )
-            new_k, new_v = [], []
+            new_k, new_v, hists = [], [], []
             for i in range(self.num_layers):
                 if whole:
                     pools = (pages_k, pages_v)
@@ -649,16 +748,17 @@ def _build_modules():
                         ),
                     )
                     pools = (pages_k[i], pages_v[i])
-                x, k, v = PagedTransformerBlock(
+                x, k, v, *hist = PagedTransformerBlock(
                     num_heads=self.num_heads, dtype=self.dtype,
-                    precision=self.precision, name=f"block_{i}"
+                    precision=self.precision, name=f"block_{i}",
+                    spec=self.spec,
                 )(x, *pools, block_tables, lengths,
-                  adapter_idx=adapter_idx, **per_layer)
+                  adapter_idx=adapter_idx, **per_layer,
+                  positions=positions, token_mask=token_mask)
                 new_k.append(k)
                 new_v.append(v)
-            x = nn.LayerNorm(dtype=jnp.float32)(x)
-            logits = _dense(self.precision, self.vocab_size, self.dtype, "head")(x)
-            return logits.astype(jnp.float32), jnp.stack(new_k), jnp.stack(new_v)
+                hists += hist
+            return _head(self, x, new_k, new_v, hists)
 
     return PagedTransformerBlock, PagedTransformerLM, ChunkTransformerLM
 
@@ -1010,6 +1110,7 @@ def paged_hbm_accounting(
     reclaimable_weight_bytes: int = 0,
     kv_dtype: str = "bf16",
     host_tier_gib: float = 0.0,
+    weight_bytes: int = 0,
 ) -> Dict[str, int]:
     """Pool-HBM bytes for ``streams`` concurrent streams at ``ctx_len``
     tokens — the capacity model the bench certifies (VERDICT r5 #3/#5).
@@ -1111,8 +1212,16 @@ def paged_hbm_accounting(
       re-derivable cache the OS may reclaim by dropping demoted pages
       (they re-prefill on miss, exactly as without the tier).
 
-    BASE weights, activations, and the host runtime stay out of scope:
-    this prices what scales with streams and adapter multiplexing.
+    * **base weights** — ``weight_bytes``: the served tree **as it
+      rests** (``ops/surgery.tree_hbm_bytes``; an engine's is
+      ``lane_report()["weight_bytes"]``), a fixed term like the adapter
+      pool.  A routed spec's tree rests in
+      bf16 (norm scales and the router f32) and is read as it is:
+      OLMoE at 8 layers is 7.13 GB, no more.  GPT-2's rests in f32 and
+      every program holds a bf16 cast of it beside that while it runs
+      (PERF.md §4): price that lane's transient on top yourself.
+
+    Activations and the host runtime stay out of scope.
     """
     shard = max(1, int(tp_degree))
     if num_heads is not None and num_heads % shard:
@@ -1146,7 +1255,9 @@ def paged_hbm_accounting(
     return {
         "pool_bytes": pool,
         "working_set_bytes": ws,
-        "peak_bytes": at_rest + ws + inflight + int(adapter_bytes),
+        "peak_bytes": (at_rest + ws + inflight + int(adapter_bytes)
+                       + int(weight_bytes)),
+        "weight_bytes": int(weight_bytes),
         "per_stream_bytes": (at_rest + ws) // max(1, streams),
         "reclaimable_bytes": int(
             cached_prefix_pages * page_bytes
@@ -1196,7 +1307,8 @@ def paged_capacity_streams(
         inflight_prefill_tokens=inflight_prefill_tokens,
         adapter_bytes=adapter_bytes, **model_kw
     )
-    fixed = one["inflight_prefill_bytes"] + one["adapter_bytes"]
+    fixed = (one["inflight_prefill_bytes"] + one["adapter_bytes"]
+             + one["weight_bytes"])  # (weight_bytes= rides model_kw)
     per_stream = max(1, one["peak_bytes"] - fixed)
     usable = max(0, int(budget_bytes) - fixed)
     return int(usable // per_stream)
@@ -1692,10 +1804,16 @@ class PagedEngine:
         max_adapters: int = 0,
         lora_rank: int = 8,
         weight_registry: Any = None,
+        spec: Any = None,
     ):
         import jax
         import jax.numpy as jnp
 
+        from seldon_core_tpu.models.spec import GPT2
+
+        # what the block is made of (models/spec.py): GPT-2's unless the
+        # deployment names another arch
+        self.spec = spec = spec or GPT2
         if max_len % page_size:
             raise ValueError(f"max_len {max_len} must be a multiple of page_size {page_size}")
         # serving-mesh knobs (r11 tp, r19 dp): an explicit mesh wins;
@@ -1726,6 +1844,23 @@ class PagedEngine:
         # weight-only lane under its serving-config name
         self.precision = validate_precision(precision) or "bf16"
         quantize = quantize or quantize_mode_for(self.precision)
+        # lanes whose stated precondition a routed spec breaks refuse
+        # it here, by name, rather than serve something else
+        if spec.routed and (quantize or self.precision != "bf16"):
+            raise ValueError(
+                f"arch={spec.name!r} routes tokens to experts: the int8 "
+                "surgery and the w8a8 projections know nn.Dense kernels, "
+                "not (experts, d, f) expert matrices — serve it with "
+                f"precision 'bf16' (got quantize={quantize!r}, "
+                f"precision={self.precision!r})"
+            )
+        if spec.routed and mesh is not None:
+            raise ValueError(
+                f"arch={spec.name!r} routes tokens to experts: no "
+                "sharding rule places expert matrices on a mesh yet, and "
+                "the grouped expert matmul is a custom call GSPMD cannot "
+                "partition — serve it on one chip (tp=1, dp=1)"
+            )
         if quantize == "int8":
             # weight-only int8: weights rest in HBM at half the bytes
             # and dequantise once per chunk program (measured 1.38x
@@ -1793,6 +1928,7 @@ class PagedEngine:
             # GSPMD can't partition the custom call, so a TP mesh would
             # all-gather the pool per layer per step
             decode_kernel=mesh is None,
+            spec=spec,
         )
         # decode-chunk twin: pool-free attention over a once-per-chunk
         # gathered context + in-chunk ring (same parameter tree — the
@@ -1804,7 +1940,7 @@ class PagedEngine:
         self.chunk_module = get_chunk_lm_class()(
             vocab_size=vocab_size, d_model=d_model, num_layers=num_layers,
             num_heads=num_heads, max_len=max_len, dtype=dtype,
-            precision=module_precision,
+            precision=module_precision, spec=spec,
         )
         # COUPLED ENV KNOBS: SELDON_TPU_PAGED_KERNEL opts into the
         # pallas decode kernels, but those live in the POOL chunk's
@@ -1935,6 +2071,11 @@ class PagedEngine:
             min_weight_size=shard_min_weight_size,
             num_heads=num_heads, seq_shard=self._seq_shard,
         )
+        # the served tree as it rests (all shards): lane_report's
+        # weight_bytes, paged_hbm_accounting's fixed term
+        from seldon_core_tpu.ops.surgery import tree_hbm_bytes
+
+        self._weight_bytes = tree_hbm_bytes(self.params)
         # sibling per-page scale tables (int8 pool only): one f32 per
         # page per k/v, indexed exactly like the pool's page axis — the
         # export/migration/import paths slice them with the same page
@@ -2075,6 +2216,15 @@ class PagedEngine:
         if not max_adapters:
             max_adapters = int(_knobs.raw("SELDON_TPU_MAX_ADAPTERS", "0") or 0)
         self.max_adapters = max(0, int(max_adapters))
+        if spec.rope and self.max_adapters:
+            raise ValueError(
+                f"arch={spec.name!r} rotates q and k between the qkv "
+                "projection and attention: the in-kernel LoRA fold adds "
+                "the projection's low-rank delta inside the attention "
+                "kernel, which is sound only while nothing that depends "
+                "on position or is non-linear (RoPE, QK-norm) sits "
+                "between — serve it without adapters (max_adapters=0)"
+            )
         self._registry = weight_registry
         self._lora = None
         if self.max_adapters:
@@ -2167,6 +2317,16 @@ class PagedEngine:
                           # lanes x steps actually run — their ratio is
                           # the context a decode step is read against
                           "decode_kv_tokens": 0, "decode_lane_steps": 0,
+                          # routed experts (a routed spec; 0 otherwise),
+                          # counted by the programs and read back with a
+                          # chunk's tokens: (token, expert) assignments
+                          # of real tokens over all layers; experts hit
+                          # summed over decode (layer, step)s, and those
+                          # (layer, step)s — their quotient is the mean
+                          # number of experts whose weights one decode
+                          # step streams per layer
+                          "moe_assignments": 0,
+                          "moe_active_expert_steps": 0, "moe_layer_steps": 0,
                           # waiting where it happens: seconds (and
                           # streams) between submit and a stream's first
                           # prefill slice — the engine's own queue —
@@ -2325,6 +2485,12 @@ class PagedEngine:
         self._seam = _WaveSeam(
             self, _knobs.raw("SELDON_TPU_PROFILE_DIR") or None
         )
+        # routed experts: cumulative assignments per (layer, expert),
+        # and the prefill programs' histograms still on the device —
+        # read back with the next chunk's tokens, never on their own
+        self._moe_hits = np.zeros(
+            (num_layers, spec.num_experts), np.int64)
+        self._moe_pending: List[Any] = []
 
         # speculative mode: per-slot draft/verify INSIDE the batched
         # engine — each chunk is ONE verify forward of width draft_k+1
@@ -2496,6 +2662,18 @@ class PagedEngine:
         dtype = self._jnp.float32 if self.precision == "w8a8" else self._dtype
         return materialize(params, self.quantize, dtype)
 
+    def _lm(self, module, params, *args, token_mask=None, **kw):
+        """One forward of the paged LM or its chunk twin: ``(logits, K,
+        V, hist)``.  ``hist`` is ``(int32[layers, E],)``, a routed
+        spec's assignment histogram over the rows ``token_mask`` keeps,
+        and ``()`` for a dense FFN — whose call is spelt exactly as it
+        was before there was a second spec.  (A tuple, so a program
+        splices it into what it returns without a branch.)"""
+        if self.spec.routed:
+            kw["token_mask"] = token_mask
+        out = module.apply({"params": params}, *args, **kw)
+        return (*out[:3], out[3:])
+
     def _tp_jit(self, fn, *, name: str, n_rep_in: int,
                 out_spec: Sequence[str],
                 donate_argnums: Tuple[int, ...] = (1, 2),
@@ -2588,6 +2766,14 @@ class PagedEngine:
             ),
         )
 
+    def _routed_rows(self, bucket: int, true_lens):
+        """``(k, bucket)`` mask of a prefill's real tokens, for a routed
+        spec's assignment counters; None (and no traced operation) for
+        a dense one."""
+        if not self.spec.routed:
+            return None
+        return self._jnp.arange(bucket)[None, :] < true_lens[:, None]
+
     def _build_prefill(self, bucket: int, k: int):
         """Prefill program for ``k`` same-bucket prompts in ONE call.
 
@@ -2607,10 +2793,11 @@ class PagedEngine:
             lengths = jnp.zeros((k,), jnp.int32)
             pk_pages, sk = kv_split(pk)
             pv_pages, sv = kv_split(pv)
-            logits, nk, nv = self.module.apply(
-                {"params": params}, tokens, positions, pk_pages, pv_pages,
+            logits, nk, nv, hist = self._lm(
+                self.module, params, tokens, positions, pk_pages, pv_pages,
                 block_rows, lengths, lora=lora, adapter_idx=adapter_idx,
                 kv_scales=kv_scales_arg(sk, sv),
+                token_mask=self._routed_rows(bucket, true_lens),
             )
             valid = jnp.arange(bucket)[None, :] < true_lens[:, None]
             pk, pv = self._write_kv(
@@ -2618,7 +2805,7 @@ class PagedEngine:
                 from_zero=True,
             )
             last = logits[jnp.arange(k), true_lens - 1]  # (k, vocab)
-            return last, pk, pv
+            return (last, pk, pv, *hist)
 
         return self._sentinels["paged_prefill"].wrap(
             self._tp_jit(prefill, name=f"paged_prefill_b{bucket}_k{k}",
@@ -2654,12 +2841,13 @@ class PagedEngine:
             positions = cached_lens[:, None] + jnp.arange(bucket)[None, :]
             pk_pages, sk = kv_split(pk)
             pv_pages, sv = kv_split(pv)
-            logits, nk, nv = self.module.apply(
-                {"params": params}, tokens,
+            logits, nk, nv, hist = self._lm(
+                self.module, params, tokens,
                 jnp.minimum(positions, self.max_len - 1),
                 pk_pages, pv_pages, read_rows, cached_lens,
                 lora=lora, adapter_idx=adapter_idx,
                 kv_scales=kv_scales_arg(sk, sv),
+                token_mask=self._routed_rows(bucket, true_lens),
             )
             valid = jnp.arange(bucket)[None, :] < true_lens[:, None]
             pk, pv = self._write_kv(
@@ -2667,7 +2855,7 @@ class PagedEngine:
                 from_zero=True,
             )
             last = logits[jnp.arange(k), true_lens - 1]  # (k, vocab)
-            return last, pk, pv
+            return (last, pk, pv, *hist)
 
         return self._sentinels["paged_prefill"].wrap(
             self._tp_jit(prefill,
@@ -2991,7 +3179,7 @@ class PagedEngine:
         ring_v = jnp.zeros((L, B, steps, h, hd), dtype)
 
         def step(carry, t):
-            logits, lengths, keys, done, emitted, ring_k, ring_v = carry
+            logits, lengths, keys, done, emitted, ring_k, ring_v, *moe = carry
             typed = jax.random.wrap_key_data(keys)
             split = jax.vmap(jax.random.split)(typed)
             step_keys = split[:, 1]
@@ -3007,11 +3195,12 @@ class PagedEngine:
             emitted = emitted + active.astype(jnp.int32)
             done = done | (token == eos_ids) | (emitted >= max_new)
             positions = lengths[:, None]  # new token's absolute position
-            new_logits, nk, nv = self.chunk_module.apply(
-                {"params": params}, token[:, None],
+            new_logits, nk, nv, hist = self._lm(
+                self.chunk_module, params, token[:, None],
                 jnp.minimum(positions, self.max_len - 1),
                 ctx_k, ctx_v, ring_k, ring_v, t, len0,
                 lora=lora, adapter_idx=adapter_idx,
+                token_mask=active[:, None],
             )
             # ring col t <- this step's K/V: ONE uniform DUS (inactive
             # lanes write garbage there; never written back — emitted
@@ -3021,12 +3210,16 @@ class PagedEngine:
             ring_v = jax.lax.dynamic_update_slice(ring_v, nv, (0, 0, t, 0, 0))
             logits = jnp.where(active[:, None], new_logits[:, 0], logits)
             lengths = lengths + active.astype(jnp.int32)
-            return (logits, lengths, keys, done, emitted, ring_k, ring_v), token
+            moe = self._moe_step(moe, hist, active)
+            return (logits, lengths, keys, done, emitted, ring_k, ring_v,
+                    *moe), token
 
-        (logits, lengths, keys, done, emitted, ring_k, ring_v), toks = jax.lax.scan(
-            step, (logits, lengths, keys, done, emitted, ring_k, ring_v),
-            jnp.arange(steps),
-        )
+        (logits, lengths, keys, done, emitted, ring_k, ring_v, *moe), toks = (
+            jax.lax.scan(
+                step, (logits, lengths, keys, done, emitted, ring_k, ring_v,
+                       *self._moe_carry()),
+                jnp.arange(steps),
+            ))
 
         # ---- write-back: ring -> pool pages, once per chunk ----------
         # Page-aligned: per slot, shift the ring to page alignment
@@ -3112,8 +3305,32 @@ class PagedEngine:
             (logits, lengths, keys, done, emitted) = (
                 a[inv_perm] for a in (logits, lengths, keys, done, emitted)
             )
-            return toks_out, pk, pv, logits, lengths, keys, done, emitted
-        return toks.T, pk, pv, logits, lengths, keys, done, emitted
+            return (toks_out, pk, pv, logits, lengths, keys, done, emitted,
+                    *moe)
+        return toks.T, pk, pv, logits, lengths, keys, done, emitted, *moe
+
+    def _moe_carry(self):
+        """The decode chunk's routing accumulator, a routed spec's one
+        extra scan carry and output: ``int32[layers, E + 2]`` — per
+        expert the assignments of active lanes, then the experts hit
+        summed over the steps, then the steps in which a lane ran.
+        ``()`` for a dense spec: its carry and outputs are as they were."""
+        if not self.spec.routed:
+            return ()
+        return (self._jnp.zeros(
+            (self.module.num_layers, self.spec.num_experts + 2),
+            self._jnp.int32),)
+
+    def _moe_step(self, moe, hist, active):
+        """Add one decode step's ``(int32[layers, E],)`` histogram."""
+        if not moe:
+            return ()
+        jnp = self._jnp
+        (hist,) = hist
+        ran = jnp.broadcast_to(
+            jnp.any(active).astype(jnp.int32), (hist.shape[0], 1))
+        hit = (hist > 0).sum(axis=1, keepdims=True).astype(jnp.int32)
+        return (moe[0] + jnp.concatenate([hist, hit, ran], axis=1),)
 
     def _chunk_fn_pool(
         self, steps, buckets, params, pk, pv, logits, lengths, block_tables,
@@ -3153,7 +3370,7 @@ class PagedEngine:
             attn_tables = block_tables
 
         def step(carry, _):
-            pk, pv, logits, lengths, keys, done, emitted = carry
+            pk, pv, logits, lengths, keys, done, emitted, *moe = carry
             typed = jax.random.wrap_key_data(keys)
             split = jax.vmap(jax.random.split)(typed)
             step_keys = split[:, 1]
@@ -3168,22 +3385,25 @@ class PagedEngine:
             positions = lengths[:, None]
             pk_pages, sk = kv_split(pk)
             pv_pages, sv = kv_split(pv)
-            new_logits, nk, nv = self.module.apply(
-                {"params": params}, token[:, None],
+            new_logits, nk, nv, hist = self._lm(
+                self.module, params, token[:, None],
                 jnp.minimum(positions, self.max_len - 1),
                 pk_pages, pv_pages, attn_tables, lengths,
                 lora=lora, adapter_idx=adapter_idx,
                 kv_scales=kv_scales_arg(sk, sv),
+                token_mask=active[:, None],
             )
             pk, pv = self._write_kv(
                 pk, pv, nk, nv, block_tables, lengths, active[:, None]
             )
             logits = jnp.where(active[:, None], new_logits[:, 0], logits)
             lengths = lengths + active.astype(jnp.int32)
-            return (pk, pv, logits, lengths, keys, done, emitted), token
+            moe = self._moe_step(moe, hist, active)
+            return (pk, pv, logits, lengths, keys, done, emitted, *moe), token
 
-        (pk, pv, logits, lengths, keys, done, emitted), toks = jax.lax.scan(
-            step, (pk, pv, logits, lengths, keys, done, emitted),
+        (pk, pv, logits, lengths, keys, done, emitted, *moe), toks = jax.lax.scan(
+            step, (pk, pv, logits, lengths, keys, done, emitted,
+                   *self._moe_carry()),
             None, length=steps,
         )
         if multi:
@@ -3191,8 +3411,9 @@ class PagedEngine:
             (logits, lengths, keys, done, emitted) = (
                 a[inv_perm] for a in (logits, lengths, keys, done, emitted)
             )
-            return toks_out, pk, pv, logits, lengths, keys, done, emitted
-        return toks.T, pk, pv, logits, lengths, keys, done, emitted
+            return (toks_out, pk, pv, logits, lengths, keys, done, emitted,
+                    *moe)
+        return toks.T, pk, pv, logits, lengths, keys, done, emitted, *moe
 
     def _draft_rollout_fn(self, params, windows, lens):
         """Greedy ``draft_k``-token rollout of the windowed draft model
@@ -3250,8 +3471,11 @@ class PagedEngine:
         positions = lengths[:, None] + jnp.arange(L)[None, :]
         pk_pages, sk = kv_split(pk)
         pv_pages, sv = kv_split(pv)
-        logits, nk, nv = self.module.apply(
-            {"params": params}, segs,
+        # (a routed spec's histogram is not kept: a verify forward's
+        # rejected positions are no decode steps, and the routing
+        # counters say so by not counting them)
+        logits, nk, nv, _hist = self._lm(
+            self.module, params, segs,
             jnp.minimum(positions, self.max_len - 1),
             pk_pages, pv_pages, block_tables, lengths,
             lora=lora, adapter_idx=adapter_idx,
@@ -4764,10 +4988,19 @@ class PagedEngine:
         k = 1
         while k < len(group):
             k *= 2
+        tokens = sum(n for _s, _start, n in group)
+        routed = (
+            # every real token is routed to top-k experts in every layer
+            # and none is dropped, so the host knows the count the
+            # program's histogram will add up to
+            {"assignments": tokens * self.spec.experts_per_tok
+                            * self.module.num_layers}
+            if self.spec.routed else {}
+        )
         self._seam.begin_prefill(
             bucket=bucket, k=k, rows=len(group),
-            tokens=sum(n for _s, _start, n in group), padded=k * bucket,
-            cached=int(use_cache),
+            tokens=tokens, padded=k * bucket,
+            cached=int(use_cache), **routed,
         )
         try:
             with self._lock:
@@ -4820,7 +5053,7 @@ class PagedEngine:
                 cp = start // ps
                 row = self._block_tables[stream.slot, cp : cp + wp]
                 write_rows[i, : len(row)] = row
-            last, pk_out, pv_out = self._prefill_cached_jit[key3](
+            last, pk_out, pv_out, *hist = self._prefill_cached_jit[key3](
                 self.params, *self._kv_args(),
                 jnp.asarray(padded), jnp.asarray(true_lens),
                 jnp.asarray(cached_lens), jnp.asarray(read_rows),
@@ -4844,13 +5077,14 @@ class PagedEngine:
                 padded[i, :n] = stream.prompt
                 true_lens[i] = n
                 block_rows[i] = self._block_tables[stream.slot, :pages_h]
-            last, pk_out, pv_out = self._prefill_jit[key2](
+            last, pk_out, pv_out, *hist = self._prefill_jit[key2](
                 self.params, *self._kv_args(),
                 jnp.asarray(padded), jnp.asarray(true_lens),
                 jnp.asarray(block_rows), *lora_args,
             )
             self._seam.dispatched()
             self._store_kv(pk_out, pv_out)
+        self._moe_hold(hist)  # a routed spec's int32[layers, E]
         finals: List[Tuple[int, _Stream]] = []
         for i, (stream, start, n) in enumerate(group):
             stream.prefilled = start + n
@@ -5879,7 +6113,55 @@ class PagedEngine:
             "kernel_active": self._kernel_active,
             "kernel_impl": self._kernel_impl,
             "pool_shard_bytes": self._pool_shard_bytes,
+            # which block this replica serves, and what its weights hold
+            # as they rest (paged_hbm_accounting's weight_bytes)
+            "arch": self.spec.name,
+            "weight_bytes": self._weight_bytes,
         }
+
+    def _moe_hold(self, counts):
+        """Keep a just-dispatched program's routing counts (``()`` for a
+        dense spec) until the next chunk is harvested, and start their
+        copy to the host now: it lands while the device works, so the
+        harvest that reads them waits for nothing but its tokens."""
+        for c in counts:
+            c.copy_to_host_async()
+        self._moe_pending += counts
+
+    def _moe_readback(self, moe):
+        """A routed spec's routing counts of this wave, read where the
+        chunk's tokens just were: the chunk's accumulator
+        (``int32[layers, E + 2]``, see ``_moe_carry``) and the
+        histograms of the prefill programs dispatched since the last
+        chunk — all on their way to the host since their dispatch
+        (:meth:`_moe_hold`).  None for a dense spec."""
+        if not self.spec.routed:
+            return None
+        pending, self._moe_pending = self._moe_pending, []
+        # the chunk's accumulator was held last (a speculative engine's
+        # verify program keeps none)
+        chunk = np.asarray(pending.pop()) if moe else None
+        return chunk, [np.asarray(h) for h in pending]
+
+    def _moe_count_locked(self, moe_np) -> Dict[str, float]:
+        """Book one wave's routing counts; returns the harvest
+        annotation's ``experts_active``: experts hit per decode
+        (layer, step) of this chunk."""
+        if moe_np is None:
+            return {}
+        chunk, prefills = moe_np
+        e = self.spec.num_experts
+        if chunk is None:
+            chunk = np.zeros((self._moe_hits.shape[0], e + 2), np.int64)
+        hits = chunk[:, :e].astype(np.int64)
+        for h in prefills:
+            hits = hits + h
+        self._moe_hits += hits
+        self._counters["moe_assignments"] += int(hits.sum())
+        active, steps = int(chunk[:, e].sum()), int(chunk[:, e + 1].sum())
+        self._counters["moe_active_expert_steps"] += active
+        self._counters["moe_layer_steps"] += steps
+        return {"experts_active": round(active / max(steps, 1), 2)}
 
     def engine_stats(self, detail: bool = False) -> Dict[str, Any]:
         """Counters + live occupancy, the generation observability
@@ -5977,7 +6259,17 @@ class PagedEngine:
                 # wave-loop program (the device's idle time as the
                 # program sees it)
                 "host_gap_s": self._seam.host_gap_s,
+                # routed experts: the busiest (layer, expert) pair's
+                # cumulative assignments and the mean over pairs — how
+                # far routing is from even (0 for a dense spec)
+                "moe_load_max": int(self._moe_hits.max(initial=0)),
+                "moe_load_mean": (
+                    float(self._moe_hits.mean()) if self._moe_hits.size
+                    else 0.0),
             }
+            moe_expert_hits = (  # cumulative assignments per expert
+                self._moe_hits.sum(axis=0).tolist()
+                if detail and self.spec.routed else None)
         if self._capture_enabled:
             try:
                 from seldon_core_tpu.utils import capture as _capture_mod
@@ -6012,6 +6304,8 @@ class PagedEngine:
         if detail:
             # host_gap_s by the phase it was spent in
             out["phase_s"] = dict(self._seam.phase_s)
+            if moe_expert_hits is not None:
+                out["moe_expert_hits"] = moe_expert_hits
             if self._watchdog is not None:
                 out["watchdog"] = self._watchdog.stats()
             if self.recorder is not None:
@@ -6553,14 +6847,15 @@ class PagedEngine:
             chunk_args = chunk_args + (
                 self._lora.device_args(), jnp.asarray(adapter_wave),
             )
-        toks, pk_out, pv_out, self._logits, lengths_out, self._keys, _, emitted = (
-            self._get_chunk(steps, buckets)(*chunk_args)
-        )
+        (toks, pk_out, pv_out, self._logits, lengths_out, self._keys, _,
+         emitted, *moe) = self._get_chunk(steps, buckets)(*chunk_args)
         self._seam.dispatched()
+        self._moe_hold(moe)
         self._store_kv(pk_out, pv_out)
         self._seam.enter("wait")
         toks_np = np.asarray(toks)
         emitted_np = np.asarray(emitted)
+        moe_np = self._moe_readback(moe)
         # single-writer window: the chunk runs with its streams pinned
         # and admission only mutates lengths between chunks under the lock
         # graftlint: allow[lock-discipline] — single-writer chunk window
@@ -6612,7 +6907,8 @@ class PagedEngine:
                     finished += 1
                 else:
                     self._stream_push(stream)
-            self._seam.stats(tokens=chunk_tokens, finished=finished)
+            self._seam.stats(tokens=chunk_tokens, finished=finished,
+                             **self._moe_count_locked(moe_np))
             if self._debug_invariants:  # chunk-boundary allocator audit
                 self._check_invariants_locked()
             more = bool(self._queue) or any(s is not None for s in self._slots)
@@ -6901,6 +7197,7 @@ class PagedEngine:
         self._seam.enter("wait")
         out_np = np.asarray(out)
         counts_np = np.asarray(counts)
+        moe_np = self._moe_readback(())  # the prefills' histograms
         # same single-writer window as the decode chunk: streams
         # pinned, admission between chunks
         # graftlint: allow[lock-discipline] — single-writer chunk window
@@ -6914,6 +7211,7 @@ class PagedEngine:
             self._counters["chunk_wall_s"] += chunk_wall
             self._counters["decode_lane_steps"] += len(runnable)
             self._counters["decode_kv_tokens"] += verify_kv
+            self._moe_count_locked(moe_np)
             chunk_tokens = 0
             finished = 0
             for stream in runnable:
@@ -7044,9 +7342,22 @@ class StreamingLM(TPUComponent):
         max_adapters: int = 0,
         lora_rank: int = 8,
         adapters: Any = None,
+        arch: str = "gpt2",
+        num_experts: int = 0,
+        experts_per_tok: int = 0,
+        expert_width: int = 0,
         **kwargs: Any,
     ):
         super().__init__(**kwargs)
+        from seldon_core_tpu.models.spec import model_spec
+
+        # the block the deployment serves (models/spec.py): ``arch``
+        # names it, the sizes (0 = as published) resize it; an unknown
+        # arch fails here, at construction
+        self.spec = model_spec(
+            str(arch), num_experts=num_experts,
+            experts_per_tok=experts_per_tok, expert_width=expert_width,
+        )
         self.config = dict(
             vocab_size=int(vocab_size), d_model=int(d_model),
             num_layers=int(num_layers), num_heads=int(num_heads),
@@ -7142,7 +7453,8 @@ class StreamingLM(TPUComponent):
 
             from seldon_core_tpu.models.generate import load_lm_params
 
-            params = load_lm_params(self.model_uri, self.config, self.seed)
+            params = load_lm_params(
+                self.model_uri, self.config, self.seed, spec=self.spec)
             from seldon_core_tpu.parallel.mesh import mesh_from_axes
 
             mesh = mesh_from_axes(self.mesh_axes)
@@ -7159,7 +7471,7 @@ class StreamingLM(TPUComponent):
                 params, dtype=jnp.bfloat16, mesh=mesh, tp=self.tp or None,
                 dp=self.dp or None,
                 max_adapters=self.max_adapters, lora_rank=self.lora_rank,
-                weight_registry=registry,
+                weight_registry=registry, spec=self.spec,
                 **self.config, **self.engine_config,
             )
             # canonical seldon_tpu_engine_* metrics on the process

@@ -498,6 +498,20 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
     "kv_tier_bytes_promoted": ("counter",
                                "seldon_tpu_engine_kv_tier_bytes_promoted_total",
                                "container bytes promoted back into HBM"),
+    "moe_assignments": ("counter", "seldon_tpu_engine_moe_assignments_total",
+                        "(token, expert) assignments of real tokens, over "
+                        "all layers of a routed model"),
+    "moe_active_expert_steps": (
+        "counter", "seldon_tpu_engine_moe_active_expert_steps_total",
+        "experts hit, summed over the decode (layer, step)s that ran"),
+    "moe_layer_steps": ("counter", "seldon_tpu_engine_moe_layer_steps_total",
+                        "decode (layer, step)s of a routed model that ran"),
+    "moe_load_max": ("gauge", "seldon_tpu_engine_moe_load_max",
+                     "cumulative assignments of the busiest (layer, "
+                     "expert) pair"),
+    "moe_load_mean": ("gauge", "seldon_tpu_engine_moe_load_mean",
+                      "mean cumulative assignments per (layer, expert) "
+                      "pair"),
     "kv_tier_host_bytes": ("gauge",
                            "seldon_tpu_engine_kv_tier_host_bytes",
                            "live container bytes parked in the tier's "

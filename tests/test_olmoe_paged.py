@@ -1,0 +1,448 @@
+"""OLMoE on the paged engine (PR 26): RoPE, RMSNorm, QK-norm and routed
+SwiGLU experts through the same pool, kernel, prefix cache and programs
+as GPT-2, compared on **logits** with the benchmark's plain float32
+reference (``benchmarks/reference/olmoe.py``).
+
+Small size, CPU: d 64, 4 heads of 16, 8 experts top-2 of width 32, 2
+layers.  The engine's own compiled programs are driven through the
+seams its other tests use (``_build_prefill``, ``_build_prefill_cached``,
+``_get_chunk``): whole prefill, prefill then decode through the cache,
+and a prefix-cached suffix, each on the kernel lane (Pallas in
+interpret mode), the XLA gather lane and the ring chunk.
+"""
+
+import hashlib
+import os
+import sys
+from dataclasses import replace
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from seldon_core_tpu.models.generate import load_lm_params
+from seldon_core_tpu.models.paged import (
+    PagedEngine,
+    StreamingLM,
+    get_paged_lm_class,
+    paged_hbm_accounting,
+)
+from seldon_core_tpu.models.spec import GPT2, OLMOE, init_params, model_spec
+from seldon_core_tpu.ops import moe
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
+from reference import olmoe as ref  # noqa: E402
+
+MODEL = dict(hidden_size=64, num_hidden_layers=2, num_attention_heads=4,
+             num_experts=8, num_experts_per_tok=2, intermediate_size=32,
+             rms_norm_eps=1e-5, rope_theta=10000, vocab_size=97)
+SPEC, SIZES = ref.spec_and_config(MODEL)
+PAGE, MAX_LEN, SLOTS = 8, 64, 4
+PROMPT = np.random.default_rng(5).integers(0, 97, size=29).tolist()
+
+# float32 compute against a float32 reference: what is left is the order
+# of sums (a grouped matmul, a paged softmax merged by the flash rule).
+# Logits have unit spread; the largest difference seen over the nine
+# lane x program cases is 1.1e-6.  1e-4 is ~100x that, and 1/43 of what
+# the mildest of the four wrong programs below moves them by (a bf16
+# router: 4.3e-3; no QK-norm 0.57, a dropped expert 0.70, renormalised
+# gates 1.08).
+F32_ATOL = 1e-4
+# bfloat16 compute (8 bits of mantissa) through 2 layers at d = 64:
+# rounding of every matmul output, of K and V in the pool and of the
+# residual stream.  Largest difference seen: 0.0204, 0.022 of the
+# logits' spread (0.93).  A little over twice that, as a share of the
+# spread; a missing QK-norm, a renormalised gate or a dropped expert
+# moves logits by 0.6-1.1 of it.
+BF16_ATOL = 0.05
+
+LANES = {
+    "kernel": {"SELDON_TPU_PAGED_KERNEL": "force", "SELDON_TPU_CHUNK_IMPL": "pool"},
+    "gather": {"SELDON_TPU_PAGED_KERNEL": "0", "SELDON_TPU_CHUNK_IMPL": "pool"},
+    "ring": {"SELDON_TPU_PAGED_KERNEL": "0", "SELDON_TPU_CHUNK_IMPL": "ring"},
+}
+
+
+def _engine(monkeypatch, lane, dtype=jnp.float32, spec=SPEC, params=None,
+            steps_per_call=1, **kw):
+    for k, v in LANES[lane].items():
+        monkeypatch.setenv(k, v)
+    if params is None:
+        params = init_params(SPEC, SIZES, 3, dtype=dtype)
+    return PagedEngine(
+        params, **SIZES, max_len=MAX_LEN, page_size=PAGE, max_slots=SLOTS,
+        steps_per_call=steps_per_call, dtype=dtype, spec=spec, **kw), params
+
+
+def _reference(params, tokens):
+    f32 = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), params)
+    return np.asarray(ref.logits(f32, MODEL, tokens))
+
+
+def _table(first_page, pages):
+    row = np.zeros((MAX_LEN // PAGE,), np.int32)
+    row[:pages] = np.arange(first_page, first_page + pages)
+    return row
+
+
+def _prefill(eng, prompt):
+    """The whole-prefill program on ``prompt`` in slot row 0 (pages 1..):
+    last-position logits; the engine's pools now hold its K/V."""
+    bucket = next(b for b in eng.prompt_buckets if b >= len(prompt))
+    tokens = np.zeros((1, bucket), np.int32)
+    tokens[0, :len(prompt)] = prompt
+    pages_h = eng._pages_pow2(-(-bucket // PAGE))
+    last, pk, pv, *hist = eng._build_prefill(bucket, 1)(
+        eng.params, *eng._kv_args(), jnp.asarray(tokens),
+        jnp.asarray([len(prompt)], jnp.int32),
+        jnp.asarray(_table(1, pages_h)[None, :pages_h]))
+    eng._store_kv(pk, pv)
+    return np.asarray(last[0]), (np.asarray(hist[0]) if hist else None)
+
+
+def _decode(eng, last, length, steps):
+    """``steps`` greedy decode steps of lane 0 through the one-step
+    chunk program: the tokens it chose and the logits after each."""
+    logits = jnp.zeros((SLOTS, SIZES["vocab_size"]), jnp.float32).at[0].set(last)
+    lengths = np.zeros((SLOTS,), np.int32)
+    lengths[0] = length
+    tables = np.zeros((SLOTS, MAX_LEN // PAGE), np.int32)
+    tables[0] = _table(1, MAX_LEN // PAGE)
+    done = np.ones((SLOTS,), bool)
+    done[0] = False
+    keys = eng._keys
+    toks, rows, moe_acc = [], [], None
+    for _ in range(steps):
+        horizon = eng._pages_pow2(-(-(int(lengths[0]) + 1) // PAGE))
+        out = eng._get_chunk(1, ((SLOTS, horizon),))(
+            eng.params, *eng._kv_args(), logits, jnp.asarray(lengths),
+            jnp.asarray(tables[:, :horizon]), keys, jnp.asarray(done),
+            jnp.zeros((SLOTS,), jnp.int32), jnp.full((SLOTS,), 99, jnp.int32),
+            jnp.zeros((SLOTS,), jnp.float32), jnp.zeros((SLOTS,), jnp.int32),
+            jnp.full((SLOTS,), -1, jnp.int32), jnp.arange(SLOTS, dtype=jnp.int32))
+        tok, pk, pv, logits, lengths_out, keys, _done, _emitted, moe_acc = out
+        eng._store_kv(pk, pv)
+        lengths = np.array(lengths_out)
+        toks.append(int(tok[0, 0]))
+        rows.append(np.asarray(logits[0]))
+    return toks, np.stack(rows), np.asarray(moe_acc)
+
+
+def _cached_suffix(eng, prompt, cached):
+    """Prefill ``prompt[:cached]`` whole, then ``prompt[cached:]`` with
+    the cached-suffix program over those pages: last-position logits."""
+    _prefill(eng, prompt[:cached])
+    suffix = prompt[cached:]
+    bucket = next(b for b in eng.prompt_buckets if b >= len(suffix))
+    rp, wp = eng._pages_pow2(cached // PAGE), -(-bucket // PAGE)
+    tokens = np.zeros((1, bucket), np.int32)
+    tokens[0, :len(suffix)] = suffix
+    full = _table(1, MAX_LEN // PAGE)
+    last, pk, pv, _hist = eng._build_prefill_cached(bucket, 1, rp)(
+        eng.params, *eng._kv_args(), jnp.asarray(tokens),
+        jnp.asarray([len(suffix)], jnp.int32), jnp.asarray([cached], jnp.int32),
+        jnp.asarray(full[None, :rp]),
+        jnp.asarray(full[None, cached // PAGE: cached // PAGE + wp]))
+    eng._store_kv(pk, pv)
+    return np.asarray(last[0])
+
+
+def _run(eng, program):
+    """``(served logits rows, the token sequence they are rows of, first
+    row's position)`` for one program."""
+    n = len(PROMPT)
+    if program == "prefill":
+        last, _hist = _prefill(eng, PROMPT)
+        return last[None], PROMPT, n - 1
+    if program == "cached":
+        return _cached_suffix(eng, PROMPT, 2 * PAGE)[None], PROMPT, n - 1
+    last, _hist = _prefill(eng, PROMPT)
+    toks, rows, _acc = _decode(eng, last, n, steps=6)
+    return rows, PROMPT + toks, n
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode", "cached"])
+@pytest.mark.parametrize("lane", sorted(LANES))
+def test_logits_match_the_reference_f32(monkeypatch, lane, program):
+    eng, params = _engine(monkeypatch, lane)
+    try:
+        assert eng._kernel_active == (lane == "kernel")
+        rows, tokens, at = _run(eng, program)
+        want = _reference(params, tokens)[at: at + len(rows)]
+        assert np.abs(rows - want).max() < F32_ATOL
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode", "cached"])
+@pytest.mark.parametrize("lane", ["kernel", "gather"])
+def test_logits_match_the_reference_bf16(monkeypatch, lane, program):
+    """The serving precision: weights at rest in bf16 (router and norm
+    scales f32), bf16 pool and matmuls, f32 router."""
+    eng, params = _engine(monkeypatch, lane, dtype=jnp.bfloat16)
+    try:
+        assert params["block_0"]["experts_gate"].dtype == jnp.bfloat16
+        assert params["block_0"]["router"].dtype == jnp.float32
+        rows, tokens, at = _run(eng, program)
+        want = _reference(params, tokens)[at: at + len(rows)]
+        assert np.abs(rows - want).max() < BF16_ATOL * want.std()
+    finally:
+        eng.close()
+
+
+def test_rope_and_the_prefix_cache(monkeypatch):
+    """Positions are absolute, K is cached after RoPE: a suffix
+    prefilled over cached pages (at any page-aligned cut) sees what a
+    whole prefill sees, logit for logit."""
+    eng, _params = _engine(monkeypatch, "gather")
+    try:
+        whole, _hist = _prefill(eng, PROMPT)
+        for cached in (PAGE, 3 * PAGE):
+            assert np.abs(_cached_suffix(eng, PROMPT, cached) - whole).max() < 1e-5
+    finally:
+        eng.close()
+
+
+def _bf16_router(h, w, k):
+    return _ROUTE(h.astype(jnp.bfloat16), w.astype(jnp.bfloat16), k)
+
+
+def _renormalised(h, w, k):
+    gates, experts = _ROUTE(h, w, k)
+    return gates / gates.sum(-1, keepdims=True), experts
+
+
+def _dropped_last(h, w, k):
+    gates, experts = _ROUTE(h, w, k)
+    return gates.at[:, -1].set(0.0), experts
+
+
+_ROUTE = moe.route
+
+
+@pytest.mark.parametrize("wrong", ["bf16_router", "renormalised_gates",
+                                   "no_qk_norm", "dropped_expert"])
+def test_the_tolerance_fails_a_wrong_program(monkeypatch, wrong):
+    """What the f32 tolerance is for: each of these computes something
+    else than the source defines, and none stays inside it."""
+    spec = SPEC
+    if wrong == "no_qk_norm":
+        from dataclasses import replace
+
+        spec = replace(SPEC, qk_norm=False)
+    else:
+        monkeypatch.setattr(moe, "route", {
+            "bf16_router": _bf16_router, "renormalised_gates": _renormalised,
+            "dropped_expert": _dropped_last}[wrong])
+    eng, params = _engine(monkeypatch, "gather", spec=spec)
+    try:
+        rows, tokens, at = _run(eng, "decode")
+        want = _reference(params, tokens)[at: at + len(rows)]
+        assert np.abs(rows - want).max() > 10 * F32_ATOL
+    finally:
+        eng.close()
+
+
+def test_engine_serves_and_counts_routing(monkeypatch):
+    """Through submit/step: greedy tokens equal the reference's
+    teacher-forced argmax (f32), a repeat is admitted on the prefix
+    cache and answers the same, and the routing counters add up."""
+    eng, params = _engine(monkeypatch, "kernel", steps_per_call=4)
+    try:
+        first = eng.submit(np.asarray(PROMPT, np.int32), max_new_tokens=8)
+        eng.run()
+        toks = [int(t) for t in first.result]
+        want = _reference(params, PROMPT + toks[:-1])[len(PROMPT) - 1:]
+        assert toks == want.argmax(-1).tolist()
+        stats = eng.engine_stats(detail=True)
+        layers, k = MODEL["num_hidden_layers"], MODEL["num_experts_per_tok"]
+        # every prompt token and every fed-back token, top-k each, per layer
+        assert stats["moe_assignments"] == (len(PROMPT) + 8) * k * layers
+        assert sum(stats["moe_expert_hits"]) == stats["moe_assignments"]
+        # one lane decoding: exactly k experts hit per (layer, step)
+        assert stats["moe_layer_steps"] == 8 * layers
+        assert stats["moe_active_expert_steps"] == 8 * layers * k
+        assert stats["moe_load_max"] >= stats["moe_load_mean"] > 0
+
+        again = eng.submit(np.asarray(PROMPT, np.int32), max_new_tokens=8)
+        eng.run()
+        assert [int(t) for t in again.result] == toks
+        assert eng.engine_stats()["prefix_hits"] == 1
+    finally:
+        eng.close()
+
+
+def test_stream_survives_evict_and_restore(monkeypatch):
+    """K/V pages are all a preemption moves: an OLMoE stream evicted
+    mid-decode and re-admitted answers as one that never was."""
+    eng, _params = _engine(monkeypatch, "gather", steps_per_call=2)
+    try:
+        calm = eng.submit(np.asarray(PROMPT, np.int32), max_new_tokens=10)
+        eng.run()
+        stream = eng.submit(np.asarray(PROMPT[::-1], np.int32), max_new_tokens=10)
+        eng.step()
+        eng.step()
+        with eng._lock:
+            eng._evict_locked(stream)
+        eng.run()
+        fresh = eng.submit(np.asarray(PROMPT[::-1], np.int32), max_new_tokens=10)
+        eng.run()
+        assert eng.engine_stats()["evictions"] == 1
+        assert stream.result.tolist() == fresh.result.tolist()
+        assert len(calm.result) == 10
+    finally:
+        eng.close()
+
+
+class TestFences:
+    def _params(self):
+        return init_params(SPEC, SIZES, 0, dtype=jnp.float32)
+
+    def _make(self, **kw):
+        return PagedEngine(self._params(), **SIZES, max_len=MAX_LEN,
+                           page_size=PAGE, max_slots=SLOTS, spec=SPEC, **kw)
+
+    def test_adapters_with_a_rope_arch(self):
+        with pytest.raises(ValueError, match="rotates q and k"):
+            self._make(max_adapters=2)
+
+    @pytest.mark.parametrize("kw", [{"quantize": "int8"}, {"precision": "w8a8"},
+                                    {"precision": "int8w"}])
+    def test_int8_lanes_with_an_expert_arch(self, kw):
+        with pytest.raises(ValueError, match="expert matrices"):
+            self._make(**kw)
+
+    @pytest.mark.parametrize("kw", [{"tp": 2}, {"dp": 2}])
+    def test_a_mesh_with_an_expert_arch(self, kw):
+        with pytest.raises(ValueError, match="one chip"):
+            self._make(**kw)
+
+    def test_unknown_arch_fails_at_construction(self):
+        with pytest.raises(ValueError, match="serves"):
+            StreamingLM(arch="mixtral")
+        with pytest.raises(ValueError, match="no experts"):
+            model_spec("gpt2", num_experts=4)
+
+
+def test_weights_rest_in_bf16_and_are_counted_as_they_are(monkeypatch):
+    params = init_params(SPEC, SIZES, 1)
+    kinds = {str(leaf.dtype) for leaf in jax.tree_util.tree_leaves(params)}
+    assert kinds == {"bfloat16", "float32"}
+    f32 = {k for k, v in params["block_0"].items()
+           if jax.tree_util.tree_leaves(v)[0].dtype == jnp.float32}
+    assert f32 == {"attn_norm", "ffn_norm", "q_norm", "k_norm", "router"}
+    at_rest = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(params))
+    eng, _ = _engine(monkeypatch, "gather", dtype=jnp.bfloat16, params=params)
+    try:
+        assert eng.lane_report()["weight_bytes"] == at_rest
+        assert eng.lane_report()["arch"] == "olmoe"
+    finally:
+        eng.close()
+    priced = paged_hbm_accounting(
+        streams=2, ctx_len=64, d_model=64, num_layers=2, weight_bytes=at_rest)
+    bare = paged_hbm_accounting(streams=2, ctx_len=64, d_model=64, num_layers=2)
+    assert priced["peak_bytes"] - bare["peak_bytes"] == at_rest
+    # the same seed makes the same tree; another seed another
+    again, other = init_params(SPEC, SIZES, 1), init_params(SPEC, SIZES, 2)
+    same = jax.tree_util.tree_map(lambda a, b: bool((a == b).all()), params, again)
+    assert all(jax.tree_util.tree_leaves(same))
+    assert not bool((params["head"]["kernel"] == other["head"]["kernel"]).all())
+
+
+# the initialiser reads the tree off the module, so a block no arch
+# names yet gets the tree that block applies: the two the spec's fields
+# allow beside GPT-2 and OLMoE
+THIRD_SPECS = {
+    "rope_dense": replace(OLMOE, name="rope-dense", ffn="gelu", num_experts=0,
+                          experts_per_tok=0, expert_width=0),
+    "layernorm_experts": replace(GPT2, name="ln-moe", ffn="moe", num_experts=8,
+                                 experts_per_tok=2, expert_width=32),
+}
+
+
+@pytest.mark.parametrize("which", sorted(THIRD_SPECS))
+def test_the_initialiser_follows_the_spec_not_a_name(monkeypatch, which):
+    spec = THIRD_SPECS[which]
+    assert not spec.transformer_lm and GPT2.transformer_lm
+    config = dict(SIZES, max_len=MAX_LEN)
+    params = load_lm_params("", config, 4, spec=spec)
+    block = set(params["block_0"])
+    assert ("router" in block) == spec.routed
+    assert ("mlp_in" in block) == (not spec.routed)
+    assert ("q_norm" in block) == spec.qk_norm
+    assert ("pos_embed" in params) == (not spec.rope)
+    assert ("bias" in params["head"]) == spec.bias
+    want = jnp.float32 if spec.weights_f32 else jnp.bfloat16
+    assert params["head"]["kernel"].dtype == want
+    assert params["tok_embed"]["embedding"].dtype == want
+    # and the engine's own program applies it
+    eng, _ = _engine(monkeypatch, "gather", dtype=jnp.bfloat16, spec=spec,
+                     params=params)
+    try:
+        last, *_ = _prefill(eng, PROMPT)
+        assert np.isfinite(last).all() and last.std() > 0.1
+    finally:
+        eng.close()
+
+
+# ---------------------------------------------------------------------------
+# GPT-2's programs did not change
+# ---------------------------------------------------------------------------
+
+# sha-256 of the lowered text of the programs the two gpt2-large cells
+# run (prefill, cached prefill, chunk, bucketed chunk), at a small size
+# on the CPU, per decode lane, taken on the parent commit (89e949e) with
+# the function below.  A second model reads a spec; GPT-2's value of it
+# must trace what was there.  A PR that changes GPT-2's programs on
+# purpose (or the jax version) takes these again and says so.
+GPT2_CFG = dict(vocab_size=64, d_model=32, num_layers=2, num_heads=2, max_len=64)
+PARENT_SHA = {
+    "kernel": {
+        "prefill_b16_k2": "5ef734ce28371fe7913c64f3b37b47ff3b9f1a5a6500f0adc5a6d0746d72528f",
+        "prefill_cached_b16_k2_r2": "b02869115cb72ef159f121dc1fef1f37fd9c3baf2fc3fb6a96365fa9929ec1c6",
+        "chunk_s2_4x4": "ff3282bf2d154dbd3be59dc9dcd15989d4c99358fd67c87ee784137d556ab1cb",
+        "chunk_s2_2x2_2x4": "38ec6a9e44843b711da6d163773456fc5f83849f3f407667b0842c9f27d07d1a",
+    },
+    "gather": {
+        "prefill_b16_k2": "f9ae2fa113df6fbf845f2983a405fc1c6a04b840862649813190b054415e4e77",
+        "prefill_cached_b16_k2_r2": "13a93432b2bb62e12bf24b1bdf64f99dd40139efcdbe702a881ef248241383dd",
+        "chunk_s2_4x4": "54dcd2ecee6971a385b2d6b0d32989a2d7d60133de79e20e9530e7d43e334079",
+        "chunk_s2_2x2_2x4": "6ee6f04cc30b97e9a45f83aea471619782da1b0cc473b9a98abbd3d58d224a30",
+    },
+}
+
+
+def gpt2_program_shas():
+    from seldon_core_tpu.models.transformer import TransformerLM
+
+    lm = TransformerLM(dtype=jnp.float32, **GPT2_CFG)
+    params = lm.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = PagedEngine(params, **GPT2_CFG, page_size=8, max_slots=4,
+                      steps_per_call=2, dtype=jnp.bfloat16)
+    try:
+        pools = eng._kv_args()
+        i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+        unwrap = lambda fn: fn if hasattr(fn, "lower") else fn.__wrapped__  # noqa: E731
+        texts = {
+            "prefill_b16_k2": unwrap(eng._build_prefill(16, 2)).lower(
+                eng.params, *pools, i32(2, 16), i32(2), i32(2, 2)).as_text(),
+            "prefill_cached_b16_k2_r2": unwrap(
+                eng._build_prefill_cached(16, 2, 2)).lower(
+                eng.params, *pools, i32(2, 16), i32(2), i32(2), i32(2, 2),
+                i32(2, 2)).as_text(),
+            "chunk_s2_4x4": eng.lower_chunk(2, ((4, 4),)).as_text(),
+            "chunk_s2_2x2_2x4": eng.lower_chunk(2, ((2, 2), (2, 4))).as_text(),
+        }
+        return {k: hashlib.sha256(v.encode()).hexdigest() for k, v in texts.items()}
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("lane", ["kernel", "gather"])
+def test_gpt2_programs_lower_as_on_the_parent(monkeypatch, lane):
+    for k, v in LANES[lane].items():
+        monkeypatch.setenv(k, v)
+    assert GPT2 == model_spec("gpt2")
+    assert gpt2_program_shas() == PARENT_SHA[lane]

@@ -86,3 +86,62 @@ def test_stream_kernel_compiles_on_the_whole_pool(one_chip, mosaic, lanes, pages
                 if whole in ln.partition(" = ")[2].partition("(")[0]
                 and " parameter(" not in ln]
     assert not produced, produced[:3]
+
+
+# ---------------------------------------------------------------------------
+# OLMoE's geometry (PR 26): the same kernel at 16 heads of 128 over an
+# 8-layer pool, and the routed expert layer at its published widths
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("lanes,pages", [(16, 8), (32, 16)])
+def test_stream_kernel_compiles_at_olmoe_geometry(one_chip, mosaic, lanes, pages):
+    layers, heads, head_dim = 8, 16, 128
+    d = heads * head_dim
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q, pk, pv, tables, lengths, layer):
+        return kernels.paged_attention_decode(
+            q, pk, pv, tables, lengths, layer=layer, page_size=PS)
+
+    text = jax.jit(fn).lower(
+        spec((lanes, heads, head_dim), jnp.bfloat16),
+        spec((layers, NUM_PAGES, PS, d), jnp.bfloat16),
+        spec((layers, NUM_PAGES, PS, d), jnp.bfloat16),
+        spec((lanes, pages), jnp.int32), spec((lanes,), jnp.int32), spec((), jnp.int32),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows", [32, 4096])
+def test_expert_layer_compiles_as_grouped_matmul_kernels(one_chip, mosaic, rows):
+    """A decode step's 32 rows and a prefill group's 4,096 through
+    ``ops/moe.py`` at 64 experts of 2048 x 1024, top-8: the three
+    ``ragged_dot``s arrive as the TPU compiler's own Mosaic
+    grouped-matmul kernels (what the ``moe_*`` readers look for)."""
+    from seldon_core_tpu.ops import moe
+
+    d, f, e, k = 2048, 1024, 64, 8
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(h, w_router, w_gate, w_up, w_down):
+        gates, experts = moe.route(h, w_router, k)
+        out = moe.expert_ffn(h.astype(jnp.bfloat16), w_gate, w_up, w_down, gates, experts)
+        return out, moe.expert_histogram(experts, e)
+
+    compiled = jax.jit(layer).lower(
+        spec((rows, d), jnp.float32), spec((d, e), jnp.float32),
+        spec((e, d, f), jnp.bfloat16), spec((e, d, f), jnp.bfloat16),
+        spec((e, f, d), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert text.count("ragged-dot") >= 3 and "tpu_custom_call" in text
+    # only routed rows are materialised: the temporaries are a few
+    # copies of the rows x top-k assignments (the down projection leaves
+    # in f32 and is re-ordered once), a quarter of what a dense
+    # all-experts einsum's rows x 64 x (1024 + 1024 + 2048) would hold
+    routed_f32 = rows * k * d * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * routed_f32
+    assert 3 * routed_f32 < rows * e * (2 * f + d) * 2
