@@ -379,9 +379,14 @@ def _build_modules():
                 # (B, P, ps, h, hd) gathered copy below never
                 # materialises.  The current token merges via the flash
                 # rule.  Under the bucketed gather each bucket is one
-                # kernel call at its own table width — the kernel's
-                # per-lane page loop is already length-bounded, so
-                # bucketing only trims the BlockSpec grid.  NUMERIC
+                # kernel call at its own table width.  Since PR 27 the
+                # stream kernel's page loop runs each lane's
+                # ceil(length / page_size) pages and an empty lane none
+                # (before, it ran the table's width for every lane and
+                # discarded the rest: 1.7 us a slot on the v5e, PERF.md
+                # §6), so a bucket's width costs the stream impl
+                # nothing; the grid impl still pays a grid step per
+                # table slot, which bucketing trims.  NUMERIC
                 # REGIME: the kernel scores in f32 where the gather path
                 # scores in bf16, so on hardware a kernel-decode engine
                 # and a gather-path engine (e.g. a speculative verify
@@ -2317,6 +2322,12 @@ class PagedEngine:
                           # lanes x steps actually run — their ratio is
                           # the context a decode step is read against
                           "decode_kv_tokens": 0, "decode_lane_steps": 0,
+                          # the decode attention's page loop (PR 27):
+                          # table slots the launched steps were handed
+                          # (steps x lanes x table width, per bucket) and
+                          # the pages those lane-steps' caches held —
+                          # the share of the loop that is live
+                          "decode_page_slots": 0, "decode_live_pages": 0,
                           # routed experts (a routed spec; 0 otherwise),
                           # counted by the programs and read back with a
                           # chunk's tokens: (token, expert) assignments
@@ -2913,6 +2924,10 @@ class PagedEngine:
             return 1
         need = max(int(self._lengths[s.slot]) for s in runnable) + per_chunk
         return self._pages_pow2(-(-need // self.page_size))
+
+    def _pages_of(self, tokens: int) -> int:
+        """Pages that hold ``tokens`` cached tokens."""
+        return -(-tokens // self.page_size)
 
     def _pages_pow2(self, need_pages: int) -> int:
         """Round a page count up to a power of two, capped at the
@@ -6766,15 +6781,20 @@ class PagedEngine:
             # cached tokens per lane as the chunk starts: the launch's
             # kv_tokens, and the base of decode_kv_tokens at harvest
             lens0 = {s.slot: int(self._lengths[s.slot]) for s in runnable_now}
-            self._seam.stats(
-                steps=steps, lanes=len(runnable_now),
-                kv_tokens=sum(lens0.values()),
-            )
             # ctx horizons for the chunk: per length bucket (the ring
             # impl gathers only pages holding tokens that EXIST at
             # chunk start — in-chunk tokens live in the ring; the pool
             # impl's per-step tables add this chunk's growth)
             buckets, perm = self._plan_buckets(runnable_now, steps, pages_h)
+            # a step's page loop: the slots its tables hold, and the
+            # pages the runnable lanes' caches hold as the chunk starts
+            step_slots = sum(lanes * width for lanes, width in buckets)
+            self._seam.stats(
+                steps=steps, lanes=len(runnable_now),
+                kv_tokens=sum(lens0.values()),
+                pages_live=sum(self._pages_of(n) for n in lens0.values()),
+                page_slots=step_slots,
+            )
             tables = jnp.asarray(self._block_tables[:, :pages_h])
             lengths = jnp.asarray(self._lengths)
             emitted0 = jnp.zeros((self.max_slots,), jnp.int32)
@@ -6875,6 +6895,9 @@ class PagedEngine:
             self._counters["chunk_wall_s"] += chunk_wall
             chunk_tokens = 0
             finished = 0
+            # the pool pages a step reads: the ring impl keeps a
+            # chunk's own tokens out of the pool
+            grow = self._chunk_impl == "pool"
             for slot, len0 in lens0.items():
                 # the lane ran n steps, whatever became of its stream;
                 # step t attended the len0 + t tokens cached before it
@@ -6883,6 +6906,10 @@ class PagedEngine:
                 self._counters["decode_kv_tokens"] += (
                     n * len0 + n * (n - 1) // 2
                 )
+                self._counters["decode_live_pages"] += sum(
+                    self._pages_of(len0 + t * grow) for t in range(n))
+            # every launched step walks every lane's table, live or not
+            self._counters["decode_page_slots"] += steps * step_slots
             t_now = _time.time()
             for stream in decoding:
                 if stream.error is not None:
@@ -7153,9 +7180,13 @@ class PagedEngine:
             pages_h = self._pages_horizon(runnable, self.draft_k + 1)
             # one verify forward is one step a lane, over what it holds
             verify_kv = sum(int(self._lengths[s.slot]) for s in runnable)
+            verify_pages = sum(
+                self._pages_of(int(self._lengths[s.slot])) for s in runnable)
+            verify_slots = self.max_slots * pages_h
             self._seam.stats(
                 steps=self.draft_k + 1, lanes=len(runnable),
-                kv_tokens=verify_kv,
+                kv_tokens=verify_kv, pages_live=verify_pages,
+                page_slots=verify_slots,
             )
             tables = jnp.asarray(self._block_tables[:, :pages_h])
             lengths = jnp.asarray(self._lengths)
@@ -7211,6 +7242,8 @@ class PagedEngine:
             self._counters["chunk_wall_s"] += chunk_wall
             self._counters["decode_lane_steps"] += len(runnable)
             self._counters["decode_kv_tokens"] += verify_kv
+            self._counters["decode_live_pages"] += verify_pages
+            self._counters["decode_page_slots"] += verify_slots
             self._moe_count_locked(moe_np)
             chunk_tokens = 0
             finished = 0

@@ -452,6 +452,25 @@ def _paged_decode_kernel(tables_ref, lens_ref, *refs, page_size,
         acc_ref[0] = acc_ref[0] * alpha[:, None] + pv_dot
 
 
+def _split3(x):
+    """An f32 array as three f32 terms that sum to it EXACTLY and are
+    each exactly representable in bf16 (8 significant bits a term, by
+    masking the low half of the word: a truncation no compiler pass
+    can fold away, where an f32 -> bf16 -> f32 round trip may be)."""
+    import jax
+    import jax.numpy as jnp
+
+    def top(v):
+        bits = jax.lax.bitcast_convert_type(v, jnp.int32)
+        return jax.lax.bitcast_convert_type(
+            bits & jnp.int32(-65536), jnp.float32)
+
+    hi = top(x)
+    rest = x - hi
+    mid = top(rest)
+    return hi, mid, rest - mid
+
+
 def _paged_decode_kernel_stream(tables_ref, lens_ref, layer_ref, *refs,
                                 page_size, heads, head_dim, quantized=False,
                                 fold_lora=False, q_scale=1.0):
@@ -462,31 +481,58 @@ def _paged_decode_kernel_stream(tables_ref, lens_ref, layer_ref, *refs,
     so every layer of a model runs the same compiled kernel and no
     layer of the pool is ever sliced out for it.
 
-    The design motivation vs the (B, P) grid kernel: that kernel pays a
-    Mosaic grid-step per (slot, page) — B x P x layers ~ 1,000 grid
-    steps per decode step — and its BlockSpec fetches every page in the
-    sliced table even past ``length`` (pl.when skips the compute, not
-    the DMA).  Here the page loop is ``pl.when``-guarded per slot, so
-    short streams stop paying max-length HBM traffic, and the next
-    page's DMA overlaps the current page's compute.  It compiles under
-    Mosaic on the v5e with jax 0.9.0 and agrees with a host float64
-    oracle in every variant (tools/probe_kernels.py); its speed against
-    the grid kernel and XLA's gather is not measured on the current
-    code (ROADMAP S1).
+    **A lane pays for its live pages only** (PR 27).  A lane of length
+    0 writes the neutral flash state (``acc`` 0, ``m`` -inf, ``l`` 0)
+    and leaves: no projector is built, no page fetched.  A live lane
+    runs a ``fori_loop`` over its own ``ceil(length / page_size)``
+    pages (capped at the table's width), :func:`_pages_per_step` of
+    them a step, and no more.  Before PR 27 the loop ran the table's
+    full width for every lane and threw the dead iterations' arithmetic
+    away (``pl.when`` guarded the DMAs alone), so a call's time
+    followed lanes x table width whatever the cache held: 1.7 us a slot
+    at 20 x 64 and 2.6 at 16 x 128 on the v5e, the lanes at the chat
+    cell's lengths or all full (PERF.md §6, PR 27).
+
+    **A lane does not start on a cold DMA.**  The page buffers, their
+    semaphores and one SMEM word are scratch of the whole call, not of
+    a grid step: a lane's last loop step starts the NEXT live lane's
+    first copies into the buffer it is not reading (an empty lane
+    passes the baton on), and leaves in ``turn_ref`` which buffer that
+    was.  The grid runs in order on one core
+    (``dimension_semantics=("arbitrary",)``).  Worth 18-23 % of a call
+    at the chat cell's lengths.
 
     Everything stays in the pool's flattened (ps, h*hd) layout — Mosaic
     supports neither value shape-casts nor batched dots, so the
-    per-head score/weighted-sum contractions are done as block-diagonal
-    MXU matmuls: ``s = k @ QB`` with QB[r, c] = q[c, r - c*hd] masked to
-    its head's block, and the weighted value sum via ``w @ E`` where
-    E[c, r] = [r // hd == c] expands per-head weights across lanes.
+    per-head contractions are block-diagonal MXU matmuls with the heads
+    on the SUBLANES: a ``(hp, D)`` projector (row c = head c's slice of
+    q, zero elsewhere; ``hp`` = heads rounded up to 8) gives the scores
+    ``projector @ k^T`` as ``(hp, tokens)``, the softmax state is
+    ``(hp, 1)`` columns, and ``w @ v`` gives ``(hp, D)``, of which row
+    c's head-c block is the answer: it accumulates whole across the
+    steps and the block diagonal is cut out once, when the lane ends.
 
-    r18 extensions, both trace-time static flags so the base program is
-    byte-identical with them off:
+    **A step's matmuls are one bf16 MXU pass each, at f32 precision.**
+    K and V are bf16 at rest (int8 under ``quantized``: both exact in
+    bf16), so only the SMALL operand carries 24 bits: it is split into
+    three bf16 terms (:func:`_split3`), stacked on the rows — ``(3*hp,
+    D)`` for q, ``(3*hp, tokens)`` for the softmax weights — and the
+    three row blocks of the f32 result are added.  Every product is
+    exact and every sum is f32: what ``Precision.HIGHEST`` (six passes,
+    four of them over the 16 zero mantissa bits of a bf16 page cast to
+    f32) gave before; against a float64 host oracle on the chip the max
+    error is 0.8e-6 at 20 x 64 and 1.0e-6 at 16 x 128, the six-pass
+    kernel's 1.3e-6 and 1.1e-6 (tools/probe_kernels.py).  An f32 pool
+    (CPU exactness lanes) keeps ``HIGHEST`` on f32 operands, in the
+    same layout.  With both, a live page costs 0.47-0.65 us at 20 x 64
+    (its DMA: 0.40) and 0.75-0.91 at 16 x 128 (0.64): 61-86 % of the
+    DMA roofline, from 12 %.
 
-    * ``quantized`` — the pool stores int8 pages with one f32 scale per
-      page per k/v; the scale tables ride the scalar prefetch next to
-      the block table and pages dequantise in-register after the DMA.
+    Both trace-time static flags leave the base program alone:
+
+    * ``quantized`` — int8 pages with one f32 scale per page per k/v in
+      scalar-prefetched tables; the scale multiplies the page's SCORES
+      and its softmax WEIGHTS (a ``(1, tokens)`` row), never the page.
     * ``fold_lora`` — the per-lane qkv LoRA BGMV delta computes INSIDE
       this launch: the lane's adapter slot id (scalar prefetch) indexes
       the factor pools in HBM, one DMA brings the lane's (r, D)/(r, 3D)
@@ -494,8 +540,8 @@ def _paged_decode_kernel_stream(tables_ref, lens_ref, layer_ref, *refs,
       the q third folds into the scores in-register (``q_scale`` is the
       1/sqrt(hd) the caller already applied to q), and the RAW delta
       emits as a fourth output for the caller's self-term and pool
-      write.  Slot 0 holds zero factors, so no-adapter lanes compute an
-      exact 0.0 delta through the same program.
+      write.  Slot 0 holds zero factors: a lane of length 0 on slot 0
+      writes an exact 0.0 delta without fetching them.
     """
     import jax
     import jax.numpy as jnp
@@ -516,138 +562,347 @@ def _paged_decode_kernel_stream(tables_ref, lens_ref, layer_ref, *refs,
         pos += 3
     pk_hbm, pv_hbm = refs[pos], refs[pos + 1]
     acc_ref, m_ref, l_ref = refs[pos + 2], refs[pos + 3], refs[pos + 4]
-    delta_ref = refs[pos + 5] if fold_lora else None
+    pos += 5
+    delta_ref = None
+    if fold_lora:
+        delta_ref = refs[pos]
+        pos += 1
+    # scratch that outlives a grid step: the two page buffers, their
+    # DMA semaphores and the buffer the next lane starts in
+    k_buf, v_buf, sems, turn_ref = refs[pos:pos + 4]
+    if fold_lora:
+        a_scr, b_scr, lsems = refs[pos + 4:pos + 7]
 
     b = pl.program_id(0)
+    lanes = pl.num_programs(0)
     layer = layer_ref[0]
     h, hd = heads, head_dim
     D = h * hd
+    hp = -(-h // 8) * 8
+    width = tables_ref.shape[1]
+    group, span = _pages_per_step(page_size, width), k_buf.shape[1]
     length = lens_ref[b]
-    n_pages = jax.lax.div(length + page_size - 1, page_size)
+    # bf16 and int8 pages are exact in bf16: the one-pass lane.  An f32
+    # pool needs all of its bits: HIGHEST on f32 operands
+    one_pass = pk_hbm.dtype != jnp.float32
+    nt_dims = (((1,), (1,)), ((), ()))
+    highest = jax.lax.Precision.HIGHEST
 
-    def body(k_scratch, v_scratch, sems, a_scr=None, b_scr=None, lsems=None):
-        def dma(pool, scratch, slot, i, which):
-            return pltpu.make_async_copy(
-                pool.at[layer, tables_ref[b, i]], scratch.at[slot],
-                sems.at[slot, which],
-            )
+    def pages_of(lane):
+        # a lane masked done may hold more context than the table slice
+        # it was given (models/paged.py _pages_horizon): never read
+        # past it
+        return jnp.minimum(
+            jax.lax.div(lens_ref[lane] + page_size - 1, page_size), width)
 
+    def copies(lane, j, slot, which):
+        """``(held, copy)`` of loop step ``j`` of ``lane`` into buffer
+        ``slot``: K (``which`` 0) or V (1), up to ``group`` pages.  A
+        page the lane does not hold (``held`` false) is neither started
+        nor waited for."""
+        pool, buf = ((pk_hbm, k_buf), (pv_hbm, v_buf))[which]
+        n = pages_of(lane)
+        for g in range(group):
+            idx = j * group + g
+            page = tables_ref[lane, jnp.clip(idx, 0, n - 1)]
+            yield idx < n, pltpu.make_async_copy(
+                pool.at[layer, page],
+                buf.at[slot, pl.ds(g * page_size, page_size)],
+                sems.at[slot, which, g])
+
+    def start(lane, j, slot):
+        for which in (0, 1):
+            for held, copy in copies(lane, j, slot, which):
+                pl.when(held)(copy.start)
+
+    def wait(j, slot, which):
+        for held, copy in copies(b, j, slot, which):
+            pl.when(held)(copy.wait)
+
+    n_pages = pages_of(b)
+    n_steps = jax.lax.div(n_pages + group - 1, group)
+    # the next lane's first pages are fetched while this lane's last
+    # are reduced, so a lane does not start on a cold DMA: whoever runs
+    # before a live lane (live or not) starts its first step's copies
+    # and leaves the buffer they went to in ``turn_ref``
+    after = jnp.minimum(b + 1, lanes - 1)
+    hand_on = (b + 1 < lanes) & (pages_of(after) > 0)
+
+    @pl.when(b == 0)
+    def _first():
+        turn_ref[0] = 0
+        start(0, 0, 0)
+
+    base = turn_ref[0]
+
+    def stack(x):
+        """The small operand of a page's matmul: its three bf16 terms
+        on the rows (one pass), or itself (f32 pool)."""
+        if not one_pass:
+            return x
+        return jnp.concatenate(_split3(x), axis=0).astype(jnp.bfloat16)
+
+    def unstack(y):
+        """Rows (3*hp, n) of a one-pass result -> (hp, n): the small
+        terms first, so the sum rounds once at the large one."""
+        if not one_pass:
+            return y
+        return (y[2 * hp:] + y[hp:2 * hp]) + y[:hp]
+
+    def pages(ref):
+        x = ref[...]
+        if not one_pass:
+            return x
+        if x.dtype != jnp.bfloat16:
+            x = x.astype(jnp.float32)  # int8 has no direct bf16 cast
+        return x.astype(jnp.bfloat16)
+
+    def page_scales(ref, j):
+        """(1, span): the scale of each column's page (int8 pool)."""
+        col_page = jax.lax.broadcasted_iota(
+            jnp.int32, (1, span), 1) // page_size
+        out = jnp.zeros((1, span), jnp.float32)
+        for g in range(group):
+            held = jnp.minimum(j * group + g, n_pages - 1)
+            out = jnp.where(
+                col_page == g, ref[layer, tables_ref[b, held]], out)
+        return out
+
+    def lora_delta():
+        """The lane's raw (1, 3D) qkv delta, written to its output."""
+        lane = adapter_ref[b]
+        cp_a = pltpu.make_async_copy(a_hbm.at[layer, lane], a_scr, lsems.at[0])
+        cp_b = pltpu.make_async_copy(b_hbm.at[layer, lane], b_scr, lsems.at[1])
+        cp_a.start()
+        cp_b.start()
+        cp_a.wait()
+        cp_b.wait()
+        xflat = x_ref[0].astype(jnp.float32)           # (1, D) block input
+        # BGMV on the VPU: t = A[lane]^T x (rank,), delta = t B[lane]
+        t = (a_scr[...].astype(jnp.float32) * xflat).sum(axis=1, keepdims=True)
+        delta = (t * b_scr[...].astype(jnp.float32)).sum(axis=0, keepdims=True)
+        delta_ref[0] = delta                           # (1, 3D) raw, unscaled
+        return delta
+
+    @pl.when(n_pages == 0)
+    def _dead():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
         if fold_lora:
-            # the lane's factor rows start streaming before the first
-            # page DMA — the slot-index gather rides the same scalar
-            # prefetch as the block table
-            lane = adapter_ref[b]
-            cp_a = pltpu.make_async_copy(
-                a_hbm.at[layer, lane], a_scr, lsems.at[0])
-            cp_b = pltpu.make_async_copy(
-                b_hbm.at[layer, lane], b_scr, lsems.at[1])
-            cp_a.start()
-            cp_b.start()
+            # a lane with no cache still owes its delta (the caller's
+            # self term and pool write read it) unless it sits on slot
+            # 0, whose factors are zero by contract
+            @pl.when(adapter_ref[b] != 0)
+            def _delta():
+                lora_delta()
 
-        @pl.when(n_pages > 0)
-        def _warmup():
-            dma(pk_hbm, k_scratch, 0, 0, 0).start()
-            dma(pv_hbm, v_scratch, 0, 0, 1).start()
+            @pl.when(adapter_ref[b] == 0)
+            def _no_delta():
+                delta_ref[...] = jnp.zeros_like(delta_ref)
 
-        qflat = q_ref[0, 0].astype(jnp.float32)       # (D,), pre-scaled
+        @pl.when(hand_on)
+        def _hand_on():
+            start(after, 0, base)
+
+    @pl.when(n_pages > 0)
+    def _live():
+        qflat = q_ref[0].astype(jnp.float32)           # (1, D), pre-scaled
         if fold_lora:
-            cp_a.wait()
-            cp_b.wait()
-            xflat = x_ref[0, 0].astype(jnp.float32)   # (D,) block input
-            # BGMV on the VPU: t = A[lane]^T x (rank,), delta = t B[lane]
-            t = (a_scr[...].astype(jnp.float32) * xflat[None, :]).sum(axis=1)
-            delta = (t[:, None] * b_scr[...].astype(jnp.float32)).sum(axis=0)
-            delta_ref[0, 0] = delta                   # (3D,) raw, unscaled
-            qflat = qflat + q_scale * delta[:D]
-        # block-diagonal projectors, built once per slot
-        r_over = jax.lax.broadcasted_iota(jnp.int32, (D, h), 0) // hd
-        c_idx = jax.lax.broadcasted_iota(jnp.int32, (D, h), 1)
-        qb = jnp.where(r_over == c_idx, qflat[:, None], 0.0)      # (D, h)
-        e_r = jax.lax.broadcasted_iota(jnp.int32, (h, D), 1) // hd
-        e_c = jax.lax.broadcasted_iota(jnp.int32, (h, D), 0)
-        expand = jnp.where(e_r == e_c, 1.0, 0.0)                  # (h, D)
+            qflat = qflat + q_scale * lora_delta()[:, :D]
+        # row c of the projector holds head c's slice of q on its own
+        # columns; rows past the heads are zero
+        row = jax.lax.broadcasted_iota(jnp.int32, (hp, D), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (hp, D), 1)
+        own = (col >= row * hd) & (col < (row + 1) * hd)
+        terms = _split3(qflat) if one_pass else (qflat,)
+        qb = jnp.concatenate(
+            [jnp.where(own, t, 0.0) for t in terms], axis=0,
+        ).astype(jnp.bfloat16 if one_pass else jnp.float32)  # (3*hp | hp, D)
 
-        max_pages = tables_ref.shape[1]
+        def step(j, carry):
+            m_prev, l_prev, acc = carry            # (hp, 1), (hp, 1), (hp, D)
+            slot = jax.lax.rem(base + j, 2)
 
-        def loop(i, carry):
-            m_prev, l_prev, acc = carry               # (h,), (h,), (D,)
-            slot = jax.lax.rem(i, 2)
-            nxt = jax.lax.rem(i + 1, 2)
-
-            @pl.when(i + 1 < n_pages)
+            @pl.when(j + 1 < n_steps)
             def _prefetch():
-                dma(pk_hbm, k_scratch, nxt, i + 1, 0).start()
-                dma(pv_hbm, v_scratch, nxt, i + 1, 1).start()
+                start(b, j + 1, 1 - slot)
 
-            @pl.when(i < n_pages)
-            def _wait():
-                dma(pk_hbm, k_scratch, slot, i, 0).wait()
-                dma(pv_hbm, v_scratch, slot, i, 1).wait()
+            @pl.when((j + 1 == n_steps) & hand_on)
+            def _hand_on():
+                start(after, 0, 1 - slot)
 
-            k = k_scratch[slot].astype(jnp.float32)   # (ps, D)
-            v = v_scratch[slot].astype(jnp.float32)
+            wait(j, slot, 0)
+            s = unstack(jax.lax.dot_general(
+                qb, pages(k_buf.at[slot]), nt_dims,
+                preferred_element_type=jnp.float32,
+                precision=None if one_pass else highest))     # (hp, span)
+            at = j * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
             if quantized:
-                # per-page dequant in-register (scales scalar-prefetched)
-                k = k * sk_ref[layer, tables_ref[b, i]]
-                v = v * sv_ref[layer, tables_ref[b, i]]
-            # HIGHEST: a default-precision f32 dot runs as bf16 MXU
-            # passes and costs ~0.05 absolute score error (measured
-            # against a float64 host reference; the grid kernel's VPU
-            # reduce is exact) — these dots are tiny, so full precision
-            # is free
-            s = jnp.dot(k, qb, preferred_element_type=jnp.float32,
-                        precision=jax.lax.Precision.HIGHEST)  # (ps, h)
-            pos = i * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, (page_size, 1), 0)
-            s = jnp.where(pos < length, s, -jnp.inf)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))         # (h,)
+                s = s * page_scales(sk_ref, j)
+            s = jnp.where(at < length, s, -jnp.inf)
+            # every step the loop reaches holds a live token, so m_new
+            # is finite and the first step's alpha is exp(-inf) = 0
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
-            w = jnp.exp(s - m_new[None, :])           # (ps, h); dead rows 0
-            l_new = l_prev * alpha + w.sum(axis=0)
-            w_exp = jnp.dot(w, expand, preferred_element_type=jnp.float32,
-                            precision=jax.lax.Precision.HIGHEST)
-            alpha_exp = jnp.dot(alpha[None, :], expand,
-                                preferred_element_type=jnp.float32,
-                                precision=jax.lax.Precision.HIGHEST)[0]
-            acc = acc * alpha_exp + (v * w_exp).sum(axis=0)         # (D,)
-            return m_new, l_new, acc
-
-        def guarded(i, carry):
-            # static trip count (Mosaic pipelines it far better than a
-            # data-dependent bound); masked iterations skip BOTH the
-            # DMA and the flash update
-            new = loop(i, carry)
-            keep = i < n_pages
-            return tuple(
-                jnp.where(keep, n, c) for n, c in zip(new, carry)
-            )
+            w = jnp.exp(s - m_new)                 # (hp, span); dead columns 0
+            l_new = l_prev * alpha + w.sum(axis=1, keepdims=True)
+            if quantized:
+                w = w * page_scales(sv_ref, j)
+            wait(j, slot, 1)
+            for g in range(1, group):
+                # a page the lane does not hold was not fetched: its
+                # weights are 0, and 0 x whatever the buffer held must
+                # be 0
+                @pl.when(j * group + g >= n_pages)
+                def _blank(g=g):
+                    v_buf[slot, pl.ds(g * page_size, page_size)] = jnp.zeros(
+                        (page_size, D), v_buf.dtype)
+            pv = unstack(jnp.dot(
+                stack(w), pages(v_buf.at[slot]),
+                preferred_element_type=jnp.float32,
+                precision=None if one_pass else highest))     # (hp, D)
+            return m_new, l_new, acc * alpha + pv
 
         init = (
-            jnp.full((h,), -jnp.inf, jnp.float32),
-            jnp.zeros((h,), jnp.float32),
-            jnp.zeros((D,), jnp.float32),
+            jnp.full((hp, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((hp, 1), jnp.float32),
+            jnp.zeros((hp, D), jnp.float32),
         )
-        m_fin, l_fin, acc_fin = jax.lax.fori_loop(0, max_pages, guarded, init)
-        acc_ref[0, 0] = acc_fin
+        m_fin, l_fin, acc_fin = jax.lax.fori_loop(0, n_steps, step, init)
+        turn_ref[0] = jax.lax.rem(base + n_steps, 2)
+        # the block diagonal, cut out once: row c keeps head c's columns
+        acc_ref[0] = jnp.where(own, acc_fin, 0.0).sum(axis=0, keepdims=True)
         # m/l lane-padded to (h, 128): Mosaic wants 128-divisible last
         # block dims; every lane carries the same value
-        m_ref[0] = jnp.broadcast_to(m_fin[:, None], m_ref.shape[1:])
-        l_ref[0] = jnp.broadcast_to(l_fin[:, None], l_ref.shape[1:])
+        m_ref[0] = jnp.broadcast_to(m_fin[:h], m_ref.shape[1:])
+        l_ref[0] = jnp.broadcast_to(l_fin[:h], l_ref.shape[1:])
 
-    pool_dtype = pk_hbm.dtype
-    scope = dict(
-        k_scratch=pltpu.VMEM((2, page_size, D), pool_dtype),
-        v_scratch=pltpu.VMEM((2, page_size, D), pool_dtype),
-        sems=pltpu.SemaphoreType.DMA((2, 2)),
+
+def _pages_per_step(page_size: int, table_width: int) -> int:
+    """Pages one step of the stream kernel's loop reduces: enough tokens
+    that the MXU's 128 columns (the scores) and 128 contraction rows
+    (``w @ v``) are full, and no more than a table holds.  On the v5e
+    two 64-token pages a step took a live page from 0.80 to 0.55 us at
+    20 x 64 (lanes full) and four gave nothing more; at 16 x 128, where
+    a page's DMA is 0.64 us, two changed little (PERF.md §6, PR 27)."""
+    return max(1, min(128 // page_size, table_width))
+
+
+def _stream_decode(q, pk, pv, block_tables, lengths, layer, kv_scales, lora,
+                   *, quantized, fold, q_scale, interpret):
+    """The stream kernel's ``pallas_call`` on the whole flat pool (see
+    :func:`paged_attention_decode`, which calls it jitted; ``quantized``
+    and ``fold`` say whether ``kv_scales`` and ``lora`` are there)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, h, hd = q.shape
+    P = block_tables.shape[1]
+    ps = pk.shape[2]
+    if quantized:
+        sk, sv = kv_scales
+    D = h * hd
+    scalar_args = [block_tables, lengths, layer.reshape(1)]
+    n_prefetch = 3
+    if quantized:
+        scalar_args += [sk, sv]
+        n_prefetch += 2
+    if fold:
+        x, a_T, b_f, adapter_idx = lora
+        scalar_args.append(jnp.asarray(adapter_idx, jnp.int32))
+        n_prefetch += 1
+    # the kernel works in the pool's flat (ps, h*hd) layout: HBM
+    # page slices need a 128-aligned minor dim and Mosaic has no
+    # value shape-casts.  The pool arrives in it; only q (KBs) is
+    # re-laid here
+    q = q.reshape(B, 1, D)
+    # q/acc ride as (B, 1, D) with (1, 1, D) blocks: the (8, 128)
+    # divisibility rule applies to the LAST TWO dims, and the
+    # singleton middle dim satisfies it.  Index lambdas take the
+    # grid ids then every scalar-prefetch operand, so *prefetch
+    # absorbs the variable tail.
+    lane_spec = pl.BlockSpec((1, 1, D), lambda b, *prefetch: (b, 0, 0))
+    in_specs = [lane_spec]
+    tensor_args = [q]
+    if fold:
+        in_specs += [
+            lane_spec,                          # x — block inputs
+            pl.BlockSpec(memory_space=pl.ANY),  # A^T factor pool
+            pl.BlockSpec(memory_space=pl.ANY),  # B factor pool
+        ]
+        tensor_args += [x.reshape(B, 1, D), a_T, b_f]
+    in_specs += [
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+    ]
+    tensor_args += [pk, pv]
+    pad_spec = pl.BlockSpec((1, h, 128), lambda b, *prefetch: (b, 0, 0))
+    out_specs = [lane_spec, pad_spec, pad_spec]
+    out_shape = [
+        jax.ShapeDtypeStruct((B, 1, D), jnp.float32),
+        jax.ShapeDtypeStruct((B, h, 128), jnp.float32),
+        jax.ShapeDtypeStruct((B, h, 128), jnp.float32),
+    ]
+    if fold:
+        out_specs.append(
+            pl.BlockSpec((1, 1, 3 * D), lambda b, *prefetch: (b, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((B, 1, 3 * D), jnp.float32))
+    # scratch that persists over the grid: a lane's last step starts
+    # the next lane's first copies into the other page buffer
+    span = _pages_per_step(ps, P) * ps
+    scratch_shapes = [
+        pltpu.VMEM((2, span, D), pk.dtype),
+        pltpu.VMEM((2, span, D), pv.dtype),
+        pltpu.SemaphoreType.DMA((2, 2, span // ps)),
+        pltpu.SMEM((1,), jnp.int32),
+    ]
+    if fold:
+        rank = a_T.shape[2]
+        scratch_shapes += [
+            pltpu.VMEM((rank, D), a_T.dtype),
+            pltpu.VMEM((rank, 3 * D), b_f.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_prefetch,
+        grid=(B,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=scratch_shapes,
     )
-    if fold_lora:
-        rank = a_hbm.shape[2]
-        scope.update(
-            a_scr=pltpu.VMEM((rank, D), a_hbm.dtype),
-            b_scr=pltpu.VMEM((rank, 3 * D), b_hbm.dtype),
-            lsems=pltpu.SemaphoreType.DMA((2,)),
-        )
-    pl.run_scoped(body, **scope)
+    kernel = functools.partial(
+        _paged_decode_kernel_stream, page_size=ps, heads=h, head_dim=hd,
+        quantized=quantized, fold_lora=fold,
+        q_scale=q_scale)
+    outs = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        # lanes in order, on one core: each hands the next its
+        # first pages
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(*scalar_args, *tensor_args)
+    acc, m, l = outs[0], outs[1], outs[2]
+    res = (acc.reshape(B, h, hd), m[:, :, 0], l[:, :, 0])
+    if fold:
+        res = res + (outs[3].reshape(B, 3 * D),)
+    return res
+
+
+
+@functools.lru_cache(maxsize=None)
+def _stream_decode_jit():
+    """:func:`_stream_decode` under ``jax.jit``, made on first use
+    (nothing imports jax with this module)."""
+    import jax
+
+    return jax.jit(_stream_decode, static_argnames=(
+        "quantized", "fold", "q_scale", "interpret"))
 
 
 def paged_kernel_impl(heads: int, head_dim: int) -> str:
@@ -718,8 +973,10 @@ def paged_attention_decode(q, pk, pv, block_tables, lengths, *, layer,
 
     Two implementations, selected by ``SELDON_TPU_PAGED_KERNEL_IMPL``:
 
-    * ``stream`` (default) — grid=(B,), double-buffered manual DMA,
-      page loop bounded by each slot's own length.
+    * ``stream`` (default) — grid=(B,), double-buffered manual DMA;
+      a lane's loop runs its own ``ceil(length / page_size)`` pages and
+      an empty lane none, so a call's time follows the live pages
+      (0.5-0.9 us each on the v5e), not the table's width.
     * ``grid`` — the original (B, P) grid with block-table BlockSpecs
       over ONE layer of the split pool (sliced here, as its caller did
       before); kept for A/B measurement (tools/profile_paged_kernel.py).
@@ -766,75 +1023,22 @@ def paged_attention_decode(q, pk, pv, block_tables, lengths, *, layer,
         )
 
     if impl == "stream":
-        D = h * hd
-        fold = lora is not None
-        scalar_args = [
-            block_tables, lengths, jnp.asarray(layer, jnp.int32).reshape(1)]
-        n_prefetch = 3
-        if quantized:
-            scalar_args += [sk, sv]
-            n_prefetch += 2
-        if fold:
-            x, a_T, b_f, adapter_idx, q_scale = lora
-            scalar_args.append(jnp.asarray(adapter_idx, jnp.int32))
-            n_prefetch += 1
-        # the kernel works in the pool's flat (ps, h*hd) layout: HBM
-        # page slices need a 128-aligned minor dim and Mosaic has no
-        # value shape-casts.  The pool arrives in it; only q (KBs) is
-        # re-laid here
-        q = q.reshape(B, 1, D)
-        # q/acc ride as (B, 1, D) with (1, 1, D) blocks: the (8, 128)
-        # divisibility rule applies to the LAST TWO dims, and the
-        # singleton middle dim satisfies it.  Index lambdas take the
-        # grid ids then every scalar-prefetch operand, so *prefetch
-        # absorbs the variable tail.
-        lane_spec = pl.BlockSpec((1, 1, D), lambda b, *prefetch: (b, 0, 0))
-        in_specs = [lane_spec]
-        tensor_args = [q]
-        if fold:
-            in_specs += [
-                lane_spec,                          # x — block inputs
-                pl.BlockSpec(memory_space=pl.ANY),  # A^T factor pool
-                pl.BlockSpec(memory_space=pl.ANY),  # B factor pool
-            ]
-            tensor_args += [x.reshape(B, 1, D), a_T, b_f]
-        in_specs += [
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ]
-        tensor_args += [pk, pv]
-        pad_spec = pl.BlockSpec((1, h, 128), lambda b, *prefetch: (b, 0, 0))
-        out_specs = [lane_spec, pad_spec, pad_spec]
-        out_shape = [
-            jax.ShapeDtypeStruct((B, 1, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, h, 128), jnp.float32),
-            jax.ShapeDtypeStruct((B, h, 128), jnp.float32),
-        ]
-        if fold:
-            out_specs.append(
-                pl.BlockSpec((1, 1, 3 * D), lambda b, *prefetch: (b, 0, 0)))
-            out_shape.append(jax.ShapeDtypeStruct((B, 1, 3 * D), jnp.float32))
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=n_prefetch,
-            grid=(B,),
-            in_specs=in_specs,
-            out_specs=out_specs,
-        )
-        kernel = functools.partial(
-            _paged_decode_kernel_stream, page_size=ps, heads=h, head_dim=hd,
-            quantized=quantized, fold_lora=fold,
-            q_scale=float(q_scale) if fold else 1.0)
-        outs = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            interpret=interpret_mode(),
-        )(*scalar_args, *tensor_args)
-        acc, m, l = outs[0], outs[1], outs[2]
-        res = (acc.reshape(B, h, hd), m[:, :, 0], l[:, :, 0])
-        if fold:
-            res = res + (outs[3].reshape(B, 3 * D),)
-        return res
+        if lora is not None:
+            *lora, q_scale = lora
+        # one jitted function for every layer's call: the chunk programs
+        # unroll the layers, and a program that holds the kernel 36 (or
+        # 72) times over traces and lowers it ONCE — the layer is a
+        # traced scalar, so the calls share one jaxpr.  Traced anew per
+        # call the kernel cost ~1.1 s a layer before any compile-cache
+        # lookup: 40 s of set-up a chunk shape at 36 layers, in every
+        # run (PERF.md §6, PR 27)
+        return _stream_decode_jit()(
+            q, pk, pv, block_tables, lengths, jnp.asarray(layer, jnp.int32),
+            (sk, sv) if quantized else None,
+            None if lora is None else tuple(lora),
+            quantized=quantized, fold=lora is not None,
+            q_scale=float(q_scale) if lora is not None else 1.0,
+            interpret=interpret_mode())
 
     pk, pv = pk[layer], pv[layer]
     scalar_args = [block_tables, lengths]

@@ -303,6 +303,15 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
     "decode_lane_steps": ("counter",
                           "seldon_tpu_engine_decode_lane_steps_total",
                           "decode steps run, summed over lanes"),
+    "decode_page_slots": ("counter",
+                          "seldon_tpu_engine_decode_page_slots_total",
+                          "block-table slots handed to the decode "
+                          "attention: steps launched x lanes x table "
+                          "width, summed over length buckets"),
+    "decode_live_pages": ("counter",
+                          "seldon_tpu_engine_decode_live_pages_total",
+                          "KV pages the decode steps' lanes held: "
+                          "ceil(cached / page_size) summed over lane-steps"),
     "queue_wait_s": ("counter", "seldon_tpu_engine_queue_wait_seconds_total",
                      "seconds streams spent in the engine's queue, "
                      "submit to first prefill slice"),

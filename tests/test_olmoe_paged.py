@@ -397,13 +397,17 @@ def test_the_initialiser_follows_the_spec_not_a_name(monkeypatch, which):
 # the function below.  A second model reads a spec; GPT-2's value of it
 # must trace what was there.  A PR that changes GPT-2's programs on
 # purpose (or the jax version) takes these again and says so.
+# PR 27 changed the stream decode kernel on purpose: the ``kernel``
+# lane's two ``chunk_*`` hashes were taken again on its tree; its two
+# ``prefill_*`` hashes and all four of the ``gather`` lane are still
+# 89e949e's — only the kernel moved.
 GPT2_CFG = dict(vocab_size=64, d_model=32, num_layers=2, num_heads=2, max_len=64)
 PARENT_SHA = {
     "kernel": {
         "prefill_b16_k2": "5ef734ce28371fe7913c64f3b37b47ff3b9f1a5a6500f0adc5a6d0746d72528f",
         "prefill_cached_b16_k2_r2": "b02869115cb72ef159f121dc1fef1f37fd9c3baf2fc3fb6a96365fa9929ec1c6",
-        "chunk_s2_4x4": "ff3282bf2d154dbd3be59dc9dcd15989d4c99358fd67c87ee784137d556ab1cb",
-        "chunk_s2_2x2_2x4": "38ec6a9e44843b711da6d163773456fc5f83849f3f407667b0842c9f27d07d1a",
+        "chunk_s2_4x4": "04ceedbf420cb4f47f3a4d64a7d82cd5741065e466be77fad9513d10476cd3b2",
+        "chunk_s2_2x2_2x4": "1569aa08b7c74bbfb2bea2e50af781f9961c2c6b0923720ee05518d5abddef90",
     },
     "gather": {
         "prefill_b16_k2": "f9ae2fa113df6fbf845f2983a405fc1c6a04b840862649813190b054415e4e77",

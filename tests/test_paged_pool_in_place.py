@@ -200,10 +200,10 @@ D = H * HD
 LENGTHS = np.array([0, 5, 16, 32], np.int32)  # a dead lane, a partial page, full
 
 
-def _pool(rng, impl, pool):
+def _pool(rng, impl, pool, num_pages=NUM_PAGES, h=H, hd=HD):
     """``(what the pool stores, as float64, split; device array in the
     impl's layout; per-page scales or None)``."""
-    raw = rng.normal(size=(LAYERS, NUM_PAGES, PS, H, HD)).astype(np.float32)
+    raw = rng.normal(size=(LAYERS, num_pages, PS, h, hd)).astype(np.float32)
     scales = None
     if pool == "int8":
         scales = (np.abs(raw).max(axis=(2, 3, 4)) / 127.0).astype(np.float32)
@@ -214,15 +214,18 @@ def _pool(rng, impl, pool):
         dev = jnp.asarray(raw, {"f32": jnp.float32, "bf16": jnp.bfloat16}[pool])
         stored = np.asarray(dev.astype(jnp.float32), np.float64)
     if impl == "stream":
-        dev = dev.reshape(LAYERS, NUM_PAGES, PS, D)
+        dev = dev.reshape(LAYERS, num_pages, PS, h * hd)
     return stored, dev, scales
 
 
 def _oracle(q, pk, pv, tables, lengths):
-    gk = pk[tables].reshape(B, P * PS, H, HD)
-    gv = pv[tables].reshape(B, P * PS, H, HD)
+    """float64, on the host.  A length past the table attends the whole
+    table (a lane masked done may hold more than the slice it was given)."""
+    b, p = tables.shape
+    gk = pk[tables].reshape(b, p * PS, *pk.shape[2:])
+    gv = pv[tables].reshape(b, p * PS, *pv.shape[2:])
     s = np.einsum("bhd,bkhd->bhk", q.astype(np.float64), gk)
-    mask = np.arange(P * PS)[None, :] < lengths[:, None]
+    mask = np.arange(p * PS)[None, :] < lengths[:, None]
     s = np.where(mask[:, None, :], s, -np.inf)
     m = s.max(-1)
     with np.errstate(invalid="ignore"):
@@ -231,12 +234,12 @@ def _oracle(q, pk, pv, tables, lengths):
     return np.einsum("bhk,bkhd->bhd", w, gv) / np.where(l > 0, l, 1.0)[..., None]
 
 
-def _check(outs, ref):
+def _check(outs, ref, lengths=LENGTHS):
     acc, l = np.asarray(outs[0], np.float64), np.asarray(outs[2], np.float64)
-    live = LENGTHS > 0
+    live = lengths > 0
     got = acc / np.where(l > 0, l, 1.0)[..., None]
     assert float(np.max(np.abs(got[live] - ref[live]))) < 1e-4
-    # the dead lane carries the neutral flash state, not NaN
+    # a dead lane carries the neutral flash state, not NaN
     assert np.all(l[~live] == 0.0) and np.all(acc[~live] == 0.0)
     assert np.all(np.isinf(np.asarray(outs[1])[~live]))
 
@@ -264,21 +267,25 @@ def test_kernel_reads_its_layer_of_the_whole_pool(impl, pool, layer, monkeypatch
     _check(outs, _oracle(q, pkn[layer], pvn[layer], tables, LENGTHS))
 
 
+def _lora_factors(rng, lanes, d, rank=4, slots=3):
+    """``(x, a, b, idx)``: slot 0 holds zero factors (no adapter)."""
+    x = rng.normal(size=(lanes, d)).astype(np.float32)
+    a = rng.normal(size=(LAYERS, slots, d, rank)).astype(np.float32) * 0.05
+    b = rng.normal(size=(LAYERS, slots, rank, 3 * d)).astype(np.float32) * 0.05
+    a[:, 0] = 0.0
+    b[:, 0] = 0.0
+    return x, a, b, (np.arange(lanes) % slots).astype(np.int32)
+
+
 @pytest.mark.parametrize("layer", range(LAYERS))
 def test_lora_fold_indexes_layer_and_slot(layer, monkeypatch):
     monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", "stream")
     rng = np.random.default_rng(11)
-    rank, slots = 4, 3
     q = rng.normal(size=(B, H, HD)).astype(np.float32)
     pkn, pk, _ = _pool(rng, "stream", "f32")
     pvn, pv, _ = _pool(rng, "stream", "f32")
     tables = rng.integers(1, NUM_PAGES, size=(B, P)).astype(np.int32)
-    x = rng.normal(size=(B, D)).astype(np.float32)
-    a = rng.normal(size=(LAYERS, slots, D, rank)).astype(np.float32) * 0.05
-    b = rng.normal(size=(LAYERS, slots, rank, 3 * D)).astype(np.float32) * 0.05
-    a[:, 0] = 0.0  # slot 0 = no adapter
-    b[:, 0] = 0.0
-    idx = (np.arange(B) % slots).astype(np.int32)
+    x, a, b, idx = _lora_factors(rng, B, D)
     q_scale = HD ** -0.5
     outs = paged_attention_decode(
         jnp.asarray(q), pk, pv, jnp.asarray(tables), jnp.asarray(LENGTHS),
@@ -289,6 +296,66 @@ def test_lora_fold_indexes_layer_and_slot(layer, monkeypatch):
     assert float(np.max(np.abs(np.asarray(outs[3]) - delta))) < 1e-4
     q_eff = q + q_scale * delta[:, :D].reshape(B, H, HD)
     _check(outs, _oracle(q_eff, pkn[layer], pvn[layer], tables, LENGTHS))
+
+
+# the page loop's edges (PR 27): a lane pays for ceil(length / page_size)
+# pages and a lane of length 0 for none.  name -> (table width, lengths)
+_FULL = 4 * PS
+LOOP_EDGES = {
+    # the doc cell's shape: 4 callers on 32 lanes
+    "mostly_dead": (4, np.where(np.arange(32) % 8 == 3, [13, 32, 7, 25] * 8, 0)),
+    # exactly 1, a page, a page and one, the full table, none
+    "page_edges": (4, np.array([1, PS, PS + 1, _FULL, 0, PS - 1, 2 * PS, _FULL - 1])),
+    # a table wider than any lane needs (a long bucket's short lanes)
+    "wide_table": (16, np.array([3, 2 * PS, 0, PS + 2])),
+    # a lane masked done may hold more than its table slice: it attends
+    # the slice, and never reads a table entry past it
+    "past_the_table": (2, np.array([5 * PS, 2 * PS + 1, 1, 0])),
+}
+
+
+@pytest.mark.parametrize("variant", ["bf16", "int8", "lora"])
+@pytest.mark.parametrize("heads,head_dim", [(20, 64), (16, 128)])
+@pytest.mark.parametrize("edge", sorted(LOOP_EDGES))
+def test_stream_kernel_pays_for_live_pages_only(edge, heads, head_dim, variant,
+                                                monkeypatch):
+    """Both served geometries (GPT-2-large's 20 x 64: heads padded to 24
+    projector rows; OLMoE's 16 x 128), with the int8 pool and with the
+    in-kernel LoRA fold, against the float64 oracle at the tolerance the
+    kernel has always had."""
+    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", "stream")
+    width, lengths = LOOP_EDGES[edge]
+    lengths = lengths.astype(np.int32)
+    lanes, d, layer = len(lengths), heads * head_dim, 1
+    num_pages = 24
+    rng = np.random.default_rng(27)
+    q = rng.normal(size=(lanes, heads, head_dim)).astype(np.float32) * head_dim ** -0.5
+    pool = "int8" if variant == "int8" else "bf16"
+    pkn, pk, sk = _pool(rng, "stream", pool, num_pages, heads, head_dim)
+    pvn, pv, sv = _pool(rng, "stream", pool, num_pages, heads, head_dim)
+    tables = rng.integers(1, num_pages, size=(lanes, width)).astype(np.int32)
+    kw, q_eff = {}, q
+    if variant == "int8":
+        kw["kv_scales"] = (jnp.asarray(sk), jnp.asarray(sv))
+    if variant == "lora":
+        x, a, b, idx = _lora_factors(rng, lanes, d)
+        q_scale = head_dim ** -0.5
+        kw["lora"] = (jnp.asarray(x), jnp.asarray(np.swapaxes(a, -1, -2)),
+                      jnp.asarray(b), jnp.asarray(idx), q_scale)
+        delta = np.einsum("bd,bdr,bre->be", x, a[layer][idx], b[layer][idx])
+        q_eff = q + q_scale * delta[:, :d].reshape(lanes, heads, head_dim)
+    outs = jax.jit(lambda *a_: paged_attention_decode(
+        *a_, layer=jnp.int32(layer), page_size=PS, **kw))(
+        jnp.asarray(q), pk, pv, jnp.asarray(tables), jnp.asarray(lengths))
+    # the readers find the kernel by its first output: 3-D, f32
+    assert outs[0].shape == (lanes, heads, head_dim) and outs[0].dtype == jnp.float32
+    if variant == "lora":
+        # every lane's delta, dead lanes' too: the caller's self term
+        # and pool write read it (slot 0's is an exact 0.0)
+        got = np.asarray(outs[3], np.float64)
+        assert float(np.max(np.abs(got - delta))) < 1e-4
+        assert np.all(got[idx == 0] == 0.0)
+    _check(outs, _oracle(q_eff, pkn[layer], pvn[layer], tables, lengths), lengths)
 
 
 @pytest.mark.parametrize("impl,ndim", [("stream", 5), ("grid", 4)])

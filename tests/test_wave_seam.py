@@ -105,7 +105,9 @@ class TestPhasesOnTheProfilersClock:
             assert admit[2] <= launch[1] and launch[2] <= wait[1]
             assert wait[2] <= harvest[1] and harvest[2] <= record[1]
             assert set(admit[3]) >= {"admitted", "queue_depth"}
-            assert set(launch[3]) >= {"steps", "lanes", "kv_tokens"}
+            assert set(launch[3]) >= {"steps", "lanes", "kv_tokens",
+                                      "pages_live", "page_slots"}
+            assert 0 < launch[3]["pages_live"] <= launch[3]["page_slots"]
             assert set(harvest[3]) >= {"tokens", "finished"}
             assert launch[3]["steps"] == 4 and launch[3]["lanes"] >= 1
             prefills += inside(wave, "prefill")
@@ -175,6 +177,67 @@ class TestCountedWhereItHappens:
         assert set(stats["phase_s"]) == {"admit", "prefill", "launch", "wait",
                                          "harvest", "record", "between"}
 
+    @pytest.mark.parametrize("lane,slots,live", [
+        # 8 lanes in two buckets of 4; lengths 7 and 20, 4 steps, pages
+        # of 8.  ring (the CPU default): tables cover the cache as the
+        # chunk starts, 1 and 4 (3 rounded up) pages wide; its own
+        # tokens stay in the ring, so each step reads 1 + 3 pool pages
+        ("ring", 4 * (4 * 1 + 4 * 4), 4 * (1 + 3)),
+        # pool (the kernel's lane): tables cover the chunk's growth too,
+        # 2 and 4 pages; lane A crosses a page: 7, 8 | 9, 10 tokens
+        ("kernel", 4 * (4 * 2 + 4 * 4), (1 + 1 + 2 + 2) + 4 * 3),
+    ])
+    def test_page_loop_slots_and_live_pages_of_a_two_lane_wave(
+            self, monkeypatch, lane, slots, live):
+        if lane == "kernel":
+            monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
+        eng = _tiny_engine()
+        try:
+            assert eng._chunk_impl == ("pool" if lane == "kernel" else "ring")
+            for n, f in [(7, 1), (20, 2)]:
+                eng.submit(_prompt(n, f), max_new_tokens=4)
+            eng.run()
+            stats = eng.engine_stats()
+        finally:
+            eng.close()
+        assert stats["chunks"] == 1 and stats["bucketed_chunks"] == 1
+        assert stats["decode_lane_steps"] == 2 * 4
+        assert stats["decode_page_slots"] == slots
+        assert stats["decode_live_pages"] == live
+        assert stats["decode_live_pages"] <= stats["decode_page_slots"]
+
+    def test_live_page_share_reader_takes_the_counters_deltas(self):
+        """``benchmarks/layer_metrics/decode_live_page_pct.py`` on a
+        hand-made ``ctx``; an engine without the counters (the parent of
+        PR 27) gives it nothing, and it does not raise."""
+        import importlib.util
+        import sys
+
+        bench = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks")
+        sys.path.insert(0, bench)
+        try:
+            spec = importlib.util.spec_from_file_location(
+                "decode_live_page_pct", os.path.join(
+                    bench, "layer_metrics", "decode_live_page_pct.py"))
+            reader = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(reader)
+        finally:
+            sys.path.remove(bench)
+
+        def ctx(before, after):
+            return {"window": (100.0, 150.0), "engine": {"window": [before, after]},
+                    "trace": None, "config": {}}
+
+        before = {"decode_live_pages": 100, "decode_page_slots": 400}
+        after = {"decode_live_pages": 1630, "decode_page_slots": 3400}
+        assert reader.read(ctx(before, after)) == pytest.approx(51.0)
+        older = {"tokens": 9, "decode_kv_tokens": 5}
+        assert reader.read(ctx(older, dict(older, tokens=19))) is None
+        assert reader.read(ctx(before, before)) is None
+        assert reader.read(ctx(before, None)) is None
+        assert reader.read({"window": (0.0, 1.0), "engine": {}, "trace": None}) is None
+
     def test_no_gap_is_counted_across_an_idle_engine(self):
         eng = _tiny_engine()
         try:
@@ -198,6 +261,11 @@ class TestCountedWhereItHappens:
             eng.close()
         assert stats["decode_lane_steps"] == stats["chunks"] >= 1
         assert stats["decode_kv_tokens"] >= 9 * stats["chunks"]
+        # 9 tokens and up in pages of 8: 2 live pages a verify forward
+        # or more, of the 8 lanes' tables
+        assert 2 * stats["chunks"] <= stats["decode_live_pages"]
+        assert stats["decode_live_pages"] <= stats["decode_page_slots"]
+        assert stats["decode_page_slots"] % 8 == 0
         assert stats["prefill_padded_tokens"] == 16
 
 

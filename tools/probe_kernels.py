@@ -9,12 +9,21 @@ interpreted) in its own try block so one refusal does not hide the
 next.  Widths follow the StreamingLM smoke (h8 x hd64, page 64, 16
 slots, max_len 1024) and the ResNet/ViT servers.
 
+The paged stream kernel is also probed at the benchmark's two
+geometries (GPT-2-large's 20 heads of 64, OLMoE's 16 of 128): its
+``max_abs_err`` against the float64 oracle is the number a change to
+the kernel's arithmetic must not raise.  ``--root <checkout>`` imports
+``seldon_core_tpu`` from another checkout (a ``git archive`` of the
+parent commit), so the SAME cases, inputs and oracle give the parent's
+kernel's errors to lay beside the change's (PERF.md §6, PR 27).
+
 Prints one line per case and writes the full record (compiler message
-included) to ``chiprun_out/kernel_probe.json``.  Exit code 1 when any
-case failed, 2 when the backend is not a TPU (pass ``--interpret`` to
-rehearse the script itself on CPU).
+included) to ``chiprun_out/kernel_probe.json`` (``--out`` names
+another file).  Exit code 1 when any case failed, 2 when the backend is
+not a TPU (pass ``--interpret`` to rehearse the script itself on CPU).
 
 Run:  python tools/probe_kernels.py [--interpret] [--only NAME ...]
+      [--root .bench_checkout/parent --out chiprun_out/kernel_probe_parent.json]
 """
 
 from __future__ import annotations
@@ -29,10 +38,8 @@ import traceback
 import numpy as np
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
 
 H, HD, PS, SLOTS, MAX_LEN = 8, 64, 64, 16, 1024
-D = H * HD
 PAGES_PER = MAX_LEN // PS
 NUM_PAGES = SLOTS * PAGES_PER + 1
 
@@ -40,22 +47,22 @@ NUM_PAGES = SLOTS * PAGES_PER + 1
 LAYERS, LAYER = 2, 1  # a whole pool of two layers; the kernel reads the second
 
 
-def _paged_inputs(rng, pool_dtype, impl):
-    """A whole pool + block tables with ragged lengths: one empty lane,
-    one single token, partial pages and a full table.  The device pool
-    is in the layout the impl reads: flat ``(L, pages, ps, h*hd)`` for
-    stream, split for grid; the oracle gets layer ``LAYER`` of what the
-    pool stores."""
+def _paged_inputs(rng, pool_dtype, impl, h, hd):
+    """A whole pool + block tables with ragged lengths: empty lanes,
+    one single token, exactly one page, one token more, partial pages
+    and a full table.  The device pool is in the layout the impl reads:
+    flat ``(L, pages, ps, h*hd)`` for stream, split for grid; the
+    oracle gets layer ``LAYER`` of what the pool stores."""
     import jax.numpy as jnp
 
     batch = SLOTS
-    q = rng.normal(size=(batch, H, HD)).astype(np.float32) * HD ** -0.5
-    pk = rng.normal(size=(LAYERS, NUM_PAGES, PS, H, HD)).astype(np.float32)
-    pv = rng.normal(size=(LAYERS, NUM_PAGES, PS, H, HD)).astype(np.float32)
+    q = rng.normal(size=(batch, h, hd)).astype(np.float32) * hd ** -0.5
+    pk = rng.normal(size=(LAYERS, NUM_PAGES, PS, h, hd)).astype(np.float32)
+    pv = rng.normal(size=(LAYERS, NUM_PAGES, PS, h, hd)).astype(np.float32)
     tables = rng.permutation(np.arange(1, NUM_PAGES))[: batch * PAGES_PER]
     tables = tables.reshape(batch, PAGES_PER).astype(np.int32)
     lengths = rng.integers(1, MAX_LEN, size=(batch,)).astype(np.int32)
-    lengths[:4] = (0, 1, PS, MAX_LEN - 1)
+    lengths[:7] = (0, 1, PS, MAX_LEN - 1, PS + 1, MAX_LEN, 0)
     scales = None
     if pool_dtype == jnp.int8:
         sk = np.abs(pk).max(axis=(2, 3, 4)) / 127.0
@@ -71,15 +78,15 @@ def _paged_inputs(rng, pool_dtype, impl):
         pk = np.asarray(pk_dev.astype(jnp.float32))
         pv = np.asarray(pv_dev.astype(jnp.float32))
     if impl == "stream":
-        pk_dev = pk_dev.reshape(LAYERS, NUM_PAGES, PS, D)
-        pv_dev = pv_dev.reshape(LAYERS, NUM_PAGES, PS, D)
+        pk_dev = pk_dev.reshape(LAYERS, NUM_PAGES, PS, h * hd)
+        pv_dev = pv_dev.reshape(LAYERS, NUM_PAGES, PS, h * hd)
     return q, pk[LAYER], pv[LAYER], pk_dev, pv_dev, tables, lengths, scales
 
 
 def _paged_oracle(q, pk, pv, tables, lengths):
     batch, pages = tables.shape
-    gk = pk[tables].reshape(batch, pages * PS, H, HD).astype(np.float64)
-    gv = pv[tables].reshape(batch, pages * PS, H, HD).astype(np.float64)
+    gk = pk[tables].reshape(batch, pages * PS, *pk.shape[2:]).astype(np.float64)
+    gv = pv[tables].reshape(batch, pages * PS, *pv.shape[2:]).astype(np.float64)
     s = np.einsum("bhd,bkhd->bhk", q.astype(np.float64), gk)
     mask = np.arange(pages * PS)[None, :] < lengths[:, None]
     s = np.where(mask[:, None, :], s, -np.inf)
@@ -91,7 +98,9 @@ def _paged_oracle(q, pk, pv, tables, lengths):
     return out, l
 
 
-def _paged_case(impl, pool="bf16", lora=False):
+def _paged_case(impl, pool="bf16", lora=False, h=H, hd=HD):
+    d = h * hd
+
     def run():
         import jax
         import jax.numpy as jnp
@@ -102,7 +111,7 @@ def _paged_case(impl, pool="bf16", lora=False):
         rng = np.random.default_rng(0)
         pool_dtype = {"bf16": jnp.bfloat16, "f32": jnp.float32, "int8": jnp.int8}[pool]
         q, pk, pv, pk_dev, pv_dev, tables, lengths, scales = _paged_inputs(
-            rng, pool_dtype, impl)
+            rng, pool_dtype, impl, h, hd)
         kw = {}
         if scales is not None:
             kw["kv_scales"] = tuple(jnp.asarray(s) for s in scales)
@@ -110,13 +119,13 @@ def _paged_case(impl, pool="bf16", lora=False):
         q_eff = q  # what the oracle attends with
         if lora:
             rank, slots = 8, 4
-            x = rng.normal(size=(SLOTS, D)).astype(np.float32)
-            a = rng.normal(size=(LAYERS, slots, D, rank)).astype(np.float32) * 0.05
-            b = rng.normal(size=(LAYERS, slots, rank, 3 * D)).astype(np.float32) * 0.05
+            x = rng.normal(size=(SLOTS, d)).astype(np.float32)
+            a = rng.normal(size=(LAYERS, slots, d, rank)).astype(np.float32) * 0.05
+            b = rng.normal(size=(LAYERS, slots, rank, 3 * d)).astype(np.float32) * 0.05
             a[:, 0] = 0.0  # slot 0 = no adapter
             b[:, 0] = 0.0
             idx = (np.arange(SLOTS) % slots).astype(np.int32)
-            q_scale = HD ** -0.5
+            q_scale = hd ** -0.5
             kw["lora"] = (
                 jnp.asarray(x), jnp.asarray(np.swapaxes(a, -1, -2)),
                 jnp.asarray(b), jnp.asarray(idx), q_scale,
@@ -124,7 +133,7 @@ def _paged_case(impl, pool="bf16", lora=False):
             delta_ref = np.einsum("bd,bdr,bre->be", x, a[LAYER][idx], b[LAYER][idx])
             # the kernel receives the UNADAPTED pre-scaled q and folds
             # the delta's q third itself
-            q_eff = q + q_scale * delta_ref[:, :D].reshape(SLOTS, H, HD)
+            q_eff = q + q_scale * delta_ref[:, :d].reshape(SLOTS, h, hd)
         fn = jax.jit(lambda *a_: paged_attention_decode(
             *a_, layer=LAYER, page_size=PS, **kw))
         outs = fn(jnp.asarray(q), pk_dev, pv_dev, jnp.asarray(tables),
@@ -217,6 +226,11 @@ CASES = {
     "paged_stream_f32": _paged_case("stream", pool="f32"),
     "paged_stream_int8kv": _paged_case("stream", pool="int8"),
     "paged_stream_lora": _paged_case("stream", lora=True),
+    # the benchmark's geometries: GPT-2-large, OLMoE
+    "paged_stream_bf16_20x64": _paged_case("stream", h=20, hd=64),
+    "paged_stream_bf16_16x128": _paged_case("stream", h=16, hd=128),
+    "paged_stream_int8kv_20x64": _paged_case("stream", pool="int8", h=20, hd=64),
+    "paged_stream_lora_20x64": _paged_case("stream", lora=True, h=20, hd=64),
     "paged_grid_bf16": _paged_case("grid"),
     "paged_grid_int8kv": _paged_case("grid", pool="int8"),
     "flash_256": _flash_case(256, causal=False),
@@ -233,7 +247,12 @@ def main(argv=None) -> int:
     ap.add_argument("--interpret", action="store_true",
                     help="rehearse on a non-TPU backend (interpret mode)")
     ap.add_argument("--only", nargs="*", default=None)
+    ap.add_argument("--root", default=ROOT,
+                    help="checkout to import seldon_core_tpu from")
+    ap.add_argument("--out", default=os.path.join(
+        ROOT, "chiprun_out", "kernel_probe.json"))
     args = ap.parse_args(argv)
+    sys.path.insert(0, os.path.abspath(args.root))
 
     from seldon_core_tpu.utils.compile_cache import configure_compile_cache
 
@@ -247,7 +266,8 @@ def main(argv=None) -> int:
               "(--interpret rehearses the script on CPU)", file=sys.stderr)
         return 2
 
-    record = {"device": device, "jax": jax.__version__, "cases": {}}
+    record = {"device": device, "jax": jax.__version__,
+              "root": os.path.abspath(args.root), "cases": {}}
     failed = 0
     for name, fn in CASES.items():
         if args.only and name not in args.only:
@@ -267,9 +287,8 @@ def main(argv=None) -> int:
             brief["error"] = brief["error"][:300]
         print(f"{'PASS' if entry['ok'] else 'FAIL'} {name} {json.dumps(brief)}", flush=True)
 
-    out_dir = os.path.join(ROOT, "chiprun_out")
-    os.makedirs(out_dir, exist_ok=True)
-    with open(os.path.join(out_dir, "kernel_probe.json"), "w") as f:
+    os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+    with open(args.out, "w") as f:
         json.dump(record, f, indent=1)
     print(json.dumps({"device": device, "failed": failed,
                       "cases": len(record["cases"])}))

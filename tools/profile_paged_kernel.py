@@ -1,26 +1,35 @@
-"""Profile the fused Pallas paged-decode lane against the XLA gather
-lane on the raw attention step (r18, ROADMAP 1).
+"""Time the paged-decode kernel alone, at a cell's shapes, with the
+lanes' lengths drawn as the cell holds them.
 
-Times `paged_attention_decode` (kernel) vs the dense gather+softmax
-XLA program on identical pool state — N iterations inside one jit per
-arm (one dispatch, one readback, so per-call cost cannot pollute
-the per-step number) — and prints the capacity-side arithmetic next to
-the timing: HBM bytes/step at bf16 vs int8 page storage and the Mosaic
-grid-step count of each kernel impl.
+One call of ``ops/kernels.paged_attention_decode`` per layer inside a
+scan of ``--steps`` decode steps over a whole ``(layers, pages,
+page_size, heads*head_dim)`` pool — one dispatch, one readback, so the
+number is the kernel's device time and not the host's.  Printed per
+arm: time a call, a *loop slot* (lanes x table width: what the page
+loop's static trip count covers) and a *live page* (sum over lanes of
+``ceil(length / page_size)``: what the cache holds), and the share of
+the DMA roofline (bytes of the live pages, K and V, over the chip's
+819 GB/s, over the time).  If a call's time follows the slots, the loop
+computes on dead slots; if it follows the live pages, it does not
+(PERF.md §6, PR 27).
 
-Off-TPU the kernel runs in interpret mode: a correctness harness, not
-a timing one — the tool still prints the host-arithmetic terms but
-labels the timing columns accordingly.  The bench's compact
-`paged_kernel_x` gate (>= 1.5) is adjudicated from the engine-level
-`kernel_lane` blob on a TPU run, not from this micro-probe; this tool
-exists to decompose WHERE a regression lives (kernel step vs engine
-overhead) when that gate moves.
+Lengths: ``--live-lanes`` of the ``--lanes`` hold a context, the rest
+hold 0 tokens (a released slot).  ``--ctx`` is one length (``342``), a
+range drawn uniformly (``512-992``) or a list cycled over the live
+lanes (``130,342,700``).  ``--full`` adds the all-lanes-full arm
+(every lane at table width x page size) for comparison.
 
-Run:  python tools/profile_paged_kernel.py [--streams 16] [--ctx 512]
-      [--impl stream|grid] [--kv-dtype bf16|int8] [--steps 32]
+Off-TPU the kernel runs interpreted: the arithmetic is checked, the
+times mean nothing and are labelled so.
+
+Run:  python tools/profile_paged_kernel.py --geometry gpt2 --lanes 16
+      --table-pages 8 --ctx 342 --full
+      python tools/profile_paged_kernel.py --geometry olmoe --lanes 32
+      --table-pages 16 --live-lanes 4 --ctx 512-992
 """
 
 import argparse
+import json
 import os
 import sys
 import time
@@ -29,36 +38,60 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+HBM_BYTES_PER_S = 819e9  # TPU v5e (benchmarks/harness/peaks.py)
 
-def _time_arm(fn, args, steps, repeats):
-    """Best-of-N wall over a scan-of-steps jit: returns per-step us."""
+# heads, head_dim, layers: the two geometries the benchmark serves
+GEOMETRIES = {"gpt2": (20, 64, 36), "olmoe": (16, 128, 8)}
+
+
+def parse_ctx(text, live, cap, rng):
+    """The live lanes' lengths from ``--ctx``: ``N``, ``LO-HI`` (uniform
+    draw) or ``A,B,C`` (cycled), clipped to the table's capacity."""
+    if "-" in text:
+        lo, hi = (int(x) for x in text.split("-"))
+        out = rng.integers(lo, hi + 1, size=live)
+    else:
+        vals = [int(x) for x in text.split(",")]
+        out = np.asarray([vals[i % len(vals)] for i in range(live)])
+    return np.minimum(out, cap).astype(np.int32)
+
+
+def _time_arm(fn, args, calls, repeats):
+    """Best-of-N wall over one jit of ``calls`` kernel calls: us a call."""
     import jax
 
-    out = fn(*args)
-    jax.block_until_ready(out)  # compile + warm
+    jax.block_until_ready(fn(*args))  # compile + warm
     best = float("inf")
     for _ in range(repeats):
         t0 = time.perf_counter()
-        out = fn(*args)
-        jax.block_until_ready(out)
+        jax.block_until_ready(fn(*args))
         best = min(best, time.perf_counter() - t0)
-    return best / steps * 1e6
+    return best / calls * 1e6
 
 
 def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--streams", type=int, default=16)
-    ap.add_argument("--ctx", type=int, default=512)
-    ap.add_argument("--heads", type=int, default=8)
-    ap.add_argument("--head-dim", type=int, default=64)
-    ap.add_argument("--layers", type=int, default=8,
-                    help="layer count for the HBM bytes/step term "
-                    "(the micro-probe times ONE layer's attention)")
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--geometry", choices=sorted(GEOMETRIES), default="gpt2")
+    ap.add_argument("--heads", type=int, default=None)
+    ap.add_argument("--head-dim", type=int, default=None)
+    ap.add_argument("--layers", type=int, default=None)
+    ap.add_argument("--num-pages", type=int, default=513)
     ap.add_argument("--page-size", type=int, default=64)
+    ap.add_argument("--lanes", type=int, default=16)
+    ap.add_argument("--table-pages", type=int, default=8)
+    ap.add_argument("--live-lanes", type=int, default=None,
+                    help="lanes that hold a context (default: all)")
+    ap.add_argument("--ctx", default="342",
+                    help="N | LO-HI | A,B,C: cached tokens of a live lane")
+    ap.add_argument("--full", action="store_true",
+                    help="also time every lane at the table's capacity")
     ap.add_argument("--impl", choices=("stream", "grid"), default="stream")
     ap.add_argument("--kv-dtype", choices=("bf16", "int8"), default="bf16")
-    ap.add_argument("--steps", type=int, default=32)
+    ap.add_argument("--steps", type=int, default=8)
     ap.add_argument("--repeats", type=int, default=5)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--json", default=None,
+                    help="append one JSON line per arm to this file")
     args = ap.parse_args()
 
     os.environ["SELDON_TPU_PAGED_KERNEL_IMPL"] = args.impl
@@ -66,103 +99,93 @@ def main():
     import jax
     import jax.numpy as jnp
 
-    from seldon_core_tpu.models.paged import paged_hbm_accounting
     from seldon_core_tpu.ops.kernels import paged_attention_decode
 
-    B, h, hd, ps = args.streams, args.heads, args.head_dim, args.page_size
-    pages_per = -(-args.ctx // ps)
-    num_pages = B * pages_per + 1
+    g_heads, g_hd, g_layers = GEOMETRIES[args.geometry]
+    h = args.heads or g_heads
+    hd = args.head_dim or g_hd
+    layers = args.layers or g_layers
+    ps, lanes, width = args.page_size, args.lanes, args.table_pages
+    num_pages = args.num_pages
+    d = h * hd
+    live = lanes if args.live_lanes is None else args.live_lanes
     on_tpu = jax.default_backend() == "tpu"
+    rng = np.random.default_rng(args.seed)
 
-    rng = np.random.default_rng(0)
-    dt = jnp.bfloat16
-    q = jnp.asarray(rng.normal(size=(B, h, hd)), dt)
-    # ONE layer of pool, held as the whole-pool form the wrapper takes
-    # (L = 1), in the layout the impl reads: flat for stream, split for
-    # grid — the form each rests in under the engine (pool_is_flat)
-    pool_shape = ((num_pages, ps, h * hd) if args.impl == "stream"
-                  else (num_pages, ps, h, hd))
-    pk = jnp.asarray(rng.normal(size=pool_shape), dt)
-    pv = jnp.asarray(rng.normal(size=pool_shape), dt)
+    quantized = args.kv_dtype == "int8"
+    pool_dtype = jnp.int8 if quantized else jnp.bfloat16
+    shape = ((layers, num_pages, ps, d) if args.impl == "stream"
+             else (layers, num_pages, ps, h, hd))
+    key = jax.random.key(args.seed)
+    kk, kv, kq = jax.random.split(key, 3)
+    if quantized:
+        pk = jax.random.randint(kk, shape, -127, 128, jnp.int8)
+        pv = jax.random.randint(kv, shape, -127, 128, jnp.int8)
+        scales = (jnp.full((layers, num_pages), 0.01, jnp.float32),) * 2
+    else:
+        pk = jax.random.normal(kk, shape, pool_dtype)
+        pv = jax.random.normal(kv, shape, pool_dtype)
+        scales = None
+    q = jax.random.normal(kq, (lanes, h, hd), jnp.bfloat16) * hd ** -0.5
+    # distinct pages a lane, as the allocator hands them out (page 0 is
+    # the trash page); more table slots than pages wrap around
     tables = jnp.asarray(
-        1 + np.arange(B * pages_per).reshape(B, pages_per) % (num_pages - 1),
-        jnp.int32)
-    lengths = jnp.full((B,), args.ctx, jnp.int32)
-
-    kv_scales = None
-    if args.kv_dtype == "int8":
-        def quantise(pool):
-            f = pool.astype(jnp.float32).reshape(num_pages, -1)
-            amax = jnp.maximum(jnp.max(jnp.abs(f), axis=1) / 127.0, 1e-8)
-            q = jnp.clip(jnp.round(f / amax[:, None]), -127, 127)
-            return q.astype(jnp.int8).reshape(pool.shape), amax
-
-        pk, amax = quantise(pk)
-        pv, vmax = quantise(pv)
-        kv_scales = (amax, vmax)
+        1 + rng.permutation(lanes * width).reshape(lanes, width)
+        % (num_pages - 1), jnp.int32)
 
     steps = args.steps
 
     @jax.jit
-    def kernel_arm(q, pk, pv, tables, lengths):
+    def arm(q, pk, pv, tables, lengths):
         def step(c, _):
-            acc, m, el = paged_attention_decode(
-                c, pk[None], pv[None], tables, lengths, layer=0,
-                page_size=ps,
-                kv_scales=(None if kv_scales is None
-                           else tuple(s[None] for s in kv_scales)))
-            return (acc / jnp.maximum(el, 1e-9)[..., None]).astype(c.dtype), 0
+            def layer(c, li):
+                acc, _m, el = paged_attention_decode(
+                    c, pk, pv, tables, lengths, layer=li, page_size=ps,
+                    kv_scales=scales)[:3]
+                out = acc / jnp.maximum(el, 1e-9)[..., None]
+                return (0.5 * c + 0.5 * out.astype(c.dtype)), None
+            c, _ = jax.lax.scan(layer, c, jnp.arange(layers, dtype=jnp.int32))
+            return c, None
         out, _ = jax.lax.scan(step, q, None, length=steps)
         return out
 
-    @jax.jit
-    def xla_arm(q, pk, pv, tables, lengths):
-        def step(c, _):
-            gk = pk[tables].reshape(B, pages_per * ps, h, hd)
-            gv = pv[tables].reshape(B, pages_per * ps, h, hd)
-            if kv_scales is not None:
-                gk = (gk.astype(jnp.float32)
-                      * kv_scales[0][tables].reshape(B, pages_per, 1, 1, 1)
-                      .repeat(ps, 1).reshape(B, pages_per * ps, 1, 1))
-                gv = (gv.astype(jnp.float32)
-                      * kv_scales[1][tables].reshape(B, pages_per, 1, 1, 1)
-                      .repeat(ps, 1).reshape(B, pages_per * ps, 1, 1))
-            s = jnp.einsum("bhd,bkhd->bhk", c.astype(jnp.float32),
-                           gk.astype(jnp.float32))
-            mask = jnp.arange(pages_per * ps)[None, :] < lengths[:, None]
-            s = jnp.where(mask[:, None, :], s, -jnp.inf)
-            w = jax.nn.softmax(s, axis=-1)
-            out = jnp.einsum("bhk,bkhd->bhd", w, gv.astype(jnp.float32))
-            return out.astype(c.dtype), 0
-        out, _ = jax.lax.scan(step, q, None, length=steps)
-        return out
+    cap = width * ps
+    arms = []
+    lens = np.zeros((lanes,), np.int32)
+    lens[rng.permutation(lanes)[:live]] = parse_ctx(args.ctx, live, cap, rng)
+    arms.append((f"live {live}/{lanes} ctx {args.ctx}", lens))
+    if args.full:
+        arms.append(("all lanes full", np.full((lanes,), cap, np.int32)))
 
-    arm_args = (q, pk, pv, tables, lengths)
-    kern_us = _time_arm(kernel_arm, arm_args, steps, args.repeats)
-    xla_us = _time_arm(xla_arm, arm_args, steps, args.repeats)
-
-    acct_kw = dict(
-        num_layers=args.layers, d_model=h * hd, page_size=ps,
-        ctx_len=args.ctx, streams=B, chunk_impl="pool",
-        dtype_bytes=2)
-    bf16_bytes = paged_hbm_accounting(**acct_kw)["pool_bytes"]
-    int8_bytes = paged_hbm_accounting(kv_dtype="int8", **acct_kw)["pool_bytes"]
-    grid_steps = B if args.impl == "stream" else B * pages_per
-
-    lane = "TPU" if on_tpu else "interpret (CORRECTNESS ONLY, not a timing)"
-    print(f"paged-decode kernel probe — impl={args.impl} "
-          f"kv_dtype={args.kv_dtype} lane={lane}")
-    print(f"  streams={B} ctx={args.ctx} heads={h} head_dim={hd} "
-          f"page_size={ps} pages/seq={pages_per}")
-    print(f"  kernel per-step: {kern_us:10.1f} us")
-    print(f"  XLA    per-step: {xla_us:10.1f} us")
-    print(f"  kernel_x       : {xla_us / max(kern_us, 1e-9):10.2f}x"
-          + ("" if on_tpu else "   (interpret-mode ratio — not citable)"))
-    print(f"  mosaic grid steps/launch: {grid_steps}"
-          f"  (DMA loop depth {pages_per} per lane)" )
-    print(f"  HBM pool bytes ({args.layers}L model): "
-          f"bf16 {bf16_bytes:,}  int8 {int8_bytes:,}  "
-          f"ratio {bf16_bytes / max(int8_bytes, 1):.2f}x")
+    lane_note = "TPU" if on_tpu else "interpret (CORRECTNESS ONLY, not a timing)"
+    print(f"paged-decode kernel — impl={args.impl} kv={args.kv_dtype} "
+          f"{h}x{hd} layers={layers} pool={num_pages}x{ps} "
+          f"table={lanes}x{width} lane={lane_note}")
+    page_bytes = 2 * ps * d * np.dtype(pool_dtype).itemsize
+    for name, lens in arms:
+        us = _time_arm(arm, (q, pk, pv, tables, jnp.asarray(lens)),
+                       steps * layers, args.repeats)
+        slots = lanes * width
+        pages = int(np.sum(-(-lens // ps)))
+        roof = pages * page_bytes / HBM_BYTES_PER_S * 1e6
+        rec = {
+            "arm": name, "geometry": f"{h}x{hd}", "impl": args.impl,
+            "kv": args.kv_dtype, "lanes": lanes, "table_pages": width,
+            "live_lanes": int((lens > 0).sum()),
+            "tokens": int(lens.sum()), "loop_slots": slots,
+            "live_pages": pages, "us_per_call": round(us, 2),
+            "us_per_slot": round(us / slots, 4),
+            "us_per_live_page": round(us / max(pages, 1), 4),
+            "dma_roofline_pct": round(100.0 * roof / us, 2),
+            "on_tpu": on_tpu,
+        }
+        print(f"  {name:28s} {us:9.1f} us a call | {us / slots:7.3f} us a "
+              f"slot ({slots}) | {us / max(pages, 1):7.3f} us a live page "
+              f"({pages}) | {100.0 * roof / us:5.1f} % of the DMA roofline")
+        if args.json:
+            os.makedirs(os.path.dirname(os.path.abspath(args.json)), exist_ok=True)
+            with open(args.json, "a") as f:
+                f.write(json.dumps(rec) + "\n")
 
 
 if __name__ == "__main__":
