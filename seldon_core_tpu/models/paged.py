@@ -48,13 +48,12 @@ logger = logging.getLogger(__name__)
 
 def paged_kernel_mode() -> str:
     """The ``SELDON_TPU_PAGED_KERNEL`` env value ("0" | "1" | "auto" |
-    "force") — the ONE place its vocabulary lives.  The block's kernel
-    gate, the pool-layout decision (:func:`pool_is_flat`) and the
-    engine's chunk-impl auto-select all read through here, so a new
-    mode string cannot leave the three silently disagreeing.  Since the
-    r18 default flip the unset value is "auto": the kernel lane is the
-    production decode path on single-chip TPU backends, and "0"
-    restores the XLA gather lane byte-for-byte."""
+    "force") — the ONE place its vocabulary lives.  The LM's kernel
+    gate and the engine's chunk-impl auto-select both read through
+    here, so a new mode string cannot leave them silently disagreeing.
+    Since the r18 default flip the unset value is "auto": the kernel
+    lane is the production decode path on single-chip TPU backends, and
+    "0" restores the XLA gather lane byte-for-byte."""
     return _knobs.raw("SELDON_TPU_PAGED_KERNEL", "auto")
 
 
@@ -81,23 +80,31 @@ def paged_kernel_requested(mode: Optional[str] = None) -> bool:
     return False
 
 
-def paged_kernel_static_eligible(mode: str, mesh_absent: bool, dtype) -> bool:
-    """The STATIC half of the pallas decode-kernel gate, shared by the
-    block's trace-time ``use_kernel`` and the engine's chunk-impl
-    auto-select so the two cannot drift: requested by env (explicitly
-    or via the "auto" default on TPU), no TP mesh (GSPMD can't
-    partition the pallas call), a bf16 or f32 pool (f32 is the
-    exactness lane the kernel-parity tests pin), and a TPU backend
-    unless forced (interpret mode).  The block adds its trace-local
-    terms (decode step, the pool in its impl's layout) on top."""
+def paged_kernel_static_eligible(mode: str, mesh_absent: bool, dtype,
+                                 heads: int, head_dim: int) -> bool:
+    """THE pallas decode-kernel gate, shared by the LM's trace-time
+    choice of lane and the engine's chunk-impl auto-select so the two
+    cannot drift: requested by env (explicitly or via the "auto"
+    default on TPU), no TP mesh (GSPMD can't partition the pallas
+    call), a bf16 or f32 pool (f32 is the exactness lane the
+    kernel-parity tests pin), a TPU backend unless forced (interpret
+    mode), and — where Mosaic compiles it — a 128-aligned ``heads *
+    head_dim``: the kernel DMAs ``(page_size, heads * head_dim)`` page
+    slices out of HBM and Mosaic wants that minor dim in whole lane
+    tiles (the interpreter takes any width).  A replica it turns down
+    serves the ring chunk and the XLA gather.  The block adds only its
+    trace-local term (a decode step) on top."""
     import jax
     import jax.numpy as jnp
+
+    from seldon_core_tpu.ops import kernels
 
     return (
         paged_kernel_requested(mode)
         and mesh_absent
         and dtype in (jnp.bfloat16, jnp.float32)
         and (mode == "force" or jax.default_backend() == "tpu")
+        and ((heads * head_dim) % 128 == 0 or kernels.interpret_mode())
     )
 
 
@@ -264,15 +271,14 @@ def _build_modules():
             # returns (x, k, v), and a routed spec's assignment
             # histogram int32[E] as a fourth value
             # pk/pv + layer: two forms, picked by the LM.  ``layer`` an
-            # int — the kernel lane's: pk/pv are the WHOLE pools, flat
-            # (L, num_pages, ps, d) or (grid impl) split (L, num_pages,
-            # ps, h, hd); the decode kernel addresses (layer, page) in
-            # them, the gather reads pk[layer, tables], lora/kv_scales
-            # are the whole (L, ...) tables, and a flat pool gets its
-            # K/V back flat (B, L, d).  ``layer=None`` — every other
-            # lane's, traced exactly as before PR 25: pk/pv are ONE
-            # layer, (num_pages, ps, d) or (num_pages, ps, h, hd); the
-            # gather below reshapes either to (B, cache_len, h, hd)
+            # int — the kernel lane's: pk/pv are the WHOLE pools
+            # (L, num_pages, ps, d); the decode kernel addresses
+            # (layer, page) in them, the gather reads pk[layer, tables],
+            # lora/kv_scales are the whole (L, ...) tables, and K/V come
+            # back flat (B, L, d).  ``layer=None`` — every other lane's,
+            # traced exactly as before PR 25: pk/pv are ONE layer
+            # (num_pages, ps, d), which the gather below reshapes to
+            # (B, cache_len, h, hd), and K/V come back (B, L, h, hd)
             # block_tables: (B, P) int32, or a TUPLE of per-bucket
             # tables ((B0, P0), (B1, P1), ...) with sum(Bb) == B — the
             # r6 length-bucketed gather: lanes arrive bucket-sorted and
@@ -305,20 +311,11 @@ def _build_modules():
             # vs-kernel measurements that kept it opt-in predate the
             # streaming DMA rework; SELDON_TPU_PAGED_KERNEL=0 restores
             # the XLA gather lane byte-for-byte
+            # the LM hands over the whole pool only where the kernel
+            # lane serves (paged_kernel_static_eligible); what is left
+            # is that this call is a decode step
             whole = layer is not None
             use_kernel = seg_len == 1 and whole
-            if use_kernel:
-                from seldon_core_tpu.ops.kernels import paged_kernel_impl
-
-                # the LM hands over the whole pool only where the kernel
-                # lane serves (no TP mesh; env, dtype, backend — the
-                # shared static predicate); what is left is that the
-                # pool rests in the layout the serving impl reads
-                # (pool_is_flat makes the same choice): flat for the
-                # stream kernel's (ps, h*hd) page DMA, split for the
-                # grid impl's BlockSpecs
-                kernel_impl = paged_kernel_impl(heads, head_dim)
-                use_kernel = pk.ndim == (4 if kernel_impl == "stream" else 5)
             # the kernel indexes the whole (L, ...) factor pools and
             # scale tables itself; everything else reads this layer's
             lora_pools, scale_tables = lora, kv_scales
@@ -327,19 +324,15 @@ def _build_modules():
                         for t, ab in lora.items()}
             if whole and kv_scales is not None:
                 kv_scales = (kv_scales[0][layer], kv_scales[1][layer])
-            # r18: the per-lane qkv LoRA BGMV folds INTO the stream
-            # kernel launch (the slot-index gather rides the scalar
+            # r18: the per-lane qkv LoRA BGMV folds INTO the kernel
+            # launch (the slot-index gather rides the scalar
             # prefetch next to the block tables) — one fused program
             # instead of kernel + two einsums.  Sound without further
             # care because this model applies no RoPE between the qkv
             # projection and attention (learned positional embeddings
             # add at the LM level), so the low-rank delta is linear in
-            # the projection output.  Grid impl keeps the outside-
-            # kernel einsum path.
-            fold_qkv = (
-                use_kernel and lora is not None and "qkv" in lora
-                and kernel_impl == "stream"
-            )
+            # the projection output.
+            fold_qkv = use_kernel and lora is not None and "qkv" in lora
 
             spec = self.spec
 
@@ -360,7 +353,7 @@ def _build_modules():
             y = _norm(spec, "attn_norm")(x)
             qkv = _proj("qkv", 3 * d_model, y)
             q, k, v = jnp.split(qkv, 3, axis=-1)
-            # a flat whole pool takes its K/V as the projection left
+            # the whole pool takes its K/V as the projection left
             # them: (B, L, h, hd) -> (B, L, d) is a re-lay on the chip
             # ((20, 64) minor dims do not tile like 1280), so handing
             # the split form to write_kv cost a copy per page block
@@ -380,15 +373,13 @@ def _build_modules():
                 # materialises.  The current token merges via the flash
                 # rule.  Under the bucketed gather each bucket is one
                 # kernel call at its own table width.  Since PR 27 the
-                # stream kernel's page loop runs each lane's
+                # kernel's page loop runs each lane's
                 # ceil(length / page_size) pages and an empty lane none
                 # (before, it ran the table's width for every lane and
                 # discarded the rest: 1.7 us a slot on the v5e, PERF.md
-                # §6), so a bucket's width costs the stream impl
-                # nothing; the grid impl still pays a grid step per
-                # table slot, which bucketing trims.  NUMERIC
-                # REGIME: the kernel scores in f32 where the gather path
-                # scores in bf16, so on hardware a kernel-decode engine
+                # §6), so a bucket's width costs the kernel nothing.
+                # NUMERIC REGIME: the kernel scores in f32 where the
+                # gather path scores in bf16, so a kernel-decode engine
                 # and a gather-path engine (e.g. a speculative verify
                 # program) can break argmax ties differently — each lane
                 # is deterministic, the f32 exactness lanes always use
@@ -485,10 +476,10 @@ def _build_modules():
                 for tb in tables:
                     nb = tb.shape[0]
                     sl = slice(off, off + nb)
-                    # (nb, P, ps, h, hd) split / (nb, P, ps, d) flat.  A
-                    # whole pool is indexed (layer, page) in ONE gather:
-                    # pk[layer][tb] would cut the layer out first, and
-                    # XLA does not fuse that slice into the gather
+                    # (nb, P, ps, d).  A whole pool is indexed (layer,
+                    # page) in ONE gather: pk[layer][tb] would cut the
+                    # layer out first, and XLA does not fuse that slice
+                    # into the gather
                     gk = pk[layer, tb] if whole else pk[tb]
                     gv = pv[layer, tb] if whole else pv[tb]
                     pages_per, page_size = gk.shape[1], gk.shape[2]
@@ -498,7 +489,7 @@ def _build_modules():
                         # fetch — one f32 scale per gathered page,
                         # broadcast over its (ps, ...) token block
                         sk_l, sv_l = kv_scales
-                        bshape = (nb, pages_per) + (1,) * (gk.ndim - 2)
+                        bshape = (nb, pages_per, 1, 1)
                         gk = (
                             gk.astype(jnp.float32) * sk_l[tb].reshape(bshape)
                         ).astype(self.dtype)
@@ -537,7 +528,7 @@ def _build_modules():
 
             x = x + _proj("attn_proj", d_model, attn)
             x, hist = _ffn(self, x, _proj, token_mask)
-            if whole and pk.ndim == 4:
+            if whole:
                 k, v = k_flat, v_flat
             return (x, k, v, *hist)
 
@@ -734,7 +725,8 @@ def _build_modules():
             # the pool, in any program of that engine.  Every other lane
             # slices here, as before PR 25, and lowers unchanged.
             whole = self.decode_kernel and paged_kernel_static_eligible(
-                paged_kernel_mode(), True, self.dtype
+                paged_kernel_mode(), True, self.dtype,
+                self.num_heads, self.d_model // self.num_heads,
             )
             new_k, new_v, hists = [], [], []
             for i in range(self.num_layers):
@@ -787,39 +779,6 @@ def get_chunk_lm_class():
     return _MODULES[2]
 
 
-def pool_is_flat(mesh=None, *, num_heads: int, head_dim: int) -> bool:
-    """Whether KV pools rest FLAT ``(L, pages, ps, d_model)``: the
-    layout every reader but one works in.  The stream decode kernel
-    DMAs ``(ps, h*hd)`` page slices, the XLA gather lane, the ring chunk
-    and every TP-mesh lane read the flat pool, and ``write_kv``'s
-    in-place updates tile on it.  Only the ``grid`` kernel impl's
-    BlockSpecs index the split ``(L, pages, ps, h, hd)`` form — so the
-    pool is split exactly where the kernel lane is wanted (no mesh: a
-    TP mesh turns the kernels off) AND the impl that will serve this
-    geometry (:func:`ops.kernels.paged_kernel_impl`: the env choice or
-    the Mosaic alignment fallback) is ``grid``.
-
-    What the v5e showed (PERF.md §6, PR 25): XLA lays a split bf16 pool
-    out PAGE-MINOR (``{1,4,3,2,0}``: a 64-wide minor dim would pad 2x
-    under the (8, 128) tile, so the 513 pages become the minor dim and
-    nothing is padded) — one page is then strided across its whole
-    layer, a ``write_kv`` update cost 0.16 ms (decode token) or 8.4-9.7
-    ms (prefill page block), and feeding the stream kernel meant slicing
-    a layer out (84 MB, 2.4 ms) and re-laying it page-major (another
-    84 MB, 0.23 ms) per layer per decode step: 69-80 % of device time
-    at GPT-2-large size.  At rest in the reader's layout none of that
-    runs, and the same writes cost 7 and 15-18 microseconds.
-
-    ONE shared decision for every lane (PagedEngine and the speculative
-    _PagedState must agree, or cross-lane bit-equality breaks on
-    layout)."""
-    if mesh is not None or not paged_kernel_requested():
-        return True
-    from seldon_core_tpu.ops.kernels import paged_kernel_impl
-
-    return paged_kernel_impl(num_heads, head_dim) != "grid"
-
-
 def kv_split(pool):
     """Split a pool argument into ``(pages, scales)`` — the r18 int8
     bundle is a 2-tuple ``(int8 pages, f32 per-page scales)``; a bare
@@ -852,8 +811,9 @@ def kv_scales_arg(sk, sv):
 
 def write_kv(pk, pv, new_k, new_v, block_tables, start, valid, *, page_size, max_len,
              from_zero: bool = False):
-    """Write one call's K/V — ``(layers, B, L, d)`` flat, or ``(layers,
-    B, L, h, hd)`` split — into a paged pool, in place.
+    """Write one call's K/V — ``(layers, B, L, d)``, or ``(layers, B,
+    L, h, hd)`` as the gather lane and the ring chunk still hand them
+    over — into the paged pool ``(layers, pages, ps, d)``, in place.
 
     ``start``: (B,) absolute position of each row's first token;
     invalid lanes are redirected to trash page 0.  Shared by the
@@ -876,15 +836,16 @@ def write_kv(pk, pv, new_k, new_v, block_tables, start, valid, *, page_size, max
       seg_len x rows unrolled.
 
     In place is not the same as cheap: what a DUS costs is set by the
-    pool's layout.  On the FLAT pool an update is ``[L, 1, 1, d]`` or
+    pool's layout.  On this pool an update is ``[L, 1, 1, d]`` or
     ``[L, 1, ps, d]`` against a page-major ``(…, ps, d)`` tiling and
     touches L short runs: 7 us a decode token, 15-18 us a page block on
-    the v5e at GPT-2-large size.  On the SPLIT pool the v5e's layout is
-    page-minor (:func:`pool_is_flat`), every element of the update
-    lands in a tile of its own, and one update cost 0.16 ms (decode
-    token) or 8.4-9.7 ms (page block) — 37-47 % of device time before
-    PR 25 (PERF.md §5, §6).  New K/V should arrive in
-    the pool's own form: the flat pool's block hands them back flat,
+    the v5e at GPT-2-large size.  A pool split ``(…, ps, h, hd)``, as
+    it rested until PR 25, XLA laid out page-minor on the v5e (a
+    64-wide minor dim would pad 2x under the (8, 128) tile): every
+    element of an update landed in a tile of its own, and one update
+    cost 0.16 ms (decode token) or 8.4-9.7 ms (page block) — 37-47 % of
+    device time (PERF.md §6, PR 25).  New K/V should arrive in the
+    pool's own form: the kernel lane's block hands them back flat,
     because the ``(h, hd) -> d`` reshape done here is a re-lay (a copy
     per page block) on the chip, not a free collapse.
     """
@@ -903,15 +864,12 @@ def write_kv(pk, pv, new_k, new_v, block_tables, start, valid, *, page_size, max
         )
         return (pk_pages, sk), (pv_pages, sv)
 
-    # Two pool storage layouts: FLAT ``(L, pages, ps, d_model)``, where
-    # every lane but one rests, and the 5-d split layout of the grid
-    # kernel impl (:func:`pool_is_flat`).  A lane that hands over split
-    # K/V for a flat pool (every lane but the kernel lane's) has them
-    # merged here — logically contiguous, a re-lay on the chip.
-    if pk.ndim == 4 and new_k.ndim == 5:
+    # A lane that hands over split K/V (every lane but the kernel
+    # lane's) has them merged here — logically contiguous, a re-lay on
+    # the chip.
+    if new_k.ndim == 5:
         new_k = new_k.reshape(*new_k.shape[:3], -1)
         new_v = new_v.reshape(*new_v.shape[:3], -1)
-    tail0 = (0,) * (pk.ndim - 3)
 
     seg_len = new_k.shape[2]
     B = new_k.shape[1]
@@ -924,10 +882,10 @@ def write_kv(pk, pv, new_k, new_v, block_tables, start, valid, *, page_size, max
                 valid[s, 0], jnp.take(block_tables[s], page_idx[s]), 0
             )
             pk = jax.lax.dynamic_update_slice(
-                pk, new_k[:, s][:, None], (0, page, offs[s]) + tail0
+                pk, new_k[:, s][:, None], (0, page, offs[s], 0)
             )
             pv = jax.lax.dynamic_update_slice(
-                pv, new_v[:, s][:, None], (0, page, offs[s]) + tail0
+                pv, new_v[:, s][:, None], (0, page, offs[s], 0)
             )
         return pk, pv
 
@@ -941,10 +899,10 @@ def write_kv(pk, pv, new_k, new_v, block_tables, start, valid, *, page_size, max
                 blen = min(page_size, seg_len - lo)
                 page = block_tables[s, j]
                 pk = jax.lax.dynamic_update_slice(
-                    pk, new_k[:, s, lo : lo + blen][:, None], (0, page, 0) + tail0
+                    pk, new_k[:, s, lo : lo + blen][:, None], (0, page, 0, 0)
                 )
                 pv = jax.lax.dynamic_update_slice(
-                    pv, new_v[:, s, lo : lo + blen][:, None], (0, page, 0) + tail0
+                    pv, new_v[:, s, lo : lo + blen][:, None], (0, page, 0, 0)
                 )
         return pk, pv
 
@@ -959,10 +917,10 @@ def write_kv(pk, pv, new_k, new_v, block_tables, start, valid, *, page_size, max
                 valid[s, t], jnp.take(block_tables[s], page_idx[s, t]), 0
             )
             pk = jax.lax.dynamic_update_slice(
-                pk, new_k[:, s, t][:, None, None], (0, page, offs[s, t]) + tail0
+                pk, new_k[:, s, t][:, None, None], (0, page, offs[s, t], 0)
             )
             pv = jax.lax.dynamic_update_slice(
-                pv, new_v[:, s, t][:, None, None], (0, page, offs[s, t]) + tail0
+                pv, new_v[:, s, t][:, None, None], (0, page, offs[s, t], 0)
             )
     return pk, pv
 
@@ -993,42 +951,35 @@ def _write_kv_int8(pk, sk, pv, sv, new_k, new_v, block_tables, start, valid, *,
     import jax
     import jax.numpy as jnp
 
-    if pk.ndim == 4 and new_k.ndim == 5:
+    if new_k.ndim == 5:
         new_k = new_k.reshape(*new_k.shape[:3], -1)
         new_v = new_v.reshape(*new_v.shape[:3], -1)
-    tail0 = (0,) * (pk.ndim - 3)
-    tail_shape = pk.shape[3:]
-    L = pk.shape[0]
+    L, d = pk.shape[0], pk.shape[3]
 
     def _quant(pagef):
-        # pagef: (L, 1, ps, *tail) f32 — one scale per LAYER (the page
+        # pagef: (L, 1, ps, d) f32 — one scale per LAYER (the page
         # axis is the sliced singleton)
-        amax = jnp.max(jnp.abs(pagef), axis=tuple(range(1, pagef.ndim)))
+        amax = jnp.max(jnp.abs(pagef), axis=(1, 2, 3))
         scale = jnp.maximum(amax / 127.0, 1e-8)  # (L,)
         q = jnp.clip(
-            jnp.round(pagef / scale.reshape((L,) + (1,) * (pagef.ndim - 1))),
-            -127, 127,
+            jnp.round(pagef / scale.reshape(L, 1, 1, 1)), -127, 127,
         ).astype(jnp.int8)
         return q, scale
 
     def _rmw_token(pool, scales, tok, page, off):
-        # tok: (L, *tail) f32 — requant one page with ``tok`` at ``off``
+        # tok: (L, d) f32 — requant one page with ``tok`` at ``off``
         oldq = jax.lax.dynamic_slice(
-            pool, (0, page, 0) + tail0, (L, 1, page_size) + tail_shape
+            pool, (0, page, 0, 0), (L, 1, page_size, d)
         )
         olds = jax.lax.dynamic_slice(scales, (0, page), (L, 1))
-        pagef = oldq.astype(jnp.float32) * olds.reshape(
-            (L, 1, 1) + (1,) * len(tail_shape)
-        )
-        live = (jnp.arange(page_size) < off).reshape(
-            (1, 1, page_size) + (1,) * len(tail_shape)
-        )
+        pagef = oldq.astype(jnp.float32) * olds.reshape(L, 1, 1, 1)
+        live = (jnp.arange(page_size) < off).reshape(1, 1, page_size, 1)
         pagef = jnp.where(live, pagef, 0.0)
         pagef = jax.lax.dynamic_update_slice(
-            pagef, tok[:, None, None], (0, 0, off) + tail0
+            pagef, tok[:, None, None], (0, 0, off, 0)
         )
         q, scale = _quant(pagef)
-        pool = jax.lax.dynamic_update_slice(pool, q, (0, page, 0) + tail0)
+        pool = jax.lax.dynamic_update_slice(pool, q, (0, page, 0, 0))
         scales = jax.lax.dynamic_update_slice(
             scales, scale[:, None], (0, page)
         )
@@ -1055,7 +1006,7 @@ def _write_kv_int8(pk, sk, pv, sv, new_k, new_v, block_tables, start, valid, *,
                         blk = jnp.pad(blk, pad)
                     q, scale = _quant(blk)
                     pool = jax.lax.dynamic_update_slice(
-                        pool, q, (0, page, 0) + tail0
+                        pool, q, (0, page, 0, 0)
                     )
                     scales = jax.lax.dynamic_update_slice(
                         scales, scale[:, None], (0, page)
@@ -1123,12 +1074,10 @@ def paged_hbm_accounting(
     Terms, each measured in earlier rounds rather than assumed:
 
     * **pool (at rest)** — pages x page_size x d_model x 2 (K+V) x
-      layers: logical bytes in either layout.  The v5e holds the split
-      (heads, head_dim) pool unpadded too — it lays it out page-minor
-      instead of padding the 64-wide minor dim (``hbm_peak_gib`` 10.33
-      = f32 weights + their bf16 cast + 6.05 GB of pool; PERF.md §6,
-      PR 25) — so the 2.0x this term once charged the split pool is
-      gone.
+      layers: the logical bytes of the ``(layers, pages, page_size,
+      d_model)`` pool, which the v5e holds unpadded (``hbm_peak_gib``
+      8.92 = f32 weights + their bf16 cast + 6.05 GB of pool; PERF.md
+      §4, ledger PR 25).
     * **donated vs copied** — the chunk program donates pk/pv
       (``donate_argnums``), so exactly ONE pool copy is live during a
       chunk; without donation XLA keeps input AND output pools and the
@@ -1938,28 +1887,24 @@ class PagedEngine:
         # decode-chunk twin: pool-free attention over a once-per-chunk
         # gathered context + in-chunk ring (same parameter tree — the
         # r5 fix for per-step gather cost scaling superlinearly with
-        # slots).  SELDON_TPU_CHUNK_IMPL=pool restores the legacy
-        # per-step-gather chunk for A/B.
-        import os as _os
-
+        # slots).
         self.chunk_module = get_chunk_lm_class()(
             vocab_size=vocab_size, d_model=d_model, num_layers=num_layers,
             num_heads=num_heads, max_len=max_len, dtype=dtype,
             precision=module_precision, spec=spec,
         )
-        # COUPLED ENV KNOBS: SELDON_TPU_PAGED_KERNEL opts into the
-        # pallas decode kernels, but those live in the POOL chunk's
-        # per-step attention — the default ring chunk never reads the
-        # pool per step, so with CHUNK_IMPL=ring the kernel opt-in has
-        # ZERO speed effect.  Unset CHUNK_IMPL therefore auto-selects
-        # the pool impl when the kernel opt-in can actually fire — same
-        # eligibility terms as the LM's gate (bf16/f32, no TP mesh, TPU
-        # backend unless forced); a requested-but-ineligible kernel
-        # keeps the ring chunk and says why.  An EXPLICIT ring choice
-        # wins but is warned about.
+        # TWO AXES pick the decode lane.  SELDON_TPU_PAGED_KERNEL asks
+        # for the pallas decode kernel; paged_kernel_static_eligible
+        # says whether this replica can run it.  SELDON_TPU_CHUNK_IMPL
+        # picks the chunk: the kernel lives in the POOL chunk's per-step
+        # attention, the ring chunk never reads the pool per step — so
+        # unset, it follows the kernel (pool where eligible, ring
+        # elsewhere, with a WARN if the kernel was asked for by name),
+        # and an explicit ring beside an explicit kernel request wins
+        # and is warned about.
         kernel_mode = paged_kernel_mode()
         kernel_eligible = paged_kernel_static_eligible(
-            kernel_mode, mesh is None, dtype
+            kernel_mode, mesh is None, dtype, num_heads, head_dim
         )
         self._chunk_impl = _knobs.raw("SELDON_TPU_CHUNK_IMPL", "")
         if not self._chunk_impl:
@@ -1976,8 +1921,9 @@ class PagedEngine:
                 # that cannot fire deserves the WARN
                 logger.warning(
                     "SELDON_TPU_PAGED_KERNEL=%s requested but the kernel "
-                    "cannot run here (needs bf16/f32, no TP mesh, and a TPU "
-                    "backend unless force) — keeping the ring chunk",
+                    "cannot run here (needs bf16/f32, no TP mesh, a TPU "
+                    "backend unless force, and on a TPU heads * head_dim "
+                    "in multiples of 128) — keeping the ring chunk",
                     kernel_mode,
                 )
         elif paged_kernel_explicit(kernel_mode) and self._chunk_impl == "ring":
@@ -2004,35 +1950,13 @@ class PagedEngine:
                 "are '1' (disable) and '2' (default)"
             )
         self._ctx_buckets = int(buckets_env)
-        # pool storage layout: FLAT (L, pages, ps, d_model) wherever
-        # its readers work in that form — the stream kernel, the XLA
-        # gather, the ring chunk, every mesh lane — and split only under
-        # the grid kernel impl, whose BlockSpecs index (h, hd).  The
-        # shared decision helper reads the impl that will SERVE this
-        # geometry, not the env wish
-        self._pool_flat = pool_is_flat(
-            mesh, num_heads=num_heads, head_dim=head_dim)
-        pool_shape = (
-            (num_layers, self.num_pages, self.page_size, d_model)
-            if self._pool_flat
-            else (num_layers, self.num_pages, self.page_size, num_heads, head_dim)
-        )
         # r18: which decode lane this replica actually runs — the
-        # kernel fires where the pool chunk invokes it (the pool rests
-        # in its impl's layout by the rule above); exported as the
+        # kernel fires where the pool chunk invokes it; exported as the
         # `kernel_active` gauge so dashboards see the lane, not just a
         # one-shot WARN
         self._kernel_active = bool(
             self._chunk_impl == "pool" and kernel_eligible
         )
-        # which kernel implementation serves this geometry (a stream
-        # request on an unaligned h*hd is swapped for grid) — reported
-        # next to kernel_active in lane_report()
-        self._kernel_impl = None
-        if self._kernel_active:
-            from seldon_core_tpu.ops.kernels import paged_kernel_impl
-
-            self._kernel_impl = paged_kernel_impl(num_heads, head_dim)
         # r18 int8 KV pool: pages rest int8 with ONE f32 scale per page
         # per k/v in a sibling (layers, num_pages) table — half the
         # pool bytes (≈2x paged_capacity_streams), dequantised
@@ -2061,17 +1985,19 @@ class PagedEngine:
         pool_dtype = jnp.int8 if self._kv_int8 else dtype
         self._pool_dtype = pool_dtype
         # tensor-parallel decode: megatron-style param shardings + the
-        # pool sharded on its heads axis (dim 3 either way — in the
-        # flat layout d_model is head-major contiguous, so sharding it
-        # at head boundaries is the same partition; created sharded,
-        # never materialised on one device); XLA inserts the ICI
+        # pool (L, pages, ps, d_model) sharded on dim 3 (d_model is
+        # head-major contiguous, so sharding it at head boundaries
+        # shards the heads; created sharded, never materialised on one
+        # device); XLA inserts the ICI
         # collectives inside the SAME compiled chunk program (the
         # scaling-book recipe — no hand-written collectives).
         # mesh=None -> plain pools
         from seldon_core_tpu.parallel.sharding import shard_decode_state
 
         self.params, self.pages_k, self.pages_v = shard_decode_state(
-            params, mesh, pool_shape=pool_shape, dtype=pool_dtype,
+            params, mesh,
+            pool_shape=(num_layers, self.num_pages, self.page_size, d_model),
+            dtype=pool_dtype,
             model_axis=model_axis, data_axis=data_axis,
             min_weight_size=shard_min_weight_size,
             num_heads=num_heads, seq_shard=self._seq_shard,
@@ -3168,17 +3094,14 @@ class PagedEngine:
                 adapter_idx = adapter_idx[perm]
 
         len0 = lengths  # frozen at chunk start: ctx mask + write-back base
-        # POOL layout: flat (L, pages, ps, d), or split (L, pages, ps,
-        # h, hd) under the grid kernel impl (pool_is_flat).
-        # WORKING-SET layout:
-        # always split — measured end-to-end, the per-step dense ctx
+        # POOL layout: (L, pages, ps, d).  WORKING-SET layout: split
+        # (…, h, hd) — measured end-to-end, the per-step dense ctx
         # reads run ~1.5x faster against the split buffer (flat ctx
         # repacked per step for the attention einsums: 13.9k vs 21.2k
         # tok/s at 128 streams), while the pool's at-rest layout only
         # matters for the once-per-chunk gather and write-back.  So:
         # flat at rest, split in flight.
-        tail = tuple(pk.shape[3:])
-        # per bucket: (L, Bb, Pb, ps, *tail) -> split (L, Bb, Cb, h, hd)
+        # per bucket: (L, Bb, Pb, ps, d) -> split (L, Bb, Cb, h, hd)
         ctx_k, ctx_v = [], []
         off = 0
         for nb, hb in buckets:
@@ -3251,8 +3174,6 @@ class PagedEngine:
         p0 = jnp.minimum(len0, self.max_len - 1) // ps  # (B,) first page idx
         off0 = jnp.minimum(len0, self.max_len - 1) % ps
 
-        tail0 = (0,) * len(tail)  # pool-rank index padding
-
         ctx_ks = ctx_k if multi else (ctx_k,)
         ctx_vs = ctx_v if multi else (ctx_v,)
         off_b = 0
@@ -3301,15 +3222,16 @@ class PagedEngine:
                     valid = (j * ps < off + em) & (em > 0)
                     page = jnp.where(
                         valid, jnp.take(table_s, p0[g] + j, mode="clip"), 0)
-                    win_k = aligned_k[:, None, j * ps:(j + 1) * ps]  # (L,1,ps,h,hd)
-                    win_v = aligned_v[:, None, j * ps:(j + 1) * ps]
-                    if len(tail) == 1:  # flat pool: merge h x hd (contiguous)
-                        win_k = win_k.reshape(L, 1, ps, -1)
-                        win_v = win_v.reshape(L, 1, ps, -1)
+                    # (L, 1, ps, h, hd) -> the pool's (L, 1, ps, d):
+                    # merge h x hd (contiguous)
+                    win_k = aligned_k[:, None, j * ps:(j + 1) * ps].reshape(
+                        L, 1, ps, -1)
+                    win_v = aligned_v[:, None, j * ps:(j + 1) * ps].reshape(
+                        L, 1, ps, -1)
                     pk = jax.lax.dynamic_update_slice(
-                        pk, win_k, (0, page, 0) + tail0)
+                        pk, win_k, (0, page, 0, 0))
                     pv = jax.lax.dynamic_update_slice(
-                        pv, win_v, (0, page, 0) + tail0)
+                        pv, win_v, (0, page, 0, 0))
                 return (pk, pv), ()
 
             (pk, pv), _ = jax.lax.scan(write_slot, (pk, pv), jnp.arange(nb))
@@ -5323,7 +5245,6 @@ class PagedEngine:
             # the disaggregation wire does
             ks = np.asarray(self.scales_k[:, idx])
             vs = np.asarray(self.scales_v[:, idx])
-        layout = "flat" if self._pool_flat else "split"
         demoted = 0
         bytes_demoted = 0
         evicted = 0
@@ -5337,7 +5258,9 @@ class PagedEngine:
                 "k": k[:, i:i + 1],
                 "v": v[:, i:i + 1],
                 "page_size": self.page_size,
-                "layout": layout,
+                # the one layout a pool has; the field stays on the
+                # wire for peers
+                "layout": "flat",
             }
             if ks is not None:
                 payload["k_scales"] = ks[:, i:i + 1]
@@ -5441,7 +5364,7 @@ class PagedEngine:
                     (stream.kv_payload or {}).get("last_logits"), np.float32
                 ).reshape(-1),
                 "page_size": self.page_size,
-                "layout": "flat" if self._pool_flat else "split",
+                "layout": "flat",
             }
             if self._kv_int8:
                 # int8 pages travel NATIVELY — the per-page scales ride
@@ -5675,7 +5598,7 @@ class PagedEngine:
                 "adapter": s.adapter,
                 "pending": s.pending,
                 "page_size": self.page_size,
-                "layout": "flat" if self._pool_flat else "split",
+                "layout": "flat",
             }
             with self._lock:
                 if self._slots[slot] is not s:
@@ -6118,15 +6041,13 @@ class PagedEngine:
         needs to tell WHERE and HOW it is served (``/health/status``):
         the mesh degrees the engine got (not what was requested), the
         chunk implementation, and whether decode attention runs the
-        Pallas kernel and which implementation of it."""
+        Pallas kernel."""
         return {
             "tp": self.tp_degree,
             "dp": self.dp_degree,
             "chunk_impl": self._chunk_impl,
-            "pool_layout": "flat" if self._pool_flat else "split",
             "kv_dtype": "int8" if self._kv_int8 else str(np.dtype(self._dtype)),
             "kernel_active": self._kernel_active,
-            "kernel_impl": self._kernel_impl,
             "pool_shard_bytes": self._pool_shard_bytes,
             # which block this replica serves, and what its weights hold
             # as they rest (paged_hbm_accounting's weight_bytes)

@@ -93,15 +93,7 @@ class _PagedState:
             if _dp > 1 and num_pages % _dp:
                 num_pages += -num_pages % _dp
         cfg = module
-        head_dim = cfg.d_model // cfg.num_heads
-        from seldon_core_tpu.models.paged import pool_is_flat
-
-        # ONE shared layout decision with PagedEngine (cross-lane
-        # bit-equality depends on both lanes picking the same pool form)
-        if pool_is_flat(mesh, num_heads=cfg.num_heads, head_dim=head_dim):
-            shape = (cfg.num_layers, num_pages, page_size, cfg.d_model)
-        else:
-            shape = (cfg.num_layers, num_pages, page_size, cfg.num_heads, head_dim)
+        shape = (cfg.num_layers, num_pages, page_size, cfg.d_model)
         # same tensor-parallel layout as PagedEngine (shared helper):
         # megatron param specs + pool sharded on heads, created sharded,
         # collectives inserted by XLA; mesh=None -> plain pools

@@ -23,12 +23,9 @@ accelerator; this is part of the TPU-first redesign.)
 from __future__ import annotations
 
 import functools
-import logging
 from typing import Tuple
 
 import numpy as np
-
-logger = logging.getLogger(__name__)
 
 # Mosaic's default scoped-VMEM limit on a v5e core; blocks are
 # double-buffered, so one grid step may hold half of it
@@ -386,72 +383,6 @@ def flash_attn_fn(block_q: int = 128, block_k: int = 128):
 # paged attention decode (flash-decoding over a paged K/V pool)
 # ---------------------------------------------------------------------------
 
-def _paged_decode_kernel(tables_ref, lens_ref, *refs, page_size,
-                         quantized=False):
-    """One (slot, page) grid step of online-softmax decode attention.
-
-    The page block arrives via a block-table-indexed BlockSpec (scalar
-    prefetch), so each grid step DMAs exactly one page from HBM —
-    the (B, P, ps, h, hd) gathered copy the XLA path materialises per
-    layer per step never exists.  acc/m/l are outputs revisited across
-    the page dimension (flash carry), emitted unnormalised for the
-    caller to merge with the current-token term.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    if quantized:
-        sk_ref, sv_ref, q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref = refs
-    else:
-        q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref = refs
-
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    length = lens_ref[b]
-    start = p * page_size
-
-    @pl.when(start < length)
-    def _step():
-        q = q_ref[0].astype(jnp.float32)          # (h, hd), pre-scaled
-        k = k_ref[0].astype(jnp.float32)          # (ps, h, hd)
-        v = v_ref[0].astype(jnp.float32)
-        if quantized:
-            # int8 pages dequantise in-register: one f32 scale per page
-            # per k/v, scalar-prefetched next to the block table
-            k = k * sk_ref[tables_ref[b, p]]
-            v = v * sv_ref[tables_ref[b, p]]
-        # Mosaic has no batched-dot lowering — broadcast-multiply-
-        # reduce on the VPU instead; the (h, ps, hd) intermediate is
-        # ~128 KB of VMEM and the page DMA dominates regardless
-        kt = k.transpose(1, 0, 2)                 # (h, ps, hd)
-        s = (q[:, None, :] * kt).sum(axis=2)      # (h, ps)
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-        s = jnp.where(pos < length, s, -jnp.inf)
-        # m/l carries are lane-padded to (h, 128) — Mosaic requires the
-        # last block dim be 128-divisible (or the full array dim);
-        # column 0 is the value, the broadcast keeps every lane equal
-        m_prev = m_ref[0, :, 0]                   # (h,)
-        l_prev = l_ref[0, :, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_new)
-        w = jnp.exp(s - m_new[:, None])           # (h, ps)
-        m_ref[0] = jnp.broadcast_to(m_new[:, None], m_ref.shape[1:])
-        l_ref[0] = jnp.broadcast_to(
-            (l_prev * alpha + w.sum(axis=1))[:, None], l_ref.shape[1:]
-        )
-        vt = v.transpose(1, 0, 2)                 # (h, ps, hd)
-        pv_dot = (w[:, :, None] * vt).sum(axis=1)  # (h, hd)
-        acc_ref[0] = acc_ref[0] * alpha[:, None] + pv_dot
-
-
 def _split3(x):
     """An f32 array as three f32 terms that sum to it EXACTLY and are
     each exactly representable in bf16 (8 significant bits a term, by
@@ -471,7 +402,7 @@ def _split3(x):
     return hi, mid, rest - mid
 
 
-def _paged_decode_kernel_stream(tables_ref, lens_ref, layer_ref, *refs,
+def _paged_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
                                 page_size, heads, head_dim, quantized=False,
                                 fold_lora=False, q_scale=1.0):
     """One slot of streaming flash-decoding: grid=(B,), the WHOLE
@@ -874,7 +805,7 @@ def _stream_decode(q, pk, pv, block_tables, lengths, layer, kv_scales, lora,
         scratch_shapes=scratch_shapes,
     )
     kernel = functools.partial(
-        _paged_decode_kernel_stream, page_size=ps, heads=h, head_dim=hd,
+        _paged_attention_kernel, page_size=ps, heads=h, head_dim=hd,
         quantized=quantized, fold_lora=fold,
         q_scale=q_scale)
     outs = pl.pallas_call(
@@ -905,174 +836,82 @@ def _stream_decode_jit():
         "quantized", "fold", "q_scale", "interpret"))
 
 
-def paged_kernel_impl(heads: int, head_dim: int) -> str:
-    """The decode-kernel implementation that will serve this geometry —
-    the env choice (``SELDON_TPU_PAGED_KERNEL_IMPL``) plus the Mosaic
-    alignment fallback: the stream kernel DMAs (ps, h*hd) page slices
-    and Mosaic requires a 128-aligned minor dim, so tiny models
-    (h*hd % 128 != 0) take the grid kernel on hardware.  Callers that
-    gate stream-only features (the in-kernel LoRA fold) resolve through
-    here so they cannot disagree with :func:`paged_attention_decode`."""
-    from seldon_core_tpu.runtime import knobs
-
-    impl = knobs.raw("SELDON_TPU_PAGED_KERNEL_IMPL", "stream")
-    if impl == "stream" and (heads * head_dim) % 128 != 0 and not interpret_mode():
-        logger.warning(
-            "paged decode kernel: stream impl needs a 128-aligned h*hd, got "
-            "%d x %d — serving this geometry with the grid impl",
-            heads, head_dim,
-        )
-        return "grid"
-    return impl
-
-
 def paged_attention_decode(q, pk, pv, block_tables, lengths, *, layer,
                            page_size, kv_scales=None, lora=None):
     """Unnormalised flash state of decode attention over one layer of a
     paged pool, addressed IN the whole pool.
 
     ``q`` (B, h, hd) — current-step queries, already scaled;
-    ``pk``/``pv`` — the WHOLE pools, in the layout the serving impl
-    reads: ``(L, num_pages, ps, h*hd)`` flat for ``stream``,
-    ``(L, num_pages, ps, h, hd)`` split for ``grid``
-    (``models/paged.pool_is_flat`` makes the same choice for the pool
-    at rest); ``layer`` — which layer of them to attend over, a python
-    int or a traced int32 scalar; ``block_tables`` (B, P); ``lengths``
-    (B,) cached token counts.  Returns ``(acc, m, l)`` f32 — merge with
-    the in-segment term via the usual flash rule.
+    ``pk``/``pv`` — the WHOLE pools, ``(L, num_pages, ps, h*hd)``;
+    ``layer`` — which layer of them to attend over, a python int or a
+    traced int32 scalar; ``block_tables`` (B, P); ``lengths`` (B,)
+    cached token counts.  Returns ``(acc, m, l)`` f32 — merge with the
+    in-segment term via the usual flash rule.
 
     Why the whole pool: on the v5e a ``pool[layer]`` slice is an 84 MB
-    copy at GPT-2-large size, and re-laying a split layer to the flat
-    ``(ps, h*hd)`` form the stream kernel DMAs is another (a minor-dims
-    reshape is NOT free under the (8, 128) tiling: it was
+    copy at GPT-2-large size, and re-laying a ``(ps, h, hd)`` page to
+    the flat ``(ps, h*hd)`` form the kernel DMAs is another (a
+    minor-dims reshape is NOT free under the (8, 128) tiling: it was
     ``copy_bf16_513_64_1280_``, 12 % of device time; PERF.md §6, PR 25).
-    The stream kernel therefore takes the pool in ``pl.ANY`` and the
-    layer as a scalar-prefetch operand — one compiled kernel for every
-    layer — and DMAs ``pool.at[layer, page]``.
+    The kernel therefore takes the pool in ``pl.ANY`` and the layer as a
+    scalar-prefetch operand — one compiled kernel for every layer — and
+    DMAs ``pool.at[layer, page]``.
 
     ``kv_scales`` (r18): ``(sk, sv)`` per-page f32 scale tables
     ``(L, num_pages)`` for an int8 pool, indexed ``[layer, page]`` like
     the pool — pages dequantise in-register inside the online-softmax
     loop (no dequantised copy of the cache ever exists in HBM).
 
-    ``lora`` (r18, stream impl only): ``(x, a_T, b, adapter_idx,
-    q_scale)`` folds the per-lane qkv BGMV delta into the same launch —
-    ``x`` (B, d) block inputs, ``a_T`` (L, slots, r, d) TRANSPOSED first
-    factors (the DMA wants the 128-aligned d minor), ``b`` (L, slots, r,
-    3d), both indexed ``[layer, slot]``, ``adapter_idx`` (B,) int32 slot
-    ids, ``q_scale`` the static 1/sqrt(hd) already applied to q.  The
-    return grows a fourth element: the raw (B, 3d) f32 delta for the
-    caller's self-term and pool write.
+    ``lora`` (r18): ``(x, a_T, b, adapter_idx, q_scale)`` folds the
+    per-lane qkv BGMV delta into the same launch — ``x`` (B, d) block
+    inputs, ``a_T`` (L, slots, r, d) TRANSPOSED first factors (the DMA
+    wants the 128-aligned d minor), ``b`` (L, slots, r, 3d), both
+    indexed ``[layer, slot]``, ``adapter_idx`` (B,) int32 slot ids,
+    ``q_scale`` the static 1/sqrt(hd) already applied to q.  The return
+    grows a fourth element: the raw (B, 3d) f32 delta for the caller's
+    self-term and pool write.
 
-    TPU-first replacement for the ``pk[layer][block_tables]`` gather in
+    TPU-first replacement for the ``pk[layer, block_tables]`` gather in
     ``PagedTransformerBlock`` (models/paged.py): the gather copies the
     whole live cache through HBM per layer per step; here pages stream
     HBM->VMEM, indexed by the scalar-prefetched block table
     (the vLLM paged-attention idea recast in pallas; reference has no
-    counterpart — it is pre-LLM).
-
-    Two implementations, selected by ``SELDON_TPU_PAGED_KERNEL_IMPL``:
-
-    * ``stream`` (default) — grid=(B,), double-buffered manual DMA;
-      a lane's loop runs its own ``ceil(length / page_size)`` pages and
-      an empty lane none, so a call's time follows the live pages
-      (0.5-0.9 us each on the v5e), not the table's width.
-    * ``grid`` — the original (B, P) grid with block-table BlockSpecs
-      over ONE layer of the split pool (sliced here, as its caller did
-      before); kept for A/B measurement (tools/profile_paged_kernel.py).
+    counterpart — it is pre-LLM).  grid=(B,), double-buffered manual
+    DMA; a lane's loop runs its own ``ceil(length / page_size)`` pages
+    and an empty lane none, so a call's time follows the live pages
+    (0.5-0.9 us each on the v5e), not the table's width
+    (:func:`_paged_attention_kernel`).  Mosaic needs a 128-aligned
+    ``h*hd`` for the page DMA: ``models/paged.paged_kernel_static_eligible``
+    keeps other geometries off this lane on hardware.
     """
-    import functools
-
-    import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
-    B, h, hd = q.shape
-    P = block_tables.shape[1]
-    ps = pk.shape[2]
-    if page_size != ps:
+    if pk.ndim != 4 or pv.ndim != 4:
         raise ValueError(
-            f"page_size={page_size} does not match the pool's page dim {ps}"
+            "paged_attention_decode reads the whole pool as a 4-d "
+            f"(layers, pages, page_size, heads * head_dim) array, got {pk.shape}"
+        )
+    if page_size != pk.shape[2]:
+        raise ValueError(
+            f"page_size={page_size} does not match the pool's page dim "
+            f"{pk.shape[2]}"
         )
 
-    quantized = kv_scales is not None
-    if quantized:
-        sk, sv = kv_scales
-        sk = jnp.asarray(sk, jnp.float32)
-        sv = jnp.asarray(sv, jnp.float32)
-
-    impl = paged_kernel_impl(h, hd)
-    if lora is not None and impl != "stream":
-        raise ValueError(
-            "paged_attention_decode: the in-kernel LoRA fold is a stream-impl "
-            f"feature but paged_kernel_impl resolved to {impl!r} — callers "
-            "must gate the fold on paged_kernel_impl(heads, head_dim)"
-        )
-
-    if impl not in ("stream", "grid"):
-        raise ValueError(
-            f"unknown SELDON_TPU_PAGED_KERNEL_IMPL {impl!r}: use 'stream' or 'grid'"
-        )
-    want_ndim = 4 if impl == "stream" else 5
-    if pk.ndim != want_ndim or pv.ndim != want_ndim:
-        raise ValueError(
-            f"paged_attention_decode: the {impl} impl reads the whole pool "
-            f"as a {want_ndim}-d array, got {pk.shape} — the pool rests in "
-            "the layout models/paged.pool_is_flat picks for the serving impl"
-        )
-
-    if impl == "stream":
-        if lora is not None:
-            *lora, q_scale = lora
-        # one jitted function for every layer's call: the chunk programs
-        # unroll the layers, and a program that holds the kernel 36 (or
-        # 72) times over traces and lowers it ONCE — the layer is a
-        # traced scalar, so the calls share one jaxpr.  Traced anew per
-        # call the kernel cost ~1.1 s a layer before any compile-cache
-        # lookup: 40 s of set-up a chunk shape at 36 layers, in every
-        # run (PERF.md §6, PR 27)
-        return _stream_decode_jit()(
-            q, pk, pv, block_tables, lengths, jnp.asarray(layer, jnp.int32),
-            (sk, sv) if quantized else None,
-            None if lora is None else tuple(lora),
-            quantized=quantized, fold=lora is not None,
-            q_scale=float(q_scale) if lora is not None else 1.0,
-            interpret=interpret_mode())
-
-    pk, pv = pk[layer], pv[layer]
-    scalar_args = [block_tables, lengths]
-    n_prefetch = 2
-    if quantized:
-        scalar_args += [sk[layer], sv[layer]]
-        n_prefetch += 2
-    lane2 = lambda b, p, *prefetch: (b, 0, 0)  # noqa: E731
-    page2 = lambda b, p, *prefetch: (prefetch[0][b, p], 0, 0, 0)  # noqa: E731
-    pad2 = lambda b, p, *prefetch: (b, 0, 0)  # noqa: E731
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,
-        grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((1, h, hd), lane2),
-            pl.BlockSpec((1, ps, h, hd), page2),
-            pl.BlockSpec((1, ps, h, hd), page2),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, h, hd), lane2),
-            pl.BlockSpec((1, h, 128), pad2),
-            pl.BlockSpec((1, h, 128), pad2),
-        ],
-    )
-    kernel = functools.partial(
-        _paged_decode_kernel, page_size=ps, quantized=quantized)
-    acc, m, l = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, h, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, h, 128), jnp.float32),
-            jax.ShapeDtypeStruct((B, h, 128), jnp.float32),
-        ],
-        interpret=interpret_mode(),
-    )(*scalar_args, q, pk, pv)
-    return acc, m[:, :, 0], l[:, :, 0]
+    if kv_scales is not None:
+        kv_scales = tuple(jnp.asarray(s, jnp.float32) for s in kv_scales)
+    q_scale = 1.0
+    if lora is not None:
+        *lora, q_scale = lora
+        lora = tuple(lora)
+    # one jitted function for every layer's call: the chunk programs
+    # unroll the layers, and a program that holds the kernel 36 (or
+    # 72) times over traces and lowers it ONCE — the layer is a
+    # traced scalar, so the calls share one jaxpr.  Traced anew per
+    # call the kernel cost ~1.1 s a layer before any compile-cache
+    # lookup: 40 s of set-up a chunk shape at 36 layers, in every
+    # run (PERF.md §6, PR 27)
+    return _stream_decode_jit()(
+        q, pk, pv, block_tables, lengths, jnp.asarray(layer, jnp.int32),
+        kv_scales, lora,
+        quantized=kv_scales is not None, fold=lora is not None,
+        q_scale=float(q_scale), interpret=interpret_mode())

@@ -146,19 +146,17 @@ def shard_decode_state(
     model_axis: str = MODEL_AXIS,
     data_axis: str = DATA_AXIS,
     min_weight_size: int = 16_384,
-    num_heads: Optional[int] = None,
+    num_heads: int,
     seq_shard: bool = True,
 ):
     """Serving-mesh layout for the paged-decode lanes: megatron param
     specs + K/V pools sharded on BOTH mesh axes.
 
-    * ``model`` axis — the heads dim (dim 3 of either layout: split
-      ``(layers, pages, page_size, heads, head_dim)`` or flat
-      ``(layers, pages, page_size, d_model)``; d_model is head-major
-      contiguous, so a head-boundary-aligned partition of dim 3 is
-      the same sharding).  ``num_heads`` carries the divisibility
-      constraint for the flat layout (dim 3's size is d_model there,
-      but shards must align to head boundaries).
+    * ``model`` axis — the heads, as dim 3 of the pool ``(layers,
+      pages, page_size, d_model)``: d_model is head-major contiguous,
+      so a head-boundary-aligned partition of it shards the heads.
+      ``num_heads`` carries the divisibility constraint (dim 3's size
+      is d_model, but shards must align to head boundaries).
     * ``data`` axis — the PAGE dim (dim 1): every data shard owns
       ``num_pages // dp`` pages of the global pool, which is both the
       throughput story (each replica group's streams write their own
@@ -204,8 +202,6 @@ def shard_decode_state(
     shape = mesh_shape(mesh)
     axis_size = shape.get(model_axis, 1)
     dp_size = shape.get(data_axis, 1)
-    if num_heads is None:
-        num_heads = pool_shape[3]
     if axis_size > 1 and num_heads % axis_size == 0:
         heads_entry = model_axis
     else:
@@ -235,9 +231,8 @@ def shard_decode_state(
                 data_axis, model_axis, num_pages, data_axis, dp_size,
             )
         pages_entry = None
-    # trailing dims default to unsharded, so this spec covers both the
-    # rank-4 flat pool and the rank-5 split pool; a 1-D model mesh
-    # yields the exact historical P(None, None, None, model) spelling
+    # a 1-D model mesh yields the exact historical
+    # P(None, None, None, model) spelling
     pool_spec = P(None, pages_entry, None, heads_entry)
     make_pool = jax.jit(
         lambda: jnp.zeros(pool_shape, dtype),

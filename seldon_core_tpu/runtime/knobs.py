@@ -166,12 +166,8 @@ ENV_KNOBS: Dict[str, Knob] = _knobs(
          "pallas decode-kernel lane ('0' | '1' | 'auto' | 'force'; "
          "default 'auto' = on for single-chip TPU backends, off "
          "elsewhere — '0' restores the XLA gather lane byte-for-byte); "
-         "the KV pool rests flat under it unless the impl is 'grid'",
-         "architecture.md §5b-septies"),
-    Knob("SELDON_TPU_PAGED_KERNEL_IMPL", "str", "stream", False,
-         "pallas decode kernel implementation ('stream' | 'grid'): "
-         "'stream' addresses (layer, page) in the whole flat pool; "
-         "'grid' (A/B only) keeps the split pool its BlockSpecs index",
+         "a replica that cannot run it (TP mesh, other dtype, or on a "
+         "TPU heads*head_dim not in multiples of 128) keeps the gather",
          "architecture.md §5b-septies"),
     Knob("SELDON_TPU_KV_DTYPE", "str", "bf16", False,
          "KV pool element dtype ('bf16' | 'int8'); int8 stores pages "

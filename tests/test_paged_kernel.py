@@ -35,26 +35,20 @@ def _dense_reference(q, pk, pv, tables, lengths):
     return jnp.einsum("bhk,bkhd->bhd", w, gv), m, w.sum(-1)
 
 
-def _whole_pool(rng, impl, num_pages, ps, h, hd):
-    """A LAYERS-deep pool as numpy (split) and as the device array the
-    serving impl reads: flat (L, pages, ps, h*hd) for stream, split for
-    grid."""
+def _whole_pool(rng, num_pages, ps, h, hd):
+    """A LAYERS-deep pool as numpy (heads apart, for the oracle) and as
+    the device array the kernel reads: (L, pages, ps, h*hd)."""
     pool = rng.normal(size=(LAYERS, num_pages, ps, h, hd)).astype(np.float32)
-    dev = jnp.asarray(pool)
-    if impl == "stream":
-        dev = dev.reshape(LAYERS, num_pages, ps, h * hd)
-    return pool, dev
+    return pool, jnp.asarray(pool).reshape(LAYERS, num_pages, ps, h * hd)
 
 
 @pytest.mark.parametrize("layer", range(LAYERS))
-@pytest.mark.parametrize("impl", ["stream", "grid"])
-def test_kernel_matches_dense_flash_state(impl, layer, monkeypatch):
-    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", impl)
+def test_kernel_matches_dense_flash_state(layer):
     rng = np.random.default_rng(0)
     B, h, hd, ps, P, num_pages = 4, 8, 64, 16, 4, 32
     q = jnp.asarray(rng.normal(size=(B, h, hd)).astype(np.float32))
-    pkn, pk = _whole_pool(rng, impl, num_pages, ps, h, hd)
-    pvn, pv = _whole_pool(rng, impl, num_pages, ps, h, hd)
+    pkn, pk = _whole_pool(rng, num_pages, ps, h, hd)
+    pvn, pv = _whole_pool(rng, num_pages, ps, h, hd)
     tables = jnp.asarray(rng.integers(1, num_pages, size=(B, P)).astype(np.int32))
     # ragged lengths incl. partial pages and a full table
     lengths = jnp.asarray(np.array([5, 16, 37, 64], np.int32))
@@ -74,14 +68,12 @@ def test_kernel_matches_dense_flash_state(impl, layer, monkeypatch):
 
 
 @pytest.mark.parametrize("layer", range(LAYERS))
-@pytest.mark.parametrize("impl", ["stream", "grid"])
-def test_kernel_zero_length_lane_is_finite(impl, layer, monkeypatch):
-    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", impl)
+def test_kernel_zero_length_lane_is_finite(layer):
     rng = np.random.default_rng(1)
     B, h, hd, ps, P, num_pages = 2, 4, 32, 8, 2, 8
     q = jnp.asarray(rng.normal(size=(B, h, hd)).astype(np.float32))
-    _, pk = _whole_pool(rng, impl, num_pages, ps, h, hd)
-    _, pv = _whole_pool(rng, impl, num_pages, ps, h, hd)
+    _, pk = _whole_pool(rng, num_pages, ps, h, hd)
+    _, pv = _whole_pool(rng, num_pages, ps, h, hd)
     tables = jnp.zeros((B, P), jnp.int32)
     lengths = jnp.asarray(np.array([0, 3], np.int32))
     acc, m, l = paged_attention_decode(
@@ -101,19 +93,17 @@ def test_kernel_zero_length_lane_is_finite(impl, layer, monkeypatch):
     (3, 9, 0, 14),     # a table wider than any lane needs
 ], ids=["ragged", "page_edges", "mostly_dead", "wide_table"])
 @pytest.mark.parametrize("layer", range(LAYERS))
-@pytest.mark.parametrize("impl", ["stream", "grid"])
-def test_kernel_matches_float64_host_oracle(impl, layer, lengths, monkeypatch):
+def test_kernel_matches_float64_host_oracle(layer, lengths):
     """Adjudicate numerics against a HOST float64 oracle, not another
     on-chip program: an on-TPU 'reference' einsum is itself bf16-rounded
     (default matmul precision), which masked a bf16-precision bug in
     the stream kernel's MXU dots on hardware (r4, docs/architecture.md
     'Decode-step cost, decomposed honestly')."""
-    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", impl)
     rng = np.random.default_rng(3)
     B, h, hd, ps, P, num_pages = 4, 8, 64, 16, 4, 32
     qn = rng.normal(size=(B, h, hd)).astype(np.float32)
-    pkn, pk = _whole_pool(rng, impl, num_pages, ps, h, hd)
-    pvn, pv = _whole_pool(rng, impl, num_pages, ps, h, hd)
+    pkn, pk = _whole_pool(rng, num_pages, ps, h, hd)
+    pvn, pv = _whole_pool(rng, num_pages, ps, h, hd)
     tn = rng.integers(1, num_pages, size=(B, P)).astype(np.int32)
     ln = np.array(lengths, np.int32)
     live = ln > 0
@@ -132,8 +122,8 @@ def test_kernel_matches_float64_host_oracle(impl, layer, lengths, monkeypatch):
         lambda *a: paged_attention_decode(*a, layer=layer, page_size=ps)
     )(jnp.asarray(qn), pk, pv, jnp.asarray(tn), jnp.asarray(ln))
     out = np.asarray(acc / l[..., None], np.float64)
-    assert float(np.max(np.abs(out[live] - ref[live]))) < 1e-4, impl
-    assert float(np.max(np.abs(np.asarray(m, np.float64)[live] - m64[live]))) < 1e-4, impl
+    assert float(np.max(np.abs(out[live] - ref[live]))) < 1e-4
+    assert float(np.max(np.abs(np.asarray(m, np.float64)[live] - m64[live]))) < 1e-4
     # an empty lane: the neutral flash state
     assert np.all(np.asarray(l)[~live] == 0.0) and np.all(np.asarray(acc)[~live] == 0.0)
 
@@ -163,9 +153,8 @@ def _run_engine(cfg, params, prompts):
 def test_engine_tokens_identical_kernel_vs_gather(monkeypatch):
     cfg, params, prompts = _lm_fixture()
 
-    def run(mode, impl="stream"):
+    def run(mode):
         monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", mode)
-        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", impl)
         # the decode kernel lives in the POOL chunk's per-step
         # attention — the default ring chunk never reads the pool per
         # step, so without this the kernel gate was never reached and
@@ -178,8 +167,7 @@ def test_engine_tokens_identical_kernel_vs_gather(monkeypatch):
     monkeypatch.delenv("SELDON_TPU_CHUNK_IMPL", raising=False)
     monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "0")
     gather, _ = _run_engine(cfg, params, prompts)
-    for impl in ("stream", "grid"):  # interpret-mode pallas on CPU
-        assert np.array_equal(gather, run("force", impl)), impl
+    assert np.array_equal(gather, run("force"))  # interpret-mode pallas on CPU
 
 
 def test_kernel_optin_autoselects_pool_chunk(monkeypatch):

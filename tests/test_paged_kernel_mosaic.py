@@ -48,7 +48,6 @@ def mosaic(monkeypatch):
     keep the persistent compile cache out of it (an entry compiled for
     a described chip cannot be read back without one)."""
     monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
-    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", "stream")
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     yield

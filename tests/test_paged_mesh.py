@@ -210,13 +210,13 @@ class TestSeqShardUnits:
                            devices=jax.devices()[:4])
 
     def test_pool_sharded_on_both_axes(self, mesh):
-        pool_shape = (1, 6, 8, 4, 8)
+        pool_shape = (1, 6, 8, 32)
         _, pk, pv = shard_decode_state(
             {}, mesh, pool_shape=pool_shape, dtype=jnp.float32, num_heads=4,
         )
         assert tuple(pk.sharding.spec) == (None, "data", None, "model")
         # one device holds pages/2 x heads/2
-        assert pk.addressable_shards[0].data.shape == (1, 3, 8, 2, 8)
+        assert pk.addressable_shards[0].data.shape == (1, 3, 8, 16)
         np.testing.assert_array_equal(np.asarray(pv), np.zeros(pool_shape))
 
     def test_indivisible_pages_replicate_page_dim_with_warn(
@@ -226,7 +226,7 @@ class TestSeqShardUnits:
             logging.WARNING, logger="seldon_core_tpu.parallel.sharding"
         ):
             _, pk, _ = shard_decode_state(
-                {}, mesh, pool_shape=(1, 5, 8, 4, 8), dtype=jnp.float32,
+                {}, mesh, pool_shape=(1, 5, 8, 32), dtype=jnp.float32,
                 num_heads=4,
             )
         assert any("num_pages=5" in r.message for r in caplog.records)
@@ -239,7 +239,7 @@ class TestSeqShardUnits:
             logging.WARNING, logger="seldon_core_tpu.parallel.sharding"
         ):
             _, pk, _ = shard_decode_state(
-                {}, mesh, pool_shape=(1, 6, 8, 4, 8), dtype=jnp.float32,
+                {}, mesh, pool_shape=(1, 6, 8, 32), dtype=jnp.float32,
                 num_heads=4, seq_shard=False,
             )
         # an explicit opt-out is not a degrade: no WARN
@@ -250,7 +250,7 @@ class TestSeqShardUnits:
     def test_one_d_model_mesh_keeps_historical_spec(self):
         mesh1d = create_mesh({"model": 2}, devices=jax.devices()[:2])
         _, pk, _ = shard_decode_state(
-            {}, mesh1d, pool_shape=(1, 6, 8, 4, 8), dtype=jnp.float32,
+            {}, mesh1d, pool_shape=(1, 6, 8, 32), dtype=jnp.float32,
             num_heads=4,
         )
         assert tuple(pk.sharding.spec) == (None, None, None, "model")

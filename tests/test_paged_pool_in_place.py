@@ -1,6 +1,7 @@
-"""The KV pool does not move (PR 25): it rests in the layout its reader
-uses, the decode kernel addresses (layer, page) in the whole pool, and
-K/V writes land in place.
+"""The KV pool does not move (PR 25): it rests ``(layers, pages,
+page_size, d_model)`` everywhere (PR 28: the one layout), the decode
+kernel addresses (layer, page) in the whole pool, and K/V writes land
+in place.
 
 Fast tier, CPU, the kernel forced (Pallas in interpret mode):
 
@@ -9,11 +10,14 @@ Fast tier, CPU, the kernel forced (Pallas in interpret mode):
   variables, and the pools a program returns come from its arguments
   through ``dynamic_update_slice`` alone;
 * the kernel against a float64 host oracle on a 3-layer pool whose
-  layers differ, per layer, impl and pool dtype (a wrong layer index is
-  a wrong answer), the in-kernel LoRA fold and the zero-length lane;
-* the one layout decision (``pool_is_flat``) and what the engine
-  reports of it under stream, grid, kernel-off and a TP mesh.
+  layers differ, per layer and pool dtype (a wrong layer index is a
+  wrong answer), the in-kernel LoRA fold and the zero-length lane;
+* the decode lane, chosen from what the code can see (mode, mesh, dtype,
+  backend, geometry) and reported by ``lane_report()``; the deleted
+  impl knob; what a 5-d KV container meets at the pool.
 """
+
+import logging
 
 import numpy as np
 import pytest
@@ -22,9 +26,13 @@ import jax
 import jax.numpy as jnp
 from jax.extend import core as jex_core
 
-from seldon_core_tpu.models.paged import PagedEngine, pool_is_flat
+from seldon_core_tpu.models import paged
+from seldon_core_tpu.models.paged import PagedEngine
 from seldon_core_tpu.models.transformer import TransformerLM
+from seldon_core_tpu.ops import kernels
 from seldon_core_tpu.ops.kernels import paged_attention_decode
+from seldon_core_tpu.runtime import knobs
+from seldon_core_tpu.runtime.component import MicroserviceError
 
 CFG = dict(vocab_size=64, d_model=32, num_layers=3, num_heads=2, max_len=256)
 LAYERS = CFG["num_layers"]
@@ -46,7 +54,6 @@ def _engine(params, **kw):
 def kernel_lane(monkeypatch):
     monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
     monkeypatch.setenv("SELDON_TPU_CHUNK_IMPL", "pool")
-    monkeypatch.delenv("SELDON_TPU_PAGED_KERNEL_IMPL", raising=False)
     monkeypatch.delenv("SELDON_TPU_KV_DTYPE", raising=False)
 
 
@@ -136,7 +143,7 @@ class TestProgramsAddressThePoolInPlace:
     ):
         eng = _engine(params)
         try:
-            assert eng._kernel_active and eng._pool_flat
+            assert eng._kernel_active and eng.pages_k.ndim == 4
             jaxpr, pools = _traced(eng._chunk_program(4, buckets),
                                    eng.chunk_example_args(buckets))
             audit = _PoolAudit(eng.pages_k.shape)
@@ -200,9 +207,9 @@ D = H * HD
 LENGTHS = np.array([0, 5, 16, 32], np.int32)  # a dead lane, a partial page, full
 
 
-def _pool(rng, impl, pool, num_pages=NUM_PAGES, h=H, hd=HD):
-    """``(what the pool stores, as float64, split; device array in the
-    impl's layout; per-page scales or None)``."""
+def _pool(rng, pool, num_pages=NUM_PAGES, h=H, hd=HD):
+    """``(what the pool stores, as float64, heads apart; the device
+    pool; per-page scales or None)``."""
     raw = rng.normal(size=(LAYERS, num_pages, PS, h, hd)).astype(np.float32)
     scales = None
     if pool == "int8":
@@ -213,9 +220,7 @@ def _pool(rng, impl, pool, num_pages=NUM_PAGES, h=H, hd=HD):
     else:
         dev = jnp.asarray(raw, {"f32": jnp.float32, "bf16": jnp.bfloat16}[pool])
         stored = np.asarray(dev.astype(jnp.float32), np.float64)
-    if impl == "stream":
-        dev = dev.reshape(LAYERS, num_pages, PS, h * hd)
-    return stored, dev, scales
+    return stored, dev.reshape(LAYERS, num_pages, PS, h * hd), scales
 
 
 def _oracle(q, pk, pv, tables, lengths):
@@ -246,24 +251,21 @@ def _check(outs, ref, lengths=LENGTHS):
 
 @pytest.mark.parametrize("layer", range(LAYERS))
 @pytest.mark.parametrize("pool", ["f32", "bf16", "int8"])
-@pytest.mark.parametrize("impl", ["stream", "grid"])
-def test_kernel_reads_its_layer_of_the_whole_pool(impl, pool, layer, monkeypatch):
-    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", impl)
+def test_kernel_reads_its_layer_of_the_whole_pool(pool, layer):
     rng = np.random.default_rng(7)
     q = rng.normal(size=(B, H, HD)).astype(np.float32)
-    pkn, pk, sk = _pool(rng, impl, pool)
-    pvn, pv, sv = _pool(rng, impl, pool)
+    pkn, pk, sk = _pool(rng, pool)
+    pvn, pv, sv = _pool(rng, pool)
     tables = rng.integers(1, NUM_PAGES, size=(B, P)).astype(np.int32)
     kw = {}
     if pool == "int8":
         kw["kv_scales"] = (jnp.asarray(sk), jnp.asarray(sv))
-    # stream: the layer is a traced scalar (one kernel for all layers)
-    layer_arg = jnp.int32(layer) if impl == "stream" else layer
+    # the layer is a traced scalar (one kernel for all layers)
     outs = jax.jit(
         lambda q, pk, pv, t, n, layer: paged_attention_decode(
             q, pk, pv, t, n, layer=layer, page_size=PS, **kw),
-        static_argnums=() if impl == "stream" else (5,),
-    )(jnp.asarray(q), pk, pv, jnp.asarray(tables), jnp.asarray(LENGTHS), layer_arg)
+    )(jnp.asarray(q), pk, pv, jnp.asarray(tables), jnp.asarray(LENGTHS),
+      jnp.int32(layer))
     _check(outs, _oracle(q, pkn[layer], pvn[layer], tables, LENGTHS))
 
 
@@ -278,12 +280,11 @@ def _lora_factors(rng, lanes, d, rank=4, slots=3):
 
 
 @pytest.mark.parametrize("layer", range(LAYERS))
-def test_lora_fold_indexes_layer_and_slot(layer, monkeypatch):
-    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", "stream")
+def test_lora_fold_indexes_layer_and_slot(layer):
     rng = np.random.default_rng(11)
     q = rng.normal(size=(B, H, HD)).astype(np.float32)
-    pkn, pk, _ = _pool(rng, "stream", "f32")
-    pvn, pv, _ = _pool(rng, "stream", "f32")
+    pkn, pk, _ = _pool(rng, "f32")
+    pvn, pv, _ = _pool(rng, "f32")
     tables = rng.integers(1, NUM_PAGES, size=(B, P)).astype(np.int32)
     x, a, b, idx = _lora_factors(rng, B, D)
     q_scale = HD ** -0.5
@@ -317,13 +318,11 @@ LOOP_EDGES = {
 @pytest.mark.parametrize("variant", ["bf16", "int8", "lora"])
 @pytest.mark.parametrize("heads,head_dim", [(20, 64), (16, 128)])
 @pytest.mark.parametrize("edge", sorted(LOOP_EDGES))
-def test_stream_kernel_pays_for_live_pages_only(edge, heads, head_dim, variant,
-                                                monkeypatch):
+def test_stream_kernel_pays_for_live_pages_only(edge, heads, head_dim, variant):
     """Both served geometries (GPT-2-large's 20 x 64: heads padded to 24
     projector rows; OLMoE's 16 x 128), with the int8 pool and with the
     in-kernel LoRA fold, against the float64 oracle at the tolerance the
     kernel has always had."""
-    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", "stream")
     width, lengths = LOOP_EDGES[edge]
     lengths = lengths.astype(np.int32)
     lanes, d, layer = len(lengths), heads * head_dim, 1
@@ -331,8 +330,8 @@ def test_stream_kernel_pays_for_live_pages_only(edge, heads, head_dim, variant,
     rng = np.random.default_rng(27)
     q = rng.normal(size=(lanes, heads, head_dim)).astype(np.float32) * head_dim ** -0.5
     pool = "int8" if variant == "int8" else "bf16"
-    pkn, pk, sk = _pool(rng, "stream", pool, num_pages, heads, head_dim)
-    pvn, pv, sv = _pool(rng, "stream", pool, num_pages, heads, head_dim)
+    pkn, pk, sk = _pool(rng, pool, num_pages, heads, head_dim)
+    pvn, pv, sv = _pool(rng, pool, num_pages, heads, head_dim)
     tables = rng.integers(1, num_pages, size=(lanes, width)).astype(np.int32)
     kw, q_eff = {}, q
     if variant == "int8":
@@ -358,12 +357,9 @@ def test_stream_kernel_pays_for_live_pages_only(edge, heads, head_dim, variant,
     _check(outs, _oracle(q_eff, pkn[layer], pvn[layer], tables, lengths), lengths)
 
 
-@pytest.mark.parametrize("impl,ndim", [("stream", 5), ("grid", 4)])
-def test_pool_in_the_other_impls_layout_is_refused(impl, ndim, monkeypatch):
-    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", impl)
-    shape = (LAYERS, NUM_PAGES, PS, H, HD) if ndim == 5 else (LAYERS, NUM_PAGES, PS, D)
-    pool = jnp.zeros(shape, jnp.float32)
-    with pytest.raises(ValueError, match="pool_is_flat"):
+def test_pool_with_its_heads_apart_is_refused():
+    pool = jnp.zeros((LAYERS, NUM_PAGES, PS, H, HD), jnp.float32)
+    with pytest.raises(ValueError, match="4-d"):
         paged_attention_decode(
             jnp.zeros((B, H, HD)), pool, pool, jnp.zeros((B, P), jnp.int32),
             jnp.asarray(LENGTHS), layer=0, page_size=PS)
@@ -399,46 +395,109 @@ def test_three_layer_engine_tokens_kernel_vs_gather(params, monkeypatch, kv):
 
 
 # ---------------------------------------------------------------------------
-# (c) the one layout decision, and what the engine reports of it
+# (c) the lane, chosen from what the code can see
 # ---------------------------------------------------------------------------
 
+# heads * head_dim: CFG's 2 x 16 = 32 is not a multiple of 128 (Mosaic
+# cannot DMA its pages; the interpreter can), 2 x 64 = 128 is
+ALIGNED = dict(CFG, d_model=128)
 
-class TestLayoutDecision:
-    GEOM = dict(num_heads=CFG["num_heads"], head_dim=16)
 
-    @pytest.mark.parametrize("mode,impl,mesh,flat", [
-        ("force", "stream", None, True),    # the stream kernel reads flat
-        ("force", "grid", None, False),     # only grid's BlockSpecs need split
-        ("1", "grid", None, False),
-        ("0", "grid", None, True),          # kernel off: the impl is moot
-        ("0", "stream", None, True),
-        ("auto", "grid", None, jax.default_backend() != "tpu"),
-        ("force", "grid", object(), True),  # a TP mesh turns the kernels off
+@pytest.fixture(scope="module")
+def aligned_params():
+    lm = TransformerLM(dtype=jnp.float32, **ALIGNED)
+    return lm.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]
+
+
+class TestDecodeLane:
+    @pytest.mark.parametrize("mode,backend,mesh,aligned,interpret,kernel", [
+        # the CPU's lanes (an engine is built for each of these)
+        ("force", "cpu", False, False, True, True),    # PARENT_SHA's toy lane
+        ("force", "cpu", False, True, True, True),
+        ("force", "cpu", False, True, False, True),    # the Mosaic tests' lane
+        ("force", "cpu", False, False, False, False),  # compiled, unaligned
+        ("force", "cpu", True, True, True, False),     # a TP mesh: the gather
+        ("0", "cpu", False, True, True, False),
+        ("1", "cpu", False, True, True, False),        # 1 does not interpret
+        ("auto", "cpu", False, True, True, False),
+        # a chip's (the predicate alone: nothing here can build for one)
+        ("auto", "tpu", False, True, False, True),     # the benchmark's cells
+        ("1", "tpu", False, True, False, True),
+        ("auto", "tpu", False, False, False, False),   # unaligned -> gather
+        ("1", "tpu", False, False, False, False),
+        ("force", "tpu", False, False, False, False),
+        ("auto", "tpu", True, True, False, False),
+        ("0", "tpu", False, True, False, False),
     ])
-    def test_pool_is_flat(self, monkeypatch, mode, impl, mesh, flat):
+    def test_decode_lane_is_chosen_from_what_the_code_sees(
+        self, params, aligned_params, monkeypatch, caplog, mode, backend, mesh,
+        aligned, interpret, kernel,
+    ):
         monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", mode)
-        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", impl)
-        assert pool_is_flat(mesh, **self.GEOM) is flat
+        monkeypatch.delenv("SELDON_TPU_CHUNK_IMPL", raising=False)
+        monkeypatch.setattr(kernels, "interpret_mode", lambda: interpret)
+        cfg = ALIGNED if aligned else CFG
+        geometry = (cfg["num_heads"], cfg["d_model"] // cfg["num_heads"])
+        with monkeypatch.context() as m:
+            m.setattr(jax, "default_backend", lambda: backend)
+            assert paged.paged_kernel_static_eligible(
+                mode, not mesh, jnp.float32, *geometry) is kernel
+            # a term it has always had
+            assert not paged.paged_kernel_static_eligible(
+                mode, not mesh, jnp.int8, *geometry)
+        if backend != jax.default_backend():
+            return
+        if mesh and len(jax.devices()) < 2:
+            pytest.skip("needs two devices for a TP mesh")
+        with caplog.at_level(logging.WARNING, logger=paged.__name__):
+            eng = PagedEngine(
+                aligned_params if aligned else params, **cfg, dtype=jnp.float32,
+                page_size=8, max_slots=4, steps_per_call=4,
+                **({"tp": 2} if mesh else {}))
+        try:
+            rep = eng.lane_report()
+            assert rep["kernel_active"] is kernel
+            assert rep["chunk_impl"] == ("pool" if kernel else "ring")
+            assert rep["tp"] == (2 if mesh else 1)
+            assert eng.engine_stats()["kernel_active"] == int(kernel)
+            # one layout, and nothing left that reports one
+            assert eng.pages_k.shape == (
+                LAYERS, eng.num_pages, eng.page_size, cfg["d_model"])
+            assert set(rep) == {
+                "tp", "dp", "chunk_impl", "kv_dtype", "kernel_active",
+                "pool_shard_bytes", "arch", "weight_bytes"}
+        finally:
+            eng.close()
+        # a kernel asked for by name that cannot run says so, once
+        warned = [r for r in caplog.records if "cannot run here" in r.message]
+        assert len(warned) == int(mode in ("1", "force") and not kernel)
 
-    @pytest.mark.parametrize("mode,impl,layout,active,reported", [
-        ("force", "stream", "flat", True, "stream"),
-        ("force", "grid", "split", True, "grid"),
-        ("0", "stream", "flat", False, None),
-        ("0", "grid", "flat", False, None),
-    ])
-    def test_engine_reports_the_lane(self, params, monkeypatch, mode, impl,
-                                     layout, active, reported):
-        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", mode)
-        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", impl)
-        monkeypatch.setenv("SELDON_TPU_CHUNK_IMPL", "pool")
+    def test_the_impl_knob_is_gone(self, params, monkeypatch):
+        name = "SELDON_TPU_PAGED_KERNEL_IMPL"
+        assert not knobs.declared(name)
+        with pytest.raises(knobs.UndeclaredKnobError):
+            knobs.raw(name)
+        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
+        reports = []
+        for value in (None, "grid"):
+            if value:
+                monkeypatch.setenv(name, value)
+            eng = _engine(params)
+            try:
+                reports.append((eng.lane_report(), eng.pages_k.shape))
+            finally:
+                eng.close()
+        assert reports[0] == reports[1] and reports[0][0]["kernel_active"]
+
+    def test_explicit_ring_with_kernel_request_stays_flat(self, params, monkeypatch):
+        """The ring chunk never calls the kernel: the request buys only
+        the warning."""
+        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
+        monkeypatch.setenv("SELDON_TPU_CHUNK_IMPL", "ring")
         eng = _engine(params)
         try:
             rep = eng.lane_report()
-            assert rep["pool_layout"] == layout
-            assert rep["kernel_active"] is active
-            assert rep["kernel_impl"] == reported
-            assert eng.engine_stats()["kernel_active"] == int(active)
-            assert eng.pages_k.ndim == (4 if layout == "flat" else 5)
+            assert eng.pages_k.ndim == 4 and rep["kernel_active"] is False
         finally:
             eng.close()
 
@@ -446,23 +505,45 @@ class TestLayoutDecision:
         if len(jax.devices()) < 2:
             pytest.skip("needs two devices for a TP mesh")
         monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
-        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL_IMPL", "grid")
         eng = _engine(params, tp=2)
         try:
             rep = eng.lane_report()
-            assert rep["tp"] == 2 and rep["pool_layout"] == "flat"
-            assert rep["kernel_active"] is False and rep["kernel_impl"] is None
+            assert rep["tp"] == 2 and eng.pages_k.ndim == 4
+            assert rep["kernel_active"] is False
         finally:
             eng.close()
 
-    def test_explicit_ring_with_kernel_request_stays_flat(self, params, monkeypatch):
-        """The ring chunk never calls the kernel: the request buys no
-        split pool any more, only the warning."""
+    @pytest.mark.parametrize("kv", ["bf16", "int8"])
+    def test_container_with_its_heads_apart_is_refused_at_the_pool(
+        self, params, monkeypatch, kv
+    ):
+        """A handoff container may hold rank-5 K/V (``codec/bufview.py``
+        packs and unpacks rank 4 or 5: peers that rested their pool
+        split wrote such frames).  Where it meets the pool it is a
+        geometry mismatch, a 400 before anything is scattered — what
+        this engine did with one before the split pool went (PR 28),
+        plain and with the int8 pool's scale frames."""
         monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
-        monkeypatch.setenv("SELDON_TPU_CHUNK_IMPL", "ring")
+        monkeypatch.setenv("SELDON_TPU_CHUNK_IMPL", "pool")
+        monkeypatch.setenv("SELDON_TPU_KV_DTYPE", kv)
         eng = _engine(params)
         try:
-            rep = eng.lane_report()
-            assert rep["pool_layout"] == "flat" and rep["kernel_active"] is False
+            prompt = np.arange(1, 12, dtype=np.int32)
+            good = eng.prefill_export(prompt)
+            assert good["layout"] == "flat" and good["k"].ndim == 4
+            assert ("k_scales" in good) is (kv == "int8")
+            heads = CFG["num_heads"]
+            split = dict(good, layout="split", **{
+                n: good[n].reshape(*good[n].shape[:3], heads, -1) for n in "kv"})
+            before = np.asarray(eng.pages_k)
+            with pytest.raises(MicroserviceError) as err:
+                eng.submit_prefilled(split, max_new_tokens=2)
+            assert err.value.reason == "KV_LAYOUT_MISMATCH"
+            assert err.value.status_code == 400
+            np.testing.assert_array_equal(np.asarray(eng.pages_k), before)
+            # the same pages as the pool holds them are taken
+            stream = eng.submit_prefilled(good, max_new_tokens=2)
+            eng.run()
+            assert len(stream.result) == 2
         finally:
             eng.close()

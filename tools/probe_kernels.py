@@ -47,12 +47,11 @@ NUM_PAGES = SLOTS * PAGES_PER + 1
 LAYERS, LAYER = 2, 1  # a whole pool of two layers; the kernel reads the second
 
 
-def _paged_inputs(rng, pool_dtype, impl, h, hd):
+def _paged_inputs(rng, pool_dtype, h, hd):
     """A whole pool + block tables with ragged lengths: empty lanes,
     one single token, exactly one page, one token more, partial pages
-    and a full table.  The device pool is in the layout the impl reads:
-    flat ``(L, pages, ps, h*hd)`` for stream, split for grid; the
-    oracle gets layer ``LAYER`` of what the pool stores."""
+    and a full table.  The device pool is ``(L, pages, ps, h*hd)``; the
+    oracle gets layer ``LAYER`` of what the pool stores, heads apart."""
     import jax.numpy as jnp
 
     batch = SLOTS
@@ -77,9 +76,8 @@ def _paged_inputs(rng, pool_dtype, impl, h, hd):
         # the oracle sees what the pool stores, not what was drawn
         pk = np.asarray(pk_dev.astype(jnp.float32))
         pv = np.asarray(pv_dev.astype(jnp.float32))
-    if impl == "stream":
-        pk_dev = pk_dev.reshape(LAYERS, NUM_PAGES, PS, h * hd)
-        pv_dev = pv_dev.reshape(LAYERS, NUM_PAGES, PS, h * hd)
+    pk_dev = pk_dev.reshape(LAYERS, NUM_PAGES, PS, h * hd)
+    pv_dev = pv_dev.reshape(LAYERS, NUM_PAGES, PS, h * hd)
     return q, pk[LAYER], pv[LAYER], pk_dev, pv_dev, tables, lengths, scales
 
 
@@ -98,7 +96,7 @@ def _paged_oracle(q, pk, pv, tables, lengths):
     return out, l
 
 
-def _paged_case(impl, pool="bf16", lora=False, h=H, hd=HD):
+def _paged_case(pool="bf16", lora=False, h=H, hd=HD):
     d = h * hd
 
     def run():
@@ -107,11 +105,10 @@ def _paged_case(impl, pool="bf16", lora=False, h=H, hd=HD):
 
         from seldon_core_tpu.ops.kernels import paged_attention_decode
 
-        os.environ["SELDON_TPU_PAGED_KERNEL_IMPL"] = impl
         rng = np.random.default_rng(0)
         pool_dtype = {"bf16": jnp.bfloat16, "f32": jnp.float32, "int8": jnp.int8}[pool]
         q, pk, pv, pk_dev, pv_dev, tables, lengths, scales = _paged_inputs(
-            rng, pool_dtype, impl, h, hd)
+            rng, pool_dtype, h, hd)
         kw = {}
         if scales is not None:
             kw["kv_scales"] = tuple(jnp.asarray(s) for s in scales)
@@ -222,17 +219,15 @@ def _int8_matmul_case():
 
 
 CASES = {
-    "paged_stream_bf16": _paged_case("stream"),
-    "paged_stream_f32": _paged_case("stream", pool="f32"),
-    "paged_stream_int8kv": _paged_case("stream", pool="int8"),
-    "paged_stream_lora": _paged_case("stream", lora=True),
+    "paged_stream_bf16": _paged_case(),
+    "paged_stream_f32": _paged_case(pool="f32"),
+    "paged_stream_int8kv": _paged_case(pool="int8"),
+    "paged_stream_lora": _paged_case(lora=True),
     # the benchmark's geometries: GPT-2-large, OLMoE
-    "paged_stream_bf16_20x64": _paged_case("stream", h=20, hd=64),
-    "paged_stream_bf16_16x128": _paged_case("stream", h=16, hd=128),
-    "paged_stream_int8kv_20x64": _paged_case("stream", pool="int8", h=20, hd=64),
-    "paged_stream_lora_20x64": _paged_case("stream", lora=True, h=20, hd=64),
-    "paged_grid_bf16": _paged_case("grid"),
-    "paged_grid_int8kv": _paged_case("grid", pool="int8"),
+    "paged_stream_bf16_20x64": _paged_case(h=20, hd=64),
+    "paged_stream_bf16_16x128": _paged_case(h=16, hd=128),
+    "paged_stream_int8kv_20x64": _paged_case(pool="int8", h=20, hd=64),
+    "paged_stream_lora_20x64": _paged_case(lora=True, h=20, hd=64),
     "flash_256": _flash_case(256, causal=False),
     "flash_256_causal": _flash_case(256, causal=True),
     "flash_197_vit": _flash_case(197, causal=False),

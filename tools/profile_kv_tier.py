@@ -68,7 +68,6 @@ def bandwidth(args, eng, np, jnp):
         ks = np.asarray(eng.scales_k[:, idx])
         vs = np.asarray(eng.scales_v[:, idx])
     t_gather = time.perf_counter() - t0
-    layout = "flat" if eng._pool_flat else "split"
 
     blobs = []
     t0 = time.perf_counter()
@@ -77,7 +76,7 @@ def bandwidth(args, eng, np, jnp):
             "prompt": np.asarray(toks, np.int32),
             "last_logits": np.zeros((1,), np.float32),
             "k": k[:, i:i + 1], "v": v[:, i:i + 1],
-            "page_size": eng.page_size, "layout": layout,
+            "page_size": eng.page_size, "layout": "flat",
         }
         if ks is not None:
             payload["k_scales"] = ks[:, i:i + 1]

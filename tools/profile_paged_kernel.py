@@ -85,7 +85,6 @@ def main():
                     help="N | LO-HI | A,B,C: cached tokens of a live lane")
     ap.add_argument("--full", action="store_true",
                     help="also time every lane at the table's capacity")
-    ap.add_argument("--impl", choices=("stream", "grid"), default="stream")
     ap.add_argument("--kv-dtype", choices=("bf16", "int8"), default="bf16")
     ap.add_argument("--steps", type=int, default=8)
     ap.add_argument("--repeats", type=int, default=5)
@@ -93,8 +92,6 @@ def main():
     ap.add_argument("--json", default=None,
                     help="append one JSON line per arm to this file")
     args = ap.parse_args()
-
-    os.environ["SELDON_TPU_PAGED_KERNEL_IMPL"] = args.impl
 
     import jax
     import jax.numpy as jnp
@@ -114,8 +111,7 @@ def main():
 
     quantized = args.kv_dtype == "int8"
     pool_dtype = jnp.int8 if quantized else jnp.bfloat16
-    shape = ((layers, num_pages, ps, d) if args.impl == "stream"
-             else (layers, num_pages, ps, h, hd))
+    shape = (layers, num_pages, ps, d)
     key = jax.random.key(args.seed)
     kk, kv, kq = jax.random.split(key, 3)
     if quantized:
@@ -158,7 +154,7 @@ def main():
         arms.append(("all lanes full", np.full((lanes,), cap, np.int32)))
 
     lane_note = "TPU" if on_tpu else "interpret (CORRECTNESS ONLY, not a timing)"
-    print(f"paged-decode kernel — impl={args.impl} kv={args.kv_dtype} "
+    print(f"paged-decode kernel — kv={args.kv_dtype} "
           f"{h}x{hd} layers={layers} pool={num_pages}x{ps} "
           f"table={lanes}x{width} lane={lane_note}")
     page_bytes = 2 * ps * d * np.dtype(pool_dtype).itemsize
@@ -169,7 +165,7 @@ def main():
         pages = int(np.sum(-(-lens // ps)))
         roof = pages * page_bytes / HBM_BYTES_PER_S * 1e6
         rec = {
-            "arm": name, "geometry": f"{h}x{hd}", "impl": args.impl,
+            "arm": name, "geometry": f"{h}x{hd}",
             "kv": args.kv_dtype, "lanes": lanes, "table_pages": width,
             "live_lanes": int((lens > 0).sum()),
             "tokens": int(lens.sum()), "loop_slots": slots,
