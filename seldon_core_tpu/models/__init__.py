@@ -7,32 +7,48 @@ prepackaged-server enum, reference: proto/seldon_deployment.proto:102-113
 and operator/controllers/seldondeployment_prepackaged_servers.go:109).
 """
 
+import importlib
+import importlib.util
+
 from seldon_core_tpu.engine.units import register_implementation
 from seldon_core_tpu.models.jaxserver import JaxServer  # noqa: F401
 
 register_implementation("JAX_SERVER", JaxServer)
 
 
+class _OnFirstUse:
+    """A server class behind a toolkit whose import alone costs seconds
+    (sklearn ~6 s, torch ~2.5 s: a generation server's start paid both
+    and used neither).  Registered when the toolkit is installed, imported
+    when a graph first asks for it; ``__module__`` / ``__qualname__`` are
+    the class's own, which is what ``implementation_path`` reads."""
+
+    def __init__(self, module: str, qualname: str):
+        self.__module__, self.__qualname__ = module, qualname
+
+    def __call__(self, **kwargs):
+        cls = getattr(importlib.import_module(self.__module__), self.__qualname__)
+        return cls(**kwargs)
+
+
+def _installed(*toolkits: str) -> bool:
+    return all(importlib.util.find_spec(t) is not None for t in toolkits)
+
+
 def _register_optional() -> None:
     """Servers gated on optional third-party toolkits."""
-    try:
-        from seldon_core_tpu.models.sklearnserver import SKLearnServer
-
-        register_implementation("SKLEARN_SERVER", SKLearnServer)
-    except ImportError:
-        pass
+    if _installed("sklearn", "joblib"):
+        register_implementation("SKLEARN_SERVER", _OnFirstUse(
+            "seldon_core_tpu.models.sklearnserver", "SKLearnServer"))
     # xgboost/mlflow servers carry their own fallback lanes (JSON
     # booster evaluator / MLmodel sklearn flavor) so they register —
     # and RUN — regardless of the optional packages (VERDICT r4 #4)
     from seldon_core_tpu.models.xgboostserver import XGBoostServer
 
     register_implementation("XGBOOST_SERVER", XGBoostServer)
-    try:
-        from seldon_core_tpu.models.torchserver import TorchServer
-
-        register_implementation("TORCH_SERVER", TorchServer)
-    except ImportError:
-        pass
+    if _installed("torch"):
+        register_implementation("TORCH_SERVER", _OnFirstUse(
+            "seldon_core_tpu.models.torchserver", "TorchServer"))
     from seldon_core_tpu.models.mlflowserver import MLFlowServer
 
     register_implementation("MLFLOW_SERVER", MLFlowServer)

@@ -1371,7 +1371,7 @@ class _Stream:
         "kv_imported", "adapter", "adapter_slot", "adapter_pinned",
         "cost_page_s", "cost_t", "cost_prefill_tokens",
         "cost_decode_tokens", "cost_preempts", "cost_restores",
-        "cost_closed", "tier_promote",
+        "cost_closed", "tier_promote", "inflight",
     )
 
     def __init__(self, req_id, prompt, max_new, temperature, top_k, eos_id, seed):
@@ -1383,6 +1383,10 @@ class _Stream:
         self.eos_id = eos_id
         self.seed = seed
         self.tokens: List[int] = []
+        # tokens of launched waves not harvested yet: what the lane WILL
+        # have emitted unless it meets eos.  Planners count them
+        # (``planned``); ``tokens`` holds only what was read back
+        self.inflight = 0
         self.event = threading.Event()
         self.result: Optional[np.ndarray] = None
         self.error: Optional[Exception] = None
@@ -1482,6 +1486,32 @@ class _Stream:
         # back into the tier if the stream dies before that
         self.tier_promote: Optional[Dict[str, Any]] = None
 
+    @property
+    def planned(self) -> int:
+        """Tokens the stream holds once every launched wave is read."""
+        return len(self.tokens) + self.inflight
+
+
+class _Wave:
+    """One launched decode wave: what is in flight between
+    ``PagedEngine.launch`` and ``PagedEngine.harvest``.  The harvest
+    reads tokens, stamps, finishes and the chunk record off this, never
+    off the engine's current slots: by then the next wave may have been
+    launched and a predicted finisher's slot handed to a joiner."""
+
+    __slots__ = (
+        "number", "seq", "overlapped", "done", "t_launch",
+        "lanes", "active_n", "puids", "trace_id", "stalled",
+        "lens0", "steps", "buckets", "step_slots",
+        "toks", "emitted", "finite", "moe", "has_moe",
+        "admitted_n", "prefill_tokens", "prefill_wall",
+    )
+
+    def __init__(self, **kw):
+        self.done = False
+        for k, v in kw.items():
+            setattr(self, k, v)
+
 
 def journal_entry(
     *,
@@ -1528,22 +1558,28 @@ class _WaveSeam:
 
     * **Phases on the profiler's clock.**  ``begin_wave`` opens a
       ``jax.profiler.StepTraceAnnotation`` (``seldon.wave``, ``step_num``
-      = the wave's number) and ``enter`` a ``TraceAnnotation``
-      (``seldon.wave.<phase>``) that lasts until the next ``enter``, so
-      the phases tile the step: ``admit``, ``launch``, ``wait``,
-      ``harvest``, ``record``.  ``prefill`` (one per ``_prefill_group``
-      call) nests inside whichever of them runs it.  They land on the
-      engine thread's line of the host plane of the same ``.xplane.pb``
-      as the device's operations; with no profiler session open each is
-      a flag test.
-    * **The host gap.**  ``drained`` marks the return of a blocking
-      readback (nothing is in flight any more), ``dispatched`` the
-      return of the next dispatch of a program of the wave loop (its
-      argument transfers, signature walk and enqueue are host work the
-      device waits for); the time between is ``host_gap_s``, kept by
+      = the number of the wave this step launches) and ``enter`` a
+      ``TraceAnnotation`` (``seldon.wave.<phase>``) that lasts until the
+      next ``enter``, so the phases tile the step: ``admit``, ``launch``,
+      then ``wait``, ``harvest``, ``record``.  In the serving loop the
+      last three belong to the PREVIOUS wave, launched one step earlier
+      and harvested under the chunk this step just enqueued; each
+      carries its own ``wave=``.  ``prefill`` (one per
+      ``_prefill_group`` call) nests inside whichever of them runs it.
+      They land on the engine thread's line of the host plane of the
+      same ``.xplane.pb`` as the device's operations; with no profiler
+      session open each is a flag test.
+    * **The host gap.**  ``dispatched`` marks the return of a dispatch
+      of a program of the wave loop (its argument transfers, signature
+      walk and enqueue are host work the device waits for) and numbers
+      it (``seq``); ``drained(upto)`` the return of a blocking readback
+      of what dispatch ``upto`` produced.  The device runs one queue in
+      order, so everything up to ``upto`` has run; the gap opens only
+      if nothing was dispatched after it, i.e. nothing is in flight any
+      more.  The time to the next dispatch is ``host_gap_s``, kept by
       the phase it was spent in (``phase_s``; ``between`` is the time
-      between two ``step()`` calls).  A wave that leaves no work behind
-      closes the gap uncounted.
+      between two steps).  A wave that leaves no work behind closes the
+      gap uncounted.
     * **The profile window.**  ``arm`` asks for ``seconds`` of
       ``jax.profiler`` trace under ``SELDON_TPU_PROFILE_DIR``;
       ``boundary`` (every wave boundary, on the engine thread) starts it,
@@ -1566,9 +1602,15 @@ class _WaveSeam:
         # open annotations, outermost first: the step, its current
         # phase, a prefill group nested in that — (phase, annotation)
         self._open: List[Tuple[str, Any]] = []
+        # whether the outermost of them is a step: a burst's last wave is
+        # harvested with nothing left to launch, outside any step
+        self._in_step = False
         self._phase = "between"
         self._gap_open = False
         self._mark = 0.0
+        # dispatches of wave-loop programs so far: a readback names the
+        # one it waited for, and opens the gap only if it is the newest
+        self.seq = 0
         self._profile_dir = profile_dir
         self._profile_lock = threading.Lock()
         self._profile: Dict[str, Any] = {"state": "idle"}
@@ -1594,16 +1636,20 @@ class _WaveSeam:
         self._account(self._open[-1][0] if self._open else "between")
 
     def begin_wave(self) -> None:
+        while self._open:  # a step an exception cut, or never harvested
+            self._pop()
+        self._in_step = False
         self.boundary()
         self.wave += 1
         # the step itself is no phase: time under it alone stays with
         # whatever was running (``between``, until ``enter``)
         self._push(self._phase, self._profiler.StepTraceAnnotation(
             "seldon.wave", step_num=self.wave))
+        self._in_step = True
 
     def enter(self, phase: str, **stats: Any) -> None:
         """End the wave's current phase and begin ``phase``."""
-        while len(self._open) > 1:
+        while len(self._open) > (1 if self._in_step else 0):
             self._pop()
         self._push(phase, self._profiler.TraceAnnotation(
             "seldon.wave." + phase, **stats))
@@ -1628,21 +1674,28 @@ class _WaveSeam:
         follows is waiting for a request and no host gap."""
         while self._open:
             self._pop()
+        self._in_step = False
         if not more:
             self._gap_open = False
 
     # ---- the host gap --------------------------------------------------
 
-    def drained(self) -> None:
-        """A blocking readback returned: nothing is in flight."""
-        self._gap_open = True
-        self._mark = self._clock()
+    def drained(self, upto: Optional[int] = None) -> None:
+        """A blocking readback of dispatch ``upto``'s output returned
+        (None: of the newest).  Nothing is in flight if no program was
+        dispatched after it; otherwise the device has its next program
+        queued and no gap opens."""
+        if upto is None or upto == self.seq:
+            self._gap_open = True
+            self._mark = self._clock()
 
-    def dispatched(self) -> None:
-        """A program of the wave loop has been enqueued."""
+    def dispatched(self) -> int:
+        """A program of the wave loop has been enqueued; its number."""
+        self.seq += 1
         if self._gap_open:
             self._account(self._phase)
             self._gap_open = False
+        return self.seq
 
     @property
     def host_gap_s(self) -> float:
@@ -1683,6 +1736,14 @@ class _WaveSeam:
     def profile_status(self) -> Dict[str, Any]:
         with self._profile_lock:
             return dict(self._profile)
+
+    def boundary_due(self) -> bool:
+        """Whether the next boundary opens or closes a window: its
+        snapshot is exact only with every launched wave harvested."""
+        prof = self._profile
+        return prof["state"] == "armed" or (
+            prof["state"] == "tracing"
+            and self._monotonic() - prof["t_start"] >= prof["seconds"])
 
     def boundary(self) -> None:
         """Open an armed window, close one that has run its time.
@@ -2254,6 +2315,10 @@ class PagedEngine:
                           # the pages those lane-steps' caches held —
                           # the share of the loop that is live
                           "decode_page_slots": 0, "decode_live_pages": 0,
+                          # of ``chunks``: those enqueued while an
+                          # earlier wave's tokens were still unread, so
+                          # the device found them queued (PR 29)
+                          "waves_overlapped": 0,
                           # routed experts (a routed spec; 0 otherwise),
                           # counted by the programs and read back with a
                           # chunk's tokens: (token, expert) assignments
@@ -2428,6 +2493,15 @@ class PagedEngine:
         self._moe_hits = np.zeros(
             (num_layers, spec.num_experts), np.int64)
         self._moe_pending: List[Any] = []
+        # waves launched and not harvested yet, oldest first: one while
+        # step() runs, two for the moment a serving loop has launched
+        # wave N+1 and not yet read wave N.  Written under _lock;
+        # launched and harvested by the engine thread alone
+        self._inflight: List[_Wave] = []
+        self._t_drained = 0.0  # perf_counter of the last wave readback
+        # the counters a wave record carries as deltas, as the last
+        # record left them (_record_deltas_locked)
+        self._rec_base: Dict[str, int] = {}
 
         # speculative mode: per-slot draft/verify INSIDE the batched
         # engine — each chunk is ONE verify forward of width draft_k+1
@@ -3621,15 +3695,12 @@ class PagedEngine:
             compiles_delta=delta,
         )
 
-    def _quarantine_poisoned(self, runnable: List[_Stream]) -> List[_Stream]:
-        """Post-chunk NaN/Inf screen on the served logits (r17): fault
-        point ``paged.nan`` poisons ONE runnable lane first (chaos), the
-        screen — one jitted ``isfinite`` reduction, (max_slots,) bools
-        back — then retires every non-finite lane's stream with a 500
-        ``NUMERIC_POISON`` and a ``quarantined`` count.  Wave-mates are
-        untouched (lanes are arithmetically independent), so one sick
-        stream never becomes a ``fail_all``.  Returns the quarantined
-        streams; their slots/pages are already released.
+    def _screen_logits(self, runnable: List[_Stream]):
+        """Post-chunk NaN/Inf screen on the served logits (r17), enqueued
+        right behind the chunk it judges: fault point ``paged.nan``
+        poisons ONE runnable lane first (chaos), then one jitted
+        ``isfinite`` reduction — (max_slots,) bools, read at the wave's
+        harvest (:meth:`_quarantine_locked`).  None with the guard off.
 
         DECODE lane only: the speculative verify program returns argmax
         token ids — its logits never land in ``self._logits`` or reach
@@ -3645,29 +3716,41 @@ class PagedEngine:
                 victim.slot, victim.req_id,
             )
         if not self._nan_guard or not runnable:
-            return []
+            return None
         if self._isfinite_jit is None:
             self._isfinite_jit = self._jax.jit(
                 lambda l: jnp.isfinite(l).all(axis=-1)
             )
-        finite = np.asarray(self._isfinite_jit(self._logits))
-        poisoned = [s for s in runnable if not finite[s.slot]]
-        if not poisoned:
+        return self._isfinite_jit(self._logits)
+
+    def _quarantine_locked(self, wave: _Wave, finite) -> List[_Stream]:
+        """Retire every lane of ``wave`` whose logits the screen found
+        non-finite with a 500 ``NUMERIC_POISON`` and a ``quarantined``
+        count.  Wave-mates are untouched (lanes are arithmetically
+        independent), so one sick stream never becomes a ``fail_all``.
+        A lane whose stream ended in an earlier wave ran on for nobody:
+        nothing to retire.  Returns the quarantined streams; their
+        slots/pages are already released."""
+        if finite is None:
             return []
-        with self._lock:
-            for s in poisoned:
-                self._counters["quarantined"] += 1
-                self._fail_stream_locked(s, MicroserviceError(
-                    f"stream req {s.req_id} quarantined: served logits "
-                    f"went non-finite after {len(s.tokens)} tokens "
-                    "(numeric poison contained to this stream; its "
-                    "wave-mates are unaffected)",
-                    status_code=500, reason="NUMERIC_POISON",
-                ))
-        logger.error(
-            "NaN guard quarantined %d stream(s): %s",
-            len(poisoned), [s.req_id for s in poisoned],
-        )
+        poisoned = [
+            s for s, slot, _n in wave.lanes
+            if not finite[slot] and s.result is None and s.error is None
+        ]
+        for s in poisoned:
+            self._counters["quarantined"] += 1
+            self._fail_stream_locked(s, MicroserviceError(
+                f"stream req {s.req_id} quarantined: served logits "
+                f"went non-finite after {len(s.tokens)} tokens "
+                "(numeric poison contained to this stream; its "
+                "wave-mates are unaffected)",
+                status_code=500, reason="NUMERIC_POISON",
+            ))
+        if poisoned:
+            logger.error(
+                "NaN guard quarantined %d stream(s): %s",
+                len(poisoned), [s.req_id for s in poisoned],
+            )
         return poisoned
 
     # ---- host control -----------------------------------------------------
@@ -4547,7 +4630,11 @@ class PagedEngine:
         0) engine behaves exactly as before."""
         candidates = [
             s for s in self._slots
+            # (a lane of a wave in flight has progress nobody has read:
+            # launch harvests before it admits where a preemption may
+            # come, _must_know_locked, so this holds but for a race)
             if s is not None and s.priority < stream.priority
+            and not s.inflight
         ]
         if not candidates:
             return None
@@ -5907,10 +5994,13 @@ class PagedEngine:
             self._gen_span_deferred(stream, "gen.finish", now, 0.0, **finish_tags)
         self._cost_close_locked(stream)  # idempotent with the traced close
         self._tier_putback_locked(stream)
-        self._slots[slot] = None
+        if self._slots[slot] is stream:
+            # (a predicted finisher gave its slot up when its last wave
+            # was launched: a joiner may hold it by now)
+            self._slots[slot] = None
+            self._lengths[slot] = 0
         self._free_locked(stream.pages)
         stream.pages = []
-        self._lengths[slot] = 0
         self._release_adapter_locked(stream)
         self._counters["completed"] += 1
         stream.event.set()
@@ -5993,7 +6083,11 @@ class PagedEngine:
         now = None
         for stream in active:
             if stream.cancelled:
-                self._finish_locked(stream)
+                # with a wave in flight the cancel retires at that
+                # wave's harvest, with its tokens: the lane sits this
+                # wave out
+                if not stream.inflight:
+                    self._finish_locked(stream)
                 continue
             if stream.deadline is not None:
                 now = _time.monotonic() if now is None else now
@@ -6010,12 +6104,12 @@ class PagedEngine:
             live.append(stream)
         return live
 
-    def _contain_chunk_fault(self, streams: List[_Stream], exc: Exception) -> bool:
+    def _contain_chunk_fault(self, streams: List[_Stream], exc: Exception) -> None:
         """Graceful degradation for an injected chunk failure: error out
         ONLY the streams that would have run this chunk (clean upstream
         503s), keep every other slot and the queue alive, and leave the
         allocator consistent — the chaos invariant is that ``fail_all``
-        is never needed.  Returns step()'s has-more-work value."""
+        is never needed."""
         err = MicroserviceError(
             f"decode chunk failed: {exc}",
             status_code=503, reason="ENGINE_CHUNK_FAULT",
@@ -6026,15 +6120,38 @@ class PagedEngine:
                 self._fail_stream_locked(stream, err)
             if self._debug_invariants:
                 self._check_invariants_locked()
-            more = bool(self._queue) or any(s is not None for s in self._slots)
         # the fault is a watchdog signal: a sustained fault rate drives
         # the engine health state machine toward degraded/evacuating
         self._feed_watchdog(0.0, fault=True)
-        return more
+
+    def _has_streams_locked(self) -> bool:
+        """A queued stream or one in a slot: something to admit or decode."""
+        return bool(self._queue) or any(s is not None for s in self._slots)
 
     def has_work(self) -> bool:
         with self._lock:
-            return bool(self._queue) or any(s is not None for s in self._slots)
+            return self._has_streams_locked() or bool(self._inflight)
+
+    def _live_streams_locked(self) -> List[_Stream]:
+        """Every stream the engine holds: slots, then the unfinished
+        lanes of waves in flight (a predicted finisher has given its
+        slot up and lives in its wave alone), then the queue."""
+        live = [s for s in self._slots if s is not None]
+        for wave in self._inflight:
+            live += [
+                s for s, _slot, _n in wave.lanes
+                if s.result is None and s.error is None and s not in live
+            ]
+        return live + list(self._queue)
+
+    def _abandon_inflight_locked(self) -> None:
+        """Forget the waves in flight (their streams are being failed
+        or journaled whole): nothing of them will be harvested."""
+        for wave in self._inflight:
+            wave.done = True
+            for s, _slot, _n in wave.lanes:
+                s.inflight = 0
+        self._inflight = []
 
     def lane_report(self) -> Dict[str, Any]:
         """The static lane this engine was built on — what a client
@@ -6064,19 +6181,24 @@ class PagedEngine:
             c.copy_to_host_async()
         self._moe_pending += counts
 
-    def _moe_readback(self, moe):
-        """A routed spec's routing counts of this wave, read where the
-        chunk's tokens just were: the chunk's accumulator
-        (``int32[layers, E + 2]``, see ``_moe_carry``) and the
-        histograms of the prefill programs dispatched since the last
-        chunk — all on their way to the host since their dispatch
-        (:meth:`_moe_hold`).  None for a dense spec."""
+    def _moe_take(self) -> List[Any]:
+        """Hand over the routing counts held since the last wave was
+        launched: they are that wave's, whatever is dispatched next."""
+        pending, self._moe_pending = self._moe_pending, []
+        return pending
+
+    def _moe_readback(self, pending: List[Any], has_chunk: bool):
+        """A routed spec's routing counts of one wave
+        (:meth:`_moe_take`), read where the chunk's tokens just were:
+        the chunk's accumulator (``int32[layers, E + 2]``, see
+        ``_moe_carry``; held last, and a speculative engine's verify
+        program keeps none) and the histograms of the prefill programs
+        dispatched since the wave before — all on their way to the host
+        since their dispatch (:meth:`_moe_hold`).  None for a dense
+        spec."""
         if not self.spec.routed:
             return None
-        pending, self._moe_pending = self._moe_pending, []
-        # the chunk's accumulator was held last (a speculative engine's
-        # verify program keeps none)
-        chunk = np.asarray(pending.pop()) if moe else None
+        chunk = np.asarray(pending.pop()) if has_chunk else None
         return chunk, [np.asarray(h) for h in pending]
 
     def _moe_count_locked(self, moe_np) -> Dict[str, float]:
@@ -6333,7 +6455,8 @@ class PagedEngine:
 
         with self._lock:
             self._closed = True  # stops admission: submits now 503
-            victims = [s for s in self._slots if s is not None] + list(self._queue)
+            victims = self._live_streams_locked()
+            self._abandon_inflight_locked()
             now = _time.monotonic()
             entries: List[Dict[str, Any]] = []
             for s in victims:
@@ -6451,7 +6574,8 @@ class PagedEngine:
         """Error out every queued and in-flight stream, returning their
         pages to the pool — the engine stays usable afterwards."""
         with self._lock:
-            victims = [s for s in self._slots if s is not None] + list(self._queue)
+            victims = self._live_streams_locked()
+            self._abandon_inflight_locked()
             self._queue.clear()
             self._queued.clear()
             for i in range(self.max_slots):
@@ -6471,40 +6595,30 @@ class PagedEngine:
 
     def _record_prefill_wave(
         self, *, wall_s: float, tokens: int, occupancy: int,
-        admissions: int, stalls: int, pre_hits: int, pre_saved: int,
-        pre_slo: Dict[str, int], puids=(), pre_tier=None,
-    ) -> bool:
+        admissions: int, stalls: int, puids=(),
+    ) -> None:
         """Record a wave that carried ONLY prefill work — budgeted
         prefill-only waves AND waves whose streams all finished at
         prefill (kv_export workers, spec max_new=1).  Without this the
         recorder's window mix undercounts against the prefill_tokens
-        counter exactly on pure prefill workers.  Returns step()'s
-        has-more-work value."""
-        self._seam.enter("record")
+        counter exactly on pure prefill workers.
+
+        Its programs are enqueued by now, behind whatever is in flight;
+        that wave is harvested first, so the records stay in the order
+        of their waves."""
+        number = self._seam.wave
+        if self._inflight:
+            self._drain_inflight("record")
+        else:
+            self._seam.enter("record")
         with self._lock:
             if self._debug_invariants:
                 self._check_invariants_locked()
-            more = bool(self._queue) or any(
-                s is not None for s in self._slots
-            )
             queue_depth = len(self._queue)
-            prefix_hits_d = self._counters["prefix_hits"] - pre_hits
-            prefix_saved_d = (
-                self._counters["prefix_tokens_saved"] - pre_saved
-            )
-            slo_d = {
-                k: self._counters[k] - pre_slo[k]
-                for k in _SLO_COUNTER_KEYS
-            }
-            # KV tier deltas ride the record only when the tier is on:
-            # the off lane's chunk records stay byte-identical
-            tier_d = (
-                {k: self._counters[k] - pre_tier[k] for k in _TIER_DELTA_KEYS}
-                if pre_tier is not None else {}
-            )
-            pages_cached = len(self._lru)
+            deltas = self._record_deltas_locked()
         self._record_chunk({
             "phase": "prefill",
+            "wave": number,
             # puid linkage (r21): breach dumps index the requests the
             # wave actually carried, not just an anonymous ring slice
             "puids": list(puids),
@@ -6521,51 +6635,139 @@ class PagedEngine:
             "tokens": tokens,
             "prefill_tokens": tokens,
             "decode_tokens": 0,
-            "prefix_hits": prefix_hits_d,
-            "prefix_tokens_saved": prefix_saved_d,
-            "prefix_pages_cached": pages_cached,
-            **slo_d,
-            **tier_d,
+            **deltas,
         })
-        return more
 
     def step(self) -> bool:
-        """Admit + prefill joiners, run one decode chunk, retire finished.
+        """Admit + prefill joiners, run one decode chunk, retire finished:
+        one whole wave, harvested before it returns —
+        ``harvest(launch())``.
 
         Returns True while there is (or may be) more work.
 
         One wave = one ``seldon.wave`` step on the profiler's clock,
         tiled by its phases (:class:`_WaveSeam`).
         """
+        return self.harvest(self.launch())
+
+    def launch(self) -> Optional[_Wave]:
+        """The first half of a wave: admit, enqueue the joiners' prefill,
+        plan and enqueue the decode chunk, and write the state the chunk
+        WILL leave (:meth:`_launch_decode`).  Returns what is in flight,
+        for :meth:`harvest`; None when the wave left nothing to read
+        (no decoder could run, a prefill-only wave, a speculative
+        engine's whole round).
+
+        Opens the ``seldon.wave`` step that the next :meth:`harvest`
+        closes.  A serving loop calls ``launch`` again BEFORE it
+        harvests: the device then finds its next programs queued when
+        the chunk ends, and harvest, screen, record and the next
+        admission run under a running chunk.  Where the plan needs
+        state only a harvest knows, ``launch`` harvests what is in
+        flight first (:meth:`_must_know_locked`)."""
         seam = self._seam
+        with self._lock:
+            if not self._has_streams_locked():
+                # no wave, no step, no number: what is in flight is
+                # harvested outside a step
+                return None
+        if self._inflight and seam.boundary_due():
+            # a profile window opens or closes at this boundary, with an
+            # engine_stats() snapshot: exact once nothing launched is unread
+            self._drain_inflight(None)
         seam.begin_wave()
-        more = False
         try:
             seam.enter("admit")
             if self.speculative is not None:
-                more = self._step_speculative()
-            else:
-                more = self._step_decode()
+                # the host drafts from the accepted tokens: a round is
+                # launched and read in one piece
+                self._step_speculative()
+                return None
+            return self._launch_decode()
+        except BaseException:
+            self._flush_spans()
+            seam.end_wave(False)
+            raise
+
+    def harvest(self, wave: Optional[_Wave]) -> bool:
+        """The second half: read ``wave``'s tokens back, screen, deliver,
+        finish and record them (a wave already harvested, or None, has
+        nothing to read), then close the step :meth:`launch` opened.
+        Returns True while there is (or may be) more work."""
+        more = False
+        try:
+            if wave is not None and not wave.done:
+                self._harvest_wave(wave)
+            more = self.has_work()
             return more
         finally:
             # spans queued inside _lock-held retire/evict code emit here,
             # after every lock has dropped (a JSONL-exporting tracer does
             # disk I/O) — including on the early-return paths
             self._flush_spans()
-            seam.end_wave(more)
+            self._seam.end_wave(more)
 
-    def _step_decode(self) -> bool:
+    def _must_know_locked(self) -> bool:
+        """Whether the next wave must be planned from harvested state,
+        not from the state the wave in flight is predicted to leave:
+        the allocator audit and an armed fault point look at (or break)
+        one wave at a time, and a preemption picks its victim by real
+        progress and discards it."""
+        if self._debug_invariants or _faults.enabled():
+            return True
+        if not self._queue:
+            return False
+        waiting = max(s.priority for s in self._queue)
+        return any(
+            s is not None and s.priority < waiting for s in self._slots
+        )
+
+    def _drain_inflight(self, resume: Optional[str]) -> None:
+        """Harvest whatever is in flight, inside the launch that found
+        it must know; the step goes on in phase ``resume``."""
+        for wave in list(self._inflight):
+            self._harvest_wave(wave)
+        if resume is not None:
+            self._seam.enter(resume)
+
+    def _all_stalled_locked(self, active: List[_Stream], budget: int) -> bool:
+        """Whether every decoder of ``active`` stalls on pages, so that
+        the wave would evict one.  Grows the tables it can, as the plan
+        that follows would."""
+        decoding = [
+            s for s in active if not budget or s.prefilled >= len(s.prompt)
+        ]
+        if not decoding or len(decoding) < len(active):
+            return False  # a prefill backlog: the eviction loop stands down
+        return not any(
+            self._ensure_pages_locked(s, per_chunk=self.steps_per_call)
+            for s in decoding
+        )
+
+    def _record_deltas_locked(self) -> Dict[str, int]:
+        """The prefix, SLO and KV-tier counters' change since the last
+        wave record: every event lands in exactly one record, whichever
+        half of whichever wave it happened under.  (KV-tier deltas ride
+        the record only when the tier is on: the off lane's chunk
+        records stay byte-identical.)"""
+        keys = ("prefix_hits", "prefix_tokens_saved") + _SLO_COUNTER_KEYS
+        if self._kv_tier is not None:
+            keys += _TIER_DELTA_KEYS
+        now = {k: self._counters[k] for k in keys}
+        base, self._rec_base = self._rec_base, now
+        out = {k: now[k] - base.get(k, 0) for k in keys[:2]}
+        # a gauge among the deltas, where the records have always had it
+        out["prefix_pages_cached"] = len(self._lru)
+        out.update((k, now[k] - base.get(k, 0)) for k in keys[2:])
+        return out
+
+    def _launch_decode(self) -> Optional[_Wave]:
         jnp = self._jnp
         with self._lock:
-            # pre-admission prefix + SLO counters: the chunk record
-            # carries this wave's deltas (flight-recorder contract)
-            pre_hits = self._counters["prefix_hits"]
-            pre_saved = self._counters["prefix_tokens_saved"]
-            pre_slo = {k: self._counters[k] for k in _SLO_COUNTER_KEYS}
-            pre_tier = (
-                {k: self._counters[k] for k in _TIER_DELTA_KEYS}
-                if self._kv_tier is not None else None
-            )
+            must_know = bool(self._inflight) and self._must_know_locked()
+        if must_know:
+            self._drain_inflight("admit")
+        with self._lock:
             admitted = self._admit_locked()
             self._seam.stats(
                 admitted=len(admitted), queue_depth=len(self._queue)
@@ -6589,21 +6791,29 @@ class PagedEngine:
             active = self._retire_cancelled_locked(
                 [s for s in self._slots if s is not None]
             )
+            # every decoder stalled on pages: the eviction below picks
+            # its victim by real progress and discards it, so it waits
+            # for the wave in flight
+            must_know = bool(self._inflight) and self._all_stalled_locked(
+                active, budget)
+        if must_know:
+            self._drain_inflight("launch")
+            with self._lock:
+                active = self._retire_cancelled_locked(
+                    [s for s in self._slots if s is not None]
+                )
         if not active:
             # every admitted stream finished AT prefill (kv_export
             # workers, cancellations): the wave still carried prefill
             # work and must be recorded, or a pure prefill worker's
             # window mix reads zero
             if wave_prefill_tokens:
-                return self._record_prefill_wave(
+                self._record_prefill_wave(
                     wall_s=wave_prefill_wall, tokens=wave_prefill_tokens,
                     occupancy=0, admissions=len(admitted), stalls=0,
-                    pre_hits=pre_hits, pre_saved=pre_saved,
-                    pre_slo=pre_slo, pre_tier=pre_tier,
                     puids=[s.puid for s, _ in admitted if s.puid],
                 )
-            with self._lock:
-                return bool(self._queue)
+            return None
         with self._lock:
             if budget:
                 # chunked co-scheduling (r15): only fully-prefilled
@@ -6630,7 +6840,7 @@ class PagedEngine:
             # base-size chunks were making steadily.
             steps = self.steps_per_call
             if decoding and not self._queue and not prefilling:
-                most = max(s.max_new - len(s.tokens) for s in decoding)
+                most = max(s.max_new - s.planned for s in decoding)
                 free = self._allocatable_locked()  # LRU-cached pages reclaim on demand
                 while steps * 2 <= self.max_steps and steps < most:
                     nxt = steps * 2
@@ -6674,7 +6884,7 @@ class PagedEngine:
                     ):
                         stalled[stream.slot] = False
             if not decoding and not prefilling:
-                return bool(self._queue)
+                return None
             runnable_now = [s for s in decoding if not stalled[s.slot]]
             if budget and runnable_now:
                 # decode admitted FIRST: never squeezed below one step,
@@ -6694,7 +6904,7 @@ class PagedEngine:
             for stream in decoding:
                 s = stream.slot
                 done_in[s] = stalled[s]
-                max_new[s] = stream.max_new - len(stream.tokens)
+                max_new[s] = stream.max_new - stream.planned
                 temps[s] = stream.temperature
                 top_ks[s] = stream.top_k
                 eos_ids[s] = stream.eos_id
@@ -6710,14 +6920,20 @@ class PagedEngine:
             # a step's page loop: the slots its tables hold, and the
             # pages the runnable lanes' caches hold as the chunk starts
             step_slots = sum(lanes * width for lanes, width in buckets)
+            # enqueued while an earlier wave's tokens are still unread
+            overlapped = bool(self._inflight)
             self._seam.stats(
                 steps=steps, lanes=len(runnable_now),
                 kv_tokens=sum(lens0.values()),
                 pages_live=sum(self._pages_of(n) for n in lens0.values()),
-                page_slots=step_slots,
+                page_slots=step_slots, overlapped=int(overlapped),
             )
-            tables = jnp.asarray(self._block_tables[:, :pages_h])
-            lengths = jnp.asarray(self._lengths)
+            # copies: the host goes on writing these tables (this wave's
+            # predicted lengths, the next wave's admissions) while the
+            # transfer, or on the CPU backend the program itself, may
+            # still read what it was handed
+            tables = jnp.asarray(self._block_tables[:, :pages_h].copy())
+            lengths = jnp.asarray(self._lengths.copy())
             emitted0 = jnp.zeros((self.max_slots,), jnp.int32)
             # multi-LoRA (r16): the wave's per-lane adapter slot ids —
             # a TRACED argument, so any mix of adapters runs this same
@@ -6747,20 +6963,16 @@ class PagedEngine:
             # prompt still holds) — record the wave so the scheduler's
             # chunk mix stays observable
             if wave_prefill_tokens:
-                return self._record_prefill_wave(
+                self._record_prefill_wave(
                     wall_s=wave_prefill_wall, tokens=wave_prefill_tokens,
                     occupancy=len(active), admissions=len(admitted),
-                    stalls=int(stalled.sum()), pre_hits=pre_hits,
-                    pre_saved=pre_saved, pre_slo=pre_slo,
-                    pre_tier=pre_tier,
+                    stalls=int(stalled.sum()),
                     puids=[s.puid for s in active if s.puid],
                 )
-            with self._lock:
-                if self._debug_invariants:
+            elif self._debug_invariants:
+                with self._lock:
                     self._check_invariants_locked()
-                return bool(self._queue) or any(
-                    s is not None for s in self._slots
-                )
+            return None
 
         try:
             # fault point paged.chunk fires BEFORE the device call is
@@ -6771,7 +6983,8 @@ class PagedEngine:
             # donated buffers may be gone by then)
             _faults.raise_if("paged.chunk")
         except _faults.InjectedFault as exc:
-            return self._contain_chunk_fault(runnable_now, exc)
+            self._contain_chunk_fault(runnable_now, exc)
+            return None
         # KV tier (r22): decode-growth allocations above may have
         # staged demotions — gather them before the chunk writes the
         # pool (no-op when off)
@@ -6788,31 +7001,90 @@ class PagedEngine:
             chunk_args = chunk_args + (
                 self._lora.device_args(), jnp.asarray(adapter_wave),
             )
-        (toks, pk_out, pv_out, self._logits, lengths_out, self._keys, _,
+        (toks, pk_out, pv_out, self._logits, _lengths_out, self._keys, _,
          emitted, *moe) = self._get_chunk(steps, buckets)(*chunk_args)
-        self._seam.dispatched()
+        seq = self._seam.dispatched()
         self._moe_hold(moe)
         self._store_kv(pk_out, pv_out)
-        self._seam.enter("wait")
-        toks_np = np.asarray(toks)
-        emitted_np = np.asarray(emitted)
-        moe_np = self._moe_readback(moe)
-        # single-writer window: the chunk runs with its streams pinned
-        # and admission only mutates lengths between chunks under the lock
-        # graftlint: allow[lock-discipline] — single-writer chunk window
-        self._lengths = np.array(lengths_out)  # copy: jax views are read-only
-        self._seam.drained()
-        chunk_wall = _time.perf_counter() - t_chunk
-        self._seam.enter("harvest")
-        # poison-stream quarantine BEFORE harvest: a lane whose served
-        # logits went non-finite must not deliver this chunk's tokens
-        # (they were computed alongside the poison) — it retires with
-        # 500 NUMERIC_POISON while its wave-mates harvest normally
-        self._quarantine_poisoned(runnable_now)
+        # the NaN screen judges THIS chunk's logits: enqueued right
+        # behind it, before a later wave's prefill or chunk overwrites
+        # the lanes, and read where the tokens are
+        finite = self._screen_logits(runnable_now)
+        for out in (toks, emitted, finite):
+            if out is not None:
+                out.copy_to_host_async()
 
         with self._lock:
+            # the state the chunk WILL leave, unless a lane meets eos: a
+            # lane emits min(steps, what is left of its budget), its
+            # cache grows by as much, and it finishes iff that reaches
+            # max_new.  The next wave is planned from this; the harvest
+            # reconciles (a lane that ended early is finished there and
+            # whatever a later wave computed for it is dropped)
+            lanes = []
+            for stream in runnable_now:
+                slot = stream.slot
+                n = min(steps, stream.max_new - stream.planned)
+                stream.inflight += n
+                self._lengths[slot] += n
+                lanes.append((stream, slot, n))
+                if stream.planned >= stream.max_new:
+                    # a predicted finisher gives up its SLOT to admission
+                    # at once; its pages wait for the harvest, where
+                    # _finish_locked frees them with the tokens in hand
+                    self._slots[slot] = None
+                    self._lengths[slot] = 0
+            wave = _Wave(
+                number=self._seam.wave, seq=seq, overlapped=overlapped,
+                t_launch=t_chunk, lanes=lanes, active_n=len(active),
+                puids=sorted({s.puid for s in active if s.puid}),
+                trace_id=(
+                    # exemplar seed: any traced stream in the wave links
+                    # this chunk's duration observation to one real trace
+                    next((s.trace_id for s in decoding if s.trace_id), "")
+                    if self._telemetry_enabled else ""
+                ),
+                stalled=int(stalled.sum()), lens0=lens0, steps=steps,
+                buckets=buckets, step_slots=step_slots,
+                toks=toks, emitted=emitted, finite=finite,
+                moe=self._moe_take(), has_moe=bool(moe),
+                admitted_n=len(admitted),
+                prefill_tokens=wave_prefill_tokens,
+                prefill_wall=wave_prefill_wall,
+            )
+            self._inflight.append(wave)
+        return wave
+
+    def _harvest_wave(self, wave: _Wave) -> None:
+        import time as _time
+
+        seam = self._seam
+        seam.enter("wait", wave=wave.number)
+        toks_np = np.asarray(wave.toks)
+        emitted_np = np.asarray(wave.emitted)
+        finite_np = None if wave.finite is None else np.asarray(wave.finite)
+        moe_np = self._moe_readback(wave.moe, wave.has_moe)
+        seam.drained(wave.seq)
+        # the chunk's wall: from its enqueue or, when it was queued
+        # behind an earlier wave, from that wave's readback
+        now = _time.perf_counter()
+        chunk_wall = now - max(wave.t_launch, self._t_drained)
+        self._t_drained = now
+        seam.enter("harvest", wave=wave.number)
+        steps, lens0 = wave.steps, wave.lens0
+
+        with self._lock:
+            wave.done = True
+            if wave in self._inflight:
+                self._inflight.remove(wave)
+            # poison-stream quarantine BEFORE harvest: a lane whose served
+            # logits went non-finite must not deliver this chunk's tokens
+            # (they were computed alongside the poison) — it retires with
+            # 500 NUMERIC_POISON while its wave-mates harvest normally
+            self._quarantine_locked(wave, finite_np)
             self._counters["chunks"] += 1
-            self._counters["bucketed_chunks"] += int(len(buckets) > 1)
+            self._counters["waves_overlapped"] += int(wave.overlapped)
+            self._counters["bucketed_chunks"] += int(len(wave.buckets) > 1)
             self._counters["chunk_wall_s"] += chunk_wall
             chunk_tokens = 0
             finished = 0
@@ -6830,19 +7102,23 @@ class PagedEngine:
                 self._counters["decode_live_pages"] += sum(
                     self._pages_of(len0 + t * grow) for t in range(n))
             # every launched step walks every lane's table, live or not
-            self._counters["decode_page_slots"] += steps * step_slots
+            self._counters["decode_page_slots"] += steps * wave.step_slots
             t_now = _time.time()
-            for stream in decoding:
-                if stream.error is not None:
-                    continue  # quarantined by the NaN screen pre-harvest
-                s = stream.slot
-                if stalled[s]:
+            # the lanes AS LAUNCHED: a predicted finisher's slot may
+            # hold a joiner of the next wave by now
+            for stream, slot, predicted in wave.lanes:
+                stream.inflight -= predicted
+                if stream.error is not None or stream.result is not None:
+                    # quarantined by the screen above, failed meanwhile,
+                    # or ended in an earlier wave than was predicted
+                    # (eos, a cancel): what this wave computed for the
+                    # lane is dropped, never delivered
                     continue
-                n = int(emitted_np[s])
+                n = int(emitted_np[slot])
                 self._counters["tokens"] += n
                 chunk_tokens += n
                 stream.cost_decode_tokens += n
-                got = toks_np[s, :n].tolist()
+                got = toks_np[slot, :n].tolist()
                 if got and not stream.tokens and not stream.t_first_token:
                     # TTFT numerator: the stream's first decode token
                     # landed in this chunk (chunk-boundary resolution —
@@ -6850,68 +7126,49 @@ class PagedEngine:
                     stream.t_first_token = t_now
                 stream.tokens.extend(got)
                 hit_eos = stream.eos_id in got
-                if hit_eos or len(stream.tokens) >= stream.max_new:
+                if (hit_eos or len(stream.tokens) >= stream.max_new
+                        or stream.cancelled):
+                    # a cancel that landed under this wave retires with
+                    # this wave's tokens, as it would at the next launch
                     self._finish_locked(stream)
                     finished += 1
                 else:
                     self._stream_push(stream)
-            self._seam.stats(tokens=chunk_tokens, finished=finished,
-                             **self._moe_count_locked(moe_np))
+            seam.stats(tokens=chunk_tokens, finished=finished,
+                       **self._moe_count_locked(moe_np))
             if self._debug_invariants:  # chunk-boundary allocator audit
                 self._check_invariants_locked()
-            more = bool(self._queue) or any(s is not None for s in self._slots)
             queue_depth = len(self._queue)
-            prefix_hits_d = self._counters["prefix_hits"] - pre_hits
-            prefix_saved_d = self._counters["prefix_tokens_saved"] - pre_saved
-            slo_d = {k: self._counters[k] - pre_slo[k] for k in _SLO_COUNTER_KEYS}
-            tier_d = (
-                {k: self._counters[k] - pre_tier[k] for k in _TIER_DELTA_KEYS}
-                if pre_tier is not None else {}
-            )
-            pages_cached = len(self._lru)
-            # exemplar seed: any traced stream in the wave links this
-            # chunk's duration observation back to one real trace
-            chunk_trace = ""
-            if self._telemetry_enabled:
-                chunk_trace = next(
-                    (s.trace_id for s in decoding if s.trace_id), ""
-                )
-            # puid linkage (r21): breach dumps index the requests
-            # active in the wave instead of staying an anonymous ring
-            wave_puids = sorted(
-                {s.puid for s in active if s.puid}
-            )
-        self._seam.enter("record")
+            deltas = self._record_deltas_locked()
+        seam.enter("record", wave=wave.number)
         self._record_chunk({
             "phase": "decode",
-            "puids": wave_puids,
-            "trace_id": chunk_trace,
+            "wave": wave.number,
+            # puid linkage (r21): breach dumps index the requests
+            # active in the wave instead of staying an anonymous ring
+            "puids": wave.puids,
+            "trace_id": wave.trace_id,
             "wall_ms": round(chunk_wall * 1000.0, 3),
-            "prefill_wall_ms": round(wave_prefill_wall * 1000.0, 3),
+            "prefill_wall_ms": round(wave.prefill_wall * 1000.0, 3),
             "tp_degree": self.tp_degree,
             "dp_degree": self.dp_degree,
             "steps": steps,
-            "buckets": [list(b) for b in buckets],
-            "occupancy": len(active),
-            "admissions": len(admitted),
-            "stalls": int(stalled.sum()),
+            "buckets": [list(b) for b in wave.buckets],
+            "occupancy": wave.active_n,
+            "admissions": wave.admitted_n,
+            "stalls": wave.stalled,
             "queue_depth": queue_depth,
             # the wave's token mix: "tokens" is the TOTAL work the wave
             # carried (the budgeted quantity); the split is what the
             # chunk-mix observability reads (r15 — "tokens" used to
             # conflate the two on admission waves)
-            "tokens": chunk_tokens + wave_prefill_tokens,
-            "prefill_tokens": wave_prefill_tokens,
+            "tokens": chunk_tokens + wave.prefill_tokens,
+            "prefill_tokens": wave.prefill_tokens,
             "decode_tokens": chunk_tokens,
-            "prefix_hits": prefix_hits_d,
-            "prefix_tokens_saved": prefix_saved_d,
-            "prefix_pages_cached": pages_cached,
-            **slo_d,
-            **tier_d,
+            **deltas,
         })
-        return more
 
-    def _step_speculative(self) -> bool:
+    def _step_speculative(self) -> None:
         """One draft/verify round for every active slot.
 
         Drafting is host-side ngram lookup on each stream's own context
@@ -6925,13 +7182,6 @@ class PagedEngine:
 
         jnp = self._jnp
         with self._lock:
-            pre_hits = self._counters["prefix_hits"]
-            pre_saved = self._counters["prefix_tokens_saved"]
-            pre_slo = {k: self._counters[k] for k in _SLO_COUNTER_KEYS}
-            pre_tier = (
-                {k: self._counters[k] for k in _TIER_DELTA_KEYS}
-                if self._kv_tier is not None else None
-            )
             admitted = self._admit_locked()
             self._seam.stats(
                 admitted=len(admitted), queue_depth=len(self._queue)
@@ -6997,15 +7247,12 @@ class PagedEngine:
             # pending-append completed max_new==1 streams): still a
             # prefill wave the recorder must see
             if wave_prefill_tokens:
-                return self._record_prefill_wave(
+                self._record_prefill_wave(
                     wall_s=wave_prefill_wall, tokens=wave_prefill_tokens,
                     occupancy=0, admissions=len(admitted), stalls=0,
-                    pre_hits=pre_hits, pre_saved=pre_saved,
-                    pre_slo=pre_slo, pre_tier=pre_tier,
                     puids=[s.puid for s, _ in admitted if s.puid],
                 )
-            with self._lock:
-                return bool(self._queue)
+            return
         with self._lock:
             # chunked: streams mid-prefill never verify, and streams
             # whose final slice ran THIS wave verify next wave (that is
@@ -7040,7 +7287,7 @@ class PagedEngine:
                     if stalled[stream.slot] and self._ensure_pages_locked(stream):
                         stalled[stream.slot] = False
             if not active:
-                return bool(self._queue)
+                return
             L = self.draft_k + 1
             segs = np.zeros((self.max_slots, L), np.int32)
             n_drafts = np.zeros((self.max_slots,), np.int32)
@@ -7128,7 +7375,8 @@ class PagedEngine:
         try:  # same pre-device-call containment as the decode path
             _faults.raise_if("paged.chunk")
         except _faults.InjectedFault as exc:
-            return self._contain_chunk_fault(runnable, exc)
+            self._contain_chunk_fault(runnable, exc)
+            return
         # KV tier (r22): verify-lane page growth may have staged
         # demotions — gather before the chunk writes the pool
         self._tier_flush()
@@ -7149,7 +7397,8 @@ class PagedEngine:
         self._seam.enter("wait")
         out_np = np.asarray(out)
         counts_np = np.asarray(counts)
-        moe_np = self._moe_readback(())  # the prefills' histograms
+        # the prefills' histograms: the verify program keeps none
+        moe_np = self._moe_readback(self._moe_take(), False)
         # same single-writer window as the decode chunk: streams
         # pinned, admission between chunks
         # graftlint: allow[lock-discipline] — single-writer chunk window
@@ -7187,16 +7436,8 @@ class PagedEngine:
             self._seam.stats(tokens=chunk_tokens, finished=finished)
             if self._debug_invariants:  # chunk-boundary allocator audit
                 self._check_invariants_locked()
-            more = bool(self._queue) or any(s is not None for s in self._slots)
             queue_depth = len(self._queue)
-            prefix_hits_d = self._counters["prefix_hits"] - pre_hits
-            prefix_saved_d = self._counters["prefix_tokens_saved"] - pre_saved
-            slo_d = {k: self._counters[k] - pre_slo[k] for k in _SLO_COUNTER_KEYS}
-            tier_d = (
-                {k: self._counters[k] - pre_tier[k] for k in _TIER_DELTA_KEYS}
-                if pre_tier is not None else {}
-            )
-            pages_cached = len(self._lru)
+            deltas = self._record_deltas_locked()
             chunk_trace = ""
             if self._telemetry_enabled:
                 chunk_trace = next(
@@ -7223,13 +7464,8 @@ class PagedEngine:
             "tokens": chunk_tokens + wave_prefill_tokens,
             "prefill_tokens": wave_prefill_tokens,
             "decode_tokens": chunk_tokens,
-            "prefix_hits": prefix_hits_d,
-            "prefix_tokens_saved": prefix_saved_d,
-            "prefix_pages_cached": pages_cached,
-            **slo_d,
-            **tier_d,
+            **deltas,
         })
-        return more
 
     def run(self) -> None:
         """Drain everything synchronously (test / batch-job entrypoint)."""
@@ -7526,13 +7762,24 @@ class StreamingLM(TPUComponent):
         while not self._stop:
             self._wake.wait(timeout=0.5)
             self._wake.clear()
+            # one wave deep: wave N+1 is launched, from the state wave N
+            # will leave, before wave N's tokens are read back — the
+            # device finds its next programs queued when a chunk ends.
+            # The same two halves step() runs back to back
+            prev = None
             try:
                 self.engine.wave_boundary()
                 while self.engine.has_work():
                     if self._stop:
                         break
-                    self.engine.step()
+                    nxt = self.engine.launch()
+                    self.engine.harvest(prev)
+                    prev = nxt
                     collect(2.0)
+                # stopping (shutdown, drain, evacuation): the last wave
+                # is read before anyone looks at stream state
+                if prev is not None and not prev.done:
+                    self.engine.harvest(prev)
             except Exception as exc:  # surface to all waiters, don't die silently
                 self.engine.fail_all(exc)
             collect(0.5)

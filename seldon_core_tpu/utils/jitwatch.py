@@ -35,11 +35,20 @@ def sentinel_enabled() -> bool:
     return knobs.flag("SELDON_TPU_JIT_SENTINEL")
 
 
+# dtype -> its name.  ``str(np.dtype)`` is a dozen python-level calls; a
+# walk over a 36-layer parameter tree pays it per leaf, per jit call,
+# for the handful of dtypes a model has.
+_DTYPE_NAMES: dict = {}
+
+
 def _leaf_sig(x: Any) -> Any:
     shape = getattr(x, "shape", None)
     dtype = getattr(x, "dtype", None)
     if shape is not None and dtype is not None:
-        return (tuple(shape), str(dtype))
+        name = _DTYPE_NAMES.get(dtype)
+        if name is None:
+            name = _DTYPE_NAMES[dtype] = str(dtype)
+        return (tuple(shape), name)
     # weak_type-irrelevant python scalars: jit re-traces on dtype class,
     # not value — collapse to the type name
     return type(x).__name__

@@ -312,6 +312,11 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
                           "seldon_tpu_engine_decode_live_pages_total",
                           "KV pages the decode steps' lanes held: "
                           "ceil(cached / page_size) summed over lane-steps"),
+    "waves_overlapped": ("counter",
+                         "seldon_tpu_engine_waves_overlapped_total",
+                         "decode chunks enqueued while an earlier wave's "
+                         "tokens were still unread (of chunks_total): the "
+                         "device found them queued when that wave ended"),
     "queue_wait_s": ("counter", "seldon_tpu_engine_queue_wait_seconds_total",
                      "seconds streams spent in the engine's queue, "
                      "submit to first prefill slice"),

@@ -458,13 +458,15 @@ class TestEvacuation:
             # finish before evacuate() quiesces the loop)
             import time as _time
 
-            orig_step = lm_a.engine.step
+            # (the serving loop chains the wave's two halves, PR 29:
+            # launch is the one it calls once a wave)
+            orig_launch = lm_a.engine.launch
 
-            def slow_step():
+            def slow_launch():
                 _time.sleep(0.05)
-                return orig_step()
+                return orig_launch()
 
-            lm_a.engine.step = slow_step
+            lm_a.engine.launch = slow_launch
             t = threading.Thread(target=consume)
             t.start()
             # wait until genuinely mid-decode, then evacuate A -> B

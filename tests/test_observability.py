@@ -1217,6 +1217,22 @@ class TestJitSentinel:
         b(jnp.zeros((2,)))  # same array shape, distinct static key
         assert sentinel.compiles == 2
 
+    def test_a_leafs_dtype_is_named_once(self):
+        """The walk names a leaf ``(shape, str(dtype))``; the name of a
+        dtype is computed once, not per leaf per call (a dozen python
+        calls each, over a whole parameter tree)."""
+        import numpy as np
+
+        from seldon_core_tpu.utils import jitwatch
+
+        import jax.numpy as jnp
+
+        for leaf in (np.zeros((2, 3), np.float32), jnp.zeros((4,), jnp.bfloat16),
+                     np.zeros((), np.int32)):
+            assert jitwatch._leaf_sig(leaf) == (tuple(leaf.shape), str(leaf.dtype))
+            assert jitwatch._DTYPE_NAMES[leaf.dtype] == str(leaf.dtype)
+        assert jitwatch._leaf_sig(3) == "int" and jitwatch._leaf_sig(None) == "NoneType"
+
     def test_kill_switch_returns_fn_unwrapped(self, monkeypatch):
         from seldon_core_tpu.utils.jitwatch import JitSentinel
 

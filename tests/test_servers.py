@@ -1169,6 +1169,32 @@ class TestXGBoostServerFallback:
         import seldon_core_tpu.models  # noqa: F401 — triggers registration
         assert "XGBOOST_SERVER" in BUILTIN_IMPLEMENTATIONS
 
+    def test_heavy_toolkits_are_imported_on_first_use(self):
+        """Registering the prepackaged servers imports neither sklearn
+        nor torch (seconds each, paid by every server start); the
+        registered factory is the class's path and builds the class."""
+        import subprocess
+        import sys
+
+        code = (
+            "import sys, seldon_core_tpu.models\n"
+            "from seldon_core_tpu.engine.units import (BUILTIN_IMPLEMENTATIONS as B,\n"
+            "    implementation_path, make_builtin)\n"
+            "heavy = [m for m in ('sklearn', 'torch', 'pandas') if m in sys.modules]\n"
+            "assert not heavy, heavy\n"
+            "assert implementation_path('SKLEARN_SERVER') == "
+            "'seldon_core_tpu.models.sklearnserver.SKLearnServer'\n"
+            "assert implementation_path('TORCH_SERVER') == "
+            "'seldon_core_tpu.models.torchserver.TorchServer'\n"
+            "server = make_builtin('SKLEARN_SERVER', model_uri='nowhere')\n"
+            "assert type(server).__name__ == 'SKLearnServer' and 'sklearn' in sys.modules\n"
+            "print('ok')\n"
+        )
+        out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                             env=dict(__import__("os").environ, JAX_PLATFORMS="cpu"),
+                             timeout=300)
+        assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr[-2000:]
+
     def test_unsupported_objective_rejected(self, tmp_path):
         from seldon_core_tpu.models.xgboostserver import XGBoostServer
         from seldon_core_tpu.runtime.component import MicroserviceError

@@ -38,6 +38,18 @@ def _prompt(length, first):
     return ((np.arange(length, dtype=np.int32) * 7 + first) % 64).astype(np.int32)
 
 
+def _run_chained(eng, between=None):
+    """The halves as ``StreamingLM._loop`` composes them: wave N+1 is
+    launched before wave N is harvested."""
+    prev = None
+    while eng.has_work():
+        nxt = eng.launch()
+        if between is not None:
+            between(eng)
+        eng.harvest(prev)
+        prev = nxt
+
+
 def _seam_events(trace_dir):
     """``(name, start_ns, end_ns, stats)`` of every ``seldon.wave*``
     event of a recorded trace, read as the benchmark reads one."""
@@ -57,9 +69,15 @@ def _seam_events(trace_dir):
 
 
 class TestPhasesOnTheProfilersClock:
-    def test_every_wave_is_a_step_tiled_by_its_phases(self, tmp_path):
+    @pytest.mark.parametrize("loop", ["step_by_step", "chained"])
+    def test_every_wave_is_a_step_tiled_by_its_phases(self, tmp_path, loop):
+        """A step is admit, launch, then wait, harvest, record: of its own
+        wave when ``step()`` runs the halves back to back, of the wave
+        before when the serving loop chains them (the last wave of a
+        burst is then read outside any step: nothing is left to launch)."""
         import jax
 
+        run = _run_chained if loop == "chained" else (lambda e: e.run())
         eng = _tiny_engine()
         try:
             # warm every shape outside the trace, then the traced
@@ -75,10 +93,10 @@ class TestPhasesOnTheProfilersClock:
             jax.profiler.start_trace(str(tmp_path), profiler_options=options)
             try:
                 eng.submit(_prompt(5, 11), max_new_tokens=6)
-                eng.run()
+                run(eng)
                 for n, f in [(6, 12), (7, 13), (9, 14)] + [(20 + i, 15 + i) for i in range(5)]:
                     eng.submit(_prompt(n, f), max_new_tokens=6)
-                eng.run()
+                run(eng)
             finally:
                 jax.profiler.stop_trace()
             after = eng.engine_stats()
@@ -96,21 +114,50 @@ class TestPhasesOnTheProfilersClock:
             return [e for e in events if e[0] == f"seldon.wave.{name}"
                     and wave[1] <= e[1] and e[2] <= wave[2]]
 
-        prefills = []
-        for wave in waves:
-            got = {name: inside(wave, name) for name in PHASES}
+        def of_wave(name, number):
+            return [e for e in events if e[0] == f"seldon.wave.{name}"
+                    and e[3].get("wave") == number]
+
+        prefills, harvested, overlapped = [], [], []
+        for i, wave in enumerate(waves):
+            number = wave[3]["step_num"]
+            got = {name: inside(wave, name) for name in ("admit", "launch")}
+            assert {k: len(v) for k, v in got.items()} == {"admit": 1, "launch": 1}
+            # its own wave's readback, each phase once, wherever it lies
+            got.update({name: of_wave(name, number)
+                        for name in ("wait", "harvest", "record")})
             assert {k: len(v) for k, v in got.items()} == dict.fromkeys(PHASES, 1)
             admit, launch, wait, harvest, record = (got[n][0] for n in PHASES)
-            # the phases tile the step, in order
+            # the phases are in order, and tile the step they lie in
             assert admit[2] <= launch[1] and launch[2] <= wait[1]
             assert wait[2] <= harvest[1] and harvest[2] <= record[1]
+            read = [e for n in ("wait", "harvest", "record") for e in inside(wave, n)]
+            if loop == "step_by_step":
+                assert read == [wait, harvest, record]
+            else:
+                # what this step reads is the wave before, under the chunk
+                # it has just enqueued; its own is read by the next step,
+                # or outside any once nothing is left to launch
+                assert all(e[3]["wave"] == number - 1 and e[1] >= launch[2] for e in read)
+                assert len(read) == 3 * launch[3]["overlapped"]
+                if i + 1 < len(waves) and waves[i + 1][3]["step_num"] == number + 1 \
+                        and inside(waves[i + 1], "launch")[0][3]["overlapped"]:
+                    assert record[2] <= waves[i + 1][2] and wait[1] >= waves[i + 1][1]
+                else:
+                    assert wait[1] >= wave[2]
             assert set(admit[3]) >= {"admitted", "queue_depth"}
             assert set(launch[3]) >= {"steps", "lanes", "kv_tokens",
-                                      "pages_live", "page_slots"}
+                                      "pages_live", "page_slots", "overlapped"}
             assert 0 < launch[3]["pages_live"] <= launch[3]["page_slots"]
-            assert set(harvest[3]) >= {"tokens", "finished"}
+            assert set(harvest[3]) >= {"tokens", "finished", "wave"}
             assert launch[3]["steps"] == 4 and launch[3]["lanes"] >= 1
             prefills += inside(wave, "prefill")
+            harvested.append(harvest[3])
+            overlapped.append(launch[3]["overlapped"])
+        # a burst's first chunk finds the device empty, every later one
+        # is enqueued behind an unread wave iff the loop chains
+        assert sum(overlapped) == after["waves_overlapped"] - before["waves_overlapped"]
+        assert sum(overlapped) == (len(waves) - 2 if loop == "chained" else 0)
         # one prefill annotation per prefill group, carrying its work
         assert len(prefills) == after["prefill_chunks"] - before["prefill_chunks"] == 3
         shapes = sorted((p[3]["bucket"], p[3]["k"], p[3]["rows"], p[3]["padded"],
@@ -120,7 +167,6 @@ class TestPhasesOnTheProfilersClock:
             after["prefill_tokens"] - before["prefill_tokens"])
         assert sum(p[3]["padded"] for p in prefills) == (
             after["prefill_padded_tokens"] - before["prefill_padded_tokens"])
-        harvested = [inside(w, "harvest")[0][3] for w in waves]
         assert sum(h["tokens"] for h in harvested) == after["tokens"] - before["tokens"]
         assert sum(h["finished"] for h in harvested) == 9
         admitted = [inside(w, "admit")[0][3]["admitted"] for w in waves]
@@ -206,10 +252,16 @@ class TestCountedWhereItHappens:
         assert stats["decode_live_pages"] == live
         assert stats["decode_live_pages"] <= stats["decode_page_slots"]
 
-    def test_live_page_share_reader_takes_the_counters_deltas(self):
-        """``benchmarks/layer_metrics/decode_live_page_pct.py`` on a
-        hand-made ``ctx``; an engine without the counters (the parent of
-        PR 27) gives it nothing, and it does not raise."""
+    @pytest.mark.parametrize("name,part,whole", [
+        # PR 27: live pages of the page loop's slots
+        ("decode_live_page_pct", "decode_live_pages", "decode_page_slots"),
+        # PR 29: chunks enqueued behind an unread wave, of all chunks
+        ("wave_overlap_pct", "waves_overlapped", "chunks"),
+    ])
+    def test_share_readers_take_the_counters_deltas(self, name, part, whole):
+        """``benchmarks/layer_metrics/<name>.py`` on a hand-made ``ctx``;
+        an engine without the counters (the parent of the PR that brought
+        them) gives it nothing, and it does not raise."""
         import importlib.util
         import sys
 
@@ -218,8 +270,7 @@ class TestCountedWhereItHappens:
         sys.path.insert(0, bench)
         try:
             spec = importlib.util.spec_from_file_location(
-                "decode_live_page_pct", os.path.join(
-                    bench, "layer_metrics", "decode_live_page_pct.py"))
+                name, os.path.join(bench, "layer_metrics", f"{name}.py"))
             reader = importlib.util.module_from_spec(spec)
             spec.loader.exec_module(reader)
         finally:
@@ -229,8 +280,8 @@ class TestCountedWhereItHappens:
             return {"window": (100.0, 150.0), "engine": {"window": [before, after]},
                     "trace": None, "config": {}}
 
-        before = {"decode_live_pages": 100, "decode_page_slots": 400}
-        after = {"decode_live_pages": 1630, "decode_page_slots": 3400}
+        before = {part: 100, whole: 400}
+        after = {part: 1630, whole: 3400}
         assert reader.read(ctx(before, after)) == pytest.approx(51.0)
         older = {"tokens": 9, "decode_kv_tokens": 5}
         assert reader.read(ctx(older, dict(older, tokens=19))) is None
@@ -248,6 +299,49 @@ class TestCountedWhereItHappens:
             eng.submit(_prompt(5, 2), max_new_tokens=4)
             eng.run()
             assert eng.engine_stats()["host_gap_s"] - gap < 0.25
+        finally:
+            eng.close()
+
+    def test_no_gap_opens_while_a_wave_is_in_flight(self):
+        """A saturated loop, chained: the readback of wave N returns with
+        wave N+1 enqueued, so the device has its next program and the
+        host's time is no gap, however long it takes."""
+        eng = _tiny_engine()
+        try:
+            for i in range(8):
+                eng.submit(_prompt(5 + i, i + 1), max_new_tokens=64)
+            first = eng.launch()
+            gap = eng.engine_stats()["host_gap_s"]  # up to the first dispatch
+
+            def slow_host(_eng):
+                time.sleep(0.02)
+
+            prev = first
+            eng.harvest(None)
+            while eng.has_work():
+                nxt = eng.launch()
+                slow_host(eng)
+                assert eng.engine_stats()["host_gap_s"] == gap
+                eng.harvest(prev)
+                prev = nxt
+            stats = eng.engine_stats()
+            # (the burst's last readback leaves the device empty: its
+            # harvest and record are the engine's work, and counted)
+            assert stats["host_gap_s"] - gap < 0.015
+            gap = stats["host_gap_s"]
+            assert stats["chunks"] == 16
+            assert stats["waves_overlapped"] / stats["chunks"] > 0.9
+            # the same waves one at a time: every readback leaves the
+            # device empty, and the sleep is counted
+            for i in range(8):
+                eng.submit(_prompt(5 + i, i + 1), max_new_tokens=8)
+            while eng.has_work():
+                wave = eng.launch()
+                eng.harvest(wave)
+                slow_host(eng)
+            after = eng.engine_stats()
+            assert after["waves_overlapped"] == stats["waves_overlapped"]
+            assert after["host_gap_s"] - gap >= 0.02
         finally:
             eng.close()
 
@@ -503,6 +597,33 @@ def test_wave_gaps_tool_splits_a_gap_by_the_innermost_annotation():
     want = {"wait": 0.002, "harvest": 0.004, "uncovered": 0.001, "admit": 0.005,
             "prefill": 0.004, "between": 0.004}
     assert parts == pytest.approx(want)
+
+
+def test_wave_gaps_tool_splits_a_gap_under_an_overlapped_step():
+    """Chained, wave N's wait, harvest and record lie inside wave N+1's
+    step, after its launch: the innermost annotation still wins, and the
+    burst's last readback, outside any step, keeps its phases' names."""
+    from tools.profile_wave_gaps import gaps_of, split
+
+    ms = 1_000_000
+    ops = [(0, 10 * ms), (22 * ms, 60 * ms), (75 * ms, 80 * ms)]
+    assert gaps_of(ops) == [(10 * ms, 22 * ms), (60 * ms, 75 * ms)]
+    marks = [
+        (8 * ms, 40 * ms, "seldon.wave"),           # step N+1
+        (8 * ms, 9 * ms, "seldon.wave.admit"),
+        (9 * ms, 24 * ms, "seldon.wave.launch"),     # enqueues late: a gap
+        (14 * ms, 18 * ms, "seldon.wave.prefill"),
+        (24 * ms, 30 * ms, "seldon.wave.wait"),      # wave N's, under N+1's chunk
+        (30 * ms, 33 * ms, "seldon.wave.harvest"),
+        (33 * ms, 40 * ms, "seldon.wave.record"),
+        (41 * ms, 62 * ms, "seldon.wave.wait"),      # wave N+1's, no step left
+        (62 * ms, 66 * ms, "seldon.wave.harvest"),
+        (66 * ms, 70 * ms, "seldon.wave.record"),
+    ]
+    assert split((10 * ms, 22 * ms), marks) == pytest.approx(
+        {"launch": 0.008, "prefill": 0.004})
+    assert split((60 * ms, 75 * ms), marks) == pytest.approx(
+        {"wait": 0.002, "harvest": 0.004, "record": 0.004, "between": 0.005})
 
 
 @pytest.mark.parametrize("speculative,timed", [
