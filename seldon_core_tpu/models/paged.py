@@ -81,7 +81,8 @@ def paged_kernel_requested(mode: Optional[str] = None) -> bool:
 
 
 def paged_kernel_static_eligible(mode: str, mesh_absent: bool, dtype,
-                                 heads: int, head_dim: int) -> bool:
+                                 heads: int, head_dim: int,
+                                 latent: bool = False) -> bool:
     """THE pallas decode-kernel gate, shared by the LM's trace-time
     choice of lane and the engine's chunk-impl auto-select so the two
     cannot drift: requested by env (explicitly or via the "auto"
@@ -93,7 +94,10 @@ def paged_kernel_static_eligible(mode: str, mesh_absent: bool, dtype,
     slices out of HBM and Mosaic wants that minor dim in whole lane
     tiles (the interpreter takes any width).  A replica it turns down
     serves the ring chunk and the XLA gather.  The block adds only its
-    trace-local term (a decode step) on top."""
+    trace-local term (a decode step) on top.  ``latent``: the pool's
+    element is one latent row and the latent kernel's
+    (``ops/kernels.latent_attention_decode``), which cuts the row at its
+    128-aligned rank itself, so the width rule is not asked."""
     import jax
     import jax.numpy as jnp
 
@@ -104,8 +108,87 @@ def paged_kernel_static_eligible(mode: str, mesh_absent: bool, dtype,
         and mesh_absent
         and dtype in (jnp.bfloat16, jnp.float32)
         and (mode == "force" or jax.default_backend() == "tpu")
-        and ((heads * head_dim) % 128 == 0 or kernels.interpret_mode())
+        and (latent or (heads * head_dim) % 128 == 0
+             or kernels.interpret_mode())
     )
+
+
+# A prefill call pays for ``k * bucket`` positions (the group rounded up
+# to a power of two) and its temporaries grow with them.  Admission
+# groups only merge: whoever waits in the queue when a wave starts is
+# prefilled with it, so a burst of arrivals used to become ONE call of
+# any size — 32 prompts of 512 needed 18.43 GB of GPT-2-large's 15.75
+# (PERF.md §6 PR 26, ROADMAP S0c), 16 of 2,048 needed 20.42 GB of
+# GigaChat3.1's and failed all 16 (my chip run, PR 30).  So a call's
+# positions are capped at what its temporaries may take of the HBM left
+# beside the weights and the pool; a larger group is served as several
+# calls in the same wave, in arrival order.
+#
+# The share of that HBM one call's temporaries may take: the chunk
+# enqueued behind a prefill holds its own temporaries at the same time
+# (the runtime hands a program its buffers at dispatch, PERF.md §5), and
+# the allocator cannot use every gap.
+PREFILL_TEMP_SHARE = 0.5
+
+
+def prefill_position_bytes(spec, d_model: int, vocab_size: int,
+                           num_heads: int) -> int:
+    """Bytes of temporaries a prefill program keeps per padded position
+    where it keeps most, counted from the widths (an estimate of XLA's
+    buffer assignment good to a third: 8,192 positions of GigaChat3.1
+    were 3.0 GB by the compiler's count, 2.4 GB by this one):
+
+    * float32 logits at every position (ROADMAP S4) and the float32
+      residual stream beside its normed bf16 copy;
+    * the wider of the attention's rows — q, k, v in bf16 and the
+      attended values in float32; the naive latent path makes K and V
+      per head — and the FFN's: a dense layer's hidden rows (float32
+      and bf16; gate, up and their product for SwiGLU), or a routed
+      layer's rows for the assignments a token brings to the experts
+      held here (at :func:`moe.held_rows_cap`'s headroom), each its bf16
+      input, gate, up, product and float32 output, beside the shared
+      expert's."""
+    from seldon_core_tpu.ops import moe
+
+    kept = 4 * vocab_size + 6 * d_model
+    if spec.latent:
+        qk = spec.nope_dim + spec.rope_dim
+        attn = 2 * num_heads * (2 * qk + spec.v_dim) + 4 * num_heads * spec.v_dim
+    else:
+        attn = 10 * d_model
+    if not spec.routed:
+        ffn = 6 * 4 * d_model  # the GELU MLP's hidden rows
+    else:
+        swiglu = 10  # bytes a hidden value: gate, up, their product
+        ffn = swiglu * spec.dense_width if spec.dense_layers else 0
+        rows = spec.experts_per_tok * min(
+            1.0, moe.HELD_ROWS_HEADROOM * spec.held / spec.num_experts)
+        ffn = max(ffn, int(rows * (6 * d_model + swiglu * spec.expert_width))
+                  + swiglu * spec.shared_experts * spec.expert_width)
+    return kept + max(attn, ffn)
+
+
+def prefill_positions_max(free_bytes: Optional[int], position_bytes: int
+                          ) -> Optional[int]:
+    """The most positions one prefill call may pay for: the largest
+    power of two whose temporaries fit :data:`PREFILL_TEMP_SHARE` of
+    ``free_bytes``, at least one; None (no cap) where the device does
+    not say what it holds (the CPU)."""
+    if free_bytes is None:
+        return None
+    cap = 1
+    while 2 * cap * position_bytes <= PREFILL_TEMP_SHARE * max(free_bytes, 0):
+        cap *= 2
+    return cap
+
+
+def prefill_group_max(bucket: int, positions_max: Optional[int]) -> int:
+    """Prompts of ``bucket`` one prefill call takes under a cap of
+    ``positions_max`` positions (a power of two | None): at least one
+    (a bucket past the cap is still one prompt a call)."""
+    if positions_max is None:
+        return 1 << 30
+    return max(1, positions_max // bucket)
 
 
 def paged_kv_dtype_mode() -> str:
@@ -196,6 +279,8 @@ def _build_modules():
         value holds the layer's assignment histogram ``(int32[E],)``
         over the rows ``token_mask`` keeps (``()`` for a dense FFN)."""
         spec = mod.spec
+        if spec.score == "sigmoid":
+            return _ffn_grouped(mod, x, token_mask)
         d_model = x.shape[-1]
         y = _norm(spec, "ffn_norm")(x)
         if not spec.routed:
@@ -219,6 +304,167 @@ def _build_modules():
             experts, e,
             None if token_mask is None else token_mask.reshape(-1))
         return x + out.reshape(x.shape).astype(x.dtype), (hist,)
+
+    def _ffn_grouped(mod, x, token_mask):
+        """:func:`_ffn` for a spec whose router is DeepSeek-V3's: a
+        dense SwiGLU layer (``mod.routed_layer`` false; its histogram
+        is zeros, so the layers' stack keeps one shape), or sigmoid
+        group-limited routing over ``spec.num_experts`` with this
+        replica's ``spec.held`` experts computed (ops/moe.py
+        ``expert_ffn_held``) beside a shared expert."""
+        from seldon_core_tpu.ops import moe
+
+        spec = mod.spec
+        d_model = x.shape[-1]
+        rows = _norm(spec, "ffn_norm")(x).reshape(-1, d_model)
+        rest = _rest(spec, mod.dtype)
+        init = nn.initializers.normal(0.02)
+
+        def swiglu(name, width):
+            return moe.swiglu(
+                rows.astype(mod.dtype),
+                mod.param(f"{name}_gate", init, (d_model, width), rest),
+                mod.param(f"{name}_up", init, (d_model, width), rest),
+                mod.param(f"{name}_down", init, (width, d_model), rest))
+
+        e = spec.num_experts
+        if not mod.routed_layer:
+            out = swiglu("mlp", spec.dense_width)
+            hist = jnp.zeros((e,), jnp.int32)
+        else:
+            held, f = spec.held, spec.expert_width
+            w_router = mod.param("router", init, (d_model, e), jnp.float32)
+            bias = mod.param("score_bias", init, (e,), jnp.float32)
+            w_gate = mod.param("experts_gate", init, (held, d_model, f), rest)
+            w_up = mod.param("experts_up", init, (held, d_model, f), rest)
+            w_down = mod.param("experts_down", init, (held, f, d_model), rest)
+            gates, experts = moe.route_grouped(
+                rows, w_router, bias, spec.experts_per_tok, spec.n_group,
+                spec.topk_group, spec.norm_topk, spec.routed_scale)
+            out = moe.expert_ffn_held(
+                rows.astype(mod.dtype), w_gate, w_up, w_down, gates, experts,
+                spec.expert_offset, e)
+            if spec.shared_experts:
+                out = out + swiglu("shared", spec.shared_experts * f)
+            hist = moe.expert_histogram(
+                experts, e,
+                None if token_mask is None else token_mask.reshape(-1))
+        return x + out.reshape(x.shape).astype(x.dtype), (hist,)
+
+    def _latent_block(mod, x, pool, tables, lengths, layer, positions,
+                      token_mask):
+        """A block of latent attention (MLA): ``(x, row, None, hist)``
+        with ``row`` ``(B, L, W)`` this call's cache rows
+        ``[RMSNorm(c_kv) ; RoPE(k_r) ; 0]`` (``W`` = ``spec.cache_width``:
+        the values in whole lane tiles) for the caller to write — one
+        pool, no V.  ``pool`` is the whole ``(L, pages, ps, W)``
+        pool with ``layer`` an int (the kernel lane) or one layer of it.
+
+        Two attention paths in one model.  A segment (a prefill, a
+        cached suffix) is **naive**: K and V are made per head from the
+        latent rows — the cached prefix's, gathered through the block
+        table, and the segment's own — and attended causally
+        (``ops/mla.py naive_attention``).  A decode step is
+        **absorbed**: ``W_uk`` folds into q and ``W_uv`` into the
+        output, so the step reads each cached 576-wide row once for all
+        heads — the latent kernel where the LM hands over the whole
+        pool (``ops/kernels.latent_attention_decode``), a gather and two
+        einsums elsewhere — and the step's own row joins by the flash
+        rule."""
+        from seldon_core_tpu.models.spec import rope_interleaved, yarn_inv_freq
+        from seldon_core_tpu.ops import mla
+
+        spec = mod.spec
+        heads, rank = mod.num_heads, spec.kv_rank
+        nope, rdim, vdim = spec.nope_dim, spec.rope_dim, spec.v_dim
+        batch, seg_len, d_model = x.shape
+        whole = layer is not None
+
+        def proj(name, features, inp):
+            return _dense(mod.precision, features, mod.dtype, name, spec)(inp)
+
+        def rms(name):
+            return nn.RMSNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
+                              name=name)
+
+        y = _norm(spec, "attn_norm")(x)
+        c_q = rms("q_a_norm")(proj("q_a", spec.q_rank, y))
+        q = proj("q_b", heads * (nope + rdim), c_q.astype(mod.dtype)).reshape(
+            batch, seg_len, heads, nope + rdim)
+        kva = proj("kv_a", rank + rdim, y)
+        c_kv = rms("kv_a_norm")(kva[..., :rank])
+        inv = yarn_inv_freq(spec)
+        q_nope = q[..., :nope]
+        q_rope = rope_interleaved(q[..., nope:], positions, inv).astype(mod.dtype)
+        k_rope = rope_interleaved(
+            kva[..., None, rank:], positions, inv)[..., 0, :]
+        # the cache row, as attention reads it: normed, rotated, in the
+        # pool's type (this call attends its own rows in that type too,
+        # so a prompt prefilled whole and one resumed from cached pages
+        # see the same keys)
+        lanes = spec.cache_width(d_model)  # the row in whole lane tiles
+        tail = jnp.zeros((batch, seg_len, lanes - rank - rdim), mod.dtype)
+        row = jnp.concatenate(
+            [c_kv.astype(mod.dtype), k_rope.astype(mod.dtype), tail], axis=-1)
+        rest = _rest(spec, mod.dtype)
+        init = nn.initializers.normal(0.02)
+        # W_kvb rests split: (heads, rank, nope) makes k_nope from c_kv
+        # (or folds into q), (heads, rank, v) makes v (or unfolds the
+        # attended latent)
+        w_uk = mod.param("kv_b_k", init, (heads, rank, nope), rest)
+        w_uv = mod.param("kv_b_v", init, (heads, rank, vdim), rest)
+        scale = spec.softmax_scale
+
+        def cached(tb):
+            """A bucket's cached rows (nb, C, W), or None for a table
+            of no width (a prefill from position 0 reads no cache)."""
+            if tb.shape[1] == 0:
+                return None
+            rows = pool[layer, tb] if whole else pool[tb]
+            return rows.reshape(tb.shape[0], -1, rows.shape[-1])
+
+        outs, off = [], 0
+        for tb in tables:
+            nb = tb.shape[0]
+            sl = slice(off, off + nb)
+            off += nb
+            if seg_len > 1:
+                outs.append(mla.naive_attention(
+                    q_nope[sl], q_rope[sl], cached(tb), lengths[sl], row[sl],
+                    w_uk, w_uv, scale, mod.dtype))
+                continue
+            q_abs = jnp.einsum(
+                "bhn,hrn->bhr", q_nope[sl][:, 0], w_uk.astype(mod.dtype),
+                preferred_element_type=jnp.float32)
+            q_full = (jnp.concatenate(
+                [q_abs, q_rope[sl][:, 0].astype(jnp.float32),
+                 jnp.zeros((nb, heads, lanes - rank - rdim), jnp.float32)],
+                axis=-1) * scale).astype(mod.dtype)            # (nb, h, W)
+            if whole:
+                from seldon_core_tpu.ops.kernels import latent_attention_decode
+
+                state = latent_attention_decode(
+                    q_full, pool, tb, lengths[sl], layer=layer,
+                    page_size=pool.shape[2], rank=rank)
+            else:
+                rows = cached(tb)
+                valid = jnp.arange(rows.shape[1])[None, :] < lengths[sl][:, None]
+                state = mla.ctx_state(q_full, rows, valid, rank)
+            own = row[sl]                                      # (nb, 1, W)
+            latent = mla.merge(
+                state, mla.ctx_state(
+                    q_full, own, jnp.ones(own.shape[:2], bool), rank))
+            # (heads lead on both sides: the CPU backend has no bf16
+            # thunk for the "bhr,hrv->bhv" form)
+            out = jnp.einsum(
+                "hbr,hrv->hbv", jnp.swapaxes(latent, 0, 1).astype(mod.dtype),
+                w_uv.astype(mod.dtype), preferred_element_type=jnp.float32)
+            outs.append(jnp.swapaxes(out, 0, 1).astype(mod.dtype)[:, None])
+        attn = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
+        attn = attn.reshape(batch, seg_len, heads * vdim)
+        x = x + proj("attn_proj", d_model, attn)
+        x, hist = _ffn_grouped(mod, x, token_mask)
+        return (x, row, None, *hist)
 
     def _embed(lm, tokens, positions):
         tokens = tokens.astype(jnp.int32)
@@ -244,7 +490,8 @@ def _build_modules():
         x = _norm(lm.spec, "final_norm")(x)
         logits = _dense(lm.precision, lm.vocab_size, lm.dtype, "head",
                         lm.spec)(x)
-        out = (logits.astype(jnp.float32), jnp.stack(new_k), jnp.stack(new_v))
+        out = (logits.astype(jnp.float32), jnp.stack(new_k),
+               None if new_v[0] is None else jnp.stack(new_v))  # one pool: no V
         return out + (jnp.stack(hists),) if hists else out
 
     class PagedTransformerBlock(nn.Module):
@@ -259,6 +506,7 @@ def _build_modules():
         dtype: Any = jnp.bfloat16
         precision: str = "bf16"  # "w8a8": int8×int8 projections
         spec: Any = GPT2
+        routed_layer: bool = True  # a spec with leading dense layers
 
         @nn.compact
         def __call__(self, x, pk, pv, block_tables, lengths,
@@ -301,6 +549,10 @@ def _build_modules():
                 if isinstance(block_tables, (tuple, list))
                 else (block_tables,)
             )
+            if self.spec.latent:
+                # one latent pool (pv is None), another attention
+                return _latent_block(self, x, pk, tables, lengths, layer,
+                                     positions, token_mask)
             d_model = x.shape[-1]
             heads = self.num_heads
             head_dim = d_model // heads
@@ -664,6 +916,12 @@ def _build_modules():
         def __call__(self, tokens, positions, ctx_k, ctx_v, ring_k, ring_v,
                      step, len0, lora=None, adapter_idx=None,
                      token_mask=None):
+            if self.spec.latent:
+                raise ValueError(
+                    f"arch={self.spec.name!r} caches one latent row a "
+                    "token: the ring chunk's pre-gathered K and V context "
+                    "and ring are not built for it yet — it serves the "
+                    "pool chunk (SELDON_TPU_CHUNK_IMPL=pool or unset)")
             x = _embed(self, tokens, positions)
             bucketed = isinstance(ctx_k, (tuple, list))
             new_k, new_v, hists = [], [], []
@@ -727,6 +985,7 @@ def _build_modules():
             whole = self.decode_kernel and paged_kernel_static_eligible(
                 paged_kernel_mode(), True, self.dtype,
                 self.num_heads, self.d_model // self.num_heads,
+                latent=self.spec.latent,
             )
             new_k, new_v, hists = [], [], []
             for i in range(self.num_layers):
@@ -744,11 +1003,15 @@ def _build_modules():
                             if kv_scales is not None else None
                         ),
                     )
-                    pools = (pages_k[i], pages_v[i])
+                    pools = (pages_k[i],
+                             None if pages_v is None else pages_v[i])
+                kinds = ({"routed_layer": False}
+                         if self.spec.routed and not self.spec.layer_routed(i)
+                         else {})
                 x, k, v, *hist = PagedTransformerBlock(
                     num_heads=self.num_heads, dtype=self.dtype,
                     precision=self.precision, name=f"block_{i}",
-                    spec=self.spec,
+                    spec=self.spec, **kinds,
                 )(x, *pools, block_tables, lengths,
                   adapter_idx=adapter_idx, **per_layer,
                   positions=positions, token_mask=token_mask)
@@ -871,6 +1134,9 @@ def write_kv(pk, pv, new_k, new_v, block_tables, start, valid, *, page_size, max
         new_k = new_k.reshape(*new_k.shape[:3], -1)
         new_v = new_v.reshape(*new_v.shape[:3], -1)
 
+    # a latent cache is ONE pool of rows (models/spec.py cache_pools):
+    # pv and new_v are None, and every write below is the K write alone
+    two = pv is not None
     seg_len = new_k.shape[2]
     B = new_k.shape[1]
     if seg_len == 1:
@@ -884,9 +1150,10 @@ def write_kv(pk, pv, new_k, new_v, block_tables, start, valid, *, page_size, max
             pk = jax.lax.dynamic_update_slice(
                 pk, new_k[:, s][:, None], (0, page, offs[s], 0)
             )
-            pv = jax.lax.dynamic_update_slice(
-                pv, new_v[:, s][:, None], (0, page, offs[s], 0)
-            )
+            if two:
+                pv = jax.lax.dynamic_update_slice(
+                    pv, new_v[:, s][:, None], (0, page, offs[s], 0)
+                )
         return pk, pv
 
     if from_zero:
@@ -901,9 +1168,11 @@ def write_kv(pk, pv, new_k, new_v, block_tables, start, valid, *, page_size, max
                 pk = jax.lax.dynamic_update_slice(
                     pk, new_k[:, s, lo : lo + blen][:, None], (0, page, 0, 0)
                 )
-                pv = jax.lax.dynamic_update_slice(
-                    pv, new_v[:, s, lo : lo + blen][:, None], (0, page, 0, 0)
-                )
+                if two:
+                    pv = jax.lax.dynamic_update_slice(
+                        pv, new_v[:, s, lo : lo + blen][:, None],
+                        (0, page, 0, 0)
+                    )
         return pk, pv
 
     # short mid-sequence segments (draft_k+1 wide): token-wise DUS
@@ -919,9 +1188,11 @@ def write_kv(pk, pv, new_k, new_v, block_tables, start, valid, *, page_size, max
             pk = jax.lax.dynamic_update_slice(
                 pk, new_k[:, s, t][:, None, None], (0, page, offs[s, t], 0)
             )
-            pv = jax.lax.dynamic_update_slice(
-                pv, new_v[:, s, t][:, None, None], (0, page, offs[s, t], 0)
-            )
+            if two:
+                pv = jax.lax.dynamic_update_slice(
+                    pv, new_v[:, s, t][:, None, None],
+                    (0, page, offs[s, t], 0)
+                )
     return pk, pv
 
 
@@ -1876,6 +2147,17 @@ class PagedEngine:
                 "the grouped expert matmul is a custom call GSPMD cannot "
                 "partition — serve it on one chip (tp=1, dp=1)"
             )
+        if spec.latent and speculative:
+            raise ValueError(self._latent_refusal(
+                "the speculative lane",
+                "its verify forward writes k + 1 rows a lane and rolls "
+                "back by length, which the latent block's two attention "
+                "paths (a segment naive, a step absorbed) have not been "
+                "held to — serve it with speculative=None"))
+        if spec.latent and _knobs.flag("SELDON_TPU_KV_OFFLOAD"):
+            raise ValueError(self._latent_refusal(
+                "the host KV tier (SELDON_TPU_KV_OFFLOAD)",
+                "its containers hold a K and a V block of d_model a page"))
         if quantize == "int8":
             # weight-only int8: weights rest in HBM at half the bytes
             # and dequantise once per chunk program (measured 1.38x
@@ -1934,6 +2216,9 @@ class PagedEngine:
             self.num_pages += -self.num_pages % _dp
         self.prompt_buckets = sorted(set(prompt_buckets or _buckets_for(max_len)))
         head_dim = d_model // num_heads
+        # the cache's geometry is the model's (models/spec.py): K and V
+        # of d_model each, or one latent row of kv_rank + rope_dim
+        self.cache_width = int(spec.cache_width(d_model))
         module_precision = "w8a8" if self.precision == "w8a8" else "bf16"
         self.module = get_paged_lm_class()(
             vocab_size=vocab_size, d_model=d_model, num_layers=num_layers,
@@ -1965,11 +2250,20 @@ class PagedEngine:
         # and is warned about.
         kernel_mode = paged_kernel_mode()
         kernel_eligible = paged_kernel_static_eligible(
-            kernel_mode, mesh is None, dtype, num_heads, head_dim
+            kernel_mode, mesh is None, dtype, num_heads, head_dim,
+            latent=spec.latent,
         )
         self._chunk_impl = _knobs.raw("SELDON_TPU_CHUNK_IMPL", "")
+        if spec.latent and self._chunk_impl == "ring":
+            raise ValueError(self._latent_refusal(
+                "the ring chunk (SELDON_TPU_CHUNK_IMPL=ring)",
+                "its once-per-chunk context and ring are K and V buffers "
+                "split into heads — leave the knob unset or set it to pool"))
         if not self._chunk_impl:
-            self._chunk_impl = "pool" if kernel_eligible else "ring"
+            # a latent pool decodes in the pool chunk whichever lane its
+            # attention takes (the kernel, or the gather and einsums)
+            self._chunk_impl = (
+                "pool" if kernel_eligible or spec.latent else "ring")
             if kernel_eligible:
                 logger.info(
                     "SELDON_TPU_PAGED_KERNEL is set: auto-selecting the pool "
@@ -2028,6 +2322,12 @@ class PagedEngine:
         # not priced — both degrade to the native pool with a WARN.
         kv_dtype = paged_kv_dtype_mode()
         self._kv_int8 = False
+        if kv_dtype == "int8" and spec.latent:
+            raise ValueError(self._latent_refusal(
+                "the int8 KV pool (SELDON_TPU_KV_DTYPE=int8)",
+                "one scale a page would span a normed latent and a rotary "
+                "key of different ranges, and the latent kernel has no "
+                "dequantising lane"))
         if kv_dtype == "int8":
             if mesh is not None or self._chunk_impl != "pool":
                 logger.warning(
@@ -2057,11 +2357,13 @@ class PagedEngine:
 
         self.params, self.pages_k, self.pages_v = shard_decode_state(
             params, mesh,
-            pool_shape=(num_layers, self.num_pages, self.page_size, d_model),
+            pool_shape=(num_layers, self.num_pages, self.page_size,
+                        self.cache_width),
             dtype=pool_dtype,
             model_axis=model_axis, data_axis=data_axis,
             min_weight_size=shard_min_weight_size,
             num_heads=num_heads, seq_shard=self._seq_shard,
+            pools=spec.cache_pools,
         )
         # the served tree as it rests (all shards): lane_report's
         # weight_bytes, paged_hbm_accounting's fixed term
@@ -2094,9 +2396,34 @@ class PagedEngine:
             self._pool_shard_bytes = 2 * int(shard.nbytes)
         else:
             self.tp_degree = 1
-            self._pool_shard_bytes = 2 * int(self.pages_k.nbytes)
+            self._pool_shard_bytes = spec.cache_pools * int(self.pages_k.nbytes)
             if self._kv_int8:
                 self._pool_shard_bytes += 2 * int(self.scales_k.nbytes)
+        if spec.experts_held:
+            from seldon_core_tpu.ops import moe as _moe
+
+            self._moe_held_pass_rows = _moe.held_rows_cap(
+                self.max_slots, spec.experts_per_tok, spec.held,
+                spec.num_experts)
+        else:
+            self._moe_held_pass_rows = 0
+        # what one prefill call may pay for (module top): from what this
+        # device says it holds, less the weights as they rest (a
+        # float32 tree is cast to the compute type inside each program:
+        # half as much again while one runs) and the pool
+        limit = (self.pages_k.addressable_shards[0].device.memory_stats()
+                 or {}).get("bytes_limit")
+        resting = self._weight_bytes // self.tp_degree
+        if spec.weights_f32 and np.dtype(dtype).itemsize < 4:
+            resting += resting // 2
+        self.prefill_positions_max = prefill_positions_max(
+            None if limit is None
+            else int(limit) - resting - self._pool_shard_bytes,
+            prefill_position_bytes(spec, d_model, self.vocab_size, num_heads))
+        logger.info(
+            "a prefill call takes at most %s positions (%s B of HBM, %d "
+            "resting, %d pool)", self.prefill_positions_max, limit, resting,
+            self._pool_shard_bytes)
         # lane sharding (r19): under dp>1 the slot-major host arrays
         # (logits, block tables, sampling knobs, rng keys) batch-shard
         # on the data axis — each replica group carries max_slots/dp
@@ -2208,6 +2535,11 @@ class PagedEngine:
         if not max_adapters:
             max_adapters = int(_knobs.raw("SELDON_TPU_MAX_ADAPTERS", "0") or 0)
         self.max_adapters = max(0, int(max_adapters))
+        if spec.latent and self.max_adapters:
+            raise ValueError(self._latent_refusal(
+                "adapters (max_adapters > 0)",
+                "the LoRA pools name the qkv and mlp projections of a "
+                "multi-head block, which this one does not have"))
         if spec.rope and self.max_adapters:
             raise ValueError(
                 f"arch={spec.name!r} rotates q and k between the qkv "
@@ -2329,6 +2661,17 @@ class PagedEngine:
                           # step streams per layer
                           "moe_assignments": 0,
                           "moe_active_expert_steps": 0, "moe_layer_steps": 0,
+                          # a replica that holds a share of the experts
+                          # (spec.experts_held): the assignments that
+                          # fell to experts it holds, and the held
+                          # experts hit summed over decode (routed
+                          # layer, step)s — 0 where every expert is held
+                          "moe_local_assignments": 0,
+                          "moe_held_active_expert_steps": 0,
+                          # cached latent rows read by decode
+                          # lane-steps, summed over the layers (a latent
+                          # pool: decode_kv_tokens x layers; 0 otherwise)
+                          "latent_kv_tokens": 0,
                           # waiting where it happens: seconds (and
                           # streams) between submit and a stream's first
                           # prefill slice — the engine's own queue —
@@ -2623,6 +2966,22 @@ class PagedEngine:
 
     # ---- jitted programs --------------------------------------------------
 
+    def _latent_refusal(self, what: str, why: str) -> str:
+        """The one wording of a lane a latent pool cannot take yet."""
+        return (
+            f"arch={self.spec.name!r} caches one latent row of "
+            f"{self.spec.cache_values} values a token in one pool (no V): "
+            f"{what} cannot take a latent pool yet — {why}"
+        )
+
+    def _refuse_latent(self, what: str) -> None:
+        """Containers that carry K and V pages of ``d_model`` between
+        engines (disaggregated prefill, migration) raise here."""
+        if self.spec.latent:
+            raise ValueError(self._latent_refusal(
+                what, "its container holds a \"k\" and a \"v\" block of "
+                "d_model a page"))
+
     def _write_kv(self, pk, pv, new_k, new_v, block_row_or_tables, start, valid,
                   from_zero: bool = False):
         return write_kv(
@@ -2804,9 +3163,14 @@ class PagedEngine:
             lengths = jnp.zeros((k,), jnp.int32)
             pk_pages, sk = kv_split(pk)
             pv_pages, sv = kv_split(pv)
+            # a latent block gathers the cached rows a segment attends
+            # (the multi-head block's gather of them is masked out by
+            # lengths 0, which XLA cannot elide): from position 0 there
+            # are none, and a table of no width says so
+            read_rows = block_rows[:, :0] if self.spec.latent else block_rows
             logits, nk, nv, hist = self._lm(
                 self.module, params, tokens, positions, pk_pages, pv_pages,
-                block_rows, lengths, lora=lora, adapter_idx=adapter_idx,
+                read_rows, lengths, lora=lora, adapter_idx=adapter_idx,
                 kv_scales=kv_scales_arg(sk, sv),
                 token_mask=self._routed_rows(bucket, true_lens),
             )
@@ -3066,6 +3430,8 @@ class PagedEngine:
             # int8 pool's (pages, scales) bundle abstracts leaf-wise.
             if isinstance(p, tuple):
                 return tuple(pool_arg(x) for x in p)
+            if p is None:  # a latent cache has no V pool
+                return None
             if self._mesh is not None:
                 return jax.ShapeDtypeStruct(p.shape, p.dtype,
                                             sharding=p.sharding)
@@ -3328,8 +3694,11 @@ class PagedEngine:
         ``()`` for a dense spec: its carry and outputs are as they were."""
         if not self.spec.routed:
             return ()
+        # a spec that holds a share carries a fourth kind of column: the
+        # HELD experts hit, summed over the steps
         return (self._jnp.zeros(
-            (self.module.num_layers, self.spec.num_experts + 2),
+            (self.module.num_layers,
+             self.spec.num_experts + 2 + bool(self.spec.experts_held)),
             self._jnp.int32),)
 
     def _moe_step(self, moe, hist, active):
@@ -3341,7 +3710,16 @@ class PagedEngine:
         ran = jnp.broadcast_to(
             jnp.any(active).astype(jnp.int32), (hist.shape[0], 1))
         hit = (hist > 0).sum(axis=1, keepdims=True).astype(jnp.int32)
-        return (moe[0] + jnp.concatenate([hist, hit, ran], axis=1),)
+        cols = [hist, hit, ran]
+        spec = self.spec
+        if spec.dense_layers:  # a dense layer routes nothing: no step of its
+            routed = (jnp.arange(hist.shape[0]) >= spec.dense_layers)
+            cols[2] = ran * routed[:, None].astype(jnp.int32)
+        if spec.experts_held:
+            lo = spec.expert_offset
+            cols.append((hist[:, lo:lo + spec.held] > 0).sum(
+                axis=1, keepdims=True).astype(jnp.int32))
+        return (moe[0] + jnp.concatenate(cols, axis=1),)
 
     def _chunk_fn_pool(
         self, steps, buckets, params, pk, pv, logits, lengths, block_tables,
@@ -4960,16 +5338,15 @@ class PagedEngine:
             )
             target.setdefault(bucket, []).append((stream, start, n))
             tokens += n
-        for bucket, group in plain.items():
-            completed.extend(
-                self._prefill_group(bucket, group, use_cache=False)
-            )
-            calls += 1
-        for bucket, group in cached.items():
-            completed.extend(
-                self._prefill_group(bucket, group, use_cache=True)
-            )
-            calls += 1
+        # one device call a (bucket, kind) group, cut where the call's
+        # padded positions would pass prefill_positions_max
+        for use_cache, by_bucket in ((False, plain), (True, cached)):
+            for bucket, joined in by_bucket.items():
+                most = prefill_group_max(bucket, self.prefill_positions_max)
+                for lo in range(0, len(joined), most):
+                    completed.extend(self._prefill_group(
+                        bucket, joined[lo:lo + most], use_cache=use_cache))
+                    calls += 1
         wall = _time.perf_counter() - t_start
         with self._lock:
             self._counters["queue_wait_s"] += queue_wait
@@ -5018,7 +5395,7 @@ class PagedEngine:
             # and none is dropped, so the host knows the count the
             # program's histogram will add up to
             {"assignments": tokens * self.spec.experts_per_tok
-                            * self.module.num_layers}
+                            * (self.module.num_layers - self.spec.dense_layers)}
             if self.spec.routed else {}
         )
         self._seam.begin_prefill(
@@ -5496,6 +5873,7 @@ class PagedEngine:
         payload for :meth:`submit_prefilled` on a decode engine.
         ``drive=False`` when another thread owns the step loop (the
         single-stepper invariant); the default drives inline."""
+        self._refuse_latent("a disaggregated prefill export")
         stream = self.submit(
             np.asarray(prompt), max_new_tokens=1, seed=seed,
             priority=priority, deadline=deadline, kv_export=True,
@@ -5516,6 +5894,7 @@ class PagedEngine:
         machinery applies unchanged).  The payload is validated against
         this engine's pool geometry first, because a scatter of
         mismatched bytes would serve garbage rather than raise."""
+        self._refuse_latent("a disaggregated prefill import")
         prompt = np.asarray(payload["prompt"], np.int32).reshape(-1)
         k = np.asarray(payload["k"])
         v = np.asarray(payload["v"])
@@ -5632,6 +6011,10 @@ class PagedEngine:
                 and s.kv_import is None
                 and s.prefilled >= len(s.prompt)
                 and self.speculative is None
+                # a latent pool's pages fit no migration container yet:
+                # its streams are the drain journal's, like a
+                # speculative engine's
+                and not self.spec.latent
             ]
         if not exportable:
             return []
@@ -5727,6 +6110,7 @@ class PagedEngine:
         the payload's original mode)."""
         import time as _time
 
+        self._refuse_latent("a migration import")
         prompt = np.asarray(payload["prompt"], np.int32).reshape(-1)
         tokens = np.asarray(payload.get("tokens", []), np.int32).reshape(-1)
         k = np.asarray(payload["k"])
@@ -6170,7 +6554,19 @@ class PagedEngine:
             # as they rest (paged_hbm_accounting's weight_bytes)
             "arch": self.spec.name,
             "weight_bytes": self._weight_bytes,
+            # what the cache holds: the attention kind, the lanes of a
+            # token's row per layer and pool, and of a routed spec's
+            # experts how many rest here
+            "attention": self.spec.attention,
+            "cache_width": self.cache_width,
+            "experts_held": self.spec.held if self.spec.routed else 0,
         }
+
+    def _moe_held_hits(self):
+        """Cumulative assignments per (routed layer, expert held here)."""
+        spec = self.spec
+        lo = spec.expert_offset
+        return self._moe_hits[spec.dense_layers:, lo:lo + spec.held]
 
     def _moe_hold(self, counts):
         """Keep a just-dispatched program's routing counts (``()`` for a
@@ -6210,7 +6606,7 @@ class PagedEngine:
         chunk, prefills = moe_np
         e = self.spec.num_experts
         if chunk is None:
-            chunk = np.zeros((self._moe_hits.shape[0], e + 2), np.int64)
+            chunk = np.zeros((self._moe_hits.shape[0], e + 3), np.int64)
         hits = chunk[:, :e].astype(np.int64)
         for h in prefills:
             hits = hits + h
@@ -6219,6 +6615,12 @@ class PagedEngine:
         active, steps = int(chunk[:, e].sum()), int(chunk[:, e + 1].sum())
         self._counters["moe_active_expert_steps"] += active
         self._counters["moe_layer_steps"] += steps
+        if self.spec.experts_held:
+            lo = self.spec.expert_offset
+            self._counters["moe_local_assignments"] += int(
+                hits[:, lo:lo + self.spec.held].sum())
+            active = int(chunk[:, e + 2].sum())  # of the experts held here
+            self._counters["moe_held_active_expert_steps"] += active
         return {"experts_active": round(active / max(steps, 1), 2)}
 
     def engine_stats(self, detail: bool = False) -> Dict[str, Any]:
@@ -6244,6 +6646,7 @@ class PagedEngine:
         else:
             health, health_code, watchdog_trips = "healthy", 0, 0
         with self._lock:
+            held_hits = self._moe_held_hits()
             out = {
                 **self._counters,
                 "active_slots": sum(s is not None for s in self._slots),
@@ -6320,10 +6723,16 @@ class PagedEngine:
                 # routed experts: the busiest (layer, expert) pair's
                 # cumulative assignments and the mean over pairs — how
                 # far routing is from even (0 for a dense spec)
-                "moe_load_max": int(self._moe_hits.max(initial=0)),
+                # (a replica that holds a share: over its routed layers'
+                # HELD experts, the ones whose load it carries)
+                "moe_load_max": int(held_hits.max(initial=0)),
                 "moe_load_mean": (
-                    float(self._moe_hits.mean()) if self._moe_hits.size
-                    else 0.0),
+                    float(held_hits.mean()) if held_hits.size else 0.0),
+                # rows one pass of a decode step's held experts takes
+                # (ops/moe.py held_rows_cap at max_slots tokens): the
+                # row count of its grouped matmuls in a trace; 0 unless
+                # this replica holds a share
+                "moe_held_pass_rows": self._moe_held_pass_rows,
             }
             moe_expert_hits = (  # cumulative assignments per expert
                 self._moe_hits.sum(axis=0).tolist()
@@ -6925,6 +7334,8 @@ class PagedEngine:
             self._seam.stats(
                 steps=steps, lanes=len(runnable_now),
                 kv_tokens=sum(lens0.values()),
+                latent_tokens=(
+                    sum(lens0.values()) if self.spec.latent else 0),
                 pages_live=sum(self._pages_of(n) for n in lens0.values()),
                 page_slots=step_slots, overlapped=int(overlapped),
             )
@@ -7096,9 +7507,11 @@ class PagedEngine:
                 # step t attended the len0 + t tokens cached before it
                 n = int(emitted_np[slot])
                 self._counters["decode_lane_steps"] += n
-                self._counters["decode_kv_tokens"] += (
-                    n * len0 + n * (n - 1) // 2
-                )
+                read = n * len0 + n * (n - 1) // 2
+                self._counters["decode_kv_tokens"] += read
+                if self.spec.latent:  # a row a layer
+                    self._counters["latent_kv_tokens"] += (
+                        read * self.module.num_layers)
                 self._counters["decode_live_pages"] += sum(
                     self._pages_of(len0 + t * grow) for t in range(n))
             # every launched step walks every lane's table, live or not
@@ -7536,6 +7949,7 @@ class StreamingLM(TPUComponent):
         num_experts: int = 0,
         experts_per_tok: int = 0,
         expert_width: int = 0,
+        arch_sizes: Any = None,
         **kwargs: Any,
     ):
         super().__init__(**kwargs)
@@ -7544,10 +7958,18 @@ class StreamingLM(TPUComponent):
         # the block the deployment serves (models/spec.py): ``arch``
         # names it, the sizes (0 = as published) resize it; an unknown
         # arch fails here, at construction
-        self.spec = model_spec(
-            str(arch), num_experts=num_experts,
-            experts_per_tok=experts_per_tok, expert_width=expert_width,
-        )
+        # ``arch_sizes`` (a JSON object) resizes any further field the
+        # arch has, by the names models/spec.py gives them: a replica's
+        # share of an expert-parallel layer (experts_held,
+        # expert_offset), its layer kinds (dense_layers), a test's
+        # ranks and head widths
+        if isinstance(arch_sizes, str):
+            import json as _json
+
+            arch_sizes = _json.loads(arch_sizes) if arch_sizes else None
+        self.spec = model_spec(str(arch), **{
+            "num_experts": num_experts, "experts_per_tok": experts_per_tok,
+            "expert_width": expert_width, **dict(arch_sizes or {})})
         self.config = dict(
             vocab_size=int(vocab_size), d_model=int(d_model),
             num_layers=int(num_layers), num_heads=int(num_heads),

@@ -1,21 +1,37 @@
 """What a decoder block is made of: the one description the paged
-block, its decode-chunk twin, the LM around them and the parameter
-initialiser all read.
+block, its decode-chunk twin, the LM around them, the KV pool and the
+parameter initialiser all read.
 
-The paged engine's contract with a model is narrow: per layer a cache
-element of ``num_heads x head_dim = d_model`` for K and for V, written
-after whatever the model does to K (positions, norms), and next-token
-logits.  Everything around the cache is the model's: how positions
-enter (a learned table added to the embedding | rotary embedding on q
-and k before the cache write), which norm (LayerNorm | RMSNorm), a
-norm on q and k, and the feed-forward (dense GELU MLP | routed SwiGLU
-experts, :mod:`seldon_core_tpu.ops.moe`).  ``GPT2`` and ``OLMOE`` are
-the two values served; a new architecture is a new value (and new
-branches where the block reads a field it has not met), not a new
-block.
+The paged engine's contract with a model: per layer **one cache row a
+token**, ``cache_width`` values wide, in ``cache_pools`` pools of one
+shape ``(layers, pages, page_size, cache_width)``, written after
+whatever the model does to what it caches (positions, norms), and
+next-token logits.  Multi-head attention caches K and V, each ``num_heads
+x head_dim = d_model`` wide, in two pools; latent attention (MLA) caches
+one row ``[c_kv ; RoPE(k_r)]`` of ``kv_rank + rope_dim`` values (in
+whole 128-lane tiles: 576 values rest in 640 lanes) in one pool and no
+V.  Everything around the cache is the model's: how
+positions enter (a learned table added to the embedding | rotary
+embedding before the cache write, plain or YaRN-scaled), which norm
+(LayerNorm | RMSNorm), a norm on q and k, the attention's projections
+(fused qkv | low-rank q and a shared latent for k and v, absorbed into
+q and the output in a decode step), which layers are dense and which
+routed, how the router scores and picks (softmax top-k | sigmoid with a
+selection-only bias and group-limited top-k, renormalised and scaled),
+a shared expert beside the routed ones, and **which routed experts this
+replica holds** (``experts_held`` from ``expert_offset``, of
+``num_experts`` the router scores: one chip's share of an
+expert-parallel layer computes its own experts' part and nothing
+stands in for the rest).
 
-``head_dim`` is not a field: the pool's element is ``d_model`` wide and
-heads split it evenly, so it is ``d_model // num_heads`` everywhere.
+``GPT2``, ``OLMOE`` and ``DEEPSEEK_V3`` are the values served; a new
+architecture is a new value (and new branches where the block reads a
+field it has not met), not a new block.
+
+For multi-head attention ``head_dim`` is not a field: the pool's
+element is ``d_model`` wide and heads split it evenly.  Latent
+attention's head widths (``nope_dim``, ``rope_dim``, ``v_dim``) and
+ranks are fields: none follows from ``d_model``.
 """
 
 from __future__ import annotations
@@ -49,10 +65,94 @@ class ModelSpec:
     # in the compute type, made so and never cast (norm scales and a
     # router rest in float32 either way)
     weights_f32: bool = True
+    # ---- attention: "mha" (K and V of d_model each) | "mla" (one
+    # latent row of kv_rank + rope_dim; q through a q_rank bottleneck;
+    # heads of nope_dim + rope_dim against values of v_dim)
+    attention: str = "mha"
+    q_rank: int = 0
+    kv_rank: int = 0
+    nope_dim: int = 0
+    rope_dim: int = 0
+    v_dim: int = 0
+    # YaRN (rope_factor 1 = plain RoPE): the inverse frequencies blend
+    # interpolation and extrapolation between the beta_fast and
+    # beta_slow correction dims of the original context, and the
+    # softmax scale carries mscale_all_dim's m squared
+    rope_factor: float = 1.0
+    rope_orig_len: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # ---- feed-forward by layer: the first dense_layers are dense
+    # SwiGLU of dense_width, the rest routed (ffn == "moe")
+    dense_layers: int = 0
+    dense_width: int = 0
+    shared_experts: int = 0       # SwiGLU of shared_experts * expert_width
+    # ---- the router: "softmax" (plain top-k, gates as they are) |
+    # "sigmoid" (a selection-only bias; n_group groups scored by their
+    # two best, topk_group of them kept; the chosen gates divided by
+    # their sum when norm_topk, times routed_scale)
+    score: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk: bool = False
+    routed_scale: float = 1.0
+    # ---- the share: of the num_experts the router scores, this
+    # replica holds experts_held (0 = all) starting at expert_offset
+    experts_held: int = 0
+    expert_offset: int = 0
 
     @property
     def routed(self) -> bool:
         return self.ffn == "moe"
+
+    @property
+    def latent(self) -> bool:
+        return self.attention == "mla"
+
+    @property
+    def cache_pools(self) -> int:
+        """Pools of ``(layers, pages, page_size, cache_width)`` a
+        model's cache takes: K and V | one latent row."""
+        return 1 if self.latent else 2
+
+    def cache_width(self, d_model: int) -> int:
+        """Lanes a token's cache row takes, per layer and pool:
+        ``d_model``, or a latent row's :attr:`cache_values` rounded up
+        to whole 128-lane tiles (576 -> 640, the tail zero).  Under the
+        TPU's (8, 128) tiling a 576-wide minor dim occupies 640 lanes of
+        HBM whatever the array is called, and the decode kernel can
+        only cut HBM in whole tiles; so the padding is in the shape,
+        where the allocator's byte accounting sees it."""
+        if not self.latent:
+            return d_model
+        return -(-self.cache_values // 128) * 128
+
+    @property
+    def cache_values(self) -> int:
+        """Values a latent row holds: ``[c_kv ; RoPE(k_r)]``."""
+        return self.kv_rank + self.rope_dim
+
+    def layer_routed(self, layer: int) -> bool:
+        return self.routed and layer >= self.dense_layers
+
+    @property
+    def held(self) -> int:
+        """Routed experts whose matrices this replica holds."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def softmax_scale(self) -> float:
+        """Latent attention's score scale: ``(nope + rope) ** -0.5``,
+        times YaRN's ``m ** 2`` with ``m = 0.1 * mscale_all_dim *
+        ln(factor) + 1``."""
+        import math
+
+        scale = (self.nope_dim + self.rope_dim) ** -0.5
+        if self.rope_factor > 1.0 and self.rope_mscale_all_dim:
+            m = 0.1 * self.rope_mscale_all_dim * math.log(self.rope_factor) + 1.0
+            scale *= m * m
+        return scale
 
     @property
     def transformer_lm(self) -> bool:
@@ -83,9 +183,36 @@ OLMOE = ModelSpec(
     weights_f32=False,
 )
 
-_ARCHS = {"gpt2": GPT2, "olmoe": OLMOE}
-_SIZES = ("num_experts", "experts_per_tok", "expert_width", "rope_theta",
-          "norm_eps")
+# ai-sage/GigaChat3.1-702B-A36B config.json (model_type deepseek_v3):
+# MLA with q rank 1536, latent 512 + 64 rope, heads of 128 + 64 against
+# values of 192; YaRN factor 64 over 4096, theta 100,000; 3 leading
+# dense SwiGLU layers of 18,432, then 256 sigmoid-routed experts of
+# 2048 in 8 groups (4 kept), top-8 renormalised and scaled 2.5, beside
+# one shared expert; RMSNorm eps 1e-6, no biases
+DEEPSEEK_V3 = ModelSpec(
+    name="deepseek_v3", positions="rope", norm="rmsnorm", norm_eps=1e-6,
+    ffn="moe", num_experts=256, experts_per_tok=8, expert_width=2048,
+    rope_theta=100_000.0, bias=False, residual_f32=True, weights_f32=False,
+    attention="mla", q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64,
+    v_dim=192, rope_factor=64.0, rope_orig_len=4096, rope_beta_fast=32.0,
+    rope_beta_slow=1.0, rope_mscale_all_dim=1.0, dense_layers=3,
+    dense_width=18_432, shared_experts=1, score="sigmoid", n_group=8,
+    topk_group=4, norm_topk=True, routed_scale=2.5,
+)
+
+_ARCHS = {"gpt2": GPT2, "olmoe": OLMOE, "deepseek_v3": DEEPSEEK_V3}
+# the sizes any routed arch has; those only DeepSeek-V3's expert layer
+# and attention have; and the two every arch has
+_EXPERT_SIZES = ("num_experts", "experts_per_tok", "expert_width")
+_DEEPSEEK_SIZES = (
+    "dense_layers", "dense_width", "shared_experts", "n_group", "topk_group",
+    "routed_scale", "experts_held", "expert_offset",
+    "q_rank", "kv_rank", "nope_dim", "rope_dim", "v_dim", "rope_factor",
+    "rope_orig_len", "rope_beta_fast", "rope_beta_slow", "rope_mscale_all_dim")
+_SIZES = _EXPERT_SIZES + _DEEPSEEK_SIZES + ("rope_theta", "norm_eps")
+# a size that may be given as 0 and mean it (0 elsewhere = as published)
+_ZERO_MEANS_ZERO = ("dense_layers", "shared_experts", "expert_offset",
+                    "experts_held")
 
 
 def model_spec(arch: str = "gpt2", **sizes: Any) -> ModelSpec:
@@ -99,21 +226,42 @@ def model_spec(arch: str = "gpt2", **sizes: Any) -> ModelSpec:
         raise ValueError(
             f"arch={arch!r}: the paged engine serves {sorted(_ARCHS)}"
         ) from None
-    given = {k: v for k, v in sizes.items() if v}
+    given = {k: v for k, v in sizes.items()
+             if v or (v is not None and k in _ZERO_MEANS_ZERO and spec.latent)}
     unknown = sorted(set(given) - set(_SIZES))
     if unknown:
         raise ValueError(f"model_spec: unknown sizes {unknown}")
-    if not spec.routed and given.keys() & _SIZES[:3]:  # the expert sizes
+    if not spec.routed and given.keys() & set(_EXPERT_SIZES):
         raise ValueError(f"arch={spec.name!r} has no experts to size")
+    if not spec.latent and given.keys() & set(_DEEPSEEK_SIZES):
+        raise ValueError(
+            f"arch={spec.name!r} has no "
+            f"{sorted(given.keys() & set(_DEEPSEEK_SIZES))}")
     if given:
+        floats = ("rope_theta", "norm_eps", "routed_scale", "rope_factor",
+                  "rope_beta_fast", "rope_beta_slow", "rope_mscale_all_dim")
         spec = replace(spec, **{
-            k: (float(v) if k in ("rope_theta", "norm_eps") else int(v))
+            k: (float(v) if k in floats else int(v))
             for k, v in given.items()
         })
     if spec.routed and not 0 < spec.experts_per_tok <= spec.num_experts:
         raise ValueError(
             f"experts_per_tok {spec.experts_per_tok} of {spec.num_experts} "
             "experts")
+    if spec.routed and (
+            spec.num_experts % spec.n_group
+            or not 0 < spec.topk_group <= spec.n_group
+            or spec.experts_per_tok
+            > spec.topk_group * (spec.num_experts // spec.n_group)):
+        raise ValueError(
+            f"{spec.num_experts} experts in {spec.n_group} groups, "
+            f"{spec.topk_group} kept, top-{spec.experts_per_tok}")
+    if spec.routed and not (
+            0 <= spec.expert_offset
+            and spec.expert_offset + spec.held <= spec.num_experts):
+        raise ValueError(
+            f"experts_held {spec.held} from {spec.expert_offset} of "
+            f"{spec.num_experts} experts")
     return spec
 
 
@@ -128,6 +276,55 @@ def rope(x, positions, theta: float):
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
     x = x.astype(jnp.float32)
     x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def yarn_inv_freq(spec: ModelSpec):
+    """RoPE's inverse frequencies ``(rope_dim // 2,)`` float32 for a
+    latent spec: plain ``theta ** (-2i / dim)`` at ``rope_factor`` 1,
+    else YaRN's blend (HF ``_compute_yarn_parameters``) — dim ``i``
+    keeps its frequency (extrapolation) below the ``beta_fast``
+    correction dim, divides it by the factor (interpolation) above the
+    ``beta_slow`` one, and ramps linearly between.  ``mscale /
+    mscale_all_dim`` is 1 in the served configuration, so cos and sin
+    are not scaled; ``m`` enters the softmax scale instead
+    (:attr:`ModelSpec.softmax_scale`)."""
+    import math
+
+    import numpy as np
+
+    dim, base = spec.rope_dim, spec.rope_theta
+    pos_freqs = base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    extrapolation = 1.0 / pos_freqs
+    if spec.rope_factor <= 1.0:
+        return extrapolation.astype(np.float32)
+    interpolation = 1.0 / (spec.rope_factor * pos_freqs)
+
+    def correction_dim(rotations):
+        return (dim * math.log(spec.rope_orig_len / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(correction_dim(spec.rope_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(spec.rope_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001  # HF's guard against a zero-width ramp
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / (high - low),
+                   0.0, 1.0)
+    keep = 1.0 - ramp  # the share of extrapolation
+    return (interpolation * (1.0 - keep) + extrapolation * keep).astype(np.float32)
+
+
+def rope_interleaved(x, positions, inv_freq):
+    """HF ``modeling_deepseek_v3.py``'s rotary embedding: ``x`` ``(...,
+    L, heads, dim)`` arrives with its pairs interleaved ``(x0, x1, x2,
+    x3, ...)``, is re-laid to halves ``(x0, x2, ... ; x1, x3, ...)`` and
+    rotated in rotate-half form.  ``positions`` ``(..., L)``.  f32."""
+    import jax.numpy as jnp
+
+    x = x.astype(jnp.float32)
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq)
+    cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
@@ -150,11 +347,21 @@ def init_params(spec: ModelSpec, config: Dict[str, int], seed: int,
     Every leaf is uniform — bits, a scale and a shift, so the CPU (the
     benchmark's reference) and the chip (the server) make the same tree
     from the same seed — over a range its name picks: a norm's
-    ``scale`` in [0.5, 1.5) and a ``bias`` within ±0.1, so that one left
-    out shows; an ``embedding`` ±sqrt(3) (unit variance); any other
-    leaf is a matrix, ±sqrt(3 / fan_in) with the fan-in its
-    second-to-last dim, experts' included (unit-variance outputs).  A
-    leaf's stream is keyed by its path, not its place in the tree."""
+    ``scale`` in [0.5, 1.5) and a ``bias`` (a router's ``score_bias``
+    too) within ±0.1, so that one left out shows; an ``embedding``
+    ±sqrt(3) (unit variance); any other leaf is a matrix, ±sqrt(3 /
+    fan_in) with the fan-in its second-to-last dim, experts' included
+    (unit-variance outputs).  A leaf's stream is keyed by its path, not
+    its place in the tree.
+
+    Where a replica holds a share of the routed experts, the
+    ``score_bias`` is **ordered by the seed, not redrawn**
+    (:func:`share_bias`): among near-unit-variance logits a ±0.1 bias
+    decides which experts are chosen, so eight held experts drawn low
+    stream less and answer faster than eight drawn high — 5,750 to
+    6,100 tokens/s by seed on the chip (PERF.md §6, PR 30) — and a
+    seed would set the amount of work, which is the configuration's to
+    set."""
     import zlib
 
     import jax
@@ -170,9 +377,11 @@ def init_params(spec: ModelSpec, config: Dict[str, int], seed: int,
                               **config)
     i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
     pool = jax.ShapeDtypeStruct(
-        (config["num_layers"], 2, 8, config["d_model"]), dtype)
+        (config["num_layers"], 2, 8, spec.cache_width(config["d_model"])),
+        dtype)
     declared = jax.eval_shape(
-        lm.init, jax.random.key(0), i32((1, 8)), i32((1, 8)), pool, pool,
+        lm.init, jax.random.key(0), i32((1, 8)), i32((1, 8)), pool,
+        pool if spec.cache_pools == 2 else None,
         i32((1, 1)), i32((1,)))["params"]
 
     # one compiled program per distinct (shape, range, type): the
@@ -188,9 +397,28 @@ def init_params(spec: ModelSpec, config: Dict[str, int], seed: int,
         if name == "scale":
             lo, hi = 0.5, 1.5
         else:
-            hi = (0.1 if name == "bias"
+            hi = (0.1 if name == "bias" or name.endswith("_bias")
                   else (3.0 if name == "embedding" else 3.0 / leaf.shape[-2]) ** 0.5)
             lo = -hi
         key = jax.random.fold_in(root, zlib.crc32(path.encode()))
-        tree[path] = uniform(key, leaf.shape, lo, hi, jnp.dtype(leaf.dtype))
+        if name == "score_bias" and spec.experts_held:
+            tree[path] = share_bias(key, leaf.shape[0], spec.held, hi)
+        else:
+            tree[path] = uniform(key, leaf.shape, lo, hi, jnp.dtype(leaf.dtype))
     return unflatten_dict(tree, sep="/")
+
+
+def share_bias(key, num_experts: int, held: int, most: float):
+    """A router's correction bias ``float32[num_experts]`` for replicas
+    that hold ``held`` experts each: every replica's block holds the
+    same ``held`` values, evenly spaced within ±``most`` (8: ∓0.0875,
+    ∓0.0625, ∓0.0375, ∓0.0125), in an order ``key`` draws per block.
+    Every share of every seed then has the same experts to offer, and
+    every group of blocks the same: the seed permutes the load, it does
+    not size it."""
+    import jax
+    import jax.numpy as jnp
+
+    values = most * (2.0 * (jnp.arange(held, dtype=jnp.float32) + 0.5) / held - 1.0)
+    keys = jax.random.split(key, num_experts // held)
+    return jax.vmap(lambda k: jax.random.permutation(k, values))(keys).reshape(-1)

@@ -915,3 +915,261 @@ def paged_attention_decode(q, pk, pv, block_tables, lengths, *, layer,
         kv_scales, lora,
         quantized=kv_scales is not None, fold=lora is not None,
         q_scale=float(q_scale), interpret=interpret_mode())
+
+
+# ---------------------------------------------------------------------------
+# latent attention decode (absorbed MLA over a paged pool of latent rows)
+# ---------------------------------------------------------------------------
+
+# Tokens one step of the latent kernel's page loop reduces (the stream
+# kernel's is 128: ``_pages_per_step``).  A latent row is read once for
+# all heads, so a 64-token page is 0.10 us of DMA and 0.05 us of MXU on
+# the v5e and a two-page step is mostly the loop's own fixed cost (its
+# DMA waits, the flash rescale of a (heads, rank) accumulator).  64
+# lanes of 600-2,000 tokens on the v5e, us a live page by step size: 64
+# tokens 0.51, 128 0.31, 256 0.23, 512 0.18, 1,024 0.16 (17 -> 56 % of
+# the rows' DMA roofline; lanes of 2,000-4,000: 30 -> 67 %; my chip
+# runs, PR 30, tools/profile_latent_kernel.py).  A step's last pages
+# may be blanks (a lane's length is no multiple of the step): under one
+# step's work a lane.  At 1,024 tokens the two page buffers are 2.6 MB
+# of VMEM.
+LATENT_STEP_TOKENS = 1024
+
+
+def _latent_pages_per_step(page_size: int, table_width: int,
+                           step_tokens: int) -> int:
+    return max(1, min(step_tokens // page_size, table_width))
+
+
+def _latent_attention_kernel(tables_ref, lens_ref, layer_ref, q_ref, pool_hbm,
+                             acc_ref, m_ref, l_ref, buf, sems, turn_ref, *,
+                             page_size, rank, group):
+    """One lane of absorbed latent attention: the stream kernel's page
+    loop (:func:`_paged_attention_kernel`) over ONE pool whose row is
+    ``[c_kv ; k_r]``, read once for all heads.
+
+    Shared with the stream kernel, in the same words: ``grid=(B,)`` in
+    order on one core, the whole ``(L, pages, ps, W)`` pool in HBM, the
+    layer a scalar-prefetch operand, double-buffered manual DMA of
+    ``pool.at[layer, page]``, several pages a step
+    (:data:`LATENT_STEP_TOKENS`), a
+    ``fori_loop`` over the lane's own ``ceil(length / page_size)`` pages
+    (an empty lane writes the neutral state and leaves), and the hand-on
+    of the next live lane's first pages through ``turn_ref``.  Not
+    shared: there is no block diagonal to build or cut — ``q`` arrives
+    ``(heads, W)`` with ``W_uk`` folded in, the heads on the sublanes,
+    and a page's scores are one ``(heads, W) x (W, tokens)`` matmul, its
+    values one ``(heads, tokens) x (tokens, rank)`` matmul on the row's
+    first ``rank`` lanes (the rotary tail is key only); there is one
+    pool, so one buffer and one semaphore a page; and **no
+    :func:`_split3`**: both small operands ride in the pool's type, one
+    bf16 MXU pass each (121 FLOP a byte against the v5e's ridge of 240:
+    three passes would make the kernel compute-bound), as the XLA lane
+    rounds them.  An f32 pool (the CPU exactness lane) multiplies at
+    ``HIGHEST``.  ``W`` is the pool's lane-aligned row (576 values in
+    640 lanes, the tail zero in q and in the pool alike): Mosaic slices
+    HBM in whole 128-lane tiles, and under the (8, 128) tiling a
+    576-wide minor dim occupies 640 lanes of HBM whatever it is
+    called."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    lanes = pl.num_programs(0)
+    layer = layer_ref[0]
+    width = tables_ref.shape[1]
+    heads, row_w = q_ref.shape[1], q_ref.shape[2]
+    span = buf.shape[1]  # ``group`` pages of ``page_size`` tokens
+    length = lens_ref[b]
+    precision = (jax.lax.Precision.HIGHEST
+                 if pool_hbm.dtype == jnp.float32 else None)
+    nt_dims = (((1,), (1,)), ((), ()))
+
+    def pages_of(lane):
+        return jnp.minimum(
+            jax.lax.div(lens_ref[lane] + page_size - 1, page_size), width)
+
+    def copies(lane, j, slot):
+        n = pages_of(lane)
+        for g in range(group):
+            idx = j * group + g
+            page = tables_ref[lane, jnp.clip(idx, 0, n - 1)]
+            yield idx < n, pltpu.make_async_copy(
+                pool_hbm.at[layer, page],
+                buf.at[slot, pl.ds(g * page_size, page_size)],
+                sems.at[slot, g])
+
+    def start(lane, j, slot):
+        for held, copy in copies(lane, j, slot):
+            pl.when(held)(copy.start)
+
+    def wait(j, slot):
+        for held, copy in copies(b, j, slot):
+            pl.when(held)(copy.wait)
+
+    n_pages = pages_of(b)
+    n_steps = jax.lax.div(n_pages + group - 1, group)
+    after = jnp.minimum(b + 1, lanes - 1)
+    hand_on = (b + 1 < lanes) & (pages_of(after) > 0)
+
+    @pl.when(b == 0)
+    def _first():
+        turn_ref[0] = 0
+        start(0, 0, 0)
+
+    base = turn_ref[0]
+
+    @pl.when(n_pages == 0)
+    def _dead():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+        @pl.when(hand_on)
+        def _hand_on():
+            start(after, 0, base)
+
+    @pl.when(n_pages > 0)
+    def _live():
+        q = q_ref[0]                                   # (heads, W), scaled
+
+        def step(j, carry):
+            m_prev, l_prev, acc = carry      # (heads, 1) x 2, (heads, rank)
+            slot = jax.lax.rem(base + j, 2)
+
+            @pl.when(j + 1 < n_steps)
+            def _prefetch():
+                start(b, j + 1, 1 - slot)
+
+            @pl.when((j + 1 == n_steps) & hand_on)
+            def _hand_on():
+                start(after, 0, 1 - slot)
+
+            wait(j, slot)
+            for g in range(1, group):
+                # a page the lane does not hold was not fetched: its
+                # weights are 0, and 0 x whatever the buffer held must
+                # be 0
+                @pl.when(j * group + g >= n_pages)
+                def _blank(g=g):
+                    buf[slot, pl.ds(g * page_size, page_size)] = jnp.zeros(
+                        (page_size, row_w), buf.dtype)
+            rows = buf[slot]                           # (span, W)
+            latent = rows[:, :rank]
+            s = jax.lax.dot_general(
+                q, rows, nt_dims, precision=precision,
+                preferred_element_type=jnp.float32)           # (heads, span)
+            at = j * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+            # (a step may reach past the table where its pages do not
+            # divide the table's width, and a lane masked done may hold
+            # more than the table it was handed)
+            s = jnp.where(at < jnp.minimum(length, width * page_size), s,
+                          -jnp.inf)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            w = jnp.exp(s - m_new)
+            l_new = l_prev * alpha + w.sum(axis=1, keepdims=True)
+            pv = jnp.dot(w.astype(buf.dtype), latent, precision=precision,
+                         preferred_element_type=jnp.float32)  # (heads, rank)
+            return m_new, l_new, acc * alpha + pv
+
+        init = (
+            jnp.full((heads, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((heads, 1), jnp.float32),
+            jnp.zeros((heads, rank), jnp.float32),
+        )
+        m_fin, l_fin, acc_fin = jax.lax.fori_loop(0, n_steps, step, init)
+        turn_ref[0] = jax.lax.rem(base + n_steps, 2)
+        acc_ref[0] = acc_fin
+        m_ref[0] = jnp.broadcast_to(m_fin, m_ref.shape[1:])
+        l_ref[0] = jnp.broadcast_to(l_fin, l_ref.shape[1:])
+
+
+def _latent_decode(q, pool, block_tables, lengths, layer, *, rank,
+                   step_tokens, interpret):
+    """The latent kernel's ``pallas_call`` on the whole pool (see
+    :func:`latent_attention_decode`, which calls it jitted)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, h, W = q.shape
+    ps = pool.shape[2]
+    group = _latent_pages_per_step(ps, block_tables.shape[1], step_tokens)
+    span = group * ps
+    q_spec = pl.BlockSpec((1, h, W), lambda b, *prefetch: (b, 0, 0))
+    acc_spec = pl.BlockSpec((1, h, rank), lambda b, *prefetch: (b, 0, 0))
+    pad_spec = pl.BlockSpec((1, h, 128), lambda b, *prefetch: (b, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B,),
+        in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[acc_spec, pad_spec, pad_spec],
+        scratch_shapes=[
+            pltpu.VMEM((2, span, W), pool.dtype),
+            pltpu.SemaphoreType.DMA((2, span // ps)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    acc, m, l = pl.pallas_call(
+        functools.partial(_latent_attention_kernel, page_size=ps, rank=rank,
+                          group=group),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((B, h, rank), jnp.float32),
+            jax.ShapeDtypeStruct((B, h, 128), jnp.float32),
+            jax.ShapeDtypeStruct((B, h, 128), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(block_tables, lengths, layer.reshape(1), q.astype(pool.dtype), pool)
+    return acc, m[:, :, 0], l[:, :, 0]
+
+
+@functools.lru_cache(maxsize=None)
+def _latent_decode_jit():
+    import jax
+
+    return jax.jit(_latent_decode, static_argnames=(
+        "rank", "step_tokens", "interpret"))
+
+
+def latent_attention_decode(q, pool, block_tables, lengths, *, layer,
+                            page_size, rank):
+    """Unnormalised flash state of absorbed latent attention (MLA) over
+    one layer of a paged pool of latent rows, addressed IN the whole
+    pool: :func:`paged_attention_decode`'s twin for a cache whose row is
+    ``[c_kv ; k_r]`` and has no V.
+
+    ``q`` ``(B, h, W)`` — the step's queries with ``W_uk`` folded in and
+    the rotary part appended, already scaled, zero in the lanes that
+    pad the row to ``W``; ``pool`` — the WHOLE ``(L, num_pages, ps, W)``
+    pool; ``layer`` a python int or traced
+    int32 scalar; ``block_tables`` ``(B, P)``; ``lengths`` ``(B,)``;
+    ``rank`` — the row's leading values that are also the value read.
+    Returns ``(acc (B, h, rank), m (B, h), l (B, h))`` float32 — what
+    ``ops/mla.py ctx_state`` returns for the gathered rows; join the
+    step's own row with ``ops/mla.py merge``.
+
+    A row is read once for all heads: at 64 heads and 512 + 64 values
+    it needs 1,152 B and 139,264 FLOP; the DMA moves its 640 lanes,
+    1,280 B."""
+    import jax.numpy as jnp
+
+    if pool.ndim != 4 or q.shape[-1] != pool.shape[-1]:
+        raise ValueError(
+            "latent_attention_decode reads the whole pool as a 4-d (layers, "
+            f"pages, page_size, row) array of q's row width, got {pool.shape} "
+            f"for q {q.shape}")
+    if page_size != pool.shape[2]:
+        raise ValueError(
+            f"page_size={page_size} does not match the pool's page dim "
+            f"{pool.shape[2]}")
+    return _latent_decode_jit()(
+        q, pool, block_tables, lengths, jnp.asarray(layer, jnp.int32),
+        rank=int(rank), step_tokens=LATENT_STEP_TOKENS,
+        interpret=interpret_mode())

@@ -148,6 +148,7 @@ def shard_decode_state(
     min_weight_size: int = 16_384,
     num_heads: int,
     seq_shard: bool = True,
+    pools: int = 2,
 ):
     """Serving-mesh layout for the paged-decode lanes: megatron param
     specs + K/V pools sharded on BOTH mesh axes.
@@ -179,6 +180,9 @@ def shard_decode_state(
     ``mesh=None`` is the single-device case: params untouched, plain
     unsharded pools — so callers need no conditional.
 
+    ``pools=1`` (a latent cache: one row a token, no V; single device
+    only) makes no second pool and returns None in its place.
+
     Returns ``(params, pool_k, pool_v)``.
     """
     import jax
@@ -193,8 +197,10 @@ def shard_decode_state(
         return (
             jax.device_put(params),
             jnp.zeros(pool_shape, dtype),
-            jnp.zeros(pool_shape, dtype),
+            jnp.zeros(pool_shape, dtype) if pools == 2 else None,
         )
+    if pools != 2:
+        raise ValueError("a one-pool (latent) cache has no sharding rule")
 
     params = shard_params(
         params, mesh, model_axis=model_axis, min_weight_size=min_weight_size

@@ -520,6 +520,22 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
         "experts hit, summed over the decode (layer, step)s that ran"),
     "moe_layer_steps": ("counter", "seldon_tpu_engine_moe_layer_steps_total",
                         "decode (layer, step)s of a routed model that ran"),
+    "moe_local_assignments": (
+        "counter", "seldon_tpu_engine_moe_local_assignments_total",
+        "assignments that fell to experts this replica holds (a replica "
+        "holding a share of an expert-parallel layer; 0 otherwise)"),
+    "moe_held_active_expert_steps": (
+        "counter", "seldon_tpu_engine_moe_held_active_expert_steps_total",
+        "held experts hit, summed over the decode (routed layer, step)s "
+        "that ran"),
+    "latent_kv_tokens": ("counter",
+                         "seldon_tpu_engine_latent_kv_tokens_total",
+                         "cached latent rows read by decode lane-steps, "
+                         "over all layers (a latent pool; 0 otherwise)"),
+    "moe_held_pass_rows": (
+        "gauge", "seldon_tpu_engine_moe_held_pass_rows",
+        "rows one pass of a decode step's held experts computes (a "
+        "replica holding a share of an expert-parallel layer; 0 otherwise)"),
     "moe_load_max": ("gauge", "seldon_tpu_engine_moe_load_max",
                      "cumulative assignments of the busiest (layer, "
                      "expert) pair"),

@@ -143,3 +143,88 @@ def test_expert_layer_compiles_as_grouped_matmul_kernels(one_chip, mosaic, rows)
     routed_f32 = rows * k * d * 4
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * routed_f32
     assert 3 * routed_f32 < rows * e * (2 * f + d) * 2
+
+
+# ---------------------------------------------------------------------------
+# GigaChat3.1 / DeepSeek-V3 (PR 30): the latent kernel and the held experts
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("lanes,pages", [(64, 16), (64, 32), (64, 64), (128, 64)])
+def test_latent_kernel_compiles_on_the_whole_pool(one_chip, mosaic, lanes, pages):
+    """The long-answer cell's chunk buckets: 64 or 128 lanes of 64 heads
+    over tables of 16-64 pages, on the whole ``(6, 8193, 64, 640)`` pool
+    (a 576-value row in 640 lanes: Mosaic cuts HBM in whole 128-lane
+    tiles, which is why the pool is shaped so)."""
+    layers, num_pages, heads, lanes_w, rank = 6, 8193, 64, 640, 512
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q, pool, tables, lengths, layer):
+        return kernels.latent_attention_decode(
+            q, pool, tables, lengths, layer=layer, page_size=PS, rank=rank)
+
+    compiled = jax.jit(fn).lower(
+        spec((lanes, heads, lanes_w), jnp.bfloat16),
+        spec((layers, num_pages, PS, lanes_w), jnp.bfloat16),
+        spec((lanes, pages), jnp.int32), spec((lanes,), jnp.int32),
+        spec((), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and " = " in ln]
+    assert len(calls) == 1, calls
+    # what the benchmark's readers find the kernel by: its first output
+    # is (lanes, heads, rank) f32
+    assert calls[0].partition(" = ")[2].lstrip("(").startswith(
+        f"f32[{lanes},{heads},{rank}]"), calls[0][:200]
+    whole = f"[{layers},{num_pages},{PS},{lanes_w}]"
+    produced = [ln for ln in text.splitlines()
+                if whole in ln.partition(" = ")[2].partition("(")[0]
+                and " parameter(" not in ln]
+    assert not produced, produced[:3]
+
+
+def test_a_576_wide_pool_cannot_be_cut_by_the_kernel(one_chip, mosaic):
+    """Why the row rests in 640 lanes and not 576."""
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q, pool, tables, lengths, layer):
+        return kernels.latent_attention_decode(
+            q, pool, tables, lengths, layer=layer, page_size=PS, rank=512)
+
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(fn).lower(
+            spec((64, 64, 576), jnp.bfloat16), spec((6, 513, PS, 576), jnp.bfloat16),
+            spec((64, 16), jnp.int32), spec((64,), jnp.int32),
+            spec((), jnp.int32)).compile()
+
+
+@pytest.mark.parametrize("rows", [128, 8192])
+def test_held_expert_layer_compiles_with_rows_of_a_pass(one_chip, mosaic, rows):
+    """A decode step's 128 rows and a prefill group's 8,192 through
+    ``ops/moe.py`` at GigaChat3.1's widths, 8 of 256 experts held: the
+    grouped matmuls take a pass's rows (``held_rows_cap``), not the
+    ``rows x 8`` assignments, so the temporaries stay a few hundred MB."""
+    from seldon_core_tpu.ops import moe
+
+    d, f, e, held, k = 7168, 2048, 256, 8, 8
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(h, w_router, bias, w_gate, w_up, w_down):
+        gates, experts = moe.route_grouped(h, w_router, bias, k, 8, 4, True, 2.5)
+        out = moe.expert_ffn_held(
+            h.astype(jnp.bfloat16), w_gate, w_up, w_down, gates, experts, 0, e)
+        return out, moe.expert_histogram(experts, e)
+
+    compiled = jax.jit(layer).lower(
+        spec((rows, d), jnp.float32), spec((d, e), jnp.float32),
+        spec((e,), jnp.float32), spec((held, d, f), jnp.bfloat16),
+        spec((held, d, f), jnp.bfloat16), spec((held, f, d), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert text.count("ragged-dot") >= 3 and "tpu_custom_call" in text
+    cap = moe.held_rows_cap(rows, k, held, e)   # four times an even 1/32 share
+    assert cap == rows * k // 8 and f"[{cap},{f}]" in text
+    assert f"[{rows * k},{d}]" not in text  # never all the assignments' rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 12 * rows * d * 4

@@ -465,7 +465,11 @@ class TestDecodeLane:
                 LAYERS, eng.num_pages, eng.page_size, cfg["d_model"])
             assert set(rep) == {
                 "tp", "dp", "chunk_impl", "kv_dtype", "kernel_active",
-                "pool_shard_bytes", "arch", "weight_bytes"}
+                "pool_shard_bytes", "arch", "weight_bytes",
+                # PR 30: what the cache holds
+                "attention", "cache_width", "experts_held"}
+            assert (rep["attention"], rep["cache_width"], rep["experts_held"]) == (
+                "mha", cfg["d_model"], 0)
         finally:
             eng.close()
         # a kernel asked for by name that cannot run says so, once

@@ -1,0 +1,182 @@
+#!/usr/bin/env python3
+"""The readings behind the limits of ``benchmarks/harness/kinds/
+generation_share.py``: what GigaChat3.1's plain float32 reference says
+of the tokens that the same forward pass serves in a lower precision, or
+with a fault.  CPU only (``JAX_PLATFORMS=cpu``), ~1 minute a variant at
+512 positions; nothing here is a device number.
+
+    python tools/precision_readings.py --seed 3000005003 --positions 512
+
+One sequence of seeded token ids is run through ``reference/
+deepseek_v3.py`` (the judge) and through this file's copy of its forward
+pass with roundings put in, and each variant's greedy token at every
+position is judged as the kind judges a served one: its gap under the
+reference's top-1 in deviations of that position's logits.  Printed per
+variant: positions whose token is not the top-1, positions *off* (gap
+over ``TIE_STDS``), the largest gap, the gaps' 5th and 50th percentile,
+and the logits' own distance from float32's (rms over the vocabulary, in
+deviations: the median position).
+
+Variants (``--variants`` picks, default all):
+
+* ``stated``: what the configuration states — every matmul's operands
+  and result rounded to bfloat16, norms, softmax, router and the
+  residual stream in float32;
+* ``bf16-residual``: the step nearest below — the residual stream
+  rounded to bfloat16 too;
+* ``e4m3``: the operands of every weight matmul rounded to 8 bits
+  (float8 e4m3, scaled to the row's / the matrix's largest value), the
+  attention's own products in bfloat16;
+* ``no-bias``, ``no-shared``: the stated precision with the correction
+  bias left out of the selection | without the shared expert;
+* ``early-row``: the reference's own top-1 of the position before (a
+  stale page, a shifted row).
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [ROOT, os.path.join(ROOT, "benchmarks")]
+
+VARIANTS = ("stated", "bf16-residual", "e4m3", "no-bias", "no-shared", "early-row")
+
+
+def rounded_logits(ref, params, model, tokens, residual_bf16=False, e4m3=False,
+                   bias=True, shared=True):
+    """``reference/deepseek_v3.py logits`` with its matmuls' operands and
+    results rounded: the same equations, line for line."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    eps, heads = model["rms_norm_eps"], model["num_attention_heads"]
+    nope, rdim, rank = (model["qk_nope_head_dim"], model["qk_rope_head_dim"],
+                        model["kv_lora_rank"])
+    held, offset = model["n_routed_experts"], model.get("expert_offset", 0)
+    scale = ref.softmax_scale(model)
+    freq = jnp.asarray(ref.inv_freq(model), jnp.float32)
+
+    def f32(a):
+        return jnp.asarray(a).astype(jnp.float32)
+
+    def b16(a):
+        return a.astype(jnp.bfloat16).astype(jnp.float32)
+
+    def q8(a, axis):
+        unit = jnp.max(jnp.abs(a), axis=axis, keepdims=True) / 448.0 + 1e-30
+        return (a / unit).astype(jnp.float8_e4m3fn).astype(jnp.float32) * unit
+
+    def act(a):   # an operand of a weight matmul
+        return q8(a, -1) if e4m3 else b16(a)
+
+    def w(a):     # a weight, as the matmul reads it
+        return q8(f32(a), None) if e4m3 else f32(a)
+
+    def res(a):   # the residual stream
+        return b16(a) if residual_bf16 else a
+
+    def rms_norm(x, scale_):
+        return x / jnp.sqrt((x * x).mean(-1, keepdims=True) + eps) * f32(scale_)
+
+    def rotate(x, pos):
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        ang = pos.astype(jnp.float32).reshape(-1, *([1] * (x.ndim - 2)), 1) * freq
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+    def swiglu(h, gate, up, down):
+        h = act(h)
+        return act(b16(jax.nn.silu(h @ w(gate)) * (h @ w(up)))) @ w(down)
+
+    with jax.default_matmul_precision("highest"):
+        tokens = jnp.asarray(tokens, jnp.int32)
+        n = tokens.shape[0]
+        pos = jnp.arange(n)
+        x = res(f32(params["tok_embed"]["embedding"][tokens]))
+        for i in range(model["num_hidden_layers"]):
+            p = params[f"block_{i}"]
+            h = rms_norm(x, p["attn_norm"]["scale"])
+            c_q = rms_norm(b16(act(h) @ w(p["q_a"]["kernel"])), p["q_a_norm"]["scale"])
+            q = b16(act(c_q) @ w(p["q_b"]["kernel"])).reshape(n, heads, nope + rdim)
+            kva = b16(act(h) @ w(p["kv_a"]["kernel"]))
+            c_kv = b16(rms_norm(kva[:, :rank], p["kv_a_norm"]["scale"]))
+            k_r = b16(rotate(kva[:, rank:], pos))
+            q_nope, q_r = q[..., :nope], b16(rotate(q[..., nope:], pos))
+            k_nope = b16(jnp.einsum("cr,hrn->hcn", c_kv, f32(p["kv_b_k"])))
+            v = b16(jnp.einsum("cr,hrv->hcv", c_kv, f32(p["kv_b_v"])))
+            out = []
+            for lo in range(0, n, ref.QUERY_BLOCK):
+                hi = min(n, lo + ref.QUERY_BLOCK)
+                s = (jnp.einsum("qhn,hcn->hqc", q_nope[lo:hi], k_nope)
+                     + jnp.einsum("qhr,cr->hqc", q_r[lo:hi], k_r)) * scale
+                s = jnp.where((pos[None, :] <= pos[lo:hi, None])[None], s, -jnp.inf)
+                out.append(jnp.einsum("hqc,hcv->qhv", b16(jax.nn.softmax(s, axis=-1)), v))
+            attn = b16(jnp.concatenate(out, axis=0).reshape(n, -1))
+            x = res(x + b16(act(attn) @ w(p["attn_proj"]["kernel"])))
+
+            h = rms_norm(x, p["ffn_norm"]["scale"])
+            if i < model["first_k_dense_replace"]:
+                x = res(x + swiglu(h, p["mlp_gate"], p["mlp_up"], p["mlp_down"]))
+                continue
+            scores = jax.nn.sigmoid(h @ f32(p["router"]))
+            score_bias = np.asarray(p["score_bias"], np.float32)
+            weights, chosen = ref.route(model, scores, score_bias if bias else 0 * score_bias)
+            y = swiglu(h, p["shared_gate"], p["shared_up"], p["shared_down"]) \
+                if model["n_shared_experts"] and shared else jnp.zeros_like(x)
+            for e in range(held):
+                rows, slot = np.nonzero(chosen == e + offset)
+                if rows.size:
+                    part = swiglu(h[rows], p["experts_gate"][e], p["experts_up"][e],
+                                  p["experts_down"][e])
+                    y = y.at[rows].add(part * weights[rows, slot][:, None])
+            x = res(x + y)
+        return rms_norm(x, params["final_norm"]["scale"]) @ f32(params["head"]["kernel"])
+
+
+def main() -> int:
+    import numpy as np
+
+    from harness import manifest
+    from harness.kinds.generation_share import TIE_STDS
+
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--config", default="gigachat3.1-702b-a36b")
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--positions", type=int, default=512)
+    ap.add_argument("--variants", default=",".join(VARIANTS))
+    args = ap.parse_args()
+    config = manifest.load_json(os.path.join(manifest.BENCH_DIR, "configs", args.config + ".json"))
+    model = config["model"]
+    ref = manifest.module("reference", config["reference"])
+    params = ref.make_params(model, args.seed)
+    n = args.positions
+    tokens = np.random.default_rng(args.seed).integers(0, model["vocab_size"], size=n).tolist()
+    plain = np.asarray(ref.logits(params, model, tokens))
+    std = plain.std(-1)
+    how = {"stated": {}, "bf16-residual": {"residual_bf16": True}, "e4m3": {"e4m3": True},
+           "no-bias": {"bias": False}, "no-shared": {"shared": False}}
+    for name in args.variants.split(","):
+        if name == "early-row":
+            served, far = np.concatenate([plain[:1].argmax(-1), plain[:-1].argmax(-1)]), None
+        else:
+            got = np.asarray(rounded_logits(ref, params, model, tokens, **how[name]))
+            served = got.argmax(-1)
+            far = float(np.median(np.sqrt(((got - plain) ** 2).mean(-1)) / std))
+        gaps = (plain.max(-1) - plain[np.arange(n), served]) / std
+        print(json.dumps({"variant": name, "seed": args.seed, "positions": n,
+                          "not_top1": int((gaps > 0).sum()), "off": int((gaps > TIE_STDS).sum()),
+                          "off_share_pct": 100.0 * float((gaps > TIE_STDS).mean()),
+                          "worst_gap_stds": float(gaps.max()),
+                          "gap_stds_p05_p50": [float(q) for q in np.quantile(gaps, [0.05, 0.5])],
+                          "logits_rms_stds": far}),
+              flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

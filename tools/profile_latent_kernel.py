@@ -1,0 +1,102 @@
+#!/usr/bin/env python3
+"""Time the latent decode kernel alone at a cell's shapes (chip).
+
+The whole ``(layers, pages, 64, 640)`` pool, one call a layer in a scan
+— as a decode step of the chunk program makes them — over lanes whose
+cached lengths are drawn from ``--ctx``: prints microseconds a call and
+a live page, and the share of the DMA roofline (rows needed x 1,152 B
+over 819 GB/s).  ``--step-tokens`` sets
+``ops/kernels.LATENT_STEP_TOKENS`` (tokens one step of the page loop
+reduces) for a sweep; ``--interpret`` rehearses on the CPU.
+
+    python tools/profile_latent_kernel.py --lanes 64 --table-pages 32 \
+        --ctx 600-2000 --step-tokens 128,256,512 --json chiprun_out/x.jsonl
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--lanes", type=int, default=64)
+    ap.add_argument("--table-pages", type=int, default=32)
+    ap.add_argument("--ctx", default="600-2000", help="N or LO-HI cached tokens a lane")
+    ap.add_argument("--layers", type=int, default=6)
+    ap.add_argument("--pages", type=int, default=8193)
+    ap.add_argument("--heads", type=int, default=64)
+    ap.add_argument("--rank", type=int, default=512)
+    ap.add_argument("--values", type=int, default=576)
+    ap.add_argument("--step-tokens", default="128")
+    ap.add_argument("--repeats", type=int, default=20)
+    ap.add_argument("--interpret", action="store_true")
+    ap.add_argument("--json", default="")
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from seldon_core_tpu.ops import kernels
+
+    if args.interpret:
+        kernels.interpret_mode = lambda: True
+    ps, lanes_w = 64, -(-args.values // 128) * 128
+    lo, _, hi = args.ctx.partition("-")
+    lo, hi = int(lo), int(hi or lo)
+    rng = np.random.default_rng(0)
+    lengths = rng.integers(lo, hi + 1, size=args.lanes).astype(np.int32)
+    lengths = np.minimum(lengths, args.table_pages * ps)
+    tables = rng.permutation(np.arange(1, args.pages))[
+        : args.lanes * args.table_pages].reshape(args.lanes, args.table_pages).astype(np.int32)
+    pool = jax.random.normal(
+        jax.random.key(0), (args.layers, args.pages, ps, lanes_w), jnp.bfloat16)
+    q = (jax.random.normal(jax.random.key(1), (args.lanes, args.heads, lanes_w),
+                           jnp.float32) * 0.05).astype(jnp.bfloat16)
+    live = int(sum(-(-int(n) // ps) for n in lengths))
+    rows = int(lengths.sum())
+    out = []
+    for step_tokens in (int(x) for x in args.step_tokens.split(",")):
+        kernels.LATENT_STEP_TOKENS = step_tokens  # a static argument of the call
+
+        @jax.jit
+        def layers(q, pool, tables, lengths):
+            def one(carry, layer):
+                acc, m, l = kernels.latent_attention_decode(
+                    q, pool, tables, lengths, layer=layer, page_size=ps, rank=args.rank)
+                return carry + acc.sum() + m.max() + l.sum(), ()
+            total, _ = jax.lax.scan(one, jnp.float32(0), jnp.arange(args.layers))
+            return total
+
+        operands = (q, pool, jnp.asarray(tables), jnp.asarray(lengths))
+        jax.block_until_ready(layers(*operands))
+        t0 = time.perf_counter()
+        for _ in range(args.repeats):
+            r = layers(*operands)
+        jax.block_until_ready(r)
+        call_us = 1e6 * (time.perf_counter() - t0) / args.repeats / args.layers
+        floor_us = 1e6 * rows * 2 * args.values / 819e9
+        rec = {"lanes": args.lanes, "table_pages": args.table_pages, "ctx": args.ctx,
+               "step_tokens": step_tokens, "live_pages": live, "rows": rows,
+               "call_us": round(call_us, 1), "live_page_us": round(call_us / max(live, 1), 3),
+               "dma_roofline_pct": round(100 * floor_us / call_us, 1),
+               "device": jax.devices()[0].device_kind}
+        print(json.dumps(rec), flush=True)
+        out.append(rec)
+    if args.json:
+        os.makedirs(os.path.dirname(args.json) or ".", exist_ok=True)
+        with open(args.json, "a") as f:
+            for rec in out:
+                f.write(json.dumps(rec) + "\n")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
