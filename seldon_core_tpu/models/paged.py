@@ -6560,7 +6560,44 @@ class PagedEngine:
             "attention": self.spec.attention,
             "cache_width": self.cache_width,
             "experts_held": self.spec.held if self.spec.routed else 0,
+            # which grouped expert matmul each program traced
+            # (ops/moe.py grouped_swiglu): {} for a dense model
+            "expert_matmul": self._expert_matmul_report(),
         }
+
+    def _expert_matmul_report(self) -> Dict[str, str]:
+        """``"stream"`` | ``"ragged_dot"`` for the chunk programs (a
+        decode step routes ``max_slots`` tokens; a speculative verify
+        ``draft_k + 1`` times as many) and for every prefill bucket x
+        group a call may take, from the rule the programs trace with
+        (ops/moe.py ``layer_expert_matmul``)."""
+        spec = self.spec
+        if not spec.routed:
+            return {}
+        from seldon_core_tpu.ops import moe
+
+        tree_util = self._jax.tree_util
+        gate = next(
+            leaf for path, leaf in tree_util.tree_flatten_with_path(self.params)[0]
+            if "experts_gate" in tree_util.keystr(path))
+        held, d_model, width = gate.shape[-3:]
+
+        def impl(tokens: int) -> str:
+            return moe.layer_expert_matmul(
+                tokens, spec.experts_per_tok, held, spec.num_experts,
+                d_model, width, gate.dtype, held_pass=spec.score == "sigmoid")
+
+        report = {"chunk": impl(self.max_slots)}
+        if self.speculative is not None:
+            report["spec_chunk"] = impl(self.max_slots * (self.draft_k + 1))
+        for bucket in self.prompt_buckets:
+            most = min(self.max_slots,
+                       prefill_group_max(bucket, self.prefill_positions_max))
+            k = 1
+            while k <= most:
+                report[f"prefill_b{bucket}_k{k}"] = impl(bucket * k)
+                k *= 2
+        return report
 
     def _moe_held_hits(self):
         """Cumulative assignments per (routed layer, expert held here)."""

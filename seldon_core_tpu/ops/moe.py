@@ -7,15 +7,26 @@ is ``HIGHEST``); top-k of the probabilities, gates **not** renormalised
 (OLMoE's ``norm_topk_prob: false``).
 
 ``expert_ffn``: the ``T x k`` (token, expert) assignments are sorted by
-expert and the three SwiGLU matmuls run as ``jax.lax.ragged_dot`` over
-the sorted rows with the experts' group sizes: every routed pair is
+expert and the three SwiGLU matmuls run as one grouped SwiGLU over the
+sorted rows with the experts' group sizes: every routed pair is
 computed, none is dropped (no capacity factor), and an expert nobody
-chose costs nothing but its place in the group table.  XLA's TPU
-backend lowers ``ragged_dot`` to a Mosaic grouped-matmul kernel of its
-own (``ragged-dot-*`` custom calls: visible in the device trace as
-Mosaic kernels with a 2-D output), so one code path serves a 32-row
-decode step inside a scan and a 4,096-row prefill group.  Matmuls take
+chose costs nothing but its place in the group table.  Matmuls take
 the activations' type (bf16 when serving) and accumulate in f32.
+
+``grouped_swiglu`` is that one entry, for ``expert_ffn`` and
+``expert_ffn_held`` alike, and its implementation is a function of the
+call's static shape, operand type and backend
+(``expert_matmul_impl``): under the chip's ridge in mean rows a group,
+in bf16 on a TPU, a Pallas kernel written for a call bound by
+streaming the hit experts' matrices (``stream_matmul``: sorted rows
+resident in VMEM, each hit expert's matrices streamed once in blocks
+of whole K, gate and up in one call with ``silu(gate) * up`` applied
+in VMEM); everywhere else ``jax.lax.ragged_dot``, which XLA's TPU
+backend lowers to a Mosaic grouped-matmul kernel of its own
+(``ragged-dot-*`` custom calls).  Either way the device trace shows
+Mosaic kernels with a 2-D ``(rows, width)`` output, and one entry
+serves a 256-row decode step inside a scan and a 16,384-row prefill
+group.
 
 ``route_grouped`` is the DeepSeek-V3 router: sigmoid scores in float32,
 a correction bias that takes part in the *selection* alone, groups of
@@ -35,6 +46,8 @@ metadata (``jax.named_scope``), whatever the compiler calls them.
 """
 
 from __future__ import annotations
+
+import functools
 
 EXPERTS_SCOPE = "moe_experts"
 ROUTER_SCOPE = "moe_router"
@@ -58,11 +71,304 @@ def route(h, w_router, top_k: int):
     return gates, experts.astype(jnp.int32)
 
 
+# ---------------------------------------------------------------------------
+# the grouped SwiGLU: one entry, the implementation chosen by shape
+# ---------------------------------------------------------------------------
+
+# Mean rows a group (rows / groups) under which a call takes the
+# streaming kernel below; at and over it ``ragged_dot``.  Under the
+# chip's ridge (~240 FLOP/B: 240 rows an expert) a call is bound by
+# streaming the hit experts' matrices, and there XLA's ``ragged_dot``
+# reads 23-38 % of that stream at 8 and more rows a group (59-70 % at a
+# decode step's 4).  A layer's three matmuls on the v5e, ``ragged_dot``
+# / this kernel in ms, at OLMoE's 64 x (2048, 1024) by rows: 256 1.66 /
+# 1.13 (59 -> 87 % of the stream), 2,048 2.72 / 1.17, 4,096 2.89 / 1.34,
+# 8,192 3.25 / 1.87, 16,384 4.33 / 2.88; at GigaChat's 8 held x (7168,
+# 2048) by a pass's rows (a quarter of them real): 128 0.94 / 0.79 (69
+# -> 82 %), 512 2.00 / 0.96, 1,024 2.00 / 1.14, 2,048 2.32 / 1.30,
+# 4,096 3.24 / 2.41, 8,192 3.88 / 3.53 (my chip runs, PR 31,
+# tools/probe_moe.py).  The line is the ridge, not the last row count
+# the kernel won at: past it the kernel is a compute-bound matmul with a
+# row loop nobody tuned.
+STREAM_MAX_MEAN_ROWS = 256
+# Bytes of one streamed weight block (whole K, as wide an N as fits):
+# two matrices (gate and up), double-buffered, are four of these in
+# VMEM.  A decode step's share of the stream by block bytes (same runs):
+# OLMoE 1 MiB (2048 x 256) 82 %, 2 MiB 87, 4 MiB (whole N) 88; GigaChat
+# (7168 x 128) 79, (x 256) 75, (x 512, 7 MiB) 82, (x 1024) 81.
+STREAM_BLOCK_BYTES = 8 << 20
+# Bytes of sorted rows one kernel call keeps resident (in float32, and
+# the pipeline allots them twice): a call of more rows is cut into
+# segments of :func:`stream_segment_rows`, each its own pair of kernel
+# calls over the groups its rows belong to — the rows are sorted by
+# group, so only a group that straddles a cut is streamed twice.
+STREAM_SEGMENT_BYTES = 16 << 20
+
+
+def matmul_backend() -> str:
+    """Where a grouped matmul traced now will run: ``"tpu"``, or the
+    process's default backend.  The tests of the streaming kernel answer
+    ``"interpret"`` here (the Pallas interpreter)."""
+    import jax
+
+    return jax.default_backend()
+
+
+def stream_block(k: int, n: int, itemsize: int = 2,
+                 block_bytes: int = STREAM_BLOCK_BYTES) -> int:
+    """The width of one streamed ``(k, width)`` weight block: the widest
+    multiple of 128 that divides ``n`` within ``block_bytes`` (at least
+    128; ``n`` itself where it is no multiple of 128)."""
+    if n % 128:
+        return n
+    fits = [w for w in range(128, n + 1, 128)
+            if n % w == 0 and k * w * itemsize <= block_bytes]
+    return max(fits, default=128)
+
+
+def stream_row_tile(rows: int, groups: int) -> int:
+    """Rows one matmul of the streaming kernel takes (a group's rows are
+    computed this many at a time from the 8-row tile its first row lies
+    in): the power of two from the mean rows a group, within 16 and
+    128.  It hardly matters under the ridge (a decode step at 8 to 128:
+    OLMoE 1.12-1.14 ms, GigaChat 0.79-0.83; 2,048 rows at 16 to 256:
+    1.16-1.22 ms): a matmul of few rows costs what loading its weights
+    into the MXU costs."""
+    tile = 16
+    while tile < 128 and tile * groups < rows:
+        tile *= 2
+    return tile
+
+
+def stream_segment_rows(width: int) -> int:
+    """Rows of one kernel call at rows ``width`` wide: what
+    :data:`STREAM_SEGMENT_BYTES` holds of them in float32, in whole
+    hundreds and twenty-eights."""
+    return STREAM_SEGMENT_BYTES // (4 * width) // 128 * 128
+
+
+def expert_matmul_impl(rows: int, groups: int, k: int, n: int, dtype,
+                       backend: str) -> str:
+    """``"stream"`` or ``"ragged_dot"`` for a grouped SwiGLU of ``rows``
+    sorted rows over ``groups`` experts of ``(k, n)`` gate and up and
+    ``(n, k)`` down matrices: a pure function of what a trace can see.
+    The streaming kernel where the operands are bfloat16, the backend
+    is a TPU (or the Pallas interpreter), the mean rows a group are
+    under :data:`STREAM_MAX_MEAN_ROWS` and a block of whole K and a
+    segment of rows fit the kernel's VMEM at these widths;
+    ``ragged_dot`` everywhere else."""
+    import jax.numpy as jnp
+
+    if backend not in ("tpu", "interpret") or jnp.dtype(dtype) != jnp.bfloat16:
+        return "ragged_dot"
+    if rows >= STREAM_MAX_MEAN_ROWS * groups:
+        return "ragged_dot"
+    fits = (max(k, n) * 128 * 2 <= STREAM_BLOCK_BYTES
+            and stream_segment_rows(max(k, n)) >= 256)
+    return "stream" if fits else "ragged_dot"
+
+
+def _stream_kernel(ids_ref, starts_ref, sizes_ref, hit_ref, x_ref, *refs,
+                   rows, row_tile, gated):
+    """One grid step ``(j, v)``: the ``v``-th *hit* group's rows through
+    the ``j``-th ``(K, width)`` block of its matrix (or of its gate and
+    up matrices, with ``silu(gate) * up`` applied here).
+
+    The sorted rows rest whole in VMEM in float32 (a dynamic row slice
+    must start on a tile of 8, which bfloat16's packed 16 would coarsen)
+    and are cast to the matrices' type a tile at a time.  A group's rows
+    are computed ``row_tile`` at a time from the 8-row tile its first
+    row lies in; what a tile holds of *earlier* groups is kept, what it
+    holds of *later* ones is overwritten when their turn comes (groups
+    are visited in ascending order), so rows past the last group are
+    left with whatever was computed there.  ``ids`` lists the hit groups
+    first and then repeats the last: the pipeline fetches a block only
+    when its index changes, so a group with no rows fetches nothing."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    n_w = 2 if gated else 1
+    w_refs, out_ref, acc_ref = refs[:n_w], refs[n_w], refs[n_w + 1]
+    v = pl.program_id(1)
+    group = ids_ref[v]
+    start = starts_ref[group]
+    end = start + sizes_ref[group]
+
+    @pl.when(v == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(v < hit_ref[0])
+    def _():
+        base = (start // 8) * 8
+
+        def tile(c, carry):
+            r0 = pl.multiple_of(base + c * row_tile, 8)
+            x = x_ref[pl.ds(r0, row_tile), :].astype(w_refs[0].dtype)
+            y = jnp.dot(x, w_refs[0][0], preferred_element_type=jnp.float32)
+            if gated:
+                up = jnp.dot(x, w_refs[1][0], preferred_element_type=jnp.float32)
+                y = jax.nn.silu(y) * up
+            row = r0 + jax.lax.broadcasted_iota(jnp.int32, (row_tile, 1), 0)
+            acc_ref[pl.ds(r0, row_tile), :] = jnp.where(
+                row >= start, y, acc_ref[pl.ds(r0, row_tile), :])
+            return carry
+
+        jax.lax.fori_loop(0, pl.cdiv(end - base, row_tile), tile, 0)
+
+    @pl.when(v == pl.num_programs(1) - 1)
+    def _():
+        out_ref[...] = acc_ref[pl.ds(0, rows), :].astype(out_ref.dtype)
+
+
+def stream_matmul(x, matrices, sizes, *, interpret: bool, row_tile: int = 16,
+                  block_bytes: int = STREAM_BLOCK_BYTES):
+    """``x`` ``(R, K)`` sorted by group, ``sizes`` ``(G,)`` -> ``(R, N)``
+    float32: ``x[g's rows] @ W[g]`` for one ``(G, K, N)`` matrix, or
+    ``silu(x @ W_gate[g]) * (x @ W_up[g])`` for two, as ONE Pallas call
+    (``moe_stream_down`` / ``moe_stream_gate_up``) that streams each hit
+    group's matrices once in ``(K, width)`` blocks
+    (:func:`stream_block`), double-buffered by the pipeline, with the
+    rows and the accumulator resident.  Operands in the matrices' type,
+    float32 accumulation.  The output is 2-D ``(R, N)``: what the
+    benchmark's readers know a grouped matmul by."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, k = x.shape
+    groups, _, n = matrices[0].shape
+    gated = len(matrices) == 2
+    width = stream_block(k, n, jnp.dtype(matrices[0].dtype).itemsize, block_bytes)
+    sizes = sizes.astype(jnp.int32)
+    starts = jnp.cumsum(sizes) - sizes
+    seen = jnp.cumsum((sizes > 0).astype(jnp.int32))    # hit groups up to g
+    hit = seen[-1]
+    # the v-th hit group is the first whose count passes v; past the
+    # last hit group the list repeats it
+    v = jnp.minimum(jnp.arange(groups), hit - 1)
+    ids = jnp.minimum((seen[None, :] <= v[:, None]).sum(axis=-1),
+                      groups - 1).astype(jnp.int32)
+    padded = -(-rows // 8) * 8 + row_tile
+    x = jnp.concatenate(
+        [x.astype(jnp.float32), jnp.zeros((padded - rows, k), jnp.float32)])
+    w_spec = pl.BlockSpec((1, k, width), lambda j, v, ids, *_: (ids[v], 0, j))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(n // width, groups),
+        in_specs=[pl.BlockSpec((padded, k), lambda j, v, *_: (0, 0))]
+        + [w_spec] * len(matrices),
+        out_specs=pl.BlockSpec((rows, width), lambda j, v, *_: (0, j)),
+        scratch_shapes=[pltpu.VMEM((padded, width), jnp.float32)],
+    )
+    # resident rows and the streamed blocks twice (the pipeline's two
+    # buffers), the output block twice, the accumulator
+    vmem = (2 * padded * k * 4 + 2 * len(matrices) * k * width * 2
+            + 2 * rows * width * 4 + padded * width * 4)
+    return pl.pallas_call(
+        functools.partial(_stream_kernel, rows=rows, row_tile=row_tile,
+                          gated=gated),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, n), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=min(vmem + (16 << 20), 120 << 20)),
+        interpret=interpret,
+        name="moe_stream_gate_up" if gated else "moe_stream_down",
+    )(ids, starts, sizes, hit.reshape(1), x, *matrices)
+
+
+def stream_swiglu(rows, w_gate, w_up, w_down, sizes, *, interpret: bool):
+    """The grouped SwiGLU as two streaming kernels a segment of
+    :func:`stream_segment_rows` rows: ``silu(gate) * up`` in the first,
+    the down projection of what it leaves in the second.  A segment is
+    handed the part of each group's rows that lies in it; one with no
+    row of any group (a held pass's rows past its groups) runs nothing
+    and comes back as zeros.  Jitted: a program traces and lowers it
+    once for all its layers."""
+    return _stream_swiglu_jit()(rows, w_gate, w_up, w_down, sizes,
+                                interpret=interpret)
+
+
+@functools.lru_cache(maxsize=None)
+def _stream_swiglu_jit():
+    import jax
+
+    return jax.jit(_stream_swiglu, static_argnames=("interpret",))
+
+
+def _stream_swiglu(rows, w_gate, w_up, w_down, sizes, *, interpret: bool):
+    import jax
+    import jax.numpy as jnp
+
+    total, d = rows.shape
+    groups, _, f = w_gate.shape
+    kw = dict(interpret=interpret, row_tile=stream_row_tile(total, groups))
+
+    def run(x, part):
+        act = stream_matmul(x, (w_gate, w_up), part, **kw)
+        return stream_matmul(act, (w_down,), part, **kw)
+
+    seg = stream_segment_rows(max(d, f))
+    if total <= seg:
+        return run(rows, sizes)
+    ends = jnp.cumsum(sizes.astype(jnp.int32))
+    starts = ends - sizes
+    outs = []
+    for lo in range(0, total, seg):
+        hi = min(lo + seg, total)
+        part = jnp.clip(ends, lo, hi) - jnp.clip(starts, lo, hi)
+        outs.append(jax.lax.cond(
+            part.sum() > 0, run,
+            lambda x, _part: jnp.zeros((x.shape[0], d), jnp.float32),
+            rows[lo:hi], part))
+    return jnp.concatenate(outs)
+
+
+def ragged_swiglu(rows, w_gate, w_up, w_down, sizes, inner):
+    """The grouped SwiGLU as three ``jax.lax.ragged_dot``s; gate and up
+    leave theirs in ``inner``."""
+    import jax
+    import jax.numpy as jnp
+
+    gate = jax.lax.ragged_dot(rows, w_gate, sizes, preferred_element_type=inner)
+    up = jax.lax.ragged_dot(rows, w_up, sizes, preferred_element_type=inner)
+    act = (jax.nn.silu(gate.astype(jnp.float32))
+           * up.astype(jnp.float32)).astype(w_down.dtype)
+    # what leaves the layer stays float32 up to the residual add
+    return jax.lax.ragged_dot(act, w_down, sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def grouped_swiglu(rows, w_gate, w_up, w_down, sizes, *, inner=None):
+    """``W_down[g] (silu(r W_gate[g]) * (r W_up[g]))`` for every row
+    ``r`` of ``rows`` ``(R, d)``, sorted by group with ``sizes`` ``(G,)``
+    rows each (rows past the groups come back undefined): ``(R, d)``
+    float32.  ``w_gate`` / ``w_up`` ``(G, d, f)``, ``w_down`` ``(G, f,
+    d)``.  The implementation is :func:`expert_matmul_impl`'s answer for
+    this call's static shape, type and backend; ``inner`` is the type
+    gate and up leave ``ragged_dot`` in (the rows' type by default; the
+    streaming kernel keeps them in float32)."""
+    import jax
+
+    d, f = w_gate.shape[1:]
+    backend = matmul_backend()
+    impl = expert_matmul_impl(rows.shape[0], w_gate.shape[0], d, f,
+                              rows.dtype, backend)
+    with jax.named_scope(EXPERTS_SCOPE):
+        if impl == "stream":
+            return stream_swiglu(rows, w_gate, w_up, w_down, sizes,
+                                  interpret=backend != "tpu")
+        return ragged_swiglu(rows, w_gate, w_up, w_down, sizes,
+                             rows.dtype if inner is None else inner)
+
+
 def expert_ffn(h, w_gate, w_up, w_down, gates, experts):
     """``sum_k gates[t, k] * W_down[e] (silu(h W_gate[e]) * (h W_up[e]))``
     with ``e = experts[t, k]``: ``h`` ``(T, d)``, ``w_gate``/``w_up``
     ``(E, d, f)``, ``w_down`` ``(E, f, d)`` -> ``(T, d)`` float32."""
-    import jax
     import jax.numpy as jnp
 
     tokens, top_k = experts.shape
@@ -72,14 +378,7 @@ def expert_ffn(h, w_gate, w_up, w_down, gates, experts):
     sizes = jnp.zeros((num_experts,), jnp.int32).at[flat].add(1)
     rows = h.astype(w_gate.dtype)[order // top_k]           # (T*k, d)
     inner = jnp.float32 if h.dtype == jnp.float32 else w_gate.dtype
-    with jax.named_scope(EXPERTS_SCOPE):
-        gate = jax.lax.ragged_dot(rows, w_gate, sizes, preferred_element_type=inner)
-        up = jax.lax.ragged_dot(rows, w_up, sizes, preferred_element_type=inner)
-        act = (jax.nn.silu(gate.astype(jnp.float32))
-               * up.astype(jnp.float32)).astype(w_down.dtype)
-        # what leaves the layer stays float32 up to the residual add
-        out = jax.lax.ragged_dot(act, w_down, sizes,
-                                 preferred_element_type=jnp.float32)
+    out = grouped_swiglu(rows, w_gate, w_up, w_down, sizes, inner=inner)
     # back to (token, k) order, then the gated sum over a token's k
     out = out[jnp.argsort(order)].reshape(tokens, top_k, -1)
     return jnp.einsum("tkd,tk->td", out, gates)
@@ -145,6 +444,21 @@ def held_rows_cap(tokens: int, top_k: int, held: int, num_experts: int) -> int:
     return cap
 
 
+def layer_expert_matmul(tokens: int, top_k: int, held: int, num_experts: int,
+                        d_model: int, width: int, dtype, *, held_pass: bool,
+                        backend=None) -> str:
+    """What :func:`grouped_swiglu` runs in an expert layer over
+    ``tokens`` tokens: :func:`expert_matmul_impl` at the rows the layer
+    hands it — every assignment (:func:`expert_ffn`), or one pass's
+    :func:`held_rows_cap` (:func:`expert_ffn_held`, ``held_pass``).  An
+    engine's ``lane_report()`` says it of each of its programs."""
+    rows = (held_rows_cap(tokens, top_k, held, num_experts) if held_pass
+            else tokens * top_k)
+    return expert_matmul_impl(
+        rows, held, d_model, width, dtype,
+        matmul_backend() if backend is None else backend)
+
+
 def expert_ffn_held(h, w_gate, w_up, w_down, gates, experts, offset: int,
                     num_experts: int):
     """The held experts' part of :func:`expert_ffn`'s sum: ``w_gate`` /
@@ -188,15 +502,7 @@ def expert_ffn_held(h, w_gate, w_up, w_down, gates, experts, offset: int,
         sizes = (which[:, None] == jnp.arange(held)[None, :]).sum(
             axis=0).astype(jnp.int32)
         rows = rows_in[jnp.minimum(idx // top_k, tokens - 1)]       # (cap, d)
-        with jax.named_scope(EXPERTS_SCOPE):
-            gate = jax.lax.ragged_dot(rows, w_gate, sizes,
-                                      preferred_element_type=inner)
-            up = jax.lax.ragged_dot(rows, w_up, sizes,
-                                    preferred_element_type=inner)
-            act = (jax.nn.silu(gate.astype(jnp.float32))
-                   * up.astype(jnp.float32)).astype(w_down.dtype)
-            out = jax.lax.ragged_dot(act, w_down, sizes,
-                                     preferred_element_type=jnp.float32)
+        out = grouped_swiglu(rows, w_gate, w_up, w_down, sizes, inner=inner)
         # rows past the groups hold whatever the kernel left there
         out = jnp.where((which < held)[:, None], out, 0.0)
         out = jnp.concatenate([out, jnp.zeros((1, d_model), out.dtype)])

@@ -183,15 +183,22 @@ def test_logits_match_the_reference_f32(monkeypatch, lane, program):
 
 
 @pytest.mark.parametrize("program", ["prefill", "decode", "cached"])
-@pytest.mark.parametrize("lane", sorted(LANES))
-def test_logits_match_the_reference_bf16(monkeypatch, lane, program):
+@pytest.mark.parametrize("lane,experts", [
+    *((lane, "ragged_dot") for lane in sorted(LANES)), ("kernel", "stream")])
+def test_logits_match_the_reference_bf16(monkeypatch, lane, program, experts):
     """The serving precision: matrices at rest in bf16 (router, its
     correction bias and norm scales f32), a bf16 latent pool, f32
-    router and residual stream."""
+    router and residual stream; the held experts through ``ragged_dot``
+    (what a CPU traces) and through the streaming kernel inside the
+    pass loop (what a TPU traces at a decode pass's rows: here under
+    the interpreter)."""
+    if experts == "stream":
+        monkeypatch.setattr(moe, "matmul_backend", lambda: "interpret")
     eng, params = _engine(
         monkeypatch, lane, dtype=jnp.bfloat16,
         params=init_params(SPEC, SIZES, BF16_SEED, dtype=jnp.bfloat16))
     try:
+        assert set(eng.lane_report()["expert_matmul"].values()) >= {experts}
         block = params["block_1"]
         assert block["experts_gate"].dtype == block["kv_b_k"].dtype == jnp.bfloat16
         assert block["router"].dtype == block["score_bias"].dtype == jnp.float32

@@ -2,7 +2,12 @@
 all-experts einsum (every expert applied to every row, then only the
 routed ones kept): random routing, an expert that receives no row, one
 that receives every row, a decode step's few rows and a prefill
-group's many."""
+group's many.
+
+Every case runs on both implementations of ``grouped_swiglu``:
+``ragged_dot`` in float32 (what the CPU lanes trace) and the streaming
+Pallas kernel under the interpreter in bfloat16 (``lane``: the test
+answers ``"interpret"`` where the program asks for its backend)."""
 
 import numpy as np
 import pytest
@@ -23,6 +28,28 @@ def weights(seed=0, dtype=jnp.float32):
             (jax.random.normal(ks[3], (E, F, D), jnp.float32) * F ** -0.5).astype(dtype))
 
 
+# bf16 operands against the same bf16-rounded operands in float32: what
+# is left is the rounding of each matmul's output, a few parts in a
+# thousand of unit-spread values
+TOL = {"ragged_dot": 1e-5, "stream": 0.02}
+
+
+@pytest.fixture(params=["ragged_dot", "stream"])
+def lane(request, monkeypatch):
+    """``(implementation, operand type)``; on ``stream`` the grouped
+    SwiGLU is told its backend is the Pallas interpreter."""
+    if request.param == "stream":
+        monkeypatch.setattr(moe, "matmul_backend", lambda: "interpret")
+        return "stream", jnp.bfloat16
+    return "ragged_dot", jnp.float32
+
+
+def runs(impl, rows, groups=E, dtype=None):
+    """The rule's answer for this test's widths, as the call asks it."""
+    dtype = dtype or (jnp.bfloat16 if impl == "stream" else jnp.float32)
+    return moe.expert_matmul_impl(rows, groups, D, F, dtype, moe.matmul_backend())
+
+
 def dense(h, w_gate, w_up, w_down, gates, experts):
     """Every expert on every row, in float32; then the routed pairs."""
     h, w_gate, w_up, w_down = (a.astype(jnp.float32) for a in (h, w_gate, w_up, w_down))
@@ -34,23 +61,32 @@ def dense(h, w_gate, w_up, w_down, gates, experts):
     return (picked * gates[:, :, None]).sum(axis=1)
 
 
-@pytest.mark.parametrize("rows,top_k", [(1, 2), (4, 2), (32, 2), (200, 3), (64, 8)])
-def test_routed_rows_equal_the_dense_einsum(rows, top_k):
-    w_router, w_gate, w_up, w_down = weights()
-    h = jax.random.normal(jax.random.key(rows), (rows, D), jnp.float32)
+# (700, 3) is a prefill group's: 262 rows an expert, over the rule's
+# line, so both lanes run ragged_dot there (in bf16 on the second);
+# (50, 3) and (25, 8) are no multiple of the kernel's row tile
+@pytest.mark.parametrize("rows,top_k", [(1, 2), (4, 2), (32, 2), (200, 3), (64, 8),
+                                        (50, 3), (25, 8), (700, 3)])
+def test_routed_rows_equal_the_dense_einsum(lane, rows, top_k):
+    impl, dtype = lane
+    w_router, w_gate, w_up, w_down = weights(dtype=dtype)
+    h = jax.random.normal(jax.random.key(rows), (rows, D), jnp.float32).astype(dtype)
     gates, experts = moe.route(h, w_router, top_k)
+    over = rows * top_k >= moe.STREAM_MAX_MEAN_ROWS * E
+    assert runs(impl, rows * top_k) == ("ragged_dot" if over else impl)
     got = moe.expert_ffn(h, w_gate, w_up, w_down, gates, experts)
     want = dense(h, w_gate, w_up, w_down, gates, experts)
     assert got.shape == (rows, D) and got.dtype == jnp.float32
-    assert np.abs(np.asarray(got - want)).max() < 1e-5
+    assert np.abs(np.asarray(got - want)).max() < TOL[impl]
 
 
 @pytest.mark.parametrize("case", ["an_expert_with_no_row", "one_expert_takes_all",
                                   "first_and_last_only"])
-def test_uneven_groups(case):
-    _w_router, w_gate, w_up, w_down = weights(1)
+def test_uneven_groups(lane, case):
+    impl, dtype = lane
+    _w_router, w_gate, w_up, w_down = weights(1, dtype)
     rows, top_k = 24, 2
-    h = jax.random.normal(jax.random.key(7), (rows, D), jnp.float32)
+    assert runs(impl, rows * top_k) == impl
+    h = jax.random.normal(jax.random.key(7), (rows, D), jnp.float32).astype(dtype)
     rng = np.random.default_rng(3)
     if case == "an_expert_with_no_row":       # expert 3 never chosen
         pool = np.array([e for e in range(E) if e != 3])
@@ -64,7 +100,7 @@ def test_uneven_groups(case):
     gates = jnp.asarray(rng.uniform(0.05, 0.5, size=(rows, top_k)), jnp.float32)
     got = moe.expert_ffn(h, w_gate, w_up, w_down, gates, experts)
     want = dense(h, w_gate, w_up, w_down, gates, experts)
-    assert np.abs(np.asarray(got - want)).max() < 1e-5
+    assert np.abs(np.asarray(got - want)).max() < TOL[impl]
     hist = np.asarray(moe.expert_histogram(experts, E))
     assert hist.sum() == rows * top_k
     assert hist.tolist() == np.bincount(np.asarray(experts).ravel(), minlength=E).tolist()
@@ -95,22 +131,25 @@ def test_histogram_counts_only_the_rows_the_mask_keeps():
     assert np.asarray(kept).tolist() == [2, 1, 0, 0, 0, 0, 0, 1]
 
 
-def test_bf16_rows_accumulate_in_float32():
-    """bf16 operands, f32 accumulation: against the same bf16-rounded
-    operands in float32 what is left is the rounding of each matmul's
-    output, a few parts in a thousand of unit-spread values."""
+def test_bf16_rows_accumulate_in_float32(lane):
+    """bf16 operands, f32 accumulation (``TOL``), on ``ragged_dot``
+    (gate and up leave it in bf16) and in the kernel (which keeps them
+    in float32 until their product)."""
+    impl, _dtype = lane
     w_router, w_gate, w_up, w_down = weights(4, jnp.bfloat16)
     h = jax.random.normal(jax.random.key(5), (48, D), jnp.float32).astype(jnp.bfloat16)
     gates, experts = moe.route(h, w_router, 2)
+    assert runs(impl, 96, dtype=jnp.bfloat16) == impl
     got = moe.expert_ffn(h, w_gate, w_up, w_down, gates, experts)
     want = dense(h, w_gate, w_up, w_down, gates, experts)
     assert got.dtype == jnp.float32
     assert np.abs(np.asarray(got - want)).max() < 0.02
 
 
-def test_inside_a_scan_as_the_decode_chunk_runs_it():
-    w_router, w_gate, w_up, w_down = weights(6)
-    hs = jax.random.normal(jax.random.key(9), (3, 4, D), jnp.float32)
+def test_inside_a_scan_as_the_decode_chunk_runs_it(lane):
+    impl, dtype = lane
+    w_router, w_gate, w_up, w_down = weights(6, dtype)
+    hs = jax.random.normal(jax.random.key(9), (3, 4, D), jnp.float32).astype(dtype)
 
     def step(acc, h):
         gates, experts = moe.route(h, w_router, 2)
@@ -122,4 +161,200 @@ def test_inside_a_scan_as_the_decode_chunk_runs_it():
     for i in range(3):
         gates, experts = moe.route(hs[i], w_router, 2)
         want = dense(hs[i], w_gate, w_up, w_down, gates, experts)
-        assert np.abs(np.asarray(outs[i] - want)).max() < 1e-5
+        assert np.abs(np.asarray(outs[i] - want)).max() < TOL[impl]
+
+
+# ---------------------------------------------------------------------------
+# a replica's share (expert_ffn_held): pad rows past the groups, a
+# second pass, and the while_loop both run in
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("case", ["one_pass_with_pad_rows", "a_second_pass",
+                                  "nothing_local"])
+def test_held_experts_in_a_while_loop(lane, case):
+    """Experts 2..5 held, of 32 as the pass is sized: 64 rows
+    (``held_rows_cap``) of which random choices among the eight fill
+    about 40 (the rest lie past the groups and come back as whatever
+    the kernel left there); every token choosing two held experts is 80
+    and needs two passes; no token choosing any leaves the loop unrun."""
+    impl, dtype = lane
+    _w_router, w_gate, w_up, w_down = weights(8, dtype)
+    tokens, top_k, offset, held, of = 40, 2, 2, 4, 32
+    assert moe.held_rows_cap(tokens, top_k, held, of) == 64
+    assert runs(impl, 64, groups=held) == impl
+    h = jax.random.normal(jax.random.key(21), (tokens, D), jnp.float32).astype(dtype)
+    rng = np.random.default_rng(12)
+    if case == "one_pass_with_pad_rows":
+        experts = np.stack([rng.choice(E, top_k, replace=False) for _ in range(tokens)])
+    elif case == "a_second_pass":
+        experts = np.tile([2, 3], (tokens, 1))       # 80 local assignments
+    else:
+        experts = np.stack([rng.choice([0, 1, 6, 7], top_k, replace=False)
+                            for _ in range(tokens)])
+    experts = jnp.asarray(experts, jnp.int32)
+    gates = jnp.asarray(rng.uniform(0.05, 0.5, size=(tokens, top_k)), jnp.float32)
+    sl = slice(offset, offset + held)
+    got = jax.jit(lambda *a: moe.expert_ffn_held(*a, offset, of))(
+        h, w_gate[sl], w_up[sl], w_down[sl], gates, experts)
+    local = (experts >= offset) & (experts < offset + held)
+    want = dense(h, w_gate, w_up, w_down, jnp.where(local, gates, 0.0), experts)
+    assert got.shape == (tokens, D) and got.dtype == jnp.float32
+    assert np.abs(np.asarray(got - want)).max() < TOL[impl]
+    if case == "nothing_local":
+        assert not np.asarray(got).any()
+
+
+# ---------------------------------------------------------------------------
+# the streaming kernel by itself, and the rule that chooses it
+# ---------------------------------------------------------------------------
+
+def grouped_dense(x, matrices, sizes):
+    """Every row through its own group's matrices, in float32."""
+    owner = np.repeat(np.arange(len(sizes)), sizes)
+    x = np.asarray(x.astype(jnp.float32))[: len(owner)]
+    mats = [np.asarray(m.astype(jnp.float32)) for m in matrices]
+    y = np.einsum("rk,rkn->rn", x, mats[0][owner])
+    if len(mats) == 2:
+        y = np.asarray(jax.nn.silu(jnp.asarray(y))) * np.einsum("rk,rkn->rn", x, mats[1][owner])
+    return y
+
+
+@pytest.mark.parametrize("row_tile", [8, 16, 128])
+@pytest.mark.parametrize("sizes", [
+    (5, 0, 7, 1, 0, 0, 19, 3),        # empty groups, none aligned to a tile
+    (0, 0, 0, 35, 0, 0, 0, 0),        # every row on one group: several tiles
+    (1, 1, 1, 1, 1, 1, 1, 1),
+    (0, 0, 9, 4, 0, 0, 0, 0),         # rows past the groups: 22 of 35
+    (0, 0, 0, 0, 0, 0, 0, 0),         # no group hit: nothing to compute
+])
+def test_the_streaming_kernel_against_each_rows_own_group(sizes, row_tile):
+    """``stream_matmul`` with one matrix a group and with two (gate and
+    up, ``silu(gate) * up`` in the kernel), in ``(K, 128)`` blocks of a
+    256-wide matrix (two grid columns), 35 rows (no multiple of 8)."""
+    k, n, rows = 32, 256, 35
+    ks = jax.random.split(jax.random.key(sum(sizes) + row_tile), 3)
+    x = jax.random.normal(ks[0], (rows, k), jnp.float32).astype(jnp.bfloat16)
+    w1 = (jax.random.normal(ks[1], (E, k, n), jnp.float32) * k ** -0.5).astype(jnp.bfloat16)
+    w2 = (jax.random.normal(ks[2], (E, k, n), jnp.float32) * k ** -0.5).astype(jnp.bfloat16)
+    assert moe.stream_block(k, n, block_bytes=k * 128 * 2) == 128
+    real = sum(sizes)
+    for matrices in ((w1,), (w1, w2)):
+        got = moe.stream_matmul(x, matrices, jnp.asarray(sizes, jnp.int32), interpret=True,
+                                row_tile=row_tile, block_bytes=k * 128 * 2)
+        assert got.shape == (rows, n) and got.dtype == jnp.float32
+        want = grouped_dense(x, matrices, np.asarray(sizes))
+        assert np.isfinite(np.asarray(got)).all()
+        assert np.abs(np.asarray(got)[:real] - want).max(initial=0.0) < 2e-3
+
+
+@pytest.mark.parametrize("real", [300, 200, 100])
+def test_a_call_of_more_rows_than_a_segment_is_cut_by_rows(monkeypatch, real):
+    """300 sorted rows in segments of 128: a group that straddles a cut
+    is computed in both segments, part by part; with 200 real rows the
+    third segment holds rows past the groups only, with 100 the second
+    too, and runs nothing."""
+    monkeypatch.setattr(moe, "STREAM_SEGMENT_BYTES", 128 * 4 * D)
+    assert moe.stream_segment_rows(D) == 128
+    _w_router, w_gate, w_up, w_down = weights(13, jnp.bfloat16)
+    rng = np.random.default_rng(real)
+    sizes = rng.multinomial(real, [0.3, 0.0, 0.1, 0.25, 0.0, 0.05, 0.2, 0.1])
+    x = jax.random.normal(jax.random.key(real), (300, D), jnp.float32).astype(jnp.bfloat16)
+    got = jax.jit(lambda *a: moe.stream_swiglu(*a, interpret=True))(
+        x, w_gate, w_up, w_down, jnp.asarray(sizes, jnp.int32))
+    assert got.shape == (300, D) and got.dtype == jnp.float32
+    act = grouped_dense(x, (w_gate, w_up), sizes)
+    want = grouped_dense(jnp.asarray(act).astype(jnp.bfloat16), (w_down,), sizes)
+    assert np.abs(np.asarray(got)[:real] - want).max() < 0.02
+    if real <= 256:     # a segment of no group's rows comes back as zeros
+        assert not np.asarray(got)[256:].any()
+
+
+def test_the_rule_is_a_function_of_shape_type_and_backend():
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    rule = moe.expert_matmul_impl
+    # OLMoE: a decode step's 32 lanes x top-8 over 64 experts of 2048 x 1024
+    assert rule(256, 64, 2048, 1024, bf16, "tpu") == "stream"
+    assert rule(256, 64, 2048, 1024, bf16, "interpret") == "stream"
+    # GigaChat: a decode pass's 128 rows over 8 held experts of 7168 x 2048
+    assert rule(moe.held_rows_cap(128, 8, 8, 256), 8, 7168, 2048, bf16, "tpu") == "stream"
+    # ... and its prefill passes: a 1,024-token prompt's 128 rows an
+    # expert stream, 256 and more (the ridge) stay on ragged_dot
+    for tokens, runs_ in ((1024, "stream"), (2048, "ragged_dot"), (8192, "ragged_dot")):
+        rows = moe.held_rows_cap(tokens, 8, 8, 256)
+        assert rows == tokens and rule(rows, 8, 7168, 2048, bf16, "tpu") == runs_
+    # OLMoE's prefill groups: 32 to 128 rows an expert stream, the
+    # largest (512 x 4 prompts: 256 rows an expert) does not
+    for rows in (2048, 4096, 8192):
+        assert rule(rows, 64, 2048, 1024, bf16, "tpu") == "stream"
+    assert rule(16384, 64, 2048, 1024, bf16, "tpu") == "ragged_dot"
+    assert [moe.stream_row_tile(r, 64) for r in (256, 2048, 8192)] == [16, 32, 128]
+    assert moe.stream_segment_rows(2048) == 2048 and moe.stream_segment_rows(7168) == 512
+    # the CPU exactness lanes, and every backend that is neither
+    assert rule(256, 64, 2048, 1024, f32, "tpu") == "ragged_dot"
+    assert rule(256, 64, 2048, 1024, bf16, "cpu") == "ragged_dot"
+    assert rule(256, 64, 2048, 1024, bf16, "gpu") == "ragged_dot"
+    assert moe.matmul_backend() == "cpu"
+    # the layer's rows are the rule's: every assignment, or one pass's
+    assert moe.layer_expert_matmul(
+        32, 8, 64, 64, 2048, 1024, bf16, held_pass=False, backend="tpu") == "stream"
+    assert moe.layer_expert_matmul(
+        2048, 8, 8, 256, 7168, 2048, bf16, held_pass=True, backend="tpu") == "ragged_dot"
+    # a block is whole K and the widest N that divides and fits
+    assert moe.stream_block(2048, 1024) == 1024 and moe.stream_block(1024, 2048) == 2048
+    assert moe.stream_block(7168, 2048) * 7168 * 2 <= moe.STREAM_BLOCK_BYTES
+    assert 7168 % moe.stream_block(2048, 7168) == 0
+    assert moe.stream_block(32, 16) == 16         # no multiple of 128: whole
+
+
+@pytest.mark.parametrize("backend", ["cpu", "interpret"])
+def test_lane_report_says_what_the_programs_traced(monkeypatch, backend):
+    """A small OLMoE engine (8 experts top-2, 4 slots, buckets 16 / 32 /
+    64): ``lane_report()["expert_matmul"]`` names every chunk and
+    prefill program, and each program's trace asked the rule the same
+    question and got the same answer."""
+    import os
+    import sys
+
+    from seldon_core_tpu.models.paged import PagedEngine
+    from seldon_core_tpu.models.spec import init_params
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
+    from reference import olmoe as ref
+
+    spec, sizes = ref.spec_and_config(dict(
+        hidden_size=64, num_hidden_layers=2, num_attention_heads=4, num_experts=8,
+        num_experts_per_tok=2, intermediate_size=32, rms_norm_eps=1e-5,
+        rope_theta=10000, vocab_size=97))
+    monkeypatch.setattr(moe, "matmul_backend", lambda: backend)
+    # the rule's line drawn where this engine's programs straddle it
+    monkeypatch.setattr(moe, "STREAM_MAX_MEAN_ROWS", 32)
+    asked, rule = [], moe.expert_matmul_impl
+    monkeypatch.setattr(moe, "expert_matmul_impl",
+                        lambda *a: asked.append(rule(*a)) or asked[-1])
+    eng = PagedEngine(init_params(spec, sizes, 3, dtype=jnp.bfloat16), **sizes,
+                      max_len=64, page_size=8, max_slots=4, steps_per_call=2,
+                      dtype=jnp.bfloat16, spec=spec)
+    try:
+        report = eng.lane_report()["expert_matmul"]
+        assert sorted(report) == ["chunk"] + [
+            f"prefill_b{b}_k{k}" for b in (16, 32, 64) for k in (1, 2, 4)]
+        if backend == "cpu":
+            assert set(report.values()) == {"ragged_dot"}
+        else:   # under 32 rows an expert: 8 x 32 = 256 assignment rows
+            assert {k for k, v in report.items() if v == "stream"} == {
+                "chunk", "prefill_b16_k1", "prefill_b16_k2", "prefill_b16_k4",
+                "prefill_b32_k1", "prefill_b32_k2", "prefill_b64_k1"}
+        i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+        unwrap = lambda fn: fn if hasattr(fn, "lower") else fn.__wrapped__  # noqa: E731
+        pools = eng._kv_args()
+        for name in ("chunk", "prefill_b16_k2", "prefill_b64_k2"):
+            del asked[:]
+            if name == "chunk":
+                eng.lower_chunk(2, ((4, 4),))
+            else:
+                bucket, k = int(name.split("_")[1][1:]), int(name.split("_")[2][1:])
+                unwrap(eng._build_prefill(bucket, k)).lower(
+                    eng.params, *pools, i32(k, bucket), i32(k), i32(k, bucket // 8))
+            assert asked and set(asked) == {report[name]}, name
+    finally:
+        eng.close()

@@ -176,13 +176,19 @@ def test_logits_match_the_reference_f32(monkeypatch, lane, program):
         eng.close()
 
 
+@pytest.mark.parametrize("experts", ["ragged_dot", "stream"])
 @pytest.mark.parametrize("program", ["prefill", "decode", "cached"])
 @pytest.mark.parametrize("lane", ["kernel", "gather"])
-def test_logits_match_the_reference_bf16(monkeypatch, lane, program):
+def test_logits_match_the_reference_bf16(monkeypatch, lane, program, experts):
     """The serving precision: weights at rest in bf16 (router and norm
-    scales f32), bf16 pool and matmuls, f32 router."""
+    scales f32), bf16 pool and matmuls, f32 router; the experts through
+    ``ragged_dot`` (what a CPU traces) and through the streaming kernel
+    (what a TPU traces at these rows: here under the interpreter)."""
+    if experts == "stream":
+        monkeypatch.setattr(moe, "matmul_backend", lambda: "interpret")
     eng, params = _engine(monkeypatch, lane, dtype=jnp.bfloat16)
     try:
+        assert set(eng.lane_report()["expert_matmul"].values()) >= {experts}
         assert params["block_0"]["experts_gate"].dtype == jnp.bfloat16
         assert params["block_0"]["router"].dtype == jnp.float32
         rows, tokens, at = _run(eng, program)

@@ -112,12 +112,40 @@ def test_stream_kernel_compiles_on_the_whole_pool(one_chip, mosaic, geometry,
 # OLMoE (PR 26): the routed expert layer at its published widths
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("rows", [32, 4096])
-def test_expert_layer_compiles_as_grouped_matmul_kernels(one_chip, mosaic, rows):
-    """A decode step's 32 rows and a prefill group's 4,096 through
-    ``ops/moe.py`` at 64 experts of 2048 x 1024, top-8: the three
-    ``ragged_dot``s arrive as the TPU compiler's own Mosaic
-    grouped-matmul kernels (what the ``moe_*`` readers look for)."""
+@pytest.fixture(params=["cpu", "tpu"])
+def backend(request, monkeypatch):
+    """What ``ops/moe.py`` is told its grouped matmuls run on: the
+    process's own backend (the CPU: ``ragged_dot`` at every shape), or
+    the TPU the programs here are compiled for (the rule's choice)."""
+    from seldon_core_tpu.ops import moe
+
+    if request.param == "tpu":
+        monkeypatch.setattr(moe, "matmul_backend", lambda: "tpu")
+    return request.param
+
+
+def assert_expert_kernels(text, streams, rows, d, f):
+    """The three ``ragged_dot``s as the TPU compiler's own Mosaic
+    grouped-matmul kernels, or the two streaming kernels by name; either
+    way custom calls whose first output is 2-D ``(rows, width)``: what
+    the benchmark's readers know a decode step's expert kernels by."""
+    assert "tpu_custom_call" in text
+    if streams:
+        assert "ragged-dot" not in text
+        assert "moe_stream_gate_up" in text and "moe_stream_down" in text
+        assert f"f32[{rows},{f}]" in text and f"f32[{rows},{d}]" in text
+    else:
+        assert text.count("ragged-dot") >= 3 and "moe_stream" not in text
+
+
+@pytest.mark.parametrize("rows", [32, 512, 4096])
+def test_expert_layer_compiles_as_grouped_matmul_kernels(one_chip, mosaic, backend, rows):
+    """A decode step's 32 rows and prefill groups of 512 and 4,096
+    through ``ops/moe.py`` at 64 experts of 2048 x 1024, top-8, the
+    decode step inside a ``scan`` as the chunk runs it: on a TPU the
+    first streams its experts through the Pallas kernel, the second (64
+    rows an expert) too, in two segments of 2,048 rows, and the third
+    (512 rows an expert) stays on ``ragged_dot``."""
     from seldon_core_tpu.ops import moe
 
     d, f, e, k = 2048, 1024, 64, 8
@@ -126,16 +154,23 @@ def test_expert_layer_compiles_as_grouped_matmul_kernels(one_chip, mosaic, rows)
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def layer(h, w_router, w_gate, w_up, w_down):
-        gates, experts = moe.route(h, w_router, k)
-        out = moe.expert_ffn(h.astype(jnp.bfloat16), w_gate, w_up, w_down, gates, experts)
-        return out, moe.expert_histogram(experts, e)
+        def step(h, _):
+            gates, experts = moe.route(h, w_router, k)
+            out = moe.expert_ffn(h.astype(jnp.bfloat16), w_gate, w_up, w_down, gates, experts)
+            return h + out, moe.expert_histogram(experts, e)
+
+        if rows > 32:
+            return step(h, None)
+        out, hist = jax.lax.scan(step, h, None, length=2)
+        return out, hist.sum(axis=0)
 
     compiled = jax.jit(layer).lower(
         spec((rows, d), jnp.float32), spec((d, e), jnp.float32),
         spec((e, d, f), jnp.bfloat16), spec((e, d, f), jnp.bfloat16),
         spec((e, f, d), jnp.bfloat16)).compile()
     text = compiled.as_text()
-    assert text.count("ragged-dot") >= 3 and "tpu_custom_call" in text
+    assert_expert_kernels(text, backend == "tpu" and rows < 4096,
+                          min(rows * k, moe.stream_segment_rows(d)), d, f)
     # only routed rows are materialised: the temporaries are a few
     # copies of the rows x top-k assignments (the down projection leaves
     # in f32 and is re-ordered once), a quarter of what a dense
@@ -199,12 +234,16 @@ def test_a_576_wide_pool_cannot_be_cut_by_the_kernel(one_chip, mosaic):
             spec((), jnp.int32)).compile()
 
 
-@pytest.mark.parametrize("rows", [128, 8192])
-def test_held_expert_layer_compiles_with_rows_of_a_pass(one_chip, mosaic, rows):
-    """A decode step's 128 rows and a prefill group's 8,192 through
-    ``ops/moe.py`` at GigaChat3.1's widths, 8 of 256 experts held: the
-    grouped matmuls take a pass's rows (``held_rows_cap``), not the
-    ``rows x 8`` assignments, so the temporaries stay a few hundred MB."""
+@pytest.mark.parametrize("rows", [128, 1024, 8192])
+def test_held_expert_layer_compiles_with_rows_of_a_pass(one_chip, mosaic, backend, rows):
+    """A decode step's 128 rows and prefill groups of 1,024 and 8,192
+    through ``ops/moe.py`` at GigaChat3.1's widths, 8 of 256 experts
+    held: the grouped matmuls take a pass's rows (``held_rows_cap``),
+    not the ``rows x 8`` assignments, so the temporaries stay a few
+    hundred MB; on a TPU the decode pass streams its experts through
+    the Pallas kernel inside the pass loop, the 1,024-row pass too (in
+    two segments of 512), and the largest (1,024 rows an expert) stays
+    on ``ragged_dot``."""
     from seldon_core_tpu.ops import moe
 
     d, f, e, held, k = 7168, 2048, 256, 8, 8
@@ -223,8 +262,10 @@ def test_held_expert_layer_compiles_with_rows_of_a_pass(one_chip, mosaic, rows):
         spec((e,), jnp.float32), spec((held, d, f), jnp.bfloat16),
         spec((held, d, f), jnp.bfloat16), spec((held, f, d), jnp.bfloat16)).compile()
     text = compiled.as_text()
-    assert text.count("ragged-dot") >= 3 and "tpu_custom_call" in text
     cap = moe.held_rows_cap(rows, k, held, e)   # four times an even 1/32 share
-    assert cap == rows * k // 8 and f"[{cap},{f}]" in text
+    assert_expert_kernels(text, backend == "tpu" and rows < 8192,
+                          min(cap, moe.stream_segment_rows(d)), d, f)
+    # a pass's rows at an expert's width (cut into segments, at d_model)
+    assert cap == rows * k // 8 and (f"[{cap},{f}]" in text or f"[{cap},{d}]" in text)
     assert f"[{rows * k},{d}]" not in text  # never all the assignments' rows
     assert compiled.memory_analysis().temp_size_in_bytes < 12 * rows * d * 4

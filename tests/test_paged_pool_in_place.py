@@ -467,9 +467,12 @@ class TestDecodeLane:
                 "tp", "dp", "chunk_impl", "kv_dtype", "kernel_active",
                 "pool_shard_bytes", "arch", "weight_bytes",
                 # PR 30: what the cache holds
-                "attention", "cache_width", "experts_held"}
+                "attention", "cache_width", "experts_held",
+                # PR 31: which grouped expert matmul each program traced
+                "expert_matmul"}
             assert (rep["attention"], rep["cache_width"], rep["experts_held"]) == (
                 "mha", cfg["d_model"], 0)
+            assert rep["expert_matmul"] == {}     # a dense model has none
         finally:
             eng.close()
         # a kernel asked for by name that cannot run says so, once

@@ -1,15 +1,32 @@
 #!/usr/bin/env python3
-"""Time the routed expert layer (ops/moe.py) alone, at OLMoE's widths.
+"""Time the grouped expert SwiGLU (ops/moe.py) alone, at a routed
+configuration's widths.
 
-On the chip: ``python tools/probe_moe.py`` prints one JSON line per row
-count (32 = a decode step of 32 lanes, up to 4096 = the largest prefill
-group of the chat cells) with the layer's milliseconds, the experts its
-random routing hit, the expert-weight bytes those need and the share of
-the HBM roofline that is (decode is bound by streaming the experts hit),
-and the FLOPs of the routed rows over the bf16 peak (prefill).  Off the
-chip ``--rehearse`` runs a toy size for control flow only and prints no
-rate.  ``--gmm`` also times JAX's Pallas megablox grouped matmul on the
-same sorted rows, the alternative ``ragged_dot`` was chosen over.
+On the chip: ``python tools/probe_moe.py --widths olmoe|gigachat``
+prints one JSON line per (assignment rows, implementation): the three
+matmuls' milliseconds a call (a ``scan`` of calls in one program, so no
+dispatch is in it), the groups hit, the expert-weight bytes those need
+and the share of the HBM roofline that is (a decode step is bound by
+streaming the experts hit), the rows' FLOPs over the bf16 peak (a
+prefill group), and how far its real rows lie from ``ragged_dot``'s
+(bf16 operands either way; the streaming kernel keeps gate and up in
+float32 where ``ragged_dot`` rounds them to bf16).  The implementations: ``ragged_dot`` (XLA's own Mosaic
+grouped matmul), ``megablox_gmm`` (JAX's Pallas grouped matmul at
+``tiling=(128, 512, 512)``; ``--gmm``) and ``stream`` (ops/moe.py
+``stream_swiglu``: its row tile, block shape and segment rows in the
+line; with ``--row-tile`` / ``--block-mb`` one ``stream_matmul`` a
+matmul at each given shape instead).  ``--rule`` adds what
+``grouped_swiglu`` itself runs at that shape.
+
+The rows arrive sorted by group with uneven sizes drawn from ``--seed``
+as the cells draw them: ``olmoe`` — ``rows / 8`` tokens each choosing 8
+of 64 experts, near even (a decode step of 32 lanes is 256 rows, ~63
+experts hit); ``gigachat`` — one pass of a replica's 8 held experts of
+256, ``rows`` = four times an even share of ``rows`` tokens' top-8, so
+a quarter of the rows are real, the rest lie past the groups, and the
+held experts are chosen as unevenly as the cell's (~6 of 8 hit by 128
+tokens).  Off the chip ``--rehearse`` runs a toy size through the
+Pallas interpreter for control flow only and prints no rate.
 """
 
 from __future__ import annotations
@@ -25,12 +42,48 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 HBM_BYTES_PER_S = 819e9   # TPU v5e, Google Cloud documentation
 BF16_FLOPS = 197e12
 
+# (groups held, experts routed over, top-k, d_model, expert width)
+WIDTHS = {"olmoe": (64, 64, 8, 2048, 1024), "gigachat": (8, 256, 8, 7168, 2048)}
+# how unevenly a replica's 8 held experts are chosen (PERF.md section 6,
+# PR 30: ~6 of 8 hit a decode step, max over mean 2.7-2.9)
+HELD_PROFILE = (2.7, 1.8, 1.3, 1.0, 0.7, 0.4, 0.08, 0.02)
+
+
+def draw_sizes(widths: str, rows: int, seed: int, groups: int, top_k: int):
+    """Group sizes of one call of ``rows`` sorted rows (see the module
+    docstring); their sum is ``rows`` for ``olmoe`` and about a quarter
+    of it for ``gigachat``."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    if widths == "olmoe":
+        tokens = max(1, rows // top_k)
+        logits = rng.normal(0.0, 0.15, groups) + rng.gumbel(size=(tokens, groups))
+        chosen = np.argsort(-logits, axis=-1)[:, :top_k]
+        sizes = np.bincount(chosen.ravel(), minlength=groups)
+        sizes[0] += rows - sizes.sum()      # rows no multiple of top-k
+        return sizes.astype(np.int32)
+    profile = rng.permutation(np.resize(HELD_PROFILE, groups))
+    p = np.minimum(1.0, profile * top_k / WIDTHS[widths][1])
+    sizes = rng.binomial(rows, p)
+    while sizes.sum() > rows:               # a pass holds ``rows`` at most
+        sizes[np.argmax(sizes)] -= 1
+    return sizes.astype(np.int32)
+
 
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--widths", choices=sorted(WIDTHS), default="olmoe")
     ap.add_argument("--gmm", action="store_true")
-    ap.add_argument("--rows", type=int, nargs="*", default=[32, 256, 1024, 4096])
+    ap.add_argument("--rule", action="store_true")
+    ap.add_argument("--no-stream", action="store_true")
+    ap.add_argument("--rows", type=int, nargs="*",
+                    default=[128, 256, 512, 1024, 2048, 4096, 8192])
+    ap.add_argument("--row-tile", type=int, nargs="*", default=None)
+    ap.add_argument("--block-mb", type=float, nargs="*", default=None)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--reps", type=int, default=16)
     args = ap.parse_args()
 
     import jax
@@ -44,73 +97,108 @@ def main() -> int:
     if not on_chip and not args.rehearse:
         print(json.dumps({"error": f"no TPU here ({dev.platform}); --rehearse for a toy run"}))
         return 1
-    d, f, e, k = (2048, 1024, 64, 8) if on_chip else (64, 32, 8, 2)
-    rows = args.rows if on_chip else [4, 32]
-    key = jax.random.key(0)
-    ks = jax.random.split(key, 5)
+    groups, _routed, top_k, d, f = WIDTHS[args.widths]
+    rows_list = args.rows
+    if not on_chip:
+        d, f, rows_list, args.reps = 128, 256, [32, 64], 2
+        moe.matmul_backend = lambda: "interpret"
+    # no shape given: what the rule's path runs (``stream_swiglu``: its
+    # own row tile, block and segments); else one kernel call a matmul
+    # at each given shape (the rows must fit one segment)
+    swept = bool(args.row_tile or args.block_mb)
+    row_tiles = args.row_tile or [None]
+    blocks = [int(mb * (1 << 20)) for mb in args.block_mb] if args.block_mb \
+        else [moe.STREAM_BLOCK_BYTES]
+    ks = jax.random.split(jax.random.key(args.seed % (1 << 31)), 4)
     dt = jnp.bfloat16
-    w_router = jax.random.normal(ks[0], (d, e), jnp.float32) * d ** -0.5
-    w_gate = (jax.random.normal(ks[1], (e, d, f), jnp.float32) * d ** -0.5).astype(dt)
-    w_up = (jax.random.normal(ks[2], (e, d, f), jnp.float32) * d ** -0.5).astype(dt)
-    w_down = (jax.random.normal(ks[3], (e, f, d), jnp.float32) * f ** -0.5).astype(dt)
+    w_gate = (jax.random.normal(ks[0], (groups, d, f), jnp.float32) * d ** -0.5).astype(dt)
+    w_up = (jax.random.normal(ks[1], (groups, d, f), jnp.float32) * d ** -0.5).astype(dt)
+    w_down = (jax.random.normal(ks[2], (groups, f, d), jnp.float32) * f ** -0.5).astype(dt)
+    weights = (w_gate, w_up, w_down)  # arguments: a closure would bake
+    # 0.8 GB of constants into each executable
 
-    weights = (w_router, w_gate, w_up, w_down)  # arguments: a closure would
-    # bake 0.8 GB of constants into each executable
+    def ragged(x, wg, wu, wd, sizes):
+        return moe.ragged_swiglu(x, wg, wu, wd, sizes, dt)
 
-    @jax.jit
-    def layer(h, w_router, w_gate, w_up, w_down):
-        gates, experts = moe.route(h, w_router, k)
-        out = moe.expert_ffn(h.astype(dt), w_gate, w_up, w_down, gates, experts)
-        return out, moe.expert_histogram(experts, e)
-
-    @jax.jit
-    def gmm_layer(h, w_router, w_gate, w_up, w_down):
+    def megablox(x, wg, wu, wd, sizes):
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-        gates, experts = moe.route(h, w_router, k)
-        flat = experts.reshape(-1)
-        order = jnp.argsort(flat, stable=True)
-        sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
-        xs = h.astype(dt)[order // k]
-        tile = (min(128, xs.shape[0]), 512, 512)
-        g = gmm(xs, w_gate, sizes, preferred_element_type=dt, tiling=tile)
-        u = gmm(xs, w_up, sizes, preferred_element_type=dt, tiling=tile)
+        tile = (min(128, x.shape[0]), 512, 512)
+        g = gmm(x, wg, sizes, preferred_element_type=dt, tiling=tile)
+        u = gmm(x, wu, sizes, preferred_element_type=dt, tiling=tile)
         a = (jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)).astype(dt)
-        o = gmm(a, w_down, sizes, preferred_element_type=dt, tiling=tile)
-        o = o[jnp.argsort(order)].reshape(h.shape[0], k, -1)
-        return jnp.einsum("tkd,tk->td", o.astype(jnp.float32), gates), sizes
+        return gmm(a, wd, sizes, preferred_element_type=jnp.float32, tiling=tile)
 
-    def timed(fn, h, reps):
-        out = fn(h, *weights)
-        jax.block_until_ready(out)
+    def stream(row_tile, block_bytes):
+        def fn(x, wg, wu, wd, sizes):
+            if not swept:
+                return moe.stream_swiglu(x, wg, wu, wd, sizes, interpret=not on_chip)
+            kw = dict(interpret=not on_chip, block_bytes=block_bytes,
+                      row_tile=row_tile or moe.stream_row_tile(x.shape[0], groups))
+            act = moe.stream_matmul(x, (wg, wu), sizes, **kw)
+            return moe.stream_matmul(act, (wd,), sizes, **kw)
+        return fn
+
+    def rule(x, wg, wu, wd, sizes):
+        return moe.grouped_swiglu(x, wg, wu, wd, sizes)
+
+    def timed(fn, x, sizes):
+        """Seconds a call, from a scan of ``reps`` calls that each read
+        the one before (nothing to hoist, no dispatch between)."""
+        @jax.jit
+        def many(x, wg, wu, wd, sizes):
+            def step(x, _):
+                out = fn(x, wg, wu, wd, sizes)
+                return (x + 1e-3 * out.astype(x.dtype)).astype(x.dtype), ()
+            return jax.lax.scan(step, x, None, length=args.reps)[0]
+
+        jax.block_until_ready(many(x, *weights, sizes))
         t0 = time.perf_counter()
-        for _ in range(reps):
-            out = fn(h, *weights)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / reps, out
+        jax.block_until_ready(many(x, *weights, sizes))
+        return (time.perf_counter() - t0) / args.reps
 
-    for n in rows:
-        h = jax.random.normal(jax.random.fold_in(ks[4], n), (n, d), jnp.float32)
-        seconds, (out, hist) = timed(layer, h, 50 if n <= 256 else 10)
-        hit = int((np.asarray(hist) > 0).sum())
-        line = {"rows": n, "assignments": n * k, "experts_hit": hit,
-                "device": dev.device_kind, "impl": "ragged_dot"}
-        if on_chip:
-            weight_bytes = hit * 3 * d * f * 2
-            flops = 2.0 * n * k * 3 * d * f
-            line.update(ms=1e3 * seconds,
-                        weight_stream_roofline_pct=100 * weight_bytes / HBM_BYTES_PER_S / seconds,
-                        routed_flops_pct_of_peak=100 * flops / BF16_FLOPS / seconds)
-        print(json.dumps(line), flush=True)
-        if args.gmm and on_chip:
+    for n in rows_list:
+        sizes_np = draw_sizes(args.widths, n, args.seed + n, groups, top_k)
+        sizes = jnp.asarray(sizes_np)
+        hit, real = int((sizes_np > 0).sum()), int(sizes_np.sum())
+        x = jax.random.normal(jax.random.fold_in(ks[3], n), (n, d), jnp.float32).astype(dt)
+        impls = [("ragged_dot", ragged, {})]
+        if args.gmm:
+            impls.append(("megablox_gmm", megablox, {"tiling": [min(128, n), 512, 512]}))
+        if not args.no_stream:
+            for row_tile in row_tiles:
+                for block in blocks:
+                    impls.append(("stream", stream(row_tile, block), {
+                        "row_tile": row_tile or moe.stream_row_tile(n, groups),
+                        "segment_rows": n if swept else moe.stream_segment_rows(max(d, f)),
+                        "block_gate_up": [d, moe.stream_block(d, f, block_bytes=block)],
+                        "block_down": [f, moe.stream_block(f, d, block_bytes=block)]}))
+        if args.rule:
+            impls.append(("grouped_swiglu", rule, {
+                "runs": moe.expert_matmul_impl(n, groups, d, f, dt, moe.matmul_backend())}))
+        base = want = None
+        for name, fn, extra in impls:
+            line = {"widths": args.widths, "rows": n, "real_rows": real, "groups": groups,
+                    "groups_hit": hit, "device": dev.device_kind, "impl": name, **extra}
             try:
-                seconds, _ = timed(gmm_layer, h, 50 if n <= 256 else 10)
-                print(json.dumps({"rows": n, "impl": "megablox_gmm", "ms": 1e3 * seconds}),
-                      flush=True)
-            except Exception as exc:  # noqa: BLE001 — the alternative may not lower
-                print(json.dumps({"rows": n, "impl": "megablox_gmm",
-                                  "error": f"{type(exc).__name__}: {str(exc)[:300]}"}),
-                      flush=True)
+                seconds = timed(fn, x, sizes)
+            except Exception as exc:  # noqa: BLE001 — an alternative may not lower
+                line["error"] = f"{type(exc).__name__}: {str(exc)[:300]}"
+                print(json.dumps(line), flush=True)
+                continue
+            out = np.asarray(jax.jit(fn)(x, *weights, sizes))[:real]
+            want = out if name == "ragged_dot" else want
+            line["max_abs_diff_from_ragged_dot"] = float(np.abs(out - want).max(initial=0.0))
+            if on_chip:
+                base = seconds if name == "ragged_dot" else base
+                line.update(
+                    ms=1e3 * seconds,
+                    weight_stream_roofline_pct=100 * hit * 3 * d * f * 2
+                    / HBM_BYTES_PER_S / seconds,
+                    routed_flops_pct_of_peak=100 * 2.0 * real * 3 * d * f
+                    / BF16_FLOPS / seconds,
+                    over_ragged_dot=base / seconds if base else None)
+            print(json.dumps(line), flush=True)
     return 0
 
 
