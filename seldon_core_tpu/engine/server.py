@@ -31,6 +31,7 @@ from aiohttp import web
 from seldon_core_tpu.engine.service import PredictorService, failure_message
 from seldon_core_tpu.proto import pb, services
 from seldon_core_tpu.runtime.component import MicroserviceError
+from seldon_core_tpu.runtime.executor_pool import dispatch_pool
 from seldon_core_tpu.runtime.message import InternalFeedback, InternalMessage
 from seldon_core_tpu.runtime.rest import _error_response, _request_body
 
@@ -406,8 +407,15 @@ def build_gateway_app(gateway: Gateway, auth=None) -> web.Application:
                 status=501,
             )
         # t_ingress: the handler's entry stamp.  The generator's first
-        # next() waits for a thread of the default executor, and every
-        # stream waiting in token_queue.get() holds one: the engine
+        # next() submits and then waits for a slot and a prefill —
+        # seconds, under a standing queue — so it runs on the shared
+        # dispatch pool (runtime/executor_pool.py: 128 threads that
+        # spend their life blocked); every later next() waits one wave
+        # at most and takes the loop's default executor, min(32,
+        # cpu_count + 4) threads.  On that executor alone, more callers
+        # waiting for a slot than it has threads took every one of them
+        # and the decoding streams went silent (ROADMAP S9: 160 callers
+        # on 128 slots, 10-19 s across a window's opening).  The engine
         # counts entry -> submit as ingress_wait_s, the queue it cannot
         # see (same process, so a monotonic stamp is a valid carrier)
         meta = {"tags": dict(msg.meta.tags), "puid": msg.meta.puid,
@@ -440,7 +448,7 @@ def build_gateway_app(gateway: Gateway, auth=None) -> web.Application:
         try:
             arr = msg.array()
             it = gen_fn(arr, [], meta=meta)
-            first = await loop.run_in_executor(None, next, it, sentinel)
+            first = await loop.run_in_executor(dispatch_pool(), next, it, sentinel)
         except Exception as e:  # noqa: BLE001
             return _error_response(e)
         resp = web.StreamResponse(headers={
@@ -947,8 +955,8 @@ def add_seldon_service(server: grpc.aio.Server, gateway: Gateway, auth=None) -> 
             )
         import time as _mono_time
 
-        # t_ingress: as in the SSE twin, this lane's first next() waits
-        # for a thread of the same default executor
+        # t_ingress: as in the SSE twin; this lane's first next() (the
+        # wait for a slot) runs on the dispatch pool too
         meta = {"tags": dict(msg.meta.tags), "puid": msg.meta.puid,
                 "t_ingress": _mono_time.monotonic()}
         # SLO parity with the SSE twin: the streaming generator runs on
@@ -976,10 +984,12 @@ def add_seldon_service(server: grpc.aio.Server, gateway: Gateway, auth=None) -> 
         loop = asyncio.get_running_loop()
         it = gen_fn(msg.array(), [], meta=meta)
         sentinel = object()
+        pool = dispatch_pool()  # the first pull only: the wait for a slot
         try:
             while True:
                 try:
-                    chunk = await loop.run_in_executor(None, next, it, sentinel)
+                    chunk = await loop.run_in_executor(pool, next, it, sentinel)
+                    pool = None
                 except MicroserviceError as e:
                     await context.abort(
                         grpc.StatusCode.INVALID_ARGUMENT

@@ -147,10 +147,13 @@ def prefill_position_bytes(spec, d_model: int, vocab_size: int,
       layer's rows for the assignments a token brings to the experts
       held here (at :func:`moe.held_rows_cap`'s headroom), each its bf16
       input, gate, up, product and float32 output, beside the shared
-      expert's."""
+      expert's; a double layer's dense and routed rows together."""
     from seldon_core_tpu.ops import moe
 
     kept = 4 * vocab_size + 6 * d_model
+    if spec.double_layer:
+        # the shortcut's float32 input and output wait out a half-layer
+        kept += 8 * d_model
     if spec.latent:
         qk = spec.nope_dim + spec.rope_dim
         attn = 2 * num_heads * (2 * qk + spec.v_dim) + 4 * num_heads * spec.v_dim
@@ -160,11 +163,17 @@ def prefill_position_bytes(spec, d_model: int, vocab_size: int,
         ffn = 6 * 4 * d_model  # the GELU MLP's hidden rows
     else:
         swiglu = 10  # bytes a hidden value: gate, up, their product
-        ffn = swiglu * spec.dense_width if spec.dense_layers else 0
+        dense = spec.dense_layers or spec.double_layer
+        ffn = swiglu * spec.dense_width if dense else 0
         rows = spec.experts_per_tok * min(
-            1.0, moe.HELD_ROWS_HEADROOM * spec.held / spec.num_experts)
-        ffn = max(ffn, int(rows * (6 * d_model + swiglu * spec.expert_width))
+            1.0, moe.HELD_ROWS_HEADROOM * spec.held / spec.router_outputs)
+        routed = (int(rows * (6 * d_model + swiglu * spec.expert_width))
                   + swiglu * spec.shared_experts * spec.expert_width)
+        # a double layer's routed shortcut runs beside its dense
+        # half-layer, not in another layer's place (the chip compiler:
+        # 363 KB a position at LongCat-Flash's widths, 332 KB by this
+        # count; b1024_k4 1.73 GiB, b512_k4 0.89)
+        ffn = ffn + routed if spec.double_layer else max(ffn, routed)
     return kept + max(attn, ffn)
 
 
@@ -305,6 +314,37 @@ def _build_modules():
             None if token_mask is None else token_mask.reshape(-1))
         return x + out.reshape(x.shape).astype(x.dtype), (hist,)
 
+    def _swiglu_ffn(mod, rows, names, width):
+        """A dense SwiGLU FFN (or a shared expert) of ``width`` over
+        ``rows`` ``(T, d)``, its gate, up and down matrices declared
+        under ``names``: float32 ``(T, d)``."""
+        from seldon_core_tpu.ops import moe
+
+        d_model = rows.shape[-1]
+        rest = _rest(mod.spec, mod.dtype)
+        init = nn.initializers.normal(0.02)
+        gate, up, down = names
+        return moe.swiglu(
+            rows.astype(mod.dtype),
+            mod.param(gate, init, (d_model, width), rest),
+            mod.param(up, init, (d_model, width), rest),
+            mod.param(down, init, (width, d_model), rest))
+
+    def _held_experts(mod, d_model, outputs):
+        """The parameters of a layer that holds a share of its routed
+        experts: the float32 router over ``outputs`` and its correction
+        bias, and the ``spec.held`` experts' gate, up and down
+        matrices."""
+        spec = mod.spec
+        held, f = spec.held, spec.expert_width
+        rest = _rest(spec, mod.dtype)
+        init = nn.initializers.normal(0.02)
+        return (mod.param("router", init, (d_model, outputs), jnp.float32),
+                mod.param("score_bias", init, (outputs,), jnp.float32),
+                mod.param("experts_gate", init, (held, d_model, f), rest),
+                mod.param("experts_up", init, (held, d_model, f), rest),
+                mod.param("experts_down", init, (held, f, d_model), rest))
+
     def _ffn_grouped(mod, x, token_mask):
         """:func:`_ffn` for a spec whose router is DeepSeek-V3's: a
         dense SwiGLU layer (``mod.routed_layer`` false; its histogram
@@ -317,27 +357,17 @@ def _build_modules():
         spec = mod.spec
         d_model = x.shape[-1]
         rows = _norm(spec, "ffn_norm")(x).reshape(-1, d_model)
-        rest = _rest(spec, mod.dtype)
-        init = nn.initializers.normal(0.02)
 
         def swiglu(name, width):
-            return moe.swiglu(
-                rows.astype(mod.dtype),
-                mod.param(f"{name}_gate", init, (d_model, width), rest),
-                mod.param(f"{name}_up", init, (d_model, width), rest),
-                mod.param(f"{name}_down", init, (width, d_model), rest))
+            return _swiglu_ffn(
+                mod, rows, (f"{name}_gate", f"{name}_up", f"{name}_down"), width)
 
         e = spec.num_experts
         if not mod.routed_layer:
             out = swiglu("mlp", spec.dense_width)
             hist = jnp.zeros((e,), jnp.int32)
         else:
-            held, f = spec.held, spec.expert_width
-            w_router = mod.param("router", init, (d_model, e), jnp.float32)
-            bias = mod.param("score_bias", init, (e,), jnp.float32)
-            w_gate = mod.param("experts_gate", init, (held, d_model, f), rest)
-            w_up = mod.param("experts_up", init, (held, d_model, f), rest)
-            w_down = mod.param("experts_down", init, (held, f, d_model), rest)
+            w_router, bias, w_gate, w_up, w_down = _held_experts(mod, d_model, e)
             gates, experts = moe.route_grouped(
                 rows, w_router, bias, spec.experts_per_tok, spec.n_group,
                 spec.topk_group, spec.norm_topk, spec.routed_scale)
@@ -345,7 +375,8 @@ def _build_modules():
                 rows.astype(mod.dtype), w_gate, w_up, w_down, gates, experts,
                 spec.expert_offset, e)
             if spec.shared_experts:
-                out = out + swiglu("shared", spec.shared_experts * f)
+                out = out + swiglu(
+                    "shared", spec.shared_experts * spec.expert_width)
             hist = moe.expert_histogram(
                 experts, e,
                 None if token_mask is None else token_mask.reshape(-1))
@@ -354,11 +385,84 @@ def _build_modules():
     def _latent_block(mod, x, pool, tables, lengths, layer, positions,
                       token_mask):
         """A block of latent attention (MLA): ``(x, row, None, hist)``
-        with ``row`` ``(B, L, W)`` this call's cache rows
+        with ``row`` ``(B, L, W)`` this call's cache rows for the caller
+        to write — one pool, no V — or, for a spec whose layer is
+        double, :func:`_double_layer`'s two rows."""
+        if mod.spec.double_layer:
+            return _double_layer(mod, x, pool, tables, lengths, layer,
+                                 positions, token_mask)
+        x, row = _latent_attention(mod, x, pool, tables, lengths, layer,
+                                   positions)
+        x, hist = _ffn_grouped(mod, x, token_mask)
+        return (x, row, None, *hist)
+
+    def _double_layer(mod, x, pool, tables, lengths, layer, positions,
+                      token_mask):
+        """A LongCat-Flash layer: ``(x, (row_0, row_1), None, hist)``.
+        Two halves, each a latent attention with its own cache row
+        (attention ``2 * layer + i`` of the pool) and a dense SwiGLU FFN
+        of ``spec.dense_width``; the routed experts read the FIRST
+        half's post-attention norm and are added after the SECOND half
+        (the shortcut: in a deployment their exchange overlaps the dense
+        half-layer; here nothing orders the two branches but their
+        data, and XLA schedules them as it likes)."""
+        spec = mod.spec
+        d_model = x.shape[-1]
+        rows = []
+        for i in range(2):
+            x, row = _latent_attention(mod, x, pool, tables, lengths, layer,
+                                       positions, sub=i)
+            rows.append(row)
+            g = _norm(spec, f"ffn_norm_{i}")(x).reshape(-1, d_model)
+            if i == 0:
+                shortcut, hist = _shortcut_experts(mod, g, token_mask)
+            dense = _swiglu_ffn(
+                mod, g, (f"mlp_gate_{i}", f"mlp_up_{i}", f"mlp_down_{i}"),
+                spec.dense_width)
+            x = x + dense.reshape(x.shape).astype(x.dtype)
+        x = x + shortcut.reshape(x.shape).astype(x.dtype)
+        return (x, tuple(rows), None, hist)
+
+    def _shortcut_experts(mod, rows, token_mask):
+        """LongCat-Flash's routed experts over ``rows`` ``(T, d)``
+        float32: ``(m (T, d) float32, hist)``.  The router scores
+        ``spec.num_experts`` real and ``spec.zero_experts`` identity
+        experts; this replica computes its ``spec.held`` real experts'
+        part for the tokens routed to them (ops/moe.py
+        ``expert_ffn_held``; an absent real expert adds nothing) and
+        the identity experts' part for every token.  ``hist`` is
+        ``int32[spec.hist_width]``: assignments per router output, then
+        tokens by their number of real picks."""
+        from seldon_core_tpu.ops import moe
+
+        spec = mod.spec
+        e, outputs = spec.num_experts, spec.router_outputs
+        w_router, bias, w_gate, w_up, w_down = _held_experts(
+            mod, rows.shape[-1], outputs)
+        gates, experts = moe.route_zero(
+            rows, w_router, bias, spec.experts_per_tok, spec.routed_scale)
+        out = moe.expert_ffn_held(
+            rows.astype(mod.dtype), w_gate, w_up, w_down, gates, experts,
+            spec.expert_offset, outputs)
+        out = out + moe.identity_experts(rows, gates, experts, e)
+        mask = None if token_mask is None else token_mask.reshape(-1)
+        hist = jnp.concatenate([
+            moe.expert_histogram(experts, outputs, mask),
+            moe.real_pick_histogram(experts, e, mask)])
+        return out, hist
+
+    def _latent_attention(mod, x, pool, tables, lengths, layer, positions,
+                          sub=None):
+        """``x + attention(norm(x))`` by latent attention (MLA): ``(x,
+        row)`` with ``row`` ``(B, L, W)`` this call's cache rows
         ``[RMSNorm(c_kv) ; RoPE(k_r) ; 0]`` (``W`` = ``spec.cache_width``:
         the values in whole lane tiles) for the caller to write — one
         pool, no V.  ``pool`` is the whole ``(L, pages, ps, W)``
         pool with ``layer`` an int (the kernel lane) or one layer of it.
+        ``sub`` (a double layer's half, 0 or 1) names the half's
+        parameters ``<name>_<sub>`` and picks its cache row: attention
+        ``2 * layer + sub`` of the whole pool, or row ``sub`` of the
+        layer's two.
 
         Two attention paths in one model.  A segment (a prefill, a
         cached suffix) is **naive**: K and V are made per head from the
@@ -379,20 +483,33 @@ def _build_modules():
         nope, rdim, vdim = spec.nope_dim, spec.rope_dim, spec.v_dim
         batch, seg_len, d_model = x.shape
         whole = layer is not None
+        tag = "" if sub is None else f"_{sub}"
+        if sub is not None:
+            if whole:
+                layer = 2 * layer + sub
+            else:
+                pool = pool[sub]
 
         def proj(name, features, inp):
-            return _dense(mod.precision, features, mod.dtype, name, spec)(inp)
+            return _dense(mod.precision, features, mod.dtype, name + tag,
+                          spec)(inp)
 
         def rms(name):
             return nn.RMSNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
-                              name=name)
+                              name=name + tag)
 
-        y = _norm(spec, "attn_norm")(x)
+        y = _norm(spec, "attn_norm" + tag)(x)
         c_q = rms("q_a_norm")(proj("q_a", spec.q_rank, y))
         q = proj("q_b", heads * (nope + rdim), c_q.astype(mod.dtype)).reshape(
             batch, seg_len, heads, nope + rdim)
         kva = proj("kv_a", rank + rdim, y)
         c_kv = rms("kv_a_norm")(kva[..., :rank])
+        if spec.mla_lora_scale:
+            # constants on q (exact in bfloat16 at the published ranks:
+            # 2) and on the normed latent as it is cached (float32
+            # here, rounded once into the pool's type)
+            s_q, s_kv = spec.lora_scales(d_model)
+            q, c_kv = q * jnp.asarray(s_q, q.dtype), c_kv * s_kv
         inv = yarn_inv_freq(spec)
         q_nope = q[..., :nope]
         q_rope = rope_interleaved(q[..., nope:], positions, inv).astype(mod.dtype)
@@ -411,8 +528,8 @@ def _build_modules():
         # W_kvb rests split: (heads, rank, nope) makes k_nope from c_kv
         # (or folds into q), (heads, rank, v) makes v (or unfolds the
         # attended latent)
-        w_uk = mod.param("kv_b_k", init, (heads, rank, nope), rest)
-        w_uv = mod.param("kv_b_v", init, (heads, rank, vdim), rest)
+        w_uk = mod.param("kv_b_k" + tag, init, (heads, rank, nope), rest)
+        w_uv = mod.param("kv_b_v" + tag, init, (heads, rank, vdim), rest)
         scale = spec.softmax_scale
 
         def cached(tb):
@@ -462,9 +579,7 @@ def _build_modules():
             outs.append(jnp.swapaxes(out, 0, 1).astype(mod.dtype)[:, None])
         attn = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
         attn = attn.reshape(batch, seg_len, heads * vdim)
-        x = x + proj("attn_proj", d_model, attn)
-        x, hist = _ffn_grouped(mod, x, token_mask)
-        return (x, row, None, *hist)
+        return x + proj("attn_proj", d_model, attn), row
 
     def _embed(lm, tokens, positions):
         tokens = tokens.astype(jnp.int32)
@@ -1003,7 +1118,10 @@ def _build_modules():
                             if kv_scales is not None else None
                         ),
                     )
-                    pools = (pages_k[i],
+                    # (a double layer's two attentions: its two rows)
+                    subs = self.spec.attn_sublayers
+                    pools = (pages_k[i] if subs == 1
+                             else pages_k[subs * i:subs * (i + 1)],
                              None if pages_v is None else pages_v[i])
                 kinds = ({"routed_layer": False}
                          if self.spec.routed and not self.spec.layer_routed(i)
@@ -1015,7 +1133,8 @@ def _build_modules():
                 )(x, *pools, block_tables, lengths,
                   adapter_idx=adapter_idx, **per_layer,
                   positions=positions, token_mask=token_mask)
-                new_k.append(k)
+                # one cache row an attention: a double layer brings two
+                new_k += k if isinstance(k, tuple) else [k]
                 new_v.append(v)
                 hists += hist
             return _head(self, x, new_k, new_v, hists)
@@ -1338,6 +1457,7 @@ def paged_hbm_accounting(
     kv_dtype: str = "bf16",
     host_tier_gib: float = 0.0,
     weight_bytes: int = 0,
+    cache_pools: int = 2,
 ) -> Dict[str, int]:
     """Pool-HBM bytes for ``streams`` concurrent streams at ``ctx_len``
     tokens — the capacity model the bench certifies (VERDICT r5 #3/#5).
@@ -1446,6 +1566,12 @@ def paged_hbm_accounting(
       every program holds a bf16 cast of it beside that while it runs
       (PERF.md §4): price that lane's transient on top yourself.
 
+    * **a latent pool** — ``cache_pools=1`` with ``d_model`` the row's
+      lanes (``spec.cache_width``: 640 for 576 values) and
+      ``num_layers`` the pool's leading axis, attention sub-layers
+      (``spec.cache_layers``: two a LongCat-Flash layer); the default 2
+      is K and V of ``d_model`` a layer.
+
     Activations and the host runtime stay out of scope.
     """
     shard = max(1, int(tp_degree))
@@ -1462,7 +1588,7 @@ def paged_hbm_accounting(
     pages = -(-ctx_len // page_size)
     kv_int8 = kv_dtype == "int8"
     pool_elt_bytes = 1 if kv_int8 else dtype_bytes
-    tok_bytes = num_layers * d_model * 2 * pool_elt_bytes
+    tok_bytes = num_layers * d_model * cache_pools * pool_elt_bytes
     # sibling scale table: one f32 per page per k/v per layer
     page_scale_bytes = num_layers * 2 * 4 if kv_int8 else 0
     page_bytes = page_size * tok_bytes + page_scale_bytes
@@ -1472,7 +1598,7 @@ def paged_hbm_accounting(
         # the ring impl's gathered working set holds the COMPUTE dtype
         ws = int(
             streams * (pages * page_size + steps_per_call)
-            * num_layers * d_model * 2 * dtype_bytes * split_tile_pad
+            * num_layers * d_model * cache_pools * dtype_bytes * split_tile_pad
         ) // kv_shard
     at_rest = pool if donated else 2 * pool
     inflight_pages = -(-int(inflight_prefill_tokens) // page_size)
@@ -2357,8 +2483,10 @@ class PagedEngine:
 
         self.params, self.pages_k, self.pages_v = shard_decode_state(
             params, mesh,
-            pool_shape=(num_layers, self.num_pages, self.page_size,
-                        self.cache_width),
+            # the leading axis counts attention sub-layers (a double
+            # layer has two), not layers
+            pool_shape=(spec.cache_layers(num_layers), self.num_pages,
+                        self.page_size, self.cache_width),
             dtype=pool_dtype,
             model_axis=model_axis, data_axis=data_axis,
             min_weight_size=shard_min_weight_size,
@@ -2404,7 +2532,7 @@ class PagedEngine:
 
             self._moe_held_pass_rows = _moe.held_rows_cap(
                 self.max_slots, spec.experts_per_tok, spec.held,
-                spec.num_experts)
+                spec.router_outputs)
         else:
             self._moe_held_pass_rows = 0
         # what one prefill call may pay for (module top): from what this
@@ -2668,6 +2796,17 @@ class PagedEngine:
                           # layer, step)s — 0 where every expert is held
                           "moe_local_assignments": 0,
                           "moe_held_active_expert_steps": 0,
+                          # a router that also scores identity experts
+                          # (spec.zero_experts; 0 otherwise): picks
+                          # that fell on them; the (token, layer)s
+                          # routed; and of those the ones that chose at
+                          # most a third / all or all but one of their
+                          # picks among the REAL experts — a token's
+                          # expert work varies
+                          "moe_zero_assignments": 0,
+                          "moe_routed_tokens": 0,
+                          "moe_few_real_tokens": 0,
+                          "moe_many_real_tokens": 0,
                           # cached latent rows read by decode
                           # lane-steps, summed over the layers (a latent
                           # pool: decode_kv_tokens x layers; 0 otherwise)
@@ -2834,7 +2973,7 @@ class PagedEngine:
         # and the prefill programs' histograms still on the device —
         # read back with the next chunk's tokens, never on their own
         self._moe_hits = np.zeros(
-            (num_layers, spec.num_experts), np.int64)
+            (num_layers, spec.hist_width), np.int64)
         self._moe_pending: List[Any] = []
         # waves launched and not harvested yet, oldest first: one while
         # step() runs, two for the moment a serving loop has launched
@@ -3698,7 +3837,7 @@ class PagedEngine:
         # HELD experts hit, summed over the steps
         return (self._jnp.zeros(
             (self.module.num_layers,
-             self.spec.num_experts + 2 + bool(self.spec.experts_held)),
+             self.spec.hist_width + 2 + bool(self.spec.experts_held)),
             self._jnp.int32),)
 
     def _moe_step(self, moe, hist, active):
@@ -3709,9 +3848,12 @@ class PagedEngine:
         (hist,) = hist
         ran = jnp.broadcast_to(
             jnp.any(active).astype(jnp.int32), (hist.shape[0], 1))
-        hit = (hist > 0).sum(axis=1, keepdims=True).astype(jnp.int32)
-        cols = [hist, hit, ran]
         spec = self.spec
+        # experts hit: of the real ones (a histogram that also counts
+        # identity experts and tokens by their real picks is wider)
+        real = hist[:, :spec.num_experts] if spec.zero_experts else hist
+        hit = (real > 0).sum(axis=1, keepdims=True).astype(jnp.int32)
+        cols = [hist, hit, ran]
         if spec.dense_layers:  # a dense layer routes nothing: no step of its
             routed = (jnp.arange(hist.shape[0]) >= spec.dense_layers)
             cols[2] = ran * routed[:, None].astype(jnp.int32)
@@ -6559,7 +6701,14 @@ class PagedEngine:
             # experts how many rest here
             "attention": self.spec.attention,
             "cache_width": self.cache_width,
+            # the pool's leading axis: attention sub-layers (a double
+            # layer has two), not layers
+            "cache_layers": int(self.pages_k.shape[0]),
             "experts_held": self.spec.held if self.spec.routed else 0,
+            # the most padded positions one prefill call takes (derived
+            # from the HBM left beside weights and pool; None = no cap):
+            # a larger admission group is served as several calls
+            "prefill_positions_max": self.prefill_positions_max,
             # which grouped expert matmul each program traced
             # (ops/moe.py grouped_swiglu): {} for a dense model
             "expert_matmul": self._expert_matmul_report(),
@@ -6584,8 +6733,8 @@ class PagedEngine:
 
         def impl(tokens: int) -> str:
             return moe.layer_expert_matmul(
-                tokens, spec.experts_per_tok, held, spec.num_experts,
-                d_model, width, gate.dtype, held_pass=spec.score == "sigmoid")
+                tokens, spec.experts_per_tok, held, spec.router_outputs,
+                d_model, width, gate.dtype, held_pass=spec.score != "softmax")
 
         report = {"chunk": impl(self.max_slots)}
         if self.speculative is not None:
@@ -6641,14 +6790,24 @@ class PagedEngine:
         if moe_np is None:
             return {}
         chunk, prefills = moe_np
-        e = self.spec.num_experts
+        spec = self.spec
+        e = spec.hist_width  # the histogram's columns; the step counters follow
         if chunk is None:
             chunk = np.zeros((self._moe_hits.shape[0], e + 3), np.int64)
         hits = chunk[:, :e].astype(np.int64)
         for h in prefills:
             hits = hits + h
         self._moe_hits += hits
-        self._counters["moe_assignments"] += int(hits.sum())
+        outputs = spec.router_outputs
+        self._counters["moe_assignments"] += int(hits[:, :outputs].sum())
+        if spec.zero_experts:
+            by_real = hits[:, outputs:].sum(axis=0)   # tokens by real picks
+            k = spec.experts_per_tok
+            self._counters["moe_zero_assignments"] += int(
+                hits[:, spec.num_experts:outputs].sum())
+            self._counters["moe_routed_tokens"] += int(by_real.sum())
+            self._counters["moe_few_real_tokens"] += int(by_real[:k // 3 + 1].sum())
+            self._counters["moe_many_real_tokens"] += int(by_real[k - 1:].sum())
         active, steps = int(chunk[:, e].sum()), int(chunk[:, e + 1].sum())
         self._counters["moe_active_expert_steps"] += active
         self._counters["moe_layer_steps"] += steps
@@ -6771,9 +6930,17 @@ class PagedEngine:
                 # this replica holds a share
                 "moe_held_pass_rows": self._moe_held_pass_rows,
             }
-            moe_expert_hits = (  # cumulative assignments per expert
-                self._moe_hits.sum(axis=0).tolist()
+            outputs = self.spec.router_outputs
+            moe_expert_hits = (  # cumulative assignments per router output
+                self._moe_hits[:, :outputs].sum(axis=0).tolist()
                 if detail and self.spec.routed else None)
+            # per layer the histogram over every router output, identity
+            # experts last; and (token, layer)s by how many REAL experts
+            # they chose (0 .. experts_per_tok)
+            moe_zero_detail = (
+                (self._moe_hits[:, :outputs].tolist(),
+                 self._moe_hits[:, outputs:].sum(axis=0).tolist())
+                if detail and self.spec.zero_experts else None)
         if self._capture_enabled:
             try:
                 from seldon_core_tpu.utils import capture as _capture_mod
@@ -6810,6 +6977,9 @@ class PagedEngine:
             out["phase_s"] = dict(self._seam.phase_s)
             if moe_expert_hits is not None:
                 out["moe_expert_hits"] = moe_expert_hits
+            if moe_zero_detail is not None:
+                (out["moe_layer_expert_hits"],
+                 out["moe_real_picks_hist"]) = moe_zero_detail
             if self._watchdog is not None:
                 out["watchdog"] = self._watchdog.stats()
             if self.recorder is not None:
@@ -7546,9 +7716,9 @@ class PagedEngine:
                 self._counters["decode_lane_steps"] += n
                 read = n * len0 + n * (n - 1) // 2
                 self._counters["decode_kv_tokens"] += read
-                if self.spec.latent:  # a row a layer
+                if self.spec.latent:  # a row an attention sub-layer
                     self._counters["latent_kv_tokens"] += (
-                        read * self.module.num_layers)
+                        read * self.pages_k.shape[0])
                 self._counters["decode_live_pages"] += sum(
                     self._pages_of(len0 + t * grow) for t in range(n))
             # every launched step walks every lane's table, live or not

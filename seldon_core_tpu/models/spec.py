@@ -2,11 +2,16 @@
 block, its decode-chunk twin, the LM around them, the KV pool and the
 parameter initialiser all read.
 
-The paged engine's contract with a model: per layer **one cache row a
-token**, ``cache_width`` values wide, in ``cache_pools`` pools of one
-shape ``(layers, pages, page_size, cache_width)``, written after
-whatever the model does to what it caches (positions, norms), and
-next-token logits.  Multi-head attention caches K and V, each ``num_heads
+The paged engine's contract with a model: per **attention sub-layer**
+one cache row a token, ``cache_width`` values wide, in ``cache_pools``
+pools of one shape ``(attention sub-layers, pages, page_size,
+cache_width)``, written after whatever the model does to what it caches
+(positions, norms, a constant scale), and next-token logits.  The
+pool's leading axis counts attentions, not layers
+(:meth:`ModelSpec.cache_layers`): one a layer for every block but
+LongCat-Flash's double layer, which has two, so attention ``2 * layer +
+i`` owns pool row ``2 * layer + i`` while the routing counters stay one
+row a *layer*.  Multi-head attention caches K and V, each ``num_heads
 x head_dim = d_model`` wide, in two pools; latent attention (MLA) caches
 one row ``[c_kv ; RoPE(k_r)]`` of ``kv_rank + rope_dim`` values (in
 whole 128-lane tiles: 576 values rest in 640 lanes) in one pool and no
@@ -16,17 +21,27 @@ embedding before the cache write, plain or YaRN-scaled), which norm
 (LayerNorm | RMSNorm), a norm on q and k, the attention's projections
 (fused qkv | low-rank q and a shared latent for k and v, absorbed into
 q and the output in a decode step), which layers are dense and which
-routed, how the router scores and picks (softmax top-k | sigmoid with a
-selection-only bias and group-limited top-k, renormalised and scaled),
-a shared expert beside the routed ones, and **which routed experts this
+routed (or, in a double layer, that every layer holds two dense FFNs
+*and* routed experts on a shortcut round the second half), how the
+router scores and picks (softmax top-k | sigmoid with a selection-only
+bias and group-limited top-k, renormalised and scaled | softmax over
+real and identity experts with a selection-only bias, scaled and not
+renormalised), a shared expert beside the routed ones, identity
+("zero-computation") experts after them, and **which routed experts this
 replica holds** (``experts_held`` from ``expert_offset``, of
 ``num_experts`` the router scores: one chip's share of an
 expert-parallel layer computes its own experts' part and nothing
 stands in for the rest).
 
-``GPT2``, ``OLMOE`` and ``DEEPSEEK_V3`` are the values served; a new
-architecture is a new value (and new branches where the block reads a
-field it has not met), not a new block.
+``GPT2``, ``OLMOE``, ``DEEPSEEK_V3`` and ``LONGCAT_FLASH`` are the values
+served; a new architecture is a new value (and new branches where the
+block reads a field it has not met), not a new block.  The fourth value
+added ``double_layer`` (two latent attentions and two dense SwiGLU FFNs
+a layer, the routed experts computed from the first half's normed
+stream and added after the second), ``zero_experts`` (identity experts
+the router scores after the ``num_experts`` real ones),
+``mla_lora_scale`` (constant scales on q and on the cached latent) and
+``score="softmax_bias"``.
 
 For multi-head attention ``head_dim`` is not a field: the pool's
 element is ``d_model`` wide and heads split it evenly.  Latent
@@ -36,6 +51,7 @@ ranks are fields: none follows from ``d_model``.
 
 from __future__ import annotations
 
+import re
 from dataclasses import dataclass, replace
 from functools import partial
 from typing import Any, Dict
@@ -101,6 +117,19 @@ class ModelSpec:
     # replica holds experts_held (0 = all) starting at expert_offset
     experts_held: int = 0
     expert_offset: int = 0
+    # ---- LongCat-Flash.  double_layer: a layer is two latent
+    # attentions (each its own cache row) and two dense SwiGLU FFNs of
+    # dense_width, with the routed experts on a shortcut: computed from
+    # the first half's post-attention norm, added after the second half.
+    # zero_experts: router outputs num_experts .. + zero_experts are
+    # identity experts (gate * h: no matrices, nothing streamed).
+    # score "softmax_bias": softmax over num_experts + zero_experts, a
+    # selection-only bias, the chosen probabilities times routed_scale
+    # as they are.  mla_lora_scale: q times (d_model / q_rank) ** 0.5
+    # and the normed latent times (d_model / kv_rank) ** 0.5
+    double_layer: bool = False
+    zero_experts: int = 0
+    mla_lora_scale: bool = False
 
     @property
     def routed(self) -> bool:
@@ -135,6 +164,36 @@ class ModelSpec:
 
     def layer_routed(self, layer: int) -> bool:
         return self.routed and layer >= self.dense_layers
+
+    @property
+    def attn_sublayers(self) -> int:
+        """Attentions, and so cache rows a token, in one layer."""
+        return 2 if self.double_layer else 1
+
+    def cache_layers(self, num_layers: int) -> int:
+        """The pool's leading axis: attention sub-layers, not layers."""
+        return num_layers * self.attn_sublayers
+
+    @property
+    def router_outputs(self) -> int:
+        """Outputs the router scores: real experts, then identity ones."""
+        return self.num_experts + self.zero_experts
+
+    @property
+    def hist_width(self) -> int:
+        """Columns of a layer's routing histogram: assignments per
+        router output and, where some outputs are identity experts, the
+        tokens by how many REAL experts they chose (``0 ..
+        experts_per_tok``: a token's expert work varies)."""
+        return self.router_outputs + (
+            self.experts_per_tok + 1 if self.zero_experts else 0)
+
+    def lora_scales(self, d_model: int):
+        """``(s_q, s_kv)``: the constants on q and on the normed latent
+        (1, 1 unless ``mla_lora_scale``)."""
+        if not self.mla_lora_scale:
+            return 1.0, 1.0
+        return ((d_model / self.q_rank) ** 0.5, (d_model / self.kv_rank) ** 0.5)
 
     @property
     def held(self) -> int:
@@ -200,19 +259,41 @@ DEEPSEEK_V3 = ModelSpec(
     topk_group=4, norm_topk=True, routed_scale=2.5,
 )
 
-_ARCHS = {"gpt2": GPT2, "olmoe": OLMOE, "deepseek_v3": DEEPSEEK_V3}
+# meituan-longcat/LongCat-Flash-Omni config.json (the language model;
+# HF modeling_longcat_flash.py): 28 double layers of two MLA attentions
+# (q rank 1536, latent 512 + 64 rope, heads of 128 + 64 against values
+# of 128, q and the latent scaled by (6144 / rank) ** 0.5, theta 1e7, no
+# scaling) and two dense SwiGLU FFNs of 12,288; 512 experts of 2048 and
+# 256 identity experts behind one softmax router with a selection-only
+# bias, top-12 times 6, not renormalised; RMSNorm eps 1e-5, no biases,
+# no shared expert, no leading dense layers
+LONGCAT_FLASH = ModelSpec(
+    name="longcat_flash", positions="rope", norm="rmsnorm", norm_eps=1e-5,
+    ffn="moe", num_experts=512, experts_per_tok=12, expert_width=2048,
+    rope_theta=10_000_000.0, bias=False, residual_f32=True, weights_f32=False,
+    attention="mla", q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64,
+    v_dim=128, dense_width=12_288, score="softmax_bias", routed_scale=6.0,
+    double_layer=True, zero_experts=256, mla_lora_scale=True,
+)
+
+_ARCHS = {"gpt2": GPT2, "olmoe": OLMOE, "deepseek_v3": DEEPSEEK_V3,
+          "longcat_flash": LONGCAT_FLASH}
 # the sizes any routed arch has; those only DeepSeek-V3's expert layer
 # and attention have; and the two every arch has
 _EXPERT_SIZES = ("num_experts", "experts_per_tok", "expert_width")
-_DEEPSEEK_SIZES = (
-    "dense_layers", "dense_width", "shared_experts", "n_group", "topk_group",
-    "routed_scale", "experts_held", "expert_offset",
-    "q_rank", "kv_rank", "nope_dim", "rope_dim", "v_dim", "rope_factor",
+_LATENT_SIZES = (
+    "dense_width", "routed_scale", "experts_held", "expert_offset",
+    "q_rank", "kv_rank", "nope_dim", "rope_dim", "v_dim")
+_DEEPSEEK_SIZES = _LATENT_SIZES + (
+    "dense_layers", "shared_experts", "n_group", "topk_group", "rope_factor",
     "rope_orig_len", "rope_beta_fast", "rope_beta_slow", "rope_mscale_all_dim")
-_SIZES = _EXPERT_SIZES + _DEEPSEEK_SIZES + ("rope_theta", "norm_eps")
+# ... and the one LongCat-Flash's router has beside the latent ones
+_LONGCAT_SIZES = _LATENT_SIZES + ("zero_experts",)
+_SIZES = tuple(dict.fromkeys(
+    _EXPERT_SIZES + _DEEPSEEK_SIZES + _LONGCAT_SIZES + ("rope_theta", "norm_eps")))
 # a size that may be given as 0 and mean it (0 elsewhere = as published)
 _ZERO_MEANS_ZERO = ("dense_layers", "shared_experts", "expert_offset",
-                    "experts_held")
+                    "experts_held", "zero_experts")
 
 
 def model_spec(arch: str = "gpt2", **sizes: Any) -> ModelSpec:
@@ -233,10 +314,11 @@ def model_spec(arch: str = "gpt2", **sizes: Any) -> ModelSpec:
         raise ValueError(f"model_spec: unknown sizes {unknown}")
     if not spec.routed and given.keys() & set(_EXPERT_SIZES):
         raise ValueError(f"arch={spec.name!r} has no experts to size")
-    if not spec.latent and given.keys() & set(_DEEPSEEK_SIZES):
-        raise ValueError(
-            f"arch={spec.name!r} has no "
-            f"{sorted(given.keys() & set(_DEEPSEEK_SIZES))}")
+    own = (set(_LONGCAT_SIZES) if spec.double_layer
+           else set(_DEEPSEEK_SIZES) if spec.latent else set())
+    foreign = given.keys() & (set(_DEEPSEEK_SIZES) | set(_LONGCAT_SIZES)) - own
+    if foreign:
+        raise ValueError(f"arch={spec.name!r} has no {sorted(foreign)}")
     if given:
         floats = ("rope_theta", "norm_eps", "routed_scale", "rope_factor",
                   "rope_beta_fast", "rope_beta_slow", "rope_mscale_all_dim")
@@ -244,15 +326,15 @@ def model_spec(arch: str = "gpt2", **sizes: Any) -> ModelSpec:
             k: (float(v) if k in floats else int(v))
             for k, v in given.items()
         })
-    if spec.routed and not 0 < spec.experts_per_tok <= spec.num_experts:
+    if spec.routed and not 0 < spec.experts_per_tok <= spec.router_outputs:
         raise ValueError(
-            f"experts_per_tok {spec.experts_per_tok} of {spec.num_experts} "
+            f"experts_per_tok {spec.experts_per_tok} of {spec.router_outputs} "
             "experts")
     if spec.routed and (
             spec.num_experts % spec.n_group
             or not 0 < spec.topk_group <= spec.n_group
             or spec.experts_per_tok
-            > spec.topk_group * (spec.num_experts // spec.n_group)):
+            > spec.topk_group * (spec.router_outputs // spec.n_group)):
         raise ValueError(
             f"{spec.num_experts} experts in {spec.n_group} groups, "
             f"{spec.topk_group} kept, top-{spec.experts_per_tok}")
@@ -347,12 +429,28 @@ def init_params(spec: ModelSpec, config: Dict[str, int], seed: int,
     Every leaf is uniform — bits, a scale and a shift, so the CPU (the
     benchmark's reference) and the chip (the server) make the same tree
     from the same seed — over a range its name picks: a norm's
-    ``scale`` in [0.5, 1.5) and a ``bias`` (a router's ``score_bias``
-    too) within ±0.1, so that one left out shows; an ``embedding``
+    ``scale`` in [0.5, 1.5) and a ``bias`` (a sigmoid router's
+    ``score_bias`` too) within ±0.1, so that one left out shows — a
+    softmax router's ``score_bias`` within ± the mean probability ``1 /
+    outputs``, which is to probabilities that add up to 1 what ±0.1 is
+    to scores in (0, 1): a tenth of unity would choose the experts by
+    itself; an ``embedding``
     ±sqrt(3) (unit variance); any other leaf is a matrix, ±sqrt(3 /
     fan_in) with the fan-in its second-to-last dim, experts' included
     (unit-variance outputs).  A leaf's stream is keyed by its path, not
     its place in the tree.
+
+    Where the spec scales its low-rank paths (``mla_lora_scale``), the
+    matrices a rank feeds — ``q_b``, ``kv_b_k``, ``kv_b_v`` — take the
+    fan-in ``d_model``, not their own: the constants ``(d_model / rank)
+    ** 0.5`` exist because the source draws every matrix at one
+    deviation, which leaves those paths ``(rank / d_model) ** 0.5``
+    small, and restore q, k and v to unit variance.  Drawn at their own
+    fan-in they would come out 2 and 3.46 times too large, the scores
+    6.9 times, and attention near-argmax: a step function that bfloat16
+    rounding moves at half the positions (49.6 % of 512 served tokens
+    off by over 0.09 deviations on the CPU, tools/precision_readings.py,
+    PERF.md section 6 PR 32).
 
     Where a replica holds a share of the routed experts, the
     ``score_bias`` is **ordered by the seed, not redrawn**
@@ -377,8 +475,8 @@ def init_params(spec: ModelSpec, config: Dict[str, int], seed: int,
                               **config)
     i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
     pool = jax.ShapeDtypeStruct(
-        (config["num_layers"], 2, 8, spec.cache_width(config["d_model"])),
-        dtype)
+        (spec.cache_layers(config["num_layers"]), 2, 8,
+         spec.cache_width(config["d_model"])), dtype)
     declared = jax.eval_shape(
         lm.init, jax.random.key(0), i32((1, 8)), i32((1, 8)), pool,
         pool if spec.cache_pools == 2 else None,
@@ -400,12 +498,23 @@ def init_params(spec: ModelSpec, config: Dict[str, int], seed: int,
             hi = (0.1 if name == "bias" or name.endswith("_bias")
                   else (3.0 if name == "embedding" else 3.0 / leaf.shape[-2]) ** 0.5)
             lo = -hi
+        if name == "score_bias" and spec.score == "softmax_bias":
+            lo, hi = -1.0 / leaf.shape[0], 1.0 / leaf.shape[0]
+        if spec.mla_lora_scale and _RANK_FED.search(path):
+            hi = (3.0 / config["d_model"]) ** 0.5
+            lo = -hi
         key = jax.random.fold_in(root, zlib.crc32(path.encode()))
-        if name == "score_bias" and spec.experts_held:
+        if (name == "score_bias" and spec.experts_held
+                and leaf.shape[0] % spec.held == 0):
             tree[path] = share_bias(key, leaf.shape[0], spec.held, hi)
         else:
             tree[path] = uniform(key, leaf.shape, lo, hi, jnp.dtype(leaf.dtype))
     return unflatten_dict(tree, sep="/")
+
+
+# leaves whose input is a low-rank latent: q_b's kernel, kv_b_k, kv_b_v
+# (a double layer's carry a sub-layer suffix)
+_RANK_FED = re.compile(r"/(q_b(_\d)?/kernel|kv_b_[kv](_\d)?)$")
 
 
 def share_bias(key, num_experts: int, held: int, most: float):

@@ -34,6 +34,14 @@ experts scored by their two best of which the best few are kept, top-k
 among those, and the chosen experts' own sigmoid scores divided by
 their sum and scaled.
 
+``route_zero`` is the LongCat-Flash router: softmax in float32 over the
+real experts and, after them, the identity ("zero-computation")
+experts; a correction bias that takes part in the *selection* alone;
+top-k of the whole width; the chosen probabilities times a constant,
+not renormalised.  A pick past the real experts is an identity expert:
+``identity_experts`` adds ``gate * h`` for it, with no matrices and no
+row in any grouped matmul.
+
 ``expert_ffn_held`` is one replica's share of an expert-parallel layer:
 the router scores every expert, this replica holds ``w_gate.shape[0]``
 of them from ``offset`` on, and the result is the part those experts
@@ -420,6 +428,52 @@ def route_grouped(h, w_router, bias, top_k: int, n_group: int,
     return gates, experts.astype(jnp.int32)
 
 
+def route_zero(h, w_router, bias, top_k: int, scale: float):
+    """``h`` ``(T, d)``, ``w_router`` ``(d, E + Z)``, ``bias`` ``(E +
+    Z,)`` -> ``(gates (T, k) f32, experts (T, k) int32)``, as HF
+    ``modeling_longcat_flash.py`` routes: ``p = softmax(h W)`` over the
+    real and the identity experts together; the ``top_k`` largest of ``p
+    + bias`` chosen; the gates are ``p`` (never ``p + bias``) of the
+    chosen times ``scale``, not renormalised.  Ties go to the lower
+    index."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope(ROUTER_SCOPE):
+        logits = jnp.dot(
+            h.astype(jnp.float32), w_router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+        probs = jax.nn.softmax(logits, axis=-1)
+        _, experts = jax.lax.top_k(probs + bias.astype(jnp.float32), top_k)
+        gates = jnp.take_along_axis(probs, experts, axis=-1) * scale
+    return gates, experts.astype(jnp.int32)
+
+
+def identity_experts(h, gates, experts, num_experts: int):
+    """The identity experts' part of a token's sum: ``(sum of the gates
+    of its picks >= num_experts) * h`` — ``h`` ``(T, d)`` float32 -> ``(T,
+    d)`` float32.  No matrices: a token's own chip computes it."""
+    import jax.numpy as jnp
+
+    gate = jnp.where(experts >= num_experts, gates, 0.0).sum(axis=-1)
+    return gate[:, None] * h.astype(jnp.float32)
+
+
+def real_pick_histogram(experts, num_experts: int, mask=None):
+    """Tokens by how many of their picks are real experts (``<
+    num_experts``): ``int32[k + 1]`` over the rows ``mask`` keeps."""
+    import jax
+    import jax.numpy as jnp
+
+    real = (experts < num_experts).sum(axis=-1)                    # (T,)
+    hit = jax.nn.one_hot(real, experts.shape[-1] + 1, dtype=jnp.int32)
+    if mask is not None:
+        hit = hit * mask.astype(jnp.int32)[:, None]
+    return hit.sum(axis=0)
+
+
 # A pass of ``expert_ffn_held`` holds this many times the rows an even
 # router would send here: routing is not even (PERF.md section 6, PR 30:
 # a share's load swings 2.5 to 3.4 % about 3.125), and a second pass
@@ -464,7 +518,8 @@ def expert_ffn_held(h, w_gate, w_up, w_down, gates, experts, offset: int,
     """The held experts' part of :func:`expert_ffn`'s sum: ``w_gate`` /
     ``w_up`` ``(H, d, f)`` and ``w_down`` ``(H, f, d)`` are experts
     ``offset .. offset + H`` of the ``num_experts`` that ``experts``
-    ``(T, k)`` names.
+    ``(T, k)`` names (every output the router scores, identity experts
+    included: they are what an even share is a share of).
 
     The assignments are sorted local-first by expert and computed
     :func:`held_rows_cap` rows a pass (``ragged_dot`` over the rows'

@@ -528,6 +528,21 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
         "counter", "seldon_tpu_engine_moe_held_active_expert_steps_total",
         "held experts hit, summed over the decode (routed layer, step)s "
         "that ran"),
+    "moe_zero_assignments": (
+        "counter", "seldon_tpu_engine_moe_zero_assignments_total",
+        "picks that fell on identity (zero-computation) experts (a router "
+        "that scores them; 0 otherwise)"),
+    "moe_routed_tokens": (
+        "counter", "seldon_tpu_engine_moe_routed_tokens_total",
+        "(token, layer)s routed by a router that scores identity experts"),
+    "moe_few_real_tokens": (
+        "counter", "seldon_tpu_engine_moe_few_real_tokens_total",
+        "(token, layer)s that chose at most a third of their picks among "
+        "the real experts"),
+    "moe_many_real_tokens": (
+        "counter", "seldon_tpu_engine_moe_many_real_tokens_total",
+        "(token, layer)s that chose all or all but one of their picks "
+        "among the real experts"),
     "latent_kv_tokens": ("counter",
                          "seldon_tpu_engine_latent_kv_tokens_total",
                          "cached latent rows read by decode lane-steps, "

@@ -611,48 +611,60 @@ def test_more_local_assignments_than_a_pass_holds_are_all_computed():
 # what cannot take a latent pool yet says so, by name
 # ---------------------------------------------------------------------------
 
+def _longcat():
+    """A small LongCat-Flash (PR 32): the other latent pool, which what
+    refuses one refuses by the same cases."""
+    spec = model_spec(
+        "longcat_flash", num_experts=8, zero_experts=4, experts_per_tok=4,
+        expert_width=32, dense_width=96, experts_held=4, expert_offset=2,
+        q_rank=24, kv_rank=16, nope_dim=8, rope_dim=4, v_dim=8)
+    return spec, dict(SIZES, num_layers=2)
+
+
+@pytest.mark.parametrize("arch", ["deepseek_v3", "longcat_flash"])
 class TestFences:
-    def _make(self, monkeypatch=None, **kw):
+    def _make(self, arch, **kw):
+        spec, sizes = (SPEC, SIZES) if arch == "deepseek_v3" else _longcat()
         return PagedEngine(
-            init_params(SPEC, SIZES, 0, dtype=jnp.float32), **SIZES,
-            max_len=MAX_LEN, page_size=PAGE, max_slots=SLOTS, spec=SPEC, **kw)
+            init_params(spec, sizes, 0, dtype=jnp.float32), **sizes,
+            max_len=MAX_LEN, page_size=PAGE, max_slots=SLOTS, spec=spec, **kw)
 
-    def test_int8_kv(self, monkeypatch):
+    def test_int8_kv(self, monkeypatch, arch):
         monkeypatch.setenv("SELDON_TPU_KV_DTYPE", "int8")
-        with pytest.raises(ValueError, match="latent row.*int8 KV pool"):
-            self._make()
+        with pytest.raises(ValueError, match=f"{arch}.*latent row.*int8 KV pool"):
+            self._make(arch)
 
-    def test_adapters(self):
-        with pytest.raises(ValueError, match="latent row.*adapters"):
-            self._make(max_adapters=2)
+    def test_adapters(self, arch):
+        with pytest.raises(ValueError, match=f"{arch}.*latent row.*adapters"):
+            self._make(arch, max_adapters=2)
 
     @pytest.mark.parametrize("kw", [{"tp": 2}, {"dp": 2}])
-    def test_a_mesh(self, kw):
-        with pytest.raises(ValueError, match="deepseek_v3.*one chip"):
-            self._make(**kw)
+    def test_a_mesh(self, kw, arch):
+        with pytest.raises(ValueError, match=f"{arch}.*one chip"):
+            self._make(arch, **kw)
 
     @pytest.mark.parametrize("kw", [{"quantize": "int8"}, {"precision": "w8a8"}])
-    def test_int8_weights(self, kw):
-        with pytest.raises(ValueError, match="expert matrices"):
-            self._make(**kw)
+    def test_int8_weights(self, kw, arch):
+        with pytest.raises(ValueError, match=f"{arch}.*expert matrices"):
+            self._make(arch, **kw)
 
-    def test_the_speculative_lane(self):
-        with pytest.raises(ValueError, match="latent row.*speculative lane"):
-            self._make(speculative={"draft": "ngram"})
+    def test_the_speculative_lane(self, arch):
+        with pytest.raises(ValueError, match=f"{arch}.*latent row.*speculative lane"):
+            self._make(arch, speculative={"draft": "ngram"})
 
-    def test_the_kv_tier(self, monkeypatch):
+    def test_the_kv_tier(self, monkeypatch, arch):
         monkeypatch.setenv("SELDON_TPU_KV_OFFLOAD", "1")
-        with pytest.raises(ValueError, match="latent row.*host KV tier"):
-            self._make()
+        with pytest.raises(ValueError, match=f"{arch}.*latent row.*host KV tier"):
+            self._make(arch)
 
-    def test_the_ring_chunk(self, monkeypatch):
+    def test_the_ring_chunk(self, monkeypatch, arch):
         monkeypatch.setenv("SELDON_TPU_CHUNK_IMPL", "ring")
-        with pytest.raises(ValueError, match="latent row.*ring chunk"):
-            self._make()
+        with pytest.raises(ValueError, match=f"{arch}.*latent row.*ring chunk"):
+            self._make(arch)
 
-    def test_disaggregated_prefill_and_migration(self, monkeypatch):
+    def test_disaggregated_prefill_and_migration(self, monkeypatch, arch):
         monkeypatch.delenv("SELDON_TPU_CHUNK_IMPL", raising=False)
-        eng = self._make()
+        eng = self._make(arch)
         try:
             with pytest.raises(ValueError, match="latent row.*prefill export"):
                 eng.prefill_export(np.asarray(PROMPT, np.int32))
@@ -669,9 +681,12 @@ class TestFences:
         finally:
             eng.close()
 
-    def test_sizes_an_arch_does_not_have(self):
+    def test_sizes_an_arch_does_not_have(self, arch):
         with pytest.raises(ValueError, match="has no"):
             model_spec("olmoe", kv_rank=16)
+        with pytest.raises(ValueError, match="has no"):
+            model_spec(arch, **({"zero_experts": 4} if arch == "deepseek_v3"
+                                else {"n_group": 4}))
         with pytest.raises(ValueError, match="experts_held"):
             model_spec("deepseek_v3", experts_held=8, expert_offset=250)
         with pytest.raises(ValueError, match="groups"):

@@ -269,3 +269,80 @@ def test_held_expert_layer_compiles_with_rows_of_a_pass(one_chip, mosaic, backen
     assert cap == rows * k // 8 and (f"[{cap},{f}]" in text or f"[{cap},{d}]" in text)
     assert f"[{rows * k},{d}]" not in text  # never all the assignments' rows
     assert compiled.memory_analysis().temp_size_in_bytes < 12 * rows * d * 4
+
+
+# ---------------------------------------------------------------------------
+# LongCat-Flash (PR 32): the same two kernels at another pool and other widths
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("lanes,pages", [(64, 8), (64, 16), (64, 32), (64, 48), (128, 48)])
+def test_latent_kernel_compiles_on_the_double_layer_s_pool(one_chip, mosaic, lanes, pages):
+    """The think cell's chunk buckets on the whole ``(8, 6145, 64, 640)``
+    pool — 4 layers x 2 attentions — with a traced attention index (the
+    block passes ``2 * layer + i``), at tables of 8 to 48 pages: 48, the
+    per-stream table at 3,072 tokens, is no power of two."""
+    attentions, num_pages, heads, lanes_w, rank = 8, 6145, 64, 640, 512
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q, pool, tables, lengths, layer):
+        return kernels.latent_attention_decode(
+            q, pool, tables, lengths, layer=2 * layer + 1, page_size=PS, rank=rank)
+
+    compiled = jax.jit(fn).lower(
+        spec((lanes, heads, lanes_w), jnp.bfloat16),
+        spec((attentions, num_pages, PS, lanes_w), jnp.bfloat16),
+        spec((lanes, pages), jnp.int32), spec((lanes,), jnp.int32),
+        spec((), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and " = " in ln]
+    assert len(calls) == 1, calls
+    assert calls[0].partition(" = ")[2].lstrip("(").startswith(
+        f"f32[{lanes},{heads},{rank}]"), calls[0][:200]
+    whole = f"[{attentions},{num_pages},{PS},{lanes_w}]"
+    produced = [ln for ln in text.splitlines()
+                if whole in ln.partition(" = ")[2].partition("(")[0]
+                and " parameter(" not in ln]
+    assert not produced, produced[:3]
+
+
+@pytest.mark.parametrize("rows", [128, 512, 2048, 4096])
+def test_shortcut_experts_compile_with_rows_of_a_pass(one_chip, mosaic, backend, rows):
+    """A decode step's 128 tokens and prefill groups of 512 to 4,096
+    through LongCat-Flash's router and its held experts at the published
+    widths, 16 of 512 real experts held beside 256 identity experts: a
+    pass holds four times an even share of the ``rows x 12`` picks over
+    all 768 outputs, which is ``rows`` itself; on a TPU every pass under
+    256 rows an expert streams through the Pallas kernel (2,048 rows in
+    segments of 640), and 4,096 rows over 16 experts stay on
+    ``ragged_dot``."""
+    from seldon_core_tpu.ops import moe
+
+    d, f, real, zero, held, k = 6144, 2048, 512, 256, 16, 12
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(h, w_router, bias, w_gate, w_up, w_down):
+        gates, experts = moe.route_zero(h, w_router, bias, k, 6.0)
+        out = moe.expert_ffn_held(
+            h.astype(jnp.bfloat16), w_gate, w_up, w_down, gates, experts, 0, real + zero)
+        out = out + moe.identity_experts(h, gates, experts, real)
+        return out, moe.expert_histogram(experts, real + zero), \
+            moe.real_pick_histogram(experts, real)
+
+    compiled = jax.jit(layer).lower(
+        spec((rows, d), jnp.float32), spec((d, real + zero), jnp.float32),
+        spec((real + zero,), jnp.float32), spec((held, d, f), jnp.bfloat16),
+        spec((held, d, f), jnp.bfloat16), spec((held, f, d), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    cap = moe.held_rows_cap(rows, k, held, real + zero)
+    assert cap == rows
+    assert moe.layer_expert_matmul(rows, k, held, real + zero, d, f, jnp.bfloat16,
+                                   held_pass=True, backend="tpu") == (
+        "stream" if rows < 4096 else "ragged_dot")
+    assert_expert_kernels(text, backend == "tpu" and rows < 4096,
+                          min(cap, moe.stream_segment_rows(d)), d, f)
+    assert f"[{rows * k},{d}]" not in text  # never all the picks' rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * rows * d * 4

@@ -469,7 +469,11 @@ class TestDecodeLane:
                 # PR 30: what the cache holds
                 "attention", "cache_width", "experts_held",
                 # PR 31: which grouped expert matmul each program traced
-                "expert_matmul"}
+                "expert_matmul",
+                # PR 32: the pool's leading axis (attention sub-layers)
+                # and the cap on a prefill call's padded positions
+                "cache_layers", "prefill_positions_max"}
+            assert rep["cache_layers"] == LAYERS
             assert (rep["attention"], rep["cache_width"], rep["experts_held"]) == (
                 "mha", cfg["d_model"], 0)
             assert rep["expert_matmul"] == {}     # a dense model has none

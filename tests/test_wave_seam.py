@@ -563,6 +563,59 @@ class TestIngressStamp:
         assert stats["queue_waits"] == 1
 
 
+    @pytest.mark.parametrize("waiting", [3, 12])
+    def test_streams_waiting_for_a_slot_do_not_silence_a_decoding_one(self, waiting):
+        """ROADMAP S9: a reply's first pull (submit, the wait for a slot,
+        the prefill) holds a thread of the dispatch pool, not one of the
+        loop's default executor, which only ever waits a wave: with two
+        default threads and more callers than that waiting for a slot, a
+        stream that has one still gets its tokens."""
+        import asyncio
+        import threading
+        from concurrent.futures import ThreadPoolExecutor
+
+        from aiohttp.test_utils import TestClient, TestServer
+
+        from seldon_core_tpu.engine.server import build_gateway_app
+        from seldon_core_tpu.runtime.component import TPUComponent
+
+        slot = threading.Event()
+
+        class Stub(TPUComponent):
+            def predict(self, X, names, meta=None):
+                return X
+
+            def predict_stream(self, X, names, meta=None):
+                if int(X[0][0]) == 0:      # a caller with no slot yet
+                    slot.wait(timeout=60)
+                for i in range(3):
+                    yield [i, i + 1]
+
+        async def scenario():
+            asyncio.get_running_loop().set_default_executor(ThreadPoolExecutor(2))
+            client = TestClient(TestServer(build_gateway_app(_gateway(Stub()))))
+            await client.start_server()
+            try:
+                async def ask(first):
+                    resp = await client.post("/api/v0.1/generate/stream",
+                                             json={"data": {"ndarray": [[first, 2, 3]]}})
+                    return await resp.text()
+
+                queued = [asyncio.ensure_future(ask(0)) for _ in range(waiting)]
+                await asyncio.sleep(0.3)   # they are in their first pull
+                decoding = await asyncio.wait_for(ask(7), timeout=20)
+                assert not any(q.done() for q in queued)
+                slot.set()
+                return decoding, await asyncio.gather(*queued)
+            finally:
+                slot.set()
+                await client.close()
+
+        decoding, queued = asyncio.run(scenario())
+        assert decoding.count("data:") == 4 and "event: end" in decoding
+        assert all(text.count("data:") == 4 for text in queued)
+
+
 def test_device_report_gives_the_peak_beside_bytes_in_use(monkeypatch):
     import jax
 

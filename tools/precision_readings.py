@@ -1,15 +1,16 @@
 #!/usr/bin/env python3
 """The readings behind the limits of ``benchmarks/harness/kinds/
-generation_share.py``: what GigaChat3.1's plain float32 reference says
-of the tokens that the same forward pass serves in a lower precision, or
-with a fault.  CPU only (``JAX_PLATFORMS=cpu``), ~1 minute a variant at
-512 positions; nothing here is a device number.
+generation_share.py``: what a configuration's plain float32 reference
+(GigaChat3.1's, or with ``--config longcat-flash-omni`` LongCat-Flash's)
+says of the tokens that the same forward pass serves in a lower
+precision, or with a fault.  CPU only (``JAX_PLATFORMS=cpu``), 1-2
+minutes a variant at 512 positions; nothing here is a device number.
 
     python tools/precision_readings.py --seed 3000005003 --positions 512
 
-One sequence of seeded token ids is run through ``reference/
-deepseek_v3.py`` (the judge) and through this file's copy of its forward
-pass with roundings put in, and each variant's greedy token at every
+One sequence of seeded token ids is run through the configuration's
+``reference/<name>.py`` (the judge) and through this file's copy of its
+forward pass with roundings put in, and each variant's greedy token at every
 position is judged as the kind judges a served one: its gap under the
 reference's top-1 in deviations of that position's logits.  Printed per
 variant: positions whose token is not the top-1, positions *off* (gap
@@ -28,7 +29,8 @@ Variants (``--variants`` picks, default all):
   (float8 e4m3, scaled to the row's / the matrix's largest value), the
   attention's own products in bfloat16;
 * ``no-bias``, ``no-shared``: the stated precision with the correction
-  bias left out of the selection | without the shared expert;
+  bias left out of the selection | without the shared expert (for
+  LongCat-Flash, which has none: without the identity experts' part);
 * ``early-row``: the reference's own top-1 of the position before (a
   stale page, a shifted row).
 """
@@ -46,6 +48,117 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "benchmarks")]
 VARIANTS = ("stated", "bf16-residual", "e4m3", "no-bias", "no-shared", "early-row")
 
 
+def roundings(residual_bf16: bool, e4m3: bool):
+    """``(f32, b16, act, w, res)``: a leaf as float32; a matmul's result
+    rounded to bfloat16; an operand of a weight matmul, a weight as the
+    matmul reads it, and the residual stream, each as the variant
+    rounds it."""
+    import jax.numpy as jnp
+
+    def f32(a):
+        return jnp.asarray(a).astype(jnp.float32)
+
+    def b16(a):
+        return a.astype(jnp.bfloat16).astype(jnp.float32)
+
+    def q8(a, axis):
+        unit = jnp.max(jnp.abs(a), axis=axis, keepdims=True) / 448.0 + 1e-30
+        return (a / unit).astype(jnp.float8_e4m3fn).astype(jnp.float32) * unit
+
+    def act(a):
+        return q8(a, -1) if e4m3 else b16(a)
+
+    def w(a):
+        return q8(f32(a), None) if e4m3 else f32(a)
+
+    def res(a):
+        return b16(a) if residual_bf16 else a
+
+    return f32, b16, act, w, res
+
+
+def rounded_logits_longcat(ref, params, model, tokens, residual_bf16=False, e4m3=False,
+                           bias=True, shared=True):
+    """``reference/longcat_flash.py logits`` with its matmuls' operands
+    and results rounded: the same equations, line for line (``shared``
+    false leaves the identity experts' part out)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    eps, heads = model["rms_norm_eps"], model["num_attention_heads"]
+    nope, rdim, rank = (model["qk_nope_head_dim"], model["qk_rope_head_dim"],
+                        model["kv_lora_rank"])
+    d = model["hidden_size"]
+    held, offset = model["n_routed_experts"], model.get("expert_offset", 0)
+    real = ref.real_experts(model)
+    s_q, s_kv = (d / model["q_lora_rank"]) ** 0.5, (d / rank) ** 0.5
+    scale = (nope + rdim) ** -0.5
+    freq = jnp.asarray(1.0 / model["rope_theta"] ** (np.arange(0, rdim, 2) / rdim),
+                       jnp.float32)
+    f32, b16, act, w, res = roundings(residual_bf16, e4m3)
+
+    def rms_norm(x, scale_):
+        return x / jnp.sqrt((x * x).mean(-1, keepdims=True) + eps) * f32(scale_)
+
+    def rotate(x, pos):
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        ang = pos.astype(jnp.float32).reshape(-1, *([1] * (x.ndim - 2)), 1) * freq
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+    def swiglu(h, gate, up, down):
+        h = act(h)
+        return act(b16(jax.nn.silu(h @ w(gate)) * (h @ w(up)))) @ w(down)
+
+    with jax.default_matmul_precision("highest"):
+        tokens = jnp.asarray(tokens, jnp.int32)
+        n = tokens.shape[0]
+        pos = jnp.arange(n)
+        x = res(f32(params["tok_embed"]["embedding"][tokens]))
+        for layer in range(model["num_layers"]):
+            p = params[f"block_{layer}"]
+            for i in (0, 1):
+                h = rms_norm(x, p[f"attn_norm_{i}"]["scale"])
+                c_q = rms_norm(b16(act(h) @ w(p[f"q_a_{i}"]["kernel"])),
+                               p[f"q_a_norm_{i}"]["scale"])
+                q = (b16(act(c_q) @ w(p[f"q_b_{i}"]["kernel"])) * s_q).reshape(
+                    n, heads, nope + rdim)
+                kva = b16(act(h) @ w(p[f"kv_a_{i}"]["kernel"]))
+                c_kv = b16(rms_norm(kva[:, :rank], p[f"kv_a_norm_{i}"]["scale"]) * s_kv)
+                k_r = b16(rotate(kva[:, rank:], pos))
+                q_nope, q_r = q[..., :nope], b16(rotate(q[..., nope:], pos))
+                k_nope = b16(jnp.einsum("cr,hrn->hcn", c_kv, f32(p[f"kv_b_k_{i}"])))
+                v = b16(jnp.einsum("cr,hrv->hcv", c_kv, f32(p[f"kv_b_v_{i}"])))
+                out = []
+                for lo in range(0, n, ref.QUERY_BLOCK):
+                    hi = min(n, lo + ref.QUERY_BLOCK)
+                    s = (jnp.einsum("qhn,hcn->hqc", q_nope[lo:hi], k_nope)
+                         + jnp.einsum("qhr,cr->hqc", q_r[lo:hi], k_r)) * scale
+                    s = jnp.where((pos[None, :] <= pos[lo:hi, None])[None], s, -jnp.inf)
+                    out.append(jnp.einsum("hqc,hcv->qhv", b16(jax.nn.softmax(s, axis=-1)), v))
+                attn = b16(jnp.concatenate(out, axis=0).reshape(n, -1))
+                x = res(x + b16(act(attn) @ w(p[f"attn_proj_{i}"]["kernel"])))
+
+                g = rms_norm(x, p[f"ffn_norm_{i}"]["scale"])
+                if i == 0:
+                    probs = jax.nn.softmax(g @ f32(p["router"]), axis=-1)
+                    score_bias = np.asarray(p["score_bias"], np.float32)
+                    weights, chosen = ref.route(model, probs,
+                                                score_bias if bias else 0 * score_bias)
+                    m = jnp.asarray(np.where(chosen >= real, weights, 0.0).sum(-1))[:, None] * g \
+                        if shared else jnp.zeros_like(x)
+                    for e in range(held):
+                        rows, slot = np.nonzero(chosen == e + offset)
+                        if rows.size:
+                            part = swiglu(g[rows], p["experts_gate"][e], p["experts_up"][e],
+                                          p["experts_down"][e])
+                            m = m.at[rows].add(part * weights[rows, slot][:, None])
+                x = res(x + swiglu(g, p[f"mlp_gate_{i}"], p[f"mlp_up_{i}"], p[f"mlp_down_{i}"]))
+            x = res(x + m)
+        return rms_norm(x, params["final_norm"]["scale"]) @ f32(params["head"]["kernel"])
+
+
 def rounded_logits(ref, params, model, tokens, residual_bf16=False, e4m3=False,
                    bias=True, shared=True):
     """``reference/deepseek_v3.py logits`` with its matmuls' operands and
@@ -61,24 +174,7 @@ def rounded_logits(ref, params, model, tokens, residual_bf16=False, e4m3=False,
     scale = ref.softmax_scale(model)
     freq = jnp.asarray(ref.inv_freq(model), jnp.float32)
 
-    def f32(a):
-        return jnp.asarray(a).astype(jnp.float32)
-
-    def b16(a):
-        return a.astype(jnp.bfloat16).astype(jnp.float32)
-
-    def q8(a, axis):
-        unit = jnp.max(jnp.abs(a), axis=axis, keepdims=True) / 448.0 + 1e-30
-        return (a / unit).astype(jnp.float8_e4m3fn).astype(jnp.float32) * unit
-
-    def act(a):   # an operand of a weight matmul
-        return q8(a, -1) if e4m3 else b16(a)
-
-    def w(a):     # a weight, as the matmul reads it
-        return q8(f32(a), None) if e4m3 else f32(a)
-
-    def res(a):   # the residual stream
-        return b16(a) if residual_bf16 else a
+    f32, b16, act, w, res = roundings(residual_bf16, e4m3)
 
     def rms_norm(x, scale_):
         return x / jnp.sqrt((x * x).mean(-1, keepdims=True) + eps) * f32(scale_)
@@ -164,7 +260,9 @@ def main() -> int:
         if name == "early-row":
             served, far = np.concatenate([plain[:1].argmax(-1), plain[:-1].argmax(-1)]), None
         else:
-            got = np.asarray(rounded_logits(ref, params, model, tokens, **how[name]))
+            rounded = (rounded_logits_longcat if config["reference"] == "longcat_flash"
+                       else rounded_logits)
+            got = np.asarray(rounded(ref, params, model, tokens, **how[name]))
             served = got.argmax(-1)
             far = float(np.median(np.sqrt(((got - plain) ** 2).mean(-1)) / std))
         gaps = (plain.max(-1) - plain[np.arange(n), served]) / std
