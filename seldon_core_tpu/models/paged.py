@@ -476,7 +476,7 @@ def _build_modules():
         einsums elsewhere — and the step's own row joins by the flash
         rule."""
         from seldon_core_tpu.models.spec import rope_interleaved, yarn_inv_freq
-        from seldon_core_tpu.ops import mla
+        from seldon_core_tpu.ops import kernels, mla
 
         spec = mod.spec
         heads, rank = mod.num_heads, spec.kv_rank
@@ -546,9 +546,12 @@ def _build_modules():
             sl = slice(off, off + nb)
             off += nb
             if seg_len > 1:
+                fused = kernels.prefill_attention_impl(
+                    seg_len, nope + rdim, vdim, mod.dtype, tb.shape[1],
+                    whole) == "fused"
                 outs.append(mla.naive_attention(
                     q_nope[sl], q_rope[sl], cached(tb), lengths[sl], row[sl],
-                    w_uk, w_uv, scale, mod.dtype))
+                    w_uk, w_uv, scale, mod.dtype, fused=fused))
                 continue
             q_abs = jnp.einsum(
                 "bhn,hrn->bhr", q_nope[sl][:, 0], w_uk.astype(mod.dtype),
@@ -580,6 +583,28 @@ def _build_modules():
         attn = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
         attn = attn.reshape(batch, seg_len, heads * vdim)
         return x + proj("attn_proj", d_model, attn), row
+
+    def _segment_attention(mod, q, k, v, scale):
+        """Causal attention of a segment ``(B, L, h, hd)`` over itself
+        alone (a prefill from position zero): ``(B, L, h, hd)``.  The
+        gather path's own einsums without their cache half — bf16 scores
+        masked with finfo.min, f32 softmax — on every backend and lane.
+        The fused kernel (``ops/kernels.py causal_attention``) is not
+        asked here: a v5e reads it level with these three fusions at
+        the shapes the cells run (ms a GPT-2-large layer, XLA / kernel:
+        ``b1024_k2`` 0.208 / 0.208, ``b1024_k1`` 0.138 / 0.133,
+        ``b512_k4`` 0.150 / 0.156; OLMoE ``b512_k4`` 0.127 / 0.141) and
+        ahead only at ``b1024_k4`` (0.839 / 0.354), which no cell's
+        traffic forms (PERF.md §5, §6 PR 33; ROADMAP S11 a)."""
+        seg_len = q.shape[1]
+        ss = jnp.einsum("bqhd,bkhd->bhqk", q * scale, k)
+        seg_mask = (
+            jnp.arange(seg_len)[None, :] <= jnp.arange(seg_len)[:, None]
+        )  # (L, L) causal within this segment
+        ss = jnp.where(seg_mask[None, None], ss, jnp.finfo(ss.dtype).min)
+        weights = jax.nn.softmax(
+            ss.astype(jnp.float32), axis=-1).astype(mod.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
 
     def _embed(lm, tokens, positions):
         tokens = tokens.astype(jnp.int32)
@@ -843,6 +868,14 @@ def _build_modules():
                 for tb in tables:
                     nb = tb.shape[0]
                     sl = slice(off, off + nb)
+                    if tb.shape[1] == 0:
+                        # a table of no width: a prefill from position
+                        # zero, whose segment has no cache to read and
+                        # attends over itself alone
+                        outs.append(_segment_attention(
+                            self, q[sl], k[sl], v[sl], scale))
+                        off += nb
+                        continue
                     # (nb, P, ps, d).  A whole pool is indexed (layer,
                     # page) in ONE gather: pk[layer][tb] would cut the
                     # layer out first, and XLA does not fuse that slice
@@ -2438,6 +2471,17 @@ class PagedEngine:
         self._kernel_active = bool(
             self._chunk_impl == "pool" and kernel_eligible
         )
+        # what each from-zero prefill program attends with: the latent
+        # block's own rule at this engine's widths, type and lane
+        # ("fused": ops/kernels.py causal_attention); the multi-head
+        # block and every cached-suffix program are XLA's
+        from seldon_core_tpu.ops.kernels import prefill_attention_impl
+
+        self._prefill_attention = {
+            bucket: prefill_attention_impl(
+                bucket, spec.nope_dim + spec.rope_dim, spec.v_dim, dtype, 0,
+                kernel_eligible) if spec.latent else "xla"
+            for bucket in self.prompt_buckets}
         # r18 int8 KV pool: pages rest int8 with ONE f32 scale per page
         # per k/v in a sibling (layers, num_pages) table — half the
         # pool bytes (≈2x paged_capacity_streams), dequantised
@@ -2763,6 +2807,11 @@ class PagedEngine:
                           # bucket, so a call computes k * bucket
                           # positions whatever its true tokens
                           "prefill_padded_tokens": 0,
+                          # of those, the positions whose attention ran
+                          # in the fused causal kernel (a from-zero
+                          # prefill of a bucket ``_prefill_attention``
+                          # gives "fused"; ops/kernels.py causal_attention)
+                          "prefill_fused_positions": 0,
                           # decode work where it is done: cached tokens
                           # each lane's decode steps attended (the
                           # lane's length at each step it ran) and
@@ -3302,11 +3351,12 @@ class PagedEngine:
             lengths = jnp.zeros((k,), jnp.int32)
             pk_pages, sk = kv_split(pk)
             pv_pages, sv = kv_split(pv)
-            # a latent block gathers the cached rows a segment attends
-            # (the multi-head block's gather of them is masked out by
-            # lengths 0, which XLA cannot elide): from position 0 there
-            # are none, and a table of no width says so
-            read_rows = block_rows[:, :0] if self.spec.latent else block_rows
+            # from position 0 there is no cache to read, and a table of
+            # no width says so to every block kind: the segment attends
+            # over itself alone (a table with width has its pages
+            # gathered and scored, then masked out by lengths 0, which
+            # XLA cannot elide)
+            read_rows = block_rows[:, :0]
             logits, nk, nv, hist = self._lm(
                 self.module, params, tokens, positions, pk_pages, pv_pages,
                 read_rows, lengths, lora=lora, adapter_idx=adapter_idx,
@@ -5540,14 +5590,17 @@ class PagedEngine:
                             * (self.module.num_layers - self.spec.dense_layers)}
             if self.spec.routed else {}
         )
+        fused = not use_cache and self._prefill_attention[bucket] == "fused"
         self._seam.begin_prefill(
             bucket=bucket, k=k, rows=len(group),
             tokens=tokens, padded=k * bucket,
-            cached=int(use_cache), **routed,
+            cached=int(use_cache), fused=int(fused), **routed,
         )
         try:
             with self._lock:
                 self._counters["prefill_padded_tokens"] += k * bucket
+                if fused:
+                    self._counters["prefill_fused_positions"] += k * bucket
             return self._prefill_group_call(bucket, k, group, use_cache)
         finally:
             self._seam.end_prefill()
@@ -6712,6 +6765,12 @@ class PagedEngine:
             # which grouped expert matmul each program traced
             # (ops/moe.py grouped_swiglu): {} for a dense model
             "expert_matmul": self._expert_matmul_report(),
+            # what each bucket's from-zero prefill attends with:
+            # "fused" (ops/kernels.py causal_attention) or "xla"; every
+            # cached-suffix prefill is XLA's
+            "prefill_attention": {
+                f"b{bucket}": impl
+                for bucket, impl in self._prefill_attention.items()},
         }
 
     def _expert_matmul_report(self) -> Dict[str, str]:

@@ -236,18 +236,17 @@ class Int8Dense:
 # ---------------------------------------------------------------------------
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                  block_q: int, block_k: int, n_kv: int, causal: bool,
-                  scale: float, valid_k: int):
-    """Grid cell (batch*head, q-block, kv-block): the kv axis is the
-    innermost grid dimension, so the online-softmax carry lives in VMEM
-    scratch across kv steps — KV streams block-by-block from HBM and
-    VMEM holds O(block_q * d + block_k * d), independent of sequence
-    length (the standard TPU flash-attention shape)."""
+                  block_k: int, n_kv: int, scale: float, valid_k: int):
+    """Grid cell (batch*head, q-block, kv-block) of FULL (not causal)
+    attention: the kv axis is the innermost grid dimension, so the
+    online-softmax carry lives in VMEM scratch across kv steps — KV
+    streams block-by-block from HBM and VMEM holds O(block_q * d +
+    block_k * d), independent of sequence length (the standard TPU
+    flash-attention shape).  The causal form is :func:`_causal_kernel`."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -256,37 +255,23 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # causal: kv blocks entirely beyond this q block contribute nothing
-    needed = jnp.logical_or(
-        jnp.logical_not(causal), j * block_k <= (qi + 1) * block_q - 1
-    )
-
-    @pl.when(needed)
-    def _step():
-        q = q_ref[0].astype(jnp.float32) * scale  # (block_q, d)
-        k = k_ref[0].astype(jnp.float32)  # (block_k, d)
-        v = v_ref[0].astype(jnp.float32)
-        s = q @ k.T
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            s = jnp.where(k_pos > q_pos, -jnp.inf, s)
-        if valid_k % block_k:  # tail block carries sequence padding
-            s = jnp.where(k_pos >= valid_k, -jnp.inf, s)
-        m = m_ref[...]
-        blk_max = jnp.max(s, axis=-1)
-        new_m = jnp.maximum(m, blk_max)
-        safe_m = jnp.where(jnp.isfinite(new_m), new_m, 0.0)
-        p = jnp.exp(s - safe_m[:, None])
-        p = jnp.where(jnp.isfinite(s), p, 0.0)
-        correction = jnp.where(jnp.isfinite(m), jnp.exp(m - safe_m), 0.0)
-        m_ref[...] = new_m
-        l_ref[...] = l_ref[...] * correction + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * correction[:, None] + p @ v
+    q = q_ref[0].astype(jnp.float32) * scale  # (block_q, d)
+    k = k_ref[0].astype(jnp.float32)  # (block_k, d)
+    v = v_ref[0].astype(jnp.float32)
+    s = q @ k.T
+    if valid_k % block_k:  # tail block carries sequence padding
+        k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(k_pos >= valid_k, -jnp.inf, s)
+    m = m_ref[...]
+    blk_max = jnp.max(s, axis=-1)
+    new_m = jnp.maximum(m, blk_max)
+    safe_m = jnp.where(jnp.isfinite(new_m), new_m, 0.0)
+    p = jnp.exp(s - safe_m[:, None])
+    p = jnp.where(jnp.isfinite(s), p, 0.0)
+    correction = jnp.where(jnp.isfinite(m), jnp.exp(m - safe_m), 0.0)
+    m_ref[...] = new_m
+    l_ref[...] = l_ref[...] * correction + jnp.sum(p, axis=-1)
+    acc_ref[...] = acc_ref[...] * correction[:, None] + p @ v
 
     @pl.when(j == n_kv - 1)
     def _emit():
@@ -303,8 +288,11 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 128, block_k: 
     Shapes follow plain_attention: (batch, seq, heads, head_dim).  The
     per-chip counterpart of ring attention (which shards ACROSS chips;
     this streams WITHIN one chip's sequence shard).  Non-tiling lengths
-    are block-padded (padded keys masked in-kernel); only cross-length
-    causal falls back to the einsum path.
+    are block-padded (padded keys masked in-kernel).  Causal attention
+    of a sequence over itself is :func:`causal_attention` (the kernel a
+    latent block's prefill from position zero runs); cross-length
+    causal, and a causal sequence whose K and V outgrow that kernel's
+    share of VMEM, fall back to the einsum path.
     """
     import jax
     import jax.numpy as jnp
@@ -315,9 +303,17 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 128, block_k: 
 
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    if causal and sq != sk:
-        # cross-length causal has no absolute-position convention here
-        return plain_attention(q, k, v, causal=causal)
+    if causal:
+        fits = _causal_kv_bytes(sq, d, d, q.dtype) <= _CAUSAL_KV_VMEM_BYTES
+        if sq != sk or not fits:
+            # cross-length causal has no absolute-position convention
+            # here; the causal kernel keeps a head's K and V whole in
+            # VMEM, and a sequence too long for that has the einsum form
+            return plain_attention(q, k, v, causal=causal)
+        return causal_attention(
+            q, k, v, 1.0 / float(np.sqrt(d)), block_q=block_q,
+            # (its query block holds whole key blocks)
+            block_k=block_k if block_q % block_k == 0 else block_q)
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     # non-tiling lengths (e.g. ViT's 197 tokens) pad up to the block
@@ -346,8 +342,8 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 128, block_k: 
 
     qf, kf, vf = fold(q), fold(k), fold(v)
     kernel = functools.partial(
-        _flash_kernel, block_q=block_q, block_k=block_k, n_kv=n_kv,
-        causal=causal, scale=1.0 / float(np.sqrt(d)), valid_k=valid_k,
+        _flash_kernel, block_k=block_k, n_kv=n_kv,
+        scale=1.0 / float(np.sqrt(d)), valid_k=valid_k,
     )
     out = pl.pallas_call(
         kernel,
@@ -368,6 +364,206 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 128, block_k: 
     )(qf, kf, vf)
     out = out.reshape(b, h, sq_p, d).transpose(0, 2, 1, 3)
     return out[:, :sq] if pad_q else out
+
+
+# ---------------------------------------------------------------------------
+# causal attention of a segment over itself (a prefill from position 0)
+# ---------------------------------------------------------------------------
+
+# Queries a grid step holds and keys one step of its loop scores: the
+# v5e sweep's best or within its noise at every cell's shape (tools/
+# profile_prefill_attention.py, PERF.md §5: a GPT-2 b1024_k4 layer 1.20
+# ms at 128 x 128, 0.52 at 256 x 256, 0.36 at 512 x 512)
+CAUSAL_BLOCK_Q = 512
+CAUSAL_BLOCK_K = 512
+# the most VMEM a head's resident K and V (double-buffered) may take of
+# a core's 128 MiB
+_CAUSAL_KV_VMEM_BYTES = 48 * 1024 * 1024
+
+
+def _causal_kv_bytes(seg_len: int, d_qk: int, d_v: int, dtype) -> int:
+    """VMEM a head's whole K and V take, twice (the pipeline fetches the
+    next head's while this one's are read)."""
+    return 2 * (_padded_block_bytes((seg_len, d_qk), dtype)
+                + _padded_block_bytes((seg_len, d_v), dtype))
+
+
+def prefill_attention_impl(seg_len: int, d_qk: int, d_v: int, dtype,
+                           table_width: int, kernel_lane: bool) -> str:
+    """``"fused"`` or ``"xla"`` for the latent block's attention of a
+    prefill segment of ``seg_len`` positions whose read table is
+    ``table_width`` pages wide: a pure function of what a trace can see.
+    The fused kernel (:func:`causal_attention`) where the segment starts
+    at position zero (a table of no width: there is no cache to read),
+    the engine's kernel lane serves (``kernel_lane``: the LM handed the
+    block the whole pool, ``models/paged.py
+    paged_kernel_static_eligible`` — a TPU or the forced interpreter, no
+    mesh, which GSPMD cannot partition the custom call over, and
+    ``SELDON_TPU_PAGED_KERNEL`` not "0"), the operands are bfloat16, the
+    segment is at least one query block long and a head's K and V fit
+    the kernel's VMEM; XLA's ``naive_attention`` everywhere else.  The
+    multi-head block does not ask: at its cells' shapes the v5e sweep
+    reads XLA over the segment alone as fast (``models/paged.py
+    _segment_attention`` has the numbers)."""
+    import jax.numpy as jnp
+
+    if table_width or not kernel_lane or jnp.dtype(dtype) != jnp.bfloat16:
+        return "xla"
+    fits = _causal_kv_bytes(seg_len, d_qk, d_v, dtype) <= _CAUSAL_KV_VMEM_BYTES
+    return "fused" if seg_len >= CAUSAL_BLOCK_Q and fits else "xla"
+
+
+def _lanes(x, width: int):
+    """A lane-replicated ``(rows, 128)`` statistic at ``width`` lanes."""
+    import jax.numpy as jnp
+
+    if width <= 128:
+        return x[:, :width]
+    return jnp.tile(x, (1, -(-width // 128)))[:, :width]
+
+
+def _causal_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                   block_q: int, block_k: int, scale: float):
+    """Grid cell (batch*head, q-block).  The head's K and V rest WHOLE
+    in VMEM: their block index is the head alone, so the pipeline
+    fetches them once a head — each key from HBM exactly once, none
+    again for a later query block — and the loop below walks the key
+    blocks a query block can see: ``qi * block_q / block_k`` of them
+    wholly under the diagonal (no mask is built for those) and the
+    ``block_q / block_k`` the diagonal crosses.  Blocks above it are
+    neither fetched again nor computed.
+
+    The operands go into the MXU in their own type (bfloat16 in a
+    prefill) with float32 accumulation; scores, running max, sum and
+    ``exp`` are float32; the weights are cast to V's type for ``p @ v``
+    (as the XLA form casts its softmax).  The running max and sum are
+    kept 128 lanes wide, every lane the row's value: a ``(rows, 1)``
+    statistic costs a relayout at every use (0.60 against 0.36 ms a
+    GPT-2 ``b1024_k4`` layer, my chip run, PR 33).  Every row sees key 0
+    in the first block the loop takes, so no running max is -inf after
+    it and no ``exp(-inf - -inf)`` arises; rows past a prompt's true
+    length (a bucket's padding) are computed like any other and read by
+    nobody."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    qi = pl.program_id(1)
+    q = q_ref[0]                                   # (block_q, d_qk)
+    sub = block_q // block_k
+    d_v = acc_ref.shape[-1]
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def step(j, masked: bool):
+        start = pl.multiple_of(j * block_k, block_k)
+        k = k_ref[0, pl.ds(start, block_k), :]     # (block_k, d_qk)
+        v = v_ref[0, pl.ds(start, block_k), :]     # (block_k, d_v)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        if masked:
+            q_at = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0)
+            k_at = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(k_at <= q_at, s, -jnp.inf)
+        m = m_ref[...]                             # (block_q, 128)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1)[:, None])
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - _lanes(m_new, block_k))
+        m_ref[...] = m_new
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1)[:, None]
+        acc_ref[...] = _lanes(alpha, d_v) * acc_ref[...] + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+    first = qi * sub
+
+    def under(j, carry):
+        step(j, masked=False)
+        return carry
+
+    jax.lax.fori_loop(0, first, under, 0)
+    for i in range(sub):
+        step(first + i, masked=True)
+    o_ref[0] = (acc_ref[...] / _lanes(l_ref[...], d_v)).astype(o_ref.dtype)
+
+
+def causal_attention(q, k, v, scale: float, *, block_q: int = None,
+                     block_k: int = None):
+    """Causal softmax attention of a segment over itself in ONE kernel:
+    ``q`` ``(B, L, h, d_qk)``, ``k`` ``(B, L, h, d_qk)``, ``v`` ``(B, L,
+    h, d_v)`` -> ``(B, L, h, d_v)`` in q's type.  Row ``i`` attends keys
+    ``0..i`` with weights ``softmax(scale * q_i . k_j)``; no score
+    matrix reaches HBM.  ``d_qk`` and ``d_v`` may differ (latent
+    attention's 192 against 128).  A length that is no multiple of the
+    query block is padded to one (pad keys lie after every real row, so
+    causality hides them) and the pad rows cut off.
+
+    The kernel's call is a ``pallas_call`` whose output is ``(B * h, L,
+    d_v)``: three dims, which is how the benchmark's readers tell it
+    from the grouped expert matmuls (two) — see
+    ``benchmarks/layer_metrics/moe_work.py``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, seg, h, d_qk = q.shape
+    d_v = v.shape[-1]
+    block_q = block_q or CAUSAL_BLOCK_Q
+    block_k = min(block_k or CAUSAL_BLOCK_K, block_q)
+    if block_q % block_k:
+        raise ValueError(
+            f"causal_attention: block_q {block_q} must be a multiple of "
+            f"block_k {block_k}")
+    if _causal_kv_bytes(seg, d_qk, d_v, q.dtype) > _CAUSAL_KV_VMEM_BYTES:
+        raise ValueError(
+            f"causal_attention: a head's K and V of {seg} positions do not "
+            f"fit the {_CAUSAL_KV_VMEM_BYTES >> 20} MiB they may take of VMEM")
+    if block_q > seg:
+        # one query block, cut to the segment; its key blocks stay
+        # where they still divide it
+        block_q = -(-seg // 8) * 8
+        block_k = block_k if block_q % block_k == 0 else block_q
+    pad = (-seg) % block_q
+    if pad:
+        q, k, v = (jnp.pad(x, [(0, 0), (0, pad), (0, 0), (0, 0)])
+                   for x in (q, k, v))
+    seg_p = seg + pad
+
+    # (B, L, h, d) -> (B*h, L, d): one head's rows contiguous
+    def fold(x):
+        return x.transpose(0, 2, 1, 3).reshape(b * h, seg_p, x.shape[-1])
+
+    need = (_causal_kv_bytes(seg_p, d_qk, d_v, q.dtype)
+            + 8 * block_q * max(block_k, 128) * 4
+            + 4 * _padded_block_bytes((block_q, max(d_qk, d_v)), np.float32))
+    out = pl.pallas_call(
+        functools.partial(_causal_kernel, block_q=block_q, block_k=block_k,
+                          scale=float(scale)),
+        grid=(b * h, seg_p // block_q),
+        in_specs=[
+            pl.BlockSpec((1, block_q, d_qk), lambda i, qi: (i, qi, 0)),
+            pl.BlockSpec((1, seg_p, d_qk), lambda i, qi: (i, 0, 0)),
+            pl.BlockSpec((1, seg_p, d_v), lambda i, qi: (i, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, block_q, d_v), lambda i, qi: (i, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b * h, seg_p, d_v), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=int(max(need, _VMEM_LIMIT_BYTES)),
+        ),
+        name="prefill_causal_attention",
+        interpret=interpret_mode(),
+    )(fold(q), fold(k), fold(v))
+    out = out.reshape(b, h, seg_p, d_v).transpose(0, 2, 1, 3)
+    return out[:, :seg] if pad else out
 
 
 def flash_attn_fn(block_q: int = 128, block_k: int = 128):

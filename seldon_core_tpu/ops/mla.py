@@ -58,7 +58,7 @@ def merge(*states):
 
 
 def naive_attention(q_nope, q_rope, ctx, ctx_len, seg, w_uk, w_uv,
-                    scale: float, dtype):
+                    scale: float, dtype, fused: bool = False):
     """Causal attention of a segment over its cached prefix and itself,
     K and V made per head from the latent rows.
 
@@ -67,7 +67,10 @@ def naive_attention(q_nope, q_rope, ctx, ctx_len, seg, w_uk, w_uv,
     ``(B,)`` are the prefix, or None; ``seg`` ``(B, L, W)`` the
     segment's own rows; ``w_uk`` ``(h, rank, n)``, ``w_uv`` ``(h, rank,
     v)``.  Returns ``(B, L, h, v)`` in ``dtype``.  Scores and softmax in
-    float32, :data:`QUERY_BLOCK` queries at a time."""
+    float32: in XLA :data:`QUERY_BLOCK` queries at a time, or — a
+    segment with no prefix where the caller's rule says ``fused``
+    (``ops/kernels.py prefill_attention_impl``) — in the fused causal
+    kernel, on ``k = [k_nope ; k_rope]`` made a head as below."""
     import jax
     import jax.numpy as jnp
 
@@ -82,6 +85,15 @@ def naive_attention(q_nope, q_rope, ctx, ctx_len, seg, w_uk, w_uv,
                         preferred_element_type=jnp.float32).astype(dtype)
     v = jnp.einsum("bcr,hrv->bchv", c_kv, w_uv.astype(dtype),
                    preferred_element_type=jnp.float32).astype(dtype)
+    if ctx is None and fused:
+        from seldon_core_tpu.ops.kernels import causal_attention
+
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(
+                k_rope[:, :, None, :], k_nope.shape[:3] + k_rope.shape[-1:])],
+            axis=-1)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        return causal_attention(q, k, v, scale).astype(dtype)
     key_at = jnp.arange(rows.shape[1])
     in_prefix = key_at[None, :] < ctx_len[:, None]             # (B, keys)
 

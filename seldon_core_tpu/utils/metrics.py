@@ -296,6 +296,11 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
                               "positions the prefill programs computed: "
                               "each call pays its group rounded up to a "
                               "power of two times its prompt bucket"),
+    "prefill_fused_positions": ("counter",
+                                "seldon_tpu_engine_prefill_fused_positions_total",
+                                "of those positions, the ones of from-zero "
+                                "prefill calls whose attention ran in the "
+                                "fused causal kernel"),
     "decode_kv_tokens": ("counter",
                          "seldon_tpu_engine_decode_kv_tokens_total",
                          "cached tokens attended, summed over every "

@@ -407,16 +407,22 @@ def test_the_initialiser_follows_the_spec_not_a_name(monkeypatch, which):
 # lane's two ``chunk_*`` hashes were taken again on its tree; its two
 # ``prefill_*`` hashes and all four of the ``gather`` lane are still
 # 89e949e's — only the kernel moved.
+# PR 33 changed the from-zero prefill on purpose (a read table of no
+# width: the segment attends over itself alone, no gather of masked
+# pages): the two ``prefill_b16_k2`` hashes, one a lane, were taken
+# again on its tree.  The other six — the cached-suffix prefill and both
+# chunk programs of both lanes — are what they were: those programs keep
+# a table with width and lower byte-identically.
 GPT2_CFG = dict(vocab_size=64, d_model=32, num_layers=2, num_heads=2, max_len=64)
 PARENT_SHA = {
     "kernel": {
-        "prefill_b16_k2": "5ef734ce28371fe7913c64f3b37b47ff3b9f1a5a6500f0adc5a6d0746d72528f",
+        "prefill_b16_k2": "0dce4d1e07c0695af68f1c06795e6c1796ebdf02f8707d76af0eb6a0c251b3d6",
         "prefill_cached_b16_k2_r2": "b02869115cb72ef159f121dc1fef1f37fd9c3baf2fc3fb6a96365fa9929ec1c6",
         "chunk_s2_4x4": "04ceedbf420cb4f47f3a4d64a7d82cd5741065e466be77fad9513d10476cd3b2",
         "chunk_s2_2x2_2x4": "1569aa08b7c74bbfb2bea2e50af781f9961c2c6b0923720ee05518d5abddef90",
     },
     "gather": {
-        "prefill_b16_k2": "f9ae2fa113df6fbf845f2983a405fc1c6a04b840862649813190b054415e4e77",
+        "prefill_b16_k2": "37061f9681fd61e11bd4b65d460ffbcf901e4fc4a6ac56426ab8bffe61c38020",
         "prefill_cached_b16_k2_r2": "13a93432b2bb62e12bf24b1bdf64f99dd40139efcdbe702a881ef248241383dd",
         "chunk_s2_4x4": "54dcd2ecee6971a385b2d6b0d32989a2d7d60133de79e20e9530e7d43e334079",
         "chunk_s2_2x2_2x4": "6ee6f04cc30b97e9a45f83aea471619782da1b0cc473b9a98abbd3d58d224a30",

@@ -346,3 +346,35 @@ def test_shortcut_experts_compile_with_rows_of_a_pass(one_chip, mosaic, backend,
                           min(cap, moe.stream_segment_rows(d)), d, f)
     assert f"[{rows * k},{d}]" not in text  # never all the picks' rows
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * rows * d * 4
+
+
+# (cell, k, bucket, heads, d_qk, d_v): the five cells' largest from-zero
+# prefill groups.  The latent cells' run the fused causal kernel; the
+# multi-head cells' stay on XLA, which ties with it there on the chip
+# (PERF.md §5) — Mosaic takes them all the same
+PREFILL_SHAPES = [
+    ("gpt2-doc", 4, 1024, 20, 64, 64), ("gpt2-chat", 4, 512, 20, 64, 64),
+    ("olmoe", 4, 512, 16, 128, 128),
+    ("gigachat", 1, 2048, 64, 192, 192), ("gigachat", 2, 1024, 64, 192, 192),
+    ("longcat", 2, 1024, 64, 192, 128), ("longcat", 4, 512, 64, 192, 128),
+]
+
+
+@pytest.mark.parametrize("cell,k,bucket,heads,d_qk,d_v", PREFILL_SHAPES, ids=[
+    f"{c}_b{b}_k{k}" for c, k, b, _h, _q, _v in PREFILL_SHAPES])
+def test_prefill_causal_kernel_compiles_at_the_cells_shapes(
+        one_chip, mosaic, cell, k, bucket, heads, d_qk, d_v):
+    """PR 33: a head's whole K and V resident in VMEM, a dynamic loop
+    over the key blocks under the diagonal, 64- and 192-wide heads (half
+    a lane tile; one and a half), d_qk != d_v."""
+    def spec(d):
+        return jax.ShapeDtypeStruct((k, bucket, heads, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, key, v: kernels.causal_attention(q, key, v, d_qk ** -0.5)
+    ).lower(spec(d_qk), spec(d_qk), spec(d_v)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "prefill_causal_attention" in text
+    assert kernels.prefill_attention_impl(
+        bucket, d_qk, d_v, jnp.bfloat16, 0, True) == "fused"

@@ -472,7 +472,9 @@ class TestDecodeLane:
                 "expert_matmul",
                 # PR 32: the pool's leading axis (attention sub-layers)
                 # and the cap on a prefill call's padded positions
-                "cache_layers", "prefill_positions_max"}
+                "cache_layers", "prefill_positions_max",
+                # PR 33: what a from-zero prefill attends with
+                "prefill_attention"}
             assert rep["cache_layers"] == LAYERS
             assert (rep["attention"], rep["cache_width"], rep["experts_held"]) == (
                 "mha", cfg["d_model"], 0)
