@@ -250,6 +250,21 @@ def components_health(gateway) -> Dict[str, Dict[str, object]]:
     return out
 
 
+def _pull(it, written: Optional[float], sentinel):
+    """The next event of a component's blocking ``predict_stream``
+    generator (on an executor thread), ``sentinel`` at its end.
+    ``written``: the ``time.monotonic()`` at which the transport's
+    write of the event before returned — sent into the generator,
+    which counts the event's way out to there
+    (``PagedEngine.stream_events``); None on the first pull."""
+    try:
+        if written is not None and hasattr(it, "send"):
+            return it.send(written)
+        return next(it)
+    except StopIteration:
+        return sentinel
+
+
 def _http_status(out: InternalMessage) -> int:
     """HTTP code for a gateway response: FAILURE statuses surface their
     code (clamped to a valid HTTP error range), everything else is 200.
@@ -448,7 +463,8 @@ def build_gateway_app(gateway: Gateway, auth=None) -> web.Application:
         try:
             arr = msg.array()
             it = gen_fn(arr, [], meta=meta)
-            first = await loop.run_in_executor(dispatch_pool(), next, it, sentinel)
+            first = await loop.run_in_executor(
+                dispatch_pool(), _pull, it, None, sentinel)
         except Exception as e:  # noqa: BLE001
             return _error_response(e)
         resp = web.StreamResponse(headers={
@@ -466,8 +482,12 @@ def build_gateway_app(gateway: Gateway, auth=None) -> web.Application:
                     break
                 payload = _json.dumps({"tokens": _np.asarray(chunk).tolist()})
                 await resp.write(f"data: {payload}\n\n".encode())
+                # the write has returned: the event's way out ends here,
+                # and the stamp rides the next pull back to the engine
+                written = _mono_time.monotonic()
                 try:
-                    chunk = await loop.run_in_executor(None, next, it, sentinel)
+                    chunk = await loop.run_in_executor(
+                        None, _pull, it, written, sentinel)
                 except MicroserviceError as e:
                     await resp.write(
                         (f"event: error\ndata: {_json.dumps(e.to_status())}\n\n").encode()
@@ -985,10 +1005,12 @@ def add_seldon_service(server: grpc.aio.Server, gateway: Gateway, auth=None) -> 
         it = gen_fn(msg.array(), [], meta=meta)
         sentinel = object()
         pool = dispatch_pool()  # the first pull only: the wait for a slot
+        written = None
         try:
             while True:
                 try:
-                    chunk = await loop.run_in_executor(pool, next, it, sentinel)
+                    chunk = await loop.run_in_executor(
+                        pool, _pull, it, written, sentinel)
                     pool = None
                 except MicroserviceError as e:
                     await context.abort(
@@ -1004,6 +1026,8 @@ def add_seldon_service(server: grpc.aio.Server, gateway: Gateway, auth=None) -> 
                 )
                 out.meta.puid = msg.meta.puid
                 yield out.to_proto()
+                # the yield has been taken: the SSE twin's stamp
+                written = _mono_time.monotonic()
         finally:
             # client cancel/disconnect: closing the generator triggers
             # its finally-clause, which cancels the engine stream

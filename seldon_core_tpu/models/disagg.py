@@ -646,11 +646,7 @@ class DisaggregatedLM(StreamingLM):
             raise job.error
         stream = job.stream
         try:
-            while True:
-                got = stream.token_queue.get()
-                if got is None:
-                    break
-                yield np.asarray(got, np.int32)
+            yield from self.engine.stream_events(stream)
             if stream.error:
                 raise stream.error
         finally:

@@ -1802,6 +1802,8 @@ class _Stream:
         "cost_page_s", "cost_t", "cost_prefill_tokens",
         "cost_decode_tokens", "cost_preempts", "cost_restores",
         "cost_closed", "tier_promote", "inflight",
+        "m_ingress", "m_submit", "m_admit", "m_first", "prefill_open",
+        "push_stamps",
     )
 
     def __init__(self, req_id, prompt, max_new, temperature, top_k, eos_id, seed):
@@ -1880,6 +1882,26 @@ class _Stream:
         # wall time the result was delivered (_finish_locked): closes
         # the queue_wait / prefill / decode request decomposition
         self.t_finish = 0.0
+        # the same lifecycle on ONE clock, ``time.monotonic()``: every
+        # duration the engine counts is a difference of these (the t_*
+        # above are wall-clock and stay a span's START for export).
+        # m_ingress: the handler's entry stamp (submit(t_ingress=)),
+        # else the submit; m_admit: the first prefill slice (where
+        # queue_wait_s ends); m_first: the harvest whose readback held
+        # the stream's first token.  An eviction restarts them with the
+        # t_* (the re-run is a request of its own to the sums)
+        self.m_ingress = 0.0
+        self.m_submit = 0.0
+        self.m_admit = 0.0
+        self.m_first = 0.0
+        # the prompt's last prefill call, enqueued and not yet proved
+        # run: (wall start, monotonic start, span tags) until the first
+        # readback that can only return after it (_close_prefill)
+        self.prefill_open: Optional[Tuple[float, float, Dict[str, Any]]] = None
+        # token streaming: the monotonic stamp of each event queued and
+        # not yet picked up, oldest first (appended by the engine
+        # thread before the event, popped by the consumer after it)
+        self.push_stamps: Deque[float] = deque()
         self.queue_depth_at_submit = 0
         # SLO lifecycle (r10): admission/shedding order (higher wins),
         # absolute time.monotonic() expiry (None = no deadline), and
@@ -1934,7 +1956,7 @@ class _Wave:
         "lanes", "active_n", "puids", "trace_id", "stalled",
         "lens0", "steps", "buckets", "step_slots",
         "toks", "emitted", "finite", "moe", "has_moe",
-        "admitted_n", "prefill_tokens", "prefill_wall",
+        "admitted_n", "prefill_tokens",
     )
 
     def __init__(self, **kw):
@@ -2010,6 +2032,12 @@ class _WaveSeam:
       the phase it was spent in (``phase_s``; ``between`` is the time
       between two steps).  A wave that leaves no work behind closes the
       gap uncounted.
+    * **The engine thread's time, always.**  Every phase's wall time is
+      booked where it ends, gap or no gap (``phase_walls``): one clock
+      read a phase.  ``wait`` is the thread blocked in a readback — the
+      device sets the pace; every other phase but ``between`` (waiting
+      for a request) is the host's work, and once ``host_work_s``
+      nears ``host_work_s + host_wait_s`` the host sets it.
     * **The profile window.**  ``arm`` asks for ``seconds`` of
       ``jax.profiler`` trace under ``SELDON_TPU_PROFILE_DIR``;
       ``boundary`` (every wave boundary, on the engine thread) starts it,
@@ -2029,6 +2057,11 @@ class _WaveSeam:
         self._monotonic = _time.monotonic
         self.wave = 0
         self.phase_s: Dict[str, float] = {p: 0.0 for p in self.PHASES}
+        # every phase's wall seconds so far, the phase now open and
+        # where it began: ONE tuple, replaced whole where a phase ends,
+        # so that another thread reads a consistent three (phase_walls)
+        self._walls: Tuple[Dict[str, float], str, float] = (
+            {p: 0.0 for p in self.PHASES}, "between", self._clock())
         # open annotations, outermost first: the step, its current
         # phase, a prefill group nested in that — (phase, annotation)
         self._open: List[Tuple[str, Any]] = []
@@ -2048,10 +2081,14 @@ class _WaveSeam:
     # ---- phases --------------------------------------------------------
 
     def _account(self, phase: str) -> None:
-        """Book the open gap's time since the last mark to the phase
-        that ends here, and move on to ``phase``."""
+        """Book the wall time, and the open gap's time since the last
+        mark, to the phase that ends here, and move on to ``phase``."""
+        now = self._clock()
+        walls, ending, since = self._walls
+        walls = dict(walls)
+        walls[ending] += now - since
+        self._walls = (walls, phase, now)
         if self._gap_open:
-            now = self._clock()
             self.phase_s[self._phase] += now - self._mark
             self._mark = now
         self._phase = phase
@@ -2131,6 +2168,15 @@ class _WaveSeam:
     def host_gap_s(self) -> float:
         return sum(self.phase_s.values())
 
+    def phase_walls(self) -> Dict[str, float]:
+        """Wall seconds of the engine thread by phase, the open phase's
+        time so far included: they sum to the time since the seam was
+        made, whichever thread asks and whenever."""
+        walls, phase, since = self._walls
+        walls = dict(walls)
+        walls[phase] += self._clock() - since
+        return walls
+
     # ---- the profile window --------------------------------------------
 
     def arm(self, seconds: float) -> Dict[str, Any]:
@@ -2203,6 +2249,33 @@ class _WaveSeam:
             update = dict(state="failed", error=f"{type(exc).__name__}: {exc}")
         with self._profile_lock:
             prof.update(update)
+
+
+class _DeliveryTally:
+    """A token event's way out, summed where the consumers' threads
+    stand: from ``_stream_push``'s stamp to the return of the
+    transport's write, how many events, and how many of them found
+    their stream's NEXT event queued already when they were picked up
+    (the consumer is a whole wave behind).  Its own lock: the engine
+    thread never takes it, ``engine_stats()`` reads under it."""
+
+    __slots__ = ("_lock", "lag_s", "events", "behind")
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.lag_s = 0.0
+        self.events = 0
+        self.behind = 0
+
+    def add(self, lag_s: float, behind: bool) -> None:
+        with self._lock:
+            self.lag_s += max(0.0, lag_s)
+            self.events += 1
+            self.behind += int(behind)
+
+    def read(self) -> Tuple[float, int, int]:
+        with self._lock:
+            return self.lag_s, self.events, self.behind
 
 
 class PagedEngine:
@@ -2889,11 +2962,25 @@ class PagedEngine:
                           "adapter_loads": 0, "adapter_evictions": 0,
                           "adapter_hits": 0, "adapter_misses": 0,
                           "multi_adapter_chunks": 0,
-                          # wall seconds inside device calls + readback,
-                          # split by phase: decode-rate observability
-                          # (tokens / chunk_wall_s) independent of
-                          # admission cost
-                          "chunk_wall_s": 0.0, "prefill_wall_s": 0.0,
+                          # a request's way on the engine's own clock
+                          # (monotonic stamps; closed at the harvest
+                          # whose readback proves the programs ran):
+                          # ingress stamp, else submit, -> the harvest
+                          # that held the stream's first token; the
+                          # stream's admission (its first prefill
+                          # slice) -> that harvest: its own wave,
+                          # prefill and chunk; first token -> finish
+                          # and the tokens after the first, summed as
+                          # a stream finishes
+                          "ttft_s": 0.0, "ttfts": 0,
+                          "first_token_s": 0.0, "first_tokens": 0,
+                          "decode_stream_s": 0.0,
+                          "decode_stream_tokens": 0,
+                          # wall seconds of decode waves, each from its
+                          # chunk's enqueue or the readback before it
+                          # to its own readback: the programs queued
+                          # in between ran in it, a wave's prefills too
+                          "chunk_wall_s": 0.0,
                           # per-request cost ledger (r20): totals accrued
                           # once per stream at termination (finish/fail/
                           # export/migrate-out), so the per-adapter split
@@ -2942,6 +3029,9 @@ class PagedEngine:
         import time as _time_mod
 
         self._cost_clock = _time_mod.monotonic
+        # the one clock of engine_stats()'s ``clock_s`` and of every
+        # duration counted from stamps (never injected)
+        self._monotonic = _time_mod.monotonic
         self._telemetry_enabled = _telemetry.telemetry_enabled()
 
         # ---- observability: flight recorder + profiler hook (r7) ----
@@ -3018,6 +3108,9 @@ class PagedEngine:
         self._seam = _WaveSeam(
             self, _knobs.raw("SELDON_TPU_PROFILE_DIR") or None
         )
+        # token events delivered: added to by stream_events() on the
+        # consumers' threads, current within a wave
+        self._deliveries = _DeliveryTally()
         # routed experts: cumulative assignments per (layer, expert),
         # and the prefill programs' histograms still on the device —
         # read back with the next chunk's tokens, never on their own
@@ -4092,16 +4185,17 @@ class PagedEngine:
         thread, so contextvar nesting cannot do it.  No-op (no tracer or
         untraced stream) costs one attribute read.
 
-        These spans are on the host's clock.  JAX returns from a
-        dispatch before the device finishes, so ``gen.prefill`` (ended
-        where ``_prefill_group`` returns, with no readback) times the
-        ENQUEUE on an accelerator and carries ``timed="enqueue"``; the
-        prefill's execution lands in the wave's chunk readback, i.e. in
-        ``gen.decode`` and ``chunk_wall_s`` (measured on the v5e, PERF.md
-        §6, PR 24).  Only a group that ends in a blocking readback (the
-        speculative engine's pending token, a KV export's logits) is
-        ``timed="device"``.  The device's own clock is the profile
-        window's: ``seldon.wave.*`` beside the programs' executions."""
+        A span's start is wall-clock (for export), its duration a
+        difference of ``time.monotonic()`` stamps.  JAX returns from a
+        dispatch before the device finishes, so no span ends where a
+        dispatch returns: ``gen.prefill`` runs from the enqueue of the
+        prompt's last prefill call to the first readback that can only
+        return once that call has run (``_close_prefill``: the harvest
+        that first carries the stream; for the speculative engine's
+        pending token or a KV export's logits, the group's own
+        readback), and ``gen.decode`` from there to the finish.  The
+        device's own clock is the profile window's: ``seldon.wave.*``
+        beside the programs' executions."""
         if not stream.trace_id:
             return
         from seldon_core_tpu.utils.tracing import record_span
@@ -4511,11 +4605,12 @@ class PagedEngine:
             # t_first_token - t_submit by the bench gate and the
             # profile tool, tracer installed or not
             stream.t_submit = _time.time()
+            stream.m_submit = stream.m_ingress = _time.monotonic()
             stream.queue_depth_at_submit = len(self._queue)
             if t_ingress is not None:
-                self._counters["ingress_wait_s"] += max(
-                    0.0, _time.monotonic() - float(t_ingress)
-                )
+                stream.m_ingress = min(float(t_ingress), stream.m_submit)
+                self._counters["ingress_wait_s"] += (
+                    stream.m_submit - stream.m_ingress)
                 self._counters["ingress_waits"] += 1
             # puid linkage is independent of tracing: wave records and
             # capture containers must join to the request even when no
@@ -5424,8 +5519,9 @@ class PagedEngine:
         """Monolithic prefill wave (chunk budget OFF — the historical
         path): every admitted stream's full uncached suffix runs in
         this one wave.  Returns ``(completed streams, prompt tokens
-        computed, wall seconds)`` — the same contract as the chunked
-        slice runner, so both step paths share one completion tail."""
+        computed, perf_counter at the first enqueue)`` — the same
+        contract as the chunked slice runner, so both step paths share
+        one completion tail."""
         return self._run_prefill_slices([
             (s, s.prefilled, len(s.prompt) - s.prefilled) for s in streams
         ])
@@ -5472,15 +5568,15 @@ class PagedEngine:
         cached-suffix program for everything mid-prompt: a chunk slice
         IS a suffix prefill whose "cached" prefix is the pages earlier
         slices already wrote.  Returns ``(completed streams, prompt
-        tokens computed, wall seconds)``; kv_export streams resolve
-        with their handoff payload instead of entering decode.
+        tokens computed, perf_counter as the first call was about to be
+        enqueued)``; kv_export streams resolve with their handoff
+        payload instead of entering decode.
 
-        The wall seconds (``prefill_wall_s``, the recorder's
-        ``prefill_wall_ms``) are host time around dispatches: on an
-        accelerator they read the enqueue, not the prefill programs'
-        execution, which the wave's chunk readback waits out
-        (``chunk_wall_s``).  No ``block_until_ready`` is added to make
-        them true: that would serialise the host behind the device."""
+        Nothing here times the programs: a dispatch returns before the
+        device has run it, so a prefill's seconds are closed where a
+        readback proves it ran — the harvest that holds the stream's
+        first token (``first_token_s``, ``gen.prefill``), or a
+        prefill-only wave's record."""
         if not slices:
             return [], 0, 0.0
         # KV tier (r22): staged demotions must gather before this
@@ -5488,25 +5584,27 @@ class PagedEngine:
         self._tier_flush()
         import time as _time
 
-        t_start = _time.perf_counter()
+        t_enqueue = _time.perf_counter()
         t_admit = _time.time()
+        m_admit = _time.monotonic()
         queue_wait, queue_waits = 0.0, 0
         for stream, start, _n in slices:
             if not stream.t_prefill_start:
                 stream.t_prefill_start = t_admit  # queue-wait term ends
-                if stream.t_submit:
+                stream.m_admit = m_admit
+                if stream.m_submit:
                     # counted for every stream, traced or not (an
                     # eviction restarts both stamps: a re-queue is a
                     # wait of its own)
-                    queue_wait += max(0.0, t_admit - stream.t_submit)
+                    queue_wait += max(0.0, m_admit - stream.m_submit)
                     queue_waits += 1
             # queue-wait is the irreducible tail term (§10a): one span
             # per stream, emitted on its FIRST slice
             if stream.trace_id and start == stream.cached_len:
                 self._gen_span(
                     stream, "gen.queued", stream.t_submit or t_admit,
-                    max(0.0, t_admit - stream.t_submit)
-                    if stream.t_submit else 0.0,
+                    max(0.0, m_admit - stream.m_submit)
+                    if stream.m_submit else 0.0,
                     slot=stream.slot,
                     queue_depth=stream.queue_depth_at_submit,
                 )
@@ -5539,12 +5637,10 @@ class PagedEngine:
                     completed.extend(self._prefill_group(
                         bucket, joined[lo:lo + most], use_cache=use_cache))
                     calls += 1
-        wall = _time.perf_counter() - t_start
         with self._lock:
             self._counters["queue_wait_s"] += queue_wait
             self._counters["queue_waits"] += queue_waits
             if calls:
-                self._counters["prefill_wall_s"] += wall
                 self._counters["prefill_tokens"] += tokens
                 self._counters["prefill_chunks"] += calls
             if self._prefix_cache_enabled:
@@ -5559,7 +5655,7 @@ class PagedEngine:
         if exports:
             self._export_streams(exports)
             completed = [s for s in completed if not s.kv_export]
-        return completed, tokens, wall
+        return completed, tokens, t_enqueue
 
     def _prefill_group(
         self, bucket: int, group: List[Tuple[_Stream, int, int]],
@@ -5612,7 +5708,7 @@ class PagedEngine:
         import time as _time
 
         jnp = self._jnp
-        t_group = _time.time()
+        t_group, m_group = _time.time(), _time.monotonic()
         ps = self.page_size
         # multi-LoRA trailing args: per-row adapter slots (pad rows 0 —
         # the zero adapter, deltas exactly 0.0 into the trash page)
@@ -5730,29 +5826,56 @@ class PagedEngine:
                 stream.kv_payload = {
                     "last_logits": last_np[j].astype(np.float32, copy=False)
                 }
-        t_done = _time.time()
-        # what the span's end waited for: a readback above, or nothing
-        timed = (
-            "device" if self.speculative is not None or exports
-            else "enqueue"
-        )
+        # the prompt's last call is enqueued: its seconds close where a
+        # readback proves it ran — here, if one was made above (the
+        # speculative engine's pending token, a KV export's logits),
+        # else at the harvest that first carries the stream
+        proved = self.speculative is not None or bool(exports)
+        t_done, m_done = _time.time(), _time.monotonic()
         out: List[_Stream] = []
         for _i, stream in finals:
-            stream.t_decode_start = t_done
-            if stream.trace_id:
-                # the group prefills in ONE device call, so every
-                # member's span carries the group wall (tagged with
-                # the group size so a reader knows it is shared)
-                self._gen_span(
-                    stream, "gen.prefill", t_group, t_done - t_group,
-                    slot=stream.slot, bucket=bucket,
-                    prompt_len=len(stream.prompt),
-                    cached_tokens=stream.cached_len,
-                    pages_held=len(stream.pages),
-                    group_size=len(group), timed=timed,
-                )
+            # the group prefills in ONE device call, so every member's
+            # span carries the group wall (tagged with the group size so
+            # a reader knows it is shared)
+            stream.prefill_open = (t_group, m_group, dict(
+                slot=stream.slot, bucket=bucket,
+                prompt_len=len(stream.prompt),
+                cached_tokens=stream.cached_len,
+                pages_held=len(stream.pages), group_size=len(group),
+            ))
+            if proved:
+                self._close_prefill(stream, t_done, m_done)
             out.append(stream)
         return out
+
+    def _close_prefill(self, stream: _Stream, t_now: float, m_now: float,
+                       locked: bool = False) -> None:
+        """A readback that could only return once ``stream``'s last
+        prefill call had run has returned at ``t_now`` / ``m_now``
+        (wall / monotonic): ``gen.prefill`` ends and ``gen.decode``
+        begins here.  ``locked``: the caller holds ``_lock`` (the span
+        is queued for ``_flush_spans``)."""
+        if stream.prefill_open is None:
+            return
+        t_start, m_start, tags = stream.prefill_open
+        stream.prefill_open = None
+        stream.t_decode_start = t_now
+        emit = self._gen_span_deferred if locked else self._gen_span
+        emit(stream, "gen.prefill", t_start, max(0.0, m_now - m_start), **tags)
+
+    def _first_token_locked(self, stream: _Stream, t_now: float,
+                            m_now: float) -> None:
+        """The readback that returned at ``t_now`` / ``m_now`` held
+        ``stream``'s first token: stamp it, and count the request's way
+        to it — from its ingress stamp (``ttft_s``) and from its
+        admission (``first_token_s``).  Caller holds ``_lock``."""
+        stream.t_first_token = t_now
+        stream.m_first = m_now
+        self._counters["ttft_s"] += m_now - stream.m_ingress
+        self._counters["ttfts"] += 1
+        if stream.m_admit:
+            self._counters["first_token_s"] += m_now - stream.m_admit
+            self._counters["first_tokens"] += 1
 
     # ---- disaggregated prefill/decode: KV-page handoff (r15) --------------
 
@@ -5791,7 +5914,7 @@ class PagedEngine:
 
         jnp = self._jnp
         payload = stream.kv_import
-        t0 = _time.time()
+        t0, m0 = _time.time(), _time.monotonic()
         plen = len(stream.prompt)
         # migration imports (r17) also carry the decoded-token pages:
         # the peer resumes at the exact next token, so the scatter
@@ -5845,7 +5968,6 @@ class PagedEngine:
             stream.tokens = [int(t) for t in np.asarray(mig_tokens).reshape(-1)]
         if migration:
             stream.streamed = int(payload.get("streamed") or 0)
-        stream.t_decode_start = _time.time()
         with self._lock:
             if extra:
                 # decode resumes mid-sequence: lengths must count the
@@ -5854,14 +5976,13 @@ class PagedEngine:
             stream.kv_import = None  # payload consumed: free the host copy
             stream.kv_imported = True
             self._counters["migrated_in" if migration else "kv_imports"] += 1
-        if stream.trace_id:
-            self._gen_span(
-                stream, "gen.prefill", t0, stream.t_decode_start - t0,
-                slot=slot, bucket=0, prompt_len=plen,
-                cached_tokens=0, pages_held=len(stream.pages),
-                group_size=1, imported=True, migrated=migration,
-                timed="enqueue",
-            )
+        # the scatter is enqueued, not run: the span closes at the
+        # harvest that first carries the stream (_close_prefill)
+        stream.prefill_open = (t0, m0, dict(
+            slot=slot, bucket=0, prompt_len=plen, cached_tokens=0,
+            pages_held=len(stream.pages), group_size=1, imported=True,
+            migrated=migration,
+        ))
 
     # ---- hierarchical KV tier (r22) ---------------------------------------
 
@@ -6466,14 +6587,22 @@ class PagedEngine:
         self, prompt_len: int, max_new: int
     ) -> Optional[float]:
         """Predicted service seconds for one request from this engine's
-        own measured rates (cumulative wall / cumulative tokens —
+        own measured rates (cumulative seconds / cumulative tokens —
         stable after warmup, no tuning): the admission-pricing input
         disaggregated serving uses to fast-fail deadlines a request
         cannot meet BEFORE burning prefill on it.  ``None`` while the
-        engine is cold (nothing measured yet — admit unpriced)."""
+        engine is cold (nothing measured yet — admit unpriced).
+
+        Both rates are closed at readbacks, never where a dispatch
+        returns.  A prompt token costs ``first_token_s`` (streams'
+        admission -> the harvest that held their first token: the
+        prefill call AND the chunk it shared a wave with, each member
+        of a group charged the group's whole wave — what a request
+        waits, not its share of the device) over the prompt tokens
+        computed; a new token ``chunk_wall_s`` over the tokens read."""
         with self._lock:
             ptok = self._counters["prefill_tokens"]
-            pwall = self._counters["prefill_wall_s"]
+            pwall = self._counters["first_token_s"]
             dtok = self._counters["tokens"]
             dwall = self._counters["chunk_wall_s"]
         if ptok <= 0 or pwall <= 0 or dtok <= 0 or dwall <= 0:
@@ -6522,15 +6651,42 @@ class PagedEngine:
         new = toks[stream.streamed :]
         if new:
             stream.streamed += len(new)
+            stream.push_stamps.append(self._monotonic())
             q.put([int(t) for t in new])
+
+    def stream_events(self, stream: _Stream):
+        """The consumer's end of a ``stream_tokens`` stream: a generator
+        of int32 arrays, one per event ``_stream_push`` queued, until
+        the stream ends.  It counts each event's way out
+        (``deliver_lag_s`` / ``deliveries``): from the push's stamp to
+        the ``time.monotonic()`` the consumer SENDS back with its next
+        pull — stamped where its transport's write returned — or, for a
+        consumer that only iterates, to that pull itself; and
+        ``deliveries_behind``, the events whose stream already had its
+        next one queued when they were picked up.  On the consumer's
+        thread throughout: the engine thread only stamps."""
+        q, stamps, tally = stream.token_queue, stream.push_stamps, self._deliveries
+        while True:
+            got = q.get()
+            if got is None:
+                return
+            pushed = stamps.popleft()
+            behind = bool(stamps)
+            written = yield np.asarray(got, np.int32)
+            tally.add((written or self._monotonic()) - pushed, behind)
 
     def _finish_locked(self, stream: _Stream) -> None:
         import time as _time
 
         slot = stream.slot
         stream.t_finish = _time.time()
+        m_finish = _time.monotonic()
         toks = stream.tokens[: stream.max_new]
         emitted_n = len(toks)
+        stream.prefill_open = None  # never proved run: no span
+        if stream.m_first:
+            self._counters["decode_stream_s"] += m_finish - stream.m_first
+            self._counters["decode_stream_tokens"] += max(0, emitted_n - 1)
         eos = stream.eos_id
         if eos in toks:
             cut = toks.index(eos) + 1
@@ -6541,13 +6697,15 @@ class PagedEngine:
         if stream.token_queue is not None:
             stream.token_queue.put(None)  # end-of-stream
         if stream.trace_id:
-            import time as _time
-
-            now = _time.time()
+            now = stream.t_finish
             if stream.t_decode_start:
                 self._gen_span_deferred(
                     stream, "gen.decode", stream.t_decode_start,
-                    max(0.0, now - stream.t_decode_start),
+                    # from the readback that closed gen.prefill: the
+                    # first-token harvest, unless the prefill group
+                    # made one of its own
+                    (m_finish - stream.m_first) if stream.m_first
+                    else max(0.0, now - stream.t_decode_start),
                     slot=slot, tokens=emitted_n,
                 )
             finish_tags: Dict[str, Any] = dict(
@@ -6603,6 +6761,9 @@ class PagedEngine:
         # the decomposition blames served time on the queue-wait term
         # it exists to isolate
         stream.t_submit = now
+        stream.m_submit = stream.m_ingress = _time.monotonic()
+        stream.m_admit = stream.m_first = 0.0
+        stream.prefill_open = None
         stream.t_prefill_start = 0.0
         stream.t_decode_start = 0.0
         # the re-derived run re-emits its first token: a stale stamp
@@ -6900,10 +7061,31 @@ class PagedEngine:
             watchdog_trips = self._watchdog.trips
         else:
             health, health_code, watchdog_trips = "healthy", 0, 0
+        lag_s, deliveries, behind = self._deliveries.read()
         with self._lock:
             held_hits = self._moe_held_hits()
+            walls = self._seam.phase_walls()
             out = {
                 **self._counters,
+                # time.monotonic() as these counters were read: a
+                # snapshot carries its own clock, so two of them give a
+                # rate without the reader's
+                "clock_s": self._monotonic(),
+                # the engine thread's wall seconds at work (every phase
+                # of the seam but ``wait`` and ``between``) and blocked
+                # in a readback (``wait``: the device sets the pace);
+                # work / (work + wait) nearing 1 = the host sets it
+                "host_work_s": sum(
+                    v for k, v in walls.items()
+                    if k not in ("wait", "between")),
+                "host_wait_s": walls["wait"],
+                # token events out: seconds from _stream_push's stamp
+                # to the return of the transport's write, their count,
+                # and those that found their stream's next event queued
+                # already when picked up (stream_events())
+                "deliver_lag_s": lag_s,
+                "deliveries": deliveries,
+                "deliveries_behind": behind,
                 "active_slots": sum(s is not None for s in self._slots),
                 "queued_streams": len(self._queue),
                 # mapped pages only: LRU-cached pages are reclaimable
@@ -7032,8 +7214,10 @@ class PagedEngine:
             ):
                 out.pop(k, None)
         if detail:
-            # host_gap_s by the phase it was spent in
+            # host_gap_s by the phase it was spent in, and beside it
+            # the engine thread's whole wall time by phase
             out["phase_s"] = dict(self._seam.phase_s)
+            out["phase_wall_s"] = walls
             if moe_expert_hits is not None:
                 out["moe_expert_hits"] = moe_expert_hits
             if moe_zero_detail is not None:
@@ -7269,7 +7453,7 @@ class PagedEngine:
                 stream.event.set()
 
     def _record_prefill_wave(
-        self, *, wall_s: float, tokens: int, occupancy: int,
+        self, *, t_enqueue: float, tokens: int, occupancy: int,
         admissions: int, stalls: int, puids=(),
     ) -> None:
         """Record a wave that carried ONLY prefill work — budgeted
@@ -7280,12 +7464,23 @@ class PagedEngine:
 
         Its programs are enqueued by now, behind whatever is in flight;
         that wave is harvested first, so the records stay in the order
-        of their waves."""
+        of their waves.  Its wall is taken as a decode wave's is: from
+        its first enqueue (``t_enqueue``) or the readback before it to
+        a readback of its own — the pool as its last program leaves it,
+        waited for here, since no chunk follows whose tokens would be."""
+        import time as _time
+
         number = self._seam.wave
         if self._inflight:
-            self._drain_inflight("record")
+            self._drain_inflight("wait")
         else:
-            self._seam.enter("record")
+            self._seam.enter("wait")
+        self._jax.block_until_ready(self._kv_args())
+        self._seam.drained()
+        now = _time.perf_counter()
+        wall_s = now - max(t_enqueue, self._t_drained)
+        self._t_drained = now
+        self._seam.enter("record")
         with self._lock:
             if self._debug_invariants:
                 self._check_invariants_locked()
@@ -7298,7 +7493,6 @@ class PagedEngine:
             # wave actually carried, not just an anonymous ring slice
             "puids": list(puids),
             "wall_ms": round(wall_s * 1000.0, 3),
-            "prefill_wall_ms": round(wall_s * 1000.0, 3),
             "tp_degree": self.tp_degree,
             "dp_degree": self.dp_degree,
             "steps": 0,
@@ -7452,11 +7646,11 @@ class PagedEngine:
         self._tier_promote_ready()
         budget = self.chunk_token_budget
         wave_prefill_tokens = 0
-        wave_prefill_wall = 0.0
+        t_prefill = 0.0  # perf_counter at the wave's first prefill enqueue
         if not budget:
             # monolithic prefill (the historical wave shape): admitted
             # prompts prefill whole, then decode in this same wave
-            _done, wave_prefill_tokens, wave_prefill_wall = (
+            _done, wave_prefill_tokens, t_prefill = (
                 self._prefill_streams([s for s, _ in admitted])
             )
 
@@ -7484,7 +7678,7 @@ class PagedEngine:
             # window mix reads zero
             if wave_prefill_tokens:
                 self._record_prefill_wave(
-                    wall_s=wave_prefill_wall, tokens=wave_prefill_tokens,
+                    t_enqueue=t_prefill, tokens=wave_prefill_tokens,
                     occupancy=0, admissions=len(admitted), stalls=0,
                     puids=[s.puid for s, _ in admitted if s.puid],
                 )
@@ -7631,9 +7825,8 @@ class PagedEngine:
         # budget covers both, and streams completing here decode next
         # wave (their lanes stay masked in this chunk's done_in)
         if slices:
-            _done, ptok, pwall = self._run_prefill_slices(slices)
+            _done, ptok, t_prefill = self._run_prefill_slices(slices)
             wave_prefill_tokens += ptok
-            wave_prefill_wall += pwall
         if not runnable_now:
             # prefill-only wave: no decode lane could run, but slices
             # made progress (or every decoder awaits pages a chunking
@@ -7641,7 +7834,7 @@ class PagedEngine:
             # chunk mix stays observable
             if wave_prefill_tokens:
                 self._record_prefill_wave(
-                    wall_s=wave_prefill_wall, tokens=wave_prefill_tokens,
+                    t_enqueue=t_prefill, tokens=wave_prefill_tokens,
                     occupancy=len(active), admissions=len(admitted),
                     stalls=int(stalled.sum()),
                     puids=[s.puid for s in active if s.puid],
@@ -7727,7 +7920,6 @@ class PagedEngine:
                 moe=self._moe_take(), has_moe=bool(moe),
                 admitted_n=len(admitted),
                 prefill_tokens=wave_prefill_tokens,
-                prefill_wall=wave_prefill_wall,
             )
             self._inflight.append(wave)
         return wave
@@ -7782,7 +7974,7 @@ class PagedEngine:
                     self._pages_of(len0 + t * grow) for t in range(n))
             # every launched step walks every lane's table, live or not
             self._counters["decode_page_slots"] += steps * wave.step_slots
-            t_now = _time.time()
+            t_now, m_now = _time.time(), _time.monotonic()
             # the lanes AS LAUNCHED: a predicted finisher's slot may
             # hold a joiner of the next wave by now
             for stream, slot, predicted in wave.lanes:
@@ -7793,16 +7985,19 @@ class PagedEngine:
                     # (eos, a cancel): what this wave computed for the
                     # lane is dropped, never delivered
                     continue
+                # the chunk ran behind the stream's prefill: its
+                # readback is the first proof that the prefill has run
+                self._close_prefill(stream, t_now, m_now, locked=True)
                 n = int(emitted_np[slot])
                 self._counters["tokens"] += n
                 chunk_tokens += n
                 stream.cost_decode_tokens += n
                 got = toks_np[slot, :n].tolist()
                 if got and not stream.tokens and not stream.t_first_token:
-                    # TTFT numerator: the stream's first decode token
-                    # landed in this chunk (chunk-boundary resolution —
-                    # the finest the host observes)
-                    stream.t_first_token = t_now
+                    # the stream's first decode token landed in this
+                    # chunk (chunk-boundary resolution — the finest the
+                    # host observes)
+                    self._first_token_locked(stream, t_now, m_now)
                 stream.tokens.extend(got)
                 hit_eos = stream.eos_id in got
                 if (hit_eos or len(stream.tokens) >= stream.max_new
@@ -7828,7 +8023,6 @@ class PagedEngine:
             "puids": wave.puids,
             "trace_id": wave.trace_id,
             "wall_ms": round(chunk_wall * 1000.0, 3),
-            "prefill_wall_ms": round(wave.prefill_wall * 1000.0, 3),
             "tp_degree": self.tp_degree,
             "dp_degree": self.dp_degree,
             "steps": steps,
@@ -7870,11 +8064,11 @@ class PagedEngine:
         self._tier_promote_ready()
         budget = self.chunk_token_budget
         wave_prefill_tokens = 0
-        wave_prefill_wall = 0.0
+        t_prefill = 0.0  # perf_counter at the wave's first prefill enqueue
         fresh: List[_Stream] = []
         slices: List[Tuple[_Stream, int, int]] = []
         if not budget:
-            fresh, wave_prefill_tokens, wave_prefill_wall = (
+            fresh, wave_prefill_tokens, t_prefill = (
                 self._prefill_streams([s for s, _ in admitted])
             )
         else:
@@ -7892,14 +8086,14 @@ class PagedEngine:
                     budget - verify_lanes * (self.draft_k + 1),
                 )
             if slices:
-                fresh, wave_prefill_tokens, wave_prefill_wall = (
+                fresh, wave_prefill_tokens, t_prefill = (
                     self._run_prefill_slices(slices)
                 )
 
         self._seam.enter("launch")
         with self._lock:
             self._counters["prefills"] += len(admitted)
-            t_now = _time.time()
+            t_now, m_now = _time.time(), _time.monotonic()
             for stream in fresh:
                 # the prefill's argmax IS the first generated token:
                 # emit it now so round 1 verifies continuations of it
@@ -7907,7 +8101,7 @@ class PagedEngine:
                 if stream.result is not None or stream.error is not None:
                     continue
                 if not stream.t_first_token:
-                    stream.t_first_token = t_now
+                    self._first_token_locked(stream, t_now, m_now)
                 stream.tokens.append(int(stream.pending))
                 self._counters["tokens"] += 1
                 if stream.pending == stream.eos_id or len(stream.tokens) >= stream.max_new:
@@ -7927,7 +8121,7 @@ class PagedEngine:
             # prefill wave the recorder must see
             if wave_prefill_tokens:
                 self._record_prefill_wave(
-                    wall_s=wave_prefill_wall, tokens=wave_prefill_tokens,
+                    t_enqueue=t_prefill, tokens=wave_prefill_tokens,
                     occupancy=0, admissions=len(admitted), stalls=0,
                     puids=[s.puid for s, _ in admitted if s.puid],
                 )
@@ -8087,6 +8281,11 @@ class PagedEngine:
         self._seam.enter("harvest")
 
         with self._lock:
+            t_now, m_now = _time.time(), _time.monotonic()
+            for stream in runnable:
+                # (an imported stream's scatter: the prefill groups'
+                # own readback closed the others where it returned)
+                self._close_prefill(stream, t_now, m_now, locked=True)
             self._counters["chunks"] += 1
             self._counters["chunk_wall_s"] += chunk_wall
             self._counters["decode_lane_steps"] += len(runnable)
@@ -8131,7 +8330,6 @@ class PagedEngine:
             "puids": wave_puids,
             "trace_id": chunk_trace,
             "wall_ms": round(chunk_wall * 1000.0, 3),
-            "prefill_wall_ms": round(wave_prefill_wall * 1000.0, 3),
             "tp_degree": self.tp_degree,
             "dp_degree": self.dp_degree,
             "steps": self.draft_k + 1,
@@ -9055,11 +9253,10 @@ class StreamingLM(TPUComponent):
         )
         self._wake.set()
         try:
-            while True:
-                got = stream.token_queue.get()
-                if got is None:
-                    break
-                yield np.asarray(got, np.int32)
+            # (a consumer that send()s the time.monotonic() at which its
+            # transport's write returned has its delivery counted to
+            # there: PagedEngine.stream_events)
+            yield from self.engine.stream_events(stream)
             if stream.error:
                 err = stream.error
                 self._maybe_capture(

@@ -337,6 +337,49 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
                    "seconds the engine had work and nothing in flight: "
                    "last readback of a wave to the return of the next "
                    "dispatch"),
+    # the host half on the engine's own clock (monotonic stamps, closed
+    # at readbacks): the engine thread at work / blocked in a readback,
+    # a request's way to its first token and from there to its finish,
+    # a token event's way from the harvest to the transport's write
+    "host_work_s": ("counter", "seldon_tpu_engine_host_work_seconds_total",
+                    "seconds of the engine thread in the wave loop's "
+                    "phases other than wait (admit, prefill, launch, "
+                    "harvest, record): the host's work"),
+    "host_wait_s": ("counter", "seldon_tpu_engine_host_wait_seconds_total",
+                    "seconds the engine thread was blocked in a wave's "
+                    "readback: the device set the pace"),
+    "ttft_s": ("counter", "seldon_tpu_engine_ttft_seconds_total",
+               "seconds from a request's ingress stamp (else its submit) "
+               "to the harvest that held its first token"),
+    "ttfts": ("counter", "seldon_tpu_engine_ttfts_total",
+              "streams whose first token was counted"),
+    "first_token_s": ("counter",
+                      "seldon_tpu_engine_first_token_seconds_total",
+                      "seconds from a stream's admission (first prefill "
+                      "slice) to the harvest that held its first token: "
+                      "its own wave, prefill and chunk"),
+    "first_tokens": ("counter", "seldon_tpu_engine_first_tokens_total",
+                     "admitted streams whose first token was counted"),
+    "decode_stream_s": ("counter",
+                        "seldon_tpu_engine_decode_stream_seconds_total",
+                        "seconds from a stream's first token to its "
+                        "finish, summed as streams finish"),
+    "decode_stream_tokens": ("counter",
+                             "seldon_tpu_engine_decode_stream_tokens_total",
+                             "tokens after the first of finished streams "
+                             "(with decode_stream_seconds_total: the "
+                             "time per token a stream saw)"),
+    "deliver_lag_s": ("counter",
+                      "seldon_tpu_engine_deliver_lag_seconds_total",
+                      "seconds from a token event's push at the harvest "
+                      "to the return of the transport's write"),
+    "deliveries": ("counter", "seldon_tpu_engine_deliveries_total",
+                   "token events written to a consumer"),
+    "deliveries_behind": ("counter",
+                          "seldon_tpu_engine_deliveries_behind_total",
+                          "token events picked up with their stream's "
+                          "next event already queued: the consumer was "
+                          "a whole wave behind"),
     # disaggregated prefill/decode (r15): the KV-page handoff lane
     "kv_exports": ("counter", "seldon_tpu_engine_kv_exports_total",
                    "prefills exported as KV-page handoff payloads "
@@ -587,7 +630,9 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
 # cost_by_adapter is an adapter->totals dict the bridge exports itself
 # with adapter labels (COST_LEDGER_METRICS below — the flat mapping
 # can't carry labels, same shape as adapter_requests)
-ENGINE_STATS_EXCLUDED = {"chunk_wall_s", "prefill_wall_s", "jit_compiles",
+# clock_s is the snapshot's own time.monotonic(): what a reader of two
+# snapshots divides by, no series
+ENGINE_STATS_EXCLUDED = {"chunk_wall_s", "clock_s", "jit_compiles",
                          "adapter_requests", "health", "cost_by_adapter"}
 
 ADAPTER_REQUESTS_METRIC = "seldon_tpu_engine_adapter_requests_total"

@@ -455,3 +455,40 @@ class TestProfileEngineTraceTool:
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
         assert callable(mod.main)
+
+
+@pytest.mark.parametrize("key", [
+    "clock_s", "host_work_s", "host_wait_s", "ttft_s", "ttfts",
+    "first_token_s", "first_tokens", "decode_stream_s",
+    "decode_stream_tokens", "deliver_lag_s", "deliveries",
+    "deliveries_behind",
+])
+def test_the_host_halfs_default_keys_are_mapped_or_excluded(key):
+    """PR 35: each is in the DEFAULT ``engine_stats()`` (no
+    ``?detail=1``), a number, and exported by the bridge — all but the
+    snapshot's own clock, which is what a reader divides by."""
+    from seldon_core_tpu.utils.metrics import (
+        ENGINE_STATS_EXCLUDED,
+        ENGINE_STATS_METRICS,
+    )
+
+    eng = _tiny_engine()
+    try:
+        eng.submit(np.arange(5, dtype=np.int32) % 64, max_new_tokens=6)
+        eng.run()
+        stats = eng.engine_stats()
+        detail = eng.engine_stats(detail=True)
+    finally:
+        eng.close()
+    assert isinstance(stats[key], (int, float)) and stats[key] >= 0
+    if key == "clock_s":
+        assert key in ENGINE_STATS_EXCLUDED and key not in ENGINE_STATS_METRICS
+        assert detail[key] >= stats[key] > 0
+    else:
+        kind, name, _doc = ENGINE_STATS_METRICS[key]
+        assert kind == "counter" and name.endswith("_total")
+    # what went with the enqueue's clock stays gone
+    assert "prefill_wall_s" not in stats
+    assert "phase_wall_s" not in stats and set(detail["phase_wall_s"]) == set(
+        detail["phase_s"])
+    assert all("prefill_wall_ms" not in rec for rec in detail["recorder"])

@@ -194,11 +194,11 @@ class TestCountedWhereItHappens:
             t_in = time.monotonic() - 1.5
             for n, f in first:
                 s = eng.submit(_prompt(n, f), max_new_tokens=new, t_ingress=t_in)
-                s.t_submit -= 2.0
+                s.m_submit -= 2.0
             eng.run()
             for n, f in second:
                 s = eng.submit(_prompt(n, f), max_new_tokens=new)
-                s.t_submit -= 2.0
+                s.m_submit -= 2.0
             eng.run()
             stats = eng.engine_stats(detail=True)
         finally:
@@ -679,18 +679,353 @@ def test_wave_gaps_tool_splits_a_gap_under_an_overlapped_step():
         {"wait": 0.002, "harvest": 0.004, "record": 0.004, "between": 0.005})
 
 
-@pytest.mark.parametrize("speculative,timed", [
-    (None, "enqueue"), ({"draft": "ngram", "draft_k": 2}, "device")])
-def test_gen_prefill_span_says_what_its_end_waited_for(speculative, timed):
-    from seldon_core_tpu.utils import tracing
+def _throttled(fn, rounds=40):
+    """``fn`` with ``rounds`` matrix products folded into its first
+    output: the dispatch returns as soon as ever (a host callback would
+    hold it on the CPU backend), the outputs are late."""
+    import jax
 
-    tracer = tracing.setup_tracing("wave-seam-test")
-    eng = _tiny_engine(speculative=speculative)
-    try:
-        eng.submit(_prompt(5, 1), max_new_tokens=4, trace_id="puid-24")
-        eng.run()
-        spans = {s.name: s for s in tracer.find("puid-24")}
-        assert spans["gen.prefill"].tags["timed"] == timed
-    finally:
-        eng.close()
-        tracing._tracer = None
+    def slow(*args):
+        out = fn(*args)
+        x = jnp.full((512, 512), 1e-3, jnp.float32)
+        burnt = jax.lax.fori_loop(0, rounds, lambda _i, a: jnp.tanh(a @ x), x)
+        return (out[0] + (0 * burnt[0, 0]).astype(out[0].dtype),) + tuple(out[1:])
+
+    return jax.jit(slow)
+
+
+class TestTheEngineThreadsTimeByPhase:
+    """PR 35: every phase's wall time is booked where it ends, gap or
+    no gap, on the clock ``engine_stats()`` carries."""
+
+    @pytest.mark.parametrize("loop", ["step_by_step", "chained"])
+    def test_walls_sum_to_the_clock_and_match_the_annotations(self, tmp_path, loop):
+        import jax
+
+        run = _run_chained if loop == "chained" else (lambda e: e.run())
+        eng = _tiny_engine()
+        try:
+            groups = ([(5, 1)], [(6, 2), (7, 3), (9, 4)])
+            for group in groups:  # warm the shapes
+                for n, f in group:
+                    eng.submit(_prompt(n, f), max_new_tokens=10)
+                eng.run()
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+            try:
+                before = eng.engine_stats(detail=True)
+                for group in groups:
+                    for n, f in group:
+                        eng.submit(_prompt(n, f + 10), max_new_tokens=10)
+                    run(eng)
+                    time.sleep(0.02)  # between: waiting, not work
+                after = eng.engine_stats(detail=True)
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            eng.close()
+        walls = {k: after["phase_wall_s"][k] - before["phase_wall_s"][k]
+                 for k in after["phase_wall_s"]}
+        assert set(walls) == {"admit", "prefill", "launch", "wait", "harvest",
+                              "record", "between"}
+        elapsed = after["clock_s"] - before["clock_s"]
+        # the phases are the engine thread's whole time: they sum to the
+        # elapsed clock, and without ``between`` to work + wait
+        assert sum(walls.values()) == pytest.approx(elapsed, abs=2e-3)
+        assert walls["between"] >= 2 * 0.02
+        work = after["host_work_s"] - before["host_work_s"]
+        wait = after["host_wait_s"] - before["host_wait_s"]
+        assert work + wait == pytest.approx(elapsed - walls["between"], abs=2e-3)
+        assert wait == pytest.approx(walls["wait"]) and work > 0.0 and wait > 0.0
+        # and each is its annotations' time in the recorded trace (a
+        # prefill group nests in the phase that runs it, which lends it
+        # that much)
+        events = [e for e in _seam_events(str(tmp_path)) if e[0] != "seldon.wave"]
+        traced = dict.fromkeys(walls, 0.0)
+        for name, start, end, _stats in events:
+            traced[name.rsplit(".", 1)[1]] += (end - start) / 1e9
+        nested = [e for e in events if e[0] == "seldon.wave.prefill"]
+        for name, start, end, _stats in events:
+            if name != "seldon.wave.prefill":
+                traced[name.rsplit(".", 1)[1]] -= sum(
+                    (e[2] - e[1]) / 1e9 for e in nested
+                    if start <= e[1] and e[2] <= end)
+        assert len(events) >= 5 * 6
+        for phase in ("admit", "prefill", "launch", "wait", "harvest", "record"):
+            # (an annotation opens after the clock is read and closes
+            # before it: tens of microseconds an event)
+            assert traced[phase] == pytest.approx(
+                walls[phase], abs=1e-4 * len(events) + 0.1 * walls[phase]), phase
+
+    def test_a_throttled_chunk_is_waited_for_not_worked_on(self):
+        eng = _tiny_engine()
+        try:
+            eng.submit(_prompt(5, 1), max_new_tokens=4)
+            eng.run()
+            (key, fn), = eng._chunk_jit.items()
+            eng._chunk_jit[key] = _throttled(fn)
+            eng.submit(_prompt(5, 2), max_new_tokens=4)
+            eng.run()  # the throttled program is compiled
+            before = eng.engine_stats()
+            eng.submit(_prompt(5, 3), max_new_tokens=4)
+            t0 = time.monotonic()
+            wave = eng.launch()
+            t1 = time.monotonic()
+            assert eng.engine_stats()["host_wait_s"] == before["host_wait_s"]
+            eng.harvest(wave)
+            t2 = time.monotonic()
+            after = eng.engine_stats()
+        finally:
+            eng.close()
+        assert t2 - t1 > t1 - t0  # the chunk ran under the harvest, not the launch
+        wait = after["host_wait_s"] - before["host_wait_s"]
+        work = after["host_work_s"] - before["host_work_s"]
+        assert wait >= 0.9 * (t2 - t1) - 2e-3
+        assert work <= (t1 - t0) + 0.1 * (t2 - t1) + 2e-3
+
+
+class TestARequestsWayOnTheEnginesClock:
+    def test_first_token_and_decode_sums_against_hand_computed_stamps(self):
+        """Groups of 1 and 3, two waves each (6 tokens in chunks of 4):
+        the sums lie between what the test's own stamps around
+        ``launch`` and ``harvest`` allow."""
+        eng = _tiny_engine()
+        try:
+            eng.submit(_prompt(5, 1), max_new_tokens=6)
+            for n, f in [(6, 2), (7, 3), (9, 4)]:
+                eng.submit(_prompt(n, f), max_new_tokens=6)
+            eng.run()  # warm
+            lo = dict.fromkeys(("ttft_s", "first_token_s", "decode_stream_s"), 0.0)
+            hi = dict(lo)
+            base = eng.engine_stats()
+            for group in ([(5, 11)], [(6, 12), (7, 13), (9, 14)]):
+                k = len(group)
+                t_in = time.monotonic() - 1.5
+                for i, (n, f) in enumerate(group):
+                    # the first of a group carries a handler's stamp
+                    eng.submit(_prompt(n, f), max_new_tokens=6,
+                               t_ingress=t_in if i == 0 else None)
+                s0 = time.monotonic()
+                time.sleep(0.03)               # in the queue
+                a0 = time.monotonic()
+                first = eng.launch()           # admitted in here
+                a1 = time.monotonic()
+                time.sleep(0.05)
+                h0 = time.monotonic()
+                eng.harvest(first)             # first tokens in here
+                h1 = time.monotonic()
+                time.sleep(0.04)
+                second = eng.launch()
+                f0 = time.monotonic()
+                eng.harvest(second)            # finished in here
+                f1 = time.monotonic()
+                assert not eng.has_work()
+                lo["first_token_s"] += k * (h0 - a1)
+                hi["first_token_s"] += k * (h1 - a0)
+                lo["ttft_s"] += (h0 - t_in) + (k - 1) * (h0 - s0)
+                hi["ttft_s"] += (h1 - t_in) + (k - 1) * (h1 - (s0 - 0.02))
+                lo["decode_stream_s"] += k * (f0 - h1)
+                hi["decode_stream_s"] += k * (f1 - h0)
+            stats = eng.engine_stats()
+        finally:
+            eng.close()
+        got = {key: stats[key] - base[key] for key in stats
+               if isinstance(stats[key], (int, float))}
+        assert got["ttfts"] == got["first_tokens"] == 4
+        assert got["decode_stream_tokens"] == 4 * (6 - 1)
+        for key in lo:
+            assert lo[key] <= got[key] <= hi[key], (key, lo[key], got[key], hi[key])
+        # a request's way to its first token is its waits and its wave
+        assert got["ttft_s"] == pytest.approx(
+            got["ingress_wait_s"] + got["queue_wait_s"] + got["first_token_s"])
+
+    @pytest.mark.parametrize("speculative", [None, {"draft": "ngram", "draft_k": 2}])
+    def test_gen_prefill_ends_where_a_readback_proves_it_and_prices_a_prompt(
+            self, speculative):
+        """D10: a throttled prefill program's seconds are in
+        ``gen.prefill`` and in ``predict_cost_s``'s prefill term — both
+        end at the first-token harvest (the speculative engine's group
+        reads its pending token back itself) and neither says ``timed``."""
+        from seldon_core_tpu.utils import tracing
+
+        tracer = tracing.setup_tracing("wave-seam-test")
+        eng = _tiny_engine(speculative=speculative)
+        try:
+            eng.submit(_prompt(5, 1), max_new_tokens=4)
+            eng.run()
+            (key, fn), = eng._prefill_jit.items()
+            eng._prefill_jit[key] = _throttled(fn)
+            eng.submit(_prompt(5, 2), max_new_tokens=4)
+            eng.run()  # the throttled program is compiled
+            before = eng.engine_stats()
+            eng.submit(_prompt(5, 3), max_new_tokens=4, trace_id="puid-35")
+            t0 = time.monotonic()
+            wave = eng.launch()
+            t1 = time.monotonic()
+            eng.harvest(wave)
+            t2 = time.monotonic()
+            eng.run()
+            after = eng.engine_stats()
+            spans = {s.name: s for s in tracer.find("puid-35")}
+            cost = eng.predict_cost_s(5, 0)
+        finally:
+            eng.close()
+            tracing._tracer = None
+        prefill = spans["gen.prefill"]
+        assert "timed" not in prefill.tags and prefill.tags["prompt_len"] == 5
+        if speculative is None:
+            assert t2 - t1 > t1 - t0  # enqueued at once, run under the harvest
+            ran = t2 - t1
+        else:
+            ran = 0.5 * (t2 - t0)  # the group waited for its pending token
+        assert prefill.duration_s >= 0.9 * ran
+        first = after["first_token_s"] - before["first_token_s"]
+        assert after["first_tokens"] - before["first_tokens"] == 1 and first >= 0.9 * ran
+        # three prompts of 5 tokens so far; two ran the throttled program
+        assert after["prefill_tokens"] == 15
+        assert cost == pytest.approx(5 * after["first_token_s"] / 15)
+        assert cost >= 0.9 * ran / 3
+        # the decode span begins where the prefill's ended
+        decode = spans["gen.decode"]
+        assert decode.start_s >= prefill.start_s
+        assert prefill.duration_s + decode.duration_s <= (t2 - t0) + (
+            after["clock_s"] - before["clock_s"])
+
+
+class TestATokensWayOut:
+    def test_a_consumer_a_wave_behind_is_behind_and_one_that_keeps_up_is_not(self):
+        eng = _tiny_engine()
+        try:
+            eng.submit(_prompt(5, 9), max_new_tokens=12)
+            eng.run()  # warm
+            # 12 tokens in chunks of 4: three events a stream
+            keeps_up = eng.submit(_prompt(5, 1), max_new_tokens=12, stream_tokens=True)
+            events = eng.stream_events(keeps_up)
+            got = []
+            while eng.has_work():
+                eng.step()
+                got.append(next(events))      # one a wave, as it lands
+            assert next(events, None) is None
+            stats = eng.engine_stats()
+            assert [len(g) for g in got] == [4, 4, 4]
+            assert (stats["deliveries"], stats["deliveries_behind"]) == (3, 0)
+            # (unstamped, an event's way out ends at the next pull)
+            assert 0.0 <= stats["deliver_lag_s"] < 5.0
+
+            sleeper = eng.submit(_prompt(5, 2), max_new_tokens=12, stream_tokens=True)
+            eng.run()                          # it sleeps through every wave
+            pushed = list(sleeper.push_stamps)
+            assert len(pushed) == 3 and pushed == sorted(pushed)
+            events = eng.stream_events(sleeper)
+            late = [next(events)]
+            # the transport's stamp rides the next pull: 0.25 s, 0.5 s
+            # and 1 s after each push
+            late.append(events.send(pushed[0] + 0.25))
+            late.append(events.send(pushed[1] + 0.5))
+            with pytest.raises(StopIteration):
+                events.send(pushed[2] + 1.0)
+            after = eng.engine_stats()
+        finally:
+            eng.close()
+        assert np.concatenate(late).tolist() == sleeper.result.tolist()
+        assert after["deliveries"] - stats["deliveries"] == 3
+        # the first two found their successor queued, the last had none
+        assert after["deliveries_behind"] - stats["deliveries_behind"] == 2
+        assert after["deliver_lag_s"] - stats["deliver_lag_s"] == pytest.approx(1.75)
+
+    @pytest.mark.parametrize("lane", ["sse", "grpc"])
+    def test_the_handler_stamps_the_write_and_sends_it_back(self, lane):
+        """Both streaming handlers pull the next event with the
+        ``time.monotonic()`` at which their transport took the one
+        before: the SSE lane after ``await resp.write``, the gRPC lane
+        after its ``yield`` is taken."""
+        import asyncio
+
+        from seldon_core_tpu.engine.server import (add_seldon_service,
+                                                   build_gateway_app)
+        from seldon_core_tpu.runtime.component import TPUComponent
+
+        sent, yielded = [], []
+
+        class Stub(TPUComponent):
+            def predict(self, X, names, meta=None):
+                return X
+
+            def predict_stream(self, X, names, meta=None):
+                for i in range(3):
+                    yielded.append(time.monotonic())
+                    sent.append((yield np.asarray([i, i + 1], np.int32)))
+
+        async def sse():
+            from aiohttp.test_utils import TestClient, TestServer
+
+            client = TestClient(TestServer(build_gateway_app(_gateway(Stub()))))
+            await client.start_server()
+            try:
+                resp = await client.post("/api/v0.1/generate/stream",
+                                         json={"data": {"ndarray": [[1, 2, 3]]}})
+                return (await resp.text()).count("data: {\"tokens\"")
+            finally:
+                await client.close()
+
+        async def grpc_lane():
+            import grpc
+
+            from seldon_core_tpu.proto import services
+            from seldon_core_tpu.runtime.message import InternalMessage
+
+            server = grpc.aio.server()
+            add_seldon_service(server, _gateway(Stub()))
+            port = server.add_insecure_port("127.0.0.1:0")
+            await server.start()
+            channel = grpc.aio.insecure_channel(f"127.0.0.1:{port}")
+            try:
+                call = services.unary_stream_callable(channel, "Seldon", "GenerateStream")
+                req = InternalMessage(payload=np.array([[1, 2, 3]], "int32"),
+                                      kind="ndarray").to_proto()
+                return len([msg async for msg in call(req)])
+            finally:
+                await channel.close()
+                await server.stop(grace=None)
+
+        t0 = time.monotonic()
+        assert asyncio.run(sse() if lane == "sse" else grpc_lane()) == 3
+        t1 = time.monotonic()
+        assert len(sent) == 3 and all(isinstance(w, float) for w in sent)
+        # each stamp lies after the event's yield and before the next
+        assert all(y <= w for y, w in zip(yielded, sent))
+        assert all(w <= y for w, y in zip(sent, yielded[1:] + [t1]))
+        assert t0 <= sent[0]
+
+    def test_the_served_path_counts_its_deliveries(self):
+        import asyncio
+
+        from aiohttp.test_utils import TestClient, TestServer
+
+        from seldon_core_tpu.engine.server import build_gateway_app
+
+        lm = _streaming_lm()
+
+        async def scenario():
+            client = TestClient(TestServer(build_gateway_app(_gateway(lm))))
+            await client.start_server()
+            try:
+                resp = await client.post("/api/v0.1/generate/stream",
+                                         json={"data": {"ndarray": [[1, 2, 3, 4, 5]]}})
+                return await resp.text()
+            finally:
+                await client.close()
+
+        try:
+            text = asyncio.run(scenario())
+            stats = lm.engine.engine_stats()
+        finally:
+            lm.shutdown()
+        # 6 tokens in chunks of 4: two events, written and counted
+        assert text.count("data: {\"tokens\"") == 2 and "event: end" in text
+        assert stats["deliveries"] == 2 and stats["deliveries_behind"] <= 1
+        assert 0.0 < stats["deliver_lag_s"] < 30.0
+        assert stats["ttfts"] == stats["first_tokens"] == 1
+        assert stats["decode_stream_tokens"] == 5
+        assert stats["ttft_s"] >= stats["first_token_s"] > 0.0

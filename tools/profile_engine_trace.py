@@ -23,9 +23,8 @@ Set SELDON_TPU_PROFILE_DIR and the run is taken inside one armed
 profile window (``PagedEngine.arm_profile``, what ``POST /debug/profile``
 calls) after a warm-up pass over the same lengths: the trace lands
 there with the wave loop's ``seldon.wave.*`` annotations beside the
-device's operations, and a last table sets the host's prefill clocks
-(``prefill_wall_s``, the ``gen.prefill`` spans) against the prefill
-programs' device seconds of the same window.
+device's operations, and the last lines give the engine's own clocks
+over the window (``host_work_s`` / ``host_wait_s``, ``first_token_s``).
 """
 
 import argparse
@@ -209,50 +208,34 @@ def main():
               f"prefill), {mixed}/{rs['records']} waves mixed "
               "prefill+decode")
     if profiling:
-        enqueue_table(eng.profile_status(), spans)
+        window_lines(eng.profile_status())
     eng.close()
     tracing._tracer = None
 
 
-def enqueue_table(window, spans):
-    """The host's prefill clocks against the device's, over one armed
-    window.  JAX returns from a dispatch before the device finishes, so
-    a host span around ``_prefill_group`` that ends in no readback
-    times the enqueue."""
-    from jax.profiler import ProfileData
-
-    from tools.profile_wave_gaps import find_xplane
-
+def window_lines(window):
+    """The armed window, and the engine's own clocks over it (the two
+    ``engine_stats()`` snapshots at its edges): the engine thread at
+    work and blocked in a readback, and a stream's way to its first
+    token, each closed at a harvest."""
     if window.get("state") != "done":
         print(f"profile window: {window}")
         return
-    found = find_xplane(window["dir"])
-    device_s, runs, phases = 0.0, 0, defaultdict(float)
-    for plane in ProfileData.from_file(found).planes:
-        for line in plane.lines:
-            for ev in line.events:
-                if plane.name.startswith("/device:") and line.name == "XLA Modules" \
-                        and "paged_prefill" in ev.name:
-                    device_s += ev.duration_ns / 1e9
-                    runs += 1
-                elif ev.name.startswith("seldon.wave."):
-                    phases[ev.name] += ev.duration_ns / 1e9
     a, b = window["stats_start"], window["stats_stop"]
-    in_window = [s for s in spans if s.name == "gen.prefill"]
-    groups = {(round(s.start_s, 6), s.tags.get("bucket")): s.duration_s
-              for s in in_window}
+
+    def d(key):
+        return b[key] - a[key]
+
     print(f"\nprofile window: waves {window['wave_start']}..{window['wave_stop']}, "
-          f"{window['t_stop'] - window['t_start']:.3f} s -> {found}")
-    print(f"prefill programs on the device: {runs} runs, {device_s * 1e3:.1f} ms "
-          "(0 runs: no device plane, i.e. not a chip)")
-    print(f"prefill_wall_s over the window: "
-          f"{(b['prefill_wall_s'] - a['prefill_wall_s']) * 1e3:.1f} ms; "
-          f"chunk_wall_s {(b['chunk_wall_s'] - a['chunk_wall_s']) * 1e3:.1f} ms; "
-          f"host_gap_s {(b['host_gap_s'] - a['host_gap_s']) * 1e3:.1f} ms")
-    print(f"gen.prefill spans: {len(in_window)} over {len(groups)} groups, "
-          f"{sum(groups.values()) * 1e3:.1f} ms summed once a group")
-    print("seldon.wave.* seconds in the trace: " + ", ".join(
-        f"{k.rsplit('.', 1)[1]} {v * 1e3:.1f} ms" for k, v in sorted(phases.items())))
+          f"{window['t_stop'] - window['t_start']:.3f} s under {window['dir']} "
+          "(tools/profile_wave_gaps.py reads its seldon.wave.* annotations)")
+    print(f"engine thread: host_work_s {d('host_work_s') * 1e3:.1f} ms, "
+          f"host_wait_s {d('host_wait_s') * 1e3:.1f} ms, "
+          f"host_gap_s {d('host_gap_s') * 1e3:.1f} ms; "
+          f"chunk_wall_s {d('chunk_wall_s') * 1e3:.1f} ms")
+    if d("first_tokens"):
+        print(f"admission -> first-token harvest: {d('first_tokens')} streams, "
+              f"mean {d('first_token_s') / d('first_tokens') * 1e3:.1f} ms")
 
 
 if __name__ == "__main__":
