@@ -328,7 +328,14 @@ class DisaggregatedLM(StreamingLM):
             # same URI/config/seed as the decode engine -> identical
             # params, which is the bit-exactness precondition of the
             # handoff (documented in docs §5b-quater)
-            params = load_lm_params(self.model_uri, self.config, self.seed)
+            # ... cast once as the engines hold it (the float32 tree let
+            # go): every prefill engine is handed this one tree and
+            # takes it by identity, so N workers share one residency
+            params = PagedEngine.resting_tree(
+                load_lm_params(self.model_uri, self.config, self.seed),
+                dtype=jnp.bfloat16,
+                quantize=self.engine_config["quantize"],
+                precision=self.engine_config["precision"], **self.config)
             eng_cfg = dict(self.engine_config)
             eng_cfg.update(
                 max_slots=self.prefill_slots,

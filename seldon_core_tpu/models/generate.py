@@ -47,10 +47,12 @@ def load_lm_params(model_uri: str, config: Dict[str, int], seed: int,
 
     ``spec`` (models/spec.py; None = GPT-2) says what the block is made
     of.  The one block ``TransformerLM`` builds keeps that module's own
-    tree (float32 at rest); any other is ``spec.init_params``: the tree
-    the paged LM declares for that spec, each leaf made in the type it
-    rests in (bf16 matrices for OLMoE: an f32 tree of its size does not
-    fit the chip)."""
+    tree, made in float32 (a checkpoint's type; ``Generator`` and the
+    speculative lanes serve it so, a ``PagedEngine`` casts it to its
+    compute type once, as it takes it: models/spec.py ``rest_tree``);
+    any other is ``spec.init_params``: the tree the paged LM declares
+    for that spec, each leaf made in the type it rests in (bf16 matrices
+    for OLMoE: an f32 tree of its size does not fit the chip)."""
     import jax
     import jax.numpy as jnp
 

@@ -53,7 +53,7 @@ from __future__ import annotations
 
 import re
 from dataclasses import dataclass, replace
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Dict
 
 
@@ -76,10 +76,11 @@ class ModelSpec:
     # add, which is what a routed model's near-ties at the k-th expert
     # are decided by
     residual_f32: bool = False
-    # how matrices and embeddings rest on the device: float32, cast to
-    # the compute type inside every program (TransformerLM's trees) |
-    # in the compute type, made so and never cast (norm scales and a
-    # router rest in float32 either way)
+    # the type ``init`` makes matrices and embeddings in: float32
+    # (TransformerLM's trees, as its checkpoints and the reference hold
+    # them) | the compute type (norm scales and a router are float32
+    # either way).  Not how an engine holds them: it casts a float32
+    # tree to its compute type once, as it takes it (:func:`rest_tree`)
     weights_f32: bool = True
     # ---- attention: "mha" (K and V of d_model each) | "mla" (one
     # latent row of kv_rank + rope_dim; q through a q_rank bottleneck;
@@ -217,7 +218,7 @@ class ModelSpec:
     def transformer_lm(self) -> bool:
         """Whether ``models/transformer.py TransformerLM`` builds this
         block.  It builds one — learned positions, LayerNorm, biased
-        dense GELU MLP, float32 at rest — and then its ``init`` makes
+        dense GELU MLP, made in float32 — and then its ``init`` makes
         the tree, values included, as every GPT-2 test, checkpoint and
         reference expects; any other block's tree is
         :func:`init_params`'s."""
@@ -414,6 +415,90 @@ def rope_interleaved(x, positions, inv_freq):
 # the parameter initialiser: the tree is the module's own
 # ---------------------------------------------------------------------------
 
+def declared_tree(spec: ModelSpec, config: Dict[str, int], dtype=None):
+    """The parameter tree the paged LM declares for ``spec`` at
+    ``config``'s sizes — names, shapes and the type each leaf rests in,
+    as ``jax.ShapeDtypeStruct``s: ``jax.eval_shape`` of the module's own
+    ``init``, so nothing is allocated and a new field of the spec needs
+    no second description.  ``dtype`` is the compute type (bf16 when
+    serving).  Kept by its arguments: a process asks the same
+    declaration again for every engine it builds."""
+    import jax.numpy as jnp
+
+    return _declared(spec, tuple(sorted(config.items())),
+                     jnp.dtype(dtype or jnp.bfloat16).name)
+
+
+@lru_cache(maxsize=32)
+def _declared(spec, sizes, dtype_name):
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.models.paged import get_paged_lm_class
+
+    config, dtype = dict(sizes), jnp.dtype(dtype_name)
+    # the gather lane's module: same tree as every other lane's, and it
+    # takes one layer's pool at a time, so any small pool will do
+    lm = get_paged_lm_class()(dtype=dtype, spec=spec, decode_kernel=False,
+                              **config)
+    i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+    pool = jax.ShapeDtypeStruct(
+        (spec.cache_layers(config["num_layers"]), 2, 8,
+         spec.cache_width(config["d_model"])), dtype)
+    return jax.eval_shape(
+        lm.init, jax.random.key(0), i32((1, 8)), i32((1, 8)), pool,
+        pool if spec.cache_pools == 2 else None,
+        i32((1, 1)), i32((1,)))["params"]
+
+
+def rest_tree(params, spec: ModelSpec, config: Dict[str, int], dtype=None):
+    """``params`` as an engine whose programs compute in ``dtype``
+    holds them: every leaf the module would cast whole where it is used
+    — what :func:`declared_tree` puts in the compute type for a spec
+    whose tree is made in it: the projections' kernels and the biases
+    added to their outputs, the embeddings, the head, expert matrices —
+    cast **once, here**, by the rounding ``promote_dtype`` would apply
+    in every program call: the matmuls' operands are the same values,
+    and no program converts a matrix again.  A norm's scale and bias
+    and a router stay float32 (they are multiplied in float32; casting
+    them would change the result).
+
+    What is done is a function of each leaf's type beside ``dtype``: a
+    leaf already in the type it is declared in — a tree made in the
+    compute type, any tree under a float32 engine — and a leaf the
+    module does not declare are returned as they are, no copy; a tree
+    with nothing to cast is returned itself.  The caller's arrays are
+    never deleted: whoever owns the wide tree lets go of it."""
+    import jax
+    import jax.numpy as jnp
+
+    dtype = jnp.dtype(dtype or jnp.bfloat16)
+
+    def wide(leaf):
+        kind = getattr(leaf, "dtype", None)
+        return (kind is not None and jnp.issubdtype(kind, jnp.floating)
+                and jnp.dtype(kind).itemsize > dtype.itemsize)
+
+    if not any(wide(leaf) for leaf in jax.tree_util.tree_leaves(params)):
+        return params
+    from flax.traverse_util import flatten_dict
+
+    declared = flatten_dict(
+        declared_tree(replace(spec, weights_f32=False), config, dtype))
+
+    cast = []
+
+    def rest(path, leaf):
+        want = declared.get(tuple(getattr(p, "key", None) for p in path))
+        if want is None or want.dtype != dtype or not wide(leaf):
+            return leaf
+        cast.append(path)
+        return jnp.asarray(leaf).astype(dtype)
+
+    rested = jax.tree_util.tree_map_with_path(rest, params)
+    return rested if cast else params
+
+
 def init_params(spec: ModelSpec, config: Dict[str, int], seed: int,
                 dtype=None):
     """Seeded random weights of the block ``spec`` describes, whatever
@@ -466,21 +551,7 @@ def init_params(spec: ModelSpec, config: Dict[str, int], seed: int,
     import jax.numpy as jnp
     from flax.traverse_util import flatten_dict, unflatten_dict
 
-    from seldon_core_tpu.models.paged import get_paged_lm_class
-
-    dtype = dtype or jnp.bfloat16
-    # the gather lane's module: same tree as every other lane's, and it
-    # takes one layer's pool at a time, so any small pool will do
-    lm = get_paged_lm_class()(dtype=dtype, spec=spec, decode_kernel=False,
-                              **config)
-    i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
-    pool = jax.ShapeDtypeStruct(
-        (spec.cache_layers(config["num_layers"]), 2, 8,
-         spec.cache_width(config["d_model"])), dtype)
-    declared = jax.eval_shape(
-        lm.init, jax.random.key(0), i32((1, 8)), i32((1, 8)), pool,
-        pool if spec.cache_pools == 2 else None,
-        i32((1, 1)), i32((1,)))["params"]
+    declared = declared_tree(spec, config, dtype)
 
     # one compiled program per distinct (shape, range, type): the
     # layers' leaves share them

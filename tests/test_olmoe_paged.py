@@ -437,6 +437,11 @@ def gpt2_program_shas():
     params = lm.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]
     eng = PagedEngine(params, **GPT2_CFG, page_size=8, max_slots=4,
                       steps_per_call=2, dtype=jnp.bfloat16)
+    # PR 37: the engine holds the tree cast to bf16; the programs are
+    # lowered here as the parent's were, handed the float32 tree, so the
+    # hashes still say that the *programs* trace what they did
+    # (tests/test_weights_at_rest.py holds what they are handed now)
+    eng.params = jax.device_put(params)
     try:
         pools = eng._kv_args()
         i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731

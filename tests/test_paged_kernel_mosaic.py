@@ -378,3 +378,28 @@ def test_prefill_causal_kernel_compiles_at_the_cells_shapes(
     assert "tpu_custom_call" in text and "prefill_causal_attention" in text
     assert kernels.prefill_attention_impl(
         bucket, d_qk, d_v, jnp.bfloat16, 0, True) == "fused"
+
+
+def test_a_stack_of_layers_is_compiled_once_and_called(one_chip, mosaic):
+    """PR 37: the engine compiles its programs on a TPU with
+    ``paged.TPU_COMPILER_OPTIONS``.  The chip's compiler knows the
+    option by that name, and a stack of identical GPT-2-large MLPs on
+    bf16 weights carries no more program text with it than without
+    (the whole prefill: 91.5 MB without, 7.8 MB with; PERF.md §6)."""
+    from seldon_core_tpu.models import paged
+
+    layers = 12
+
+    def stack(x, w_in, w_out):
+        for i in range(layers):
+            x = x + jax.nn.gelu(x @ w_in[i]) @ w_out[i]
+        return x
+
+    def bf16(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    lowered = jax.jit(stack).lower(
+        bf16(1024, D), [bf16(D, 4 * D)] * layers, [bf16(4 * D, D)] * layers)
+    plain = lowered.compile().memory_analysis().generated_code_size_in_bytes
+    shared = lowered.compile(compiler_options=paged.TPU_COMPILER_OPTIONS)
+    assert shared.memory_analysis().generated_code_size_in_bytes <= plain

@@ -474,7 +474,9 @@ class TestDecodeLane:
                 # and the cap on a prefill call's padded positions
                 "cache_layers", "prefill_positions_max",
                 # PR 33: what a from-zero prefill attends with
-                "prefill_attention"}
+                "prefill_attention",
+                # PR 37: the type the matrices rest in
+                "weights"}
             assert rep["cache_layers"] == LAYERS
             assert (rep["attention"], rep["cache_width"], rep["experts_held"]) == (
                 "mha", cfg["d_model"], 0)
