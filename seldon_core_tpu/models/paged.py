@@ -154,7 +154,23 @@ def prefill_position_bytes(spec, d_model: int, vocab_size: int,
     if spec.double_layer:
         # the shortcut's float32 input and output wait out a half-layer
         kept += 8 * d_model
-    if spec.latent:
+    if spec.kinds:
+        # the wider of the two kinds' rows, and under an indexed layer the
+        # scores of ops/mla.py INDEX_QUERY_BLOCK queries against every
+        # position: the attention's in float32 and bf16, the indexer's in
+        # float32 (what a position adds to each block's (heads, block,
+        # positions) arrays)
+        from seldon_core_tpu.ops import mla
+
+        def rows(heads, qk, v):
+            return 2 * heads * (2 * qk + v) + 4 * heads * v
+
+        attn = max(
+            rows(num_heads, spec.nope_dim + spec.rope_dim, spec.v_dim)
+            + mla.INDEX_QUERY_BLOCK * (6 * num_heads + 4 * spec.index_heads),
+            rows(spec.win_heads, spec.win_nope_dim + spec.win_rope_dim,
+                 spec.win_v_dim))
+    elif spec.latent:
         qk = spec.nope_dim + spec.rope_dim
         attn = 2 * num_heads * (2 * qk + spec.v_dim) + 4 * num_heads * spec.v_dim
     else:
@@ -420,7 +436,7 @@ def _build_modules():
         return x + out.reshape(x.shape).astype(x.dtype), (hist,)
 
     def _latent_block(mod, x, pool, tables, lengths, layer, positions,
-                      token_mask):
+                      token_mask, window=None):
         """A block of latent attention (MLA): ``(x, row, None, hist)``
         with ``row`` ``(B, L, W)`` this call's cache rows for the caller
         to write — one pool, no V — or, for a spec whose layer is
@@ -428,6 +444,16 @@ def _build_modules():
         if mod.spec.double_layer:
             return _double_layer(mod, x, pool, tables, lengths, layer,
                                  positions, token_mask)
+        if mod.spec.kinds:
+            # ``pool`` is the kind's pools (the full layers' rows and
+            # indexer keys | the window layers' rows), ``layer`` the
+            # layer's place among its kind's or None, and the rows come
+            # back named by kind: ("full", row, key) | ("window", row)
+            x, rows, *read = _latent_attention(
+                mod, x, pool, tables, lengths, layer, positions,
+                kind=mod.kind, window=window, counted=token_mask)
+            x, hist = _ffn_grouped(mod, x, token_mask)
+            return (x, (mod.kind.name, *rows), None, *hist, *read)
         x, row = _latent_attention(mod, x, pool, tables, lengths, layer,
                                    positions)
         x, hist = _ffn_grouped(mod, x, token_mask)
@@ -489,7 +515,7 @@ def _build_modules():
         return out, hist
 
     def _latent_attention(mod, x, pool, tables, lengths, layer, positions,
-                          sub=None):
+                          sub=None, kind=None, window=None, counted=None):
         """``x + attention(norm(x))`` by latent attention (MLA): ``(x,
         row)`` with ``row`` ``(B, L, W)`` this call's cache rows
         ``[RMSNorm(c_kv) ; RoPE(k_r) ; 0]`` (``W`` = ``spec.cache_width``:
@@ -511,15 +537,69 @@ def _build_modules():
         heads — the latent kernel where the LM hands over the whole
         pool (``ops/kernels.latent_attention_decode``), a gather and two
         einsums elsewhere — and the step's own row joins by the flash
-        rule."""
-        from seldon_core_tpu.models.spec import rope_interleaved, yarn_inv_freq
+        rule.
+
+        ``kind`` (a spec whose layers differ, models/spec.py
+        ``AttnKind``): the layer's own heads, ranks, head widths and
+        theta, and ``(x, rows)`` comes back with ``rows`` the layer's
+        cache rows, ``(row,)`` or ``(row, index key)``.  A **window**
+        kind reads ``pool`` (its kind's rows) through ``window`` =
+        ``(tables (B, P_w), base (B,))`` — a lane's live pages and the
+        position its table's first column starts at — over the
+        ``kind.window - 1`` positions before the token; ``tables`` only
+        says whether the call starts at position zero.  A **full** kind
+        with an indexer (``kind.topk``) reads ``pool`` = ``(rows,
+        indexer keys)``: a segment from zero attends each row's best
+        ``topk`` positions (``ops/mla.py indexed_attention``); a decode
+        step whose bucket holds a lane with ``topk`` cached positions or
+        more scores the cached keys, takes the best ``topk`` of them and
+        the step's own and reads those rows alone (``sparse_select``: a
+        gather of rows, then ``ctx_state``), and any other bucket runs
+        the page loop over every row, as a spec without an indexer.
+        A decode step of a kind also says what it read, as a third value
+        ``int32[2]``: the cached indexer keys it scored and the cached
+        rows its attention read (the lengths it handed the kernel, the
+        chosen set's cached members), over the lanes ``counted`` ``(B,
+        1)`` keeps."""
+        from dataclasses import replace as _replace
+
+        from seldon_core_tpu.models.spec import (
+            lane_tiles,
+            rope_interleaved,
+            yarn_inv_freq,
+        )
         from seldon_core_tpu.ops import kernels, mla
 
         spec = mod.spec
         heads, rank = mod.num_heads, spec.kv_rank
         nope, rdim, vdim = spec.nope_dim, spec.rope_dim, spec.v_dim
         batch, seg_len, d_model = x.shape
+        q_rank = spec.q_rank
+        # the row in whole lane tiles
+        lanes = spec.cache_width(d_model) if kind is None else kind.lanes
         whole = layer is not None
+        topk = kind.topk if kind is not None else 0
+        windowed = kind is not None and bool(kind.window)
+        idx_pool = None
+        if kind is not None:
+            heads, rank, q_rank = kind.heads, kind.kv_rank, kind.q_rank
+            nope, rdim, vdim = kind.nope_dim, kind.rope_dim, kind.v_dim
+            if topk:
+                pool, idx_pool = pool
+            if windowed:
+                # the window's table stands where the block table does:
+                # one bucket of every lane, positions counted from the
+                # table's first column
+                from_zero = tables[0].shape[1] == 0
+                w_tables, w_base = window
+                tables = (w_tables[:, :0] if from_zero else w_tables,)
+                # (never negative: an idle lane's length is 0 under
+                # whatever base its slot's last stream left, and a lane
+                # of negative length is neither empty nor live to the
+                # kernel's hand-on chain)
+                w_first = jnp.maximum(
+                    jnp.maximum(lengths - (kind.window - 1), 0) - w_base, 0)
+                lengths = jnp.maximum(lengths - w_base, 0)
         tag = "" if sub is None else f"_{sub}"
         if sub is not None:
             if whole:
@@ -536,7 +616,7 @@ def _build_modules():
                               name=name + tag)
 
         y = _norm(spec, "attn_norm" + tag)(x)
-        c_q = rms("q_a_norm")(proj("q_a", spec.q_rank, y))
+        c_q = rms("q_a_norm")(proj("q_a", q_rank, y))
         q = proj("q_b", heads * (nope + rdim), c_q.astype(mod.dtype)).reshape(
             batch, seg_len, heads, nope + rdim)
         kva = proj("kv_a", rank + rdim, y)
@@ -545,9 +625,11 @@ def _build_modules():
             # constants on q (exact in bfloat16 at the published ranks:
             # 2) and on the normed latent as it is cached (float32
             # here, rounded once into the pool's type)
-            s_q, s_kv = spec.lora_scales(d_model)
+            s_q, s_kv = (spec.lora_scales(d_model) if kind is None
+                         else spec.lora_scales(d_model, kind))
             q, c_kv = q * jnp.asarray(s_q, q.dtype), c_kv * s_kv
-        inv = yarn_inv_freq(spec)
+        inv = yarn_inv_freq(spec if kind is None else _replace(
+            spec, rope_theta=kind.rope_theta, rope_dim=kind.rope_dim))
         q_nope = q[..., :nope]
         q_rope = rope_interleaved(q[..., nope:], positions, inv).astype(mod.dtype)
         k_rope = rope_interleaved(
@@ -556,7 +638,6 @@ def _build_modules():
         # pool's type (this call attends its own rows in that type too,
         # so a prompt prefilled whole and one resumed from cached pages
         # see the same keys)
-        lanes = spec.cache_width(d_model)  # the row in whole lane tiles
         tail = jnp.zeros((batch, seg_len, lanes - rank - rdim), mod.dtype)
         row = jnp.concatenate(
             [c_kv.astype(mod.dtype), k_rope.astype(mod.dtype), tail], axis=-1)
@@ -567,7 +648,30 @@ def _build_modules():
         # attended latent)
         w_uk = mod.param("kv_b_k" + tag, init, (heads, rank, nope), rest)
         w_uv = mod.param("kv_b_v" + tag, init, (heads, rank, vdim), rest)
-        scale = spec.softmax_scale
+        scale = spec.softmax_scale if kind is None else kind.softmax_scale
+        if topk:
+            # the indexer: 64 heads of 128 from the normed q latent, one
+            # key a token (LayerNorm'd) and one weight a head from the
+            # layer's normed input; the first rope_dim dims rotated; q
+            # and the key in the type the key is cached in
+            ih, idim = spec.index_heads, spec.index_dim
+            ilanes = lane_tiles(idim)
+            i_scale = ih ** -0.5 * idim ** -0.5
+            q_i = proj("index_q", ih * idim, c_q.astype(mod.dtype)).reshape(
+                batch, seg_len, ih, idim)
+            k_i = nn.LayerNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
+                               name="index_k_norm" + tag)(proj("index_k", idim, y))
+            w_i = proj("index_w", ih, y).astype(jnp.float32)
+
+            def rotated(v):  # (B, L, j, idim): the first rdim dims
+                return jnp.concatenate([
+                    rope_interleaved(v[..., :rdim], positions, inv),
+                    v[..., rdim:].astype(jnp.float32),
+                    jnp.zeros(v.shape[:-1] + (ilanes - idim,), jnp.float32),
+                ], axis=-1).astype(mod.dtype)
+
+            q_i = rotated(q_i)
+            key_row = rotated(k_i[:, :, None, :])[:, :, 0, :]   # (B, L, ilanes)
 
         def cached(tb):
             """A bucket's cached rows (nb, C, W), or None for a table
@@ -577,18 +681,28 @@ def _build_modules():
             rows = pool[layer, tb] if whole else pool[tb]
             return rows.reshape(tb.shape[0], -1, rows.shape[-1])
 
-        outs, off = [], 0
+        outs, reads, off = [], [], 0
         for tb in tables:
             nb = tb.shape[0]
             sl = slice(off, off + nb)
             off += nb
+            if seg_len > 1 and topk:
+                if tb.shape[1]:
+                    raise ValueError(
+                        "an indexed layer prefills from position zero: a "
+                        "segment over cached rows is not built")
+                outs.append(mla.indexed_attention(
+                    q_nope[sl], q_rope[sl], row[sl], w_uk, w_uv, scale,
+                    mod.dtype, q_i[sl], w_i[sl], key_row[sl], i_scale, topk))
+                continue
             if seg_len > 1:
                 fused = kernels.prefill_attention_impl(
                     seg_len, nope + rdim, vdim, mod.dtype, tb.shape[1],
                     whole) == "fused"
                 outs.append(mla.naive_attention(
                     q_nope[sl], q_rope[sl], cached(tb), lengths[sl], row[sl],
-                    w_uk, w_uv, scale, mod.dtype, fused=fused))
+                    w_uk, w_uv, scale, mod.dtype, fused=fused,
+                    **({"window": kind.window} if windowed else {})))
                 continue
             q_abs = jnp.einsum(
                 "bhn,hrn->bhr", q_nope[sl][:, 0], w_uk.astype(mod.dtype),
@@ -597,20 +711,70 @@ def _build_modules():
                 [q_abs, q_rope[sl][:, 0].astype(jnp.float32),
                  jnp.zeros((nb, heads, lanes - rank - rdim), jnp.float32)],
                 axis=-1) * scale).astype(mod.dtype)            # (nb, h, W)
-            if whole:
-                from seldon_core_tpu.ops.kernels import latent_attention_decode
-
-                state = latent_attention_decode(
-                    q_full, pool, tb, lengths[sl], layer=layer,
-                    page_size=pool.shape[2], rank=rank)
-            else:
-                rows = cached(tb)
-                valid = jnp.arange(rows.shape[1])[None, :] < lengths[sl][:, None]
-                state = mla.ctx_state(q_full, rows, valid, rank)
             own = row[sl]                                      # (nb, 1, W)
-            latent = mla.merge(
-                state, mla.ctx_state(
-                    q_full, own, jnp.ones(own.shape[:2], bool), rank))
+            offset = {"starts": w_first[sl]} if windowed else {}
+            live = (jnp.ones((nb,), bool) if counted is None
+                    else counted[sl].reshape(nb))
+
+            def tally(keys, rows, live=live):
+                """``int32[2]``: per-lane counts summed over the lanes
+                that run."""
+                return jnp.stack([jnp.where(live, n, 0).sum() for n in (keys, rows)]
+                                 ).astype(jnp.int32)
+
+            def dense(q_full=q_full, tb=tb, sl=sl, own=own, offset=offset):
+                """Every cached row (a window's live ones), then the
+                step's own by the flash rule."""
+                first = w_first[sl] if windowed else 0
+                if whole:
+                    from seldon_core_tpu.ops.kernels import (
+                        latent_attention_decode,
+                    )
+
+                    state = latent_attention_decode(
+                        q_full, pool, tb, lengths[sl], layer=layer,
+                        page_size=pool.shape[2], rank=rank, **offset)
+                else:
+                    rows = cached(tb)
+                    at = jnp.arange(rows.shape[1])[None, :]
+                    valid = at < lengths[sl][:, None]
+                    if offset:
+                        valid &= at >= w_first[sl][:, None]
+                    state = mla.ctx_state(q_full, rows, valid, rank)
+                return mla.merge(
+                    state, mla.ctx_state(
+                        q_full, own, jnp.ones(own.shape[:2], bool), rank)
+                ), tally(0, jnp.maximum(lengths[sl] - first, 0))
+
+            def sparse(q_full=q_full, tb=tb, sl=sl, own=own):
+                """The indexer's best ``topk`` of the cached positions
+                and the step's own: their rows alone."""
+                ps = pool.shape[-2]
+                keys = idx_pool[layer, tb] if whole else idx_pool[tb]
+                keys = keys.reshape(nb, -1, keys.shape[-1])
+                cached_sc = mla.index_scores(
+                    q_i[sl], w_i[sl], keys, i_scale)[:, 0]
+                own_sc = mla.index_scores(
+                    q_i[sl], w_i[sl], key_row[sl], i_scale)[:, 0, 0]
+                # each position's row in the pool (pages x page_size
+                # rows a layer), carried through the selection's sort
+                row_at = (tb[:, :, None] * ps + jnp.arange(ps)).reshape(nb, -1)
+                _at, is_cached, own_in, chosen = mla.sparse_select(
+                    cached_sc, own_sc, lengths[sl], topk, carry=row_at)
+                flat = pool.reshape(*pool.shape[:-3], -1, pool.shape[-1])
+                rows = flat[layer, chosen] if whole else flat[chosen]  # (nb, k, W)
+                return mla.merge(
+                    mla.ctx_state(q_full, rows, is_cached, rank),
+                    mla.ctx_state(q_full, own, own_in[:, None], rank)
+                ), tally(jnp.minimum(lengths[sl], keys.shape[1]),
+                         is_cached.sum(axis=-1))
+
+            if topk and tb.shape[1] * pool.shape[-2] > topk:
+                latent, read = jax.lax.cond(
+                    mla.any_over(lengths[sl], topk), sparse, dense)
+            else:
+                latent, read = dense()
+            reads.append(read)
             # (heads lead on both sides: the CPU backend has no bf16
             # thunk for the "bhr,hrv->bhv" form)
             out = jnp.einsum(
@@ -618,8 +782,16 @@ def _build_modules():
                 w_uv.astype(mod.dtype), preferred_element_type=jnp.float32)
             outs.append(jnp.swapaxes(out, 0, 1).astype(mod.dtype)[:, None])
         attn = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
+        if spec.attn_gate:
+            # one gate a head, from the layer's normed input
+            gate = jax.nn.sigmoid(proj("attn_gate", heads, y).astype(jnp.float32))
+            attn = (attn.astype(jnp.float32) * gate[..., None]).astype(mod.dtype)
         attn = attn.reshape(batch, seg_len, heads * vdim)
-        return x + proj("attn_proj", d_model, attn), row
+        x = x + proj("attn_proj", d_model, attn)
+        if kind is None:
+            return x, row
+        rows = (row, key_row) if topk else (row,)
+        return (x, rows, sum(reads)) if reads else (x, rows)
 
     def _segment_attention(mod, q, k, v, scale):
         """Causal attention of a segment ``(B, L, h, hd)`` over itself
@@ -684,11 +856,13 @@ def _build_modules():
         precision: str = "bf16"  # "w8a8": int8×int8 projections
         spec: Any = GPT2
         routed_layer: bool = True  # a spec with leading dense layers
+        kind: Any = None  # a spec with layer kinds: this layer's AttnKind
 
         @nn.compact
         def __call__(self, x, pk, pv, block_tables, lengths,
                      lora=None, adapter_idx=None, kv_scales=None,
-                     layer=None, positions=None, token_mask=None):
+                     layer=None, positions=None, token_mask=None,
+                     window=None):
             # x: (B, L, d)
             # positions: (B, L) absolute token indices (a RoPE spec
             # reads them; GPT-2's enter at the LM's embedding)
@@ -729,7 +903,7 @@ def _build_modules():
             if self.spec.latent:
                 # one latent pool (pv is None), another attention
                 return _latent_block(self, x, pk, tables, lengths, layer,
-                                     positions, token_mask)
+                                     positions, token_mask, window)
             d_model = x.shape[-1]
             heads = self.num_heads
             head_dim = d_model // heads
@@ -1158,7 +1332,7 @@ def _build_modules():
         @nn.compact
         def __call__(self, tokens, positions, pages_k, pages_v, block_tables,
                      lengths, lora=None, adapter_idx=None, kv_scales=None,
-                     token_mask=None):
+                     token_mask=None, window=None):
             x = _embed(self, tokens, positions)
             # The kernel lane (no TP mesh — decode_kernel=False is how
             # the engine encodes one; env, dtype, backend: the shared
@@ -1173,6 +1347,9 @@ def _build_modules():
                 latent=self.spec.latent,
             )
             new_k, new_v, hists = [], [], []
+            if self.spec.kinds:
+                return self._kinds(x, positions, pages_k, block_tables,
+                                   lengths, token_mask, window, whole)
             for i in range(self.num_layers):
                 if whole:
                     pools = (pages_k, pages_v)
@@ -1208,6 +1385,45 @@ def _build_modules():
                 new_v.append(v)
                 hists += hist
             return _head(self, x, new_k, new_v, hists)
+
+        def _kinds(self, x, positions, pools, block_tables, lengths,
+                   token_mask, window, whole):
+            """The layers of a spec whose attention differs by layer:
+            ``pools`` is ``{"full", "index", "window"}`` (models/spec.py
+            ``cache_kinds``), each ``(layers of the kind, pages,
+            page_size, lanes)``; layer ``i`` reads its kind's pools at
+            its place among that kind's layers, whole with the place as
+            ``layer`` on the kernel lane and cut to its own rows
+            elsewhere.  The new rows come back a dict of the same names,
+            each stacked over its kind's layers."""
+            spec = self.spec
+            rows = {"full": [], "index": [], "window": []}
+            hists, reads = [], []
+            for i in range(self.num_layers):
+                kind = spec.attn_kind(i, self.num_heads)
+                at = spec.kind_index(i)
+                names = ("window",) if kind.window else ("full", "index")
+                mine = tuple(pools[n] if whole else pools[n][at]
+                             for n in names)
+                x, new, _v, hist, *read = PagedTransformerBlock(
+                    num_heads=self.num_heads, dtype=self.dtype,
+                    precision=self.precision, name=f"block_{i}",
+                    spec=spec, routed_layer=spec.layer_routed(i), kind=kind,
+                )(x, mine if kind.topk else mine[0], None, block_tables,
+                  lengths, layer=at if whole else None, positions=positions,
+                  token_mask=token_mask, window=window)
+                for name, row in zip(names, new[1:]):
+                    rows[name].append(row)
+                hists.append(hist)
+                reads += read
+            x = _norm(spec, "final_norm")(x)
+            logits = _dense(self.precision, self.vocab_size, self.dtype,
+                            "head", spec)(x)
+            # (a decode step's fifth value: what each layer read,
+            # int32[layers, 2] — _latent_attention)
+            return (logits.astype(jnp.float32),
+                    {n: jnp.stack(r) for n, r in rows.items()}, None,
+                    jnp.stack(hists), *((jnp.stack(reads),) if reads else ()))
 
     return PagedTransformerBlock, PagedTransformerLM, ChunkTransformerLM
 
@@ -1248,6 +1464,16 @@ def kv_join(pages, scales):
     if scales is None:
         return pages
     return (pages, scales)
+
+
+def window_kwarg(window):
+    """``{"window": window}`` for a cache of kinds' window tables, ``{}``
+    for None — the keyword a program passes on to the LM and to the
+    write; like :func:`kv_scales_arg`, a helper so that jitted callers
+    spell no ternary on what is a fact of the call's structure."""
+    if window is None:
+        return {}
+    return {"window": window}
 
 
 def kv_scales_arg(sk, sv):
@@ -1504,6 +1730,43 @@ def _write_kv_int8(pk, sk, pv, sv, new_k, new_v, block_tables, start, valid, *,
     return pk, sk, pv, sv
 
 
+def write_kinds(pools, new, block_tables, start, valid, window, *, page_size,
+                max_len, from_zero: bool = False):
+    """:func:`write_kv` for a cache of row kinds (models/spec.py
+    ``cache_kinds``): ``pools`` and ``new`` are ``{"full", "index",
+    "window"}``.  The full layers' rows and their indexer keys land where
+    the block table says, as any latent row.  The window layers' rows
+    land through ``window`` = ``(tables (B, P_w), base (B,))``: a lane's
+    table covers positions ``base .. base + P_w * page_size``, so a
+    decode step's row is written at ``start - base`` of it, and a
+    prefill from zero writes the table's span of its rows — ``P_w`` page
+    blocks from position ``base`` (whole pages: ``base`` is a page's
+    first position) — and nothing of the prompt behind the window."""
+    import jax
+    import jax.numpy as jnp
+
+    w_tables, w_base = window
+    out = {}
+    for name in ("full", "index"):
+        out[name], _ = write_kv(
+            pools[name], None, new[name], None, block_tables, start, valid,
+            page_size=page_size, max_len=max_len, from_zero=from_zero)
+    rows = new["window"]                              # (layers, B, L, W)
+    span = w_tables.shape[1] * page_size
+    if from_zero:
+        rows = jnp.pad(rows, [(0, 0), (0, 0), (0, span), (0, 0)])
+        rows = jnp.stack([
+            jax.lax.dynamic_slice_in_dim(rows[:, s], w_base[s], span, axis=1)
+            for s in range(rows.shape[1])], axis=1)
+        at = jnp.zeros_like(start)
+    else:
+        at = start - w_base
+    out["window"], _ = write_kv(
+        pools["window"], None, rows, None, w_tables, at, valid,
+        page_size=page_size, max_len=span, from_zero=from_zero)
+    return out
+
+
 def paged_hbm_accounting(
     *,
     streams: int,
@@ -1528,6 +1791,7 @@ def paged_hbm_accounting(
     host_tier_gib: float = 0.0,
     weight_bytes: int = 0,
     cache_pools: int = 2,
+    cache_kinds: Sequence[Tuple[int, int, int]] = (),
 ) -> Dict[str, int]:
     """Pool-HBM bytes for ``streams`` concurrent streams at ``ctx_len``
     tokens — the capacity model the bench certifies (VERDICT r5 #3/#5).
@@ -1642,6 +1906,20 @@ def paged_hbm_accounting(
       (``spec.cache_layers``: two a LongCat-Flash layer); the default 2
       is K and V of ``d_model`` a layer.
 
+    * **a cache of row kinds** — ``cache_kinds``: ``(layers, lanes,
+      window)`` a kind (``spec.cache_kinds`` with the window layers'
+      ``spec.window``, 0 for a kind whose pages grow with the stream), in
+      place of ``num_layers`` x ``d_model`` x ``cache_pools``.  A kind
+      with a window holds a stream's last ``window`` positions and one
+      chunk's growth, in whole pages whose first need not start the
+      window — ``ceil((window - 1 + steps_per_call) / page_size) + 1``
+      pages at most (the engine's ``window_table_pages``), however long
+      the stream: past that its pages go back to the allocator, so a
+      stream's bytes stop growing in those layers (``window_bytes``, in
+      ``pool_bytes`` and ``peak_bytes``).  In-flight prefill scratch and
+      the prefix residue price the growing kinds alone (a spec with kinds
+      takes neither lane); the native pool type and the pool chunk only.
+
     Activations and the host runtime stay out of scope.
     """
     shard = max(1, int(tp_degree))
@@ -1659,10 +1937,20 @@ def paged_hbm_accounting(
     kv_int8 = kv_dtype == "int8"
     pool_elt_bytes = 1 if kv_int8 else dtype_bytes
     tok_bytes = num_layers * d_model * cache_pools * pool_elt_bytes
+    window_bytes = 0
+    if cache_kinds:
+        tok_bytes = sum(layers * lanes for layers, lanes, window in cache_kinds
+                        if not window) * pool_elt_bytes
+        for layers, lanes, window in cache_kinds:
+            if window:
+                held = min(pages, -(-(window - 1 + steps_per_call)
+                                    // page_size) + 1)
+                window_bytes += int(streams * held * page_size * layers
+                                    * lanes * pool_elt_bytes)
     # sibling scale table: one f32 per page per k/v per layer
     page_scale_bytes = num_layers * 2 * 4 if kv_int8 else 0
     page_bytes = page_size * tok_bytes + page_scale_bytes
-    pool = int(streams * pages * page_bytes) // kv_shard
+    pool = int(streams * pages * page_bytes + window_bytes) // kv_shard
     ws = 0
     if chunk_impl == "ring":
         # the ring impl's gathered working set holds the COMPUTE dtype
@@ -1675,6 +1963,7 @@ def paged_hbm_accounting(
     inflight = int(inflight_pages * page_bytes) // kv_shard
     return {
         "pool_bytes": pool,
+        "window_bytes": window_bytes // kv_shard,
         "working_set_bytes": ws,
         "peak_bytes": (at_rest + ws + inflight + int(adapter_bytes)
                        + int(weight_bytes)),
@@ -1840,7 +2129,7 @@ class _Stream:
         "cost_decode_tokens", "cost_preempts", "cost_restores",
         "cost_closed", "tier_promote", "inflight",
         "m_ingress", "m_submit", "m_admit", "m_first", "prefill_open",
-        "push_stamps",
+        "push_stamps", "wpages", "wfirst",
     )
 
     def __init__(self, req_id, prompt, max_new, temperature, top_k, eos_id, seed):
@@ -1861,6 +2150,10 @@ class _Stream:
         self.error: Optional[Exception] = None
         self.slot: Optional[int] = None
         self.pages: List[int] = []
+        # a cache of kinds: the window layers' pages this stream holds,
+        # oldest first, and the logical page the first of them is
+        self.wpages: List[int] = []
+        self.wfirst = 0
         # tokens already resident in shared prefix-cache pages at
         # admission (page-aligned); prefill runs only past this point
         self.cached_len = 0
@@ -2427,6 +2720,33 @@ class PagedEngine:
             raise ValueError(self._latent_refusal(
                 "the host KV tier (SELDON_TPU_KV_OFFLOAD)",
                 "its containers hold a K and a V block of d_model a page"))
+        if spec.kinds:
+            # a cache of row kinds (models/spec.py cache_kinds): what
+            # assumes one element a token whose pages grow with a
+            # stream's length in every layer is refused here, by name
+            # (the speculative lane, the host tier, the ring chunk, int8
+            # rows, disaggregation and migration by the latent fences; a
+            # mesh by the routed one)
+            for asked, what, why in (
+                (prefix_cache, "the prefix cache (prefix_cache=True)",
+                 "a cached prefix is usable only with the window layers' "
+                 "last rows, which went back to the allocator behind the "
+                 "window, and an indexed layer prefills from position zero "
+                 "— leave prefix_cache unset or false"),
+                (chunk_token_budget or int(
+                    _knobs.raw("SELDON_TPU_CHUNK_TOKEN_BUDGET", "0") or 0),
+                 "chunked prefill (chunk_token_budget)",
+                 "its slices are cached-suffix prefills, and an indexed "
+                 "layer's segment selects among its own rows only"),
+                (max_adapters or int(
+                    _knobs.raw("SELDON_TPU_MAX_ADAPTERS", "0") or 0),
+                 "multi-LoRA adapters (max_adapters)",
+                 "the factor pools name one attention's projections a "
+                 "layer"),
+            ):
+                if asked:
+                    raise ValueError(self._kinds_refusal(what, why))
+            prefix_cache = False  # (unset: the env's default is not asked)
         if quantize == "int8":
             # weight-only int8: weights rest in HBM at half the bytes
             # and dequantise once per chunk program (measured 1.38x
@@ -2595,10 +2915,14 @@ class PagedEngine:
         # block and every cached-suffix program are XLA's
         from seldon_core_tpu.ops.kernels import prefill_attention_impl
 
+        # (a spec with layer kinds: its window layers'; an indexed layer
+        # selects a block of queries at a time in XLA)
+        qk_v = ((spec.win_nope_dim + spec.win_rope_dim, spec.win_v_dim)
+                if spec.kinds else (spec.nope_dim + spec.rope_dim, spec.v_dim))
         self._prefill_attention = {
             bucket: prefill_attention_impl(
-                bucket, spec.nope_dim + spec.rope_dim, spec.v_dim, dtype, 0,
-                kernel_eligible) if spec.latent else "xla"
+                bucket, *qk_v, dtype, 0, kernel_eligible)
+            if spec.latent else "xla"
             for bucket in self.prompt_buckets}
         # r18 int8 KV pool: pages rest int8 with ONE f32 scale per page
         # per k/v in a sibling (layers, num_pages) table — half the
@@ -2643,11 +2967,14 @@ class PagedEngine:
         # mesh=None -> plain pools
         from seldon_core_tpu.parallel.sharding import shard_decode_state
 
+        # a spec with layer kinds: the three pools' (name, layers, lanes)
+        self.cache_kinds = spec.cache_kinds(num_layers) if spec.kinds else ()
         self.params, self.pages_k, self.pages_v = shard_decode_state(
             params, mesh,
             # the leading axis counts attention sub-layers (a double
             # layer has two), not layers
-            pool_shape=(spec.cache_layers(num_layers), self.num_pages,
+            pool_shape=(self.cache_kinds[0][1] if spec.kinds
+                        else spec.cache_layers(num_layers), self.num_pages,
                         self.page_size, self.cache_width),
             dtype=pool_dtype,
             model_axis=model_axis, data_axis=data_axis,
@@ -2655,6 +2982,32 @@ class PagedEngine:
             num_heads=num_heads, seq_shard=self._seq_shard,
             pools=spec.cache_pools,
         )
+        # a cache of kinds: one pool a row kind.  The full layers' rows
+        # and their indexer keys share the block table (and so the page
+        # count); the window layers' rows have a pool, a free list and a
+        # table of their own, of fixed width: what a window and one chunk
+        # can touch.  The pool holds every slot's table full (and page 0,
+        # the trash): a stream holds window pages only while it holds a
+        # slot, so the pool never runs short and no lane waits for it
+        self.window_pages = 0
+        if spec.kinds:
+            self.window_pages = spec.window_table_pages(
+                self.page_size, self.max_steps)
+            self.num_window_pages = self.max_slots * self.window_pages + 1
+            (_f, _fl, _fw), (_i, il, iw), (_w, wl, ww) = self.cache_kinds
+            self.pages_k = {
+                "full": self.pages_k,
+                "index": jnp.zeros(
+                    (il, self.num_pages, self.page_size, iw), pool_dtype),
+                "window": jnp.zeros(
+                    (wl, self.num_window_pages, self.page_size, ww),
+                    pool_dtype),
+            }
+            self._free_wpages: Deque[int] = deque(
+                range(1, self.num_window_pages))  # 0 = trash
+            self._wtables = np.zeros(
+                (self.max_slots, self.window_pages), np.int32)
+            self._wbase = np.zeros((self.max_slots,), np.int32)
         # the served tree as it rests (all shards): lane_report's
         # weight_bytes, paged_hbm_accounting's fixed term
         from seldon_core_tpu.ops.surgery import tree_hbm_bytes
@@ -2692,7 +3045,9 @@ class PagedEngine:
             self._pool_shard_bytes = 2 * int(shard.nbytes)
         else:
             self.tp_degree = 1
-            self._pool_shard_bytes = spec.cache_pools * int(self.pages_k.nbytes)
+            self._pool_shard_bytes = spec.cache_pools * sum(
+                int(pool.nbytes)
+                for pool in jax.tree_util.tree_leaves(self.pages_k))
             if self._kv_int8:
                 self._pool_shard_bytes += 2 * int(self.scales_k.nbytes)
         if spec.experts_held:
@@ -2707,7 +3062,8 @@ class PagedEngine:
         # device says it holds, less the weights as they rest (in the
         # compute type since the cast above: no program makes a second
         # copy of them while it runs) and the pool
-        limit = (self.pages_k.addressable_shards[0].device.memory_stats()
+        limit = (jax.tree_util.tree_leaves(self.pages_k)[0]
+                 .addressable_shards[0].device.memory_stats()
                  or {}).get("bytes_limit")
         resting = self._weight_bytes // self.tp_degree
         self.prefill_positions_max = prefill_positions_max(
@@ -2982,6 +3338,14 @@ class PagedEngine:
                           # lane-steps, summed over the layers (a latent
                           # pool: decode_kv_tokens x layers; 0 otherwise)
                           "latent_kv_tokens": 0,
+                          # a spec with layer kinds (0 otherwise): what
+                          # its selection and its windows read (the
+                          # chunk's counter row, _sparse_step) and the
+                          # window layers' pages given back
+                          "index_keys_scored": 0, "sparse_rows_read": 0,
+                          "sparse_rows_cached": 0, "sparse_lane_steps": 0,
+                          "window_rows_read": 0,
+                          "window_pages_released": 0,
                           # waiting where it happens: seconds (and
                           # streams) between submit and a stream's first
                           # prefill slice — the engine's own queue —
@@ -3304,6 +3668,18 @@ class PagedEngine:
             f"{what} cannot take a latent pool yet — {why}"
         )
 
+    def _kinds_refusal(self, what: str, why: str) -> str:
+        """The one wording of a lane a cache of row kinds cannot take
+        yet."""
+        kinds = ", ".join(f"{name} x{layers} of {lanes} lanes" for
+                          name, layers, lanes in self.spec.cache_kinds(
+                              len(self.spec.layer_kinds)))
+        return (
+            f"arch={self.spec.name!r} keeps a cache of row kinds ({kinds}; "
+            f"the window layers give their pages back behind the window): "
+            f"{what} cannot take it yet — {why}"
+        )
+
     def _refuse_latent(self, what: str) -> None:
         """Containers that carry K and V pages of ``d_model`` between
         engines (disaggregated prefill, migration) raise here."""
@@ -3313,7 +3689,12 @@ class PagedEngine:
                 "d_model a page"))
 
     def _write_kv(self, pk, pv, new_k, new_v, block_row_or_tables, start, valid,
-                  from_zero: bool = False):
+                  from_zero: bool = False, window=None):
+        if self.spec.kinds:
+            return write_kinds(
+                pk, new_k, block_row_or_tables, start, valid, window,
+                page_size=self.page_size, max_len=self.max_len,
+                from_zero=from_zero), None
         return write_kv(
             pk, pv, new_k, new_v, block_row_or_tables, start, valid,
             page_size=self.page_size, max_len=self.max_len, from_zero=from_zero,
@@ -3517,11 +3898,15 @@ class PagedEngine:
         jax, jnp = self._jax, self._jnp
 
         def prefill(params, pk, pv, tokens, true_lens, block_rows,
-                    lora=None, adapter_idx=None):
+                    lora=None, adapter_idx=None, window=None):
             # tokens: (k, bucket)  true_lens: (k,)  block_rows: (k, P)
             # lora/adapter_idx: the multi-LoRA trailing args (engines
             # with adapters enabled only — pad rows carry slot 0)
+            # window: a cache of kinds' ``(window rows (k, P_w), base
+            # (k,))`` — the window layers' write table and the position
+            # its first column starts at
             params = self._materialize(params)
+            kinds = window_kwarg(window)
             positions = jnp.broadcast_to(jnp.arange(bucket)[None, :], (k, bucket))
             lengths = jnp.zeros((k,), jnp.int32)
             pk_pages, sk = kv_split(pk)
@@ -3536,12 +3921,12 @@ class PagedEngine:
                 self.module, params, tokens, positions, pk_pages, pv_pages,
                 read_rows, lengths, lora=lora, adapter_idx=adapter_idx,
                 kv_scales=kv_scales_arg(sk, sv),
-                token_mask=self._routed_rows(bucket, true_lens),
+                token_mask=self._routed_rows(bucket, true_lens), **kinds,
             )
             valid = jnp.arange(bucket)[None, :] < true_lens[:, None]
             pk, pv = self._write_kv(
                 pk, pv, nk, nv, block_rows, jnp.zeros((k,), jnp.int32), valid,
-                from_zero=True,
+                from_zero=True, **kinds,
             )
             last = logits[jnp.arange(k), true_lens - 1]  # (k, vocab)
             return (last, pk, pv, *hist)
@@ -3775,8 +4160,12 @@ class PagedEngine:
         horizon — representative, not necessarily a specialization the
         scheduler has compiled (serving slices tables to its own pow2
         page horizon per call)."""
+        kinds = ({"window": (
+            self._jnp.zeros((self.max_slots, self.window_pages), "int32"),
+            self._jnp.zeros((self.max_slots,), "int32"))}
+            if self.spec.kinds else {})
         return self._chunk_program(steps, buckets).lower(
-            *self.chunk_example_args(buckets))
+            *self.chunk_example_args(buckets), **kinds)
 
     def chunk_example_args(self, buckets: Tuple[Tuple[int, int], ...]):
         """Representative arguments of the decode chunk for one bucket
@@ -3794,6 +4183,8 @@ class PagedEngine:
             # int8 pool's (pages, scales) bundle abstracts leaf-wise.
             if isinstance(p, tuple):
                 return tuple(pool_arg(x) for x in p)
+            if isinstance(p, dict):  # a cache of kinds: a pool a kind
+                return {name: pool_arg(x) for name, x in p.items()}
             if p is None:  # a latent cache has no V pool
                 return None
             if self._mesh is not None:
@@ -4059,14 +4450,45 @@ class PagedEngine:
         if not self.spec.routed:
             return ()
         # a spec that holds a share carries a fourth kind of column: the
-        # HELD experts hit, summed over the steps
+        # HELD experts hit, summed over the steps; a spec with layer
+        # kinds one more ROW, whose first columns are what its selection
+        # and its windows read (:meth:`_sparse_step`)
         return (self._jnp.zeros(
-            (self.module.num_layers,
+            (self.module.num_layers + bool(self.spec.kinds),
              self.spec.hist_width + 2 + bool(self.spec.experts_held)),
             self._jnp.int32),)
 
-    def _moe_step(self, moe, hist, active):
-        """Add one decode step's ``(int32[layers, E],)`` histogram."""
+    # the columns of a spec with layer kinds' extra counter row
+    SPARSE_COUNTERS = ("index_keys_scored", "sparse_rows_read",
+                       "sparse_rows_cached", "sparse_lane_steps",
+                       "window_rows_read")
+
+    def _sparse_step(self, lengths, active, reads):
+        """One decode step's row of a spec with layer kinds, ``int32[5]``
+        (:data:`SPARSE_COUNTERS`).  What was read is the blocks' own
+        account (``reads`` ``int32[layers, 2]``, ``_latent_attention``:
+        the cached indexer keys a layer scored, the cached rows its
+        attention read — every row where the bucket ran the page loop,
+        the chosen set's cached members where it selected, a window's
+        live rows), summed by the layers' kind.  What it is held against
+        comes from the lengths the step starts at: ``sparse_rows_cached``
+        the rows cached for the active lanes times the full layers,
+        ``sparse_lane_steps`` the lanes holding ``index_topk`` or more."""
+        jnp, spec = self._jnp, self.spec
+        is_window = [k == "window" for k in spec.layer_kinds[:reads.shape[0]]]
+        windowed, full = jnp.asarray(is_window), is_window.count(False)
+        cached = jnp.where(active, lengths, 0)
+        return jnp.stack([
+            reads[:, 0].sum(),
+            jnp.where(windowed, 0, reads[:, 1]).sum(),
+            cached.sum() * full,
+            (active & (lengths >= spec.index_topk)).sum(),
+            jnp.where(windowed, reads[:, 1], 0).sum(),
+        ]).astype(jnp.int32)
+
+    def _moe_step(self, moe, hist, active, sparse=None):
+        """Add one decode step's ``(int32[layers, E],)`` histogram (and
+        a spec with layer kinds' counter row)."""
         if not moe:
             return ()
         jnp = self._jnp
@@ -4086,12 +4508,16 @@ class PagedEngine:
             lo = spec.expert_offset
             cols.append((hist[:, lo:lo + spec.held] > 0).sum(
                 axis=1, keepdims=True).astype(jnp.int32))
-        return (moe[0] + jnp.concatenate(cols, axis=1),)
+        step = jnp.concatenate(cols, axis=1)
+        if sparse is not None:
+            step = jnp.concatenate([step, jnp.pad(
+                sparse, (0, step.shape[1] - sparse.shape[0]))[None]], axis=0)
+        return (moe[0] + step,)
 
     def _chunk_fn_pool(
         self, steps, buckets, params, pk, pv, logits, lengths, block_tables,
         keys, done, emitted, max_new, temps, top_ks, eos_ids, perm,
-        lora=None, adapter_idx=None,
+        lora=None, adapter_idx=None, window=None,
     ):
         """Legacy chunk implementation (SELDON_TPU_CHUNK_IMPL=pool):
         per-step pool gather + per-slot DUS writes.  Kept selectable
@@ -4116,6 +4542,8 @@ class PagedEngine:
             )
             if adapter_idx is not None:
                 adapter_idx = adapter_idx[perm]
+            if window is not None:
+                window = (window[0][perm], window[1][perm])
             split_tables = []
             off = 0
             for nb, hb in buckets:
@@ -4124,6 +4552,9 @@ class PagedEngine:
             attn_tables = tuple(split_tables)
         else:
             attn_tables = block_tables
+        # a cache of kinds: the window layers' tables ride beside the
+        # block tables, to the LM and to the write
+        kinds = window_kwarg(window)
 
         def step(carry, _):
             pk, pv, logits, lengths, keys, done, emitted, *moe = carry
@@ -4147,14 +4578,17 @@ class PagedEngine:
                 pk_pages, pv_pages, attn_tables, lengths,
                 lora=lora, adapter_idx=adapter_idx,
                 kv_scales=kv_scales_arg(sk, sv),
-                token_mask=active[:, None],
+                token_mask=active[:, None], **kinds,
             )
             pk, pv = self._write_kv(
-                pk, pv, nk, nv, block_tables, lengths, active[:, None]
+                pk, pv, nk, nv, block_tables, lengths, active[:, None], **kinds
             )
             logits = jnp.where(active[:, None], new_logits[:, 0], logits)
+            moe = self._moe_step(
+                moe, hist[:1], active,
+                *((self._sparse_step(lengths, active, hist[1]),)
+                  if kinds else ()))
             lengths = lengths + active.astype(jnp.int32)
-            moe = self._moe_step(moe, hist, active)
             return (pk, pv, logits, lengths, keys, done, emitted, *moe), token
 
         (pk, pv, logits, lengths, keys, done, emitted, *moe), toks = jax.lax.scan(
@@ -5043,6 +5477,51 @@ class PagedEngine:
                     self._page_entry.pop(p, None)
                 self._free_pages.append(p)
 
+    # ---- the window layers' pages (a spec with layer kinds) ----------------
+
+    def _window_first(self, length: int) -> int:
+        """The first logical page a step at position ``length`` (and so
+        any later one) still reads in a window layer."""
+        return max(0, length - (self.spec.window - 1)) // self.page_size
+
+    def _window_ensure_locked(self, stream: _Stream, length: int,
+                              horizon: int) -> None:
+        """Move ``stream``'s window pages to what steps from position
+        ``length`` up to ``horizon`` touch: the pages wholly behind the
+        window at ``length`` go back to the allocator (no later step
+        reads them; a wave still in flight reads them before anything
+        enqueued after this can write them — programs run in order),
+        pages up to the horizon are taken, and the lane's table and base
+        are rewritten.  The pool backs every slot's whole table, and
+        only a stream in a slot holds pages: none is ever missing."""
+        first = self._window_first(length)
+        drop = min(first - stream.wfirst, len(stream.wpages))
+        if drop > 0:
+            self._free_wpages.extend(stream.wpages[:drop])
+            del stream.wpages[:drop]
+            self._counters["window_pages_released"] += drop
+        stream.wfirst = max(stream.wfirst, first)
+        need = -(-horizon // self.page_size) - stream.wfirst
+        while len(stream.wpages) < need:
+            stream.wpages.append(self._free_wpages.popleft())
+        row = self._wtables[stream.slot]
+        row[:] = 0
+        row[:len(stream.wpages)] = stream.wpages
+        self._wbase[stream.slot] = stream.wfirst * self.page_size
+
+    def _free_window_locked(self, stream: _Stream) -> None:
+        """Every window page ``stream`` holds goes back (finish,
+        eviction, failure)."""
+        if stream.wpages:
+            self._free_wpages.extend(stream.wpages)
+            stream.wpages = []
+            if stream.slot is not None and self._slots[stream.slot] in (
+                    stream, None):
+                # (a predicted finisher's slot may hold a joiner by now)
+                self._wtables[stream.slot] = 0
+                self._wbase[stream.slot] = 0
+        stream.wfirst = 0
+
     # ---- per-request cost ledger (r20) ------------------------------------
 
     def _cost_touch_locked(self, stream: _Stream) -> None:
@@ -5213,6 +5692,25 @@ class PagedEngine:
             if entry.page != p or self._prefix_index.get(entry.key) is not entry \
                     or self._page_entry.get(p) is not entry:
                 problems.append(f"LRU entry for page {p} inconsistent with index")
+        if self.spec.kinds:
+            # the window pool: a page is free or held by one stream, and
+            # a stream's pages are the ones its lane's table names
+            held: Dict[int, int] = {}
+            for st in self._live_streams_locked():
+                for pg in st.wpages:
+                    held[pg] = held.get(pg, 0) + 1
+                if st.slot is not None and self._slots[st.slot] is st and (
+                        list(self._wtables[st.slot, :len(st.wpages)])
+                        != st.wpages):
+                    problems.append(
+                        f"stream {st.req_id}: window table != its pages")
+            free_w = list(self._free_wpages)
+            if any(n > 1 for n in held.values()) or set(free_w) & set(held):
+                problems.append("a window page is held twice or free and held")
+            if len(free_w) + len(held) != self.num_window_pages - 1 or 0 in held:
+                problems.append(
+                    f"window pages: {len(free_w)} free + {len(held)} held != "
+                    f"{self.num_window_pages - 1}")
         problems.extend(self._adapter_problems_locked())
         if self._kv_tier is not None:
             # tier partition (r22): the tier's own level/accounting
@@ -5308,6 +5806,7 @@ class PagedEngine:
         self._tier_putback_locked(stream)
         if stream.pages:
             self._free_locked(stream.pages)
+            self._free_window_locked(stream)
             stream.pages = []
         stream.slot = None
         self._release_adapter_locked(stream)
@@ -5525,6 +6024,9 @@ class PagedEngine:
         row[: len(stream.pages)] = stream.pages
         self._block_tables[slot] = row
         self._lengths[slot] = plen
+        if self.spec.kinds:
+            stream.wpages, stream.wfirst = [], self._window_first(plen)
+            self._window_ensure_locked(stream, plen, plen)
         # the lane's adapter slot id: every engine program gathers this
         # lane's low-rank factors by it (0 = the zero adapter)
         self._adapter_slots[slot] = stream.adapter_slot
@@ -5854,10 +6356,19 @@ class PagedEngine:
                 padded[i, :n] = stream.prompt
                 true_lens[i] = n
                 block_rows[i] = self._block_tables[stream.slot, :pages_h]
+            kinds = {}
+            if self.spec.kinds:
+                # the window layers' write tables (pad rows: the trash page)
+                w_rows = np.zeros((k, self.window_pages), np.int32)
+                w_base = np.zeros((k,), np.int32)
+                for i, (stream, _start, _n) in enumerate(group):
+                    w_rows[i] = self._wtables[stream.slot]
+                    w_base[i] = self._wbase[stream.slot]
+                kinds["window"] = (jnp.asarray(w_rows), jnp.asarray(w_base))
             last, pk_out, pv_out, *hist = self._prefill_jit[key2](
                 self.params, *self._kv_args(),
                 jnp.asarray(padded), jnp.asarray(true_lens),
-                jnp.asarray(block_rows), *lora_args,
+                jnp.asarray(block_rows), *lora_args, **kinds,
             )
             self._seam.dispatched()
             self._store_kv(pk_out, pv_out)
@@ -6246,6 +6757,7 @@ class PagedEngine:
                 self._cost_close_locked(stream)
                 if stream.pages:
                     self._free_locked(stream.pages)
+                    self._free_window_locked(stream)
                     stream.pages = []
                 stream.slot = None
                 self._release_adapter_locked(stream)
@@ -6721,6 +7233,9 @@ class PagedEngine:
                 return False
             self._block_tables[slot, len(stream.pages)] = got[0]
             stream.pages.extend(got)
+        if self.spec.kinds:
+            self._window_ensure_locked(
+                stream, int(self._lengths[slot]), horizon)
         return True
 
     def _stream_push(self, stream: _Stream) -> None:
@@ -6822,6 +7337,7 @@ class PagedEngine:
             self._slots[slot] = None
             self._lengths[slot] = 0
         self._free_locked(stream.pages)
+        self._free_window_locked(stream)
         stream.pages = []
         self._release_adapter_locked(stream)
         self._counters["completed"] += 1
@@ -6864,6 +7380,7 @@ class PagedEngine:
         self._tier_putback_locked(stream)
         self._slots[slot] = None
         self._free_locked(stream.pages)
+        self._free_window_locked(stream)
         stream.pages = []
         stream.tokens = []
         stream.slot = None
@@ -7003,8 +7520,20 @@ class PagedEngine:
             "cache_width": self.cache_width,
             # the pool's leading axis: attention sub-layers (a double
             # layer has two), not layers
-            "cache_layers": int(self.pages_k.shape[0]),
+            "cache_layers": self.spec.cache_layers(self.module.num_layers),
             "experts_held": self.spec.held if self.spec.routed else 0,
+            # a spec with layer kinds: one pool a row kind (the full
+            # layers' rows and indexer keys share the block table's
+            # pages; the window layers' have their own), how many rows a
+            # full layer attends and a window layer's positions
+            **({"cache_kinds": [
+                    {"name": name, "layers": layers, "width": lanes,
+                     "pages": int(self.pages_k[name].shape[1])}
+                    for name, layers, lanes in self.cache_kinds],
+                "index_topk": self.spec.index_topk,
+                "window": self.spec.window,
+                "window_table_pages": self.window_pages}
+               if self.spec.kinds else {}),
             # the most padded positions one prefill call takes (derived
             # from the HBM left beside weights and pool; None = no cap):
             # a larger admission group is served as several calls
@@ -7100,6 +7629,10 @@ class PagedEngine:
         e = spec.hist_width  # the histogram's columns; the step counters follow
         if chunk is None:
             chunk = np.zeros((self._moe_hits.shape[0], e + 3), np.int64)
+        elif spec.kinds:  # the last row: what selection and windows read
+            for name, n in zip(self.SPARSE_COUNTERS, chunk[-1]):
+                self._counters[name] += int(n)
+            chunk = chunk[:-1]
         hits = chunk[:, :e].astype(np.int64)
         for h in prefills:
             hits = hits + h
@@ -7180,6 +7713,18 @@ class PagedEngine:
                     self.num_pages - 1 - len(self._free_pages) - len(self._lru)
                 ),
                 "pool_pages_total": self.num_pages - 1,
+                # a cache of kinds: the pages each allocator has out (the
+                # full layers' rows and indexer keys share the block
+                # table's; the window layers' come back behind the window)
+                # (0 for a spec of one kind)
+                "full_pages_held": (
+                    self.num_pages - 1 - len(self._free_pages)
+                    if self.spec.kinds else 0),
+                "window_pages_held": (
+                    self.num_window_pages - 1 - len(self._free_wpages)
+                    if self.spec.kinds else 0),
+                "window_pages_total": (
+                    self.num_window_pages - 1 if self.spec.kinds else 0),
                 "prefix_pages_cached": len(self._lru),
                 # tensor-parallel lane (r11): the degree this engine
                 # runs at (1 = single-chip) and the PER-SHARD K+V pool
@@ -7531,6 +8076,7 @@ class PagedEngine:
                 self._tier_putback_locked(stream)
                 if stream.pages:
                     self._free_locked(stream.pages)
+                    self._free_window_locked(stream)
                     stream.pages = []
                 stream.error = exc
                 self._release_adapter_locked(stream)
@@ -7914,6 +8460,11 @@ class PagedEngine:
                     sum(lens0.values()) if self.spec.latent else 0),
                 pages_live=sum(self._pages_of(n) for n in lens0.values()),
                 page_slots=step_slots, overlapped=int(overlapped),
+                **({"sparse_lanes": sum(
+                        n >= self.spec.index_topk for n in lens0.values()),
+                    "window_pages": sum(
+                        len(s.wpages) for s in runnable_now)}
+                   if self.spec.kinds else {}),
             )
             # copies: the host goes on writing these tables (this wave's
             # predicted lengths, the next wave's admissions) while the
@@ -7922,6 +8473,11 @@ class PagedEngine:
             tables = jnp.asarray(self._block_tables[:, :pages_h].copy())
             lengths = jnp.asarray(self._lengths.copy())
             emitted0 = jnp.zeros((self.max_slots,), jnp.int32)
+            # a cache of kinds: the window layers' tables as this wave
+            # reads them (the next wave's planning rewrites the host's)
+            chunk_kinds = ({"window": (jnp.asarray(self._wtables.copy()),
+                                       jnp.asarray(self._wbase.copy()))}
+                           if self.spec.kinds else {})
             # multi-LoRA (r16): the wave's per-lane adapter slot ids —
             # a TRACED argument, so any mix of adapters runs this same
             # compiled program (idle lanes gather harmlessly)
@@ -7988,7 +8544,8 @@ class PagedEngine:
                 self._lora.device_args(), jnp.asarray(adapter_wave),
             )
         (toks, pk_out, pv_out, self._logits, _lengths_out, self._keys, _,
-         emitted, *moe) = self._get_chunk(steps, buckets)(*chunk_args)
+         emitted, *moe) = self._get_chunk(steps, buckets)(
+             *chunk_args, **chunk_kinds)
         seq = self._seam.dispatched()
         self._moe_hold(moe)
         self._store_kv(pk_out, pv_out)
@@ -8017,9 +8574,15 @@ class PagedEngine:
                 if stream.planned >= stream.max_new:
                     # a predicted finisher gives up its SLOT to admission
                     # at once; its pages wait for the harvest, where
-                    # _finish_locked frees them with the tokens in hand
+                    # _finish_locked frees them with the tokens in hand —
+                    # but its window pages go with the slot (the chunk
+                    # just enqueued is the last program to touch them, and
+                    # whatever a joiner writes there is enqueued after it):
+                    # only a stream in a slot holds window pages
                     self._slots[slot] = None
                     self._lengths[slot] = 0
+                    if self.spec.kinds:
+                        self._free_window_locked(stream)
             wave = _Wave(
                 number=self._seam.wave, seq=seq, overlapped=overlapped,
                 t_launch=t_chunk, lanes=lanes, active_n=len(active),
@@ -8085,7 +8648,7 @@ class PagedEngine:
                 self._counters["decode_kv_tokens"] += read
                 if self.spec.latent:  # a row an attention sub-layer
                     self._counters["latent_kv_tokens"] += (
-                        read * self.pages_k.shape[0])
+                        read * self.spec.cache_layers(self.module.num_layers))
                 self._counters["decode_live_pages"] += sum(
                     self._pages_of(len0 + t * grow) for t in range(n))
             # every launched step walks every lane's table, live or not
@@ -8530,6 +9093,7 @@ class StreamingLM(TPUComponent):
         experts_per_tok: int = 0,
         expert_width: int = 0,
         arch_sizes: Any = None,
+        prompt_buckets: Any = None,
         **kwargs: Any,
     ):
         super().__init__(**kwargs)
@@ -8580,6 +9144,14 @@ class StreamingLM(TPUComponent):
             # SELDON_TPU_CHUNK_TOKEN_BUDGET; 0 = monolithic prefill)
             chunk_token_budget=int(chunk_token_budget),
         )
+        # the prefill buckets, where the doubling ladder to max_len is
+        # not the one wanted (a JSON list)
+        if isinstance(prompt_buckets, str):
+            import json as _json
+
+            prompt_buckets = _json.loads(prompt_buckets) if prompt_buckets else None
+        if prompt_buckets:
+            self.engine_config["prompt_buckets"] = [int(b) for b in prompt_buckets]
         # multi-LoRA (r16): adapter pool slots (0 defers to
         # SELDON_TPU_MAX_ADAPTERS; 0 = adapters off) + the factor rank
         # every registered adapter must share (one pool shape), and the

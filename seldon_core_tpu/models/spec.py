@@ -33,8 +33,10 @@ replica holds** (``experts_held`` from ``expert_offset``, of
 expert-parallel layer computes its own experts' part and nothing
 stands in for the rest).
 
-``GPT2``, ``OLMOE``, ``DEEPSEEK_V3`` and ``LONGCAT_FLASH`` are the values
-served; a new architecture is a new value (and new branches where the
+``GPT2``, ``OLMOE``, ``DEEPSEEK_V3``, ``LONGCAT_FLASH`` and ``DOTS3_NOTE``
+are the values served (the fifth added layer KINDS: ``layer_kinds``,
+``attn_kind``, ``cache_kinds`` — layers that differ in their attention
+and a cache of one pool a row kind); a new architecture is a new value (and new branches where the
 block reads a field it has not met), not a new block.  The fourth value
 added ``double_layer`` (two latent attentions and two dense SwiGLU FFNs
 a layer, the routed experts computed from the first half's normed
@@ -54,7 +56,40 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, replace
 from functools import lru_cache, partial
-from typing import Any, Dict
+from typing import Any, Dict, Tuple
+
+
+def lane_tiles(values: int) -> int:
+    """``values`` rounded up to whole 128-lane tiles: the lanes a cache
+    row of that many values rests in (576 -> 640)."""
+    return -(-values // 128) * 128
+
+
+@dataclass(frozen=True)
+class AttnKind:
+    """One layer's latent attention at its own sizes
+    (:meth:`ModelSpec.attn_kind`): ``window`` 0 attends over every
+    earlier position, ``topk`` over the best that many by the layer's
+    indexer (0: no indexer)."""
+    name: str
+    heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    rope_theta: float
+    window: int
+    topk: int
+
+    @property
+    def lanes(self) -> int:
+        """The cache row in whole 128-lane tiles."""
+        return lane_tiles(self.kv_rank + self.rope_dim)
+
+    @property
+    def softmax_scale(self) -> float:
+        return (self.nope_dim + self.rope_dim) ** -0.5
 
 
 @dataclass(frozen=True)
@@ -131,6 +166,30 @@ class ModelSpec:
     double_layer: bool = False
     zero_experts: int = 0
     mla_lora_scale: bool = False
+    # ---- layer kinds (dots3-note).  layer_kinds[i] names layer i's
+    # attention, "full" | "window" (() = every layer the one attention
+    # the fields above describe).  A FULL layer is that attention plus
+    # an indexer (DeepSeek sparse attention's: index_heads heads of
+    # index_dim score every cached position, a row attends over the
+    # index_topk best) and caches a second row a token, the indexer's
+    # key.  A WINDOW layer is latent attention at widths of its own (the
+    # win_* fields; its heads too, whatever the LM's num_heads says)
+    # over the token and the ``window - 1`` positions before it.
+    # attn_gate: one sigmoid gate a head, read from the layer's normed
+    # input, on the attended values before the output projection
+    layer_kinds: Tuple[str, ...] = ()
+    window: int = 0
+    win_heads: int = 0
+    win_q_rank: int = 0
+    win_kv_rank: int = 0
+    win_nope_dim: int = 0
+    win_rope_dim: int = 0
+    win_v_dim: int = 0
+    win_rope_theta: float = 10_000.0
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
+    attn_gate: bool = False
 
     @property
     def routed(self) -> bool:
@@ -156,7 +215,7 @@ class ModelSpec:
         where the allocator's byte accounting sees it."""
         if not self.latent:
             return d_model
-        return -(-self.cache_values // 128) * 128
+        return lane_tiles(self.cache_values)
 
     @property
     def cache_values(self) -> int:
@@ -176,6 +235,53 @@ class ModelSpec:
         return num_layers * self.attn_sublayers
 
     @property
+    def kinds(self) -> bool:
+        """Whether the layers differ in their attention: then the cache
+        is one pool a ROW kind (:meth:`cache_kinds`), not one element."""
+        return bool(self.layer_kinds)
+
+    def layer_kind(self, layer: int) -> str:
+        return self.layer_kinds[layer] if self.layer_kinds else "full"
+
+    def kind_index(self, layer: int) -> int:
+        """Layer ``layer``'s place among the layers of its kind: the
+        leading index of its rows in that kind's pool."""
+        kind = self.layer_kind(layer)
+        return sum(k == kind for k in self.layer_kinds[:layer])
+
+    def attn_kind(self, layer: int, num_heads: int) -> "AttnKind":
+        """Layer ``layer``'s attention at its own sizes."""
+        if self.layer_kind(layer) == "window":
+            return AttnKind(
+                "window", self.win_heads, self.win_q_rank, self.win_kv_rank,
+                self.win_nope_dim, self.win_rope_dim, self.win_v_dim,
+                self.win_rope_theta, self.window, 0)
+        return AttnKind(
+            "full", num_heads, self.q_rank, self.kv_rank, self.nope_dim,
+            self.rope_dim, self.v_dim, self.rope_theta, 0,
+            self.index_topk if self.kinds else 0)
+
+    def cache_kinds(self, num_layers: int) -> Tuple[Tuple[str, int, int], ...]:
+        """``(name, layers, lanes)`` of each pool a spec with layer kinds
+        keeps: the full layers' latent rows and their indexer keys (both
+        addressed by a stream's block table, which grows with its
+        length) and the window layers' rows (addressed by a table of
+        fixed width: pages behind the window go back to the allocator).
+        Lanes are values in whole 128-lane tiles, as
+        :meth:`cache_width`."""
+        kinds = self.layer_kinds[:num_layers]
+        full, win = kinds.count("full"), kinds.count("window")
+        return (("full", full, lane_tiles(self.kv_rank + self.rope_dim)),
+                ("index", full, lane_tiles(self.index_dim)),
+                ("window", win, lane_tiles(self.win_kv_rank + self.win_rope_dim)))
+
+    def window_table_pages(self, page_size: int, steps: int) -> int:
+        """Columns of a lane's window table: the pages ``window``
+        positions can touch (the first need not start a page) and those
+        a chunk of ``steps`` positions grows into."""
+        return -(-(self.window - 1 + steps) // page_size) + 1
+
+    @property
     def router_outputs(self) -> int:
         """Outputs the router scores: real experts, then identity ones."""
         return self.num_experts + self.zero_experts
@@ -189,12 +295,15 @@ class ModelSpec:
         return self.router_outputs + (
             self.experts_per_tok + 1 if self.zero_experts else 0)
 
-    def lora_scales(self, d_model: int):
+    def lora_scales(self, d_model: int, kind: "AttnKind" = None):
         """``(s_q, s_kv)``: the constants on q and on the normed latent
-        (1, 1 unless ``mla_lora_scale``)."""
+        (1, 1 unless ``mla_lora_scale``), at ``kind``'s ranks where the
+        layers differ."""
         if not self.mla_lora_scale:
             return 1.0, 1.0
-        return ((d_model / self.q_rank) ** 0.5, (d_model / self.kv_rank) ** 0.5)
+        q_rank, kv_rank = ((kind.q_rank, kind.kv_rank) if kind
+                           else (self.q_rank, self.kv_rank))
+        return ((d_model / q_rank) ** 0.5, (d_model / kv_rank) ** 0.5)
 
     @property
     def held(self) -> int:
@@ -277,8 +386,36 @@ LONGCAT_FLASH = ModelSpec(
     double_layer=True, zero_experts=256, mla_lora_scale=True,
 )
 
+# dots-studio/dots3-note-prev config.json (model_type dots3_note; the
+# language model): 46 layers whose attention layer_types names — 13
+# "full_attention" (MLA: 128 heads, q rank 1024, latent 512 + 64 rope,
+# heads of 128 + 64 against values of 128, theta 8e7, no scaling; an
+# indexer of 64 heads x 128 picks the 2,048 positions a row attends)
+# and 33 "sliding_attention" (MLA at the swa_* sizes: 64 heads, q rank
+# 1024, latent 1024 + 64, heads of 192 + 64 against 128, theta 50,000,
+# over 513 positions) in the period full, window x 3 after two leading
+# full layers — each with a headwise output gate and q and the latent
+# rescaled by (5120 / rank) ** 0.5 (apply_mla_qkv_lora_rescale, read as
+# LongCat-Flash's pair); one leading dense SwiGLU layer of 13,824, then
+# 256 sigmoid-routed experts of 1536 (noaux_tc: a selection-only bias,
+# one group), top-8 renormalised, scale 1, beside one shared expert;
+# RMSNorm eps 1e-5, no biases
+DOTS3_NOTE = ModelSpec(
+    name="dots3_note", positions="rope", norm="rmsnorm", norm_eps=1e-5,
+    ffn="moe", num_experts=256, experts_per_tok=8, expert_width=1536,
+    rope_theta=80_000_000.0, bias=False, residual_f32=True, weights_f32=False,
+    attention="mla", q_rank=1024, kv_rank=512, nope_dim=128, rope_dim=64,
+    v_dim=128, dense_layers=1, dense_width=13_824, shared_experts=1,
+    score="sigmoid", n_group=1, topk_group=1, norm_topk=True, routed_scale=1.0,
+    mla_lora_scale=True,
+    layer_kinds=("full", "full") + ("window", "window", "window", "full") * 11,
+    window=513, win_heads=64, win_q_rank=1024, win_kv_rank=1024,
+    win_nope_dim=192, win_rope_dim=64, win_v_dim=128, win_rope_theta=50_000.0,
+    index_heads=64, index_dim=128, index_topk=2048, attn_gate=True,
+)
+
 _ARCHS = {"gpt2": GPT2, "olmoe": OLMOE, "deepseek_v3": DEEPSEEK_V3,
-          "longcat_flash": LONGCAT_FLASH}
+          "longcat_flash": LONGCAT_FLASH, "dots3_note": DOTS3_NOTE}
 # the sizes any routed arch has; those only DeepSeek-V3's expert layer
 # and attention have; and the two every arch has
 _EXPERT_SIZES = ("num_experts", "experts_per_tok", "expert_width")
@@ -290,8 +427,17 @@ _DEEPSEEK_SIZES = _LATENT_SIZES + (
     "rope_orig_len", "rope_beta_fast", "rope_beta_slow", "rope_mscale_all_dim")
 # ... and the one LongCat-Flash's router has beside the latent ones
 _LONGCAT_SIZES = _LATENT_SIZES + ("zero_experts",)
+# ... and those of a spec whose layers differ in kind: the window
+# layers' own widths, the indexer's, and which layer is which
+_KIND_SIZES = (
+    "layer_kinds", "window", "win_heads", "win_q_rank", "win_kv_rank",
+    "win_nope_dim", "win_rope_dim", "win_v_dim", "win_rope_theta",
+    "index_heads", "index_dim", "index_topk")
+_DOTS3_SIZES = _LATENT_SIZES + (
+    "dense_layers", "shared_experts", "n_group", "topk_group") + _KIND_SIZES
 _SIZES = tuple(dict.fromkeys(
-    _EXPERT_SIZES + _DEEPSEEK_SIZES + _LONGCAT_SIZES + ("rope_theta", "norm_eps")))
+    _EXPERT_SIZES + _DEEPSEEK_SIZES + _LONGCAT_SIZES + _DOTS3_SIZES
+    + ("rope_theta", "norm_eps")))
 # a size that may be given as 0 and mean it (0 elsewhere = as published)
 _ZERO_MEANS_ZERO = ("dense_layers", "shared_experts", "expert_offset",
                     "experts_held", "zero_experts")
@@ -316,17 +462,24 @@ def model_spec(arch: str = "gpt2", **sizes: Any) -> ModelSpec:
     if not spec.routed and given.keys() & set(_EXPERT_SIZES):
         raise ValueError(f"arch={spec.name!r} has no experts to size")
     own = (set(_LONGCAT_SIZES) if spec.double_layer
+           else set(_DOTS3_SIZES) if spec.kinds
            else set(_DEEPSEEK_SIZES) if spec.latent else set())
-    foreign = given.keys() & (set(_DEEPSEEK_SIZES) | set(_LONGCAT_SIZES)) - own
+    foreign = given.keys() & (set(_DEEPSEEK_SIZES) | set(_LONGCAT_SIZES)
+                              | set(_DOTS3_SIZES)) - own
     if foreign:
         raise ValueError(f"arch={spec.name!r} has no {sorted(foreign)}")
     if given:
         floats = ("rope_theta", "norm_eps", "routed_scale", "rope_factor",
-                  "rope_beta_fast", "rope_beta_slow", "rope_mscale_all_dim")
+                  "rope_beta_fast", "rope_beta_slow", "rope_mscale_all_dim",
+                  "win_rope_theta")
         spec = replace(spec, **{
-            k: (float(v) if k in floats else int(v))
+            k: (float(v) if k in floats
+                else tuple(str(x) for x in v) if k == "layer_kinds" else int(v))
             for k, v in given.items()
         })
+    if spec.kinds and set(spec.layer_kinds) - {"full", "window"}:
+        raise ValueError(
+            f"layer_kinds {spec.layer_kinds}: a layer is 'full' or 'window'")
     if spec.routed and not 0 < spec.experts_per_tok <= spec.router_outputs:
         raise ValueError(
             f"experts_per_tok {spec.experts_per_tok} of {spec.router_outputs} "
@@ -442,6 +595,16 @@ def _declared(spec, sizes, dtype_name):
     lm = get_paged_lm_class()(dtype=dtype, spec=spec, decode_kernel=False,
                               **config)
     i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+    if spec.kinds:
+        # a pool a row kind, a segment from position zero (a table of no
+        # width), and the window layers' table beside it
+        pools = {name: jax.ShapeDtypeStruct((layers, 2, 8, lanes), dtype)
+                 for name, layers, lanes in spec.cache_kinds(
+                     config["num_layers"])}
+        return jax.eval_shape(
+            lm.init, jax.random.key(0), i32((1, 8)), i32((1, 8)), pools, None,
+            i32((1, 0)), i32((1,)),
+            window=(i32((1, 0)), i32((1,))))["params"]
     pool = jax.ShapeDtypeStruct(
         (spec.cache_layers(config["num_layers"]), 2, 8,
          spec.cache_width(config["d_model"])), dtype)

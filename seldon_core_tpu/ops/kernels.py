@@ -423,7 +423,7 @@ def _lanes(x, width: int):
 
 
 def _causal_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                   block_q: int, block_k: int, scale: float):
+                   block_q: int, block_k: int, scale: float, window: int = 0):
     """Grid cell (batch*head, q-block).  The head's K and V rest WHOLE
     in VMEM: their block index is the head alone, so the pipeline
     fetches them once a head — each key from HBM exactly once, none
@@ -443,7 +443,15 @@ def _causal_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     in the first block the loop takes, so no running max is -inf after
     it and no ``exp(-inf - -inf)`` arises; rows past a prompt's true
     length (a bucket's padding) are computed like any other and read by
-    nobody."""
+    nobody.
+
+    ``window`` (0: none): row ``i`` attends keys ``i - window + 1 .. i``.
+    The loop then starts at the first key block any of the query block's
+    rows can see — blocks wholly behind the window are neither read nor
+    scored — and every block it takes is masked on both edges (there are
+    ``(block_q + window) / block_k`` of them, not a prompt's worth).  A
+    row may see nothing in a block before its own, so the running max can
+    be -inf there and the rescale guards it."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -467,11 +475,19 @@ def _causal_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             q_at = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 0)
             k_at = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(k_at <= q_at, s, -jnp.inf)
+            seen = k_at <= q_at
+            if window:
+                seen &= k_at > q_at - window
+            s = jnp.where(seen, s, -jnp.inf)
         m = m_ref[...]                             # (block_q, 128)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1)[:, None])
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - _lanes(m_new, block_k))
+        if window:  # a row that has seen no key yet: exp(-inf - -inf)
+            m_at = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+            alpha = jnp.exp(m - m_at)
+            p = jnp.exp(s - _lanes(m_at, block_k))
+        else:
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - _lanes(m_new, block_k))
         m_ref[...] = m_new
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1)[:, None]
         acc_ref[...] = _lanes(alpha, d_v) * acc_ref[...] + jnp.dot(
@@ -480,17 +496,19 @@ def _causal_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     first = qi * sub
 
     def under(j, carry):
-        step(j, masked=False)
+        step(j, masked=bool(window))
         return carry
 
-    jax.lax.fori_loop(0, first, under, 0)
+    lo = (jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+          if window else 0)
+    jax.lax.fori_loop(lo, first, under, 0)
     for i in range(sub):
         step(first + i, masked=True)
     o_ref[0] = (acc_ref[...] / _lanes(l_ref[...], d_v)).astype(o_ref.dtype)
 
 
 def causal_attention(q, k, v, scale: float, *, block_q: int = None,
-                     block_k: int = None):
+                     block_k: int = None, window: int = 0):
     """Causal softmax attention of a segment over itself in ONE kernel:
     ``q`` ``(B, L, h, d_qk)``, ``k`` ``(B, L, h, d_qk)``, ``v`` ``(B, L,
     h, d_v)`` -> ``(B, L, h, d_v)`` in q's type.  Row ``i`` attends keys
@@ -498,7 +516,9 @@ def causal_attention(q, k, v, scale: float, *, block_q: int = None,
     matrix reaches HBM.  ``d_qk`` and ``d_v`` may differ (latent
     attention's 192 against 128).  A length that is no multiple of the
     query block is padded to one (pad keys lie after every real row, so
-    causality hides them) and the pad rows cut off.
+    causality hides them) and the pad rows cut off.  ``window`` (0:
+    none): row ``i`` attends keys ``i - window + 1 .. i`` only, and key
+    blocks wholly behind a query block's window are skipped.
 
     The kernel's call is a ``pallas_call`` whose output is ``(B * h, L,
     d_v)``: three dims, which is how the benchmark's readers tell it
@@ -541,7 +561,8 @@ def causal_attention(q, k, v, scale: float, *, block_q: int = None,
             + 4 * _padded_block_bytes((block_q, max(d_qk, d_v)), np.float32))
     out = pl.pallas_call(
         functools.partial(_causal_kernel, block_q=block_q, block_k=block_k,
-                          scale=float(scale)),
+                          scale=float(scale), **(
+                              {"window": int(window)} if window else {})),
         grid=(b * h, seg_p // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d_qk), lambda i, qi: (i, qi, 0)),
@@ -559,7 +580,8 @@ def causal_attention(q, k, v, scale: float, *, block_q: int = None,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=int(max(need, _VMEM_LIMIT_BYTES)),
         ),
-        name="prefill_causal_attention",
+        name=("prefill_window_attention" if window
+              else "prefill_causal_attention"),
         interpret=interpret_mode(),
     )(fold(q), fold(k), fold(v))
     out = out.reshape(b, h, seg_p, d_v).transpose(0, 2, 1, 3)
@@ -1137,9 +1159,8 @@ def _latent_pages_per_step(page_size: int, table_width: int,
     return max(1, min(step_tokens // page_size, table_width))
 
 
-def _latent_attention_kernel(tables_ref, lens_ref, layer_ref, q_ref, pool_hbm,
-                             acc_ref, m_ref, l_ref, buf, sems, turn_ref, *,
-                             page_size, rank, group):
+def _latent_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
+                             page_size, rank, group, offset=False):
     """One lane of absorbed latent attention: the stream kernel's page
     loop (:func:`_paged_attention_kernel`) over ONE pool whose row is
     ``[c_kv ; k_r]``, read once for all heads.
@@ -1166,12 +1187,22 @@ def _latent_attention_kernel(tables_ref, lens_ref, layer_ref, q_ref, pool_hbm,
     640 lanes, the tail zero in q and in the pool alike): Mosaic slices
     HBM in whole 128-lane tiles, and under the (8, 128) tiling a
     576-wide minor dim occupies 640 lanes of HBM whatever it is
-    called."""
+    called.  Heads, row width and rank are the operands': 64 heads on a
+    640-lane row of rank 512 and 64 on a 1,152-lane row of rank 1,024
+    are one kernel.
+
+    ``offset``: a fourth scalar-prefetch operand ``starts`` ``(B,)``
+    gives each lane's first live position (a window's trailing edge):
+    the page loop starts at the step that holds it and positions before
+    it are masked like those past the length."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if offset:
+        starts_ref, *refs = refs
+    q_ref, pool_hbm, acc_ref, m_ref, l_ref, buf, sems, turn_ref = refs
     b = pl.program_id(0)
     lanes = pl.num_programs(0)
     layer = layer_ref[0]
@@ -1179,13 +1210,27 @@ def _latent_attention_kernel(tables_ref, lens_ref, layer_ref, q_ref, pool_hbm,
     heads, row_w = q_ref.shape[1], q_ref.shape[2]
     span = buf.shape[1]  # ``group`` pages of ``page_size`` tokens
     length = lens_ref[b]
+
+    def first_of(lane):
+        """The first step of a lane's page loop."""
+        if not offset:
+            return 0
+        # (never past the lane's last step: the step a predecessor
+        # started for it is always waited for)
+        last = jnp.maximum(
+            jax.lax.div(pages_of(lane) + group - 1, group) - 1, 0)
+        return jnp.minimum(
+            jax.lax.div(jnp.maximum(starts_ref[lane], 0), span), last)
     precision = (jax.lax.Precision.HIGHEST
                  if pool_hbm.dtype == jnp.float32 else None)
     nt_dims = (((1,), (1,)), ((), ()))
 
     def pages_of(lane):
-        return jnp.minimum(
+        pages = jnp.minimum(
             jax.lax.div(lens_ref[lane] + page_size - 1, page_size), width)
+        # (a length below zero is an empty lane too: neither branch
+        # below would take it, and the hand-on chain would break there)
+        return jnp.maximum(pages, 0) if offset else pages
 
     def copies(lane, j, slot):
         n = pages_of(lane)
@@ -1207,13 +1252,14 @@ def _latent_attention_kernel(tables_ref, lens_ref, layer_ref, q_ref, pool_hbm,
 
     n_pages = pages_of(b)
     n_steps = jax.lax.div(n_pages + group - 1, group)
+    j_first = first_of(b)
     after = jnp.minimum(b + 1, lanes - 1)
     hand_on = (b + 1 < lanes) & (pages_of(after) > 0)
 
     @pl.when(b == 0)
     def _first():
         turn_ref[0] = 0
-        start(0, 0, 0)
+        start(0, first_of(0), 0)
 
     base = turn_ref[0]
 
@@ -1225,7 +1271,7 @@ def _latent_attention_kernel(tables_ref, lens_ref, layer_ref, q_ref, pool_hbm,
 
         @pl.when(hand_on)
         def _hand_on():
-            start(after, 0, base)
+            start(after, first_of(after), base)
 
     @pl.when(n_pages > 0)
     def _live():
@@ -1233,7 +1279,7 @@ def _latent_attention_kernel(tables_ref, lens_ref, layer_ref, q_ref, pool_hbm,
 
         def step(j, carry):
             m_prev, l_prev, acc = carry      # (heads, 1) x 2, (heads, rank)
-            slot = jax.lax.rem(base + j, 2)
+            slot = jax.lax.rem(base + j - j_first, 2)
 
             @pl.when(j + 1 < n_steps)
             def _prefetch():
@@ -1241,7 +1287,7 @@ def _latent_attention_kernel(tables_ref, lens_ref, layer_ref, q_ref, pool_hbm,
 
             @pl.when((j + 1 == n_steps) & hand_on)
             def _hand_on():
-                start(after, 0, 1 - slot)
+                start(after, first_of(after), 1 - slot)
 
             wait(j, slot)
             for g in range(1, group):
@@ -1261,11 +1307,18 @@ def _latent_attention_kernel(tables_ref, lens_ref, layer_ref, q_ref, pool_hbm,
             # (a step may reach past the table where its pages do not
             # divide the table's width, and a lane masked done may hold
             # more than the table it was handed)
-            s = jnp.where(at < jnp.minimum(length, width * page_size), s,
-                          -jnp.inf)
+            live = at < jnp.minimum(length, width * page_size)
+            if offset:
+                live &= at >= starts_ref[b]
+            s = jnp.where(live, s, -jnp.inf)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            w = jnp.exp(s - m_new)
+            if offset:  # a step wholly before the window's edge
+                m_at = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+                alpha = jnp.exp(m_prev - m_at)
+                w = jnp.exp(s - m_at)
+            else:
+                alpha = jnp.exp(m_prev - m_new)
+                w = jnp.exp(s - m_new)
             l_new = l_prev * alpha + w.sum(axis=1, keepdims=True)
             pv = jnp.dot(w.astype(buf.dtype), latent, precision=precision,
                          preferred_element_type=jnp.float32)  # (heads, rank)
@@ -1276,15 +1329,26 @@ def _latent_attention_kernel(tables_ref, lens_ref, layer_ref, q_ref, pool_hbm,
             jnp.zeros((heads, 1), jnp.float32),
             jnp.zeros((heads, rank), jnp.float32),
         )
-        m_fin, l_fin, acc_fin = jax.lax.fori_loop(0, n_steps, step, init)
-        turn_ref[0] = jax.lax.rem(base + n_steps, 2)
+        m_fin, l_fin, acc_fin = jax.lax.fori_loop(
+            j_first, n_steps, step, init)
+        turn_ref[0] = jax.lax.rem(base + n_steps - j_first, 2)
         acc_ref[0] = acc_fin
         m_ref[0] = jnp.broadcast_to(m_fin, m_ref.shape[1:])
         l_ref[0] = jnp.broadcast_to(l_fin, l_ref.shape[1:])
 
 
-def _latent_decode(q, pool, block_tables, lengths, layer, *, rank,
-                   step_tokens, interpret):
+def _given(value) -> tuple:
+    """``(value,)``, or ``()`` for None: whether an optional operand was
+    handed over is a fact of the call's structure, fixed at trace time,
+    not a traced value — a helper, so that a jitted caller spells no
+    ternary the jit-purity linter cannot tell from tracer control flow."""
+    if value is None:
+        return ()
+    return (value,)
+
+
+def _latent_decode(q, pool, block_tables, lengths, layer, starts=None, *,
+                   rank, step_tokens, interpret):
     """The latent kernel's ``pallas_call`` on the whole pool (see
     :func:`latent_attention_decode`, which calls it jitted)."""
     import jax
@@ -1299,8 +1363,9 @@ def _latent_decode(q, pool, block_tables, lengths, layer, *, rank,
     q_spec = pl.BlockSpec((1, h, W), lambda b, *prefetch: (b, 0, 0))
     acc_spec = pl.BlockSpec((1, h, rank), lambda b, *prefetch: (b, 0, 0))
     pad_spec = pl.BlockSpec((1, h, 128), lambda b, *prefetch: (b, 0, 0))
+    starts = _given(starts)  # a fourth scalar-prefetch operand, or none
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=3 + len(starts),
         grid=(B,),
         in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=[acc_spec, pad_spec, pad_spec],
@@ -1312,7 +1377,7 @@ def _latent_decode(q, pool, block_tables, lengths, layer, *, rank,
     )
     acc, m, l = pl.pallas_call(
         functools.partial(_latent_attention_kernel, page_size=ps, rank=rank,
-                          group=group),
+                          group=group, offset=len(starts) == 1),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, h, rank), jnp.float32),
@@ -1322,7 +1387,8 @@ def _latent_decode(q, pool, block_tables, lengths, layer, *, rank,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(block_tables, lengths, layer.reshape(1), q.astype(pool.dtype), pool)
+    )(block_tables, lengths, layer.reshape(1), *starts,
+      q.astype(pool.dtype), pool)
     return acc, m[:, :, 0], l[:, :, 0]
 
 
@@ -1335,7 +1401,7 @@ def _latent_decode_jit():
 
 
 def latent_attention_decode(q, pool, block_tables, lengths, *, layer,
-                            page_size, rank):
+                            page_size, rank, starts=None):
     """Unnormalised flash state of absorbed latent attention (MLA) over
     one layer of a paged pool of latent rows, addressed IN the whole
     pool: :func:`paged_attention_decode`'s twin for a cache whose row is
@@ -1349,7 +1415,10 @@ def latent_attention_decode(q, pool, block_tables, lengths, *, layer,
     ``rank`` — the row's leading values that are also the value read.
     Returns ``(acc (B, h, rank), m (B, h), l (B, h))`` float32 — what
     ``ops/mla.py ctx_state`` returns for the gathered rows; join the
-    step's own row with ``ops/mla.py merge``.
+    step's own row with ``ops/mla.py merge``.  ``starts`` ``(B,)``
+    int32 (None: 0): a lane attends positions ``starts .. lengths - 1``
+    of its table's span only — a window's live rows — and pages wholly
+    before ``starts`` are not read.
 
     A row is read once for all heads: at 64 heads and 512 + 64 values
     it needs 1,152 B and 139,264 FLOP; the DMA moves its 640 lanes,
@@ -1367,5 +1436,6 @@ def latent_attention_decode(q, pool, block_tables, lengths, *, layer,
             f"{pool.shape[2]}")
     return _latent_decode_jit()(
         q, pool, block_tables, lengths, jnp.asarray(layer, jnp.int32),
+        *(s.astype(jnp.int32) for s in _given(starts)),
         rank=int(rank), step_tokens=LATENT_STEP_TOKENS,
         interpret=interpret_mode())

@@ -58,7 +58,8 @@ def merge(*states):
 
 
 def naive_attention(q_nope, q_rope, ctx, ctx_len, seg, w_uk, w_uv,
-                    scale: float, dtype, fused: bool = False):
+                    scale: float, dtype, fused: bool = False,
+                    window: int = 0):
     """Causal attention of a segment over its cached prefix and itself,
     K and V made per head from the latent rows.
 
@@ -70,7 +71,9 @@ def naive_attention(q_nope, q_rope, ctx, ctx_len, seg, w_uk, w_uv,
     float32: in XLA :data:`QUERY_BLOCK` queries at a time, or — a
     segment with no prefix where the caller's rule says ``fused``
     (``ops/kernels.py prefill_attention_impl``) — in the fused causal
-    kernel, on ``k = [k_nope ; k_rope]`` made a head as below."""
+    kernel, on ``k = [k_nope ; k_rope]`` made a head as below.
+    ``window`` (a segment with no prefix only): row ``i`` attends keys
+    ``i - window + 1 .. i``, not every earlier one."""
     import jax
     import jax.numpy as jnp
 
@@ -93,7 +96,10 @@ def naive_attention(q_nope, q_rope, ctx, ctx_len, seg, w_uk, w_uv,
                 k_rope[:, :, None, :], k_nope.shape[:3] + k_rope.shape[-1:])],
             axis=-1)
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
-        return causal_attention(q, k, v, scale).astype(dtype)
+        return causal_attention(q, k, v, scale, window=window).astype(dtype)
+    if window and ctx is not None:
+        raise ValueError("naive_attention: a window over a cached prefix "
+                         "is not built (a window layer prefills from zero)")
     key_at = jnp.arange(rows.shape[1])
     in_prefix = key_at[None, :] < ctx_len[:, None]             # (B, keys)
 
@@ -106,6 +112,8 @@ def naive_attention(q_nope, q_rope, ctx, ctx_len, seg, w_uk, w_uv,
         q_at = first + jnp.arange(qn.shape[1])
         own = (key_at[None, :] >= cached) & (
             key_at[None, :] - cached <= q_at[:, None])         # (bq, keys)
+        if window:
+            own &= key_at[None, :] > q_at[:, None] - window
         seen = in_prefix[:, None, :] | own[None]               # (B, bq, keys)
         s = jnp.where(seen[:, None], s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1).astype(dtype)
@@ -123,4 +131,147 @@ def naive_attention(q_nope, q_rope, ctx, ctx_len, seg, w_uk, w_uv,
 
     out = jax.lax.map(
         block, (cut(q_nope), cut(q_rope), jnp.arange(blocks) * bq))
+    return jnp.moveaxis(out, 0, 1).reshape(batch, seg_len, *out.shape[3:])
+
+
+# ---------------------------------------------------------------------------
+# learned sparse attention: an indexer scores every earlier position and
+# a row attends over the best ``topk`` (DeepSeek sparse attention's form)
+# ---------------------------------------------------------------------------
+
+# queries :func:`indexed_attention` scores at once: the attention's
+# (B, heads, 128, keys) float32 beside the indexer's (B, index heads,
+# 128, keys) is 0.4 GB at 128 + 64 heads and 4,096 keys
+INDEX_QUERY_BLOCK = 128
+
+
+def index_scores(q_idx, w_idx, keys, scale: float):
+    """The indexer's score of every key for every query: ``I[t, s] =
+    scale * sum_j w[t, j] * relu(q[t, j] . k[s])``.  ``q_idx`` ``(B, Q,
+    j, d)`` and ``keys`` ``(B, C, d)`` in the type the keys are cached in
+    (one MXU pass, float32 accumulation), ``w_idx`` ``(B, Q, j)``
+    float32: ``(B, Q, C)`` float32."""
+    import jax
+    import jax.numpy as jnp
+
+    s = jnp.einsum("bqjd,bcd->bjqc", q_idx, keys,
+                   preferred_element_type=jnp.float32)
+    s = jax.nn.relu(s) * jnp.swapaxes(w_idx, 1, 2)[..., None]
+    return s.sum(axis=1) * scale
+
+
+def kth_mask(scores, allowed, k: int):
+    """Which of each row's ``allowed`` entries are among its ``k``
+    largest, ties to the lower index: bool like ``scores`` ``(..., C)``.
+    A row with ``k`` allowed entries or fewer keeps them all."""
+    import jax
+    import jax.numpy as jnp
+
+    if scores.shape[-1] <= k:
+        return allowed
+    s = jnp.where(allowed, scores, -jnp.inf)
+    kth = jax.lax.top_k(s, k)[0][..., -1:]      # -inf: fewer than k allowed
+    above = s > kth
+    tie = (s == kth) & allowed
+    room = k - above.sum(axis=-1, keepdims=True)
+    return above | (tie & (jnp.cumsum(tie, axis=-1) <= room))
+
+
+def any_over(lengths, topk: int):
+    """Whether a decode step over lanes holding ``lengths`` cached
+    positions selects: some lane's candidates (its cached positions and
+    its own) outnumber ``topk``.  The block's rule and the engine's
+    counters both read it here."""
+    import jax.numpy as jnp
+
+    return jnp.any(lengths >= topk)
+
+
+def sparse_select(scores, own_score, lengths, topk: int, carry=None):
+    """A decode step's chosen positions: of each lane's ``lengths``
+    cached positions (``scores`` ``(B, C)``, the table's whole span) and
+    its own (``own_score`` ``(B,)``, position ``lengths``) the ``topk``
+    of largest score, ties to the lower position — every one of them
+    while there are ``topk`` or fewer.  Returns ``(at (B, k), cached (B,
+    k), own (B,))``: the chosen positions, which of them are cached rows
+    to read (the rest are blanks or the lane's own position), and
+    whether the step's own row is among the chosen.
+
+    ``carry`` ``(B, C)`` int32 (each position's row in the pool) rides
+    through the sort and comes back for the chosen as a fourth value
+    ``(B, k)``: looked up afterwards it would be a gather of ``B * k``
+    single elements, which a v5e runs at ~8 ns an element — a sixth of a
+    decode step at 128 lanes and 2,048 rows (PERF.md section 6, PR 38).
+    One sort either way: ``lax.top_k`` of this many is a full sort on
+    the chip too, so the sort is spelt out."""
+    import jax
+    import jax.numpy as jnp
+
+    at = jnp.arange(scores.shape[1])[None, :]
+    here = lengths[:, None]
+    s = jnp.where(at < here, scores, -jnp.inf)
+    s = jnp.where(at == here, own_score[:, None], s)
+    k = min(topk, scores.shape[1])
+    # descending by score, ties to the lower position: ascending by
+    # (-score, position), as ``lax.top_k`` orders them
+    neg, idx, *rows = jax.lax.sort(
+        (-s, jnp.broadcast_to(at, s.shape).astype(jnp.int32),
+         *(() if carry is None else (carry,))),
+        dimension=1, num_keys=2)
+    idx = idx[:, :k]
+    valid = neg[:, :k] < jnp.inf
+    own = valid & (idx == here)
+    return (idx, valid & ~own, own.any(axis=-1), *(r[:, :k] for r in rows))
+
+
+def indexed_attention(q_nope, q_rope, seg, w_uk, w_uv, scale: float, dtype,
+                      q_idx, w_idx, k_idx, index_scale: float, topk: int):
+    """:func:`naive_attention` of a segment from position zero whose row
+    ``t`` attends over the ``topk`` positions ``s <= t`` the indexer
+    scores highest (:func:`index_scores`, :func:`kth_mask`; every one
+    while ``t < topk``).  ``q_idx`` ``(B, L, j, d)``, ``w_idx`` ``(B, L,
+    j)``, ``k_idx`` ``(B, L, d)`` beside ``naive_attention``'s operands.
+    :data:`INDEX_QUERY_BLOCK` queries at a time: neither the indexer's
+    ``(j, L, L)`` nor the attention's ``(h, L, L)`` scores exist whole
+    (4.3 and 8.6 GB at 4,096 positions).  ``(B, L, h, v)`` in ``dtype``."""
+    import jax
+    import jax.numpy as jnp
+
+    batch, seg_len = seg.shape[:2]
+    rank = w_uk.shape[1]
+    c_kv = seg[..., :rank]
+    k_rope = seg[..., rank:rank + q_rope.shape[-1]]
+    k_nope = jnp.einsum("bcr,hrn->bchn", c_kv, w_uk.astype(dtype),
+                        preferred_element_type=jnp.float32).astype(dtype)
+    v = jnp.einsum("bcr,hrv->bchv", c_kv, w_uv.astype(dtype),
+                   preferred_element_type=jnp.float32).astype(dtype)
+    key_at = jnp.arange(seg_len)
+
+    def block(args):
+        qn, qr, qi, wi, first = args
+        q_at = first + jnp.arange(qn.shape[1])
+        seen = jnp.broadcast_to(
+            key_at[None, :] <= q_at[:, None], (batch, qn.shape[1], seg_len))
+        if seg_len > topk:
+            seen = kth_mask(index_scores(qi, wi, k_idx, index_scale), seen, topk)
+        s = (jnp.einsum("bqhn,bchn->bhqc", qn, k_nope,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bqhr,bcr->bhqc", qr, k_rope,
+                          preferred_element_type=jnp.float32)) * scale
+        s = jnp.where(seen[:, None], s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1).astype(dtype)
+        return jnp.einsum("bhqc,bchv->bqhv", p, v,
+                          preferred_element_type=jnp.float32).astype(dtype)
+
+    bq = INDEX_QUERY_BLOCK
+    if seg_len <= bq or seg_len % bq:
+        return block((q_nope, q_rope, q_idx, w_idx, 0))
+    blocks = seg_len // bq
+
+    def cut(x):  # (B, L, ...) -> (blocks, B, bq, ...)
+        return jnp.moveaxis(
+            x.reshape(batch, blocks, bq, *x.shape[2:]), 1, 0)
+
+    out = jax.lax.map(block, (cut(q_nope), cut(q_rope), cut(q_idx),
+                              cut(w_idx), jnp.arange(blocks) * bq))
     return jnp.moveaxis(out, 0, 1).reshape(batch, seg_len, *out.shape[3:])

@@ -595,6 +595,38 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
                          "seldon_tpu_engine_latent_kv_tokens_total",
                          "cached latent rows read by decode lane-steps, "
                          "over all layers (a latent pool; 0 otherwise)"),
+    # a spec with layer kinds (PR 38): its selection's and its windows'
+    # reads, and the two allocators' pages; 0 on any other engine
+    "index_keys_scored": (
+        "counter", "seldon_tpu_engine_index_keys_scored_total",
+        "cached indexer keys scored by decode lane-steps, over the full "
+        "layers"),
+    "sparse_rows_read": (
+        "counter", "seldon_tpu_engine_sparse_rows_read_total",
+        "cached rows the full layers' decode attention read (the chosen "
+        "ones where a bucket selects)"),
+    "sparse_rows_cached": (
+        "counter", "seldon_tpu_engine_sparse_rows_cached_total",
+        "rows cached for the lane-steps sparse_rows_read counts"),
+    "sparse_lane_steps": (
+        "counter", "seldon_tpu_engine_sparse_lane_steps_total",
+        "decode lane-steps holding index_topk cached rows or more"),
+    "window_rows_read": (
+        "counter", "seldon_tpu_engine_window_rows_read_total",
+        "cached rows the window layers' decode attention read"),
+    "window_pages_released": (
+        "counter", "seldon_tpu_engine_window_pages_released_total",
+        "window-layer pages given back to their allocator behind the "
+        "window"),
+    "full_pages_held": (
+        "gauge", "seldon_tpu_engine_full_pages_held",
+        "pages out of the block table's allocator (a cache of row kinds)"),
+    "window_pages_held": (
+        "gauge", "seldon_tpu_engine_window_pages_held",
+        "pages out of the window layers' allocator"),
+    "window_pages_total": (
+        "gauge", "seldon_tpu_engine_window_pool_pages",
+        "pages the window layers' allocator has"),
     "moe_held_pass_rows": (
         "gauge", "seldon_tpu_engine_moe_held_pass_rows",
         "rows one pass of a decode step's held experts computes (a "

@@ -403,3 +403,106 @@ def test_a_stack_of_layers_is_compiled_once_and_called(one_chip, mosaic):
     plain = lowered.compile().memory_analysis().generated_code_size_in_bytes
     shared = lowered.compile(compiler_options=paged.TPU_COMPILER_OPTIONS)
     assert shared.memory_analysis().generated_code_size_in_bytes <= plain
+
+
+# ---------------------------------------------------------------------------
+# dots3-note (PR 38): the latent kernel at two shapes with a start offset,
+# the windowed causal kernel, and the XLA programs of the selection
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kind,heads,lanes_w,rank,num_pages,pages", [
+    ("full", 128, 640, 512, 14337, 64), ("full", 128, 640, 512, 14337, 112),
+    ("window", 64, 1152, 1024, 1281, 10)])
+def test_latent_kernel_compiles_at_both_of_dots3_s_shapes(
+        one_chip, mosaic, kind, heads, lanes_w, rank, num_pages, pages):
+    """The long-doc cell's chunk: 128 lanes of 128 heads on the full
+    layers' ``(3, 14337, 64, 640)`` pool (tables of 64 and 112 pages), and
+    of 64 heads on the window layers' ``(3, 1281, 64, 1152)`` pool through
+    a table of 10 pages with each lane's first live position."""
+    lanes, offset = 128, kind == "window"
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q, pool, tables, lengths, layer, starts):
+        return kernels.latent_attention_decode(
+            q, pool, tables, lengths, layer=layer, page_size=PS, rank=rank,
+            **({"starts": starts} if offset else {}))
+
+    compiled = jax.jit(fn).lower(
+        spec((lanes, heads, lanes_w), jnp.bfloat16),
+        spec((3, num_pages, PS, lanes_w), jnp.bfloat16),
+        spec((lanes, pages), jnp.int32), spec((lanes,), jnp.int32),
+        spec((), jnp.int32), spec((lanes,), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and " = " in ln]
+    assert len(calls) == 1, calls
+    # what the benchmark's readers tell the two kernels apart by
+    assert calls[0].partition(" = ")[2].lstrip("(").startswith(
+        f"f32[{lanes},{heads},{rank}]"), calls[0][:200]
+
+
+@pytest.mark.parametrize("k,bucket", [(1, 4096), (1, 3072), (2, 3072)])
+def test_windowed_causal_kernel_compiles_at_the_cells_shapes(
+        one_chip, mosaic, k, bucket):
+    """A window layer's prefill: 64 heads of 256 against values of 128,
+    513 positions, a head's K and V of 4,096 positions resident."""
+    heads, d_qk, d_v = 64, 256, 128
+
+    def spec(d):
+        return jax.ShapeDtypeStruct((k, bucket, heads, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, key, v: kernels.causal_attention(
+            q, key, v, d_qk ** -0.5, window=513)
+    ).lower(spec(d_qk), spec(d_qk), spec(d_v)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "prefill_window_attention" in text
+    assert kernels.prefill_attention_impl(
+        bucket, d_qk, d_v, jnp.bfloat16, 0, True) == "fused"
+
+
+def test_the_selection_s_programs_compile_at_the_cells_shapes(one_chip, mosaic):
+    """What a full layer adds in XLA: a decode step's scores of 7,168
+    cached indexer keys a lane, the best 2,048, and the gather of their
+    rows from the whole pool; a prefill's indexed attention over 4,096
+    positions, a block of queries at a time.  Neither may need more than
+    a few hundred MB beside its operands."""
+    from seldon_core_tpu.ops import mla
+
+    lanes, pages, topk, rank = 128, 112, 2048, 512
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(q_full, q_i, w_i, key_row, own, pool, idx_pool, table, lengths):
+        keys = idx_pool[1, table].reshape(lanes, pages * PS, 128)
+        cached = mla.index_scores(q_i, w_i, keys, 1 / 90.5)[:, 0]
+        own_sc = mla.index_scores(q_i, w_i, key_row, 1 / 90.5)[:, 0, 0]
+        at, is_cached, own_in = mla.sparse_select(cached, own_sc, lengths, topk)
+        page = jnp.take_along_axis(table, at // PS, axis=1)
+        rows = pool[1, page, at % PS]
+        return mla.merge(mla.ctx_state(q_full, rows, is_cached, rank),
+                         mla.ctx_state(q_full, own, own_in[:, None], rank))
+
+    compiled = jax.jit(step).lower(
+        spec((lanes, 128, 640)), spec((lanes, 1, 64, 128)),
+        spec((lanes, 1, 64), jnp.float32), spec((lanes, 1, 128)),
+        spec((lanes, 1, 640)), spec((3, 14337, PS, 640)),
+        spec((3, 14337, PS, 128)), spec((lanes, pages), jnp.int32),
+        spec((lanes,), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+    seg = 4096
+
+    def prefill(q_nope, q_rope, rows, w_uk, w_uv, q_i, w_i, k_i):
+        return mla.indexed_attention(
+            q_nope, q_rope, rows, w_uk, w_uv, 192 ** -0.5, jnp.bfloat16,
+            q_i, w_i, k_i, 1 / 90.5, topk)
+
+    compiled = jax.jit(prefill).lower(
+        spec((1, seg, 128, 128)), spec((1, seg, 128, 64)), spec((1, seg, 640)),
+        spec((128, 512, 128)), spec((128, 512, 128)), spec((1, seg, 64, 128)),
+        spec((1, seg, 64), jnp.float32), spec((1, seg, 128))).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9

@@ -1,7 +1,15 @@
 #!/usr/bin/env python3
 """The readings behind the limits of ``benchmarks/harness/kinds/
 generation_share.py``: what a configuration's plain float32 reference
-(GigaChat3.1's, or with ``--config longcat-flash-omni`` LongCat-Flash's)
+(GigaChat3.1's, with ``--config longcat-flash-omni`` LongCat-Flash's, with
+``--config dots3-note-prev --positions 3334 --judged 256`` dots3-note's:
+there the last ``--judged`` positions are judged (without it every one
+past ``index_topk``), as the cell's sample is — a prompt of 3,078 and 256
+served tokens — each variant is also put through the cell's own kind
+(``harness/kinds/generation_share_sparse.py verdict``: ``ok``, ``near``),
+and the wrong programs are the reference's own ``variant``s — the
+selection left out, the window one position short or wide, the gate or
+the rescale left out)
 says of the tokens that the same forward pass serves in a lower
 precision, or with a fault.  CPU only (``JAX_PLATFORMS=cpu``), 1-2
 minutes a variant at 512 positions; nothing here is a device number.
@@ -234,6 +242,118 @@ def rounded_logits(ref, params, model, tokens, residual_bf16=False, e4m3=False,
         return rms_norm(x, params["final_norm"]["scale"]) @ f32(params["head"]["kernel"])
 
 
+def rounded_logits_dots3(ref, params, model, tokens, residual_bf16=False, e4m3=False,
+                         **_unused):
+    """``reference/dots3_note.py logits`` with its matmuls' operands and
+    results rounded as the variant says: the stated precision (bfloat16
+    operands and results, the indexer's q, key and products among them;
+    norms, softmax, gate, router and the residual stream float32) or
+    8-bit operands.  The selection is made on the rounded scores, so a
+    rounding that moves a row's 2,048th place moves its set."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    f32, b16, act, w, res = roundings(residual_bf16, e4m3)
+    eps, d = model["rms_norm_eps"], model["hidden_size"]
+
+    def mm(a, m):
+        return b16(act(a) @ w(m))
+
+    def rms_norm(v, scale_):
+        return v / jnp.sqrt((v * v).mean(-1, keepdims=True) + eps) * f32(scale_)
+
+    def swiglu(h, gate, up, down):
+        return mm(b16(jax.nn.silu(mm(h, gate)) * mm(h, up)), down)
+
+    with jax.default_matmul_precision("highest"):
+        tokens = jnp.asarray(tokens, jnp.int32)
+        n = tokens.shape[0]
+        pos = jnp.arange(n)
+        x = f32(params["tok_embed"]["embedding"][tokens])
+        for i in range(model["num_hidden_layers"]):
+            p, kind = params[f"block_{i}"], ref.kinds_of(model)[i]
+            sz = ref.sizes(model, kind)
+            heads, rank, q_rank = (sz["num_attention_heads"], sz["kv_lora_rank"],
+                                   sz["q_lora_rank"])
+            nope, rdim = sz["qk_nope_head_dim"], sz["qk_rope_head_dim"]
+            freq = jnp.asarray(
+                1.0 / sz["rope_theta"] ** (np.arange(0, rdim, 2) / rdim), jnp.float32)
+
+            def rotate(v, at=pos, freq=freq):
+                v1, v2 = v[..., 0::2], v[..., 1::2]
+                ang = at.astype(jnp.float32).reshape(-1, *([1] * (v.ndim - 2)), 1) * freq
+                cos, sin = jnp.cos(ang), jnp.sin(ang)
+                return jnp.concatenate([v1 * cos - v2 * sin, v2 * cos + v1 * sin], axis=-1)
+
+            y = rms_norm(x, p["attn_norm"]["scale"])
+            c_q = rms_norm(mm(y, p["q_a"]["kernel"]), p["q_a_norm"]["scale"])
+            q = b16(mm(c_q, p["q_b"]["kernel"]) * (d / q_rank) ** 0.5).reshape(
+                n, heads, nope + rdim)
+            kva = mm(y, p["kv_a"]["kernel"])
+            c_kv = b16(rms_norm(kva[:, :rank], p["kv_a_norm"]["scale"]) * (d / rank) ** 0.5)
+            k_r = b16(rotate(kva[:, rank:]))
+            q_nope, q_r = q[..., :nope], b16(rotate(q[..., nope:]))
+            k_nope = b16(jnp.einsum("cr,hrn->hcn", act(c_kv), w(p["kv_b_k"])))
+            v = b16(jnp.einsum("cr,hrv->hcv", act(c_kv), w(p["kv_b_v"])))
+            at = np.arange(n)
+            seen = at[None, :] <= at[:, None]
+            if kind == "window":
+                seen &= at[None, :] > at[:, None] - model["sliding_window_size"]
+            else:
+                ih, idim = model["index_n_heads"], model["index_head_dim"]
+                q_i = mm(c_q, p["index_q"]["kernel"]).reshape(n, ih, idim)
+                q_i = b16(jnp.concatenate([rotate(q_i[..., :rdim]), q_i[..., rdim:]], -1))
+                k_i = mm(y, p["index_k"]["kernel"])
+                mean = k_i.mean(-1, keepdims=True)
+                k_i = ((k_i - mean) / jnp.sqrt(((k_i - mean) ** 2).mean(-1, keepdims=True) + eps)
+                       * f32(p["index_k_norm"]["scale"]) + f32(p["index_k_norm"]["bias"]))
+                k_i = b16(jnp.concatenate([rotate(k_i[:, :rdim]), k_i[:, rdim:]], -1))
+                w_i = mm(y, p["index_w"]["kernel"])
+                index = []
+                for lo in range(0, n, ref.QUERY_BLOCK):
+                    hit = jax.nn.relu(jnp.einsum(
+                        "qjd,cd->qjc", act(q_i[lo:lo + ref.QUERY_BLOCK]), act(k_i)))
+                    index.append(jnp.einsum("qjc,qj->qc", hit, w_i[lo:lo + ref.QUERY_BLOCK]))
+                seen = ref.select(model, np.asarray(jnp.concatenate(index, 0))
+                                  * np.float32(ih ** -0.5 * idim ** -0.5))
+            seen = jnp.asarray(seen)
+            out = []
+            for lo in range(0, n, ref.QUERY_BLOCK):
+                hi = min(n, lo + ref.QUERY_BLOCK)
+                s = (jnp.einsum("qhn,hcn->hqc", q_nope[lo:hi], k_nope)
+                     + jnp.einsum("qhr,cr->hqc", q_r[lo:hi], k_r)) * (nope + rdim) ** -0.5
+                prob = b16(jax.nn.softmax(jnp.where(seen[None, lo:hi], s, -jnp.inf), axis=-1))
+                out.append(b16(jnp.einsum("hqc,hcv->qhv", prob, v)))
+            gate = jax.nn.sigmoid(mm(y, p["attn_gate"]["kernel"]))
+            attn = b16(jnp.concatenate(out, 0) * gate[:, :, None]).reshape(n, -1)
+            x = res(x + mm(attn, p["attn_proj"]["kernel"]))
+            h = rms_norm(x, p["ffn_norm"]["scale"])
+            if i < model["first_k_dense_replace"]:
+                x = res(x + swiglu(h, p["mlp_gate"], p["mlp_up"], p["mlp_down"]))
+                continue
+            weights, picked = ref.route(
+                model, jax.nn.sigmoid(h @ f32(p["router"])), p["score_bias"])
+            offset = model.get("expert_offset", 0)
+            routed = jnp.zeros_like(x)
+            for e in range(model["n_routed_experts"]):
+                rows, slot = np.nonzero(picked == e + offset)
+                if rows.size:
+                    part = swiglu(h[rows], p["experts_gate"][e], p["experts_up"][e],
+                                  p["experts_down"][e])
+                    routed = routed.at[rows].add(part * weights[rows, slot][:, None])
+            x = res(x + routed + swiglu(h, p["shared_gate"], p["shared_up"], p["shared_down"]))
+        xn = rms_norm(x, params["final_norm"]["scale"])
+        return mm(xn, params["head"]["kernel"])
+
+
+# dots3-note's wrong programs, each the reference itself with one thing
+# changed (``reference/dots3_note.py`` ``variant``), in float32
+DOTS3_WRONG = {"no-selection": "no_selection", "window-short": "window_short",
+               "window-wide": "window_wide", "no-gate": "no_gate",
+               "no-rescale": "no_rescale"}
+
+
 def main() -> int:
     import numpy as np
 
@@ -245,6 +365,8 @@ def main() -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--positions", type=int, default=512)
     ap.add_argument("--variants", default=",".join(VARIANTS))
+    ap.add_argument("--judged", type=int, default=0,
+                    help="judge the last N positions only (dots3-note)")
     args = ap.parse_args()
     config = manifest.load_json(os.path.join(manifest.BENCH_DIR, "configs", args.config + ".json"))
     model = config["model"]
@@ -252,21 +374,46 @@ def main() -> int:
     params = ref.make_params(model, args.seed)
     n = args.positions
     tokens = np.random.default_rng(args.seed).integers(0, model["vocab_size"], size=n).tolist()
-    plain = np.asarray(ref.logits(params, model, tokens))
+    dots3 = config["reference"] == "dots3_note"
+    # (dots3-note: judged where a row has over index_topk candidates and a
+    # slid window, as the cell's sample is: the last --judged positions, or
+    # every one past index_topk)
+    first = (n - args.judged if args.judged else model["index_topk"] + 1) if dots3 else 0
+    tail = {"tail": n - first} if dots3 and args.judged else {}
+    plain = np.asarray(ref.logits(params, model, tokens, **tail))
+    plain = plain if tail else plain[first:]
     std = plain.std(-1)
     how = {"stated": {}, "bf16-residual": {"residual_bf16": True}, "e4m3": {"e4m3": True},
            "no-bias": {"bias": False}, "no-shared": {"shared": False}}
-    for name in args.variants.split(","):
+    names = args.variants.split(",")
+    if dots3 and args.variants == ",".join(VARIANTS):
+        names = ["stated", "e4m3", *DOTS3_WRONG, "early-row"]
+    for name in names:
         if name == "early-row":
             served, far = np.concatenate([plain[:1].argmax(-1), plain[:-1].argmax(-1)]), None
+        elif dots3 and name in DOTS3_WRONG:
+            got = np.asarray(ref.logits(params, model, tokens, variant=DOTS3_WRONG[name],
+                                        **tail))
+            got = got if tail else got[first:]
+            served = got.argmax(-1)
+            far = float(np.median(np.sqrt(((got - plain) ** 2).mean(-1)) / std))
+        elif dots3:
+            got = np.asarray(rounded_logits_dots3(
+                ref, params, model, tokens, **how[name]))[first:]
+            served = got.argmax(-1)
+            far = float(np.median(np.sqrt(((got - plain) ** 2).mean(-1)) / std))
         else:
             rounded = (rounded_logits_longcat if config["reference"] == "longcat_flash"
                        else rounded_logits)
             got = np.asarray(rounded(ref, params, model, tokens, **how[name]))
             served = got.argmax(-1)
             far = float(np.median(np.sqrt(((got - plain) ** 2).mean(-1)) / std))
-        gaps = (plain.max(-1) - plain[np.arange(n), served]) / std
-        print(json.dumps({"variant": name, "seed": args.seed, "positions": n,
+        gaps = (plain.max(-1) - plain[np.arange(n - first), served]) / std
+        kind = {}
+        if dots3:  # ... and through the cell's own comparison
+            v = manifest.module("harness/kinds", config["kind"]).verdict(gaps)
+            kind = {"ok": v["ok"], "near": v["near"], "near_share_pct": 100 * v["near_share"]}
+        print(json.dumps({"variant": name, "seed": args.seed, "positions": n - first, **kind,
                           "not_top1": int((gaps > 0).sum()), "off": int((gaps > TIE_STDS).sum()),
                           "off_share_pct": 100.0 * float((gaps > TIE_STDS).mean()),
                           "worst_gap_stds": float(gaps.max()),
