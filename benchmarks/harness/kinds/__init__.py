@@ -9,7 +9,9 @@ the program's counters are read:
 * ``serve_sample(served, work, seed) -> [sample]``: the seeded sample,
   served (JSON-able; the reference process judges it);
 * ``judge(ref, params, model, samples) -> verdict`` (in the reference's
-  process; ``ok`` decides ``correct``) and ``verdict_line(verdict)``;
+  process; ``ok`` decides ``correct``), ``verdict_line(verdict)`` and
+  ``compared(verdict) -> {name: [number, limit]}``, which the result's
+  line and the last lines on standard error repeat;
 * ``warm_up(served, server, work, seed) -> report`` with ``missing``;
 * ``counters(served) -> dict | None``: one reading of the program's
   counters, taken at the window's and the trace's edges.

@@ -181,3 +181,15 @@ def verdict_line(v: dict) -> str:
     return (f"{v['exact']}/{v['positions']} served tokens (prompts of {v['prompt_lens']}) are "
             f"the reference's top-1; worst gap {v['worst_gap_stds']:.4f} standard deviations "
             f"against a tolerance of {v['tie_stds']}; ok={v['ok']}")
+
+
+def compared(v: dict) -> dict:
+    """``{name: [number, limit]}``: each number a verdict of this kind or
+    of one that builds on it compared, beside its limit (the worst gap
+    against ``worst_gap_max``, or against ``tie_stds`` where every
+    position must be a near-tie; the shares against their ``_max``)."""
+    out = {"worst_gap_stds": [v["worst_gap_stds"], v.get("worst_gap_max", v["tie_stds"])]}
+    for share in ("off_share", "near_share"):
+        if share in v:
+            out[share] = [v[share], v[share + "_max"]]
+    return out

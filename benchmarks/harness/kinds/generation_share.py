@@ -60,6 +60,7 @@ import numpy as np
 
 from harness.kinds.generation import (  # noqa: F401 — the kind's interface
     TIE_STDS,
+    compared,
     content,
     counters,
     fields,

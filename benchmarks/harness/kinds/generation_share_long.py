@@ -32,6 +32,7 @@ from harness.kinds.generation_share import (  # noqa: F401 — the kind's interf
     SAMPLE_NEW,
     TIE_STDS,
     WORST_GAP_STDS,
+    compared,
     content,
     counters,
     fields,

@@ -54,6 +54,7 @@ from harness.kinds.generation_share_long import (  # noqa: F401 — the kind's i
     OFF_SHARE_MAX,
     TIE_STDS,
     WORST_GAP_STDS,
+    compared,
     content,
     counters,
     fields,

@@ -33,17 +33,27 @@ table), ``span`` = ``pages x page_size``, ``topk`` = ``index_topk``, by
   ``lanes`` and holds ``index_n_heads`` and a ``span`` — and ``(lanes,
   span)`` itself in any type but a sort's (``fusion_f32_64_7168_``: the
   sum, the masks that cut at the length and put the own score in its
-  slot);
+  slot); **or a ``pallas_kernel``** whose first output is those scores,
+  ``(lanes, span)`` or ``(lanes, pages, page_size)``: scoring fused over
+  the key pages is the same work;
 * **top-k**: a ``sort`` of ``(lanes, span)`` (``sort_f32_64_7168_``: the
   scores with positions and pool rows carried) and what is cut from its
   outputs, ``(lanes, topk)``;
 * **the attention that reads chosen rows**: the gather of the rows
   ``(lanes x topk, row lanes)`` or ``(lanes, topk, row lanes)``
   (``fusion_bf16_131072_640_``), their scores and weights ``(lanes,
-  heads, topk)``, the weighted rows ``(lanes, heads, rank)`` outside a
-  kernel (``fusion_bf16_64_128_512_``) and the flash statistics and merge
+  heads, topk)``, the weighted rows ``(lanes, heads, rank)``
+  (``fusion_bf16_64_128_512_``) and the flash statistics and merge
   with the step's own row ``(lanes, heads)`` in float32 at the full
-  layers' head count (``fusion_f32_64_128_``).  **Where a bucket has as
+  layers' head count (``fusion_f32_64_128_``); **or a ``pallas_kernel``**
+  whose first output is ``(lanes, heads, rank)``, three dims — the flash
+  state ``ops/kernels.py latent_attention_decode`` returns for a full
+  layer (``pallas_kernel_f32_64_128_512_``; ``mla_work.is_latent_kernel``
+  spells the same rule): a kernel that walks a lane's pages under a mask
+  of the chosen positions does the work of the gather, the scores and
+  the weighted rows, and is taken for what it does.  The needed work
+  stays the chosen set's cached members (``sparse_rows_read``), not the
+  rows such a kernel moves.  **Where a bucket has as
   many lanes as the layer has heads** (128 x 128) that shape is also the
   indexed prefill's softmax statistics of one group's block of 128
   queries (``fusion_f32_128_128_``: 0.064 s a 3,072-position prompt, 2 %
@@ -140,7 +150,16 @@ def plain(key: str):
     return None if key.startswith("pallas_kernel") else [d for d in dims_of(key) if d != 1]
 
 
+def kernel_dims(key: str):
+    """The dims of a ``pallas_kernel``'s first output, or None."""
+    return dims_of(key) if key.startswith("pallas_kernel") else None
+
+
 def is_index_score(key: str, z: dict) -> bool:
+    kernel = kernel_dims(key)
+    if kernel:
+        return any(kernel in ([lanes, span], [lanes, pages, z["page_size"]])
+                   for lanes in z["lanes"] for pages, span in selecting(z))
     dims = plain(key)
     if not dims or key.startswith("sort"):
         return False
@@ -165,10 +184,13 @@ def is_topk(key: str, z: dict) -> bool:
 
 
 def is_sparse_attention(key: str, z: dict) -> bool:
+    heads, rank, topk = z["num_attention_heads"], z["kv_lora_rank"], z["index_topk"]
+    kernel = kernel_dims(key)
+    if kernel:
+        return len(kernel) == 3 and kernel[0] in z["lanes"] and kernel[1:] == [heads, rank]
     dims = plain(key)
     if not dims:
         return False
-    heads, rank, topk = z["num_attention_heads"], z["kv_lora_rank"], z["index_topk"]
     row = lanes_of(rank + z["qk_rope_head_dim"])
     for lanes in z["lanes"]:
         if dims in ([lanes * topk, row], [lanes, topk, row], [lanes * topk, rank],

@@ -5,7 +5,8 @@ scored, over the full layers, counted by the program
 FLOP (``dots3_work``); the least time is the larger of bytes over HBM
 bytes/s and FLOPs over bf16 FLOP/s (the bytes, at 64 FLOP a byte); the
 share is that over the traced seconds of the scoring operations (the
-keys' gather, the heads' products, the weighted sum: not the top-k).
+keys' gather, the heads' products, the weighted sum, or a Pallas kernel
+whose output is the ``(lanes, span)`` scores: not the top-k).
 
 Counter and seconds are both of the traced interval."""
 

@@ -6,7 +6,9 @@ FLOP at 128 heads (``dots3_work``); the least time is the larger of bytes
 over HBM bytes/s and FLOPs over bf16 FLOP/s; the share is that over the
 traced seconds of the operations that gather the chosen rows, score them,
 weight them and merge the step's own row (``dots3_work``'s rule: XLA's
-gather and einsums, no Pallas kernel yet).
+gather and einsums, or a Pallas kernel whose output is the full layers'
+flash state ``(lanes, heads, rank)``: whichever does the work, the rows
+needed are the chosen set's, not the rows a kernel moves).
 
 Counter and seconds are both of the traced interval."""
 

@@ -18,7 +18,9 @@ request travels to the traffic file's protocol
 
 Earlier lines say what set-up was spent on, whether anything compiled
 inside the window, how many samples stand behind each percentile and
-how busy the generator was.  The last line is the result object.
+how busy the generator was.  The last line is the result object; its
+last key, ``compared``, holds each number ``correct`` compared beside its
+limit, and the last lines on standard error say the same.
 Without an accelerator (or outside a checkout of the program) the exit
 code is not 0 and no result is printed; ``--rehearse-cpu`` is the
 explicit toy-size CPU mode of the tests, marked ``"rehearsal": true``.
@@ -362,7 +364,14 @@ def run(args, t_start: float, children: list) -> int:
         result["breakdown"] = trace["breakdown"]
     if args.rehearse_cpu:
         result["rehearsal"] = True
+    # each number ``correct`` compared, beside its limit: the line's last key
+    # and the last lines on standard error
+    compared = dict(getattr(kind, "compared", lambda _v: {})(verdict),
+                    failed_requests=[len(failed), 0])
+    result["compared"] = compared
     print(json.dumps(result), flush=True)
+    for name, (number, limit) in compared.items():
+        sys.stderr.write(f"[bench] compared {name}: {number} against a limit of {limit}\n")
     return 0
 
 

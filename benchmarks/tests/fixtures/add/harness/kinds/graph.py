@@ -64,3 +64,7 @@ def judge(ref, params, model: dict, samples: list) -> dict:
 def verdict_line(v: dict) -> str:
     return (f"{v['rows']} served score rows lie within {v['worst_abs']:.2e} of the "
             f"reference's against a tolerance of {v['atol']}; ok={v['ok']}")
+
+
+def compared(v: dict) -> dict:
+    return {"worst_abs": [v["worst_abs"], v["atol"]]}

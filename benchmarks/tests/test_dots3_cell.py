@@ -334,6 +334,65 @@ def test_the_merge_at_a_bucket_as_wide_as_the_heads_is_counted_when_that_bucket_
     assert reader(ctx) == pytest.approx(two_buckets + 100 * 0.064 / 3.0576)
 
 
+def test_the_readings_do_not_know_what_did_the_work():
+    """The same counters and the same seconds, once in PR 38's operations
+    (XLA's gather of the chosen rows, the merge and statistics, the
+    weighted rows) and once in ONE ``pallas_kernel`` whose output is the
+    full layers' flash state beside the merge: the sparse readers read
+    the same, and ``step_mfu_pct`` the same again on a trace whose
+    operations are renamed wholesale."""
+    from layer_metrics import dots3_work as work
+
+    names = ("sparse_mla_kernel_roofline", "sparse_window_time_share_pct", "step_mfu_pct")
+    readers = {name: manifest.reader("layer_metrics", name) for name in names}
+    others = {"fusion_bf16_7168_64_128_": 0.106, "fusion_f32_64_7168_": 0.039,
+              "sort_f32_64_7168_": 0.109, "pallas_kernel_f32_128_64_1024_": 0.074,
+              "pallas_kernel_f32_128_1536_": 0.098, "fusion_f32_128_": 0.097}
+    xla = dict(others, **{"fusion_bf16_131072_640_": 1.009, "fusion_f32_64_128_": 0.124,
+                          "fusion_bf16_64_128_512_": 0.093})
+    kernel = dict(others, **{"pallas_kernel_f32_64_128_512_": 1.216, "fusion_f32_64_128_": 0.010})
+    assert sum(xla.values()) == pytest.approx(sum(kernel.values()))
+    renamed = {f"op_{i}": v for i, v in enumerate(xla.values())}
+
+    def ctx_of(ops):
+        ctx = recorded_ctx()
+        for snapshot, scale in zip(ctx["engine"]["trace"], (0, 1)):
+            snapshot.update(prefill_tokens=9216 * scale, prefills=3 * scale,
+                            decode_lane_steps=10_240 * scale,
+                            moe_local_assignments=24_000 * scale)
+        ctx["trace"] = {"busy_s": 3.061, "window_s": 3.065,
+                        "modules": {"jit_paged_chunk_s8_64x64_64x112": {"count": 10,
+                                                                        "seconds": 2.33}},
+                        "ops": {k: {"count": 1, "seconds": v} for k, v in ops.items()}}
+        return ctx
+
+    got = {kind: {name: read(ctx_of(ops)) for name, read in readers.items()}
+           for kind, ops in (("xla", xla), ("kernel", kernel), ("renamed", renamed))}
+    assert got["kernel"] == pytest.approx(got["xla"])
+    assert 0 < got["xla"]["sparse_mla_kernel_roofline"] < 100
+    assert 0 < got["xla"]["step_mfu_pct"] < 100
+    assert got["renamed"]["step_mfu_pct"] == got["xla"]["step_mfu_pct"]
+    assert got["renamed"]["sparse_mla_kernel_roofline"] is None
+    # the needed work is the chosen set's, whatever a kernel moved to find it
+    least = max(69_206_016 * 1152 / 8.19e11, 69_206_016 * 278_528 / 1.97e14)
+    assert got["kernel"]["sparse_mla_kernel_roofline"] == pytest.approx(100 * least / 1.226)
+    # a scoring kernel is the indexer's, by its scores' shape; the kernels of
+    # other layers stay theirs
+    z = work.sizes(config())
+    for key in ("pallas_kernel_f32_64_7168_", "pallas_kernel_f32_64_112_64_",
+                "pallas_kernel_f32_128_4096_", "pallas_kernel_bf16_64_64_64_"):
+        assert work.is_index_score(key, z) and not work.is_sparse_attention(key, z), key
+    assert work.is_sparse_attention("pallas_kernel_f32_128_128_512_", z)
+    for key in ("pallas_kernel_f32_128_64_1024_", "pallas_kernel_f32_128_1536_",
+                "pallas_kernel_f32_128_5120_", "pallas_kernel_bf16_64_3072_128_",
+                "pallas_kernel_f32_64_64_512_", "pallas_kernel_f32_32_128_512_",
+                "pallas_kernel_f32_64_2048_"):
+        assert not work.is_sparse_attention(key, z) and not work.is_index_score(key, z), key
+    rules = (work.is_index_score, work.is_topk, work.is_sparse_attention,
+             work.is_window_kernel)
+    assert all(sum(r(k, z) for r in rules) <= 1 for k in list(xla) + list(kernel))
+
+
 def test_the_kinds_three_limits():
     """``generation_share``'s two limits and the third, which a program
     that reads every row fails where the first two pass it."""

@@ -272,12 +272,12 @@ def test_the_traffic_reaches_twelve_programs_and_every_request_fits():
     cellrow = next(w for w in m["workloads"] if w["name"] == SHIPPED)
     assert cellrow["chips"] == 1 and len(cellrow["why"]) <= 200
     new = [x for x in m["per_layer"] if x["name"] in NEW]
-    assert len(new) == 6 and all(x["workloads"] == [SHIPPED] and x["moves"] == "out_tok_s"
+    assert len(new) == 6 and all(SHIPPED in x["workloads"] and x["moves"] == "out_tok_s"
                                  for x in new)
     # how uneven the held experts' load is: OLMoE's reader, which reads the engine's own
     # ``moe_load_max`` / ``moe_load_mean`` (over the held experts where a share is held)
     load = next(x for x in m["per_layer"] if x["name"] == "expert_load_max_over_mean")
-    assert load["workloads"][-1] == SHIPPED
+    assert SHIPPED in load["workloads"]
     assert not any(x["name"] == "mla_proj_time_share_pct" for x in m["per_layer"])
 
 

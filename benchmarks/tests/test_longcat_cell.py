@@ -410,16 +410,17 @@ def test_the_traffic_reaches_sixteen_programs_and_every_request_fits():
     assert sample == 257 + 514 + 1024 + 384
     assert cellrow["chips"] == 1 and len(cellrow["why"]) <= 200
     new = [x for x in m["per_layer"] if x["name"] in NEW]
-    assert len(new) == 4 and all(x["workloads"] == [SHIPPED] and x["moves"] == "out_tok_s"
+    assert len(new) == 4 and all(SHIPPED in x["workloads"] and x["moves"] == "out_tok_s"
                                  for x in new)
     for name in JOINED + ("out_tok_s", "decode_step_ms", "hbm_peak_gib"):
         metric = next(x for x in m["end_to_end"] + m["per_layer"] if x["name"] == name)
-        assert metric["workloads"][-1] == SHIPPED, name
+        assert SHIPPED in metric["workloads"], name
     for name in ("moe_time_share_pct", "moe_decode_roofline", "experts_active_mean",
                  "paged_kernel_roofline", "paged_decode_roofline", "kernel_time_share_pct"):
         metric = next(x for x in m["per_layer"] if x["name"] == name)
         assert SHIPPED not in metric["workloads"], name
-    assert len(m["workloads"]) == 5 and len(m["configs"]) == 4
+    assert SHIPPED in [w["name"] for w in m["workloads"]]
+    assert "longcat-flash-omni" in [c["name"] for c in m["configs"]]
 
 
 def test_the_plain_reference_s_own_continuation_is_correct_and_a_wrong_one_is_not():

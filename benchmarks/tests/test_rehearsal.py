@@ -36,11 +36,22 @@ def test_a_cell_runs_and_its_last_line_parses(tree, trace):
     assert out.returncode == 0, out.stderr[-3000:]
     lines = out.stdout.strip().splitlines()
     result = json.loads(lines[-1])
-    assert set(result) - {"rehearsal", "breakdown"} == RESULT_KEYS and result["rehearsal"]
+    assert set(result) - {"rehearsal", "breakdown", "compared"} == RESULT_KEYS
+    assert result["rehearsal"]
     assert result["correct"] is True and result["failed"] == 0 and result["attempted"] > 0
+    # each number compared beside its limit: the line's last key, and said again by
+    # the last lines on standard error
+    assert list(result)[-1] == "compared"
+    assert set(result["compared"]) == {"worst_gap_stds", "failed_requests"}
+    assert all(number <= limit for number, limit in result["compared"].values())
+    said = out.stderr.strip().splitlines()[-len(result["compared"]):]
+    assert [line.split()[2].rstrip(":") for line in said] == list(result["compared"]), said
     assert result["device"]["platform"] == "cpu"
-    for metric in result["metrics"].values():
-        assert set(metric) == {"value", "unit"} and metric["value"] > 0
+    for name, metric in result["metrics"].items():
+        # the share of token events that found the next one queued is a true 0 in
+        # about half of the toy runs (and in every cell on the chip)
+        assert set(metric) == {"value", "unit"}
+        assert metric["value"] > 0 or name == "deliver_behind_pct", name
     earlier = "\n".join(lines[:-1])
     for said in ("set-up:", "samples behind each percentile", "generator busy share",
                  "reference:"):
@@ -67,6 +78,8 @@ def test_a_cell_of_another_kind_over_another_protocol_arrives_as_files(tree, tra
                                       else {"batch_rows_mean"})
     assert all(v["value"] > 0 for v in result["metrics"].values())
     assert "served score rows lie within" in "\n".join(lines[:-1])
+    worst, limit = result["compared"]["worst_abs"]
+    assert 0 <= worst <= limit and result["compared"]["failed_requests"] == [0, 0]
 
 
 def test_without_a_chip_the_measurement_path_fails(tree):

@@ -111,12 +111,14 @@ def test_the_helper_picks_the_last_sample_before_the_trace_and_none_inside_it():
 def test_every_new_metric_is_declared_for_its_cells_and_moves_their_metric():
     m = manifest.load_manifest()
     by_name = {e["name"]: e for e in m["per_layer"]}
-    saturated = [w["name"] for w in m["workloads"] if w["name"].endswith("-saturated")]
-    assert len(saturated) == 4
+    cells = {w["name"] for w in m["workloads"]}
     for name, _want in CASES:
         entry = by_name[name]
         assert entry["source"] == "program_counter"
         doc = name in ("engine_ttft_mean_ms", "first_token_mean_ms")
-        assert entry["workloads"] == (["gpt2-large.doc-prefill"] if doc else saturated)
+        # each entry against its own list: a cell whose stretch holds nothing
+        # for a reader to read is not on that reader's list (PERF.md section 3)
+        assert entry["workloads"] and set(entry["workloads"]) <= cells
+        assert ("gpt2-large.doc-prefill" in entry["workloads"]) == doc
+        assert all(w.endswith("-saturated") for w in entry["workloads"]) != doc
         assert entry["moves"] == ("ttft_p50_ms" if doc else "out_tok_s")
-    assert [e["name"] for e in m["per_layer"][-7:]] == [name for name, _ in CASES]
