@@ -552,15 +552,19 @@ def _build_modules():
         indexer keys)``: a segment from zero attends each row's best
         ``topk`` positions (``ops/mla.py indexed_attention``); a decode
         step whose bucket holds a lane with ``topk`` cached positions or
-        more scores the cached keys, takes the best ``topk`` of them and
-        the step's own and reads those rows alone (``sparse_select``: a
-        gather of rows, then ``ctx_state``), and any other bucket runs
-        the page loop over every row, as a spec without an indexer.
-        A decode step of a kind also says what it read, as a third value
-        ``int32[2]``: the cached indexer keys it scored and the cached
-        rows its attention read (the lengths it handed the kernel, the
-        chosen set's cached members), over the lanes ``counted`` ``(B,
-        1)`` keeps."""
+        more scores the cached keys, keeps the best ``topk`` of them and
+        the step's own as a mask (``kth_mask``, the prefill's rule) and
+        runs the same page loop under it — the kernel streams the lane's
+        rows and the masked ones weigh exactly 0 (``chosen=``; the
+        one-layer lane hands ``ctx_state`` the mask) — and any other
+        bucket runs the page loop over every row, as a spec without an
+        indexer.  A decode step of a kind also says what it read, as a
+        third value ``int32[3]``: the cached indexer keys it scored, the
+        cached rows its attention read (the lengths it handed the
+        kernel; where it selected, the chosen set's cached members) and
+        the rows the page loop streamed under a mask (the lengths again:
+        over the rows read, what a kernel that skipped pages could
+        save), over the lanes ``counted`` ``(B, 1)`` keeps."""
         from dataclasses import replace as _replace
 
         from seldon_core_tpu.models.spec import (
@@ -716,58 +720,58 @@ def _build_modules():
             live = (jnp.ones((nb,), bool) if counted is None
                     else counted[sl].reshape(nb))
 
-            def tally(keys, rows, live=live):
-                """``int32[2]``: per-lane counts summed over the lanes
+            def tally(keys, rows, moved=0, live=live):
+                """``int32[3]``: per-lane counts summed over the lanes
                 that run."""
-                return jnp.stack([jnp.where(live, n, 0).sum() for n in (keys, rows)]
-                                 ).astype(jnp.int32)
+                return jnp.stack([jnp.where(live, n, 0).sum()
+                                  for n in (keys, rows, moved)]).astype(jnp.int32)
 
-            def dense(q_full=q_full, tb=tb, sl=sl, own=own, offset=offset):
+            def cached_state(q_full=q_full, tb=tb, sl=sl, offset=offset,
+                             **chosen):
+                """The flash state of the bucket's cached rows (a
+                window's live ones; of them those a mask ``chosen``
+                ``(nb, C)`` keeps, where one is handed over)."""
+                if whole:
+                    return kernels.latent_attention_decode(
+                        q_full, pool, tb, lengths[sl], layer=layer,
+                        page_size=pool.shape[2], rank=rank, **offset, **chosen)
+                rows = cached(tb)
+                at = jnp.arange(rows.shape[1])[None, :]
+                valid = at < lengths[sl][:, None]
+                if offset:
+                    valid &= at >= w_first[sl][:, None]
+                for mask in chosen.values():
+                    valid &= mask
+                return mla.ctx_state(q_full, rows, valid, rank)
+
+            def dense(q_full=q_full, sl=sl, own=own):
                 """Every cached row (a window's live ones), then the
                 step's own by the flash rule."""
                 first = w_first[sl] if windowed else 0
-                if whole:
-                    from seldon_core_tpu.ops.kernels import (
-                        latent_attention_decode,
-                    )
-
-                    state = latent_attention_decode(
-                        q_full, pool, tb, lengths[sl], layer=layer,
-                        page_size=pool.shape[2], rank=rank, **offset)
-                else:
-                    rows = cached(tb)
-                    at = jnp.arange(rows.shape[1])[None, :]
-                    valid = at < lengths[sl][:, None]
-                    if offset:
-                        valid &= at >= w_first[sl][:, None]
-                    state = mla.ctx_state(q_full, rows, valid, rank)
                 return mla.merge(
-                    state, mla.ctx_state(
+                    cached_state(), mla.ctx_state(
                         q_full, own, jnp.ones(own.shape[:2], bool), rank)
                 ), tally(0, jnp.maximum(lengths[sl] - first, 0))
 
             def sparse(q_full=q_full, tb=tb, sl=sl, own=own):
                 """The indexer's best ``topk`` of the cached positions
-                and the step's own: their rows alone."""
-                ps = pool.shape[-2]
+                and the step's own, as a mask over the table's span
+                (``step_mask``: ``kth_mask``, a prefill's rule): the
+                page loop streams the lane's rows and weighs the chosen
+                alone."""
                 keys = idx_pool[layer, tb] if whole else idx_pool[tb]
                 keys = keys.reshape(nb, -1, keys.shape[-1])
-                cached_sc = mla.index_scores(
-                    q_i[sl], w_i[sl], keys, i_scale)[:, 0]
+                scores = mla.index_scores(q_i[sl], w_i[sl], keys, i_scale)[:, 0]
                 own_sc = mla.index_scores(
                     q_i[sl], w_i[sl], key_row[sl], i_scale)[:, 0, 0]
-                # each position's row in the pool (pages x page_size
-                # rows a layer), carried through the selection's sort
-                row_at = (tb[:, :, None] * ps + jnp.arange(ps)).reshape(nb, -1)
-                _at, is_cached, own_in, chosen = mla.sparse_select(
-                    cached_sc, own_sc, lengths[sl], topk, carry=row_at)
-                flat = pool.reshape(*pool.shape[:-3], -1, pool.shape[-1])
-                rows = flat[layer, chosen] if whole else flat[chosen]  # (nb, k, W)
+                is_cached, own_in = mla.step_mask(
+                    scores, own_sc, lengths[sl], topk)
+                # (the kernel streams every row the indexer scored)
+                scored = jnp.minimum(lengths[sl], keys.shape[1])
                 return mla.merge(
-                    mla.ctx_state(q_full, rows, is_cached, rank),
+                    cached_state(chosen=is_cached),
                     mla.ctx_state(q_full, own, own_in[:, None], rank)
-                ), tally(jnp.minimum(lengths[sl], keys.shape[1]),
-                         is_cached.sum(axis=-1))
+                ), tally(scored, is_cached.sum(axis=-1), scored)
 
             if topk and tb.shape[1] * pool.shape[-2] > topk:
                 latent, read = jax.lax.cond(
@@ -1420,7 +1424,7 @@ def _build_modules():
             logits = _dense(self.precision, self.vocab_size, self.dtype,
                             "head", spec)(x)
             # (a decode step's fifth value: what each layer read,
-            # int32[layers, 2] — _latent_attention)
+            # int32[layers, 3] — _latent_attention)
             return (logits.astype(jnp.float32),
                     {n: jnp.stack(r) for n, r in rows.items()}, None,
                     jnp.stack(hists), *((jnp.stack(reads),) if reads else ()))
@@ -3344,7 +3348,7 @@ class PagedEngine:
                           # window layers' pages given back
                           "index_keys_scored": 0, "sparse_rows_read": 0,
                           "sparse_rows_cached": 0, "sparse_lane_steps": 0,
-                          "window_rows_read": 0,
+                          "window_rows_read": 0, "sparse_rows_moved": 0,
                           "window_pages_released": 0,
                           # waiting where it happens: seconds (and
                           # streams) between submit and a stream's first
@@ -4461,16 +4465,17 @@ class PagedEngine:
     # the columns of a spec with layer kinds' extra counter row
     SPARSE_COUNTERS = ("index_keys_scored", "sparse_rows_read",
                        "sparse_rows_cached", "sparse_lane_steps",
-                       "window_rows_read")
+                       "window_rows_read", "sparse_rows_moved")
 
     def _sparse_step(self, lengths, active, reads):
-        """One decode step's row of a spec with layer kinds, ``int32[5]``
+        """One decode step's row of a spec with layer kinds, ``int32[6]``
         (:data:`SPARSE_COUNTERS`).  What was read is the blocks' own
-        account (``reads`` ``int32[layers, 2]``, ``_latent_attention``:
+        account (``reads`` ``int32[layers, 3]``, ``_latent_attention``:
         the cached indexer keys a layer scored, the cached rows its
         attention read — every row where the bucket ran the page loop,
         the chosen set's cached members where it selected, a window's
-        live rows), summed by the layers' kind.  What it is held against
+        live rows — and the rows the page loop streamed under a
+        selection's mask), summed by the layers' kind.  What it is held against
         comes from the lengths the step starts at: ``sparse_rows_cached``
         the rows cached for the active lanes times the full layers,
         ``sparse_lane_steps`` the lanes holding ``index_topk`` or more."""
@@ -4484,6 +4489,7 @@ class PagedEngine:
             cached.sum() * full,
             (active & (lengths >= spec.index_topk)).sum(),
             jnp.where(windowed, reads[:, 1], 0).sum(),
+            reads[:, 2].sum(),
         ]).astype(jnp.int32)
 
     def _moe_step(self, moe, hist, active, sparse=None):

@@ -1160,7 +1160,8 @@ def _latent_pages_per_step(page_size: int, table_width: int,
 
 
 def _latent_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
-                             page_size, rank, group, offset=False):
+                             page_size, rank, group, offset=False,
+                             masked=False):
     """One lane of absorbed latent attention: the stream kernel's page
     loop (:func:`_paged_attention_kernel`) over ONE pool whose row is
     ``[c_kv ; k_r]``, read once for all heads.
@@ -1194,7 +1195,13 @@ def _latent_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
     ``offset``: a fourth scalar-prefetch operand ``starts`` ``(B,)``
     gives each lane's first live position (a window's trailing edge):
     the page loop starts at the step that holds it and positions before
-    it are masked like those past the length."""
+    it are masked like those past the length.
+
+    ``masked``: a second blocked operand ``chosen`` ``(1, steps, span)``
+    int32, the lane's row of a mask over its table's span cut by the
+    loop's steps: a position whose entry is 0 gets weight 0 — a
+    selection's rows alone are attended.  The lane's pages are fetched
+    by its length all the same; the mask only cuts weights."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -1202,7 +1209,13 @@ def _latent_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
 
     if offset:
         starts_ref, *refs = refs
-    q_ref, pool_hbm, acc_ref, m_ref, l_ref, buf, sems, turn_ref = refs
+    q_ref, *refs = refs
+    if masked:
+        chosen_ref, *refs = refs
+    pool_hbm, acc_ref, m_ref, l_ref, buf, sems, turn_ref = refs
+    # either may leave a whole step without a live position, and either
+    # caller's lengths may fall below zero (an idle lane)
+    sparse = offset or masked
     b = pl.program_id(0)
     lanes = pl.num_programs(0)
     layer = layer_ref[0]
@@ -1230,7 +1243,7 @@ def _latent_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
             jax.lax.div(lens_ref[lane] + page_size - 1, page_size), width)
         # (a length below zero is an empty lane too: neither branch
         # below would take it, and the hand-on chain would break there)
-        return jnp.maximum(pages, 0) if offset else pages
+        return jnp.maximum(pages, 0) if sparse else pages
 
     def copies(lane, j, slot):
         n = pages_of(lane)
@@ -1310,9 +1323,12 @@ def _latent_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
             live = at < jnp.minimum(length, width * page_size)
             if offset:
                 live &= at >= starts_ref[b]
+            if masked:
+                live &= chosen_ref[0, pl.ds(j, 1), :] != 0
             s = jnp.where(live, s, -jnp.inf)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            if offset:  # a step wholly before the window's edge
+            if sparse:  # a step wholly before the window's edge, or
+                # one that holds no chosen row
                 m_at = jnp.where(m_new == -jnp.inf, 0.0, m_new)
                 alpha = jnp.exp(m_prev - m_at)
                 w = jnp.exp(s - m_at)
@@ -1347,8 +1363,8 @@ def _given(value) -> tuple:
     return (value,)
 
 
-def _latent_decode(q, pool, block_tables, lengths, layer, starts=None, *,
-                   rank, step_tokens, interpret):
+def _latent_decode(q, pool, block_tables, lengths, layer, starts=None,
+                   chosen=None, *, rank, step_tokens, interpret):
     """The latent kernel's ``pallas_call`` on the whole pool (see
     :func:`latent_attention_decode`, which calls it jitted)."""
     import jax
@@ -1364,10 +1380,21 @@ def _latent_decode(q, pool, block_tables, lengths, layer, starts=None, *,
     acc_spec = pl.BlockSpec((1, h, rank), lambda b, *prefetch: (b, 0, 0))
     pad_spec = pl.BlockSpec((1, h, 128), lambda b, *prefetch: (b, 0, 0))
     starts = _given(starts)  # a fourth scalar-prefetch operand, or none
+    # a second blocked operand, or none: a lane's row of the mask, a row
+    # of ``span`` positions a step of its page loop (a dynamic index on
+    # the sublanes, where Mosaic takes one); a last step that reaches
+    # past the table's span holds 0 there
+    steps = -(-block_tables.shape[1] // group)
+    chosen = tuple(
+        jnp.pad(mask, ((0, 0), (0, steps * span - mask.shape[1]))
+                ).reshape(B, steps, span)
+        for mask in _given(chosen))
+    mask_specs = [pl.BlockSpec((1, steps, span),
+                               lambda b, *prefetch: (b, 0, 0))] * len(chosen)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3 + len(starts),
         grid=(B,),
-        in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=[q_spec, *mask_specs, pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=[acc_spec, pad_spec, pad_spec],
         scratch_shapes=[
             pltpu.VMEM((2, span, W), pool.dtype),
@@ -1377,7 +1404,8 @@ def _latent_decode(q, pool, block_tables, lengths, layer, starts=None, *,
     )
     acc, m, l = pl.pallas_call(
         functools.partial(_latent_attention_kernel, page_size=ps, rank=rank,
-                          group=group, offset=len(starts) == 1),
+                          group=group, offset=len(starts) == 1,
+                          masked=len(chosen) == 1),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, h, rank), jnp.float32),
@@ -1388,7 +1416,7 @@ def _latent_decode(q, pool, block_tables, lengths, layer, starts=None, *,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(block_tables, lengths, layer.reshape(1), *starts,
-      q.astype(pool.dtype), pool)
+      q.astype(pool.dtype), *chosen, pool)
     return acc, m[:, :, 0], l[:, :, 0]
 
 
@@ -1401,7 +1429,7 @@ def _latent_decode_jit():
 
 
 def latent_attention_decode(q, pool, block_tables, lengths, *, layer,
-                            page_size, rank, starts=None):
+                            page_size, rank, starts=None, chosen=None):
     """Unnormalised flash state of absorbed latent attention (MLA) over
     one layer of a paged pool of latent rows, addressed IN the whole
     pool: :func:`paged_attention_decode`'s twin for a cache whose row is
@@ -1414,11 +1442,17 @@ def latent_attention_decode(q, pool, block_tables, lengths, *, layer,
     int32 scalar; ``block_tables`` ``(B, P)``; ``lengths`` ``(B,)``;
     ``rank`` — the row's leading values that are also the value read.
     Returns ``(acc (B, h, rank), m (B, h), l (B, h))`` float32 — what
-    ``ops/mla.py ctx_state`` returns for the gathered rows; join the
-    step's own row with ``ops/mla.py merge``.  ``starts`` ``(B,)``
+    ``ops/mla.py ctx_state`` returns for the same rows under the same
+    mask; join the step's own row with ``ops/mla.py merge``.  ``starts`` ``(B,)``
     int32 (None: 0): a lane attends positions ``starts .. lengths - 1``
     of its table's span only — a window's live rows — and pages wholly
-    before ``starts`` are not read.
+    before ``starts`` are not read.  ``chosen`` ``(B, P * page_size)``
+    bool (None: every position): of those positions a lane attends the
+    ones its row of the mask keeps — a selection's rows, each at weight
+    exactly 0 or as the flash rule has it; the kernel streams the lane's
+    pages by its length whatever the mask says.  Either is a fact of
+    the call's structure: without it the kernel traced is the one a
+    caller that never heard of it traces.
 
     A row is read once for all heads: at 64 heads and 512 + 64 values
     it needs 1,152 B and 139,264 FLOP; the DMA moves its 640 lanes,
@@ -1434,8 +1468,14 @@ def latent_attention_decode(q, pool, block_tables, lengths, *, layer,
         raise ValueError(
             f"page_size={page_size} does not match the pool's page dim "
             f"{pool.shape[2]}")
+    span = (q.shape[0], block_tables.shape[1] * page_size)
+    if chosen is not None and chosen.shape != span:
+        raise ValueError(
+            f"chosen masks the table's span {span}, got {chosen.shape}")
     return _latent_decode_jit()(
         q, pool, block_tables, lengths, jnp.asarray(layer, jnp.int32),
-        *(s.astype(jnp.int32) for s in _given(starts)),
+        **{name: value.astype(jnp.int32)
+           for name, value in (("starts", starts), ("chosen", chosen))
+           if value is not None},
         rank=int(rank), step_tokens=LATENT_STEP_TOKENS,
         interpret=interpret_mode())

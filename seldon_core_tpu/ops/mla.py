@@ -13,6 +13,11 @@ step wants, since it reads each cached row once for all heads.  The
 Pallas kernel of the absorbed form is ``ops/kernels.py
 latent_attention_decode``; it returns the same unnormalised flash state
 as :func:`ctx_state`, and :func:`merge` joins states by the flash rule.
+**Learned sparse attention** (:func:`index_scores`, :func:`kth_mask`,
+:func:`step_mask`, :func:`indexed_attention`) chooses by a mask in both
+forms: a prefill's block of queries masks its scores, a decode step
+hands the mask to the page loop (``latent_attention_decode(chosen=)``)
+or to :func:`ctx_state` as ``valid``.
 """
 
 from __future__ import annotations
@@ -163,7 +168,11 @@ def index_scores(q_idx, w_idx, keys, scale: float):
 def kth_mask(scores, allowed, k: int):
     """Which of each row's ``allowed`` entries are among its ``k``
     largest, ties to the lower index: bool like ``scores`` ``(..., C)``.
-    A row with ``k`` allowed entries or fewer keeps them all."""
+    A row with ``k`` allowed entries or fewer keeps them all.  The one
+    selection rule: a prefill's rows (:func:`indexed_attention`) and a
+    decode step's cached positions with its own (:func:`step_mask`)
+    both take their chosen set from here, as a mask — no position list
+    is sorted out and no row gathered by it."""
     import jax
     import jax.numpy as jnp
 
@@ -187,41 +196,22 @@ def any_over(lengths, topk: int):
     return jnp.any(lengths >= topk)
 
 
-def sparse_select(scores, own_score, lengths, topk: int, carry=None):
-    """A decode step's chosen positions: of each lane's ``lengths``
+def step_mask(scores, own_score, lengths, topk: int):
+    """A decode step's chosen set, as a mask: of each lane's ``lengths``
     cached positions (``scores`` ``(B, C)``, the table's whole span) and
     its own (``own_score`` ``(B,)``, position ``lengths``) the ``topk``
-    of largest score, ties to the lower position — every one of them
-    while there are ``topk`` or fewer.  Returns ``(at (B, k), cached (B,
-    k), own (B,))``: the chosen positions, which of them are cached rows
-    to read (the rest are blanks or the lane's own position), and
-    whether the step's own row is among the chosen.
-
-    ``carry`` ``(B, C)`` int32 (each position's row in the pool) rides
-    through the sort and comes back for the chosen as a fourth value
-    ``(B, k)``: looked up afterwards it would be a gather of ``B * k``
-    single elements, which a v5e runs at ~8 ns an element — a sixth of a
-    decode step at 128 lanes and 2,048 rows (PERF.md section 6, PR 38).
-    One sort either way: ``lax.top_k`` of this many is a full sort on
-    the chip too, so the sort is spelt out."""
-    import jax
+    of largest score by :func:`kth_mask`'s rule.  Returns ``(cached (B,
+    C), own (B,))``: which cached rows are attended — what the page loop
+    takes as ``chosen`` — and whether the step's own row is."""
     import jax.numpy as jnp
 
     at = jnp.arange(scores.shape[1])[None, :]
     here = lengths[:, None]
-    s = jnp.where(at < here, scores, -jnp.inf)
-    s = jnp.where(at == here, own_score[:, None], s)
-    k = min(topk, scores.shape[1])
-    # descending by score, ties to the lower position: ascending by
-    # (-score, position), as ``lax.top_k`` orders them
-    neg, idx, *rows = jax.lax.sort(
-        (-s, jnp.broadcast_to(at, s.shape).astype(jnp.int32),
-         *(() if carry is None else (carry,))),
-        dimension=1, num_keys=2)
-    idx = idx[:, :k]
-    valid = neg[:, :k] < jnp.inf
-    own = valid & (idx == here)
-    return (idx, valid & ~own, own.any(axis=-1), *(r[:, :k] for r in rows))
+    # the step's own position stands in its own column
+    is_own = at == here
+    mask = kth_mask(
+        jnp.where(is_own, own_score[:, None], scores), at <= here, topk)
+    return mask & (at < here), (mask & is_own).any(axis=-1)
 
 
 def indexed_attention(q_nope, q_rope, seg, w_uk, w_uv, scale: float, dtype,

@@ -614,6 +614,10 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
     "window_rows_read": (
         "counter", "seldon_tpu_engine_window_rows_read_total",
         "cached rows the window layers' decode attention read"),
+    "sparse_rows_moved": (
+        "counter", "seldon_tpu_engine_sparse_rows_moved_total",
+        "cached rows the page loop streamed under a selection's mask "
+        "(over sparse_rows_read: what a page-skipping kernel could save)"),
     "window_pages_released": (
         "counter", "seldon_tpu_engine_window_pages_released_total",
         "window-layer pages given back to their allocator behind the "

@@ -198,6 +198,8 @@ class TestLogits:
         assert 0 < d["sparse_rows_read"] < d["sparse_rows_cached"]
         assert d["index_keys_scored"] > 0 and d["window_rows_read"] > 0
         assert d["sparse_lane_steps"] > 0
+        # what the page loop streamed under a mask: more than was needed
+        assert d["sparse_rows_read"] < d["sparse_rows_moved"] <= d["sparse_rows_cached"]
         assert after["window_pages_released"] > before["window_pages_released"]
 
     def test_the_read_counters_are_the_blocks_own_account(self, f32_engine):
@@ -221,6 +223,8 @@ class TestLogits:
             int(s[t, :t].sum()) for s in chosen for t in at)
         assert d["sparse_rows_read"] < 2 * TOPK * steps  # the own row was chosen somewhere
         assert d["index_keys_scored"] == d["sparse_rows_cached"] == 2 * sum(at)
+        # every step selected: the page loop streamed each lane's rows
+        assert d["sparse_rows_moved"] == 2 * sum(at)
         assert d["sparse_lane_steps"] == steps
         assert d["window_rows_read"] == 2 * (WINDOW - 1) * steps
 
@@ -253,10 +257,10 @@ class TestLogits:
 
 class TestSelection:
     def test_chosen_set_is_the_references_at_every_step(self):
-        """``sparse_select`` (a decode step: cached scores and the own)
-        and ``kth_mask`` (a prefill's rows) keep exactly the positions
-        the reference's stable sort keeps, ties included, at every
-        length from one to past ``topk``."""
+        """``step_mask`` (a decode step: cached scores and the own) and
+        ``kth_mask`` (a prefill's rows) keep exactly the positions the
+        reference's stable sort keeps, ties included, at every length
+        from one to past ``topk``."""
         rng = np.random.default_rng(2)
         n, topk = 40, 12
         # few distinct values: ties at the cut are the rule
@@ -267,12 +271,56 @@ class TestSelection:
         np.testing.assert_array_equal(got, want)
         for t in range(n):
             cached = np.where(np.arange(n) < t, scores[t], 7.0)  # junk past the length
-            at, is_cached, own = mla.sparse_select(
+            is_cached, own = mla.step_mask(
                 jnp.asarray(cached)[None], jnp.asarray(scores[t, t:t + 1]),
                 jnp.asarray([t]), topk)
-            at, is_cached = np.asarray(at[0]), np.asarray(is_cached[0])
-            chosen = set(at[is_cached].tolist()) | ({t} if bool(own[0]) else set())
+            chosen = set(np.nonzero(np.asarray(is_cached[0]))[0].tolist()) | (
+                {t} if bool(own[0]) else set())
             assert chosen == set(np.nonzero(want[t])[0].tolist()), t
+
+    def test_the_kernel_lane_and_the_one_layer_lane_are_one_block(self, monkeypatch):
+        """A full layer's decode step that selects, called as the kernel
+        lane calls it (the whole pools and the layer's place: the page
+        loop under the mask) and as every other lane does (the layer's
+        own pools: a gather, ``ctx_state`` under the same mask): the same
+        stream out, the same rows to write, the same ``int32`` account —
+        keys scored and rows moved the lengths, rows read the chosen
+        set's cached members."""
+        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
+        paged.get_paged_lm_class()
+        block = paged._MODULES[0](
+            num_heads=SIZES["num_heads"], dtype=jnp.float32, spec=SPEC,
+            routed_layer=False, kind=SPEC.attn_kind(0, SIZES["num_heads"]))
+        rng = np.random.default_rng(5)
+        d, pages, table_w = MODEL["hidden_size"], 64, 12
+        kind = block.kind
+        lengths = jnp.asarray([40, 20, 9, 31], jnp.int32)  # over | over | under | idle
+        counted = jnp.asarray([[True], [True], [True], [False]])
+        lanes = len(lengths)
+        x = jnp.asarray(rng.normal(size=(lanes, 1, d)).astype(np.float32))
+        pools = tuple(
+            jnp.asarray(rng.normal(size=(2, pages, PAGE, w)).astype(np.float32))
+            for w in (kind.lanes, 128))
+        tables = (jnp.asarray(
+            rng.permutation(np.arange(1, pages))[:lanes * table_w].reshape(
+                lanes, table_w), jnp.int32),)
+        assert table_w * PAGE > TOPK
+        args = dict(positions=lengths[:, None], token_mask=counted, window=None)
+        params = block.init(jax.random.key(1), x, pools, None, tables, lengths,
+                            layer=1, **args)
+        whole = block.apply(params, x, pools, None, tables, lengths, layer=1, **args)
+        alone = block.apply(params, x, tuple(p[1] for p in pools), None, tables,
+                            lengths, layer=None, **args)
+        np.testing.assert_allclose(whole[0], alone[0], atol=2e-5, rtol=0)
+        for a, b in zip(whole[1][1:], alone[1][1:]):
+            np.testing.assert_array_equal(a, b)
+        read, read_alone = np.asarray(whole[-1]), np.asarray(alone[-1])
+        assert read.dtype == np.int32 and read.shape == (3,)
+        np.testing.assert_array_equal(read, read_alone)
+        live = int(lengths[:3].sum())
+        assert read[0] == read[2] == live
+        # each selecting lane reads topk rows, or one fewer with its own
+        assert 2 * (TOPK - 1) + 9 <= read[1] <= 2 * TOPK + 9 < live
 
     def test_reference_chosen_sets(self, f32_engine):
         """The reference's own sets: all of a row's positions while it

@@ -412,28 +412,33 @@ def test_a_stack_of_layers_is_compiled_once_and_called(one_chip, mosaic):
 
 @pytest.mark.parametrize("kind,heads,lanes_w,rank,num_pages,pages", [
     ("full", 128, 640, 512, 14337, 64), ("full", 128, 640, 512, 14337, 112),
-    ("window", 64, 1152, 1024, 1281, 10)])
+    ("window", 64, 1152, 1024, 1281, 10),
+    ("masked", 128, 640, 512, 14337, 64), ("masked", 128, 640, 512, 14337, 112)])
 def test_latent_kernel_compiles_at_both_of_dots3_s_shapes(
         one_chip, mosaic, kind, heads, lanes_w, rank, num_pages, pages):
     """The long-doc cell's chunk: 128 lanes of 128 heads on the full
     layers' ``(3, 14337, 64, 640)`` pool (tables of 64 and 112 pages), and
     of 64 heads on the window layers' ``(3, 1281, 64, 1152)`` pool through
-    a table of 10 pages with each lane's first live position."""
-    lanes, offset = 128, kind == "window"
+    a table of 10 pages with each lane's first live position; ``masked``
+    (PR 40): the full layers' call under a selection's mask, a lane's
+    ``(steps, 1024)`` int32 rows of it blocked into VMEM."""
+    lanes, offset, masked = 128, kind == "window", kind == "masked"
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def fn(q, pool, tables, lengths, layer, starts):
+    def fn(q, pool, tables, lengths, layer, starts, chosen):
         return kernels.latent_attention_decode(
             q, pool, tables, lengths, layer=layer, page_size=PS, rank=rank,
-            **({"starts": starts} if offset else {}))
+            **({"starts": starts} if offset else {}),
+            **({"chosen": chosen} if masked else {}))
 
     compiled = jax.jit(fn).lower(
         spec((lanes, heads, lanes_w), jnp.bfloat16),
         spec((3, num_pages, PS, lanes_w), jnp.bfloat16),
         spec((lanes, pages), jnp.int32), spec((lanes,), jnp.int32),
-        spec((), jnp.int32), spec((lanes,), jnp.int32)).compile()
+        spec((), jnp.int32), spec((lanes,), jnp.int32),
+        spec((lanes, pages * PS), jnp.bool_)).compile()
     text = compiled.as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and " = " in ln]
     assert len(calls) == 1, calls
@@ -464,9 +469,10 @@ def test_windowed_causal_kernel_compiles_at_the_cells_shapes(
 
 
 def test_the_selection_s_programs_compile_at_the_cells_shapes(one_chip, mosaic):
-    """What a full layer adds in XLA: a decode step's scores of 7,168
-    cached indexer keys a lane, the best 2,048, and the gather of their
-    rows from the whole pool; a prefill's indexed attention over 4,096
+    """What a full layer adds: a decode step's scores of 7,168 cached
+    indexer keys a lane, the best 2,048 as a mask and the page loop
+    under it (PR 40: no row is gathered, and the step's temporaries are
+    the scores' alone); a prefill's indexed attention over 4,096
     positions, a block of queries at a time.  Neither may need more than
     a few hundred MB beside its operands."""
     from seldon_core_tpu.ops import mla
@@ -480,11 +486,12 @@ def test_the_selection_s_programs_compile_at_the_cells_shapes(one_chip, mosaic):
         keys = idx_pool[1, table].reshape(lanes, pages * PS, 128)
         cached = mla.index_scores(q_i, w_i, keys, 1 / 90.5)[:, 0]
         own_sc = mla.index_scores(q_i, w_i, key_row, 1 / 90.5)[:, 0, 0]
-        at, is_cached, own_in = mla.sparse_select(cached, own_sc, lengths, topk)
-        page = jnp.take_along_axis(table, at // PS, axis=1)
-        rows = pool[1, page, at % PS]
-        return mla.merge(mla.ctx_state(q_full, rows, is_cached, rank),
-                         mla.ctx_state(q_full, own, own_in[:, None], rank))
+        is_cached, own_in = mla.step_mask(cached, own_sc, lengths, topk)
+        return mla.merge(
+            kernels.latent_attention_decode(
+                q_full, pool, table, lengths, layer=1, page_size=PS, rank=rank,
+                chosen=is_cached),
+            mla.ctx_state(q_full, own, own_in[:, None], rank))
 
     compiled = jax.jit(step).lower(
         spec((lanes, 128, 640)), spec((lanes, 1, 64, 128)),
@@ -493,6 +500,11 @@ def test_the_selection_s_programs_compile_at_the_cells_shapes(one_chip, mosaic):
         spec((3, 14337, PS, 128)), spec((lanes, pages), jnp.int32),
         spec((lanes,), jnp.int32)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # no operation makes the (lanes x topk, 640) array of gathered rows
+    assert f"bf16[{lanes * topk},640]" not in text
+    assert f"bf16[{lanes},{topk},640]" not in text
 
     seg = 4096
 

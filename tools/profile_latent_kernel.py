@@ -7,7 +7,10 @@ cached lengths are drawn from ``--ctx``: prints microseconds a call and
 a live page, and the share of the DMA roofline (rows needed x 1,152 B
 over 819 GB/s).  ``--step-tokens`` sets
 ``ops/kernels.LATENT_STEP_TOKENS`` (tokens one step of the page loop
-reduces) for a sweep; ``--interpret`` rehearses on the CPU.
+reduces) for a sweep; ``--chosen K`` hands the kernel a mask that keeps
+``K`` of each lane's cached rows (a selection's: the rows moved are the
+lengths', the rows needed ``min(K, length)``); ``--interpret`` rehearses
+on the CPU.
 
     python tools/profile_latent_kernel.py --lanes 64 --table-pages 32 \
         --ctx 600-2000 --step-tokens 128,256,512 --json chiprun_out/x.jsonl
@@ -35,6 +38,8 @@ def main() -> int:
     ap.add_argument("--rank", type=int, default=512)
     ap.add_argument("--values", type=int, default=576)
     ap.add_argument("--step-tokens", default="128")
+    ap.add_argument("--chosen", type=int, default=0,
+                    help="mask the lanes' rows down to K chosen each")
     ap.add_argument("--repeats", type=int, default=20)
     ap.add_argument("--interpret", action="store_true")
     ap.add_argument("--json", default="")
@@ -61,21 +66,30 @@ def main() -> int:
     q = (jax.random.normal(jax.random.key(1), (args.lanes, args.heads, lanes_w),
                            jnp.float32) * 0.05).astype(jnp.bfloat16)
     live = int(sum(-(-int(n) // ps) for n in lengths))
-    rows = int(lengths.sum())
+    rows = moved = int(lengths.sum())
+    masks = ()
+    if args.chosen:
+        cached = np.arange(args.table_pages * ps)[None, :] < lengths[:, None]
+        # the K smallest of a uniform draw over the lane's cached rows
+        draw = np.where(cached, rng.random(cached.shape), 2.0)
+        keep = cached & (draw <= np.sort(draw, axis=1)[:, args.chosen - 1:args.chosen])
+        masks = (jnp.asarray(keep),)
+        rows = int(keep.sum())
     out = []
     for step_tokens in (int(x) for x in args.step_tokens.split(",")):
         kernels.LATENT_STEP_TOKENS = step_tokens  # a static argument of the call
 
         @jax.jit
-        def layers(q, pool, tables, lengths):
+        def layers(q, pool, tables, lengths, *mask):
             def one(carry, layer):
                 acc, m, l = kernels.latent_attention_decode(
-                    q, pool, tables, lengths, layer=layer, page_size=ps, rank=args.rank)
+                    q, pool, tables, lengths, layer=layer, page_size=ps, rank=args.rank,
+                    **dict(zip(("chosen",), mask)))
                 return carry + acc.sum() + m.max() + l.sum(), ()
             total, _ = jax.lax.scan(one, jnp.float32(0), jnp.arange(args.layers))
             return total
 
-        operands = (q, pool, jnp.asarray(tables), jnp.asarray(lengths))
+        operands = (q, pool, jnp.asarray(tables), jnp.asarray(lengths), *masks)
         jax.block_until_ready(layers(*operands))
         t0 = time.perf_counter()
         for _ in range(args.repeats):
@@ -84,7 +98,9 @@ def main() -> int:
         call_us = 1e6 * (time.perf_counter() - t0) / args.repeats / args.layers
         floor_us = 1e6 * rows * 2 * args.values / 819e9
         rec = {"lanes": args.lanes, "table_pages": args.table_pages, "ctx": args.ctx,
+               "heads": args.heads, "chosen": args.chosen,
                "step_tokens": step_tokens, "live_pages": live, "rows": rows,
+               "rows_moved": moved, "moved_row_ns": round(1e3 * call_us / max(moved, 1), 2),
                "call_us": round(call_us, 1), "live_page_us": round(call_us / max(live, 1), 3),
                "dma_roofline_pct": round(100 * floor_us / call_us, 1),
                "device": jax.devices()[0].device_kind}
