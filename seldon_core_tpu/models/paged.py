@@ -90,9 +90,10 @@ def paged_kernel_static_eligible(mode: str, mesh_absent: bool, dtype,
     call), a bf16 or f32 pool (f32 is the exactness lane the
     kernel-parity tests pin), a TPU backend unless forced (interpret
     mode), and — where Mosaic compiles it — a 128-aligned ``heads *
-    head_dim``: the kernel DMAs ``(page_size, heads * head_dim)`` page
-    slices out of HBM and Mosaic wants that minor dim in whole lane
-    tiles (the interpreter takes any width).  A replica it turns down
+    head_dim`` with ``heads`` the K/V heads (a grouped-query spec's
+    ``kv_heads``: the pool's row, not q's): the kernel DMAs ``(page_size,
+    heads * head_dim)`` page slices out of HBM and Mosaic wants that
+    minor dim in whole lane tiles (the interpreter takes any width).  A replica it turns down
     serves the ring chunk and the XLA gather.  The block adds only its
     trace-local term (a decode step) on top.  ``latent``: the pool's
     element is one latent row and the latent kernel's
@@ -154,7 +155,12 @@ def prefill_position_bytes(spec, d_model: int, vocab_size: int,
     if spec.double_layer:
         # the shortcut's float32 input and output wait out a half-layer
         kept += 8 * d_model
-    if spec.kinds:
+    if spec.kv_heads:
+        # grouped-query heads: q and the attended values are num_heads x
+        # head_dim wide (bf16 q, float32 and bf16 values), k and v
+        # kv_heads x head_dim each
+        attn = (8 * num_heads + 4 * spec.kv_heads) * spec.head_dim
+    elif spec.kinds:
         # the wider of the two kinds' rows, and under an indexed layer the
         # scores of ops/mla.py INDEX_QUERY_BLOCK queries against every
         # position: the attention's in float32 and bf16, the indexer's in
@@ -314,8 +320,18 @@ def _build_modules():
                               name=name)
         return nn.LayerNorm(dtype=jnp.float32)
 
-    def _heads(mod, q, k, v, positions, shape):
-        """Split flat q/k/v into heads; before that the spec's QK-norm
+    def _rotates(mod):
+        """Whether this block rotates q and k: the spec's positions, or
+        its layer kind's where positions are a kind (a full layer of a
+        grouped-query spec with kinds has none at all)."""
+        kind = getattr(mod, "kind", None)
+        if kind is not None and not mod.spec.latent:
+            return kind.positions == "rope"
+        return mod.spec.rope
+
+    def _heads(mod, q, k, v, positions, shape, kv_shape=None):
+        """Split flat q/k/v into heads (``kv_shape``: k and v where they
+        hold fewer heads than q); before that the spec's QK-norm
         (RMSNorm over the whole projection), after it its rotary
         embedding at the tokens' absolute positions — both on q and k
         only, both before K is cached."""
@@ -325,17 +341,18 @@ def _build_modules():
                            name="q_norm")(q)
             k = nn.RMSNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
                            name="k_norm")(k)
-        q, k, v = q.reshape(shape), k.reshape(shape), v.reshape(shape)
-        if spec.rope:
+        kv_shape = kv_shape or shape
+        q, k, v = q.reshape(shape), k.reshape(kv_shape), v.reshape(kv_shape)
+        if _rotates(mod):
             from seldon_core_tpu.models.spec import rope
 
             q = rope(q, positions, spec.rope_theta)
             k = rope(k, positions, spec.rope_theta)
-        if spec.qk_norm or spec.rope:  # both compute in f32
+        if spec.qk_norm or _rotates(mod):  # both compute in f32
             q, k = q.astype(mod.dtype), k.astype(mod.dtype)
         return q, k, v
 
-    def _ffn(mod, x, proj, token_mask):
+    def _ffn(mod, x, proj, token_mask, router_logits=None):
         """The block's second half: ``x + FFN(norm(x))``.  Dense GELU
         MLP, or routed SwiGLU experts (ops/moe.py) — then the second
         value holds the layer's assignment histogram ``(int32[E],)``
@@ -353,15 +370,35 @@ def _build_modules():
 
         e, f = spec.num_experts, spec.expert_width
         init = nn.initializers.normal(0.02)
-        w_router = mod.param("router", init, (d_model, e), jnp.float32)
         rest = _rest(spec, mod.dtype)
-        w_gate = mod.param("experts_gate", init, (e, d_model, f), rest)
-        w_up = mod.param("experts_up", init, (e, d_model, f), rest)
-        w_down = mod.param("experts_down", init, (e, f, d_model), rest)
+        # (every expert, or a replica's share of them: spec.held)
+        held = spec.held
         rows = y.reshape(-1, d_model)
-        gates, experts = moe.route(rows, w_router, spec.experts_per_tok)
-        out = moe.expert_ffn(
-            rows.astype(mod.dtype), w_gate, w_up, w_down, gates, experts)
+        # what the spec adds to the call, and nothing where it adds
+        # nothing: OLMoE's trace is as it was
+        renorm = {"norm": True} if spec.norm_topk else {}
+        act = {} if spec.expert_act == "silu" else {"act": spec.expert_act}
+        if router_logits is None:
+            w_router = mod.param("router", init, (d_model, e), jnp.float32)
+            gates, experts = moe.route(
+                rows, w_router, spec.experts_per_tok, **renorm)
+        else:
+            # the router read the attention's input: its logits came
+            # with the call, (T, E) float32
+            gates, experts = moe.route(
+                None, None, spec.experts_per_tok,
+                logits=router_logits.reshape(-1, e), **renorm)
+        w_gate = mod.param("experts_gate", init, (held, d_model, f), rest)
+        w_up = mod.param("experts_up", init, (held, d_model, f), rest)
+        w_down = mod.param("experts_down", init, (held, f, d_model), rest)
+        if spec.experts_held:
+            out = moe.expert_ffn_held(
+                rows.astype(mod.dtype), w_gate, w_up, w_down, gates, experts,
+                spec.expert_offset, e, **act)
+        else:
+            out = moe.expert_ffn(
+                rows.astype(mod.dtype), w_gate, w_up, w_down, gates, experts,
+                **act)
         hist = moe.expert_histogram(
             experts, e,
             None if token_mask is None else token_mask.reshape(-1))
@@ -797,6 +834,139 @@ def _build_modules():
         rows = (row, key_row) if topk else (row,)
         return (x, rows, sum(reads)) if reads else (x, rows)
 
+    def _grouped_block(mod, x, pk, pv, tables, lengths, layer, positions,
+                       token_mask, window=None):
+        """A block of grouped-query attention (a spec that sets
+        ``kv_heads`` and ``head_dim``): ``num_heads`` query heads of
+        ``head_dim`` — q and the output projection's input are
+        ``num_heads x head_dim`` wide, whatever ``d_model`` is — over
+        ``kv_heads`` K/V heads, query head ``c`` reading K/V head ``c //
+        (num_heads / kv_heads)``; K and V are cached ``kv_heads x
+        head_dim`` wide each, flat.  Returns ``(x, k, v, hist)`` with
+        ``k`` / ``v`` ``(B, L, kv_heads x head_dim)`` for the caller to
+        write, or for a spec with layer kinds ``(x, (kind name, k), v,
+        hist, read)`` as :func:`_latent_block` does.
+
+        ``mod.kind`` (a spec whose layers differ, models/spec.py
+        ``AttnKind``): positions are the kind's — a window layer
+        rotates q and k, a full layer of ``full_positions="none"`` does
+        not — and a **window** kind reads ``pk`` / ``pv`` (its kind's
+        pools) through ``window`` = ``(tables (B, P_w), base (B,))``, a
+        lane's live pages and the position its table's first column
+        starts at, over the ``kind.window - 1`` positions before the
+        token (``tables`` only says whether the call starts at zero).
+
+        A segment prefills from position zero: causal (or window)
+        attention over itself, the fused kernel where
+        ``ops/kernels.py prefill_attention_impl`` says so and
+        ``ops/gqa.py segment_attention`` a block of queries at a time
+        elsewhere — never an ``(heads, S, S)`` score array.  A decode
+        step reads the cached rows through the page loop where the LM
+        hands over the whole pools (``paged_attention_decode``: a page's
+        K and V slices streamed once for the query heads of each group)
+        and through a gather and two einsums elsewhere (``ops/gqa.py
+        ctx_state``), and joins its own row by the flash rule.  A step of
+        a kind also says what it read, ``int32[3]`` as
+        :func:`_latent_attention`: 0, the cached rows its attention read
+        (a window's live ones) over the lanes ``token_mask`` keeps, 0.
+
+        The router of ``router_from="attn_input"`` reads ``y``, the
+        rows that feed q, k and v: its logits are computed here and
+        handed to :func:`_ffn`, whose experts act on the post-attention
+        norm."""
+        from seldon_core_tpu.ops import gqa, kernels, mla, moe
+
+        spec, kind = mod.spec, mod.kind
+        heads = mod.num_heads
+        batch, seg_len, d_model = x.shape
+        kv_heads, head_dim = spec.head_sizes(heads, d_model)
+        q_w, kv_w = heads * head_dim, kv_heads * head_dim
+        whole = layer is not None
+        windowed = kind is not None and bool(kind.window)
+        if windowed:
+            # the window's table stands where the block table does: one
+            # bucket of every lane, positions counted from the table's
+            # first column (never negative: an idle lane's length is 0
+            # under whatever base its slot's last stream left)
+            from_zero = tables[0].shape[1] == 0
+            w_tables, w_base = window
+            tables = (w_tables[:, :0] if from_zero else w_tables,)
+            w_first = jnp.maximum(
+                jnp.maximum(lengths - (kind.window - 1), 0) - w_base, 0)
+            lengths = jnp.maximum(lengths - w_base, 0)
+
+        def proj(name, features, inp):
+            return _dense(mod.precision, features, mod.dtype, name, spec)(inp)
+
+        y = _norm(spec, "attn_norm")(x)
+        router_logits = None
+        if spec.router_from == "attn_input":
+            w_router = mod.param(
+                "router", nn.initializers.normal(0.02),
+                (d_model, spec.num_experts), jnp.float32)
+            router_logits = moe.router_logits(
+                y.reshape(-1, d_model), w_router)
+        qkv = proj("qkv", q_w + 2 * kv_w, y)
+        q, k, v = (qkv[..., :q_w], qkv[..., q_w:q_w + kv_w],
+                   qkv[..., q_w + kv_w:])
+        q, k, v = _heads(mod, q, k, v, positions,
+                         (batch, seg_len, heads, head_dim),
+                         (batch, seg_len, kv_heads, head_dim))
+        # K is cached as attention reads it (rotated where the layer
+        # rotates), flat as the pool's row
+        k_flat = k.reshape(batch, seg_len, kv_w)
+        v_flat = v.reshape(batch, seg_len, kv_w)
+        scale = float(head_dim) ** -0.5
+
+        outs, reads, off = [], [], 0
+        for tb in tables:
+            nb = tb.shape[0]
+            sl = slice(off, off + nb)
+            off += nb
+            if seg_len > 1:
+                if tb.shape[1]:
+                    raise ValueError(
+                        "a grouped-query layer prefills from position zero: "
+                        "a segment over cached rows is not built")
+                fused = kernels.prefill_attention_impl(
+                    seg_len, head_dim, head_dim, mod.dtype, 0, whole) == "fused"
+                outs.append(gqa.segment_attention(
+                    q[sl], k[sl], v[sl], scale, mod.dtype, fused=fused,
+                    **({"window": kind.window} if windowed else {})))
+                continue
+            q1 = (q[sl][:, 0].astype(jnp.float32) * scale).astype(mod.dtype)
+            first = w_first[sl] if windowed else 0
+            if whole:
+                cached = kernels.paged_attention_decode(
+                    q1, pk, pv, tb, lengths[sl], layer=layer,
+                    page_size=pk.shape[2],
+                    **({"starts": first} if windowed else {}))
+            else:
+                rows_k, rows_v = pk[tb], pv[tb]       # (nb, P, ps, kv_w)
+                at = jnp.arange(rows_k.shape[1] * rows_k.shape[2])[None, :]
+                valid = (at < lengths[sl][:, None]) & (
+                    at >= jnp.asarray(first).reshape(-1, 1))
+                cached = gqa.ctx_state(
+                    q1, rows_k.reshape(nb, -1, kv_heads, head_dim),
+                    rows_v.reshape(nb, -1, kv_heads, head_dim), valid)
+            own = gqa.ctx_state(q1, k[sl], v[sl], jnp.ones((nb, 1), bool))
+            outs.append(mla.merge(cached, own).astype(mod.dtype)[:, None])
+            live = (jnp.ones((nb,), bool) if token_mask is None
+                    else token_mask[sl].reshape(nb))
+            rows_read = jnp.where(
+                live, jnp.maximum(lengths[sl] - first, 0), 0).sum()
+            reads.append(jnp.stack(
+                [jnp.zeros((), jnp.int32), rows_read.astype(jnp.int32),
+                 jnp.zeros((), jnp.int32)]))
+        attn = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
+        x = x + proj("attn_proj", d_model,
+                     attn.reshape(batch, seg_len, q_w))
+        x, hist = _ffn(mod, x, proj, token_mask, router_logits)
+        if kind is None:
+            return (x, k_flat, v_flat, *hist)
+        read = (sum(reads),) if reads else ()
+        return (x, (kind.name, k_flat), v_flat, *hist, *read)
+
     def _segment_attention(mod, q, k, v, scale):
         """Causal attention of a segment ``(B, L, h, hd)`` over itself
         alone (a prefill from position zero): ``(B, L, h, hd)``.  The
@@ -908,6 +1078,11 @@ def _build_modules():
                 # one latent pool (pv is None), another attention
                 return _latent_block(self, x, pk, tables, lengths, layer,
                                      positions, token_mask, window)
+            if self.spec.kv_heads:
+                # grouped-query heads, K/V pools of kinds, a router that
+                # reads this block's normed input
+                return _grouped_block(self, x, pk, pv, tables, lengths, layer,
+                                      positions, token_mask, window)
             d_model = x.shape[-1]
             heads = self.num_heads
             head_dim = d_model // heads
@@ -1347,13 +1522,14 @@ def _build_modules():
             # slices here, as before PR 25, and lowers unchanged.
             whole = self.decode_kernel and paged_kernel_static_eligible(
                 paged_kernel_mode(), True, self.dtype,
-                self.num_heads, self.d_model // self.num_heads,
+                *self.spec.head_sizes(self.num_heads, self.d_model),
                 latent=self.spec.latent,
             )
             new_k, new_v, hists = [], [], []
             if self.spec.kinds:
-                return self._kinds(x, positions, pages_k, block_tables,
-                                   lengths, token_mask, window, whole)
+                return self._kinds(x, positions, pages_k, pages_v,
+                                   block_tables, lengths, token_mask, window,
+                                   whole)
             for i in range(self.num_layers):
                 if whole:
                     pools = (pages_k, pages_v)
@@ -1390,7 +1566,7 @@ def _build_modules():
                 hists += hist
             return _head(self, x, new_k, new_v, hists)
 
-        def _kinds(self, x, positions, pools, block_tables, lengths,
+        def _kinds(self, x, positions, pools, pools_v, block_tables, lengths,
                    token_mask, window, whole):
             """The layers of a spec whose attention differs by layer:
             ``pools`` is ``{"full", "index", "window"}`` (models/spec.py
@@ -1399,25 +1575,34 @@ def _build_modules():
             its place among that kind's layers, whole with the place as
             ``layer`` on the kernel lane and cut to its own rows
             elsewhere.  The new rows come back a dict of the same names,
-            each stacked over its kind's layers."""
+            each stacked over its kind's layers.  A multi-head spec's
+            kinds are ``{"full", "window"}`` twice, K in ``pools`` and V
+            in ``pools_v`` (None for a latent spec), and V's rows come
+            back a dict beside K's."""
             spec = self.spec
-            rows = {"full": [], "index": [], "window": []}
+            rows = {name: [] for name in pools}
+            rows_v = {name: [] for name in pools_v or ()}
             hists, reads = [], []
             for i in range(self.num_layers):
                 kind = spec.attn_kind(i, self.num_heads)
                 at = spec.kind_index(i)
-                names = ("window",) if kind.window else ("full", "index")
+                names = (("window",) if kind.window
+                         else ("full", "index") if spec.latent else ("full",))
                 mine = tuple(pools[n] if whole else pools[n][at]
                              for n in names)
-                x, new, _v, hist, *read = PagedTransformerBlock(
+                mine_v = (None if pools_v is None else pools_v[names[0]]
+                          if whole else pools_v[names[0]][at])
+                x, new, v, hist, *read = PagedTransformerBlock(
                     num_heads=self.num_heads, dtype=self.dtype,
                     precision=self.precision, name=f"block_{i}",
                     spec=spec, routed_layer=spec.layer_routed(i), kind=kind,
-                )(x, mine if kind.topk else mine[0], None, block_tables,
+                )(x, mine if kind.topk else mine[0], mine_v, block_tables,
                   lengths, layer=at if whole else None, positions=positions,
                   token_mask=token_mask, window=window)
                 for name, row in zip(names, new[1:]):
                     rows[name].append(row)
+                if pools_v is not None:
+                    rows_v[names[0]].append(v)
                 hists.append(hist)
                 reads += read
             x = _norm(spec, "final_norm")(x)
@@ -1426,7 +1611,8 @@ def _build_modules():
             # (a decode step's fifth value: what each layer read,
             # int32[layers, 3] — _latent_attention)
             return (logits.astype(jnp.float32),
-                    {n: jnp.stack(r) for n, r in rows.items()}, None,
+                    {n: jnp.stack(r) for n, r in rows.items()},
+                    {n: jnp.stack(r) for n, r in rows_v.items()} or None,
                     jnp.stack(hists), *((jnp.stack(reads),) if reads else ()))
 
     return PagedTransformerBlock, PagedTransformerLM, ChunkTransformerLM
@@ -1735,7 +1921,7 @@ def _write_kv_int8(pk, sk, pv, sv, new_k, new_v, block_tables, start, valid, *,
 
 
 def write_kinds(pools, new, block_tables, start, valid, window, *, page_size,
-                max_len, from_zero: bool = False):
+                max_len, from_zero: bool = False, pools_v=None, new_v=None):
     """:func:`write_kv` for a cache of row kinds (models/spec.py
     ``cache_kinds``): ``pools`` and ``new`` are ``{"full", "index",
     "window"}``.  The full layers' rows and their indexer keys land where
@@ -1745,30 +1931,46 @@ def write_kinds(pools, new, block_tables, start, valid, window, *, page_size,
     decode step's row is written at ``start - base`` of it, and a
     prefill from zero writes the table's span of its rows — ``P_w`` page
     blocks from position ``base`` (whole pages: ``base`` is a page's
-    first position) — and nothing of the prompt behind the window."""
+    first position) — and nothing of the prompt behind the window.
+    K/V kinds (a multi-head spec's ``{"full", "window"}``): ``pools_v``
+    and ``new_v`` hold V under the same names and ride every write
+    beside K.  Returns ``(pools, V pools)``, the second None for a
+    latent cache, which has no V."""
     import jax
     import jax.numpy as jnp
 
     w_tables, w_base = window
-    out = {}
-    for name in ("full", "index"):
-        out[name], _ = write_kv(
-            pools[name], None, new[name], None, block_tables, start, valid,
-            page_size=page_size, max_len=max_len, from_zero=from_zero)
-    rows = new["window"]                              # (layers, B, L, W)
+    out, out_v = {}, {}
+
+    def of(name):  # the kind's V pool and V rows, or None twice
+        if pools_v is None:
+            return None, None
+        return pools_v[name], new_v[name]
+
     span = w_tables.shape[1] * page_size
-    if from_zero:
+
+    def windowed(rows):  # (layers, B, L, W): the table's span of them
+        if not from_zero:
+            return rows
         rows = jnp.pad(rows, [(0, 0), (0, 0), (0, span), (0, 0)])
-        rows = jnp.stack([
+        return jnp.stack([
             jax.lax.dynamic_slice_in_dim(rows[:, s], w_base[s], span, axis=1)
             for s in range(rows.shape[1])], axis=1)
-        at = jnp.zeros_like(start)
-    else:
-        at = start - w_base
-    out["window"], _ = write_kv(
-        pools["window"], None, rows, None, w_tables, at, valid,
-        page_size=page_size, max_len=span, from_zero=from_zero)
-    return out
+
+    for name in pools:
+        pool_v, rows_v = of(name)
+        if name == "window":
+            at = jnp.zeros_like(start) if from_zero else start - w_base
+            out[name], out_v[name] = write_kv(
+                pools[name], pool_v, windowed(new[name]),
+                None if rows_v is None else windowed(rows_v), w_tables, at,
+                valid, page_size=page_size, max_len=span, from_zero=from_zero)
+        else:
+            out[name], out_v[name] = write_kv(
+                pools[name], pool_v, new[name], rows_v, block_tables, start,
+                valid, page_size=page_size, max_len=max_len,
+                from_zero=from_zero)
+    return out, (None if pools_v is None else out_v)
 
 
 def paged_hbm_accounting(
@@ -2728,10 +2930,19 @@ class PagedEngine:
             # a cache of row kinds (models/spec.py cache_kinds): what
             # assumes one element a token whose pages grow with a
             # stream's length in every layer is refused here, by name
-            # (the speculative lane, the host tier, the ring chunk, int8
-            # rows, disaggregation and migration by the latent fences; a
-            # mesh by the routed one)
+            # (a latent spec's speculative lane, host tier, ring chunk,
+            # int8 rows, disaggregation and migration by the latent
+            # fences, a K/V spec's by the same wording below; a mesh by
+            # the routed one)
             for asked, what, why in (
+                (speculative and not spec.latent, "the speculative lane",
+                 "its verify forward writes k + 1 rows a lane and rolls "
+                 "back by length, and a window layer's pages behind the "
+                 "window are gone — serve it with speculative=None"),
+                (not spec.latent and _knobs.flag("SELDON_TPU_KV_OFFLOAD"),
+                 "the host KV tier (SELDON_TPU_KV_OFFLOAD)",
+                 "its containers hold a K and a V block of d_model a page "
+                 "in every layer"),
                 (prefix_cache, "the prefix cache (prefix_cache=True)",
                  "a cached prefix is usable only with the window layers' "
                  "last rows, which went back to the allocator behind the "
@@ -2851,10 +3062,16 @@ class PagedEngine:
         # and is warned about.
         kernel_mode = paged_kernel_mode()
         kernel_eligible = paged_kernel_static_eligible(
-            kernel_mode, mesh is None, dtype, num_heads, head_dim,
-            latent=spec.latent,
+            kernel_mode, mesh is None, dtype,
+            *spec.head_sizes(num_heads, d_model), latent=spec.latent,
         )
         self._chunk_impl = _knobs.raw("SELDON_TPU_CHUNK_IMPL", "")
+        if spec.kinds and not spec.latent and self._chunk_impl == "ring":
+            raise ValueError(self._kinds_refusal(
+                "the ring chunk (SELDON_TPU_CHUNK_IMPL=ring)",
+                "its once-per-chunk context is gathered through one block "
+                "table for every layer — leave the knob unset or set it to "
+                "pool"))
         if spec.latent and self._chunk_impl == "ring":
             raise ValueError(self._latent_refusal(
                 "the ring chunk (SELDON_TPU_CHUNK_IMPL=ring)",
@@ -2864,7 +3081,8 @@ class PagedEngine:
             # a latent pool decodes in the pool chunk whichever lane its
             # attention takes (the kernel, or the gather and einsums)
             self._chunk_impl = (
-                "pool" if kernel_eligible or spec.latent else "ring")
+                "pool" if kernel_eligible or spec.latent or spec.kinds
+                else "ring")
             if kernel_eligible:
                 logger.info(
                     "SELDON_TPU_PAGED_KERNEL is set: auto-selecting the pool "
@@ -2921,12 +3139,14 @@ class PagedEngine:
 
         # (a spec with layer kinds: its window layers'; an indexed layer
         # selects a block of queries at a time in XLA)
-        qk_v = ((spec.win_nope_dim + spec.win_rope_dim, spec.win_v_dim)
+        # (a grouped-query block: its heads' width, q, k and v alike)
+        qk_v = ((spec.head_dim, spec.head_dim) if spec.kv_heads
+                else (spec.win_nope_dim + spec.win_rope_dim, spec.win_v_dim)
                 if spec.kinds else (spec.nope_dim + spec.rope_dim, spec.v_dim))
         self._prefill_attention = {
             bucket: prefill_attention_impl(
                 bucket, *qk_v, dtype, 0, kernel_eligible)
-            if spec.latent else "xla"
+            if spec.latent or spec.kv_heads else "xla"
             for bucket in self.prompt_buckets}
         # r18 int8 KV pool: pages rest int8 with ONE f32 scale per page
         # per k/v in a sibling (layers, num_pages) table — half the
@@ -2938,6 +3158,11 @@ class PagedEngine:
         # not priced — both degrade to the native pool with a WARN.
         kv_dtype = paged_kv_dtype_mode()
         self._kv_int8 = False
+        if kv_dtype == "int8" and spec.kinds and not spec.latent:
+            raise ValueError(self._kinds_refusal(
+                "the int8 KV pool (SELDON_TPU_KV_DTYPE=int8)",
+                "the page loop over grouped heads and a window's first live "
+                "position has no dequantising lane"))
         if kv_dtype == "int8" and spec.latent:
             raise ValueError(self._latent_refusal(
                 "the int8 KV pool (SELDON_TPU_KV_DTYPE=int8)",
@@ -2998,15 +3223,19 @@ class PagedEngine:
             self.window_pages = spec.window_table_pages(
                 self.page_size, self.max_steps)
             self.num_window_pages = self.max_slots * self.window_pages + 1
-            (_f, _fl, _fw), (_i, il, iw), (_w, wl, ww) = self.cache_kinds
-            self.pages_k = {
-                "full": self.pages_k,
-                "index": jnp.zeros(
-                    (il, self.num_pages, self.page_size, iw), pool_dtype),
-                "window": jnp.zeros(
-                    (wl, self.num_window_pages, self.page_size, ww),
-                    pool_dtype),
-            }
+
+            def kind_pools(full):
+                """One pool a kind: the full layers' (made above), and
+                zeros for every other kind — the window layers' over
+                their own pages."""
+                return {name: full if name == "full" else jnp.zeros(
+                    (layers, self.num_window_pages if name == "window"
+                     else self.num_pages, self.page_size, lanes), pool_dtype)
+                    for name, layers, lanes in self.cache_kinds}
+
+            self.pages_k = kind_pools(self.pages_k)
+            if not spec.latent:  # K/V kinds: V's pools beside K's
+                self.pages_v = kind_pools(self.pages_v)
             self._free_wpages: Deque[int] = deque(
                 range(1, self.num_window_pages))  # 0 = trash
             self._wtables = np.zeros(
@@ -3350,6 +3579,14 @@ class PagedEngine:
                           "sparse_rows_cached": 0, "sparse_lane_steps": 0,
                           "window_rows_read": 0, "sparse_rows_moved": 0,
                           "window_pages_released": 0,
+                          # grouped-query heads over K/V pools of kinds
+                          # (0 otherwise): cached K/V rows decode
+                          # lane-steps read, summed over the layers (a
+                          # full layer's every cached row, a window
+                          # layer's live ones: the chunk's counter row)
+                          # and what they would read with no window
+                          # (decode_kv_tokens x layers)
+                          "gqa_kv_rows_read": 0, "gqa_kv_rows_cached": 0,
                           # waiting where it happens: seconds (and
                           # streams) between submit and a stream's first
                           # prefill slice — the engine's own queue —
@@ -3691,14 +3928,18 @@ class PagedEngine:
             raise ValueError(self._latent_refusal(
                 what, "its container holds a \"k\" and a \"v\" block of "
                 "d_model a page"))
+        if self.spec.kinds:
+            raise ValueError(self._kinds_refusal(
+                what, "its container holds a \"k\" and a \"v\" block of "
+                "d_model a page in every layer, addressed by one table"))
 
     def _write_kv(self, pk, pv, new_k, new_v, block_row_or_tables, start, valid,
                   from_zero: bool = False, window=None):
-        if self.spec.kinds:
+        if self.spec.kinds:  # (a latent cache's pv and new_v are None)
             return write_kinds(
                 pk, new_k, block_row_or_tables, start, valid, window,
                 page_size=self.page_size, max_len=self.max_len,
-                from_zero=from_zero), None
+                from_zero=from_zero, pools_v=pv, new_v=new_v)
         return write_kv(
             pk, pv, new_k, new_v, block_row_or_tables, start, valid,
             page_size=self.page_size, max_len=self.max_len, from_zero=from_zero,
@@ -4478,7 +4719,11 @@ class PagedEngine:
         selection's mask), summed by the layers' kind.  What it is held against
         comes from the lengths the step starts at: ``sparse_rows_cached``
         the rows cached for the active lanes times the full layers,
-        ``sparse_lane_steps`` the lanes holding ``index_topk`` or more."""
+        ``sparse_lane_steps`` the lanes holding ``index_topk`` or more.
+        K/V kinds (a multi-head spec: ``_grouped_block`` says the same
+        ``reads``, nothing scored, nothing masked) ride the same row:
+        the host books its full and window columns as
+        ``gqa_kv_rows_read`` (:meth:`_moe_count_locked`)."""
         jnp, spec = self._jnp, self.spec
         is_window = [k == "window" for k in spec.layer_kinds[:reads.shape[0]]]
         windowed, full = jnp.asarray(is_window), is_window.count(False)
@@ -6930,10 +7175,10 @@ class PagedEngine:
                 and s.kv_import is None
                 and s.prefilled >= len(s.prompt)
                 and self.speculative is None
-                # a latent pool's pages fit no migration container yet:
-                # its streams are the drain journal's, like a
-                # speculative engine's
-                and not self.spec.latent
+                # a latent pool's pages, and a cache of kinds', fit no
+                # migration container yet: their streams are the drain
+                # journal's, like a speculative engine's
+                and not self.spec.latent and not self.spec.kinds
             ]
         if not exportable:
             return []
@@ -7507,6 +7752,8 @@ class PagedEngine:
         the mesh degrees the engine got (not what was requested), the
         chunk implementation, and whether decode attention runs the
         Pallas kernel."""
+        kv_heads, head_dim = self.spec.head_sizes(
+            self.module.num_heads, self.module.d_model)
         return {
             "tp": self.tp_degree,
             "dp": self.dp_degree,
@@ -7528,6 +7775,11 @@ class PagedEngine:
             # layer has two), not layers
             "cache_layers": self.spec.cache_layers(self.module.num_layers),
             "experts_held": self.spec.held if self.spec.routed else 0,
+            # grouped-query heads: the K/V heads and head width the
+            # pool's row is made of, and what the router reads
+            **({"kv_heads": kv_heads, "head_dim": head_dim,
+                "router_from": self.spec.router_from}
+               if self.spec.kv_heads else {}),
             # a spec with layer kinds: one pool a row kind (the full
             # layers' rows and indexer keys share the block table's
             # pages; the window layers' have their own), how many rows a
@@ -7636,8 +7888,15 @@ class PagedEngine:
         if chunk is None:
             chunk = np.zeros((self._moe_hits.shape[0], e + 3), np.int64)
         elif spec.kinds:  # the last row: what selection and windows read
-            for name, n in zip(self.SPARSE_COUNTERS, chunk[-1]):
-                self._counters[name] += int(n)
+            row = dict(zip(self.SPARSE_COUNTERS, map(int, chunk[-1])))
+            if not spec.latent:
+                # K/V kinds select nothing: the full layers' rows and
+                # the window layers' live ones are what decode read
+                row = {"window_rows_read": row["window_rows_read"],
+                       "gqa_kv_rows_read": (row["sparse_rows_read"]
+                                            + row["window_rows_read"])}
+            for name, n in row.items():
+                self._counters[name] += n
             chunk = chunk[:-1]
         hits = chunk[:, :e].astype(np.int64)
         for h in prefills:
@@ -8449,6 +8708,7 @@ class PagedEngine:
             # cached tokens per lane as the chunk starts: the launch's
             # kv_tokens, and the base of decode_kv_tokens at harvest
             lens0 = {s.slot: int(self._lengths[s.slot]) for s in runnable_now}
+            kinds = self.spec.layer_kinds[:self.module.num_layers]
             # ctx horizons for the chunk: per length bucket (the ring
             # impl gathers only pages holding tokens that EXIST at
             # chunk start — in-chunk tokens live in the ring; the pool
@@ -8470,7 +8730,15 @@ class PagedEngine:
                         n >= self.spec.index_topk for n in lens0.values()),
                     "window_pages": sum(
                         len(s.wpages) for s in runnable_now)}
-                   if self.spec.kinds else {}),
+                   if self.spec.kinds and self.spec.latent else {}),
+                # K/V kinds: the window pages the lanes hold and the rows
+                # a step of this chunk starts by reading, over the layers
+                **({"window_pages": sum(len(s.wpages) for s in runnable_now),
+                    "kv_rows": sum(
+                        n * kinds.count("full")
+                        + min(n, self.spec.window - 1) * kinds.count("window")
+                        for n in lens0.values())}
+                   if self.spec.kinds and not self.spec.latent else {}),
             )
             # copies: the host goes on writing these tables (this wave's
             # predicted lengths, the next wave's admissions) while the
@@ -8655,6 +8923,9 @@ class PagedEngine:
                 if self.spec.latent:  # a row an attention sub-layer
                     self._counters["latent_kv_tokens"] += (
                         read * self.spec.cache_layers(self.module.num_layers))
+                elif self.spec.kinds:  # K/V kinds: with no window, every row
+                    self._counters["gqa_kv_rows_cached"] += (
+                        read * self.module.num_layers)
                 self._counters["decode_live_pages"] += sum(
                     self._pages_of(len0 + t * grow) for t in range(n))
             # every launched step walks every lane's table, live or not

@@ -33,8 +33,8 @@ replica holds** (``experts_held`` from ``expert_offset``, of
 expert-parallel layer computes its own experts' part and nothing
 stands in for the rest).
 
-``GPT2``, ``OLMOE``, ``DEEPSEEK_V3``, ``LONGCAT_FLASH`` and ``DOTS3_NOTE``
-are the values served (the fifth added layer KINDS: ``layer_kinds``,
+``GPT2``, ``OLMOE``, ``DEEPSEEK_V3``, ``LONGCAT_FLASH``, ``DOTS3_NOTE``
+and ``SMALLTHINKER`` are the values served (the fifth added layer KINDS: ``layer_kinds``,
 ``attn_kind``, ``cache_kinds`` — layers that differ in their attention
 and a cache of one pool a row kind); a new architecture is a new value (and new branches where the
 block reads a field it has not met), not a new block.  The fourth value
@@ -45,8 +45,21 @@ the router scores after the ``num_experts`` real ones),
 ``mla_lora_scale`` (constant scales on q and on the cached latent) and
 ``score="softmax_bias"``.
 
-For multi-head attention ``head_dim`` is not a field: the pool's
-element is ``d_model`` wide and heads split it evenly.  Latent
+For multi-head attention ``kv_heads`` and ``head_dim`` are fields whose
+0 means what GPT-2 and OLMoE have: as many K/V heads as query heads, of
+``d_model / num_heads``, so the pool's element is ``d_model`` wide.  The
+sixth value (``SMALLTHINKER``) sets both: 28 query heads of 128 (q and
+the output projection's input are 3,584 wide, not ``d_model``) over 4
+K/V heads, a cache row of ``kv_heads x head_dim`` = 512 values in each
+of K and V (:meth:`ModelSpec.cache_width`), query head ``h`` reading K/V
+head ``h // (num_heads / kv_heads)``.  It also made ``layer_kinds`` mean
+something for a multi-head spec — "full" | "window" layers over K/V
+pools of kinds (:meth:`ModelSpec.cache_kinds`), with **positions a
+kind** (``full_positions="none"``: the window layers rotate q and k, the
+full layers carry no positional encoding at all) — and added
+``router_from="attn_input"`` (the routing logits come from the block's
+normed input, the rows that feed q, k and v), ``expert_act="relu"`` and
+``norm_topk`` / ``experts_held`` behind a softmax router.  Latent
 attention's head widths (``nope_dim``, ``rope_dim``, ``v_dim``) and
 ranks are fields: none follows from ``d_model``.
 """
@@ -67,10 +80,12 @@ def lane_tiles(values: int) -> int:
 
 @dataclass(frozen=True)
 class AttnKind:
-    """One layer's latent attention at its own sizes
+    """One layer's attention at its own sizes
     (:meth:`ModelSpec.attn_kind`): ``window`` 0 attends over every
     earlier position, ``topk`` over the best that many by the layer's
-    indexer (0: no indexer)."""
+    indexer (0: no indexer).  A multi-head spec's kinds leave the latent
+    ranks and widths 0 and say how positions enter the layer
+    (``positions``: "rope" | "none")."""
     name: str
     heads: int
     q_rank: int
@@ -81,6 +96,7 @@ class AttnKind:
     rope_theta: float
     window: int
     topk: int
+    positions: str = "rope"
 
     @property
     def lanes(self) -> int:
@@ -121,6 +137,13 @@ class ModelSpec:
     # latent row of kv_rank + rope_dim; q through a q_rank bottleneck;
     # heads of nope_dim + rope_dim against values of v_dim)
     attention: str = "mha"
+    # multi-head attention's K/V heads and head width (0 = num_heads and
+    # d_model / num_heads: K and V of d_model each).  Set, q and the
+    # output projection's input are num_heads x head_dim wide, K and V
+    # kv_heads x head_dim, and query head h reads K/V head
+    # h // (num_heads / kv_heads)
+    kv_heads: int = 0
+    head_dim: int = 0
     q_rank: int = 0
     kv_rank: int = 0
     nope_dim: int = 0
@@ -149,6 +172,12 @@ class ModelSpec:
     topk_group: int = 1
     norm_topk: bool = False
     routed_scale: float = 1.0
+    # what the router reads: "ffn_input" (the post-attention norm, which
+    # the experts act on) | "attn_input" (the block's normed input, the
+    # rows that feed q, k and v: a router placed before the attention)
+    router_from: str = "ffn_input"
+    # the experts' gate activation: "silu" (SwiGLU) | "relu" (ReGLU)
+    expert_act: str = "silu"
     # ---- the share: of the num_experts the router scores, this
     # replica holds experts_held (0 = all) starting at expert_offset
     experts_held: int = 0
@@ -190,6 +219,10 @@ class ModelSpec:
     index_dim: int = 0
     index_topk: int = 0
     attn_gate: bool = False
+    # a multi-head spec with layer kinds: how positions enter a FULL
+    # layer ("" = as ``positions`` says | "none": no positional encoding
+    # at all; the window layers keep ``positions``)
+    full_positions: str = ""
 
     @property
     def routed(self) -> bool:
@@ -207,15 +240,22 @@ class ModelSpec:
 
     def cache_width(self, d_model: int) -> int:
         """Lanes a token's cache row takes, per layer and pool:
-        ``d_model``, or a latent row's :attr:`cache_values` rounded up
+        ``d_model`` (``kv_heads x head_dim`` where a multi-head spec sets
+        them), or a latent row's :attr:`cache_values` rounded up
         to whole 128-lane tiles (576 -> 640, the tail zero).  Under the
         TPU's (8, 128) tiling a 576-wide minor dim occupies 640 lanes of
         HBM whatever the array is called, and the decode kernel can
         only cut HBM in whole tiles; so the padding is in the shape,
         where the allocator's byte accounting sees it."""
         if not self.latent:
-            return d_model
+            return self.kv_heads * self.head_dim or d_model
         return lane_tiles(self.cache_values)
+
+    def head_sizes(self, num_heads: int, d_model: int) -> Tuple[int, int]:
+        """``(kv_heads, head_dim)`` of multi-head attention with
+        ``num_heads`` query heads."""
+        return (self.kv_heads or num_heads,
+                self.head_dim or d_model // num_heads)
 
     @property
     def cache_values(self) -> int:
@@ -251,6 +291,13 @@ class ModelSpec:
 
     def attn_kind(self, layer: int, num_heads: int) -> "AttnKind":
         """Layer ``layer``'s attention at its own sizes."""
+        if not self.latent:
+            window = self.layer_kind(layer) == "window"
+            return AttnKind(
+                "window" if window else "full", num_heads, 0, 0, 0, 0, 0,
+                self.rope_theta, self.window if window else 0, 0,
+                positions=(self.positions if window or not self.full_positions
+                           else self.full_positions))
         if self.layer_kind(layer) == "window":
             return AttnKind(
                 "window", self.win_heads, self.win_q_rank, self.win_kv_rank,
@@ -268,9 +315,14 @@ class ModelSpec:
         length) and the window layers' rows (addressed by a table of
         fixed width: pages behind the window go back to the allocator).
         Lanes are values in whole 128-lane tiles, as
-        :meth:`cache_width`."""
+        :meth:`cache_width`.  A multi-head spec keeps two kinds, the full
+        and the window layers' K/V rows of ``kv_heads x head_dim``, each
+        in :attr:`cache_pools` pools (K and V)."""
         kinds = self.layer_kinds[:num_layers]
         full, win = kinds.count("full"), kinds.count("window")
+        if not self.latent:
+            width = self.kv_heads * self.head_dim
+            return (("full", full, width), ("window", win, width))
         return (("full", full, lane_tiles(self.kv_rank + self.rope_dim)),
                 ("index", full, lane_tiles(self.index_dim)),
                 ("window", win, lane_tiles(self.win_kv_rank + self.win_rope_dim)))
@@ -414,13 +466,35 @@ DOTS3_NOTE = ModelSpec(
     index_heads=64, index_dim=128, index_topk=2048, attn_gate=True,
 )
 
+# PowerInfer/SmallThinker-21BA3B-Instruct config.json: 52 layers, each
+# routed; 28 query heads of 128 over 4 K/V heads (q 3,584 wide beside a
+# hidden size of 2,560); sliding_window_layout = rope_layout = 0, 1, 1, 1
+# x 13: a layer with 1 attends over 4,096 positions and rotates q and k
+# (rotate-half, theta 1,500,000, no scaling), a layer with 0 attends
+# over every earlier position and has no positional encoding at all;
+# 64 ReLU-gated experts of 768 ("sparse ReGLU"), softmax top-6
+# renormalised (norm_topk_prob), the router reading the attention's
+# input ("router placed before attention"); RMSNorm eps 1e-6, no biases,
+# no QK-norm, no shared expert, untied embedding and head
+SMALLTHINKER = ModelSpec(
+    name="smallthinker", positions="rope", norm="rmsnorm", norm_eps=1e-6,
+    ffn="moe", num_experts=64, experts_per_tok=6, expert_width=768,
+    rope_theta=1_500_000.0, bias=False, residual_f32=True, weights_f32=False,
+    kv_heads=4, head_dim=128, norm_topk=True, router_from="attn_input",
+    expert_act="relu", layer_kinds=("full", "window", "window", "window") * 13,
+    window=4096, full_positions="none",
+)
+
 _ARCHS = {"gpt2": GPT2, "olmoe": OLMOE, "deepseek_v3": DEEPSEEK_V3,
-          "longcat_flash": LONGCAT_FLASH, "dots3_note": DOTS3_NOTE}
-# the sizes any routed arch has; those only DeepSeek-V3's expert layer
-# and attention have; and the two every arch has
+          "longcat_flash": LONGCAT_FLASH, "dots3_note": DOTS3_NOTE,
+          "smallthinker": SMALLTHINKER}
+# the sizes any routed arch has; a replica's share of the experts; those
+# only DeepSeek-V3's expert layer and attention have; and the two every
+# arch has
 _EXPERT_SIZES = ("num_experts", "experts_per_tok", "expert_width")
-_LATENT_SIZES = (
-    "dense_width", "routed_scale", "experts_held", "expert_offset",
+_SHARE_SIZES = ("experts_held", "expert_offset")
+_LATENT_SIZES = _SHARE_SIZES + (
+    "dense_width", "routed_scale",
     "q_rank", "kv_rank", "nope_dim", "rope_dim", "v_dim")
 _DEEPSEEK_SIZES = _LATENT_SIZES + (
     "dense_layers", "shared_experts", "n_group", "topk_group", "rope_factor",
@@ -435,10 +509,22 @@ _KIND_SIZES = (
     "index_heads", "index_dim", "index_topk")
 _DOTS3_SIZES = _LATENT_SIZES + (
     "dense_layers", "shared_experts", "n_group", "topk_group") + _KIND_SIZES
+# ... and those of grouped-query heads over K/V pools of kinds, with a
+# share of the experts behind a softmax router
+_SMALLTHINKER_SIZES = _SHARE_SIZES + (
+    "kv_heads", "head_dim", "layer_kinds", "window")
+# the sizes an arch has beside the expert sizes and the two every arch
+# has: what ``model_spec`` lets a caller set, by the arch's name (a share
+# of the experts and layer kinds belong to the archs whose block was
+# built for them, latent or not)
+_OWN_SIZES = {"gpt2": (), "olmoe": (), "deepseek_v3": _DEEPSEEK_SIZES,
+              "longcat_flash": _LONGCAT_SIZES, "dots3_note": _DOTS3_SIZES,
+              "smallthinker": _SMALLTHINKER_SIZES}
 _SIZES = tuple(dict.fromkeys(
-    _EXPERT_SIZES + _DEEPSEEK_SIZES + _LONGCAT_SIZES + _DOTS3_SIZES
-    + ("rope_theta", "norm_eps")))
-# a size that may be given as 0 and mean it (0 elsewhere = as published)
+    _EXPERT_SIZES + sum(_OWN_SIZES.values(), ()) + ("rope_theta", "norm_eps")))
+# a size that may be given as 0 and mean it, for an arch that takes
+# sizes of its own (0 elsewhere = as published; GPT-2 and OLMoE, which
+# have none of these, take a 0 as "not given")
 _ZERO_MEANS_ZERO = ("dense_layers", "shared_experts", "expert_offset",
                     "experts_held", "zero_experts")
 
@@ -454,18 +540,15 @@ def model_spec(arch: str = "gpt2", **sizes: Any) -> ModelSpec:
         raise ValueError(
             f"arch={arch!r}: the paged engine serves {sorted(_ARCHS)}"
         ) from None
+    own = set(_OWN_SIZES[spec.name])
     given = {k: v for k, v in sizes.items()
-             if v or (v is not None and k in _ZERO_MEANS_ZERO and spec.latent)}
+             if v or (v is not None and k in _ZERO_MEANS_ZERO and own)}
     unknown = sorted(set(given) - set(_SIZES))
     if unknown:
         raise ValueError(f"model_spec: unknown sizes {unknown}")
     if not spec.routed and given.keys() & set(_EXPERT_SIZES):
         raise ValueError(f"arch={spec.name!r} has no experts to size")
-    own = (set(_LONGCAT_SIZES) if spec.double_layer
-           else set(_DOTS3_SIZES) if spec.kinds
-           else set(_DEEPSEEK_SIZES) if spec.latent else set())
-    foreign = given.keys() & (set(_DEEPSEEK_SIZES) | set(_LONGCAT_SIZES)
-                              | set(_DOTS3_SIZES)) - own
+    foreign = given.keys() & set(sum(_OWN_SIZES.values(), ())) - own
     if foreign:
         raise ValueError(f"arch={spec.name!r} has no {sorted(foreign)}")
     if given:
@@ -480,6 +563,10 @@ def model_spec(arch: str = "gpt2", **sizes: Any) -> ModelSpec:
     if spec.kinds and set(spec.layer_kinds) - {"full", "window"}:
         raise ValueError(
             f"layer_kinds {spec.layer_kinds}: a layer is 'full' or 'window'")
+    if not spec.latent and bool(spec.kv_heads) != bool(spec.head_dim):
+        raise ValueError(
+            f"kv_heads {spec.kv_heads} and head_dim {spec.head_dim}: "
+            "grouped-query heads set both")
     if spec.routed and not 0 < spec.experts_per_tok <= spec.router_outputs:
         raise ValueError(
             f"experts_per_tok {spec.experts_per_tok} of {spec.router_outputs} "
@@ -602,7 +689,8 @@ def _declared(spec, sizes, dtype_name):
                  for name, layers, lanes in spec.cache_kinds(
                      config["num_layers"])}
         return jax.eval_shape(
-            lm.init, jax.random.key(0), i32((1, 8)), i32((1, 8)), pools, None,
+            lm.init, jax.random.key(0), i32((1, 8)), i32((1, 8)), pools,
+            None if spec.latent else pools,  # K and V pools of kinds
             i32((1, 0)), i32((1,)),
             window=(i32((1, 0)), i32((1,))))["params"]
     pool = jax.ShapeDtypeStruct(

@@ -518,7 +518,12 @@ def causal_attention(q, k, v, scale: float, *, block_q: int = None,
     query block is padded to one (pad keys lie after every real row, so
     causality hides them) and the pad rows cut off.  ``window`` (0:
     none): row ``i`` attends keys ``i - window + 1 .. i`` only, and key
-    blocks wholly behind a query block's window are skipped.
+    blocks wholly behind a query block's window are skipped.  ``k`` and
+    ``v`` may hold fewer heads than ``q`` (grouped-query attention: ``h``
+    a multiple of theirs): query head ``c`` reads K/V head ``c // (h /
+    kv_heads)`` through the block index — nothing is repeated to ``h``
+    heads, and the pipeline fetches a K/V head once for the query heads
+    that follow one another on it.
 
     The kernel's call is a ``pallas_call`` whose output is ``(B * h, L,
     d_v)``: three dims, which is how the benchmark's readers tell it
@@ -531,6 +536,11 @@ def causal_attention(q, k, v, scale: float, *, block_q: int = None,
 
     b, seg, h, d_qk = q.shape
     d_v = v.shape[-1]
+    kv_heads = k.shape[2]
+    if h % kv_heads:
+        raise ValueError(
+            f"causal_attention: {h} query heads over {kv_heads} K/V heads")
+    share = h // kv_heads
     block_q = block_q or CAUSAL_BLOCK_Q
     block_k = min(block_k or CAUSAL_BLOCK_K, block_q)
     if block_q % block_k:
@@ -554,7 +564,13 @@ def causal_attention(q, k, v, scale: float, *, block_q: int = None,
 
     # (B, L, h, d) -> (B*h, L, d): one head's rows contiguous
     def fold(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, seg_p, x.shape[-1])
+        return x.transpose(0, 2, 1, 3).reshape(-1, seg_p, x.shape[-1])
+
+    def kv_at(i, qi):
+        # grid cell i = batch * h + query head: its K/V head's rows
+        return ((i // h) * kv_heads + (i % h) // share, 0, 0)
+
+    kv_index = kv_at if share > 1 else (lambda i, qi: (i, 0, 0))
 
     need = (_causal_kv_bytes(seg_p, d_qk, d_v, q.dtype)
             + 8 * block_q * max(block_k, 128) * 4
@@ -566,8 +582,8 @@ def causal_attention(q, k, v, scale: float, *, block_q: int = None,
         grid=(b * h, seg_p // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d_qk), lambda i, qi: (i, qi, 0)),
-            pl.BlockSpec((1, seg_p, d_qk), lambda i, qi: (i, 0, 0)),
-            pl.BlockSpec((1, seg_p, d_v), lambda i, qi: (i, 0, 0)),
+            pl.BlockSpec((1, seg_p, d_qk), kv_index),
+            pl.BlockSpec((1, seg_p, d_v), kv_index),
         ],
         out_specs=pl.BlockSpec((1, block_q, d_v), lambda i, qi: (i, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, seg_p, d_v), q.dtype),
@@ -622,7 +638,8 @@ def _split3(x):
 
 def _paged_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
                                 page_size, heads, head_dim, quantized=False,
-                                fold_lora=False, q_scale=1.0):
+                                fold_lora=False, q_scale=1.0, kv_heads=None,
+                                offset=False):
     """One slot of streaming flash-decoding: grid=(B,), the WHOLE
     ``(L, pages, ps, h*hd)`` K/V pools stay in HBM and each slot's live
     pages arrive via double-buffered manual DMA of
@@ -682,6 +699,22 @@ def _paged_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
     * ``quantized`` — int8 pages with one f32 scale per page per k/v in
       scalar-prefetched tables; the scale multiplies the page's SCORES
       and its softmax WEIGHTS (a ``(1, tokens)`` row), never the page.
+    * ``kv_heads`` (grouped-query heads; None: as many as ``heads``) —
+      the pool's row is ``kv_heads * head_dim`` wide and query head
+      ``c`` reads K/V head ``c // (heads / kv_heads)``: the projector's
+      row ``c`` holds head ``c``'s slice of q on ITS K/V HEAD's columns,
+      so a page's K and V slices are streamed once and one matmul scores
+      them against every query head of each group; ``w @ v`` gives
+      ``(hp, kv_heads * head_dim)``, of which row ``c`` keeps its K/V
+      head's block.  The rows of a group share columns, so the answer is
+      cut out as ``(hp, head_dim)`` rows (one static lane slice a K/V
+      head) where one K/V head a query head sums the masked rows into
+      one flat row: q and the output then ride ``(heads, head_dim)``.
+    * ``offset`` — a fourth scalar-prefetch operand ``starts`` ``(B,)``
+      gives each lane's first live position (a window's trailing edge),
+      as :func:`_latent_attention_kernel` has it: the page loop starts
+      at the step that holds it and positions before it are masked like
+      those past the length.
     * ``fold_lora`` — the per-lane qkv LoRA BGMV delta computes INSIDE
       this launch: the lane's adapter slot id (scalar prefetch) indexes
       the factor pools in HBM, one DMA brings the lane's (r, D)/(r, 3D)
@@ -698,9 +731,12 @@ def _paged_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
     from jax.experimental.pallas import tpu as pltpu
 
     pos = 0
+    if offset:
+        starts_ref = refs[0]
+        pos = 1
     if quantized:
-        sk_ref, sv_ref = refs[0], refs[1]
-        pos = 2
+        sk_ref, sv_ref = refs[pos], refs[pos + 1]
+        pos += 2
     if fold_lora:
         adapter_ref = refs[pos]
         pos += 1
@@ -726,10 +762,13 @@ def _paged_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
     lanes = pl.num_programs(0)
     layer = layer_ref[0]
     h, hd = heads, head_dim
-    D = h * hd
+    grouped = kv_heads is not None and kv_heads != heads
+    share = h // kv_heads if grouped else 1    # query heads a K/V head
+    D = (kv_heads if grouped else h) * hd      # the pool's row
     hp = -(-h // 8) * 8
     width = tables_ref.shape[1]
-    group, span = _pages_per_step(page_size, width), k_buf.shape[1]
+    span = k_buf.shape[1]
+    group = span // page_size
     length = lens_ref[b]
     # bf16 and int8 pages are exact in bf16: the one-pass lane.  An f32
     # pool needs all of its bits: HIGHEST on f32 operands
@@ -741,8 +780,22 @@ def _paged_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
         # a lane masked done may hold more context than the table slice
         # it was given (models/paged.py _pages_horizon): never read
         # past it
-        return jnp.minimum(
+        pages = jnp.minimum(
             jax.lax.div(lens_ref[lane] + page_size - 1, page_size), width)
+        # (a window's lengths are counted from its table's first column
+        # and may fall below zero on an idle lane: an empty lane too)
+        return jnp.maximum(pages, 0) if offset else pages
+
+    def first_of(lane):
+        """The first step of a lane's page loop."""
+        if not offset:
+            return 0
+        # (never past the lane's last step: the step a predecessor
+        # started for it is always waited for)
+        last = jnp.maximum(
+            jax.lax.div(pages_of(lane) + group - 1, group) - 1, 0)
+        return jnp.minimum(
+            jax.lax.div(jnp.maximum(starts_ref[lane], 0), span), last)
 
     def copies(lane, j, slot, which):
         """``(held, copy)`` of loop step ``j`` of ``lane`` into buffer
@@ -770,6 +823,7 @@ def _paged_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
 
     n_pages = pages_of(b)
     n_steps = jax.lax.div(n_pages + group - 1, group)
+    j_first = first_of(b)
     # the next lane's first pages are fetched while this lane's last
     # are reduced, so a lane does not start on a cold DMA: whoever runs
     # before a live lane (live or not) starts its first step's copies
@@ -780,7 +834,7 @@ def _paged_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
     @pl.when(b == 0)
     def _first():
         turn_ref[0] = 0
-        start(0, 0, 0)
+        start(0, first_of(0), 0)
 
     base = turn_ref[0]
 
@@ -852,18 +906,31 @@ def _paged_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
 
         @pl.when(hand_on)
         def _hand_on():
-            start(after, 0, base)
+            start(after, first_of(after), base)
 
     @pl.when(n_pages > 0)
     def _live():
-        qflat = q_ref[0].astype(jnp.float32)           # (1, D), pre-scaled
-        if fold_lora:
-            qflat = qflat + q_scale * lora_delta()[:, :D]
+        if grouped:
+            # q arrives (hp, hd), pre-scaled, zero past the heads: row
+            # c's slice is laid on the columns of its K/V head, c // share
+            qflat = jnp.concatenate(
+                [q_ref[0].astype(jnp.float32)] * kv_heads, axis=1)  # (hp, D)
+        else:
+            qflat = q_ref[0].astype(jnp.float32)       # (1, D), pre-scaled
+            if fold_lora:
+                qflat = qflat + q_scale * lora_delta()[:, :D]
         # row c of the projector holds head c's slice of q on its own
-        # columns; rows past the heads are zero
+        # columns (its K/V head's, where heads are grouped); rows past
+        # the heads are zero
         row = jax.lax.broadcasted_iota(jnp.int32, (hp, D), 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (hp, D), 1)
-        own = (col >= row * hd) & (col < (row + 1) * hd)
+        if grouped:
+            own = (row < 0)
+            for g in range(kv_heads):
+                own |= ((row >= g * share) & (row < (g + 1) * share)
+                        & (col >= g * hd) & (col < (g + 1) * hd))
+        else:
+            own = (col >= row * hd) & (col < (row + 1) * hd)
         terms = _split3(qflat) if one_pass else (qflat,)
         qb = jnp.concatenate(
             [jnp.where(own, t, 0.0) for t in terms], axis=0,
@@ -871,7 +938,7 @@ def _paged_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
 
         def step(j, carry):
             m_prev, l_prev, acc = carry            # (hp, 1), (hp, 1), (hp, D)
-            slot = jax.lax.rem(base + j, 2)
+            slot = jax.lax.rem(base + j - j_first if offset else base + j, 2)
 
             @pl.when(j + 1 < n_steps)
             def _prefetch():
@@ -879,7 +946,7 @@ def _paged_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
 
             @pl.when((j + 1 == n_steps) & hand_on)
             def _hand_on():
-                start(after, 0, 1 - slot)
+                start(after, first_of(after), 1 - slot)
 
             wait(j, slot, 0)
             s = unstack(jax.lax.dot_general(
@@ -889,12 +956,24 @@ def _paged_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
             at = j * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
             if quantized:
                 s = s * page_scales(sk_ref, j)
-            s = jnp.where(at < length, s, -jnp.inf)
+            if offset:
+                # (a step may reach past the table where its pages do not
+                # divide the table's width)
+                live = ((at < jnp.minimum(length, width * page_size))
+                        & (at >= starts_ref[b]))
+            else:
+                live = at < length
+            s = jnp.where(live, s, -jnp.inf)
             # every step the loop reaches holds a live token, so m_new
             # is finite and the first step's alpha is exp(-inf) = 0
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            w = jnp.exp(s - m_new)                 # (hp, span); dead columns 0
+            if offset:  # a step may lie wholly before the window's edge
+                m_at = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+                alpha = jnp.exp(m_prev - m_at)
+                w = jnp.exp(s - m_at)
+            else:
+                alpha = jnp.exp(m_prev - m_new)
+                w = jnp.exp(s - m_new)             # (hp, span); dead columns 0
             l_new = l_prev * alpha + w.sum(axis=1, keepdims=True)
             if quantized:
                 w = w * page_scales(sv_ref, j)
@@ -918,14 +997,28 @@ def _paged_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
             jnp.zeros((hp, 1), jnp.float32),
             jnp.zeros((hp, D), jnp.float32),
         )
-        m_fin, l_fin, acc_fin = jax.lax.fori_loop(0, n_steps, step, init)
-        turn_ref[0] = jax.lax.rem(base + n_steps, 2)
-        # the block diagonal, cut out once: row c keeps head c's columns
-        acc_ref[0] = jnp.where(own, acc_fin, 0.0).sum(axis=0, keepdims=True)
+        m_fin, l_fin, acc_fin = jax.lax.fori_loop(j_first, n_steps, step, init)
+        turn_ref[0] = jax.lax.rem(
+            base + n_steps - j_first if offset else base + n_steps, 2)
+        if grouped:
+            # row c keeps its K/V head's block: one lane slice a K/V
+            # head, the rows of the other groups zero
+            kept = jnp.where(own, acc_fin, 0.0)
+            out = kept[:, :hd]
+            for g in range(1, kv_heads):
+                out = out + kept[:, g * hd:(g + 1) * hd]
+            acc_ref[0] = out
+        else:
+            # the block diagonal, cut out once: row c keeps head c's
+            # columns
+            acc_ref[0] = jnp.where(own, acc_fin, 0.0).sum(
+                axis=0, keepdims=True)
         # m/l lane-padded to (h, 128): Mosaic wants 128-divisible last
-        # block dims; every lane carries the same value
-        m_ref[0] = jnp.broadcast_to(m_fin[:h], m_ref.shape[1:])
-        l_ref[0] = jnp.broadcast_to(l_fin[:h], l_ref.shape[1:])
+        # block dims; every lane carries the same value (grouped heads
+        # leave in whole sublane tiles, hp of them)
+        rows = m_ref.shape[1]
+        m_ref[0] = jnp.broadcast_to(m_fin[:rows], m_ref.shape[1:])
+        l_ref[0] = jnp.broadcast_to(l_fin[:rows], l_ref.shape[1:])
 
 
 def _pages_per_step(page_size: int, table_width: int) -> int:
@@ -938,11 +1031,22 @@ def _pages_per_step(page_size: int, table_width: int) -> int:
     return max(1, min(128 // page_size, table_width))
 
 
+# Tokens one step of the page loop reduces where query heads share K/V
+# heads (the ungrouped loop's is 128: ``_pages_per_step``).  A grouped
+# row is narrow (4 x 128 values against GPT-2-large's 1,280): a 64-token
+# page is 0.16 us of DMA for K and V together, so a two-page step is
+# mostly the loop's own fixed cost, as the latent kernel found
+# (:data:`LATENT_STEP_TOKENS`).  At 512 tokens the four page buffers are
+# 2 MB of VMEM.
+GROUPED_STEP_TOKENS = 512
+
+
 def _stream_decode(q, pk, pv, block_tables, lengths, layer, kv_scales, lora,
-                   *, quantized, fold, q_scale, interpret):
+                   starts=None, *, quantized, fold, q_scale, interpret):
     """The stream kernel's ``pallas_call`` on the whole flat pool (see
     :func:`paged_attention_decode`, which calls it jitted; ``quantized``
-    and ``fold`` say whether ``kv_scales`` and ``lora`` are there)."""
+    and ``fold`` say whether ``kv_scales`` and ``lora`` are there, and
+    ``starts`` whether the lanes have a first live position)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -954,8 +1058,13 @@ def _stream_decode(q, pk, pv, block_tables, lengths, layer, kv_scales, lora,
     if quantized:
         sk, sv = kv_scales
     D = h * hd
-    scalar_args = [block_tables, lengths, layer.reshape(1)]
-    n_prefetch = 3
+    if pk.shape[3] != D:
+        # fewer K/V heads than query heads: the kernel of the pool's own
+        # row, q and the answer riding (heads, head_dim)
+        return _grouped_stream_decode(
+            q, pk, pv, block_tables, lengths, layer, starts, interpret=interpret)
+    scalar_args = [block_tables, lengths, layer.reshape(1), *_given(starts)]
+    n_prefetch = len(scalar_args)
     if quantized:
         scalar_args += [sk, sv]
         n_prefetch += 2
@@ -1025,7 +1134,7 @@ def _stream_decode(q, pk, pv, block_tables, lengths, layer, kv_scales, lora,
     kernel = functools.partial(
         _paged_attention_kernel, page_size=ps, heads=h, head_dim=hd,
         quantized=quantized, fold_lora=fold,
-        q_scale=q_scale)
+        q_scale=q_scale, offset=len(_given(starts)) == 1)
     outs = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -1043,6 +1152,58 @@ def _stream_decode(q, pk, pv, block_tables, lengths, layer, kv_scales, lora,
     return res
 
 
+def _grouped_stream_decode(q, pk, pv, block_tables, lengths, layer, starts, *,
+                           interpret):
+    """:func:`_stream_decode` for a pool whose row holds fewer K/V heads
+    than ``q`` has query heads: the same kernel body with ``kv_heads``
+    set, :data:`GROUPED_STEP_TOKENS` a step, q and the answer as
+    ``(1, heads, head_dim)`` blocks (the rows of a group share the row's
+    columns, so nothing sums them into one flat row).  The native pool
+    type only: no int8 scales, no LoRA fold."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, h, hd = q.shape
+    ps, D = pk.shape[2], pk.shape[3]
+    kv_heads = D // hd
+    hp = -(-h // 8) * 8    # the heads in whole sublane tiles, in and out
+    q = jnp.pad(q, ((0, 0), (0, hp - h), (0, 0)))
+    span = max(1, min(GROUPED_STEP_TOKENS // ps, block_tables.shape[1])) * ps
+    scalar_args = [block_tables, lengths, layer.reshape(1), *_given(starts)]
+    head_spec = pl.BlockSpec((1, hp, hd), lambda b, *prefetch: (b, 0, 0))
+    pad_spec = pl.BlockSpec((1, hp, 128), lambda b, *prefetch: (b, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalar_args),
+        grid=(B,),
+        in_specs=[head_spec, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[head_spec, pad_spec, pad_spec],
+        scratch_shapes=[
+            pltpu.VMEM((2, span, D), pk.dtype),
+            pltpu.VMEM((2, span, D), pv.dtype),
+            pltpu.SemaphoreType.DMA((2, 2, span // ps)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    acc, m, l = pl.pallas_call(
+        functools.partial(
+            _paged_attention_kernel, page_size=ps, heads=h, head_dim=hd,
+            kv_heads=kv_heads, offset=starts is not None),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((B, hp, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B, hp, 128), jnp.float32),
+            jax.ShapeDtypeStruct((B, hp, 128), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(*scalar_args, q, pk, pv)
+    return acc[:, :h], m[:, :h, 0], l[:, :h, 0]
+
+
 
 @functools.lru_cache(maxsize=None)
 def _stream_decode_jit():
@@ -1055,7 +1216,7 @@ def _stream_decode_jit():
 
 
 def paged_attention_decode(q, pk, pv, block_tables, lengths, *, layer,
-                           page_size, kv_scales=None, lora=None):
+                           page_size, kv_scales=None, lora=None, starts=None):
     """Unnormalised flash state of decode attention over one layer of a
     paged pool, addressed IN the whole pool.
 
@@ -1089,6 +1250,18 @@ def paged_attention_decode(q, pk, pv, block_tables, lengths, *, layer,
     grows a fourth element: the raw (B, 3d) f32 delta for the caller's
     self-term and pool write.
 
+    **Grouped-query heads** are read off the operands: a pool whose row
+    is narrower than ``h * hd`` holds ``row / hd`` K/V heads, and query
+    head ``c`` attends K/V head ``c // (h / kv_heads)`` — each page's K
+    and V slices are streamed once and scored against every query head
+    of their group (never repeated to ``h`` heads in HBM or VMEM).
+    ``starts`` ``(B,)`` int32 (None: 0): a lane attends positions
+    ``starts .. lengths - 1`` of its table's span only — a window's live
+    rows — and pages wholly before ``starts`` are not read
+    (:func:`latent_attention_decode` has the same operand).  Neither
+    takes ``kv_scales`` or ``lora`` yet; without either the kernel
+    traced is the one a caller that never heard of them traces.
+
     TPU-first replacement for the ``pk[layer, block_tables]`` gather in
     ``PagedTransformerBlock`` (models/paged.py): the gather copies the
     whole live cache through HBM per layer per step; here pages stream
@@ -1115,6 +1288,18 @@ def paged_attention_decode(q, pk, pv, block_tables, lengths, *, layer,
             f"{pk.shape[2]}"
         )
 
+    grouped = pk.shape[3] != q.shape[1] * q.shape[2]
+    if grouped and (pk.shape[3] % q.shape[2]
+                    or q.shape[1] % (pk.shape[3] // q.shape[2])):
+        raise ValueError(
+            f"paged_attention_decode: a pool row of {pk.shape[3]} does not "
+            f"hold K/V heads of {q.shape[2]} that {q.shape[1]} query heads "
+            "divide over")
+    if (grouped or starts is not None) and (
+            kv_scales is not None or lora is not None):
+        raise ValueError(
+            "paged_attention_decode: grouped-query heads and a window's "
+            "starts take the native pool only (no kv_scales, no lora fold)")
     if kv_scales is not None:
         kv_scales = tuple(jnp.asarray(s, jnp.float32) for s in kv_scales)
     q_scale = 1.0
@@ -1131,6 +1316,7 @@ def paged_attention_decode(q, pk, pv, block_tables, lengths, *, layer,
     return _stream_decode_jit()(
         q, pk, pv, block_tables, lengths, jnp.asarray(layer, jnp.int32),
         kv_scales, lora,
+        **({} if starts is None else {"starts": starts.astype(jnp.int32)}),
         quantized=kv_scales is not None, fold=lora is not None,
         q_scale=float(q_scale), interpret=interpret_mode())
 

@@ -4,7 +4,12 @@ rows only.
 ``route``: logits, softmax and the chosen gates in float32 (on a TPU an
 f32 matmul runs in bf16 passes unless told otherwise, so the router's
 is ``HIGHEST``); top-k of the probabilities, gates **not** renormalised
-(OLMoE's ``norm_topk_prob: false``).
+(OLMoE's ``norm_topk_prob: false``) unless ``norm`` says so
+(SmallThinker's ``true``); the logits may be handed over
+(``router_logits``: a router that reads the attention's input computes
+them before the attention, in the same program).  The experts' gate
+activation is a static argument of the routed entries below (``act``:
+"silu" | "relu", SwiGLU | ReGLU); "silu" traces what it always did.
 
 ``expert_ffn``: the ``T x k`` (token, expert) assignments are sorted by
 expert and the three SwiGLU matmuls run as one grouped SwiGLU over the
@@ -61,22 +66,53 @@ EXPERTS_SCOPE = "moe_experts"
 ROUTER_SCOPE = "moe_router"
 
 
-def route(h, w_router, top_k: int):
-    """``h`` ``(T, d)``, ``w_router`` ``(d, E)`` -> ``(gates (T, k) f32,
-    experts (T, k) int32)``: the top-k softmax probabilities as they
-    are, and whose they are."""
+def router_logits(h, w_router):
+    """``h`` ``(T, d)`` x ``w_router`` ``(d, E)`` in float32 at
+    ``HIGHEST``: the routing logits ``(T, E)``."""
     import jax
     import jax.numpy as jnp
 
     with jax.named_scope(ROUTER_SCOPE):
-        logits = jnp.dot(
+        return jnp.dot(
             h.astype(jnp.float32), w_router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
+
+
+def route(h, w_router, top_k: int, *, logits=None, norm: bool = False):
+    """``h`` ``(T, d)``, ``w_router`` ``(d, E)`` -> ``(gates (T, k) f32,
+    experts (T, k) int32)``: the top-k softmax probabilities as they
+    are, and whose they are.  ``logits`` ``(T, E)``: the router's
+    outputs where they were computed already (:func:`router_logits` of
+    other rows than the experts act on; ``h`` and ``w_router`` are then
+    not read).  ``norm``: the chosen probabilities divided by their sum
+    — the same numbers as a softmax over the chosen logits."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope(ROUTER_SCOPE):
+        if logits is None:
+            logits = jnp.dot(
+                h.astype(jnp.float32), w_router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32,
+            )
         probs = jax.nn.softmax(logits, axis=-1)
         gates, experts = jax.lax.top_k(probs, top_k)
+        if norm:
+            gates = gates / gates.sum(axis=-1, keepdims=True)
     return gates, experts.astype(jnp.int32)
+
+
+def activation(act: str):
+    """The experts' gate activation by name."""
+    import jax
+
+    try:
+        return {"silu": jax.nn.silu, "relu": jax.nn.relu}[act]
+    except KeyError:
+        raise ValueError(f"expert activation {act!r}: 'silu' or 'relu'") from None
 
 
 # ---------------------------------------------------------------------------
@@ -177,10 +213,10 @@ def expert_matmul_impl(rows: int, groups: int, k: int, n: int, dtype,
 
 
 def _stream_kernel(ids_ref, starts_ref, sizes_ref, hit_ref, x_ref, *refs,
-                   rows, row_tile, gated):
+                   rows, row_tile, gated, act="silu"):
     """One grid step ``(j, v)``: the ``v``-th *hit* group's rows through
     the ``j``-th ``(K, width)`` block of its matrix (or of its gate and
-    up matrices, with ``silu(gate) * up`` applied here).
+    up matrices, with ``act(gate) * up`` applied here).
 
     The sorted rows rest whole in VMEM in float32 (a dynamic row slice
     must start on a tile of 8, which bfloat16's packed 16 would coarsen)
@@ -217,7 +253,7 @@ def _stream_kernel(ids_ref, starts_ref, sizes_ref, hit_ref, x_ref, *refs,
             y = jnp.dot(x, w_refs[0][0], preferred_element_type=jnp.float32)
             if gated:
                 up = jnp.dot(x, w_refs[1][0], preferred_element_type=jnp.float32)
-                y = jax.nn.silu(y) * up
+                y = activation(act)(y) * up
             row = r0 + jax.lax.broadcasted_iota(jnp.int32, (row_tile, 1), 0)
             acc_ref[pl.ds(r0, row_tile), :] = jnp.where(
                 row >= start, y, acc_ref[pl.ds(r0, row_tile), :])
@@ -231,10 +267,10 @@ def _stream_kernel(ids_ref, starts_ref, sizes_ref, hit_ref, x_ref, *refs,
 
 
 def stream_matmul(x, matrices, sizes, *, interpret: bool, row_tile: int = 16,
-                  block_bytes: int = STREAM_BLOCK_BYTES):
+                  block_bytes: int = STREAM_BLOCK_BYTES, act: str = "silu"):
     """``x`` ``(R, K)`` sorted by group, ``sizes`` ``(G,)`` -> ``(R, N)``
     float32: ``x[g's rows] @ W[g]`` for one ``(G, K, N)`` matrix, or
-    ``silu(x @ W_gate[g]) * (x @ W_up[g])`` for two, as ONE Pallas call
+    ``act(x @ W_gate[g]) * (x @ W_up[g])`` for two, as ONE Pallas call
     (``moe_stream_down`` / ``moe_stream_gate_up``) that streams each hit
     group's matrices once in ``(K, width)`` blocks
     (:func:`stream_block`), double-buffered by the pipeline, with the
@@ -277,7 +313,7 @@ def stream_matmul(x, matrices, sizes, *, interpret: bool, row_tile: int = 16,
             + 2 * rows * width * 4 + padded * width * 4)
     return pl.pallas_call(
         functools.partial(_stream_kernel, rows=rows, row_tile=row_tile,
-                          gated=gated),
+                          gated=gated, **({} if act == "silu" else {"act": act})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, n), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -288,26 +324,28 @@ def stream_matmul(x, matrices, sizes, *, interpret: bool, row_tile: int = 16,
     )(ids, starts, sizes, hit.reshape(1), x, *matrices)
 
 
-def stream_swiglu(rows, w_gate, w_up, w_down, sizes, *, interpret: bool):
+def stream_swiglu(rows, w_gate, w_up, w_down, sizes, *, interpret: bool,
+                  act: str = "silu"):
     """The grouped SwiGLU as two streaming kernels a segment of
-    :func:`stream_segment_rows` rows: ``silu(gate) * up`` in the first,
+    :func:`stream_segment_rows` rows: ``act(gate) * up`` in the first,
     the down projection of what it leaves in the second.  A segment is
     handed the part of each group's rows that lies in it; one with no
     row of any group (a held pass's rows past its groups) runs nothing
     and comes back as zeros.  Jitted: a program traces and lowers it
     once for all its layers."""
     return _stream_swiglu_jit()(rows, w_gate, w_up, w_down, sizes,
-                                interpret=interpret)
+                                interpret=interpret, act=act)
 
 
 @functools.lru_cache(maxsize=None)
 def _stream_swiglu_jit():
     import jax
 
-    return jax.jit(_stream_swiglu, static_argnames=("interpret",))
+    return jax.jit(_stream_swiglu, static_argnames=("interpret", "act"))
 
 
-def _stream_swiglu(rows, w_gate, w_up, w_down, sizes, *, interpret: bool):
+def _stream_swiglu(rows, w_gate, w_up, w_down, sizes, *, interpret: bool,
+                   act: str = "silu"):
     import jax
     import jax.numpy as jnp
 
@@ -316,8 +354,8 @@ def _stream_swiglu(rows, w_gate, w_up, w_down, sizes, *, interpret: bool):
     kw = dict(interpret=interpret, row_tile=stream_row_tile(total, groups))
 
     def run(x, part):
-        act = stream_matmul(x, (w_gate, w_up), part, **kw)
-        return stream_matmul(act, (w_down,), part, **kw)
+        hidden = stream_matmul(x, (w_gate, w_up), part, act=act, **kw)
+        return stream_matmul(hidden, (w_down,), part, **kw)
 
     seg = stream_segment_rows(max(d, f))
     if total <= seg:
@@ -335,7 +373,7 @@ def _stream_swiglu(rows, w_gate, w_up, w_down, sizes, *, interpret: bool):
     return jnp.concatenate(outs)
 
 
-def ragged_swiglu(rows, w_gate, w_up, w_down, sizes, inner):
+def ragged_swiglu(rows, w_gate, w_up, w_down, sizes, inner, act: str = "silu"):
     """The grouped SwiGLU as three ``jax.lax.ragged_dot``s; gate and up
     leave theirs in ``inner``."""
     import jax
@@ -343,15 +381,16 @@ def ragged_swiglu(rows, w_gate, w_up, w_down, sizes, inner):
 
     gate = jax.lax.ragged_dot(rows, w_gate, sizes, preferred_element_type=inner)
     up = jax.lax.ragged_dot(rows, w_up, sizes, preferred_element_type=inner)
-    act = (jax.nn.silu(gate.astype(jnp.float32))
-           * up.astype(jnp.float32)).astype(w_down.dtype)
+    hidden = (activation(act)(gate.astype(jnp.float32))
+              * up.astype(jnp.float32)).astype(w_down.dtype)
     # what leaves the layer stays float32 up to the residual add
-    return jax.lax.ragged_dot(act, w_down, sizes,
+    return jax.lax.ragged_dot(hidden, w_down, sizes,
                               preferred_element_type=jnp.float32)
 
 
-def grouped_swiglu(rows, w_gate, w_up, w_down, sizes, *, inner=None):
-    """``W_down[g] (silu(r W_gate[g]) * (r W_up[g]))`` for every row
+def grouped_swiglu(rows, w_gate, w_up, w_down, sizes, *, inner=None,
+                   act: str = "silu"):
+    """``W_down[g] (act(r W_gate[g]) * (r W_up[g]))`` for every row
     ``r`` of ``rows`` ``(R, d)``, sorted by group with ``sizes`` ``(G,)``
     rows each (rows past the groups come back undefined): ``(R, d)``
     float32.  ``w_gate`` / ``w_up`` ``(G, d, f)``, ``w_down`` ``(G, f,
@@ -368,13 +407,13 @@ def grouped_swiglu(rows, w_gate, w_up, w_down, sizes, *, inner=None):
     with jax.named_scope(EXPERTS_SCOPE):
         if impl == "stream":
             return stream_swiglu(rows, w_gate, w_up, w_down, sizes,
-                                  interpret=backend != "tpu")
+                                  interpret=backend != "tpu", act=act)
         return ragged_swiglu(rows, w_gate, w_up, w_down, sizes,
-                             rows.dtype if inner is None else inner)
+                             rows.dtype if inner is None else inner, act)
 
 
-def expert_ffn(h, w_gate, w_up, w_down, gates, experts):
-    """``sum_k gates[t, k] * W_down[e] (silu(h W_gate[e]) * (h W_up[e]))``
+def expert_ffn(h, w_gate, w_up, w_down, gates, experts, *, act: str = "silu"):
+    """``sum_k gates[t, k] * W_down[e] (act(h W_gate[e]) * (h W_up[e]))``
     with ``e = experts[t, k]``: ``h`` ``(T, d)``, ``w_gate``/``w_up``
     ``(E, d, f)``, ``w_down`` ``(E, f, d)`` -> ``(T, d)`` float32."""
     import jax.numpy as jnp
@@ -386,7 +425,8 @@ def expert_ffn(h, w_gate, w_up, w_down, gates, experts):
     sizes = jnp.zeros((num_experts,), jnp.int32).at[flat].add(1)
     rows = h.astype(w_gate.dtype)[order // top_k]           # (T*k, d)
     inner = jnp.float32 if h.dtype == jnp.float32 else w_gate.dtype
-    out = grouped_swiglu(rows, w_gate, w_up, w_down, sizes, inner=inner)
+    out = grouped_swiglu(rows, w_gate, w_up, w_down, sizes, inner=inner,
+                         act=act)
     # back to (token, k) order, then the gated sum over a token's k
     out = out[jnp.argsort(order)].reshape(tokens, top_k, -1)
     return jnp.einsum("tkd,tk->td", out, gates)
@@ -514,7 +554,7 @@ def layer_expert_matmul(tokens: int, top_k: int, held: int, num_experts: int,
 
 
 def expert_ffn_held(h, w_gate, w_up, w_down, gates, experts, offset: int,
-                    num_experts: int):
+                    num_experts: int, *, act: str = "silu"):
     """The held experts' part of :func:`expert_ffn`'s sum: ``w_gate`` /
     ``w_up`` ``(H, d, f)`` and ``w_down`` ``(H, f, d)`` are experts
     ``offset .. offset + H`` of the ``num_experts`` that ``experts``
@@ -557,7 +597,8 @@ def expert_ffn_held(h, w_gate, w_up, w_down, gates, experts, offset: int,
         sizes = (which[:, None] == jnp.arange(held)[None, :]).sum(
             axis=0).astype(jnp.int32)
         rows = rows_in[jnp.minimum(idx // top_k, tokens - 1)]       # (cap, d)
-        out = grouped_swiglu(rows, w_gate, w_up, w_down, sizes, inner=inner)
+        out = grouped_swiglu(rows, w_gate, w_up, w_down, sizes, inner=inner,
+                             act=act)
         # rows past the groups hold whatever the kernel left there
         out = jnp.where((which < held)[:, None], out, 0.0)
         out = jnp.concatenate([out, jnp.zeros((1, d_model), out.dtype)])

@@ -618,6 +618,17 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
         "counter", "seldon_tpu_engine_sparse_rows_moved_total",
         "cached rows the page loop streamed under a selection's mask "
         "(over sparse_rows_read: what a page-skipping kernel could save)"),
+    # grouped-query heads over K/V pools of kinds (PR 41): what decode
+    # read of the cache and what it would read with no window; 0 on any
+    # other engine
+    "gqa_kv_rows_read": (
+        "counter", "seldon_tpu_engine_gqa_kv_rows_read_total",
+        "cached K/V rows decode lane-steps read, over the layers (a full "
+        "layer's every cached row, a window layer's live ones)"),
+    "gqa_kv_rows_cached": (
+        "counter", "seldon_tpu_engine_gqa_kv_rows_cached_total",
+        "K/V rows cached for the lane-steps gqa_kv_rows_read counts, "
+        "over the layers (what they would read with no window)"),
     "window_pages_released": (
         "counter", "seldon_tpu_engine_window_pages_released_total",
         "window-layer pages given back to their allocator behind the "

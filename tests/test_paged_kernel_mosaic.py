@@ -518,3 +518,62 @@ def test_the_selection_s_programs_compile_at_the_cells_shapes(one_chip, mosaic):
         spec((128, 512, 128)), spec((128, 512, 128)), spec((1, seg, 64, 128)),
         spec((1, seg, 64), jnp.float32), spec((1, seg, 128))).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+
+
+# ---------------------------------------------------------------------------
+# grouped-query heads (PR 41): SmallThinker's 28 query heads of 128 on 4
+# K/V heads, pools of 512 lanes — the full layers' (3, 10241, 64, 512)
+# under the block table, the window layers' (9, 4225, 64, 512) under the
+# 66-column window table with a first live position a lane
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kind,layers,num_pages,pages,lanes", [
+    ("full", 3, 10241, 160, 64), ("full", 3, 10241, 32, 32),
+    ("window", 9, 4225, 66, 64), ("window", 9, 4225, 66, 32)])
+def test_grouped_page_loop_compiles_at_smallthinker_s_shapes(
+        one_chip, mosaic, kind, layers, num_pages, pages, lanes):
+    heads, kv_heads, hd = 28, 4, 128
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def call(q, pk, pv, tables, lengths, starts):
+        return kernels.paged_attention_decode(
+            q, pk, pv, tables, lengths, layer=1, page_size=PS,
+            **({"starts": starts} if kind == "window" else {}))
+
+    pool = spec((layers, num_pages, PS, kv_heads * hd), jnp.bfloat16)
+    compiled = jax.jit(call).lower(
+        spec((lanes, heads, hd), jnp.bfloat16), pool, pool,
+        spec((lanes, pages), jnp.int32), spec((lanes,), jnp.int32),
+        spec((lanes,), jnp.int32)).compile()
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln and " = " in ln]
+    assert len(calls) == 1, calls
+    # what the benchmark's reader knows the page loop by: the attended
+    # values of every query head, the heads in whole sublane tiles
+    assert calls[0].partition(" = ")[2].lstrip("(").startswith(
+        f"f32[{lanes},32,{hd}]"), calls[0][:200]
+
+
+@pytest.mark.parametrize("k,bucket,window", [
+    (1, 8192, 0), (1, 8192, 4096), (1, 10240, 4096), (2, 2048, 0), (1, 3072, 4096)])
+def test_grouped_causal_kernel_compiles_at_smallthinker_s_shapes(
+        one_chip, mosaic, k, bucket, window):
+    """A prefill from zero: 28 query heads on 4 K/V heads by the block
+    index, a K/V head's 8,192 (10,240) positions resident, a window
+    layer's blocks behind 4,096 skipped."""
+    def spec(heads):
+        return jax.ShapeDtypeStruct((k, bucket, heads, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, key, v: kernels.causal_attention(
+            q, key, v, 128 ** -0.5, window=window)
+    ).lower(spec(28), spec(4), spec(4)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and (
+        "prefill_window_attention" if window else "prefill_causal_attention") in text
+    assert f"bf16[{k * 28},{bucket},128]" in text
+    assert kernels.prefill_attention_impl(
+        bucket, 128, 128, jnp.bfloat16, 0, True) == "fused"

@@ -9,7 +9,13 @@ served tokens — each variant is also put through the cell's own kind
 (``harness/kinds/generation_share_sparse.py verdict``: ``ok``, ``near``),
 and the wrong programs are the reference's own ``variant``s — the
 selection left out, the window one position short or wide, the gate or
-the rescale left out)
+the rescale left out; with ``--config smallthinker-21b-a3b --positions
+4864 --judged 256`` SmallThinker's: a prompt of 4,608 past the 4,096
+window and 256 judged tokens, through ``generation_share``'s two limits
+as ``harness/kinds/generation_share_window.py`` applies them, the wrong
+programs ``reference/smallthinker.py``'s ``VARIANTS`` — the router fed
+from the post-attention norm, ``silu`` for ``relu``, a full layer
+rotated, a window layer left whole, a K/V head mapped ``h % 4``)
 says of the tokens that the same forward pass serves in a lower
 precision, or with a fault.  CPU only (``JAX_PLATFORMS=cpu``), 1-2
 minutes a variant at 512 positions; nothing here is a device number.
@@ -347,11 +353,59 @@ def rounded_logits_dots3(ref, params, model, tokens, residual_bf16=False, e4m3=F
         return mm(xn, params["head"]["kernel"])
 
 
+def smallthinker_rounding(e4m3: bool):
+    """``reference/smallthinker.py``'s ``rounding=`` hook: ``(operand,
+    result, weight)`` as the stated precision rounds them (bfloat16
+    operands and results, float32 accumulation; the router, norms,
+    softmax and residual stream float32), or with the operands of every
+    weight matmul in 8 bits."""
+    f32, b16, act, w, _res = roundings(False, e4m3)
+    return (act if e4m3 else b16), b16, w
+
+
 # dots3-note's wrong programs, each the reference itself with one thing
 # changed (``reference/dots3_note.py`` ``variant``), in float32
 DOTS3_WRONG = {"no-selection": "no_selection", "window-short": "window_short",
                "window-wide": "window_wide", "no-gate": "no_gate",
                "no-rescale": "no_rescale"}
+
+
+def smallthinker_readings(args, config, ref, params, tokens) -> int:
+    """The stated precision, 8-bit operands, each wrong program of the
+    reference's own and a stale row, judged at the last ``--judged``
+    positions (every one without it) as the cell's kind judges them."""
+    import numpy as np
+
+    from harness import manifest
+
+    model, n = config["model"], len(tokens)
+    kind = manifest.module("harness/kinds", config["kind"])
+    tail = {"tail": args.judged} if args.judged else {}
+    judged = args.judged or n
+    plain = np.asarray(ref.logits(params, model, tokens, **tail))
+    std = plain.std(-1)
+    names = (["stated", "e4m3", *ref.VARIANTS, "early-row"]
+             if args.variants == ",".join(VARIANTS) else args.variants.split(","))
+    for name in names:
+        far = None
+        if name == "early-row":
+            served = np.concatenate([plain[:1].argmax(-1), plain[:-1].argmax(-1)])
+        else:
+            how = ({"variant": name} if name in ref.VARIANTS
+                   else {"rounding": smallthinker_rounding(name == "e4m3")})
+            got = np.asarray(ref.logits(params, model, tokens, **tail, **how))
+            served = got.argmax(-1)
+            far = float(np.median(np.sqrt(((got - plain) ** 2).mean(-1)) / std))
+        gaps = (plain.max(-1) - plain[np.arange(judged), served]) / std
+        off = int((gaps > kind.TIE_STDS).sum())
+        ok = off <= kind.OFF_SHARE_MAX * judged and float(gaps.max()) <= kind.WORST_GAP_STDS
+        print(json.dumps({"variant": name, "seed": args.seed, "positions": judged, "ok": bool(ok),
+                          "not_top1": int((gaps > 0).sum()), "off": off,
+                          "off_share_pct": 100.0 * off / judged,
+                          "worst_gap_stds": float(gaps.max()),
+                          "gap_stds_p05_p50": [float(q) for q in np.quantile(gaps, [0.05, 0.5])],
+                          "logits_rms_stds": far}), flush=True)
+    return 0
 
 
 def main() -> int:
@@ -375,6 +429,8 @@ def main() -> int:
     n = args.positions
     tokens = np.random.default_rng(args.seed).integers(0, model["vocab_size"], size=n).tolist()
     dots3 = config["reference"] == "dots3_note"
+    if config["reference"] == "smallthinker":
+        return smallthinker_readings(args, config, ref, params, tokens)
     # (dots3-note: judged where a row has over index_topk candidates and a
     # slid window, as the cell's sample is: the last --judged positions, or
     # every one past index_topk)
