@@ -146,7 +146,9 @@ def prefill_position_bytes(spec, d_model: int, vocab_size: int,
       per head — and the FFN's: a dense layer's hidden rows (float32
       and bf16; gate, up and their product for SwiGLU), or a routed
       layer's rows for the assignments a token brings to the experts
-      held here (at :func:`moe.held_rows_cap`'s headroom), each its bf16
+      held here (at ``moe.HELD_ROWS_HEADROOM`` even shares: what
+      :func:`moe.held_rows_cap` holds under the ridge and the bound on
+      what it holds over it, where a pass is smaller), each its bf16
       input, gate, up, product and float32 output, beside the shared
       expert's; a double layer's dense and routed rows together."""
     from seldon_core_tpu.ops import moe
@@ -3283,14 +3285,6 @@ class PagedEngine:
                 for pool in jax.tree_util.tree_leaves(self.pages_k))
             if self._kv_int8:
                 self._pool_shard_bytes += 2 * int(self.scales_k.nbytes)
-        if spec.experts_held:
-            from seldon_core_tpu.ops import moe as _moe
-
-            self._moe_held_pass_rows = _moe.held_rows_cap(
-                self.max_slots, spec.experts_per_tok, spec.held,
-                spec.router_outputs)
-        else:
-            self._moe_held_pass_rows = 0
         # what one prefill call may pay for (module top): from what this
         # device says it holds, less the weights as they rest (in the
         # compute type since the cast above: no program makes a second
@@ -3556,6 +3550,17 @@ class PagedEngine:
                           # layer, step)s — 0 where every expert is held
                           "moe_local_assignments": 0,
                           "moe_held_active_expert_steps": 0,
+                          # ... and what its prefill calls' held passes
+                          # did, by the host's arithmetic on each call's
+                          # routing histogram (ops/moe.py
+                          # held_pass_account): the rows the passes
+                          # computed, the local assignments they were
+                          # for, and the passes beyond a layer's first.
+                          # The histogram leaves a call's pad positions
+                          # out and the pass does not: a lower bound
+                          "prefill_held_rows": 0,
+                          "prefill_held_local": 0,
+                          "prefill_held_extra_passes": 0,
                           # a router that also scores identity experts
                           # (spec.zero_experts; 0 otherwise): picks
                           # that fell on them; the (token, layer)s
@@ -6524,6 +6529,8 @@ class PagedEngine:
                             * (self.module.num_layers - self.spec.dense_layers)}
             if self.spec.routed else {}
         )
+        if self.spec.experts_held:
+            routed["held_rows"] = self._held_pass_rows(k * bucket)
         fused = not use_cache and self._prefill_attention[bucket] == "fused"
         self._seam.begin_prefill(
             bucket=bucket, k=k, rows=len(group),
@@ -6623,7 +6630,8 @@ class PagedEngine:
             )
             self._seam.dispatched()
             self._store_kv(pk_out, pv_out)
-        self._moe_hold(hist)  # a routed spec's int32[layers, E]
+        # a routed spec's int32[layers, E], beside its held pass's rows
+        self._moe_hold(hist, self._held_pass_rows(k * bucket))
         finals: List[Tuple[int, _Stream]] = []
         for i, (stream, start, n) in enumerate(group):
             stream.prefilled = start + n
@@ -7799,6 +7807,12 @@ class PagedEngine:
             # which grouped expert matmul each program traced
             # (ops/moe.py grouped_swiglu): {} for a dense model
             "expert_matmul": self._expert_matmul_report(),
+            # ... and, where a replica holds a share of the experts, the
+            # rows a held pass of each computes
+            **({"held_pass_rows": {
+                    name: self._held_pass_rows(tokens)
+                    for name, tokens in self._routed_program_tokens().items()}}
+               if self.spec.experts_held else {}),
             # what each bucket's from-zero prefill attends with:
             # "fused" (ops/kernels.py causal_attention) or "xla"; every
             # cached-suffix prefill is XLA's
@@ -7807,12 +7821,27 @@ class PagedEngine:
                 for bucket, impl in self._prefill_attention.items()},
         }
 
-    def _expert_matmul_report(self) -> Dict[str, str]:
-        """``"stream"`` | ``"ragged_dot"`` for the chunk programs (a
+    def _routed_program_tokens(self) -> Dict[str, int]:
+        """The tokens each program routes a layer: the chunk programs (a
         decode step routes ``max_slots`` tokens; a speculative verify
-        ``draft_k + 1`` times as many) and for every prefill bucket x
-        group a call may take, from the rule the programs trace with
-        (ops/moe.py ``layer_expert_matmul``)."""
+        ``draft_k + 1`` times as many) and every prefill bucket x group
+        a call may take."""
+        tokens = {"chunk": self.max_slots}
+        if self.speculative is not None:
+            tokens["spec_chunk"] = self.max_slots * (self.draft_k + 1)
+        for bucket in self.prompt_buckets:
+            most = min(self.max_slots,
+                       prefill_group_max(bucket, self.prefill_positions_max))
+            k = 1
+            while k <= most:
+                tokens[f"prefill_b{bucket}_k{k}"] = bucket * k
+                k *= 2
+        return tokens
+
+    def _expert_matmul_report(self) -> Dict[str, str]:
+        """``"stream"`` | ``"ragged_dot"`` for each program
+        (:meth:`_routed_program_tokens`), from the rule the programs
+        trace with (ops/moe.py ``layer_expert_matmul``)."""
         spec = self.spec
         if not spec.routed:
             return {}
@@ -7823,23 +7852,26 @@ class PagedEngine:
             leaf for path, leaf in tree_util.tree_flatten_with_path(self.params)[0]
             if "experts_gate" in tree_util.keystr(path))
         held, d_model, width = gate.shape[-3:]
-
-        def impl(tokens: int) -> str:
-            return moe.layer_expert_matmul(
+        # a softmax router's layer runs the held pass where the replica
+        # holds a share (_ffn); the other routers' layers always do
+        held_pass = bool(spec.experts_held) or spec.score != "softmax"
+        return {
+            name: moe.layer_expert_matmul(
                 tokens, spec.experts_per_tok, held, spec.router_outputs,
-                d_model, width, gate.dtype, held_pass=spec.score != "softmax")
+                d_model, width, gate.dtype, held_pass=held_pass)
+            for name, tokens in self._routed_program_tokens().items()}
 
-        report = {"chunk": impl(self.max_slots)}
-        if self.speculative is not None:
-            report["spec_chunk"] = impl(self.max_slots * (self.draft_k + 1))
-        for bucket in self.prompt_buckets:
-            most = min(self.max_slots,
-                       prefill_group_max(bucket, self.prefill_positions_max))
-            k = 1
-            while k <= most:
-                report[f"prefill_b{bucket}_k{k}"] = impl(bucket * k)
-                k *= 2
-        return report
+    def _held_pass_rows(self, tokens: int) -> int:
+        """The rows one held pass computes in a program that routes
+        ``tokens`` tokens a layer (ops/moe.py ``held_rows_cap``); 0
+        unless this replica holds a share of the experts."""
+        spec = self.spec
+        if not spec.experts_held:
+            return 0
+        from seldon_core_tpu.ops import moe
+
+        return moe.held_rows_cap(tokens, spec.experts_per_tok, spec.held,
+                                 spec.router_outputs)
 
     def _moe_held_hits(self):
         """Cumulative assignments per (routed layer, expert held here)."""
@@ -7847,14 +7879,16 @@ class PagedEngine:
         lo = spec.expert_offset
         return self._moe_hits[spec.dense_layers:, lo:lo + spec.held]
 
-    def _moe_hold(self, counts):
+    def _moe_hold(self, counts, held_rows: int = 0):
         """Keep a just-dispatched program's routing counts (``()`` for a
         dense spec) until the next chunk is harvested, and start their
         copy to the host now: it lands while the device works, so the
-        harvest that reads them waits for nothing but its tokens."""
+        harvest that reads them waits for nothing but its tokens.
+        ``held_rows``: the rows a held pass of a prefill program
+        computes, kept beside its histogram."""
         for c in counts:
             c.copy_to_host_async()
-        self._moe_pending += counts
+        self._moe_pending += [(c, held_rows) for c in counts]
 
     def _moe_take(self) -> List[Any]:
         """Hand over the routing counts held since the last wave was
@@ -7873,8 +7907,8 @@ class PagedEngine:
         spec."""
         if not self.spec.routed:
             return None
-        chunk = np.asarray(pending.pop()) if has_chunk else None
-        return chunk, [np.asarray(h) for h in pending]
+        chunk = np.asarray(pending.pop()[0]) if has_chunk else None
+        return chunk, [(np.asarray(h), rows) for h, rows in pending]
 
     def _moe_count_locked(self, moe_np) -> Dict[str, float]:
         """Book one wave's routing counts; returns the harvest
@@ -7898,9 +7932,18 @@ class PagedEngine:
             for name, n in row.items():
                 self._counters[name] += n
             chunk = chunk[:-1]
+        from seldon_core_tpu.ops import moe
+
         hits = chunk[:, :e].astype(np.int64)
-        for h in prefills:
+        lo, held = spec.expert_offset, spec.held
+        for h, held_rows in prefills:
             hits = hits + h
+            if held_rows:
+                rows, local, extra = moe.held_pass_account(
+                    h[spec.dense_layers:, lo:lo + held].sum(axis=1), held_rows)
+                self._counters["prefill_held_rows"] += rows
+                self._counters["prefill_held_local"] += local
+                self._counters["prefill_held_extra_passes"] += extra
         self._moe_hits += hits
         outputs = spec.router_outputs
         self._counters["moe_assignments"] += int(hits[:, :outputs].sum())
@@ -7915,10 +7958,9 @@ class PagedEngine:
         active, steps = int(chunk[:, e].sum()), int(chunk[:, e + 1].sum())
         self._counters["moe_active_expert_steps"] += active
         self._counters["moe_layer_steps"] += steps
-        if self.spec.experts_held:
-            lo = self.spec.expert_offset
+        if spec.experts_held:
             self._counters["moe_local_assignments"] += int(
-                hits[:, lo:lo + self.spec.held].sum())
+                hits[:, lo:lo + held].sum())
             active = int(chunk[:, e + 2].sum())  # of the experts held here
             self._counters["moe_held_active_expert_steps"] += active
         return {"experts_active": round(active / max(steps, 1), 2)}
@@ -8065,7 +8107,7 @@ class PagedEngine:
                 # (ops/moe.py held_rows_cap at max_slots tokens): the
                 # row count of its grouped matmuls in a trace; 0 unless
                 # this replica holds a share
-                "moe_held_pass_rows": self._moe_held_pass_rows,
+                "moe_held_pass_rows": self._held_pass_rows(self.max_slots),
             }
             outputs = self.spec.router_outputs
             moe_expert_hits = (  # cumulative assignments per router output

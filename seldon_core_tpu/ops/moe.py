@@ -61,6 +61,7 @@ metadata (``jax.named_scope``), whatever the compiler calls them.
 from __future__ import annotations
 
 import functools
+import math
 
 EXPERTS_SCOPE = "moe_experts"
 ROUTER_SCOPE = "moe_router"
@@ -514,28 +515,74 @@ def real_pick_histogram(experts, num_experts: int, mask=None):
     return hit.sum(axis=0)
 
 
-# A pass of ``expert_ffn_held`` holds this many times the rows an even
-# router would send here: routing is not even (PERF.md section 6, PR 30:
-# a share's load swings 2.5 to 3.4 % about 3.125), and a second pass
-# streams the held experts' matrices again, where empty rows cost only
-# their MXU time.
+# A pass of ``expert_ffn_held`` under the ridge holds this many times the
+# rows an even router would send here: routing is not even (PERF.md
+# section 6, PR 30: a share's load swings 2.5 to 3.4 % about 3.125), and
+# a second pass streams the held experts' matrices again, where empty
+# rows cost only their MXU time.
 HELD_ROWS_HEADROOM = 4
 # ... and never fewer than this: a pass's sort slice, gathers and three
 # kernel launches are not worth a smaller one.
 HELD_ROWS_MIN = 64
+# Over the ridge (a pass of :data:`STREAM_MAX_MEAN_ROWS` rows an expert
+# and more) it is the other way round: a row costs its FLOPs and three
+# float32 copies of ``(rows, d_model)`` whether it is real or empty, and
+# a second pass costs only its rows.  So a pass holds this many times
+# the even share, in whole 512s.  The whole held pass on the v5e, ms a
+# layer, sized at that power of two / 2 x / 1.5 x / 1.25 x the even share
+# / two passes of 0.75 x (my chip runs, PR 42, tools/probe_moe.py
+# --held; near-even seeded routing): SmallThinker's 16 x (2560, 768) at
+# 2,048 tokens 3.05 / 1.89 / 1.63 / 1.62 / 1.44, 4,096 5.86 / 4.32 / 2.64
+# / 2.59 / 3.55, 8,192 11.25 / 8.15 / 7.70 / 7.46 / 6.49; GigaChat's 8 x
+# (7168, 2048) at 6,144 14.75 / 9.03 / 9.15 / 8.88 / 12.44, 8,192 17.95 /
+# 16.92 / 11.21 / 11.17 / 16.15.  1.25 buys under 3 % over 1.5 and leaves
+# a share that runs a fifth over even (a cell's busiest expert is 1.3-1.4
+# times its mean) a second pass; two passes cost from a sixth less to a
+# third more than one of twice the rows, never a pass's weights again.
+HELD_ROWS_RIDGE_HEADROOM = 1.5
 
 
 def held_rows_cap(tokens: int, top_k: int, held: int, num_experts: int) -> int:
-    """Rows one pass of :func:`expert_ffn_held` computes: the share of
-    the ``tokens x top_k`` assignments that ``held`` of ``num_experts``
-    experts get when routing is even, :data:`HELD_ROWS_HEADROOM` times
-    over, rounded up to a power of two.  More local assignments than
-    that are computed in further passes, none dropped."""
+    """Rows one pass of :func:`expert_ffn_held` computes, from the share
+    of the ``tokens x top_k`` assignments that ``held`` of
+    ``num_experts`` experts get when routing is even.  More local
+    assignments than a pass holds are computed in further passes, none
+    dropped.
+
+    Under the ridge — where :data:`HELD_ROWS_HEADROOM` times that
+    share, rounded up to a power of two, still leaves an expert fewer
+    than :data:`STREAM_MAX_MEAN_ROWS` rows (a decode chunk, a small
+    prefill) — that power of two.  At and over it,
+    :data:`HELD_ROWS_RIDGE_HEADROOM` times the share in whole 512s,
+    never over every assignment and never under the ridge's own
+    ``STREAM_MAX_MEAN_ROWS x held``: what the power of two is at the
+    line, so the two halves meet there."""
     even = tokens * top_k * held / num_experts
     cap = HELD_ROWS_MIN
     while cap < HELD_ROWS_HEADROOM * even:
         cap *= 2
-    return cap
+    ridge = STREAM_MAX_MEAN_ROWS * held
+    if cap < ridge:
+        return cap
+
+    def tiles(rows):
+        return math.ceil(rows / 512) * 512
+
+    return max(ridge, min(tiles(HELD_ROWS_RIDGE_HEADROOM * even),
+                          tiles(tokens * top_k)))
+
+
+def held_pass_account(local, cap: int):
+    """What a program's held passes did, from its routing histogram:
+    ``local`` holds a routed layer's local assignments each (any
+    integers a layer), ``cap`` the program's :func:`held_rows_cap`.
+    ``(rows computed, local assignments, passes beyond a layer's
+    first)``: a layer runs ``ceil(local / cap)`` passes of ``cap`` rows
+    (:func:`expert_ffn_held`'s loop), none where nothing is local."""
+    local = [int(n) for n in local]
+    passes = [-(-n // cap) for n in local]
+    return (cap * sum(passes), sum(local),
+            sum(max(0, p - 1) for p in passes))
 
 
 def layer_expert_matmul(tokens: int, top_k: int, held: int, num_experts: int,

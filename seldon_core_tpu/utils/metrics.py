@@ -646,6 +646,18 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
         "gauge", "seldon_tpu_engine_moe_held_pass_rows",
         "rows one pass of a decode step's held experts computes (a "
         "replica holding a share of an expert-parallel layer; 0 otherwise)"),
+    "prefill_held_rows": (
+        "counter", "seldon_tpu_engine_prefill_held_rows_total",
+        "rows the held-experts passes of prefill calls computed (passes a "
+        "routed layer x the program's rows a pass; by the call's routing "
+        "histogram, which leaves pad positions out)"),
+    "prefill_held_local": (
+        "counter", "seldon_tpu_engine_prefill_held_local_total",
+        "local assignments those passes were for: over prefill_held_rows, "
+        "how full a pass is"),
+    "prefill_held_extra_passes": (
+        "counter", "seldon_tpu_engine_prefill_held_extra_passes_total",
+        "held-experts passes of prefill calls beyond a layer's first"),
     "moe_load_max": ("gauge", "seldon_tpu_engine_moe_load_max",
                      "cumulative assignments of the busiest (layer, "
                      "expert) pair"),

@@ -302,6 +302,15 @@ def test_engine_serves_and_counts_routing_and_latent_rows(monkeypatch):
         local = sum(int(((c >= 2) & (c < 6)).sum()) for c in routing)
         assert stats["moe_local_assignments"] == local
         assert 0 < local < stats["moe_assignments"]
+        # the prompt's one prefill call: a held pass an expert layer at
+        # its bucket's rows, for the prompt's own local assignments
+        bucket = next(b for b in eng.prompt_buckets if b >= len(PROMPT))
+        assert stats["prefill_held_rows"] == expert_layers * moe.held_rows_cap(
+            bucket, k, 4, MODEL["n_routed_experts_published"])
+        assert stats["prefill_held_local"] == sum(
+            int(((c[:len(PROMPT)] >= 2) & (c[:len(PROMPT)] < 6)).sum())
+            for c in routing)
+        assert stats["prefill_held_extra_passes"] == 0
         # one lane decoding: k experts hit per (expert layer, step), and
         # of the held ones those the reference chose there
         assert stats["moe_layer_steps"] == 8 * expert_layers

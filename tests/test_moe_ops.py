@@ -204,6 +204,103 @@ def test_held_experts_in_a_while_loop(lane, case):
         assert not np.asarray(got).any()
 
 
+# The four configurations that hold a share, at their published sizes:
+# (top-k, held, router outputs), the decode pass's (tokens, rows) and the
+# prefill programs' tokens with, where the pass stays under the ridge,
+# its rows as they were before the rule told the regimes apart (PR 42).
+HELD_SHARES = {
+    "gigachat3.1": ((8, 8, 256), (128, 128), {
+        16: 64, 128: 128, 1024: 1024, 2048: 2048, 4096: None, 8192: None}),
+    "longcat-flash": ((12, 16, 768), (128, 128), {
+        16: 64, 512: 512, 1024: 1024, 2048: 2048, 3072: 4096, 4096: 4096}),
+    "dots3": ((8, 8, 256), (128, 128), {
+        16: 64, 1024: 1024, 2048: 2048, 3072: None, 4096: None, 6144: None,
+        7168: None, 8192: None}),
+    "smallthinker": ((6, 16, 64), (64, 512), {
+        16: 128, 128: 1024, 512: 4096, 1024: 4096, 2048: None, 3072: None,
+        4096: None, 6144: None, 8192: None}),
+}
+# ... and the rows over the ridge, where the issue's table names them
+OVER_THE_RIDGE = {
+    "gigachat3.1": {4096: 2048, 8192: 3072},
+    "dots3": {3072: 2048, 4096: 2048, 6144: 2560, 8192: 3072},
+    "smallthinker": {2048: 4608, 3072: 7168, 4096: 9216, 6144: 13824, 8192: 18432},
+}
+
+
+@pytest.mark.parametrize("config", sorted(HELD_SHARES))
+def test_a_pass_s_rows_follow_the_regime(config):
+    """Under the ridge (fewer than 256 rows an expert at four even
+    shares) a pass is the power of two it always was: every decode
+    chunk, every small prefill.  At and over it: whole 512s, at least
+    the ridge's own rows, at least the even share (one pass when routing
+    is even), at most every assignment (the ridge's rows first), and
+    never fewer for more tokens."""
+    (top_k, held, outputs), (slots, decode_rows), prefills = HELD_SHARES[config]
+    cap = lambda tokens: moe.held_rows_cap(tokens, top_k, held, outputs)  # noqa: E731
+    ridge = moe.STREAM_MAX_MEAN_ROWS * held
+    assert cap(slots) == decode_rows < ridge
+    for tokens, before in prefills.items():
+        rows, even = cap(tokens), tokens * top_k * held / outputs
+        if before is not None:
+            assert rows == before, tokens
+            # the line itself (four shares are exactly the ridge) is
+            # where the two halves meet
+            assert rows < ridge or rows == ridge >= 1.5 * even, tokens
+            continue
+        assert rows == OVER_THE_RIDGE[config].get(tokens, rows), tokens
+        assert rows % 512 == 0 and ridge <= rows, tokens
+        assert even <= rows <= max(ridge, -(-tokens * top_k // 512) * 512), tokens
+        # what the pass was: the power of two over four even shares
+        assert rows < 4 * even or rows == ridge, tokens
+    sizes = sorted(prefills)
+    assert [cap(t) for t in sizes] == sorted(cap(t) for t in sizes)
+
+
+@pytest.mark.parametrize("passes", [1, 2, 3])
+def test_a_tight_pass_over_the_ridge_drops_nothing(passes):
+    """4 of 16 experts held, 1,024 tokens choosing 4: four even shares
+    are 4,096 rows (1,024 an expert: over the ridge), so a pass holds
+    1,536.  Even routing fills one pass; piled onto this share it takes
+    two and three, and the sum is ``expert_ffn``'s over the held experts
+    either way.  The engine's three counters are this arithmetic
+    (``held_pass_account``)."""
+    tokens, top_k, held, of, offset = 1024, 4, 4, 16, 4
+    cap = moe.held_rows_cap(tokens, top_k, held, of)
+    assert cap == 1536 >= moe.STREAM_MAX_MEAN_ROWS * held
+    ks = jax.random.split(jax.random.key(40 + passes), 4)
+    w_gate = jax.random.normal(ks[0], (of, D, F), jnp.float32) * D ** -0.5
+    w_up = jax.random.normal(ks[1], (of, D, F), jnp.float32) * D ** -0.5
+    w_down = jax.random.normal(ks[2], (of, F, D), jnp.float32) * F ** -0.5
+    h = jax.random.normal(ks[3], (tokens, D), jnp.float32)
+    rng = np.random.default_rng(passes)
+    # how many of a token's four picks fall on the held experts 4..7
+    local_picks = {1: rng.choice([0, 1, 2], tokens, p=[0.3, 0.4, 0.3]),
+                   2: rng.choice([2, 3], tokens),
+                   3: np.full(tokens, 4)}[passes]
+    inside, outside = np.arange(offset, offset + held), np.r_[0:offset, offset + held:of]
+    experts = np.stack([
+        np.r_[rng.choice(inside, n, replace=False),
+              rng.choice(outside, top_k - n, replace=False)][rng.permutation(top_k)]
+        for n in local_picks]).astype(np.int32)
+    n_local = int(local_picks.sum())
+    assert -(-n_local // cap) == passes
+    experts = jnp.asarray(experts)
+    gates = jnp.asarray(rng.uniform(0.05, 0.5, size=(tokens, top_k)), jnp.float32)
+    sl = slice(offset, offset + held)
+    got = jax.jit(lambda *a: moe.expert_ffn_held(*a, offset, of))(
+        h, w_gate[sl], w_up[sl], w_down[sl], gates, experts)
+    local = (experts >= offset) & (experts < offset + held)
+    want = jax.jit(moe.expert_ffn)(
+        h, w_gate, w_up, w_down, jnp.where(local, gates, 0.0), experts)
+    assert np.abs(np.asarray(got - want)).max() < 1e-5
+    assert np.abs(np.asarray(want)).max() > 0.1
+    # two routed layers of one prefill call: this one and one nobody chose
+    hist = np.asarray(moe.expert_histogram(experts, of))
+    assert moe.held_pass_account([hist[sl].sum(), 0], cap) == (
+        passes * cap, n_local, passes - 1)
+
+
 # ---------------------------------------------------------------------------
 # the streaming kernel by itself, and the rule that chooses it
 # ---------------------------------------------------------------------------
@@ -279,9 +376,12 @@ def test_the_rule_is_a_function_of_shape_type_and_backend():
     assert rule(moe.held_rows_cap(128, 8, 8, 256), 8, 7168, 2048, bf16, "tpu") == "stream"
     # ... and its prefill passes: a 1,024-token prompt's 128 rows an
     # expert stream, 256 and more (the ridge) stay on ragged_dot
-    for tokens, runs_ in ((1024, "stream"), (2048, "ragged_dot"), (8192, "ragged_dot")):
+    # (at the ridge four even shares, which are the tokens; past it one
+    # and a half: 8,192 tokens' 2,048 local assignments in 3,072 rows)
+    for tokens, rows_, runs_ in ((1024, 1024, "stream"), (2048, 2048, "ragged_dot"),
+                                 (8192, 3072, "ragged_dot")):
         rows = moe.held_rows_cap(tokens, 8, 8, 256)
-        assert rows == tokens and rule(rows, 8, 7168, 2048, bf16, "tpu") == runs_
+        assert rows == rows_ and rule(rows, 8, 7168, 2048, bf16, "tpu") == runs_
     # OLMoE's prefill groups: 32 to 128 rows an expert stream, the
     # largest (512 x 4 prompts: 256 rows an expert) does not
     for rows in (2048, 4096, 8192):

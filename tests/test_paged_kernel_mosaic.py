@@ -242,8 +242,8 @@ def test_held_expert_layer_compiles_with_rows_of_a_pass(one_chip, mosaic, backen
     not the ``rows x 8`` assignments, so the temporaries stay a few
     hundred MB; on a TPU the decode pass streams its experts through
     the Pallas kernel inside the pass loop, the 1,024-row pass too (in
-    two segments of 512), and the largest (1,024 rows an expert) stays
-    on ``ragged_dot``."""
+    two segments of 512), and the largest — over the ridge: one and a
+    half even shares, 384 rows an expert — stays on ``ragged_dot``."""
     from seldon_core_tpu.ops import moe
 
     d, f, e, held, k = 7168, 2048, 256, 8, 8
@@ -262,11 +262,13 @@ def test_held_expert_layer_compiles_with_rows_of_a_pass(one_chip, mosaic, backen
         spec((e,), jnp.float32), spec((held, d, f), jnp.bfloat16),
         spec((held, d, f), jnp.bfloat16), spec((held, f, d), jnp.bfloat16)).compile()
     text = compiled.as_text()
-    cap = moe.held_rows_cap(rows, k, held, e)   # four times an even 1/32 share
+    # four times an even 1/32 share under the ridge, 1.5 times over it
+    cap = moe.held_rows_cap(rows, k, held, e)
     assert_expert_kernels(text, backend == "tpu" and rows < 8192,
                           min(cap, moe.stream_segment_rows(d)), d, f)
     # a pass's rows at an expert's width (cut into segments, at d_model)
-    assert cap == rows * k // 8 and (f"[{cap},{f}]" in text or f"[{cap},{d}]" in text)
+    assert cap == {128: 128, 1024: 1024, 8192: 3072}[rows]
+    assert f"[{cap},{f}]" in text or f"[{cap},{d}]" in text
     assert f"[{rows * k},{d}]" not in text  # never all the assignments' rows
     assert compiled.memory_analysis().temp_size_in_bytes < 12 * rows * d * 4
 
@@ -313,7 +315,8 @@ def test_shortcut_experts_compile_with_rows_of_a_pass(one_chip, mosaic, backend,
     through LongCat-Flash's router and its held experts at the published
     widths, 16 of 512 real experts held beside 256 identity experts: a
     pass holds four times an even share of the ``rows x 12`` picks over
-    all 768 outputs, which is ``rows`` itself; on a TPU every pass under
+    all 768 outputs, which is ``rows`` itself (at 4,096 the ridge's own
+    rows, where the rule's two halves meet); on a TPU every pass under
     256 rows an expert streams through the Pallas kernel (2,048 rows in
     segments of 640), and 4,096 rows over 16 experts stay on
     ``ragged_dot``."""

@@ -281,3 +281,8 @@ class TestPages:
         # ceil((8 - 1 + 1) / 4) + 1 columns a lane, every slot's backed
         assert report["window_table_pages"] == 3
         assert kinds["window"]["pages"] == SLOTS * 3 + 1
+        # a softmax router over a share runs the held pass: the rule was
+        # asked about a pass's rows, not every assignment's (PR 42)
+        rows = report["held_pass_rows"]
+        assert sorted(rows) == sorted(report["expert_matmul"])
+        assert rows["chunk"] == eng.engine_stats()["moe_held_pass_rows"] == 64

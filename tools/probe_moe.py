@@ -27,12 +27,23 @@ a quarter of the rows are real, the rest lie past the groups, and the
 held experts are chosen as unevenly as the cell's (~6 of 8 hit by 128
 tokens).  Off the chip ``--rehearse`` runs a toy size through the
 Pallas interpreter for control flow only and prints no rate.
+
+``--held`` times the WHOLE held pass instead (``moe.expert_ffn_held``:
+sort, row gather, the grouped SwiGLU, mask, pad, the gathers back to
+the tokens), a line per (``--tokens``, rows a pass): a replica's share
+at ``--widths smallthinker|gigachat|dots3`` routed near-evenly from
+``--seed``, the pass sized by :func:`moe.held_rows_cap`, at the power of
+two over ``HELD_ROWS_HEADROOM`` even shares (the rule under the ridge) and
+at each ``--cap-over-even`` times the even share in whole 512s (under 1 the
+local assignments need a second pass: what that costs).  It is the
+table ``HELD_ROWS_RIDGE_HEADROOM`` was chosen from.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import sys
 import time
@@ -43,7 +54,8 @@ HBM_BYTES_PER_S = 819e9   # TPU v5e, Google Cloud documentation
 BF16_FLOPS = 197e12
 
 # (groups held, experts routed over, top-k, d_model, expert width)
-WIDTHS = {"olmoe": (64, 64, 8, 2048, 1024), "gigachat": (8, 256, 8, 7168, 2048)}
+WIDTHS = {"olmoe": (64, 64, 8, 2048, 1024), "gigachat": (8, 256, 8, 7168, 2048),
+          "dots3": (8, 256, 8, 7168, 1536), "smallthinker": (16, 64, 6, 2560, 768)}
 # how unevenly a replica's 8 held experts are chosen (PERF.md section 6,
 # PR 30: ~6 of 8 hit a decode step, max over mean 2.7-2.9)
 HELD_PROFILE = (2.7, 1.8, 1.3, 1.0, 0.7, 0.4, 0.08, 0.02)
@@ -71,6 +83,69 @@ def draw_sizes(widths: str, rows: int, seed: int, groups: int, top_k: int):
     return sizes.astype(np.int32)
 
 
+def held_sweep(args, dev, on_chip: bool) -> int:
+    """``--held``: one line per (tokens, rows a pass), see the module
+    docstring."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from seldon_core_tpu.ops import moe
+
+    held, outputs, top_k, d, f = WIDTHS[args.widths]
+    tokens_list, reps = args.tokens, args.reps
+    if not on_chip:
+        d, f, tokens_list, reps = 128, 256, [256], 2
+    dt = jnp.bfloat16
+    ks = jax.random.split(jax.random.key(args.seed % (1 << 31)), 4)
+    weights = tuple(
+        (jax.random.normal(k, shape, jnp.float32) * shape[1] ** -0.5).astype(dt)
+        for k, shape in zip(ks, ((held, d, f), (held, d, f), (held, f, d))))
+    rule = moe.held_rows_cap
+    rng = np.random.default_rng(args.seed)
+    for tokens in tokens_list:
+        logits = rng.normal(0.0, 0.15, outputs) + rng.gumbel(size=(tokens, outputs))
+        experts_np = np.argsort(-logits, axis=-1)[:, :top_k].astype(np.int32)
+        n_local = int((experts_np < held).sum())
+        even = tokens * top_k * held / outputs
+        experts = jnp.asarray(experts_np)
+        gates = jnp.full((tokens, top_k), 1.0 / top_k, jnp.float32)
+        h = jax.random.normal(jax.random.fold_in(ks[3], tokens), (tokens, d),
+                              jnp.float32).astype(dt)
+        # the rule under the ridge, whatever the size
+        pow2 = max(moe.HELD_ROWS_MIN,
+                   1 << math.ceil(math.log2(moe.HELD_ROWS_HEADROOM * even)))
+        caps = {"rule": rule(tokens, top_k, held, outputs), "4x_pow2": pow2}
+        for over in args.cap_over_even:
+            caps[f"{over}x"] = max(512, math.ceil(over * even / 512) * 512)
+        for label, cap in caps.items():
+            moe.held_rows_cap = lambda *_a, cap=cap: cap   # read as the pass is traced
+
+            @jax.jit
+            def many(h, wg, wu, wd, gates, experts):
+                def step(h, _):
+                    out = moe.expert_ffn_held(h, wg, wu, wd, gates, experts, 0, outputs)
+                    return (h + 1e-3 * out.astype(h.dtype)).astype(h.dtype), ()
+                return jax.lax.scan(step, h, None, length=reps)[0]
+
+            line = {"widths": args.widths, "tokens": tokens, "cap": cap, "sized": label,
+                    "even": even, "local": n_local, "passes": -(-n_local // cap),
+                    "impl": moe.expert_matmul_impl(cap, held, d, f, dt, moe.matmul_backend()),
+                    "device": dev.device_kind}
+            try:
+                jax.block_until_ready(many(h, *weights, gates, experts))
+                t0 = time.perf_counter()
+                jax.block_until_ready(many(h, *weights, gates, experts))
+                if on_chip:
+                    line["ms_a_layer"] = 1e3 * (time.perf_counter() - t0) / reps
+            except Exception as exc:  # noqa: BLE001 — a size may not fit
+                line["error"] = f"{type(exc).__name__}: {str(exc)[:300]}"
+            finally:
+                moe.held_rows_cap = rule
+            print(json.dumps(line), flush=True)
+    return 0
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--rehearse", action="store_true")
@@ -82,6 +157,11 @@ def main() -> int:
                     default=[128, 256, 512, 1024, 2048, 4096, 8192])
     ap.add_argument("--row-tile", type=int, nargs="*", default=None)
     ap.add_argument("--block-mb", type=float, nargs="*", default=None)
+    ap.add_argument("--held", action="store_true")
+    ap.add_argument("--tokens", type=int, nargs="*",
+                    default=[2048, 3072, 4096, 6144, 8192])
+    ap.add_argument("--cap-over-even", type=float, nargs="*",
+                    default=[2.0, 1.5, 1.25, 0.75])
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--reps", type=int, default=16)
     args = ap.parse_args()
@@ -97,6 +177,10 @@ def main() -> int:
     if not on_chip and not args.rehearse:
         print(json.dumps({"error": f"no TPU here ({dev.platform}); --rehearse for a toy run"}))
         return 1
+    if args.held:
+        if not on_chip:
+            moe.matmul_backend = lambda: "interpret"
+        return held_sweep(args, dev, on_chip)
     groups, _routed, top_k, d, f = WIDTHS[args.widths]
     rows_list = args.rows
     if not on_chip:
