@@ -589,7 +589,9 @@ def _build_modules():
         says whether the call starts at position zero.  A **full** kind
         with an indexer (``kind.topk``) reads ``pool`` = ``(rows,
         indexer keys)``: a segment from zero attends each row's best
-        ``topk`` positions (``ops/mla.py indexed_attention``); a decode
+        ``topk`` positions (``ops/mla.py indexed_attention``: in the
+        fused causal kernel under the chosen set's mask where
+        ``prefill_attention_impl`` says so at this kind's widths); a decode
         step whose bucket holds a lane with ``topk`` cached positions or
         more scores the cached keys, keeps the best ``topk`` of them and
         the step's own as a mask (``kth_mask``, the prefill's rule) and
@@ -734,9 +736,12 @@ def _build_modules():
                     raise ValueError(
                         "an indexed layer prefills from position zero: a "
                         "segment over cached rows is not built")
+                fused = kernels.prefill_attention_impl(
+                    seg_len, nope + rdim, vdim, mod.dtype, 0, whole) == "fused"
                 outs.append(mla.indexed_attention(
                     q_nope[sl], q_rope[sl], row[sl], w_uk, w_uv, scale,
-                    mod.dtype, q_i[sl], w_i[sl], key_row[sl], i_scale, topk))
+                    mod.dtype, q_i[sl], w_i[sl], key_row[sl], i_scale, topk,
+                    fused=fused))
                 continue
             if seg_len > 1:
                 fused = kernels.prefill_attention_impl(
@@ -3139,8 +3144,8 @@ class PagedEngine:
         # block and every cached-suffix program are XLA's
         from seldon_core_tpu.ops.kernels import prefill_attention_impl
 
-        # (a spec with layer kinds: its window layers'; an indexed layer
-        # selects a block of queries at a time in XLA)
+        # (a spec with layer kinds: its window layers' — the indexed
+        # layers', at the full kind's widths, stand beside them below)
         # (a grouped-query block: its heads' width, q, k and v alike)
         qk_v = ((spec.head_dim, spec.head_dim) if spec.kv_heads
                 else (spec.win_nope_dim + spec.win_rope_dim, spec.win_v_dim)
@@ -3150,6 +3155,16 @@ class PagedEngine:
                 bucket, *qk_v, dtype, 0, kernel_eligible)
             if spec.latent or spec.kv_heads else "xla"
             for bucket in self.prompt_buckets}
+        # ... and what an indexed layer's does under its selection (the
+        # same kernel under the chosen set's mask, or a block of queries
+        # at a time in XLA: ops/mla.py indexed_attention); {} for a spec
+        # without an indexer
+        self._prefill_indexed_attention = {
+            bucket: prefill_attention_impl(
+                bucket, spec.nope_dim + spec.rope_dim, spec.v_dim, dtype, 0,
+                kernel_eligible)
+            for bucket in self.prompt_buckets} if (
+                spec.latent and spec.kinds and spec.index_topk) else {}
         # r18 int8 KV pool: pages rest int8 with ONE f32 scale per page
         # per k/v in a sibling (layers, num_pages) table — half the
         # pool bytes (≈2x paged_capacity_streams), dequantised
@@ -3517,6 +3532,11 @@ class PagedEngine:
                           # prefill of a bucket ``_prefill_attention``
                           # gives "fused"; ops/kernels.py causal_attention)
                           "prefill_fused_positions": 0,
+                          # ... and the ones whose INDEXED layers
+                          # attended in it under the selection's mask
+                          # (``_prefill_indexed_attention``; 0 for a
+                          # spec without an indexer)
+                          "prefill_indexed_fused_positions": 0,
                           # decode work where it is done: cached tokens
                           # each lane's decode steps attended (the
                           # lane's length at each step it ran) and
@@ -6532,16 +6552,21 @@ class PagedEngine:
         if self.spec.experts_held:
             routed["held_rows"] = self._held_pass_rows(k * bucket)
         fused = not use_cache and self._prefill_attention[bucket] == "fused"
+        indexed_fused = (not use_cache and self._prefill_indexed_attention.get(
+            bucket) == "fused")
         self._seam.begin_prefill(
             bucket=bucket, k=k, rows=len(group),
             tokens=tokens, padded=k * bucket,
-            cached=int(use_cache), fused=int(fused), **routed,
+            cached=int(use_cache), fused=int(fused),
+            indexed_fused=int(indexed_fused), **routed,
         )
         try:
             with self._lock:
                 self._counters["prefill_padded_tokens"] += k * bucket
                 if fused:
                     self._counters["prefill_fused_positions"] += k * bucket
+                if indexed_fused:
+                    self._counters["prefill_indexed_fused_positions"] += k * bucket
             return self._prefill_group_call(bucket, k, group, use_cache)
         finally:
             self._seam.end_prefill()
@@ -7815,10 +7840,15 @@ class PagedEngine:
                if self.spec.experts_held else {}),
             # what each bucket's from-zero prefill attends with:
             # "fused" (ops/kernels.py causal_attention) or "xla"; every
-            # cached-suffix prefill is XLA's
+            # cached-suffix prefill is XLA's.  A spec with an indexer
+            # says its indexed layers' beside its window layers', as
+            # "b<bucket>_indexed" (the kernel under the chosen set's
+            # mask, or XLA a block of queries at a time)
             "prefill_attention": {
-                f"b{bucket}": impl
-                for bucket, impl in self._prefill_attention.items()},
+                **{f"b{bucket}": impl
+                   for bucket, impl in self._prefill_attention.items()},
+                **{f"b{bucket}_indexed": impl for bucket, impl
+                   in self._prefill_indexed_attention.items()}},
         }
 
     def _routed_program_tokens(self) -> Dict[str, int]:

@@ -401,10 +401,13 @@ def prefill_attention_impl(seg_len: int, d_qk: int, d_v: int, dtype,
     mesh, which GSPMD cannot partition the custom call over, and
     ``SELDON_TPU_PAGED_KERNEL`` not "0"), the operands are bfloat16, the
     segment is at least one query block long and a head's K and V fit
-    the kernel's VMEM; XLA's ``naive_attention`` everywhere else.  The
-    multi-head block does not ask: at its cells' shapes the v5e sweep
-    reads XLA over the segment alone as fast (``models/paged.py
-    _segment_attention`` has the numbers)."""
+    the kernel's VMEM; XLA's ``naive_attention`` everywhere else.  A
+    layer with an indexer asks at its own widths and takes the answer
+    for the attention under its selection (``ops/mla.py
+    indexed_attention``: the kernel under the chosen set's mask, or XLA
+    a block of queries at a time).  The multi-head block does not ask:
+    at its cells' shapes the v5e sweep reads XLA over the segment alone
+    as fast (``models/paged.py _segment_attention`` has the numbers)."""
     import jax.numpy as jnp
 
     if table_width or not kernel_lane or jnp.dtype(dtype) != jnp.bfloat16:
@@ -422,8 +425,8 @@ def _lanes(x, width: int):
     return jnp.tile(x, (1, -(-width // 128)))[:, :width]
 
 
-def _causal_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                   block_q: int, block_k: int, scale: float, window: int = 0):
+def _causal_kernel(q_ref, k_ref, v_ref, *refs, block_q: int, block_k: int,
+                   scale: float, window: int = 0, chosen_lanes: int = 0):
     """Grid cell (batch*head, q-block).  The head's K and V rest WHOLE
     in VMEM: their block index is the head alone, so the pipeline
     fetches them once a head — each key from HBM exactly once, none
@@ -451,11 +454,26 @@ def _causal_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     scored — and every block it takes is masked on both edges (there are
     ``(block_q + window) / block_k`` of them, not a prompt's worth).  A
     row may see nothing in a block before its own, so the running max can
-    be -inf there and the rescale guards it."""
+    be -inf there and the rescale guards it.
+
+    ``chosen_lanes`` (0: no mask; else a fourth operand stands before the
+    output, :func:`_pack_chosen`'s words): row ``i`` attends the keys its
+    bits name — a subset of ``0..i``, so it is the only mask such a call
+    builds, the diagonal's blocks included.  The loop keeps its bound:
+    every key block up to the diagonal is weighed under the words' bits,
+    ``block_k / chosen_lanes`` columns of 128 lanes at a time (one ``and``
+    and one compare a score), none above it is touched.  A row's chosen
+    set need not hold a key of the first blocks, so the rescale is
+    guarded as a window's; a row of the entry's padding has none at all
+    and divides by 1."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    if chosen_lanes:
+        chosen_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        o_ref, m_ref, l_ref, acc_ref = refs
     qi = pl.program_id(1)
     q = q_ref[0]                                   # (block_q, d_qk)
     sub = block_q // block_k
@@ -471,7 +489,19 @@ def _causal_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        if masked:
+        if chosen_lanes:
+            # key s: bit (s % (32 * lanes)) // lanes of word s % lanes in
+            # tile s // (32 * lanes) — a block's keys are whole columns
+            # of one tile's lanes, each under one bit
+            at = start % (32 * chosen_lanes)
+            words = chosen_ref[0, start // (32 * chosen_lanes)]
+            cols = [
+                jnp.where(
+                    (words & jnp.left_shift(1, at // chosen_lanes + c)) != 0,
+                    s[:, c * chosen_lanes:(c + 1) * chosen_lanes], -jnp.inf)
+                for c in range(block_k // chosen_lanes)]
+            s = cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=-1)
+        elif masked:
             q_at = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 0)
             k_at = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -481,7 +511,8 @@ def _causal_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             s = jnp.where(seen, s, -jnp.inf)
         m = m_ref[...]                             # (block_q, 128)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1)[:, None])
-        if window:  # a row that has seen no key yet: exp(-inf - -inf)
+        if window or chosen_lanes:
+            # a row that has seen no key yet: exp(-inf - -inf)
             m_at = jnp.where(m_new == -jnp.inf, 0.0, m_new)
             alpha = jnp.exp(m - m_at)
             p = jnp.exp(s - _lanes(m_at, block_k))
@@ -501,14 +532,40 @@ def _causal_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     lo = (jnp.maximum(qi * block_q - (window - 1), 0) // block_k
           if window else 0)
+    if chosen_lanes:
+        jax.lax.fori_loop(0, first + sub, under, 0)
+        total = l_ref[...]
+        o_ref[0] = (acc_ref[...] / _lanes(
+            jnp.where(total == 0.0, 1.0, total), d_v)).astype(o_ref.dtype)
+        return
     jax.lax.fori_loop(lo, first, under, 0)
     for i in range(sub):
         step(first + i, masked=True)
     o_ref[0] = (acc_ref[...] / _lanes(l_ref[...], d_v)).astype(o_ref.dtype)
 
 
+def _pack_chosen(chosen, lanes: int):
+    """A ``(B, L, L)`` mask (row ``t``, key ``s``) as the words the
+    masked causal kernel reads: ``(B, tiles, L, lanes)`` int32, key
+    ``s`` bit ``(s % (32 * lanes)) // lanes`` of word ``s % lanes`` in
+    tile ``s // (32 * lanes)`` — a key block of ``lanes`` keys is one
+    bit of a tile's every lane, so the kernel unpacks with an ``and``
+    and no relayout, and a row's 4,096 keys are 512 bytes (one fetch a
+    query block: an eighth of the mask as int8)."""
+    import jax
+    import jax.numpy as jnp
+
+    b, rows, keys = chosen.shape
+    tiles = -(-keys // (32 * lanes))
+    bits = jnp.pad(chosen != 0, [(0, 0), (0, 0), (0, tiles * 32 * lanes - keys)])
+    bits = bits.reshape(b, rows, tiles, 32, lanes).astype(jnp.uint32)
+    words = (bits << jnp.arange(32, dtype=jnp.uint32)[:, None]).sum(
+        axis=3, dtype=jnp.uint32)
+    return jnp.swapaxes(jax.lax.bitcast_convert_type(words, jnp.int32), 1, 2)
+
+
 def causal_attention(q, k, v, scale: float, *, block_q: int = None,
-                     block_k: int = None, window: int = 0):
+                     block_k: int = None, window: int = 0, chosen=None):
     """Causal softmax attention of a segment over itself in ONE kernel:
     ``q`` ``(B, L, h, d_qk)``, ``k`` ``(B, L, h, d_qk)``, ``v`` ``(B, L,
     h, d_v)`` -> ``(B, L, h, d_v)`` in q's type.  Row ``i`` attends keys
@@ -524,6 +581,20 @@ def causal_attention(q, k, v, scale: float, *, block_q: int = None,
     kv_heads)`` through the block index — nothing is repeated to ``h``
     heads, and the pipeline fetches a K/V head once for the query heads
     that follow one another on it.
+
+    ``chosen`` (None: every key up to the row's own): a ``(B, L, L)``
+    mask, bool or any integer, one for all heads of a batch row — row
+    ``t`` attends the keys ``s`` where ``chosen[b, t, s]``, which the
+    caller keeps within ``s <= t`` (a learned selection's chosen set:
+    ``ops/mla.py indexed_attention``).  The loop's bound is the causal
+    one — key blocks above a query block's diagonal are neither fetched
+    nor scored — and every block it visits is weighed under the mask,
+    which reaches the kernel packed 32 keys a word
+    (:func:`_pack_chosen`).  It is a fact of the call's structure:
+    without it the traced call is the one every other caller traces,
+    with it the call is ``prefill_chosen_attention`` with a fourth
+    operand.  A key block of more than 128 keys must hold whole columns
+    of 128 and divide 4,096.
 
     The kernel's call is a ``pallas_call`` whose output is ``(B * h, L,
     d_v)``: three dims, which is how the benchmark's readers tell it
@@ -561,6 +632,18 @@ def causal_attention(q, k, v, scale: float, *, block_q: int = None,
         q, k, v = (jnp.pad(x, [(0, 0), (0, pad), (0, 0), (0, 0)])
                    for x in (q, k, v))
     seg_p = seg + pad
+    masked = {}
+    if chosen is not None:
+        lanes = min(block_k, 128)
+        if window or chosen.shape != (b, seg, seg) or (32 * lanes) % block_k:
+            raise ValueError(
+                f"causal_attention: a chosen set {chosen.shape} under window "
+                f"{window} and key blocks of {block_k} (it stands alone, is "
+                f"{(b, seg, seg)}, and its key blocks divide {32 * lanes})")
+        # (pad rows choose nothing and no row chooses a pad key)
+        words = _pack_chosen(
+            jnp.pad(chosen, [(0, 0), (0, pad), (0, pad)]), lanes)
+        masked = {"chosen_lanes": lanes}
 
     # (B, L, h, d) -> (B*h, L, d): one head's rows contiguous
     def fold(x):
@@ -575,16 +658,25 @@ def causal_attention(q, k, v, scale: float, *, block_q: int = None,
     need = (_causal_kv_bytes(seg_p, d_qk, d_v, q.dtype)
             + 8 * block_q * max(block_k, 128) * 4
             + 4 * _padded_block_bytes((block_q, max(d_qk, d_v)), np.float32))
+    if masked:
+        need += 2 * _padded_block_bytes(
+            (words.shape[1], block_q, lanes), np.int32)
     out = pl.pallas_call(
         functools.partial(_causal_kernel, block_q=block_q, block_k=block_k,
                           scale=float(scale), **(
-                              {"window": int(window)} if window else {})),
+                              {"window": int(window)} if window else {}),
+                          **masked),
         grid=(b * h, seg_p // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d_qk), lambda i, qi: (i, qi, 0)),
             pl.BlockSpec((1, seg_p, d_qk), kv_index),
             pl.BlockSpec((1, seg_p, d_v), kv_index),
-        ],
+        ] + ([
+            # a batch row's words for the query block, every tile: the
+            # same for all of its heads
+            pl.BlockSpec((1, words.shape[1], block_q, lanes),
+                         lambda i, qi: (i // h, 0, qi, 0)),
+        ] if masked else []),
         out_specs=pl.BlockSpec((1, block_q, d_v), lambda i, qi: (i, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, seg_p, d_v), q.dtype),
         scratch_shapes=[
@@ -597,9 +689,10 @@ def causal_attention(q, k, v, scale: float, *, block_q: int = None,
             vmem_limit_bytes=int(max(need, _VMEM_LIMIT_BYTES)),
         ),
         name=("prefill_window_attention" if window
+              else "prefill_chosen_attention" if masked
               else "prefill_causal_attention"),
         interpret=interpret_mode(),
-    )(fold(q), fold(k), fold(v))
+    )(fold(q), fold(k), fold(v), *([words] if masked else []))
     out = out.reshape(b, h, seg_p, d_v).transpose(0, 2, 1, 3)
     return out[:, :seg] if pad else out
 

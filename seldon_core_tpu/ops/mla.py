@@ -15,9 +15,11 @@ latent_attention_decode``; it returns the same unnormalised flash state
 as :func:`ctx_state`, and :func:`merge` joins states by the flash rule.
 **Learned sparse attention** (:func:`index_scores`, :func:`kth_mask`,
 :func:`step_mask`, :func:`indexed_attention`) chooses by a mask in both
-forms: a prefill's block of queries masks its scores, a decode step
-hands the mask to the page loop (``latent_attention_decode(chosen=)``)
-or to :func:`ctx_state` as ``valid``.
+forms: a prefill's blocks of queries hand their chosen rows to the fused
+causal kernel (``causal_attention(chosen=)``) or mask their own scores
+in XLA, a decode step hands the mask to the page loop
+(``latent_attention_decode(chosen=)``) or to :func:`ctx_state` as
+``valid``.
 """
 
 from __future__ import annotations
@@ -62,6 +64,19 @@ def merge(*states):
     return acc_all / l_all[..., None]
 
 
+def _kernel_operands(q_nope, q_rope, k_nope, k_rope):
+    """``(q, k)`` as the fused causal kernel takes them, a head each:
+    ``[nope ; rope]``, the one rotary key every head shares (``(B, L,
+    r)``) repeated beside each head's ``k_nope``."""
+    import jax.numpy as jnp
+
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(
+            k_rope[:, :, None, :], k_nope.shape[:3] + k_rope.shape[-1:])],
+        axis=-1)
+    return jnp.concatenate([q_nope, q_rope], axis=-1), k
+
+
 def naive_attention(q_nope, q_rope, ctx, ctx_len, seg, w_uk, w_uv,
                     scale: float, dtype, fused: bool = False,
                     window: int = 0):
@@ -96,11 +111,7 @@ def naive_attention(q_nope, q_rope, ctx, ctx_len, seg, w_uk, w_uv,
     if ctx is None and fused:
         from seldon_core_tpu.ops.kernels import causal_attention
 
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(
-                k_rope[:, :, None, :], k_nope.shape[:3] + k_rope.shape[-1:])],
-            axis=-1)
-        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        q, k = _kernel_operands(q_nope, q_rope, k_nope, k_rope)
         return causal_attention(q, k, v, scale, window=window).astype(dtype)
     if window and ctx is not None:
         raise ValueError("naive_attention: a window over a cached prefix "
@@ -144,9 +155,10 @@ def naive_attention(q_nope, q_rope, ctx, ctx_len, seg, w_uk, w_uv,
 # a row attends over the best ``topk`` (DeepSeek sparse attention's form)
 # ---------------------------------------------------------------------------
 
-# queries :func:`indexed_attention` scores at once: the attention's
-# (B, heads, 128, keys) float32 beside the indexer's (B, index heads,
-# 128, keys) is 0.4 GB at 128 + 64 heads and 4,096 keys
+# queries :func:`indexed_attention` selects at once: the indexer's (B,
+# index heads, 128, keys) float32 is 0.13 GB at 64 heads and 4,096 keys
+# (and, on the XLA lane, the attention's (B, heads, 128, keys) beside
+# it 0.27 GB more at 128 heads)
 INDEX_QUERY_BLOCK = 128
 
 
@@ -215,15 +227,28 @@ def step_mask(scores, own_score, lengths, topk: int):
 
 
 def indexed_attention(q_nope, q_rope, seg, w_uk, w_uv, scale: float, dtype,
-                      q_idx, w_idx, k_idx, index_scale: float, topk: int):
+                      q_idx, w_idx, k_idx, index_scale: float, topk: int,
+                      fused: bool = False):
     """:func:`naive_attention` of a segment from position zero whose row
     ``t`` attends over the ``topk`` positions ``s <= t`` the indexer
     scores highest (:func:`index_scores`, :func:`kth_mask`; every one
     while ``t < topk``).  ``q_idx`` ``(B, L, j, d)``, ``w_idx`` ``(B, L,
     j)``, ``k_idx`` ``(B, L, d)`` beside ``naive_attention``'s operands.
-    :data:`INDEX_QUERY_BLOCK` queries at a time: neither the indexer's
-    ``(j, L, L)`` nor the attention's ``(h, L, L)`` scores exist whole
-    (4.3 and 8.6 GB at 4,096 positions).  ``(B, L, h, v)`` in ``dtype``."""
+    The selection runs :data:`INDEX_QUERY_BLOCK` queries at a time on
+    either lane, so the indexer's ``(j, L, L)`` scores never exist whole
+    (4.3 GB at 4,096 positions).  The attention under it: where the
+    caller's rule says ``fused`` (``ops/kernels.py
+    prefill_attention_impl`` at this layer's widths) the blocks hand back
+    their chosen rows alone — a ``(B, L, L)`` mask — and the fused causal
+    kernel weighs ``k = [k_nope ; k_rope]`` and ``v``, made a head as
+    ``naive_attention(fused=True)`` makes them, under it
+    (``causal_attention(chosen=)``: no score reaches HBM; while ``L <=
+    topk`` no mask is built and the call is the plain causal one);
+    elsewhere each block also scores, masks and weighs its 128 queries
+    against every key in XLA (float32 operands, the CPU, a mesh,
+    ``SELDON_TPU_PAGED_KERNEL=0``, a bucket under one query block), so
+    the attention's ``(h, L, L)`` scores do not exist whole either (8.6
+    GB).  ``(B, L, h, v)`` in ``dtype``."""
     import jax
     import jax.numpy as jnp
 
@@ -237,13 +262,19 @@ def indexed_attention(q_nope, q_rope, seg, w_uk, w_uv, scale: float, dtype,
                    preferred_element_type=jnp.float32).astype(dtype)
     key_at = jnp.arange(seg_len)
 
-    def block(args):
-        qn, qr, qi, wi, first = args
-        q_at = first + jnp.arange(qn.shape[1])
+    def chosen(qi, wi, first):
+        """The rows' chosen sets ``(B, bq, L)``: the one selection rule
+        under the causal triangle."""
+        q_at = first + jnp.arange(qi.shape[1])
         seen = jnp.broadcast_to(
-            key_at[None, :] <= q_at[:, None], (batch, qn.shape[1], seg_len))
+            key_at[None, :] <= q_at[:, None], (batch, qi.shape[1], seg_len))
         if seg_len > topk:
             seen = kth_mask(index_scores(qi, wi, k_idx, index_scale), seen, topk)
+        return seen
+
+    def block(args):
+        qn, qr, qi, wi, first = args
+        seen = chosen(qi, wi, first)
         s = (jnp.einsum("bqhn,bchn->bhqc", qn, k_nope,
                         preferred_element_type=jnp.float32)
              + jnp.einsum("bqhr,bcr->bhqc", qr, k_rope,
@@ -254,14 +285,28 @@ def indexed_attention(q_nope, q_rope, seg, w_uk, w_uv, scale: float, dtype,
                           preferred_element_type=jnp.float32).astype(dtype)
 
     bq = INDEX_QUERY_BLOCK
-    if seg_len <= bq or seg_len % bq:
-        return block((q_nope, q_rope, q_idx, w_idx, 0))
-    blocks = seg_len // bq
+    blocks = 0 if seg_len <= bq or seg_len % bq else seg_len // bq
 
     def cut(x):  # (B, L, ...) -> (blocks, B, bq, ...)
         return jnp.moveaxis(
             x.reshape(batch, blocks, bq, *x.shape[2:]), 1, 0)
 
-    out = jax.lax.map(block, (cut(q_nope), cut(q_rope), cut(q_idx),
-                              cut(w_idx), jnp.arange(blocks) * bq))
-    return jnp.moveaxis(out, 0, 1).reshape(batch, seg_len, *out.shape[3:])
+    def whole(out):  # (blocks, B, bq, ...) -> (B, L, ...)
+        return jnp.moveaxis(out, 0, 1).reshape(batch, seg_len, *out.shape[3:])
+
+    firsts = jnp.arange(blocks) * bq
+    if fused:
+        from seldon_core_tpu.ops.kernels import causal_attention
+
+        masked = {}
+        if seg_len > topk and blocks:
+            masked["chosen"] = whole(jax.lax.map(
+                lambda args: chosen(*args), (cut(q_idx), cut(w_idx), firsts)))
+        elif seg_len > topk:
+            masked["chosen"] = chosen(q_idx, w_idx, 0)
+        q, k = _kernel_operands(q_nope, q_rope, k_nope, k_rope)
+        return causal_attention(q, k, v, scale, **masked).astype(dtype)
+    if not blocks:
+        return block((q_nope, q_rope, q_idx, w_idx, 0))
+    return whole(jax.lax.map(
+        block, (cut(q_nope), cut(q_rope), cut(q_idx), cut(w_idx), firsts)))

@@ -301,6 +301,11 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
                                 "of those positions, the ones of from-zero "
                                 "prefill calls whose attention ran in the "
                                 "fused causal kernel"),
+    "prefill_indexed_fused_positions": (
+        "counter", "seldon_tpu_engine_prefill_indexed_fused_positions_total",
+        "of those positions, the ones of from-zero prefill calls whose "
+        "indexed layers attended in the fused causal kernel under the "
+        "selection's mask (0 for a model without an indexer)"),
     "decode_kv_tokens": ("counter",
                          "seldon_tpu_engine_decode_kv_tokens_total",
                          "cached tokens attended, summed over every "

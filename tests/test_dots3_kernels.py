@@ -12,7 +12,12 @@ on the CPU (arithmetic only: nothing about Mosaic or speed), each against
   same kernel under the mask as ``chosen``) against attention over every
   row under the reference's mask (PR 40: the page loop streams the
   lane's rows and the masked ones weigh exactly 0), and a call without
-  a mask still traces the kernel it traced before there was one.
+  a mask still traces the kernel it traced before there was one;
+* ``causal_attention(chosen=)`` (PR 43: a prefill's rows attend their
+  chosen sets in the fused causal kernel) against a float32 softmax
+  under the reference's mask at the full layers' head widths, and
+  ``causal_attention`` without a mask — plain, windowed, grouped —
+  tracing what it traced before there was one.
 """
 
 import os
@@ -324,3 +329,210 @@ def test_a_call_without_a_mask_traces_the_kernel_it_traced(name):
     assert _traced(shape) == digest
     # ... and the mask is a structure of its own
     assert _traced(shape, chosen=True) != digest
+
+
+# ---- a prefill's rows under their chosen sets (PR 43) -------------------
+
+D_QK, D_V = 192, 128       # a full layer's head: 128 nope + 64 rope | values
+
+# name -> (prompts, length, topk, query block, key block): what the
+# selection looks like is made in ``_chosen_case``
+CHOSEN_CASES = {
+    "a_real_selection_over_more_than_topk": (1, 256, 96, 64, 64),
+    "no_chosen_key_in_the_first_visited_block": (1, 256, 48, 64, 64),
+    "ties_at_the_cut": (1, 192, 40, 64, 32),
+    "a_length_that_is_no_multiple_of_the_query_block": (1, 200, 64, 64, 64),
+    "two_prompts_two_masks": (2, 128, 32, 64, 64),
+    "a_key_block_of_two_columns_of_lanes": (1, 512, 160, 256, 256),
+}
+
+
+def _chosen_case(name):
+    """``(q, k, v, scores, mask, (bq, bk), topk)`` of a case: float32
+    operands, the indexer's scores and ``kth_mask``'s chosen sets."""
+    b, seg, topk, bq, bk = CHOSEN_CASES[name]
+    rng = np.random.default_rng(len(name))
+    q, k = (rng.normal(size=(b, seg, 2, D_QK)).astype(np.float32) for _ in range(2))
+    v = rng.normal(size=(b, seg, 2, D_V)).astype(np.float32)
+    scores = rng.normal(size=(b, seg, seg)).astype(np.float32)
+    if name.startswith("no_chosen_key"):
+        scores[:, :, :bk] -= 100.0   # the first block's keys: every row's last
+    if name.startswith("ties"):
+        scores = rng.integers(0, 3, size=(b, seg, seg)).astype(np.float32)
+    at = np.arange(seg)
+    causal = np.broadcast_to(at[None, :] <= at[:, None], (b, seg, seg))
+    mask = mla.kth_mask(jnp.asarray(scores), jnp.asarray(causal), topk)
+    return q, k, v, scores, np.asarray(mask), (bq, bk), topk
+
+
+def _masked_softmax(q, k, v, mask, scale):
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   precision=jax.lax.Precision.HIGHEST) * scale
+    p = jax.nn.softmax(jnp.where(mask[:, None], s, -jnp.inf), axis=-1)
+    return np.asarray(jnp.einsum("bhqk,bkhd->bqhd", p, v,
+                                 precision=jax.lax.Precision.HIGHEST))
+
+
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 3e-5), (jnp.bfloat16, 0.03)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("name", sorted(CHOSEN_CASES))
+def test_causal_attention_under_a_selections_mask(name, dtype, atol):
+    """The fused causal kernel under ``chosen`` is the float32 softmax
+    under the same mask — the operands as they are (bf16: scores and
+    softmax float32, the weights rounded for ``p @ v``; seen 0.012) —
+    and the mask is the reference's chosen set, row for row."""
+    q, k, v, scores, mask, (bq, bk), topk = _chosen_case(name)
+    b, seg = mask.shape[:2]
+    scale = D_QK ** -0.5
+    ops = [jnp.asarray(x, dtype) for x in (q, k, v)]
+    got = kernels.causal_attention(*ops, scale, block_q=bq, block_k=bk,
+                                   chosen=jnp.asarray(mask))
+    assert got.shape == (b, seg, 2, D_V) and got.dtype == dtype
+    want = _masked_softmax(*(x.astype(jnp.float32) for x in ops), mask, scale)
+    assert np.abs(np.asarray(got, np.float32) - want).max() < atol
+    # the chosen set: the reference's, and topk of a row past topk
+    for row in range(b):
+        np.testing.assert_array_equal(
+            mask[row], ref.select({"index_topk": topk}, scores[row]))
+    assert (mask.sum(-1) == np.minimum(np.arange(seg) + 1, topk)).all()
+    # ... and it is the whole difference: the plain causal call is not this
+    plain = kernels.causal_attention(*ops, scale, block_q=bq, block_k=bk)
+    assert np.abs(np.asarray(plain, np.float32)[:, topk + 8:]
+                  - want[:, topk + 8:]).max() > 0.05
+    if name.startswith("no_chosen_key"):
+        # rows past topk + a block hold no key of the loop's first block:
+        # their running max is -inf after it, and the rescale is guarded
+        late = slice(topk + bk, None)
+        assert not mask[:, late, :bk].any() and mask[:, :bk, :bk].any()
+        assert np.isfinite(np.asarray(got, np.float32)).all()
+    if name.startswith("ties"):
+        t = seg - 1
+        kth = np.sort(scores[0, t])[::-1][topk - 1]
+        tied = scores[0, t] == kth
+        assert 0 < mask[0, t][tied].sum() < tied.sum()
+        assert (np.nonzero(tied & mask[0, t])[0].max()
+                < np.nonzero(tied & ~mask[0, t])[0].min())
+    if name.startswith("two_prompts"):
+        assert (mask[0] != mask[1]).any()
+        swapped = kernels.causal_attention(
+            *ops, scale, block_q=bq, block_k=bk, chosen=jnp.asarray(mask[::-1]))
+        assert np.abs(np.asarray(swapped, np.float32) - want).max() > 0.05
+
+
+def test_a_chosen_set_stands_alone_and_fits_its_blocks():
+    q = jnp.zeros((1, 96, 2, 16), jnp.float32)
+    mask = jnp.ones((1, 96, 96), bool)
+    with pytest.raises(ValueError, match="chosen"):
+        kernels.causal_attention(q, q, q, 1.0, block_q=32, block_k=32,
+                                 chosen=mask, window=8)
+    with pytest.raises(ValueError, match="chosen"):
+        kernels.causal_attention(q, q, q, 1.0, block_q=32, block_k=32,
+                                 chosen=mask[:, :64])
+    # a key block over 128 keys holds whole columns of 128 and divides 4,096
+    wide = jnp.zeros((1, 384, 1, 16), jnp.float32)
+    with pytest.raises(ValueError, match="chosen"):
+        kernels.causal_attention(wide, wide, wide, 1.0, block_q=384, block_k=384,
+                                 chosen=jnp.ones((1, 384, 384), bool))
+
+
+def _indexed_operands(seg, topk, dtype=jnp.float32, b=2, h=2, rank=32, seed=7):
+    rng = np.random.default_rng(seed)
+
+    def draw(*shape):
+        return jnp.asarray(rng.normal(size=shape).astype(np.float32), dtype)
+
+    n, r, vd, ih, idim = 16, 8, 8, 2, 16
+    return dict(
+        q_nope=draw(b, seg, h, n), q_rope=draw(b, seg, h, r), seg=draw(b, seg, 128),
+        w_uk=draw(h, rank, n) * 0.2, w_uv=draw(h, rank, vd) * 0.2, scale=0.2,
+        dtype=dtype, q_idx=draw(b, seg, ih, idim),
+        w_idx=jnp.asarray(rng.normal(size=(b, seg, ih)), jnp.float32),
+        k_idx=draw(b, seg, idim), index_scale=0.1, topk=topk)
+
+
+def _kernel_names(fn, *args):
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append((eqn.params["name"] if "name" in eqn.params
+                              else eqn.params["name_and_src_info"].name,
+                              len(eqn.invars)))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("seg,topk,kernel", [
+    (256, 64, ("prefill_chosen_attention", 4)),    # blocks of 128 queries select
+    (200, 64, ("prefill_chosen_attention", 4)),    # no multiple of 128: at once
+    (64, 64, ("prefill_causal_attention", 3)),     # L <= topk: no mask is built
+    (48, 64, ("prefill_causal_attention", 3)),
+], ids=["blocks_select", "one_block_selects", "at_topk_plain", "under_topk_plain"])
+def test_indexed_attention_fused_is_its_xla_form(monkeypatch, seg, topk, kernel):
+    """``indexed_attention(fused=True)`` — the selection as ever, the
+    attention in the causal kernel under its mask — is the XLA lane's
+    function of the same operands; while ``L <= topk`` the call is the
+    plain causal one (three operands, no mask)."""
+    monkeypatch.setattr(kernels, "CAUSAL_BLOCK_Q", 32)
+    monkeypatch.setattr(kernels, "CAUSAL_BLOCK_K", 32)
+    ops = _indexed_operands(seg, topk)
+    xla = mla.indexed_attention(**ops)
+    fused = mla.indexed_attention(**ops, fused=True)
+    np.testing.assert_allclose(fused, xla, rtol=2e-4, atol=2e-4)
+    assert _kernel_names(lambda: mla.indexed_attention(**ops, fused=True)) == [kernel]
+    assert _kernel_names(lambda: mla.indexed_attention(**ops)) == []
+    if seg > topk:
+        # the selection matters: attention over every earlier row is not this
+        dense = mla.indexed_attention(**dict(ops, topk=seg), fused=True)
+        assert float(jnp.abs(dense - xla).max()) > 0.02
+
+
+# the traced ``causal_attention`` of a call WITHOUT a chosen set, as the
+# tree before PR 43 traced it (sha256 of ``str(jax.make_jaxpr(...))``):
+# (prompts, length, heads, K/V heads, d_qk, d_v), window -> digest.  The
+# configurations that hand no mask over compile what they compiled; a PR
+# that changes the kernel for them on purpose measures their cells and
+# replaces these.
+UNCHOSEN_JAXPRS = {
+    "gigachat": ((2, 2048, 64, 64, 192, 192), 0, "c2c9b0697b9f998b"),
+    "longcat": ((4, 1024, 64, 64, 192, 128), 0, "43edf1551595801e"),
+    "dots3_window": ((1, 4096, 64, 64, 256, 128), 513, "7bf7dd0d27f94337"),
+    "smallthinker_grouped": ((1, 4096, 28, 4, 128, 128), 0, "5ea086b79502049f"),
+    "smallthinker_grouped_window": ((1, 8192, 28, 4, 128, 128), 4096,
+                                    "7b928f71d41cded1"),
+}
+
+
+def _traced_causal(monkeypatch, shape, window=0, chosen=False):
+    import hashlib
+
+    b, seg, h, kv_heads, d_qk, d_v = shape
+    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
+
+    def spec(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    def call(q, k, v, *mask):
+        return kernels.causal_attention(
+            q, k, v, 0.07, **({"window": window} if window else {}),
+            **({"chosen": mask[0]} if mask else {}))
+
+    text = str(jax.make_jaxpr(call)(
+        spec(b, seg, h, d_qk), spec(b, seg, kv_heads, d_qk),
+        spec(b, seg, kv_heads, d_v),
+        *([spec(b, seg, seg, dtype=jnp.bool_)] if chosen else [])))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("name", sorted(UNCHOSEN_JAXPRS))
+def test_causal_attention_without_a_chosen_set_traces_what_it_traced(
+        monkeypatch, name):
+    shape, window, digest = UNCHOSEN_JAXPRS[name]
+    assert _traced_causal(monkeypatch, shape, window) == digest
+    if not window:
+        # ... and the chosen set is a structure of its own
+        assert _traced_causal(monkeypatch, shape, chosen=True) != digest

@@ -335,6 +335,159 @@ class TestSelection:
                 counts, np.minimum(np.arange(len(PROMPTS[2])) + 1, TOPK))
 
 
+def _fused_here(monkeypatch, block=16):
+    """Toy sizes: on the kernel lane (the Pallas interpreter) the rule
+    answers ``"fused"`` for a bf16 from-zero prefill of any bucket of at
+    least ``block`` positions.  The blocks and the lane's knob are read
+    when an engine is built AND when a program is traced (``_build``
+    puts the environment back): both are held for the whole test."""
+    from seldon_core_tpu.ops import kernels
+
+    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
+    monkeypatch.setattr(kernels, "CAUSAL_BLOCK_Q", block)
+    monkeypatch.setattr(kernels, "CAUSAL_BLOCK_K", block)
+
+
+def _prefill_kernels(eng, bucket):
+    """The names of the ``prefill_*`` kernels the from-zero program of
+    ``bucket`` traces, in the layers' order."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(eqn.params["name"] if "name" in eqn.params
+                             else eqn.params["name_and_src_info"].name)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    pages = eng._pages_pow2(-(-bucket // PAGE))
+    program = eng._build_prefill(bucket, 1).__wrapped__
+    walk(jax.make_jaxpr(lambda *args: program(
+        *args, window=(i32(1, eng.window_pages), i32(1))))(
+        eng.params, *eng._kv_args(), i32(1, bucket), i32(1), i32(1, pages)).jaxpr)
+    return [name for name in found if name.startswith("prefill_")]
+
+
+def _prefill_logits(eng, prompt):
+    """The from-zero program of the prompt's bucket on ``prompt`` alone:
+    its last position's logits (the rows it writes go to the trash
+    page: a from-zero prefill attends its own segment)."""
+    bucket = next(b for b in eng.prompt_buckets if b >= len(prompt))
+    tokens = np.zeros((1, bucket), np.int32)
+    tokens[0, :len(prompt)] = prompt
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    last, pk, pv, *_hist = eng._build_prefill(bucket, 1)(
+        eng.params, *eng._kv_args(), jnp.asarray(tokens),
+        jnp.asarray([len(prompt)], jnp.int32),
+        i32(1, eng._pages_pow2(-(-bucket // PAGE))),
+        window=(i32(1, eng.window_pages), i32(1)))
+    eng._store_kv(pk, pv)
+    return np.asarray(last)[0]
+
+
+class TestIndexedPrefill:
+    """PR 43: a from-zero prefill's indexed layers attend in the fused
+    causal kernel under the selection's mask where the rule says so at
+    their widths (layers: full, window, window, full; ``index_topk``
+    16, buckets 16 / 32 / 64)."""
+
+    def test_the_kernel_lanes_logits_are_the_xla_lanes(self, monkeypatch):
+        """The same weights on the kernel lane, the attention of every
+        from-zero prefill in the kernel (blocks of 16) against XLA's (the
+        buckets lie under the shipped query block): the prefill
+        program's logits agree within the file's bf16 tolerance, and
+        each holds the float32 reference to it."""
+        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
+        prompts = PROMPTS[::2]             # the buckets past index_topk
+        # (the XLA engine builds and traces under the shipped blocks)
+        xla, params = _build("kernel", jnp.bfloat16, seed=BF16_SEED)
+        assert set(xla.lane_report()["prefill_attention"].values()) == {"xla"}
+        assert _prefill_kernels(xla, 64) == []
+        want_xla = [_prefill_logits(xla, p) for p in prompts]
+        _fused_here(monkeypatch)
+        fused, _ = _build("kernel", jnp.bfloat16, seed=BF16_SEED)
+        assert set(fused.lane_report()["prefill_attention"].values()) == {"fused"}
+        assert _prefill_kernels(fused, 64).count("prefill_chosen_attention") == 2
+        for prompt, xla_row in zip(prompts, want_xla):
+            got = _prefill_logits(fused, prompt)
+            np.testing.assert_allclose(got, xla_row, atol=BF16_ATOL, rtol=0)
+            want = np.asarray(ref.logits(params, MODEL, prompt))[-1]
+            np.testing.assert_allclose(got, want, atol=BF16_ATOL, rtol=0)
+
+    @pytest.mark.parametrize("block", [16, 32])
+    def test_the_report_is_what_each_program_traced(self, monkeypatch, block):
+        """``lane_report()["prefill_attention"]`` says a bucket each what
+        the window layers (``b<bucket>``) and the indexed layers
+        (``b<bucket>_indexed``) attend with, and the programs ask the
+        same rule as they trace: under the mask past ``index_topk``
+        positions, the plain causal call up to it, nothing of the
+        kernel's under a query block."""
+        _fused_here(monkeypatch, block)
+        eng, _ = _build("kernel", jnp.bfloat16)
+        report = eng.lane_report()["prefill_attention"]
+        assert set(report) == {f"b{b}{tag}" for b in eng.prompt_buckets
+                               for tag in ("", "_indexed")}
+        for bucket in eng.prompt_buckets:
+            want = "fused" if bucket >= block else "xla"
+            assert report[f"b{bucket}"] == report[f"b{bucket}_indexed"] == want
+            full = ("prefill_chosen_attention" if bucket > TOPK
+                    else "prefill_causal_attention")
+            assert _prefill_kernels(eng, bucket) == (
+                [full, "prefill_window_attention", "prefill_window_attention", full]
+                if want == "fused" else [])
+
+    @pytest.mark.parametrize("lane,dtype", [
+        ("kernel", jnp.float32), ("gather", jnp.float32), ("gather", jnp.bfloat16)])
+    def test_float32_and_the_gather_lane_keep_the_xla_form(
+            self, monkeypatch, lane, dtype):
+        """An exactness engine and ``SELDON_TPU_PAGED_KERNEL=0`` (one
+        numeric regime; a mesh is refused for a latent pool altogether,
+        and the rule answers a mesh's ``kernel_lane=False`` like the
+        knob's)."""
+        _fused_here(monkeypatch)
+        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", LANES[lane]["SELDON_TPU_PAGED_KERNEL"])
+        eng, _ = _build(lane, dtype)
+        assert set(eng.lane_report()["prefill_attention"].values()) == {"xla"}
+        assert _prefill_kernels(eng, 64) == []
+        stream = eng.submit(np.asarray(PROMPTS[0], np.int32), max_new_tokens=1)
+        eng.run()
+        assert stream.error is None
+        stats = eng.engine_stats()
+        assert stats["prefill_padded_tokens"] > 0
+        assert stats["prefill_indexed_fused_positions"] == 0
+        assert stats["prefill_fused_positions"] == 0
+
+    def test_the_counter_is_what_the_annotation_says(self, monkeypatch):
+        """``prefill_indexed_fused_positions`` rises by the padded
+        positions of the calls whose ``seldon.wave.prefill`` says
+        ``indexed_fused``: every call of a bucket of a query block or
+        more, none of the bucket under it."""
+        _fused_here(monkeypatch, 32)
+        eng, _ = _build("kernel", jnp.bfloat16)
+        said = []
+        begin = eng._seam.begin_prefill
+
+        def spy(**stats):
+            said.append(stats)
+            return begin(**stats)
+
+        monkeypatch.setattr(eng._seam, "begin_prefill", spy)
+        streams = [eng.submit(np.asarray(p, np.int32), max_new_tokens=1)
+                   for p in PROMPTS]
+        eng.run()
+        assert all(s.error is None for s in streams)
+        assert sorted(c["bucket"] for c in said) == [16, 32, 64]
+        for call in said:
+            assert call["indexed_fused"] == call["fused"] == int(call["bucket"] >= 32)
+        stats = eng.engine_stats()
+        assert stats["prefill_padded_tokens"] == sum(c["padded"] for c in said)
+        assert stats["prefill_indexed_fused_positions"] == sum(
+            c["padded"] for c in said if c["indexed_fused"]) == 32 + 64
+        assert stats["prefill_fused_positions"] == 32 + 64
+
+
 class TestReferenceTail:
     @pytest.mark.parametrize("tail,variant", [(1, ""), (7, ""), (7, "window_short")])
     def test_the_last_layer_queried_at_the_tail_alone_gives_the_same_rows(

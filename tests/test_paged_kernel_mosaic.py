@@ -383,6 +383,28 @@ def test_prefill_causal_kernel_compiles_at_the_cells_shapes(
         bucket, d_qk, d_v, jnp.bfloat16, 0, True) == "fused"
 
 
+@pytest.mark.parametrize("k,bucket", [(1, 4096), (2, 3072), (1, 7168)],
+                         ids=["b4096_k1", "b3072_k2", "b7168_k1"])
+def test_prefill_kernel_under_a_chosen_set_compiles_at_dots3s_shapes(
+        one_chip, mosaic, k, bucket):
+    """PR 43: an indexed layer's prefill (128 heads of 192 / 128) under
+    the selection's ``(k, L, L)`` mask — packed 32 keys a word, a
+    ``(512, 128)`` int32 tile a query block and 4,096 keys, a dynamic
+    bit a key block, four columns of 128 lanes joined a score block."""
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, key, v, m: kernels.causal_attention(q, key, v, 192 ** -0.5, chosen=m)
+    ).lower(spec(k, bucket, 128, 192), spec(k, bucket, 128, 192),
+            spec(k, bucket, 128, 128), spec(k, bucket, bucket, dtype=jnp.bool_)
+            ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "prefill_chosen_attention" in text
+    assert kernels.prefill_attention_impl(
+        bucket, 192, 128, jnp.bfloat16, 0, True) == "fused"
+
+
 def test_a_stack_of_layers_is_compiled_once_and_called(one_chip, mosaic):
     """PR 37: the engine compiles its programs on a TPU with
     ``paged.TPU_COMPILER_OPTIONS``.  The chip's compiler knows the

@@ -148,6 +148,16 @@ RULE = [
     (1024, 192, 192, 0, True, jnp.float16, "xla"),
     (512, 64, 64, 0, True, jnp.bfloat16, "fused"),        # any width that fits
     (1 << 17, 192, 192, 0, True, jnp.bfloat16, "xla"),    # K and V over VMEM
+    # PR 43: dots3's indexed layers ask at their own widths (128 heads
+    # of 192 / 128) and take the answer for the attention under their
+    # selection; the window layers' 256 / 128 beside them
+    (4096, 192, 128, 0, True, jnp.bfloat16, "fused"),
+    (3072, 192, 128, 0, True, jnp.bfloat16, "fused"),
+    (7168, 192, 128, 0, True, jnp.bfloat16, "fused"),
+    (4096, 256, 128, 0, True, jnp.bfloat16, "fused"),
+    (4096, 192, 128, 0, False, jnp.bfloat16, "xla"),      # a mesh, the CPU, =0
+    (4096, 192, 128, 0, True, jnp.float32, "xla"),
+    (256, 192, 128, 0, True, jnp.bfloat16, "xla"),        # under a query block
 ]
 
 
@@ -396,6 +406,34 @@ def test_the_engine_says_what_ran(monkeypatch, arch, lane):
         # of 16
         assert stats["prefill_padded_tokens"] == 2 * 32 + 16
         assert stats["prefill_fused_positions"] == (64 if fused else 0)
+        # no indexer: nothing attends under a selection's mask (PR 43)
+        assert stats["prefill_indexed_fused_positions"] == 0
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("arch", ["olmoe", "longcat"])
+def test_a_spec_without_an_indexer_counts_no_indexed_positions(monkeypatch, arch):
+    """``prefill_indexed_fused_positions`` and the report's
+    ``b<bucket>_indexed`` entries are an indexed spec's alone (dots3:
+    ``tests/test_dots3_paged.py TestIndexedPrefill``)."""
+    _fused_here(monkeypatch, block=32)
+    eng = _engine(monkeypatch, arch, jnp.bfloat16, lane="force")
+    said = []
+    begin = eng._seam.begin_prefill
+    monkeypatch.setattr(eng._seam, "begin_prefill",
+                        lambda **stats: (said.append(stats), begin(**stats))[1])
+    try:
+        assert set(eng.lane_report()["prefill_attention"]) == {
+            f"b{b}" for b in eng.prompt_buckets}
+        stream = eng.submit(np.asarray(PROMPT, np.int32), max_new_tokens=1)
+        eng.run()
+        assert stream.error is None
+        stats = eng.engine_stats()
+        assert [c["indexed_fused"] for c in said] == [0]
+        assert [c["fused"] for c in said] == [int(_impl(arch, "force") == "fused")]
+        assert stats["prefill_padded_tokens"] == 32
+        assert stats["prefill_indexed_fused_positions"] == 0
     finally:
         eng.close()
 

@@ -285,4 +285,8 @@ class TestPages:
         # asked about a pass's rows, not every assignment's (PR 42)
         rows = report["held_pass_rows"]
         assert sorted(rows) == sorted(report["expert_matmul"])
+        # no indexer: no layer attends under a selection's mask (PR 43)
+        assert set(report["prefill_attention"]) == {
+            f"b{b}" for b in eng.prompt_buckets}
+        assert eng.engine_stats()["prefill_indexed_fused_positions"] == 0
         assert rows["chunk"] == eng.engine_stats()["moe_held_pass_rows"] == 64

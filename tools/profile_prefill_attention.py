@@ -23,6 +23,14 @@ implementations:
   pair (query block x key block; default: the kernel's own), for a
   latent shape inside ``naive_attention`` as the engine runs it.
 
+An **indexed** cell (``dots3``: a full layer whose rows attend the 2,048
+positions an indexer picks) times ``ops/mla.py indexed_attention`` whole
+— the selection and the attention under it — as ``xla`` (a block of 128
+queries scored against every key) and ``fused`` (the blocks select, the
+causal kernel weighs under their mask), and the kernel alone on a mask
+made beforehand by the same rule: ``kernel_chosen`` (packing included)
+beside ``kernel_plain`` (no mask: what the mask costs the kernel).
+
 ``max_abs_err`` is the implementation's distance from a float32 softmax
 over the same bf16 operands (unit-normal q, k, v), over the first
 prompt's rows.  Off the chip ``--rehearse`` runs a toy size through the
@@ -52,7 +60,11 @@ CELLS = {
     "gigachat": (64, 192, 192, 512, [(1, 2048), (2, 1024), (1, 1024)]),
     "longcat": (64, 192, 128, 512, [(2, 1024), (1, 1024), (4, 512), (1, 512)]),
 }
-TOY = {"toy": (2, 16, 16, 0, [(2, 128)]), "toy_latent": (2, 24, 16, 32, [(1, 128)])}
+TOY = {"toy": (2, 16, 16, 0, [(2, 128)]), "toy_latent": (2, 24, 16, 32, [(1, 128)]),
+       "toy_indexed": (2, 24, 16, 32, [(1, 256)])}
+# cell -> (index heads, index dim, topk): the cells whose layer selects
+INDEXED = {"dots3": (64, 128, 2048), "toy_indexed": (2, 16, 96)}
+CELLS["dots3"] = (128, 192, 128, 512, [(1, 3072), (1, 4096), (2, 3072), (2, 4096)])
 
 
 def xla_table(q, k, v, pool_k, pool_v, table, scale):
@@ -91,13 +103,15 @@ def xla_segment(q, k, v, scale):
     return jnp.einsum("bhqk,bkhd->bqhd", w, v)
 
 
-def oracle(q, k, v, scale):
-    """Float32 causal softmax of the first prompt's rows."""
+def oracle(q, k, v, scale, seen=None):
+    """Float32 causal softmax of the first prompt's rows (under its
+    ``(L, L)`` mask ``seen``, where the layer selects)."""
     import numpy as np
 
     q, k, v = (np.asarray(x[0], np.float32) for x in (q, k, v))
     s = np.einsum("qhd,khd->hqk", q, k) * scale
-    s = np.where(np.tril(np.ones(s.shape[-2:], bool)), s, -np.inf)
+    s = np.where(np.tril(np.ones(s.shape[-2:], bool)) if seen is None
+                 else np.asarray(seen), s, -np.inf)
     w = np.exp(s - s.max(-1, keepdims=True))
     return np.einsum("hqk,khd->qhd", w / w.sum(-1, keepdims=True), v)
 
@@ -108,6 +122,8 @@ def main() -> int:
     ap.add_argument("--cells", nargs="+", default=sorted(CELLS))
     ap.add_argument("--blocks", nargs="*", default=[],
                     help="QxK pairs for the fused kernel (default: its own)")
+    ap.add_argument("--groups", nargs="*", default=[],
+                    help="KxBUCKET prefill groups instead of the cells' own")
     ap.add_argument("--calls", type=int, default=12, help="layer-calls a program")
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--skip", nargs="*", default=[], choices=["xla_table", "xla", "fused"])
@@ -153,11 +169,13 @@ def main() -> int:
 
     for cell, (heads, d_qk, d_v, rank, groups) in cells.items():
         scale = float(d_qk) ** -0.5
+        groups = [tuple(int(x) for x in g.split("x")) for g in args.groups] or groups
         for group, seg in groups:
             key = jax.random.key(args.seed)
             kq, kk, kv, kp = jax.random.split(key, 4)
             flops = group * heads * seg * seg * (d_qk + d_v)
             arms = {}
+            heads_judged = slice(None)
             if rank:
                 # a latent shape: q halves, the segment's rows, W_uk, W_uv
                 rope = d_qk - 128 if d_qk > 128 else d_qk // 3
@@ -168,20 +186,6 @@ def main() -> int:
                         * rank ** -0.5).astype(dtype)
                 w_uv = (jax.random.normal(kp, (heads, rank, d_v), dtype)
                         * rank ** -0.5).astype(dtype)
-                rest = (rows, w_uk, w_uv)
-
-                def naive(fused, bq=None, bk=None):
-                    def fn(q, rows, w_uk, w_uv):
-                        # (naive_attention takes the kernel's own blocks:
-                        # read as each arm is traced)
-                        kernels.CAUSAL_BLOCK_Q, kernels.CAUSAL_BLOCK_K = (
-                            (bq, bk) if bq else own_blocks)
-                        return mla.naive_attention(
-                            q[..., :nope], q[..., nope:], None,
-                            jnp.zeros((group,), jnp.int32), rows, w_uk, w_uv,
-                            scale, dtype, fused=fused)
-                    return fn
-
                 k_full = jnp.concatenate([
                     jnp.einsum("bcr,hrn->bchn", rows[..., :rank], w_uk,
                                preferred_element_type=jnp.float32).astype(dtype),
@@ -189,6 +193,76 @@ def main() -> int:
                                      (group, seg, heads, rope))], -1)
                 v_full = jnp.einsum("bcr,hrv->bchv", rows[..., :rank], w_uv,
                                     preferred_element_type=jnp.float32).astype(dtype)
+
+                def own(bq, bk):
+                    # (the latent forms take the kernel's own blocks:
+                    # read as each arm is traced)
+                    kernels.CAUSAL_BLOCK_Q, kernels.CAUSAL_BLOCK_K = (
+                        (bq, bk) if bq else own_blocks)
+
+            if cell in INDEXED:
+                # an indexed layer: the indexer's operands beside the
+                # latent ones, and the rule's own mask, made a block of
+                # 128 queries at a time, for the kernel-alone arms
+                ih, idim, topk = INDEXED[cell]
+                ki, kw, kx = jax.random.split(jax.random.key(args.seed + 1), 3)
+                q_idx = jax.random.normal(ki, (group, seg, ih, idim), dtype)
+                w_idx = jax.random.normal(kw, (group, seg, ih), jnp.float32)
+                k_idx = jax.random.normal(kx, (group, seg, idim), dtype)
+                i_scale = ih ** -0.5 * idim ** -0.5
+                at = jnp.arange(seg)
+                bq_sel = min(128, seg)
+
+                @jax.jit
+                def select(qi, wi, first):
+                    under = at[None, None, :] <= (first + at[:bq_sel])[None, :, None]
+                    return mla.kth_mask(
+                        mla.index_scores(qi, wi, k_idx, i_scale),
+                        jnp.broadcast_to(under, (group, bq_sel, seg)), topk)
+
+                mask = jnp.concatenate([
+                    select(q_idx[:, f:f + bq_sel], w_idx[:, f:f + bq_sel], f)
+                    for f in range(0, seg, bq_sel)], axis=1)
+                # (four heads: the whole float32 square of 128 is 8.6 GB)
+                heads_judged = slice(0, 4)
+                want = oracle(q[:, :, :4], k_full[:, :, :4], v_full[:, :, :4],
+                              scale, mask[0])
+                rest = (rows, w_uk, w_uv, q_idx, w_idx, k_idx, k_full, v_full, mask)
+
+                def indexed(fused, bq=None, bk=None):
+                    def fn(q, rows, w_uk, w_uv, q_idx, w_idx, k_idx, _k, _v, _m):
+                        own(bq, bk)
+                        return mla.indexed_attention(
+                            q[..., :nope], q[..., nope:], rows, w_uk, w_uv,
+                            scale, dtype, q_idx, w_idx, k_idx, i_scale, topk,
+                            fused=fused)
+                    return fn
+
+                def alone(masked, bq=None, bk=None):
+                    def fn(q, _r, _uk, _uv, _qi, _wi, _ki, k, v, m):
+                        return kernels.causal_attention(
+                            q, k, v, scale, block_q=bq, block_k=bk,
+                            **({"chosen": m} if masked else {}))
+                    return fn
+
+                arms["xla"] = indexed(False)
+                for bq, bk in blocks:
+                    tag = f"_{bq}x{bk}" if bq else ""
+                    arms["fused" + tag] = indexed(True, bq, bk)
+                    arms["kernel_chosen" + tag] = alone(True, bq, bk)
+                    arms["kernel_plain" + tag] = alone(False, bq, bk)
+            elif rank:
+                rest = (rows, w_uk, w_uv)
+
+                def naive(fused, bq=None, bk=None):
+                    def fn(q, rows, w_uk, w_uv):
+                        own(bq, bk)
+                        return mla.naive_attention(
+                            q[..., :nope], q[..., nope:], None,
+                            jnp.zeros((group,), jnp.int32), rows, w_uk, w_uv,
+                            scale, dtype, fused=fused)
+                    return fn
+
                 want = oracle(q, k_full, v_full, scale)
                 arms["xla"] = naive(False)
                 for bq, bk in blocks:
@@ -215,7 +289,8 @@ def main() -> int:
                 line = {"cell": cell, "k": group, "bucket": seg, "heads": heads,
                         "d_qk": d_qk, "d_v": d_v, "impl": name}
                 try:
-                    got = np.asarray(jax.jit(fn)(q, *rest)[0], np.float32)
+                    got = np.asarray(
+                        jax.jit(fn)(q, *rest)[0][:, heads_judged], np.float32)
                     line["max_abs_err"] = float(np.abs(got - want).max())
                     if on_chip:
                         s = timed(fn, q, rest)
