@@ -803,13 +803,7 @@ def init_params(spec: ModelSpec, config: Dict[str, int], seed: int,
     from flax.traverse_util import flatten_dict, unflatten_dict
 
     declared = declared_tree(spec, config, dtype)
-
-    # one compiled program per distinct (shape, range, type): the
-    # layers' leaves share them
-    @partial(jax.jit, static_argnums=(1, 2, 3, 4))
-    def uniform(key, shape, lo, hi, leaf_dtype):
-        return jax.random.uniform(key, shape, jnp.float32, lo, hi).astype(leaf_dtype)
-
+    uniform = _uniform()
     root = jax.random.key(int(seed) % (1 << 63))
     tree = {}
     for path, leaf in flatten_dict(declared, sep="/").items():
@@ -832,6 +826,21 @@ def init_params(spec: ModelSpec, config: Dict[str, int], seed: int,
         else:
             tree[path] = uniform(key, leaf.shape, lo, hi, jnp.dtype(leaf.dtype))
     return unflatten_dict(tree, sep="/")
+
+
+@lru_cache(maxsize=None)
+def _uniform():
+    """The jitted draw of one leaf, made once a process: one compiled
+    program per distinct (shape, range, type), which the layers' leaves
+    share and every later ``init_params`` finds again."""
+    import jax
+    import jax.numpy as jnp
+
+    @partial(jax.jit, static_argnums=(1, 2, 3, 4))
+    def uniform(key, shape, lo, hi, leaf_dtype):
+        return jax.random.uniform(key, shape, jnp.float32, lo, hi).astype(leaf_dtype)
+
+    return uniform
 
 
 # leaves whose input is a low-rank latent: q_b's kernel, kv_b_k, kv_b_v

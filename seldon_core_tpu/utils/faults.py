@@ -295,8 +295,8 @@ def corrupt_bytes(point: str, data: bytes) -> bytes:
     if not k or not data:
         return data
     out = bytearray(data)
-    for _ in range(min(k, len(out))):
-        i = random.randrange(len(out))
+    # without replacement: a byte flipped twice is a byte left as it was
+    for i in random.sample(range(len(out)), min(k, len(out))):
         out[i] ^= 0xFF
     logger.warning("injected %s: flipped %d byte(s) of a %d-byte payload",
                    point, min(k, len(out)), len(out))

@@ -17,10 +17,16 @@ through its own front door (``submit`` / ``step``: its allocator, its
 tables, its compiled programs), on the kernel lane (Pallas in interpret
 mode) and the XLA gather lane; a prefill program's logits are read where
 the engine calls it.
+
+``test_dots3_prefill.py`` has the indexed prefill under the fused
+kernel, the reference's tail, the share and the component's front door
+(PR 44 split one file of 821 s along its classes; this one holds the
+module's engines).
 """
 
 import os
 import sys
+from functools import partial
 
 import numpy as np
 import pytest
@@ -28,10 +34,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import paged_harness as harness
 from seldon_core_tpu.models import paged
-from seldon_core_tpu.models.paged import PagedEngine, StreamingLM
+from seldon_core_tpu.models.paged import PagedEngine
 from seldon_core_tpu.models.spec import init_params, model_spec
-from seldon_core_tpu.ops import mla
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
 from reference import dots3_note as ref  # noqa: E402
@@ -79,86 +85,39 @@ F32_ATOL = 1e-4
 # position wide on seed 6).
 BF16_ATOL, BF16_SEED = 0.12, 3
 
-LANES = {
-    "kernel": {"SELDON_TPU_PAGED_KERNEL": "force"},
-    "gather": {"SELDON_TPU_PAGED_KERNEL": "0"},
-}
-
-
 def _build(lane, dtype, seed=3, **kw):
-    saved = {k: os.environ.get(k) for k in ("SELDON_TPU_PAGED_KERNEL",
-                                            "SELDON_TPU_CHUNK_IMPL",
-                                            "SELDON_TPU_PAGED_DEBUG")}
-    os.environ.pop("SELDON_TPU_CHUNK_IMPL", None)
-    os.environ.update(LANES[lane], SELDON_TPU_PAGED_DEBUG="1")
-    try:
-        params = init_params(SPEC, SIZES, seed, dtype=dtype)
-        kw.setdefault("steps_per_call", 1)
-        eng = PagedEngine(params, **SIZES, max_len=MAX_LEN, page_size=PAGE,
-                          max_slots=SLOTS, dtype=dtype, spec=SPEC, **kw)
-    finally:
-        for k, v in saved.items():
-            os.environ.pop(k, None) if v is None else os.environ.update({k: v})
-    return eng, params
+    """``(engine, params)``, the allocator audited at every chunk
+    boundary."""
+    with harness.environment(SELDON_TPU_PAGED_DEBUG="1"):
+        return harness.build(SPEC, SIZES, lane, dtype, seed=seed, max_len=MAX_LEN,
+                             page_size=PAGE, max_slots=SLOTS, **kw)
 
 
 @pytest.fixture(scope="module", params=["kernel", "gather"])
-def f32_engine(request):
-    return _build(request.param, jnp.float32)
+def module_f32(request):
+    eng, params = _build(request.param, jnp.float32)
+    yield eng, params
+    eng.close()
 
 
-@pytest.fixture(scope="module")
-def bf16_engine():
-    return _build("kernel", jnp.bfloat16, seed=BF16_SEED)
+# a case's view: the lane's environment held while it steps the engine
+# (the lane's knob is read again at every trace: ``harness.tracing``)
+@pytest.fixture
+def f32_engine(module_f32, monkeypatch):
+    harness.hold(monkeypatch, module_f32[0])
+    return module_f32
 
 
-def _serve(eng, prompts, new=NEW):
-    """Serve ``prompts`` together, a token a step: per prompt ``(tokens,
-    rows)`` with ``rows[i]`` the engine's logits after ``i`` tokens
-    (``rows[0]``: the prefill program's)."""
-    first = {}
-    build = eng._build_prefill
-
-    def spy(bucket, k):
-        fn = build(bucket, k)
-
-        def call(*args, **kw):
-            out = fn(*args, **kw)
-            lens = np.asarray(args[4])
-            for row, n in zip(np.asarray(out[0]), lens):
-                first[int(n)] = row
-            return out
-        return call
-
-    eng._build_prefill = spy
-    eng._prefill_jit.clear()
-    try:
-        streams = [eng.submit(np.asarray(p, np.int32), max_new_tokens=new)
-                   for p in prompts]
-        slots, rows = {}, [[] for _ in prompts]
-        for _step in range(new):
-            eng.step()
-            for i, s in enumerate(streams):
-                if s.slot is not None:
-                    slots[i] = s.slot
-                rows[i].append(np.asarray(eng._logits[slots[i]]))
-        assert all(s.event.is_set() for s in streams)
-    finally:
-        eng._build_prefill = build
-        eng._prefill_jit.clear()
-    return [(s.result.tolist(), np.stack([first[len(p)]] + r[:-1]))
-            for s, p, r in zip(streams, prompts, rows)]
+@pytest.fixture
+def own_engine(monkeypatch):
+    """``own_engine(lane, dtype, **build's) -> (engine, params)`` for a
+    case that patches what a trace reads or counts from zero: the lane's
+    environment held to the case's end, closed after it."""
+    yield from harness.own(monkeypatch, _build)
 
 
-_SERVED_ONE = {}
-
-
-def _served_one(eng):
-    """``_serve(eng, PROMPTS[:1])``, once an engine: the wrong-program
-    cases all read the same served rows."""
-    if id(eng) not in _SERVED_ONE:
-        _SERVED_ONE[id(eng)] = _serve(eng, PROMPTS[:1])[0]
-    return _SERVED_ONE[id(eng)]
+_serve = partial(harness.serve, new=NEW)
+_served_one = partial(harness.served_one, prompt=PROMPTS[0], new=NEW)
 
 
 def _reference(params, prompt, tokens, **kw):
@@ -167,16 +126,7 @@ def _reference(params, prompt, tokens, **kw):
     return rows[len(prompt) - 1:]
 
 
-def _held_nothing(eng):
-    stats = eng.engine_stats()
-    with eng._lock:
-        eng._check_invariants_locked()
-    # (no slot keeps a table or a base behind: an idle lane under a stale
-    # base had a negative length in its window's terms, which hung the
-    # chip's kernel — PERF.md section 6, PR 38)
-    return (stats["full_pages_held"], stats["window_pages_held"],
-            stats["pool_pages_used"]) == (0, 0, 0) and not (
-                eng._wtables.any() or eng._wbase.any())
+_held_nothing = harness.held_nothing
 
 
 class TestLogits:
@@ -228,8 +178,8 @@ class TestLogits:
         assert d["sparse_lane_steps"] == steps
         assert d["window_rows_read"] == 2 * (WINDOW - 1) * steps
 
-    def test_stated_precision(self, bf16_engine):
-        eng, params = bf16_engine
+    def test_stated_precision(self, own_engine):
+        eng, params = own_engine("kernel", jnp.bfloat16, seed=BF16_SEED)
         (tokens, rows), = _serve(eng, PROMPTS[:1])
         want = _reference(params, PROMPTS[0], tokens)
         np.testing.assert_allclose(rows, want, atol=BF16_ATOL, rtol=0)
@@ -256,72 +206,6 @@ class TestLogits:
 
 
 class TestSelection:
-    def test_chosen_set_is_the_references_at_every_step(self):
-        """``step_mask`` (a decode step: cached scores and the own) and
-        ``kth_mask`` (a prefill's rows) keep exactly the positions the
-        reference's stable sort keeps, ties included, at every length
-        from one to past ``topk``."""
-        rng = np.random.default_rng(2)
-        n, topk = 40, 12
-        # few distinct values: ties at the cut are the rule
-        scores = rng.integers(0, 6, size=(n, n)).astype(np.float32)
-        want = ref.select({"index_topk": topk}, scores)
-        causal = np.tril(np.ones((n, n), bool))
-        got = np.asarray(mla.kth_mask(jnp.asarray(scores), jnp.asarray(causal), topk))
-        np.testing.assert_array_equal(got, want)
-        for t in range(n):
-            cached = np.where(np.arange(n) < t, scores[t], 7.0)  # junk past the length
-            is_cached, own = mla.step_mask(
-                jnp.asarray(cached)[None], jnp.asarray(scores[t, t:t + 1]),
-                jnp.asarray([t]), topk)
-            chosen = set(np.nonzero(np.asarray(is_cached[0]))[0].tolist()) | (
-                {t} if bool(own[0]) else set())
-            assert chosen == set(np.nonzero(want[t])[0].tolist()), t
-
-    def test_the_kernel_lane_and_the_one_layer_lane_are_one_block(self, monkeypatch):
-        """A full layer's decode step that selects, called as the kernel
-        lane calls it (the whole pools and the layer's place: the page
-        loop under the mask) and as every other lane does (the layer's
-        own pools: a gather, ``ctx_state`` under the same mask): the same
-        stream out, the same rows to write, the same ``int32`` account —
-        keys scored and rows moved the lengths, rows read the chosen
-        set's cached members."""
-        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
-        paged.get_paged_lm_class()
-        block = paged._MODULES[0](
-            num_heads=SIZES["num_heads"], dtype=jnp.float32, spec=SPEC,
-            routed_layer=False, kind=SPEC.attn_kind(0, SIZES["num_heads"]))
-        rng = np.random.default_rng(5)
-        d, pages, table_w = MODEL["hidden_size"], 64, 12
-        kind = block.kind
-        lengths = jnp.asarray([40, 20, 9, 31], jnp.int32)  # over | over | under | idle
-        counted = jnp.asarray([[True], [True], [True], [False]])
-        lanes = len(lengths)
-        x = jnp.asarray(rng.normal(size=(lanes, 1, d)).astype(np.float32))
-        pools = tuple(
-            jnp.asarray(rng.normal(size=(2, pages, PAGE, w)).astype(np.float32))
-            for w in (kind.lanes, 128))
-        tables = (jnp.asarray(
-            rng.permutation(np.arange(1, pages))[:lanes * table_w].reshape(
-                lanes, table_w), jnp.int32),)
-        assert table_w * PAGE > TOPK
-        args = dict(positions=lengths[:, None], token_mask=counted, window=None)
-        params = block.init(jax.random.key(1), x, pools, None, tables, lengths,
-                            layer=1, **args)
-        whole = block.apply(params, x, pools, None, tables, lengths, layer=1, **args)
-        alone = block.apply(params, x, tuple(p[1] for p in pools), None, tables,
-                            lengths, layer=None, **args)
-        np.testing.assert_allclose(whole[0], alone[0], atol=2e-5, rtol=0)
-        for a, b in zip(whole[1][1:], alone[1][1:]):
-            np.testing.assert_array_equal(a, b)
-        read, read_alone = np.asarray(whole[-1]), np.asarray(alone[-1])
-        assert read.dtype == np.int32 and read.shape == (3,)
-        np.testing.assert_array_equal(read, read_alone)
-        live = int(lengths[:3].sum())
-        assert read[0] == read[2] == live
-        # each selecting lane reads topk rows, or one fewer with its own
-        assert 2 * (TOPK - 1) + 9 <= read[1] <= 2 * TOPK + 9 < live
-
     def test_reference_chosen_sets(self, f32_engine):
         """The reference's own sets: all of a row's positions while it
         has ``topk`` or fewer, exactly ``topk`` after."""
@@ -333,198 +217,6 @@ class TestSelection:
             counts = kept.sum(-1)
             np.testing.assert_array_equal(
                 counts, np.minimum(np.arange(len(PROMPTS[2])) + 1, TOPK))
-
-
-def _fused_here(monkeypatch, block=16):
-    """Toy sizes: on the kernel lane (the Pallas interpreter) the rule
-    answers ``"fused"`` for a bf16 from-zero prefill of any bucket of at
-    least ``block`` positions.  The blocks and the lane's knob are read
-    when an engine is built AND when a program is traced (``_build``
-    puts the environment back): both are held for the whole test."""
-    from seldon_core_tpu.ops import kernels
-
-    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
-    monkeypatch.setattr(kernels, "CAUSAL_BLOCK_Q", block)
-    monkeypatch.setattr(kernels, "CAUSAL_BLOCK_K", block)
-
-
-def _prefill_kernels(eng, bucket):
-    """The names of the ``prefill_*`` kernels the from-zero program of
-    ``bucket`` traces, in the layers' order."""
-    found = []
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                found.append(eqn.params["name"] if "name" in eqn.params
-                             else eqn.params["name_and_src_info"].name)
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
-
-    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
-    pages = eng._pages_pow2(-(-bucket // PAGE))
-    program = eng._build_prefill(bucket, 1).__wrapped__
-    walk(jax.make_jaxpr(lambda *args: program(
-        *args, window=(i32(1, eng.window_pages), i32(1))))(
-        eng.params, *eng._kv_args(), i32(1, bucket), i32(1), i32(1, pages)).jaxpr)
-    return [name for name in found if name.startswith("prefill_")]
-
-
-def _prefill_logits(eng, prompt):
-    """The from-zero program of the prompt's bucket on ``prompt`` alone:
-    its last position's logits (the rows it writes go to the trash
-    page: a from-zero prefill attends its own segment)."""
-    bucket = next(b for b in eng.prompt_buckets if b >= len(prompt))
-    tokens = np.zeros((1, bucket), np.int32)
-    tokens[0, :len(prompt)] = prompt
-    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
-    last, pk, pv, *_hist = eng._build_prefill(bucket, 1)(
-        eng.params, *eng._kv_args(), jnp.asarray(tokens),
-        jnp.asarray([len(prompt)], jnp.int32),
-        i32(1, eng._pages_pow2(-(-bucket // PAGE))),
-        window=(i32(1, eng.window_pages), i32(1)))
-    eng._store_kv(pk, pv)
-    return np.asarray(last)[0]
-
-
-class TestIndexedPrefill:
-    """PR 43: a from-zero prefill's indexed layers attend in the fused
-    causal kernel under the selection's mask where the rule says so at
-    their widths (layers: full, window, window, full; ``index_topk``
-    16, buckets 16 / 32 / 64)."""
-
-    def test_the_kernel_lanes_logits_are_the_xla_lanes(self, monkeypatch):
-        """The same weights on the kernel lane, the attention of every
-        from-zero prefill in the kernel (blocks of 16) against XLA's (the
-        buckets lie under the shipped query block): the prefill
-        program's logits agree within the file's bf16 tolerance, and
-        each holds the float32 reference to it."""
-        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
-        prompts = PROMPTS[::2]             # the buckets past index_topk
-        # (the XLA engine builds and traces under the shipped blocks)
-        xla, params = _build("kernel", jnp.bfloat16, seed=BF16_SEED)
-        assert set(xla.lane_report()["prefill_attention"].values()) == {"xla"}
-        assert _prefill_kernels(xla, 64) == []
-        want_xla = [_prefill_logits(xla, p) for p in prompts]
-        _fused_here(monkeypatch)
-        fused, _ = _build("kernel", jnp.bfloat16, seed=BF16_SEED)
-        assert set(fused.lane_report()["prefill_attention"].values()) == {"fused"}
-        assert _prefill_kernels(fused, 64).count("prefill_chosen_attention") == 2
-        for prompt, xla_row in zip(prompts, want_xla):
-            got = _prefill_logits(fused, prompt)
-            np.testing.assert_allclose(got, xla_row, atol=BF16_ATOL, rtol=0)
-            want = np.asarray(ref.logits(params, MODEL, prompt))[-1]
-            np.testing.assert_allclose(got, want, atol=BF16_ATOL, rtol=0)
-
-    @pytest.mark.parametrize("block", [16, 32])
-    def test_the_report_is_what_each_program_traced(self, monkeypatch, block):
-        """``lane_report()["prefill_attention"]`` says a bucket each what
-        the window layers (``b<bucket>``) and the indexed layers
-        (``b<bucket>_indexed``) attend with, and the programs ask the
-        same rule as they trace: under the mask past ``index_topk``
-        positions, the plain causal call up to it, nothing of the
-        kernel's under a query block."""
-        _fused_here(monkeypatch, block)
-        eng, _ = _build("kernel", jnp.bfloat16)
-        report = eng.lane_report()["prefill_attention"]
-        assert set(report) == {f"b{b}{tag}" for b in eng.prompt_buckets
-                               for tag in ("", "_indexed")}
-        for bucket in eng.prompt_buckets:
-            want = "fused" if bucket >= block else "xla"
-            assert report[f"b{bucket}"] == report[f"b{bucket}_indexed"] == want
-            full = ("prefill_chosen_attention" if bucket > TOPK
-                    else "prefill_causal_attention")
-            assert _prefill_kernels(eng, bucket) == (
-                [full, "prefill_window_attention", "prefill_window_attention", full]
-                if want == "fused" else [])
-
-    @pytest.mark.parametrize("lane,dtype", [
-        ("kernel", jnp.float32), ("gather", jnp.float32), ("gather", jnp.bfloat16)])
-    def test_float32_and_the_gather_lane_keep_the_xla_form(
-            self, monkeypatch, lane, dtype):
-        """An exactness engine and ``SELDON_TPU_PAGED_KERNEL=0`` (one
-        numeric regime; a mesh is refused for a latent pool altogether,
-        and the rule answers a mesh's ``kernel_lane=False`` like the
-        knob's)."""
-        _fused_here(monkeypatch)
-        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", LANES[lane]["SELDON_TPU_PAGED_KERNEL"])
-        eng, _ = _build(lane, dtype)
-        assert set(eng.lane_report()["prefill_attention"].values()) == {"xla"}
-        assert _prefill_kernels(eng, 64) == []
-        stream = eng.submit(np.asarray(PROMPTS[0], np.int32), max_new_tokens=1)
-        eng.run()
-        assert stream.error is None
-        stats = eng.engine_stats()
-        assert stats["prefill_padded_tokens"] > 0
-        assert stats["prefill_indexed_fused_positions"] == 0
-        assert stats["prefill_fused_positions"] == 0
-
-    def test_the_counter_is_what_the_annotation_says(self, monkeypatch):
-        """``prefill_indexed_fused_positions`` rises by the padded
-        positions of the calls whose ``seldon.wave.prefill`` says
-        ``indexed_fused``: every call of a bucket of a query block or
-        more, none of the bucket under it."""
-        _fused_here(monkeypatch, 32)
-        eng, _ = _build("kernel", jnp.bfloat16)
-        said = []
-        begin = eng._seam.begin_prefill
-
-        def spy(**stats):
-            said.append(stats)
-            return begin(**stats)
-
-        monkeypatch.setattr(eng._seam, "begin_prefill", spy)
-        streams = [eng.submit(np.asarray(p, np.int32), max_new_tokens=1)
-                   for p in PROMPTS]
-        eng.run()
-        assert all(s.error is None for s in streams)
-        assert sorted(c["bucket"] for c in said) == [16, 32, 64]
-        for call in said:
-            assert call["indexed_fused"] == call["fused"] == int(call["bucket"] >= 32)
-        stats = eng.engine_stats()
-        assert stats["prefill_padded_tokens"] == sum(c["padded"] for c in said)
-        assert stats["prefill_indexed_fused_positions"] == sum(
-            c["padded"] for c in said if c["indexed_fused"]) == 32 + 64
-        assert stats["prefill_fused_positions"] == 32 + 64
-
-
-class TestReferenceTail:
-    @pytest.mark.parametrize("tail,variant", [(1, ""), (7, ""), (7, "window_short")])
-    def test_the_last_layer_queried_at_the_tail_alone_gives_the_same_rows(
-            self, tail, variant):
-        """``logits(tail=)`` leaves the last layer's queries, attention
-        and FFN to the last rows (the cell's reference pass: a sixth less
-        work): the same logits there, past ``index_topk`` and the window."""
-        params = init_params(SPEC, SIZES, 5, dtype=jnp.float32)
-        seq = np.random.default_rng(7).integers(0, 64, size=40).tolist()
-        whole = np.asarray(ref.logits(params, MODEL, seq, variant=variant))
-        got = np.asarray(ref.logits(params, MODEL, seq, tail=tail, variant=variant))
-        assert got.shape == (tail, 64)
-        np.testing.assert_allclose(got, whole[-tail:], atol=2e-6, rtol=0)
-
-
-class TestShares:
-    def test_shares_add_up_to_the_uncut_layer(self):
-        """The routed parts of the four replicas that share a layer and
-        the shared expert counted once are the layer with every expert
-        held."""
-        whole = dict(MODEL, n_routed_experts=8, expert_offset=0)
-        spec, sizes = ref.spec_and_config(whole)
-        params = init_params(spec, sizes, 5, dtype=jnp.float32)
-        x = jnp.asarray(np.random.default_rng(1).normal(size=(19, 32)), jnp.float32)
-        pos = jnp.arange(19)
-        with jax.default_matmul_precision("highest"):
-            p = params["block_1"]
-            full, parts = ref.layer(p, whole, 1, x, pos)
-            total = 0.0
-            for r in range(4):
-                share = dict(p, **{k: p[k][2 * r:2 * r + 2] for k in (
-                    "experts_gate", "experts_up", "experts_down")})
-                _x, mine = ref.layer(share, whole, 1, x, pos, held=(2 * r, 2))
-                total = total + mine["routed"]
-                np.testing.assert_allclose(mine["shared"], parts["shared"], atol=1e-6)
-        np.testing.assert_allclose(total, parts["routed"], atol=1e-5)
-        assert float(jnp.abs(parts["routed"]).max()) > 1e-3
 
 
 def _fake_stream(slot=0):
@@ -579,8 +271,8 @@ class TestWindowAllocator:
     def test_pages_of_both_kinds_return_at_finish_eviction_and_abort(
             self, f32_engine):
         eng, _ = f32_engine
-        # finish
-        _serve(eng, PROMPTS[:2], new=6)
+        # finish (the streams TestLogits served: nothing new to compile)
+        _serve(eng, PROMPTS)
         assert _held_nothing(eng)
         # eviction: back to the queue with no page of either kind
         stream = eng.submit(np.asarray(PROMPTS[0], np.int32), max_new_tokens=8)
@@ -598,7 +290,7 @@ class TestWindowAllocator:
             eng.step()
         assert _held_nothing(eng)
 
-    def test_a_predicted_finisher_gives_its_window_pages_up_with_its_slot(self):
+    def test_a_predicted_finisher_gives_its_window_pages_up_with_its_slot(self, own_engine):
         """The serving loop's order (a wave launched before the one in
         flight is read): a stream whose budget the launched chunk
         exhausts gives up its slot and its window pages at once, a
@@ -606,7 +298,7 @@ class TestWindowAllocator:
         stream still decodes the reference's greedy tokens.  Only a
         stream in a slot ever holds window pages, so the pool — every
         slot's table full — is never short."""
-        eng, params = _build("gather", jnp.float32)
+        eng, params = own_engine("gather", jnp.float32)  # (its audit is turned off)
         eng._debug_invariants = False      # plan from predicted state
         news = (3, 9, 9, 9, 6, 6)          # two more streams than slots
         prompts = [PROMPTS[0]] * len(news)  # (one prefill bucket: few programs)
@@ -725,31 +417,3 @@ class TestFences:
         assert eng._prefix_cache_enabled is False
 
 
-class TestServed:
-    def test_streaming_lm_serves_the_arch_on_the_normal_path(self, monkeypatch):
-        """``arch="dots3_note"`` through ``STREAMING_LM``'s own
-        constructor, its JSON sizes and its prompt buckets."""
-        monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "0")
-        import json
-
-        spec_sizes = {
-            k: getattr(SPEC, k) for k in (
-                "num_experts", "experts_per_tok", "expert_width", "dense_width",
-                "experts_held", "expert_offset", "q_rank", "kv_rank", "nope_dim",
-                "rope_dim", "v_dim", "window", "win_heads", "win_q_rank",
-                "win_kv_rank", "win_nope_dim", "win_rope_dim", "win_v_dim",
-                "index_heads", "index_dim", "index_topk")}
-        spec_sizes["layer_kinds"] = list(SPEC.layer_kinds)
-        lm = StreamingLM(
-            arch="dots3_note", arch_sizes=json.dumps(spec_sizes), **SIZES,
-            max_len=MAX_LEN, page_size=PAGE, max_slots=2, steps_per_call=4,
-            max_new_tokens=6, seed=3, prompt_buckets="[8, 24, 64]")
-        assert lm.spec == SPEC
-        lm.load()
-        try:
-            assert lm.engine.prompt_buckets == [8, 24, 64]
-            out = lm.predict(np.asarray([PROMPTS[0]], np.int32), None)
-            assert np.asarray(out).shape == (1, 6)
-            assert lm.engine.lane_report()["cache_kinds"][2]["name"] == "window"
-        finally:
-            lm.shutdown()

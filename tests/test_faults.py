@@ -587,6 +587,15 @@ class TestR17Grammar:
         # budget spent: second call passes through untouched
         assert faults.corrupt_bytes("transport.corrupt", data) == data
 
+    @pytest.mark.parametrize("k", [1, 2, 64])
+    def test_corrupt_bytes_flips_k_distinct_bytes(self, k):
+        """Positions are drawn without replacement: a byte picked twice
+        would flip back, and an armed point inject nothing."""
+        faults.inject("transport.corrupt", times=1, k=k)
+        data = bytes(range(64))
+        out = faults.corrupt_bytes("transport.corrupt", data)
+        assert sum(a != b for a, b in zip(out, data)) == k
+
 
 class TestNanQuarantine:
     def test_injected_nan_quarantines_one_stream_wave_mates_bit_identical(

@@ -12,8 +12,6 @@ interpret mode), the XLA gather lane and the ring chunk.
 """
 
 import hashlib
-import os
-import sys
 from dataclasses import replace
 
 import numpy as np
@@ -22,25 +20,15 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import paged_harness as harness
+from paged_harness import LANES, MAX_LEN, PAGE, PROMPT, SLOTS, cached_suffix, prefill, run_program
 from seldon_core_tpu.models.generate import load_lm_params
-from seldon_core_tpu.models.paged import (
-    PagedEngine,
-    StreamingLM,
-    get_paged_lm_class,
-    paged_hbm_accounting,
-)
+from seldon_core_tpu.models.paged import PagedEngine, StreamingLM, paged_hbm_accounting
 from seldon_core_tpu.models.spec import GPT2, OLMOE, init_params, model_spec
 from seldon_core_tpu.ops import moe
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
-from reference import olmoe as ref  # noqa: E402
-
-MODEL = dict(hidden_size=64, num_hidden_layers=2, num_attention_heads=4,
-             num_experts=8, num_experts_per_tok=2, intermediate_size=32,
-             rms_norm_eps=1e-5, rope_theta=10000, vocab_size=97)
-SPEC, SIZES = ref.spec_and_config(MODEL)
-PAGE, MAX_LEN, SLOTS = 8, 64, 4
-PROMPT = np.random.default_rng(5).integers(0, 97, size=29).tolist()
+ref, MODEL = harness.MODELS["olmoe"]
+SPEC, SIZES = harness.spec_and_sizes("olmoe")
 
 # float32 compute against a float32 reference: what is left is the order
 # of sums (a grouped matmul, a paged softmax merged by the flash rule).
@@ -58,22 +46,8 @@ F32_ATOL = 1e-4
 # moves logits by 0.6-1.1 of it.
 BF16_ATOL = 0.05
 
-LANES = {
-    "kernel": {"SELDON_TPU_PAGED_KERNEL": "force", "SELDON_TPU_CHUNK_IMPL": "pool"},
-    "gather": {"SELDON_TPU_PAGED_KERNEL": "0", "SELDON_TPU_CHUNK_IMPL": "pool"},
-    "ring": {"SELDON_TPU_PAGED_KERNEL": "0", "SELDON_TPU_CHUNK_IMPL": "ring"},
-}
 
-
-def _engine(monkeypatch, lane, dtype=jnp.float32, spec=SPEC, params=None,
-            steps_per_call=1, **kw):
-    for k, v in LANES[lane].items():
-        monkeypatch.setenv(k, v)
-    if params is None:
-        params = init_params(SPEC, SIZES, 3, dtype=dtype)
-    return PagedEngine(
-        params, **SIZES, max_len=MAX_LEN, page_size=PAGE, max_slots=SLOTS,
-        steps_per_call=steps_per_call, dtype=dtype, spec=spec, **kw), params
+engines, own_engine = harness.fixtures(SPEC, SIZES)
 
 
 def _reference(params, tokens):
@@ -81,134 +55,47 @@ def _reference(params, tokens):
     return np.asarray(ref.logits(f32, MODEL, tokens))
 
 
-def _table(first_page, pages):
-    row = np.zeros((MAX_LEN // PAGE,), np.int32)
-    row[:pages] = np.arange(first_page, first_page + pages)
-    return row
-
-
-def _prefill(eng, prompt):
-    """The whole-prefill program on ``prompt`` in slot row 0 (pages 1..):
-    last-position logits; the engine's pools now hold its K/V."""
-    bucket = next(b for b in eng.prompt_buckets if b >= len(prompt))
-    tokens = np.zeros((1, bucket), np.int32)
-    tokens[0, :len(prompt)] = prompt
-    pages_h = eng._pages_pow2(-(-bucket // PAGE))
-    last, pk, pv, *hist = eng._build_prefill(bucket, 1)(
-        eng.params, *eng._kv_args(), jnp.asarray(tokens),
-        jnp.asarray([len(prompt)], jnp.int32),
-        jnp.asarray(_table(1, pages_h)[None, :pages_h]))
-    eng._store_kv(pk, pv)
-    return np.asarray(last[0]), (np.asarray(hist[0]) if hist else None)
-
-
-def _decode(eng, last, length, steps):
-    """``steps`` greedy decode steps of lane 0 through the one-step
-    chunk program: the tokens it chose and the logits after each."""
-    logits = jnp.zeros((SLOTS, SIZES["vocab_size"]), jnp.float32).at[0].set(last)
-    lengths = np.zeros((SLOTS,), np.int32)
-    lengths[0] = length
-    tables = np.zeros((SLOTS, MAX_LEN // PAGE), np.int32)
-    tables[0] = _table(1, MAX_LEN // PAGE)
-    done = np.ones((SLOTS,), bool)
-    done[0] = False
-    keys = eng._keys
-    toks, rows, moe_acc = [], [], None
-    for _ in range(steps):
-        horizon = eng._pages_pow2(-(-(int(lengths[0]) + 1) // PAGE))
-        out = eng._get_chunk(1, ((SLOTS, horizon),))(
-            eng.params, *eng._kv_args(), logits, jnp.asarray(lengths),
-            jnp.asarray(tables[:, :horizon]), keys, jnp.asarray(done),
-            jnp.zeros((SLOTS,), jnp.int32), jnp.full((SLOTS,), 99, jnp.int32),
-            jnp.zeros((SLOTS,), jnp.float32), jnp.zeros((SLOTS,), jnp.int32),
-            jnp.full((SLOTS,), -1, jnp.int32), jnp.arange(SLOTS, dtype=jnp.int32))
-        tok, pk, pv, logits, lengths_out, keys, _done, _emitted, moe_acc = out
-        eng._store_kv(pk, pv)
-        lengths = np.array(lengths_out)
-        toks.append(int(tok[0, 0]))
-        rows.append(np.asarray(logits[0]))
-    return toks, np.stack(rows), np.asarray(moe_acc)
-
-
-def _cached_suffix(eng, prompt, cached):
-    """Prefill ``prompt[:cached]`` whole, then ``prompt[cached:]`` with
-    the cached-suffix program over those pages: last-position logits."""
-    _prefill(eng, prompt[:cached])
-    suffix = prompt[cached:]
-    bucket = next(b for b in eng.prompt_buckets if b >= len(suffix))
-    rp, wp = eng._pages_pow2(cached // PAGE), -(-bucket // PAGE)
-    tokens = np.zeros((1, bucket), np.int32)
-    tokens[0, :len(suffix)] = suffix
-    full = _table(1, MAX_LEN // PAGE)
-    last, pk, pv, _hist = eng._build_prefill_cached(bucket, 1, rp)(
-        eng.params, *eng._kv_args(), jnp.asarray(tokens),
-        jnp.asarray([len(suffix)], jnp.int32), jnp.asarray([cached], jnp.int32),
-        jnp.asarray(full[None, :rp]),
-        jnp.asarray(full[None, cached // PAGE: cached // PAGE + wp]))
-    eng._store_kv(pk, pv)
-    return np.asarray(last[0])
-
-
-def _run(eng, program):
-    """``(served logits rows, the token sequence they are rows of, first
-    row's position)`` for one program."""
-    n = len(PROMPT)
-    if program == "prefill":
-        last, _hist = _prefill(eng, PROMPT)
-        return last[None], PROMPT, n - 1
-    if program == "cached":
-        return _cached_suffix(eng, PROMPT, 2 * PAGE)[None], PROMPT, n - 1
-    last, _hist = _prefill(eng, PROMPT)
-    toks, rows, _acc = _decode(eng, last, n, steps=6)
-    return rows, PROMPT + toks, n
-
-
 @pytest.mark.parametrize("program", ["prefill", "decode", "cached"])
 @pytest.mark.parametrize("lane", sorted(LANES))
-def test_logits_match_the_reference_f32(monkeypatch, lane, program):
-    eng, params = _engine(monkeypatch, lane)
-    try:
-        assert eng._kernel_active == (lane == "kernel")
-        rows, tokens, at = _run(eng, program)
-        want = _reference(params, tokens)[at: at + len(rows)]
-        assert np.abs(rows - want).max() < F32_ATOL
-    finally:
-        eng.close()
+def test_logits_match_the_reference_f32(engines, lane, program):
+    eng, params = engines(lane)
+    assert eng._kernel_active == (lane == "kernel")
+    rows, tokens, at = run_program(eng, program)
+    want = _reference(params, tokens)[at: at + len(rows)]
+    assert np.abs(rows - want).max() < F32_ATOL
 
 
 @pytest.mark.parametrize("experts", ["ragged_dot", "stream"])
 @pytest.mark.parametrize("program", ["prefill", "decode", "cached"])
 @pytest.mark.parametrize("lane", ["kernel", "gather"])
-def test_logits_match_the_reference_bf16(monkeypatch, lane, program, experts):
+def test_logits_match_the_reference_bf16(monkeypatch, engines, own_engine, lane,
+                                         program, experts):
     """The serving precision: weights at rest in bf16 (router and norm
     scales f32), bf16 pool and matmuls, f32 router; the experts through
     ``ragged_dot`` (what a CPU traces) and through the streaming kernel
-    (what a TPU traces at these rows: here under the interpreter)."""
+    (what a TPU traces at these rows: here under the interpreter: an
+    engine of its own, traced under the patch)."""
     if experts == "stream":
         monkeypatch.setattr(moe, "matmul_backend", lambda: "interpret")
-    eng, params = _engine(monkeypatch, lane, dtype=jnp.bfloat16)
-    try:
-        assert set(eng.lane_report()["expert_matmul"].values()) >= {experts}
-        assert params["block_0"]["experts_gate"].dtype == jnp.bfloat16
-        assert params["block_0"]["router"].dtype == jnp.float32
-        rows, tokens, at = _run(eng, program)
-        want = _reference(params, tokens)[at: at + len(rows)]
-        assert np.abs(rows - want).max() < BF16_ATOL * want.std()
-    finally:
-        eng.close()
+        eng, params = own_engine(lane, jnp.bfloat16)
+    else:
+        eng, params = engines(lane, jnp.bfloat16)
+    assert set(eng.lane_report()["expert_matmul"].values()) >= {experts}
+    assert params["block_0"]["experts_gate"].dtype == jnp.bfloat16
+    assert params["block_0"]["router"].dtype == jnp.float32
+    rows, tokens, at = run_program(eng, program)
+    want = _reference(params, tokens)[at: at + len(rows)]
+    assert np.abs(rows - want).max() < BF16_ATOL * want.std()
 
 
-def test_rope_and_the_prefix_cache(monkeypatch):
+def test_rope_and_the_prefix_cache(engines):
     """Positions are absolute, K is cached after RoPE: a suffix
     prefilled over cached pages (at any page-aligned cut) sees what a
     whole prefill sees, logit for logit."""
-    eng, _params = _engine(monkeypatch, "gather")
-    try:
-        whole, _hist = _prefill(eng, PROMPT)
-        for cached in (PAGE, 3 * PAGE):
-            assert np.abs(_cached_suffix(eng, PROMPT, cached) - whole).max() < 1e-5
-    finally:
-        eng.close()
+    eng, _params = engines("gather")
+    whole, _hist = prefill(eng, PROMPT)
+    for cached in (PAGE, 3 * PAGE):
+        assert np.abs(cached_suffix(eng, PROMPT, cached) - whole).max() < 1e-5
 
 
 def _bf16_router(h, w, k):
@@ -230,76 +117,68 @@ _ROUTE = moe.route
 
 @pytest.mark.parametrize("wrong", ["bf16_router", "renormalised_gates",
                                    "no_qk_norm", "dropped_expert"])
-def test_the_tolerance_fails_a_wrong_program(monkeypatch, wrong):
+def test_the_tolerance_fails_a_wrong_program(monkeypatch, own_engine, wrong):
     """What the f32 tolerance is for: each of these computes something
-    else than the source defines, and none stays inside it."""
+    else than the source defines, and none stays inside it (each traced
+    anew, on an engine of its own)."""
     spec = SPEC
     if wrong == "no_qk_norm":
-        from dataclasses import replace
-
         spec = replace(SPEC, qk_norm=False)
     else:
         monkeypatch.setattr(moe, "route", {
             "bf16_router": _bf16_router, "renormalised_gates": _renormalised,
             "dropped_expert": _dropped_last}[wrong])
-    eng, params = _engine(monkeypatch, "gather", spec=spec)
-    try:
-        rows, tokens, at = _run(eng, "decode")
-        want = _reference(params, tokens)[at: at + len(rows)]
-        assert np.abs(rows - want).max() > 10 * F32_ATOL
-    finally:
-        eng.close()
+    eng, params = own_engine("gather", spec=spec)
+    rows, tokens, at = run_program(eng, "decode")
+    want = _reference(params, tokens)[at: at + len(rows)]
+    assert np.abs(rows - want).max() > 10 * F32_ATOL
 
 
-def test_engine_serves_and_counts_routing(monkeypatch):
+def test_engine_serves_and_counts_routing(own_engine):
     """Through submit/step: greedy tokens equal the reference's
     teacher-forced argmax (f32), a repeat is admitted on the prefix
-    cache and answers the same, and the routing counters add up."""
-    eng, params = _engine(monkeypatch, "kernel", steps_per_call=4)
-    try:
-        first = eng.submit(np.asarray(PROMPT, np.int32), max_new_tokens=8)
-        eng.run()
-        toks = [int(t) for t in first.result]
-        want = _reference(params, PROMPT + toks[:-1])[len(PROMPT) - 1:]
-        assert toks == want.argmax(-1).tolist()
-        stats = eng.engine_stats(detail=True)
-        layers, k = MODEL["num_hidden_layers"], MODEL["num_experts_per_tok"]
-        # every prompt token and every fed-back token, top-k each, per layer
-        assert stats["moe_assignments"] == (len(PROMPT) + 8) * k * layers
-        assert sum(stats["moe_expert_hits"]) == stats["moe_assignments"]
-        # one lane decoding: exactly k experts hit per (layer, step)
-        assert stats["moe_layer_steps"] == 8 * layers
-        assert stats["moe_active_expert_steps"] == 8 * layers * k
-        assert stats["moe_load_max"] >= stats["moe_load_mean"] > 0
+    cache and answers the same, and the routing counters add up — from
+    zero: an engine of its own (the only one of four steps a call)."""
+    eng, params = own_engine("kernel", steps_per_call=4)
+    first = eng.submit(np.asarray(PROMPT, np.int32), max_new_tokens=8)
+    eng.run()
+    toks = [int(t) for t in first.result]
+    want = _reference(params, PROMPT + toks[:-1])[len(PROMPT) - 1:]
+    assert toks == want.argmax(-1).tolist()
+    stats = eng.engine_stats(detail=True)
+    layers, k = MODEL["num_hidden_layers"], MODEL["num_experts_per_tok"]
+    # every prompt token and every fed-back token, top-k each, per layer
+    assert stats["moe_assignments"] == (len(PROMPT) + 8) * k * layers
+    assert sum(stats["moe_expert_hits"]) == stats["moe_assignments"]
+    # one lane decoding: exactly k experts hit per (layer, step)
+    assert stats["moe_layer_steps"] == 8 * layers
+    assert stats["moe_active_expert_steps"] == 8 * layers * k
+    assert stats["moe_load_max"] >= stats["moe_load_mean"] > 0
 
-        again = eng.submit(np.asarray(PROMPT, np.int32), max_new_tokens=8)
-        eng.run()
-        assert [int(t) for t in again.result] == toks
-        assert eng.engine_stats()["prefix_hits"] == 1
-    finally:
-        eng.close()
+    again = eng.submit(np.asarray(PROMPT, np.int32), max_new_tokens=8)
+    eng.run()
+    assert [int(t) for t in again.result] == toks
+    assert eng.engine_stats()["prefix_hits"] == 1
 
 
-def test_stream_survives_evict_and_restore(monkeypatch):
+def test_stream_survives_evict_and_restore(own_engine):
     """K/V pages are all a preemption moves: an OLMoE stream evicted
-    mid-decode and re-admitted answers as one that never was."""
-    eng, _params = _engine(monkeypatch, "gather", steps_per_call=2)
-    try:
-        calm = eng.submit(np.asarray(PROMPT, np.int32), max_new_tokens=10)
-        eng.run()
-        stream = eng.submit(np.asarray(PROMPT[::-1], np.int32), max_new_tokens=10)
-        eng.step()
-        eng.step()
-        with eng._lock:
-            eng._evict_locked(stream)
-        eng.run()
-        fresh = eng.submit(np.asarray(PROMPT[::-1], np.int32), max_new_tokens=10)
-        eng.run()
-        assert eng.engine_stats()["evictions"] == 1
-        assert stream.result.tolist() == fresh.result.tolist()
-        assert len(calm.result) == 10
-    finally:
-        eng.close()
+    mid-decode and re-admitted answers as one that never was.  (An
+    engine of its own: it counts evictions from zero.)"""
+    eng, _params = own_engine("gather", steps_per_call=2)
+    calm = eng.submit(np.asarray(PROMPT, np.int32), max_new_tokens=10)
+    eng.run()
+    stream = eng.submit(np.asarray(PROMPT[::-1], np.int32), max_new_tokens=10)
+    eng.step()
+    eng.step()
+    with eng._lock:
+        eng._evict_locked(stream)
+    eng.run()
+    fresh = eng.submit(np.asarray(PROMPT[::-1], np.int32), max_new_tokens=10)
+    eng.run()
+    assert eng.engine_stats()["evictions"] == 1
+    assert stream.result.tolist() == fresh.result.tolist()
+    assert len(calm.result) == 10
 
 
 class TestFences:
@@ -332,7 +211,7 @@ class TestFences:
             model_spec("gpt2", num_experts=4)
 
 
-def test_weights_rest_in_bf16_and_are_counted_as_they_are(monkeypatch):
+def test_weights_rest_in_bf16_and_are_counted_as_they_are(own_engine):
     params = init_params(SPEC, SIZES, 1)
     kinds = {str(leaf.dtype) for leaf in jax.tree_util.tree_leaves(params)}
     assert kinds == {"bfloat16", "float32"}
@@ -340,12 +219,9 @@ def test_weights_rest_in_bf16_and_are_counted_as_they_are(monkeypatch):
            if jax.tree_util.tree_leaves(v)[0].dtype == jnp.float32}
     assert f32 == {"attn_norm", "ffn_norm", "q_norm", "k_norm", "router"}
     at_rest = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(params))
-    eng, _ = _engine(monkeypatch, "gather", dtype=jnp.bfloat16, params=params)
-    try:
-        assert eng.lane_report()["weight_bytes"] == at_rest
-        assert eng.lane_report()["arch"] == "olmoe"
-    finally:
-        eng.close()
+    eng, _ = own_engine("gather", jnp.bfloat16, params=params)  # seed 1's tree
+    assert eng.lane_report()["weight_bytes"] == at_rest
+    assert eng.lane_report()["arch"] == "olmoe"
     priced = paged_hbm_accounting(
         streams=2, ctx_len=64, d_model=64, num_layers=2, weight_bytes=at_rest)
     bare = paged_hbm_accounting(streams=2, ctx_len=64, d_model=64, num_layers=2)
@@ -369,7 +245,7 @@ THIRD_SPECS = {
 
 
 @pytest.mark.parametrize("which", sorted(THIRD_SPECS))
-def test_the_initialiser_follows_the_spec_not_a_name(monkeypatch, which):
+def test_the_initialiser_follows_the_spec_not_a_name(own_engine, which):
     spec = THIRD_SPECS[which]
     assert not spec.transformer_lm and GPT2.transformer_lm
     config = dict(SIZES, max_len=MAX_LEN)
@@ -384,13 +260,24 @@ def test_the_initialiser_follows_the_spec_not_a_name(monkeypatch, which):
     assert params["head"]["kernel"].dtype == want
     assert params["tok_embed"]["embedding"].dtype == want
     # and the engine's own program applies it
-    eng, _ = _engine(monkeypatch, "gather", dtype=jnp.bfloat16, spec=spec,
-                     params=params)
-    try:
-        last, *_ = _prefill(eng, PROMPT)
-        assert np.isfinite(last).all() and last.std() > 0.1
-    finally:
-        eng.close()
+    eng, _ = own_engine("gather", jnp.bfloat16, spec=spec, params=params)
+    last, _hist = prefill(eng, PROMPT)
+    assert np.isfinite(last).all() and last.std() > 0.1
+
+
+def test_a_second_draw_compiles_nothing_and_is_the_same_tree():
+    """``init_params`` draws through one jitted function a process (PR
+    44: it made the function anew on every call, and with it the 18
+    programs of a tree): a second draw at the same sizes finds every
+    program compiled, and the seed's tree is the seed's tree."""
+    from seldon_core_tpu.models.spec import _uniform
+
+    first = init_params(SPEC, SIZES, 6)
+    compiled = _uniform()._cache_size()
+    second = init_params(SPEC, SIZES, 6)
+    assert _uniform()._cache_size() == compiled > 0
+    same = jax.tree_util.tree_map(lambda a, b: bool((a == b).all()), first, second)
+    assert all(jax.tree_util.tree_leaves(same))
 
 
 # ---------------------------------------------------------------------------

@@ -30,6 +30,17 @@ D = H * HD
 RANK, SLOTS = 8, 8
 
 
+@pytest.fixture(scope="module", autouse=True)
+def the_compiler_as_it_serves():
+    """``tests/conftest.py`` turns most of XLA's optimisations off to
+    save the suite's compile time; here the compiler's verdict at the
+    cells' shapes is the point, so this file compiles as a server does."""
+    before = jax.config.read("jax_disable_most_optimizations")
+    jax.config.update("jax_disable_most_optimizations", False)
+    yield
+    jax.config.update("jax_disable_most_optimizations", before)
+
+
 @pytest.fixture(scope="module")
 def one_chip():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")

@@ -9,21 +9,14 @@ cached-suffix program computes at ``cached_lens = 0``) and against a
 prompt resumed at a page boundary.
 """
 
-import os
-import sys
-
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
-from seldon_core_tpu.models.paged import PagedEngine
-from seldon_core_tpu.models.spec import GPT2, init_params
+import paged_harness as harness
+from paged_harness import PAGE, PROMPT, fused_here
 from seldon_core_tpu.ops import kernels
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
-from reference import deepseek_v3, longcat_flash, olmoe  # noqa: E402
 
 
 def plain_causal(q, k, v, scale):
@@ -33,23 +26,6 @@ def plain_causal(q, k, v, scale):
     s = np.where(np.tril(np.ones(s.shape[-2:], bool)), s, -np.inf)
     w = np.exp(s - s.max(-1, keepdims=True))
     return np.einsum("bhqk,bkhd->bqhd", w / w.sum(-1, keepdims=True), v)
-
-
-def pallas_calls(fn, *args):
-    """``(name, output shapes)`` of every ``pallas_call`` ``fn`` traces."""
-    found = []
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                found.append((eqn.params["name"] if "name" in eqn.params
-                              else eqn.params["name_and_src_info"].name,
-                              [tuple(o.aval.shape) for o in eqn.outvars]))
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
-
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return found
 
 
 def operands(seg, d_qk, d_v, dtype, batch=2, heads=2, seed=0):
@@ -101,7 +77,7 @@ class TestKernel:
         """``benchmarks/layer_metrics/moe_work.py`` takes every
         ``pallas_kernel`` of two dims or fewer for a grouped matmul."""
         q, k, v = operands(32, 64, 64, jnp.bfloat16)
-        calls = pallas_calls(lambda q, k, v: kernels.causal_attention(
+        calls = harness.pallas_calls(lambda q, k, v: kernels.causal_attention(
             q, k, v, 0.125, block_q=32, block_k=32), q, k, v)
         assert calls == [("prefill_causal_attention", [(4, 32, 64)])]
 
@@ -127,7 +103,7 @@ class TestKernel:
         q, k, v = operands(96, 64, 64, jnp.float32)
         with pytest.raises(ValueError, match="VMEM"):
             kernels.causal_attention(q, k, v, 0.125, block_q=32, block_k=32)
-        assert pallas_calls(
+        assert harness.pallas_calls(
             lambda q, k, v: kernels.flash_attention(q, k, v, causal=True), q, k, v) == []
         np.testing.assert_allclose(
             np.asarray(kernels.flash_attention(q, k, v, causal=True)),
@@ -168,100 +144,58 @@ def test_the_rule_is_a_function_of_what_a_trace_sees(
         seg, d_qk, d_v, dtype, width, kernel_lane) == want
 
 
-def test_the_multi_head_block_never_asks_the_rule(monkeypatch):
+def test_the_multi_head_block_never_asks_the_rule(monkeypatch, own_engine):
     """GPT-2's and OLMoE's from-zero prefill is XLA over the segment on
     every lane: the rule is the latent block's alone."""
     def refuse(*a, **kw):
         raise AssertionError("the multi-head block asked the rule")
     monkeypatch.setattr(kernels, "prefill_attention_impl", refuse)
-    _fused_here(monkeypatch)
-    eng = _engine(monkeypatch, "gpt2", jnp.bfloat16, lane="force")
-    try:
-        assert set(eng.lane_report()["prefill_attention"].values()) == {"xla"}
-        _from_zero(eng, [PROMPT])
-    finally:
-        eng.close()
+    fused_here(monkeypatch)
+    eng = own_engine("gpt2", jnp.bfloat16, "kernel")
+    assert set(eng.lane_report()["prefill_attention"].values()) == {"xla"}
+    _from_zero(eng, [PROMPT])
 
 
 # ---- the engines --------------------------------------------------------
 
-PAGE, MAX_LEN, SLOTS = 8, 64, 4
-PROMPT = np.random.default_rng(5).integers(0, 97, size=29).tolist()
 OTHER = np.random.default_rng(6).integers(0, 97, size=21).tolist()
 
-OLMOE_MODEL = dict(hidden_size=64, num_hidden_layers=2, num_attention_heads=4,
-                   num_experts=8, num_experts_per_tok=2, intermediate_size=32,
-                   rms_norm_eps=1e-5, rope_theta=10000, vocab_size=97)
-GIGACHAT_MODEL = dict(
-    hidden_size=64, num_hidden_layers=3, num_attention_heads=4, vocab_size=97,
-    n_routed_experts=4, n_routed_experts_published=8, expert_offset=2,
-    num_experts_per_tok=2, moe_intermediate_size=32,
-    first_k_dense_replace=1, intermediate_size=96, n_shared_experts=1,
-    n_group=4, topk_group=2, routed_scaling_factor=2.5, norm_topk_prob=True,
-    q_lora_rank=24, kv_lora_rank=16,
-    qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=12, rope_theta=100000,
-    rope_scaling=dict(factor=64, original_max_position_embeddings=16,
-                      beta_fast=32, beta_slow=1, mscale=1, mscale_all_dim=1),
-    rms_norm_eps=1e-6)
-LONGCAT_MODEL = dict(
-    hidden_size=64, num_layers=2, num_attention_heads=4, vocab_size=97,
-    n_routed_experts=4, n_routed_experts_published=8, expert_offset=2,
-    zero_expert_num=4, moe_topk=4, expert_ffn_hidden_size=32, ffn_hidden_size=96,
-    routed_scaling_factor=6, q_lora_rank=24, kv_lora_rank=16,
-    qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, rope_theta=10000000,
-    mla_scale_q_lora=True, mla_scale_kv_lora=True, rms_norm_eps=1e-5)
-
-
-def _spec_and_sizes(arch):
-    if arch == "gpt2":
-        return GPT2, dict(vocab_size=97, d_model=64, num_layers=2, num_heads=4)
-    ref, model = {"olmoe": (olmoe, OLMOE_MODEL),
-                  "gigachat": (deepseek_v3, GIGACHAT_MODEL),
-                  "longcat": (longcat_flash, LONGCAT_MODEL)}[arch]
-    return ref.spec_and_config(model)
-
-
 ARCHS = ["gpt2", "olmoe", "gigachat", "longcat"]
-
-
 LATENT = ("gigachat", "longcat")
-
-
-def _fused_here(monkeypatch, block=16):
-    """Toy sizes: on the kernel lane (``lane="force"``: the Pallas
-    interpreter) the rule answers ``"fused"`` for a latent engine's bf16
-    from-zero prefill of any bucket of at least ``block`` positions."""
-    monkeypatch.setattr(kernels, "CAUSAL_BLOCK_Q", block)
-    monkeypatch.setattr(kernels, "CAUSAL_BLOCK_K", block)
 
 
 def _impl(arch, lane):
     """What a from-zero prefill of ``arch`` attends with on ``lane``."""
-    return "fused" if arch in LATENT and lane == "force" else "xla"
+    return "fused" if arch in LATENT and lane == "kernel" else "xla"
 
 
 def _impls(eng):
     return set(eng.lane_report()["prefill_attention"].values())
 
 
-def _engine(monkeypatch, arch, dtype, lane="0", seed=4, **kw):
-    monkeypatch.delenv("SELDON_TPU_CHUNK_IMPL", raising=False)
-    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", lane)
-    spec, sizes = _spec_and_sizes(arch)
-    params = init_params(spec, dict(sizes, max_len=MAX_LEN), seed, dtype=dtype)
-    return PagedEngine(params, **sizes, max_len=MAX_LEN, page_size=PAGE,
-                       max_slots=SLOTS, steps_per_call=1, dtype=dtype,
-                       spec=spec, **kw)
+def _build(arch, dtype, lane="gather", seed=4):
+    """An engine of ``arch`` on ``lane`` (the kernel asked for, or not,
+    and the chunk left to follow: off the kernel lane the multi-head
+    pools decode in the ring chunk, the latent ones in the pool chunk)."""
+    if lane == "gather" and arch not in LATENT:
+        lane = "ring"
+    return harness.build(*harness.spec_and_sizes(arch), lane, dtype, seed=seed)
 
 
-def _rows(prompts, first_pages):
-    full = np.zeros((len(prompts), MAX_LEN // PAGE), np.int32)
-    for i, first in enumerate(first_pages):
-        full[i] = np.arange(first, first + MAX_LEN // PAGE)
-    return full
+@pytest.fixture
+def own_engine(monkeypatch):
+    """``own_engine(arch, dtype, lane) -> engine``: no two cases here
+    serve one (arch, type, lane) under the same blocks, so each builds
+    its own, after whatever it patches; closed after the case."""
+    for get in harness.own(monkeypatch, _build):
+        yield lambda *key: get(*key)[0]
 
 
-def _written(eng, pages):
+def _written(eng, prompts):
+    """The pool rows of the pages ``prompts`` fill in their slot rows."""
+    rows = harness.tables(eng, len(prompts))
+    pages = np.concatenate([rows[i, :-(-len(p) // PAGE)]
+                            for i, p in enumerate(prompts)])
     pools = [eng.pages_k] + ([] if eng.pages_v is None else [eng.pages_v])
     return [np.asarray(p[:, pages], np.float32) for p in pools]
 
@@ -269,20 +203,8 @@ def _written(eng, pages):
 def _from_zero(eng, prompts):
     """The from-zero program on ``prompts`` (slot rows from page 1 and
     page 9): last-position logits and the pool rows it wrote."""
-    k = len(prompts)
-    bucket = next(b for b in eng.prompt_buckets if b >= max(map(len, prompts)))
-    tokens = np.zeros((k, bucket), np.int32)
-    for i, p in enumerate(prompts):
-        tokens[i, :len(p)] = p
-    pages_h = eng._pages_pow2(-(-bucket // PAGE))
-    table = _rows(prompts, [1, 9][:k])[:, :pages_h]
-    last, pk, pv, *_hist = eng._build_prefill(bucket, k)(
-        eng.params, *eng._kv_args(), jnp.asarray(tokens),
-        jnp.asarray([len(p) for p in prompts], jnp.int32), jnp.asarray(table))
-    eng._store_kv(pk, pv)
-    pages = np.concatenate([table[i, :-(-len(p) // PAGE)]
-                            for i, p in enumerate(prompts)])
-    return np.asarray(last), _written(eng, pages)
+    last, _hist = harness.prefill_group(eng, prompts)
+    return last, _written(eng, prompts)
 
 
 def _through_the_table(eng, prompts, cached=0):
@@ -290,25 +212,7 @@ def _through_the_table(eng, prompts, cached=0):
     table it is what every from-zero prefill traced before PR 33 (every
     cached page gathered, scored and masked out); at a page boundary it
     resumes prompts whose first ``cached`` tokens are already written."""
-    k = len(prompts)
-    suffixes = [p[cached:] for p in prompts]
-    bucket = next(b for b in eng.prompt_buckets if b >= max(map(len, suffixes)))
-    tokens = np.zeros((k, bucket), np.int32)
-    for i, p in enumerate(suffixes):
-        tokens[i, :len(p)] = p
-    rp = eng._pages_pow2(max(1, cached // PAGE) if cached else -(-bucket // PAGE))
-    wp = -(-bucket // PAGE)
-    full = _rows(prompts, [1, 9][:k])
-    first = cached // PAGE
-    last, pk, pv, *_hist = eng._build_prefill_cached(bucket, k, rp)(
-        eng.params, *eng._kv_args(), jnp.asarray(tokens),
-        jnp.asarray([len(p) for p in suffixes], jnp.int32),
-        jnp.full((k,), cached, jnp.int32), jnp.asarray(full[:, :rp]),
-        jnp.asarray(full[:, first:first + wp]))
-    eng._store_kv(pk, pv)
-    pages = np.concatenate([full[i, :-(-len(p) // PAGE)]
-                            for i, p in enumerate(prompts)])
-    return np.asarray(last), _written(eng, pages)
+    return harness.resume_group(eng, prompts, cached), _written(eng, prompts)
 
 
 def _close(got, want, share, rows_share):
@@ -322,25 +226,22 @@ def _close(got, want, share, rows_share):
 
 
 @pytest.mark.parametrize("arch", ARCHS)
-def test_the_segment_alone_is_the_parents_form_f32(monkeypatch, arch):
+def test_the_segment_alone_is_the_parents_form_f32(own_engine, arch):
     """Float32, XLA on both sides: leaving the masked cache half out
     changes the order of no sum that matters (seen: 0 to 5e-7)."""
-    eng = _engine(monkeypatch, arch, jnp.float32)
-    try:
-        assert _impls(eng) == {"xla"}
-        want = _through_the_table(eng, [PROMPT, OTHER])
-        got = _from_zero(eng, [PROMPT, OTHER])
-        _close(got, want, 1e-5, 1e-6)
-    finally:
-        eng.close()
+    eng = own_engine(arch, jnp.float32, "gather")
+    assert _impls(eng) == {"xla"}
+    want = _through_the_table(eng, [PROMPT, OTHER])
+    got = _from_zero(eng, [PROMPT, OTHER])
+    _close(got, want, 1e-5, 1e-6)
 
 
-LANES = pytest.mark.parametrize("lane", ["0", "force"], ids=["gather", "kernel"])
+LANES = pytest.mark.parametrize("lane", ["gather", "kernel"])
 
 
 @LANES
 @pytest.mark.parametrize("arch", ARCHS)
-def test_a_from_zero_prefill_is_the_parents_form_bf16(monkeypatch, arch, lane):
+def test_a_from_zero_prefill_is_the_parents_form_bf16(monkeypatch, own_engine, arch, lane):
     """The serving precision.  XLA over the segment alone rounds as the
     parent's form did (the multi-head engines on both lanes, every
     engine on the gather lane); a latent engine's kernel and its XLA
@@ -349,143 +250,122 @@ def test_a_from_zero_prefill_is_the_parents_form_bf16(monkeypatch, arch, lane):
     0.05 (the engines' own bf16 tolerance against their float32
     references), and a written row by one or two bf16 steps (2^-8 of
     its value each)."""
-    _fused_here(monkeypatch)
-    eng = _engine(monkeypatch, arch, jnp.bfloat16, lane=lane)
-    try:
-        assert _impls(eng) == {_impl(arch, lane)}
-        want = _through_the_table(eng, [PROMPT, OTHER])
-        got = _from_zero(eng, [PROMPT, OTHER])
-        _close(got, want, 0.05, 2.0 ** -6)
-    finally:
-        eng.close()
+    fused_here(monkeypatch)
+    eng = own_engine(arch, jnp.bfloat16, lane)
+    assert _impls(eng) == {_impl(arch, lane)}
+    want = _through_the_table(eng, [PROMPT, OTHER])
+    got = _from_zero(eng, [PROMPT, OTHER])
+    _close(got, want, 0.05, 2.0 ** -6)
 
 
 @LANES
 @pytest.mark.parametrize("arch", ARCHS)
 def test_a_prompt_prefilled_whole_is_one_resumed_at_a_page_boundary(
-        monkeypatch, arch, lane):
+        monkeypatch, own_engine, arch, lane):
     """The from-zero program against the cached-suffix program (XLA,
     over pages the from-zero program wrote) on the same prompt, on the
     gather lane (XLA on both sides: ``SELDON_TPU_PAGED_KERNEL=0`` keeps
     one numeric regime) and on the whole-pool kernel lane (the latent
     engines' from-zero program is the fused kernel there)."""
-    _fused_here(monkeypatch)
-    eng = _engine(monkeypatch, arch, jnp.bfloat16, lane=lane)
-    try:
-        assert _impls(eng) == {_impl(arch, lane)}
-        whole, _rows_whole = _from_zero(eng, [PROMPT])
-        _from_zero(eng, [PROMPT[:2 * PAGE]])
-        resumed, _rows_resumed = _through_the_table(eng, [PROMPT], cached=2 * PAGE)
-        assert np.abs(whole - resumed).max() <= 0.05 * resumed.std()
-    finally:
-        eng.close()
+    fused_here(monkeypatch)
+    eng = own_engine(arch, jnp.bfloat16, lane)
+    assert _impls(eng) == {_impl(arch, lane)}
+    whole, _rows_whole = _from_zero(eng, [PROMPT])
+    _from_zero(eng, [PROMPT[:2 * PAGE]])
+    resumed, _rows_resumed = _through_the_table(eng, [PROMPT], cached=2 * PAGE)
+    assert np.abs(whole - resumed).max() <= 0.05 * resumed.std()
 
 
 @LANES
 @pytest.mark.parametrize("arch", ["gpt2", "gigachat"])
-def test_the_engine_says_what_ran(monkeypatch, arch, lane):
+def test_the_engine_says_what_ran(monkeypatch, own_engine, arch, lane):
     """``prefill_fused_positions`` rises beside ``prefill_padded_tokens``
     by the padded positions of the calls the kernel served; the lane
     report names each bucket's implementation; served tokens are valid
     either way."""
-    _fused_here(monkeypatch, block=32)
-    eng = _engine(monkeypatch, arch, jnp.bfloat16, lane=lane)
+    fused_here(monkeypatch, block=32)
+    eng = own_engine(arch, jnp.bfloat16, lane)
     fused = _impl(arch, lane) == "fused"
-    try:
-        report = eng.lane_report()["prefill_attention"]
-        assert set(report) == {f"b{b}" for b in eng.prompt_buckets}
-        # the bucket of 16 is under the (patched) query block
-        assert report["b16"] == "xla"
-        assert report["b32"] == report["b64"] == _impl(arch, lane)
-        streams = [eng.submit(np.asarray(p, np.int32), max_new_tokens=3)
-                   for p in (PROMPT, OTHER, PROMPT[:5])]
-        eng.run()
-        assert all(s.error is None and len(s.result) == 3 for s in streams)
-        stats = eng.engine_stats()
-        # a group of two in the bucket of 32 and one prompt in the bucket
-        # of 16
-        assert stats["prefill_padded_tokens"] == 2 * 32 + 16
-        assert stats["prefill_fused_positions"] == (64 if fused else 0)
-        # no indexer: nothing attends under a selection's mask (PR 43)
-        assert stats["prefill_indexed_fused_positions"] == 0
-    finally:
-        eng.close()
+    report = eng.lane_report()["prefill_attention"]
+    assert set(report) == {f"b{b}" for b in eng.prompt_buckets}
+    # the bucket of 16 is under the (patched) query block
+    assert report["b16"] == "xla"
+    assert report["b32"] == report["b64"] == _impl(arch, lane)
+    streams = [eng.submit(np.asarray(p, np.int32), max_new_tokens=3)
+               for p in (PROMPT, OTHER, PROMPT[:5])]
+    eng.run()
+    assert all(s.error is None and len(s.result) == 3 for s in streams)
+    stats = eng.engine_stats()
+    # a group of two in the bucket of 32 and one prompt in the bucket
+    # of 16
+    assert stats["prefill_padded_tokens"] == 2 * 32 + 16
+    assert stats["prefill_fused_positions"] == (64 if fused else 0)
+    # no indexer: nothing attends under a selection's mask (PR 43)
+    assert stats["prefill_indexed_fused_positions"] == 0
 
 
 @pytest.mark.parametrize("arch", ["olmoe", "longcat"])
-def test_a_spec_without_an_indexer_counts_no_indexed_positions(monkeypatch, arch):
+def test_a_spec_without_an_indexer_counts_no_indexed_positions(monkeypatch, own_engine, arch):
     """``prefill_indexed_fused_positions`` and the report's
     ``b<bucket>_indexed`` entries are an indexed spec's alone (dots3:
     ``tests/test_dots3_paged.py TestIndexedPrefill``)."""
-    _fused_here(monkeypatch, block=32)
-    eng = _engine(monkeypatch, arch, jnp.bfloat16, lane="force")
+    fused_here(monkeypatch, block=32)
+    eng = own_engine(arch, jnp.bfloat16, "kernel")
     said = []
     begin = eng._seam.begin_prefill
     monkeypatch.setattr(eng._seam, "begin_prefill",
                         lambda **stats: (said.append(stats), begin(**stats))[1])
-    try:
-        assert set(eng.lane_report()["prefill_attention"]) == {
-            f"b{b}" for b in eng.prompt_buckets}
-        stream = eng.submit(np.asarray(PROMPT, np.int32), max_new_tokens=1)
-        eng.run()
-        assert stream.error is None
-        stats = eng.engine_stats()
-        assert [c["indexed_fused"] for c in said] == [0]
-        assert [c["fused"] for c in said] == [int(_impl(arch, "force") == "fused")]
-        assert stats["prefill_padded_tokens"] == 32
-        assert stats["prefill_indexed_fused_positions"] == 0
-    finally:
-        eng.close()
+    assert set(eng.lane_report()["prefill_attention"]) == {
+        f"b{b}" for b in eng.prompt_buckets}
+    stream = eng.submit(np.asarray(PROMPT, np.int32), max_new_tokens=1)
+    eng.run()
+    assert stream.error is None
+    stats = eng.engine_stats()
+    assert [c["indexed_fused"] for c in said] == [0]
+    assert [c["fused"] for c in said] == [int(_impl(arch, "kernel") == "fused")]
+    assert stats["prefill_padded_tokens"] == 32
+    assert stats["prefill_indexed_fused_positions"] == 0
 
 
 def _traced_kernels(eng, bucket):
     """The ``pallas_call`` s of the from-zero program of ``bucket``."""
     i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
     pages = eng._pages_pow2(-(-bucket // PAGE))
-    return pallas_calls(
+    return harness.pallas_calls(
         eng._build_prefill(bucket, 1).__wrapped__, eng.params,
         *eng._kv_args(), i32(1, bucket), i32(1), i32(1, pages))
 
 
 @pytest.mark.parametrize("arch", ["gpt2", "gigachat", "longcat"])
-def test_the_report_is_what_each_program_traced(monkeypatch, arch):
+def test_the_report_is_what_each_program_traced(monkeypatch, own_engine, arch):
     """The engine derives its report from the rule on the host; the
     programs ask the rule as they trace.  Both agree, bucket by bucket:
     a ``prefill_causal_attention`` call an attention (three dims out)
     where the report says "fused", none where it says "xla"."""
-    _fused_here(monkeypatch, block=32)
-    eng = _engine(monkeypatch, arch, jnp.bfloat16, lane="force")
-    try:
-        spec = eng.spec
-        attentions = eng.module.num_layers * (2 if arch == "longcat" else 1)
-        for bucket in eng.prompt_buckets:
-            calls = [c for c in _traced_kernels(eng, bucket)
-                     if c[0] == "prefill_causal_attention"]
-            if eng.lane_report()["prefill_attention"][f"b{bucket}"] == "fused":
-                assert calls == [("prefill_causal_attention", [
-                    (eng.module.num_heads, bucket, spec.v_dim)])] * attentions
-            else:
-                assert calls == []
-    finally:
-        eng.close()
+    fused_here(monkeypatch, block=32)
+    eng = own_engine(arch, jnp.bfloat16, "kernel")
+    spec = eng.spec
+    attentions = eng.module.num_layers * (2 if arch == "longcat" else 1)
+    for bucket in eng.prompt_buckets:
+        calls = [c for c in _traced_kernels(eng, bucket)
+                 if c[0] == "prefill_causal_attention"]
+        if eng.lane_report()["prefill_attention"][f"b{bucket}"] == "fused":
+            assert calls == [("prefill_causal_attention", [
+                (eng.module.num_heads, bucket, spec.v_dim)])] * attentions
+        else:
+            assert calls == []
 
 
-def test_an_f32_engine_and_a_cached_suffix_never_take_the_kernel(monkeypatch):
-    _fused_here(monkeypatch)
-    eng = _engine(monkeypatch, "gigachat", jnp.float32, lane="force")
-    try:
-        assert _impls(eng) == {"xla"}
-        assert _traced_kernels(eng, 16) == []
-    finally:
-        eng.close()
-    eng = _engine(monkeypatch, "gigachat", jnp.bfloat16, lane="force")
-    try:
-        assert _impls(eng) == {"fused"}
-        _from_zero(eng, [PROMPT[:2 * PAGE]])
-        i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
-        cached = pallas_calls(
-            eng._build_prefill_cached(16, 1, 2).__wrapped__, eng.params,
-            *eng._kv_args(), i32(1, 16), i32(1), i32(1), i32(1, 2), i32(1, 2))
-        assert cached == []
-    finally:
-        eng.close()
+def test_an_f32_engine_and_a_cached_suffix_never_take_the_kernel(monkeypatch, own_engine):
+    fused_here(monkeypatch)
+    eng = own_engine("gigachat", jnp.float32, "kernel")
+    assert _impls(eng) == {"xla"}
+    assert _traced_kernels(eng, 16) == []
+    eng = own_engine("gigachat", jnp.bfloat16, "kernel")
+    assert _impls(eng) == {"fused"}
+    _from_zero(eng, [PROMPT[:2 * PAGE]])
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    cached = harness.pallas_calls(
+        eng._build_prefill_cached(16, 1, 2).__wrapped__, eng.params,
+        *eng._kv_args(), i32(1, 16), i32(1), i32(1), i32(1, 2), i32(1, 2))
+    assert cached == []

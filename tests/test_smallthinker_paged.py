@@ -19,14 +19,14 @@ logits are read where the engine calls it.
 
 import os
 import sys
+from functools import partial
 
 import numpy as np
 import pytest
 
 import jax.numpy as jnp
 
-from seldon_core_tpu.models.paged import PagedEngine
-from seldon_core_tpu.models.spec import init_params
+import paged_harness as harness
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
 from reference import smallthinker as ref  # noqa: E402
@@ -66,92 +66,32 @@ F32_ATOL = 1e-4
 # move the logits by 0.4 and more
 BF16_ATOL, BF16_SEED = 0.1, 7
 
-LANES = {
-    "kernel": {"SELDON_TPU_PAGED_KERNEL": "force"},
-    "gather": {"SELDON_TPU_PAGED_KERNEL": "0"},
-}
-
 
 def _build(lane, dtype, seed=3, **kw):
-    saved = {k: os.environ.get(k) for k in ("SELDON_TPU_PAGED_KERNEL",
-                                            "SELDON_TPU_CHUNK_IMPL",
-                                            "SELDON_TPU_PAGED_DEBUG")}
-    os.environ.pop("SELDON_TPU_CHUNK_IMPL", None)
-    os.environ.update(LANES[lane], SELDON_TPU_PAGED_DEBUG="1")
-    try:
-        params = init_params(SPEC, SIZES, seed, dtype=dtype)
-        kw.setdefault("steps_per_call", 1)
-        eng = PagedEngine(params, **SIZES, max_len=MAX_LEN, page_size=PAGE,
-                          max_slots=SLOTS, dtype=dtype, spec=SPEC, **kw)
-    finally:
-        for k, v in saved.items():
-            os.environ.pop(k, None) if v is None else os.environ.update({k: v})
-    return eng, params
+    """``(engine, params)``, the allocator audited at every chunk
+    boundary."""
+    with harness.environment(SELDON_TPU_PAGED_DEBUG="1"):
+        return harness.build(SPEC, SIZES, lane, dtype, seed=seed, max_len=MAX_LEN,
+                             page_size=PAGE, max_slots=SLOTS, **kw)
 
 
 @pytest.fixture(scope="module", params=["kernel", "gather"])
-def f32_engine(request):
-    return _build(request.param, jnp.float32)
+def module_f32(request):
+    eng, params = _build(request.param, jnp.float32)
+    yield eng, params
+    eng.close()
 
 
-@pytest.fixture(scope="module")
-def bf16_engine():
-    return _build("kernel", jnp.bfloat16, seed=BF16_SEED)
+# a case's view: the lane's environment held while it steps the engine
+# (the lane's knob is read again at every trace: ``harness.tracing``)
+@pytest.fixture
+def f32_engine(module_f32, monkeypatch):
+    harness.hold(monkeypatch, module_f32[0])
+    return module_f32
 
 
-@pytest.fixture(scope="module")
-def chunk_engine():
-    """Four steps a call: a lane passes the window inside a chunk."""
-    return _build("kernel", jnp.float32, steps_per_call=4)
-
-
-def _serve(eng, prompts, new=NEW):
-    """Serve ``prompts`` together, a token a step: per prompt ``(tokens,
-    rows)`` with ``rows[i]`` the engine's logits after ``i`` tokens
-    (``rows[0]``: the prefill program's)."""
-    first = {}
-    build = eng._build_prefill
-
-    def spy(bucket, k):
-        fn = build(bucket, k)
-
-        def call(*args, **kw):
-            out = fn(*args, **kw)
-            lens = np.asarray(args[4])
-            for row, n in zip(np.asarray(out[0]), lens):
-                first[int(n)] = row
-            return out
-        return call
-
-    eng._build_prefill = spy
-    eng._prefill_jit.clear()
-    try:
-        streams = [eng.submit(np.asarray(p, np.int32), max_new_tokens=new)
-                   for p in prompts]
-        slots, rows = {}, [[] for _ in prompts]
-        for _step in range(new):
-            eng.step()
-            for i, s in enumerate(streams):
-                if s.slot is not None:
-                    slots[i] = s.slot
-                rows[i].append(np.asarray(eng._logits[slots[i]]))
-        assert all(s.event.is_set() for s in streams)
-    finally:
-        eng._build_prefill = build
-        eng._prefill_jit.clear()
-    return [(s.result.tolist(), np.stack([first[len(p)]] + r[:-1]))
-            for s, p, r in zip(streams, prompts, rows)]
-
-
-_SERVED_ONE = {}
-
-
-def _served_one(eng):
-    """``_serve(eng, PROMPTS[:1])``, once an engine: the wrong-program
-    cases all read the same served rows."""
-    if id(eng) not in _SERVED_ONE:
-        _SERVED_ONE[id(eng)] = _serve(eng, PROMPTS[:1])[0]
-    return _SERVED_ONE[id(eng)]
+_serve = partial(harness.serve, new=NEW)
+_served_one = partial(harness.served_one, prompt=PROMPTS[0], new=NEW)
 
 
 def _reference(params, prompt, tokens, **kw):
@@ -160,13 +100,7 @@ def _reference(params, prompt, tokens, **kw):
     return rows[len(prompt) - 1:]
 
 
-def _held_nothing(eng):
-    stats = eng.engine_stats()
-    with eng._lock:
-        eng._check_invariants_locked()
-    return (stats["full_pages_held"], stats["window_pages_held"],
-            stats["pool_pages_used"]) == (0, 0, 0) and not (
-                eng._wtables.any() or eng._wbase.any())
+_held_nothing = harness.held_nothing
 
 
 GQA_COUNTERS = ("gqa_kv_rows_read", "gqa_kv_rows_cached", "window_rows_read",
@@ -203,33 +137,6 @@ class TestLogits:
         assert 0 < after["moe_local_assignments"] < after["moe_assignments"]
         assert after["moe_held_active_expert_steps"] > 0
         assert after["moe_held_pass_rows"] == 64
-
-    def test_bfloat16_prefill_and_decode(self, bf16_engine):
-        eng, params = bf16_engine
-        for prompt, (tokens, rows) in zip(PROMPTS, _serve(eng, PROMPTS)):
-            want = _reference(params, prompt, tokens)
-            np.testing.assert_allclose(rows, want, atol=BF16_ATOL, rtol=0)
-        assert _held_nothing(eng)
-
-    def test_a_lane_passes_the_window_inside_a_chunk(self, chunk_engine):
-        """Four steps a call from a prompt of 3 and one of 6: both reach
-        the window's 8 positions between two host visits, so the window
-        table's base moves only after the chunk that slid past it; the
-        tokens are the one-step engine's, which the logits tests hold."""
-        eng, params = chunk_engine
-        prompts = [PROMPTS[1], PROMPTS[0][:6]]
-        streams = [eng.submit(np.asarray(p, np.int32), max_new_tokens=NEW)
-                   for p in prompts]
-        while not all(s.event.is_set() for s in streams):
-            eng.step()
-        for prompt, stream in zip(prompts, streams):
-            tokens = stream.result.tolist()
-            want = _reference(params, prompt, tokens)
-            # greedy: each served token is the reference's top-1 given
-            # the same prefix
-            assert tokens == want.argmax(-1).tolist()
-        assert _held_nothing(eng)
-        assert eng.engine_stats()["window_pages_released"] > 0
 
 
 # what ``correct`` must be able to tell, held here on logits: each wrong
