@@ -140,7 +140,10 @@ def prefill_position_bytes(spec, d_model: int, vocab_size: int,
     were 3.0 GB by the compiler's count, 2.4 GB by this one):
 
     * float32 logits at every position (ROADMAP S4) and the float32
-      residual stream beside its normed bf16 copy;
+      residual stream beside its normed bf16 copy — under a residual
+      of ``spec.hc_mult`` rows (ops/hyper.py) those rows twice, the
+      ones a sub-layer's mixing reads and the ones it writes, beside
+      the one row the sub-layer reads and its normed copy;
     * the wider of the attention's rows — q, k, v in bf16 and the
       attended values in float32; the naive latent path makes K and V
       per head — and the FFN's: a dense layer's hidden rows (float32
@@ -154,6 +157,8 @@ def prefill_position_bytes(spec, d_model: int, vocab_size: int,
     from seldon_core_tpu.ops import moe
 
     kept = 4 * vocab_size + 6 * d_model
+    if spec.hc_mult:
+        kept += 2 * 4 * spec.hc_mult * d_model
     if spec.double_layer:
         # the shortcut's float32 input and output wait out a half-layer
         kept += 8 * d_model
@@ -437,13 +442,31 @@ def _build_modules():
                 mod.param("experts_up", init, (held, d_model, f), rest),
                 mod.param("experts_down", init, (held, f, d_model), rest))
 
-    def _ffn_grouped(mod, x, token_mask):
+    def _mixed(mod, name, x, sublayer):
+        """One sub-layer under a residual of several rows (ops/hyper.py):
+        ``x`` ``(n, B, L, d)`` float32; ``sublayer(h)`` takes the row
+        ``H_pre X`` ``(B, L, d)`` and gives its output (no ``x + ...``)
+        and whatever else it returns.  The mixing's parameters are the
+        sub-layer's own, under ``name``: ``phi``, ``bias``, ``scale``,
+        float32."""
+        from seldon_core_tpu.ops import hyper
+
+        spec = mod.spec
+        h, h_post, h_res = hyper.hyper_pre(
+            x, HyperMix(name=name)(x), iters=spec.hc_sinkhorn_iters,
+            eps=spec.hc_eps, lo=spec.hc_res_min, hi=spec.hc_res_max)
+        y, *rest = sublayer(h)
+        return (hyper.hyper_post(x, y, h_post, h_res), *rest)
+
+    def _ffn_grouped(mod, x, token_mask, mixed=False):
         """:func:`_ffn` for a spec whose router is DeepSeek-V3's: a
         dense SwiGLU layer (``mod.routed_layer`` false; its histogram
         is zeros, so the layers' stack keeps one shape), or sigmoid
         group-limited routing over ``spec.num_experts`` with this
         replica's ``spec.held`` experts computed (ops/moe.py
-        ``expert_ffn_held``) beside a shared expert."""
+        ``expert_ffn_held``) beside a shared expert.  ``mixed`` (a
+        residual of several rows): the FFN's output alone comes back,
+        float32, for the caller to write through its mixing."""
         from seldon_core_tpu.ops import moe
 
         spec = mod.spec
@@ -472,7 +495,8 @@ def _build_modules():
             hist = moe.expert_histogram(
                 experts, e,
                 None if token_mask is None else token_mask.reshape(-1))
-        return x + out.reshape(x.shape).astype(x.dtype), (hist,)
+        out = out.reshape(x.shape).astype(x.dtype)
+        return (out if mixed else x + out), (hist,)
 
     def _latent_block(mod, x, pool, tables, lengths, layer, positions,
                       token_mask, window=None):
@@ -493,6 +517,14 @@ def _build_modules():
                 kind=mod.kind, window=window, counted=token_mask)
             x, hist = _ffn_grouped(mod, x, token_mask)
             return (x, (mod.kind.name, *rows), None, *hist, *read)
+        if mod.spec.hc_mult:
+            # ``x`` is the token's rows, stream-major (n, B, L, d): each
+            # sub-layer reads a mix of them and writes back through one
+            x, row = _mixed(mod, "hc_attn", x, lambda h: _latent_attention(
+                mod, h, pool, tables, lengths, layer, positions, mixed=True))
+            x, hist = _mixed(mod, "hc_ffn", x, lambda h: _ffn_grouped(
+                mod, h, token_mask, mixed=True))
+            return (x, row, None, *hist)
         x, row = _latent_attention(mod, x, pool, tables, lengths, layer,
                                    positions)
         x, hist = _ffn_grouped(mod, x, token_mask)
@@ -554,7 +586,8 @@ def _build_modules():
         return out, hist
 
     def _latent_attention(mod, x, pool, tables, lengths, layer, positions,
-                          sub=None, kind=None, window=None, counted=None):
+                          sub=None, kind=None, window=None, counted=None,
+                          mixed=False):
         """``x + attention(norm(x))`` by latent attention (MLA): ``(x,
         row)`` with ``row`` ``(B, L, W)`` this call's cache rows
         ``[RMSNorm(c_kv) ; RoPE(k_r) ; 0]`` (``W`` = ``spec.cache_width``:
@@ -605,7 +638,11 @@ def _build_modules():
         kernel; where it selected, the chosen set's cached members) and
         the rows the page loop streamed under a mask (the lengths again:
         over the rows read, what a kernel that skipped pages could
-        save), over the lanes ``counted`` ``(B, 1)`` keeps."""
+        save), over the lanes ``counted`` ``(B, 1)`` keeps.
+
+        ``mixed`` (a residual of several rows, :func:`_mixed`): ``x`` is
+        the row the mixing read, and the attention's output alone comes
+        back in its place, for the caller to write through the mixing."""
         from dataclasses import replace as _replace
 
         from seldon_core_tpu.models.spec import (
@@ -835,7 +872,8 @@ def _build_modules():
             gate = jax.nn.sigmoid(proj("attn_gate", heads, y).astype(jnp.float32))
             attn = (attn.astype(jnp.float32) * gate[..., None]).astype(mod.dtype)
         attn = attn.reshape(batch, seg_len, heads * vdim)
-        x = x + proj("attn_proj", d_model, attn)
+        out = proj("attn_proj", d_model, attn)
+        x = out if mixed else x + out
         if kind is None:
             return x, row
         rows = (row, key_row) if topk else (row,)
@@ -1005,6 +1043,10 @@ def _build_modules():
         )(tokens)
         if lm.spec.residual_f32:
             x = x.astype(jnp.float32)  # and every ``x + ...`` after it
+        if lm.spec.hc_mult:
+            # a residual of several rows, stream-major (n, B, L, d):
+            # every row starts as the token's embedding
+            x = jnp.broadcast_to(x[None], (lm.spec.hc_mult, *x.shape))
         if lm.spec.rope:
             return x  # positions enter in every block, on q and k
         pos = nn.Embed(
@@ -1016,13 +1058,33 @@ def _build_modules():
     def _head(lm, x, new_k, new_v, hists):
         """Final norm and unembedding; ``(logits, K, V)`` stacked over
         layers, and a routed spec's ``int32[layers, E]`` assignment
-        histogram as a fourth value."""
+        histogram as a fourth value.  A residual of several rows leaves
+        as their sum."""
+        if lm.spec.hc_mult:
+            x = x.sum(axis=0)
         x = _norm(lm.spec, "final_norm")(x)
         logits = _dense(lm.precision, lm.vocab_size, lm.dtype, "head",
                         lm.spec)(x)
         out = (logits.astype(jnp.float32), jnp.stack(new_k),
                None if new_v[0] is None else jnp.stack(new_v))  # one pool: no V
         return out + (jnp.stack(hists),) if hists else out
+
+    class HyperMix(nn.Module):
+        """The mixing parameters of one sub-layer under a residual of
+        several rows (ops/hyper.py), float32 at rest and in use: ``phi``
+        ``(2n + n^2, n d)`` (a coefficient a row), ``bias`` and ``scale``
+        (alpha_pre, alpha_post, alpha_res)."""
+
+        @nn.compact
+        def __call__(self, x):
+            from seldon_core_tpu.ops import hyper
+
+            n, d_model = x.shape[0], x.shape[-1]
+            k = hyper.coefficients(n)
+            init = nn.initializers.normal(0.02)
+            return {"phi": self.param("phi", init, (k, n * d_model), jnp.float32),
+                    "bias": self.param("bias", init, (k,), jnp.float32),
+                    "scale": self.param("scale", init, (3,), jnp.float32)}
 
     class PagedTransformerBlock(nn.Module):
         """TransformerBlock whose attention reads a paged K/V pool.
@@ -3308,6 +3370,8 @@ class PagedEngine:
                  .addressable_shards[0].device.memory_stats()
                  or {}).get("bytes_limit")
         resting = self._weight_bytes // self.tp_degree
+        # mixed sub-layers of a residual of several rows: two a layer
+        self._hyper_sublayers = 2 * num_layers if spec.hc_mult else 0
         self.prefill_positions_max = prefill_positions_max(
             None if limit is None
             else int(limit) - resting - self._pool_shard_bytes,
@@ -3596,6 +3660,13 @@ class PagedEngine:
                           # lane-steps, summed over the layers (a latent
                           # pool: decode_kv_tokens x layers; 0 otherwise)
                           "latent_kv_tokens": 0,
+                          # a residual of several rows (spec.hc_mult,
+                          # ops/hyper.py; 0 otherwise): padded positions
+                          # x mixed sub-layers (two a layer) the prefill
+                          # calls and the decode steps ran — a call's
+                          # k x bucket, a step's max_slots lanes
+                          "hyper_prefill_positions": 0,
+                          "hyper_decode_positions": 0,
                           # a spec with layer kinds (0 otherwise): what
                           # its selection and its windows read (the
                           # chunk's counter row, _sparse_step) and the
@@ -6567,6 +6638,8 @@ class PagedEngine:
                     self._counters["prefill_fused_positions"] += k * bucket
                 if indexed_fused:
                     self._counters["prefill_indexed_fused_positions"] += k * bucket
+                self._counters["hyper_prefill_positions"] += (
+                    k * bucket * self._hyper_sublayers)
             return self._prefill_group_call(bucket, k, group, use_cache)
         finally:
             self._seam.end_prefill()
@@ -7785,6 +7858,8 @@ class PagedEngine:
         the mesh degrees the engine got (not what was requested), the
         chunk implementation, and whether decode attention runs the
         Pallas kernel."""
+        from seldon_core_tpu.ops import hyper as _hyper
+
         kv_heads, head_dim = self.spec.head_sizes(
             self.module.num_heads, self.module.d_model)
         return {
@@ -7808,6 +7883,13 @@ class PagedEngine:
             # layer has two), not layers
             "cache_layers": self.spec.cache_layers(self.module.num_layers),
             "experts_held": self.spec.held if self.spec.routed else 0,
+            # a residual of several rows: how many, which form of the
+            # mixing the programs traced and its parameters' bytes
+            **({"hyper_streams": self.spec.hc_mult,
+                "hyper_mix": _hyper.hyper_impl(self.spec.hc_mult),
+                "hyper_weight_bytes": self._hyper_sublayers * _hyper.weight_bytes(
+                    self.spec.hc_mult, self.module.d_model)}
+               if self.spec.hc_mult else {}),
             # grouped-query heads: the K/V heads and head width the
             # pool's row is made of, and what the router reads
             **({"kv_heads": kv_heads, "head_dim": head_dim,
@@ -8138,6 +8220,11 @@ class PagedEngine:
                 # row count of its grouped matmuls in a trace; 0 unless
                 # this replica holds a share
                 "moe_held_pass_rows": self._held_pass_rows(self.max_slots),
+                # a residual of several rows: how many, and the Sinkhorn
+                # iterations of each mixed sub-layer (0: one row)
+                "hyper_streams": self.spec.hc_mult,
+                "hyper_sinkhorn_iters": (
+                    self.spec.hc_sinkhorn_iters if self.spec.hc_mult else 0),
             }
             outputs = self.spec.router_outputs
             moe_expert_hits = (  # cumulative assignments per router output
@@ -9002,6 +9089,9 @@ class PagedEngine:
                     self._pages_of(len0 + t * grow) for t in range(n))
             # every launched step walks every lane's table, live or not
             self._counters["decode_page_slots"] += steps * wave.step_slots
+            # ... and every lane's residual through every mixed sub-layer
+            self._counters["hyper_decode_positions"] += (
+                steps * self.max_slots * self._hyper_sublayers)
             t_now, m_now = _time.time(), _time.monotonic()
             # the lanes AS LAUNCHED: a predicted finisher's slot may
             # hold a joiner of the next wave by now

@@ -33,8 +33,8 @@ replica holds** (``experts_held`` from ``expert_offset``, of
 expert-parallel layer computes its own experts' part and nothing
 stands in for the rest).
 
-``GPT2``, ``OLMOE``, ``DEEPSEEK_V3``, ``LONGCAT_FLASH``, ``DOTS3_NOTE``
-and ``SMALLTHINKER`` are the values served (the fifth added layer KINDS: ``layer_kinds``,
+``GPT2``, ``OLMOE``, ``DEEPSEEK_V3``, ``LONGCAT_FLASH``, ``DOTS3_NOTE``,
+``SMALLTHINKER`` and ``XING4_0`` are the values served (the fifth added layer KINDS: ``layer_kinds``,
 ``attn_kind``, ``cache_kinds`` — layers that differ in their attention
 and a cache of one pool a row kind); a new architecture is a new value (and new branches where the
 block reads a field it has not met), not a new block.  The fourth value
@@ -62,6 +62,13 @@ normed input, the rows that feed q, k and v), ``expert_act="relu"`` and
 ``norm_topk`` / ``experts_held`` behind a softmax router.  Latent
 attention's head widths (``nope_dim``, ``rope_dim``, ``v_dim``) and
 ranks are fields: none follows from ``d_model``.
+
+The seventh value (``XING4_0``) changed the RESIDUAL: ``hc_mult`` rows
+of ``d_model`` a token in place of one (manifold-constrained
+hyper-connections; ``ops/hyper.py`` has the equations), every attention
+and every FFN reading a learned, input-dependent mix of the rows and
+writing back through another.  The rows live inside a program: the
+cache row, the pool and the allocator are DeepSeek-V3's.
 """
 
 from __future__ import annotations
@@ -223,6 +230,18 @@ class ModelSpec:
     # layer ("" = as ``positions`` says | "none": no positional encoding
     # at all; the window layers keep ``positions``)
     full_positions: str = ""
+    # ---- the residual (Xing4.0: manifold-constrained hyper-connections,
+    # ops/hyper.py).  hc_mult rows of d_model a token (0 = the one row
+    # every other arch carries); each sub-layer reads sigmoid(H_pre) of
+    # them, and writes H_res X + 2 sigmoid(H_post) x its output, H_res the
+    # exp of its clipped logits after hc_sinkhorn_iters row-then-column
+    # normalisations with hc_eps in each divisor (and in the RMSNorm of
+    # the flattened rows the coefficients are read from)
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_min: float = -30.0
+    hc_res_max: float = 30.0
 
     @property
     def routed(self) -> bool:
@@ -485,9 +504,26 @@ SMALLTHINKER = ModelSpec(
     window=4096, full_positions="none",
 )
 
+# XingChen-AGI/Xing4.0-29B-A4B config.json (model_type xing4_0): the
+# DeepSeek-V3 block at other sizes — MLA with q rank 768, latent 512 + 64
+# rope, heads of 128 + 64 against values of 128, YaRN factor 64 over
+# 4,096 at theta 10,000; 2 leading dense SwiGLU layers of 9,216, then 64
+# sigmoid-routed experts of 1,024 in one group (noaux_tc), top-4
+# renormalised and scaled 2.0, beside one shared expert; RMSNorm eps
+# 1e-6 — under a residual of hc_mult = 4 rows mixed round every
+# attention and every FFN (hc_sinkhorn_iters 20, hc_eps 1e-6,
+# mhc_h_res_clamp_min / _max -30 / 30)
+XING4_0 = replace(
+    DEEPSEEK_V3, name="xing4_0", num_experts=64, experts_per_tok=4,
+    expert_width=1024, rope_theta=10_000.0, q_rank=768, v_dim=128,
+    dense_layers=2, dense_width=9216, n_group=1, topk_group=1,
+    routed_scale=2.0, hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6,
+    hc_res_min=-30.0, hc_res_max=30.0,
+)
+
 _ARCHS = {"gpt2": GPT2, "olmoe": OLMOE, "deepseek_v3": DEEPSEEK_V3,
           "longcat_flash": LONGCAT_FLASH, "dots3_note": DOTS3_NOTE,
-          "smallthinker": SMALLTHINKER}
+          "smallthinker": SMALLTHINKER, "xing4_0": XING4_0}
 # the sizes any routed arch has; a replica's share of the experts; those
 # only DeepSeek-V3's expert layer and attention have; and the two every
 # arch has
@@ -513,13 +549,17 @@ _DOTS3_SIZES = _LATENT_SIZES + (
 # share of the experts behind a softmax router
 _SMALLTHINKER_SIZES = _SHARE_SIZES + (
     "kv_heads", "head_dim", "layer_kinds", "window")
+# ... and those of a residual of several rows over DeepSeek-V3's block
+_HYPER_SIZES = ("hc_mult", "hc_sinkhorn_iters", "hc_eps", "hc_res_min",
+                "hc_res_max")
 # the sizes an arch has beside the expert sizes and the two every arch
 # has: what ``model_spec`` lets a caller set, by the arch's name (a share
 # of the experts and layer kinds belong to the archs whose block was
 # built for them, latent or not)
 _OWN_SIZES = {"gpt2": (), "olmoe": (), "deepseek_v3": _DEEPSEEK_SIZES,
               "longcat_flash": _LONGCAT_SIZES, "dots3_note": _DOTS3_SIZES,
-              "smallthinker": _SMALLTHINKER_SIZES}
+              "smallthinker": _SMALLTHINKER_SIZES,
+              "xing4_0": _DEEPSEEK_SIZES + _HYPER_SIZES}
 _SIZES = tuple(dict.fromkeys(
     _EXPERT_SIZES + sum(_OWN_SIZES.values(), ()) + ("rope_theta", "norm_eps")))
 # a size that may be given as 0 and mean it, for an arch that takes
@@ -554,7 +594,7 @@ def model_spec(arch: str = "gpt2", **sizes: Any) -> ModelSpec:
     if given:
         floats = ("rope_theta", "norm_eps", "routed_scale", "rope_factor",
                   "rope_beta_fast", "rope_beta_slow", "rope_mscale_all_dim",
-                  "win_rope_theta")
+                  "win_rope_theta", "hc_eps", "hc_res_min", "hc_res_max")
         spec = replace(spec, **{
             k: (float(v) if k in floats
                 else tuple(str(x) for x in v) if k == "layer_kinds" else int(v))
@@ -567,6 +607,15 @@ def model_spec(arch: str = "gpt2", **sizes: Any) -> ModelSpec:
         raise ValueError(
             f"kv_heads {spec.kv_heads} and head_dim {spec.head_dim}: "
             "grouped-query heads set both")
+    if spec.hc_mult == 1 or spec.hc_mult < 0:
+        raise ValueError(
+            f"hc_mult {spec.hc_mult}: a residual of one row has nothing to "
+            "mix (a stream count is 0 or at least 2)")
+    if spec.hc_mult and (spec.hc_sinkhorn_iters < 1
+                         or spec.hc_res_min >= spec.hc_res_max):
+        raise ValueError(
+            f"hc_sinkhorn_iters {spec.hc_sinkhorn_iters}, clamp "
+            f"[{spec.hc_res_min}, {spec.hc_res_max}]")
     if spec.routed and not 0 < spec.experts_per_tok <= spec.router_outputs:
         raise ValueError(
             f"experts_per_tok {spec.experts_per_tok} of {spec.router_outputs} "
@@ -773,7 +822,10 @@ def init_params(spec: ModelSpec, config: Dict[str, int], seed: int,
     itself; an ``embedding``
     ±sqrt(3) (unit variance); any other leaf is a matrix, ±sqrt(3 /
     fan_in) with the fan-in its second-to-last dim, experts' included
-    (unit-variance outputs).  A leaf's stream is keyed by its path, not
+    (unit-variance outputs) — its last for a ``phi`` (a residual of
+    several rows' mixing, ops/hyper.py: the matrix rests transposed, a
+    coefficient a row; its ``scale``, alpha, in [0.5, 1.5) and its
+    ``bias`` within ±0.1 then move every coefficient by tenths).  A leaf's stream is keyed by its path, not
     its place in the tree.
 
     Where the spec scales its low-rank paths (``mla_lora_scale``), the
@@ -812,7 +864,8 @@ def init_params(spec: ModelSpec, config: Dict[str, int], seed: int,
             lo, hi = 0.5, 1.5
         else:
             hi = (0.1 if name == "bias" or name.endswith("_bias")
-                  else (3.0 if name == "embedding" else 3.0 / leaf.shape[-2]) ** 0.5)
+                  else (3.0 if name == "embedding" else 3.0 / leaf.shape[
+                      -1 if name == "phi" else -2]) ** 0.5)
             lo = -hi
         if name == "score_bias" and spec.score == "softmax_bias":
             lo, hi = -1.0 / leaf.shape[0], 1.0 / leaf.shape[0]

@@ -600,6 +600,22 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
                          "seldon_tpu_engine_latent_kv_tokens_total",
                          "cached latent rows read by decode lane-steps, "
                          "over all layers (a latent pool; 0 otherwise)"),
+    # a residual of several rows (PR 45, ops/hyper.py): the positions its
+    # mixing ran; 0 on any other engine
+    "hyper_prefill_positions": (
+        "counter", "seldon_tpu_engine_hyper_prefill_positions_total",
+        "padded positions x mixed sub-layers the prefill calls ran (a "
+        "residual of several rows; 0 otherwise)"),
+    "hyper_decode_positions": (
+        "counter", "seldon_tpu_engine_hyper_decode_positions_total",
+        "lanes x mixed sub-layers the decode steps ran (a residual of "
+        "several rows; 0 otherwise)"),
+    "hyper_streams": (
+        "gauge", "seldon_tpu_engine_hyper_streams",
+        "rows of a token's residual (0: the one row of every other arch)"),
+    "hyper_sinkhorn_iters": (
+        "gauge", "seldon_tpu_engine_hyper_sinkhorn_iters",
+        "Sinkhorn iterations of each mixed sub-layer (0: one row)"),
     # a spec with layer kinds (PR 38): its selection's and its windows'
     # reads, and the two allocators' pages; 0 on any other engine
     "index_keys_scored": (

@@ -25,7 +25,7 @@ from seldon_core_tpu.models.spec import GPT2, init_params
 from seldon_core_tpu.ops import kernels
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
-from reference import deepseek_v3, longcat_flash, olmoe  # noqa: E402
+from reference import deepseek_v3, longcat_flash, olmoe, xing4  # noqa: E402
 
 PAGE, MAX_LEN, SLOTS = 8, 64, 4
 PROMPT = np.random.default_rng(5).integers(0, 97, size=29).tolist()
@@ -68,6 +68,20 @@ MODELS = {
         routed_scaling_factor=6, q_lora_rank=24, kv_lora_rank=16,
         qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, rope_theta=10000000,
         mla_scale_q_lora=True, mla_scale_kv_lora=True, rms_norm_eps=1e-5)),
+    # Xing4.0: the "gigachat" entry's ranks and heads under a residual of
+    # 4 rows (20 Sinkhorn iterations, clamp +-30), 8 experts top-2 in one
+    # group, every one held, 1 dense + 2 expert layers
+    "xing4": (xing4, dict(
+        hidden_size=64, num_hidden_layers=3, num_attention_heads=4, vocab_size=97,
+        n_routed_experts=8, num_experts_per_tok=2, moe_intermediate_size=32,
+        first_k_dense_replace=1, intermediate_size=96, n_shared_experts=1,
+        n_group=1, topk_group=1, routed_scaling_factor=2.0, norm_topk_prob=True,
+        q_lora_rank=24, kv_lora_rank=16,
+        qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=12, rope_theta=10000,
+        rope_scaling=dict(factor=64, original_max_position_embeddings=16,
+                          beta_fast=32, beta_slow=1, mscale=1, mscale_all_dim=1),
+        rms_norm_eps=1e-6, hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6,
+        mhc_h_res_clamp_min=-30, mhc_h_res_clamp_max=30)),
 }
 
 

@@ -398,7 +398,11 @@ class TestEndToEndCapture:
             eng = lm.engine
             s = eng.submit(np.arange(5, dtype=np.int32) % 64,
                            max_new_tokens=4, puid="e2e-err-1")
-            eng.step()
+            # the serving loop is the engine's one stepper: a step() from
+            # here races it for the donated pool once a compile outlasts
+            # the loop's 0.5 s poll ("Buffer has been deleted or donated")
+            lm._wake.set()
+            assert s.event.wait(timeout=300)
             eng.fail_stream(s, RuntimeError("boom"))
             lm._maybe_capture(
                 [s], tags={}, meta={"puid": "e2e-err-1"}, request_seed=9,

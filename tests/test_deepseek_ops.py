@@ -360,13 +360,15 @@ def test_stream_survives_evict_and_restore(own_engine):
     assert stream.result.tolist() == fresh.result.tolist()
 
 
-@pytest.mark.parametrize("arch", ["deepseek_v3", "longcat_flash"])
+@pytest.mark.parametrize("arch", ["deepseek_v3", "longcat_flash", "xing4_0"])
 class TestFences:
     def _make(self, arch, **kw):
-        # (the small LongCat-Flash of PR 32: the other latent pool, which
-        # what refuses one refuses by the same cases)
+        # (the small LongCat-Flash of PR 32 and the small Xing4.0 of PR
+        # 45: the other latent pools, which what refuses one refuses by
+        # the same cases)
         spec, sizes = harness.spec_and_sizes(
-            "gigachat" if arch == "deepseek_v3" else "longcat")
+            {"deepseek_v3": "gigachat", "longcat_flash": "longcat",
+             "xing4_0": "xing4"}[arch])
         return PagedEngine(
             init_params(spec, sizes, 0, dtype=jnp.float32), **sizes,
             max_len=MAX_LEN, page_size=PAGE, max_slots=SLOTS, spec=spec, **kw)
@@ -427,8 +429,8 @@ class TestFences:
         with pytest.raises(ValueError, match="has no"):
             model_spec("olmoe", kv_rank=16)
         with pytest.raises(ValueError, match="has no"):
-            model_spec(arch, **({"zero_experts": 4} if arch == "deepseek_v3"
-                                else {"n_group": 4}))
+            model_spec(arch, **({"n_group": 4} if arch == "longcat_flash"
+                                else {"zero_experts": 4}))
         with pytest.raises(ValueError, match="experts_held"):
             model_spec("deepseek_v3", experts_held=8, expert_offset=250)
         with pytest.raises(ValueError, match="groups"):

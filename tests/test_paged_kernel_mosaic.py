@@ -613,3 +613,95 @@ def test_grouped_causal_kernel_compiles_at_smallthinker_s_shapes(
     assert f"bf16[{k * 28},{bucket},128]" in text
     assert kernels.prefill_attention_impl(
         bucket, 128, 128, jnp.bfloat16, 0, True) == "fused"
+
+
+# ---------------------------------------------------------------------------
+# Xing4.0 (PR 45): the mixing's two kernels, and the whole LM's programs
+# at the configuration's widths
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("positions", [4096, 128])
+def test_the_mixing_s_kernels_compile_at_xing4_s_widths(monkeypatch, one_chip, mosaic,
+                                                        positions):
+    """Mosaic takes ``hyper_pre_mix`` and ``hyper_post_mix`` at 4 rows of
+    3,584: a 4,096-position prefill call and a 128-lane decode step."""
+    from seldon_core_tpu.ops import hyper
+
+    monkeypatch.setattr(hyper, "backend", lambda: "tpu")
+    n, c = 4, 3584
+    kw = dict(iters=20, eps=1e-6, lo=-30.0, hi=30.0)
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = {"phi": shape((24, n * c)), "bias": shape((24,)), "scale": shape((3,))}
+    pre = jax.jit(lambda x, p: hyper.hyper_pre(x, p, **kw)).lower(
+        shape((n, positions, c)), params).compile()
+    post = jax.jit(hyper.hyper_post, donate_argnums=0).lower(
+        shape((n, positions, c)), shape((positions, c), jnp.bfloat16),
+        shape((positions, n)), shape((positions, n, n))).compile()
+    assert "tpu_custom_call" in pre.as_text() and "tpu_custom_call" in post.as_text()
+    # the rows are rewritten where they rest: no second copy of them
+    assert post.memory_analysis().temp_size_in_bytes < n * positions * c * 4 // 2
+
+
+def test_xing4_s_programs_compile_and_the_prefill_cap_s_count_holds(
+        monkeypatch, one_chip, mosaic):
+    """The whole LM at the configuration's sizes (abstract weights: 7.95
+    GB as they rest), a 4,096-position prefill from zero and a 128-lane
+    decode step over the (6, 9217, 64, 640) pool: both compile, every
+    layer runs its latent / prefill kernel, its grouped matmuls and the
+    mixing's pair, and the prefill's temporaries and logits are within a
+    third of ``prefill_position_bytes``'s count (339 KB a position)."""
+    import json
+
+    from seldon_core_tpu.models import paged
+    from seldon_core_tpu.models.spec import declared_tree, model_spec
+    from seldon_core_tpu.ops import hyper, moe
+
+    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
+    monkeypatch.setattr(hyper, "backend", lambda: "tpu")
+    monkeypatch.setattr(moe, "matmul_backend", lambda: "tpu")
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "configs",
+                           "xing4.0-29b-a4b.json")) as f:
+        cfg = json.load(f)
+    served = {p["name"]: p["value"]
+              for p in cfg["deployment"]["predictors"][0]["graph"]["parameters"]}
+    spec = model_spec(served["arch"], **json.loads(served["arch_sizes"]))
+    sizes = dict(vocab_size=int(served["vocab_size"]), d_model=int(served["d_model"]),
+                 num_layers=int(served["num_layers"]), num_heads=int(served["num_heads"]))
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    tree = declared_tree(spec, dict(sizes, max_len=int(served["max_len"])), jnp.bfloat16)
+    resting = sum(leaf.size * leaf.dtype.itemsize
+                  for leaf in jax.tree_util.tree_leaves(tree))
+    assert abs(resting - 7.95e9) < 0.02e9
+    params = jax.tree_util.tree_map(lambda leaf: shape(leaf.shape, leaf.dtype), tree)
+    lm = paged.get_paged_lm_class()(dtype=jnp.bfloat16, spec=spec, decode_kernel=True,
+                                    max_len=int(served["max_len"]), **sizes)
+    pool = shape((6, int(served["num_pages"]), 64, 640), jnp.bfloat16)
+
+    def run(params, tokens, positions, pool, tables, lengths):
+        return lm.apply({"params": params}, tokens, positions, pool, None, tables,
+                        lengths, token_mask=jnp.ones(tokens.shape, bool))
+
+    def compiled(batch, seg, width):
+        i32 = jnp.int32
+        return jax.jit(run).lower(
+            params, shape((batch, seg), i32), shape((batch, seg), i32), pool,
+            shape((batch, width), i32), shape((batch,), i32)).compile(
+                compiler_options=paged.TPU_COMPILER_OPTIONS)
+
+    prefill = compiled(1, 4096, 0)
+    memory = prefill.memory_analysis()
+    counted = 4096 * paged.prefill_position_bytes(spec, 3584, 16384, 32)
+    by_compiler = memory.temp_size_in_bytes + memory.output_size_in_bytes
+    assert 2 / 3 < counted / by_compiler < 3 / 2, (counted, by_compiler)
+    step = compiled(128, 1, 72)
+    assert step.memory_analysis().temp_size_in_bytes < 1 << 29
+    for program in (prefill, step):
+        # a layer: the attention's kernel, the experts' or none (the dense
+        # layer), and four of the mixing's
+        assert program.as_text().count("tpu_custom_call") >= 6 * 5

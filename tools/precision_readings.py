@@ -15,7 +15,15 @@ window and 256 judged tokens, through ``generation_share``'s two limits
 as ``harness/kinds/generation_share_window.py`` applies them, the wrong
 programs ``reference/smallthinker.py``'s ``VARIANTS`` — the router fed
 from the post-attention norm, ``silu`` for ``relu``, a full layer
-rotated, a window layer left whole, a K/V head mapped ``h % 4``)
+rotated, a window layer left whole, a K/V head mapped ``h % 4``; with
+``--config xing4.0-29b-a4b --positions 4352 --judged 256`` Xing4.0's: the
+longest prompt of its cell and 256 judged tokens, through the two limits
+of its kind (``harness/kinds/generation_share_whole.py verdict``), the
+wrong programs
+``reference/xing4_wrong.py``'s mixings — the Sinkhorn stopped at one
+iteration, H_post without its 2, the input-dependent term dropped, H_res
+the identity, H_pre applied after the norm, bfloat16 coefficients — and
+the mean at the exit, which gives the same logits)
 says of the tokens that the same forward pass serves in a lower
 precision, or with a fault.  CPU only (``JAX_PLATFORMS=cpu``), 1-2
 minutes a variant at 512 positions; nothing here is a device number.
@@ -408,6 +416,50 @@ def smallthinker_readings(args, config, ref, params, tokens) -> int:
     return 0
 
 
+def xing4_readings(args, config, ref, params, tokens) -> int:
+    """The stated precision, 8-bit operands, each wrong mixing of
+    ``reference/xing4_wrong.py`` and a stale row, judged at the last
+    ``--judged`` positions (every one without it) by the cell's kind."""
+    import numpy as np
+
+    from harness import manifest
+    from reference import xing4_wrong
+
+    model, n = config["model"], len(tokens)
+    kind = manifest.module("harness/kinds", config["kind"])
+    tail = {"tail": args.judged} if args.judged else {}
+    judged = args.judged or n
+    plain = np.asarray(ref.logits(params, model, tokens, **tail))
+    std = plain.std(-1)
+    wrongs = [*xing4_wrong.WRONG, *xing4_wrong.SAME_LOGITS]
+    names = (["stated", "e4m3", *wrongs, "early-row"]
+             if args.variants == ",".join(VARIANTS) else args.variants.split(","))
+    for name in names:
+        far = None
+        if name == "early-row":
+            served = np.concatenate([plain[:1].argmax(-1), plain[:-1].argmax(-1)])
+        elif name in wrongs:
+            with xing4_wrong.wrong(name, model, ref) as other:
+                got = np.asarray(ref.logits(params, other, tokens, **tail))
+        else:
+            got = np.asarray(ref.logits(
+                params, model, tokens, **tail,
+                rounding=smallthinker_rounding(name == "e4m3")))
+        if name != "early-row":
+            served = got.argmax(-1)
+            far = float(np.median(np.sqrt(((got - plain) ** 2).mean(-1)) / std))
+        gaps = (plain.max(-1) - plain[np.arange(judged), served]) / std
+        v = kind.verdict(gaps)  # the cell's own comparison
+        print(json.dumps({"variant": name, "seed": args.seed, "positions": judged,
+                          "ok": bool(v["ok"]), "not_top1": int((gaps > 0).sum()),
+                          "off": v["off"], "off_share_pct": 100.0 * v["off_share"],
+                          "far": v["far"], "far_share_pct": 100.0 * v["far_share"],
+                          "worst_gap_stds": float(gaps.max()),
+                          "gap_stds_p50_p90": [float(q) for q in np.quantile(gaps, [0.5, 0.9])],
+                          "logits_rms_stds": far}), flush=True)
+    return 0
+
+
 def main() -> int:
     import numpy as np
 
@@ -431,6 +483,8 @@ def main() -> int:
     dots3 = config["reference"] == "dots3_note"
     if config["reference"] == "smallthinker":
         return smallthinker_readings(args, config, ref, params, tokens)
+    if config["reference"] == "xing4":
+        return xing4_readings(args, config, ref, params, tokens)
     # (dots3-note: judged where a row has over index_topk candidates and a
     # slid window, as the cell's sample is: the last --judged positions, or
     # every one past index_topk)
