@@ -3227,6 +3227,9 @@ class PagedEngine:
                 kernel_eligible)
             for bucket in self.prompt_buckets} if (
                 spec.latent and spec.kinds and spec.index_topk) else {}
+        # which grouped expert matmul a program that routes so many
+        # tokens a layer traces (_expert_matmul_of), as it was first asked
+        self._expert_matmul: Dict[int, str] = {}
         # r18 int8 KV pool: pages rest int8 with ONE f32 scale per page
         # per k/v in a sibling (layers, num_pages) table — half the
         # pool bytes (≈2x paged_capacity_streams), dequantised
@@ -3645,6 +3648,13 @@ class PagedEngine:
                           "prefill_held_rows": 0,
                           "prefill_held_local": 0,
                           "prefill_held_extra_passes": 0,
+                          # the routed layers of the prefill calls
+                          # dispatched, and of those the ones of
+                          # programs whose grouped matmuls are the
+                          # tiled kernel (lane_report()'s
+                          # "expert_matmul"): the lane's engagement
+                          "prefill_expert_layer_calls": 0,
+                          "prefill_expert_layer_calls_tiled": 0,
                           # a router that also scores identity experts
                           # (spec.zero_experts; 0 otherwise): picks
                           # that fell on them; the (token, layer)s
@@ -6622,6 +6632,8 @@ class PagedEngine:
         )
         if self.spec.experts_held:
             routed["held_rows"] = self._held_pass_rows(k * bucket)
+        if self.spec.routed:
+            routed["expert_matmul"] = self._expert_matmul_of(k * bucket)
         fused = not use_cache and self._prefill_attention[bucket] == "fused"
         indexed_fused = (not use_cache and self._prefill_indexed_attention.get(
             bucket) == "fused")
@@ -6638,6 +6650,11 @@ class PagedEngine:
                     self._counters["prefill_fused_positions"] += k * bucket
                 if indexed_fused:
                     self._counters["prefill_indexed_fused_positions"] += k * bucket
+                if self.spec.routed:
+                    layers = self.module.num_layers - self.spec.dense_layers
+                    self._counters["prefill_expert_layer_calls"] += layers
+                    if routed["expert_matmul"] == "tiled":
+                        self._counters["prefill_expert_layer_calls_tiled"] += layers
                 self._counters["hyper_prefill_positions"] += (
                     k * bucket * self._hyper_sublayers)
             return self._prefill_group_call(bucket, k, group, use_cache)
@@ -7951,14 +7968,22 @@ class PagedEngine:
         return tokens
 
     def _expert_matmul_report(self) -> Dict[str, str]:
-        """``"stream"`` | ``"ragged_dot"`` for each program
-        (:meth:`_routed_program_tokens`), from the rule the programs
-        trace with (ops/moe.py ``layer_expert_matmul``)."""
-        spec = self.spec
-        if not spec.routed:
+        """``"stream"`` | ``"tiled"`` | ``"ragged_dot"`` for each
+        program (:meth:`_routed_program_tokens`), from the rule the
+        programs trace with (ops/moe.py ``layer_expert_matmul``)."""
+        if not self.spec.routed:
             return {}
+        return {name: self._expert_matmul_of(tokens)
+                for name, tokens in self._routed_program_tokens().items()}
+
+    def _expert_matmul_of(self, tokens: int) -> str:
+        """The rule's answer for a program that routes ``tokens`` tokens
+        a layer."""
+        if tokens in self._expert_matmul:
+            return self._expert_matmul[tokens]
         from seldon_core_tpu.ops import moe
 
+        spec = self.spec
         tree_util = self._jax.tree_util
         gate = next(
             leaf for path, leaf in tree_util.tree_flatten_with_path(self.params)[0]
@@ -7967,11 +7992,10 @@ class PagedEngine:
         # a softmax router's layer runs the held pass where the replica
         # holds a share (_ffn); the other routers' layers always do
         held_pass = bool(spec.experts_held) or spec.score != "softmax"
-        return {
-            name: moe.layer_expert_matmul(
-                tokens, spec.experts_per_tok, held, spec.router_outputs,
-                d_model, width, gate.dtype, held_pass=held_pass)
-            for name, tokens in self._routed_program_tokens().items()}
+        impl = self._expert_matmul[tokens] = moe.layer_expert_matmul(
+            tokens, spec.experts_per_tok, held, spec.router_outputs,
+            d_model, width, gate.dtype, held_pass=held_pass)
+        return impl
 
     def _held_pass_rows(self, tokens: int) -> int:
         """The rows one held pass computes in a program that routes

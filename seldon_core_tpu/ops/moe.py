@@ -21,17 +21,22 @@ the activations' type (bf16 when serving) and accumulate in f32.
 ``grouped_swiglu`` is that one entry, for ``expert_ffn`` and
 ``expert_ffn_held`` alike, and its implementation is a function of the
 call's static shape, operand type and backend
-(``expert_matmul_impl``): under the chip's ridge in mean rows a group,
-in bf16 on a TPU, a Pallas kernel written for a call bound by
+(``expert_matmul_impl``).  In bf16 on a TPU, under the chip's ridge in
+mean rows a group: a Pallas kernel written for a call bound by
 streaming the hit experts' matrices (``stream_matmul``: sorted rows
 resident in VMEM, each hit expert's matrices streamed once in blocks
 of whole K, gate and up in one call with ``silu(gate) * up`` applied
-in VMEM); everywhere else ``jax.lax.ragged_dot``, which XLA's TPU
-backend lowers to a Mosaic grouped-matmul kernel of its own
-(``ragged-dot-*`` custom calls).  Either way the device trace shows
-Mosaic kernels with a 2-D ``(rows, width)`` output, and one entry
-serves a 256-row decode step inside a scan and a 16,384-row prefill
-group.
+in VMEM).  At and over the ridge: a Pallas kernel written for a call
+bound by its rows' FLOPs (``tiled_matmul``: one call a matmul at any
+row count, rows and results in bf16 blocks through the pipeline, row
+tiles that start at each group's own first row so a group wastes under
+a tile, the next group's matrices fetched behind the compute, nothing
+visited past the last group).  Everywhere else ``jax.lax.ragged_dot``,
+which XLA's TPU backend lowers to a Mosaic grouped-matmul kernel of
+its own (``ragged-dot-*`` custom calls).  Every way the device trace
+shows Mosaic kernels with a 2-D ``(rows, width)`` output, and one
+entry serves a 256-row decode step inside a scan and a 16,384-row
+prefill group.
 
 ``route_grouped`` is the DeepSeek-V3 router: sigmoid scores in float32,
 a correction bias that takes part in the *selection* alone, groups of
@@ -121,7 +126,8 @@ def activation(act: str):
 # ---------------------------------------------------------------------------
 
 # Mean rows a group (rows / groups) under which a call takes the
-# streaming kernel below; at and over it ``ragged_dot``.  Under the
+# streaming kernel below; at and over it the tiled one (and
+# ``ragged_dot`` wherever neither kernel is taken).  Under the
 # chip's ridge (~240 FLOP/B: 240 rows an expert) a call is bound by
 # streaming the hit experts' matrices, and there XLA's ``ragged_dot``
 # reads 23-38 % of that stream at 8 and more rows a group (59-70 % at a
@@ -133,8 +139,10 @@ def activation(act: str):
 # -> 82 %), 512 2.00 / 0.96, 1,024 2.00 / 1.14, 2,048 2.32 / 1.30,
 # 4,096 3.24 / 2.41, 8,192 3.88 / 3.53 (my chip runs, PR 31,
 # tools/probe_moe.py).  The line is the ridge, not the last row count
-# the kernel won at: past it the kernel is a compute-bound matmul with a
-# row loop nobody tuned.
+# the kernel won at: past it the call is a compute-bound matmul, which
+# this kernel's resident float32 rows and segments were not built for
+# and the tiled kernel is (PR 46: at Xing4's 64 x (3584, 1024), 16,384
+# rows, ``ragged_dot`` 7.70 ms / this kernel 4.75 / the tiled one 3.85).
 STREAM_MAX_MEAN_ROWS = 256
 # Bytes of one streamed weight block (whole K, as wide an N as fits):
 # two matrices (gate and up), double-buffered, are four of these in
@@ -148,6 +156,31 @@ STREAM_BLOCK_BYTES = 8 << 20
 # calls over the groups its rows belong to — the rows are sorted by
 # group, so only a group that straddles a cut is streamed twice.
 STREAM_SEGMENT_BYTES = 16 << 20
+# Rows one matmul of the tiled kernel takes (the lane at and over the
+# ridge, :func:`tiled_matmul`): a group's rows are computed this many at
+# a time from the 16-row tile its first row lies in, so a group wastes
+# under a tile.  A layer's three matmuls on the v5e by this tile, 128 /
+# 256 / 512 rows, in ms (my chip runs, PR 46, tools/probe_moe.py; the
+# probe's own pass over the rows, ~0.5 ms at the first shape, is in
+# every number): Xing4's 64 x (3584, 1024) at 16,384 rows 3.85 / 4.21 /
+# 5.74 (``ragged_dot`` 7.70) and with 12,288 of them real 3.46 / 3.60
+# (7.05); OLMoE's 64 x (2048, 1024) at 16,384 rows 2.08 / 2.37 (4.33),
+# at 32,768 3.87 / 4.17 (6.78); SmallThinker's 16 x (2560, 768) at
+# 13,824 rows 1.07 / 1.14 / 1.33 (1.81); GigaChat's 8 x (7168, 2048) at
+# 2,048 rows, a quarter real, 1.11 / 1.23 (2.32).  64 rows read 128's
+# 3.84 at the first shape: with the compute taken out the two calls
+# still take 3.15 ms (they move 1.83 GB of weights, rows and results),
+# with the weights' stream taken out 3.53 — the lane sits within a
+# fifth of either, and what a smaller tile saves in rows the stream
+# does not give back.
+TILED_ROW_TILE = 128
+# Bytes of one block of sorted rows in, or of their results out, in the
+# tiled kernel's pipeline (each buffered twice, beside four streamed
+# weight blocks of :data:`STREAM_BLOCK_BYTES`): 1,024 rows at every
+# width the cells have.  A group that straddles a block's edge costs a
+# tile more: Xing4's 16,384 rows in blocks of 512 / 1,024 / 2,048 rows
+# 4.02 / 3.85 / 3.84 ms, OLMoE's 2.21 / 2.08 (same runs).
+TILED_ROWS_BYTES = 16 << 20
 
 
 def matmul_backend() -> str:
@@ -192,24 +225,39 @@ def stream_segment_rows(width: int) -> int:
     return STREAM_SEGMENT_BYTES // (4 * width) // 128 * 128
 
 
+def tiled_row_block(rows: int, d: int, f: int) -> int:
+    """Sorted rows the tiled SwiGLU's pipeline holds at a time, one
+    count for both kernels (they share the visits): blocks of ``(rows,
+    d)`` operands in and ``(rows, width)`` bfloat16 results out for gate
+    and up, ``(rows, f)`` in and ``(rows, width)`` float32 out for the
+    down projection, each buffered twice.  The most of 1,024, 512 and
+    256 that the call has and :data:`TILED_ROWS_BYTES` hold of each; 0
+    where not even 256 fit."""
+    blocks = ((d, 2), (stream_block(d, f), 2), (f, 2), (stream_block(f, d), 4))
+    fits = [b for b in (1024, 512, 256) if b <= max(rows, 256)
+            and all(b * width * size <= TILED_ROWS_BYTES for width, size in blocks)]
+    return max(fits, default=0)
+
+
 def expert_matmul_impl(rows: int, groups: int, k: int, n: int, dtype,
                        backend: str) -> str:
-    """``"stream"`` or ``"ragged_dot"`` for a grouped SwiGLU of ``rows``
-    sorted rows over ``groups`` experts of ``(k, n)`` gate and up and
-    ``(n, k)`` down matrices: a pure function of what a trace can see.
-    The streaming kernel where the operands are bfloat16, the backend
-    is a TPU (or the Pallas interpreter), the mean rows a group are
-    under :data:`STREAM_MAX_MEAN_ROWS` and a block of whole K and a
-    segment of rows fit the kernel's VMEM at these widths;
-    ``ragged_dot`` everywhere else."""
+    """``"stream"``, ``"tiled"`` or ``"ragged_dot"`` for a grouped
+    SwiGLU of ``rows`` sorted rows over ``groups`` experts of ``(k, n)``
+    gate and up and ``(n, k)`` down matrices: a pure function of what a
+    trace can see.  Where the operands are bfloat16 and the backend is a
+    TPU (or the Pallas interpreter): under :data:`STREAM_MAX_MEAN_ROWS`
+    mean rows a group the streaming kernel, at and over it the tiled
+    one, each where a block of whole K and its rows fit the kernel's
+    VMEM at these widths; ``ragged_dot`` everywhere else."""
     import jax.numpy as jnp
 
     if backend not in ("tpu", "interpret") or jnp.dtype(dtype) != jnp.bfloat16:
         return "ragged_dot"
+    block_fits = max(k, n) * 128 * 2 <= STREAM_BLOCK_BYTES
     if rows >= STREAM_MAX_MEAN_ROWS * groups:
-        return "ragged_dot"
-    fits = (max(k, n) * 128 * 2 <= STREAM_BLOCK_BYTES
-            and stream_segment_rows(max(k, n)) >= 256)
+        fits = block_fits and tiled_row_block(rows, k, n) > 0
+        return "tiled" if fits else "ragged_dot"
+    fits = block_fits and stream_segment_rows(max(k, n)) >= 256
     return "stream" if fits else "ragged_dot"
 
 
@@ -334,15 +382,16 @@ def stream_swiglu(rows, w_gate, w_up, w_down, sizes, *, interpret: bool,
     row of any group (a held pass's rows past its groups) runs nothing
     and comes back as zeros.  Jitted: a program traces and lowers it
     once for all its layers."""
-    return _stream_swiglu_jit()(rows, w_gate, w_up, w_down, sizes,
-                                interpret=interpret, act=act)
+    return _swiglu_jit(_stream_swiglu)(rows, w_gate, w_up, w_down, sizes,
+                                       interpret=interpret, act=act)
 
 
 @functools.lru_cache(maxsize=None)
-def _stream_swiglu_jit():
+def _swiglu_jit(swiglu):
+    """A kernel lane's SwiGLU, jitted once a process."""
     import jax
 
-    return jax.jit(_stream_swiglu, static_argnames=("interpret", "act"))
+    return jax.jit(swiglu, static_argnames=("interpret", "act"))
 
 
 def _stream_swiglu(rows, w_gate, w_up, w_down, sizes, *, interpret: bool,
@@ -374,6 +423,155 @@ def _stream_swiglu(rows, w_gate, w_up, w_down, sizes, *, interpret: bool,
     return jnp.concatenate(outs)
 
 
+def tiled_visits(sizes, rows: int, row_block: int):
+    """The tiled kernel's grid, from the groups' sizes: a *visit* is a
+    (group, row block) pair that share rows, in ascending order of both
+    — a group that lies in one block of ``row_block`` sorted rows is one
+    visit, one that straddles a block's edge one a block.  ``(groups
+    (V,), blocks (V,), starts (G,), ends (G,), visits (1,))`` int32 with
+    ``V = blocks + G - 1``, the most there can be; past the last real
+    visit the lists repeat it, so the pipeline fetches nothing more."""
+    import jax.numpy as jnp
+
+    sizes = sizes.astype(jnp.int32)
+    groups = sizes.shape[0]
+    blocks = -(-rows // row_block)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // row_block
+    count = jnp.where(sizes > 0, (ends - 1) // row_block - first + 1, 0)
+    upto = jnp.cumsum(count)                      # visits up to and with g
+    v = jnp.minimum(jnp.arange(blocks + groups - 1), jnp.maximum(upto[-1] - 1, 0))
+    group = jnp.minimum((upto[None, :] <= v[:, None]).sum(axis=-1), groups - 1)
+    block = first[group] + v - (upto[group] - count[group])
+    return (group.astype(jnp.int32),
+            jnp.clip(block, 0, blocks - 1).astype(jnp.int32),
+            starts, ends, upto[-1:])
+
+
+def _tiled_kernel(groups_ref, blocks_ref, starts_ref, ends_ref, visits_ref,
+                  x_ref, *refs, row_block, row_tile, gated, act="silu"):
+    """One grid step ``(j, v)``: the rows that the ``v``-th visit's
+    group has in its row block through the ``j``-th ``(K, width)`` block
+    of the group's matrix (or of its gate and up matrices, with
+    ``act(gate) * up`` applied here, in float32).
+
+    The block's rows arrive in the matrices' type through the pipeline
+    and its results leave the same way; consecutive visits to one block
+    find both where they were, and consecutive visits of one group find
+    its matrices.  The group's rows are computed ``row_tile`` at a time
+    from the 16-row tile its first row in the block lies in (a packed
+    bfloat16 tile), the last tile pulled back inside the block; what a
+    tile holds of *earlier* groups is kept, what it holds of *later*
+    ones is overwritten when their turn comes, so rows past the last
+    group are left with whatever was computed there, and a block no
+    group reaches is never written."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    n_w = 2 if gated else 1
+    w_refs, out_ref = refs[:n_w], refs[n_w]
+    v = pl.program_id(1)
+
+    @pl.when(v < visits_ref[0])
+    def _():
+        group = groups_ref[v]
+        first = blocks_ref[v] * row_block
+        lo = jnp.maximum(starts_ref[group] - first, 0)
+        hi = jnp.minimum(ends_ref[group] - first, row_block)
+        base = (lo // 16) * 16
+
+        def tile(c, carry):
+            r0 = pl.multiple_of(
+                jnp.minimum(base + c * row_tile, row_block - row_tile), 16)
+            x = x_ref[pl.ds(r0, row_tile), :]
+            y = jnp.dot(x, w_refs[0][0], preferred_element_type=jnp.float32)
+            if gated:
+                up = jnp.dot(x, w_refs[1][0], preferred_element_type=jnp.float32)
+                y = activation(act)(y) * up
+            row = r0 + jax.lax.broadcasted_iota(jnp.int32, (row_tile, 1), 0)
+            out_ref[pl.ds(r0, row_tile), :] = jnp.where(
+                row >= lo, y.astype(out_ref.dtype), out_ref[pl.ds(r0, row_tile), :])
+            return carry
+
+        jax.lax.fori_loop(0, pl.cdiv(hi - base, row_tile), tile, 0)
+
+
+def tiled_matmul(x, matrices, visits, *, interpret: bool, row_block: int,
+                 row_tile: int, out_dtype=None,
+                 block_bytes: int = STREAM_BLOCK_BYTES, act: str = "silu"):
+    """``x`` ``(R, K)`` sorted by group, ``visits`` = :func:`tiled_visits`
+    of the groups' sizes at ``row_block`` -> ``(R, N)`` in ``out_dtype``
+    (float32 by default): ``x[g's rows] @ W[g]`` for one ``(G, K, N)``
+    matrix, or ``act(x @ W_gate[g]) * (x @ W_up[g])`` for two, as ONE
+    Pallas call at any row count (``moe_tiled_down`` /
+    ``moe_tiled_gate_up``): a grid over the visits, rows and results in
+    blocks of ``row_block`` through the pipeline, each hit group's
+    matrices streamed once in ``(K, width)`` blocks
+    (:func:`stream_block`) behind the group before it.  Operands in the
+    matrices' type, float32 accumulation; rows past the last group are
+    undefined.  The output is 2-D ``(R, N)``: what the benchmark's
+    readers know a grouped matmul by."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, k = x.shape
+    n = matrices[0].shape[-1]
+    gated = len(matrices) == 2
+    out_dtype = jnp.dtype(jnp.float32 if out_dtype is None else out_dtype)
+    width = stream_block(k, n, jnp.dtype(matrices[0].dtype).itemsize, block_bytes)
+    row_tile = min(row_tile, row_block)
+    w_spec = pl.BlockSpec((1, k, width), lambda j, v, g, *_: (g[v], 0, j))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(n // width, visits[0].shape[0]),
+        in_specs=[pl.BlockSpec((row_block, k), lambda j, v, g, b, *_: (b[v], 0))]
+        + [w_spec] * len(matrices),
+        out_specs=pl.BlockSpec((row_block, width), lambda j, v, g, b, *_: (b[v], j)),
+    )
+    # the rows', the matrices' and the results' blocks twice (the
+    # pipeline's two buffers) and a tile's float32 products
+    vmem = (2 * row_block * k * 2 + 2 * len(matrices) * k * width * 2
+            + 2 * row_block * width * out_dtype.itemsize
+            + (len(matrices) + 1) * row_tile * width * 4)
+    return pl.pallas_call(
+        functools.partial(_tiled_kernel, row_block=row_block, row_tile=row_tile,
+                          gated=gated, **({} if act == "silu" else {"act": act})),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, n), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=min(vmem + (16 << 20), 120 << 20)),
+        interpret=interpret,
+        name="moe_tiled_gate_up" if gated else "moe_tiled_down",
+    )(*visits, x.astype(matrices[0].dtype), *matrices)
+
+
+def tiled_swiglu(rows, w_gate, w_up, w_down, sizes, *, interpret: bool,
+                 act: str = "silu"):
+    """The grouped SwiGLU as two tiled kernels over all the rows:
+    ``act(gate) * up`` in the first, which leaves it in the matrices'
+    type (what the down projection's operand is rounded to on every
+    lane), the down projection in the second.  Jitted: a program traces
+    and lowers it once for all its layers."""
+    return _swiglu_jit(_tiled_swiglu)(rows, w_gate, w_up, w_down, sizes,
+                                      interpret=interpret, act=act)
+
+
+def _tiled_swiglu(rows, w_gate, w_up, w_down, sizes, *, interpret: bool,
+                  act: str = "silu"):
+    total, d = rows.shape
+    row_block = tiled_row_block(total, d, w_gate.shape[-1])
+    kw = dict(interpret=interpret, row_block=row_block, row_tile=TILED_ROW_TILE)
+    visits = tiled_visits(sizes, total, row_block)
+    hidden = tiled_matmul(rows, (w_gate, w_up), visits, act=act,
+                          out_dtype=w_down.dtype, **kw)
+    return tiled_matmul(hidden, (w_down,), visits, **kw)
+
+
 def ragged_swiglu(rows, w_gate, w_up, w_down, sizes, inner, act: str = "silu"):
     """The grouped SwiGLU as three ``jax.lax.ragged_dot``s; gate and up
     leave theirs in ``inner``."""
@@ -397,8 +595,8 @@ def grouped_swiglu(rows, w_gate, w_up, w_down, sizes, *, inner=None,
     float32.  ``w_gate`` / ``w_up`` ``(G, d, f)``, ``w_down`` ``(G, f,
     d)``.  The implementation is :func:`expert_matmul_impl`'s answer for
     this call's static shape, type and backend; ``inner`` is the type
-    gate and up leave ``ragged_dot`` in (the rows' type by default; the
-    streaming kernel keeps them in float32)."""
+    gate and up leave ``ragged_dot`` in (the rows' type by default; both
+    kernels keep them in float32 until ``act(gate) * up`` is taken)."""
     import jax
 
     d, f = w_gate.shape[1:]
@@ -409,6 +607,9 @@ def grouped_swiglu(rows, w_gate, w_up, w_down, sizes, *, inner=None,
         if impl == "stream":
             return stream_swiglu(rows, w_gate, w_up, w_down, sizes,
                                   interpret=backend != "tpu", act=act)
+        if impl == "tiled":
+            return tiled_swiglu(rows, w_gate, w_up, w_down, sizes,
+                                 interpret=backend != "tpu", act=act)
         return ragged_swiglu(rows, w_gate, w_up, w_down, sizes,
                              rows.dtype if inner is None else inner, act)
 
@@ -609,8 +810,8 @@ def expert_ffn_held(h, w_gate, w_up, w_down, gates, experts, offset: int,
     included: they are what an even share is a share of).
 
     The assignments are sorted local-first by expert and computed
-    :func:`held_rows_cap` rows a pass (``ragged_dot`` over the rows'
-    group sizes, as ``expert_ffn``), in a ``while_loop`` that runs as
+    :func:`held_rows_cap` rows a pass (:func:`grouped_swiglu` over the
+    rows' group sizes, as ``expert_ffn``), in a ``while_loop`` that runs as
     many passes as the local assignments need: one, unless routing
     piles onto this share.  A pass's rows go back to their tokens by
     ``top_k`` gathers of ``(T, d)`` (a token's j-th assignment reads its

@@ -679,6 +679,15 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
     "prefill_held_extra_passes": (
         "counter", "seldon_tpu_engine_prefill_held_extra_passes_total",
         "held-experts passes of prefill calls beyond a layer's first"),
+    "prefill_expert_layer_calls": (
+        "counter", "seldon_tpu_engine_prefill_expert_layer_calls_total",
+        "routed layers of the prefill calls dispatched (0 for a dense "
+        "model)"),
+    "prefill_expert_layer_calls_tiled": (
+        "counter", "seldon_tpu_engine_prefill_expert_layer_calls_tiled_total",
+        "of those, the layers of programs whose grouped expert matmuls "
+        "run in the tiled kernel (ops/moe.py): over "
+        "prefill_expert_layer_calls, the lane's engagement"),
     "moe_load_max": ("gauge", "seldon_tpu_engine_moe_load_max",
                      "cumulative assignments of the busiest (layer, "
                      "expert) pair"),

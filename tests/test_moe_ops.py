@@ -4,10 +4,11 @@ routed ones kept): random routing, an expert that receives no row, one
 that receives every row, a decode step's few rows and a prefill
 group's many.
 
-Every case runs on both implementations of ``grouped_swiglu``:
-``ragged_dot`` in float32 (what the CPU lanes trace) and the streaming
-Pallas kernel under the interpreter in bfloat16 (``lane``: the test
-answers ``"interpret"`` where the program asks for its backend)."""
+Every case runs on both sides of ``grouped_swiglu``'s rule:
+``ragged_dot`` in float32 (what the CPU lanes trace) and the Pallas
+kernels under the interpreter in bfloat16 — the streaming one under the
+ridge, the tiled one at and over it (``lane``: the test answers
+``"interpret"`` where the program asks for its backend)."""
 
 import numpy as np
 import pytest
@@ -31,7 +32,7 @@ def weights(seed=0, dtype=jnp.float32):
 # bf16 operands against the same bf16-rounded operands in float32: what
 # is left is the rounding of each matmul's output, a few parts in a
 # thousand of unit-spread values
-TOL = {"ragged_dot": 1e-5, "stream": 0.02}
+TOL = {"ragged_dot": 1e-5, "stream": 0.02, "tiled": 0.02}
 
 
 @pytest.fixture(params=["ragged_dot", "stream"])
@@ -62,7 +63,8 @@ def dense(h, w_gate, w_up, w_down, gates, experts):
 
 
 # (700, 3) is a prefill group's: 262 rows an expert, over the rule's
-# line, so both lanes run ragged_dot there (in bf16 on the second);
+# line, so the first lane runs ragged_dot there and the second the
+# tiled kernel (2,100 rows: no multiple of its row block);
 # (50, 3) and (25, 8) are no multiple of the kernel's row tile
 @pytest.mark.parametrize("rows,top_k", [(1, 2), (4, 2), (32, 2), (200, 3), (64, 8),
                                         (50, 3), (25, 8), (700, 3)])
@@ -72,11 +74,12 @@ def test_routed_rows_equal_the_dense_einsum(lane, rows, top_k):
     h = jax.random.normal(jax.random.key(rows), (rows, D), jnp.float32).astype(dtype)
     gates, experts = moe.route(h, w_router, top_k)
     over = rows * top_k >= moe.STREAM_MAX_MEAN_ROWS * E
-    assert runs(impl, rows * top_k) == ("ragged_dot" if over else impl)
+    ran = {"stream": "tiled"}.get(impl, impl) if over else impl
+    assert runs(impl, rows * top_k) == ran
     got = moe.expert_ffn(h, w_gate, w_up, w_down, gates, experts)
     want = dense(h, w_gate, w_up, w_down, gates, experts)
     assert got.shape == (rows, D) and got.dtype == jnp.float32
-    assert np.abs(np.asarray(got - want)).max() < TOL[impl]
+    assert np.abs(np.asarray(got - want)).max() < TOL[ran]
 
 
 @pytest.mark.parametrize("case", ["an_expert_with_no_row", "one_expert_takes_all",
@@ -366,6 +369,146 @@ def test_a_call_of_more_rows_than_a_segment_is_cut_by_rows(monkeypatch, real):
         assert not np.asarray(got)[256:].any()
 
 
+# ---------------------------------------------------------------------------
+# the tiled kernel by itself: the lane at and over the ridge
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("row_block,row_tile", [(64, 32), (64, 16), (32, 32), (160, 64)])
+@pytest.mark.parametrize("sizes", [
+    (5, 0, 7, 1, 0, 0, 19, 3),        # empty groups, each smaller than a tile
+    (40, 0, 37, 1, 0, 0, 50, 22),     # groups that straddle a row block's edge
+    (0, 0, 0, 150, 0, 0, 0, 0),       # every row on one group: every block
+    (1, 1, 1, 1, 1, 1, 1, 1),
+    (0, 0, 70, 4, 0, 0, 0, 0),        # rows past the groups: 76 of 150
+    (64, 0, 0, 0, 64, 0, 0, 22),      # groups that end where a block does
+    (0, 0, 0, 0, 0, 0, 0, 0),         # no group hit: nothing to compute
+])
+def test_the_tiled_kernel_against_each_rows_own_group(sizes, row_block, row_tile):
+    """``tiled_matmul`` with one matrix a group and with two (gate and
+    up, ``silu(gate) * up`` in the kernel), in ``(K, 128)`` blocks of a
+    256-wide matrix (two grid columns), 150 rows — no multiple of a row
+    block or of a tile, so the last block is partial: every row before
+    the groups' end is its own group's, whatever lies past it."""
+    k, n, rows = 32, 256, 150
+    ks = jax.random.split(jax.random.key(sum(sizes) + row_tile), 3)
+    x = jax.random.normal(ks[0], (rows, k), jnp.float32).astype(jnp.bfloat16)
+    w1 = (jax.random.normal(ks[1], (E, k, n), jnp.float32) * k ** -0.5).astype(jnp.bfloat16)
+    w2 = (jax.random.normal(ks[2], (E, k, n), jnp.float32) * k ** -0.5).astype(jnp.bfloat16)
+    real = sum(sizes)
+    visits = moe.tiled_visits(jnp.asarray(sizes, jnp.int32), rows, row_block)
+    for matrices in ((w1,), (w1, w2)):
+        got = moe.tiled_matmul(x, matrices, visits, interpret=True, row_block=row_block,
+                               row_tile=row_tile, block_bytes=k * 128 * 2)
+        assert got.shape == (rows, n) and got.dtype == jnp.float32
+        want = grouped_dense(x, matrices, np.asarray(sizes))
+        assert np.abs(np.asarray(got)[:real] - want).max(initial=0.0) < 2e-3
+    # the gate and up's result leaves in the matrices' type where asked
+    hidden = moe.tiled_matmul(x, (w1, w2), visits, interpret=True, row_block=row_block,
+                              row_tile=row_tile, out_dtype=jnp.bfloat16)
+    assert hidden.dtype == jnp.bfloat16     # rounded once: half a unit in the last of 8 bits
+    np.testing.assert_allclose(np.asarray(hidden.astype(jnp.float32))[:real], want,
+                               rtol=2.0 ** -8, atol=2e-3)
+
+
+@pytest.mark.parametrize("sizes,row_block,visits", [
+    # one group a block; a group over three blocks; groups sharing one
+    ((64, 64, 64), 64, [(0, 0), (1, 1), (2, 2)]),
+    ((0, 150, 0), 64, [(1, 0), (1, 1), (1, 2)]),
+    ((40, 0, 37, 1, 50, 22), 64, [(0, 0), (2, 0), (2, 1), (3, 1), (4, 1), (5, 2)]),
+    ((40, 0, 37, 1, 51, 21), 64, [(0, 0), (2, 0), (2, 1), (3, 1), (4, 1), (4, 2), (5, 2)]),
+    ((0, 0, 9), 64, [(2, 0)]),
+    ((0, 0, 0), 64, []),
+])
+def test_a_visit_is_a_group_and_a_row_block_that_share_rows(sizes, row_block, visits):
+    """The tiled kernel's grid: (group, block) pairs in ascending order,
+    a group's matrices fetched once however many blocks it spans (the
+    pairs of one group are adjacent), the list's tail repeating the last
+    real visit so nothing more is fetched."""
+    rows = 150
+    group, block, starts, ends, count = (
+        np.asarray(a) for a in moe.tiled_visits(jnp.asarray(sizes, jnp.int32), rows, row_block))
+    most = -(-rows // row_block) + len(sizes) - 1
+    assert group.shape == block.shape == (most,) and count.tolist() == [len(visits)]
+    assert list(zip(group.tolist(), block.tolist()))[:len(visits)] == visits
+    assert ends.tolist() == np.cumsum(sizes).tolist()
+    assert (ends - starts).tolist() == list(sizes)
+    if visits:
+        assert set(zip(group[len(visits):].tolist(),
+                       block[len(visits):].tolist())) <= {visits[-1]}
+    assert ((0 <= block) & (block < -(-rows // row_block))).all()
+
+
+@pytest.mark.parametrize("act", ["silu", "relu"])
+@pytest.mark.parametrize("real", [700, 523, 0])
+def test_the_tiled_swiglu_is_float32_ragged_dot_s_inside_the_streams_tolerance(real, act):
+    """The whole lane as ``grouped_swiglu`` runs it over the ridge (the
+    rule's own tiles: 700 rows are a partial second block of 512) against ``ragged_dot`` on the same bf16-rounded
+    operands in float32: every real row inside the streaming kernel's
+    tolerance, SwiGLU and ReGLU; with 523 real rows the rest lie past the
+    groups and come back as whatever was there."""
+    rows = 700
+    _w_router, w_gate, w_up, w_down = weights(17, jnp.bfloat16)
+    rng = np.random.default_rng(real)
+    sizes = rng.multinomial(real, [0.3, 0.0, 0.1, 0.25, 0.0, 0.05, 0.2, 0.1])
+    x = jax.random.normal(jax.random.key(real), (rows, D), jnp.float32).astype(jnp.bfloat16)
+    assert moe.expert_matmul_impl(rows, 2, D, F, jnp.bfloat16, "interpret") == "tiled"
+    got = moe.tiled_swiglu(x, w_gate, w_up, w_down, jnp.asarray(sizes, jnp.int32),
+                           interpret=True, act=act)
+    assert got.shape == (rows, D) and got.dtype == jnp.float32
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    want = moe.ragged_swiglu(f32(x), f32(w_gate), f32(w_up), f32(w_down),
+                             jnp.asarray(sizes, jnp.int32), jnp.float32, act)
+    assert np.abs(np.asarray(got)[:real] - np.asarray(want)[:real]).max(
+        initial=0.0) < TOL["stream"]
+    other = moe.ragged_swiglu(f32(x), f32(w_gate), f32(w_up), f32(w_down),
+                              jnp.asarray(sizes, jnp.int32), jnp.float32,
+                              "relu" if act == "silu" else "silu")
+    if real:
+        assert np.abs(np.asarray(got)[:real] - np.asarray(other)[:real]).max() > 0.05
+
+
+@pytest.mark.parametrize("passes", [1, 2])
+def test_held_experts_over_the_ridge_through_the_tiled_kernel(monkeypatch, passes):
+    """``expert_ffn_held`` end to end where a pass is over the ridge, in
+    bf16 under the interpreter: 4 of 16 experts held, 1,024 tokens
+    choosing 4, a pass of 1,536 rows on the tiled kernel — against
+    ``expert_ffn`` over all sixteen with the absent experts' gates at 0
+    (16,384 / 16 = 256 rows an expert: the tiled kernel too)."""
+    monkeypatch.setattr(moe, "matmul_backend", lambda: "interpret")
+    tokens, top_k, held, of, offset = 1024, 4, 4, 16, 4
+    cap = moe.held_rows_cap(tokens, top_k, held, of)
+    assert cap == 1536
+    assert moe.expert_matmul_impl(cap, held, D, F, jnp.bfloat16, "interpret") == "tiled"
+    ks = jax.random.split(jax.random.key(60 + passes), 4)
+    bf16 = jnp.bfloat16
+    w_gate = (jax.random.normal(ks[0], (of, D, F), jnp.float32) * D ** -0.5).astype(bf16)
+    w_up = (jax.random.normal(ks[1], (of, D, F), jnp.float32) * D ** -0.5).astype(bf16)
+    w_down = (jax.random.normal(ks[2], (of, F, D), jnp.float32) * F ** -0.5).astype(bf16)
+    h = jax.random.normal(ks[3], (tokens, D), jnp.float32).astype(bf16)
+    rng = np.random.default_rng(passes)
+    local_picks = {1: rng.choice([0, 1, 2], tokens, p=[0.3, 0.4, 0.3]),
+                   2: rng.choice([2, 3], tokens)}[passes]
+    inside, outside = np.arange(offset, offset + held), np.r_[0:offset, offset + held:of]
+    experts = jnp.asarray(np.stack([
+        np.r_[rng.choice(inside, n, replace=False),
+              rng.choice(outside, top_k - n, replace=False)][rng.permutation(top_k)]
+        for n in local_picks]).astype(np.int32))
+    assert -(-int(local_picks.sum()) // cap) == passes
+    gates = jnp.asarray(rng.uniform(0.05, 0.5, size=(tokens, top_k)), jnp.float32)
+    sl = slice(offset, offset + held)
+    got = jax.jit(lambda *a: moe.expert_ffn_held(*a, offset, of))(
+        h, w_gate[sl], w_up[sl], w_down[sl], gates, experts)
+    local = (experts >= offset) & (experts < offset + held)
+    masked = jnp.where(local, gates, 0.0)
+    assert moe.expert_matmul_impl(tokens * top_k, of, D, F, bf16, "interpret") == "tiled"
+    whole = jax.jit(moe.expert_ffn)(h, w_gate, w_up, w_down, masked, experts)
+    want = dense(h, w_gate, w_up, w_down, masked, experts)
+    assert got.shape == (tokens, D) and got.dtype == jnp.float32
+    assert np.abs(np.asarray(got - want)).max() < TOL["tiled"]
+    assert np.abs(np.asarray(whole - want)).max() < TOL["tiled"]
+    assert np.abs(np.asarray(want)).max() > 0.1
+
+
 def test_the_rule_is_a_function_of_shape_type_and_backend():
     bf16, f32 = jnp.bfloat16, jnp.float32
     rule = moe.expert_matmul_impl
@@ -375,30 +518,58 @@ def test_the_rule_is_a_function_of_shape_type_and_backend():
     # GigaChat: a decode pass's 128 rows over 8 held experts of 7168 x 2048
     assert rule(moe.held_rows_cap(128, 8, 8, 256), 8, 7168, 2048, bf16, "tpu") == "stream"
     # ... and its prefill passes: a 1,024-token prompt's 128 rows an
-    # expert stream, 256 and more (the ridge) stay on ragged_dot
+    # expert stream, 256 and more (the ridge) take the tiled kernel
     # (at the ridge four even shares, which are the tokens; past it one
     # and a half: 8,192 tokens' 2,048 local assignments in 3,072 rows)
-    for tokens, rows_, runs_ in ((1024, 1024, "stream"), (2048, 2048, "ragged_dot"),
-                                 (8192, 3072, "ragged_dot")):
+    for tokens, rows_, runs_ in ((1024, 1024, "stream"), (2048, 2048, "tiled"),
+                                 (8192, 3072, "tiled")):
         rows = moe.held_rows_cap(tokens, 8, 8, 256)
         assert rows == rows_ and rule(rows, 8, 7168, 2048, bf16, "tpu") == runs_
     # OLMoE's prefill groups: 32 to 128 rows an expert stream, the
     # largest (512 x 4 prompts: 256 rows an expert) does not
     for rows in (2048, 4096, 8192):
         assert rule(rows, 64, 2048, 1024, bf16, "tpu") == "stream"
-    assert rule(16384, 64, 2048, 1024, bf16, "tpu") == "ragged_dot"
+    assert rule(16384, 64, 2048, 1024, bf16, "tpu") == "tiled"
+    assert rule(16384, 64, 2048, 1024, bf16, "interpret") == "tiled"
+    # Xing4's two prefill programs hold 16,384 rows over 64 whole experts
+    # of 3584 x 1024 (the ridge's own line), its decode chunk 2,048
+    for tokens in (3072, 4096):
+        assert moe.layer_expert_matmul(
+            tokens, 4, 64, 64, 3584, 1024, bf16, held_pass=True, backend="tpu") == "tiled"
+    assert moe.layer_expert_matmul(
+        128, 4, 64, 64, 3584, 1024, bf16, held_pass=True, backend="tpu") == "stream"
+    # its tiles: 128 rows at a time, in blocks of rows that fit the
+    # pipeline beside a block of whole K
+    assert moe.TILED_ROW_TILE == 128
+    assert moe.tiled_row_block(16384, 3584, 1024) == 1024
+    assert moe.tiled_row_block(2048, 7168, 2048) == 1024
+    assert moe.tiled_row_block(2048, 16384, 2048) == 512        # 1,024 rows of d: 32 MiB
+    assert moe.tiled_row_block(16384, 1024, 16384) == 512       # ... or of the hidden rows
+    assert moe.tiled_row_block(300, 2048, 1024) == 256          # never over the rows
+    assert moe.tiled_row_block(16384, 40960, 1024) == 0         # not 256 rows of whole K
+    # widths neither kernel's VMEM rule takes stay on ragged_dot, on
+    # both sides of the ridge
+    assert rule(256, 64, 40960, 1024, bf16, "tpu") == "ragged_dot"
+    assert rule(16384, 64, 40960, 1024, bf16, "tpu") == "ragged_dot"
+    assert rule(16384, 64, 1024, 40960, bf16, "tpu") == "ragged_dot"
     assert [moe.stream_row_tile(r, 64) for r in (256, 2048, 8192)] == [16, 32, 128]
     assert moe.stream_segment_rows(2048) == 2048 and moe.stream_segment_rows(7168) == 512
     # the CPU exactness lanes, and every backend that is neither
     assert rule(256, 64, 2048, 1024, f32, "tpu") == "ragged_dot"
     assert rule(256, 64, 2048, 1024, bf16, "cpu") == "ragged_dot"
     assert rule(256, 64, 2048, 1024, bf16, "gpu") == "ragged_dot"
+    # ... over the ridge as under it
+    assert rule(16384, 64, 2048, 1024, f32, "tpu") == "ragged_dot"
+    assert rule(16384, 64, 2048, 1024, bf16, "cpu") == "ragged_dot"
+    assert rule(16384, 64, 2048, 1024, bf16, "gpu") == "ragged_dot"
     assert moe.matmul_backend() == "cpu"
     # the layer's rows are the rule's: every assignment, or one pass's
     assert moe.layer_expert_matmul(
         32, 8, 64, 64, 2048, 1024, bf16, held_pass=False, backend="tpu") == "stream"
     assert moe.layer_expert_matmul(
-        2048, 8, 8, 256, 7168, 2048, bf16, held_pass=True, backend="tpu") == "ragged_dot"
+        2048, 8, 8, 256, 7168, 2048, bf16, held_pass=True, backend="tpu") == "tiled"
+    assert moe.layer_expert_matmul(
+        2048, 8, 8, 256, 7168, 2048, bf16, held_pass=True, backend="cpu") == "ragged_dot"
     # a block is whole K and the widest N that divides and fits
     assert moe.stream_block(2048, 1024) == 1024 and moe.stream_block(1024, 2048) == 2048
     assert moe.stream_block(7168, 2048) * 7168 * 2 <= moe.STREAM_BLOCK_BYTES
@@ -406,12 +577,10 @@ def test_the_rule_is_a_function_of_shape_type_and_backend():
     assert moe.stream_block(32, 16) == 16         # no multiple of 128: whole
 
 
-@pytest.mark.parametrize("backend", ["cpu", "interpret"])
-def test_lane_report_says_what_the_programs_traced(monkeypatch, backend):
-    """A small OLMoE engine (8 experts top-2, 4 slots, buckets 16 / 32 /
-    64): ``lane_report()["expert_matmul"]`` names every chunk and
-    prefill program, and each program's trace asked the rule the same
-    question and got the same answer."""
+def small_olmoe_engine(monkeypatch, backend):
+    """A small OLMoE engine (8 experts top-2, two layers, 4 slots,
+    buckets 16 / 32 / 64) whose grouped matmuls are told they run on
+    ``backend``."""
     import os
     import sys
 
@@ -426,14 +595,22 @@ def test_lane_report_says_what_the_programs_traced(monkeypatch, backend):
         num_experts_per_tok=2, intermediate_size=32, rms_norm_eps=1e-5,
         rope_theta=10000, vocab_size=97))
     monkeypatch.setattr(moe, "matmul_backend", lambda: backend)
+    return PagedEngine(init_params(spec, sizes, 3, dtype=jnp.bfloat16), **sizes,
+                       max_len=64, page_size=8, max_slots=4, steps_per_call=2,
+                       dtype=jnp.bfloat16, spec=spec)
+
+
+@pytest.mark.parametrize("backend", ["cpu", "interpret"])
+def test_lane_report_says_what_the_programs_traced(monkeypatch, backend):
+    """The small OLMoE engine: ``lane_report()["expert_matmul"]`` names
+    every chunk and prefill program, and each program's trace asked the rule the same
+    question and got the same answer."""
     # the rule's line drawn where this engine's programs straddle it
     monkeypatch.setattr(moe, "STREAM_MAX_MEAN_ROWS", 32)
     asked, rule = [], moe.expert_matmul_impl
     monkeypatch.setattr(moe, "expert_matmul_impl",
                         lambda *a: asked.append(rule(*a)) or asked[-1])
-    eng = PagedEngine(init_params(spec, sizes, 3, dtype=jnp.bfloat16), **sizes,
-                      max_len=64, page_size=8, max_slots=4, steps_per_call=2,
-                      dtype=jnp.bfloat16, spec=spec)
+    eng = small_olmoe_engine(monkeypatch, backend)
     try:
         report = eng.lane_report()["expert_matmul"]
         assert sorted(report) == ["chunk"] + [
@@ -444,6 +621,8 @@ def test_lane_report_says_what_the_programs_traced(monkeypatch, backend):
             assert {k for k, v in report.items() if v == "stream"} == {
                 "chunk", "prefill_b16_k1", "prefill_b16_k2", "prefill_b16_k4",
                 "prefill_b32_k1", "prefill_b32_k2", "prefill_b64_k1"}
+            assert {k for k, v in report.items() if v == "tiled"} == {
+                "prefill_b32_k4", "prefill_b64_k2", "prefill_b64_k4"}
         i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
         unwrap = lambda fn: fn if hasattr(fn, "lower") else fn.__wrapped__  # noqa: E731
         pools = eng._kv_args()
@@ -456,5 +635,37 @@ def test_lane_report_says_what_the_programs_traced(monkeypatch, backend):
                 unwrap(eng._build_prefill(bucket, k)).lower(
                     eng.params, *pools, i32(k, bucket), i32(k), i32(k, bucket // 8))
             assert asked and set(asked) == {report[name]}, name
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("backend", ["cpu", "interpret"])
+def test_the_engine_counts_how_often_the_tiled_lane_engages(monkeypatch, backend):
+    """The same small OLMoE engine with the line drawn at 16 rows an
+    expert: a 40-token prompt's ``b64_k1`` program (128 assignment rows
+    over 8 experts) is over it, a 10-token prompt's ``b16_k1`` under.
+    ``prefill_expert_layer_calls`` counts both calls' routed layers at
+    dispatch, ``prefill_expert_layer_calls_tiled`` the first's alone
+    where the backend takes the kernels, none on the CPU."""
+    monkeypatch.setattr(moe, "STREAM_MAX_MEAN_ROWS", 16)
+    eng = small_olmoe_engine(monkeypatch, backend)
+    try:
+        report = eng.lane_report()["expert_matmul"]
+        over = "tiled" if backend == "interpret" else "ragged_dot"
+        assert report["prefill_b64_k1"] == over
+        assert report["prefill_b16_k1"] == ("stream" if backend == "interpret"
+                                            else "ragged_dot")
+        stats = eng.engine_stats()
+        assert stats["prefill_expert_layer_calls"] == 0
+        assert stats["prefill_expert_layer_calls_tiled"] == 0
+        rng = np.random.default_rng(5)
+        for n in (40, 10):
+            done = eng.submit(rng.integers(1, 97, n).astype(np.int32), max_new_tokens=2)
+            eng.run()
+            assert len(done.result) == 2
+        stats = eng.engine_stats()
+        assert stats["prefill_expert_layer_calls"] == 2 * 2
+        assert stats["prefill_expert_layer_calls_tiled"] == (
+            2 if backend == "interpret" else 0)
     finally:
         eng.close()

@@ -135,18 +135,25 @@ def backend(request, monkeypatch):
     return request.param
 
 
-def assert_expert_kernels(text, streams, rows, d, f):
+def assert_expert_kernels(text, impl, rows, d, f):
     """The three ``ragged_dot``s as the TPU compiler's own Mosaic
-    grouped-matmul kernels, or the two streaming kernels by name; either
-    way custom calls whose first output is 2-D ``(rows, width)``: what
-    the benchmark's readers know a decode step's expert kernels by."""
+    grouped-matmul kernels, or the two streaming kernels (``impl``
+    "stream": a segment's ``rows``) or the two tiled ones ("tiled": all
+    the ``rows`` of the call) by name; every way custom calls whose
+    first output is 2-D ``(rows, width)``: what the benchmark's readers
+    know an expert layer's kernels by."""
     assert "tpu_custom_call" in text
-    if streams:
-        assert "ragged-dot" not in text
+    if impl == "stream":
+        assert "ragged-dot" not in text and "moe_tiled" not in text
         assert "moe_stream_gate_up" in text and "moe_stream_down" in text
         assert f"f32[{rows},{f}]" in text and f"f32[{rows},{d}]" in text
+    elif impl == "tiled":
+        assert "ragged-dot" not in text and "moe_stream" not in text
+        assert "moe_tiled_gate_up" in text and "moe_tiled_down" in text
+        assert f"bf16[{rows},{f}]" in text and f"f32[{rows},{d}]" in text
     else:
-        assert text.count("ragged-dot") >= 3 and "moe_stream" not in text
+        assert text.count("ragged-dot") >= 3
+        assert "moe_stream" not in text and "moe_tiled" not in text
 
 
 @pytest.mark.parametrize("rows", [32, 512, 4096])
@@ -156,7 +163,8 @@ def test_expert_layer_compiles_as_grouped_matmul_kernels(one_chip, mosaic, backe
     decode step inside a ``scan`` as the chunk runs it: on a TPU the
     first streams its experts through the Pallas kernel, the second (64
     rows an expert) too, in two segments of 2,048 rows, and the third
-    (512 rows an expert) stays on ``ragged_dot``."""
+    (512 rows an expert: over the ridge) runs the tiled kernel over all
+    its 32,768 rows in one call a matmul."""
     from seldon_core_tpu.ops import moe
 
     d, f, e, k = 2048, 1024, 64, 8
@@ -180,8 +188,10 @@ def test_expert_layer_compiles_as_grouped_matmul_kernels(one_chip, mosaic, backe
         spec((e, d, f), jnp.bfloat16), spec((e, d, f), jnp.bfloat16),
         spec((e, f, d), jnp.bfloat16)).compile()
     text = compiled.as_text()
-    assert_expert_kernels(text, backend == "tpu" and rows < 4096,
-                          min(rows * k, moe.stream_segment_rows(d)), d, f)
+    impl = "ragged_dot" if backend != "tpu" else "stream" if rows < 4096 else "tiled"
+    assert_expert_kernels(
+        text, impl,
+        rows * k if impl == "tiled" else min(rows * k, moe.stream_segment_rows(d)), d, f)
     # only routed rows are materialised: the temporaries are a few
     # copies of the rows x top-k assignments (the down projection leaves
     # in f32 and is re-ordered once), a quarter of what a dense
@@ -254,7 +264,8 @@ def test_held_expert_layer_compiles_with_rows_of_a_pass(one_chip, mosaic, backen
     hundred MB; on a TPU the decode pass streams its experts through
     the Pallas kernel inside the pass loop, the 1,024-row pass too (in
     two segments of 512), and the largest — over the ridge: one and a
-    half even shares, 384 rows an expert — stays on ``ragged_dot``."""
+    half even shares, 384 rows an expert — runs the tiled kernel over
+    the pass's 3,072 rows."""
     from seldon_core_tpu.ops import moe
 
     d, f, e, held, k = 7168, 2048, 256, 8, 8
@@ -275,8 +286,9 @@ def test_held_expert_layer_compiles_with_rows_of_a_pass(one_chip, mosaic, backen
     text = compiled.as_text()
     # four times an even 1/32 share under the ridge, 1.5 times over it
     cap = moe.held_rows_cap(rows, k, held, e)
-    assert_expert_kernels(text, backend == "tpu" and rows < 8192,
-                          min(cap, moe.stream_segment_rows(d)), d, f)
+    impl = "ragged_dot" if backend != "tpu" else "stream" if rows < 8192 else "tiled"
+    assert_expert_kernels(
+        text, impl, cap if impl == "tiled" else min(cap, moe.stream_segment_rows(d)), d, f)
     # a pass's rows at an expert's width (cut into segments, at d_model)
     assert cap == {128: 128, 1024: 1024, 8192: 3072}[rows]
     assert f"[{cap},{f}]" in text or f"[{cap},{d}]" in text
@@ -329,8 +341,8 @@ def test_shortcut_experts_compile_with_rows_of_a_pass(one_chip, mosaic, backend,
     all 768 outputs, which is ``rows`` itself (at 4,096 the ridge's own
     rows, where the rule's two halves meet); on a TPU every pass under
     256 rows an expert streams through the Pallas kernel (2,048 rows in
-    segments of 640), and 4,096 rows over 16 experts stay on
-    ``ragged_dot``."""
+    segments of 640), and 4,096 rows over 16 experts run the tiled
+    kernel."""
     from seldon_core_tpu.ops import moe
 
     d, f, real, zero, held, k = 6144, 2048, 512, 256, 16, 12
@@ -355,9 +367,10 @@ def test_shortcut_experts_compile_with_rows_of_a_pass(one_chip, mosaic, backend,
     assert cap == rows
     assert moe.layer_expert_matmul(rows, k, held, real + zero, d, f, jnp.bfloat16,
                                    held_pass=True, backend="tpu") == (
-        "stream" if rows < 4096 else "ragged_dot")
-    assert_expert_kernels(text, backend == "tpu" and rows < 4096,
-                          min(cap, moe.stream_segment_rows(d)), d, f)
+        "stream" if rows < 4096 else "tiled")
+    impl = "ragged_dot" if backend != "tpu" else "stream" if rows < 4096 else "tiled"
+    assert_expert_kernels(
+        text, impl, cap if impl == "tiled" else min(cap, moe.stream_segment_rows(d)), d, f)
     assert f"[{rows * k},{d}]" not in text  # never all the picks' rows
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * rows * d * 4
 
@@ -705,3 +718,48 @@ def test_xing4_s_programs_compile_and_the_prefill_cap_s_count_holds(
         # a layer: the attention's kernel, the experts' or none (the dense
         # layer), and four of the mixing's
         assert program.as_text().count("tpu_custom_call") >= 6 * 5
+    # a prefill's held pass (16,384 rows over 64 whole experts: the
+    # ridge's own line) runs the tiled kernel, a decode step's the
+    # streaming one; neither leaves a ragged_dot
+    assert_expert_kernels(prefill.as_text(), "tiled", 16384, 3584, 1024)
+    assert_expert_kernels(step.as_text(), "stream", moe.stream_segment_rows(3584),
+                          3584, 1024)
+
+
+# (cell, experts held, d_model, expert width, rows): the passes over the
+# ridge that the cells form (PERF.md section 5, PR 46's table)
+TILED_SHAPES = [
+    ("xing4", 64, 3584, 1024, 16384), ("olmoe", 64, 2048, 1024, 16384),
+    ("smallthinker", 16, 2560, 768, 7168), ("smallthinker", 16, 2560, 768, 18432),
+    ("gigachat", 8, 7168, 2048, 2048), ("gigachat", 8, 7168, 2048, 3072),
+    ("dots3", 8, 5120, 1536, 2560), ("longcat", 16, 6144, 2048, 4096),
+]
+
+
+@pytest.mark.parametrize("cell,held,d,f,rows", TILED_SHAPES)
+def test_the_tiled_kernels_compile_at_the_cells_shapes(one_chip, cell, held, d, f, rows):
+    """The tiled grouped SwiGLU alone at each width family's rows over
+    the ridge: two Mosaic kernels, gate and up leaving ``(rows, f)`` in
+    bfloat16 and the down projection ``(rows, d)`` in float32, and no
+    copy of the rows beside them (the streaming kernel keeps them whole
+    in float32: a call's temporaries here are the first kernel's
+    output)."""
+    from seldon_core_tpu.ops import moe
+
+    assert moe.expert_matmul_impl(rows, held, d, f, jnp.bfloat16, "tpu") == "tiled"
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(
+            lambda *a: moe._tiled_swiglu(*a, interpret=False)).lower(
+                spec((rows, d), jnp.bfloat16), spec((held, d, f), jnp.bfloat16),
+                spec((held, d, f), jnp.bfloat16), spec((held, f, d), jnp.bfloat16),
+                spec((held,), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    assert_expert_kernels(compiled.as_text(), "tiled", rows, d, f)
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * f * 2 + (1 << 20)

@@ -285,17 +285,21 @@ def test_route_renormalises_and_takes_logits():
     assert np.array_equal(whose, experts) and np.allclose(again, normed)
 
 
-@pytest.mark.parametrize("impl", ["ragged_dot", "stream"])
+@pytest.mark.parametrize("impl", ["ragged_dot", "stream", "tiled"])
 def test_relu_is_not_silu(monkeypatch, impl):
-    if impl == "stream":
+    """Both activations on every lane of ``grouped_swiglu``: 24 rows
+    over three groups (under the ridge: the streaming kernel where the
+    backend takes kernels), and 776 (over it: the tiled one)."""
+    if impl != "ragged_dot":
         monkeypatch.setattr(moe, "matmul_backend", lambda: "interpret")
     rng = np.random.default_rng(1)
-    dtype = jnp.bfloat16 if impl == "stream" else jnp.float32
-    rows = jnp.asarray(rng.standard_normal((24, 128)), dtype)
+    dtype = jnp.float32 if impl == "ragged_dot" else jnp.bfloat16
+    n, sizes = (776, [300, 0, 470]) if impl == "tiled" else (24, [8, 0, 16])
+    rows = jnp.asarray(rng.standard_normal((n, 128)), dtype)
     gate, up = (jnp.asarray(rng.standard_normal((3, 128, 128)) * 0.1, dtype) for _ in "gu")
     down = jnp.asarray(rng.standard_normal((3, 128, 128)) * 0.1, dtype)
-    sizes = jnp.asarray([8, 0, 16], jnp.int32)
-    assert moe.expert_matmul_impl(24, 3, 128, 128, dtype, moe.matmul_backend()) == impl
+    sizes = jnp.asarray(sizes, jnp.int32)
+    assert moe.expert_matmul_impl(n, 3, 128, 128, dtype, moe.matmul_backend()) == impl
     got = {act: np.asarray(moe.grouped_swiglu(rows, gate, up, down, sizes, act=act),
                            np.float32) for act in ("silu", "relu")}
     group = np.repeat(np.arange(3), np.asarray(sizes))
@@ -304,7 +308,8 @@ def test_relu_is_not_silu(monkeypatch, impl):
         want = np.stack([
             f32(fn(f32(rows)[i] @ f32(gate)[g]) * (f32(rows)[i] @ f32(up)[g])) @ f32(down)[g]
             for i, g in enumerate(group)])
-        np.testing.assert_allclose(got[act], want, atol=0.05 if impl == "stream" else 1e-4)
-    assert np.abs(got["silu"] - got["relu"]).max() > 0.05
+        np.testing.assert_allclose(got[act][:len(group)], want,
+                                   atol=1e-4 if impl == "ragged_dot" else 0.05)
+    assert np.abs(got["silu"] - got["relu"])[:len(group)].max() > 0.05
     with pytest.raises(ValueError, match="activation"):
         moe.activation("gelu")
