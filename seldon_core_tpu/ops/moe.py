@@ -726,8 +726,11 @@ HELD_ROWS_HEADROOM = 4
 # kernel launches are not worth a smaller one.
 HELD_ROWS_MIN = 64
 # Over the ridge (a pass of :data:`STREAM_MAX_MEAN_ROWS` rows an expert
-# and more) it is the other way round: a row costs its FLOPs and three
-# float32 copies of ``(rows, d_model)`` whether it is real or empty, and
+# and more) it is the other way round: a row, real or empty, costs the
+# gather that brings it in, and a real one its FLOPs and the float32
+# ``d_model`` the down projection writes once (the readings below are
+# older: then ``ragged_dot`` spent FLOPs on empty rows too, and a mask
+# and a zero row copied ``(rows, d_model)`` in float32 twice more), and
 # a second pass costs only its rows.  So a pass holds this many times
 # the even share, in whole 512s.  The whole held pass on the v5e, ms a
 # layer, sized at that power of two / 2 x / 1.5 x / 1.25 x the even share
@@ -815,8 +818,10 @@ def expert_ffn_held(h, w_gate, w_up, w_down, gates, experts, offset: int,
     many passes as the local assignments need: one, unless routing
     piles onto this share.  A pass's rows go back to their tokens by
     ``top_k`` gathers of ``(T, d)`` (a token's j-th assignment reads its
-    row, or a zero row when it is absent or another pass's) — no
-    scatter, which serialises on a TPU."""
+    row; where it is absent or another pass's, the gathered row is
+    selected to zero before the gate multiplies it) — no scatter, which
+    serialises on a TPU, and no copy of the pass's ``(rows, d)`` result
+    between the kernel and the gathers."""
     import jax
     import jax.numpy as jnp
 
@@ -847,13 +852,16 @@ def expert_ffn_held(h, w_gate, w_up, w_down, gates, experts, offset: int,
         rows = rows_in[jnp.minimum(idx // top_k, tokens - 1)]       # (cap, d)
         out = grouped_swiglu(rows, w_gate, w_up, w_down, sizes, inner=inner,
                              act=act)
-        # rows past the groups hold whatever the kernel left there
-        out = jnp.where((which < held)[:, None], out, 0.0)
-        out = jnp.concatenate([out, jnp.zeros((1, d_model), out.dtype)])
+        # rows past the groups hold whatever the kernel left there (NaN
+        # for all the pass knows): no valid index points at one, and an
+        # absent assignment reads row 0 (real in any pass that runs) and
+        # is selected to zero — never multiplied to it
         at = rank - base
-        at = jnp.where(is_local & (at >= 0) & (at < cap), at, cap)
+        valid = is_local & (at >= 0) & (at < cap)
+        at = jnp.where(valid, at, 0)
         for j in range(top_k):
-            y = y + out[at[:, j]] * gates[:, j, None]
+            y = y + jnp.where(valid[:, j, None], out[at[:, j]],
+                              0.0) * gates[:, j, None]
         return base + cap, y
 
     _, y = jax.lax.while_loop(

@@ -172,19 +172,27 @@ def test_inside_a_scan_as_the_decode_chunk_runs_it(lane):
 # second pass, and the while_loop both run in
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("case", ["one_pass_with_pad_rows", "a_second_pass",
-                                  "nothing_local"])
-def test_held_experts_in_a_while_loop(lane, case):
-    """Experts 2..5 held, of 32 as the pass is sized: 64 rows
-    (``held_rows_cap``) of which random choices among the eight fill
-    about 40 (the rest lie past the groups and come back as whatever
-    the kernel left there); every token choosing two held experts is 80
-    and needs two passes; no token choosing any leaves the loop unrun."""
-    impl, dtype = lane
+HELD_LOOP_CASES = ["one_pass_with_pad_rows", "a_second_pass", "nothing_local"]
+
+
+def held_loop(*args):
+    """Experts 2..5 of 32 on :func:`held_loop_inputs`' arguments."""
+    return moe.expert_ffn_held(*args, 2, 32)
+
+
+def traced_anew(fn):
+    """``jax.jit`` of ``fn`` that shares no trace with another test's: the
+    lane's backend and a wrapped ``grouped_swiglu`` are read as the pass
+    is traced, and jit caches by the function it was handed."""
+    return jax.jit(lambda *args: fn(*args))
+
+
+def held_loop_inputs(case, dtype):
+    """Experts 2..5 held, of 32 as the pass is sized, 40 tokens choosing
+    two of eight: ``expert_ffn_held``'s arguments up to the gates and
+    experts, and the dense reference with the absent experts' gates at 0."""
     _w_router, w_gate, w_up, w_down = weights(8, dtype)
-    tokens, top_k, offset, held, of = 40, 2, 2, 4, 32
-    assert moe.held_rows_cap(tokens, top_k, held, of) == 64
-    assert runs(impl, 64, groups=held) == impl
+    tokens, top_k, offset, held = 40, 2, 2, 4
     h = jax.random.normal(jax.random.key(21), (tokens, D), jnp.float32).astype(dtype)
     rng = np.random.default_rng(12)
     if case == "one_pass_with_pad_rows":
@@ -197,11 +205,24 @@ def test_held_experts_in_a_while_loop(lane, case):
     experts = jnp.asarray(experts, jnp.int32)
     gates = jnp.asarray(rng.uniform(0.05, 0.5, size=(tokens, top_k)), jnp.float32)
     sl = slice(offset, offset + held)
-    got = jax.jit(lambda *a: moe.expert_ffn_held(*a, offset, of))(
-        h, w_gate[sl], w_up[sl], w_down[sl], gates, experts)
     local = (experts >= offset) & (experts < offset + held)
     want = dense(h, w_gate, w_up, w_down, jnp.where(local, gates, 0.0), experts)
-    assert got.shape == (tokens, D) and got.dtype == jnp.float32
+    return (h, w_gate[sl], w_up[sl], w_down[sl], gates, experts), want
+
+
+@pytest.mark.parametrize("case", HELD_LOOP_CASES)
+def test_held_experts_in_a_while_loop(lane, case):
+    """Experts 2..5 held, of 32 as the pass is sized: 64 rows
+    (``held_rows_cap``) of which random choices among the eight fill
+    about 40 (the rest lie past the groups and come back as whatever
+    the kernel left there); every token choosing two held experts is 80
+    and needs two passes; no token choosing any leaves the loop unrun."""
+    impl, dtype = lane
+    assert moe.held_rows_cap(40, 2, 4, 32) == 64
+    assert runs(impl, 64, groups=4) == impl
+    args, want = held_loop_inputs(case, dtype)
+    got = traced_anew(held_loop)(*args)
+    assert got.shape == (40, D) and got.dtype == jnp.float32
     assert np.abs(np.asarray(got - want)).max() < TOL[impl]
     if case == "nothing_local":
         assert not np.asarray(got).any()
@@ -467,18 +488,14 @@ def test_the_tiled_swiglu_is_float32_ragged_dot_s_inside_the_streams_tolerance(r
         assert np.abs(np.asarray(got)[:real] - np.asarray(other)[:real]).max() > 0.05
 
 
-@pytest.mark.parametrize("passes", [1, 2])
-def test_held_experts_over_the_ridge_through_the_tiled_kernel(monkeypatch, passes):
-    """``expert_ffn_held`` end to end where a pass is over the ridge, in
-    bf16 under the interpreter: 4 of 16 experts held, 1,024 tokens
-    choosing 4, a pass of 1,536 rows on the tiled kernel — against
-    ``expert_ffn`` over all sixteen with the absent experts' gates at 0
-    (16,384 / 16 = 256 rows an expert: the tiled kernel too)."""
-    monkeypatch.setattr(moe, "matmul_backend", lambda: "interpret")
+def over_the_ridge_inputs(passes):
+    """4 of 16 experts held, 1,024 tokens choosing 4, in bf16: a pass of
+    1,536 rows, filled by one pass's local picks or two's;
+    ``expert_ffn``'s arguments over all sixteen, the absent experts'
+    gates at 0."""
     tokens, top_k, held, of, offset = 1024, 4, 4, 16, 4
     cap = moe.held_rows_cap(tokens, top_k, held, of)
     assert cap == 1536
-    assert moe.expert_matmul_impl(cap, held, D, F, jnp.bfloat16, "interpret") == "tiled"
     ks = jax.random.split(jax.random.key(60 + passes), 4)
     bf16 = jnp.bfloat16
     w_gate = (jax.random.normal(ks[0], (of, D, F), jnp.float32) * D ** -0.5).astype(bf16)
@@ -495,18 +512,103 @@ def test_held_experts_over_the_ridge_through_the_tiled_kernel(monkeypatch, passe
         for n in local_picks]).astype(np.int32))
     assert -(-int(local_picks.sum()) // cap) == passes
     gates = jnp.asarray(rng.uniform(0.05, 0.5, size=(tokens, top_k)), jnp.float32)
-    sl = slice(offset, offset + held)
-    got = jax.jit(lambda *a: moe.expert_ffn_held(*a, offset, of))(
-        h, w_gate[sl], w_up[sl], w_down[sl], gates, experts)
     local = (experts >= offset) & (experts < offset + held)
-    masked = jnp.where(local, gates, 0.0)
-    assert moe.expert_matmul_impl(tokens * top_k, of, D, F, bf16, "interpret") == "tiled"
-    whole = jax.jit(moe.expert_ffn)(h, w_gate, w_up, w_down, masked, experts)
-    want = dense(h, w_gate, w_up, w_down, masked, experts)
-    assert got.shape == (tokens, D) and got.dtype == jnp.float32
+    return h, w_gate, w_up, w_down, jnp.where(local, gates, 0.0), experts
+
+
+def held_ridge(h, w_gate, w_up, w_down, gates, experts):
+    """Experts 4..7 of 16 on :func:`over_the_ridge_inputs`' arguments."""
+    return moe.expert_ffn_held(h, w_gate[4:8], w_up[4:8], w_down[4:8], gates, experts, 4, 16)
+
+
+@pytest.mark.parametrize("passes", [1, 2])
+def test_held_experts_over_the_ridge_through_the_tiled_kernel(monkeypatch, passes):
+    """``expert_ffn_held`` end to end where a pass is over the ridge, in
+    bf16 under the interpreter: 4 of 16 experts held, 1,024 tokens
+    choosing 4, a pass of 1,536 rows on the tiled kernel — against
+    ``expert_ffn`` over all sixteen with the absent experts' gates at 0
+    (16,384 / 16 = 256 rows an expert: the tiled kernel too)."""
+    monkeypatch.setattr(moe, "matmul_backend", lambda: "interpret")
+    bf16 = jnp.bfloat16
+    assert moe.expert_matmul_impl(1536, 4, D, F, bf16, "interpret") == "tiled"
+    args = over_the_ridge_inputs(passes)
+    got = traced_anew(held_ridge)(*args)
+    assert moe.expert_matmul_impl(1024 * 4, 16, D, F, bf16, "interpret") == "tiled"
+    whole = jax.jit(moe.expert_ffn)(*args)
+    want = dense(*args)
+    assert got.shape == (1024, D) and got.dtype == jnp.float32
     assert np.abs(np.asarray(got - want)).max() < TOL["tiled"]
     assert np.abs(np.asarray(whole - want)).max() < TOL["tiled"]
     assert np.abs(np.asarray(want)).max() > 0.1
+
+
+@pytest.mark.parametrize("case", [f"loop-{c}" for c in HELD_LOOP_CASES]
+                         + ["ridge-1", "ridge-2"])
+def test_rows_past_the_groups_never_reach_a_token(monkeypatch, case):
+    """A grouped matmul owes nothing past its last group (the tiled
+    kernel never writes there): with every such row NaN the held pass is
+    finite and the dense reference's, under the ridge (one pass with pad
+    rows, a second pass, none) and over it (one pass, two).  An absent
+    assignment is selected to zero, not multiplied to it."""
+    monkeypatch.setattr(moe, "matmul_backend", lambda: "interpret")
+    real, passes = moe.grouped_swiglu, []
+
+    def poisoned(rows, w_gate, w_up, w_down, sizes, **kw):
+        out = real(rows, w_gate, w_up, w_down, sizes, **kw)
+        past = jnp.arange(out.shape[0]) >= sizes.sum()
+        passes.append(out.shape)
+        return jnp.where(past[:, None], jnp.nan, out)
+
+    monkeypatch.setattr(moe, "grouped_swiglu", poisoned)
+    kind, which = case.split("-")
+    if kind == "loop":
+        args, want = held_loop_inputs(which, jnp.bfloat16)
+        got, rows, tol = traced_anew(held_loop)(*args), 64, TOL["stream"]
+    else:
+        args = over_the_ridge_inputs(int(which))
+        got, want, rows, tol = traced_anew(held_ridge)(*args), dense(*args), 1536, TOL["tiled"]
+    assert passes == [(rows, D)]  # the pass the loop traced was the poisoned one
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.abs(np.asarray(got - want)).max() < tol
+
+
+def eqns_of(fn, *args):
+    """Every equation ``fn`` traces, loop and call bodies included."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            found.append(eqn)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("regime", ["under_the_ridge", "over_the_ridge"])
+def test_no_copy_of_a_pass_s_result_stands_before_the_gathers(monkeypatch, regime):
+    """Between the grouped matmul and the ``top_k`` gathers nothing
+    rewrites the pass's ``(rows, d_model)`` float32 result: no mask
+    (``select_n``), no zero row under it (``concatenate`` / ``pad``: 235
+    MB each way a layer at Xing4's 16,384 x 3,584).  The select is over
+    the gathered ``(tokens, d_model)`` rows, once an assignment — the
+    shapes here have ``tokens != rows`` so the two cannot be confused."""
+    monkeypatch.setattr(moe, "matmul_backend", lambda: "interpret")
+    if regime == "under_the_ridge":
+        fn, (args, _want), tokens, top_k, rows = (
+            held_loop, held_loop_inputs("a_second_pass", jnp.bfloat16), 40, 2, 64)
+    else:
+        fn, args, tokens, top_k, rows = held_ridge, over_the_ridge_inputs(1), 1024, 4, 1536
+    shaped = [(eqn.primitive.name, tuple(out.aval.shape))
+              for eqn in eqns_of(fn, *args) for out in eqn.outvars]
+    assert (rows, D) in [shape for _name, shape in shaped]     # the pass is in there
+    copies = [(name, shape) for name, shape in shaped
+              if name in ("select_n", "concatenate", "pad")
+              and shape in ((rows, D), (rows + 1, D))]
+    assert copies == []
+    assert shaped.count(("select_n", (tokens, D))) == top_k
+    assert shaped.count(("gather", (tokens, D))) == top_k
 
 
 def test_the_rule_is_a_function_of_shape_type_and_backend():

@@ -293,6 +293,7 @@ def test_held_expert_layer_compiles_with_rows_of_a_pass(one_chip, mosaic, backen
     assert cap == {128: 128, 1024: 1024, 8192: 3072}[rows]
     assert f"[{cap},{f}]" in text or f"[{cap},{d}]" in text
     assert f"[{rows * k},{d}]" not in text  # never all the assignments' rows
+    assert f"[{cap + 1},{d}]" not in text   # nor a zero row under a pass's result
     assert compiled.memory_analysis().temp_size_in_bytes < 12 * rows * d * 4
 
 

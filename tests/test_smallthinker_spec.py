@@ -130,18 +130,21 @@ SHIPPED = {
                        index_topk=16, **LATENT),
 }
 # sha256 of each program's lowered text (prefill_b16_k2, chunk_s2_4x4,
-# chunk_s2_2x2_2x8) on the tree before this PR (commit 236919b), by lane.
+# chunk_s2_2x2_2x8) on the tree before this PR (commit 236919b), by lane;
+# the three specs that hold a share as PR 47 left them (commit 02ec28c +
+# ``expert_ffn_held`` without its mask and zero-row copies: measured in
+# their cells, PERF.md section 6).
 # A PR that changes what one of these specs traces on purpose measures
 # its cell and replaces the line.
 PARENT_SHA = {
     "olmoe/kernel": ("5dfa0a7a6a5fb154", "cf4d3494fbabf487", "bd5e3f041abd3a30"),
     "olmoe/gather": ("99e68f4f609239bc", "d1d143ef68dd754f", "a564dbb262ecc1d5"),
-    "deepseek_v3/kernel": ("52e612d1db73f3c1", "3e6c39024cb55d7e", "ba62e324c3913add"),
-    "deepseek_v3/gather": ("52e612d1db73f3c1", "4e248f27dc0bf109", "f3ec3b97bceec53a"),
-    "longcat_flash/kernel": ("6649ed7d6d8b4760", "1d5a4acee9490509", "0889430d212d3425"),
-    "longcat_flash/gather": ("6649ed7d6d8b4760", "b5788586f60b6842", "6e1163a5d2226dc0"),
-    "dots3_note/kernel": ("92914811d014f1f5", "3bc4790f970d8dfd", "f33472e724557455"),
-    "dots3_note/gather": ("92914811d014f1f5", "7707055d6d512136", "8135206dcb1e432b"),
+    "deepseek_v3/kernel": ("74b97f7e28be1e79", "147b6b2da8391aad", "fb83e7965316425b"),
+    "deepseek_v3/gather": ("74b97f7e28be1e79", "161d1c29369952f7", "7caf9f1a8407537c"),
+    "longcat_flash/kernel": ("c8644a2db8d31e72", "35c56975942fe0dc", "e826ad7641948da1"),
+    "longcat_flash/gather": ("c8644a2db8d31e72", "516e14cd27e54d4d", "09a1306ca2b66391"),
+    "dots3_note/kernel": ("3a1940b4d53c2fd5", "1c07de2a02b9fd84", "c884b5a8d0931434"),
+    "dots3_note/gather": ("3a1940b4d53c2fd5", "efe0631bdb5ac2f0", "67b5f8b8bab76ba8"),
 }
 
 

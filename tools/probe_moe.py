@@ -39,13 +39,15 @@ the chip ``--rehearse`` runs a toy size through the
 Pallas interpreter for control flow only and prints no rate.
 
 ``--held`` times the WHOLE held pass instead (``moe.expert_ffn_held``:
-sort, row gather, the grouped SwiGLU, mask, pad, the gathers back to
-the tokens), a line per (``--tokens``, rows a pass): a replica's share
-at ``--widths smallthinker|gigachat|dots3`` routed near-evenly from
+sort, row gather, the grouped SwiGLU, the gathers back to the tokens
+under their select), a line per (``--tokens``, rows a pass): a
+replica's share at any ``--widths`` (``xing4`` and ``olmoe`` hold every
+expert) routed near-evenly from
 ``--seed``, the pass sized by :func:`moe.held_rows_cap`, at the power of
 two over ``HELD_ROWS_HEADROOM`` even shares (the rule under the ridge) and
 at each ``--cap-over-even`` times the even share in whole 512s (under 1 the
-local assignments need a second pass: what that costs).  It is the
+local assignments need a second pass: what that costs); ``--sized rule``
+keeps the rule's own size alone.  It is the
 table ``HELD_ROWS_RIDGE_HEADROOM`` was chosen from.
 """
 
@@ -142,6 +144,8 @@ def held_sweep(args, dev, on_chip: bool) -> int:
         for over in args.cap_over_even:
             caps[f"{over}x"] = max(512, math.ceil(over * even / 512) * 512)
         for label, cap in caps.items():
+            if args.sized and label not in args.sized:
+                continue
             moe.held_rows_cap = lambda *_a, cap=cap: cap   # read as the pass is traced
 
             @jax.jit
@@ -193,6 +197,8 @@ def main() -> int:
                     default=[2048, 3072, 4096, 6144, 8192])
     ap.add_argument("--cap-over-even", type=float, nargs="*",
                     default=[2.0, 1.5, 1.25, 0.75])
+    ap.add_argument("--sized", nargs="*", default=None,
+                    help="of --held's sizes (rule, 4x_pow2, 1.5x, ...) only these")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--reps", type=int, default=16)
     args = ap.parse_args()
