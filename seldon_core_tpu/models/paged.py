@@ -188,7 +188,20 @@ def prefill_position_bytes(spec, d_model: int, vocab_size: int,
         attn = 2 * num_heads * (2 * qk + spec.v_dim) + 4 * num_heads * spec.v_dim
     else:
         attn = 10 * d_model
-    if not spec.routed:
+    if spec.linear:
+        # a linear layer's rows: q, k, v after the convolution and as the
+        # scan lays them (float32, twice), the scan's two solved right-hand
+        # sides and its output, and a chunk's three (64, 64) matrices a
+        # head (ops/delta.py CHUNK positions share them)
+        from seldon_core_tpu.ops import delta
+
+        qkv = 2 * spec.lin_key_dim + spec.lin_value_dim
+        attn = max(attn, 4 * spec.lin_heads * (
+            2 * qkv + 2 * (spec.lin_key_dim + spec.lin_value_dim)
+            + 3 * delta.CHUNK))
+    if spec.ffn == "swiglu":
+        ffn = 10 * spec.dense_width  # gate, up and their product
+    elif not spec.routed:
         ffn = 6 * 4 * d_model  # the GELU MLP's hidden rows
     else:
         swiglu = 10  # bytes a hidden value: gate, up, their product
@@ -367,6 +380,8 @@ def _build_modules():
         spec = mod.spec
         if spec.score == "sigmoid":
             return _ffn_grouped(mod, x, token_mask)
+        if spec.ffn == "swiglu":
+            return _ffn_swiglu(mod, x), ()
         d_model = x.shape[-1]
         y = _norm(spec, "ffn_norm")(x)
         if not spec.routed:
@@ -410,6 +425,21 @@ def _build_modules():
             experts, e,
             None if token_mask is None else token_mask.reshape(-1))
         return x + out.reshape(x.shape).astype(x.dtype), (hist,)
+
+    def _ffn_swiglu(mod, x):
+        """``x + FFN(x)`` for a spec whose every layer holds a dense
+        SwiGLU of ``spec.dense_width`` (``ffn == "swiglu"``): the norm on
+        the FFN's input, or under ``spec.post_norm`` on its OUTPUT before
+        the residual add and none on its input."""
+        spec = mod.spec
+        rows = x if spec.post_norm else _norm(spec, "ffn_norm")(x)
+        out = _swiglu_ffn(
+            mod, rows.reshape(-1, x.shape[-1]),
+            ("mlp_gate", "mlp_up", "mlp_down"), spec.dense_width,
+        ).reshape(x.shape)
+        if spec.post_norm:
+            out = _norm(spec, "ffn_post_norm")(out)
+        return x + out.astype(x.dtype)
 
     def _swiglu_ffn(mod, rows, names, width):
         """A dense SwiGLU FFN (or a shared expert) of ``width`` over
@@ -943,7 +973,9 @@ def _build_modules():
         def proj(name, features, inp):
             return _dense(mod.precision, features, mod.dtype, name, spec)(inp)
 
-        y = _norm(spec, "attn_norm")(x)
+        # (post-norm: the sub-layer reads the stream as it is, and its
+        # output is normed before the residual add)
+        y = x if spec.post_norm else _norm(spec, "attn_norm")(x)
         router_logits = None
         if spec.router_from == "attn_input":
             w_router = mod.param(
@@ -1004,8 +1036,10 @@ def _build_modules():
                 [jnp.zeros((), jnp.int32), rows_read.astype(jnp.int32),
                  jnp.zeros((), jnp.int32)]))
         attn = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
-        x = x + proj("attn_proj", d_model,
-                     attn.reshape(batch, seg_len, q_w))
+        attn = proj("attn_proj", d_model, attn.reshape(batch, seg_len, q_w))
+        if spec.post_norm:
+            attn = _norm(spec, "attn_post_norm")(attn).astype(x.dtype)
+        x = x + attn
         x, hist = _ffn(mod, x, proj, token_mask, router_logits)
         if kind is None:
             return (x, k_flat, v_flat, *hist)
@@ -1085,6 +1119,96 @@ def _build_modules():
             return {"phi": self.param("phi", init, (k, n * d_model), jnp.float32),
                     "bias": self.param("bias", init, (k,), jnp.float32),
                     "scale": self.param("scale", init, (3,), jnp.float32)}
+
+    class DeltaBlock(nn.Module):
+        """A linear-attention layer (Gated DeltaNet, ops/delta.py) and its
+        FFN: the layer of a spec with ``"linear"`` layer kinds that keeps
+        no pages.  With ``x`` the stream ``(B, L, d)``: q, k and v (one
+        ``qkv`` projection, ``spec.lin_channels`` wide) pass a causal
+        depthwise convolution of ``spec.lin_conv`` taps and SiLU; a head's
+        q and k are normalised (q times ``d_k ** -0.5``); ``beta`` and the
+        decay ``alpha`` come of the float32 ``ab`` projection, ``a_log``
+        and ``dt_bias``; the recurrence's output is RMS-normed a head
+        (``o_norm``), gated by ``silu(gate)`` and projected back.
+
+        Two calls.  **A prefill from position zero** (``state`` None):
+        ``true_lens`` ``(B,)`` are the rows' real lengths; positions past
+        them pass the pad rule, so the state ``(B, H / p, d_k, p x d_v)``
+        and the convolution's tail ``(B, taps - 1, channels)`` that come
+        back are those at each row's LAST REAL position.  **A decode
+        step** (``L`` 1): ``state`` and ``tail`` as they rest, row ``b``
+        its own lane's; a lane ``active`` leaves out keeps both.  Returns
+        ``(x, state, tail)``."""
+
+        dtype: Any = jnp.bfloat16
+        precision: str = "bf16"
+        spec: Any = GPT2
+
+        @nn.compact
+        def __call__(self, x, state=None, tail=None, true_lens=None,
+                     active=None):
+            from seldon_core_tpu.ops import delta
+
+            spec = self.spec
+            heads, dk, dv = spec.lin_heads, spec.lin_key_dim, spec.lin_value_dim
+            batch, seg_len, d_model = x.shape
+            pack = delta.pack_of(heads, dv)
+            rest = _rest(spec, self.dtype)
+            init = nn.initializers.normal(0.02)
+
+            def proj(name, features, inp):
+                return _dense(self.precision, features, self.dtype, name,
+                              spec)(inp)
+
+            y = x if spec.post_norm else _norm(spec, "attn_norm")(x)
+            qkv = proj("qkv", spec.lin_channels, y)
+            gate = proj("gate", heads * dv, y)
+            # the two gates' projection: float32 at rest and in use, as a
+            # router (alpha is an exponential of it)
+            w_ab = self.param("ab", init, (d_model, 2 * heads), jnp.float32)
+            ab = jnp.einsum("bld,dh->blh", y.astype(jnp.float32), w_ab,
+                            precision=jax.lax.Precision.HIGHEST)
+            log_alpha, beta = delta.gates(
+                ab[..., :heads], ab[..., heads:],
+                self.param("a_log", init, (heads,), jnp.float32),
+                self.param("dt_bias", init, (heads,), jnp.float32),
+                spec.lin_neg_eigval)
+            taps = self.param("conv", init, (spec.lin_conv, spec.lin_channels),
+                              rest)
+            if state is None:
+                mixed, tail = delta.conv(qkv, taps, true_lens)
+            else:
+                mixed, tail = delta.conv_step(tail, qkv[:, 0], taps, active)
+                mixed = mixed[:, None]
+            q = mixed[..., :heads * dk].reshape(batch, seg_len, heads, dk)
+            k = mixed[..., heads * dk:2 * heads * dk].reshape(
+                batch, seg_len, heads, dk)
+            v = mixed[..., 2 * heads * dk:].reshape(batch, seg_len, heads, dv)
+            q = delta.l2norm(q) * float(dk) ** -0.5
+            k = delta.l2norm(k)
+            if state is None:
+                if true_lens is not None:  # the pad rule past a row's length
+                    real = (jnp.arange(seg_len)[None, :]
+                            < true_lens[:, None])[..., None]
+                    log_alpha = jnp.where(real, log_alpha, 0.0)
+                    beta = jnp.where(real, beta, 0.0)
+                out, state = delta.chunked_scan(q, k, v, log_alpha, beta)
+                state = delta.pack_state(state, pack)
+            else:
+                state, out = delta.step(
+                    state, q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0],
+                    beta[:, 0], pack=pack, active=active)
+                out = out[:, None]
+            out = nn.RMSNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
+                             name="o_norm")(out)
+            out = out * jax.nn.silu(
+                gate.astype(jnp.float32).reshape(batch, seg_len, heads, dv))
+            out = proj("attn_proj", d_model,
+                       out.reshape(batch, seg_len, heads * dv))
+            if spec.post_norm:
+                out = _norm(spec, "attn_post_norm")(out).astype(x.dtype)
+            x = x + out
+            return _ffn_swiglu(self, x), state, tail
 
     class PagedTransformerBlock(nn.Module):
         """TransformerBlock whose attention reads a paged K/V pool.
@@ -1580,7 +1704,7 @@ def _build_modules():
         @nn.compact
         def __call__(self, tokens, positions, pages_k, pages_v, block_tables,
                      lengths, lora=None, adapter_idx=None, kv_scales=None,
-                     token_mask=None, window=None):
+                     token_mask=None, window=None, delta=None):
             x = _embed(self, tokens, positions)
             # The kernel lane (no TP mesh — decode_kernel=False is how
             # the engine encodes one; env, dtype, backend: the shared
@@ -1599,6 +1723,9 @@ def _build_modules():
                 return self._kinds(x, positions, pages_k, pages_v,
                                    block_tables, lengths, token_mask, window,
                                    whole)
+            if self.spec.linear:
+                return self._hybrid(x, positions, pages_k, pages_v,
+                                    block_tables, lengths, whole, delta or {})
             for i in range(self.num_layers):
                 if whole:
                     pools = (pages_k, pages_v)
@@ -1634,6 +1761,58 @@ def _build_modules():
                 new_v.append(v)
                 hists += hist
             return _head(self, x, new_k, new_v, hists)
+
+        def _hybrid(self, x, positions, pages_k, pages_v, block_tables,
+                    lengths, whole, delta):
+            """The layers of a spec with linear-attention layers: a
+            ``"linear"`` layer is a :class:`DeltaBlock` over its own state
+            and keeps no pages; a ``"full"`` layer is the grouped-query
+            block over the K/V pool, whose leading axis counts the full
+            layers alone (``spec.kind_index``).
+
+            ``delta`` is the linear layers' side of the call.  A prefill
+            from zero: ``{"true_lens": (B,)}``.  A decode step:
+            ``{"state": (a layer's resting state, ...), "conv": (its
+            tail, ...), "active": (slots,) bool}``, every one in SLOT
+            order, and ``"order"`` = ``(to_slot, to_lane)`` where the
+            call's lanes are a permutation of the slots (the bucketed
+            chunk): the stream's rows are gathered to slot order round a
+            linear layer, never the state.  Returns ``(logits, K, V,
+            states, tails)``, the last two a tuple a linear layer."""
+            spec = self.spec
+            new_k, new_v, states, tails = [], [], [], []
+            order = delta.get("order")
+            for i in range(self.num_layers):
+                at = spec.kind_index(i)
+                if spec.layer_kind(i) == "linear":
+                    block = DeltaBlock(dtype=self.dtype, precision=self.precision,
+                                       spec=spec, name=f"block_{i}")
+                    if "state" in delta:
+                        rows = x if order is None else x[order[0]]
+                        rows, state, tail = block(
+                            rows, delta["state"][at], delta["conv"][at],
+                            active=delta["active"])
+                        x = rows if order is None else rows[order[1]]
+                    else:
+                        x, state, tail = block(x, true_lens=delta.get("true_lens"))
+                    states.append(state)
+                    tails.append(tail)
+                    continue
+                pools = ((pages_k, pages_v) if whole
+                         else (pages_k[at], pages_v[at]))
+                x, (_name, k), v, *_read = PagedTransformerBlock(
+                    num_heads=self.num_heads, dtype=self.dtype,
+                    precision=self.precision, name=f"block_{i}", spec=spec,
+                    kind=spec.attn_kind(i, self.num_heads),
+                )(x, *pools, block_tables, lengths,
+                  layer=at if whole else None, positions=positions)
+                new_k.append(k)
+                new_v.append(v)
+            x = _norm(spec, "final_norm")(x)
+            logits = _dense(self.precision, self.vocab_size, self.dtype,
+                            "head", spec)(x)
+            return (logits.astype(jnp.float32), jnp.stack(new_k),
+                    jnp.stack(new_v), tuple(states), tuple(tails))
 
         def _kinds(self, x, positions, pools, pools_v, block_tables, lengths,
                    token_mask, window, whole):
@@ -1723,6 +1902,65 @@ def kv_join(pages, scales):
     if scales is None:
         return pages
     return (pages, scales)
+
+
+def delta_split(pool):
+    """``(K pool, (states, tails))`` of a K-pool argument that carries a
+    linear spec's state a lane (``PagedEngine._kv_args``: ``{"kv",
+    "state", "conv"}``), and ``(pool, None)`` of any other."""
+    if isinstance(pool, dict) and "state" in pool:
+        return pool["kv"], (pool["state"], pool["conv"])
+    return pool, None
+
+
+def delta_join(pool, delta):
+    """:func:`delta_split`'s inverse."""
+    if delta is None:
+        return pool
+    return {"kv": pool, "state": tuple(delta[0]), "conv": tuple(delta[1])}
+
+
+def delta_prefill_kwarg(delta, true_lens):
+    """``{"delta": ...}`` for a prefill from zero of a spec with linear
+    layers (the rows' real lengths: the pad rule's edge), ``{}`` for any
+    other — like :func:`window_kwarg`, a helper so that a jitted program
+    spells no branch on what is a fact of the call's structure."""
+    if delta is None:
+        return {}
+    return {"delta": {"true_lens": true_lens}}
+
+
+def delta_written(delta, hist, slots):
+    """A prefill's ``(resting state, what is left of hist)``: each row's
+    state and tail as of its last real position (``hist``: the LM's
+    ``(states, tails)``) written at ``slots`` over whatever the slot's
+    last stream left — a pad row names a slot past the last, which the
+    scatter drops.  ``(None, hist)`` for a spec without linear layers."""
+    if delta is None:
+        return None, hist
+    states, tails = hist
+    return ([rest.at[slots].set(new, mode="drop")
+             for rest, new in zip(delta[0], states)],
+            [rest.at[slots].set(new.astype(rest.dtype), mode="drop")
+             for rest, new in zip(delta[1], tails)]), ()
+
+
+def delta_step_kwarg(delta, active, order):
+    """``{"delta": ...}`` for a decode step: the state as it rests, the
+    lanes that run (in slot order) and the lanes' order, or ``{}``."""
+    if delta is None:
+        return {}
+    return {"delta": {
+        "state": delta[0], "conv": delta[1], "order": order,
+        "active": active if order is None else active[order[0]]}}
+
+
+def delta_carried(delta, hist):
+    """A decode step's ``(state to carry, what is left of hist)``: the
+    LM's ``(states, tails)`` take the resting ones' place."""
+    if delta is None:
+        return None, hist
+    return hist, ()
 
 
 def window_kwarg(window):
@@ -2067,6 +2305,7 @@ def paged_hbm_accounting(
     weight_bytes: int = 0,
     cache_pools: int = 2,
     cache_kinds: Sequence[Tuple[int, int, int]] = (),
+    state_bytes: int = 0,
 ) -> Dict[str, int]:
     """Pool-HBM bytes for ``streams`` concurrent streams at ``ctx_len``
     tokens — the capacity model the bench certifies (VERDICT r5 #3/#5).
@@ -2181,6 +2420,13 @@ def paged_hbm_accounting(
       (``spec.cache_layers``: two a LongCat-Flash layer); the default 2
       is K and V of ``d_model`` a layer.
 
+    * **a state a lane** — ``state_bytes``: what ONE stream's
+      linear-attention state takes as it rests (``ModelSpec.state_bytes``:
+      every linear layer's float32 state and convolution inputs; 0
+      without such layers), whatever its context: ``streams`` of them are
+      a term of ``peak_bytes`` and of ``per_stream_bytes`` beside the
+      pages (``num_layers`` then counts the layers that keep pages).
+
     * **a cache of row kinds** — ``cache_kinds``: ``(layers, lanes,
       window)`` a kind (``spec.cache_kinds`` with the window layers'
       ``spec.window``, 0 for a kind whose pages grow with the stream), in
@@ -2234,6 +2480,7 @@ def paged_hbm_accounting(
             * num_layers * d_model * cache_pools * dtype_bytes * split_tile_pad
         ) // kv_shard
     at_rest = pool if donated else 2 * pool
+    state = int(streams) * int(state_bytes)
     inflight_pages = -(-int(inflight_prefill_tokens) // page_size)
     inflight = int(inflight_pages * page_bytes) // kv_shard
     return {
@@ -2241,9 +2488,10 @@ def paged_hbm_accounting(
         "window_bytes": window_bytes // kv_shard,
         "working_set_bytes": ws,
         "peak_bytes": (at_rest + ws + inflight + int(adapter_bytes)
-                       + int(weight_bytes)),
+                       + int(weight_bytes) + state),
         "weight_bytes": int(weight_bytes),
-        "per_stream_bytes": (at_rest + ws) // max(1, streams),
+        "state_bytes": state,
+        "per_stream_bytes": (at_rest + ws + state) // max(1, streams),
         "reclaimable_bytes": int(
             cached_prefix_pages * page_bytes
         ) // kv_shard + int(reclaimable_weight_bytes),
@@ -3031,6 +3279,57 @@ class PagedEngine:
                 if asked:
                     raise ValueError(self._kinds_refusal(what, why))
             prefix_cache = False  # (unset: the env's default is not asked)
+        if spec.linear:
+            # linear-attention layers keep a state a lane that rests with
+            # the SLOT, not in pages (ops/delta.py): what assumes that a
+            # stream's whole state is its pages is refused here, by name
+            for asked, what, why in (
+                (speculative, "the speculative lane",
+                 "its verify forward writes k + 1 rows a lane and rolls "
+                 "back by length, and a linear layer's state keeps no "
+                 "earlier value to roll back to — serve it with "
+                 "speculative=None"),
+                (_knobs.flag("SELDON_TPU_KV_OFFLOAD"),
+                 "the host KV tier (SELDON_TPU_KV_OFFLOAD)",
+                 "its containers hold a K and a V block a page; a parked "
+                 "stream's state would have to travel with them"),
+                (prefix_cache, "the prefix cache (prefix_cache=True)",
+                 "a cached prefix's pages are usable only beside the "
+                 "linear layers' state as of its last token, and no "
+                 "snapshot of a state is kept — leave prefix_cache unset "
+                 "or false"),
+                (chunk_token_budget or int(
+                    _knobs.raw("SELDON_TPU_CHUNK_TOKEN_BUDGET", "0") or 0),
+                 "chunked prefill (chunk_token_budget)",
+                 "its slices are cached-suffix prefills, and the chunked "
+                 "scan starts from a state of zeros"),
+                (max_adapters or int(
+                    _knobs.raw("SELDON_TPU_MAX_ADAPTERS", "0") or 0),
+                 "multi-LoRA adapters (max_adapters)",
+                 "the factor pools name one attention's projections a "
+                 "layer"),
+                (mesh is not None, "a mesh (tp or dp over 1)",
+                 "no sharding rule places a state a lane, and the "
+                 "recurrence's heads are not the pool's — serve it on one "
+                 "chip (tp=1, dp=1)"),
+                (quantize or self.precision != "bf16",
+                 "int8 weights (quantize / precision)",
+                 "the surgery and the w8a8 projections have not been held "
+                 "to the decay's exponent of a projection — serve it with "
+                 "precision 'bf16'"),
+                (_knobs.raw("SELDON_TPU_CHUNK_IMPL", "") == "ring",
+                 "the ring chunk (SELDON_TPU_CHUNK_IMPL=ring)",
+                 "its once-per-chunk context is gathered through one "
+                 "block table for every layer — leave the knob unset or "
+                 "set it to pool"),
+                (paged_kv_dtype_mode() == "int8",
+                 "the int8 KV pool (SELDON_TPU_KV_DTYPE=int8)",
+                 "the page loop over grouped heads has no dequantising "
+                 "lane"),
+            ):
+                if asked:
+                    raise ValueError(self._linear_refusal(what, why))
+            prefix_cache = False  # (unset: the env's default is not asked)
         if quantize == "int8":
             # weight-only int8: weights rest in HBM at half the bytes
             # and dequantise once per chunk program (measured 1.38x
@@ -3150,7 +3449,8 @@ class PagedEngine:
             # a latent pool decodes in the pool chunk whichever lane its
             # attention takes (the kernel, or the gather and einsums)
             self._chunk_impl = (
-                "pool" if kernel_eligible or spec.latent or spec.kinds
+                "pool" if (kernel_eligible or spec.latent or spec.kinds
+                           or spec.linear)
                 else "ring")
             if kernel_eligible:
                 logger.info(
@@ -3186,7 +3486,15 @@ class PagedEngine:
         # traffic degenerates to one bucket automatically (identical
         # horizons), so the uniform-load programs are byte-identical
         # with the knob on.
-        buckets_env = _knobs.raw("SELDON_TPU_CTX_BUCKETS", "") or "2"
+        # (a spec with linear-attention layers runs ONE bucket unless the
+        # knob says otherwise: the split exists to spare the page loop's
+        # table at short contexts, which here is two layers of eight since
+        # the kernel pays for live pages only, while every second bucket
+        # spec is one more compiled chunk program — 6 of 10 in the served
+        # cell, ~17 s of set-up each — and makes the lanes a permutation
+        # of the slots the state rests by)
+        buckets_env = (_knobs.raw("SELDON_TPU_CTX_BUCKETS", "")
+                       or ("1" if spec.linear else "2"))
         if buckets_env not in ("1", "2"):
             raise ValueError(
                 f"SELDON_TPU_CTX_BUCKETS={buckets_env!r}: supported values "
@@ -3323,6 +3631,32 @@ class PagedEngine:
             self._wtables = np.zeros(
                 (self.max_slots, self.window_pages), np.int32)
             self._wbase = np.zeros((self.max_slots,), np.int32)
+        # linear-attention layers: a state a lane, beside the pages.  One
+        # array a linear layer — ``ops/delta.py state_shape`` float32 over
+        # the slots, and the convolution's last inputs ``(slots, taps - 1,
+        # channels)`` in the compute type — so that a layer's update
+        # replaces its own array and nothing of the others moves; a
+        # prefill writes its slots' rows, a chunk carries them all
+        self._delta_state: Tuple[Any, ...] = ()
+        self._delta_conv: Tuple[Any, ...] = ()
+        self._delta_layers = spec.state_layers(num_layers)
+        if spec.linear:
+            from seldon_core_tpu.ops import delta as _delta
+
+            shape = _delta.state_shape(
+                self.max_slots, spec.lin_heads, spec.lin_key_dim,
+                spec.lin_value_dim)
+            self._delta_state = tuple(
+                jnp.zeros(shape, jnp.float32)
+                for _ in range(self._delta_layers))
+            self._delta_conv = tuple(
+                jnp.zeros((self.max_slots, spec.lin_conv - 1,
+                           spec.lin_channels), dtype)
+                for _ in range(self._delta_layers))
+        # ... in bytes as it rests, every slot's (what the tiling pads
+        # counted): lane_report's and the gauge's delta_state_bytes, and a
+        # term of what a prefill call may not take
+        self._delta_state_bytes = self.max_slots * spec.state_bytes(num_layers)
         # the served tree as it rests (all shards): lane_report's
         # weight_bytes, paged_hbm_accounting's fixed term
         from seldon_core_tpu.ops.surgery import tree_hbm_bytes
@@ -3377,12 +3711,15 @@ class PagedEngine:
         self._hyper_sublayers = 2 * num_layers if spec.hc_mult else 0
         self.prefill_positions_max = prefill_positions_max(
             None if limit is None
-            else int(limit) - resting - self._pool_shard_bytes,
+            else (int(limit) - resting - self._pool_shard_bytes
+                  - self._delta_state_bytes),
             prefill_position_bytes(spec, d_model, self.vocab_size, num_heads))
         logger.info(
             "a prefill call takes at most %s positions (%s B of HBM, %d "
-            "resting, %d pool)", self.prefill_positions_max, limit, resting,
-            self._pool_shard_bytes)
+            "resting, %d pool%s)", self.prefill_positions_max, limit, resting,
+            self._pool_shard_bytes,
+            f", {self._delta_state_bytes} state a lane x {self.max_slots} slots"
+            if spec.linear else "")
         # lane sharding (r19): under dp>1 the slot-major host arrays
         # (logits, block tables, sampling knobs, rng keys) batch-shard
         # on the data axis — each replica group carries max_slots/dp
@@ -3677,6 +4014,14 @@ class PagedEngine:
                           # k x bucket, a step's max_slots lanes
                           "hyper_prefill_positions": 0,
                           "hyper_decode_positions": 0,
+                          # linear-attention layers (spec.linear,
+                          # ops/delta.py; 0 otherwise): lane-steps x
+                          # linear layers the decode steps ran, padded
+                          # positions x linear layers the prefill calls
+                          # scanned, and the real ones among them
+                          "delta_lane_steps": 0,
+                          "delta_prefill_positions": 0,
+                          "delta_prefill_real_positions": 0,
                           # a spec with layer kinds (0 otherwise): what
                           # its selection and its windows read (the
                           # chunk's counter row, _sparse_step) and the
@@ -4027,9 +4372,24 @@ class PagedEngine:
             f"{what} cannot take it yet — {why}"
         )
 
+    def _linear_refusal(self, what: str, why: str) -> str:
+        """The one wording of a lane that a state a lane cannot take
+        yet."""
+        spec = self.spec
+        return (
+            f"arch={spec.name!r} keeps a state of {spec.lin_heads} x "
+            f"{spec.lin_key_dim} x {spec.lin_value_dim} float32 a lane in "
+            f"each of its linear-attention layers, beside the K/V pages of "
+            f"the others: {what} cannot take a state a lane yet — {why}"
+        )
+
     def _refuse_latent(self, what: str) -> None:
         """Containers that carry K and V pages of ``d_model`` between
         engines (disaggregated prefill, migration) raise here."""
+        if self.spec.linear:
+            raise ValueError(self._linear_refusal(
+                what, "its container holds a \"k\" and a \"v\" block a page "
+                "and nothing of a lane's state"))
         if self.spec.latent:
             raise ValueError(self._latent_refusal(
                 what, "its container holds a \"k\" and a \"v\" block of "
@@ -4058,12 +4418,21 @@ class PagedEngine:
         entry (:func:`kv_split`)."""
         if self._kv_int8:
             return (self.pages_k, self.scales_k), (self.pages_v, self.scales_v)
+        if self.spec.linear:
+            # the linear layers' state rides with the K pool: donated
+            # with it, carried by a chunk's scan with it, stored back
+            # with it (:func:`delta_split`)
+            return ({"kv": self.pages_k, "state": self._delta_state,
+                     "conv": self._delta_conv}, self.pages_v)
         return self.pages_k, self.pages_v
 
     def _store_kv(self, pk, pv):
         """Inverse of :meth:`_kv_args` for a program's returned pools."""
         if self._kv_int8:
             (self.pages_k, self.scales_k), (self.pages_v, self.scales_v) = pk, pv
+        elif self.spec.linear:
+            self.pages_k, self.pages_v = pk["kv"], pv
+            self._delta_state, self._delta_conv = pk["state"], pk["conv"]
         else:
             self.pages_k, self.pages_v = pk, pv
 
@@ -4249,15 +4618,20 @@ class PagedEngine:
         jax, jnp = self._jax, self._jnp
 
         def prefill(params, pk, pv, tokens, true_lens, block_rows,
-                    lora=None, adapter_idx=None, window=None):
+                    lora=None, adapter_idx=None, window=None, slots=None):
             # tokens: (k, bucket)  true_lens: (k,)  block_rows: (k, P)
             # lora/adapter_idx: the multi-LoRA trailing args (engines
             # with adapters enabled only — pad rows carry slot 0)
             # window: a cache of kinds' ``(window rows (k, P_w), base
             # (k,))`` — the window layers' write table and the position
             # its first column starts at
+            # slots: a spec with linear layers' ``(k,)`` — where each
+            # row's state a lane rests (a pad row: past the last slot,
+            # which the scatter drops)
             params = self._materialize(params)
             kinds = window_kwarg(window)
+            pk, delta = delta_split(pk)
+            linear = delta_prefill_kwarg(delta, true_lens)
             positions = jnp.broadcast_to(jnp.arange(bucket)[None, :], (k, bucket))
             lengths = jnp.zeros((k,), jnp.int32)
             pk_pages, sk = kv_split(pk)
@@ -4273,14 +4647,16 @@ class PagedEngine:
                 read_rows, lengths, lora=lora, adapter_idx=adapter_idx,
                 kv_scales=kv_scales_arg(sk, sv),
                 token_mask=self._routed_rows(bucket, true_lens), **kinds,
+                **linear,
             )
+            delta, hist = delta_written(delta, hist, slots)
             valid = jnp.arange(bucket)[None, :] < true_lens[:, None]
             pk, pv = self._write_kv(
                 pk, pv, nk, nv, block_rows, jnp.zeros((k,), jnp.int32), valid,
                 from_zero=True, **kinds,
             )
             last = logits[jnp.arange(k), true_lens - 1]  # (k, vocab)
-            return (last, pk, pv, *hist)
+            return (last, delta_join(pk, delta), pv, *hist)
 
         return self._sentinels["paged_prefill"].wrap(
             self._tp_jit(prefill, name=f"paged_prefill_b{bucket}_k{k}",
@@ -4912,9 +5288,13 @@ class PagedEngine:
         # a cache of kinds: the window layers' tables ride beside the
         # block tables, to the LM and to the write
         kinds = window_kwarg(window)
+        # linear layers: the state rests in SLOT order whatever order the
+        # lanes run in; the stream's rows go to it and back
+        order = (inv_perm, perm) if multi else None
 
         def step(carry, _):
             pk, pv, logits, lengths, keys, done, emitted, *moe = carry
+            pk, delta = delta_split(pk)
             typed = jax.random.wrap_key_data(keys)
             split = jax.vmap(jax.random.split)(typed)
             step_keys = split[:, 1]
@@ -4936,10 +5316,13 @@ class PagedEngine:
                 lora=lora, adapter_idx=adapter_idx,
                 kv_scales=kv_scales_arg(sk, sv),
                 token_mask=active[:, None], **kinds,
+                **delta_step_kwarg(delta, active, order),
             )
+            delta, hist = delta_carried(delta, hist)
             pk, pv = self._write_kv(
                 pk, pv, nk, nv, block_tables, lengths, active[:, None], **kinds
             )
+            pk = delta_join(pk, delta)
             logits = jnp.where(active[:, None], new_logits[:, 0], logits)
             moe = self._moe_step(
                 moe, hist[:1], active,
@@ -6642,6 +7025,8 @@ class PagedEngine:
             tokens=tokens, padded=k * bucket,
             cached=int(use_cache), fused=int(fused),
             indexed_fused=int(indexed_fused), **routed,
+            **({"delta_positions": k * bucket * self._delta_layers}
+               if self.spec.linear else {}),
         )
         try:
             with self._lock:
@@ -6657,6 +7042,10 @@ class PagedEngine:
                         self._counters["prefill_expert_layer_calls_tiled"] += layers
                 self._counters["hyper_prefill_positions"] += (
                     k * bucket * self._hyper_sublayers)
+                self._counters["delta_prefill_positions"] += (
+                    k * bucket * self._delta_layers)
+                self._counters["delta_prefill_real_positions"] += (
+                    tokens * self._delta_layers)
             return self._prefill_group_call(bucket, k, group, use_cache)
         finally:
             self._seam.end_prefill()
@@ -6738,6 +7127,13 @@ class PagedEngine:
                     w_rows[i] = self._wtables[stream.slot]
                     w_base[i] = self._wbase[stream.slot]
                 kinds["window"] = (jnp.asarray(w_rows), jnp.asarray(w_base))
+            if self.spec.linear:
+                # where each row's state rests: its stream's slot (a pad
+                # row: past the last, dropped by the write)
+                at = np.full((k,), self.max_slots, np.int32)
+                for i, (stream, _start, _n) in enumerate(group):
+                    at[i] = stream.slot
+                kinds["slots"] = jnp.asarray(at)
             last, pk_out, pv_out, *hist = self._prefill_jit[key2](
                 self.params, *self._kv_args(),
                 jnp.asarray(padded), jnp.asarray(true_lens),
@@ -7298,10 +7694,11 @@ class PagedEngine:
                 and s.kv_import is None
                 and s.prefilled >= len(s.prompt)
                 and self.speculative is None
-                # a latent pool's pages, and a cache of kinds', fit no
-                # migration container yet: their streams are the drain
-                # journal's, like a speculative engine's
+                # a latent pool's pages, a cache of kinds' and a state a
+                # lane fit no migration container yet: their streams are
+                # the drain journal's, like a speculative engine's
                 and not self.spec.latent and not self.spec.kinds
+                and not self.spec.linear
             ]
         if not exportable:
             return []
@@ -7875,6 +8272,7 @@ class PagedEngine:
         the mesh degrees the engine got (not what was requested), the
         chunk implementation, and whether decode attention runs the
         Pallas kernel."""
+        from seldon_core_tpu.ops import delta as _delta
         from seldon_core_tpu.ops import hyper as _hyper
 
         kv_heads, head_dim = self.spec.head_sizes(
@@ -7912,6 +8310,23 @@ class PagedEngine:
             **({"kv_heads": kv_heads, "head_dim": head_dim,
                 "router_from": self.spec.router_from}
                if self.spec.kv_heads else {}),
+            # linear-attention layers: which layer is which, the state
+            # kinds that rest with a slot (name: layers), every slot's
+            # bytes and the type of a state, its shape a layer, and which
+            # form a decode step's update and a prefill's scan take
+            **({"layer_kinds": list(
+                    self.spec.layer_kinds[:self.module.num_layers]),
+                "state_kinds": {"linear": self._delta_layers},
+                "delta_state_bytes": self._delta_state_bytes,
+                "delta_state_dtype": str(self._delta_state[0].dtype),
+                "delta_state_shape": list(self._delta_state[0].shape),
+                "delta_step": _delta.step_impl(
+                    *self._delta_state[0].shape[2:]),
+                "delta_scan": _delta.scan_impl(),
+                # the length buckets a chunk program splits its lanes
+                # into (1 here unless SELDON_TPU_CTX_BUCKETS asks for 2)
+                "ctx_buckets": self._ctx_buckets}
+               if self.spec.linear else {}),
             # a spec with layer kinds: one pool a row kind (the full
             # layers' rows and indexer keys share the block table's
             # pages; the window layers' have their own), how many rows a
@@ -8249,6 +8664,13 @@ class PagedEngine:
                 "hyper_streams": self.spec.hc_mult,
                 "hyper_sinkhorn_iters": (
                     self.spec.hc_sinkhorn_iters if self.spec.hc_mult else 0),
+                # linear-attention layers: what every slot's state takes
+                # as it rests, and the slots that hold a stream's (0, 0
+                # without such layers)
+                "delta_state_bytes": self._delta_state_bytes,
+                "delta_slots_live": (
+                    sum(s is not None for s in self._slots)
+                    if self.spec.linear else 0),
             }
             outputs = self.spec.router_outputs
             moe_expert_hits = (  # cumulative assignments per router output
@@ -8909,6 +9331,9 @@ class PagedEngine:
                     sum(lens0.values()) if self.spec.latent else 0),
                 pages_live=sum(self._pages_of(n) for n in lens0.values()),
                 page_slots=step_slots, overlapped=int(overlapped),
+                # linear layers: the lanes whose state this chunk updates
+                **({"delta_lanes": len(runnable_now)}
+                   if self.spec.linear else {}),
                 **({"sparse_lanes": sum(
                         n >= self.spec.index_topk for n in lens0.values()),
                     "window_pages": sum(
@@ -9101,6 +9526,7 @@ class PagedEngine:
                 # step t attended the len0 + t tokens cached before it
                 n = int(emitted_np[slot])
                 self._counters["decode_lane_steps"] += n
+                self._counters["delta_lane_steps"] += n * self._delta_layers
                 read = n * len0 + n * (n - 1) // 2
                 self._counters["decode_kv_tokens"] += read
                 if self.spec.latent:  # a row an attention sub-layer

@@ -34,7 +34,7 @@ expert-parallel layer computes its own experts' part and nothing
 stands in for the rest).
 
 ``GPT2``, ``OLMOE``, ``DEEPSEEK_V3``, ``LONGCAT_FLASH``, ``DOTS3_NOTE``,
-``SMALLTHINKER`` and ``XING4_0`` are the values served (the fifth added layer KINDS: ``layer_kinds``,
+``SMALLTHINKER``, ``XING4_0`` and ``OLMO_HYBRID`` are the values served (the fifth added layer KINDS: ``layer_kinds``,
 ``attn_kind``, ``cache_kinds`` — layers that differ in their attention
 and a cache of one pool a row kind); a new architecture is a new value (and new branches where the
 block reads a field it has not met), not a new block.  The fourth value
@@ -69,6 +69,18 @@ hyper-connections; ``ops/hyper.py`` has the equations), every attention
 and every FFN reading a learned, input-dependent mix of the rows and
 writing back through another.  The rows live inside a program: the
 cache row, the pool and the allocator are DeepSeek-V3's.
+
+The eighth value (``OLMO_HYBRID``) added a layer kind that keeps **no
+pages**: ``layer_kinds`` may name a layer ``"linear"`` — Gated-DeltaNet
+linear attention (``ops/delta.py``; the ``lin_*`` fields) whose whole
+memory of a stream is a float32 state of ``lin_key_dim x lin_value_dim``
+a head and the last inputs of a short convolution, resting with the
+SLOT.  Such a spec is not a cache of kinds (:attr:`ModelSpec.kinds` is
+false): its other layers are one kind, ``"full"`` multi-head layers over
+one K/V pool whose leading axis counts them alone
+(:meth:`ModelSpec.cache_layers`, :meth:`ModelSpec.state_layers`).  With
+it came ``post_norm`` (the norm on a sub-layer's output) and ``ffn
+"swiglu"`` (a dense SwiGLU in every layer of a multi-head spec).
 """
 
 from __future__ import annotations
@@ -242,6 +254,23 @@ class ModelSpec:
     hc_eps: float = 1e-6
     hc_res_min: float = -30.0
     hc_res_max: float = 30.0
+    # ---- linear-attention layers (Olmo-Hybrid: Gated DeltaNet,
+    # ops/delta.py).  A layer_kinds entry "linear" is a layer whose
+    # attention keeps NO pages: lin_heads heads of lin_key_dim (q, k)
+    # against lin_value_dim (v), each through a causal depthwise
+    # convolution of lin_conv taps, and a state of lin_key_dim x
+    # lin_value_dim float32 a head and a lane that rests with the SLOT
+    # beside the convolution's last lin_conv - 1 inputs.  lin_neg_eigval:
+    # beta = 2 sigmoid(.) (linear_allow_neg_eigval).  post_norm: RMSNorm
+    # on each sub-layer's OUTPUT before the residual add (Olmo 2 / 3),
+    # none on its input.  ffn "swiglu": a dense SwiGLU of dense_width in
+    # every layer
+    lin_heads: int = 0
+    lin_key_dim: int = 0
+    lin_value_dim: int = 0
+    lin_conv: int = 4
+    lin_neg_eigval: bool = True
+    post_norm: bool = False
 
     @property
     def routed(self) -> bool:
@@ -290,14 +319,48 @@ class ModelSpec:
         return 2 if self.double_layer else 1
 
     def cache_layers(self, num_layers: int) -> int:
-        """The pool's leading axis: attention sub-layers, not layers."""
-        return num_layers * self.attn_sublayers
+        """The pool's leading axis: attention sub-layers, not layers —
+        and of a spec with linear layers only the layers that keep
+        pages."""
+        return (num_layers - self.state_layers(num_layers)) * self.attn_sublayers
 
     @property
     def kinds(self) -> bool:
-        """Whether the layers differ in their attention: then the cache
-        is one pool a ROW kind (:meth:`cache_kinds`), not one element."""
-        return bool(self.layer_kinds)
+        """Whether the layers differ in their attention's ROWS: then the
+        cache is one pool a row kind (:meth:`cache_kinds`), not one
+        element.  (Linear layers keep no rows at all: what is left of a
+        spec with them is one kind, in one pool.)"""
+        return bool(self.layer_kinds) and not self.linear
+
+    @property
+    def linear(self) -> bool:
+        """Whether some layers are linear attention: a state a lane
+        beside the pages (:meth:`state_layers`)."""
+        return "linear" in self.layer_kinds
+
+    def state_layers(self, num_layers: int) -> int:
+        """The layers that keep a state a lane and no pages."""
+        return self.layer_kinds[:num_layers].count("linear")
+
+    def state_bytes(self, num_layers: int) -> int:
+        """Bytes one lane's linear state takes as it rests: the float32
+        state (``ops/delta.py state_shape``: what the (8, 128) tiling
+        pads is counted) and the convolution's inputs in bf16."""
+        if not self.linear:
+            return 0
+        from seldon_core_tpu.ops import delta
+
+        _slots, groups, dk, lanes = delta.state_shape(
+            1, self.lin_heads, self.lin_key_dim, self.lin_value_dim)
+        state = 4 * groups * (-(-dk // 8) * 8) * lane_tiles(lanes)
+        conv = 2 * (self.lin_conv - 1) * self.lin_channels
+        return self.state_layers(num_layers) * (state + conv)
+
+    @property
+    def lin_channels(self) -> int:
+        """The channels a linear layer convolves: q, k and v side by
+        side."""
+        return self.lin_heads * (2 * self.lin_key_dim + self.lin_value_dim)
 
     def layer_kind(self, layer: int) -> str:
         return self.layer_kinds[layer] if self.layer_kinds else "full"
@@ -521,9 +584,28 @@ XING4_0 = replace(
     hc_res_min=-30.0, hc_res_max=30.0,
 )
 
+# allenai/Olmo-Hybrid-7B config.json (model_type olmo_hybrid): 32 layers,
+# layer_types = (linear_attention x 3, full_attention) x 8.  A full layer:
+# 30 heads = 30 K/V heads of 128 (q, k and v of d_model each), RMSNorm
+# over the whole q and k (the Olmo 2 / 3 QK-norm), rope_theta null read
+# as NO rotary embedding (the recurrent layers carry position).  A linear
+# layer: Gated DeltaNet (ops/delta.py), 30 heads of 96 (q, k) against
+# 192 (v), a convolution of 4 taps, allow_neg_eigval.  Every layer a
+# dense SwiGLU of 11,008; RMSNorm eps 1e-6 on each sub-layer's output
+# (post-norm), no biases, untied embedding and head
+OLMO_HYBRID = ModelSpec(
+    name="olmo_hybrid", positions="rope", norm="rmsnorm", norm_eps=1e-6,
+    qk_norm=True, ffn="swiglu", dense_width=11_008, bias=False,
+    residual_f32=True, weights_f32=False, kv_heads=30, head_dim=128,
+    layer_kinds=("linear", "linear", "linear", "full") * 8,
+    full_positions="none", lin_heads=30, lin_key_dim=96, lin_value_dim=192,
+    lin_conv=4, lin_neg_eigval=True, post_norm=True,
+)
+
 _ARCHS = {"gpt2": GPT2, "olmoe": OLMOE, "deepseek_v3": DEEPSEEK_V3,
           "longcat_flash": LONGCAT_FLASH, "dots3_note": DOTS3_NOTE,
-          "smallthinker": SMALLTHINKER, "xing4_0": XING4_0}
+          "smallthinker": SMALLTHINKER, "xing4_0": XING4_0,
+          "olmo_hybrid": OLMO_HYBRID}
 # the sizes any routed arch has; a replica's share of the experts; those
 # only DeepSeek-V3's expert layer and attention have; and the two every
 # arch has
@@ -552,6 +634,10 @@ _SMALLTHINKER_SIZES = _SHARE_SIZES + (
 # ... and those of a residual of several rows over DeepSeek-V3's block
 _HYPER_SIZES = ("hc_mult", "hc_sinkhorn_iters", "hc_eps", "hc_res_min",
                 "hc_res_max")
+# ... and those of linear-attention layers beside full multi-head ones
+_LINEAR_SIZES = ("kv_heads", "head_dim", "layer_kinds", "dense_width",
+                 "lin_heads", "lin_key_dim", "lin_value_dim", "lin_conv",
+                 "lin_neg_eigval")
 # the sizes an arch has beside the expert sizes and the two every arch
 # has: what ``model_spec`` lets a caller set, by the arch's name (a share
 # of the experts and layer kinds belong to the archs whose block was
@@ -559,14 +645,15 @@ _HYPER_SIZES = ("hc_mult", "hc_sinkhorn_iters", "hc_eps", "hc_res_min",
 _OWN_SIZES = {"gpt2": (), "olmoe": (), "deepseek_v3": _DEEPSEEK_SIZES,
               "longcat_flash": _LONGCAT_SIZES, "dots3_note": _DOTS3_SIZES,
               "smallthinker": _SMALLTHINKER_SIZES,
-              "xing4_0": _DEEPSEEK_SIZES + _HYPER_SIZES}
+              "xing4_0": _DEEPSEEK_SIZES + _HYPER_SIZES,
+              "olmo_hybrid": _LINEAR_SIZES}
 _SIZES = tuple(dict.fromkeys(
     _EXPERT_SIZES + sum(_OWN_SIZES.values(), ()) + ("rope_theta", "norm_eps")))
 # a size that may be given as 0 and mean it, for an arch that takes
 # sizes of its own (0 elsewhere = as published; GPT-2 and OLMoE, which
 # have none of these, take a 0 as "not given")
 _ZERO_MEANS_ZERO = ("dense_layers", "shared_experts", "expert_offset",
-                    "experts_held", "zero_experts")
+                    "experts_held", "zero_experts", "lin_neg_eigval")
 
 
 def model_spec(arch: str = "gpt2", **sizes: Any) -> ModelSpec:
@@ -597,9 +684,21 @@ def model_spec(arch: str = "gpt2", **sizes: Any) -> ModelSpec:
                   "win_rope_theta", "hc_eps", "hc_res_min", "hc_res_max")
         spec = replace(spec, **{
             k: (float(v) if k in floats
-                else tuple(str(x) for x in v) if k == "layer_kinds" else int(v))
+                else tuple(str(x) for x in v) if k == "layer_kinds"
+                else bool(v) if k == "lin_neg_eigval" else int(v))
             for k, v in given.items()
         })
+    if spec.linear and (spec.latent or not spec.lin_heads
+                        or set(spec.layer_kinds) - {"full", "linear"}):
+        raise ValueError(
+            f"arch={spec.name!r}, layer_kinds {spec.layer_kinds}: linear "
+            "layers stand beside 'full' multi-head layers of an arch that "
+            "has their sizes (lin_heads, lin_key_dim, lin_value_dim)")
+    if spec.linear and min(spec.lin_heads, spec.lin_key_dim,
+                           spec.lin_value_dim, spec.lin_conv - 1) < 1:
+        raise ValueError(
+            f"lin_heads {spec.lin_heads}, lin_key_dim {spec.lin_key_dim}, "
+            f"lin_value_dim {spec.lin_value_dim}, lin_conv {spec.lin_conv}")
     if spec.kinds and set(spec.layer_kinds) - {"full", "window"}:
         raise ValueError(
             f"layer_kinds {spec.layer_kinds}: a layer is 'full' or 'window'")
@@ -748,7 +847,9 @@ def _declared(spec, sizes, dtype_name):
     return jax.eval_shape(
         lm.init, jax.random.key(0), i32((1, 8)), i32((1, 8)), pool,
         pool if spec.cache_pools == 2 else None,
-        i32((1, 1)), i32((1,)))["params"]
+        # (grouped heads without kinds prefill from zero: a table of no
+        # width, and the linear layers' state starts at zero)
+        i32((1, 0 if spec.linear else 1)), i32((1,)))["params"]
 
 
 def rest_tree(params, spec: ModelSpec, config: Dict[str, int], dtype=None):
@@ -862,6 +963,11 @@ def init_params(spec: ModelSpec, config: Dict[str, int], seed: int,
         name = path.rsplit("/", 1)[-1]
         if name == "scale":
             lo, hi = 0.5, 1.5
+        elif name == "a_log":
+            # a linear layer's decay rate exp(a_log) in (0.05, 1): with
+            # the gate's softplus of order 1 the decay alpha spreads over
+            # (0, 1) and does not sit at 1, where it would test nothing
+            lo, hi = -3.0, 0.0
         else:
             hi = (0.1 if name == "bias" or name.endswith("_bias")
                   else (3.0 if name == "embedding" else 3.0 / leaf.shape[

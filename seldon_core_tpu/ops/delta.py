@@ -1,0 +1,467 @@
+"""Gated-DeltaNet linear attention (arXiv:2412.06464, under the key names
+HF's ``linear_*`` config keys and FLA's ``GatedDeltaNet(allow_neg_eigval=)``
+use): what a ``"linear"`` layer of ``models/paged.py`` computes between
+its projections, and the one thing it keeps a lane — a state ``S`` of
+``d_k x d_v`` float32 a head, whatever the context.
+
+With ``q_t`` and ``k_t`` a head's normalised query and key (``d_k``),
+``v_t`` its value (``d_v``), ``alpha_t`` in (0, 1) its decay and
+``beta_t`` in (0, 2) its write strength::
+
+    S' = alpha_t S_{t-1}
+    u  = beta_t (v_t - S'^T k_t)        the delta rule: what k_t reads
+    S_t = S' + k_t u^T                  is replaced by v_t, beta_t of it
+    o_t = S_t^T q_t
+
+* :func:`step` — a decode step: one position a lane against the state as
+  it rests, memory-bound (a lane-step reads and writes the state once:
+  2 x H x d_k x d_v x 4 B a layer).
+* :func:`chunked_scan` — a prefill: the same recurrence over a segment
+  in chunks of :data:`CHUNK` positions (the WY form: within a chunk the
+  ``u`` solve a unit lower-triangular system that does not involve the
+  state's rows, so a chunk is a handful of ``(64, d_k) x (d_k, d_v)``
+  matmuls and the state is carried chunk to chunk).
+* :func:`conv` / :func:`conv_step` — the causal depthwise convolution of
+  :data:`TAPS` taps and SiLU that q, k and v pass first, and the last
+  ``TAPS - 1`` inputs a lane keeps for it.
+
+**The pad rule.**  A position with ``beta = 0`` and ``alpha = 1`` leaves
+the state as it was (``u = 0``, ``S' = S``).  A prefill call padded to
+its bucket passes those at every position past a prompt's own length, so
+the state it leaves is the state at the prompt's LAST REAL position; a
+decode chunk passes them for a lane that is not running.
+
+**How the state rests** (:func:`pack_of`): ``(slots, H / p, d_k, p x
+d_v)`` float32, ``p`` heads side by side in the lanes.  Under the TPU's
+(8, 128) tiling a minor dim of 192 occupies 256 lanes of HBM whatever
+the array is called; two heads' 384 are three whole tiles.  ``p`` is 1
+wherever ``d_v`` is a multiple of 128 already (or the heads are odd):
+then the packed form is the plain one.
+"""
+
+from __future__ import annotations
+
+CHUNK = 64      # positions a chunk of the prefill's scan
+SUB = 16        # rows a diagonal block of a chunk's triangular system
+TAPS = 4        # the convolution's taps (``linear_conv_kernel_dim``)
+
+
+def pack_of(heads: int, value_dim: int) -> int:
+    """Heads side by side in the resting state's lanes: 2 where that
+    makes the minor dim whole 128-lane tiles and one head's is not."""
+    if value_dim % 128 and heads % 2 == 0 and (2 * value_dim) % 128 == 0:
+        return 2
+    return 1
+
+
+def state_shape(slots: int, heads: int, key_dim: int, value_dim: int):
+    p = pack_of(heads, value_dim)
+    return (slots, heads // p, key_dim, p * value_dim)
+
+
+def pack_state(state, pack: int):
+    """``(B, H, d_k, d_v)`` -> ``(B, H / p, d_k, p x d_v)``."""
+    if pack == 1:
+        return state
+    b, h, dk, dv = state.shape
+    return (state.reshape(b, h // pack, pack, dk, dv).transpose(0, 1, 3, 2, 4)
+            .reshape(b, h // pack, dk, pack * dv))
+
+
+def unpack_state(state, pack: int):
+    """:func:`pack_state`'s inverse."""
+    if pack == 1:
+        return state
+    b, g, dk, w = state.shape
+    return (state.reshape(b, g, dk, pack, w // pack).transpose(0, 1, 3, 2, 4)
+            .reshape(b, g * pack, dk, w // pack))
+
+
+def backend() -> str:
+    """``jax.default_backend()``; a test answers ``"interpret"`` to run
+    the kernel under the Pallas interpreter off a TPU."""
+    import jax
+
+    return jax.default_backend()
+
+
+def step_impl(key_dim: int, lanes: int, where=None) -> str:
+    """Which form a decode step's state update takes
+    (``lane_report()["delta_step"]``): ``"pallas"`` on a TPU (or the
+    interpreter where a test asks for it) where the resting state's last
+    two dims are whole (8, 128) tiles, else ``"xla"``.  On the v5e XLA's
+    form of :func:`step` reads the state three times and materialises
+    ``k`` spread over a head's lanes (a ``(slots, 15, 96, 2, 192)``
+    broadcast re-laid to 384 lanes): 27 % of the update's roofline, 60 %
+    of a decode step (my chip run, PR 48); the kernel passes over the
+    state once."""
+    where = backend() if where is None else where
+    return ("pallas" if where in ("tpu", "interpret")
+            and key_dim % 8 == 0 and lanes % 128 == 0 else "xla")
+
+
+def scan_impl() -> str:
+    """... and a prefill's chunked scan (``lane_report()["delta_scan"]``)."""
+    return "xla"
+
+
+# ---------------------------------------------------------------------------
+# the convolution
+# ---------------------------------------------------------------------------
+
+def conv(x, taps, true_lens=None):
+    """Causal depthwise convolution and SiLU over a segment from position
+    zero: ``x`` ``(B, L, C)``, ``taps`` ``(TAPS, C)`` (tap ``j`` weighs the
+    input ``TAPS - 1 - j`` positions back), float32 out.  Also the tail a
+    decode step continues from: the last ``TAPS - 1`` INPUTS of each row
+    **at its real length** ``true_lens`` ``(B,)`` (zeros where the row is
+    shorter than that), ``(B, TAPS - 1, C)`` in ``x``'s type."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("seldon.delta.conv"):
+        b, length, _c = x.shape
+        n = taps.shape[0]
+        padded = jnp.pad(x, [(0, 0), (n - 1, 0), (0, 0)])
+        w = taps.astype(jnp.float32)
+        out = sum(padded[:, j:j + length].astype(jnp.float32) * w[j]
+                  for j in range(n))
+        if true_lens is None:
+            true_lens = jnp.full((b,), length, jnp.int32)
+        # padded[b, len : len + n - 1] = x[b, len - (n - 1) : len]
+        tail = jax.vmap(lambda row, at: jax.lax.dynamic_slice_in_dim(
+            row, at, n - 1, axis=0))(padded, true_lens.astype(jnp.int32))
+        return jax.nn.silu(out), tail
+
+
+def conv_step(tail, x, taps, active=None):
+    """One position: ``tail`` ``(B, TAPS - 1, C)`` the inputs before it,
+    ``x`` ``(B, C)`` its own.  ``(out (B, C) float32, new tail)``; a lane
+    ``active`` ``(B,)`` leaves out keeps its tail."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("seldon.delta.conv"):
+        window = jnp.concatenate([tail, x[:, None].astype(tail.dtype)], axis=1)
+        out = (window.astype(jnp.float32)
+               * taps.astype(jnp.float32)[None]).sum(axis=1)
+        new = window[:, 1:]
+        if active is not None:
+            new = jnp.where(active[:, None, None], new, tail)
+        return jax.nn.silu(out), new
+
+
+def l2norm(x, eps: float = 1e-6):
+    """``x / ||x||`` over the last dim, float32 (FLA's ``l2norm``: the
+    eps under the root)."""
+    import jax
+    import jax.numpy as jnp
+
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + eps)
+
+
+def gates(a, b, a_log, dt_bias, neg_eigval: bool = True):
+    """``(log alpha, beta)`` float32 from the two gate projections ``a``
+    and ``b`` ``(..., H)``: ``alpha = exp(-exp(A_log) softplus(a +
+    dt_bias))``, ``beta = sigmoid(b)`` times 2 under ``neg_eigval``
+    (``linear_allow_neg_eigval``: the state's transition ``I - beta k
+    k^T`` may then have an eigenvalue in (-1, 0))."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    log_alpha = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+        a.astype(f32) + dt_bias.astype(f32))
+    beta = jax.nn.sigmoid(b.astype(f32)) * (2.0 if neg_eigval else 1.0)
+    return log_alpha, beta
+
+
+# ---------------------------------------------------------------------------
+# a decode step
+# ---------------------------------------------------------------------------
+
+def step(state, q, k, v, log_alpha, beta, *, pack: int = 1, active=None):
+    """One position a lane against the resting state: ``state`` ``(B, H /
+    p, d_k, p x d_v)`` float32, ``q`` / ``k`` ``(B, H, d_k)``, ``v`` ``(B,
+    H, d_v)``, ``log_alpha`` / ``beta`` ``(B, H)``.  ``(new state, o (B, H,
+    d_v) float32)``.  ``active`` ``(B,)``: a lane it leaves out passes the
+    pad rule's gates, so its state stays as it is, bit for bit.
+
+    Every operation keeps the packed state's shape or its row's ``(B, H /
+    p, p x d_v)``: elementwise products and sums over ``d_k``, one pass
+    over the state where XLA fuses them."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("seldon.delta.step"):
+        f32 = jnp.float32
+        b, h, dk = q.shape
+        dv = v.shape[-1]
+        g = h // pack
+        alpha = jnp.exp(log_alpha.astype(f32))
+        beta = beta.astype(f32)
+        if active is not None:
+            alpha = jnp.where(active[:, None], alpha, 1.0)
+            beta = jnp.where(active[:, None], beta, 0.0)
+
+        def lanes(x):  # (B, H) -> (B, G, p x d_v): a head's value its lanes over
+            return jnp.repeat(x.reshape(b, g, pack), dv, axis=-1)
+
+        where = backend()
+        if step_impl(dk, pack * dv, where) == "pallas":
+            rows = jnp.concatenate(
+                [q.astype(f32).reshape(b, g, pack, dk),
+                 k.astype(f32).reshape(b, g, pack, dk)], axis=2)
+            gates = jnp.stack([v.astype(f32).reshape(b, g, pack * dv),
+                               lanes(alpha), lanes(beta)], axis=2)
+            new, out = _step_pallas(state, rows, gates, pack=pack,
+                                    interpret=where != "tpu")
+            return new, out.reshape(b, h, dv)
+
+        def rows(x):   # (B, H, d_k) -> (B, G, d_k, p x d_v)
+            return jnp.repeat(
+                x.astype(f32).reshape(b, g, pack, dk).transpose(0, 1, 3, 2),
+                dv, axis=-1)
+
+        kk = rows(k)
+        decayed = lanes(alpha)[:, :, None, :] * state
+        read = (kk * decayed).sum(axis=2)                      # S'^T k
+        u = lanes(beta) * (v.astype(f32).reshape(b, g, pack * dv) - read)
+        new = decayed + kk * u[:, :, None, :]
+        out = (rows(q) * new).sum(axis=2)                      # S^T q
+        return new, out.reshape(b, h, dv)
+
+
+def _step_kernel(s_ref, rows_ref, gates_ref, s_out_ref, o_ref, *, pack):
+    """One lane: ``s_ref`` / ``s_out_ref`` ``(1, G, d_k, W)`` the state as
+    it rests (``W`` = ``pack`` heads' ``d_v`` side by side), ``rows_ref``
+    ``(1, G, 2 pack, d_k)`` the pair's q rows then its k rows,
+    ``gates_ref`` ``(1, G, 3, W)`` v, alpha and beta over the lanes,
+    ``o_ref`` ``(1, G, 1, W)``.  A pair of heads at a time: the state's
+    tile is read once, decayed, read against k, written through, read
+    against q — elementwise products and sums over ``d_k``, float32 on the
+    vector unit (no matmul unit: nothing is rounded to bfloat16)."""
+    import jax
+    import jax.numpy as jnp
+
+    groups, dk, width = s_ref.shape[1], s_ref.shape[2], s_ref.shape[3]
+    dv = width // pack
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1))
+    head = jax.lax.broadcasted_iota(jnp.int32, (dk, width), 1) // dv
+
+    def spread(rows):  # (pack, d_k) -> (d_k, W): lane l holds head l // d_v's column
+        out = None
+        for i in range(pack):
+            col = jnp.sum(jnp.where(eye, rows[i:i + 1], 0.0), axis=1,
+                          keepdims=True)                        # (d_k, 1)
+            col = jnp.broadcast_to(col, (dk, width))
+            out = col if out is None else jnp.where(head == i, col, out)
+        return out
+
+    def pair(g, carry):
+        s = s_ref[0, g]
+        rows = rows_ref[0, g]
+        gates = gates_ref[0, g]
+        kk = spread(rows[pack:])
+        decayed = s * gates[1:2]
+        read = jnp.sum(kk * decayed, axis=0, keepdims=True)     # S'^T k
+        u = gates[2:3] * (gates[0:1] - read)
+        new = decayed + kk * u
+        s_out_ref[0, g] = new
+        o_ref[0, g] = jnp.sum(spread(rows[:pack]) * new, axis=0, keepdims=True)
+        return carry
+
+    jax.lax.fori_loop(0, groups, pair, 0)
+
+
+def _step_pallas(state, rows, gates, *, pack, interpret):
+    """:func:`step`'s state update as one kernel call: a grid step a
+    lane, the lane's whole state (2.2 MB at 15 x 96 x 384) a block,
+    rewritten where it rests.  The state is the first output: a trace
+    names the call by it (``pallas_kernel_f32_<slots>_<G>_<d_k>_<W>_``)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, g, dk, width = state.shape
+    block = 4 * g * dk * width
+    new, out = pl.pallas_call(
+        functools.partial(_step_kernel, pack=pack),
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, g, dk, width), lambda i: (i, 0, 0, 0)),
+                  pl.BlockSpec((1, g, 2 * pack, dk), lambda i: (i, 0, 0, 0)),
+                  pl.BlockSpec((1, g, 3, width), lambda i: (i, 0, 0, 0))],
+        out_specs=[pl.BlockSpec((1, g, dk, width), lambda i: (i, 0, 0, 0)),
+                   pl.BlockSpec((1, g, 1, width), lambda i: (i, 0, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(state.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((b, g, 1, width), jnp.float32)],
+        # a lane's state is read whole before its own write, and no other
+        # grid step touches it
+        input_output_aliases={0: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            # the state's block in and out, each twice (the pipeline's
+            # two buffers), and room for the rest
+            vmem_limit_bytes=min(4 * block + (16 << 20), 100 << 20)),
+        interpret=interpret,
+        name="delta_state_step",
+    )(state, rows, gates)
+    return new, out[:, :, 0]
+
+
+# ---------------------------------------------------------------------------
+# a prefill: the chunked scan
+# ---------------------------------------------------------------------------
+
+def _unit_lower_inverse(a):
+    """``(I + a)^-1`` for ``a`` ``(..., n, n)`` strictly lower triangular,
+    by forward substitution a row at a time (row ``i`` of the inverse is
+    ``e_i - a[i] @ rows before it``): ``n - 1`` steps of one masked
+    product each, exact to rounding whatever ``a`` holds."""
+    import jax
+    import jax.numpy as jnp
+
+    n = a.shape[-1]
+    eye = jnp.eye(n, dtype=a.dtype)
+
+    def row(i, t):
+        pick = (jnp.arange(n) == i).astype(a.dtype)            # (n,)
+        a_i = (a * pick[:, None]).sum(axis=-2)                 # a[..., i, :]
+        new = -jnp.einsum("...j,...jk->...k", a_i, t,
+                          precision=jax.lax.Precision.HIGHEST)
+        return t + pick[:, None] * new[..., None, :]
+
+    return jax.lax.fori_loop(1, n, row, jnp.broadcast_to(eye, a.shape))
+
+
+def _solve_unit_lower(a, rhs, sub: int):
+    """``(I + a)^-1 rhs`` for ``a`` ``(..., C, C)`` strictly lower
+    triangular: the diagonal blocks of ``sub`` rows inverted a row at a
+    time, the blocks below them by substitution a block at a time."""
+    import jax
+    import jax.numpy as jnp
+
+    c = a.shape[-1]
+    nb = c // sub
+    hi = jax.lax.Precision.HIGHEST
+    lead = a.shape[:-2]
+    blocks = a.reshape(*lead, nb, sub, nb, sub)
+    diag = jnp.stack([blocks[..., j, :, j, :] for j in range(nb)], axis=-3)
+    inv = _unit_lower_inverse(diag)                            # (..., nb, sub, sub)
+    rhs = rhs.reshape(*lead, nb, sub, rhs.shape[-1])
+    solved = []
+    for j in range(nb):
+        r = rhs[..., j, :, :]
+        for i, x in enumerate(solved):
+            r = r - jnp.matmul(blocks[..., j, :, i, :], x, precision=hi)
+        solved.append(jnp.matmul(inv[..., j, :, :], r, precision=hi))
+    return jnp.concatenate(solved, axis=-2)
+
+
+def chunked_scan(q, k, v, log_alpha, beta, *, state=None, chunk: int = CHUNK):
+    """The recurrence over a segment: ``q`` / ``k`` ``(B, L, H, d_k)``,
+    ``v`` ``(B, L, H, d_v)``, ``log_alpha`` / ``beta`` ``(B, L, H)``;
+    ``state`` ``(B, H, d_k, d_v)`` before the first position (None:
+    zeros).  ``(o (B, L, H, d_v), final state)`` float32.
+
+    Within a chunk, with ``g_t`` the running sum of ``log alpha`` from the
+    chunk's start and ``S_0`` the state before it, ``U`` (the rows ``u_t``)
+    solves ``(I + A) U = beta V - beta e^g K S_0`` with ``A[t, i] = beta_t
+    e^{g_t - g_i} (k_t . k_i)`` for ``i < t``: two right-hand sides that do
+    not involve ``S_0`` are solved for every chunk at once (``U0``, ``W``)
+    and the sequential part is ``U = U0 - W S_0``, ``O = e^g Q S_0 + (M . Q
+    K^T) U``, ``S = e^{g_C} S_0 + (e^{g_C - g} K)^T U`` a chunk.  Decays
+    enter as ``exp`` of differences that are never positive.  Every matmul
+    is float32 at the highest precision: the state's error is the
+    recurrence's own."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("seldon.delta.scan"):
+        f32 = jnp.float32
+        hi = jax.lax.Precision.HIGHEST
+        b, length, h, dk = q.shape
+        dv = v.shape[-1]
+        if length >= chunk:
+            c = chunk
+        else:  # one chunk: the segment in whole diagonal blocks
+            c = -(-length // SUB) * SUB if length > SUB else length
+        sub = SUB if c % SUB == 0 else c
+        pad = -length % c
+        n = (length + pad) // c
+
+        def lay(x):  # (B, L, H, ...) -> (B, H, N, C, ...), pads passing the pad rule
+            x = jnp.pad(x.astype(f32),
+                        [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+            x = x.reshape(b, n, c, *x.shape[2:])
+            return jnp.moveaxis(x, 3, 1)
+
+        q, k, v = lay(q), lay(k), lay(v)                       # (B, H, N, C, d)
+        beta = lay(beta)                                       # (B, H, N, C)
+        g = jnp.cumsum(lay(log_alpha), axis=-1)
+        lower = jnp.tril(jnp.ones((c, c), bool))
+        strict = jnp.tril(jnp.ones((c, c), bool), -1)
+        decay = jnp.exp(jnp.where(lower, g[..., :, None] - g[..., None, :],
+                                  -jnp.inf))                   # e^{g_t - g_i}, i <= t
+        kk = jnp.einsum("...td,...id->...ti", k, k, precision=hi)
+        a = jnp.where(strict, beta[..., :, None] * decay * kk, 0.0)
+        rhs = jnp.concatenate(
+            [beta[..., None] * v, (beta * jnp.exp(g))[..., None] * k], axis=-1)
+        solved = _solve_unit_lower(a, rhs, sub)
+        u0, w = solved[..., :dv], solved[..., dv:]
+        qk = jnp.where(lower, decay * jnp.einsum(
+            "...td,...id->...ti", q, k, precision=hi), 0.0)
+        q_in = q * jnp.exp(g)[..., None]
+        g_end = g[..., -1:]
+        k_out = k * jnp.exp(g_end - g)[..., None]
+        carry = jnp.exp(g_end)[..., None]                      # (B, H, N, 1, 1)
+
+        def one(s, xs):
+            u0_n, w_n, qk_n, q_n, k_n, carry_n = xs
+            u = u0_n - jnp.matmul(w_n, s, precision=hi)
+            o = (jnp.matmul(q_n, s, precision=hi)
+                 + jnp.matmul(qk_n, u, precision=hi))
+            s = carry_n * s + jnp.einsum("...td,...tv->...dv", k_n, u,
+                                         precision=hi)
+            return s, o
+
+        if state is None:
+            state = jnp.zeros((b, h, dk, dv), f32)
+        xs = tuple(jnp.moveaxis(x, 2, 0)
+                   for x in (u0, w, qk, q_in, k_out, carry))
+        state, out = jax.lax.scan(one, state.astype(f32), xs)
+        out = jnp.moveaxis(out, 0, 2).reshape(b, h, n * c, dv)[:, :, :length]
+        return jnp.moveaxis(out, 1, 2), state
+
+
+def recurrence(q, k, v, log_alpha, beta, *, state=None):
+    """The same map position by position (``lax.scan`` over ``t``): what
+    :func:`chunked_scan` is held to, and the form a reader of the
+    equations at the top can check by eye."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
+    b, _length, h, dk = q.shape
+    dv = v.shape[-1]
+    if state is None:
+        state = jnp.zeros((b, h, dk, dv), f32)
+
+    def one(s, xs):
+        q_t, k_t, v_t, la_t, b_t = xs                           # (B, H, ...)
+        s = jnp.exp(la_t)[..., None, None] * s
+        read = jnp.einsum("bhkv,bhk->bhv", s, k_t, precision=hi)
+        u = b_t[..., None] * (v_t - read)
+        s = s + k_t[..., :, None] * u[..., None, :]
+        return s, jnp.einsum("bhkv,bhk->bhv", s, q_t, precision=hi)
+
+    xs = tuple(jnp.moveaxis(x.astype(f32), 1, 0)
+               for x in (q, k, v, log_alpha, beta))
+    state, out = jax.lax.scan(one, state.astype(f32), xs)
+    return jnp.moveaxis(out, 0, 1), state

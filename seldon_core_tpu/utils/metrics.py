@@ -610,6 +610,26 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
         "counter", "seldon_tpu_engine_hyper_decode_positions_total",
         "lanes x mixed sub-layers the decode steps ran (a residual of "
         "several rows; 0 otherwise)"),
+    # linear-attention layers (PR 48, ops/delta.py): the state a lane's
+    # work and size; 0 on any other engine
+    "delta_lane_steps": (
+        "counter", "seldon_tpu_engine_delta_lane_steps_total",
+        "decode lane-steps x linear-attention layers: state updates run "
+        "(0 without such layers)"),
+    "delta_prefill_positions": (
+        "counter", "seldon_tpu_engine_delta_prefill_positions_total",
+        "padded positions x linear-attention layers the prefill calls "
+        "scanned"),
+    "delta_prefill_real_positions": (
+        "counter", "seldon_tpu_engine_delta_prefill_real_positions_total",
+        "real prompt positions x linear-attention layers the prefill calls "
+        "scanned"),
+    "delta_state_bytes": (
+        "gauge", "seldon_tpu_engine_delta_state_bytes",
+        "bytes every slot's linear-attention state takes as it rests"),
+    "delta_slots_live": (
+        "gauge", "seldon_tpu_engine_delta_slots_live",
+        "slots whose linear-attention state belongs to a live stream"),
     "hyper_streams": (
         "gauge", "seldon_tpu_engine_hyper_streams",
         "rows of a token's residual (0: the one row of every other arch)"),

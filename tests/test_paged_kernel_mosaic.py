@@ -764,3 +764,98 @@ def test_the_tiled_kernels_compile_at_the_cells_shapes(one_chip, cell, held, d, 
         jax.config.update("jax_enable_compilation_cache", was)
     assert_expert_kernels(compiled.as_text(), "tiled", rows, d, f)
     assert compiled.memory_analysis().temp_size_in_bytes < rows * f * 2 + (1 << 20)
+
+
+def test_the_state_step_kernel_compiles_at_olmo_hybrid_s_shape(monkeypatch, one_chip, mosaic):
+    """``ops/delta.py delta_state_step`` on every slot's state of one
+    linear layer, (128, 15, 96, 384) float32: one Mosaic call, the state
+    rewritten where it rests (aliased: no second 283 MB)."""
+    from seldon_core_tpu.ops import delta
+
+    monkeypatch.setattr(delta, "backend", lambda: "tpu")
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    slots, heads, dk, dv = 128, 30, 96, 192
+    state = shape(delta.state_shape(slots, heads, dk, dv))
+    assert state.shape == (128, 15, 96, 384)
+    step = jax.jit(
+        lambda s, q, k, v, la, b, on: delta.step(s, q, k, v, la, b, pack=2, active=on),
+        donate_argnums=0,
+    ).lower(state, shape((slots, heads, dk)), shape((slots, heads, dk)),
+            shape((slots, heads, dv)), shape((slots, heads)), shape((slots, heads)),
+            shape((slots,), jnp.bool_)).compile()
+    assert step.as_text().count("tpu_custom_call") == 1
+    memory = step.memory_analysis()
+    assert memory.alias_size_in_bytes >= 128 * 15 * 96 * 384 * 4
+    assert memory.temp_size_in_bytes < 64 << 20
+
+
+def test_olmo_hybrid_s_programs_compile_and_the_prefill_cap_s_count_holds(
+        monkeypatch, one_chip, mosaic):
+    """The whole LM at the configuration's sizes (abstract weights: 4.87 GB
+    as they rest): a ``b512_k4`` prefill from zero (the chunked scan in
+    six layers, the fused causal kernel in two) and a 128-lane decode step
+    over the state a lane and the (2, 3073, 64, 3840) pools.  Both
+    compile; the decode step runs the state kernel in every linear layer
+    and the page loop in every full one; the prefill's temporaries and
+    logits are within a factor of two of ``prefill_position_bytes``'s
+    count (the float32 logits at every position lead it)."""
+    import json
+
+    from seldon_core_tpu.models import paged
+    from seldon_core_tpu.models.spec import declared_tree, model_spec
+    from seldon_core_tpu.ops import delta
+
+    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
+    monkeypatch.setattr(delta, "backend", lambda: "tpu")
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "configs",
+                           "olmo-hybrid-7b.json")) as f:
+        cfg = json.load(f)
+    served = {p["name"]: p["value"]
+              for p in cfg["deployment"]["predictors"][0]["graph"]["parameters"]}
+    spec = model_spec(served["arch"])
+    sizes = dict(vocab_size=int(served["vocab_size"]), d_model=int(served["d_model"]),
+                 num_layers=int(served["num_layers"]), num_heads=int(served["num_heads"]))
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    tree = declared_tree(spec, dict(sizes, max_len=int(served["max_len"])), jnp.bfloat16)
+    resting = sum(leaf.size * leaf.dtype.itemsize
+                  for leaf in jax.tree_util.tree_leaves(tree))
+    assert abs(resting - 4.87e9) < 0.01e9
+    params = jax.tree_util.tree_map(lambda leaf: shape(leaf.shape, leaf.dtype), tree)
+    lm = paged.get_paged_lm_class()(dtype=jnp.bfloat16, spec=spec, decode_kernel=True,
+                                    max_len=int(served["max_len"]), **sizes)
+    pool = shape((2, int(served["num_pages"]), 64, 3840), jnp.bfloat16)
+    i32, slots = jnp.int32, int(served["max_slots"])
+
+    def prefill(params, tokens, positions, pk, pv, tables, lengths, true_lens):
+        return lm.apply({"params": params}, tokens, positions, pk, pv, tables, lengths,
+                        delta={"true_lens": true_lens})
+
+    def step(params, tokens, positions, pk, pv, tables, lengths, state, conv, active):
+        return lm.apply({"params": params}, tokens, positions, pk, pv, tables, lengths,
+                        delta={"state": state, "conv": conv, "active": active})
+
+    def common(batch, seg, width):
+        return (params, shape((batch, seg), i32), shape((batch, seg), i32), pool, pool,
+                shape((batch, width), i32), shape((batch,), i32))
+
+    opts = dict(compiler_options=paged.TPU_COMPILER_OPTIONS)
+    first = jax.jit(prefill).lower(*common(4, 512, 0), shape((4,), i32)).compile(**opts)
+    memory = first.memory_analysis()
+    counted = 2048 * paged.prefill_position_bytes(spec, 3840, 100_352, 30)
+    by_compiler = memory.temp_size_in_bytes + memory.output_size_in_bytes
+    assert 1 / 2 < counted / by_compiler < 2, (counted, by_compiler)
+    state = tuple(shape(delta.state_shape(slots, 30, 96, 192), jnp.float32)
+                  for _ in range(6))
+    conv = tuple(shape((slots, 3, 11_520), jnp.bfloat16) for _ in range(6))
+    decode = jax.jit(step, donate_argnums=(7, 8)).lower(
+        *common(slots, 1, 16), state, conv, shape((slots,), jnp.bool_)).compile(**opts)
+    # six state kernels and two page loops a step; no copy of a state
+    assert decode.as_text().count("tpu_custom_call") >= 8
+    assert decode.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert first.as_text().count("tpu_custom_call") >= 2  # the fused causal kernel

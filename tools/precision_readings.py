@@ -16,6 +16,12 @@ as ``harness/kinds/generation_share_window.py`` applies them, the wrong
 programs ``reference/smallthinker.py``'s ``VARIANTS`` — the router fed
 from the post-attention norm, ``silu`` for ``relu``, a full layer
 rotated, a window layer left whole, a K/V head mapped ``h % 4``; with
+``--config olmo-hybrid-7b --positions 634 --judged 128`` Olmo-Hybrid's: a
+prompt of 506 (its cell's longest judged) and 128 judged tokens, through
+the two limits of ``harness/kinds/generation_state.py``, the wrong
+programs ``reference/olmo_hybrid.py``'s ``VARIANTS`` — the state kept in
+bfloat16, beta without its factor 2, the decay left at 1, the full layers
+rotated, the norms moved to the sub-layers' inputs; with
 ``--config xing4.0-29b-a4b --positions 4352 --judged 256`` Xing4.0's: the
 longest prompt of its cell and 256 judged tokens, through the two limits
 of its kind (``harness/kinds/generation_share_whole.py verdict``), the
@@ -481,7 +487,9 @@ def main() -> int:
     n = args.positions
     tokens = np.random.default_rng(args.seed).integers(0, model["vocab_size"], size=n).tolist()
     dots3 = config["reference"] == "dots3_note"
-    if config["reference"] == "smallthinker":
+    if config["reference"] in ("smallthinker", "olmo_hybrid"):
+        # (Olmo-Hybrid: the same hooks — ``rounding=`` and the reference's
+        # own ``VARIANTS`` — under its kind's two limits)
         return smallthinker_readings(args, config, ref, params, tokens)
     if config["reference"] == "xing4":
         return xing4_readings(args, config, ref, params, tokens)
