@@ -139,8 +139,15 @@ def prefill_position_bytes(spec, d_model: int, vocab_size: int,
     buffer assignment good to a third: 8,192 positions of GigaChat3.1
     were 3.0 GB by the compiler's count, 2.4 GB by this one):
 
-    * float32 logits at every position (ROADMAP S4) and the float32
-      residual stream beside its normed bf16 copy — under a residual
+    * ``4 * vocab_size``: what float32 logits at every position took.
+      No program holds them since PR 49 (a prefill unembeds the one row
+      a prompt it returns, ``_unembed``); the term is kept so that no
+      cell's ``prefill_positions_max`` moves in the same PR as the
+      program — larger groups would form than the cells' traffic warms
+      — and its removal is queued with their ``warm_group_max``
+      (ROADMAP S3 c′);
+    * the float32 residual stream beside its normed bf16 copy — under a
+      residual
       of ``spec.hc_mult`` rows (ops/hyper.py) those rows twice, the
       ones a sub-layer's mixing reads and the ones it writes, beside
       the one row the sub-layer reads and its normed copy;
@@ -1089,17 +1096,28 @@ def _build_modules():
         )(positions)
         return x + pos
 
-    def _head(lm, x, new_k, new_v, hists):
-        """Final norm and unembedding; ``(logits, K, V)`` stacked over
-        layers, and a routed spec's ``int32[layers, E]`` assignment
-        histogram as a fourth value.  A residual of several rows leaves
-        as their sum."""
+    def _unembed(lm, x, last=None):
+        """Final norm and unembedding of the residual ``(B, L, d)``:
+        float32 logits ``(B, L, vocab)``.  A residual of several rows
+        ``(n, B, L, d)`` leaves as their sum.  With ``last`` — ``(B,)``
+        int32, a row's one position to unembed — the position is
+        gathered first, so the sum, the norm, the matmul and the cast
+        run on ``(B, 1, d)`` and the logits are ``(B, 1, vocab)``: a
+        prefill returns one row a prompt (PERF.md §6 PR 49)."""
+        if last is not None:
+            x = jnp.take_along_axis(
+                x, last.reshape((1,) * (x.ndim - 3) + (-1, 1, 1)), axis=-2)
         if lm.spec.hc_mult:
             x = x.sum(axis=0)
         x = _norm(lm.spec, "final_norm")(x)
         logits = _dense(lm.precision, lm.vocab_size, lm.dtype, "head",
                         lm.spec)(x)
-        out = (logits.astype(jnp.float32), jnp.stack(new_k),
+        return logits.astype(jnp.float32)
+
+    def _head(lm, x, new_k, new_v, hists, last=None):
+        """``(logits, K, V)`` stacked over layers, and a routed spec's
+        ``int32[layers, E]`` assignment histogram as a fourth value."""
+        out = (_unembed(lm, x, last), jnp.stack(new_k),
                None if new_v[0] is None else jnp.stack(new_v))  # one pool: no V
         return out + (jnp.stack(hists),) if hists else out
 
@@ -1704,7 +1722,9 @@ def _build_modules():
         @nn.compact
         def __call__(self, tokens, positions, pages_k, pages_v, block_tables,
                      lengths, lora=None, adapter_idx=None, kv_scales=None,
-                     token_mask=None, window=None, delta=None):
+                     token_mask=None, window=None, delta=None, last=None):
+            # last: (B,) int32 — the one position of each row to
+            # unembed (a prefill's); None unembeds all L (_unembed)
             x = _embed(self, tokens, positions)
             # The kernel lane (no TP mesh — decode_kernel=False is how
             # the engine encodes one; env, dtype, backend: the shared
@@ -1722,10 +1742,11 @@ def _build_modules():
             if self.spec.kinds:
                 return self._kinds(x, positions, pages_k, pages_v,
                                    block_tables, lengths, token_mask, window,
-                                   whole)
+                                   whole, last)
             if self.spec.linear:
                 return self._hybrid(x, positions, pages_k, pages_v,
-                                    block_tables, lengths, whole, delta or {})
+                                    block_tables, lengths, whole, delta or {},
+                                    last)
             for i in range(self.num_layers):
                 if whole:
                     pools = (pages_k, pages_v)
@@ -1760,10 +1781,10 @@ def _build_modules():
                 new_k += k if isinstance(k, tuple) else [k]
                 new_v.append(v)
                 hists += hist
-            return _head(self, x, new_k, new_v, hists)
+            return _head(self, x, new_k, new_v, hists, last)
 
         def _hybrid(self, x, positions, pages_k, pages_v, block_tables,
-                    lengths, whole, delta):
+                    lengths, whole, delta, last):
             """The layers of a spec with linear-attention layers: a
             ``"linear"`` layer is a :class:`DeltaBlock` over its own state
             and keeps no pages; a ``"full"`` layer is the grouped-query
@@ -1808,14 +1829,11 @@ def _build_modules():
                   layer=at if whole else None, positions=positions)
                 new_k.append(k)
                 new_v.append(v)
-            x = _norm(spec, "final_norm")(x)
-            logits = _dense(self.precision, self.vocab_size, self.dtype,
-                            "head", spec)(x)
-            return (logits.astype(jnp.float32), jnp.stack(new_k),
+            return (_unembed(self, x, last), jnp.stack(new_k),
                     jnp.stack(new_v), tuple(states), tuple(tails))
 
         def _kinds(self, x, positions, pools, pools_v, block_tables, lengths,
-                   token_mask, window, whole):
+                   token_mask, window, whole, last):
             """The layers of a spec whose attention differs by layer:
             ``pools`` is ``{"full", "index", "window"}`` (models/spec.py
             ``cache_kinds``), each ``(layers of the kind, pages,
@@ -1853,12 +1871,9 @@ def _build_modules():
                     rows_v[names[0]].append(v)
                 hists.append(hist)
                 reads += read
-            x = _norm(spec, "final_norm")(x)
-            logits = _dense(self.precision, self.vocab_size, self.dtype,
-                            "head", spec)(x)
             # (a decode step's fifth value: what each layer read,
             # int32[layers, 3] — _latent_attention)
-            return (logits.astype(jnp.float32),
+            return (_unembed(self, x, last),
                     {n: jnp.stack(r) for n, r in rows.items()},
                     {n: jnp.stack(r) for n, r in rows_v.items()} or None,
                     jnp.stack(hists), *((jnp.stack(reads),) if reads else ()))
@@ -3931,6 +3946,9 @@ class PagedEngine:
                           # bucket, so a call computes k * bucket
                           # positions whatever its true tokens
                           "prefill_padded_tokens": 0,
+                          # ... and the rows it unembedded: one a
+                          # prompt of the padded group (PR 49)
+                          "prefill_head_rows": 0,
                           # of those, the positions whose attention ran
                           # in the fused causal kernel (a from-zero
                           # prefill of a bucket ``_prefill_attention``
@@ -4647,7 +4665,7 @@ class PagedEngine:
                 read_rows, lengths, lora=lora, adapter_idx=adapter_idx,
                 kv_scales=kv_scales_arg(sk, sv),
                 token_mask=self._routed_rows(bucket, true_lens), **kinds,
-                **linear,
+                **linear, last=true_lens - 1,
             )
             delta, hist = delta_written(delta, hist, slots)
             valid = jnp.arange(bucket)[None, :] < true_lens[:, None]
@@ -4655,8 +4673,7 @@ class PagedEngine:
                 pk, pv, nk, nv, block_rows, jnp.zeros((k,), jnp.int32), valid,
                 from_zero=True, **kinds,
             )
-            last = logits[jnp.arange(k), true_lens - 1]  # (k, vocab)
-            return (last, delta_join(pk, delta), pv, *hist)
+            return (logits[:, 0], delta_join(pk, delta), pv, *hist)  # (k, vocab)
 
         return self._sentinels["paged_prefill"].wrap(
             self._tp_jit(prefill, name=f"paged_prefill_b{bucket}_k{k}",
@@ -4699,14 +4716,14 @@ class PagedEngine:
                 lora=lora, adapter_idx=adapter_idx,
                 kv_scales=kv_scales_arg(sk, sv),
                 token_mask=self._routed_rows(bucket, true_lens),
+                last=true_lens - 1,
             )
             valid = jnp.arange(bucket)[None, :] < true_lens[:, None]
             pk, pv = self._write_kv(
                 pk, pv, nk, nv, write_rows, jnp.zeros((k,), jnp.int32), valid,
                 from_zero=True,
             )
-            last = logits[jnp.arange(k), true_lens - 1]  # (k, vocab)
-            return (last, pk, pv, *hist)
+            return (logits[:, 0], pk, pv, *hist)  # (k, vocab)
 
         return self._sentinels["paged_prefill"].wrap(
             self._tp_jit(prefill,
@@ -7031,6 +7048,7 @@ class PagedEngine:
         try:
             with self._lock:
                 self._counters["prefill_padded_tokens"] += k * bucket
+                self._counters["prefill_head_rows"] += k
                 if fused:
                     self._counters["prefill_fused_positions"] += k * bucket
                 if indexed_fused:

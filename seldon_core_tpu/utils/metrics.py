@@ -296,6 +296,11 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
                               "positions the prefill programs computed: "
                               "each call pays its group rounded up to a "
                               "power of two times its prompt bucket"),
+    "prefill_head_rows": ("counter",
+                          "seldon_tpu_engine_prefill_head_rows_total",
+                          "rows the prefill programs unembedded: one a "
+                          "prompt of each call's padded group, whatever "
+                          "the positions it computed"),
     "prefill_fused_positions": ("counter",
                                 "seldon_tpu_engine_prefill_fused_positions_total",
                                 "of those positions, the ones of from-zero "

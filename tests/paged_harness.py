@@ -25,7 +25,8 @@ from seldon_core_tpu.models.spec import GPT2, init_params
 from seldon_core_tpu.ops import kernels
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
-from reference import deepseek_v3, longcat_flash, olmoe, xing4  # noqa: E402
+from reference import (  # noqa: E402
+    deepseek_v3, dots3_note, longcat_flash, olmo_hybrid, olmoe, smallthinker, xing4)
 
 PAGE, MAX_LEN, SLOTS = 8, 64, 4
 PROMPT = np.random.default_rng(5).integers(0, 97, size=29).tolist()
@@ -82,6 +83,46 @@ MODELS = {
                           beta_fast=32, beta_slow=1, mscale=1, mscale_all_dim=1),
         rms_norm_eps=1e-6, hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6,
         mhc_h_res_clamp_min=-30, mhc_h_res_clamp_max=30)),
+    # dots3-note: d 32, full | window | window | full; the full layers 4
+    # heads under an indexer of 4 heads keeping 16 keys, the window
+    # layers 2 heads over 9 positions; 1 dense + 3 expert layers of 8
+    # experts top-2, the replica holds 2
+    "dots3": (dots3_note, dict(
+        hidden_size=32, num_hidden_layers=4, num_attention_heads=4, vocab_size=64,
+        layer_types=["full_attention", "sliding_attention", "sliding_attention",
+                     "full_attention"],
+        first_k_dense_replace=1, intermediate_size=48, moe_intermediate_size=16,
+        n_routed_experts=2, n_routed_experts_published=8, expert_offset=2,
+        n_shared_experts=1, num_experts_per_tok=2, routed_scaling_factor=1,
+        norm_topk_prob=True, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=8, v_head_dim=8, rope_theta=80000000,
+        swa_num_attention_heads=2, swa_q_lora_rank=24, swa_kv_lora_rank=32,
+        swa_qk_nope_head_dim=16, swa_qk_rope_head_dim=8, swa_v_head_dim=8,
+        swa_rope_theta=50000, sliding_window_size=9, index_n_heads=4,
+        index_head_dim=16, index_topk=16, apply_mla_qkv_lora_rescale=True,
+        rms_norm_eps=1e-5)),
+    # SmallThinker: 4 heads of 16 over 2 K/V heads, full | window x 3
+    # twice (the window layers rotate, 8 positions), 8 experts top-2 of
+    # width 32, the replica holds 4
+    "smallthinker": (smallthinker, dict(
+        hidden_size=64, num_hidden_layers=8, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=16, vocab_size=64,
+        sliding_window_layout=[0, 1, 1, 1, 0, 1, 1, 1],
+        rope_layout=[0, 1, 1, 1, 0, 1, 1, 1], sliding_window_size=8,
+        rope_theta=1500000, rms_norm_eps=1e-6, moe_ffn_hidden_size=32,
+        moe_num_primary_experts=4, moe_num_primary_experts_published=8,
+        expert_offset=2, moe_num_active_primary_experts=2, norm_topk_prob=True)),
+    # Olmo-Hybrid: d 64, 4 heads of 16 in the full layers; linear layers
+    # of 4 heads, 8 (q, k) against 64 (v): two heads rest side by side
+    # in 128 lanes; linear x 3, full, twice
+    "olmo_hybrid": (olmo_hybrid, dict(
+        model_type="olmo_hybrid", vocab_size=97, hidden_size=64,
+        intermediate_size=96, num_hidden_layers=8, num_attention_heads=4,
+        num_key_value_heads=4, rms_norm_eps=1e-6, max_position_embeddings=128,
+        layer_types=(["linear_attention"] * 3 + ["full_attention"]) * 2,
+        linear_num_key_heads=4, linear_num_value_heads=4, linear_key_head_dim=8,
+        linear_value_head_dim=64, linear_conv_kernel_dim=4,
+        linear_allow_neg_eigval=True)),
 }
 
 

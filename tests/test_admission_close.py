@@ -170,5 +170,22 @@ def test_three_long_prompts_prefill_as_two_calls_and_pay_no_empty_row(params):
         paged.PREFILL_PAD_POSITIONS = old
     after = eng.engine_stats()
     assert after["prefill_padded_tokens"] - before["prefill_padded_tokens"] == 3 * bucket
+    assert after["prefill_head_rows"] - before["prefill_head_rows"] == 2 + 1
     assert set(eng._prefill_jit) == {(bucket, 2), (bucket, 1)}
     assert all(s.result is not None and len(s.result) == 3 for s in streams)
+
+
+@pytest.mark.parametrize("rows, k", [(1, 1), (2, 2), (3, 4)])
+def test_a_prefill_call_counts_the_rows_it_unembeds(params, rows, k):
+    """``prefill_head_rows`` rises by ``k`` a call — a row a prompt of
+    the padded group (PR 49) — beside ``k * bucket`` padded positions."""
+    eng = _engine(params, max_slots=4)
+    bucket = next(b for b in eng.prompt_buckets if b >= 9)
+    for i in range(rows):
+        eng.submit(_prompt(9, i + 1), max_new_tokens=2, seed=i + 1)
+    before = eng.engine_stats()
+    eng.run()
+    after = eng.engine_stats()
+    assert set(eng._prefill_jit) == {(bucket, k)}
+    assert after["prefill_head_rows"] - before["prefill_head_rows"] == k
+    assert after["prefill_padded_tokens"] - before["prefill_padded_tokens"] == k * bucket

@@ -24,8 +24,6 @@ kernel, the reference's tail, the share and the component's front door
 module's engines).
 """
 
-import os
-import sys
 from functools import partial
 
 import numpy as np
@@ -39,23 +37,7 @@ from seldon_core_tpu.models import paged
 from seldon_core_tpu.models.paged import PagedEngine
 from seldon_core_tpu.models.spec import init_params, model_spec
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
-from reference import dots3_note as ref  # noqa: E402
-
-MODEL = dict(
-    hidden_size=32, num_hidden_layers=4, num_attention_heads=4, vocab_size=64,
-    layer_types=["full_attention", "sliding_attention", "sliding_attention",
-                 "full_attention"],
-    first_k_dense_replace=1, intermediate_size=48, moe_intermediate_size=16,
-    n_routed_experts=2, n_routed_experts_published=8, expert_offset=2,
-    n_shared_experts=1, num_experts_per_tok=2, routed_scaling_factor=1,
-    norm_topk_prob=True, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8,
-    qk_rope_head_dim=8, v_head_dim=8, rope_theta=80000000,
-    swa_num_attention_heads=2, swa_q_lora_rank=24, swa_kv_lora_rank=32,
-    swa_qk_nope_head_dim=16, swa_qk_rope_head_dim=8, swa_v_head_dim=8,
-    swa_rope_theta=50000, sliding_window_size=9, index_n_heads=4,
-    index_head_dim=16, index_topk=16, apply_mla_qkv_lora_rescale=True,
-    rms_norm_eps=1e-5)
+ref, MODEL = harness.MODELS["dots3"]
 SPEC, SIZES = ref.spec_and_config(MODEL)
 PAGE, MAX_LEN, SLOTS = 4, 64, 4
 TOPK, WINDOW = MODEL["index_topk"], MODEL["sliding_window_size"]

@@ -21,8 +21,6 @@ types' plumbing (a bfloat16 tail, float32 state); the published widths'
 precision is read by ``tools/precision_readings.py`` and on the chip.
 """
 
-import os
-import sys
 
 import numpy as np
 import pytest
@@ -34,18 +32,7 @@ from seldon_core_tpu.models import paged
 from seldon_core_tpu.models.paged import PagedEngine
 from seldon_core_tpu.models.spec import OLMO_HYBRID, init_params, model_spec
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
-from reference import olmo_hybrid as ref  # noqa: E402
-
-# d 64, 4 heads of 16 in the full layers; linear layers of 4 heads, 8
-# (q, k) against 64 (v): two heads rest side by side in 128 lanes
-TINY = dict(
-    model_type="olmo_hybrid", vocab_size=97, hidden_size=64, intermediate_size=96,
-    num_hidden_layers=8, num_attention_heads=4, num_key_value_heads=4,
-    rms_norm_eps=1e-6, max_position_embeddings=128,
-    layer_types=(["linear_attention"] * 3 + ["full_attention"]) * 2,
-    linear_num_key_heads=4, linear_num_value_heads=4, linear_key_head_dim=8,
-    linear_value_head_dim=64, linear_conv_kernel_dim=4, linear_allow_neg_eigval=True)
+ref, TINY = harness.MODELS["olmo_hybrid"]
 SPEC, SIZES = ref.spec_and_config(TINY)
 ENGINE = dict(max_len=128, prompt_buckets=[16, 32, 64])
 TOL = 3e-4

@@ -665,8 +665,9 @@ def test_xing4_s_programs_compile_and_the_prefill_cap_s_count_holds(
     GB as they rest), a 4,096-position prefill from zero and a 128-lane
     decode step over the (6, 9217, 64, 640) pool: both compile, every
     layer runs its latent / prefill kernel, its grouped matmuls and the
-    mixing's pair, and the prefill's temporaries and logits are within a
-    third of ``prefill_position_bytes``'s count (339 KB a position)."""
+    mixing's pair, and the prefill's temporaries and outputs are within a
+    third of ``prefill_position_bytes``'s count (339 KB a position; 1.39
+    GB against the compiler's 1.17 with one row unembedded, PR 49)."""
     import json
 
     from seldon_core_tpu.models import paged
@@ -697,18 +698,19 @@ def test_xing4_s_programs_compile_and_the_prefill_cap_s_count_holds(
                                     max_len=int(served["max_len"]), **sizes)
     pool = shape((6, int(served["num_pages"]), 64, 640), jnp.bfloat16)
 
-    def run(params, tokens, positions, pool, tables, lengths):
+    def run(params, tokens, positions, pool, tables, lengths, last):
         return lm.apply({"params": params}, tokens, positions, pool, None, tables,
-                        lengths, token_mask=jnp.ones(tokens.shape, bool))
+                        lengths, token_mask=jnp.ones(tokens.shape, bool), last=last)
 
-    def compiled(batch, seg, width):
+    def compiled(batch, seg, width, last=None):
         i32 = jnp.int32
         return jax.jit(run).lower(
             params, shape((batch, seg), i32), shape((batch, seg), i32), pool,
-            shape((batch, width), i32), shape((batch,), i32)).compile(
+            shape((batch, width), i32), shape((batch,), i32), last).compile(
                 compiler_options=paged.TPU_COMPILER_OPTIONS)
 
-    prefill = compiled(1, 4096, 0)
+    # as the engine's prefill calls it: one row a prompt unembedded (PR 49)
+    prefill = compiled(1, 4096, 0, shape((1,), jnp.int32))
     memory = prefill.memory_analysis()
     counted = 4096 * paged.prefill_position_bytes(spec, 3584, 16384, 32)
     by_compiler = memory.temp_size_in_bytes + memory.output_size_in_bytes
@@ -800,8 +802,10 @@ def test_olmo_hybrid_s_programs_compile_and_the_prefill_cap_s_count_holds(
     over the state a lane and the (2, 3073, 64, 3840) pools.  Both
     compile; the decode step runs the state kernel in every linear layer
     and the page loop in every full one; the prefill's temporaries and
-    logits are within a factor of two of ``prefill_position_bytes``'s
-    count (the float32 logits at every position lead it)."""
+    outputs are within a factor of two of ``prefill_position_bytes``'s
+    count (1.25 GB against the compiler's 0.78 with one row unembedded,
+    PR 49: the count keeps its ``4 * vocab_size`` a position, which no
+    program holds any more — ROADMAP S3 c')."""
     import json
 
     from seldon_core_tpu.models import paged
@@ -833,8 +837,9 @@ def test_olmo_hybrid_s_programs_compile_and_the_prefill_cap_s_count_holds(
     i32, slots = jnp.int32, int(served["max_slots"])
 
     def prefill(params, tokens, positions, pk, pv, tables, lengths, true_lens):
+        # as the engine's prefill calls it: one row a prompt unembedded (PR 49)
         return lm.apply({"params": params}, tokens, positions, pk, pv, tables, lengths,
-                        delta={"true_lens": true_lens})
+                        delta={"true_lens": true_lens}, last=true_lens - 1)
 
     def step(params, tokens, positions, pk, pv, tables, lengths, state, conv, active):
         return lm.apply({"params": params}, tokens, positions, pk, pv, tables, lengths,

@@ -17,8 +17,6 @@ through its own front door (``submit`` / ``step``), on the kernel lane
 logits are read where the engine calls it.
 """
 
-import os
-import sys
 from functools import partial
 
 import numpy as np
@@ -28,17 +26,7 @@ import jax.numpy as jnp
 
 import paged_harness as harness
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
-from reference import smallthinker as ref  # noqa: E402
-
-LAYOUT = [0, 1, 1, 1, 0, 1, 1, 1]
-MODEL = dict(
-    hidden_size=64, num_hidden_layers=8, num_attention_heads=4,
-    num_key_value_heads=2, head_dim=16, vocab_size=64,
-    sliding_window_layout=LAYOUT, rope_layout=LAYOUT, sliding_window_size=8,
-    rope_theta=1500000, rms_norm_eps=1e-6, moe_ffn_hidden_size=32,
-    moe_num_primary_experts=4, moe_num_primary_experts_published=8,
-    expert_offset=2, moe_num_active_primary_experts=2, norm_topk_prob=True)
+ref, MODEL = harness.MODELS["smallthinker"]
 SPEC, SIZES = ref.spec_and_config(MODEL)
 PAGE, MAX_LEN, SLOTS = 4, 64, 4
 WINDOW = MODEL["sliding_window_size"]
