@@ -38,6 +38,7 @@ from __future__ import annotations
 import logging
 import queue as _queue
 import threading
+import weakref
 from collections import OrderedDict, deque
 from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
@@ -296,6 +297,7 @@ from seldon_core_tpu.models.generate import _buckets_for
 from seldon_core_tpu.runtime import knobs as _knobs
 from seldon_core_tpu.runtime.component import MicroserviceError, TPUComponent
 from seldon_core_tpu.utils import faults as _faults
+from seldon_core_tpu.utils import jitwatch as _jitwatch
 from seldon_core_tpu.utils import telemetry as _telemetry
 from seldon_core_tpu.utils.deadlines import deadline_exceeded
 
@@ -2872,6 +2874,178 @@ def journal_entry(
     }
 
 
+class _DeviceClock:
+    """When each dispatched program of the wave loop FINISHED, stamped by
+    one watcher thread an engine, and from that what the device did
+    between them.
+
+    The engine thread hands over ``(enq, out, transitions)`` a
+    dispatch (:meth:`watch`: one tuple, one ``put``): ``enq`` is the
+    seam's clock as the dispatch returned, ``out`` the smallest output
+    of the program that is not donated onward, ``transitions`` the
+    ``(where, t)`` at which the engine thread changed phase since the
+    dispatch before.  The watcher blocks on each ``out`` in order (the
+    GIL released) and reads the same clock as the block returns:
+    ``done``.  The device runs one queue in order, so program *i*
+    started at ``max(enq[i], done[i-1])``, ran ``done[i]`` minus that,
+    and **the device sat idle before it for ``max(0, enq[i] -
+    done[i-1])``** — laid over the transitions, that idle is booked to
+    where the engine thread was (``by``).  ``busy + idle`` is the clock
+    from the first enqueue to the last completion, exactly.
+
+    A program with no output to wait on (None: its outputs are donated
+    onward), or whose array was deleted under the watcher, has no stamp
+    of its own: the next program's bounds it (the two count as one busy
+    block, and no idle is booked between them).
+
+    What it under-reads: ``done`` is late by the watcher's wake-up — a
+    thread switch, and the wait for the GIL when the engine thread is
+    in Python just then — so an idle interval is short by that much;
+    and time between two programs' own operations, or under an eager
+    operation between two dispatches, is not idle here.
+
+    Every sum is the watcher's; ``totals`` is ONE tuple, replaced whole,
+    so any thread reads a consistent four.  The thread starts with the
+    first dispatch and ends on a sentinel: :meth:`stop` (``close()``),
+    the seam's finalizer, or the process's ``atexit`` hook — it is never
+    inside jax when the interpreter goes."""
+
+    WHERE = ("no_work", "between", "admit", "prefill.pack", "prefill.call",
+             "prefill.tail", "launch.plan", "launch.call", "launch.post",
+             "wait", "harvest", "record")
+
+    _live: "weakref.WeakSet[_DeviceClock]" = weakref.WeakSet()
+    _hooked = False
+
+    def __init__(self, clock):
+        self._clock = clock
+        self._queue: _queue.SimpleQueue = _queue.SimpleQueue()
+        self._thread: Optional[threading.Thread] = None
+        self._stopped = False
+        self._busy = 0.0
+        self._idle = 0.0
+        self._programs = 0
+        self._by: Dict[str, float] = dict.fromkeys(self.WHERE, 0.0)
+        self.totals: Tuple[float, float, int, Dict[str, float]] = (
+            0.0, 0.0, 0, self._by)
+        # the newest completion stamped; the start of a busy block no
+        # stamp has closed yet and the programs in it; the engine
+        # thread's phase as of the last transition handed over
+        self._last_done: Optional[float] = None
+        self._open: Optional[float] = None
+        self._pending = 0
+        self._where = "no_work"
+        self.cpu_s = 0.0  # the watcher's own CPU seconds, at its exit
+
+    # ---- the engine thread's side --------------------------------------
+
+    def watch(self, enq: float, out: Any, transitions: list) -> None:
+        if self._thread is None:
+            if self._stopped:
+                return
+            self._start()
+        self._queue.put((enq, out, transitions))
+
+    def _start(self) -> None:
+        cls = _DeviceClock
+        if not cls._hooked:
+            import atexit
+
+            # registered after jax's own hooks, so run before them
+            atexit.register(cls._stop_all)
+            cls._hooked = True
+        cls._live.add(self)
+        self._thread = threading.Thread(
+            target=self._run, name="seldon-device-clock", daemon=True)
+        self._thread.start()
+
+    def stop(self, timeout: float = 5.0) -> None:
+        """End the watcher (idempotent): what is queued is settled
+        first, and nothing dispatched afterwards is watched."""
+        self._stopped = True
+        thread = self._thread
+        if thread is not None and thread.is_alive():
+            self._queue.put(None)
+            if thread is not threading.current_thread():
+                thread.join(timeout)
+
+    @classmethod
+    def _stop_all(cls) -> None:
+        for clock in list(cls._live):
+            clock.stop(timeout=2.0)
+
+    # ---- the watcher's side --------------------------------------------
+
+    def _run(self) -> None:
+        import time as _time
+
+        get, clock = self._queue.get, self._clock
+        try:
+            while True:
+                item = get()
+                if item is None:
+                    return
+                enq, out, transitions = item
+                done = None
+                if out is not None:
+                    try:
+                        out.block_until_ready()
+                        done = clock()
+                    except Exception:  # noqa: BLE001 — deleted under us, or
+                        pass           # the device failed: the next stamp bounds it
+                del out, item
+                try:
+                    self.settle(enq, done, transitions)
+                except Exception:  # noqa: BLE001 — never raises into serving
+                    logger.exception("device clock: a stamp was not settled")
+        finally:
+            self.cpu_s = _time.thread_time()
+            logger.info(
+                "device clock: %d programs stamped, busy %.3f s, idle %.3f s; "
+                "the watcher's own CPU %.3f s",
+                self._programs, self._busy, self._idle, self.cpu_s)
+
+    def settle(self, enq: float, done: Optional[float], transitions) -> None:
+        """Program enqueued at ``enq``, finished by ``done`` (None: no
+        stamp of its own), the engine thread's ``(where, t)`` since the
+        enqueue before."""
+        if self._open is None:
+            last = self._last_done
+            if last is None:
+                self._open = enq
+            elif enq > last:
+                self._book(last, enq, transitions)
+                self._idle += enq - last
+                self._open = enq
+            else:
+                self._open = last
+        if transitions:
+            self._where = transitions[-1][0]
+        self._pending += 1
+        if done is not None:
+            self._busy += done - self._open
+            self._programs += self._pending
+            self._pending = 0
+            self._last_done = done
+            self._open = None
+        self.totals = (self._busy, self._idle, self._programs, self._by)
+
+    def _book(self, a: float, b: float, transitions) -> None:
+        """The idle interval ``[a, b]`` by where the engine thread was:
+        split at every transition inside it."""
+        by = dict(self._by)  # copied, so a published dict never changes
+        where, since = self._where, a
+        for name, t in transitions:
+            if t >= b:
+                break
+            if t > since:
+                by[where] = by.get(where, 0.0) + (t - since)
+                since = t
+            where = name
+        by[where] = by.get(where, 0.0) + (b - since)
+        self._by = by
+
+
 class _WaveSeam:
     """The one seam every host phase and every device call of the wave
     loop passes through.  Always on: what it costs is in every run.
@@ -2886,26 +3060,44 @@ class _WaveSeam:
       and harvested under the chunk this step just enqueued; each
       carries its own ``wave=``.  ``prefill`` (one per
       ``_prefill_group`` call) nests inside whichever of them runs it.
-      They land on the engine thread's line of the host plane of the
-      same ``.xplane.pb`` as the device's operations; with no profiler
-      session open each is a flag test.
-    * **The host gap.**  ``dispatched`` marks the return of a dispatch
-      of a program of the wave loop (its argument transfers, signature
-      walk and enqueue are host work the device waits for) and numbers
-      it (``seq``); ``drained(upto)`` the return of a blocking readback
+      Two phases are tiled again by ``sub``: ``prefill`` by
+      ``seldon.wave.prefill.{pack,call,tail}`` (the numpy tables and
+      their puts; the jitted call; the eager tail that installs the
+      decode state) and ``launch`` by ``seldon.wave.launch.{plan,call,
+      post}`` (under the lock: retire, growth, tables; the argument puts
+      and the chunk's dispatch; the screen, the async copies, the
+      ``_Wave``).  They land on the engine thread's line of the host
+      plane of the same ``.xplane.pb`` as the device's operations; with
+      no profiler session open each is a flag test.
+    * **The device's idle time, by where the engine thread was.**
+      ``dispatched(out)`` marks the return of a dispatch of a program of
+      the wave loop (its argument transfers, signature walk and enqueue
+      are host work the device waits for), numbers it (``seq``) and
+      hands its stamp, ``out`` and the phase transitions since the
+      dispatch before to the :class:`_DeviceClock`, whose watcher thread
+      stamps the program's completion: ``device_busy_s``,
+      ``device_idle_s``, ``device_idle_by_s``.  ``no_work`` is the time
+      from ``end_wave(False)`` (no stream admitted or queued) to the
+      next ``begin_wave``: the callers' turn-around, not the host's.
+    * **The host gap** (``host_gap_s``; blind since PR 29 wherever a
+      chunk is enqueued ahead; see ``device_idle_s``).
+      ``drained(upto)`` marks the return of a blocking readback
       of what dispatch ``upto`` produced.  The device runs one queue in
       order, so everything up to ``upto`` has run; the gap opens only
       if nothing was dispatched after it, i.e. nothing is in flight any
-      more.  The time to the next dispatch is ``host_gap_s``, kept by
-      the phase it was spent in (``phase_s``; ``between`` is the time
-      between two steps).  A wave that leaves no work behind closes the
-      gap uncounted.
+      more, and lasts to the next dispatch.  A wave that leaves no work
+      behind closes the gap uncounted.  The serving loop enqueues a
+      chunk before it reads the one before, so there the gap never
+      opens while the device drains all the same.
     * **The engine thread's time, always.**  Every phase's wall time is
       booked where it ends, gap or no gap (``phase_walls``): one clock
       read a phase.  ``wait`` is the thread blocked in a readback — the
       device sets the pace; every other phase but ``between`` (waiting
       for a request) is the host's work, and once ``host_work_s``
       nears ``host_work_s + host_wait_s`` the host sets it.
+    * **Compiles, where they happen.**  ``compile_context`` tells the
+      process's backend-compile listener (``utils/jitwatch.py``) the
+      open phase and the wave as a compile fires on the engine thread.
     * **The profile window.**  ``arm`` asks for ``seconds`` of
       ``jax.profiler`` trace under ``SELDON_TPU_PROFILE_DIR``;
       ``boundary`` (every wave boundary, on the engine thread) starts it,
@@ -2915,6 +3107,15 @@ class _WaveSeam:
 
     PHASES = ("admit", "prefill", "launch", "wait", "harvest", "record",
               "between")
+    # the phases ``sub`` tiles, and the part each opens with
+    TILED = {"prefill": "pack", "launch": "plan"}
+    # a transition's name -> the phase whose wall time it is
+    _WALL_OF = dict(
+        {w: w.partition(".")[0] for w in _DeviceClock.WHERE},
+        no_work="between", **{p: p for p in PHASES})
+    # transitions kept for one dispatch: a loop that turns without ever
+    # dispatching must not grow the list
+    MAX_TRANSITIONS = 4096
 
     def __init__(self, engine: "PagedEngine", profile_dir: Optional[str]):
         import time as _time
@@ -2924,24 +3125,34 @@ class _WaveSeam:
         self._clock = _time.perf_counter
         self._monotonic = _time.monotonic
         self.wave = 0
-        self.phase_s: Dict[str, float] = {p: 0.0 for p in self.PHASES}
         # every phase's wall seconds so far, the phase now open and
         # where it began: ONE tuple, replaced whole where a phase ends,
         # so that another thread reads a consistent three (phase_walls)
         self._walls: Tuple[Dict[str, float], str, float] = (
             {p: 0.0 for p in self.PHASES}, "between", self._clock())
         # open annotations, outermost first: the step, its current
-        # phase, a prefill group nested in that — (phase, annotation)
+        # phase, a prefill group nested in that, the part of a tiled
+        # phase — (transition name, annotation)
         self._open: List[Tuple[str, Any]] = []
         # whether the outermost of them is a step: a burst's last wave is
         # harvested with nothing left to launch, outside any step
         self._in_step = False
-        self._phase = "between"
+        self._phase = "no_work"
         self._gap_open = False
+        self._gap_s = 0.0
         self._mark = 0.0
         # dispatches of wave-loop programs so far: a readback names the
         # one it waited for, and opens the gap only if it is the newest
         self.seq = 0
+        # completions, and the (where, t) since the last dispatch
+        self.device = _DeviceClock(self._clock)
+        self._transitions: List[Tuple[str, float]] = []
+        # the engine is dropped without close(): the watcher still ends
+        weakref.finalize(self, self.device.stop, 0.0)
+        # the process's compiles since this engine was built
+        _jitwatch.watch_backend_compiles()
+        self._compiles_base = _jitwatch.compile_totals()
+        self._thread_ident: Optional[int] = None
         self._profile_dir = profile_dir
         self._profile_lock = threading.Lock()
         self._profile: Dict[str, Any] = {"state": "idle"}
@@ -2955,45 +3166,76 @@ class _WaveSeam:
         walls, ending, since = self._walls
         walls = dict(walls)
         walls[ending] += now - since
-        self._walls = (walls, phase, now)
+        self._walls = (walls, self._WALL_OF.get(phase, phase), now)
         if self._gap_open:
-            self.phase_s[self._phase] += now - self._mark
+            self._gap_s += now - self._mark
             self._mark = now
         self._phase = phase
+        if len(self._transitions) < self.MAX_TRANSITIONS:
+            self._transitions.append((phase, now))
 
     def _push(self, phase: str, annotation: Any) -> None:
-        self._account(phase)
+        """Open ``annotation``; a tiled phase opens with its first part
+        inside it, and the thread moves on to that."""
         annotation.__enter__()
         self._open.append((phase, annotation))
+        part = self.TILED.get(phase)
+        if part is not None:
+            self.sub(part)
+        else:
+            self._account(phase)
 
-    def _pop(self) -> None:
-        self._open.pop()[1].__exit__(None, None, None)
-        self._account(self._open[-1][0] if self._open else "between")
+    def _close(self, keep: int) -> None:
+        """Close the open annotations down to the outermost ``keep``."""
+        while len(self._open) > keep:
+            self._open.pop()[1].__exit__(None, None, None)
+
+    def _pop(self, keep: int = 0, then: str = "between") -> None:
+        """Close down to ``keep``; the thread is back in the innermost
+        of those, or in ``then``."""
+        self._close(keep)
+        self._account(self._open[-1][0] if self._open else then)
 
     def begin_wave(self) -> None:
-        while self._open:  # a step an exception cut, or never harvested
+        if self._open:  # a step an exception cut, or never harvested
             self._pop()
         self._in_step = False
+        ident = threading.get_ident()
+        if ident != self._thread_ident:
+            self._claim_thread(ident)
         self.boundary()
         self.wave += 1
         # the step itself is no phase: time under it alone stays with
-        # whatever was running (``between``, until ``enter``)
+        # whatever was running (until ``enter``)
         self._push(self._phase, self._profiler.StepTraceAnnotation(
             "seldon.wave", step_num=self.wave))
         self._in_step = True
 
     def enter(self, phase: str, **stats: Any) -> None:
         """End the wave's current phase and begin ``phase``."""
-        while len(self._open) > (1 if self._in_step else 0):
-            self._pop()
+        self._close(1 if self._in_step else 0)
         self._push(phase, self._profiler.TraceAnnotation(
             "seldon.wave." + phase, **stats))
 
+    def sub(self, part: str) -> None:
+        """The next part of the innermost tiled phase (``prefill``,
+        ``launch``) begins: ``seldon.wave.<phase>.<part>``."""
+        phase, dot, _ = self._open[-1][0].partition(".")
+        if dot:  # the part before it ends here
+            self._close(len(self._open) - 1)
+        name = f"{phase}.{part}"
+        inner = self._profiler.TraceAnnotation("seldon.wave." + name)
+        inner.__enter__()
+        self._open.append((name, inner))
+        self._account(name)
+
     def stats(self, **stats: Any) -> None:
         """Work counted after the innermost phase began, onto its
-        annotation."""
-        if self._open:
-            self._open[-1][1].set_metadata(**stats)
+        annotation (not onto the part of it that is open)."""
+        for name, annotation in reversed(self._open):
+            if "." not in name:
+                annotation.set_metadata(**stats)
+                return
 
     def begin_prefill(self, **stats: Any) -> None:
         """One prefill group, nested in the phase that runs it."""
@@ -3001,19 +3243,21 @@ class _WaveSeam:
             "seldon.wave.prefill", **stats))
 
     def end_prefill(self) -> None:
-        self._pop()
+        for depth in range(len(self._open) - 1, -1, -1):
+            if self._open[depth][0] == "prefill":
+                self._pop(keep=depth)
+                return
 
     def end_wave(self, more: bool) -> None:
         """Close whatever the wave left open (an exception may have cut
         it anywhere).  ``more`` False: the engine has no work, so what
-        follows is waiting for a request and no host gap."""
-        while self._open:
-            self._pop()
+        follows is waiting for a request (``no_work``) and no host gap."""
+        self._pop(then="between" if more else "no_work")
         self._in_step = False
         if not more:
             self._gap_open = False
 
-    # ---- the host gap --------------------------------------------------
+    # ---- the device's side ---------------------------------------------
 
     def drained(self, upto: Optional[int] = None) -> None:
         """A blocking readback of dispatch ``upto``'s output returned
@@ -3024,17 +3268,25 @@ class _WaveSeam:
             self._gap_open = True
             self._mark = self._clock()
 
-    def dispatched(self) -> int:
-        """A program of the wave loop has been enqueued; its number."""
+    def dispatched(self, out: Any = None) -> int:
+        """A program of the wave loop has been enqueued; its number.
+        ``out``: its smallest output that is not donated onward, for the
+        device clock to wait on (None where it has none)."""
         self.seq += 1
+        now = self._clock()
         if self._gap_open:
-            self._account(self._phase)
+            self._gap_s += now - self._mark
             self._gap_open = False
+        transitions, self._transitions = self._transitions, []
+        self.device.watch(now, out, transitions)
         return self.seq
 
     @property
     def host_gap_s(self) -> float:
-        return sum(self.phase_s.values())
+        """Seconds with work and nothing in flight, readback to next
+        dispatch.  Blind since PR 29 wherever a chunk is enqueued ahead;
+        see ``device_idle_s``."""
+        return self._gap_s
 
     def phase_walls(self) -> Dict[str, float]:
         """Wall seconds of the engine thread by phase, the open phase's
@@ -3044,6 +3296,26 @@ class _WaveSeam:
         walls = dict(walls)
         walls[phase] += self._clock() - since
         return walls
+
+    # ---- compiles ------------------------------------------------------
+
+    def _claim_thread(self, ident: int) -> None:
+        """The wave loop runs on this thread: a compile that fires on it
+        is booked to the seam's open phase and wave."""
+        ref = weakref.ref(self)
+
+        def context() -> Optional[Tuple[str, int]]:
+            seam = ref()
+            return None if seam is None else (seam._phase, seam.wave)
+
+        _jitwatch.compile_context(ident, context)
+        self._thread_ident = ident
+
+    def compiles(self) -> Tuple[int, float]:
+        """(backend compiles, their seconds) of the process since this
+        engine was built."""
+        count, seconds = _jitwatch.compile_totals()
+        return count - self._compiles_base[0], seconds - self._compiles_base[1]
 
     # ---- the profile window --------------------------------------------
 
@@ -7112,13 +7384,14 @@ class PagedEngine:
                 cp = start // ps
                 row = self._block_tables[stream.slot, cp : cp + wp]
                 write_rows[i, : len(row)] = row
+            tables = (jnp.asarray(padded), jnp.asarray(true_lens),
+                      jnp.asarray(cached_lens), jnp.asarray(read_rows),
+                      jnp.asarray(write_rows))
+            self._seam.sub("call")
             last, pk_out, pv_out, *hist = self._prefill_cached_jit[key3](
-                self.params, *self._kv_args(),
-                jnp.asarray(padded), jnp.asarray(true_lens),
-                jnp.asarray(cached_lens), jnp.asarray(read_rows),
-                jnp.asarray(write_rows), *lora_args,
+                self.params, *self._kv_args(), *tables, *lora_args,
             )
-            self._seam.dispatched()
+            self._seam.dispatched(last)
             self._store_kv(pk_out, pv_out)
         else:
             key2 = (bucket, k)
@@ -7152,13 +7425,16 @@ class PagedEngine:
                 for i, (stream, _start, _n) in enumerate(group):
                     at[i] = stream.slot
                 kinds["slots"] = jnp.asarray(at)
+            tables = (jnp.asarray(padded), jnp.asarray(true_lens),
+                      jnp.asarray(block_rows))
+            self._seam.sub("call")
             last, pk_out, pv_out, *hist = self._prefill_jit[key2](
-                self.params, *self._kv_args(),
-                jnp.asarray(padded), jnp.asarray(true_lens),
-                jnp.asarray(block_rows), *lora_args, **kinds,
+                self.params, *self._kv_args(), *tables, *lora_args, **kinds,
             )
-            self._seam.dispatched()
+            self._seam.dispatched(last)
             self._store_kv(pk_out, pv_out)
+        # the eager tail: what installs the group's decode state
+        self._seam.sub("tail")
         # a routed spec's int32[layers, E], beside its held pass's rows
         self._moe_hold(hist, self._held_pass_rows(k * bucket))
         finals: List[Tuple[int, _Stream]] = []
@@ -7320,7 +7596,7 @@ class PagedEngine:
             self.params, *self._kv_args(), k, v,
             jnp.asarray(pages),
         )
-        self._seam.dispatched()
+        self._seam.dispatched()  # its outputs are the pool: donated onward
         self._store_kv(pk_out, pv_out)
         last = np.asarray(
             payload["last_logits"], np.float32
@@ -8560,6 +8836,8 @@ class PagedEngine:
         with self._lock:
             held_hits = self._moe_held_hits()
             walls = self._seam.phase_walls()
+            busy_s, idle_s, programs, idle_by = self._seam.device.totals
+            xla_compiles, xla_compile_s = self._seam.compiles()
             out = {
                 **self._counters,
                 # time.monotonic() as these counters were read: a
@@ -8661,9 +8939,24 @@ class PagedEngine:
                 # seconds in which the engine had work and nothing in
                 # flight: from the return of a wave's last blocking
                 # readback to the return of the next dispatch of a
-                # wave-loop program (the device's idle time as the
-                # program sees it)
+                # wave-loop program.  Blind since PR 29 wherever a chunk
+                # is enqueued ahead; see device_idle_s
                 "host_gap_s": self._seam.host_gap_s,
+                # the device on the seam's clock, from a completion stamp
+                # a dispatched program (_DeviceClock): seconds it ran
+                # them, seconds it sat between two of them (busy + idle
+                # = first enqueue to last completion), the completions
+                # stamped, and the idle by where the engine thread was
+                # (no_work: no stream admitted or queued)
+                "device_busy_s": busy_s,
+                "device_idle_s": idle_s,
+                "device_programs": programs,
+                "device_idle_by_s": dict(idle_by),
+                # every backend compile of the process since this engine
+                # was built, an eager operation's included, and their
+                # seconds (utils/jitwatch.py watch_backend_compiles)
+                "xla_compiles": xla_compiles,
+                "xla_compile_s": xla_compile_s,
                 # routed experts: the busiest (layer, expert) pair's
                 # cumulative assignments and the mean over pairs — how
                 # far routing is from even (0 for a dense spec)
@@ -8733,10 +9026,10 @@ class PagedEngine:
             ):
                 out.pop(k, None)
         if detail:
-            # host_gap_s by the phase it was spent in, and beside it
-            # the engine thread's whole wall time by phase
-            out["phase_s"] = dict(self._seam.phase_s)
+            # the engine thread's whole wall time by phase, and the
+            # last compiles with where the compiling thread stood
             out["phase_wall_s"] = walls
+            out["xla_compile_ring"] = _jitwatch.compile_ring()
             if moe_expert_hits is not None:
                 out["moe_expert_hits"] = moe_expert_hits
             if moe_zero_detail is not None:
@@ -8938,6 +9231,8 @@ class PagedEngine:
                 "engine closed", status_code=503, reason="SHUTTING_DOWN"
             )
         )
+        # nothing is dispatched any more: the completion watcher ends
+        self._seam.device.stop()
         # drop the engine-held registry pins: a closed engine's host
         # weight copies become reclaimable registry capacity
         if self._registry is not None:
@@ -9431,6 +9726,7 @@ class PagedEngine:
         # staged demotions — gather them before the chunk writes the
         # pool (no-op when off)
         self._tier_flush()
+        self._seam.sub("call")
         t_chunk = _time.perf_counter()
         chunk_args = (
             self.params, *self._kv_args(), self._lane_put(self._logits),
@@ -9446,7 +9742,8 @@ class PagedEngine:
         (toks, pk_out, pv_out, self._logits, _lengths_out, self._keys, _,
          emitted, *moe) = self._get_chunk(steps, buckets)(
              *chunk_args, **chunk_kinds)
-        seq = self._seam.dispatched()
+        seq = self._seam.dispatched(toks)
+        self._seam.sub("post")
         self._moe_hold(moe)
         self._store_kv(pk_out, pv_out)
         # the NaN screen judges THIS chunk's logits: enqueued right
@@ -9773,7 +10070,7 @@ class PagedEngine:
                 drafts = self._draft_rollout(
                     self._draft_params, jnp.asarray(windows), jnp.asarray(lens)
                 )
-                self._seam.dispatched()
+                self._seam.dispatched(drafts)
                 model_drafts = np.asarray(drafts)
                 self._seam.drained()
             for stream in runnable:
@@ -9851,7 +10148,7 @@ class PagedEngine:
         out, counts, pk_out, pv_out, lengths_out = self._spec_chunk(
             *spec_args
         )
-        self._seam.dispatched()
+        self._seam.dispatched(counts)
         self._store_kv(pk_out, pv_out)
         self._seam.enter("wait")
         out_np = np.asarray(out)

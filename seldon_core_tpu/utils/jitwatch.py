@@ -15,6 +15,15 @@ costs one pytree walk per call (microseconds against a chunk program's
 milliseconds), works on every jax version, and — unlike cache-size
 probing — can NAME the offending signature.  ``SELDON_TPU_JIT_SENTINEL=0``
 disables it (the wrap then returns the function untouched).
+
+What the sentinel cannot see is a compile no entry point of the engine
+made: an eager ``.at[].set`` or gather whose index shape is new compiles
+a small program of its own, inside a wave.  ``watch_backend_compiles``
+hears every one of them from ``jax.monitoring`` (the backend-compile
+event, with its seconds and the function's name) at no cost to a call
+that does not compile; it counts them all, keeps the last 32 with where
+the compiling thread stood, and adds those no sentinel-wrapped call made
+to the same ``seldon_tpu_jit_compiles_total{program=<fun_name>}``.
 """
 
 from __future__ import annotations
@@ -22,7 +31,8 @@ from __future__ import annotations
 import logging
 import os
 import threading
-from typing import Any, Callable, Set, Tuple
+from collections import deque
+from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
 logger = logging.getLogger(__name__)
 
@@ -63,13 +73,17 @@ def signature_of(args: tuple, kwargs: dict) -> Tuple:
     return (tuple(_leaf_sig(leaf) for leaf in leaves), str(treedef))
 
 
-def _count_compile(program: str, sig: Tuple, static: str) -> None:
-    logger.warning(
-        "jit compile: program=%s%s signature=%s — a new argument-shape "
-        "signature reached this entry point; if this happened under "
-        "traffic the request paid the compile",
-        program, f" [{static}]" if static else "", sig[0],
-    )
+def _count_compile(program: str, sig: Optional[Tuple], static: str) -> None:
+    """One more compile of ``program``: the sentinel's (``sig`` is the
+    signature that was new, and is logged) or the backend listener's
+    (None: its own line is logged where it fires)."""
+    if sig is not None:
+        logger.warning(
+            "jit compile: program=%s%s signature=%s — a new argument-shape "
+            "signature reached this entry point; if this happened under "
+            "traffic the request paid the compile",
+            program, f" [{static}]" if static else "", sig[0],
+        )
     try:
         from seldon_core_tpu.utils.metrics import _cache_for
 
@@ -115,6 +129,88 @@ class JitSentinel:
                     _count_compile(self.program, sig[1:], static)
             except Exception:  # noqa: BLE001 — the sentinel never breaks serving
                 logger.exception("jit sentinel failed for %s", self.program)
-            return fn(*args, **kwargs)
+            # a backend compile under this call is the sentinel's to count
+            _inside.depth = getattr(_inside, "depth", 0) + 1
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                _inside.depth -= 1
 
         return wrapped
+
+
+# ---------------------------------------------------------------------------
+# every backend compile of the process, where it happens
+# ---------------------------------------------------------------------------
+
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+COMPILE_RING = 32
+
+_inside = threading.local()  # .depth: sentinel-wrapped calls open on this thread
+_watch_lock = threading.Lock()
+_watching = False
+# (compiles, seconds) so far: ONE tuple, replaced whole under the lock
+_totals: Tuple[int, float] = (0, 0.0)
+_ring: deque = deque(maxlen=COMPILE_RING)
+# thread ident -> a callable giving (where, wave) of the wave loop that
+# runs on that thread (models/paged.py _WaveSeam.compile_context), or None
+_contexts: Dict[int, Callable[[], Optional[Tuple[str, int]]]] = {}
+
+
+def _on_duration(event: str, duration: float, **kwargs: Any) -> None:
+    """``jax.monitoring``'s listener, on the compiling thread.  Never
+    raises into the compile that fired it."""
+    if event != BACKEND_COMPILE_EVENT:
+        return
+    global _totals
+    try:
+        fun_name = str(kwargs.get("fun_name", ""))
+        context = _contexts.get(threading.get_ident())
+        where, wave = (context() if context is not None else None) or ("", 0)
+        with _watch_lock:
+            _totals = (_totals[0] + 1, _totals[1] + float(duration))
+            _ring.append({"fun_name": fun_name, "seconds": float(duration),
+                          "where": where, "wave": wave})
+        if not getattr(_inside, "depth", 0):
+            logger.info(
+                "xla compile outside every sentinel: fun_name=%s %.1f ms, "
+                "where=%s wave=%s", fun_name, 1e3 * duration, where or "-", wave)
+            _count_compile(fun_name or "unnamed", None, "")
+    except Exception:  # noqa: BLE001 — the listener never breaks a compile
+        logger.exception("backend-compile listener failed")
+
+
+def watch_backend_compiles() -> None:
+    """Register the process's one listener for backend compiles.  Called
+    wherever an engine is built; every call but the first is a flag
+    test."""
+    global _watching
+    if _watching:
+        return
+    with _watch_lock:
+        if _watching:
+            return
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _watching = True
+
+
+def compile_context(ident: int, context: Callable[[], Optional[Tuple[str, int]]]) -> None:
+    """The wave loop that runs on thread ``ident`` says where it stands
+    through ``context()`` (the newest to claim a thread holds it)."""
+    _contexts[ident] = context
+
+
+def compile_totals() -> Tuple[int, float]:
+    """(backend compiles, their seconds) of the process since
+    :func:`watch_backend_compiles`."""
+    return _totals
+
+
+def compile_ring() -> List[Dict[str, Any]]:
+    """The last ``COMPILE_RING`` compiles, oldest first: ``fun_name``,
+    ``seconds``, and the ``where`` (the seam's open phase) and ``wave``
+    of the wave loop on the compiling thread ('' and 0 on any other)."""
+    with _watch_lock:
+        return [dict(entry) for entry in _ring]

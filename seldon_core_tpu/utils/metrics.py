@@ -346,7 +346,27 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
     "host_gap_s": ("counter", "seldon_tpu_engine_host_gap_seconds_total",
                    "seconds the engine had work and nothing in flight: "
                    "last readback of a wave to the return of the next "
-                   "dispatch"),
+                   "dispatch (blind wherever a chunk is enqueued ahead: "
+                   "see seldon_tpu_engine_device_idle_seconds_total)"),
+    # the device on the engine's own clock (PR 50): a completion stamp a
+    # dispatched program of the wave loop, busy + idle = first enqueue
+    # to last completion; the idle is the labelled DEVICE_IDLE_METRIC
+    # below, by where the engine thread was
+    "device_busy_s": ("counter",
+                      "seldon_tpu_engine_device_busy_seconds_total",
+                      "seconds the device ran the wave loop's programs, "
+                      "from each one's start (its enqueue, or the "
+                      "completion before it) to its completion stamp"),
+    "device_programs": ("counter",
+                        "seldon_tpu_engine_device_programs_total",
+                        "dispatched programs of the wave loop whose "
+                        "completion was stamped"),
+    "xla_compiles": ("counter", "seldon_tpu_engine_xla_compiles_total",
+                     "backend (XLA) compiles of the process since the "
+                     "engine was built, an eager operation's included"),
+    "xla_compile_s": ("counter",
+                      "seldon_tpu_engine_xla_compile_seconds_total",
+                      "seconds of those compiles"),
     # the host half on the engine's own clock (monotonic stamps, closed
     # at readbacks): the engine thread at work / blocked in a readback,
     # a request's way to its first token and from there to its finish,
@@ -746,10 +766,16 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
 # can't carry labels, same shape as adapter_requests)
 # clock_s is the snapshot's own time.monotonic(): what a reader of two
 # snapshots divides by, no series
+# device_idle_by_s is a where->seconds dict the bridge exports itself as
+# seldon_tpu_engine_device_idle_seconds_total{where=...}; device_idle_s
+# is that series summed over where
 ENGINE_STATS_EXCLUDED = {"chunk_wall_s", "clock_s", "jit_compiles",
-                         "adapter_requests", "health", "cost_by_adapter"}
+                         "adapter_requests", "health", "cost_by_adapter",
+                         "device_idle_by_s", "device_idle_s"}
 
 ADAPTER_REQUESTS_METRIC = "seldon_tpu_engine_adapter_requests_total"
+
+DEVICE_IDLE_METRIC = "seldon_tpu_engine_device_idle_seconds_total"
 
 CHUNK_DURATION_METRIC = "seldon_tpu_engine_chunk_duration_seconds"
 
@@ -830,6 +856,14 @@ class GenerationPrometheusBridge:
     def _metric(self, kind: str, name: str, doc: str = ""):
         return self._cache.get(kind, name, self._names, doc).labels(**self._labels)
 
+    def _advance(self, key: str, value) -> float:
+        """What cumulative ``value`` grew by since the last collect (a
+        counter that went back was reset: the bridge rebases on it)."""
+        prev = self._last.get(key, 0.0)
+        cur = float(value)
+        self._last[key] = cur
+        return cur - prev if cur >= prev else cur
+
     def collect(self) -> None:
         """Never raises — the bridge must not take the decode loop down."""
         try:
@@ -843,17 +877,25 @@ class GenerationPrometheusBridge:
         # mapping can't carry — same counter-delta discipline, one
         # child per adapter name
         for adapter, count in (stats.get("adapter_requests") or {}).items():
-            key = f"adapter_requests:{adapter}"
-            prev = self._last.get(key, 0.0)
-            cur = float(count)
-            delta = cur - prev if cur >= prev else cur
-            self._last[key] = cur
+            delta = self._advance(f"adapter_requests:{adapter}", count)
             if delta > 0:
                 labels = dict(self._labels, adapter=adapter)
                 self._cache.get(
                     "counter", ADAPTER_REQUESTS_METRIC,
                     tuple(sorted(labels)),
                     "adapter-carrying requests submitted, by adapter name",
+                ).labels(**labels).inc(delta)
+        # the device's idle seconds by where the engine thread was
+        # (PR 50): one child a phase of the seam
+        for where, seconds in (stats.get("device_idle_by_s") or {}).items():
+            delta = self._advance(f"device_idle:{where}", seconds)
+            if delta > 0:
+                labels = dict(self._labels, where=where)
+                self._cache.get(
+                    "counter", DEVICE_IDLE_METRIC, tuple(sorted(labels)),
+                    "seconds the device sat between two programs of the "
+                    "wave loop, by where the engine thread was (no_work: "
+                    "no stream admitted or queued)",
                 ).labels(**labels).inc(delta)
         # per-adapter cost attribution (r20): labeled export of the
         # ledger's adapter split — same counter-delta discipline.  The
@@ -862,11 +904,8 @@ class GenerationPrometheusBridge:
         for adapter, fields in (stats.get("cost_by_adapter") or {}).items():
             for field, spec in COST_LEDGER_METRICS.items():
                 kind, name, doc = spec
-                key = f"cost_adapter:{adapter}:{field}"
-                prev = self._last.get(key, 0.0)
-                cur = float(fields.get(field, 0.0))
-                delta = cur - prev if cur >= prev else cur
-                self._last[key] = cur
+                delta = self._advance(
+                    f"cost_adapter:{adapter}:{field}", fields.get(field, 0.0))
                 if delta > 0:
                     labels = dict(self._labels, adapter=adapter)
                     self._cache.get(
@@ -881,10 +920,7 @@ class GenerationPrometheusBridge:
             if kind == "gauge":
                 metric.set(float(value))
             else:
-                prev = self._last.get(key, 0.0)
-                cur = float(value)
-                delta = cur - prev if cur >= prev else cur  # reset -> rebase
-                self._last[key] = cur
+                delta = self._advance(key, value)
                 if delta > 0:
                     metric.inc(delta)
         recorder = getattr(self.engine, "recorder", None)

@@ -489,6 +489,60 @@ def test_the_host_halfs_default_keys_are_mapped_or_excluded(key):
         assert kind == "counter" and name.endswith("_total")
     # what went with the enqueue's clock stays gone
     assert "prefill_wall_s" not in stats
-    assert "phase_wall_s" not in stats and set(detail["phase_wall_s"]) == set(
-        detail["phase_s"])
+    assert "phase_wall_s" not in stats and "phase_s" not in detail
+    assert set(detail["phase_wall_s"]) == {
+        "admit", "prefill", "launch", "wait", "harvest", "record", "between"}
+    # the device's idle by where the engine thread was: in the default
+    # view, every where a key from the start, summing to the idle
+    assert set(stats["device_idle_by_s"]) >= {"no_work", "prefill.tail", "launch.call"}
+    assert sum(stats["device_idle_by_s"].values()) == pytest.approx(
+        stats["device_idle_s"], abs=1e-9)
     assert all("prefill_wall_ms" not in rec for rec in detail["recorder"])
+
+
+@pytest.mark.parametrize("key", [
+    "device_busy_s", "device_idle_s", "device_programs", "device_idle_by_s",
+    "xla_compiles", "xla_compile_s",
+])
+def test_the_device_clocks_default_keys_are_mapped_or_excluded(key):
+    """PR 50: each is in the DEFAULT ``engine_stats()`` (what
+    ``/debug/engine`` answers without ``?detail=1``); the idle goes out
+    as ONE labelled counter by where the engine thread was, so the dict
+    and its sum are excluded from the flat mapping, and the rest are
+    counters."""
+    from prometheus_client import CollectorRegistry
+
+    from seldon_core_tpu.utils.metrics import (
+        DEVICE_IDLE_METRIC,
+        ENGINE_STATS_EXCLUDED,
+        ENGINE_STATS_METRICS,
+        GenerationPrometheusBridge,
+    )
+
+    eng = _tiny_engine()
+    try:
+        for first in (1, 2):  # step by step: the device idles between waves
+            eng.submit((np.arange(5, dtype=np.int32) + first) % 64, max_new_tokens=6)
+            eng.run()
+        eng._seam.device.stop()  # everything dispatched is settled
+        stats = eng.engine_stats()
+        registry = CollectorRegistry()
+        GenerationPrometheusBridge(eng, model_name="m", registry=registry).collect()
+    finally:
+        eng.close()
+    if key in ("device_idle_by_s", "device_idle_s"):
+        assert key in ENGINE_STATS_EXCLUDED and key not in ENGINE_STATS_METRICS
+        exported = {
+            s.labels["where"]: s.value for m in registry.collect()
+            for s in m.samples if s.name == DEVICE_IDLE_METRIC}
+        assert exported == pytest.approx(
+            {k: v for k, v in stats["device_idle_by_s"].items() if v > 0})
+        assert sum(exported.values()) == pytest.approx(stats["device_idle_s"])
+        assert stats["device_idle_s"] > 0.0
+    else:
+        kind, name, _doc = ENGINE_STATS_METRICS[key]
+        assert kind == "counter" and name.endswith("_total")
+        assert isinstance(stats[key], (int, float)) and stats[key] > 0
+        (value,) = [s.value for m in registry.collect() for s in m.samples
+                    if s.name == name]
+        assert value == pytest.approx(stats[key])

@@ -216,12 +216,19 @@ class TestCountedWhereItHappens:
         assert 2.0 * len(lengths) <= stats["queue_wait_s"] < 2.0 * len(lengths) + 1.0
         assert stats["ingress_waits"] == 1
         assert 1.5 <= stats["ingress_wait_s"] < 2.5
-        # the host gap is kept by phase, and the phases are its whole
-        assert stats["host_gap_s"] == pytest.approx(sum(stats["phase_s"].values()))
+        # the device's idle time is kept by where the engine thread was,
+        # and those are its whole (step by step, every readback leaves
+        # the device empty: the harvest and the next launch are idle)
         assert stats["host_gap_s"] > 0.0
-        assert stats["phase_s"]["harvest"] > 0.0 and stats["phase_s"]["launch"] > 0.0
-        assert set(stats["phase_s"]) == {"admit", "prefill", "launch", "wait",
-                                         "harvest", "record", "between"}
+        assert "phase_s" not in stats
+        assert stats["device_idle_s"] == pytest.approx(
+            sum(stats["device_idle_by_s"].values()), abs=1e-9)
+        assert stats["device_idle_by_s"]["harvest"] > 0.0
+        assert stats["device_idle_by_s"]["launch.plan"] > 0.0
+        assert set(stats["device_idle_by_s"]) == {
+            "no_work", "between", "admit", "prefill.pack", "prefill.call",
+            "prefill.tail", "launch.plan", "launch.call", "launch.post",
+            "wait", "harvest", "record"}
 
     @pytest.mark.parametrize("lane,slots,live", [
         # 8 lanes in two buckets of 4; lengths 7 and 20, 4 steps, pages
@@ -742,6 +749,18 @@ class TestTheEngineThreadsTimeByPhase:
         # prefill group nests in the phase that runs it, which lends it
         # that much)
         events = [e for e in _seam_events(str(tmp_path)) if e[0] != "seldon.wave"]
+        # (the parts of a tiled phase lie inside it: prefill.pack/call/tail,
+        # launch.plan/call/post — and tile it)
+        parts = [e for e in events if e[0].count(".") == 3]
+        events = [e for e in events if e[0].count(".") == 2]
+        for phase, names in (("prefill", ("pack", "call", "tail")),
+                             ("launch", ("plan", "call", "post"))):
+            for _name, start, end, _stats in [e for e in events
+                                              if e[0] == f"seldon.wave.{phase}"]:
+                inner = [p for p in parts if start <= p[1] and p[2] <= end
+                         and p[0].startswith(f"seldon.wave.{phase}.")]
+                assert [p[0].rsplit(".", 1)[1] for p in inner] == list(names)
+                assert all(a[2] <= b[1] for a, b in zip(inner, inner[1:]))
         traced = dict.fromkeys(walls, 0.0)
         for name, start, end, _stats in events:
             traced[name.rsplit(".", 1)[1]] += (end - start) / 1e9
@@ -1029,3 +1048,413 @@ class TestATokensWayOut:
         assert stats["ttfts"] == stats["first_tokens"] == 1
         assert stats["decode_stream_tokens"] == 5
         assert stats["ttft_s"] >= stats["first_token_s"] > 0.0
+
+
+# ---------------------------------------------------------------------------
+# PR 50: the device's idle time on the program's own clock
+# ---------------------------------------------------------------------------
+
+class _FakeClock:
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        return self.t
+
+
+class _FakeArray:
+    """Ready at ``done_at`` on the fake clock: waiting for it moves the
+    clock there (never back)."""
+
+    def __init__(self, clock, done_at, deleted=False):
+        self.clock, self.done_at, self.deleted = clock, done_at, deleted
+
+    def block_until_ready(self):
+        if self.deleted:
+            raise RuntimeError("Array has been deleted.")
+        self.clock.t = max(self.clock.t, self.done_at)
+        return self
+
+
+def _watched(programs):
+    """Run the watcher's loop, on this thread, over ``programs``:
+    ``(enq, done | None | "deleted", transitions)`` each."""
+    from seldon_core_tpu.models.paged import _DeviceClock
+
+    clock = _FakeClock()
+    device = _DeviceClock(clock)
+    for enq, done, transitions in programs:
+        out = None if done is None else _FakeArray(
+            clock, 0.0 if done == "deleted" else done, deleted=done == "deleted")
+        device._queue.put((enq, out, transitions))
+    device._queue.put(None)
+    device._run()
+    return device
+
+
+class TestTheDeviceClocksArithmetic:
+    def test_busy_and_idle_close_from_first_enqueue_to_last_completion(self):
+        rng = np.random.default_rng(50)
+        programs, t, phases = [], 1.0, ("admit", "prefill.pack", "launch.call", "harvest")
+        for i in range(200):
+            trans = []
+            for _ in range(int(rng.integers(0, 4))):
+                t += float(rng.uniform(0.0, 0.004))
+                trans.append((phases[int(rng.integers(0, 4))], t))
+            t += float(rng.uniform(0.0, 0.004))
+            enq = t
+            # late or early against the enqueue that follows it
+            programs.append((enq, None if i % 17 == 5 else enq + float(rng.uniform(0.0, 0.02)),
+                             trans))
+        # completions in order: a program ends no earlier than the one before
+        reach, ordered = 0.0, []
+        for enq, done, trans in programs:
+            if done is not None:
+                done = reach = max(reach, done, enq)
+            ordered.append((enq, done, trans))
+        last_done = max(d for _e, d, _t in ordered if d is not None)
+        tail = [i for i, p in enumerate(ordered) if p[1] is not None][-1]
+        device = _watched(ordered[:tail + 1])
+        busy, idle, programs_n, by = device.totals
+        assert busy + idle == pytest.approx(last_done - ordered[0][0], abs=1e-9)
+        assert sum(by.values()) == pytest.approx(idle, abs=1e-9)
+        assert programs_n == tail + 1 and idle > 0.0 and busy > 0.0
+        assert set(by) == set(device.WHERE)
+
+    def test_an_idle_interval_over_three_transitions_is_split_at_them(self):
+        device = _watched([
+            (1.0, 2.0, []),
+            # done at 2.0; the thread: wait until 2.5, harvest until 2.75,
+            # record until 3.5, then launch.plan to the enqueue at 4.0
+            (4.0, 5.0, [("wait", 1.5), ("harvest", 2.5), ("record", 2.75),
+                        ("launch.plan", 3.5)]),
+        ])
+        busy, idle, programs, by = device.totals
+        assert (busy, idle, programs) == (2.0, 2.0, 2)
+        assert {k: v for k, v in by.items() if v} == pytest.approx(
+            {"wait": 0.5, "harvest": 0.25, "record": 0.75, "launch.plan": 0.5})
+
+    def test_a_program_enqueued_before_the_one_before_finished_books_no_idle(self):
+        device = _watched([
+            (1.0, 3.0, [("launch.call", 0.5)]),
+            (2.0, 4.5, [("launch.post", 1.5), ("wait", 1.75)]),  # queued behind it
+            (4.0, 6.0, [("harvest", 3.5)]),                      # and again
+        ])
+        busy, idle, programs, by = device.totals
+        assert (busy, idle, programs) == (5.0, 0.0, 3)
+        assert not any(by.values())
+
+    @pytest.mark.parametrize("missing", [None, "deleted"])
+    def test_a_program_without_a_stamp_takes_the_next_one_as_its_bound(self, missing):
+        device = _watched([
+            (1.0, 2.0, []),
+            (3.0, missing, [("admit", 2.5)]),      # idle 2.0 -> 3.0, then no stamp
+            (3.5, 6.0, [("launch.call", 3.25)]),   # bounds both: busy 3.0 -> 6.0
+            (7.0, 7.5, [("harvest", 6.5)]),
+        ])
+        busy, idle, programs, by = device.totals
+        assert (busy, idle, programs) == (1.0 + 3.0 + 0.5, 1.0 + 1.0, 4)
+        assert {k: v for k, v in by.items() if v} == pytest.approx(
+            # the first interval began under the phase before any
+            # transition (an idle engine: no_work)
+            {"no_work": 0.5, "admit": 0.5, "launch.call": 0.5, "harvest": 0.5})
+        # the last program of all without a stamp stays open, uncounted
+        device = _watched([(1.0, 2.0, []), (3.0, missing, [])])
+        assert device.totals[:3] == (1.0, 1.0, 1)
+
+    def test_no_work_runs_from_an_emptied_engine_to_the_next_wave(self):
+        """``end_wave(False)`` to the next ``begin_wave``: the callers'
+        turn-around; ``end_wave(True)`` leaves ``between``."""
+        import jax
+
+        from seldon_core_tpu.models.paged import _WaveSeam
+
+        class Engine:
+            _jax = jax
+
+        clock = _FakeClock()
+        seam = _WaveSeam(Engine(), None)
+        seam._clock = clock
+        handed = []
+        seam.device.watch = lambda enq, out, trans: handed.append((enq, trans))
+
+        def wave(more, admit_s):
+            seam.begin_wave()
+            seam.enter("admit")
+            clock.t += admit_s
+            seam.enter("launch")
+            seam.sub("call")
+            seam.dispatched()
+            seam.sub("post")
+            seam.enter("wait")
+            clock.t += 1.0       # the chunk: done as the wait returns
+            done = clock.t
+            seam.enter("harvest")
+            clock.t += 0.25
+            seam.end_wave(more)
+            return done
+
+        from seldon_core_tpu.models.paged import _DeviceClock
+
+        device = _DeviceClock(clock)
+        clock.t = 10.0
+        seam._walls = (dict.fromkeys(seam.PHASES, 0.0), "between", clock.t)
+        done = wave(more=True, admit_s=0.5)
+        device.settle(handed[0][0], done, handed[0][1])
+        clock.t += 2.0           # between two steps of a loop with work
+        done = wave(more=False, admit_s=0.5)
+        device.settle(handed[1][0], done, handed[1][1])
+        clock.t += 4.0           # nobody asks
+        done = wave(more=False, admit_s=0.5)
+        device.settle(handed[2][0], done, handed[2][1])
+        _busy, idle, _n, by = device.totals
+        assert {k: v for k, v in by.items() if v} == pytest.approx(
+            {"harvest": 0.5, "between": 2.0, "no_work": 4.0, "admit": 1.0})
+        assert idle == pytest.approx(7.5)
+        # (the engine thread's own time keeps one name for both)
+        assert seam.phase_walls()["between"] == pytest.approx(6.0)
+
+
+class TestTheDevicesIdleTimeServed:
+    def test_a_pause_between_two_bursts_is_no_work_and_the_sum_closes(self):
+        lm = _streaming_lm()
+        try:
+            def burst(first):
+                streams = [lm.engine.submit(_prompt(5 + i, first + i), max_new_tokens=6)
+                           for i in range(3)]
+                lm._wake.set()
+                for s in streams:
+                    assert s.event.wait(120)
+
+            burst(1)     # compiles
+            burst(11)
+            emptied = time.monotonic()  # its last harvest left no stream
+            deadline = time.monotonic() + 10
+            while lm.engine.engine_stats()["device_programs"] < lm.engine._seam.seq:
+                assert time.monotonic() < deadline
+                time.sleep(0.01)
+            before = lm.engine.engine_stats()
+            time.sleep(1.0)
+            asked = time.monotonic()
+            burst(21)
+            while lm.engine.engine_stats()["device_programs"] < lm.engine._seam.seq:
+                assert time.monotonic() < deadline + 10
+                time.sleep(0.01)
+            after = lm.engine.engine_stats()
+            seq = lm.engine._seam.seq
+        finally:
+            lm.shutdown()
+        pause = after["device_idle_by_s"]["no_work"] - before["device_idle_by_s"]["no_work"]
+        assert asked - emptied >= 1.0
+        assert pause == pytest.approx(asked - emptied, rel=0.1)
+        assert after["device_programs"] == seq > before["device_programs"]
+        assert sum(after["device_idle_by_s"].values()) == pytest.approx(
+            after["device_idle_s"], abs=1e-9)
+        # busy + idle is the clock from the first enqueue to the last
+        # completion: all of the pause, and no more than the snapshots'
+        # own clock but for where each burst's last completion fell
+        # before its snapshot
+        grown = (after["device_busy_s"] + after["device_idle_s"]
+                 - before["device_busy_s"] - before["device_idle_s"])
+        assert 1.0 <= grown <= after["clock_s"] - before["clock_s"] + 0.25
+
+    def test_shutdown_ends_the_watcher_and_one_listener_serves_every_engine(self):
+        import threading
+
+        from jax._src import monitoring
+
+        from seldon_core_tpu.utils import jitwatch
+
+        def watchers():
+            return [t for t in threading.enumerate() if t.name == "seldon-device-clock"]
+
+        def listeners():
+            return [cb for cb in monitoring.get_event_duration_listeners()
+                    if cb is jitwatch._on_duration]
+
+        before = len(watchers())
+        lm = _streaming_lm()
+        try:
+            s = lm.engine.submit(_prompt(5, 1), max_new_tokens=4)
+            lm._wake.set()
+            assert s.event.wait(120)
+            assert len(watchers()) == before + 1
+            assert len(listeners()) == 1
+            thread = lm._loop_thread
+        finally:
+            lm.shutdown()
+        thread.join(30)
+        assert len(watchers()) == before
+        eng = _tiny_engine()   # a second engine of the process
+        try:
+            assert len(listeners()) == 1
+            # an engine that never dispatched has no thread, and closing
+            # one twice is nothing
+            assert len(watchers()) == before
+        finally:
+            eng.close()
+            eng.close()
+
+    def test_an_engine_dropped_without_close_ends_its_watcher(self):
+        import gc
+        import threading
+
+        eng = _tiny_engine()
+        eng.submit(_prompt(5, 1), max_new_tokens=4)
+        eng.run()
+        thread = eng._seam.device._thread
+        assert thread.is_alive()
+        del eng
+        gc.collect()
+        thread.join(10)
+        assert not thread.is_alive()
+
+
+class TestEveryCompileWhereItHappens:
+    def test_an_eager_scatter_of_a_new_index_shape_is_counted_once(self):
+        from prometheus_client import REGISTRY
+
+        from seldon_core_tpu.utils import jitwatch
+
+        def metric():
+            return sum(
+                s.value for m in REGISTRY.collect() if m.name == "seldon_tpu_jit_compiles"
+                for s in m.samples if s.name.endswith("_total")
+                and not s.labels["program"].startswith("paged_"))
+
+        eng = _tiny_engine()
+        try:
+            eng.submit(_prompt(5, 1), max_new_tokens=4)
+            eng.run()
+            seam = eng._seam
+            seam.begin_wave()
+            seam.enter("harvest")
+            before, counted = eng.engine_stats(), metric()
+            table = jnp.zeros((37, 3), jnp.float32)
+            at = jnp.asarray(np.arange(11, dtype=np.int32))
+            table.at[at].set(1.0).block_until_ready()   # (37, 3) by 11: new
+            first, counted_first = eng.engine_stats(detail=True), metric()
+            table.at[at].set(2.0).block_until_ready()   # the same shapes
+            second, counted_second = eng.engine_stats(), metric()
+            seam.end_wave(False)
+        finally:
+            eng.close()
+        assert first["xla_compiles"] - before["xla_compiles"] >= 1
+        assert first["xla_compile_s"] > before["xla_compile_s"]
+        assert second["xla_compiles"] == first["xla_compiles"]
+        assert counted_first - counted >= 1 and counted_second == counted_first
+        newest = first["xla_compile_ring"][-1]
+        assert newest["where"] == "harvest" and newest["wave"] == seam.wave
+        assert newest["fun_name"] and newest["seconds"] > 0.0
+        assert len(first["xla_compile_ring"]) <= jitwatch.COMPILE_RING
+        assert "xla_compile_ring" not in second
+
+    def test_an_entry_points_compile_is_the_sentinels_alone(self):
+        """A prefill program's first call: the sentinel counts its
+        signature, the listener the backend compile, and the metric
+        moves once, under the sentinel's name."""
+        from prometheus_client import REGISTRY
+
+        def by_program():
+            return {s.labels["program"]: s.value
+                    for m in REGISTRY.collect() if m.name == "seldon_tpu_jit_compiles"
+                    for s in m.samples if s.name.endswith("_total")}
+
+        eng = _tiny_engine()
+        try:
+            before, programs = eng.engine_stats(), by_program()
+            eng.submit(_prompt(5, 1), max_new_tokens=4)
+            eng.run()
+            after, programs_after = eng.engine_stats(detail=True), by_program()
+        finally:
+            eng.close()
+        assert after["xla_compiles"] - before["xla_compiles"] >= 2  # prefill, chunk
+        grown = {k: v - programs.get(k, 0.0) for k, v in programs_after.items()
+                 if v != programs.get(k, 0.0)}
+        assert grown.get("paged_prefill") == 1 and grown.get("paged_chunk") == 1
+        assert not any("paged_prefill_b" in k or "paged_chunk_s" in k for k in grown)
+        named = [e for e in after["xla_compile_ring"] if "paged_prefill_b16_k1" in e["fun_name"]]
+        assert named and named[-1]["where"] == "prefill.call"
+
+
+class TestSharedStateUnderManyThreads:
+    """More threads than cores, a shortened switch interval: what a
+    lost update or a torn read would break."""
+
+    def test_every_read_of_the_device_clock_is_a_consistent_four(self):
+        import sys
+        import threading
+
+        from seldon_core_tpu.models.paged import _DeviceClock
+
+        class Ready:
+            def block_until_ready(self):
+                return self
+
+        device = _DeviceClock(time.perf_counter)
+        torn, stop = [], threading.Event()
+
+        def reader():
+            last = 0.0
+            while not stop.is_set():
+                busy, idle, programs, by = device.totals
+                if abs(sum(by.values()) - idle) > 1e-9 or busy + idle < last - 1e-9:
+                    torn.append((busy, idle, programs, dict(by)))
+                last = busy + idle
+
+        readers = [threading.Thread(target=reader) for _ in range(12)]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in readers:
+                t.start()
+            deadline = time.monotonic() + 20
+            for seq in range(1, 4001):
+                now = time.perf_counter()
+                device.watch(now, Ready() if seq % 7 else None,
+                             [("harvest", now - 2e-6), ("launch.call", now - 1e-6)])
+                assert time.monotonic() < deadline
+            device.stop(timeout=20)
+        finally:
+            stop.set()
+            sys.setswitchinterval(interval)
+            for t in readers:
+                t.join(20)
+        assert not any(t.is_alive() for t in readers)
+        assert not device._thread.is_alive()
+        assert not torn, torn[:2]
+        busy, idle, programs, by = device.totals
+        assert programs >= 4000 - 4000 // 7 and busy + idle > 0.0
+        assert sum(by.values()) == pytest.approx(idle, abs=1e-9)
+
+    def test_no_compile_is_lost_when_many_threads_compile_at_once(self):
+        import sys
+        import threading
+
+        from seldon_core_tpu.utils import jitwatch
+
+        def compile_many():
+            # as under a sentinel-wrapped call: the metric is not this test's
+            jitwatch._inside.depth = 1
+            for _ in range(500):
+                jitwatch._on_duration(jitwatch.BACKEND_COMPILE_EVENT, 0.001,
+                                      fun_name="paged_stress")
+
+        before = jitwatch.compile_totals()
+        threads = [threading.Thread(target=compile_many) for _ in range(16)]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        count, seconds = jitwatch.compile_totals()
+        assert count - before[0] == 16 * 500
+        assert seconds - before[1] == pytest.approx(16 * 500 * 0.001)
+        ring = jitwatch.compile_ring()
+        assert len(ring) == jitwatch.COMPILE_RING
+        assert all(e["fun_name"] == "paged_stress" and e["where"] == "" for e in ring)

@@ -1,6 +1,8 @@
 """What the wave loop's seam costs a wave with no profiler session open:
 the calls one chained wave makes to ``models/paged.py _WaveSeam`` (a
-step, five phases, one prefill group, three dispatches, a readback,
+step, five phases, one prefill group and its three parts, the launch's
+three parts, two dispatches handed to the device clock (its watcher
+thread runs beside: nothing to wait on, so it only settles), a readback,
 the stats it attaches), timed alone on a seam with no engine behind it.
 Host time, whatever the backend: run it where the engine will run.
 
@@ -21,12 +23,16 @@ def one_wave(seam):
     seam.stats(admitted=2, queue_depth=16)
     seam.begin_prefill(bucket=512, k=2, rows=2, tokens=700, padded=1024,
                        cached=0, fused=0)
+    seam.sub("call")
     seam.dispatched()
+    seam.sub("tail")
     seam.end_prefill()
     seam.enter("launch")
     seam.stats(steps=8, lanes=32, kv_tokens=11000, latent_tokens=0,
                pages_live=190, page_slots=384, overlapped=1)
+    seam.sub("call")
     seq = seam.dispatched()
+    seam.sub("post")
     seam.enter("wait", wave=seam.wave)
     seam.drained(seq - 1)
     seam.enter("harvest", wave=seam.wave)
