@@ -665,7 +665,10 @@ def _build_modules():
         fused causal kernel under the chosen set's mask where
         ``prefill_attention_impl`` says so at this kind's widths); a decode
         step whose bucket holds a lane with ``topk`` cached positions or
-        more scores the cached keys, keeps the best ``topk`` of them and
+        more scores the cached keys (where they rest, a page loop a lane,
+        on the kernel lane: ``ops/kernels.index_scores_decode``; gathered
+        through the table and ``ops/mla.py index_scores`` elsewhere),
+        keeps the best ``topk`` of them and
         the step's own as a mask (``kth_mask``, the prefill's rule) and
         runs the same page loop under it — the kernel streams the lane's
         rows and the masked ones weigh exactly 0 (``chosen=``; the
@@ -879,15 +882,23 @@ def _build_modules():
                 (``step_mask``: ``kth_mask``, a prefill's rule): the
                 page loop streams the lane's rows and weighs the chosen
                 alone."""
-                keys = idx_pool[layer, tb] if whole else idx_pool[tb]
-                keys = keys.reshape(nb, -1, keys.shape[-1])
-                scores = mla.index_scores(q_i[sl], w_i[sl], keys, i_scale)[:, 0]
+                if whole:
+                    # the keys scored where they rest, a page loop a lane
+                    scores = kernels.index_scores_decode(
+                        q_i[sl][:, 0], w_i[sl][:, 0], idx_pool, tb,
+                        lengths[sl], layer=layer, page_size=pool.shape[2],
+                        scale=i_scale).reshape(nb, -1)
+                else:
+                    keys = idx_pool[tb]
+                    keys = keys.reshape(nb, -1, keys.shape[-1])
+                    scores = mla.index_scores(
+                        q_i[sl], w_i[sl], keys, i_scale)[:, 0]
                 own_sc = mla.index_scores(
                     q_i[sl], w_i[sl], key_row[sl], i_scale)[:, 0, 0]
                 is_cached, own_in = mla.step_mask(
                     scores, own_sc, lengths[sl], topk)
                 # (the kernel streams every row the indexer scored)
-                scored = jnp.minimum(lengths[sl], keys.shape[1])
+                scored = jnp.minimum(lengths[sl], scores.shape[1])
                 return mla.merge(
                     cached_state(chosen=is_cached),
                     mla.ctx_state(q_full, own, own_in[:, None], rank)
@@ -3822,6 +3833,11 @@ class PagedEngine:
                 kernel_eligible)
             for bucket in self.prompt_buckets} if (
                 spec.latent and spec.kinds and spec.index_topk) else {}
+        # ... and what a decode step's indexer scores its cached keys with:
+        # the page loop over the key pool on the kernel lane
+        # (ops/kernels.py index_scores_decode), the table's gather and
+        # ops/mla.py index_scores elsewhere
+        self._index_score_impl = "kernel" if kernel_eligible else "xla"
         # which grouped expert matmul a program that routes so many
         # tokens a layer traces (_expert_matmul_of), as it was first asked
         self._expert_matmul: Dict[int, str] = {}
@@ -8630,6 +8646,8 @@ class PagedEngine:
                      "pages": int(self.pages_k[name].shape[1])}
                     for name, layers, lanes in self.cache_kinds],
                 "index_topk": self.spec.index_topk,
+                **({"index_score_impl": self._index_score_impl}
+                   if self.spec.index_topk else {}),
                 "window": self.spec.window,
                 "window_table_pages": self.window_pages}
                if self.spec.kinds else {}),

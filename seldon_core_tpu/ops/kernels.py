@@ -1758,3 +1758,214 @@ def latent_attention_decode(q, pool, block_tables, lengths, *, layer,
            if value is not None},
         rank=int(rank), step_tokens=LATENT_STEP_TOKENS,
         interpret=interpret_mode())
+
+
+# ---------------------------------------------------------------------------
+# learned sparse attention's indexer, a decode step: the cached keys
+# scored where they rest
+# ---------------------------------------------------------------------------
+
+# Tokens one step of the indexer's page loop scores, at most (the latent
+# kernel's is LATENT_STEP_TOKENS): the pages of a step divide the table's
+# width, 32 of a 64-page table and 28 of a 112-page one.  A key page is
+# 16 KB, 0.02 us of DMA; a step costs ~0.3 us and ~22 ns a page on the
+# v5e, so a longer step spreads more — and fetches more blanks in a lane's
+# last.  64 lanes, us a call by step (the share of keys x 256 B over 819
+# GB/s): 64 pages at 3,800 keys a lane 512 tokens 240 (32 %), 1,024 176
+# (43), 2,048 142 (54), 4,096 127 (60); 112 pages at 3,800 245, 181, 176
+# (44), 197; at 7,100 413, 290, 235 (59), 206; at 2,100 152, 126, 136,
+# 113 — XLA's gather and product 272 and 640 whatever the lanes hold (my
+# chip runs, PR 51, tools/profile_latent_kernel.py --index).  At 2,048
+# tokens the two key buffers are 1 MB of VMEM.
+INDEX_STEP_TOKENS = 2048
+
+
+def _index_scores_kernel(tables_ref, lens_ref, layer_ref, q_ref, w_ref,
+                         pool_hbm, out_ref, buf, sems, turn_ref, *,
+                         page_size, group, scale):
+    """One lane of the indexer's scores over its cached keys (``ops/mla.py
+    index_scores`` of one query): the latent kernel's page loop
+    (:func:`_latent_attention_kernel`: ``grid=(B,)`` in order on one
+    core, the whole ``(L, pages, ps, d)`` key pool in HBM, the layer a
+    scalar-prefetch operand, double-buffered manual DMA of
+    ``pool.at[layer, page]``, ``group`` pages a step, a ``fori_loop``
+    over the lane's own ``ceil(length / page_size)`` pages, the hand-on
+    of the next live lane's first pages through ``turn_ref``) with the
+    scores in place of the flash state: ``(heads, d) x (d, tokens)`` in
+    one MXU pass of the pool's type with float32 sums, ReLU, the heads'
+    float32 weights ``(heads, 1)``, the sum over the heads and ``x
+    scale``, written as the pages' rows of the lane's ``(pages,
+    page_size)`` float32 scores, 0.0 at and past the length.
+
+    Not shared with it: a key page is 16 KB, 0.02 us of DMA, and what a
+    page costs is its descriptor and its wait on the scalar core.  So a
+    step's copies signal ONE semaphore a buffer and the step waits once,
+    for the buffer's bytes (a DMA semaphore counts bytes): every step
+    fetches ``group`` pages, a last step's blanks as copies of the
+    lane's last page, whose scores are not selected.  A lane of length 0
+    fetches nothing."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    lanes = pl.num_programs(0)
+    layer = layer_ref[0]
+    width = tables_ref.shape[1]
+    span = buf.shape[1]  # ``group`` pages of ``page_size`` tokens
+    length = jnp.minimum(lens_ref[b], width * page_size)
+    precision = (jax.lax.Precision.HIGHEST
+                 if pool_hbm.dtype == jnp.float32 else None)
+    nt_dims = (((1,), (1,)), ((), ()))
+
+    def pages_of(lane):
+        return jnp.clip(
+            jax.lax.div(lens_ref[lane] + page_size - 1, page_size), 0, width)
+
+    def start(lane, j, slot):
+        """Step ``j`` of a LIVE lane into buffer ``slot``."""
+        last = pages_of(lane) - 1
+        for g in range(group):
+            page = tables_ref[lane, jnp.minimum(j * group + g, last)]
+            pltpu.make_async_copy(
+                pool_hbm.at[layer, page],
+                buf.at[slot, pl.ds(g * page_size, page_size)],
+                sems.at[slot]).start()
+
+    n_pages = pages_of(b)
+    n_steps = jax.lax.div(n_pages + group - 1, group)
+    after = jnp.minimum(b + 1, lanes - 1)
+    hand_on = (b + 1 < lanes) & (pages_of(after) > 0)
+
+    @pl.when(b == 0)
+    def _first():
+        turn_ref[0] = 0
+
+        @pl.when(n_pages > 0)
+        def _own():
+            start(0, 0, 0)
+
+    base = turn_ref[0]
+    out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when((n_pages == 0) & hand_on)
+    def _dead():
+        start(after, 0, base)
+
+    @pl.when(n_pages > 0)
+    def _live():
+        q = q_ref[0]                                   # (heads, d)
+        w = w_ref[0]                                   # (heads, 1) float32
+
+        def step(j, carry):
+            slot = jax.lax.rem(base + j, 2)
+
+            @pl.when(j + 1 < n_steps)
+            def _prefetch():
+                start(b, j + 1, 1 - slot)
+
+            @pl.when((j + 1 == n_steps) & hand_on)
+            def _hand_on():
+                start(after, 0, 1 - slot)
+
+            # (the buffer's bytes: the step's copies together)
+            pltpu.make_async_copy(
+                buf.at[slot], buf.at[slot], sems.at[slot]).wait()
+            s = jax.lax.dot_general(
+                q, buf[slot], nt_dims, precision=precision,
+                preferred_element_type=jnp.float32)           # (heads, span)
+            s = (jnp.maximum(s, 0.0) * w).sum(axis=0, keepdims=True) * scale
+            at = j * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+            s = jnp.where(at < length, s, 0.0)                # (1, span)
+            for g in range(group):
+                out_ref[0, pl.ds(j * group + g, 1), :] = (
+                    s[:, g * page_size:(g + 1) * page_size])
+            return carry
+
+        jax.lax.fori_loop(0, n_steps, step, 0)
+        turn_ref[0] = jax.lax.rem(base + n_steps, 2)
+
+
+def _index_decode(q_idx, w_idx, pool, block_tables, lengths, layer, *,
+                  scale, step_tokens, interpret):
+    """The indexer kernel's ``pallas_call`` on the whole key pool (see
+    :func:`index_scores_decode`, which calls it jitted)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, h, d = q_idx.shape
+    ps, P = pool.shape[2], block_tables.shape[1]
+    # the most pages a step that divide the table's width: no step
+    # reaches past the lane's block of the output
+    group = max(g for g in range(1, max(1, step_tokens // ps) + 1) if P % g == 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, h, d), lambda b, *prefetch: (b, 0, 0)),
+                  pl.BlockSpec((1, h, 1), lambda b, *prefetch: (b, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, P, ps), lambda b, *prefetch: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, group * ps, d), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_index_scores_kernel, page_size=ps, group=group,
+                          scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, P, ps), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="index_scores_decode",
+    )(block_tables, lengths, layer.reshape(1), q_idx.astype(pool.dtype),
+      w_idx.astype(jnp.float32)[..., None], pool)
+
+
+@functools.lru_cache(maxsize=None)
+def _index_decode_jit():
+    import jax
+
+    return jax.jit(_index_decode, static_argnames=(
+        "scale", "step_tokens", "interpret"))
+
+
+def index_scores_decode(q_idx, w_idx, idx_pool, block_tables, lengths, *,
+                        layer, page_size, scale):
+    """A decode step's indexer scores of every cached key of its lane
+    (``ops/mla.py index_scores`` of one query a lane over the keys its
+    block table names), the keys read where they rest: the WHOLE
+    ``(L, num_pages, ps, d)`` key pool stays in HBM and a lane's page
+    loop fetches its own ``ceil(length / ps)`` pages
+    (:func:`latent_attention_decode`'s twin for the indexer's pool).
+
+    ``q_idx`` ``(B, heads, d)`` in the pool's type, ``w_idx`` ``(B,
+    heads)`` float32, ``block_tables`` ``(B, P)``, ``lengths`` ``(B,)``,
+    ``layer`` a python int or traced int32 scalar.  Returns ``(B, P,
+    ps)`` float32 — three dims, the table's span a page a row; reshape
+    to ``(B, P * ps)`` for ``ops/mla.py step_mask`` — with 0.0 at and
+    past a lane's length.  Same operands, same types and one MXU pass as
+    the einsum; only the order of the sum over the heads may differ.
+
+    A key is read once: at 64 heads of 128 it needs 256 B and 16,512
+    FLOP."""
+    import jax.numpy as jnp
+
+    if idx_pool.ndim != 4 or q_idx.shape[-1] != idx_pool.shape[-1]:
+        raise ValueError(
+            "index_scores_decode reads the whole key pool as a 4-d (layers, "
+            f"pages, page_size, d) array of q's width, got {idx_pool.shape} "
+            f"for q {q_idx.shape}")
+    if page_size != idx_pool.shape[2]:
+        raise ValueError(
+            f"page_size={page_size} does not match the pool's page dim "
+            f"{idx_pool.shape[2]}")
+    return _index_decode_jit()(
+        q_idx, w_idx, idx_pool, block_tables, lengths,
+        jnp.asarray(layer, jnp.int32), scale=float(scale),
+        step_tokens=INDEX_STEP_TOKENS, interpret=interpret_mode())

@@ -17,7 +17,12 @@ on the CPU (arithmetic only: nothing about Mosaic or speed), each against
   chosen sets in the fused causal kernel) against a float32 softmax
   under the reference's mask at the full layers' head widths, and
   ``causal_attention`` without a mask — plain, windowed, grouped —
-  tracing what it traced before there was one.
+  tracing what it traced before there was one;
+* ``index_scores_decode`` (PR 51: a decode step's indexer scores its
+  lane's cached keys in a page loop over the key pool) against
+  ``index_scores`` over the keys gathered through the table, at the
+  cell's widths and shapes, and a spec without an indexer tracing the
+  chunk it traced.
 """
 
 import os
@@ -536,3 +541,136 @@ def test_causal_attention_without_a_chosen_set_traces_what_it_traced(
     if not window:
         # ... and the chosen set is a structure of its own
         assert _traced_causal(monkeypatch, shape, chosen=True) != digest
+
+
+# ---- a decode step's indexer over the key pool (PR 51) -------------------
+
+INDEX_HEADS, INDEX_DIM, INDEX_PS, INDEX_SCALE = 64, 128, 64, (64 * 128) ** -0.5
+INDEX_TOPK = 2048
+
+
+def _index_lengths(lanes, span, rng):
+    """A length a lane, and which lanes hold each case: the edges first,
+    every other lane ragged."""
+    edges = {"empty": 0, "one_key": 1, "one_under_a_page_edge": 3 * INDEX_PS - 1,
+             "at_a_page_edge": 3 * INDEX_PS, "one_over_a_page_edge": 3 * INDEX_PS + 1,
+             "the_tables_whole_span": span}
+    lengths = rng.integers(0, span + 1, size=lanes)
+    where = {}
+    for at, (name, n) in enumerate(edges.items()):
+        # (between live lanes, and as the call's last lane: the hand-on
+        # chain passes over an empty lane and ends on any)
+        where[name] = [2 * at + 1, lanes - 1 - 2 * at]
+        lengths[where[name]] = n
+    where["ragged_across_lanes"] = [
+        lane for lane in range(lanes) if not any(lane in v for v in where.values())]
+    return lengths.astype(np.int32), where
+
+
+INDEX_CASES = ("empty", "one_key", "one_under_a_page_edge", "at_a_page_edge",
+               "one_over_a_page_edge", "the_tables_whole_span", "ragged_across_lanes")
+
+
+@pytest.fixture(scope="module")
+def index_call():
+    """``(lanes, table pages) -> (got, want, lengths, where, masks)``: the
+    kernel's scores beside ``index_scores`` over the gathered keys, and
+    ``step_mask`` over both (one call a shape, shared by the cases)."""
+    memo = {}
+
+    def call(lanes, pages):
+        if (lanes, pages) in memo:
+            return memo[lanes, pages]
+        rng = np.random.default_rng(lanes + pages)
+        span = pages * INDEX_PS
+        lengths, where = _index_lengths(lanes, span, rng)
+        # (a key pool of 300 pages: a read shares pages across lanes freely)
+        pool = jnp.asarray(rng.normal(size=(2, 300, INDEX_PS, INDEX_DIM)), jnp.bfloat16)
+        tables = jnp.asarray(rng.integers(1, 300, size=(lanes, pages)), jnp.int32)
+        q = jnp.asarray(rng.normal(size=(lanes, 1, INDEX_HEADS, INDEX_DIM)), jnp.bfloat16)
+        w = jnp.asarray(rng.normal(size=(lanes, 1, INDEX_HEADS)), jnp.float32)
+        got = kernels.index_scores_decode(
+            q[:, 0], w[:, 0], pool, tables, jnp.asarray(lengths), layer=1,
+            page_size=INDEX_PS, scale=INDEX_SCALE)
+        assert got.shape == (lanes, pages, INDEX_PS) and got.dtype == jnp.float32
+        got = got.reshape(lanes, span)
+        want = jnp.concatenate([                 # (16 lanes' gathered keys at a time)
+            mla.index_scores(
+                q[at:at + 16], w[at:at + 16],
+                pool[1, tables[at:at + 16]].reshape(16, span, INDEX_DIM),
+                INDEX_SCALE)[:, 0]
+            for at in range(0, lanes, 16)])
+        own = jnp.asarray(rng.normal(size=lanes), jnp.float32)
+        masks = [mla.step_mask(scores, own, jnp.asarray(lengths), INDEX_TOPK)
+                 for scores in (got, want)]
+        memo[lanes, pages] = (
+            np.asarray(got), np.asarray(want), lengths, where,
+            [[np.asarray(m) for m in pair] for pair in masks])
+        return memo[lanes, pages]
+
+    return call
+
+
+@pytest.mark.parametrize("case", INDEX_CASES)
+@pytest.mark.parametrize("lanes", [64, 128])
+@pytest.mark.parametrize("pages", [64, 112])
+def test_index_scores_over_the_key_pool(index_call, pages, lanes, case):
+    """The page loop's scores are ``index_scores`` of the keys the table
+    names, to the float32 rounding of a 64-term sum (same operands, same
+    types; only the order of the sum over the heads may differ), 0.0 at
+    and past a lane's length, and ``step_mask`` chooses the same set from
+    either (seeded data: no ties)."""
+    got, want, lengths, where, (mine, theirs) = index_call(lanes, pages)
+    lanes_of = where[case]
+    assert lanes_of
+    for lane in lanes_of:
+        n = int(lengths[lane])
+        np.testing.assert_allclose(got[lane, :n], want[lane, :n], rtol=1e-5,
+                                   atol=1e-5 * np.abs(want[lane]).max())
+        assert not got[lane, n:].any()
+        np.testing.assert_array_equal(mine[0][lane], theirs[0][lane])
+        assert mine[1][lane] == theirs[1][lane]
+        assert mine[0][lane].sum() + mine[1][lane] == min(n + 1, INDEX_TOPK)
+    if case == "ragged_across_lanes":
+        assert len({int(lengths[lane]) for lane in lanes_of}) > len(lanes_of) // 2
+        assert any(lengths[lane] > INDEX_TOPK for lane in lanes_of)
+
+
+def test_index_scores_refuses_a_pool_it_cannot_read():
+    q = jnp.zeros((2, 4, 128), jnp.bfloat16)
+    args = (jnp.zeros((2, 4), jnp.float32), jnp.zeros((3, 9, 8, 128), jnp.bfloat16),
+            jnp.zeros((2, 2), jnp.int32), jnp.zeros((2,), jnp.int32))
+    with pytest.raises(ValueError, match="page_size=4"):
+        kernels.index_scores_decode(q, *args, layer=0, page_size=4, scale=1.0)
+    with pytest.raises(ValueError, match="whole key pool"):
+        kernels.index_scores_decode(q[..., :64], *args, layer=0, page_size=8, scale=1.0)
+
+
+# a decode chunk of a spec WITHOUT an indexer (a tiny GigaChat on the
+# kernel lane), as the tree before PR 51 lowered it (sha256 of the
+# lowered text): only a kind with ``index_topk`` reaches the scoring, so
+# every other configuration's chunk is the one it was.  A PR that changes
+# that chunk on purpose measures those cells and replaces this.
+NO_INDEXER_CHUNK = "1ac3880c62d8c1f1"
+
+
+def test_a_spec_without_an_indexer_traces_the_chunk_it_traced(monkeypatch):
+    import hashlib
+
+    import paged_harness as harness
+
+    def chunk_text(model, lane="kernel"):
+        spec, sizes = model[0].spec_and_config(model[1])
+        eng, _params = harness.build(spec, sizes, lane, jnp.bfloat16, steps_per_call=2)
+        try:
+            with harness.tracing(eng):
+                return eng.lower_chunk(2, ((2, 2), (2, 8))).as_text()
+        finally:
+            eng.close()
+
+    text = chunk_text(harness.MODELS["gigachat"])
+    assert "_index_decode" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == NO_INDEXER_CHUNK
+    # ... and a spec with one scores in the kernel on this lane alone
+    assert "_index_decode" in chunk_text(harness.MODELS["dots3"])
+    assert "_index_decode" not in chunk_text(harness.MODELS["dots3"], "gather")

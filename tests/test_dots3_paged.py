@@ -46,6 +46,8 @@ RNG = np.random.default_rng(11)
 # both at first, over both by the end | a window's worth
 PROMPTS = [RNG.integers(0, 64, size=n).tolist() for n in (21, 5, 33)]
 NEW = 14
+# what a decode step's indexer scores its cached keys with, by lane
+INDEX_IMPL = {"kernel": "kernel", "gather": "xla"}
 
 # float32 compute against a float32 reference: what is left is the order
 # of sums (absorbed against naive attention, a paged softmax merged by
@@ -75,11 +77,18 @@ def _build(lane, dtype, seed=3, **kw):
                              page_size=PAGE, max_slots=SLOTS, **kw)
 
 
+@pytest.fixture(scope="module")
+def both_f32():
+    """``{lane: (engine, params)}``: one tree on both lanes."""
+    made = {lane: _build(lane, jnp.float32) for lane in ("kernel", "gather")}
+    yield made
+    for eng, _params in made.values():
+        eng.close()
+
+
 @pytest.fixture(scope="module", params=["kernel", "gather"])
-def module_f32(request):
-    eng, params = _build(request.param, jnp.float32)
-    yield eng, params
-    eng.close()
+def module_f32(request, both_f32):
+    return both_f32[request.param]
 
 
 # a case's view: the lane's environment held while it steps the engine
@@ -188,6 +197,24 @@ class TestLogits:
 
 
 class TestSelection:
+    def test_both_lanes_score_the_same_keys_and_serve_the_same_tokens(self, both_f32):
+        """The indexer's cached keys through the page loop over the key
+        pool (the kernel lane) or gathered through the table
+        (``SELDON_TPU_PAGED_KERNEL=0``): the same tokens past
+        ``index_topk`` positions, the same count of keys scored, and the
+        engine says which."""
+        said = {}
+        for lane, (eng, _params) in both_f32.items():
+            with harness.tracing(eng):
+                before = eng.engine_stats()["index_keys_scored"]
+                served = _serve(eng, PROMPTS)
+                scored = eng.engine_stats()["index_keys_scored"] - before
+            said[lane] = ([tokens for tokens, _rows in served], scored)
+            assert eng.lane_report()["index_score_impl"] == INDEX_IMPL[lane]
+        assert said["kernel"] == said["gather"]
+        assert said["kernel"][1] > 0
+        assert all(len(p) + NEW > TOPK for p in PROMPTS)
+
     def test_reference_chosen_sets(self, f32_engine):
         """The reference's own sets: all of a row's positions while it
         has ``topk`` or fewer, exactly ``topk`` after."""
@@ -341,6 +368,7 @@ class TestAccounting:
             ("full", 2, 128, eng.num_pages), ("index", 2, 128, eng.num_pages),
             ("window", 2, 128, eng.num_window_pages)]
         assert (report["index_topk"], report["window"]) == (TOPK, WINDOW)
+        assert report["index_score_impl"] == INDEX_IMPL[eng.lane]
         assert report["window_table_pages"] == 4
 
     def test_a_prefill_call_of_4096_positions_fits_the_cell(self):

@@ -497,6 +497,28 @@ def test_latent_kernel_compiles_at_both_of_dots3_s_shapes(
         f"f32[{lanes},{heads},{rank}]"), calls[0][:200]
 
 
+@pytest.mark.parametrize("lanes,pages", [(64, 64), (64, 112), (128, 112)])
+def test_index_kernel_compiles_at_the_cells_shapes(one_chip, mosaic, lanes, pages):
+    """PR 51: a decode step's indexer scores over the ``(3, 14337, 64,
+    128)`` key pool, a bucket of the long-doc cell's chunk each; the first
+    output three dims, ``(lanes, pages, page_size)`` — what the
+    benchmark's readers tell it from a grouped matmul by."""
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, w, pool, tables, lengths, layer: kernels.index_scores_decode(
+            q, w, pool, tables, lengths, layer=layer, page_size=PS, scale=1 / 90.5)
+    ).lower(
+        spec((lanes, 64, 128), jnp.bfloat16), spec((lanes, 64), jnp.float32),
+        spec((3, 14337, PS, 128), jnp.bfloat16), spec((lanes, pages), jnp.int32),
+        spec((lanes,), jnp.int32), spec((), jnp.int32)).compile()
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln and " = " in ln]
+    assert len(calls) == 1, calls
+    assert calls[0].partition(" = ")[2].startswith(f"f32[{lanes},{pages},{PS}]"), calls[0][:200]
+
+
 @pytest.mark.parametrize("k,bucket", [(1, 4096), (1, 3072), (2, 3072)])
 def test_windowed_causal_kernel_compiles_at_the_cells_shapes(
         one_chip, mosaic, k, bucket):
@@ -520,7 +542,8 @@ def test_windowed_causal_kernel_compiles_at_the_cells_shapes(
 
 def test_the_selection_s_programs_compile_at_the_cells_shapes(one_chip, mosaic):
     """What a full layer adds: a decode step's scores of 7,168 cached
-    indexer keys a lane, the best 2,048 as a mask and the page loop
+    indexer keys a lane (PR 51: a page loop over the key pool, no key
+    gathered), the best 2,048 as a mask and the page loop
     under it (PR 40: no row is gathered, and the step's temporaries are
     the scores' alone); a prefill's indexed attention over 4,096
     positions, a block of queries at a time.  Neither may need more than
@@ -533,8 +556,9 @@ def test_the_selection_s_programs_compile_at_the_cells_shapes(one_chip, mosaic):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def step(q_full, q_i, w_i, key_row, own, pool, idx_pool, table, lengths):
-        keys = idx_pool[1, table].reshape(lanes, pages * PS, 128)
-        cached = mla.index_scores(q_i, w_i, keys, 1 / 90.5)[:, 0]
+        cached = kernels.index_scores_decode(
+            q_i[:, 0], w_i[:, 0], idx_pool, table, lengths, layer=1,
+            page_size=PS, scale=1 / 90.5).reshape(lanes, pages * PS)
         own_sc = mla.index_scores(q_i, w_i, key_row, 1 / 90.5)[:, 0, 0]
         is_cached, own_in = mla.step_mask(cached, own_sc, lengths, topk)
         return mla.merge(
@@ -555,6 +579,9 @@ def test_the_selection_s_programs_compile_at_the_cells_shapes(one_chip, mosaic):
     # no operation makes the (lanes x topk, 640) array of gathered rows
     assert f"bf16[{lanes * topk},640]" not in text
     assert f"bf16[{lanes},{topk},640]" not in text
+    # ... nor the (lanes x pages, 64, 128) array of gathered keys
+    assert f"bf16[{lanes * pages},{PS},128]" not in text
+    assert f"bf16[{lanes},{pages},{PS},128]" not in text
 
     seg = 4096
 

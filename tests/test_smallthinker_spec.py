@@ -137,6 +137,10 @@ SHIPPED = {
 # The first of each triple (the prefill) as PR 49 left it: the final norm
 # and the head on the one row a prompt the program returns; every chunk
 # hash is unedited.
+# ``dots3_note/kernel``'s third (the one chunk whose table is wide enough
+# to select) as PR 51 left it: the cached indexer keys are scored in the
+# page loop over the key pool (``ops/kernels.py index_scores_decode``);
+# its gather lane and every other line are unedited.
 # A PR that changes what one of these specs traces on purpose measures
 # its cell and replaces the line.
 PARENT_SHA = {
@@ -146,7 +150,7 @@ PARENT_SHA = {
     "deepseek_v3/gather": ("63a3dfce2d02e8d0", "161d1c29369952f7", "7caf9f1a8407537c"),
     "longcat_flash/kernel": ("2940c8bcf2928dc3", "35c56975942fe0dc", "e826ad7641948da1"),
     "longcat_flash/gather": ("2940c8bcf2928dc3", "516e14cd27e54d4d", "09a1306ca2b66391"),
-    "dots3_note/kernel": ("4b9ed20957a8e327", "1c07de2a02b9fd84", "c884b5a8d0931434"),
+    "dots3_note/kernel": ("4b9ed20957a8e327", "1c07de2a02b9fd84", "3a8da61fc72c238b"),
     "dots3_note/gather": ("4b9ed20957a8e327", "efe0631bdb5ac2f0", "67b5f8b8bab76ba8"),
 }
 
