@@ -204,9 +204,14 @@ def prefill_position_bytes(spec, d_model: int, vocab_size: int,
         from seldon_core_tpu.ops import delta
 
         qkv = 2 * spec.lin_key_dim + spec.lin_value_dim
+        # (a decay a key channel: the gate's projection, the running sums
+        # and their exponentials a channel, and k once more a diagonal
+        # block of the chunk — its columns at each block's own reference)
+        channel = ((6 + delta.CHUNK // delta.SUB) * spec.lin_key_dim
+                   if spec.lin_gate == "channel" else 0)
         attn = max(attn, 4 * spec.lin_heads * (
             2 * qkv + 2 * (spec.lin_key_dim + spec.lin_value_dim)
-            + 3 * delta.CHUNK))
+            + 3 * delta.CHUNK + channel))
     if spec.ffn == "swiglu":
         ffn = 10 * spec.dense_width  # gate, up and their product
     elif not spec.routed:
@@ -740,9 +745,12 @@ def _build_modules():
                               name=name + tag)
 
         y = _norm(spec, "attn_norm" + tag)(x)
-        c_q = rms("q_a_norm")(proj("q_a", q_rank, y))
-        q = proj("q_b", heads * (nope + rdim), c_q.astype(mod.dtype)).reshape(
-            batch, seg_len, heads, nope + rdim)
+        if q_rank:
+            c_q = rms("q_a_norm")(proj("q_a", q_rank, y))
+            q = proj("q_b", heads * (nope + rdim), c_q.astype(mod.dtype))
+        else:  # q_lora_rank null: one plain projection, no bottleneck
+            q = proj("q", heads * (nope + rdim), y)
+        q = q.reshape(batch, seg_len, heads, nope + rdim)
         kva = proj("kv_a", rank + rdim, y)
         c_kv = rms("kv_a_norm")(kva[..., :rank])
         if spec.mla_lora_scale:
@@ -1162,6 +1170,19 @@ def _build_modules():
         and ``dt_bias``; the recurrence's output is RMS-normed a head
         (``o_norm``), gated by ``silu(gate)`` and projected back.
 
+        **The variant is the spec's** (``lin_gate``, ``lin_gate_floor``,
+        ``lin_out_gate``: Kimi Delta Attention).  A decay a key CHANNEL
+        comes of a full matrix ``a`` ``(d, H x d_k)`` that rests in the
+        compute type, its product accumulated and kept in float32, under
+        the bounded gate ``floor x sigmoid(exp(a_log) (. + dt_bias))`` with
+        ``dt_bias`` a channel; ``beta`` of a float32 ``b`` ``(d, H)``; the
+        output gate ``"sigmoid_head"`` is one sigmoid a head.  **The FFN is
+        the one the layer's place calls for**: DeepSeek-V3's (a leading
+        dense SwiGLU layer, or the routed experts held here beside a
+        shared one, with the layer's routing histogram as a last value)
+        where the spec's router is sigmoid, the dense SwiGLU of every
+        layer elsewhere.
+
         Two calls.  **A prefill from position zero** (``state`` None):
         ``true_lens`` ``(B,)`` are the rows' real lengths; positions past
         them pass the pad rule, so the state ``(B, H / p, d_k, p x d_v)``
@@ -1169,15 +1190,16 @@ def _build_modules():
         back are those at each row's LAST REAL position.  **A decode
         step** (``L`` 1): ``state`` and ``tail`` as they rest, row ``b``
         its own lane's; a lane ``active`` leaves out keeps both.  Returns
-        ``(x, state, tail)``."""
+        ``(x, state, tail)`` and a routed spec's histogram ``int32[E]``."""
 
         dtype: Any = jnp.bfloat16
         precision: str = "bf16"
         spec: Any = GPT2
+        routed_layer: bool = True  # a spec with leading dense layers
 
         @nn.compact
         def __call__(self, x, state=None, tail=None, true_lens=None,
-                     active=None):
+                     active=None, token_mask=None):
             from seldon_core_tpu.ops import delta
 
             spec = self.spec
@@ -1193,17 +1215,37 @@ def _build_modules():
 
             y = x if spec.post_norm else _norm(spec, "attn_norm")(x)
             qkv = proj("qkv", spec.lin_channels, y)
-            gate = proj("gate", heads * dv, y)
-            # the two gates' projection: float32 at rest and in use, as a
-            # router (alpha is an exponential of it)
-            w_ab = self.param("ab", init, (d_model, 2 * heads), jnp.float32)
-            ab = jnp.einsum("bld,dh->blh", y.astype(jnp.float32), w_ab,
-                            precision=jax.lax.Precision.HIGHEST)
-            log_alpha, beta = delta.gates(
-                ab[..., :heads], ab[..., heads:],
-                self.param("a_log", init, (heads,), jnp.float32),
-                self.param("dt_bias", init, (heads,), jnp.float32),
-                spec.lin_neg_eigval)
+            head_gate = spec.lin_out_gate == "sigmoid_head"
+            gate = proj("gate", heads if head_gate else heads * dv, y)
+            if spec.lin_gate == "channel":
+                with jax.named_scope("seldon.delta.gate"):
+                    # the decay's projection: a full matrix in the compute
+                    # type, its product kept in float32 (alpha is an
+                    # exponential of it), and beta's float32 as a router's
+                    w_a = self.param("a", init, (d_model, heads * dk), rest)
+                    a = jnp.einsum(
+                        "bld,dc->blc", y.astype(self.dtype),
+                        w_a.astype(self.dtype),
+                        preferred_element_type=jnp.float32)
+                    w_b = self.param("b", init, (d_model, heads), jnp.float32)
+                    b = jnp.einsum("bld,dh->blh", y.astype(jnp.float32), w_b,
+                                   precision=jax.lax.Precision.HIGHEST)
+                    log_alpha, beta = delta.gates(
+                        a, b,
+                        self.param("a_log", init, (heads,), jnp.float32),
+                        self.param("dt_bias", init, (heads * dk,), jnp.float32),
+                        spec.lin_neg_eigval, floor=spec.lin_gate_floor)
+            else:
+                # the two gates' projection: float32 at rest and in use, as a
+                # router (alpha is an exponential of it)
+                w_ab = self.param("ab", init, (d_model, 2 * heads), jnp.float32)
+                ab = jnp.einsum("bld,dh->blh", y.astype(jnp.float32), w_ab,
+                                precision=jax.lax.Precision.HIGHEST)
+                log_alpha, beta = delta.gates(
+                    ab[..., :heads], ab[..., heads:],
+                    self.param("a_log", init, (heads,), jnp.float32),
+                    self.param("dt_bias", init, (heads,), jnp.float32),
+                    spec.lin_neg_eigval)
             taps = self.param("conv", init, (spec.lin_conv, spec.lin_channels),
                               rest)
             if state is None:
@@ -1221,7 +1263,9 @@ def _build_modules():
                 if true_lens is not None:  # the pad rule past a row's length
                     real = (jnp.arange(seg_len)[None, :]
                             < true_lens[:, None])[..., None]
-                    log_alpha = jnp.where(real, log_alpha, 0.0)
+                    log_alpha = jnp.where(
+                        real[..., None] if log_alpha.ndim == 4 else real,
+                        log_alpha, 0.0)
                     beta = jnp.where(real, beta, 0.0)
                 out, state = delta.chunked_scan(q, k, v, log_alpha, beta)
                 state = delta.pack_state(state, pack)
@@ -1232,13 +1276,19 @@ def _build_modules():
                 out = out[:, None]
             out = nn.RMSNorm(epsilon=spec.norm_eps, dtype=jnp.float32,
                              name="o_norm")(out)
-            out = out * jax.nn.silu(
-                gate.astype(jnp.float32).reshape(batch, seg_len, heads, dv))
+            if head_gate:
+                out = out * jax.nn.sigmoid(gate.astype(jnp.float32))[..., None]
+            else:
+                out = out * jax.nn.silu(
+                    gate.astype(jnp.float32).reshape(batch, seg_len, heads, dv))
             out = proj("attn_proj", d_model,
                        out.reshape(batch, seg_len, heads * dv))
             if spec.post_norm:
                 out = _norm(spec, "attn_post_norm")(out).astype(x.dtype)
             x = x + out
+            if spec.score == "sigmoid":
+                x, hist = _ffn_grouped(self, x, token_mask)
+                return (x, state, tail, *hist)
             return _ffn_swiglu(self, x), state, tail
 
     class PagedTransformerBlock(nn.Module):
@@ -1759,7 +1809,7 @@ def _build_modules():
             if self.spec.linear:
                 return self._hybrid(x, positions, pages_k, pages_v,
                                     block_tables, lengths, whole, delta or {},
-                                    last)
+                                    last, token_mask)
             for i in range(self.num_layers):
                 if whole:
                     pools = (pages_k, pages_v)
@@ -1797,12 +1847,14 @@ def _build_modules():
             return _head(self, x, new_k, new_v, hists, last)
 
         def _hybrid(self, x, positions, pages_k, pages_v, block_tables,
-                    lengths, whole, delta, last):
+                    lengths, whole, delta, last, token_mask=None):
             """The layers of a spec with linear-attention layers: a
             ``"linear"`` layer is a :class:`DeltaBlock` over its own state
             and keeps no pages; a ``"full"`` layer is the grouped-query
-            block over the K/V pool, whose leading axis counts the full
-            layers alone (``spec.kind_index``).
+            block over the K/V pool — or, for a latent spec, the latent
+            block over the ONE latent pool (``pages_v`` None) — whose
+            leading axis counts the full layers alone
+            (``spec.kind_index``).
 
             ``delta`` is the linear layers' side of the call.  A prefill
             from zero: ``{"true_lens": (B,)}``.  A decode step:
@@ -1812,25 +1864,50 @@ def _build_modules():
             call's lanes are a permutation of the slots (the bucketed
             chunk): the stream's rows are gathered to slot order round a
             linear layer, never the state.  Returns ``(logits, K, V,
-            states, tails)``, the last two a tuple a linear layer."""
+            states, tails)``, the last two a tuple a linear layer, and a
+            routed spec's ``int32[layers, E]`` assignment histogram over
+            the rows ``token_mask`` keeps as a sixth value (a linear
+            layer routes as a full one does; a dense layer's row is
+            zeros)."""
             spec = self.spec
-            new_k, new_v, states, tails = [], [], [], []
+            new_k, new_v, states, tails, hists = [], [], [], [], []
             order = delta.get("order")
+            # the rows a routed layer's histogram counts, in the lanes'
+            # order and (round a linear layer of a decode step) the slots'
+            mask = {"token_mask": token_mask} if spec.routed else {}
+            slot_mask = ({"token_mask": token_mask[order[0]]}
+                         if mask and order is not None else mask)
             for i in range(self.num_layers):
                 at = spec.kind_index(i)
+                place = ({"routed_layer": False}
+                         if spec.routed and not spec.layer_routed(i) else {})
                 if spec.layer_kind(i) == "linear":
                     block = DeltaBlock(dtype=self.dtype, precision=self.precision,
-                                       spec=spec, name=f"block_{i}")
+                                       spec=spec, name=f"block_{i}", **place)
                     if "state" in delta:
                         rows = x if order is None else x[order[0]]
-                        rows, state, tail = block(
+                        rows, state, tail, *hist = block(
                             rows, delta["state"][at], delta["conv"][at],
-                            active=delta["active"])
+                            active=delta["active"], **slot_mask)
                         x = rows if order is None else rows[order[1]]
                     else:
-                        x, state, tail = block(x, true_lens=delta.get("true_lens"))
+                        x, state, tail, *hist = block(
+                            x, true_lens=delta.get("true_lens"), **mask)
                     states.append(state)
                     tails.append(tail)
+                    hists += hist
+                    continue
+                if spec.latent:
+                    # one latent pool: a row a token a full layer, no V
+                    x, row, _none, hist = PagedTransformerBlock(
+                        num_heads=self.num_heads, dtype=self.dtype,
+                        precision=self.precision, name=f"block_{i}", spec=spec,
+                        **place,
+                    )(x, pages_k if whole else pages_k[at], None, block_tables,
+                      lengths, layer=at if whole else None,
+                      positions=positions, token_mask=token_mask)
+                    new_k.append(row)
+                    hists.append(hist)
                     continue
                 pools = ((pages_k, pages_v) if whole
                          else (pages_k[at], pages_v[at]))
@@ -1843,7 +1920,9 @@ def _build_modules():
                 new_k.append(k)
                 new_v.append(v)
             return (_unembed(self, x, last), jnp.stack(new_k),
-                    jnp.stack(new_v), tuple(states), tuple(tails))
+                    jnp.stack(new_v) if new_v else None,
+                    tuple(states), tuple(tails),
+                    *((jnp.stack(hists),) if hists else ()))
 
         def _kinds(self, x, positions, pools, pools_v, block_tables, lengths,
                    token_mask, window, whole, last):
@@ -1966,11 +2045,11 @@ def delta_written(delta, hist, slots):
     scatter drops.  ``(None, hist)`` for a spec without linear layers."""
     if delta is None:
         return None, hist
-    states, tails = hist
+    states, tails, *routing = hist  # (a routed spec's histogram follows)
     return ([rest.at[slots].set(new, mode="drop")
              for rest, new in zip(delta[0], states)],
             [rest.at[slots].set(new.astype(rest.dtype), mode="drop")
-             for rest, new in zip(delta[1], tails)]), ()
+             for rest, new in zip(delta[1], tails)]), tuple(routing)
 
 
 def delta_step_kwarg(delta, active, order):
@@ -1988,7 +2067,7 @@ def delta_carried(delta, hist):
     LM's ``(states, tails)`` take the resting ones' place."""
     if delta is None:
         return None, hist
-    return hist, ()
+    return hist[:2], tuple(hist[2:])
 
 
 def window_kwarg(window):
@@ -4682,10 +4761,11 @@ class PagedEngine:
         """The one wording of a lane that a state a lane cannot take
         yet."""
         spec = self.spec
+        pages = ("latent rows" if spec.latent else "K/V pages")
         return (
             f"arch={spec.name!r} keeps a state of {spec.lin_heads} x "
             f"{spec.lin_key_dim} x {spec.lin_value_dim} float32 a lane in "
-            f"each of its linear-attention layers, beside the K/V pages of "
+            f"each of its linear-attention layers, beside the {pages} of "
             f"the others: {what} cannot take a state a lane yet — {why}"
         )
 
@@ -8633,6 +8713,10 @@ class PagedEngine:
                 "delta_step": _delta.step_impl(
                     *self._delta_state[0].shape[2:]),
                 "delta_scan": _delta.scan_impl(),
+                # the variant: one decay a head | a key channel, and the
+                # bounded gate's floor (0: the softplus gate)
+                "delta_gate": self.spec.lin_gate,
+                "delta_gate_floor": self.spec.lin_gate_floor,
                 # the length buckets a chunk program splits its lanes
                 # into (1 here unless SELDON_TPU_CTX_BUCKETS asks for 2)
                 "ctx_buckets": self._ctx_buckets}

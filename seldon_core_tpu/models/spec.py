@@ -34,7 +34,7 @@ expert-parallel layer computes its own experts' part and nothing
 stands in for the rest).
 
 ``GPT2``, ``OLMOE``, ``DEEPSEEK_V3``, ``LONGCAT_FLASH``, ``DOTS3_NOTE``,
-``SMALLTHINKER``, ``XING4_0`` and ``OLMO_HYBRID`` are the values served (the fifth added layer KINDS: ``layer_kinds``,
+``SMALLTHINKER``, ``XING4_0``, ``OLMO_HYBRID`` and ``BAILING_HYBRID`` are the values served (the fifth added layer KINDS: ``layer_kinds``,
 ``attn_kind``, ``cache_kinds`` — layers that differ in their attention
 and a cache of one pool a row kind); a new architecture is a new value (and new branches where the
 block reads a field it has not met), not a new block.  The fourth value
@@ -81,6 +81,19 @@ one K/V pool whose leading axis counts them alone
 (:meth:`ModelSpec.cache_layers`, :meth:`ModelSpec.state_layers`).  With
 it came ``post_norm`` (the norm on a sub-layer's output) and ``ffn
 "swiglu"`` (a dense SwiGLU in every layer of a multi-head spec).
+
+The ninth value (``BAILING_HYBRID``: Ling-3.0-flash) put the linear
+layers **beside a latent pool** — the layers that keep pages are
+DeepSeek-V3's latent attention over ONE pool whose leading axis counts
+them alone, with ``q_rank`` 0 (a plain q projection, no bottleneck) and
+dots3's ``attn_gate`` — made the linear layer's VARIANT facts of the spec
+(``lin_gate`` "head" | "channel": one decay a head or one a key channel,
+Kimi Delta Attention; ``lin_gate_floor``: 0 = the softplus gate, a
+negative number the bounded gate ``floor x sigmoid(.)``; ``lin_out_gate``
+"silu" over every value | "sigmoid_head" one a head), and gave a linear
+layer the FFN its place calls for: leading dense SwiGLU layers, then
+DeepSeek-V3's sigmoid-routed experts of which the replica holds a share
+(:meth:`ModelSpec.layer_routed` whatever the layer's kind).
 """
 
 from __future__ import annotations
@@ -271,6 +284,22 @@ class ModelSpec:
     lin_conv: int = 4
     lin_neg_eigval: bool = True
     post_norm: bool = False
+    # ---- the linear layer's variant (Ling-3.0-flash: Kimi Delta
+    # Attention).  lin_gate: the decay is one number a head ("head") | one
+    # a key CHANNEL ("channel": a full matrix ``a`` of lin_heads x
+    # lin_key_dim outputs, and the state's rows decay each at its own
+    # rate).  lin_gate_floor: 0 = alpha = exp(-exp(A_log) softplus(.)) |
+    # negative = the bounded gate, log alpha = floor x sigmoid(exp(A_log)
+    # (.)) in (floor, 0) (kda_safe_gate / kda_lower_bound).  lin_out_gate:
+    # "silu" (a gate a value, lin_heads x lin_value_dim wide) |
+    # "sigmoid_head" (one sigmoid gate a head).  The two swiglu limit
+    # lists are a clamp on the experts' hidden rows whose form no config
+    # key gives: kept so that a non-zero entry is REFUSED by name
+    lin_gate: str = "head"
+    lin_gate_floor: float = 0.0
+    lin_out_gate: str = "silu"
+    expert_swiglu_limits: Tuple[float, ...] = ()
+    shared_swiglu_limits: Tuple[float, ...] = ()
 
     @property
     def routed(self) -> bool:
@@ -602,10 +631,36 @@ OLMO_HYBRID = ModelSpec(
     lin_conv=4, lin_neg_eigval=True, post_norm=True,
 )
 
+# inclusionAI/Ling-3.0-flash config.json (model_type bailing_hybrid): 42
+# layers, layer_group_size 6 — layer i is latent attention where (i + 1) %
+# 6 == 0 and Kimi Delta Attention elsewhere (35 : 7).  A KDA layer: 32
+# heads of 128 (q, k) against 128 (v), a convolution of 4 taps, one decay
+# a key channel under the bounded gate (kda_safe_gate, kda_lower_bound
+# -5), beta = sigmoid(.) (no allow_neg_eigval), a sigmoid output gate a
+# head (head_wise).  An MLA layer: DeepSeek-V3's with NO q bottleneck
+# (q_lora_rank null), latent 512 + 64 rope, heads of 128 + 64 against
+# values of 128, theta 6e6, no scaling, a sigmoid gate a head.  2 leading
+# dense SwiGLU layers of 6,144, then 512 sigmoid-routed experts of 768 in
+# 8 groups (4 kept), top-8 renormalised and scaled 2.5, beside one shared
+# expert; RMSNorm eps 1e-6 on each sub-layer's input, no biases
+BAILING_HYBRID = ModelSpec(
+    name="bailing_hybrid", positions="rope", norm="rmsnorm", norm_eps=1e-6,
+    ffn="moe", num_experts=512, experts_per_tok=8, expert_width=768,
+    rope_theta=6_000_000.0, bias=False, residual_f32=True, weights_f32=False,
+    attention="mla", q_rank=0, kv_rank=512, nope_dim=128, rope_dim=64,
+    v_dim=128, dense_layers=2, dense_width=6144, shared_experts=1,
+    score="sigmoid", n_group=8, topk_group=4, norm_topk=True,
+    routed_scale=2.5, attn_gate=True,
+    layer_kinds=(("linear",) * 5 + ("full",)) * 7,
+    lin_heads=32, lin_key_dim=128, lin_value_dim=128, lin_conv=4,
+    lin_neg_eigval=False, lin_gate="channel", lin_gate_floor=-5.0,
+    lin_out_gate="sigmoid_head",
+)
+
 _ARCHS = {"gpt2": GPT2, "olmoe": OLMOE, "deepseek_v3": DEEPSEEK_V3,
           "longcat_flash": LONGCAT_FLASH, "dots3_note": DOTS3_NOTE,
           "smallthinker": SMALLTHINKER, "xing4_0": XING4_0,
-          "olmo_hybrid": OLMO_HYBRID}
+          "olmo_hybrid": OLMO_HYBRID, "bailing_hybrid": BAILING_HYBRID}
 # the sizes any routed arch has; a replica's share of the experts; those
 # only DeepSeek-V3's expert layer and attention have; and the two every
 # arch has
@@ -642,11 +697,19 @@ _LINEAR_SIZES = ("kv_heads", "head_dim", "layer_kinds", "dense_width",
 # has: what ``model_spec`` lets a caller set, by the arch's name (a share
 # of the experts and layer kinds belong to the archs whose block was
 # built for them, latent or not)
+# ... and those of linear layers of a variant beside a latent pool, under
+# DeepSeek-V3's expert layer
+_VARIANT_SIZES = ("lin_gate", "lin_gate_floor", "lin_out_gate",
+                  "expert_swiglu_limits", "shared_swiglu_limits")
+_BAILING_SIZES = _LATENT_SIZES + (
+    "dense_layers", "shared_experts", "n_group", "topk_group", "layer_kinds",
+    "lin_heads", "lin_key_dim", "lin_value_dim", "lin_conv",
+    "lin_neg_eigval") + _VARIANT_SIZES
 _OWN_SIZES = {"gpt2": (), "olmoe": (), "deepseek_v3": _DEEPSEEK_SIZES,
               "longcat_flash": _LONGCAT_SIZES, "dots3_note": _DOTS3_SIZES,
               "smallthinker": _SMALLTHINKER_SIZES,
               "xing4_0": _DEEPSEEK_SIZES + _HYPER_SIZES,
-              "olmo_hybrid": _LINEAR_SIZES}
+              "olmo_hybrid": _LINEAR_SIZES, "bailing_hybrid": _BAILING_SIZES}
 _SIZES = tuple(dict.fromkeys(
     _EXPERT_SIZES + sum(_OWN_SIZES.values(), ()) + ("rope_theta", "norm_eps")))
 # a size that may be given as 0 and mean it, for an arch that takes
@@ -681,19 +744,51 @@ def model_spec(arch: str = "gpt2", **sizes: Any) -> ModelSpec:
     if given:
         floats = ("rope_theta", "norm_eps", "routed_scale", "rope_factor",
                   "rope_beta_fast", "rope_beta_slow", "rope_mscale_all_dim",
-                  "win_rope_theta", "hc_eps", "hc_res_min", "hc_res_max")
+                  "win_rope_theta", "hc_eps", "hc_res_min", "hc_res_max",
+                  "lin_gate_floor")
+        limits = ("expert_swiglu_limits", "shared_swiglu_limits")
         spec = replace(spec, **{
             k: (float(v) if k in floats
                 else tuple(str(x) for x in v) if k == "layer_kinds"
+                else tuple(float(x) for x in v) if k in limits
+                else str(v) if k in ("lin_gate", "lin_out_gate")
                 else bool(v) if k == "lin_neg_eigval" else int(v))
             for k, v in given.items()
         })
-    if spec.linear and (spec.latent or not spec.lin_heads
+    if spec.linear and (not spec.lin_heads
                         or set(spec.layer_kinds) - {"full", "linear"}):
         raise ValueError(
             f"arch={spec.name!r}, layer_kinds {spec.layer_kinds}: linear "
-            "layers stand beside 'full' multi-head layers of an arch that "
-            "has their sizes (lin_heads, lin_key_dim, lin_value_dim)")
+            "layers stand beside 'full' layers (multi-head over K/V pages, "
+            "or latent attention over one latent pool) of an arch that has "
+            "their sizes (lin_heads, lin_key_dim, lin_value_dim)")
+    if (spec.lin_gate not in ("head", "channel")
+            or spec.lin_out_gate not in ("silu", "sigmoid_head")
+            or spec.lin_gate_floor > 0
+            or (spec.lin_gate == "channel") != (spec.lin_gate_floor < 0)):
+        raise ValueError(
+            f"lin_gate {spec.lin_gate!r}, lin_gate_floor "
+            f"{spec.lin_gate_floor}, lin_out_gate {spec.lin_out_gate!r}: a "
+            "decay is one a 'head' under the softplus gate (floor 0) or one "
+            "a 'channel' under the bounded gate (a negative floor), and the "
+            "output gate 'silu' or 'sigmoid_head'")
+    if spec.lin_gate_floor < 0:
+        from seldon_core_tpu.ops import delta
+
+        if -spec.lin_gate_floor * delta.SUB > delta.BLOCK_EXPONENT_MAX:
+            raise ValueError(
+                f"lin_gate_floor {spec.lin_gate_floor}: the chunked scan "
+                f"factors a decay a channel in blocks of {delta.SUB} "
+                f"positions, which holds down to a floor of "
+                f"{-delta.BLOCK_EXPONENT_MAX / delta.SUB} a position")
+    if any(spec.expert_swiglu_limits) or any(spec.shared_swiglu_limits):
+        raise ValueError(
+            f"arch={spec.name!r}: expert_swiglu_limit_list "
+            f"{spec.expert_swiglu_limits} / share_expert_swiglu_limit_list "
+            f"{spec.shared_swiglu_limits} name a clamp on the experts' "
+            "hidden rows in some served layer; the clamp's form is not in "
+            "the configuration and is not built — serve layers whose "
+            "entries are 0")
     if spec.linear and min(spec.lin_heads, spec.lin_key_dim,
                            spec.lin_value_dim, spec.lin_conv - 1) < 1:
         raise ValueError(
@@ -968,6 +1063,16 @@ def init_params(spec: ModelSpec, config: Dict[str, int], seed: int,
             # the gate's softplus of order 1 the decay alpha spreads over
             # (0, 1) and does not sit at 1, where it would test nothing
             lo, hi = -3.0, 0.0
+            if spec.lin_gate_floor:
+                # the bounded gate's rate exp(a_log) in (0.37, 1.65) ...
+                lo, hi = -1.0, 0.5
+        elif name == "dt_bias" and spec.lin_gate_floor:
+            # ... and a bias a key channel in [-6, -1): with the projection
+            # of order 1, log alpha = floor x sigmoid(rate (a + dt_bias))
+            # spreads a head's channels from alpha 0.99 (a memory of a
+            # hundred positions) down to 0.1, most of them slow — a gate
+            # that sat at one value a head would test Olmo's rule again
+            lo, hi = -6.0, -1.0
         else:
             hi = (0.1 if name == "bias" or name.endswith("_bias")
                   else (3.0 if name == "embedding" else 3.0 / leaf.shape[

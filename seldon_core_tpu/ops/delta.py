@@ -25,6 +25,17 @@ With ``q_t`` and ``k_t`` a head's normalised query and key (``d_k``),
   :data:`TAPS` taps and SiLU that q, k and v pass first, and the last
   ``TAPS - 1`` inputs a lane keeps for it.
 
+**A decay a key channel** (Kimi Delta Attention, arXiv:2510.26692; FLA's
+``KimiDeltaAttention``): ``alpha_t`` may be a VECTOR a head, one gate a
+key channel, and then ``S' = Diag(alpha_t) S_{t-1}`` scales the state's
+ROWS (``d_k``) where the scalar scales every entry alike.  Every function
+here takes ``log_alpha`` either way — ``(..., H)`` a head or ``(..., H,
+d_k)`` a channel — and which it got is a fact of the call's structure:
+the scalar form traces what it traced before there was a second.  The
+channel form's gate is bounded below (:func:`gates` ``floor``), which is
+what lets the chunked scan factor its decays in blocks of :data:`SUB`
+positions (:func:`chunked_scan`).
+
 **The pad rule.**  A position with ``beta = 0`` and ``alpha = 1`` leaves
 the state as it was (``u = 0``, ``S' = S``).  A prefill call padded to
 its bucket passes those at every position past a prompt's own length, so
@@ -161,18 +172,31 @@ def l2norm(x, eps: float = 1e-6):
     return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + eps)
 
 
-def gates(a, b, a_log, dt_bias, neg_eigval: bool = True):
+def gates(a, b, a_log, dt_bias, neg_eigval: bool = True, *, floor=None):
     """``(log alpha, beta)`` float32 from the two gate projections ``a``
     and ``b`` ``(..., H)``: ``alpha = exp(-exp(A_log) softplus(a +
     dt_bias))``, ``beta = sigmoid(b)`` times 2 under ``neg_eigval``
     (``linear_allow_neg_eigval``: the state's transition ``I - beta k
-    k^T`` may then have an eigenvalue in (-1, 0))."""
+    k^T`` may then have an eigenvalue in (-1, 0)).
+
+    ``floor`` (a negative number: ``kda_lower_bound`` under
+    ``kda_safe_gate``) is the bounded gate of a decay a key channel: ``a``
+    and ``dt_bias`` are ``(..., H x d_k)``, ``a_log`` ``(H,)`` a head's
+    rate, and ``log alpha = floor x sigmoid(exp(A_log) (a + dt_bias))``
+    in ``(floor, 0)`` comes back ``(..., H, d_k)``."""
     import jax
     import jax.numpy as jnp
 
     f32 = jnp.float32
-    log_alpha = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
-        a.astype(f32) + dt_bias.astype(f32))
+    if floor is None:
+        log_alpha = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+            a.astype(f32) + dt_bias.astype(f32))
+    else:
+        heads = a_log.shape[0]
+        z = (a.astype(f32) + dt_bias.astype(f32)).reshape(
+            *a.shape[:-1], heads, a.shape[-1] // heads)
+        log_alpha = float(floor) * jax.nn.sigmoid(
+            jnp.exp(a_log.astype(f32))[:, None] * z)
     beta = jax.nn.sigmoid(b.astype(f32)) * (2.0 if neg_eigval else 1.0)
     return log_alpha, beta
 
@@ -184,7 +208,8 @@ def gates(a, b, a_log, dt_bias, neg_eigval: bool = True):
 def step(state, q, k, v, log_alpha, beta, *, pack: int = 1, active=None):
     """One position a lane against the resting state: ``state`` ``(B, H /
     p, d_k, p x d_v)`` float32, ``q`` / ``k`` ``(B, H, d_k)``, ``v`` ``(B,
-    H, d_v)``, ``log_alpha`` / ``beta`` ``(B, H)``.  ``(new state, o (B, H,
+    H, d_v)``, ``log_alpha`` ``(B, H)`` (a decay a head) or ``(B, H, d_k)``
+    (a decay a key channel), ``beta`` ``(B, H)``.  ``(new state, o (B, H,
     d_v) float32)``.  ``active`` ``(B,)``: a lane it leaves out passes the
     pad rule's gates, so its state stays as it is, bit for bit.
 
@@ -199,10 +224,12 @@ def step(state, q, k, v, log_alpha, beta, *, pack: int = 1, active=None):
         b, h, dk = q.shape
         dv = v.shape[-1]
         g = h // pack
+        channel = log_alpha.ndim == 3
         alpha = jnp.exp(log_alpha.astype(f32))
         beta = beta.astype(f32)
         if active is not None:
-            alpha = jnp.where(active[:, None], alpha, 1.0)
+            alpha = jnp.where(active[:, None, None] if channel
+                              else active[:, None], alpha, 1.0)
             beta = jnp.where(active[:, None], beta, 0.0)
 
         def lanes(x):  # (B, H) -> (B, G, p x d_v): a head's value its lanes over
@@ -210,11 +237,15 @@ def step(state, q, k, v, log_alpha, beta, *, pack: int = 1, active=None):
 
         where = backend()
         if step_impl(dk, pack * dv, where) == "pallas":
+            # (a decay a channel rides with the rows: it scales the
+            # state's ROWS, as q and k weigh them)
             rows = jnp.concatenate(
                 [q.astype(f32).reshape(b, g, pack, dk),
-                 k.astype(f32).reshape(b, g, pack, dk)], axis=2)
-            gates = jnp.stack([v.astype(f32).reshape(b, g, pack * dv),
-                               lanes(alpha), lanes(beta)], axis=2)
+                 k.astype(f32).reshape(b, g, pack, dk)]
+                + ([alpha.reshape(b, g, pack, dk)] if channel else []), axis=2)
+            gates = jnp.stack(
+                [v.astype(f32).reshape(b, g, pack * dv)]
+                + ([] if channel else [lanes(alpha)]) + [lanes(beta)], axis=2)
             new, out = _step_pallas(state, rows, gates, pack=pack,
                                     interpret=where != "tpu")
             return new, out.reshape(b, h, dv)
@@ -225,7 +256,8 @@ def step(state, q, k, v, log_alpha, beta, *, pack: int = 1, active=None):
                 dv, axis=-1)
 
         kk = rows(k)
-        decayed = lanes(alpha)[:, :, None, :] * state
+        decayed = (rows(alpha) if channel
+                   else lanes(alpha)[:, :, None, :]) * state
         read = (kk * decayed).sum(axis=2)                      # S'^T k
         u = lanes(beta) * (v.astype(f32).reshape(b, g, pack * dv) - read)
         new = decayed + kk * u[:, :, None, :]
@@ -241,12 +273,18 @@ def _step_kernel(s_ref, rows_ref, gates_ref, s_out_ref, o_ref, *, pack):
     ``o_ref`` ``(1, G, 1, W)``.  A pair of heads at a time: the state's
     tile is read once, decayed, read against k, written through, read
     against q — elementwise products and sums over ``d_k``, float32 on the
-    vector unit (no matmul unit: nothing is rounded to bfloat16)."""
+    vector unit (no matmul unit: nothing is rounded to bfloat16).
+
+    **A decay a key channel**: ``rows_ref`` is ``(1, G, 3 pack, d_k)`` —
+    alpha's rows after k's — and ``gates_ref`` ``(1, G, 2, W)``, v and
+    beta: alpha is spread down the state's rows as k is, where the scalar
+    came spread over its lanes."""
     import jax
     import jax.numpy as jnp
 
     groups, dk, width = s_ref.shape[1], s_ref.shape[2], s_ref.shape[3]
     dv = width // pack
+    channel = rows_ref.shape[2] == 3 * pack
     eye = (jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
            == jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1))
     head = jax.lax.broadcasted_iota(jnp.int32, (dk, width), 1) // dv
@@ -264,10 +302,15 @@ def _step_kernel(s_ref, rows_ref, gates_ref, s_out_ref, o_ref, *, pack):
         s = s_ref[0, g]
         rows = rows_ref[0, g]
         gates = gates_ref[0, g]
-        kk = spread(rows[pack:])
-        decayed = s * gates[1:2]
-        read = jnp.sum(kk * decayed, axis=0, keepdims=True)     # S'^T k
-        u = gates[2:3] * (gates[0:1] - read)
+        kk = spread(rows[pack:2 * pack])
+        if channel:
+            decayed = s * spread(rows[2 * pack:])
+            read = jnp.sum(kk * decayed, axis=0, keepdims=True)
+            u = gates[1:2] * (gates[0:1] - read)
+        else:
+            decayed = s * gates[1:2]
+            read = jnp.sum(kk * decayed, axis=0, keepdims=True)  # S'^T k
+            u = gates[2:3] * (gates[0:1] - read)
         new = decayed + kk * u
         s_out_ref[0, g] = new
         o_ref[0, g] = jnp.sum(spread(rows[:pack]) * new, axis=0, keepdims=True)
@@ -294,8 +337,10 @@ def _step_pallas(state, rows, gates, *, pack, interpret):
         functools.partial(_step_kernel, pack=pack),
         grid=(b,),
         in_specs=[pl.BlockSpec((1, g, dk, width), lambda i: (i, 0, 0, 0)),
-                  pl.BlockSpec((1, g, 2 * pack, dk), lambda i: (i, 0, 0, 0)),
-                  pl.BlockSpec((1, g, 3, width), lambda i: (i, 0, 0, 0))],
+                  pl.BlockSpec((1, g, rows.shape[2], dk),
+                               lambda i: (i, 0, 0, 0)),
+                  pl.BlockSpec((1, g, gates.shape[2], width),
+                               lambda i: (i, 0, 0, 0))],
         out_specs=[pl.BlockSpec((1, g, dk, width), lambda i: (i, 0, 0, 0)),
                    pl.BlockSpec((1, g, 1, width), lambda i: (i, 0, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct(state.shape, jnp.float32),
@@ -363,6 +408,58 @@ def _solve_unit_lower(a, rhs, sub: int):
     return jnp.concatenate(solved, axis=-2)
 
 
+def _chunks(length: int, chunk: int):
+    """``(c, sub, pad, n)``: positions a chunk, rows a diagonal block,
+    pad positions and chunks of a segment of ``length``."""
+    if length >= chunk:
+        c = chunk
+    else:  # one chunk: the segment in whole diagonal blocks
+        c = -(-length // SUB) * SUB if length > SUB else length
+    sub = SUB if c % SUB == 0 else c
+    pad = -length % c
+    return c, sub, pad, (length + pad) // c
+
+
+def _lay(x, n: int, c: int, pad: int):
+    """``(B, L, H, ...)`` -> ``(B, H, N, C, ...)`` float32, pads passing
+    the pad rule."""
+    import jax.numpy as jnp
+
+    x = jnp.pad(x.astype(jnp.float32),
+                [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+    x = x.reshape(x.shape[0], n, c, *x.shape[2:])
+    return jnp.moveaxis(x, 3, 1)
+
+
+def _carry_chunks(state, chunks, shape, length: int):
+    """The sequential part of a scan: ``chunks`` = ``(U0, W, M . QK^T, e^g
+    Q, e^{g_C - g} K, e^{g_C})`` laid ``(B, H, N, ...)``, the state carried
+    chunk to chunk (``e^{g_C}`` scales it whole, or its rows where it is a
+    vector a head).  ``(o (B, L, H, d_v), final state)``."""
+    import jax
+    import jax.numpy as jnp
+
+    hi = jax.lax.Precision.HIGHEST
+    b, h, dk, dv = shape
+
+    def one(s, xs):
+        u0_n, w_n, qk_n, q_n, k_n, carry_n = xs
+        u = u0_n - jnp.matmul(w_n, s, precision=hi)
+        o = (jnp.matmul(q_n, s, precision=hi)
+             + jnp.matmul(qk_n, u, precision=hi))
+        s = carry_n * s + jnp.einsum("...td,...tv->...dv", k_n, u,
+                                     precision=hi)
+        return s, o
+
+    if state is None:
+        state = jnp.zeros((b, h, dk, dv), jnp.float32)
+    xs = tuple(jnp.moveaxis(x, 2, 0) for x in chunks)
+    state, out = jax.lax.scan(one, state.astype(jnp.float32), xs)
+    n, c = out.shape[0], out.shape[3]
+    out = jnp.moveaxis(out, 0, 2).reshape(b, h, n * c, dv)[:, :, :length]
+    return jnp.moveaxis(out, 1, 2), state
+
+
 def chunked_scan(q, k, v, log_alpha, beta, *, state=None, chunk: int = CHUNK):
     """The recurrence over a segment: ``q`` / ``k`` ``(B, L, H, d_k)``,
     ``v`` ``(B, L, H, d_v)``, ``log_alpha`` / ``beta`` ``(B, L, H)``;
@@ -378,28 +475,36 @@ def chunked_scan(q, k, v, log_alpha, beta, *, state=None, chunk: int = CHUNK):
     K^T) U``, ``S = e^{g_C} S_0 + (e^{g_C - g} K)^T U`` a chunk.  Decays
     enter as ``exp`` of differences that are never positive.  Every matmul
     is float32 at the highest precision: the state's error is the
-    recurrence's own."""
+    recurrence's own.
+
+    **A decay a key channel** (``log_alpha`` ``(B, L, H, d_k)``): ``g_t``
+    is a vector, ``A[t, i] = beta_t sum_c k_t[c] k_i[c] e^{g_t[c] -
+    g_i[c]}``, and the one product ``(K e^g)(K e^{-g})^T`` a chunk that
+    would make it cannot be formed: at a gate's floor of -5 a position
+    ``e^{-g}`` reaches ``e^{320}`` within 64 positions and leaves float32.
+    In blocks of :data:`SUB` = 16 positions it can — that is what the
+    floor is FOR: with ``m_j`` the running sum at block ``j``'s middle
+    position, the rows of block ``j`` carry ``e^{g_t - m_j}`` and the
+    columns up to its end ``e^{m_j - g_i}``; inside the block both
+    exponents stay within ``+-8 x 5`` and a masked pair's product within
+    ``e^{16 x 5}`` = ``e^{80}`` (float32 holds ``e^{88}``), and a column
+    before the block is never positive.  ``S_0`` enters through ``e^g K`` and ``e^g Q``,
+    the carry scales the state's ROWS by ``e^{g_C}``; everything else is
+    the scalar form's (:func:`_channel_scan`)."""
     import jax
     import jax.numpy as jnp
 
+    if log_alpha.ndim == q.ndim:
+        return _channel_scan(q, k, v, log_alpha, beta, state, chunk)
     with jax.named_scope("seldon.delta.scan"):
         f32 = jnp.float32
         hi = jax.lax.Precision.HIGHEST
         b, length, h, dk = q.shape
         dv = v.shape[-1]
-        if length >= chunk:
-            c = chunk
-        else:  # one chunk: the segment in whole diagonal blocks
-            c = -(-length // SUB) * SUB if length > SUB else length
-        sub = SUB if c % SUB == 0 else c
-        pad = -length % c
-        n = (length + pad) // c
+        c, sub, pad, n = _chunks(length, chunk)
 
-        def lay(x):  # (B, L, H, ...) -> (B, H, N, C, ...), pads passing the pad rule
-            x = jnp.pad(x.astype(f32),
-                        [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
-            x = x.reshape(b, n, c, *x.shape[2:])
-            return jnp.moveaxis(x, 3, 1)
+        def lay(x):
+            return _lay(x, n, c, pad)
 
         q, k, v = lay(q), lay(k), lay(v)                       # (B, H, N, C, d)
         beta = lay(beta)                                       # (B, H, N, C)
@@ -420,23 +525,94 @@ def chunked_scan(q, k, v, log_alpha, beta, *, state=None, chunk: int = CHUNK):
         g_end = g[..., -1:]
         k_out = k * jnp.exp(g_end - g)[..., None]
         carry = jnp.exp(g_end)[..., None]                      # (B, H, N, 1, 1)
+        return _carry_chunks(state, (u0, w, qk, q_in, k_out, carry),
+                             (b, h, dk, dv), length)
 
-        def one(s, xs):
-            u0_n, w_n, qk_n, q_n, k_n, carry_n = xs
-            u = u0_n - jnp.matmul(w_n, s, precision=hi)
-            o = (jnp.matmul(q_n, s, precision=hi)
-                 + jnp.matmul(qk_n, u, precision=hi))
-            s = carry_n * s + jnp.einsum("...td,...tv->...dv", k_n, u,
-                                         precision=hi)
-            return s, o
 
-        if state is None:
-            state = jnp.zeros((b, h, dk, dv), f32)
-        xs = tuple(jnp.moveaxis(x, 2, 0)
-                   for x in (u0, w, qk, q_in, k_out, carry))
-        state, out = jax.lax.scan(one, state.astype(f32), xs)
-        out = jnp.moveaxis(out, 0, 2).reshape(b, h, n * c, dv)[:, :, :length]
-        return jnp.moveaxis(out, 1, 2), state
+# the most a float32 exponent may be asked for inside a diagonal block
+# (``e^{88.7}`` is float32's largest): what a gate's floor times SUB may
+# not pass (``models/spec.py model_spec`` holds a spec to it)
+BLOCK_EXPONENT_MAX = 85.0
+
+
+def _channel_scan(q, k, v, log_alpha, beta, state, chunk):
+    """:func:`chunked_scan` under a decay a key channel: the same chunks,
+    the same triangular solve and sequential part, the decays factored a
+    block of :data:`SUB` positions at a time."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("seldon.delta.scan"):
+        f32 = jnp.float32
+        hi = jax.lax.Precision.HIGHEST
+        b, length, h, dk = q.shape
+        dv = v.shape[-1]
+        c, sub, pad, n = _chunks(length, chunk)
+        nb = c // sub
+
+        def lay(x):
+            return _lay(x, n, c, pad)
+
+        q, k, v = lay(q), lay(k), lay(v)                       # (B, H, N, C, d)
+        beta = lay(beta)                                       # (B, H, N, C)
+        # running sums a BLOCK at a time, and every difference of two of
+        # them as a sum of its own non-positive parts: a difference of two
+        # sums near -320 would carry their rounding (3e-5) into a decay
+        # that is not small
+        gb = jnp.cumsum(lay(log_alpha).reshape(b, h, n, nb, sub, dk), axis=-2)
+        lead = gb.shape[:-3]
+        tot = gb[..., -1, :]                                   # (B, H, N, nb, d_k)
+        at = jnp.arange(nb)
+        # r_j, the sum as block j starts; what lies after block m; and
+        # what lies between block m's end and block j's start
+        ref = jnp.einsum("...ld,jl->...jd", tot,
+                         (at[None, :] < at[:, None]).astype(f32))
+        after = jnp.einsum("...ld,ml->...md", tot,
+                           (at[None, :] > at[:, None]).astype(f32))
+        between = jnp.einsum(
+            "...ld,jml->...jmd", tot,
+            ((at[None, None, :] > at[None, :, None])
+             & (at[None, None, :] < at[:, None, None])).astype(f32))
+        g = (ref[..., :, None, :] + gb).reshape(*lead, c, dk)  # from the chunk's start
+        to_block_end = tot[..., :, None, :] - gb               # (..., nb, sub, d_k)
+        # a block's rows carry e^{g_t - g_mid}, mid the block's middle
+        # position, and the columns e^{g_mid - g_i}: within a block both
+        # exponents stay inside +-(sub / 2) x floor — a factor taken from the
+        # block's START would reach e^{-80} at its last row and flush a
+        # small component of k to zero where its partner's e^{+80} makes
+        # the pair matter — and a column before the block, e^{g_mid - g_i},
+        # is never positive; nothing after the block (those pairs are
+        # above the diagonal)
+        mid = gb[..., (sub - 1) // 2, :]                       # (..., nb, d_k)
+        within = jnp.exp(gb - mid[..., None, :])
+        k_rows = k.reshape(*lead, nb, sub, dk) * within
+        q_rows = q.reshape(*lead, nb, sub, dk) * within
+        before = at[None, :] < at[:, None]                     # [j, m]: m < j
+        cols = mid[..., :, None, None, :] + jnp.where(
+            before[:, :, None, None],
+            to_block_end[..., None, :, :, :] + between[..., :, :, None, :],
+            jnp.where(jnp.eye(nb, dtype=bool)[:, :, None, None],
+                      -gb[..., None, :, :, :], -jnp.inf))      # (..., nb, nb, sub, d_k)
+        k_cols = k[..., None, :, :] * jnp.exp(cols.reshape(*lead, nb, c, dk))
+        lower = jnp.tril(jnp.ones((c, c), bool))
+        strict = jnp.tril(jnp.ones((c, c), bool), -1)
+        kk = jnp.einsum("...jsd,...jid->...jsi", k_rows, k_cols,
+                        precision=hi).reshape(*lead, c, c)
+        a = jnp.where(strict, beta[..., :, None] * kk, 0.0)
+        e_g = jnp.exp(g)
+        rhs = jnp.concatenate(
+            [beta[..., None] * v, beta[..., None] * e_g * k], axis=-1)
+        solved = _solve_unit_lower(a, rhs, sub)
+        u0, w = solved[..., :dv], solved[..., dv:]
+        qk = jnp.where(lower, jnp.einsum(
+            "...jsd,...jid->...jsi", q_rows, k_cols,
+            precision=hi).reshape(*lead, c, c), 0.0)
+        q_in = q * e_g
+        k_out = k * jnp.exp(
+            to_block_end + after[..., :, None, :]).reshape(*lead, c, dk)
+        carry = jnp.exp(tot.sum(axis=-2))[..., None]           # (B, H, N, d_k, 1)
+        return _carry_chunks(state, (u0, w, qk, q_in, k_out, carry),
+                             (b, h, dk, dv), length)
 
 
 def recurrence(q, k, v, log_alpha, beta, *, state=None):
@@ -455,7 +631,9 @@ def recurrence(q, k, v, log_alpha, beta, *, state=None):
 
     def one(s, xs):
         q_t, k_t, v_t, la_t, b_t = xs                           # (B, H, ...)
-        s = jnp.exp(la_t)[..., None, None] * s
+        # (a decay a head scales the whole state, one a key channel its rows)
+        s = (jnp.exp(la_t)[..., None] if la_t.ndim == 3
+             else jnp.exp(la_t)[..., None, None]) * s
         read = jnp.einsum("bhkv,bhk->bhv", s, k_t, precision=hi)
         u = b_t[..., None] * (v_t - read)
         s = s + k_t[..., :, None] * u[..., None, :]

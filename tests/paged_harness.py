@@ -26,7 +26,8 @@ from seldon_core_tpu.ops import kernels
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
 from reference import (  # noqa: E402
-    deepseek_v3, dots3_note, longcat_flash, olmo_hybrid, olmoe, smallthinker, xing4)
+    deepseek_v3, dots3_note, ling3_flash, longcat_flash, olmo_hybrid, olmoe,
+    smallthinker, xing4)
 
 PAGE, MAX_LEN, SLOTS = 8, 64, 4
 PROMPT = np.random.default_rng(5).integers(0, 97, size=29).tolist()
@@ -123,6 +124,23 @@ MODELS = {
         linear_num_key_heads=4, linear_num_value_heads=4, linear_key_head_dim=8,
         linear_value_head_dim=64, linear_conv_kernel_dim=4,
         linear_allow_neg_eigval=True)),
+    # Ling-3.0-flash: d 64, 4 heads of 16 (KDA: 16 x 16 a head, one decay a
+    # key channel; MLA: latent 16 + 4 rope, heads of 8 + 4 against 8, no q
+    # bottleneck); KDA x 2, MLA, twice; a dense first layer of 96, then 32
+    # sigmoid-routed experts of 32 in 4 groups (2 kept), top-4, of which
+    # the replica holds 8
+    "ling3": (ling3_flash, dict(
+        model_type="bailing_hybrid", vocab_size=97, hidden_size=64,
+        intermediate_size=96, num_hidden_layers=6, num_attention_heads=4,
+        head_dim=16, layer_group_size=3, rms_norm_eps=1e-6,
+        kv_lora_rank=16, q_lora_rank=None, qk_nope_head_dim=8,
+        qk_rope_head_dim=4, v_head_dim=8, rope_theta=6000000,
+        short_conv_kernel_size=4, kda_lower_bound=-5, kda_safe_gate=True,
+        num_kv_heads_for_linear_attn=0, first_k_dense_replace=1,
+        num_experts=8, num_experts_published=32, expert_offset=8,
+        num_experts_per_tok=4, moe_intermediate_size=32, num_shared_experts=1,
+        n_group=4, topk_group=2, routed_scaling_factor=2.5, norm_topk_prob=True,
+        expert_swiglu_limit_list=[0] * 6, share_expert_swiglu_limit_list=[0] * 6)),
 }
 
 

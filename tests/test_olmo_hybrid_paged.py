@@ -312,3 +312,48 @@ def test_containers_are_refused_by_name():
         assert not eng._prefix_cache_enabled  # unset: off, whatever the env's default
     finally:
         eng.close()
+
+
+# ---------------------------------------------------------------------------
+# a second variant of the linear layer came (PR 52): this one lowers as it did
+# ---------------------------------------------------------------------------
+
+# sha256 of each program's lowered text (prefill_b16_k2, chunk_s2_4x4,
+# chunk_s2_2x2_2x8) on the tree before Ling-3.0-flash (commit ad8e2de), by
+# lane: the decay a key channel, the bounded gate, the head-wise output gate,
+# the routed FFN inside a linear layer and the latent pool beside the state
+# are facts of a call's structure, and a spec that has none of them traces
+# what it traced.  A PR that changes these programs on purpose measures the
+# Olmo-Hybrid cell and replaces the lines.
+PARENT_SHA = {
+    "kernel": ("7872a205e5159aac", "626501788f86f93d", "5f3c82525baa2bb0"),
+    "gather": ("7872a205e5159aac", "6c842a92ec4ec155", "d78e842ae58957de"),
+}
+
+
+@pytest.mark.parametrize("lane", sorted(PARENT_SHA))
+def test_the_programs_lower_as_before_a_second_variant_came(monkeypatch, lane):
+    import hashlib
+
+    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", {"kernel": "force", "gather": "0"}[lane])
+    monkeypatch.delenv("SELDON_TPU_CHUNK_IMPL", raising=False)
+    sizes = dict(vocab_size=64, d_model=32, num_layers=4, num_heads=4)
+    spec = model_spec("olmo_hybrid", kv_heads=4, head_dim=8, dense_width=48,
+                      layer_kinds=("linear", "linear", "linear", "full"), lin_heads=4,
+                      lin_key_dim=8, lin_value_dim=64, lin_conv=4)
+    params = init_params(spec, sizes, 1, dtype=jnp.bfloat16)
+    eng = PagedEngine(params, **sizes, max_len=64, page_size=4, max_slots=4,
+                      steps_per_call=2, dtype=jnp.bfloat16, spec=spec)
+    try:
+        i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+        unwrap = lambda fn: fn if hasattr(fn, "lower") else fn.__wrapped__  # noqa: E731
+        texts = (
+            unwrap(eng._build_prefill(16, 2)).lower(
+                eng.params, *eng._kv_args(), i32(2, 16), i32(2), i32(2, 4),
+                slots=i32(2)).as_text(),
+            eng.lower_chunk(2, ((4, 4),)).as_text(),
+            eng.lower_chunk(2, ((2, 2), (2, 8))).as_text(),
+        )
+    finally:
+        eng.close()
+    assert tuple(hashlib.sha256(t.encode()).hexdigest()[:16] for t in texts) == PARENT_SHA[lane]

@@ -891,3 +891,104 @@ def test_olmo_hybrid_s_programs_compile_and_the_prefill_cap_s_count_holds(
     assert decode.as_text().count("tpu_custom_call") >= 8
     assert decode.memory_analysis().temp_size_in_bytes < 1 << 30
     assert first.as_text().count("tpu_custom_call") >= 2  # the fused causal kernel
+
+
+def test_the_state_step_kernel_compiles_under_a_channel_decay(monkeypatch, one_chip, mosaic):
+    """``ops/delta.py delta_state_step`` at Ling-3.0-flash's shape — every
+    slot's state of one KDA layer, (128, 32, 128, 128) float32, the decay a
+    key channel riding with the rows ``(128, 32, 3, 128)``: one Mosaic call,
+    the state rewritten where it rests (aliased: no second 268 MB)."""
+    from seldon_core_tpu.ops import delta
+
+    monkeypatch.setattr(delta, "backend", lambda: "tpu")
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    slots, heads, dk, dv = 128, 32, 128, 128
+    state = shape(delta.state_shape(slots, heads, dk, dv))
+    assert state.shape == (128, 32, 128, 128) and delta.pack_of(heads, dv) == 1
+    step = jax.jit(
+        lambda s, q, k, v, la, b, on: delta.step(s, q, k, v, la, b, active=on),
+        donate_argnums=0,
+    ).lower(state, shape((slots, heads, dk)), shape((slots, heads, dk)),
+            shape((slots, heads, dv)), shape((slots, heads, dk)), shape((slots, heads)),
+            shape((slots,), jnp.bool_)).compile()
+    assert step.as_text().count("tpu_custom_call") == 1
+    memory = step.memory_analysis()
+    assert memory.alias_size_in_bytes >= 128 * 32 * 128 * 128 * 4
+    assert memory.temp_size_in_bytes < 64 << 20
+
+
+def test_ling3_s_programs_compile_and_the_prefill_cap_s_count_holds(
+        monkeypatch, one_chip, mosaic):
+    """The whole LM at the configuration's sizes (abstract weights: 3.74 GB
+    as they rest): a ``b2048_k1`` prefill from zero (the block-wise chunked
+    scan in ten layers, the fused causal kernel in two, a held pass in
+    eleven) and a 128-lane decode step over the state a lane and the ONE
+    latent pool (2, 12289, 64, 640).  Both compile; the decode step runs
+    the state kernel in every KDA layer, the latent page loop in every MLA
+    layer and two grouped matmuls a routed layer (34 Mosaic calls: 10 + 2 +
+    22) in under 0.1 GiB of temporaries; the prefill's temporaries are
+    within a factor of two of ``prefill_position_bytes``'s count (0.85 GiB
+    against the compiler's 0.86: the decay a channel is priced)."""
+    import json
+
+    from seldon_core_tpu.models import paged
+    from seldon_core_tpu.models.spec import declared_tree, model_spec
+    from seldon_core_tpu.ops import delta, moe
+
+    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
+    monkeypatch.setattr(delta, "backend", lambda: "tpu")
+    monkeypatch.setattr(moe, "matmul_backend", lambda: "tpu")
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "configs",
+                           "ling-3.0-flash.json")) as f:
+        cfg = json.load(f)
+    served = {p["name"]: p["value"]
+              for p in cfg["deployment"]["predictors"][0]["graph"]["parameters"]}
+    spec = model_spec(served["arch"], **json.loads(served["arch_sizes"]))
+    sizes = dict(vocab_size=int(served["vocab_size"]), d_model=int(served["d_model"]),
+                 num_layers=int(served["num_layers"]), num_heads=int(served["num_heads"]))
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    tree = declared_tree(spec, dict(sizes, max_len=int(served["max_len"])), jnp.bfloat16)
+    resting = sum(leaf.size * leaf.dtype.itemsize
+                  for leaf in jax.tree_util.tree_leaves(tree))
+    assert abs(resting - 3.74e9) < 0.01e9
+    params = jax.tree_util.tree_map(lambda leaf: shape(leaf.shape, leaf.dtype), tree)
+    lm = paged.get_paged_lm_class()(dtype=jnp.bfloat16, spec=spec, decode_kernel=True,
+                                    max_len=int(served["max_len"]), **sizes)
+    pool = shape((2, int(served["num_pages"]), 64, 640), jnp.bfloat16)
+    i32, slots = jnp.int32, int(served["max_slots"])
+
+    def prefill(params, tokens, positions, pk, tables, lengths, true_lens):
+        real = jnp.arange(tokens.shape[1])[None, :] < true_lens[:, None]
+        return lm.apply({"params": params}, tokens, positions, pk, None, tables, lengths,
+                        delta={"true_lens": true_lens}, last=true_lens - 1,
+                        token_mask=real)
+
+    def step(params, tokens, positions, pk, tables, lengths, state, conv, active):
+        return lm.apply({"params": params}, tokens, positions, pk, None, tables, lengths,
+                        delta={"state": state, "conv": conv, "active": active},
+                        token_mask=active[:, None])
+
+    def common(batch, seg, width):
+        return (params, shape((batch, seg), i32), shape((batch, seg), i32), pool,
+                shape((batch, width), i32), shape((batch,), i32))
+
+    opts = dict(compiler_options=paged.TPU_COMPILER_OPTIONS)
+    state = tuple(shape(delta.state_shape(slots, 32, 128, 128), jnp.float32)
+                  for _ in range(10))
+    conv = tuple(shape((slots, 3, 12_288), jnp.bfloat16) for _ in range(10))
+    decode = jax.jit(step, donate_argnums=(6, 7)).lower(
+        *common(slots, 1, 32), state, conv, shape((slots,), jnp.bool_)).compile(**opts)
+    assert decode.as_text().count("tpu_custom_call") == 10 + 2 + 2 * 11
+    assert decode.memory_analysis().temp_size_in_bytes < 1 << 28
+    first = jax.jit(prefill).lower(*common(1, 2048, 0), shape((1,), i32)).compile(**opts)
+    memory = first.memory_analysis()
+    counted = 2048 * paged.prefill_position_bytes(spec, 2560, 19_648, 32)
+    by_compiler = memory.temp_size_in_bytes + memory.output_size_in_bytes
+    assert 1 / 2 < counted / by_compiler < 2, (counted, by_compiler)
+    assert first.as_text().count("tpu_custom_call") >= 2 + 2 * 11

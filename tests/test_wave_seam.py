@@ -1265,32 +1265,33 @@ class TestTheDevicesIdleTimeServed:
 
         from seldon_core_tpu.utils import jitwatch
 
-        def watchers():
-            return [t for t in threading.enumerate() if t.name == "seldon-device-clock"]
-
         def listeners():
             return [cb for cb in monitoring.get_event_duration_listeners()
                     if cb is jitwatch._on_duration]
 
-        before = len(watchers())
+        # (its OWN engine's watcher, by identity: the process's other
+        # ``seldon-device-clock`` threads — an earlier test's engine whose
+        # watcher ends while this one counts — are not this test's)
         lm = _streaming_lm()
         try:
             s = lm.engine.submit(_prompt(5, 1), max_new_tokens=4)
             lm._wake.set()
             assert s.event.wait(120)
-            assert len(watchers()) == before + 1
+            watcher = lm.engine._seam.device._thread
+            assert watcher in threading.enumerate() and watcher.name == "seldon-device-clock"
             assert len(listeners()) == 1
             thread = lm._loop_thread
         finally:
             lm.shutdown()
         thread.join(30)
-        assert len(watchers()) == before
+        watcher.join(30)
+        assert watcher not in threading.enumerate()
         eng = _tiny_engine()   # a second engine of the process
         try:
             assert len(listeners()) == 1
             # an engine that never dispatched has no thread, and closing
             # one twice is nothing
-            assert len(watchers()) == before
+            assert eng._seam.device._thread is None
         finally:
             eng.close()
             eng.close()
