@@ -4022,9 +4022,14 @@ class PagedEngine:
         self._delta_state: Tuple[Any, ...] = ()
         self._delta_conv: Tuple[Any, ...] = ()
         self._delta_layers = spec.state_layers(num_layers)
+        # linear layers whose prefill scan the kernel serves (all or none:
+        # ops/delta.py scan_impl's rule is the head's key width)
+        self._delta_scan_kernel_layers = 0
         if spec.linear:
             from seldon_core_tpu.ops import delta as _delta
 
+            if _delta.scan_impl(spec.lin_key_dim) == "pallas":
+                self._delta_scan_kernel_layers = self._delta_layers
             shape = _delta.state_shape(
                 self.max_slots, spec.lin_heads, spec.lin_key_dim,
                 spec.lin_value_dim)
@@ -4403,10 +4408,14 @@ class PagedEngine:
                           # ops/delta.py; 0 otherwise): lane-steps x
                           # linear layers the decode steps ran, padded
                           # positions x linear layers the prefill calls
-                          # scanned, and the real ones among them
+                          # scanned, the real ones among them, and the
+                          # padded ones the scan's kernel served
+                          # (ops/delta.py scan_impl: the rest took XLA's
+                          # form)
                           "delta_lane_steps": 0,
                           "delta_prefill_positions": 0,
                           "delta_prefill_real_positions": 0,
+                          "delta_scan_kernel_positions": 0,
                           # a spec with layer kinds (0 otherwise): what
                           # its selection and its windows read (the
                           # chunk's counter row, _sparse_step) and the
@@ -7432,6 +7441,8 @@ class PagedEngine:
                     k * bucket * self._delta_layers)
                 self._counters["delta_prefill_real_positions"] += (
                     tokens * self._delta_layers)
+                self._counters["delta_scan_kernel_positions"] += (
+                    k * bucket * self._delta_scan_kernel_layers)
             return self._prefill_group_call(bucket, k, group, use_cache)
         finally:
             self._seam.end_prefill()
@@ -8712,7 +8723,7 @@ class PagedEngine:
                 "delta_state_shape": list(self._delta_state[0].shape),
                 "delta_step": _delta.step_impl(
                     *self._delta_state[0].shape[2:]),
-                "delta_scan": _delta.scan_impl(),
+                "delta_scan": _delta.scan_impl(self.spec.lin_key_dim),
                 # the variant: one decay a head | a key channel, and the
                 # bounded gate's floor (0: the softplus gate)
                 "delta_gate": self.spec.lin_gate,

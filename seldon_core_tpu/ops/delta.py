@@ -20,7 +20,13 @@ With ``q_t`` and ``k_t`` a head's normalised query and key (``d_k``),
   in chunks of :data:`CHUNK` positions (the WY form: within a chunk the
   ``u`` solve a unit lower-triangular system that does not involve the
   state's rows, so a chunk is a handful of ``(64, d_k) x (d_k, d_v)``
-  matmuls and the state is carried chunk to chunk).
+  matmuls and the state is carried chunk to chunk).  Two forms of one
+  arithmetic (:func:`scan_impl`): **on a TPU one Pallas kernel**,
+  ``delta_chunk_scan`` (:func:`_scan_pallas`) — the state resident in
+  VMEM from a prompt's first chunk to its last, a chunk's decays, its
+  ``(64, 64)`` matrices and their inverse never in HBM; **XLA's form**
+  on the CPU, under a chunk that is not :data:`CHUNK` and at a key width
+  that is not whole sublanes.
 * :func:`conv` / :func:`conv_step` — the causal depthwise convolution of
   :data:`TAPS` taps and SiLU that q, k and v pass first, and the last
   ``TAPS - 1`` inputs a lane keeps for it.
@@ -51,6 +57,8 @@ then the packed form is the plain one.
 """
 
 from __future__ import annotations
+
+import functools
 
 CHUNK = 64      # positions a chunk of the prefill's scan
 SUB = 16        # rows a diagonal block of a chunk's triangular system
@@ -111,9 +119,19 @@ def step_impl(key_dim: int, lanes: int, where=None) -> str:
             and key_dim % 8 == 0 and lanes % 128 == 0 else "xla")
 
 
-def scan_impl() -> str:
-    """... and a prefill's chunked scan (``lane_report()["delta_scan"]``)."""
-    return "xla"
+def scan_impl(key_dim: int, chunk: int = CHUNK, where=None) -> str:
+    """Which form a prefill's chunked scan takes
+    (``lane_report()["delta_scan"]``): ``"pallas"`` on a TPU (or the
+    interpreter where a test asks for it) where a chunk is whole tiles —
+    :data:`CHUNK` positions of ``d_k % 8 == 0`` — else ``"xla"``; the CPU
+    always traces XLA's form.  The kernel ``delta_chunk_scan`` pads a
+    segment to whole chunks itself (the pad rule), so every prefill
+    bucket takes it.  XLA's form ran at 2-4 % of the scan's roofline
+    (ledger, PR 52): hundreds of small float32 fusions, every
+    intermediate through HBM, the state read and written a chunk."""
+    where = backend() if where is None else where
+    return ("pallas" if where in ("tpu", "interpret") and chunk == CHUNK
+            and key_dim % 8 == 0 else "xla")
 
 
 # ---------------------------------------------------------------------------
@@ -494,6 +512,12 @@ def chunked_scan(q, k, v, log_alpha, beta, *, state=None, chunk: int = CHUNK):
     import jax
     import jax.numpy as jnp
 
+    where = backend()
+    if scan_impl(q.shape[-1], chunk, where) == "pallas":
+        with jax.named_scope("seldon.delta.scan"):
+            return _scan_jit()(q, k, v, log_alpha, beta,
+                               *(() if state is None else (state,)),
+                               interpret=where != "tpu")
     if log_alpha.ndim == q.ndim:
         return _channel_scan(q, k, v, log_alpha, beta, state, chunk)
     with jax.named_scope("seldon.delta.scan"):
@@ -613,6 +637,301 @@ def _channel_scan(q, k, v, log_alpha, beta, state, chunk):
         carry = jnp.exp(tot.sum(axis=-2))[..., None]           # (B, H, N, d_k, 1)
         return _carry_chunks(state, (u0, w, qk, q_in, k_out, carry),
                              (b, h, dk, dv), length)
+
+
+# ---------------------------------------------------------------------------
+# a prefill: the chunked scan as one kernel
+# ---------------------------------------------------------------------------
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    """A float32 product at the precision XLA's form uses: Mosaic takes
+    HIGHEST for ``contract_precision<fp32>`` and anything unsaid for its
+    own default, which is not float32's."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.lax.dot_general(a, b, dims, precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b^T
+_TN = (((0,), (0,)), ((), ()))   # a^T @ b
+_NEVER = -1e30                   # an exponent of a pair above the diagonal
+
+
+def _iotas(shape):
+    import jax
+    import jax.numpy as jnp
+
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, 0),
+            jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+
+
+def _inverse_masks(c: int, side: int):
+    """What :func:`_unit_lower_inverses_side_by_side` selects by, made once
+    a grid step: the identity, the pairs of rows, each doubling's blocks
+    below the diagonal, and the weights' block diagonal."""
+    import jax.numpy as jnp
+
+    row, col = _iotas((c, side * c))
+    col = col & (c - 1)                      # the column within its matrix
+    below, s = [], 1
+    while (2 << s) <= c:                     # blocks of 2^s rows to 2^(s+1)
+        below.append(((row >> (s + 1)) == (col >> (s + 1)))
+                     & ((row >> s) != (col >> s)))
+        s += 1
+    same = None
+    if side > 1:
+        wr, wc = _iotas((side * c, side * c))
+        same = (wr // c) == (wc // c)
+    return (jnp.where(row == col, 1.0, 0.0), (row >> 1) == (col >> 1), below, same)
+
+
+def _unit_lower_inverses_side_by_side(packs, masks):
+    """``(I + A_p)^-1`` for strictly lower triangular ``(C, C)`` matrices
+    side by side in the lanes, each of ``packs`` ``(C, side x C)``: two by
+    two the inverse is ``I - A`` exactly, and a block of ``2s`` rows
+    follows from its two of ``s`` — ``[[P, 0], [R, Q]]^-1 = [[P^-1, 0],
+    [-Q^-1 R P^-1, Q^-1]]`` — five doublings to 64, each two products on
+    the matmul unit with no cancellation the inverse itself does not have
+    (forward substitution's accuracy).  Side by side, a product's right
+    operand is the matrices down a block diagonal: one ``(side x
+    C)``-square weight load serves every matrix where one of ``C`` would
+    fill a quarter of the unit.  The ten products of one pack wait on one
+    another; the packs are walked level by level so that one's products
+    fill another's wait."""
+    import jax.numpy as jnp
+
+    eye, pairs, below, same = masks
+
+    def mm(x, y):
+        if same is not None:
+            y = jnp.where(same, jnp.concatenate([y] * (y.shape[1] // y.shape[0]),
+                                                axis=0), 0.0)
+        return _dot(x, y)
+
+    ts = [eye - jnp.where(pairs, a, 0.0) for a in packs]
+    for level in below:
+        firsts = [mm(t, jnp.where(level, a, 0.0)) for t, a in zip(ts, packs)]
+        ts = [t - mm(first, t) for first, t in zip(firsts, ts)]
+    return ts
+
+
+def _scalar_chunk(q, k, la_row, beta_col, masks):
+    """A chunk's matrices under a decay a head: ``la_row`` ``(1, C)`` the
+    chunk's ``log alpha``.  ``(A, M . Q K^T, e^g (rows), e^{g_C - g}
+    (rows), e^{g_C})``."""
+    import jax.numpy as jnp
+
+    lower, strict, eye = masks[:3]
+    c = q.shape[0]
+    # the running sum down the rows and, picked off the diagonal, along
+    # the lanes: their difference is exactly zero where t = i
+    g_col = jnp.sum(jnp.where(lower, la_row, 0.0), axis=1, keepdims=True)
+    g_row = jnp.sum(jnp.where(eye, g_col, 0.0), axis=0, keepdims=True)
+    decay = jnp.exp(jnp.where(lower, g_col - g_row, _NEVER))   # e^{g_t - g_i}, i <= t
+    kq = _dot(jnp.concatenate([k, q], axis=0), k, _NT)          # (2C, C)
+    a = jnp.where(strict, beta_col * decay * kq[:c], 0.0)
+    qk = decay * kq[c:]
+    g_end = g_col[c - 1:c]
+    return a, qk, jnp.exp(g_col), jnp.exp(g_end - g_col), jnp.exp(g_end)
+
+
+def _channel_chunk(q, k, la, beta_col, masks):
+    """A chunk's matrices under a decay a key channel, ``la`` ``(C, d_k)``:
+    :func:`_channel_scan`'s factoring — running sums a block of
+    :data:`SUB` positions, every exponent a sum of its own non-positive
+    parts, a block's rows about its middle position."""
+    import jax.numpy as jnp
+
+    lower, strict, _eye, block_lower, channels = masks
+    c, dk = k.shape
+    nb = c // SUB
+    gb = _dot(block_lower, la)                                  # sums from a block's start
+    tot = [gb[j * SUB + SUB - 1:(j + 1) * SUB] for j in range(nb)]
+    mid = [gb[j * SUB + (SUB - 1) // 2:j * SUB + (SUB - 1) // 2 + 1]
+           for j in range(nb)]
+
+    def span(lo, hi):  # what lies in blocks lo .. hi - 1
+        out = jnp.zeros_like(tot[0])
+        for at in range(lo, hi):
+            out = out + tot[at]
+        return out
+
+    def spread(parts):  # a row a block -> (C, d_k)
+        return jnp.concatenate(
+            [jnp.broadcast_to(part, (SUB, dk)) for part in parts], axis=0)
+
+    within = jnp.exp(gb - spread(mid))
+    to_block_end = spread(tot) - gb
+    k_rows, q_rows = k * within, q * within
+    kk, qk = [], []
+    for j in range(nb):
+        # block j's rows against the columns up to its end: e^{g_mid - g_i}
+        parts = [mid[j] + (to_block_end[m * SUB:(m + 1) * SUB] + span(m + 1, j))
+                 for m in range(j)]
+        parts.append(mid[j] - gb[j * SUB:(j + 1) * SUB])
+        hi = (j + 1) * SUB
+        k_cols = k[:hi] * jnp.exp(jnp.concatenate(parts, axis=0))
+        if hi < c:
+            k_cols = jnp.concatenate(
+                [k_cols, jnp.zeros((c - hi, dk), jnp.float32)], axis=0)
+        kq = _dot(jnp.concatenate([k_rows[j * SUB:hi], q_rows[j * SUB:hi]], axis=0),
+                  k_cols, _NT)                                   # (2 SUB, C)
+        kk.append(kq[:SUB])
+        qk.append(kq[SUB:])
+    a = jnp.where(strict, beta_col * jnp.concatenate(kk, axis=0), 0.0)
+    qk = jnp.where(lower, jnp.concatenate(qk, axis=0), 0.0)
+    e_g = jnp.exp(spread([span(0, j) for j in range(nb)]) + gb)
+    k_out = jnp.exp(to_block_end + spread([span(j + 1, nb) for j in range(nb)]))
+    # the carry scales the state's ROWS: the channels down the sublanes
+    carry = jnp.sum(jnp.where(channels, jnp.exp(span(0, nb)), 0.0),
+                    axis=1, keepdims=True)                       # (d_k, 1)
+    return a, qk, e_g, k_out, carry
+
+
+def _scan_kernel(*refs, channel: bool, resume: bool, side: int):
+    """One chunk of ``heads`` heads of one prompt: ``q_ref`` / ``k_ref``
+    ``(1, heads, C, d_k)``, ``v_ref`` / ``o_ref`` ``(1, heads, C, d_v)``,
+    ``beta_ref`` ``(1, 1, 1, heads, C)`` (a chunk's gates along the lanes),
+    ``la_ref`` like it (a decay a head) or like ``k_ref`` (a decay a key
+    channel), ``s_ref`` ``(1, heads, d_k, d_v)`` the state: its block does
+    not move along the chunk axis, so it stays in VMEM from the first
+    chunk (zeroed, or loaded from ``s0_ref``) to the last, after which
+    the pipeline writes it out once.
+
+    A chunk is :func:`chunked_scan`'s: ``A`` and ``M . Q K^T`` from the
+    decays, ``T = (I + A)^-1`` for ``side`` heads at a time, then against
+    the resident ``S``: ``U = T (beta V - beta e^g K S)``, ``O = e^g Q S +
+    (M . Q K^T) U``, ``S = e^{g_C} S + (e^{g_C - g} K)^T U`` — ``beta e^g
+    K`` and ``e^g Q`` stacked, so one load of ``S`` serves 128 rows.  The
+    heads are walked in straight-line code, every index static: the
+    scheduler lays one head's products over another's waits."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    if resume:
+        q_ref, k_ref, v_ref, la_ref, beta_ref, s0_ref, o_ref, s_ref = refs
+    else:
+        q_ref, k_ref, v_ref, la_ref, beta_ref, o_ref, s_ref = refs
+    heads, c, dk = q_ref.shape[1:]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = s0_ref[...] if resume else jnp.zeros_like(s_ref)
+
+    row, col = _iotas((c, c))
+    masks = (row >= col, row > col, row == col)
+    if channel:
+        drow, dcol = _iotas((dk, dk))
+        masks += (jnp.where(masks[0] & ((row // SUB) == (col // SUB)), 1.0, 0.0),
+                  drow == dcol)
+    made = []
+    for h in range(heads):
+        q, k = q_ref[0, h], k_ref[0, h]
+        beta_col = jnp.sum(jnp.where(masks[2], beta_ref[0, 0, 0, h:h + 1, :], 0.0),
+                           axis=1, keepdims=True)                # (C, 1)
+        if channel:
+            pieces = _channel_chunk(q, k, la_ref[0, h], beta_col, masks)
+        else:
+            pieces = _scalar_chunk(q, k, la_ref[0, 0, 0, h:h + 1, :], beta_col, masks)
+        made.append((q, k, beta_col) + pieces)
+    inverses = _unit_lower_inverses_side_by_side(
+        [jnp.concatenate([m[3] for m in made[at:at + side]], axis=1)
+         for at in range(0, heads, side)], _inverse_masks(c, side))
+    for h, (q, k, beta_col, _a, qk, e_g, k_out, s_carry) in enumerate(made):
+        s = s_ref[0, h]
+        from_s = _dot(jnp.concatenate([beta_col * e_g * k, e_g * q], axis=0), s)
+        at = (h % side) * c
+        u = _dot(inverses[h // side][:, at:at + c],
+                 beta_col * v_ref[0, h] - from_s[:c])
+        o_ref[0, h] = from_s[c:] + _dot(qk, u)
+        s_ref[0, h] = s_carry * s + _dot(k_out * k, u, _TN)
+
+
+def _heads_a_step(heads: int, side: int) -> int:
+    """Heads a grid step of the scan's kernel: the most up to 6 that
+    divide ``heads`` in whole ``side``s (6 of 30, 4 of 32).  Two packs'
+    chains already fill each other's waits (4 of 32 reads 1.56 ms a
+    2,048-position call where 8 reads 1.50 and 2 reads 2.01: my chip
+    runs, PR 53) and the body is straight-line code: every head more is
+    that much more to trace, compile and load in every prefill program."""
+    return max(n for n in range(side, 7, side) if heads % n == 0)
+
+
+@functools.lru_cache(maxsize=None)
+def _scan_jit():
+    """:func:`_scan_pallas` under a jit of its own: a program's linear
+    layers call it at the same shapes and share ONE trace and ONE lowering
+    of the kernel's straight-line body, where each call would cost 0.14 s
+    of set-up before any compile cache is asked (six layers in each of
+    six prefill programs in the Olmo-Hybrid cell; XLA inlines the call)."""
+    import jax
+
+    return jax.jit(_scan_pallas, static_argnames=("interpret", "heads_step", "side"))
+
+
+def _scan_pallas(q, k, v, log_alpha, beta, *state, interpret,
+                 heads_step=None, side=None):
+    """:func:`chunked_scan` as one kernel call, ``delta_chunk_scan``: a
+    grid of (prompt, block of heads, chunk), the chunk axis sequential.
+    HBM sees q, k, v and the gates once on the way in — laid ``(B, H, L,
+    d)``, a head's chunk a ``(64, d)`` tile —, the output once on the way
+    out and the final state once (``state``: none, or the one ``(B, H, d_k,
+    d_v)`` to continue from).  The output is the call's FIRST result,
+    ``(B, H, L, d_v)``: a device trace names the call by it, which is how
+    the benchmark's readers find the scan (``layer_metrics/delta_work.py
+    is_scan``: four dims whose second is H)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    b, length, h, dk = q.shape
+    dv = v.shape[-1]
+    c = CHUNK
+    pad = -length % c
+    n = (length + pad) // c
+    channel = log_alpha.ndim == q.ndim
+    side = side or (2 if h % 2 == 0 else 1)
+    hb = heads_step or _heads_a_step(h, side)
+    groups = h // hb
+
+    def rows(x):    # (B, L, H, d) -> (B, H, N x C, d): a head's chunk a tile
+        x = jnp.pad(x.astype(f32), [(0, 0), (0, pad), (0, 0), (0, 0)])
+        return jnp.moveaxis(x, 2, 1)
+
+    def lanes(x):   # (B, L, H) -> (B, groups, N, hb, C): a chunk's gates a row a head
+        x = jnp.pad(x.astype(f32), [(0, 0), (0, pad), (0, 0)])
+        return x.reshape(b, n, c, groups, hb).transpose(0, 3, 1, 4, 2)
+
+    def tile(d):
+        return pl.BlockSpec((1, hb, c, d), lambda i, g, j: (i, g, j, 0))
+
+    gate = pl.BlockSpec((1, 1, 1, hb, c), lambda i, g, j: (i, g, j, 0, 0))
+    rests = pl.BlockSpec((1, hb, dk, dv), lambda i, g, j: (i, g, 0, 0))
+    out, final = pl.pallas_call(
+        functools.partial(_scan_kernel, channel=channel, resume=len(state) == 1,
+                          side=side),
+        grid=(b, groups, n),
+        in_specs=[tile(dk), tile(dk), tile(dv), tile(dk) if channel else gate, gate]
+        + [rests] * len(state),
+        out_specs=[tile(dv), rests],
+        out_shape=[jax.ShapeDtypeStruct((b, h, n * c, dv), f32),
+                   jax.ShapeDtypeStruct((b, h, dk, dv), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # the blocks twice (2.6 MB at 8 heads of 128 x 128), the state
+            # and every head's matrices in straight-line code: well
+            # inside, and past the 16 MB a kernel gets unasked
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+        name="delta_chunk_scan",
+    )(rows(q), rows(k), rows(v),
+      rows(log_alpha) if channel else lanes(log_alpha), lanes(beta),
+      *(s0.astype(f32) for s0 in state))
+    return jnp.moveaxis(out[:, :, :length], 1, 2), final
 
 
 def recurrence(q, k, v, log_alpha, beta, *, state=None):

@@ -649,6 +649,10 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
         "counter", "seldon_tpu_engine_delta_prefill_real_positions_total",
         "real prompt positions x linear-attention layers the prefill calls "
         "scanned"),
+    "delta_scan_kernel_positions": (
+        "counter", "seldon_tpu_engine_delta_scan_kernel_positions_total",
+        "padded positions x linear-attention layers whose prefill scan ran "
+        "in the kernel delta_chunk_scan (the rest took XLA's form)"),
     "delta_state_bytes": (
         "gauge", "seldon_tpu_engine_delta_state_bytes",
         "bytes every slot's linear-attention state takes as it rests"),

@@ -8,6 +8,11 @@ outputs of order 1 (read: 1.3e-6 at 200 positions).  A state kept in
 bfloat16 moves the same outputs by 1e-2, beta without its factor 2 or a
 decay left at 1 by tenths: ``test_a_wrong_recurrence_is_not_within_the_tolerance``
 holds the three against the tolerance the sound form passes.
+
+The prefill's scan has two forms (``delta.scan_impl``): XLA's — the CPU's
+own answer — held to the recurrence, and the kernel ``delta_chunk_scan``
+under the Pallas interpreter (the ``form`` fixture's second case) held to
+float64 ``by_hand``, where neither form's own rounding hides the other's.
 """
 
 import numpy as np
@@ -54,6 +59,42 @@ def by_hand(q, k, v, log_alpha, beta):
     return out, state
 
 
+@pytest.fixture(params=["xla", "kernel"])
+def form(request, monkeypatch):
+    """XLA's form of the scan, or the kernel under the interpreter."""
+    if request.param == "kernel":
+        monkeypatch.setattr(delta, "backend", lambda: "interpret")
+    return request.param
+
+
+def scan(*args, **kw):
+    """``chunked_scan`` jitted under a function of its own: ``jax.jit`` of
+    the same function at the same shapes would hand one form's case the
+    trace the other left."""
+    return jax.jit(lambda *a: delta.chunked_scan(*a, **kw))(*args)
+
+
+def traced(*args):
+    """... and its jaxpr, under a function of its own for the same reason."""
+    return jax.make_jaxpr(lambda *a: delta.chunked_scan(*a))(*args)
+
+
+def kernel_calls(jaxpr):
+    """The ``pallas_call`` equations of a jaxpr, the scan's own jit looked
+    into (``delta._scan_jit``: a program's layers share one trace of it)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += kernel_calls(sub)
+    return found
+
+
+def truth(form, *args):
+    return by_hand(*args) if form == "kernel" else delta.recurrence(*args)
+
+
 def test_the_recurrence_is_the_equations():
     args = draw(23, seed=4)
     out, state = delta.recurrence(*args)
@@ -64,13 +105,13 @@ def test_the_recurrence_is_the_equations():
 
 
 @pytest.mark.parametrize("length", [5, 16, 40, 64, 100, 128, 200])
-def test_the_chunked_scan_is_the_recurrence(length):
+def test_the_chunked_scan_is_the_recurrence(form, length):
     """Lengths that are and are not multiples of the chunk (64) and of a
     diagonal block (16); beta over (0, 2)."""
     args = draw(length, seed=length)
     assert float(args[4].max()) > 1.0  # beta past 1: the negative eigenvalue's side
-    want_out, want_state = delta.recurrence(*args)
-    out, state = jax.jit(delta.chunked_scan)(*args)
+    want_out, want_state = truth(form, *args)
+    out, state = scan(*args)
     np.testing.assert_allclose(out, want_out, atol=TOL)
     np.testing.assert_allclose(state, want_state, atol=TOL)
     assert state.dtype == jnp.float32 and state.shape == (B, H, DK, DV)
@@ -86,30 +127,28 @@ def test_the_state_is_carried_chunk_to_chunk(chunk):
     np.testing.assert_allclose(state, want_state, atol=TOL)
 
 
-def test_a_scan_continues_from_a_state():
+def test_a_scan_continues_from_a_state(form):
     q, k, v, la, beta = draw(70, seed=2)
-    _out, first = delta.chunked_scan(q[:, :30], k[:, :30], v[:, :30],
-                                     la[:, :30], beta[:, :30])
-    out, state = delta.chunked_scan(q[:, 30:], k[:, 30:], v[:, 30:],
-                                    la[:, 30:], beta[:, 30:], state=first)
-    want_out, want_state = delta.recurrence(q, k, v, la, beta)
+    _out, first = scan(q[:, :30], k[:, :30], v[:, :30], la[:, :30], beta[:, :30])
+    out, state = jax.jit(lambda *a: delta.chunked_scan(*a[:-1], state=a[-1]))(
+        q[:, 30:], k[:, 30:], v[:, 30:], la[:, 30:], beta[:, 30:], first)
+    want_out, want_state = truth(form, q, k, v, la, beta)
     np.testing.assert_allclose(out, want_out[:, 30:], atol=TOL)
     np.testing.assert_allclose(state, want_state, atol=TOL)
 
 
 @pytest.mark.parametrize("lens", [(37, 90), (64, 5), (128, 1)])
-def test_pad_positions_leave_each_row_its_own_state(lens):
+def test_pad_positions_leave_each_row_its_own_state(form, lens):
     """Two prompts of different lengths in one call padded to 128: with
     beta 0 and alpha 1 past a row's length, the state that comes back is
     the state at that row's LAST REAL position."""
     bucket = 128
     q, k, v, la, beta = draw(bucket, seed=11)
     real = jnp.arange(bucket)[None, :, None] < jnp.asarray(lens)[:, None, None]
-    out, state = delta.chunked_scan(
-        q, k, v, jnp.where(real, la, 0.0), jnp.where(real, beta, 0.0))
+    out, state = scan(q, k, v, jnp.where(real, la, 0.0), jnp.where(real, beta, 0.0))
     for row, n in enumerate(lens):
-        want_out, want_state = delta.recurrence(
-            *(x[row:row + 1, :n] for x in (q, k, v, la, beta)))
+        want_out, want_state = truth(
+            form, *(x[row:row + 1, :n] for x in (q, k, v, la, beta)))
         np.testing.assert_allclose(state[row:row + 1], want_state, atol=TOL)
         np.testing.assert_allclose(out[row:row + 1, :n], want_out, atol=TOL)
 
@@ -214,13 +253,13 @@ def test_a_prefill_hands_state_and_tail_to_decode(n, m):
 
 
 @pytest.mark.parametrize("wrong", ["state_bf16", "beta_one", "alpha_one"])
-def test_a_wrong_recurrence_is_not_within_the_tolerance(wrong):
+def test_a_wrong_recurrence_is_not_within_the_tolerance(form, wrong):
     q, k, v, la, beta = draw(100, seed=8)
-    want_out, _state = delta.recurrence(q, k, v, la, beta)
+    want_out, _state = truth(form, q, k, v, la, beta)
     if wrong == "beta_one":
-        out, _s = delta.chunked_scan(q, k, v, la, 0.5 * beta)
+        out, _s = scan(q, k, v, la, 0.5 * beta)
     elif wrong == "alpha_one":
-        out, _s = delta.chunked_scan(q, k, v, jnp.zeros_like(la), beta)
+        out, _s = scan(q, k, v, jnp.zeros_like(la), beta)
     else:  # the state rounded to bfloat16 between chunks of 16
         state, outs = None, []
         for lo in range(0, 100, 16):
@@ -266,7 +305,6 @@ def test_the_kernel_is_asked_only_where_the_state_is_whole_tiles(kernel):
     assert delta.step_impl(8, 16) == "xla" and delta.step_impl(12, 128) == "xla"
     assert delta.step_impl(96, 384, where="cpu") == "xla"
     assert delta.step_impl(96, 384, where="tpu") == "pallas"
-    assert delta.scan_impl() == "xla"
     # a state that is not whole tiles takes XLA's form under the same knob
     q, k, v, la, beta = draw(1, seed=1)
     state = jnp.zeros(delta.state_shape(B, H, DK, DV))
@@ -286,6 +324,65 @@ def test_the_kernel_s_call_is_named_by_the_state_it_writes(kernel):
     assert len(calls) == 1
     assert tuple(calls[0].outvars[0].aval.shape) == (B, H // 2, DK, 128)
     assert dict(calls[0].params["input_output_aliases"]) == {0: 0}
+
+
+@pytest.mark.parametrize("length", [64, 150])
+def test_the_scan_kernel_at_the_published_head(kernel, length):
+    """Olmo-Hybrid's head, 96 x 192 (two heads: side by side in the
+    inverse), against float64."""
+    args = draw(length, seed=length, batch=1, heads=2, dk=96, dv=192)
+    want_out, want_state = by_hand(*args)
+    out, state = scan(*args)
+    np.testing.assert_allclose(out, want_out, atol=TOL)
+    np.testing.assert_allclose(state, want_state, atol=TOL)
+    assert out.dtype == jnp.float32 and state.dtype == jnp.float32
+
+
+def test_an_odd_head_count_is_inverted_a_head_at_a_time(kernel):
+    args = draw(70, seed=3, heads=3)
+    want_out, want_state = by_hand(*args)
+    out, state = scan(*args)
+    np.testing.assert_allclose(out, want_out, atol=TOL)
+    np.testing.assert_allclose(state, want_state, atol=TOL)
+
+
+def test_the_cpu_takes_xla_s_scan():
+    assert delta.scan_impl(96) == "xla" and delta.scan_impl(8) == "xla"
+    assert "pallas_call" not in str(traced(*draw(70)))
+
+
+def test_the_scan_kernel_is_asked_only_where_a_chunk_is_whole_tiles(kernel):
+    assert delta.scan_impl(96) == "pallas" and delta.scan_impl(8) == "pallas"
+    assert delta.scan_impl(12) == "xla"            # a key width off the sublanes
+    assert delta.scan_impl(96, chunk=32) == "xla"  # a chunk that is not CHUNK
+    assert delta.scan_impl(96, where="cpu") == "xla"
+    assert delta.scan_impl(96, where="tpu") == "pallas"
+    # a shape that is not whole tiles takes XLA's form under the same knob
+    args = draw(70, seed=1, dk=12)
+    assert "pallas_call" not in str(traced(*args))
+    want_out, want_state = delta.recurrence(*args)
+    out, state = scan(*args)
+    np.testing.assert_allclose(out, want_out, atol=TOL)
+    np.testing.assert_allclose(state, want_state, atol=TOL)
+    assert "pallas_call" in str(traced(*draw(70)))
+
+
+def test_the_scan_kernel_s_call_is_named_and_laid_for_the_readers(kernel):
+    """``delta_chunk_scan``'s FIRST output is the scan's output laid
+    ``(prompts, H, L, d_v)`` — four dims, the heads second, the prompts
+    (never the slots) first, no two heads packed into one — and the final
+    state its second: a device trace keys the kernel by the first, which
+    is how the benchmark's readers find the scan
+    (``layer_metrics/delta_work.py is_scan``, ``kda_work.py is_scan``)."""
+    args = draw(100, seed=2)
+    jaxpr = traced(*args)
+    calls = kernel_calls(jaxpr.jaxpr)
+    assert len(calls) == 1 and "delta_chunk_scan" in str(jaxpr)
+    out, state = (tuple(v.aval.shape) for v in calls[0].outvars)
+    assert out == (B, H, 128, DV) and state == (B, H, DK, DV)
+    text = jax.jit(lambda *a: delta.chunked_scan(*a)).lower(*args).as_text(
+        debug_info=True)
+    assert "seldon.delta.scan" in text
 
 
 def test_the_scopes_name_the_three_operations():

@@ -15,6 +15,11 @@ that referred a block to its START read 8e-5 at the block's last row
 ``e^{+80}`` makes the pair matter).  A decay averaged over a head's
 channels (Olmo-Hybrid's rule), beta times 2 and a state kept in bfloat16
 move the same outputs by 1e-3 to tenths.
+
+The prefill's scan has two forms (``delta.scan_impl``): XLA's — the CPU's
+own answer — and the kernel ``delta_chunk_scan`` under the Pallas
+interpreter (the ``form`` fixture's second case), which factors its decays
+the same way and is held to float64 ``by_hand`` throughout.
 """
 
 import hashlib
@@ -71,6 +76,25 @@ def by_hand(q, k, v, log_alpha, beta):
     return out, state
 
 
+@pytest.fixture(params=["xla", "kernel"])
+def form(request, monkeypatch):
+    """XLA's form of the scan, or the kernel under the interpreter."""
+    if request.param == "kernel":
+        monkeypatch.setattr(delta, "backend", lambda: "interpret")
+    return request.param
+
+
+def scan(*args):
+    """``chunked_scan`` jitted under a function of its own: ``jax.jit`` of
+    the same function at the same shapes would hand one form's case the
+    trace the other left."""
+    return jax.jit(lambda *a: delta.chunked_scan(*a))(*args)
+
+
+def truth(form, *args):
+    return by_hand(*args) if form == "kernel" else delta.recurrence(*args)
+
+
 def test_the_recurrence_under_a_channel_decay_is_the_equations():
     args = draw(23, seed=4)
     out, state = delta.recurrence(*args)
@@ -81,25 +105,25 @@ def test_the_recurrence_under_a_channel_decay_is_the_equations():
 
 
 @pytest.mark.parametrize("length", [5, 16, 17, 48, 64, 65, 100, 128, 200])
-def test_the_chunked_scan_is_the_recurrence(length):
+def test_the_chunked_scan_is_the_recurrence(form, length):
     """Lengths on and off multiples of the chunk (64) and of a diagonal
     block (16)."""
     args = draw(length, seed=length)
-    want_out, want_state = delta.recurrence(*args)
-    out, state = jax.jit(delta.chunked_scan)(*args)
+    want_out, want_state = truth(form, *args)
+    out, state = scan(*args)
     np.testing.assert_allclose(out, want_out, atol=TOL)
     np.testing.assert_allclose(state, want_state, atol=TOL)
 
 
 @pytest.mark.parametrize("length,start", [(100, 10), (128, 10), (128, 0), (200, 64),
                                           (160, 37)])
-def test_every_gate_at_its_floor_for_64_positions_running(length, start):
+def test_every_gate_at_its_floor_for_64_positions_running(form, length, start):
     """Nothing overflows, nothing is NaN, and the outputs are the
     recurrence's: against float64 by hand, where neither form's own
     rounding hides the other's."""
     args = draw(length, seed=length + start, floor_run=(start, 64))
     assert float(args[3][:, start:start + 64].max()) == FLOOR
-    out, state = jax.jit(delta.chunked_scan)(*args)
+    out, state = scan(*args)
     assert bool(jnp.isfinite(out).all()) and bool(jnp.isfinite(state).all())
     want_out, want_state = by_hand(*args)
     np.testing.assert_allclose(out, want_out, atol=TOL)
@@ -115,7 +139,7 @@ def test_a_floor_the_blocks_cannot_hold_is_refused_by_the_spec():
 
 
 @pytest.mark.parametrize("lens", [(37, 90), (64, 5), (128, 1)])
-def test_pad_positions_leave_each_row_its_own_state(lens):
+def test_pad_positions_leave_each_row_its_own_state(form, lens):
     """Two lengths in one call, padded to the longer: each row's state is
     the state at its own last real position, and its outputs before
     that are its own."""
@@ -124,10 +148,10 @@ def test_pad_positions_leave_each_row_its_own_state(lens):
     real = (jnp.arange(longest)[None, :] < jnp.asarray(lens)[:, None])
     la = jnp.where(real[..., None, None], la, 0.0)
     beta = jnp.where(real[..., None], beta, 0.0)
-    out, state = jax.jit(delta.chunked_scan)(q, k, v, la, beta)
+    out, state = scan(q, k, v, la, beta)
     for row, n in enumerate(lens):
         one = tuple(x[row:row + 1, :n] for x in (q, k, v, la, beta))
-        want_out, want_state = delta.recurrence(*one)
+        want_out, want_state = truth(form, *one)
         np.testing.assert_allclose(out[row, :n], want_out[0], atol=TOL)
         np.testing.assert_allclose(state[row], want_state[0], atol=TOL)
 
@@ -168,14 +192,14 @@ def test_a_lane_that_does_not_run_keeps_its_state_bit_for_bit():
 
 
 @pytest.mark.parametrize("length", [40, 100])
-def test_a_decay_constant_over_a_head_s_channels_is_olmo_s_result(length):
+def test_a_decay_constant_over_a_head_s_channels_is_olmo_s_result(form, length):
     """The scalar form is the special case: the same decay given a head
     and given a channel (every channel alike).  To a stated rounding —
     the two forms sum the same terms in another order (read 8e-7)."""
     q, k, v, la, beta = draw(length, seed=length)
     scalar = la[..., 0]
     spread = jnp.broadcast_to(scalar[..., None], la.shape)
-    for f in (delta.recurrence, jax.jit(delta.chunked_scan)):
+    for f in (delta.recurrence, scan):
         out_s, state_s = f(q, k, v, scalar, beta)
         out_c, state_c = f(q, k, v, spread, beta)
         np.testing.assert_allclose(out_c, out_s, atol=TOL)
@@ -205,14 +229,17 @@ def test_the_bounded_gate_is_a_channel_s_and_lies_over_its_floor():
 
 
 @pytest.mark.parametrize("wrong", ["decay_head", "beta_two", "state_bf16"])
-def test_a_wrong_recurrence_is_not_within_the_tolerance(wrong):
+def test_a_wrong_recurrence_is_not_within_the_tolerance(form, wrong):
+    """(under ``form`` "kernel" the wrong programs that have a scan run
+    the kernel's)"""
     q, k, v, la, beta = draw(100, seed=11)
-    want, _state = delta.recurrence(q, k, v, la, beta)
+    want, _state = truth(form, q, k, v, la, beta)
+    run = scan if form == "kernel" else delta.recurrence
     if wrong == "decay_head":  # Olmo-Hybrid's rule: one decay a head
         mean = jnp.log(jnp.exp(la).mean(-1))
-        got, _s = delta.recurrence(q, k, v, mean, beta)
+        got, _s = run(q, k, v, mean, beta)
     elif wrong == "beta_two":
-        got, _s = delta.recurrence(q, k, v, la, 2.0 * beta)
+        got, _s = run(q, k, v, la, 2.0 * beta)
     else:
         def one(s, xs):
             q_t, k_t, v_t, la_t, b_t = xs
@@ -271,6 +298,61 @@ def test_the_kernel_s_call_carries_the_decay_with_the_rows(kernel):
     assert "f32[4,2,3,8]" in channel and "f32[4,2,2,128]" in channel
     assert "f32[4,2,2,8]" in scalar and "f32[4,2,3,128]" in scalar
     assert "f32[4,2,3,8]" not in scalar
+
+
+@pytest.mark.parametrize("length", [64, 150])
+def test_the_scan_kernel_at_the_published_head(kernel, length):
+    """Ling-3.0-flash's head, 128 x 128 (two heads: side by side in the
+    inverse), a floor run across a chunk's edge, against float64."""
+    args = draw(length, seed=length, batch=1, dk=128, dv=128, floor_run=(40, 64))
+    want_out, want_state = by_hand(*args)
+    out, state = scan(*args)
+    assert bool(jnp.isfinite(out).all()) and bool(jnp.isfinite(state).all())
+    np.testing.assert_allclose(out, want_out, atol=TOL)
+    np.testing.assert_allclose(state, want_state, atol=TOL)
+
+
+def test_the_scan_kernel_continues_from_a_state_under_a_channel_decay(kernel):
+    q, k, v, la, beta = draw(150, seed=5)
+    _out, first = scan(*(x[:, :70] for x in (q, k, v, la, beta)))
+    out, state = jax.jit(lambda *a: delta.chunked_scan(*a[:-1], state=a[-1]))(
+        *(x[:, 70:] for x in (q, k, v, la, beta)), first)
+    want_out, want_state = by_hand(q, k, v, la, beta)
+    np.testing.assert_allclose(out, want_out[:, 70:], atol=TOL)
+    np.testing.assert_allclose(state, want_state, atol=TOL)
+
+
+def kernel_calls(jaxpr):
+    """The ``pallas_call`` equations of a jaxpr, the scan's own jit looked
+    into (``delta._scan_jit``: a program's layers share one trace of it)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += kernel_calls(sub)
+    return found
+
+
+def test_the_scan_kernel_s_call_carries_the_decay_like_the_keys(kernel):
+    """One kernel, the variant a fact of the call's structure: a decay a
+    channel rides ``(B, H, L, d_k)`` as k does, a decay a head ``(B, groups,
+    chunks, heads, 64)`` beside beta; the output ``(prompts, H, L, d_v)``
+    is the first result either way (``layer_metrics/kda_work.py is_scan``:
+    four dims, H second, the first not the slots)."""
+    def call(la_shape):
+        args = (jnp.zeros((B, 128, H, DK)), jnp.zeros((B, 128, H, DK)),
+                jnp.zeros((B, 128, H, DV)), jnp.zeros(la_shape), jnp.zeros((B, 128, H)))
+        jaxpr = jax.make_jaxpr(lambda *a: delta.chunked_scan(*a))(*args)
+        (eqn,) = kernel_calls(jaxpr.jaxpr)
+        assert "delta_chunk_scan" in str(jaxpr)
+        assert tuple(eqn.outvars[0].aval.shape) == (B, H, 128, DV)
+        assert tuple(eqn.outvars[1].aval.shape) == (B, H, DK, DV)
+        return [tuple(v.aval.shape) for v in eqn.invars]
+
+    channel, scalar = call((B, 128, H, DK)), call((B, 128, H))
+    assert channel[3] == (B, H, 128, DK) and channel[4] == (B, 1, 2, H, 64)
+    assert scalar[3] == scalar[4] == (B, 1, 2, H, 64)
 
 
 # the scalar forms as the tree before this model traced them (jaxpr text,

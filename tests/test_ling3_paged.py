@@ -136,6 +136,28 @@ def test_q_through_a_bottleneck_fails_the_tolerance():
     assert "q_a" not in tree["block_2"] and "q_a_norm" not in tree["block_2"]
 
 
+def test_the_scan_s_kernel_serves_the_same_rows_under_a_channel_decay(monkeypatch):
+    """The prefill's scan as the kernel ``delta_chunk_scan`` (under the
+    interpreter) inside the engine's prefill programs, the decay a key
+    channel: the reference's rows, ``lane_report()`` says ``"pallas"`` and
+    ``delta_scan_kernel_positions`` counts every padded position."""
+    from seldon_core_tpu.ops import delta
+
+    monkeypatch.setattr(delta, "backend", lambda: "interpret")
+    eng, params = harness.build(SPEC, SIZES, "gather", jnp.float32, **ENGINE)
+    try:
+        harness.hold(monkeypatch, eng)
+        assert eng.lane_report()["delta_scan"] == "pallas"
+        out = harness.serve(eng, PROMPTS, 3)
+        for prompt, (tokens, rows) in zip(PROMPTS, out):
+            np.testing.assert_allclose(
+                rows, reference_rows(params, prompt, tokens), atol=TOL)
+        stats = eng.engine_stats()
+        assert stats["delta_scan_kernel_positions"] == stats["delta_prefill_positions"] > 0
+    finally:
+        eng.close()
+
+
 def test_sixty_four_tokens_across_two_chunk_calls(engines):
     """A chunk of 32 steps: the state is carried by the program's scan
     and stored back with the pool; after each call the lane's logits are
@@ -228,6 +250,7 @@ def test_the_report_and_the_counters(engines, served):
     assert stats["latent_kv_tokens"] == 2 * stats["decode_kv_tokens"] > 0
     assert stats["delta_prefill_positions"] == 4 * stats["prefill_padded_tokens"]
     assert stats["delta_prefill_real_positions"] == 4 * stats["prefill_tokens"]
+    assert stats["delta_scan_kernel_positions"] == 0 and report["delta_scan"] == "xla"
     assert stats["delta_slots_live"] == 0
     # every real token is routed to top-4 in each of the 5 routed layers,
     # a linear layer's as a full one's; a quarter of the router's outputs

@@ -124,6 +124,32 @@ def test_the_step_kernel_serves_the_same_rows(monkeypatch):
         eng.close()
 
 
+@pytest.mark.parametrize("form", ["xla", "pallas"])
+def test_the_scan_s_kernel_counts_the_positions_it_served(monkeypatch, form):
+    """``delta_scan_kernel_positions`` beside ``delta_prefill_positions``:
+    equal where the prefill's scan is the kernel ``delta_chunk_scan``
+    (under the interpreter here: the same rows served), 0 on XLA's form,
+    and ``lane_report()["delta_scan"]`` says which."""
+    from seldon_core_tpu.ops import delta
+
+    if form == "pallas":
+        monkeypatch.setattr(delta, "backend", lambda: "interpret")
+    eng, params = harness.build(SPEC, SIZES, "gather", jnp.float32, **ENGINE)
+    try:
+        harness.hold(monkeypatch, eng)
+        assert eng.lane_report()["delta_scan"] == form
+        out = harness.serve(eng, PROMPTS, 3)
+        for prompt, (tokens, rows) in zip(PROMPTS, out):
+            np.testing.assert_allclose(
+                rows, reference_rows(params, prompt, tokens), atol=TOL)
+        stats = eng.engine_stats()
+        assert stats["delta_prefill_positions"] == 6 * stats["prefill_padded_tokens"] > 0
+        assert stats["delta_scan_kernel_positions"] == (
+            stats["delta_prefill_positions"] if form == "pallas" else 0)
+    finally:
+        eng.close()
+
+
 def test_sixty_four_tokens_across_two_chunk_calls(engines):
     """A chunk of 32 steps: the state is carried by the program's scan
     and stored back with the pool; after each call the lane's logits are
@@ -220,8 +246,10 @@ def test_the_report_and_the_counters(engines, served):
 
     kinds = {name: ENGINE_STATS_METRICS[name][0] for name in (
         "delta_lane_steps", "delta_prefill_positions",
-        "delta_prefill_real_positions", "delta_state_bytes", "delta_slots_live")}
-    assert list(kinds.values()) == ["counter"] * 3 + ["gauge"] * 2
+        "delta_prefill_real_positions", "delta_scan_kernel_positions",
+        "delta_state_bytes", "delta_slots_live")}
+    assert list(kinds.values()) == ["counter"] * 4 + ["gauge"] * 2
+    assert len({ENGINE_STATS_METRICS[name][1] for name in kinds}) == len(kinds)
 
 
 def test_the_chunk_program_carries_the_scopes(engines):
