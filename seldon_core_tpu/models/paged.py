@@ -212,6 +212,12 @@ def prefill_position_bytes(spec, d_model: int, vocab_size: int,
         attn = max(attn, 4 * spec.lin_heads * (
             2 * qkv + 2 * (spec.lin_key_dim + spec.lin_value_dim)
             + 3 * delta.CHUNK + channel))
+    if spec.ssm:
+        # a state-space layer's rows: the in projection's two halves (bf16)
+        # and x after the convolution, Delta, y and the gated y (float32);
+        # the scan carries the state and never lays it out a position
+        attn = max(attn, (2 * 2 + 4 * 4) * spec.ssm_inner
+                   + 4 * (spec.ssm_dt_rank + 4 * spec.ssm_state))
     if spec.ffn == "swiglu":
         ffn = 10 * spec.dense_width  # gate, up and their product
     elif not spec.routed:
@@ -1131,6 +1137,15 @@ def _build_modules():
         if lm.spec.hc_mult:
             x = x.sum(axis=0)
         x = _norm(lm.spec, "final_norm")(x)
+        if lm.spec.tied_head:
+            # the head is the embedding's transpose: the ONE matrix
+            # ``_embed`` declared, read where it rests (no second copy at
+            # rest and none in a program: the contraction runs over its
+            # minor dim)
+            table = lm.variables["params"]["tok_embed"]["embedding"]
+            return jnp.einsum(
+                "bld,vd->blv", x.astype(lm.dtype), table.astype(lm.dtype)
+            ).astype(jnp.float32)
         logits = _dense(lm.precision, lm.vocab_size, lm.dtype, "head",
                         lm.spec)(x)
         return logits.astype(jnp.float32)
@@ -1290,6 +1305,81 @@ def _build_modules():
                 x, hist = _ffn_grouped(self, x, token_mask)
                 return (x, state, tail, *hist)
             return _ffn_swiglu(self, x), state, tail
+
+    class SsmBlock(nn.Module):
+        """A selective state-space layer (Mamba-1 as Jamba runs it,
+        ops/ssm.py) and its FFN: the layer of a spec with ``"ssm"`` layer
+        kinds, which keeps no pages.  With ``u`` the normed stream ``(B,
+        L, d)``: ``[x~ ; z] = u W_in`` (``2 E`` wide, no bias); ``x~``
+        passes a causal depthwise convolution of ``spec.ssm_conv`` taps,
+        its bias and SiLU; step sizes ``Delta`` and the columns ``B``,
+        ``C`` come of ``x`` (``ssm.select``: ``x_proj``, three inner
+        RMSNorms, ``dt_proj`` and ``dt_bias``, softplus); the recurrence
+        with ``A = -exp(a_log)`` and the skip ``d_skip``; ``out = (y .
+        silu(z)) W_out``.  ``a_log`` rests ``(N, E)`` as the state does,
+        float32 with ``d_skip``, ``dt_bias`` and the norms.
+
+        The two calls, the pad rule and what comes back are
+        :class:`DeltaBlock`'s: a prefill from position zero (``state``
+        None; ``true_lens`` the rows' real lengths, ``Delta`` masked past
+        them after its softplus) returns the state ``(B, N, E)`` and the
+        tail ``(B, taps - 1, E)`` at each row's LAST REAL position; a
+        decode step (``L`` 1) takes both as they rest, and a lane
+        ``active`` leaves out keeps both."""
+
+        dtype: Any = jnp.bfloat16
+        precision: str = "bf16"
+        spec: Any = GPT2
+
+        @nn.compact
+        def __call__(self, x, state=None, tail=None, true_lens=None,
+                     active=None):
+            from seldon_core_tpu.ops import delta, ssm
+
+            spec = self.spec
+            inner, cols, rank = spec.ssm_inner, spec.ssm_state, spec.ssm_dt_rank
+            d_model = x.shape[-1]
+            rest = _rest(spec, self.dtype)
+            init = nn.initializers.normal(0.02)
+
+            def proj(name, features, inp):
+                return _dense(self.precision, features, self.dtype, name,
+                              spec)(inp)
+
+            def scale(name, width):  # an inner RMSNorm's learned scale
+                return self.param(name, init, (width,), jnp.float32)
+
+            xz = proj("in_proj", 2 * inner, _norm(spec, "attn_norm")(x))
+            mixed, z = xz[..., :inner], xz[..., inner:]
+            taps = self.param("conv", init, (spec.ssm_conv, inner), rest)
+            bias = ({"bias": self.param("conv_bias", init, (inner,), jnp.float32)}
+                    if spec.ssm_conv_bias else {})
+            if state is None:
+                mixed, tail = delta.conv(mixed, taps, true_lens,
+                                         scope="seldon.ssm.conv", **bias)
+            else:
+                mixed, tail = delta.conv_step(tail, mixed[:, 0], taps, active,
+                                              scope="seldon.ssm.conv", **bias)
+                mixed = mixed[:, None]
+            dt, b, c = ssm.select(
+                mixed, self.param("x_proj", init, (inner, rank + 2 * cols), rest),
+                scale("dt_norm", rank), scale("b_norm", cols),
+                scale("c_norm", cols),
+                self.param("dt_proj", init, (rank, inner), rest),
+                self.param("dt_bias", init, (inner,), jnp.float32),
+                eps=spec.norm_eps, dtype=self.dtype)
+            a = -jnp.exp(self.param("a_log", init, (cols, inner), jnp.float32))
+            skip = self.param("d_skip", init, (inner,), jnp.float32)
+            if state is None:
+                y, state = ssm.scan(mixed, dt, b, c, a, skip,
+                                    true_lens=true_lens)
+            else:
+                state, y = ssm.step(state, mixed[:, 0], dt[:, 0], b[:, 0],
+                                    c[:, 0], a, skip, active=active)
+                y = y[:, None]
+            out = proj("attn_proj", d_model,
+                       y * jax.nn.silu(z.astype(jnp.float32)))
+            return _ffn_swiglu(self, x + out.astype(x.dtype)), state, tail
 
     class PagedTransformerBlock(nn.Module):
         """TransformerBlock whose attention reads a paged K/V pool.
@@ -1806,7 +1896,7 @@ def _build_modules():
                 return self._kinds(x, positions, pages_k, pages_v,
                                    block_tables, lengths, token_mask, window,
                                    whole, last)
-            if self.spec.linear:
+            if self.spec.recurrent:
                 return self._hybrid(x, positions, pages_k, pages_v,
                                     block_tables, lengths, whole, delta or {},
                                     last, token_mask)
@@ -1848,9 +1938,11 @@ def _build_modules():
 
         def _hybrid(self, x, positions, pages_k, pages_v, block_tables,
                     lengths, whole, delta, last, token_mask=None):
-            """The layers of a spec with linear-attention layers: a
-            ``"linear"`` layer is a :class:`DeltaBlock` over its own state
-            and keeps no pages; a ``"full"`` layer is the grouped-query
+            """The layers of a spec with layers that keep a state a lane: a
+            ``"linear"`` layer is a :class:`DeltaBlock`, an ``"ssm"`` layer
+            a :class:`SsmBlock`, each over its own state and keeping no
+            pages (``delta`` below is either's side of the call); a
+            ``"full"`` layer is the grouped-query
             block over the K/V pool — or, for a latent spec, the latent
             block over the ONE latent pool (``pages_v`` None) — whose
             leading axis counts the full layers alone
@@ -1881,9 +1973,10 @@ def _build_modules():
                 at = spec.kind_index(i)
                 place = ({"routed_layer": False}
                          if spec.routed and not spec.layer_routed(i) else {})
-                if spec.layer_kind(i) == "linear":
-                    block = DeltaBlock(dtype=self.dtype, precision=self.precision,
-                                       spec=spec, name=f"block_{i}", **place)
+                if spec.layer_kind(i) in ("linear", "ssm"):
+                    block = (SsmBlock if spec.ssm else DeltaBlock)(
+                        dtype=self.dtype, precision=self.precision,
+                        spec=spec, name=f"block_{i}", **place)
                     if "state" in delta:
                         rows = x if order is None else x[order[0]]
                         rows, state, tail, *hist = block(
@@ -3656,9 +3749,10 @@ class PagedEngine:
                 if asked:
                     raise ValueError(self._kinds_refusal(what, why))
             prefix_cache = False  # (unset: the env's default is not asked)
-        if spec.linear:
-            # linear-attention layers keep a state a lane that rests with
-            # the SLOT, not in pages (ops/delta.py): what assumes that a
+        if spec.recurrent:
+            # linear-attention and state-space layers keep a state a lane
+            # that rests with the SLOT, not in pages (ops/delta.py,
+            # ops/ssm.py): what assumes that a
             # stream's whole state is its pages is refused here, by name
             for asked, what, why in (
                 (speculative, "the speculative lane",
@@ -3678,7 +3772,7 @@ class PagedEngine:
                 (chunk_token_budget or int(
                     _knobs.raw("SELDON_TPU_CHUNK_TOKEN_BUDGET", "0") or 0),
                  "chunked prefill (chunk_token_budget)",
-                 "its slices are cached-suffix prefills, and the chunked "
+                 "its slices are cached-suffix prefills, and the prefill's "
                  "scan starts from a state of zeros"),
                 (max_adapters or int(
                     _knobs.raw("SELDON_TPU_MAX_ADAPTERS", "0") or 0),
@@ -3827,7 +3921,7 @@ class PagedEngine:
             # attention takes (the kernel, or the gather and einsums)
             self._chunk_impl = (
                 "pool" if (kernel_eligible or spec.latent or spec.kinds
-                           or spec.linear)
+                           or spec.recurrent)
                 else "ring")
             if kernel_eligible:
                 logger.info(
@@ -3863,7 +3957,7 @@ class PagedEngine:
         # traffic degenerates to one bucket automatically (identical
         # horizons), so the uniform-load programs are byte-identical
         # with the knob on.
-        # (a spec with linear-attention layers runs ONE bucket unless the
+        # (a spec with a state a lane runs ONE bucket unless the
         # knob says otherwise: the split exists to spare the page loop's
         # table at short contexts, which here is two layers of eight since
         # the kernel pays for live pages only, while every second bucket
@@ -3871,7 +3965,7 @@ class PagedEngine:
         # cell, ~17 s of set-up each — and makes the lanes a permutation
         # of the slots the state rests by)
         buckets_env = (_knobs.raw("SELDON_TPU_CTX_BUCKETS", "")
-                       or ("1" if spec.linear else "2"))
+                       or ("1" if spec.recurrent else "2"))
         if buckets_env not in ("1", "2"):
             raise ValueError(
                 f"SELDON_TPU_CTX_BUCKETS={buckets_env!r}: supported values "
@@ -4019,9 +4113,16 @@ class PagedEngine:
         # channels)`` in the compute type — so that a layer's update
         # replaces its own array and nothing of the others moves; a
         # prefill writes its slots' rows, a chunk carries them all
+        # (a state-space layer's state rests the same way, ``(slots, N,
+        # E)`` float32 and a tail of its own channels: ops/ssm.py.  The
+        # ``_delta_*`` arrays hold whichever state the spec keeps; the
+        # ``delta_*`` COUNTERS are the delta rule's alone, the ``ssm_*``
+        # ones the state-space recurrence's)
         self._delta_state: Tuple[Any, ...] = ()
         self._delta_conv: Tuple[Any, ...] = ()
-        self._delta_layers = spec.state_layers(num_layers)
+        self._state_layers = spec.state_layers(num_layers)
+        self._delta_layers = self._state_layers if spec.linear else 0
+        self._ssm_layers = self._state_layers if spec.ssm else 0
         # linear layers whose prefill scan the kernel serves (all or none:
         # ops/delta.py scan_impl's rule is the head's key width)
         self._delta_scan_kernel_layers = 0
@@ -4030,16 +4131,14 @@ class PagedEngine:
 
             if _delta.scan_impl(spec.lin_key_dim) == "pallas":
                 self._delta_scan_kernel_layers = self._delta_layers
-            shape = _delta.state_shape(
-                self.max_slots, spec.lin_heads, spec.lin_key_dim,
-                spec.lin_value_dim)
+        if spec.recurrent:
             self._delta_state = tuple(
-                jnp.zeros(shape, jnp.float32)
-                for _ in range(self._delta_layers))
+                jnp.zeros(spec.state_shape(self.max_slots), jnp.float32)
+                for _ in range(self._state_layers))
             self._delta_conv = tuple(
-                jnp.zeros((self.max_slots, spec.lin_conv - 1,
-                           spec.lin_channels), dtype)
-                for _ in range(self._delta_layers))
+                jnp.zeros((self.max_slots, spec.state_taps - 1,
+                           spec.state_channels), dtype)
+                for _ in range(self._state_layers))
         # ... in bytes as it rests, every slot's (what the tiling pads
         # counted): lane_report's and the gauge's delta_state_bytes, and a
         # term of what a prefill call may not take
@@ -4106,7 +4205,7 @@ class PagedEngine:
             "resting, %d pool%s)", self.prefill_positions_max, limit, resting,
             self._pool_shard_bytes,
             f", {self._delta_state_bytes} state a lane x {self.max_slots} slots"
-            if spec.linear else "")
+            if spec.recurrent else "")
         # lane sharding (r19): under dp>1 the slot-major host arrays
         # (logits, block tables, sampling knobs, rng keys) batch-shard
         # on the data axis — each replica group carries max_slots/dp
@@ -4416,6 +4515,14 @@ class PagedEngine:
                           "delta_prefill_positions": 0,
                           "delta_prefill_real_positions": 0,
                           "delta_scan_kernel_positions": 0,
+                          # state-space layers (spec.ssm, ops/ssm.py; 0
+                          # otherwise, as the delta_* ones are 0 here):
+                          # lane-steps x state-space layers the decode
+                          # steps ran, padded and real positions x
+                          # state-space layers the prefill calls scanned
+                          "ssm_lane_steps": 0,
+                          "ssm_prefill_positions": 0,
+                          "ssm_prefill_real_positions": 0,
                           # a spec with layer kinds (0 otherwise): what
                           # its selection and its windows read (the
                           # chunk's counter row, _sparse_step) and the
@@ -4771,17 +4878,21 @@ class PagedEngine:
         yet."""
         spec = self.spec
         pages = ("latent rows" if spec.latent else "K/V pages")
+        state, layers = (
+            (f"{spec.ssm_state} x {spec.ssm_inner}", "state-space")
+            if spec.ssm else
+            (f"{spec.lin_heads} x {spec.lin_key_dim} x {spec.lin_value_dim}",
+             "linear-attention"))
         return (
-            f"arch={spec.name!r} keeps a state of {spec.lin_heads} x "
-            f"{spec.lin_key_dim} x {spec.lin_value_dim} float32 a lane in "
-            f"each of its linear-attention layers, beside the {pages} of "
+            f"arch={spec.name!r} keeps a state of {state} float32 a lane in "
+            f"each of its {layers} layers, beside the {pages} of "
             f"the others: {what} cannot take a state a lane yet — {why}"
         )
 
     def _refuse_latent(self, what: str) -> None:
         """Containers that carry K and V pages of ``d_model`` between
         engines (disaggregated prefill, migration) raise here."""
-        if self.spec.linear:
+        if self.spec.recurrent:
             raise ValueError(self._linear_refusal(
                 what, "its container holds a \"k\" and a \"v\" block a page "
                 "and nothing of a lane's state"))
@@ -4813,8 +4924,8 @@ class PagedEngine:
         entry (:func:`kv_split`)."""
         if self._kv_int8:
             return (self.pages_k, self.scales_k), (self.pages_v, self.scales_v)
-        if self.spec.linear:
-            # the linear layers' state rides with the K pool: donated
+        if self.spec.recurrent:
+            # the state a lane rides with the K pool: donated
             # with it, carried by a chunk's scan with it, stored back
             # with it (:func:`delta_split`)
             return ({"kv": self.pages_k, "state": self._delta_state,
@@ -4825,7 +4936,7 @@ class PagedEngine:
         """Inverse of :meth:`_kv_args` for a program's returned pools."""
         if self._kv_int8:
             (self.pages_k, self.scales_k), (self.pages_v, self.scales_v) = pk, pv
-        elif self.spec.linear:
+        elif self.spec.recurrent:
             self.pages_k, self.pages_v = pk["kv"], pv
             self._delta_state, self._delta_conv = pk["state"], pk["conv"]
         else:
@@ -7421,6 +7532,8 @@ class PagedEngine:
             indexed_fused=int(indexed_fused), **routed,
             **({"delta_positions": k * bucket * self._delta_layers}
                if self.spec.linear else {}),
+            **({"ssm_positions": k * bucket * self._ssm_layers}
+               if self.spec.ssm else {}),
         )
         try:
             with self._lock:
@@ -7443,6 +7556,10 @@ class PagedEngine:
                     tokens * self._delta_layers)
                 self._counters["delta_scan_kernel_positions"] += (
                     k * bucket * self._delta_scan_kernel_layers)
+                self._counters["ssm_prefill_positions"] += (
+                    k * bucket * self._ssm_layers)
+                self._counters["ssm_prefill_real_positions"] += (
+                    tokens * self._ssm_layers)
             return self._prefill_group_call(bucket, k, group, use_cache)
         finally:
             self._seam.end_prefill()
@@ -7525,7 +7642,7 @@ class PagedEngine:
                     w_rows[i] = self._wtables[stream.slot]
                     w_base[i] = self._wbase[stream.slot]
                 kinds["window"] = (jnp.asarray(w_rows), jnp.asarray(w_base))
-            if self.spec.linear:
+            if self.spec.recurrent:
                 # where each row's state rests: its stream's slot (a pad
                 # row: past the last, dropped by the write)
                 at = np.full((k,), self.max_slots, np.int32)
@@ -8099,7 +8216,7 @@ class PagedEngine:
                 # lane fit no migration container yet: their streams are
                 # the drain journal's, like a speculative engine's
                 and not self.spec.latent and not self.spec.kinds
-                and not self.spec.linear
+                and not self.spec.recurrent
             ]
         if not exportable:
             return []
@@ -8674,6 +8791,7 @@ class PagedEngine:
         chunk implementation, and whether decode attention runs the
         Pallas kernel."""
         from seldon_core_tpu.ops import delta as _delta
+        from seldon_core_tpu.ops import ssm as _ssm
         from seldon_core_tpu.ops import hyper as _hyper
 
         kv_heads, head_dim = self.spec.head_sizes(
@@ -8711,14 +8829,19 @@ class PagedEngine:
             **({"kv_heads": kv_heads, "head_dim": head_dim,
                 "router_from": self.spec.router_from}
                if self.spec.kv_heads else {}),
-            # linear-attention layers: which layer is which, the state
-            # kinds that rest with a slot (name: layers), every slot's
-            # bytes and the type of a state, its shape a layer, and which
-            # form a decode step's update and a prefill's scan take
+            # layers that keep a state a lane: which layer is which, the
+            # state kinds that rest with a slot (name: layers), and the
+            # length buckets a chunk program splits its lanes into (1 here
+            # unless SELDON_TPU_CTX_BUCKETS asks for 2)
             **({"layer_kinds": list(
                     self.spec.layer_kinds[:self.module.num_layers]),
-                "state_kinds": {"linear": self._delta_layers},
-                "delta_state_bytes": self._delta_state_bytes,
+                "state_kinds": {self.spec.state_kind: self._state_layers},
+                "ctx_buckets": self._ctx_buckets}
+               if self.spec.recurrent else {}),
+            # linear-attention layers: every slot's bytes and the type of
+            # a state, its shape a layer, and which form a decode step's
+            # update and a prefill's scan take
+            **({"delta_state_bytes": self._delta_state_bytes,
                 "delta_state_dtype": str(self._delta_state[0].dtype),
                 "delta_state_shape": list(self._delta_state[0].shape),
                 "delta_step": _delta.step_impl(
@@ -8727,11 +8850,17 @@ class PagedEngine:
                 # the variant: one decay a head | a key channel, and the
                 # bounded gate's floor (0: the softplus gate)
                 "delta_gate": self.spec.lin_gate,
-                "delta_gate_floor": self.spec.lin_gate_floor,
-                # the length buckets a chunk program splits its lanes
-                # into (1 here unless SELDON_TPU_CTX_BUCKETS asks for 2)
-                "ctx_buckets": self._ctx_buckets}
+                "delta_gate_floor": self.spec.lin_gate_floor}
                if self.spec.linear else {}),
+            # state-space layers: the same facts of the other recurrence,
+            # and that the head is the embedding's transpose
+            **({"ssm_state_bytes": self._delta_state_bytes,
+                "ssm_state_dtype": str(self._delta_state[0].dtype),
+                "ssm_state_shape": list(self._delta_state[0].shape),
+                "ssm_step": _ssm.step_impl(*self._delta_state[0].shape[1:]),
+                "ssm_scan": _ssm.scan_impl(*self._delta_state[0].shape[1:]),
+                "tied_head": self.spec.tied_head}
+               if self.spec.ssm else {}),
             # a spec with layer kinds: one pool a row kind (the full
             # layers' rows and indexer keys share the block table's
             # pages; the window layers' have their own), how many rows a
@@ -9091,10 +9220,18 @@ class PagedEngine:
                 # linear-attention layers: what every slot's state takes
                 # as it rests, and the slots that hold a stream's (0, 0
                 # without such layers)
-                "delta_state_bytes": self._delta_state_bytes,
+                "delta_state_bytes": (
+                    self._delta_state_bytes if self.spec.linear else 0),
                 "delta_slots_live": (
                     sum(s is not None for s in self._slots)
                     if self.spec.linear else 0),
+                # ... and state-space layers' (the other recurrence: one
+                # pair of the two reads 0 in any engine)
+                "ssm_state_bytes": (
+                    self._delta_state_bytes if self.spec.ssm else 0),
+                "ssm_slots_live": (
+                    sum(s is not None for s in self._slots)
+                    if self.spec.ssm else 0),
             }
             outputs = self.spec.router_outputs
             moe_expert_hits = (  # cumulative assignments per router output
@@ -9760,6 +9897,8 @@ class PagedEngine:
                 # linear layers: the lanes whose state this chunk updates
                 **({"delta_lanes": len(runnable_now)}
                    if self.spec.linear else {}),
+                **({"ssm_lanes": len(runnable_now)}
+                   if self.spec.ssm else {}),
                 **({"sparse_lanes": sum(
                         n >= self.spec.index_topk for n in lens0.values()),
                     "window_pages": sum(
@@ -9955,6 +10094,7 @@ class PagedEngine:
                 n = int(emitted_np[slot])
                 self._counters["decode_lane_steps"] += n
                 self._counters["delta_lane_steps"] += n * self._delta_layers
+                self._counters["ssm_lane_steps"] += n * self._ssm_layers
                 read = n * len0 + n * (n - 1) // 2
                 self._counters["decode_kv_tokens"] += read
                 if self.spec.latent:  # a row an attention sub-layer

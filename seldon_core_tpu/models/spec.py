@@ -34,7 +34,7 @@ expert-parallel layer computes its own experts' part and nothing
 stands in for the rest).
 
 ``GPT2``, ``OLMOE``, ``DEEPSEEK_V3``, ``LONGCAT_FLASH``, ``DOTS3_NOTE``,
-``SMALLTHINKER``, ``XING4_0``, ``OLMO_HYBRID`` and ``BAILING_HYBRID`` are the values served (the fifth added layer KINDS: ``layer_kinds``,
+``SMALLTHINKER``, ``XING4_0``, ``OLMO_HYBRID``, ``BAILING_HYBRID`` and ``JAMBA`` are the values served (the fifth added layer KINDS: ``layer_kinds``,
 ``attn_kind``, ``cache_kinds`` — layers that differ in their attention
 and a cache of one pool a row kind); a new architecture is a new value (and new branches where the
 block reads a field it has not met), not a new block.  The fourth value
@@ -94,6 +94,16 @@ negative number the bounded gate ``floor x sigmoid(.)``; ``lin_out_gate``
 layer the FFN its place calls for: leading dense SwiGLU layers, then
 DeepSeek-V3's sigmoid-routed experts of which the replica holds a share
 (:meth:`ModelSpec.layer_routed` whatever the layer's kind).
+
+The tenth value (``JAMBA``: AI21-Jamba2-3B) added a second layer kind
+that keeps a state a lane, ``"ssm"`` — a Mamba-1 selective state-space
+layer (``ops/ssm.py``; the ``ssm_*`` fields) whose state is ``ssm_state
+x ssm_inner`` float32 a lane and whose recurrence is no delta rule: the
+same manager (:attr:`ModelSpec.recurrent`, :meth:`ModelSpec.state_layers`,
+:meth:`ModelSpec.state_bytes`) holds either, never both in one spec —
+beside multi-query ``"full"`` layers (``kv_heads`` 1) without positions,
+and ``tied_head``: the head is the embedding's transpose, one matrix at
+rest.
 """
 
 from __future__ import annotations
@@ -300,6 +310,23 @@ class ModelSpec:
     lin_out_gate: str = "silu"
     expert_swiglu_limits: Tuple[float, ...] = ()
     shared_swiglu_limits: Tuple[float, ...] = ()
+    # ---- state-space layers (Jamba: Mamba-1, ops/ssm.py).  A layer_kinds
+    # entry "ssm" is a layer that keeps NO pages: ssm_inner channels
+    # (mamba_expand x hidden) through a causal depthwise convolution of
+    # ssm_conv taps (with a bias where ssm_conv_bias), step sizes, input
+    # and output columns read from them through a projection of
+    # ssm_dt_rank + 2 ssm_state and three inner RMSNorms, and a state of
+    # ssm_state x ssm_inner float32 a lane that rests with the SLOT beside
+    # the convolution's last ssm_conv - 1 inputs.  ssm_proj_bias (a bias
+    # on the in and out projections) is kept so that a true one is REFUSED
+    # by name.  tied_head: logits = h . embedding^T, one matrix at rest
+    ssm_inner: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_dt_rank: int = 0
+    ssm_conv_bias: bool = True
+    ssm_proj_bias: bool = False
+    tied_head: bool = False
 
     @property
     def routed(self) -> bool:
@@ -359,7 +386,7 @@ class ModelSpec:
         cache is one pool a row kind (:meth:`cache_kinds`), not one
         element.  (Linear layers keep no rows at all: what is left of a
         spec with them is one kind, in one pool.)"""
-        return bool(self.layer_kinds) and not self.linear
+        return bool(self.layer_kinds) and not self.recurrent
 
     @property
     def linear(self) -> bool:
@@ -367,22 +394,57 @@ class ModelSpec:
         beside the pages (:meth:`state_layers`)."""
         return "linear" in self.layer_kinds
 
+    @property
+    def ssm(self) -> bool:
+        """Whether some layers are state-space layers: a state a lane of
+        another recurrence (``ops/ssm.py``)."""
+        return "ssm" in self.layer_kinds
+
+    @property
+    def recurrent(self) -> bool:
+        """Whether some layers keep a state a lane and no pages, of
+        either recurrence: what the engine's state-a-lane manager asks."""
+        return self.linear or self.ssm
+
+    @property
+    def state_kind(self) -> str:
+        """The layer kind that keeps the state: "linear" | "ssm" | ""."""
+        return "linear" if self.linear else "ssm" if self.ssm else ""
+
     def state_layers(self, num_layers: int) -> int:
         """The layers that keep a state a lane and no pages."""
-        return self.layer_kinds[:num_layers].count("linear")
+        return self.layer_kinds[:num_layers].count(self.state_kind)
 
-    def state_bytes(self, num_layers: int) -> int:
-        """Bytes one lane's linear state takes as it rests: the float32
-        state (``ops/delta.py state_shape``: what the (8, 128) tiling
-        pads is counted) and the convolution's inputs in bf16."""
-        if not self.linear:
-            return 0
+    def state_shape(self, slots: int) -> Tuple[int, ...]:
+        """One layer's resting state over ``slots`` (float32): a linear
+        layer's ``ops/delta.py state_shape``, a state-space layer's
+        ``(slots, ssm_state, ssm_inner)``."""
+        if self.ssm:
+            return (slots, self.ssm_state, self.ssm_inner)
         from seldon_core_tpu.ops import delta
 
-        _slots, groups, dk, lanes = delta.state_shape(
-            1, self.lin_heads, self.lin_key_dim, self.lin_value_dim)
-        state = 4 * groups * (-(-dk // 8) * 8) * lane_tiles(lanes)
-        conv = 2 * (self.lin_conv - 1) * self.lin_channels
+        return delta.state_shape(slots, self.lin_heads, self.lin_key_dim,
+                                 self.lin_value_dim)
+
+    @property
+    def state_channels(self) -> int:
+        """The channels a state layer convolves, and its taps: the tail a
+        lane keeps is ``(taps - 1, channels)`` in the compute type."""
+        return self.ssm_inner if self.ssm else self.lin_channels
+
+    @property
+    def state_taps(self) -> int:
+        return self.ssm_conv if self.ssm else self.lin_conv
+
+    def state_bytes(self, num_layers: int) -> int:
+        """Bytes one lane's state takes as it rests, of either
+        recurrence: the float32 state (what the (8, 128) tiling pads is
+        counted) and the convolution's inputs in bf16."""
+        if not self.recurrent:
+            return 0
+        *groups, rows, lanes = self.state_shape(1)[1:]
+        state = 4 * (groups[0] if groups else 1) * (-(-rows // 8) * 8) * lane_tiles(lanes)
+        conv = 2 * (self.state_taps - 1) * self.state_channels
         return self.state_layers(num_layers) * (state + conv)
 
     @property
@@ -657,10 +719,40 @@ BAILING_HYBRID = ModelSpec(
     lin_out_gate="sigmoid_head",
 )
 
+# ai21labs/AI21-Jamba2-3B config.json (model_type jamba; HF
+# modeling_jamba.py): 28 layers, attn_layer_period 14 / attn_layer_offset
+# 7 — layer i is attention where i % 14 == 7 (layers 7 and 21) and a
+# Mamba-1 mixer elsewhere (26 : 2).  A Mamba layer: mamba_expand 2 x 2,560
+# = 5,120 channels, a convolution of 4 taps WITH a bias, step sizes and
+# the input and output columns from a projection of 160 + 16 + 16 with an
+# RMSNorm each, a state of 16 x 5,120 float32 a lane; no bias on the in
+# and out projections.  An attention layer: 20 query heads of 128 over
+# ONE K/V head, no positional encoding of any kind (the Mamba layers carry
+# position), no QK-norm, no window.  num_experts 1: every layer's FFN is
+# the dense SwiGLU of 8,192; RMSNorm eps 1e-6 on each sub-layer's input,
+# no biases, the head tied to the embedding
+def jamba_layer_kinds(num_layers: int, period: int = 14, offset: int = 7
+                      ) -> Tuple[str, ...]:
+    """The ``jamba`` family's rule: layer ``i`` is attention where ``i %
+    attn_layer_period == attn_layer_offset`` and a Mamba mixer elsewhere."""
+    return tuple("full" if i % period == offset else "ssm"
+                 for i in range(num_layers))
+
+
+JAMBA = ModelSpec(
+    name="jamba", positions="rope", norm="rmsnorm", norm_eps=1e-6,
+    ffn="swiglu", dense_width=8192, bias=False, residual_f32=True,
+    weights_f32=False, kv_heads=1, head_dim=128,
+    layer_kinds=jamba_layer_kinds(28), full_positions="none",
+    ssm_inner=5120, ssm_state=16, ssm_conv=4, ssm_dt_rank=160,
+    ssm_conv_bias=True, ssm_proj_bias=False, tied_head=True,
+)
+
 _ARCHS = {"gpt2": GPT2, "olmoe": OLMOE, "deepseek_v3": DEEPSEEK_V3,
           "longcat_flash": LONGCAT_FLASH, "dots3_note": DOTS3_NOTE,
           "smallthinker": SMALLTHINKER, "xing4_0": XING4_0,
-          "olmo_hybrid": OLMO_HYBRID, "bailing_hybrid": BAILING_HYBRID}
+          "olmo_hybrid": OLMO_HYBRID, "bailing_hybrid": BAILING_HYBRID,
+          "jamba": JAMBA}
 # the sizes any routed arch has; a replica's share of the experts; those
 # only DeepSeek-V3's expert layer and attention have; and the two every
 # arch has
@@ -705,18 +797,24 @@ _BAILING_SIZES = _LATENT_SIZES + (
     "dense_layers", "shared_experts", "n_group", "topk_group", "layer_kinds",
     "lin_heads", "lin_key_dim", "lin_value_dim", "lin_conv",
     "lin_neg_eigval") + _VARIANT_SIZES
+# ... and those of state-space layers beside multi-query full ones
+_SSM_SIZES = ("kv_heads", "head_dim", "layer_kinds", "dense_width",
+              "ssm_inner", "ssm_state", "ssm_conv", "ssm_dt_rank",
+              "ssm_conv_bias", "ssm_proj_bias")
 _OWN_SIZES = {"gpt2": (), "olmoe": (), "deepseek_v3": _DEEPSEEK_SIZES,
               "longcat_flash": _LONGCAT_SIZES, "dots3_note": _DOTS3_SIZES,
               "smallthinker": _SMALLTHINKER_SIZES,
               "xing4_0": _DEEPSEEK_SIZES + _HYPER_SIZES,
-              "olmo_hybrid": _LINEAR_SIZES, "bailing_hybrid": _BAILING_SIZES}
+              "olmo_hybrid": _LINEAR_SIZES, "bailing_hybrid": _BAILING_SIZES,
+              "jamba": _SSM_SIZES}
 _SIZES = tuple(dict.fromkeys(
     _EXPERT_SIZES + sum(_OWN_SIZES.values(), ()) + ("rope_theta", "norm_eps")))
 # a size that may be given as 0 and mean it, for an arch that takes
 # sizes of its own (0 elsewhere = as published; GPT-2 and OLMoE, which
 # have none of these, take a 0 as "not given")
 _ZERO_MEANS_ZERO = ("dense_layers", "shared_experts", "expert_offset",
-                    "experts_held", "zero_experts", "lin_neg_eigval")
+                    "experts_held", "zero_experts", "lin_neg_eigval",
+                    "ssm_conv_bias", "ssm_proj_bias")
 
 
 def model_spec(arch: str = "gpt2", **sizes: Any) -> ModelSpec:
@@ -731,6 +829,16 @@ def model_spec(arch: str = "gpt2", **sizes: Any) -> ModelSpec:
             f"arch={arch!r}: the paged engine serves {sorted(_ARCHS)}"
         ) from None
     own = set(_OWN_SIZES[spec.name])
+    if spec.ssm and (int(sizes.get("num_experts") or 1) > 1
+                     or int(sizes.get("experts_per_tok") or 1) > 1):
+        raise ValueError(
+            f"arch={spec.name!r}, num_experts {sizes.get('num_experts')} / "
+            f"experts_per_tok {sizes.get('experts_per_tok')}: a routed FFN "
+            "inside a state-space stack is not built — every layer's FFN is "
+            "the dense SwiGLU (num_experts 1)")
+    if spec.ssm:  # (one expert chosen always is the dense FFN: not a size)
+        sizes = {k: v for k, v in sizes.items()
+                 if k not in ("num_experts", "experts_per_tok")}
     given = {k: v for k, v in sizes.items()
              if v or (v is not None and k in _ZERO_MEANS_ZERO and own)}
     unknown = sorted(set(given) - set(_SIZES))
@@ -752,9 +860,26 @@ def model_spec(arch: str = "gpt2", **sizes: Any) -> ModelSpec:
                 else tuple(str(x) for x in v) if k == "layer_kinds"
                 else tuple(float(x) for x in v) if k in limits
                 else str(v) if k in ("lin_gate", "lin_out_gate")
-                else bool(v) if k == "lin_neg_eigval" else int(v))
+                else bool(v) if k in ("lin_neg_eigval", "ssm_conv_bias",
+                                      "ssm_proj_bias") else int(v))
             for k, v in given.items()
         })
+    if spec.ssm and (spec.linear or not spec.ssm_inner
+                     or set(spec.layer_kinds) - {"full", "ssm"}):
+        raise ValueError(
+            f"arch={spec.name!r}, layer_kinds {spec.layer_kinds}: state-space "
+            "layers stand beside 'full' layers (multi-head over K/V pages) of "
+            "an arch that has their sizes (ssm_inner, ssm_state, "
+            "ssm_dt_rank); 'linear' and 'ssm' layers in one spec are two "
+            "states a lane, which no manager holds")
+    if spec.ssm and (min(spec.ssm_inner, spec.ssm_state, spec.ssm_dt_rank,
+                         spec.ssm_conv - 1) < 1 or spec.ssm_proj_bias):
+        raise ValueError(
+            f"ssm_inner {spec.ssm_inner}, ssm_state {spec.ssm_state}, "
+            f"ssm_dt_rank {spec.ssm_dt_rank}, ssm_conv {spec.ssm_conv}, "
+            f"ssm_proj_bias {spec.ssm_proj_bias}: each size at least 1 (two "
+            "taps), and a bias on the mixer's in and out projections "
+            "(mamba_proj_bias) is not built")
     if spec.linear and (not spec.lin_heads
                         or set(spec.layer_kinds) - {"full", "linear"}):
         raise ValueError(
@@ -944,7 +1069,7 @@ def _declared(spec, sizes, dtype_name):
         pool if spec.cache_pools == 2 else None,
         # (grouped heads without kinds prefill from zero: a table of no
         # width, and the linear layers' state starts at zero)
-        i32((1, 0 if spec.linear else 1)), i32((1,)))["params"]
+        i32((1, 0 if spec.recurrent else 1)), i32((1,)))["params"]
 
 
 def rest_tree(params, spec: ModelSpec, config: Dict[str, int], dtype=None):
@@ -1056,8 +1181,20 @@ def init_params(spec: ModelSpec, config: Dict[str, int], seed: int,
     tree = {}
     for path, leaf in flatten_dict(declared, sep="/").items():
         name = path.rsplit("/", 1)[-1]
-        if name == "scale":
+        if name == "scale" or name.endswith("_norm"):
+            # (a state-space layer's three inner norms are leaves of the
+            # block itself: dt_norm, b_norm, c_norm)
             lo, hi = 0.5, 1.5
+        elif spec.ssm and name in ("a_log", "dt_bias", "d_skip"):
+            # a state-space layer's own initial ranges (Mamba's): A =
+            # -exp(a_log) in (-16, -1], a step size softplus(dt_bias) in
+            # [1e-3, 1e-1] (the bias uniform between their inverse
+            # softplus), so that exp(Delta A) spreads over (0.2, 1) and
+            # does not sit at 1, where the decay would test nothing; the
+            # skip D in [0.5, 1.5) so that one left out shows
+            lo, hi = {"a_log": (0.0, 2.772588722239781),
+                      "dt_bias": (-6.907255, -2.252168),
+                      "d_skip": (0.5, 1.5)}[name]
         elif name == "a_log":
             # a linear layer's decay rate exp(a_log) in (0.05, 1): with
             # the gate's softplus of order 1 the decay alpha spreads over
@@ -1080,6 +1217,14 @@ def init_params(spec: ModelSpec, config: Dict[str, int], seed: int,
             lo = -hi
         if name == "score_bias" and spec.score == "softmax_bias":
             lo, hi = -1.0 / leaf.shape[0], 1.0 / leaf.shape[0]
+        if name == "embedding" and spec.tied_head:
+            # the one matrix is also the head: drawn as a head's, fan-in
+            # d_model (unit-variance logits; the source's own
+            # initializer_range 0.02 is 1 / sqrt(2,500)).  At +-sqrt(3) a
+            # token's own logit would stand 7 deviations over the rest
+            # and every served token would repeat the last
+            hi = (3.0 / leaf.shape[-1]) ** 0.5
+            lo = -hi
         if spec.mla_lora_scale and _RANK_FED.search(path):
             hi = (3.0 / config["d_model"]) ** 0.5
             lo = -hi

@@ -138,23 +138,27 @@ def scan_impl(key_dim: int, chunk: int = CHUNK, where=None) -> str:
 # the convolution
 # ---------------------------------------------------------------------------
 
-def conv(x, taps, true_lens=None):
+def conv(x, taps, true_lens=None, *, bias=None, scope="seldon.delta.conv"):
     """Causal depthwise convolution and SiLU over a segment from position
     zero: ``x`` ``(B, L, C)``, ``taps`` ``(TAPS, C)`` (tap ``j`` weighs the
     input ``TAPS - 1 - j`` positions back), float32 out.  Also the tail a
     decode step continues from: the last ``TAPS - 1`` INPUTS of each row
     **at its real length** ``true_lens`` ``(B,)`` (zeros where the row is
-    shorter than that), ``(B, TAPS - 1, C)`` in ``x``'s type."""
+    shorter than that), ``(B, TAPS - 1, C)`` in ``x``'s type.  ``bias``
+    ``(C,)`` (a state-space layer's, ops/ssm.py) is added before the
+    SiLU; a call that passes none traces what it traced without one."""
     import jax
     import jax.numpy as jnp
 
-    with jax.named_scope("seldon.delta.conv"):
+    with jax.named_scope(scope):
         b, length, _c = x.shape
         n = taps.shape[0]
         padded = jnp.pad(x, [(0, 0), (n - 1, 0), (0, 0)])
         w = taps.astype(jnp.float32)
         out = sum(padded[:, j:j + length].astype(jnp.float32) * w[j]
                   for j in range(n))
+        if bias is not None:
+            out = out + bias.astype(jnp.float32)
         if true_lens is None:
             true_lens = jnp.full((b,), length, jnp.int32)
         # padded[b, len : len + n - 1] = x[b, len - (n - 1) : len]
@@ -163,17 +167,21 @@ def conv(x, taps, true_lens=None):
         return jax.nn.silu(out), tail
 
 
-def conv_step(tail, x, taps, active=None):
+def conv_step(tail, x, taps, active=None, *, bias=None,
+              scope="seldon.delta.conv"):
     """One position: ``tail`` ``(B, TAPS - 1, C)`` the inputs before it,
     ``x`` ``(B, C)`` its own.  ``(out (B, C) float32, new tail)``; a lane
-    ``active`` ``(B,)`` leaves out keeps its tail."""
+    ``active`` ``(B,)`` leaves out keeps its tail.  ``bias`` as
+    :func:`conv`'s."""
     import jax
     import jax.numpy as jnp
 
-    with jax.named_scope("seldon.delta.conv"):
+    with jax.named_scope(scope):
         window = jnp.concatenate([tail, x[:, None].astype(tail.dtype)], axis=1)
         out = (window.astype(jnp.float32)
                * taps.astype(jnp.float32)[None]).sum(axis=1)
+        if bias is not None:
+            out = out + bias.astype(jnp.float32)
         new = window[:, 1:]
         if active is not None:
             new = jnp.where(active[:, None, None], new, tail)

@@ -659,6 +659,25 @@ ENGINE_STATS_METRICS: Dict[str, Tuple[str, str, str]] = {
     "delta_slots_live": (
         "gauge", "seldon_tpu_engine_delta_slots_live",
         "slots whose linear-attention state belongs to a live stream"),
+    # state-space layers (PR 54, ops/ssm.py): the other recurrence's
+    # work and size; 0 on any other engine, as the delta_* ones are 0 here
+    "ssm_lane_steps": (
+        "counter", "seldon_tpu_engine_ssm_lane_steps_total",
+        "decode lane-steps x state-space layers: state updates run (0 "
+        "without such layers)"),
+    "ssm_prefill_positions": (
+        "counter", "seldon_tpu_engine_ssm_prefill_positions_total",
+        "padded positions x state-space layers the prefill calls scanned"),
+    "ssm_prefill_real_positions": (
+        "counter", "seldon_tpu_engine_ssm_prefill_real_positions_total",
+        "real prompt positions x state-space layers the prefill calls "
+        "scanned"),
+    "ssm_state_bytes": (
+        "gauge", "seldon_tpu_engine_ssm_state_bytes",
+        "bytes every slot's state-space state takes as it rests"),
+    "ssm_slots_live": (
+        "gauge", "seldon_tpu_engine_ssm_slots_live",
+        "slots whose state-space state belongs to a live stream"),
     "hyper_streams": (
         "gauge", "seldon_tpu_engine_hyper_streams",
         "rows of a token's residual (0: the one row of every other arch)"),

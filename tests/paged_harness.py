@@ -26,8 +26,8 @@ from seldon_core_tpu.ops import kernels
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
 from reference import (  # noqa: E402
-    deepseek_v3, dots3_note, ling3_flash, longcat_flash, olmo_hybrid, olmoe,
-    smallthinker, xing4)
+    deepseek_v3, dots3_note, jamba, ling3_flash, longcat_flash, olmo_hybrid,
+    olmoe, smallthinker, xing4)
 
 PAGE, MAX_LEN, SLOTS = 8, 64, 4
 PROMPT = np.random.default_rng(5).integers(0, 97, size=29).tolist()
@@ -141,6 +141,19 @@ MODELS = {
         num_experts_per_tok=4, moe_intermediate_size=32, num_shared_experts=1,
         n_group=4, topk_group=2, routed_scaling_factor=2.5, norm_topk_prob=True,
         expert_swiglu_limit_list=[0] * 6, share_expert_swiglu_limit_list=[0] * 6)),
+    # Jamba: d 64, 4 query heads of 16 over ONE K/V head in the attention
+    # layers (1 and 5 of 8: period 4, offset 1); Mamba layers of 128
+    # channels (expand 2) over 16 state columns — one whole (16, 128) tile
+    # a lane —, a dt rank of 8, a convolution of 4 taps with its bias; a
+    # dense SwiGLU of 96 in every layer, the head tied
+    "jamba": (jamba, dict(
+        model_type="jamba", vocab_size=97, hidden_size=64,
+        intermediate_size=96, num_hidden_layers=8, num_attention_heads=4,
+        num_key_value_heads=1, attn_layer_period=4, attn_layer_offset=1,
+        mamba_d_state=16, mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=8,
+        mamba_conv_bias=True, mamba_proj_bias=False, num_experts=1,
+        num_experts_per_tok=1, rms_norm_eps=1e-6, tie_word_embeddings=True,
+        max_position_embeddings=128)),
 }
 
 

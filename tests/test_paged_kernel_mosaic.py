@@ -1023,3 +1023,106 @@ def test_ling3_s_programs_compile_and_the_prefill_cap_s_count_holds(
     by_compiler = memory.temp_size_in_bytes + memory.output_size_in_bytes
     assert 1 / 2 < counted / by_compiler < 2, (counted, by_compiler)
     assert first.as_text().count("tpu_custom_call") >= 2 + 2 * 11
+
+
+def test_the_ssm_kernels_compile_at_jamba_s_shapes(monkeypatch, one_chip, mosaic):
+    """``ops/ssm.py ssm_state_step`` on every slot's state of one Mamba
+    layer, (256, 16, 5120) float32 — one Mosaic call, the state rewritten
+    where it rests (aliased: no second 84 MB) — and ``ssm_scan`` at the
+    cell's widest prefill call (8 prompts of 512): one Mosaic call whose
+    state never leaves VMEM (no ``(positions, N, E)`` array anywhere)."""
+    from seldon_core_tpu.ops import ssm
+
+    monkeypatch.setattr(ssm, "backend", lambda: "tpu")
+    slots, n, e = 256, 16, 5120
+    assert ssm.step_impl(n, e) == ssm.scan_impl(n, e) == "pallas"
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    step = jax.jit(
+        lambda s, x, dt, b, c, a, d, on: ssm.step(s, x, dt, b, c, a, d, active=on),
+        donate_argnums=0,
+    ).lower(shape((slots, n, e)), shape((slots, e)), shape((slots, e)),
+            shape((slots, n)), shape((slots, n)), shape((n, e)), shape((e,)),
+            shape((slots,), jnp.bool_)).compile()
+    assert step.as_text().count("tpu_custom_call") == 1
+    memory = step.memory_analysis()
+    assert memory.alias_size_in_bytes >= slots * n * e * 4
+    assert memory.temp_size_in_bytes < 64 << 20
+    k, length = 8, 512
+    scan = jax.jit(lambda *a: ssm.scan(*a[:-1], true_lens=a[-1])).lower(
+        shape((k, length, e)), shape((k, length, e)), shape((k, length, n)),
+        shape((k, length, n)), shape((n, e)), shape((e,)),
+        shape((k,), jnp.int32)).compile()
+    assert scan.as_text().count("tpu_custom_call") == 1
+    assert f"{length},{n},{e}" not in scan.as_text()  # no state a position
+    assert scan.memory_analysis().temp_size_in_bytes < 4 * 4 * k * length * e
+
+
+def test_jamba_s_programs_compile_and_the_prefill_cap_s_count_holds(
+        monkeypatch, one_chip, mosaic):
+    """The whole LM at the configuration's sizes (abstract weights: 6.06 GB
+    as they rest, the tied matrix once): a ``b512_k8`` prefill from zero
+    (the scan's kernel in 26 layers, the fused causal kernel at 20 query
+    heads over 1 K/V head in two) and a 256-lane decode step over the
+    state a lane and the (2, 6145, 64, 128) pools.  Both compile; the
+    decode step runs the state kernel in every Mamba layer and the grouped
+    page loop in both attention layers; the prefill's temporaries stay
+    under ``prefill_position_bytes``'s count."""
+    import json
+
+    from seldon_core_tpu.models import paged
+    from seldon_core_tpu.models.spec import declared_tree, model_spec
+    from seldon_core_tpu.ops import ssm
+
+    monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
+    monkeypatch.setattr(ssm, "backend", lambda: "tpu")
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "configs",
+                           "jamba2-3b.json")) as f:
+        cfg = json.load(f)
+    served = {p["name"]: p["value"]
+              for p in cfg["deployment"]["predictors"][0]["graph"]["parameters"]}
+    spec = model_spec(served["arch"])
+    sizes = dict(vocab_size=int(served["vocab_size"]), d_model=int(served["d_model"]),
+                 num_layers=int(served["num_layers"]), num_heads=int(served["num_heads"]))
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    tree = declared_tree(spec, dict(sizes, max_len=int(served["max_len"])), jnp.bfloat16)
+    assert "head" not in tree  # tied: the embedding is the one matrix
+    resting = sum(leaf.size * leaf.dtype.itemsize
+                  for leaf in jax.tree_util.tree_leaves(tree))
+    assert abs(resting - 6.06e9) < 0.01e9, resting
+    params = jax.tree_util.tree_map(lambda leaf: shape(leaf.shape, leaf.dtype), tree)
+    lm = paged.get_paged_lm_class()(dtype=jnp.bfloat16, spec=spec, decode_kernel=True,
+                                    max_len=int(served["max_len"]), **sizes)
+    pool = shape((2, int(served["num_pages"]), 64, 128), jnp.bfloat16)
+    i32, slots = jnp.int32, int(served["max_slots"])
+
+    def prefill(params, tokens, positions, pk, pv, tables, lengths, true_lens):
+        return lm.apply({"params": params}, tokens, positions, pk, pv, tables, lengths,
+                        delta={"true_lens": true_lens}, last=true_lens - 1)
+
+    def step(params, tokens, positions, pk, pv, tables, lengths, state, conv, active):
+        return lm.apply({"params": params}, tokens, positions, pk, pv, tables, lengths,
+                        delta={"state": state, "conv": conv, "active": active})
+
+    def common(batch, seg, width):
+        return (params, shape((batch, seg), i32), shape((batch, seg), i32), pool, pool,
+                shape((batch, width), i32), shape((batch,), i32))
+
+    opts = dict(compiler_options=paged.TPU_COMPILER_OPTIONS)
+    state = tuple(shape(spec.state_shape(slots), jnp.float32) for _ in range(26))
+    conv = tuple(shape((slots, 3, 5120), jnp.bfloat16) for _ in range(26))
+    decode = jax.jit(step, donate_argnums=(7, 8)).lower(
+        *common(slots, 1, 32), state, conv, shape((slots,), jnp.bool_)).compile(**opts)
+    assert decode.as_text().count("tpu_custom_call") >= 26 + 2
+    assert decode.memory_analysis().temp_size_in_bytes < 1 << 30
+    first = jax.jit(prefill).lower(*common(8, 512, 0), shape((8,), i32)).compile(**opts)
+    memory = first.memory_analysis()
+    counted = 4096 * paged.prefill_position_bytes(spec, 2560, 65_536, 20)
+    by_compiler = memory.temp_size_in_bytes + memory.output_size_in_bytes
+    assert 1 < counted / by_compiler < 4, (counted, by_compiler)
+    assert first.as_text().count("tpu_custom_call") >= 26 + 2

@@ -27,7 +27,14 @@ of 1,024 and 128 judged tokens through the two limits of
 ``harness/kinds/generation_share_state.py``, the wrong programs
 ``reference/ling3_flash.py``'s ``VARIANTS`` — the decay averaged over a
 head's channels, the softplus gate, beta times 2, the state kept in
-bfloat16, the head-wise gate left out; with
+bfloat16, the head-wise gate left out; with ``--config jamba2-3b
+--positions 634 --judged 128`` Jamba2-3B's: a prompt of 506 (its cell's
+longest judged) and 128 judged tokens through the two limits of
+``harness/kinds/generation_state.py``, the wrong programs
+``reference/jamba.py``'s ``VARIANTS`` — the three inner norms left out,
+``A_log`` without its ``-exp``, the softplus, the convolution's bias or
+``D x`` left out, the state kept in bfloat16, the attention layers
+rotated, an untied head, the one K/V head read as twenty; with
 ``--config xing4.0-29b-a4b --positions 4352 --judged 256`` Xing4.0's: the
 longest prompt of its cell and 256 judged tokens, through the two limits
 of its kind (``harness/kinds/generation_share_whole.py verdict``), the
@@ -493,9 +500,9 @@ def main() -> int:
     n = args.positions
     tokens = np.random.default_rng(args.seed).integers(0, model["vocab_size"], size=n).tolist()
     dots3 = config["reference"] == "dots3_note"
-    if config["reference"] in ("smallthinker", "olmo_hybrid", "ling3_flash"):
-        # (Olmo-Hybrid, Ling-3.0-flash: the same hooks — ``rounding=`` and
-        # the reference's own ``VARIANTS`` — under their kinds' two limits)
+    if config["reference"] in ("smallthinker", "olmo_hybrid", "ling3_flash", "jamba"):
+        # (Olmo-Hybrid, Ling-3.0-flash, Jamba: the same hooks — ``rounding=``
+        # and the reference's own ``VARIANTS`` — under their kinds' two limits)
         return smallthinker_readings(args, config, ref, params, tokens)
     if config["reference"] == "xing4":
         return xing4_readings(args, config, ref, params, tokens)
