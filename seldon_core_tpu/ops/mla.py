@@ -152,7 +152,10 @@ def naive_attention(q_nope, q_rope, ctx, ctx_len, seg, w_uk, w_uv,
 
 # ---------------------------------------------------------------------------
 # learned sparse attention: an indexer scores every earlier position and
-# a row attends over the best ``topk`` (DeepSeek sparse attention's form)
+# a row attends over the best ``topk`` (DeepSeek sparse attention's form).
+# The best are told by a threshold — the ``topk``-th largest score, found
+# by counting the row against candidates (:func:`_descend`), never by
+# sorting it — and kept as a mask
 # ---------------------------------------------------------------------------
 
 # queries :func:`indexed_attention` selects at once: the indexer's (B,
@@ -177,6 +180,39 @@ def index_scores(q_idx, w_idx, keys, scale: float):
     return s.sum(axis=1) * scale
 
 
+# bits of a threshold :func:`_descend` fixes in one pass over a row (a
+# divisor of 32): a pass holds the row against 2**bits - 1 candidates at
+# once inside one fused compare-and-count — the (3, lanes, span) compare
+# never leaves its fusion — and a float32's 32 bits take 32 / bits
+# passes.  On a v5e a pass costs ~1.3 us of launches and ~0.4 us a
+# candidate at (64, 7168): a decode step's mask 71 us at 1 bit, 60 at
+# 2, 108 at 4, 677 at 8, where the sort and the ties' running sum took
+# 436 (PERF.md section 6, PR 55)
+DESCENT_BITS = 2
+
+
+def _descend(holds, bits: int, shape):
+    """The largest ``t`` in ``[0, 2**bits)`` a row for which ``holds``,
+    where ``holds`` is true at 0 and, once false, false at every larger
+    ``t``: the bits of ``t`` fixed from the top, :data:`DESCENT_BITS` a
+    pass.  ``holds(cands)`` takes the candidates ``(2**DESCENT_BITS - 1,
+    *shape)`` int32 — bit patterns read as unsigned — and says which
+    hold, in the same shape; ``t`` comes back ``shape`` int32."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    t = jnp.zeros(shape, jnp.int32)
+    digits = np.arange(1, 2 ** DESCENT_BITS, dtype=np.uint32).reshape(
+        (-1,) + (1,) * len(shape))
+    top = (bits - 1) // DESCENT_BITS * DESCENT_BITS
+    for shift in range(top, -1, -DESCENT_BITS):
+        cands = t[None] | (digits << np.uint32(shift)).view(np.int32)
+        # the candidates ascend, so those that hold are a pass's first
+        # few: their count is the digit
+        t = t | (holds(cands).sum(axis=0, dtype=jnp.int32) << shift)
+    return t
+
+
 def kth_mask(scores, allowed, k: int):
     """Which of each row's ``allowed`` entries are among its ``k``
     largest, ties to the lower index: bool like ``scores`` ``(..., C)``.
@@ -184,18 +220,50 @@ def kth_mask(scores, allowed, k: int):
     selection rule: a prefill's rows (:func:`indexed_attention`) and a
     decode step's cached positions with its own (:func:`step_mask`)
     both take their chosen set from here, as a mask — no position list
-    is sorted out and no row gathered by it."""
+    is sorted out and no row gathered by it.
+
+    Nothing is sorted to find the ``k``-th largest score either: it is
+    the largest ``t`` with ``count(score >= t) >= k``, found by a descent
+    over the bits of the float32s' order-preserving integer image
+    (:func:`_descend`: a count of the row against 3 candidates a pass,
+    16 passes, where a sort orders the whole row to learn one of its
+    values), and the last admitted tie is the largest index with no more
+    than the room left of ties below it, by the same descent over the
+    index's bits (no running sum over the row).  The threshold goes back
+    to a float32 before anything is held against it: the scores are
+    ReLU-weighted sums with zeros of both signs, which are one score and
+    two bit patterns — compared as bits, a ``-0.0`` under a ``+0.0``
+    threshold would fall out of a tie it belongs to."""
     import jax
     import jax.numpy as jnp
 
-    if scores.shape[-1] <= k:
+    width = scores.shape[-1]
+    if width <= k:
         return allowed
-    s = jnp.where(allowed, scores, -jnp.inf)
-    kth = jax.lax.top_k(s, k)[0][..., -1:]      # -inf: fewer than k allowed
+    s = jnp.where(allowed, scores, -jnp.inf).astype(jnp.float32)
+    rows = s.shape[:-1] + (1,)
+    low = jnp.int32(-2 ** 31)
+
+    def image(b):  # float32 bits <-> signed ints in the floats' order
+        return b ^ ((b >> 31) & 0x7fffffff)
+
+    def count(hit):  # along a row
+        return hit.sum(axis=-1, keepdims=True, dtype=jnp.int32)
+
+    key = image(jax.lax.bitcast_convert_type(s, jnp.int32))
+    # an unsigned threshold t stands for the signed key t ^ low
+    t = _descend(lambda cands: count(key[None] >= (cands ^ low)) >= k, 32, rows)
+    # -inf where fewer than k are allowed
+    kth = jax.lax.bitcast_convert_type(image(t ^ low), jnp.float32)
     above = s > kth
     tie = (s == kth) & allowed
-    room = k - above.sum(axis=-1, keepdims=True)
-    return above | (tie & (jnp.cumsum(tie, axis=-1) <= room))
+    room = k - count(above)
+    at = jnp.arange(width, dtype=jnp.int32)
+    # ties below index t number `room` at the tie after the last admitted
+    # one; with no such tie every bit sets and every tie is below t
+    end = _descend(lambda cands: count(tie[None] & (at < cands)) <= room,
+                   width.bit_length(), rows)
+    return above | (tie & (at < end))
 
 
 def any_over(lengths, topk: int):
@@ -212,9 +280,13 @@ def step_mask(scores, own_score, lengths, topk: int):
     """A decode step's chosen set, as a mask: of each lane's ``lengths``
     cached positions (``scores`` ``(B, C)``, the table's whole span) and
     its own (``own_score`` ``(B,)``, position ``lengths``) the ``topk``
-    of largest score by :func:`kth_mask`'s rule.  Returns ``(cached (B,
-    C), own (B,))``: which cached rows are attended — what the page loop
-    takes as ``chosen`` — and whether the step's own row is."""
+    of largest score by :func:`kth_mask`'s rule (a count over the
+    span a pass of its descent, no sort of it; the own score and the cut
+    at the length are elementwise and fold into the one fusion that
+    makes the scores' integer image).
+    Returns ``(cached (B, C), own (B,))``: which cached rows are
+    attended — what the page loop takes as ``chosen`` — and whether the
+    step's own row is."""
     import jax.numpy as jnp
 
     at = jnp.arange(scores.shape[1])[None, :]

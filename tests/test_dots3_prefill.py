@@ -22,6 +22,82 @@ from test_dots3_paged import (
 )
 
 
+def _sorted_kth_mask(scores, allowed, k):
+    """``kth_mask`` as it stood until PR 55, the oracle of its rule: a
+    ``top_k`` for its last value, ties to the lower index by a running
+    sum."""
+    if scores.shape[-1] <= k:
+        return allowed
+    s = jnp.where(allowed, scores, -jnp.inf)
+    kth = jax.lax.top_k(s, k)[0][..., -1:]      # -inf: fewer than k allowed
+    above = s > kth
+    tie = (s == kth) & allowed
+    room = k - above.sum(axis=-1, keepdims=True)
+    return above | (tie & (jnp.cumsum(tie, axis=-1) <= room))
+
+
+KTH_CASES = (
+    "random", "a_third_tie", "zeros_of_both_signs", "few_none_exactly_k",
+    "k_is_one", "k_is_all_but_one", "no_more_columns_than_k",
+    "a_prefill_s_block", "extremes")
+
+
+def _kth_case(case, seed):
+    """``(scores, allowed, k)`` of one named case on one seed."""
+    rng = np.random.default_rng(100 + seed)
+    rows, width, k = 8, 200, 48
+    if case == "a_prefill_s_block":
+        # (B, 128 queries, L) under the causal triangle, few distinct values
+        batch, bq, width, k = 2, 128, 256, 100
+        scores = rng.integers(0, 9, size=(batch, bq, width)).astype(np.float32) / 4
+        first = width - bq
+        allowed = np.broadcast_to(
+            np.arange(width)[None, :] <= first + np.arange(bq)[:, None],
+            scores.shape)
+        return jnp.asarray(scores), jnp.asarray(allowed), k
+    scores = rng.standard_normal((rows, width)).astype(np.float32)
+    allowed = rng.random((rows, width)) < 0.7
+    if case == "a_third_tie":
+        scores = np.round(scores * 1.5) / 1.5
+    elif case == "zeros_of_both_signs":
+        # a ReLU-weighted sum's row: most of it zero, of either sign
+        scores = np.maximum(scores, 0.0) * rng.choice([1.0, -1.0], size=scores.shape)
+        scores = np.where(rng.random(scores.shape) < 0.5, scores, 0.0).astype(np.float32)
+        scores[:, ::3] = 0.0
+        scores[:, 1::6] = -0.0
+        allowed = rng.random((rows, width)) < 0.9
+    elif case == "few_none_exactly_k":
+        scores = np.round(scores * 2) / 2
+        allowed[0] = False
+        allowed[1] = False
+        allowed[1, rng.permutation(width)[:k]] = True        # exactly k
+        allowed[2] = False
+        allowed[2, rng.permutation(width)[:k - 1]] = True    # one short
+        allowed[3] = False
+        allowed[3, rng.permutation(width)[:k + 1]] = True    # one over
+        allowed[4] = True
+        scores[5] = 1.25                                     # one score a row
+    elif case == "k_is_one":
+        k = 1
+        scores[:4] = np.round(scores[:4])
+    elif case == "k_is_all_but_one":
+        k = width - 1
+        scores[:4] = np.round(scores[:4])
+    elif case == "no_more_columns_than_k":
+        k = width + seed                                     # C == k, C < k
+    elif case == "extremes":
+        # infinities among the allowed, the largest and smallest floats,
+        # subnormals of both signs
+        scores = np.round(scores * 2) / 2
+        pick = rng.integers(0, 8, size=scores.shape)
+        for n, value in enumerate((np.inf, -np.inf, 3.4028235e38, -3.4028235e38,
+                                   1e-45, -1e-45)):
+            scores = np.where(pick == n, np.float32(value), scores)
+        scores[0] = -np.inf
+        scores[1] = np.inf
+    return jnp.asarray(scores), jnp.asarray(allowed), k
+
+
 class TestSelection:
     def test_chosen_set_is_the_references_at_every_step(self):
         """``step_mask`` (a decode step: cached scores and the own) and
@@ -44,6 +120,40 @@ class TestSelection:
             chosen = set(np.nonzero(np.asarray(is_cached[0]))[0].tolist()) | (
                 {t} if bool(own[0]) else set())
             assert chosen == set(np.nonzero(want[t])[0].tolist()), t
+
+    @pytest.mark.parametrize("case", KTH_CASES)
+    def test_the_descent_s_mask_is_the_sort_s_bit_for_bit(self, case):
+        """``kth_mask`` finds its threshold by counting (PR 55) and hands
+        back what the sort-and-``cumsum`` rule, kept here as the oracle,
+        hands back — every entry of every row, on three seeds a case."""
+        for seed in range(3):
+            scores, allowed, k = _kth_case(case, seed)
+            want = np.asarray(_sorted_kth_mask(scores, allowed, k))
+            got = np.asarray(mla.kth_mask(scores, allowed, k))
+            assert got.dtype == want.dtype and got.shape == want.shape
+            np.testing.assert_array_equal(got, want, err_msg=f"{case} seed {seed}")
+
+    def test_a_step_s_own_column_is_chosen_as_the_sort_chooses_it(self):
+        """``step_mask`` puts the step's own score in column ``lengths``
+        and allows the columns up to it: the same cached rows and the
+        same verdict on the own as the oracle's mask of that very row,
+        at lengths under, at and over ``topk``, junk past the length."""
+        rng = np.random.default_rng(11)
+        span, topk = 96, 24
+        lengths = np.asarray([0, 5, topk - 1, topk, topk + 1, 60, span - 1], np.int32)
+        cached = np.round(rng.standard_normal((len(lengths), span)) * 2).astype(
+            np.float32) / 2
+        own = cached[np.arange(len(lengths)), (lengths * 7) % span]  # ties with a cached row
+        is_cached, own_in = mla.step_mask(
+            jnp.asarray(cached), jnp.asarray(own), jnp.asarray(lengths), topk)
+        at = np.arange(span)[None, :]
+        row = np.where(at == lengths[:, None], own[:, None], cached)
+        want = np.asarray(_sorted_kth_mask(
+            jnp.asarray(row), jnp.asarray(at <= lengths[:, None]), topk))
+        np.testing.assert_array_equal(
+            np.asarray(is_cached), want & (at < lengths[:, None]))
+        np.testing.assert_array_equal(
+            np.asarray(own_in), want[np.arange(len(lengths)), lengths])
 
     def test_the_kernel_lane_and_the_one_layer_lane_are_one_block(self, monkeypatch):
         """A full layer's decode step that selects, called as the kernel

@@ -543,7 +543,8 @@ def test_windowed_causal_kernel_compiles_at_the_cells_shapes(
 def test_the_selection_s_programs_compile_at_the_cells_shapes(one_chip, mosaic):
     """What a full layer adds: a decode step's scores of 7,168 cached
     indexer keys a lane (PR 51: a page loop over the key pool, no key
-    gathered), the best 2,048 as a mask and the page loop
+    gathered), the best 2,048 as a mask (PR 55: by counting, no sort in
+    either program) and the page loop
     under it (PR 40: no row is gathered, and the step's temporaries are
     the scores' alone); a prefill's indexed attention over 4,096
     positions, a block of queries at a time.  Neither may need more than
@@ -582,6 +583,8 @@ def test_the_selection_s_programs_compile_at_the_cells_shapes(one_chip, mosaic):
     # ... nor the (lanes x pages, 64, 128) array of gathered keys
     assert f"bf16[{lanes * pages},{PS},128]" not in text
     assert f"bf16[{lanes},{pages},{PS},128]" not in text
+    # ... and no row is sorted to learn its 2,048th score (PR 55)
+    assert "sort(" not in text
 
     seg = 4096
 
@@ -595,6 +598,7 @@ def test_the_selection_s_programs_compile_at_the_cells_shapes(one_chip, mosaic):
         spec((128, 512, 128)), spec((128, 512, 128)), spec((1, seg, 64, 128)),
         spec((1, seg, 64), jnp.float32), spec((1, seg, 128))).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+    assert "sort(" not in compiled.as_text()
 
 
 # ---------------------------------------------------------------------------

@@ -141,6 +141,9 @@ SHIPPED = {
 # to select) as PR 51 left it: the cached indexer keys are scored in the
 # page loop over the key pool (``ops/kernels.py index_scores_decode``);
 # its gather lane and every other line are unedited.
+# Both ``dots3_note`` lanes' third as PR 55 left it: the selection's
+# threshold by a descent over the scores' bits (``ops/mla.py kth_mask``),
+# the same mask bit for bit; every other hash is unedited.
 # A PR that changes what one of these specs traces on purpose measures
 # its cell and replaces the line.
 PARENT_SHA = {
@@ -150,8 +153,8 @@ PARENT_SHA = {
     "deepseek_v3/gather": ("63a3dfce2d02e8d0", "161d1c29369952f7", "7caf9f1a8407537c"),
     "longcat_flash/kernel": ("2940c8bcf2928dc3", "35c56975942fe0dc", "e826ad7641948da1"),
     "longcat_flash/gather": ("2940c8bcf2928dc3", "516e14cd27e54d4d", "09a1306ca2b66391"),
-    "dots3_note/kernel": ("4b9ed20957a8e327", "1c07de2a02b9fd84", "3a8da61fc72c238b"),
-    "dots3_note/gather": ("4b9ed20957a8e327", "efe0631bdb5ac2f0", "67b5f8b8bab76ba8"),
+    "dots3_note/kernel": ("4b9ed20957a8e327", "1c07de2a02b9fd84", "8c2993ac83c67110"),
+    "dots3_note/gather": ("4b9ed20957a8e327", "efe0631bdb5ac2f0", "c8defa565c8a704c"),
 }
 
 
