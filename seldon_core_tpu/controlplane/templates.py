@@ -293,7 +293,7 @@ def _build_kafka_logging(v: Dict[str, Any]) -> Dict[str, Any]:
 
 
 def _build_generation(v: Dict[str, Any]) -> Dict[str, Any]:
-    # param names match StreamingLM.__init__ (models/paged.py)
+    # param names match StreamingLM.__init__ (models/paged/component.py)
     params: Dict[str, Any] = {
         "d_model": v["d_model"], "num_layers": v["num_layers"],
         "num_heads": v["num_heads"], "vocab_size": v["vocab_size"],

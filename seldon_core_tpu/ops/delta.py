@@ -1,6 +1,6 @@
 """Gated-DeltaNet linear attention (arXiv:2412.06464, under the key names
 HF's ``linear_*`` config keys and FLA's ``GatedDeltaNet(allow_neg_eigval=)``
-use): what a ``"linear"`` layer of ``models/paged.py`` computes between
+use): what a ``"linear"`` layer of ``models/paged/blocks.py`` computes between
 its projections, and the one thing it keeps a lane — a state ``S`` of
 ``d_k x d_v`` float32 a head, whatever the context.
 

@@ -396,7 +396,7 @@ def prefill_attention_impl(seg_len: int, d_qk: int, d_v: int, dtype,
     The fused kernel (:func:`causal_attention`) where the segment starts
     at position zero (a table of no width: there is no cache to read),
     the engine's kernel lane serves (``kernel_lane``: the LM handed the
-    block the whole pool, ``models/paged.py
+    block the whole pool, ``models/paged/lanes.py
     paged_kernel_static_eligible`` — a TPU or the forced interpreter, no
     mesh, which GSPMD cannot partition the custom call over, and
     ``SELDON_TPU_PAGED_KERNEL`` not "0"), the operands are bfloat16, the
@@ -407,7 +407,7 @@ def prefill_attention_impl(seg_len: int, d_qk: int, d_v: int, dtype,
     indexed_attention``: the kernel under the chosen set's mask, or XLA
     a block of queries at a time).  The multi-head block does not ask:
     at its cells' shapes the v5e sweep reads XLA over the segment alone
-    as fast (``models/paged.py _segment_attention`` has the numbers)."""
+    as fast (``models/paged/blocks.py _segment_attention`` has the numbers)."""
     import jax.numpy as jnp
 
     if table_width or not kernel_lane or jnp.dtype(dtype) != jnp.bfloat16:
@@ -871,7 +871,7 @@ def _paged_attention_kernel(tables_ref, lens_ref, layer_ref, *refs,
 
     def pages_of(lane):
         # a lane masked done may hold more context than the table slice
-        # it was given (models/paged.py _pages_horizon): never read
+        # it was given (models/paged/engine.py _pages_horizon): never read
         # past it
         pages = jnp.minimum(
             jax.lax.div(lens_ref[lane] + page_size - 1, page_size), width)
@@ -1356,7 +1356,7 @@ def paged_attention_decode(q, pk, pv, block_tables, lengths, *, layer,
     traced is the one a caller that never heard of them traces.
 
     TPU-first replacement for the ``pk[layer, block_tables]`` gather in
-    ``PagedTransformerBlock`` (models/paged.py): the gather copies the
+    ``PagedTransformerBlock`` (models/paged/blocks.py): the gather copies the
     whole live cache through HBM per layer per step; here pages stream
     HBM->VMEM, indexed by the scalar-prefetched block table
     (the vLLM paged-attention idea recast in pallas; reference has no

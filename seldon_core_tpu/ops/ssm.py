@@ -1,6 +1,6 @@
 """A selective state-space layer (Mamba-1, arXiv:2312.00752, as HF's
 ``modeling_jamba.py JambaMambaMixer`` runs it): what an ``"ssm"`` layer of
-``models/paged.py`` computes between its projections, and the one thing
+``models/paged/blocks.py`` computes between its projections, and the one thing
 it keeps a lane — a state ``h`` of ``N x E`` float32 (``N`` =
 ``mamba_d_state`` columns, ``E`` = ``mamba_expand x hidden`` channels),
 whatever the context, beside the convolution's last inputs.

@@ -22,7 +22,7 @@ points, default 1).
 
 Registered injection points:
 
-* ``paged.alloc`` — ``PagedEngine._alloc_locked`` returns None (allocator
+* ``paged.alloc`` — ``PagedCache.alloc`` returns None (allocator
   exhaustion): exercises the stall/evict/rollback machinery.
 * ``paged.chunk`` — the decode/verify chunk raises *before* the device
   call is issued (buffers stay valid): exercises the engine's

@@ -153,7 +153,7 @@ _watching = False
 _totals: Tuple[int, float] = (0, 0.0)
 _ring: deque = deque(maxlen=COMPILE_RING)
 # thread ident -> a callable giving (where, wave) of the wave loop that
-# runs on that thread (models/paged.py _WaveSeam.compile_context), or None
+# runs on that thread (models/paged/seam.py _WaveSeam.compile_context), or None
 _contexts: Dict[int, Callable[[], Optional[Tuple[str, int]]]] = {}
 
 

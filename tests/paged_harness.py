@@ -285,8 +285,8 @@ def prefill_group(eng, prompts):
         last, pk, pv, *hist = eng._prefill_jit[bucket, k](
             eng.params, *eng._kv_args(), tokens, lens,
             jnp.asarray(tables(eng, k)[:, :pages_h]))
-    assert (pv is None) == (eng.pages_v is None)  # one pool or two
-    eng._store_kv(pk, pv)
+    assert (pv is None) == (eng.cache.pages_v is None)  # one pool or two
+    eng.cache.store(pk, pv)
     return np.asarray(last), (np.asarray(hist[0]) if hist else None)
 
 
@@ -314,7 +314,7 @@ def resume_group(eng, prompts, cached):
             eng.params, *eng._kv_args(), tokens, lens,
             jnp.full((k,), cached, jnp.int32), jnp.asarray(full[:, :rp]),
             jnp.asarray(full[:, first:first + wp]))
-    eng._store_kv(pk, pv)
+    eng.cache.store(pk, pv)
     return np.asarray(last)
 
 
@@ -349,7 +349,7 @@ def decode(eng, last, length, steps):
                 jnp.zeros((slots,), jnp.float32), jnp.zeros((slots,), jnp.int32),
                 jnp.full((slots,), -1, jnp.int32), jnp.arange(slots, dtype=jnp.int32))
         tok, pk, pv, logits, lengths_out, keys, _done, _emitted, moe_acc = out
-        eng._store_kv(pk, pv)
+        eng.cache.store(pk, pv)
         lengths = np.array(lengths_out)
         toks.append(int(tok[0, 0]))
         rows.append(np.asarray(logits[0]))
@@ -444,7 +444,7 @@ def held_nothing(eng):
         eng._check_invariants_locked()
     return (stats["full_pages_held"], stats["window_pages_held"],
             stats["pool_pages_used"]) == (0, 0, 0) and not (
-                eng._wtables.any() or eng._wbase.any())
+                eng.cache.wtables.any() or eng.cache.wbase.any())
 
 
 # ---- what a program traced ----

@@ -156,18 +156,18 @@ def test_a_prefill_call_is_not_padded_with_a_row_the_mxu_would_fill(rows, bucket
 
 
 def test_three_long_prompts_prefill_as_two_calls_and_pay_no_empty_row(params):
-    from seldon_core_tpu.models import paged
+    from seldon_core_tpu.models.paged import capacity  # (where the cuts read it)
 
     eng = _engine(params, max_slots=4)
     bucket = next(b for b in eng.prompt_buckets if b >= 40)
     streams = [eng.submit(_prompt(40, i + 1), max_new_tokens=3, seed=i + 1) for i in range(3)]
     before = eng.engine_stats()
-    old = paged.PREFILL_PAD_POSITIONS
-    paged.PREFILL_PAD_POSITIONS = bucket  # a toy row stands for a row of 1,024
+    old = capacity.PREFILL_PAD_POSITIONS
+    capacity.PREFILL_PAD_POSITIONS = bucket  # a toy row stands for a row of 1,024
     try:
         eng.run()
     finally:
-        paged.PREFILL_PAD_POSITIONS = old
+        capacity.PREFILL_PAD_POSITIONS = old
     after = eng.engine_stats()
     assert after["prefill_padded_tokens"] - before["prefill_padded_tokens"] == 3 * bucket
     assert after["prefill_head_rows"] - before["prefill_head_rows"] == 2 + 1

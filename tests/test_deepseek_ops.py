@@ -337,7 +337,7 @@ def test_engine_serves_and_counts_routing_and_latent_rows(own_engine):
     report = eng.lane_report()
     assert (report["arch"], report["attention"], report["cache_width"],
             report["experts_held"]) == ("deepseek_v3", "mla", 128, 4)
-    assert report["pool_shard_bytes"] == eng.pages_k.nbytes
+    assert report["pool_shard_bytes"] == eng.cache.pages_k.nbytes
 
     again = eng.submit(np.asarray(PROMPT, np.int32), max_new_tokens=8)
     eng.run()

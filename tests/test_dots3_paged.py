@@ -246,36 +246,36 @@ class TestWindowAllocator:
         is lost."""
         eng, _ = f32_engine
         rng = np.random.default_rng(seed)
-        total = eng.num_window_pages - 1
-        assert len(eng._free_wpages) == total
+        total = eng.cache.num_window_pages - 1
+        assert len(eng.cache.free_wpages) == total
         s = _fake_stream()
         length = int(rng.integers(1, 40))
-        s.wpages, s.wfirst = [], eng._window_first(length)
-        eng._window_ensure_locked(s, length, length)
+        s.wpages, s.wfirst = [], eng.cache.window_first(length)
+        eng.cache.window_ensure(s, length, length)
         held_before = {}
         while length < MAX_LEN - 1:
             steps = int(rng.integers(1, eng.max_steps + 1))
             horizon = min(length + steps, MAX_LEN)
-            eng._window_ensure_locked(s, length, horizon)
+            eng.cache.window_ensure(s, length, horizon)
             first = max(0, length - (WINDOW - 1))
-            base = int(eng._wbase[0])
+            base = int(eng.cache.wbase[0])
             assert base % PAGE == 0 and base <= first
-            row = eng._wtables[0]
+            row = eng.cache.wtables[0]
             for at in range(first, horizon):        # read or written this chunk
                 page = row[(at - base) // PAGE]
-                assert page != 0 and page not in eng._free_wpages
+                assert page != 0 and page not in eng.cache.free_wpages
                 # ... and the same page it was when the position was written
                 assert held_before.setdefault(at // PAGE, page) == page
             # nothing behind the window is kept: the table starts at the
             # page that holds the window's first position
             assert base == first // PAGE * PAGE
-            assert len(s.wpages) <= eng.window_pages
+            assert len(s.wpages) <= eng.cache.window_pages
             assert len(set(s.wpages)) == len(s.wpages)
-            assert len(eng._free_wpages) + len(s.wpages) == total
+            assert len(eng.cache.free_wpages) + len(s.wpages) == total
             length = horizon
-        eng._free_window_locked(s)
-        assert len(eng._free_wpages) == total
-        eng._wtables[0] = 0
+        eng.cache.free_window(s, eng._slots)
+        assert len(eng.cache.free_wpages) == total
+        eng.cache.wtables[0] = 0
 
     def test_pages_of_both_kinds_return_at_finish_eviction_and_abort(
             self, f32_engine):
@@ -313,14 +313,14 @@ class TestWindowAllocator:
         prompts = [PROMPTS[0]] * len(news)  # (one prefill bucket: few programs)
         streams = [eng.submit(np.asarray(p, np.int32), max_new_tokens=n)
                    for p, n in zip(prompts, news)]
-        total, prev, shared = eng.num_window_pages - 1, None, False
+        total, prev, shared = eng.cache.num_window_pages - 1, None, False
         while eng.has_work():
             nxt = eng.launch()
             with eng._lock:
                 holders = [s for s in streams if s.wpages]
                 assert all(eng._slots[s.slot] is s for s in holders)
                 assert sum(len(s.wpages) for s in holders) + len(
-                    eng._free_wpages) == total
+                    eng.cache.free_wpages) == total
                 # a finisher not yet read, its slot already a joiner's
                 shared |= any(
                     not s.event.is_set() and not s.wpages and s.slot is not None
@@ -342,14 +342,14 @@ class TestAccounting:
     def test_hbm_accounting_counts_the_kinds(self, f32_engine):
         eng, _ = f32_engine
         kinds = [(layers, lanes, WINDOW if name == "window" else 0)
-                 for name, layers, lanes in eng.cache_kinds]
+                 for name, layers, lanes in eng.cache.kinds]
         kw = dict(d_model=32, num_layers=4, page_size=PAGE, steps_per_call=1,
                   chunk_impl="pool", dtype_bytes=4, cache_pools=1,
                   cache_kinds=kinds)
         one = paged.paged_hbm_accounting(streams=1, ctx_len=MAX_LEN, **kw)
         full = MAX_LEN * (2 * 128 + 2 * 128) * 4
-        window = eng.window_pages * PAGE * 2 * 128 * 4
-        assert eng.window_pages == 4  # 8 + 1 positions across page edges, a step
+        window = eng.cache.window_pages * PAGE * 2 * 128 * 4
+        assert eng.cache.window_pages == 4  # 8 + 1 positions across page edges, a step
         assert (one["pool_bytes"], one["window_bytes"]) == (full + window, window)
         # a stream's bytes stop growing in the window layers
         short = paged.paged_hbm_accounting(streams=1, ctx_len=8, **kw)
@@ -357,8 +357,8 @@ class TestAccounting:
         assert paged.paged_capacity_streams(10 * (full + window), MAX_LEN, **kw) == 10
         assert paged.paged_max_context(full + window, max_len_cap=1 << 12, **kw) == MAX_LEN
         # ... and the engine's pools are what the accounting prices
-        assert eng._pool_shard_bytes == (
-            (eng.num_pages * 2 * 2 + eng.num_window_pages * 2) * PAGE * 128 * 4)
+        assert eng.cache.pool_shard_bytes == (
+            (eng.num_pages * 2 * 2 + eng.cache.num_window_pages * 2) * PAGE * 128 * 4)
 
     def test_lane_report_names_the_kinds(self, f32_engine):
         eng, _ = f32_engine
@@ -366,7 +366,7 @@ class TestAccounting:
         assert [(k["name"], k["layers"], k["width"], k["pages"])
                 for k in report["cache_kinds"]] == [
             ("full", 2, 128, eng.num_pages), ("index", 2, 128, eng.num_pages),
-            ("window", 2, 128, eng.num_window_pages)]
+            ("window", 2, 128, eng.cache.num_window_pages)]
         assert (report["index_topk"], report["window"]) == (TOPK, WINDOW)
         assert report["index_score_impl"] == INDEX_IMPL[eng.lane]
         assert report["window_table_pages"] == 4
@@ -424,6 +424,6 @@ class TestFences:
 
     def test_the_prefix_cache_stays_off_unasked(self, f32_engine):
         eng, _ = f32_engine
-        assert eng._prefix_cache_enabled is False
+        assert eng.cache.prefix_enabled is False
 
 

@@ -164,8 +164,9 @@ class TestSelection:
         keys scored and rows moved the lengths, rows read the chosen
         set's cached members."""
         monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "force")
-        paged.get_paged_lm_class()
-        block = paged._MODULES[0](
+        from seldon_core_tpu.models.paged.blocks import PagedTransformerBlock
+
+        block = PagedTransformerBlock(
             num_heads=SIZES["num_heads"], dtype=jnp.float32, spec=SPEC,
             routed_layer=False, kind=SPEC.attn_kind(0, SIZES["num_heads"]))
         rng = np.random.default_rng(5)
@@ -207,7 +208,7 @@ def _prefill_kernels(eng, bucket):
     pages = eng._pages_pow2(-(-bucket // PAGE))
     program = eng._build_prefill(bucket, 1).__wrapped__
     calls = harness.pallas_calls(
-        lambda *args: program(*args, window=(i32(1, eng.window_pages), i32(1))),
+        lambda *args: program(*args, window=(i32(1, eng.cache.window_pages), i32(1))),
         eng.params, *eng._kv_args(), i32(1, bucket), i32(1), i32(1, pages))
     return [name for name, _shapes in calls if name.startswith("prefill_")]
 
@@ -224,8 +225,8 @@ def _prefill_logits(eng, prompt):
         eng.params, *eng._kv_args(), jnp.asarray(tokens),
         jnp.asarray([len(prompt)], jnp.int32),
         i32(1, eng._pages_pow2(-(-bucket // PAGE))),
-        window=(i32(1, eng.window_pages), i32(1)))
-    eng._store_kv(pk, pv)
+        window=(i32(1, eng.cache.window_pages), i32(1)))
+    eng.cache.store(pk, pv)
     return np.asarray(last)[0]
 
 

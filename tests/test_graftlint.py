@@ -107,6 +107,9 @@ class TestSeededFixtures:
             _fixture("bad_lock_discipline.py"))
         syms = {(v.code, v.symbol) for v in vs}
         assert ("GL301", "BadEngine.bad_caller->_pop_locked") in syms
+        # the allocator the engine's lock guards lives in self.cache
+        assert ("GL301", "BadEngine.bad_cache_caller->cache.alloc") in syms
+        assert not [v for v in vs if "good_cache_caller" in v.symbol]
         assert ("GL302", "BadEngine.bad_writer._count") in syms
         assert ("GL302", "BadEngine.bad_writer._queue") in syms
         # lock-held callers and __init__ writes are clean

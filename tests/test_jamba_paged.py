@@ -180,8 +180,8 @@ def test_a_reused_slot_never_sees_the_old_stream_s_state_or_tail(engines):
     eng, params = engines("gather", max_slots=1)
     with harness.tracing(eng):
         first = harness.serve(eng, [PROMPTS[0]], 6)[0]
-        assert all(np.abs(np.asarray(s)).max() > 0 for s in eng._delta_state)
-        assert all(np.abs(np.asarray(t)).max() > 0 for t in eng._delta_conv)
+        assert all(np.abs(np.asarray(s)).max() > 0 for s in eng.cache.state)
+        assert all(np.abs(np.asarray(t)).max() > 0 for t in eng.cache.conv)
         second = harness.serve(eng, [PROMPTS[2]], 6)[0]
     for prompt, (tokens, rows) in ((PROMPTS[0], first), (PROMPTS[2], second)):
         np.testing.assert_allclose(rows, reference_rows(params, prompt, tokens),
@@ -211,8 +211,8 @@ def test_an_evicted_stream_restores_by_prefilling_again(engines):
 def test_bfloat16_serves_within_its_rounding(served, engines):
     out, params = served("gather", jnp.bfloat16)
     eng, _params = engines("gather", jnp.bfloat16, ctx_buckets="2")
-    assert eng._delta_state[0].dtype == jnp.float32      # the state stays float32
-    assert eng._delta_conv[0].dtype == jnp.bfloat16      # the tail rests as computed
+    assert eng.cache.state[0].dtype == jnp.float32      # the state stays float32
+    assert eng.cache.conv[0].dtype == jnp.bfloat16      # the tail rests as computed
     # the one tied matrix rests in the compute type, and there is no head
     assert eng.params["tok_embed"]["embedding"].dtype == jnp.bfloat16
     assert "head" not in eng.params
@@ -368,6 +368,6 @@ def test_containers_are_refused_by_name():
             with pytest.raises(ValueError, match="cannot take a state a lane yet"):
                 call()
         assert eng.migrate_export() == []
-        assert not eng._prefix_cache_enabled  # unset: off, whatever the env's default
+        assert not eng.cache.prefix_enabled  # unset: off, whatever the env's default
     finally:
         eng.close()

@@ -229,7 +229,7 @@ class TestTierDemotePromote:
         eng.generate(b, max_new_tokens=6)   # reclaims A's chain -> tier
         eng.generate(a, max_new_tokens=6)   # promotes A back
         with eng._lock:
-            hbm_keys = set(eng._prefix_index)
+            hbm_keys = set(eng.cache.prefix_index)
         assert not (eng._kv_tier.keys() & hbm_keys)
         s = eng.engine_stats()
         assert s["kv_tier_promotions"] >= 1
@@ -273,7 +273,7 @@ class TestTierDemotePromote:
         a = _sessions()[0]
         eng.generate(a, max_new_tokens=6)
         with eng._lock:
-            key = next(iter(eng._prefix_index))
+            key = next(iter(eng.cache.prefix_index))
         toks = tuple(range(8))
         eng._kv_tier.put(key, 0, toks, _container(toks))
         with eng._lock:

@@ -155,7 +155,7 @@ def test_engine_serves_and_counts_routing_and_latent_rows(own_engine):
     assert (report["arch"], report["attention"], report["cache_width"],
             report["cache_layers"], report["experts_held"]) == (
                 "longcat_flash", "mla", 128, 4, 4)
-    assert report["pool_shard_bytes"] == eng.pages_k.nbytes
+    assert report["pool_shard_bytes"] == eng.cache.pages_k.nbytes
     assert stats["moe_held_pass_rows"] == moe.held_rows_cap(SLOTS, k, 4, 12)
 
     again = eng.submit(np.asarray(PROMPT, np.int32), max_new_tokens=8)

@@ -61,9 +61,9 @@ def _reference(params, tokens, model=MODEL):
 def test_logits_match_the_reference_f32(engines, lane, program):
     eng, params = engines(lane)
     assert eng._kernel_active == (lane == "kernel")
-    assert eng._chunk_impl == "pool" and eng.pages_v is None
+    assert eng._chunk_impl == "pool" and eng.cache.pages_v is None
     # the pool's leading axis: 2 layers x 2 attentions; 20 values in 128 lanes
-    assert eng.pages_k.shape == (4, eng.num_pages, PAGE, 128)
+    assert eng.cache.pages_k.shape == (4, eng.num_pages, PAGE, 128)
     rows, tokens, at = run_program(eng, program)
     want = _reference(params, tokens)[at: at + len(rows)]
     assert np.abs(rows - want).max() < F32_ATOL

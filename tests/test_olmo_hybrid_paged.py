@@ -176,7 +176,7 @@ def test_a_reused_slot_never_sees_the_old_stream_s_state(engines):
     eng, params = engines("gather", max_slots=1)
     with harness.tracing(eng):
         first = harness.serve(eng, [PROMPTS[0]], 6)[0]
-        state_after = [np.asarray(s) for s in eng._delta_state]
+        state_after = [np.asarray(s) for s in eng.cache.state]
         assert all(np.abs(s).max() > 0 for s in state_after)
         second = harness.serve(eng, [PROMPTS[2]], 6)[0]
     for prompt, (tokens, rows) in ((PROMPTS[0], first), (PROMPTS[2], second)):
@@ -210,8 +210,8 @@ def test_an_evicted_stream_restores_by_prefilling_again(engines):
 def test_bfloat16_serves_within_its_rounding(served, engines):
     out, params = served("gather", jnp.bfloat16)
     eng, _params = engines("gather", jnp.bfloat16, ctx_buckets="2")
-    assert eng._delta_state[0].dtype == jnp.float32      # the state stays float32
-    assert eng._delta_conv[0].dtype == jnp.bfloat16      # the tail rests as computed
+    assert eng.cache.state[0].dtype == jnp.float32      # the state stays float32
+    assert eng.cache.conv[0].dtype == jnp.bfloat16      # the tail rests as computed
 
     for prompt, (tokens, rows) in zip(PROMPTS, out):
         want = reference_rows(params, prompt, tokens)
@@ -337,7 +337,7 @@ def test_containers_are_refused_by_name():
             with pytest.raises(ValueError, match="cannot take a state a lane yet"):
                 call()
         assert eng.migrate_export() == []
-        assert not eng._prefix_cache_enabled  # unset: off, whatever the env's default
+        assert not eng.cache.prefix_enabled  # unset: off, whatever the env's default
     finally:
         eng.close()
 

@@ -115,7 +115,7 @@ class TestPagedParity:
         assert all(s is None for s in eng._slots)
         # pool whole again: freed outright or parked on the prefix
         # cache's LRU (refcount 0, reclaimable) — nothing leaked
-        assert len(eng._free_pages) + len(eng._lru) == eng.num_pages - 1
+        assert len(eng.cache.free_pages) + len(eng.cache.lru) == eng.num_pages - 1
 
     def test_streams_join_mid_flight(self, lm):
         module, params = lm
@@ -199,7 +199,7 @@ class TestSpeculativeEngine:
         assert out[0] == first and (out[1:] == first).all()
         # slot + pages released
         assert all(s is None for s in spec._slots)
-        assert len(spec._free_pages) + len(spec._lru) == spec.num_pages - 1
+        assert len(spec.cache.free_pages) + len(spec.cache.lru) == spec.num_pages - 1
 
     def test_oracle_drafts_full_acceptance(self, lm):
         """draft='oracle' with the known continuation accepts every
@@ -620,7 +620,7 @@ class TestPageAccounting:
         for _ in range(3):
             eng.generate(np.arange(10, dtype=np.int32), max_new_tokens=5)
             # all returned: free or LRU-cached (reclaimable), none leaked
-            assert len(eng._free_pages) + len(eng._lru) == total
+            assert len(eng.cache.free_pages) + len(eng.cache.lru) == total
 
     def test_pool_smaller_than_worst_case_still_serves(self, lm):
         module, params = lm
@@ -660,7 +660,7 @@ class TestPageAccounting:
         assert a.event.is_set() and a.error is boom
         # pool whole again: freed outright or parked on the prefix
         # cache's LRU (refcount 0, reclaimable) — nothing leaked
-        assert len(eng._free_pages) + len(eng._lru) == eng.num_pages - 1
+        assert len(eng.cache.free_pages) + len(eng.cache.lru) == eng.num_pages - 1
         out = eng.generate(np.array([5, 9, 13], np.int32), max_new_tokens=4)
         want = _greedy_uncached(module, params, np.array([[5, 9, 13]]), 4)
         assert out.tolist() == want
@@ -683,7 +683,7 @@ class TestPageAccounting:
         assert b.result.tolist() == _greedy_uncached(module, params, pb[None], 14)
         # pool whole again: freed outright or parked on the prefix
         # cache's LRU (refcount 0, reclaimable) — nothing leaked
-        assert len(eng._free_pages) + len(eng._lru) == eng.num_pages - 1
+        assert len(eng.cache.free_pages) + len(eng.cache.lru) == eng.num_pages - 1
 
     def test_pool_wedge_evicts_victim_not_everyone(self, lm):
         """When every active stream stalls, the engine evicts the one
@@ -701,7 +701,7 @@ class TestPageAccounting:
         assert b.result.tolist() == _greedy_uncached(module, params, pb[None], 4)
         # pool whole again: freed outright or parked on the prefix
         # cache's LRU (refcount 0, reclaimable) — nothing leaked
-        assert len(eng._free_pages) + len(eng._lru) == eng.num_pages - 1
+        assert len(eng.cache.free_pages) + len(eng.cache.lru) == eng.num_pages - 1
 
     def test_queue_waits_for_free_slot(self, lm):
         module, params = lm
@@ -768,7 +768,7 @@ class TestMeshShardedDecode:
         _, params = lm
         mesh = create_mesh({"model": 4})  # 4 heads over 4 devices
         eng = _engine(params, mesh=mesh)
-        spec = eng.pages_k.sharding.spec
+        spec = eng.cache.pages_k.sharding.spec
         assert "model" in [ax for ax in spec if ax]  # heads axis sharded
 
     def test_component_mesh_axes(self, lm):

@@ -152,10 +152,10 @@ class TestInt8Gating:
         monkeypatch.setenv("SELDON_TPU_PAGED_KERNEL", "0")
         eng = _engine(params)
         try:
-            assert eng._kv_int8 is True
-            assert eng.pages_k.dtype == jnp.int8
-            assert eng.scales_k.dtype == jnp.float32
-            assert eng.scales_k.shape == (CFG["num_layers"], eng.num_pages)
+            assert eng.cache.int8 is True
+            assert eng.cache.pages_k.dtype == jnp.int8
+            assert eng.cache.scales_k.dtype == jnp.float32
+            assert eng.cache.scales_k.shape == (CFG["num_layers"], eng.num_pages)
             assert eng.engine_stats()["kv_dtype_int8"] == 1
         finally:
             eng.close()
@@ -167,9 +167,9 @@ class TestInt8Gating:
         monkeypatch.setenv("SELDON_TPU_CHUNK_IMPL", "ring")
         eng = _engine(params)
         try:
-            assert eng._kv_int8 is False
-            assert eng.scales_k is None
-            assert eng.pages_k.dtype == jnp.float32
+            assert eng.cache.int8 is False
+            assert eng.cache.scales_k is None
+            assert eng.cache.pages_k.dtype == jnp.float32
             assert "keeping the native pool dtype" in caplog.text
         finally:
             eng.close()

@@ -330,7 +330,7 @@ class TestMeshParitySmoke:
         eng = _engine(params, tp=2, dp=2, shard_min_weight_size=0)
         try:
             assert eng.num_pages % 2 == 0
-            assert tuple(eng.pages_k.sharding.spec) == (
+            assert tuple(eng.cache.pages_k.sharding.spec) == (
                 None, "data", None, "model",
             )
         finally:
@@ -351,7 +351,7 @@ class TestMeshParitySmoke:
         try:
             # pure throughput replicas: page dim replicated, decode
             # unchanged
-            assert tuple(eng.pages_k.sharding.spec)[1] is None
+            assert tuple(eng.cache.pages_k.sharding.spec)[1] is None
             outs = _serve(eng, _prompts())
         finally:
             eng.close()

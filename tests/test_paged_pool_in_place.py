@@ -143,10 +143,10 @@ class TestProgramsAddressThePoolInPlace:
     ):
         eng = _engine(params)
         try:
-            assert eng._kernel_active and eng.pages_k.ndim == 4
+            assert eng._kernel_active and eng.cache.pages_k.ndim == 4
             jaxpr, pools = _traced(eng._chunk_program(4, buckets),
                                    eng.chunk_example_args(buckets))
-            audit = _PoolAudit(eng.pages_k.shape)
+            audit = _PoolAudit(eng.cache.pages_k.shape)
             out = audit.walk(jaxpr, pools)
         finally:
             eng.close()
@@ -173,7 +173,7 @@ class TestProgramsAddressThePoolInPlace:
                 (eng.params, kv_k, kv_v, jnp.zeros((k, bucket), jnp.int32),
                  jnp.ones((k,), jnp.int32),
                  jnp.zeros((k, bucket // eng.page_size), jnp.int32)))
-            audit = _PoolAudit(eng.pages_k.shape)
+            audit = _PoolAudit(eng.cache.pages_k.shape)
             out = audit.walk(jaxpr, pools)
         finally:
             eng.close()
@@ -190,7 +190,7 @@ class TestProgramsAddressThePoolInPlace:
         try:
             jaxpr, pools = _traced(eng._chunk_program(4, ((4, 4),)),
                                    eng.chunk_example_args(((4, 4),)))
-            audit = _PoolAudit(eng.pages_k.shape)
+            audit = _PoolAudit(eng.cache.pages_k.shape)
             audit.walk(jaxpr, pools)
         finally:
             eng.close()
@@ -461,7 +461,7 @@ class TestDecodeLane:
             assert rep["tp"] == (2 if mesh else 1)
             assert eng.engine_stats()["kernel_active"] == int(kernel)
             # one layout, and nothing left that reports one
-            assert eng.pages_k.shape == (
+            assert eng.cache.pages_k.shape == (
                 LAYERS, eng.num_pages, eng.page_size, cfg["d_model"])
             assert set(rep) == {
                 "tp", "dp", "chunk_impl", "kv_dtype", "kernel_active",
@@ -499,7 +499,7 @@ class TestDecodeLane:
                 monkeypatch.setenv(name, value)
             eng = _engine(params)
             try:
-                reports.append((eng.lane_report(), eng.pages_k.shape))
+                reports.append((eng.lane_report(), eng.cache.pages_k.shape))
             finally:
                 eng.close()
         assert reports[0] == reports[1] and reports[0][0]["kernel_active"]
@@ -512,7 +512,7 @@ class TestDecodeLane:
         eng = _engine(params)
         try:
             rep = eng.lane_report()
-            assert eng.pages_k.ndim == 4 and rep["kernel_active"] is False
+            assert eng.cache.pages_k.ndim == 4 and rep["kernel_active"] is False
         finally:
             eng.close()
 
@@ -523,7 +523,7 @@ class TestDecodeLane:
         eng = _engine(params, tp=2)
         try:
             rep = eng.lane_report()
-            assert rep["tp"] == 2 and eng.pages_k.ndim == 4
+            assert rep["tp"] == 2 and eng.cache.pages_k.ndim == 4
             assert rep["kernel_active"] is False
         finally:
             eng.close()
@@ -550,12 +550,12 @@ class TestDecodeLane:
             heads = CFG["num_heads"]
             split = dict(good, layout="split", **{
                 n: good[n].reshape(*good[n].shape[:3], heads, -1) for n in "kv"})
-            before = np.asarray(eng.pages_k)
+            before = np.asarray(eng.cache.pages_k)
             with pytest.raises(MicroserviceError) as err:
                 eng.submit_prefilled(split, max_new_tokens=2)
             assert err.value.reason == "KV_LAYOUT_MISMATCH"
             assert err.value.status_code == 400
-            np.testing.assert_array_equal(np.asarray(eng.pages_k), before)
+            np.testing.assert_array_equal(np.asarray(eng.cache.pages_k), before)
             # the same pages as the pool holds them are taken
             stream = eng.submit_prefilled(good, max_new_tokens=2)
             eng.run()

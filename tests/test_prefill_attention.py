@@ -196,7 +196,7 @@ def _written(eng, prompts):
     rows = harness.tables(eng, len(prompts))
     pages = np.concatenate([rows[i, :-(-len(p) // PAGE)]
                             for i, p in enumerate(prompts)])
-    pools = [eng.pages_k] + ([] if eng.pages_v is None else [eng.pages_v])
+    pools = [eng.cache.pages_k] + ([] if eng.cache.pages_v is None else [eng.cache.pages_v])
     return [np.asarray(p[:, pages], np.float32) for p in pools]
 
 

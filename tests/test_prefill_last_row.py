@@ -163,7 +163,7 @@ def test_the_lowered_prefill_holds_no_all_position_logits(arch):
         i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
         more = {}
         if spec.kinds:
-            more["window"] = (i32(2, eng.window_pages), i32(2))
+            more["window"] = (i32(2, eng.cache.window_pages), i32(2))
         if spec.linear:
             more["slots"] = i32(2)
         with harness.tracing(eng):

@@ -93,7 +93,7 @@ class TestPrefixReuse:
         shared_pages = a.pages[:2]
         assert b.pages[:2] == shared_pages
         for p in shared_pages:
-            assert int(on._page_ref[p]) == 2
+            assert int(on.cache.page_ref[p]) == 2
         on.run()
         off = _engine(params, prefix_cache=False)
         np.testing.assert_array_equal(
@@ -104,8 +104,8 @@ class TestPrefixReuse:
         )
         # both finished: shared pages sit on the LRU exactly once
         for p in shared_pages:
-            assert int(on._page_ref[p]) == 0
-            assert p in on._lru
+            assert int(on.cache.page_ref[p]) == 0
+            assert p in on.cache.lru
 
     def test_env_knob_disables(self, params, monkeypatch):
         monkeypatch.setenv("SELDON_TPU_PREFIX_CACHE", "0")
@@ -115,12 +115,12 @@ class TestPrefixReuse:
         s = eng.engine_stats()
         assert s["prefix_hits"] == s["prefix_misses"] == 0
         assert s["prefix_pages_cached"] == 0
-        assert len(eng._free_pages) == eng.num_pages - 1  # all freed eagerly
+        assert len(eng.cache.free_pages) == eng.num_pages - 1  # all freed eagerly
 
     def test_constructor_arg_wins_over_env(self, params, monkeypatch):
         monkeypatch.setenv("SELDON_TPU_PREFIX_CACHE", "0")
         eng = _engine(params, prefix_cache=True)
-        assert eng._prefix_cache_enabled
+        assert eng.cache.prefix_enabled
         eng.generate(_shared_prompts()[0], max_new_tokens=4)
         assert eng.engine_stats()["prefix_pages_cached"] > 0
 
@@ -143,13 +143,13 @@ class TestAllocator:
         eng = _engine(params)
         with eng._lock:
             total = eng.num_pages - 1
-            got = eng._alloc_locked(3)
-            assert len(got) == 3 and len(eng._free_pages) == total - 3
-            assert all(int(eng._page_ref[p]) == 1 for p in got)
-            assert eng._alloc_locked(total) is None  # over capacity: refused
-            eng._free_locked(got)
-            assert len(eng._free_pages) == total
-            assert all(int(eng._page_ref[p]) == 0 for p in got)
+            got = eng.cache.alloc(3)
+            assert len(got) == 3 and len(eng.cache.free_pages) == total - 3
+            assert all(int(eng.cache.page_ref[p]) == 1 for p in got)
+            assert eng.cache.alloc(total) is None  # over capacity: refused
+            eng.cache.free(got)
+            assert len(eng.cache.free_pages) == total
+            assert all(int(eng.cache.page_ref[p]) == 0 for p in got)
 
     def test_alloc_reclaims_lru_cached_pages(self, params):
         eng = _engine(params)
@@ -158,7 +158,7 @@ class TestAllocator:
         assert s["prefix_pages_cached"] > 0
         with eng._lock:
             total = eng.num_pages - 1
-            got = eng._alloc_locked(total)  # must evict every cached page
+            got = eng.cache.alloc(total)  # must evict every cached page
             assert got is not None and len(got) == total
         s = eng.engine_stats()
         assert s["prefix_pages_cached"] == 0
@@ -184,8 +184,8 @@ class TestAllocator:
         eng.fail_all(RuntimeError("injected"))
         assert stream.pages == [] and stream.slot is not None
         with eng._lock:
-            eng._register_prefix_locked(stream)  # must not raise
-        assert not eng._prefix_index
+            eng._publish_prefix_locked(stream)  # must not raise
+        assert not eng.cache.prefix_index
 
     def test_invariant_checker_catches_corruption(self, params):
         eng = _engine(params)
@@ -193,10 +193,10 @@ class TestAllocator:
         eng.step()
         assert stream.slot is not None
         with eng._lock:
-            eng._free_pages.append(stream.pages[0])  # free AND mapped
+            eng.cache.free_pages.append(stream.pages[0])  # free AND mapped
             with pytest.raises(RuntimeError, match="invariant"):
                 eng._check_invariants_locked()
-            eng._free_pages.pop()
+            eng.cache.free_pages.pop()
             eng._check_invariants_locked()  # restored: clean
         eng.run()
 
@@ -241,7 +241,7 @@ class TestCollisionHardening:
         """With every chain key colliding, token-equality verification
         must keep foreign KV out of the match — different prompts stay
         private (and correct); identical prompts still share."""
-        monkeypatch.setattr(paged_mod, "prefix_chain_key", lambda p, t: 7)
+        monkeypatch.setattr(paged_mod.cache, "prefix_chain_key", lambda p, t: 7)
         eng = _engine(params)
         off = _engine(params, prefix_cache=False)
         p1 = (np.arange(20, dtype=np.int32) * 5) % CFG["vocab_size"]

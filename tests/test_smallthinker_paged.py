@@ -148,7 +148,7 @@ class TestPages:
         assert held["window_pages_held"] > 0 and held["full_pages_held"] > 0
         # (window pages are held by a stream in a slot only: at most a
         # table's worth a lane)
-        assert held["window_pages_held"] <= SLOTS * eng.window_pages
+        assert held["window_pages_held"] <= SLOTS * eng.cache.window_pages
         with eng._lock:
             eng._evict_locked(streams[0])
             eng._check_invariants_locked()

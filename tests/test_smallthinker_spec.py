@@ -171,7 +171,7 @@ def test_a_shipped_spec_lowers_as_on_the_parent(monkeypatch, case):
         pools = eng._kv_args()
         i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
         unwrap = lambda fn: fn if hasattr(fn, "lower") else fn.__wrapped__  # noqa: E731
-        kinds = {"window": (i32(2, eng.window_pages), i32(2))} if spec.kinds else {}
+        kinds = {"window": (i32(2, eng.cache.window_pages), i32(2))} if spec.kinds else {}
         texts = (
             unwrap(eng._build_prefill(16, 2)).lower(
                 eng.params, *pools, i32(2, 16), i32(2), i32(2, 4), **kinds).as_text(),
@@ -209,10 +209,10 @@ def test_the_accounting_counts_both_pools():
             steps_per_call=2, dtype_bytes=4, chunk_impl="pool", cache_kinds=kinds)
         # the pools hold every slot's tables full and a trash page each
         full = (4 * 16 + 1) * 4 * 32 * 4 * 2 * 1
-        win = (4 * eng.window_pages + 1) * 4 * 32 * 4 * 2 * 3
+        win = (4 * eng.cache.window_pages + 1) * 4 * 32 * 4 * 2 * 3
         assert report["pool_shard_bytes"] == full + win
-        assert said["pool_bytes"] == (4 * 16 * 1 + 4 * eng.window_pages * 3) * 4 * 32 * 4 * 2
-        assert said["window_bytes"] == 4 * eng.window_pages * 3 * 4 * 32 * 4 * 2
+        assert said["pool_bytes"] == (4 * 16 * 1 + 4 * eng.cache.window_pages * 3) * 4 * 32 * 4 * 2
+        assert said["window_bytes"] == 4 * eng.cache.window_pages * 3 * 4 * 32 * 4 * 2
         # a window layer stops growing: capacity and the longest context
         # follow the full layers alone past the window
         budget = said["pool_bytes"]

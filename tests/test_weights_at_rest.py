@@ -78,7 +78,7 @@ def _serve(eng, tree, steps):
     last, pk, pv = eng._build_prefill(bucket, 1)(
         tree, *eng._kv_args(), jnp.asarray(tokens),
         jnp.asarray([len(PROMPT)], jnp.int32), jnp.asarray(_table(pages_h)[None, :pages_h]))
-    eng._store_kv(pk, pv)
+    eng.cache.store(pk, pv)
     first = np.asarray(last[0])
     logits = jnp.zeros((SLOTS, CFG["vocab_size"]), jnp.float32).at[0].set(last[0])
     lengths = np.zeros((SLOTS,), np.int32)
@@ -97,7 +97,7 @@ def _serve(eng, tree, steps):
             jnp.zeros((SLOTS,), jnp.int32), jnp.full((SLOTS,), 99, jnp.int32),
             jnp.zeros((SLOTS,), jnp.float32), jnp.zeros((SLOTS,), jnp.int32),
             jnp.full((SLOTS,), -1, jnp.int32), jnp.arange(SLOTS, dtype=jnp.int32))
-        eng._store_kv(pk, pv)
+        eng.cache.store(pk, pv)
         lengths = np.array(lengths_out)
         toks.append(int(tok[0, 0]))
         rows.append(np.asarray(logits[0]))

@@ -63,8 +63,8 @@ def _reference(params, tokens, model=MODEL):
 def test_logits_match_the_reference_f32(engines, lane, program):
     eng, params = engines(lane)
     assert eng._kernel_active == (lane == "kernel")
-    assert eng._chunk_impl == "pool" and eng.pages_v is None
-    assert eng.pages_k.shape == (3, eng.num_pages, PAGE, 128)  # 20 values
+    assert eng._chunk_impl == "pool" and eng.cache.pages_v is None
+    assert eng.cache.pages_k.shape == (3, eng.num_pages, PAGE, 128)  # 20 values
     rows, tokens, at = run_program(eng, program)
     want = _reference(params, tokens)[at: at + len(rows)]
     assert np.abs(rows - want).max() < F32_ATOL
@@ -200,7 +200,7 @@ def test_the_cache_row_and_the_pool_are_deepseek_v3_s(engines):
     try:
         assert SPEC.cache_width(64) == plain_spec.cache_width(64) == 128
         assert (SPEC.cache_pools, SPEC.cache_values) == (1, 20)
-        assert eng.pages_k.shape == plain.pages_k.shape and eng.pages_v is None
+        assert eng.cache.pages_k.shape == plain.cache.pages_k.shape and eng.cache.pages_v is None
         a, b = eng.lane_report(), plain.lane_report()
         for key in ("pool_shard_bytes", "cache_width", "cache_layers", "attention",
                     "chunk_impl", "kv_dtype"):

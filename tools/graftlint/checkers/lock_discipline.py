@@ -10,7 +10,11 @@ those attributes from unlocked contexts are flagged.
 Rules:
 
 * GL301 — ``self._x_locked(...)`` called from a method that is neither
-  itself ``*_locked`` nor inside a ``with self.<lock>`` block.
+  itself ``*_locked`` nor inside a ``with self.<lock>`` block.  The
+  paged engine's allocator and prefix index live in ``self.cache``
+  (``models/paged/cache.py PagedCache``, which takes no lock of its
+  own: the caller holds the engine's), so ``self.cache.<books>(...)``
+  is held to the same rule.
 * GL302 — write to a lock-guarded ``self.<attr>`` (one that some
   ``*_locked`` method of the class also writes) outside lock scope
   (``__init__``/``__new__`` construct before the object escapes and
@@ -29,6 +33,32 @@ NAME = "lock-discipline"
 # a `with self.<attr>:` item counts as taking the lock when the attr
 # looks like one
 _LOCK_HINTS = ("lock", "mutex", "_cv", "_mu", "cond")
+
+
+# ``PagedCache`` methods that read or write the books the engine's lock
+# guards (free lists, reference counts, tables, the prefix index)
+_CACHE_BOOKS = frozenset((
+    "alloc", "free", "allocatable", "evict_cached", "seat", "ensure_pages",
+    "window_ensure", "free_window", "release", "match_prefix", "map_prefix",
+    "unmap_prefix", "register_prefix", "check_invariants",
+))
+
+
+def _locked_callee(call: ast.Call) -> Optional[str]:
+    """The callee's name where ``call`` is one the lock must cover:
+    ``self._x_locked(...)`` or ``self.cache.<books>(...)``."""
+    func = call.func
+    if not isinstance(func, ast.Attribute):
+        return None
+    owner = func.value
+    if isinstance(owner, ast.Name) and owner.id == "self" \
+            and func.attr.endswith("_locked"):
+        return func.attr
+    if isinstance(owner, ast.Attribute) and owner.attr == "cache" \
+            and isinstance(owner.value, ast.Name) and owner.value.id == "self" \
+            and func.attr in _CACHE_BOOKS:
+        return f"cache.{func.attr}"
+    return None
 
 
 def _is_lock_attr(attr: str) -> bool:
@@ -105,18 +135,16 @@ class _Checker:
             locked_here = in_lock or _with_takes_lock(node)
             # GL301: self.*_locked(...) calls
             for sub in self._shallow_walk(node):
-                if isinstance(sub, ast.Call) \
-                        and isinstance(sub.func, ast.Attribute) \
-                        and sub.func.attr.endswith("_locked") \
-                        and isinstance(sub.func.value, ast.Name) \
-                        and sub.func.value.id == "self" \
-                        and not locked_here:
+                callee = (_locked_callee(sub)
+                          if isinstance(sub, ast.Call) and not locked_here
+                          else None)
+                if callee is not None:
                     out.append(Violation(
                         checker=self.name, code="GL301", path=src.path,
                         line=sub.lineno,
-                        symbol=f"{cls.name}.{method.name}->{sub.func.attr}",
+                        symbol=f"{cls.name}.{method.name}->{callee}",
                         message=(
-                            f"self.{sub.func.attr}() called from "
+                            f"self.{callee}() called from "
                             f"{cls.name}.{method.name} without holding the "
                             "lock (not a *_locked method, not inside "
                             "`with self.<lock>:`)"

@@ -43,7 +43,7 @@ from tools.graftlint.core import LintContext, Source, Violation, str_const
 
 NAME = "metrics-contract"
 
-PAGED = "seldon_core_tpu/models/paged.py"
+PAGED = "seldon_core_tpu/models/paged/engine.py"
 METRICS = "seldon_core_tpu/utils/metrics.py"
 FLEETVIEW = "seldon_core_tpu/controlplane/fleetview.py"
 

@@ -24,6 +24,13 @@ class BadEngine:
     def bad_caller(self):
         return self._pop_locked()  # GL301: no lock held
 
+    def bad_cache_caller(self):
+        return self.cache.alloc(1)  # GL301: the cache's books, no lock held
+
+    def good_cache_caller(self):
+        with self._lock:
+            return self.cache.alloc(1)
+
     def bad_writer(self):
         self._count = 0  # GL302: lock-guarded state written outside the lock
         self._queue.append("x")  # GL302: container mutation outside the lock

@@ -50,7 +50,7 @@ def bandwidth(args, eng, np, jnp):
     with eng._lock:
         entries = [
             (e.key, e.parent, e.tokens, page)
-            for page, e in sorted(eng._page_entry.items())
+            for page, e in sorted(eng.cache.page_entry.items())
         ]
     entries = entries[: args.pages]
     if not entries:
@@ -61,12 +61,12 @@ def bandwidth(args, eng, np, jnp):
     # -- demote side: device->host gather, then per-page container pack
     t0 = time.perf_counter()
     idx = jnp.asarray(pages)
-    k = np.asarray(eng.pages_k[:, idx])
-    v = np.asarray(eng.pages_v[:, idx])
+    k = np.asarray(eng.cache.pages_k[:, idx])
+    v = np.asarray(eng.cache.pages_v[:, idx])
     ks = vs = None
-    if eng._kv_int8:
-        ks = np.asarray(eng.scales_k[:, idx])
-        vs = np.asarray(eng.scales_v[:, idx])
+    if eng.cache.int8:
+        ks = np.asarray(eng.cache.scales_k[:, idx])
+        vs = np.asarray(eng.cache.scales_v[:, idx])
     t_gather = time.perf_counter() - t0
 
     blobs = []
@@ -133,15 +133,15 @@ def bandwidth(args, eng, np, jnp):
         fn = eng._import_kv_jit[P] = eng._build_import_kv(P)
 
     def scatter():
-        kd = jnp.asarray(kc, eng._pool_dtype)
-        vd = jnp.asarray(vc, eng._pool_dtype)
-        if eng._kv_int8:
+        kd = jnp.asarray(kc, eng.cache.pool_dtype)
+        vd = jnp.asarray(vc, eng.cache.pool_dtype)
+        if eng.cache.int8:
             kd = (kd, jnp.asarray(np.concatenate(
                 [np.asarray(p["k_scales"]) for p in payloads], axis=1)))
             vd = (vd, jnp.asarray(np.concatenate(
                 [np.asarray(p["v_scales"]) for p in payloads], axis=1)))
         pk, pv = fn(eng.params, *eng._kv_args(), kd, vd, jnp.asarray(pages))
-        eng._store_kv(pk, pv)
+        eng.cache.store(pk, pv)
 
     scatter()  # compile outside the timed region
     t0 = time.perf_counter()
@@ -152,7 +152,7 @@ def bandwidth(args, eng, np, jnp):
            f"{'GiB/s':>9} {'ms/page':>9}")
     print(f"\nKV tier bandwidth — {P} pages x {eng.page_size} tokens, "
           f"{nbytes / (1 << 20):.1f} MiB of containers, "
-          f"pool={'int8+scales' if eng._kv_int8 else args.dtype}")
+          f"pool={'int8+scales' if eng.cache.int8 else args.dtype}")
     print(hdr)
     print("-" * len(hdr))
     print(_row("demote: gather (d2h)", P, nbytes, t_gather))

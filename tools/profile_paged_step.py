@@ -87,8 +87,8 @@ def main():
             out_shardings=ref.sharding,
         )()
 
-    pk = _make_pool(eng.pages_k)
-    pv = _make_pool(eng.pages_v)
+    pk = _make_pool(eng.cache.pages_k)
+    pv = _make_pool(eng.cache.pages_v)
     logits = jnp.zeros((B, args.vocab), jnp.float32)
     # every slot mid-generation at a distinct length
     lengths = jnp.asarray(
@@ -131,7 +131,7 @@ def main():
 
         def step(carry, _):
             pk, pv, lengths = carry
-            pk, pv = eng._write_kv(
+            pk, pv = eng.cache.write(
                 pk, pv, nk, nv, block_tables, lengths,
                 jnp.ones((B, 1), bool))
             return (pk, pv, lengths + 1), ()

@@ -69,7 +69,7 @@ CELLS["dots3"] = (128, 192, 128, 512, [(1, 3072), (1, 4096), (2, 3072), (2, 4096
 
 def xla_table(q, k, v, pool_k, pool_v, table, scale):
     """The multi-head gather path of a from-zero prefill before PR 33
-    (models/paged.py): every cached page scored and masked out."""
+    (models/paged/blocks.py): every cached page scored and masked out."""
     import jax
     import jax.numpy as jnp
 
@@ -91,7 +91,7 @@ def xla_table(q, k, v, pool_k, pool_v, table, scale):
 
 
 def xla_segment(q, k, v, scale):
-    """``_segment_attention``'s XLA form (models/paged.py)."""
+    """``_segment_attention``'s XLA form (models/paged/blocks.py)."""
     import jax
     import jax.numpy as jnp
 

@@ -1,5 +1,5 @@
 """What the wave loop's seam costs a wave with no profiler session open:
-the calls one chained wave makes to ``models/paged.py _WaveSeam`` (a
+the calls one chained wave makes to ``models/paged/seam.py _WaveSeam`` (a
 step, five phases, one prefill group and its three parts, the launch's
 three parts, two dispatches handed to the device clock (its watcher
 thread runs beside: nothing to wait on, so it only settles), a readback,

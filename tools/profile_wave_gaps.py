@@ -3,7 +3,7 @@ JAX profiler trace taken of a serving paged engine.
 
 The wave loop writes a ``seldon.wave`` step and its ``seldon.wave.<phase>``
 annotations onto the engine thread's line of the same ``.xplane.pb`` that
-holds the device's operations (``models/paged.py _WaveSeam``).  This tool
+holds the device's operations (``models/paged/seam.py _WaveSeam``).  This tool
 lays the gaps between the operations of device 0 over them:
 
 * the longest gaps, each with the seconds of it under every phase (the
