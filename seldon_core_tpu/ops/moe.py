@@ -42,7 +42,14 @@ prefill group.
 a correction bias that takes part in the *selection* alone, groups of
 experts scored by their two best of which the best few are kept, top-k
 among those, and the chosen experts' own sigmoid scores divided by
-their sum and scaled.
+their sum and scaled.  The group step sorts nothing (``kept_groups``):
+a group's best is a maximum, its second the maximum with the first's
+one position taken out, and a group is kept when fewer than
+``topk_group`` groups beat it (a larger score, or an equal one at a
+lower index — ``lax.top_k``'s rule, so the same mask); where every
+group is kept (``n_group == topk_group``, dots3's and Xing4's one
+group) no group step is traced at all.  The one ``top_k`` left is the
+last selection.
 
 ``route_zero`` is the LongCat-Flash router: softmax in float32 over the
 real experts and, after them, the identity ("zero-computation")
@@ -643,7 +650,11 @@ def route_grouped(h, w_router, bias, top_k: int, n_group: int,
     the experts of all but the ``topk_group`` best groups are set to 0
     (not -inf) and the ``top_k`` largest chosen; the gates are ``s``
     (never ``s + bias``) of the chosen, over their sum (+ 1e-20) when
-    ``norm``, times ``scale``.  Ties go to the lower index."""
+    ``norm``, times ``scale``.  Ties go to the lower index.
+
+    The group step sorts nothing (:func:`kept_groups`), and where every
+    group is kept (``topk_group >= n_group``, a fact of the spec) it is
+    not traced at all: no group's experts would be set to 0."""
     import jax
     import jax.numpy as jnp
 
@@ -655,19 +666,40 @@ def route_grouped(h, w_router, bias, top_k: int, n_group: int,
         )
         scores = jax.nn.sigmoid(logits)                       # (T, E)
         choice = scores + bias.astype(jnp.float32)
-        tokens, num_experts = choice.shape
-        grouped = choice.reshape(tokens, n_group, num_experts // n_group)
-        group_score = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)   # (T, G)
-        _, kept = jax.lax.top_k(group_score, topk_group)          # (T, g)
-        keep = jax.nn.one_hot(kept, n_group, dtype=jnp.int32).sum(axis=1) > 0
-        choice = jnp.where(keep[:, :, None], grouped, 0.0).reshape(
-            tokens, num_experts)
+        if topk_group < n_group:
+            tokens, num_experts = choice.shape
+            grouped = choice.reshape(tokens, n_group, num_experts // n_group)
+            keep = kept_groups(grouped, topk_group)
+            choice = jnp.where(keep[:, :, None], grouped, 0.0).reshape(
+                tokens, num_experts)
         _, experts = jax.lax.top_k(choice, top_k)
         gates = jnp.take_along_axis(scores, experts, axis=-1)
         if norm:
             gates = gates / (gates.sum(axis=-1, keepdims=True) + 1e-20)
         gates = gates * scale
     return gates, experts.astype(jnp.int32)
+
+
+def kept_groups(grouped, topk_group: int):
+    """``grouped`` ``(T, G, S)`` float32 -> ``bool (T, G)``: the
+    ``topk_group`` groups whose two largest add up to most, ties to the
+    lower index — the mask ``lax.top_k`` of ``lax.top_k(grouped, 2)``'s
+    sums gives, by maxima and a count.  A group's second largest is the
+    maximum with the one position ``argmax`` names taken out (a group
+    whose two largest are equal scores twice the first); a group is kept
+    when fewer than ``topk_group`` groups beat it, and one beats another
+    with a larger score, or an equal one at a lower index."""
+    import jax.numpy as jnp
+
+    n_group, size = grouped.shape[1:]
+    first = grouped.max(axis=-1)                              # (T, G)
+    at = jnp.argmax(grouped, axis=-1)
+    rest = jnp.where(jnp.arange(size) == at[..., None], -jnp.inf, grouped)
+    score = first + rest.max(axis=-1)
+    other, own = score[:, None, :], score[:, :, None]         # g' last, g before
+    index = jnp.arange(n_group)
+    beats = (other > own) | ((other == own) & (index[None, :] < index[:, None]))
+    return beats.sum(axis=-1) < topk_group
 
 
 def route_zero(h, w_router, bias, top_k: int, scale: float):

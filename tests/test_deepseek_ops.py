@@ -167,6 +167,110 @@ def test_routing_is_the_source_s_loop_ties_and_bias_included():
     assert all(len({x // (e // groups) for x in row}) <= keep for row in want_e)
 
 
+def _route_sorted(h, w_router, bias, top_k, n_group, topk_group, norm, scale):
+    """``moe.route_grouped`` as it stood before PR 57, verbatim: a group's
+    two largest by a sort of the group, the kept groups by a second
+    ``top_k`` and a ``one_hot``, whatever ``n_group``."""
+    with jax.named_scope(moe.ROUTER_SCOPE):
+        logits = jnp.dot(
+            h.astype(jnp.float32), w_router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+        scores = jax.nn.sigmoid(logits)                       # (T, E)
+        choice = scores + bias.astype(jnp.float32)
+        tokens, num_experts = choice.shape
+        grouped = choice.reshape(tokens, n_group, num_experts // n_group)
+        group_score = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)   # (T, G)
+        _, kept = jax.lax.top_k(group_score, topk_group)          # (T, g)
+        keep = jax.nn.one_hot(kept, n_group, dtype=jnp.int32).sum(axis=1) > 0
+        choice = jnp.where(keep[:, :, None], grouped, 0.0).reshape(
+            tokens, num_experts)
+        _, experts = jax.lax.top_k(choice, top_k)
+        gates = jnp.take_along_axis(scores, experts, axis=-1)
+        if norm:
+            gates = gates / (gates.sum(axis=-1, keepdims=True) + 1e-20)
+        gates = gates * scale
+    return gates, experts.astype(jnp.int32)
+
+
+def _two_best(grouped):
+    """``(T, G, 2)``: a group's largest and second largest, by numpy."""
+    return np.sort(grouped, axis=-1)[..., :-3:-1]
+
+
+def _tied_logits(kind, rng, tokens, e, groups, keep):
+    """``(logits (T, E), bias (E,))`` with ties of one kind in every row;
+    the router's weight is the identity, so a token's logits are its
+    ``h`` and equal logits are equal scores."""
+    size = e // groups
+    logits = rng.normal(size=(tokens, e)).astype(np.float32)
+    bias = np.zeros(e, np.float32)
+    if kind == "group_pair":        # a group's two largest are equal
+        grouped = logits.reshape(tokens, groups, size)
+        order = np.argsort(grouped, axis=-1)
+        best = np.take_along_axis(grouped, order[..., -1:], axis=-1)
+        np.put_along_axis(grouped, order[..., -2:-1], best, axis=-1)
+        top = _two_best(grouped)
+        assert np.array_equal(top[..., 0], top[..., 1])
+    elif kind == "boundary_groups":  # the last kept and the first dropped group tie
+        grouped = logits.reshape(tokens, groups, size)
+        rank = np.argsort(-_two_best(grouped).sum(-1), axis=-1, kind="stable")
+        if keep < groups:
+            rows = np.arange(tokens)
+            grouped[rows, rank[:, keep]] = grouped[rows, rank[:, keep - 1]]
+            score = _two_best(grouped).sum(-1)
+            assert np.array_equal(score[rows, rank[:, keep]], score[rows, rank[:, keep - 1]])
+    elif kind == "kth_expert":      # five levels: the k-th and the next are equal
+        logits = rng.integers(-2, 3, size=(tokens, e)).astype(np.float32) * 0.5
+    elif kind == "constant_row":    # every score 0.5: the index alone decides
+        logits[:] = 0.0
+    elif kind == "constant_row_biased":  # ... and a bias of few levels does
+        logits[:] = 0.0
+        bias = np.round(rng.uniform(-0.2, 0.2, size=e), 1).astype(np.float32)
+    elif kind == "negative_bias":   # every selection score under the masked groups' 0.0
+        bias = rng.uniform(-2.0, -1.0, size=e).astype(np.float32)
+    elif kind == "two_decimals":    # hundreds of ties a row, of every kind at once
+        logits = np.round(logits, 2)
+        bias = np.round(rng.uniform(-0.6, 0.1, size=e), 1).astype(np.float32)
+    else:
+        raise AssertionError(kind)
+    return logits, bias
+
+
+ROUTER_SHAPES = [(512, 8, 4, 8), (256, 8, 4, 8), (256, 1, 1, 8), (64, 1, 1, 4), (16, 4, 2, 4)]
+ROUTER_TIES = ["group_pair", "boundary_groups", "kth_expert", "constant_row",
+               "constant_row_biased", "negative_bias", "two_decimals"]
+
+
+@pytest.mark.parametrize("kind", ROUTER_TIES)
+@pytest.mark.parametrize("e,groups,keep,k", ROUTER_SHAPES,
+                         ids=["ling", "gigachat", "dots3", "xing4", "tiny"])
+def test_the_group_step_by_maxima_is_the_sort_s_bit_for_bit(e, groups, keep, k, kind):
+    """The gates' bits and the experts in their order, against the three
+    ``top_k`` form, at each cell's router and on ties of each kind."""
+    rng = np.random.default_rng(ROUTER_TIES.index(kind) * 7 + e)
+    logits, bias = _tied_logits(kind, rng, 96, e, groups, keep)
+    args = (jnp.asarray(logits), jnp.eye(e, dtype=jnp.float32), jnp.asarray(bias))
+    static = (k, groups, keep, True, 2.5)
+    want_w, want_e = jax.jit(lambda *a: _route_sorted(*a, *static))(*args)
+    got_w, got_e = jax.jit(lambda *a: moe.route_grouped(*a, *static))(*args)
+    assert got_e.dtype == jnp.int32 and got_w.dtype == jnp.float32
+    assert np.array_equal(np.asarray(got_e), np.asarray(want_e))
+    assert np.array_equal(np.asarray(got_w).view(np.int32), np.asarray(want_w).view(np.int32))
+    # the identity weight kept the ties: a token's scores are its logits'
+    scores = np.asarray(jax.nn.sigmoid(jnp.asarray(logits)))
+    picked = np.take_along_axis(scores, np.asarray(got_e), axis=-1)
+    assert np.abs(np.asarray(got_w) - 2.5 * picked / picked.sum(-1, keepdims=True)).max() < 1e-6
+    if kind == "negative_bias" and keep < groups:
+        # HF masks with 0.0, not -inf: under such a bias a dropped group's
+        # 0.0 outranks every kept expert, and the picks are dropped groups'
+        choice = (scores + bias).reshape(len(scores), groups, e // groups)
+        dropped = np.argsort(-_two_best(choice).sum(-1), axis=-1, kind="stable")[:, keep:]
+        assert all(x // (e // groups) in row for row, xs in zip(dropped, np.asarray(got_e))
+                   for x in xs)
+
+
 def test_yarn_frequencies_and_scale_are_the_closed_form():
     spec = DEEPSEEK_V3
     dim, base, factor, orig = 64, 100_000.0, 64.0, 4096

@@ -650,8 +650,10 @@ def test_index_scores_refuses_a_pool_it_cannot_read():
 # kernel lane), as the tree before PR 51 lowered it (sha256 of the
 # lowered text): only a kind with ``index_topk`` reaches the scoring, so
 # every other configuration's chunk is the one it was.  A PR that changes
-# that chunk on purpose measures those cells and replaces this.
-NO_INDEXER_CHUNK = "1ac3880c62d8c1f1"
+# that chunk on purpose measures those cells and replaces this.  (PR 57
+# did: the router's group step by maxima and a count, ``ops/moe.py
+# kept_groups``, the same gates and experts bit for bit.)
+NO_INDEXER_CHUNK = "3261aa51d6465dbc"
 
 
 def test_a_spec_without_an_indexer_traces_the_chunk_it_traced(monkeypatch):

@@ -297,6 +297,55 @@ def test_held_expert_layer_compiles_with_rows_of_a_pass(one_chip, mosaic, backen
     assert compiled.memory_analysis().temp_size_in_bytes < 12 * rows * d * 4
 
 
+# (cell, rows of a call, d_model, experts, n_group, topk_group, k): a
+# decode step's and a prefill group's rows through each cell's router
+ROUTER_SHAPES = [
+    ("ling", 128, 4096, 512, 8, 4, 8), ("ling", 2048, 4096, 512, 8, 4, 8),
+    ("gigachat", 128, 7168, 256, 8, 4, 8), ("gigachat", 2048, 7168, 256, 8, 4, 8),
+    ("dots3", 128, 5120, 256, 1, 1, 8), ("xing4", 4096, 3584, 64, 1, 1, 4),
+]
+
+
+@pytest.mark.parametrize("cell,rows,d,e,n_group,topk_group,k", ROUTER_SHAPES, ids=[
+    f"{c}-{r}" for c, r, *_ in ROUTER_SHAPES])
+def test_the_grouped_router_sorts_once_at_the_cells_shapes(
+        one_chip, mosaic, cell, rows, d, e, n_group, topk_group, k):
+    """``route_grouped`` traces one ``top_k`` — the last selection — and no
+    ``sort``, and the chip's compiler makes one sort of it, over ``(rows,
+    experts)``: nothing sorts a group (PR 57; ``sort_f32_128_8_64_`` was
+    a twentieth of the Ling cell's busy time).  Where every group is kept
+    the group step is not traced: the program is the plain sigmoid
+    router's, operation for operation."""
+    from seldon_core_tpu.ops import moe
+
+    def spec(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def grouped(h, w, bias):
+        return moe.route_grouped(h, w, bias, k, n_group, topk_group, True, 2.5)
+
+    def plain(h, w, bias):
+        with jax.named_scope(moe.ROUTER_SCOPE):
+            scores = jax.nn.sigmoid(jnp.dot(
+                h, w, precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32))
+            _, experts = jax.lax.top_k(scores + bias, k)
+            gates = jnp.take_along_axis(scores, experts, axis=-1)
+            gates = gates / (gates.sum(axis=-1, keepdims=True) + 1e-20) * 2.5
+        return gates, experts.astype(jnp.int32)
+
+    shapes = (spec((rows, d)), spec((d, e)), spec((e,)))
+    jaxpr = str(jax.make_jaxpr(grouped)(*shapes))
+    assert jaxpr.count(" top_k[") == 1 and " sort[" not in jaxpr, jaxpr
+    if topk_group >= n_group:
+        assert jaxpr == str(jax.make_jaxpr(plain)(*shapes))
+    else:
+        assert "argmax" in jaxpr and jaxpr.count("reduce_max") == 2
+    text = jax.jit(grouped).lower(*shapes).compile().as_text()
+    sorts = [ln for ln in text.splitlines() if " sort(" in ln and " = " in ln]
+    assert len(sorts) == 1 and f"f32[{rows},{e}]" in sorts[0], sorts
+
+
 # ---------------------------------------------------------------------------
 # LongCat-Flash (PR 32): the same two kernels at another pool and other widths
 # ---------------------------------------------------------------------------

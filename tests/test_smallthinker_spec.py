@@ -144,17 +144,23 @@ SHIPPED = {
 # Both ``dots3_note`` lanes' third as PR 55 left it: the selection's
 # threshold by a descent over the scores' bits (``ops/mla.py kth_mask``),
 # the same mask bit for bit; every other hash is unedited.
+# ``deepseek_v3`` and ``dots3_note``, both lanes, all three, as PR 57 left
+# them: ``ops/moe.py route_grouped``'s group step by maxima and a count
+# (two groups of which one is kept) and not traced where every group is
+# kept (``dots3_note``'s one), the same gates and experts bit for bit
+# (``tests/test_deepseek_ops.py``); ``olmoe`` and ``longcat_flash``, which
+# route by other functions, are unedited.
 # A PR that changes what one of these specs traces on purpose measures
 # its cell and replaces the line.
 PARENT_SHA = {
     "olmoe/kernel": ("2b7346bf4ebf6f97", "cf4d3494fbabf487", "bd5e3f041abd3a30"),
     "olmoe/gather": ("af5c0d85895c1373", "d1d143ef68dd754f", "a564dbb262ecc1d5"),
-    "deepseek_v3/kernel": ("63a3dfce2d02e8d0", "147b6b2da8391aad", "fb83e7965316425b"),
-    "deepseek_v3/gather": ("63a3dfce2d02e8d0", "161d1c29369952f7", "7caf9f1a8407537c"),
+    "deepseek_v3/kernel": ("6794a9292c3c4d04", "00ec546f7040f6f6", "2927130ce304ecee"),
+    "deepseek_v3/gather": ("6794a9292c3c4d04", "ed3daeeb9375dce9", "6f599d5baec12e30"),
     "longcat_flash/kernel": ("2940c8bcf2928dc3", "35c56975942fe0dc", "e826ad7641948da1"),
     "longcat_flash/gather": ("2940c8bcf2928dc3", "516e14cd27e54d4d", "09a1306ca2b66391"),
-    "dots3_note/kernel": ("4b9ed20957a8e327", "1c07de2a02b9fd84", "8c2993ac83c67110"),
-    "dots3_note/gather": ("4b9ed20957a8e327", "efe0631bdb5ac2f0", "c8defa565c8a704c"),
+    "dots3_note/kernel": ("1629dd5ae22fff87", "f489262fc226e2fd", "39c671543e739a48"),
+    "dots3_note/gather": ("1629dd5ae22fff87", "74e3922e675415ba", "412879d5bdbb9b06"),
 }
 
 
